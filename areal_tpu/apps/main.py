@@ -14,6 +14,11 @@ Two execution modes:
 - run_experiment(plan): ZMQ multi-process — one subprocess per
   WorkerConfig, file-backed name-resolve for discovery, recover retry loop
   re-submitting everything on failure (reference recover mode "auto").
+  This launcher process never initializes a JAX backend: workers inherit
+  JAX_PLATFORMS like any JAX program, and on a TPU host the first worker
+  to start owns the chips (a second colocated worker fails on "TPU
+  already in use"; disjoint gen/train meshes on one host are the
+  in-process, device-offset form).
 """
 
 import asyncio
@@ -49,12 +54,16 @@ def _make_master(plan: ExperimentPlan, pool) -> MasterWorker:
     )
 
 
-def run_experiment_inproc(plan: ExperimentPlan, tokenizer=None):
+def run_experiment_inproc(plan: ExperimentPlan, tokenizer=None, inspect=None):
     """All workers in this process — delegates to the canonical in-process
-    runner (areal_tpu/experiments/common.py run_experiment)."""
+    runner (areal_tpu/experiments/common.py run_experiment).
+
+    `inspect(master, stage)` is the canonical runner's hook (stage "built",
+    then "done"); chip_smoke.py checks weights, counters and device
+    residency through it."""
     from areal_tpu.experiments.common import run_experiment as _run_inproc
 
-    _, stats = _run_inproc(plan, tokenizer=tokenizer)
+    _, stats = _run_inproc(plan, tokenizer=tokenizer, inspect=inspect)
     return stats
 
 
@@ -169,12 +178,6 @@ def run_experiment(
         if trace_dir:
             env["AREAL_TRACE"] = os.environ.get("AREAL_TRACE", "1")
             env["AREAL_TRACE_DIR"] = trace_dir
-        if scheduler_mode != "tpu-pod":
-            # Colocated workers default to CPU: one process owns the TPU
-            # runtime (apps/worker.py applies this via jax.config, since
-            # a site PJRT plugin may ignore JAX_PLATFORMS).  On a TPU pod
-            # each worker runs on its OWN host and must claim its chips.
-            env["AREAL_WORKER_PLATFORM"] = "cpu"
         env.update(worker_env or {})
         sched = make_scheduler(
             scheduler_mode,

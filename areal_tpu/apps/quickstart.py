@@ -203,13 +203,11 @@ def _ctrl(args) -> ExperimentSaveEvalControl:
 
 
 def _run(plan, args):
-    # Deferred here so `--help`/arg errors never pay the jax import.
-    from areal_tpu.base import compilation_cache
-
-    compilation_cache.enable()
     from areal_tpu.apps import main as runner
 
     if args.multiprocess or args.launcher != "local":
+        # This launcher must stay off the JAX backend: a chip belongs to
+        # one process, and the workers are the ones that need it.
         kwargs = {}
         if args.launcher == "tpu-pod":
             if not args.tpu_name:
@@ -226,6 +224,10 @@ def _run(plan, args):
             scheduler_mode=args.launcher,
             scheduler_kwargs=kwargs,
         )
+    # Deferred here so `--help`/arg errors never pay the jax import.
+    from areal_tpu.base import compilation_cache
+
+    compilation_cache.enable()
     return runner.run_experiment_inproc(plan)
 
 

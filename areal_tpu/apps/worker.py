@@ -23,15 +23,6 @@ def main():
     p.add_argument("--trial", required=True)
     args = p.parse_args()
 
-    # Workers colocated on one host run on CPU devices unless told otherwise
-    # (one process owns the TPU runtime; see scheduler/local.py).
-    if os.environ.get("AREAL_WORKER_PLATFORM"):
-        import jax
-
-        jax.config.update(
-            "jax_platforms", os.environ["AREAL_WORKER_PLATFORM"]
-        )
-
     from areal_tpu.base import (
         compilation_cache,
         logging,

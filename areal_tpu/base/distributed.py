@@ -89,34 +89,10 @@ def is_primary() -> bool:
     return jax.process_index() == 0
 
 
-_is_tpu: Optional[bool] = None
-
-
 def is_tpu_backend() -> bool:
-    """True when the default backend's devices are TPU silicon.
+    """True when JAX's default backend is the TPU.  Everything gated on
+    this (compiled vs interpreted Pallas kernels, flash vs dense
+    attention) follows the platform JAX reports and nothing else."""
+    import jax
 
-    `jax.default_backend() == "tpu"` misses tunneled/plugin PJRT
-    platforms (e.g. a remote TPU exposed under a different platform
-    name) whose devices ARE TPUs — and everything gated on it (Pallas
-    kernels vs interpret mode, flash vs dense attention) silently falls
-    back to catastrophically slower paths.  Trust the device kind, not
-    the platform name.
-    """
-    global _is_tpu
-    if _is_tpu is None:
-        import jax
-
-        if jax.default_backend() == "tpu":
-            _is_tpu = True
-        else:
-            try:
-                kind = jax.devices()[0].device_kind
-            except Exception as e:
-                # Don't memoize a failed probe: a transient backend error
-                # here would otherwise pin the whole process on the slow
-                # non-TPU paths (interpret-mode Pallas, dense attention).
-                logger.warning(f"device-kind probe failed ({e!r}); "
-                               "treating backend as non-TPU for this call")
-                return False
-            _is_tpu = "tpu" in str(kind).lower()
-    return _is_tpu
+    return jax.default_backend() == "tpu"

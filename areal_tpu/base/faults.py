@@ -32,7 +32,7 @@ Semantics at a ``fire(point)`` call site:
 - ``error`` — raise :class:`FaultError` (p-gated), which the server
   surfaces to the client as an ordinary request failure;
 - ``hang``  — block (p-gated) until :meth:`FaultInjector.release` or the
-  ``hang_max_s`` safety cap, simulating a wedged server;
+  ``hang_max_s`` safety cap, simulating a hung server;
 - ``nan`` / ``corrupt_push`` — PASSIVE numerical-corruption kinds for
   the integrity guard plane: ``fire`` never applies them; the host asks
   :meth:`FaultInjector.poison` at a named data boundary (the train

@@ -77,8 +77,8 @@ def flops_generate(
     return total
 
 
-# Peak bf16 TFLOP/s per chip by accelerator kind (public specs); used for
-# MFU.  Override with AREAL_PEAK_TFLOPS.
+# Peak bf16 TFLOP/s per chip, keyed by a substring of the lower-cased
+# `device_kind` JAX reports (public TPU specs; the v5e says "TPU v5 lite").
 _PEAK_TFLOPS = {
     "v4": 275.0,
     "v5 lite": 197.0,  # v5e
@@ -90,19 +90,22 @@ _PEAK_TFLOPS = {
 
 
 def peak_tflops_per_device() -> Optional[float]:
-    env = os.environ.get("AREAL_PEAK_TFLOPS")
-    if env:
-        return float(env)
-    try:
-        import jax
+    """Peak of one device of the default backend.  None on CPU (no MFU
+    there); an accelerator whose `device_kind` is not in the table is an
+    error, so MFU never silently drops out of the stats on a chip."""
+    import jax
 
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
         return None
+    kind = dev.device_kind.lower()
     for key, val in _PEAK_TFLOPS.items():
         if key in kind:
             return val
-    return None
+    raise ValueError(
+        f"no peak FLOP/s entry for device_kind {dev.device_kind!r} "
+        f"(platform {dev.platform!r}); add it to _PEAK_TFLOPS"
+    )
 
 
 def mfu(flops: float, seconds: float, n_devices: int) -> Optional[float]:

@@ -289,8 +289,8 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         self.max_decode_batch = max_decode_batch
         # Decode budget above which generate() refuses the static
         # single-program path even when every request fits one pool (see
-        # generate() routing): 2048 steps ≈ tens of seconds per program,
-        # comfortably under device-runtime watchdogs.
+        # generate() routing): 2048 steps ≈ tens of seconds in one
+        # program the host cannot interrupt or retire rows from.
         self.static_path_max_new = 2048
         # "auto" = compute dtype; "int8" halves KV HBM per token (the
         # long-context capacity bound — see models.transformer.KVCache).
@@ -706,8 +706,8 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             # inflight wins when stragglers would otherwise stall retired
             # slots.  Long decodes ALWAYS go inflight: the static path is
             # one device program whose while_loop runs the whole decode
-            # (minutes on-device at 16k+ steps — TPU runtime watchdogs
-            # kill it as a stuck kernel) and allocates the full final KV
+            # (minutes on-device at 16k+ steps, with no chunk boundary for
+            # the host to act at) and allocates the full final KV
             # window from step 0, streaming depth it doesn't need yet on
             # every step; the inflight chunk loop keeps each program
             # ~chunk_t tokens and grows the window geometrically.

@@ -172,27 +172,18 @@ class TrainEngine(HostOffloadMixin, Engine):
             self.optimizer_config, max(self.ftspec.total_train_steps, 1)
         )
 
-        # Optimizer state mirrors param shapes; jitting init lets the SPMD
-        # partitioner give mu/nu the same shardings as the params (ZeRO-1).
-        self.opt_state = jax.jit(self.optimizer.init)(self.params)
-        # Commit the state to its shardings (no copy): the apply jits pin
-        # their out_shardings to these, so the params/opt/guard carry run
-        # through train steps with byte-identical cache keys — one compiled
-        # executable per apply fn for the whole trial, checkpoint restores
-        # included.
-        # Leaves the partitioner left off-mesh (scalar step counts land on
-        # a single device) are re-homed as mesh-replicated so the commit
-        # never pins state somewhere the apply jits can't accept it.
-        mesh_devices = set(self.mesh.devices.flat)
-        self.opt_shardings = jax.tree.map(
-            lambda a: (
-                a.sharding
-                if a.sharding.device_set == mesh_devices
-                else sharding.named(self.mesh, P())
-            ),
-            self.opt_state,
-        )
-        self.opt_state = jax.device_put(self.opt_state, self.opt_shardings)
+        # Optimizer state mirrors the params (ZeRO-1): every param-shaped
+        # leaf (Adam's mu/nu) is born with its param's sharding, the rest
+        # (step counts) replicated over the mesh.  Left to the SPMD
+        # partitioner, `zeros_like` outputs come back REPLICATED — full-size
+        # moments on every chip.  The apply jits pin their out_shardings to
+        # these, so the params/opt/guard carry run through train steps with
+        # byte-identical cache keys — one compiled executable per apply fn
+        # for the whole trial, checkpoint restores included.
+        self.opt_shardings = self._opt_state_shardings()
+        self.opt_state = jax.jit(
+            self.optimizer.init, out_shardings=self.opt_shardings
+        )(self.params)
 
         if 0.0 < anomaly_grad_norm_mult <= 1.0:
             raise ValueError(
@@ -232,6 +223,24 @@ class TrainEngine(HostOffloadMixin, Engine):
         # Lazy byte-size cache for perf_counters(): param/opt global
         # bytes never change shape after init, so sum the leaves once.
         self._tree_bytes: Optional[Tuple[int, int]] = None
+
+    def _opt_state_shardings(self):
+        """A leaf of the optimizer state whose tree path ends in a param's
+        path (mu['blocks']['wq'] ...) is that param's moment."""
+        by_path = dict(
+            jax.tree_util.tree_flatten_with_path(self.param_shardings)[0]
+        )
+        replicated = sharding.named(self.mesh, P())
+
+        def pick(path, leaf):
+            for i in range(len(path)):
+                if path[i:] in by_path:
+                    return by_path[path[i:]]
+            return replicated
+
+        return jax.tree_util.tree_map_with_path(
+            pick, jax.eval_shape(self.optimizer.init, self.params)
+        )
 
     def perf_counters(self) -> Dict[str, int]:
         """Memory/compile counters for the worker's MFC spans (profile
@@ -387,17 +396,19 @@ class TrainEngine(HostOffloadMixin, Engine):
             return self._apply_fn
         step = self._guarded_step
 
-        # Donation: params/opt_state/grads buffers are all dead after the
-        # step — without it the optimizer step transiently holds 2x params
-        # + 2x Adam state, the peak-memory term for large models on one
-        # chip.  Grads share the params' shape/dtype set (master dtype), so
-        # their buffers are reusable for the updated params.  The guarded
+        # Donation: params/opt_state buffers are dead after the step —
+        # without it the optimizer step transiently holds 2x params + 2x
+        # Adam state, the peak-memory term for large models on one chip.
+        # Grads are NOT donated: every param-shaped output is already
+        # aliased to params/mu/nu, so a fourth donated set has no output to
+        # land in and only earns jax's "donated buffers were not usable"
+        # warning; the caller drops them right after the call.  The guarded
         # select keeps this safe on quarantined steps: jnp.where's output
         # may alias either input, and the original values only ever flow
         # out through the jit's own outputs.
         @functools.partial(
             jax.jit,
-            donate_argnums=(0, 1, 2, 3),
+            donate_argnums=(0, 1, 3),
             out_shardings=self._apply_out_shardings(),
         )
         def apply_fn(params, opt_state, grads, guard, loss_sum):
@@ -436,7 +447,7 @@ class TrainEngine(HostOffloadMixin, Engine):
 
         @functools.partial(
             jax.jit,
-            donate_argnums=(0, 1, 2, 3),
+            donate_argnums=(0, 1, 3),
             out_shardings=self._apply_out_shardings(),
         )
         def apply_fn(params, opt_state, grads, guard, loss_sum, scale, ext_trip):
@@ -551,6 +562,7 @@ class TrainEngine(HostOffloadMixin, Engine):
             self.params, self.opt_state, acc, self._guard(), loss_sum
         )
         self.params, self.opt_state = params, opt_state
+        del acc  # a full grad tree: free it before the stats sync
 
         # Stats from loss_fn are summed across micro-batches then divided by
         # total weight where keys end in '_sum'; plain keys are averaged.
@@ -734,7 +746,7 @@ class TrainEngine(HostOffloadMixin, Engine):
             )
         )
         self.params, self.opt_state = params, opt_state
-        state["acc"] = None  # donated: drop the dead reference
+        state["acc"] = None  # consumed: free the grad tree
 
         self.last_pack_stats = {
             "real_tokens": state["real_tokens"],

@@ -808,9 +808,13 @@ def build_ppo_math(cfg: PPOMathConfig, tokenizer=None) -> ExperimentPlan:
     )
 
 
-def run_experiment(plan: ExperimentPlan, tokenizer=None):
+def run_experiment(plan: ExperimentPlan, tokenizer=None, inspect=None):
     """In-process runner: build workers, drive the master loop to completion.
     (The multi-process ZMQ runtime is areal_tpu/apps/main.py run_experiment.)
+
+    `inspect(master, stage)`, when given, is called with stage "built" once
+    every worker's models exist (before the first step) and "done" after
+    the last step, while the engines (`master.pool.workers`) are alive.
     """
     import asyncio
 
@@ -855,5 +859,9 @@ def run_experiment(plan: ExperimentPlan, tokenizer=None):
         weight_push_checksum=plan.weight_push_checksum,
     )
     master.load_recover_info()
+    if inspect is not None:
+        inspect(master, "built")
     stats = asyncio.run(master.run())
+    if inspect is not None:
+        inspect(master, "done")
     return master, stats

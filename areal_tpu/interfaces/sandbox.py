@@ -65,7 +65,7 @@ def _ulimit_wrapper(
     """Apply rlimits via a `sh -c 'ulimit ...; exec "$@"'` wrapper rather
     than preexec_fn: running Python between fork and exec is documented
     deadlock-prone in multithreaded processes, and reward grading runs
-    inside model workers full of ZMQ/JAX threads — a child wedged in
+    inside model workers full of ZMQ/JAX threads — a child stuck in
     _set_limits would burn the whole timeout and grade a correct solution
     as wrong.  The shell applies limits post-exec (posix_spawn-safe).
 

@@ -430,10 +430,7 @@ def packed_attention(
 
         use_flash = is_tpu_backend()
     if use_flash:
-        try:
-            from areal_tpu.ops.pallas.flash_attention import flash_attention
+        from areal_tpu.ops.pallas.flash_attention import flash_attention
 
-            return flash_attention(q, k, v, segment_ids, causal=causal)
-        except (ImportError, NotImplementedError):
-            pass
+        return flash_attention(q, k, v, segment_ids, causal=causal)
     return packed_attention_reference(q, k, v, segment_ids, causal=causal)

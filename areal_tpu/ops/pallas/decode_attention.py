@@ -17,8 +17,13 @@ online-softmax accumulation, the same structure as the flash forward
 Reference role: the decode half of flash_attn_with_kvcache
 (realhf/impl/model/modules/attn.py:251) + the paged/ragged decode
 kernels serving engines use.  Opt-in via AREAL_DECODE_KERNEL=1 (see
-ops/attention.decode_attention) until chip-measured; interpret mode
-covers CPU tests.
+ops/attention.decode_attention); interpret mode covers CPU tests.
+
+DOES NOT LOWER FOR TPU (jax 0.9.0): the `(1, 1)` blocks out of the `[B, 1]`
+window bounds and the one-head `(1, block_k, 1)` scale blocks are neither
+(8, 128)-aligned nor whole-array, so Pallas refuses them at lowering.  The
+kernel has only ever run interpreted; it is queued for deletion (ROADMAP
+C1/C2), not repair.
 """
 
 import functools

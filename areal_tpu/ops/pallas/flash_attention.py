@@ -495,9 +495,8 @@ def flash_attention_sharded(
     independent per (batch row, head), so no collectives are needed; GQA
     locality requires n_kv % model_axis == 0 (contiguous head sharding
     keeps each q-head group with its kv head)."""
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    from areal_tpu.base.compat import shard_map
 
     from areal_tpu.base.topology import (
         DATA_AXIS,

@@ -25,9 +25,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from areal_tpu.base.compat import shard_map
 from areal_tpu.base.topology import MODEL_AXIS, SEQ_AXIS
 from areal_tpu.ops.attention import NEG_INF, repeat_kv
 from areal_tpu.parallel.sharding import BATCH
@@ -69,14 +69,10 @@ def _ring_shard(q, k, v, segment_ids, axis_name: str, axis_size: int,
                 causal: bool, my_index=None):
     """shard_map body: each seq-axis member holds one contiguous chunk.
 
-    `my_index` overrides `lax.axis_index` for callers already inside a
-    partial-manual region (the CP+PP pipeline), where old jax cannot
-    lower axis_index.
+    `my_index` overrides `lax.axis_index` for the CP+PP pipeline, whose
+    body already receives its seq-axis index as a sharded input.
     """
     b, sq, h, d = q.shape
-    # arealint: ignore[sharding] -- guarded: callers on old-jax
-    # partial-manual paths (CP+PP pipeline) pass my_index explicitly;
-    # the axis_index default only runs under new-jax shard_map.
     my = jax.lax.axis_index(axis_name) if my_index is None else my_index
     q_pos = my * sq + jnp.arange(sq, dtype=jnp.int32)
 
@@ -137,9 +133,6 @@ def _zigzag_shard(q, k, v, segment_ids, axis_name: str, axis_size: int,
     n = axis_size
     b, sq, h, d = q.shape
     sh = sq // 2
-    # arealint: ignore[sharding] -- zigzag runs only under new-jax
-    # shard_map (ring path is causal-only and gated at the dispatcher);
-    # the old-jax full-manual fallback never lowers this body.
     c = jax.lax.axis_index(axis_name)
     ar = jnp.arange(sh, dtype=jnp.int32)
 

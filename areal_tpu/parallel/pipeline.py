@@ -31,9 +31,9 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from areal_tpu.base.compat import shard_map
 from areal_tpu.base.topology import PIPE_AXIS, SEQ_AXIS
 
 
@@ -123,9 +123,8 @@ def pipelined_blocks(
     cos_mbs, sin_mbs = to_mbs(cos), to_mbs(sin)
 
     def pipe_body(sids, qids, blocks_local, x_mbs, seg_mbs, cos_mbs, sin_mbs):
-        # Explicit per-shard index inputs instead of lax.axis_index: old
-        # jax lowers axis_index inside a partial-manual region through a
-        # partition_id HLO that the SPMD partitioner rejects.
+        # The stage / seq-chunk indices arrive as inputs sharded over
+        # their own axes, so each member reads its index from element 0.
         stage = sids[0]
         cp_info = cp_manual and (*cp_manual, qids[0])
         fwd = functools.partial(

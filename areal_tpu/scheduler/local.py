@@ -1,10 +1,11 @@
 """Local scheduler: worker jobs as subprocesses with per-job logs.
 
 Capability parity: realhf/scheduler/local/client.py (subprocess spawn with
-GPU isolation + per-worker logs).  TPU note: on a single host there is one
-TPU runtime owner, so colocated jobs default to CPU (`JAX_PLATFORMS=cpu`)
-unless the caller passes env overrides — the multi-chip story is one worker
-process per host anyway (XLA SPMD runs the mesh inside one process).
+GPU isolation + per-worker logs).  TPU note: jobs inherit the caller's
+environment (`JAX_PLATFORMS` included) plus explicit overrides.  A host's
+chips belong to one process, so the multi-chip story is one worker process
+per host (XLA SPMD runs the mesh inside one process); a second colocated
+job that needs the TPU fails at backend start-up.
 """
 
 import os

@@ -1192,7 +1192,7 @@ class GenerationServer:
     def close(self):
         self._stop.set()
         if self._faults is not None:
-            # Unblock wedged `hang` request threads so they fail fast.
+            # Unblock blocked `hang` request threads so they fail fast.
             self._faults.release()
         if self._announce_key and not self._crashed:
             # Graceful leave: deregister now so the controller drains us

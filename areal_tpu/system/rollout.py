@@ -11,7 +11,7 @@ Podracer "actor plane", arxiv 2104.06272):
   not-yet-acknowledged dispatches to it — the cached health signal is
   refreshed at a bounded rate so balancing never becomes a health-poll
   storm, and the fleet is polled *concurrently* with a per-server
-  timeout so one wedged server cannot stall everyone's refresh.
+  timeout so one hung server cannot stall everyone's refresh.
 - **Version stamping**: every trajectory records the weight version it
   STARTED sampling under (``version_start``, the head version) and the
   one it finished under — bounded-staleness admission in the

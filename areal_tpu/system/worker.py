@@ -10,6 +10,7 @@ trials) and the ZMQ stream runtime.
 """
 
 import dataclasses
+import functools
 import os
 import threading
 import time
@@ -134,11 +135,28 @@ class WorkerConfig:
     dist_num_processes: int = 1
 
 
-def _build_params_and_config(spec: ModelAbstraction, seed: int):
+@functools.lru_cache(maxsize=8)
+def _random_init_fn(cfg: ModelConfig, mesh):
+    """Jitted `seed -> params` that builds a random model ON `mesh`, in
+    the engines' layout: a model destined for chips 2-3 never touches chip
+    0, and the fp32 draws fuse into the cast instead of sitting in HBM
+    whole.  Cached per (config, mesh): a trial builds the same model for
+    several roles."""
     import jax
 
     from areal_tpu.models import transformer as tfm
+    from areal_tpu.parallel import sharding
 
+    def init(seed):
+        return tfm.init_params(cfg, jax.random.PRNGKey(seed))
+
+    shardings = sharding.tree_named(
+        mesh, sharding.param_pspecs(jax.eval_shape(init, 0))
+    )
+    return jax.jit(init, out_shardings=shardings)
+
+
+def _build_params_and_config(spec: ModelAbstraction, seed: int, mesh):
     if spec.type_ == "null":
         return None, None  # engine-less models (e.g. verification rewards)
     if spec.type_ == "config":
@@ -146,8 +164,7 @@ def _build_params_and_config(spec: ModelAbstraction, seed: int):
         return spec.args["config"], None
     if spec.type_ == "random":
         cfg: ModelConfig = spec.args["config"]
-        params = tfm.init_params(cfg, jax.random.PRNGKey(seed))
-        return cfg, params
+        return cfg, _random_init_fn(cfg, mesh)(seed)
     elif spec.type_ == "hf":
         from areal_tpu.models.hf import registry as hf
 
@@ -233,9 +250,6 @@ class ModelWorker:
             self.tokenizer = load_hf_tokenizer(self.config.tokenizer_path)
 
         for shard in self.config.shards:
-            cfg, params = _build_params_and_config(
-                shard.model, seed=self.config.seed
-            )
             off = (
                 shard.device_offset
                 if shard.device_offset is not None
@@ -243,6 +257,9 @@ class ModelWorker:
             )
             devices = jax.devices()[off : off + shard.parallel.world_size]
             mesh = make_mesh(shard.parallel, devices)
+            cfg, params = _build_params_and_config(
+                shard.model, seed=self.config.seed, mesh=mesh
+            )
             btype = shard.backend.type_
             if btype in ("train", "mock"):
                 engine = TrainEngine(

@@ -1,0 +1,590 @@
+"""The quickest proof that today's tree still starts on the chip.
+
+    python chip_smoke.py            # needs a TPU; one process; exit 0 = ok
+
+Drives the RL step — generate -> grade -> actor update -> weight
+hand-back — through the functions the CLI calls
+(`experiments.common.build_ppo_math` + `apps.main.run_experiment_inproc`,
+i.e. `apps/quickstart.py ppo-math`) at the full width and depth of
+qwen2-1.5B with random weights from a seed, and checks what comes out.
+Phases, all in this one process (a chip belongs to one process):
+
+  kernels    every Pallas kernel reachable on a chip, compiled by Mosaic
+             (not interpreted) at the 1.5B head geometry, against its
+             XLA/dense counterpart on the device
+  static     the trainer, 8 prompts x 4: static decode program
+  serving    the same plan with fewer decode slots than requests, so
+             generate() goes inflight -> paged -> ragged serving chunk
+  multichip  with >= 4 chips: the serving plan again with train f2 on
+             chips 0-1 and gen m2 on chips 2-3, weights re-laid-out f2 ->
+             m2 at every hand-back (printed as skipped with fewer)
+
+Lengths are short (prompts ~17 tokens, <= 256 new): width is what meets
+the tiling and HBM limits; lengths are the benchmark's business.
+
+The grader is the real math verifier on the `tests/fixtures.py` rows.  A
+random model never answers right, so every reward is -5 and GRPO's
+group-normalised advantage would be identically zero — no gradient,
+nothing to hand back.  The actor therefore runs with a zero value
+baseline and no advantage normalisation (`disable_value=False,
+adv_norm=False`, no critic), which turns the verifier's -5 into a real
+policy-gradient signal; the device programs are the same either way (the
+advantages are an input array).
+
+Without a TPU this exits non-zero and prints no result.
+`--cpu-rehearsal` runs the same phases at toy size on the CPU to debug
+the script before chip time is spent; it says `platform=cpu` and is never
+what the no-argument run falls back to.  Any failed check raises.
+
+Last line of stdout: {"ok": true, "device": {"platform", "kind", "count"}}.
+"""
+
+import argparse
+import gc
+import json
+import os
+import sys
+import tempfile
+import time
+
+PHASES = ("kernels", "static", "serving", "multichip")
+
+
+def log(msg):
+    print(f"[chip_smoke] {msg}", flush=True)
+
+
+def check(cond, what):
+    if not cond:
+        raise AssertionError(f"chip_smoke check failed: {what}")
+    log(f"  ok: {what}")
+
+
+# --------------------------------------------------------------------------
+# Device memory
+# --------------------------------------------------------------------------
+
+
+def bytes_per_device(arrays):
+    """Bytes the given jax.Arrays hold on each local device.  A buffer
+    counts once however many Arrays view it (the colocated generator
+    aliases the trainer's weights; `addressable_shards` itself leaves
+    per-shard views behind that `jax.live_arrays()` then lists too)."""
+    import jax
+
+    out = {d.id: 0 for d in jax.local_devices()}
+    seen = set()
+    for a in arrays:
+        if a.is_deleted():
+            continue
+        for s in a.addressable_shards:
+            ptr = s.data.unsafe_buffer_pointer()
+            if ptr not in seen:
+                seen.add(ptr)
+                out[s.device.id] += s.data.nbytes
+    return out
+
+
+def live_bytes_per_device():
+    import jax
+
+    return bytes_per_device(jax.live_arrays())
+
+
+def check_within_hbm(tag):
+    import jax
+
+    live = live_bytes_per_device()
+    for d in jax.local_devices():
+        stats = d.memory_stats() or {}
+        limit = stats.get("bytes_limit")
+        peak = stats.get("peak_bytes_in_use")
+        log(
+            f"  {tag}: device {d.id} live {live[d.id] / 1e9:.2f} GB"
+            + (f", peak {peak / 1e9:.2f} GB" if peak is not None else "")
+            + (f" of {limit / 1e9:.2f} GB" if limit else "")
+        )
+        if limit:
+            check(
+                live[d.id] <= limit and (peak is None or peak <= limit),
+                f"device {d.id} live and peak bytes within HBM",
+            )
+    return live
+
+
+def release_device_memory(tag, budget_bytes=16 << 20):
+    """A finished phase must leave the chip to the next one."""
+    while gc.collect():  # engine <-> jit-closure cycles take a few passes
+        pass
+    live = live_bytes_per_device()
+    check(
+        max(live.values()) <= budget_bytes,
+        f"{tag}: engines released ({max(live.values()) / 1e6:.0f} MB live)",
+    )
+
+
+# --------------------------------------------------------------------------
+# Phase: kernels
+# --------------------------------------------------------------------------
+
+
+def _max_err(a, b):
+    import jax.numpy as jnp
+
+    return float(
+        jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32)))
+    )
+
+
+def phase_kernels(geom, on_tpu):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from areal_tpu.base.topology import ParallelConfig, make_mesh
+    from areal_tpu.ops import attention
+    from areal_tpu.ops.pallas import flash_attention as fa
+    from areal_tpu.ops.pallas import paged_attention as pa
+
+    check(
+        fa._interpret() is (not on_tpu) and pa._interpret() is (not on_tpu),
+        "Pallas kernels " + ("compiled by Mosaic" if on_tpu else
+                             "interpreted (cpu rehearsal)"),
+    )
+    n_q, n_kv, d = geom["n_q"], geom["n_kv"], geom["d"]
+    dt = jnp.bfloat16 if on_tpu else jnp.float32
+    tol = 3e-2 if on_tpu else 2e-4
+    rng = np.random.default_rng(0)
+
+    # ---- flash: forward, backward, shard_mapped form vs the dense oracle
+    b, s = 2, geom["flash_s"]
+    q = jnp.asarray(rng.standard_normal((b, s, n_q, d)), dt)
+    k = jnp.asarray(rng.standard_normal((b, s, n_kv, d)), dt)
+    v = jnp.asarray(rng.standard_normal((b, s, n_kv, d)), dt)
+    w = jnp.asarray(rng.standard_normal((b, s, n_q, d)), jnp.float32)
+    # Packed rows: three sequences + padding, one sequence + padding.
+    seg = np.zeros((b, s), np.int32)
+    seg[0, : s // 4], seg[0, s // 4 : s // 2 + 7] = 1, 2
+    seg[0, s // 2 + 7 : s - 9] = 3
+    seg[1, : s - 40] = 1
+    seg = jnp.asarray(seg)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(
+            fn(q, k, v, seg).astype(jnp.float32) * w
+        )
+
+    ref = jax.jit(attention.packed_attention_reference)
+    flash = jax.jit(fa.flash_attention)
+    o_ref, o_fl = ref(q, k, v, seg), flash(q, k, v, seg)
+    check(bool(jnp.isfinite(o_fl.astype(jnp.float32)).all()),
+          "flash forward finite")
+    err = _max_err(o_ref, o_fl)
+    check(err <= tol, f"flash forward == dense reference (max err {err:.2e})")
+    g_ref = jax.jit(jax.grad(loss(attention.packed_attention_reference),
+                             argnums=(0, 1, 2)))(q, k, v)
+    g_fl = jax.jit(jax.grad(loss(fa.flash_attention), argnums=(0, 1, 2)))(
+        q, k, v
+    )
+    for name, a, c in zip(("dq", "dk", "dv"), g_ref, g_fl):
+        scale = float(jnp.max(jnp.abs(a.astype(jnp.float32)))) or 1.0
+        err = _max_err(a, c) / scale
+        check(err <= tol, f"flash backward {name} == reference "
+                          f"(max rel err {err:.2e})")
+    n_dev = len(jax.devices())
+    layout = "m2" if n_dev >= 2 else "d1"
+    pc = ParallelConfig.from_str(layout)
+    mesh = make_mesh(pc, jax.devices()[: pc.world_size])
+    o_sh = jax.jit(
+        lambda q, k, v: attention.packed_attention(
+            q, k, v, seg, use_flash=mesh
+        )
+    )(q, k, v)
+    err = _max_err(o_ref, o_sh)
+    check(err <= tol,
+          f"shard_mapped flash ({layout}) == reference (max err {err:.2e})")
+
+    # ---- ragged stream kernel (bf16 and int8 pools) vs the XLA gather path
+    t, n_pool, ps, mp = 40, 64, geom["page"], 4
+    check(not attention._decode_kernel_enabled(),
+          "AREAL_DECODE_KERNEL unset: ragged_paged_attention is the XLA path")
+    qs = jnp.asarray(rng.standard_normal((t, n_q, d)), dt)
+    pt = rng.integers(0, n_pool - 1, size=(t, mp)).astype(np.int32)
+    vt = rng.integers(1, mp * ps + 1, size=t).astype(np.int32)
+    vt[-6:] = 0  # stream slack lanes: dead, must come out exact zeros
+    vt[0], vt[1] = 1, mp * ps  # shortest and longest windows
+    for i in range(t):  # pages past the window are unmapped (sentinel)
+        pt[i, -(-int(vt[i]) // ps):] = n_pool
+    pt, vt = jnp.asarray(pt), jnp.asarray(vt)
+    kp = rng.standard_normal((n_pool, ps, n_kv, d))
+    vp = rng.standard_normal((n_pool, ps, n_kv, d))
+    from areal_tpu.ops.quant import kv_quant
+
+    k8, ks = kv_quant(jnp.asarray(kp, jnp.float32))
+    v8, vs = kv_quant(jnp.asarray(vp, jnp.float32))
+    for name, args in (
+        ("bf16" if on_tpu else "fp32",
+         (jnp.asarray(kp, dt), jnp.asarray(vp, dt))),
+        ("int8", (k8, v8, ks, vs)),
+    ):
+        o_x = attention.ragged_paged_attention(qs, args[0], args[1], pt, vt,
+                                               *args[2:])
+        o_k = pa.ragged_paged_attention_kernel(qs, args[0], args[1], pt, vt,
+                                               *args[2:])
+        err = _max_err(o_x, o_k)
+        check(err <= tol, f"ragged stream kernel ({name} pool) == XLA "
+                          f"gather path (max err {err:.2e})")
+        check(float(jnp.max(jnp.abs(o_k[-6:].astype(jnp.float32)))) == 0.0,
+              f"ragged stream kernel ({name} pool): dead lanes exact zeros")
+
+
+# --------------------------------------------------------------------------
+# Phases: the trainer
+# --------------------------------------------------------------------------
+
+
+def _ppo_plan(name, model_cfg, size, fileroot, **overrides):
+    from tests import fixtures
+
+    from areal_tpu.api.config import ModelAbstraction
+    from areal_tpu.api.data_api import DatasetAbstraction, MicroBatchSpec
+    from areal_tpu.api.model_api import (
+        GenerationHyperparameters,
+        OptimizerConfig,
+    )
+    from areal_tpu.experiments.common import PPOMathConfig, build_ppo_math
+    from areal_tpu.system.master import ExperimentSaveEvalControl
+
+    tok = fixtures.make_tokenizer()
+    rows = fixtures.build_math_rows(size["n_prompts"], seed=5)
+    cfg = PPOMathConfig(
+        experiment_name="chip_smoke",
+        trial_name=name,
+        actor=ModelAbstraction("random", {"config": model_cfg}),
+        dataset=DatasetAbstraction(
+            "math_code_prompt",
+            {"dataset_builder": lambda: rows, "max_length": 128},
+        ),
+        reward_interface_args={"id2info": {r["query_id"]: r for r in rows}},
+        gconfig=GenerationHyperparameters(
+            n=size["group"], max_new_tokens=size["max_new"], temperature=1.0
+        ),
+        # lr: the first Adam step moves every weight by ~lr, and a bf16
+        # master at |w| ~ 0.03 has half an ulp of 6e-5 — 2e-5 (the
+        # default) would round most of the update away.
+        optimizer=OptimizerConfig(lr=1e-4, warmup_steps_proportion=0.0),
+        ppo_kwargs={
+            "n_minibatches": 2, "kl_ctl": 0.0,
+            # See the module docstring: the verifier's constant -5 must
+            # reach the gradient.
+            "disable_value": False, "adv_norm": False,
+        },
+        mb_spec=MicroBatchSpec(max_tokens_per_mb=size["mb_tokens"]),
+        batch_size=size["n_prompts"],
+        # One batch per epoch: an epoch is a step.
+        total_train_epochs=size["steps"],
+        ctrl=ExperimentSaveEvalControl(benchmark_steps=size["steps"]),
+        fileroot=fileroot,
+        train_backend_args={"master_dtype": "bfloat16"},
+        **overrides,
+    )
+    return build_ppo_math(cfg, tok), tok
+
+
+def _engines(master):
+    """(train engine, generator engine, generator Model) of the trial."""
+    found = {}
+    for w in master.pool.workers:
+        for key, m in w.models.items():
+            found[key.split("@")[0]] = m  # "actor@0" -> "actor"
+    return found["actor"].engine, found["actor_gen"].engine, found["actor_gen"]
+
+
+def _on_mesh_only(engine, what):
+    import jax
+
+    mesh_ids = {d.id for d in engine.mesh.devices.flat}
+    where = set()
+    for leaf in jax.tree.leaves(engine.params):
+        where |= {d.id for d in leaf.sharding.device_set}
+    check(where <= mesh_ids,
+          f"{what} weights live on chips {sorted(where)} within their mesh "
+          f"{sorted(mesh_ids)}")
+    return mesh_ids
+
+
+def phase_trainer(name, model_cfg, size, serving=False, multichip=False):
+    import jax
+    import numpy as np
+
+    from areal_tpu.apps.main import run_experiment_inproc
+
+    overrides = {}
+    if serving:
+        # Fewer decode slots than requests.
+        overrides["gen_backend_args"] = {
+            "max_decode_batch": size["serving_slots"]
+        }
+    if multichip:
+        from areal_tpu.base.topology import ParallelConfig
+
+        overrides.update(
+            actor_parallel=ParallelConfig.from_str("f2"),
+            gen_parallel=ParallelConfig.from_str("m2"),
+            placement={"actor_gen": 1, "reward": 1},
+            worker_device_offsets={1: 2},
+        )
+    seen = {}
+
+    def inspect(master, stage):
+        train, gen, gen_model = _engines(master)
+        if stage == "built":
+            train_ids = _on_mesh_only(train, "trainer")
+            gen_ids = _on_mesh_only(gen, "generator")
+            live = check_within_hbm(f"{name} built")
+            if multichip:
+                check(train_ids == {0, 1} and gen_ids == {2, 3},
+                      "train f2 on chips 0-1, gen m2 on chips 2-3")
+            # Nothing was left on a chip by accident while building: each
+            # chip holds its own engines' state and (but for scalars like
+            # RNG keys) nothing else.
+            own = bytes_per_device(jax.tree.leaves(
+                (train.params, train.opt_state, gen.params)
+            ))
+            stray = {i: live[i] - own[i] for i in live}
+            check(max(abs(v) for v in stray.values()) <= 16 << 20,
+                  "after build every chip holds exactly its engines' params "
+                  f"and optimizer state (stray MB: "
+                  f"{ {i: round(v / 1e6, 1) for i, v in stray.items()} })")
+            p_b = bytes_per_device(jax.tree.leaves(train.params))
+            o_b = bytes_per_device(jax.tree.leaves(train.opt_state))
+            check(all(2 * p_b[i] <= o_b[i] <= 2 * p_b[i] + 1024
+                      for i in train_ids),
+                  "Adam moments are sharded like their params (ZeRO-1): "
+                  f"{ {i: round(o_b[i] / 1e9, 2) for i in sorted(train_ids)} }"
+                  " GB of optimizer state per trainer chip")
+            bq = train.params["blocks"]["bq"]
+            check(float(abs(np.asarray(bq, np.float32)).max()) == 0.0,
+                  "qkv bias is zero at init (the 'params changed' witness)")
+            return
+        # ---- stage "done": weights, hand-back, counters, memory
+        seen["gen_version"] = int(gen_model.version)
+        t_bq = np.asarray(train.params["blocks"]["bq"], np.float32)
+        g_bq = np.asarray(gen.params["blocks"]["bq"], np.float32)
+        check(np.isfinite(t_bq).all() and float(abs(t_bq).max()) > 0.0,
+              f"trainer params changed (max |bq| {abs(t_bq).max():.2e}, "
+              "zero at init)")
+        check(np.array_equal(t_bq, g_bq),
+              "generator holds the trainer's new qkv bias after hand-back")
+        for leaf in ("embed", "final_ln"):
+            a = np.asarray(train.params[leaf][:64], np.float32)
+            b = np.asarray(gen.params[leaf][:64], np.float32)
+            check(np.array_equal(a, b),
+                  f"generator {leaf}[:64] == trainer's after hand-back")
+        _on_mesh_only(train, "trainer")
+        _on_mesh_only(gen, "generator")
+        check_within_hbm(f"{name} done")
+        seen["gen"] = {
+            k: int(getattr(gen, k))
+            for k in ("decode_compiles", "prefill_dispatches",
+                      "lanes_dispatched", "lanes_live", "dead_live_lanes",
+                      "cache_copy_bytes", "serving_lane_budget")
+        }
+        seen["kv_paged"] = bool(gen.kv_paged)
+        # Every generation program the engine built over the whole trial.
+        seen["programs"] = sorted(
+            sig[0] if isinstance(sig[0], str) else "static"
+            for sig in gen._gen_fns
+        )
+
+    t0 = time.time()
+    with tempfile.TemporaryDirectory(prefix="chip_smoke_") as fileroot:
+        plan, tok = _ppo_plan(name, model_cfg, size, fileroot, **overrides)
+        stats = run_experiment_inproc(plan, tokenizer=tok, inspect=inspect)
+    wall = time.time() - t0
+
+    n_seqs = size["n_prompts"] * size["group"]
+    check(len(stats) == size["steps"], f"{size['steps']} steps ran")
+    for i, st in enumerate(stats):
+        loss = st["actor_train/actor_loss"]
+        check(np.isfinite(loss) and loss != 0.0,
+              f"step {i}: actor loss finite and non-zero ({loss:.4g})")
+        check(np.isfinite(st["actor_train/grad_norm"])
+              and st["actor_train/grad_norm"] > 0
+              and st["actor_train/update_norm"] > 0
+              and st["actor_train/quarantined"] == 0,
+              f"step {i}: grad norm {st['actor_train/grad_norm']:.4g} and "
+              f"update norm {st['actor_train/update_norm']:.4g} finite and "
+              "> 0, step not quarantined")
+        # The trainer re-scores the generator's tokens under the same
+        # weights: a ratio far from 1 means the two disagree about them.
+        iw = st["actor_train/importance_weight"]
+        check(0.8 < iw < 1.25,
+              f"step {i}: trainer/generator importance weight {iw:.4f} ~ 1")
+        # Graded by the math verifier: a random model is always wrong.
+        check(st["actor_train/task_reward"] == -5.0,
+              f"step {i}: rewards came from the verifier (all wrong: -5)")
+        n_tok = st["actor_train/n_response_tokens"]
+        check(0 < n_tok <= n_seqs * size["max_new"],
+              f"step {i}: {int(n_tok)} response tokens generated "
+              f"(<= {n_seqs} x {size['max_new']})")
+        no_eos = st["actor_train/no_eos_ratio"]
+        check(0.0 <= no_eos <= 1.0, f"step {i}: no-EOS ratio {no_eos:.2f}")
+        if no_eos == 1.0:
+            check(n_tok == n_seqs * size["max_new"],
+                  f"step {i}: no sequence hit EOS, so all ran to the cap")
+        for mfc in ("actor_gen", "actor_train"):
+            for key in ("perf/time_s", "perf/tflops"):
+                check(f"{mfc}/{key}" in st, f"step {i}: {mfc}/{key} in stats")
+            if jax.default_backend() != "cpu":
+                u = st.get(f"{mfc}/perf/mfu")
+                check(u is not None and 0.0 < u < 1.0,
+                      f"step {i}: {mfc}/perf/mfu = {u}")
+    check(seen["gen_version"] == size["steps"],
+          f"generator version counter == {size['steps']} steps")
+    g = seen["gen"]
+    log(f"  generation programs built over the trial: {seen['programs']}")
+    if serving:
+        check(seen["kv_paged"] and g["lanes_dispatched"] > 0
+              and g["serving_lane_budget"] > 0,
+              f"generate() took the paged ragged serving chunk "
+              f"({g['lanes_dispatched']} lanes dispatched, "
+              f"{g['lanes_live']} live, budget {g['serving_lane_budget']})")
+        check(g["prefill_dispatches"] == 0,
+              "prefill rode the serving chunk (prefill_dispatches == 0)")
+        check(g["dead_live_lanes"] == 0, "dead_live_lanes == 0")
+        check(g["cache_copy_bytes"] == 0, "paged pool: no cache grow copies")
+        check(seen["programs"].count("serving_chunk") == 1
+              and g["decode_compiles"] == 0,
+              "one serving chunk program for the whole trial, none compiled "
+              "in the last (warm) step")
+        check(not {"prefill_pages", "paged_inflight", "inflight",
+                   "prefill_slots"} & set(seen["programs"]),
+              "no two-program admit / dense inflight program was built")
+    else:
+        check(g["lanes_dispatched"] == 0 and g["prefill_dispatches"] == 0
+              and "serving_chunk" not in seen["programs"],
+              "generate() took the static decode program")
+    step_s = [st["time/step_s"] for st in stats]
+    log(
+        f"phase {name}: wall {wall:.1f}s = set-up {wall - sum(step_s[1:]):.1f}s"
+        f" (build + first step incl. compile {step_s[0]:.1f}s) + steady "
+        f"steps {[round(x, 2) for x in step_s[1:]]} s; last step gen "
+        f"{stats[-1]['actor_gen/perf/time_s']:.2f}s train "
+        f"{stats[-1]['actor_train/perf/time_s']:.2f}s"
+        + (f" cross-mesh weight hand-back send "
+           f"{stats[-1]['transfer/param_send_s']:.2f}s recv "
+           f"{stats[-1]['transfer/param_recv_s']:.2f}s "
+           f"({stats[-1]['transfer/param_bytes'] / 1e9:.2f} GB through the "
+           "host)" if "transfer/param_bytes" in stats[-1] else "")
+    )
+    release_device_memory(name)
+
+
+# --------------------------------------------------------------------------
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument(
+        "--cpu-rehearsal", action="store_true",
+        help="toy-size run on the CPU to debug this script; not a chip run",
+    )
+    ap.add_argument(
+        "--phases", default=",".join(PHASES),
+        help=f"comma-separated subset of {PHASES} (all by default)",
+    )
+    args = ap.parse_args(argv)
+    phases = [p for p in args.phases.split(",") if p]
+    unknown = set(phases) - set(PHASES)
+    if unknown:
+        ap.error(f"unknown phases {sorted(unknown)}")
+
+    import jax
+
+    backend = jax.default_backend()
+    if args.cpu_rehearsal:
+        if backend != "cpu":
+            raise SystemExit(
+                f"chip_smoke: --cpu-rehearsal wants JAX_PLATFORMS=cpu, "
+                f"found backend {backend!r}"
+            )
+    elif backend != "tpu":
+        raise SystemExit(
+            f"chip_smoke: needs a TPU, but jax.default_backend() is "
+            f"{backend!r} (devices: {jax.devices()}).  Nothing was run."
+        )
+    on_tpu = backend == "tpu"
+
+    from areal_tpu.base import compilation_cache
+    from areal_tpu.models.config import qwen2_config, tiny_config
+
+    cache_dir = compilation_cache.enable()
+    dev = jax.devices()[0]
+    device = {
+        "platform": dev.platform,
+        "kind": dev.device_kind,
+        "count": len(jax.devices()),
+    }
+    log(
+        f"platform={device['platform']} device_kind={device['kind']!r} "
+        f"devices={device['count']} jax={jax.__version__} "
+        f"cache_dir={cache_dir} "
+        f"(entries at start: {len(os.listdir(cache_dir))})"
+    )
+    if on_tpu:
+        model_cfg = qwen2_config("1.5b", param_dtype="bfloat16")
+        size = dict(n_prompts=8, group=4, max_new=256, mb_tokens=8192,
+                    steps=3, serving_slots=8)
+    else:
+        log("CPU REHEARSAL at toy size — not evidence about the chip")
+        model_cfg = tiny_config(param_dtype="float32")
+        size = dict(n_prompts=4, group=2, max_new=12, mb_tokens=4096,
+                    steps=2, serving_slots=2)
+    geom = dict(
+        n_q=model_cfg.n_q_heads, n_kv=model_cfg.n_kv_heads,
+        d=model_cfg.head_dim, flash_s=256 if on_tpu else 128,
+        page=128 if on_tpu else 8,
+    )
+    log(
+        f"model: {model_cfg.n_layers} layers, hidden {model_cfg.hidden_dim}, "
+        f"{model_cfg.n_q_heads}/{model_cfg.n_kv_heads} heads x "
+        f"{model_cfg.head_dim}, vocab {model_cfg.vocab_size}; "
+        f"{size['n_prompts']} prompts x {size['group']}, <= "
+        f"{size['max_new']} new tokens, {size['steps']} steps per trainer "
+        "phase (the first is warm-up)"
+    )
+
+    walls = {}
+    for phase in phases:
+        t0 = time.time()
+        log(f"phase {phase}: start")
+        if phase == "kernels":
+            phase_kernels(geom, on_tpu)
+        elif phase == "static":
+            phase_trainer("static", model_cfg, size)
+        elif phase == "serving":
+            phase_trainer("serving", model_cfg, size, serving=True)
+        elif phase == "multichip":
+            if device["count"] < 4:
+                log(f"multichip: skipped ({device['count']} chips)")
+                continue
+            # The serving plane again: the page pool under a model axis.
+            phase_trainer("multichip", model_cfg, dict(size, steps=2),
+                          serving=True, multichip=True)
+        walls[phase] = round(time.time() - t0, 1)
+        log(f"phase {phase}: passed in {walls[phase]}s")
+    log(
+        f"all phases passed: {walls}; cache entries at end: "
+        f"{len(os.listdir(cache_dir))}"
+    )
+    sys.stdout.flush()
+    if not on_tpu:
+        log("rehearsal finished; no result line (platform=cpu)")
+        return 0
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

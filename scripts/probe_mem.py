@@ -2,7 +2,9 @@
 
 Replicates bench.py's engine setup and prints the live-array total after
 each stage — separates "resident set too big" from "XLA transient peak
-too big" when diagnosing single-chip OOMs.
+too big" when diagnosing single-chip OOMs.  (The production path —
+build_ppo_math + run_experiment_inproc — is chip_smoke.py's `static` phase,
+which prints live and peak bytes per chip.)
 """
 
 import os
@@ -134,80 +136,5 @@ def main(size="1.5b"):
         raise
 
 
-def main_trial(size="1.5b"):
-    """PRODUCTION-path memory probe: a colocated synchronous 1.5B PPO
-    trial built by experiments.common.build_ppo_math (NOT the bench's
-    direct engine wiring) must fit this chip — the alias hot-swap
-    (donation_safe_swap=False + master-driven release_params) is wired
-    there since round 5, so the bench-only 16 GB fit claim becomes a
-    production claim.  Run: python scripts/probe_mem.py trial"""
-    from areal_tpu.base import compilation_cache
-
-    compilation_cache.enable()
-
-    from areal_tpu.api.config import ModelAbstraction
-    from areal_tpu.api.data_api import DatasetAbstraction
-    from areal_tpu.api.model_api import (
-        GenerationHyperparameters,
-        OptimizerConfig,
-    )
-    from areal_tpu.experiments.common import (
-        PPOMathConfig,
-        build_ppo_math,
-        run_experiment,
-    )
-    from areal_tpu.models.config import qwen2_config
-    from areal_tpu.system.master import ExperimentSaveEvalControl
-
-    sys.path.insert(
-        0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    )
-    from tests import fixtures
-
-    tok = fixtures.make_tokenizer()
-    cfg = qwen2_config(size, param_dtype="bfloat16")
-    # The test tokenizer's ids must stay in-vocab; 1.5b vocab is 151k so
-    # the WordPiece ids (<30k) are fine.
-    n_prompts = 8
-    pcfg = PPOMathConfig(
-        experiment_name="probe",
-        trial_name="mem",
-        actor=ModelAbstraction("random", {"config": cfg}),
-        dataset=DatasetAbstraction(
-            "math_code_prompt",
-            {
-                "dataset_builder": lambda: fixtures.build_math_rows(
-                    n_prompts, seed=5
-                ),
-                "max_length": 128,
-            },
-        ),
-        gconfig=GenerationHyperparameters(
-            n=4,
-            max_new_tokens=int(os.environ.get("PROBE_MAX_NEW", 1024)),
-            temperature=1.0,
-        ),
-        optimizer=OptimizerConfig(lr=2e-5, warmup_steps_proportion=0.0),
-        ppo_kwargs={"disable_value": True, "kl_ctl": 0.0, "adv_norm": True,
-                    "n_minibatches": 2},
-        batch_size=n_prompts,
-        total_train_epochs=1,
-        ctrl=ExperimentSaveEvalControl(),
-        fileroot="/tmp/probe_mem_trial",
-        train_backend_args={"master_dtype": "bfloat16"},
-    )
-    live_gb("before build")
-    plan = build_ppo_math(pcfg, tok)
-    t0 = time.time()
-    _, stats = run_experiment(plan, tokenizer=tok)
-    print(f"[mem] trial step took {time.time() - t0:.1f}s, "
-          f"{len(stats)} steps")
-    live_gb("after trial")
-    print("[mem] TRIAL OK — production colocated path fits")
-
-
 if __name__ == "__main__":
-    if len(sys.argv) > 1 and sys.argv[1] == "trial":
-        main_trial(sys.argv[2] if len(sys.argv) > 2 else "1.5b")
-    else:
-        main(sys.argv[1] if len(sys.argv) > 1 else "1.5b")
+    main(sys.argv[1] if len(sys.argv) > 1 else "1.5b")

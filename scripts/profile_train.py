@@ -1,7 +1,6 @@
 """Train-step component timing at bench shapes (the MFU-gap hunt).
 
-Times, each in its own jitted program with host-transfer forcing
-(block_until_ready is unreliable on tunneled runtimes):
+Times, each in its own jitted program:
   1. backbone forward only
   2. backbone forward + fused logprob head
   3. full value_and_grad (fwd+bwd) under the chosen remat policy
@@ -29,13 +28,9 @@ def main():
     p.add_argument("--seqlen", type=int, default=1024)
     p.add_argument("--remat", default="full")
     p.add_argument("--iters", type=int, default=3)
-    p.add_argument("--platform", default="auto", choices=("auto", "cpu"))
     args = p.parse_args()
 
     import jax
-
-    if args.platform == "cpu":
-        jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
     import numpy as np
 
@@ -62,12 +57,11 @@ def main():
     fwd_flops = monitor.flops_forward(cfg, n_tok, float(b * s * s))
 
     def bench(name, fn, ops_flops, *fargs):
-        out = fn(*fargs)
-        jax.tree.map(np.asarray, out)
+        jax.block_until_ready(fn(*fargs))  # compile + warm
         t0 = time.perf_counter()
         for _ in range(args.iters):
             out = fn(*fargs)
-        jax.tree.map(np.asarray, out)
+        jax.block_until_ready(out)
         dt = (time.perf_counter() - t0) / args.iters
         tf = ops_flops / dt / 1e12
         print(f"{name:28s}: {dt * 1e3:8.1f} ms  {tf:7.1f} TFLOP/s")

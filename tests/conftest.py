@@ -16,24 +16,13 @@ if "xla_force_host_platform_device_count" not in flags:
     flags += " --xla_force_host_platform_device_count=8"
 os.environ["XLA_FLAGS"] = flags.strip()
 
-import jax
-
-# Site plugins (e.g. a TPU PJRT plugin registered via sitecustomize) may have
-# programmatically overridden jax_platforms; force CPU for the fake cluster.
-jax.config.update("jax_platforms", "cpu")
-
 # Persistent XLA compilation cache: the suite compiles hundreds of tiny
-# CPU programs and recompilation dominates wall-clock on small CI hosts,
-# so repeat runs reuse compiled artifacts across processes.  The dir is
-# machine-scoped (not repo-scoped) so fresh checkouts stay warm; set
-# AREAL_JAX_CACHE_DIR= (empty) to disable.
-_jax_cache_dir = os.environ.get(
-    "AREAL_JAX_CACHE_DIR", "/tmp/areal_tpu_jax_cache"
-)
-if _jax_cache_dir:
-    jax.config.update("jax_compilation_cache_dir", _jax_cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+# CPU programs and recompilation dominates wall-clock on small CI hosts;
+# worker subprocesses (apps/worker.py) enable the same directory, so they
+# reuse the parent's compiles and repeat runs start warm.
+from areal_tpu.base import compilation_cache
+
+compilation_cache.enable()
 
 import numpy as np
 import pytest

@@ -122,6 +122,21 @@ def test_sft_e2e_loss_decreases(mode, tmp_path):
             losses.append(stats["nll"])
     assert losses[-1] < losses[0] * 0.9, losses
 
+    # ZeRO-1: Adam's two moments are sharded like their params on every
+    # device, after real train steps — never a full replica per chip.
+    def dev_bytes(tree, dev):
+        return sum(
+            s.data.nbytes
+            for leaf in jax.tree.leaves(tree)
+            for s in leaf.addressable_shards
+            if s.device == dev
+        )
+
+    eng = model.engine
+    for dev in mesh.devices.flat:
+        p, o = dev_bytes(eng.params, dev), dev_bytes(eng.opt_state, dev)
+        assert 2 * p <= o <= 2 * p + 64, (mode, dev, p, o)
+
     # Evaluate + save.
     ev = interface.evaluate(model, [next(iter(dl))])
     assert "eval_nll" in ev

@@ -382,3 +382,74 @@ class TestDecodeAttentionKernel:
         np.testing.assert_array_equal(out_ker[empty], 0.0)
         assert np.abs(out_xla[~empty]).max() > 0
         np.testing.assert_allclose(out_ker, out_xla, rtol=2e-5, atol=2e-5)
+
+
+class TestDispatchHidesNothing:
+    def test_flash_error_propagates(self, rng, monkeypatch):
+        """`use_flash=True` means the kernel: a kernel that cannot run is
+        an error, never a quiet fall-through to the dense O(S^2) oracle."""
+        from areal_tpu.ops import attention
+        from areal_tpu.ops.pallas import flash_attention as fa
+
+        def boom(*a, **k):
+            raise NotImplementedError("kernel unavailable")
+
+        monkeypatch.setattr(fa, "flash_attention", boom)
+        q, k, v, seg = _inputs(rng, s=64)
+        with pytest.raises(NotImplementedError, match="kernel unavailable"):
+            attention.packed_attention(q, k, v, seg, use_flash=True)
+
+
+class TestTPULowering:
+    """Lower the kernels a chip can reach for `platforms=["tpu"]` at the
+    qwen2-1.5B head geometry (12 q heads, 2 kv heads, d 128).  No TPU is
+    needed: lowering already runs Pallas's TPU block-shape checks — the
+    guard that would have caught kernels that only ever ran interpreted."""
+
+    N_Q, N_KV, D = 12, 2, 128
+
+    @pytest.fixture(autouse=True)
+    def _as_on_tpu(self, monkeypatch):
+        # _interpret() follows the platform: compiled kernels, as on a chip.
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def _lowered(self, fn, *args):
+        text = jax.export.export(jax.jit(fn), platforms=["tpu"])(
+            *args
+        ).mlir_module()
+        assert "tpu_custom_call" in text  # Mosaic kernel, not interpreted
+        return text
+
+    def test_flash_forward_and_backward(self):
+        b, s = 2, 256
+        q = jax.ShapeDtypeStruct((b, s, self.N_Q, self.D), jnp.bfloat16)
+        kv = jax.ShapeDtypeStruct((b, s, self.N_KV, self.D), jnp.bfloat16)
+        seg = jax.ShapeDtypeStruct((b, s), jnp.int32)
+
+        def loss(q, k, v, seg):
+            return flash_attention(q, k, v, seg).astype(jnp.float32).sum()
+
+        self._lowered(flash_attention, q, kv, kv, seg)
+        text = self._lowered(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv, seg)
+        assert text.count("tpu_custom_call") >= 3  # fwd, dq, dkv
+
+    @pytest.mark.parametrize("pool", ["bf16", "int8"])
+    def test_ragged_stream_kernel(self, pool):
+        from areal_tpu.ops.pallas.paged_attention import (
+            ragged_paged_attention_kernel,
+        )
+
+        t, n_pool, ps, mp = 40, 64, 128, 4
+        dt = jnp.int8 if pool == "int8" else jnp.bfloat16
+        args = [
+            jax.ShapeDtypeStruct((t, self.N_Q, self.D), jnp.bfloat16),
+            jax.ShapeDtypeStruct((n_pool, ps, self.N_KV, self.D), dt),
+            jax.ShapeDtypeStruct((n_pool, ps, self.N_KV, self.D), dt),
+            jax.ShapeDtypeStruct((t, mp), jnp.int32),
+            jax.ShapeDtypeStruct((t,), jnp.int32),
+        ]
+        if pool == "int8":
+            args += [
+                jax.ShapeDtypeStruct((n_pool, ps, self.N_KV), jnp.bfloat16)
+            ] * 2
+        self._lowered(ragged_paged_attention_kernel, *args)

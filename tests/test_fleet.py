@@ -99,7 +99,7 @@ class TestFaultInjector:
         t = threading.Thread(target=worker)
         t.start()
         time.sleep(0.05)
-        assert t.is_alive()  # wedged, like a hung server
+        assert t.is_alive()  # still blocked, like a hung server
         inj.release()
         t.join(timeout=5)
         assert not t.is_alive() and len(errs) == 1

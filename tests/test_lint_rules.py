@@ -346,23 +346,6 @@ class TestSharding:
         errs = errors(fs, "sharding")
         assert len(errs) == 1 and "'pp'" in errs[0].message
 
-    def test_lax_axis_index_errors(self):
-        fs = lint("""
-            def body(x):
-                i = jax.lax.axis_index("model")
-                return x + i
-        """)
-        assert len(errors(fs, "sharding")) == 1
-
-    def test_suppressed_axis_index(self):
-        fs = lint("""
-            def body(x, my_index=None):
-                # arealint: ignore[sharding] -- caller threads my_index on old-jax paths
-                i = jax.lax.axis_index("model")
-                return x + i
-        """)
-        assert not errors(fs, "sharding")
-
 
 # --------------------------------------------------------------- stats-keys
 

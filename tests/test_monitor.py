@@ -53,11 +53,29 @@ class TestFlops:
         )
         assert lower < fl < upper
 
-    def test_mfu_with_env_override(self, monkeypatch):
-        monkeypatch.setenv("AREAL_PEAK_TFLOPS", "100")
-        # 1e12 flops in 0.1s on 1 device of 100 TFLOP/s peak -> 10% MFU
-        assert monitor.mfu(1e12, 0.1, 1) == pytest.approx(0.1)
-        assert monitor.mfu(1e12, 0.1, 2) == pytest.approx(0.05)
+    def test_mfu_against_the_device_peak(self, monkeypatch):
+        import jax
+
+        class _Dev:
+            platform = "tpu"
+            device_kind = "TPU v5 lite"
+
+        assert monitor.mfu(1e12, 0.1, 1) is None  # CPU: no peak, no MFU
+        monkeypatch.setattr(jax, "devices", lambda: [_Dev()])
+        # 1.97e13 flops in 1s on 1 device of 197 TFLOP/s peak -> 10% MFU
+        assert monitor.mfu(1.97e13, 1.0, 1) == pytest.approx(0.1)
+        assert monitor.mfu(1.97e13, 1.0, 2) == pytest.approx(0.05)
+
+    def test_unknown_accelerator_kind_raises(self, monkeypatch):
+        import jax
+
+        class _Dev:
+            platform = "tpu"
+            device_kind = "TPU v99"
+
+        monkeypatch.setattr(jax, "devices", lambda: [_Dev()])
+        with pytest.raises(ValueError, match="TPU v99"):
+            monitor.peak_tflops_per_device()
 
     def test_matmul_params_moe_counts_active_experts(self):
         """MoE counts only routed (active) experts at the MoE intermediate

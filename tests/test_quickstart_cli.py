@@ -99,3 +99,31 @@ def test_example_configs_keys_resolve(cfg):
     with pytest.raises(BaseException) as ei:
         quickstart.main([cmd, "--config", path])
     assert "unknown option" not in str(ei.value)
+
+
+def test_multiprocess_launcher_stays_off_the_jax_backend():
+    """A chip belongs to one process.  The `--multiprocess` launcher
+    (imports, plan build, metrics server) must never initialize a JAX
+    backend, or on a TPU host it would take the chips from its workers."""
+    import subprocess
+    import sys
+
+    code = """
+from jax._src import xla_bridge
+import areal_tpu.apps.quickstart, areal_tpu.apps.main
+from areal_tpu.api.config import ModelAbstraction
+from areal_tpu.api.data_api import DatasetAbstraction
+from areal_tpu.base import metrics
+from areal_tpu.experiments.common import PPOMathConfig, build_ppo_math
+from areal_tpu.models.config import tiny_config
+build_ppo_math(PPOMathConfig(
+    actor=ModelAbstraction("random", {"config": tiny_config()}),
+    dataset=DatasetAbstraction("math_code_prompt", {"dataset_path": "x"}),
+))
+metrics.MetricsServer(announce=("e", "t", "master")).close()
+assert not xla_bridge.backends_are_initialized()
+"""
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, timeout=120
+    )
+    assert out.returncode == 0, out.stderr.decode(errors="replace")[-2000:]

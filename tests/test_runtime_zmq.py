@@ -140,23 +140,13 @@ def test_sft_multihost_spmd(tmp_path):
     for wc in plan.worker_configs:
         wc.tokenizer_path = "char:512"
     assert plan.model_groups == {"default@0": [0, 1]}
-    try:
-        stats = runner.run_experiment(
-            plan,
-            worker_env={
-                "JAX_PLATFORMS": "cpu",
-                "XLA_FLAGS": "--xla_force_host_platform_device_count=2",
-            },
-        )
-    except Exception as e:
-        if "Multiprocess computations aren't implemented" in (
-            str(e) + str(e.__cause__ or "")
-        ):
-            pytest.skip(
-                "this jaxlib's CPU backend has no cross-process "
-                "collectives (needs a gloo-enabled build)"
-            )
-        raise
+    stats = runner.run_experiment(
+        plan,
+        worker_env={
+            "JAX_PLATFORMS": "cpu",
+            "XLA_FLAGS": "--xla_force_host_platform_device_count=2",
+        },
+    )
     assert len(stats) == 2
     assert np.isfinite(stats[-1]["nll"])
 

@@ -262,12 +262,8 @@ def test_pod_launcher_runs_a_real_trial(tmp_path):
             "log_root": str(tmp_path / "logs"),
             "poll_interval": 0.5,
         },
-        worker_env={
-            # tpu-pod mode does NOT force AREAL_WORKER_PLATFORM=cpu (pod
-            # workers own their chips); this fake pod is this CPU host.
-            "AREAL_WORKER_PLATFORM": "cpu",
-            "JAX_PLATFORMS": "cpu",
-        },
+        # Pod workers own their chips; this fake pod is this CPU host.
+        worker_env={"JAX_PLATFORMS": "cpu"},
     )
     assert len(stats) == 2
     assert np.isfinite(stats[-1]["actor_train/actor_loss"])
