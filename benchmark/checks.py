@@ -1,0 +1,193 @@
+"""What decides `correct`.
+
+Per timed step, what `chip_smoke.py` checks of a step: a finite non-zero
+loss, gradient and update norms above zero, the step not quarantined, the
+trainer's importance weight of the generator's tokens near 1, rewards
+that came from the verifier, token counts that add up.  Over the run: the
+route the cell's file declares, no compilation inside the window (where
+every step has the same batch), the generator holding the trainer's
+weights after the last hand-back.  And,
+outside the window, the architecture's plain reference
+(`benchmark/references/`) against the log-probabilities the generator
+returned and the ones the trainer recomputes.
+"""
+
+import numpy as np
+
+from benchmark import files
+
+REFERENCE_SEQS = 2
+
+
+def reference_check(obs, rollout):
+    """Teacher-force REFERENCE_SEQS rollout sequences (the first responses
+    to the prompt of median length: the same shapes, set-up time and
+    memory whatever the seed) through the plain reference and compare with
+    the generator's returned log-probs and the trainer's recomputed ones."""
+    import time
+
+    import jax
+
+    from areal_tpu.api.data_api import MicroBatchSpec, SequenceSample
+
+    t_start = time.monotonic()
+    run = obs.run
+    ref = files.load_module("references", run.config["benchmark"]["reference"])
+    # On the CPU the generator computes in fp32; the trainer keeps bf16.
+    tols = {"generator": ref.TOLERANCE, "trainer": ref.TOLERANCE,
+            "gen_vs_trainer": ref.TOLERANCE}
+    if jax.default_backend() == "cpu":
+        tols["generator"] = ref.TOLERANCE_FP32
+    by_len = sorted(
+        range(rollout.bs),
+        key=lambda i: (sum(rollout.seqlens["packed_input_ids"][i]), i),
+    )
+    pick = by_len[rollout.bs // 2]
+    one = rollout.select_idx([pick])
+    lens = [int(l) for l in one.seqlens["packed_input_ids"][0]][:REFERENCE_SEQS]
+    n_tok = sum(lens)
+    toks = np.asarray(one.data["packed_input_ids"])[:n_tok]
+    pmask = np.asarray(one.data["prompt_mask"])[:n_tok]
+    gen_lp = np.asarray(one.data["packed_logprobs"], np.float32)
+    # The trainer re-scores just these sequences (a whole group's forward
+    # pass would set the run's HBM peak).
+    trainer = obs.interfaces["actor"].inference(
+        obs.models["actor"],
+        SequenceSample(
+            keys={"packed_input_ids"}, ids=list(one.ids),
+            seqlens={"packed_input_ids": [lens]},
+            data={"packed_input_ids": toks},
+        ),
+        MicroBatchSpec(max_tokens_per_mb=8192),
+    )
+    trn_lp = np.asarray(trainer.data["logprobs"], np.float32)
+    trn_lens = [int(l) for l in trainer.seqlens["logprobs"][0]]
+    params = obs.models["actor_gen"].engine.get_params()
+    report = {"n_tokens": 0}
+    diffs = {"generator": [], "trainer": [], "gen_vs_trainer": []}
+    off = lp_off = t_off = 0
+    for j, length in enumerate(lens):
+        seq = toks[off: off + length]
+        n_prompt = int(pmask[off: off + length].sum())
+        want = ref.next_token_logprobs(params, run.model_cfg, seq)
+        # Position t scores token t + 1; responses start at n_prompt.
+        resp = slice(n_prompt - 1, length - 1)
+        g = gen_lp[lp_off: lp_off + length - 1][resp]
+        t = trn_lp[t_off: t_off + trn_lens[j]][: length - 1][resp]
+        diffs["generator"].append(np.abs(g - want[resp]))
+        diffs["trainer"].append(np.abs(t - want[resp]))
+        diffs["gen_vs_trainer"].append(np.abs(g - t))
+        report["n_tokens"] += length - n_prompt
+        off += length
+        lp_off += length - 1
+        t_off += trn_lens[j]
+    ok = report["n_tokens"] > 0
+    for name, d in diffs.items():
+        d = np.concatenate(d)
+        report[f"{name}_mean_abs"] = float(d.mean())
+        report[f"{name}_max_abs"] = float(d.max())
+        ok = ok and bool(
+            np.isfinite(d).all() and d.mean() <= tols[name]["mean_abs"]
+            and d.max() <= tols[name]["max_abs"]
+        )
+    report["ok"] = ok
+    report["seconds"] = round(time.monotonic() - t_start, 2)
+    return report
+
+
+def handback_equal(train, gen):
+    """The generator holds the trainer's weights after the last hand-back
+    (the leaves `chip_smoke.py` compares: zero at init, so they also show
+    that training moved them)."""
+    t_bq = np.asarray(train.params["blocks"]["bq"], np.float32)
+    g_bq = np.asarray(gen.params["blocks"]["bq"], np.float32)
+    ok = (np.isfinite(t_bq).all() and float(np.abs(t_bq).max()) > 0.0
+          and np.array_equal(t_bq, g_bq))
+    for leaf in ("embed", "final_ln"):
+        a = np.asarray(train.params[leaf][:64], np.float32)
+        b = np.asarray(gen.params[leaf][:64], np.float32)
+        ok = ok and np.array_equal(a, b)
+    return bool(ok)
+
+
+def check_step(i, step, run):
+    st = step["stats"]
+    n_seqs = run.traffic["n_prompts"] * run.traffic["group"]
+    max_new = run.traffic["max_new_tokens"]
+    out = []
+
+    def need(cond, what):
+        if not cond:
+            out.append(f"step {i}: {what}")
+
+    loss = st["actor_train/actor_loss"]
+    need(np.isfinite(loss) and loss != 0.0, f"actor loss {loss}")
+    need(np.isfinite(st["actor_train/grad_norm"])
+         and st["actor_train/grad_norm"] > 0
+         and st["actor_train/update_norm"] > 0,
+         "gradient or update norm not above zero")
+    need(st["actor_train/quarantined"] == 0, "step quarantined")
+    iw = st["actor_train/importance_weight"]
+    need(0.8 < iw < 1.25, f"importance weight {iw} not within 0.8-1.25")
+    need(st["actor_train/task_reward"] == -5.0,
+         "rewards did not come from the verifier (a random model scores -5)")
+    n_gen = run.gen_tokens(step)
+    need(len(step["seq_lens"]) == n_seqs, f"{len(step['seq_lens'])} sequences")
+    need(st["actor_train/n_response_tokens"] == n_gen,
+         f"trainer saw {st['actor_train/n_response_tokens']} response "
+         f"tokens, generator returned {n_gen}")
+    if run.traffic.get("eos_reachable"):
+        need(0 < n_gen <= n_seqs * max_new, f"{n_gen} generated tokens")
+    else:  # no reachable EOS: every row runs to its budget
+        need(st["actor_train/no_eos_ratio"] == 1.0
+             and n_gen == n_seqs * max_new,
+             f"{n_gen} generated tokens, not {n_seqs} x {max_new}")
+    return out
+
+
+def check_route(run):
+    out = []
+    route = run.cell["route"]
+    programs = set(run.programs)
+    # One batch, every step the same: the warm-up step built every program.
+    # Traffic with new batches builds programs inside the window; the cost
+    # is reported (`compiles_in_window`), the route is still checked.
+    fixed = run.traffic.get("batches", 1) == 1
+    for i, step in enumerate(run.steps):
+        g = step["gen"]
+        if route == "serving":
+            if not (g["lanes_dispatched"] > 0 and g["serving_lane_budget"] > 0
+                    and g["prefill_dispatches"] == 0
+                    and g["dead_live_lanes"] == 0
+                    and g["cache_copy_bytes"] == 0):
+                out.append(f"step {i}: not the ragged serving chunk: {g}")
+        elif g["lanes_dispatched"] != 0 or g["prefill_dispatches"] != 0:
+            out.append(f"step {i}: not the static decode program: {g}")
+        if fixed and g["decode_compiles"] != 0:
+            out.append(f"step {i}: {g['decode_compiles']} decode compiles")
+    if route == "serving":
+        if "serving_chunk" not in programs or programs & {
+            "prefill_pages", "paged_inflight", "inflight", "prefill_slots"
+        } or (fixed and run.programs.count("serving_chunk") != 1):
+            out.append(f"serving route built programs {run.programs}")
+    elif "serving_chunk" in programs:
+        out.append(f"static route built programs {run.programs}")
+    if fixed and run.compiles_in_window:
+        out.append(f"{run.compiles_in_window} compilations inside the window")
+    return out
+
+
+def check_run(run):
+    """Every problem found, as text; empty means `correct`."""
+    out = []
+    if len(run.steps) < 2:
+        out.append(f"{len(run.steps)} timed steps")
+    for i, step in enumerate(run.steps):
+        out += check_step(i, step, run)
+    out += check_route(run)
+    if not getattr(run, "handback_ok", False):
+        out.append("generator weights differ from the trainer's after the "
+                   "last hand-back")
+    if not (run.reference or {}).get("ok"):
+        out.append(f"reference check failed: {run.reference}")
+    return out

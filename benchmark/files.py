@@ -1,0 +1,53 @@
+"""Find a cell's files by the names in BENCHMARK.json — the harness names
+no cell, no model, no traffic mix and no metric itself.
+
+    workloads/<cell>.json      config, traffic, chips, expected route
+    configs/<config>.json      the model's published keys + a "benchmark"
+                               group (reference, layout, reduced, assumed)
+    traffic/<traffic>.json     parameters of one traffic mix; its
+                               "generator" key names traffic/<generator>.py
+    references/<reference>.py  the architecture's plain fp32 forward pass
+    metrics/<metric>.py        one reader: read(run) -> number or None
+"""
+
+import importlib
+import json
+import os
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+
+
+def load_json(*parts):
+    path = os.path.join(HERE, *parts)
+    with open(path) as f:
+        return json.load(f)
+
+
+def benchmark_json():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def load_cell(name):
+    """(cell, config, traffic) dicts of the cell called `name`."""
+    cell = load_json("workloads", f"{name}.json")
+    config = load_json("configs", f"{cell['config']}.json")
+    traffic = load_json("traffic", f"{cell['traffic']}.json")
+    return cell, config, traffic
+
+
+def load_module(kind, name):
+    """benchmark/<kind>/<name>.py, e.g. ("metrics", "step_s")."""
+    return importlib.import_module(f"benchmark.{kind}.{name}")
+
+
+def metrics_for(cell_name, traced):
+    """The metric entries of BENCHMARK.json this cell reports in this
+    kind of run: end-to-end ones untraced, per-layer ones traced."""
+    spec = benchmark_json()
+    entries = spec["per_layer"] if traced else spec["end_to_end"]
+    return [
+        m for m in entries
+        if "workloads" not in m or cell_name in m["workloads"]
+    ]
