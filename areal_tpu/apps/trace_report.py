@@ -27,6 +27,13 @@ plus a per-step overlap fraction (how much of the stages' summed busy
 time ran concurrently — 0 under the barrier scheduler, > 0 once chunks
 of different stages execute at the same time).
 
+``--spans`` switches to the per-step span table: for every step window
+and span name the count, total and SELF seconds (duration minus the spans
+nested inside it on the same thread) — which of ``pack``,
+``grad_dispatch``, ``stats_sync``, ``chunk_host``, ``chunk_wait``,
+``params_put`` ... a slow step spent its time in.  With ``--json`` the
+rows are printed as one JSON list.
+
 ``--lineage`` switches to the causal-lineage view: joins the merged
 shards by ``trace_id`` (the ``lineage:*`` instant events every stage of
 the async-RL pipeline stamps) and renders one end-to-end timeline per
@@ -392,6 +399,59 @@ _LINEAGE_TRANSITIONS = (
 )
 
 
+def span_rows(trace) -> List[Dict[str, Any]]:
+    """-> one row per (step, span name): {step, name, n, total_us,
+    self_us}.  Self time is a span's duration minus the spans nested in
+    it on the same thread; a span belongs to the step window that holds
+    its midpoint."""
+    windows = _step_windows(trace)
+    by_thread: Dict[Tuple[int, int], List[Dict]] = {}
+    for e in trace.get("traceEvents", []):
+        if e.get("ph") == "X":
+            by_thread.setdefault((e["pid"], e.get("tid", 0)), []).append(e)
+    acc: Dict[Tuple[Any, str], List[int]] = {}
+    for spans in by_thread.values():
+        # Parents first: earlier start, and the longer span at equal starts.
+        spans.sort(key=lambda e: (int(e["ts"]), -int(e["dur"])))
+        stack: List[List[int]] = []  # [end, self_us, slot in `closed`]
+        closed: List[List[Any]] = []  # [event, self_us]
+        for e in spans:
+            ts, dur = int(e["ts"]), int(e["dur"])
+            while stack and stack[-1][0] <= ts:
+                stack.pop()
+            if stack:
+                closed[stack[-1][1]][1] -= min(dur, stack[-1][0] - ts)
+            closed.append([e, dur])
+            stack.append([ts + dur, len(closed) - 1])
+        for e, self_us in closed:
+            mid = int(e["ts"]) + int(e["dur"]) // 2
+            step = next(
+                (num for num, lo, hi in windows if lo <= mid < hi), None
+            )
+            rec = acc.setdefault((step, e["name"]), [0, 0, 0])
+            rec[0] += 1
+            rec[1] += int(e["dur"])
+            rec[2] += max(self_us, 0)
+    return [
+        {"step": step, "name": name, "n": n, "total_us": tot, "self_us": own}
+        for (step, name), (n, tot, own) in sorted(
+            acc.items(), key=lambda kv: (kv[0][0] is None, kv[0][0] or 0,
+                                         -kv[1][2])
+        )
+    ]
+
+
+def format_spans(trace) -> str:
+    lines = [f"{'step':>5} {'span':<40} {'n':>5} {'total_s':>10} {'self_s':>10}"]
+    for r in span_rows(trace):
+        step = "-" if r["step"] is None else r["step"]
+        lines.append(
+            f"{step:>5} {r['name'][:40]:<40} {r['n']:>5} "
+            f"{r['total_us'] / 1e6:>10.4f} {r['self_us'] / 1e6:>10.4f}"
+        )
+    return "\n".join(lines)
+
+
 def _pctl(vals: List[float], q: float) -> float:
     if not vals:
         return 0.0
@@ -685,6 +745,10 @@ def main(argv: Optional[List[str]] = None) -> int:
         "(from pipe:* spans) instead of the stall tables",
     )
     p.add_argument(
+        "--spans", action="store_true",
+        help="per step and span name: count, total and self seconds",
+    )
+    p.add_argument(
         "--lineage", action="store_true",
         help="per-sample causal timelines joined by trace_id "
         "(dispatch -> ... -> trained) instead of the stall tables",
@@ -720,7 +784,10 @@ def main(argv: Optional[List[str]] = None) -> int:
         for e in errors:
             print(f"  - {e}", file=sys.stderr)
         return 1
-    if args.json:
+    if args.spans:
+        print(json.dumps(span_rows(trace)) if args.json
+              else format_spans(trace))
+    elif args.json:
         print(json.dumps(json_report(trace, top=args.top)))
     elif args.pipeline:
         print(format_pipeline(trace))

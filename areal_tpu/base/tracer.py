@@ -11,9 +11,18 @@ single Perfetto-loadable ``trace.json`` — one track per process, one
 thread lane per tid, counter tracks for sampled gauges.
 
 Design constraints:
-- Zero overhead when disabled: ``span()`` returns a shared no-op context
-  manager after one dict build + one bool check; no clock reads, no
-  buffer traffic (acceptance: <1% on the bench generate path).
+- Near-zero overhead when disabled: ``span()`` costs one dict build, one
+  bool check and one inert profiler annotation; no clock reads, no
+  buffer traffic (PERF.md has the measured nanoseconds per span).
+- One clock with the device: every span also opens a
+  ``jax.profiler.TraceAnnotation("areal:<name>")`` (the master's step a
+  ``StepTraceAnnotation``), whether or not ``AREAL_TRACE`` is set.
+  ``TraceMe`` checks for a live profiler session in C++ and is inert
+  without one, so ANY session — an xprof capture, ``AREAL_DUMP_TRACE``,
+  the benchmark's ``--trace 1`` — carries the program's spans on its
+  ``/host:CPU`` plane, on the device planes' clock.  ``jax`` is never
+  imported from here: a process that has not loaded it has no profiler
+  session to write to, and its spans skip the annotation.
 - No locks on the hot path: each thread appends to its own
   ``collections.deque(maxlen=...)`` (GIL-atomic); the global registry
   lock is taken once per thread lifetime and at flush.
@@ -34,6 +43,12 @@ Usage::
         args["tflops"] = 1.23
     tracer.counter("kv_pool", live_tokens=512, allocated_tokens=4096)
     tracer.flush()
+
+A ring event records name, start, duration, thread, and what caused it:
+``parent`` is the span open on the same thread when it began, and across
+the master -> worker hop the worker's ``mfc:*`` / ``param_sync:*`` /
+``fetch`` spans carry the ``step`` of the master's ``step`` span that
+dispatched them (the pools stamp it into every request).
 
 Categories drive the stall-attribution report (apps/trace_report.py):
 ``compute`` (device math), ``comms`` (data/param movement and the waits
@@ -63,6 +78,7 @@ import atexit
 import collections
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -170,31 +186,80 @@ def _buf() -> collections.deque:
     return b
 
 
-class _Span:
-    __slots__ = ("name", "cat", "args", "t0")
+_ANNOTATIONS = None  # (TraceAnnotation, StepTraceAnnotation) once jax is loaded
 
-    def __init__(self, name: str, cat: Optional[str], args: Dict):
+
+def _annotations():
+    """jax.profiler's annotation classes, or None while this process has
+    not imported jax (never imported from here: the launcher, the reward
+    service and the verifier pool run without it)."""
+    global _ANNOTATIONS
+    if _ANNOTATIONS is None:
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        if profiler is None:
+            return None
+        _ANNOTATIONS = (
+            profiler.TraceAnnotation, profiler.StepTraceAnnotation
+        )
+    return _ANNOTATIONS
+
+
+def _annotate(name: str, args: Dict):
+    """The profiler-side half of a span: inert (a C++ bool check) unless
+    a profiler session is live in this process."""
+    classes = _annotations()
+    return classes and classes[0]("areal:" + name, **args)
+
+
+def _stack() -> list:
+    st = getattr(_tls, "stack", None)
+    if st is None:
+        st = _tls.stack = []
+    return st
+
+
+class _Span:
+    __slots__ = ("name", "cat", "args", "t0", "ann", "parent")
+
+    def __init__(self, name: str, cat: Optional[str], args: Dict, ann):
         self.name = name
         self.cat = cat
         self.args = args
+        self.ann = ann
 
     def __enter__(self) -> Dict:
+        st = _stack()
+        self.parent = st[-1].name if st else None
+        st.append(self)
+        if self.ann is not None:
+            self.ann.__enter__()
         self.t0 = time.monotonic_ns()
         return self.args
 
     def __exit__(self, *exc) -> bool:
         t1 = time.monotonic_ns()
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+        st = _stack()
+        if st and st[-1] is self:
+            st.pop()
+        elif self in st:  # coroutines on one thread close out of order
+            st.remove(self)
         ev = {
             "ph": "X",
             "name": self.name,
             "ts": self.t0 // 1000,
             "dur": max((t1 - self.t0) // 1000, 1),
             "tid": threading.get_ident(),
+            # The caller's own dict, also when still empty: values written
+            # after the block (the MFC's token counts) reach the shard.
+            "args": self.args,
         }
         if self.cat:
             ev["cat"] = self.cat
-        if self.args:
-            ev["args"] = self.args
+        if self.parent:
+            ev["parent"] = self.parent
         _buf().append(ev)
         _flight.append(
             {
@@ -209,25 +274,44 @@ class _Span:
 
 
 class _NoopSpan:
-    """Shared disabled-path span: __enter__ hands back the caller's own
-    args dict so post-hoc ``args[...] = v`` writes stay valid and cheap."""
+    """Disabled-path span: no clock read, no ring entry.  __enter__ hands
+    back the caller's own args dict so post-hoc ``args[...] = v`` writes
+    stay valid and cheap; the annotation is all that runs."""
 
-    __slots__ = ("args",)
+    __slots__ = ("args", "ann")
 
-    def __init__(self, args: Dict):
+    def __init__(self, args: Dict, ann):
         self.args = args
+        self.ann = ann
 
     def __enter__(self) -> Dict:
+        if self.ann is not None:
+            self.ann.__enter__()
         return self.args
 
     def __exit__(self, *exc) -> bool:
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
         return False
 
 
-def span(name: str, cat: Optional[str] = None, **args) -> Any:
+def _span(name: str, cat: Optional[str], args: Dict, ann) -> Any:
     if not _state["enabled"]:
-        return _NoopSpan(args)
-    return _Span(name, cat, args)
+        return _NoopSpan(args, ann)
+    return _Span(name, cat, args, ann)
+
+
+def span(name: str, cat: Optional[str] = None, **args) -> Any:
+    return _span(name, cat, args, _annotate(name, args))
+
+
+def step_span(step: int) -> Any:
+    """The master's per-step span: ``span("step", step=n)`` whose
+    annotation is a ``StepTraceAnnotation``, so xprof's step views see the
+    training steps."""
+    classes = _annotations()
+    ann = classes and classes[1]("areal:step", step_num=int(step))
+    return _span("step", None, {"step": int(step)}, ann)
 
 
 def trace(name: Optional[str] = None, cat: Optional[str] = None):
@@ -240,8 +324,6 @@ def trace(name: Optional[str] = None, cat: Optional[str] = None):
 
         @functools.wraps(fn)
         def wrapped(*a, **kw):
-            if not _state["enabled"]:
-                return fn(*a, **kw)
             with span(label, cat=cat):
                 return fn(*a, **kw)
 
@@ -304,6 +386,9 @@ def complete(
         ev["cat"] = cat
     if args:
         ev["args"] = args
+    st = _stack()
+    if st:
+        ev["parent"] = st[-1].name
     _buf().append(ev)
 
 
@@ -506,6 +591,7 @@ def _reset_for_tests() -> None:
         for b in _buffers:
             b.clear()
         _flight.clear()
+    _tls.stack = []
 
 
 atexit.register(flush)

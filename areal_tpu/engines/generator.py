@@ -49,6 +49,7 @@ from areal_tpu.models import transformer as tfm
 from areal_tpu.models.config import ModelConfig
 from areal_tpu.ops.sampling import sample_token
 from areal_tpu.parallel import sharding
+from areal_tpu.parallel.realloc import tree_bytes
 
 logger = logging.getLogger("generator")
 
@@ -258,6 +259,13 @@ def _spec_emit(
     )
 
 
+def _new_chunk_stats() -> Dict[str, Any]:
+    return {
+        "chunks": 0, "admitted": 0, "retired": 0, "chunk_host_s": 0.0,
+        "admit_waits": [], "n_waited": 0,
+    }
+
+
 class GeneratorEngine(HostOffloadMixin, Engine):
     def __init__(
         self,
@@ -418,6 +426,13 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         self.decode_compiles = 0
         self.cache_copy_bytes = 0
         self.last_pool_stats: Dict[str, Any] = {}
+        # What the last set_params() placed: global bytes and the host
+        # seconds of its cast / device_put / alias copy.
+        self.last_sync_stats: Dict[str, float] = {}
+        # Serving-plane chunk counters of the current generate() call
+        # (see _serving_counters); folded into last_pool_stats at its end.
+        self._chunk_stats: Dict[str, Any] = _new_chunk_stats()
+        self._gen_t0 = time.monotonic()
         # Ragged-stream lane accounting (serving chunk only; reset in
         # generate()): lanes_dispatched = query lanes launched (chunk
         # steps x T), lanes_live = lanes carrying a real token,
@@ -562,19 +577,27 @@ class GeneratorEngine(HostOffloadMixin, Engine):
     # ---------------- weights ----------------
 
     def set_params(self, params) -> None:
-        """Hot-swap weights (cast to compute dtype, shard onto our mesh)."""
-        cast = jax.tree.map(
-            lambda x: x.astype(self.compute_dtype)
-            if jnp.issubdtype(x.dtype, jnp.floating)
-            else x,
-            params,
-        )
+        """Hot-swap weights (cast to compute dtype, shard onto our mesh).
+        `last_sync_stats` keeps what this call placed and how long each
+        statement held the host (the worker returns it to the master)."""
+        t0 = time.monotonic()
+        with tracer.span("params_cast", cat="comms"):
+            cast = jax.tree.map(
+                lambda x: x.astype(self.compute_dtype)
+                if jnp.issubdtype(x.dtype, jnp.floating)
+                else x,
+                params,
+            )
         # New weights supersede any host-offloaded copy.
         self._host_offload = None
         self._offload_shardings = None
-        placed = jax.device_put(
-            cast, sharding.tree_named(self.mesh, sharding.param_pspecs(cast))
-        )
+        t1 = time.monotonic()
+        with tracer.span("params_put", cat="comms"):
+            placed = jax.device_put(
+                cast,
+                sharding.tree_named(self.mesh, sharding.param_pspecs(cast)),
+            )
+        t2 = time.monotonic()
         # Donation safety: same-dtype/same-sharding astype+device_put can
         # ALIAS the source engine's live buffers, which its optimizer step
         # later DONATES — async rollout would then decode from deleted
@@ -586,14 +609,22 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         if self.donation_safe_swap:
             from areal_tpu.engines.offload import buffers_alias
 
-            self.params = jax.tree.map(
-                lambda p, orig: (
-                    jnp.copy(p) if buffers_alias(p, orig) else p
-                ),
-                placed, params,
-            )
+            with tracer.span("params_alias_copy", cat="comms"):
+                self.params = jax.tree.map(
+                    lambda p, orig: (
+                        jnp.copy(p) if buffers_alias(p, orig) else p
+                    ),
+                    placed, params,
+                )
         else:
             self.params = placed
+        self.last_sync_stats = {
+            "bytes": float(tree_bytes(placed)),
+            "cast_s": t1 - t0,
+            "put_s": t2 - t1,
+            "alias_copy_s": time.monotonic() - t2,
+        }
+        tracer.counter("param_sync", **self.last_sync_stats)
 
     def get_params(self):
         self._ensure_loaded()
@@ -677,6 +708,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         self.lanes_slack = 0
         self.dead_live_lanes = 0
         self._gen_t0 = time.monotonic()
+        self._chunk_stats = _new_chunk_stats()
         prompt_lens = sample.seqlens_of(prompt_key)
         bounds = sample.cu_seqlens(prompt_key)
         prompts = np.asarray(sample.data[prompt_key])
@@ -1633,100 +1665,115 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                     parked_pending=len(st.pending),
                 )
                 return False
-            self._take_admits_serving(st)
-            # Map pages covering this chunk's worst-case advance per live
-            # slot: a prefilling row consumes up to chunk_t*Wmax prompt
-            # tokens (but never more than its remainder + the decode
-            # steps that may follow); a decoding row advances at most
-            # chunk_t (plain) or chunk_t*(K+1) (spec), clamped to its
-            # remaining emission budget + K draft-scratch positions —
-            # tokens past max_new are drained away anyway, so reserving
-            # for them would make a nearly-finished row hold pages it
-            # never usefully writes (over-budget writes drop via the
-            # sentinel; the positions they would have filled are only
-            # ever attended by tokens that are themselves over budget
-            # and discarded at drain).  Host-side int appends only.
-            max_new = gconfig.max_new_tokens
-            K = gconfig.spec_decode_k
-            Wmax = max(W, K + 1)
-            for s in range(n_slots):
-                if st.active[s] is not None:
-                    rem = int(st.prefill_rem[s])
-                    left = max(0, max_new - int(st.gen_count[s]))
-                    target = int(st.cache_len[s]) + max(
-                        1, min(
-                            chunk_t * Wmax,
-                            rem + chunk_t * (K + 1),
-                            rem + left + K,
+            cs = self._chunk_stats
+            t_host = time.monotonic()
+            with tracer.span("chunk_host", cat="host"):
+                self._take_admits_serving(st)
+                # Map pages covering this chunk's worst-case advance per live
+                # slot: a prefilling row consumes up to chunk_t*Wmax prompt
+                # tokens (but never more than its remainder + the decode
+                # steps that may follow); a decoding row advances at most
+                # chunk_t (plain) or chunk_t*(K+1) (spec), clamped to its
+                # remaining emission budget + K draft-scratch positions —
+                # tokens past max_new are drained away anyway, so reserving
+                # for them would make a nearly-finished row hold pages it
+                # never usefully writes (over-budget writes drop via the
+                # sentinel; the positions they would have filled are only
+                # ever attended by tokens that are themselves over budget
+                # and discarded at drain).  Host-side int appends only.
+                max_new = gconfig.max_new_tokens
+                K = gconfig.spec_decode_k
+                Wmax = max(W, K + 1)
+                for s in range(n_slots):
+                    if st.active[s] is not None:
+                        rem = int(st.prefill_rem[s])
+                        left = max(0, max_new - int(st.gen_count[s]))
+                        target = int(st.cache_len[s]) + max(
+                            1, min(
+                                chunk_t * Wmax,
+                                rem + chunk_t * (K + 1),
+                                rem + left + K,
+                            )
                         )
-                    )
-                    self._reserve_with_evict(alloc, s, target)
-            self._privatize_write_windows(st)
-            self._accum_pool_stats(
-                "paged", int(st.cache_len.sum()), alloc.allocated_pages() * ps
-            )
+                        self._reserve_with_evict(alloc, s, target)
+                self._privatize_write_windows(st)
+                self._accum_pool_stats(
+                    "paged", int(st.cache_len.sum()), alloc.allocated_pages() * ps
+                )
 
-            st.key, sub = jax.random.split(st.key)
-            prev_gen = st.gen_count.copy()
-            prev_rem = st.prefill_rem.copy()
+                st.key, sub = jax.random.split(st.key)
+                prev_gen = st.gen_count.copy()
+                prev_rem = st.prefill_rem.copy()
+            cs["chunk_host_s"] += time.monotonic() - t_host
             with tracer.span(
                 "serving_chunk", cat="compute", t=chunk_t, w=W
             ):
-                (
-                    out_toks, out_logps, st.logits_buf, st.pool,
-                    new_cache_len, new_gen_count, new_done, new_rem,
-                    new_off, st.tokens_buf, st.pending_tok, lane_acc,
-                ) = chunk_fn(
-                    self.params, st.pool, st.logits_buf,
-                    jnp.asarray(alloc.table), jnp.asarray(st.prompt_buf),
-                    jnp.asarray(st.prompt_off), jnp.asarray(st.prefill_rem),
-                    jnp.asarray(st.cache_len), jnp.asarray(st.gen_count),
-                    jnp.asarray(st.done_host), st.tokens_buf,
-                    st.pending_tok, sub,
-                )
+                with tracer.span("chunk_dispatch", cat="compute"):
+                    (
+                        out_toks, out_logps, st.logits_buf, st.pool,
+                        new_cache_len, new_gen_count, new_done, new_rem,
+                        new_off, st.tokens_buf, st.pending_tok, lane_acc,
+                    ) = chunk_fn(
+                        self.params, st.pool, st.logits_buf,
+                        jnp.asarray(alloc.table),
+                        jnp.asarray(st.prompt_buf),
+                        jnp.asarray(st.prompt_off),
+                        jnp.asarray(st.prefill_rem),
+                        jnp.asarray(st.cache_len),
+                        jnp.asarray(st.gen_count),
+                        jnp.asarray(st.done_host), st.tokens_buf,
+                        st.pending_tok, sub,
+                    )
                 # ONE host-sync block per chunk (the done/eos flags must
                 # be exact before the next admission round) — the lane
                 # counters ride it rather than adding a sync of their
                 # own.
-                out_toks = to_host(out_toks)
-                out_logps = to_host(out_logps)
-                lane_acc = to_host(lane_acc)
-            st.cache_len = to_host(new_cache_len).copy()
-            st.gen_count = to_host(new_gen_count).copy()
-            st.prefill_rem = to_host(new_rem).copy()
-            st.prompt_off = to_host(new_off).copy()
-            st.last_emit = st.gen_count - prev_gen
-            self.lanes_dispatched += chunk_t * self.serving_lane_budget
-            self.lanes_live += int(lane_acc[0])
-            self.lanes_slack += int(lane_acc[1])
-            self.dead_live_lanes += int(lane_acc[2])
+                with tracer.span("chunk_wait", cat="compute"):
+                    out_toks = to_host(out_toks)
+                    out_logps = to_host(out_logps)
+                    lane_acc = to_host(lane_acc)
+            cs["chunks"] += 1
+            t_host = time.monotonic()
+            with tracer.span("chunk_host", cat="host"):
+                st.cache_len = to_host(new_cache_len).copy()
+                st.gen_count = to_host(new_gen_count).copy()
+                st.prefill_rem = to_host(new_rem).copy()
+                st.prompt_off = to_host(new_off).copy()
+                st.last_emit = st.gen_count - prev_gen
+                self.lanes_dispatched += chunk_t * self.serving_lane_budget
+                self.lanes_live += int(lane_acc[0])
+                self.lanes_slack += int(lane_acc[1])
+                self.dead_live_lanes += int(lane_acc[2])
 
-            # Register prefixes that FINISHED prefilling this chunk,
-            # before any retirement below can release the owner's pages:
-            # the cache's per-page holds then keep them alive for
-            # followers regardless of when the owner finishes decoding.
-            if self.kv_share_prefix:
-                for s in range(n_slots):
-                    if (
-                        st.active[s] is not None
-                        and prev_rem[s] > 0
-                        and st.prefill_rem[s] == 0
-                    ):
-                        self._register_prefix(st, s)
+                # Register prefixes that FINISHED prefilling this chunk,
+                # before any retirement below can release the owner's pages:
+                # the cache's per-page holds then keep them alive for
+                # followers regardless of when the owner finishes decoding.
+                if self.kv_share_prefix:
+                    for s in range(n_slots):
+                        if (
+                            st.active[s] is not None
+                            and prev_rem[s] > 0
+                            and st.prefill_rem[s] == 0
+                        ):
+                            self._register_prefix(st, s)
 
-            def _retire(s):
-                alloc.release(s)
-                st.slot_prompt.pop(s, None)
-                h = st.slot_hash.pop(s, None)
-                if h is not None and st.inflight_prefix.get(h) == s:
-                    del st.inflight_prefix[h]
+                def _retire(s):
+                    cs["retired"] += 1
+                    alloc.release(s)
+                    st.slot_prompt.pop(s, None)
+                    h = st.slot_hash.pop(s, None)
+                    if h is not None and st.inflight_prefix.get(h) == s:
+                        del st.inflight_prefix[h]
 
-            self._drain_chunk_outputs(
-                out_toks, out_logps, to_host(new_done), st.active,
-                st.toks_acc, st.logps_acc, st.results, st.done_host,
-                st.cache_len, gconfig.max_new_tokens, on_retire=_retire,
-                stop_seqs=gconfig.stop,
-            )
+                self._drain_chunk_outputs(
+                    out_toks, out_logps, to_host(new_done), st.active,
+                    st.toks_acc, st.logps_acc, st.results, st.done_host,
+                    st.cache_len, gconfig.max_new_tokens, on_retire=_retire,
+                    stop_seqs=gconfig.stop,
+                )
+            cs["chunk_host_s"] += time.monotonic() - t_host
+        self.last_pool_stats.update(self._serving_counters())
         self.last_pool_stats.update(
             pool_pages=st.n_pages, page_size=ps,
             pages_recycled=alloc.pages_recycled,
@@ -1812,6 +1859,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             st.prompt_off[s] = 0
             st.last_emit[s] = 0
             admitted += 1
+        self._note_admits(admitted)
         if (
             admitted == 0
             and st.pending
@@ -1834,6 +1882,37 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             "gen_slots", live=self.live_slots, pending=len(st.pending)
         )
         return admitted
+
+    def _note_admits(self, admitted: int) -> None:
+        """Admission counters of this generate() call: how many requests
+        got a slot in this round and how long after generate() began —
+        requests admitted after the first chunk WAITED for a retirement."""
+        cs = self._chunk_stats
+        if not admitted:
+            return
+        cs["admitted"] += admitted
+        wait = time.monotonic() - self._gen_t0
+        cs["admit_waits"] += [wait] * admitted
+        if cs["chunks"]:
+            cs["n_waited"] += admitted
+
+    def _serving_counters(self) -> Dict[str, float]:
+        """The serving loop's per-generate() counters as last_pool_stats
+        keys: chunks run, requests admitted and retired, host seconds at
+        chunk boundaries, and the spread of the seconds from generate()'s
+        start to each request's admission."""
+        cs = self._chunk_stats
+        waits = sorted(cs["admit_waits"])
+        out = {
+            "chunks": cs["chunks"], "admitted": cs["admitted"],
+            "retired": cs["retired"], "chunk_host_s": cs["chunk_host_s"],
+            "n_waited": cs["n_waited"],
+            "admit_wait_mean_s": sum(waits) / max(len(waits), 1),
+            "admit_wait_p50_s": waits[len(waits) // 2] if waits else 0.0,
+            "admit_wait_max_s": waits[-1] if waits else 0.0,
+        }
+        tracer.counter("serving_chunks", **out)
+        return out
 
     def _register_prefix(self, st: "_PagedGenSession", s: int) -> None:
         """Publish slot `s`'s full prompt pages in the prefix cache (one
@@ -1971,6 +2050,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             from areal_tpu.ops.ngram import propose_ngram
 
         @functools.partial(jax.jit, donate_argnums=(1, 2, 10))
+        @jax.named_scope("gen/serving_chunk")
         def fn(params, pool, logits, page_table, prompt_buf, prompt_off,
                prefill_rem, cache_len, gen_count, done, tokens_buf,
                pending, key):
@@ -3003,14 +3083,16 @@ class GeneratorEngine(HostOffloadMixin, Engine):
 
         fn = self._get_gen_fn(b, sp, s_total, gconfig)
         with tracer.span("gen_chunk", cat="compute", b=b_real, sp=sp):
-            toks, logps, gen_len = fn(
-                self.params, prompt_tok, prompt_len, key
-            )
-            toks, logps, gen_len = (
-                to_host(toks),
-                to_host(logps),
-                to_host(gen_len),
-            )
+            with tracer.span("gen_dispatch", cat="compute"):
+                toks, logps, gen_len = fn(
+                    self.params, prompt_tok, prompt_len, key
+                )
+            with tracer.span("gen_wait", cat="compute"):
+                toks, logps, gen_len = (
+                    to_host(toks),
+                    to_host(logps),
+                    to_host(gen_len),
+                )
         for r, (i, rep, _) in enumerate(chunk):
             gl = int(gen_len[r])
             no_eos = gl == gconfig.max_new_tokens and (
@@ -3097,7 +3179,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
     def _assemble(self, sample, prompt_key, prompt_lens, results, n):
         toks = sum(len(t[0]) for t in results.values())
         self._m_tokens.inc(toks)
-        dt = time.monotonic() - getattr(self, "_gen_t0", time.monotonic())
+        dt = time.monotonic() - self._gen_t0
         if dt > 0:
             # Wall-clock goodput of the whole call, park time included —
             # the per-server throughput the fleet table reports.

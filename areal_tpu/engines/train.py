@@ -17,6 +17,7 @@ backend/mock_train.py — redesigned for TPU:
 """
 
 import functools
+import time
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -27,7 +28,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from areal_tpu.api.data_api import MicroBatchSpec, SequenceSample
 from areal_tpu.api.model_api import Engine, FinetuneSpec, OptimizerConfig
-from areal_tpu.base import faults, integrity, logging
+from areal_tpu.base import faults, integrity, logging, tracer
 from areal_tpu.base.distributed import is_primary, to_host
 from areal_tpu.engines import packing
 from areal_tpu.engines.offload import HostOffloadMixin
@@ -314,7 +315,8 @@ class TrainEngine(HostOffloadMixin, Engine):
                 total = loss + cfg.moe_aux_loss_coef * aux
                 return total * loss_scale, stats
 
-            return jax.value_and_grad(losswrap, has_aux=True)(params)
+            with jax.named_scope("train/grad"):
+                return jax.value_and_grad(losswrap, has_aux=True)(params)
 
         @jax.jit
         def grad_fn(params, batch, loss_scale):
@@ -332,6 +334,7 @@ class TrainEngine(HostOffloadMixin, Engine):
         self._grad_fns[loss_fn] = (grad_fn, grad_acc_fn)
         return self._grad_fns[loss_fn]
 
+    @jax.named_scope("train/apply")
     def _guarded_step(self, params, opt_state, grads, guard, loss_sum, ext_trip):
         """In-graph guarded optimizer step (traced inside the apply jits).
 
@@ -497,72 +500,80 @@ class TrainEngine(HostOffloadMixin, Engine):
         micro-batches uses `loss_weight_fn(batch) -> float` (e.g. number of
         loss tokens) so the final gradient equals the full-batch mean.
         """
+        t_entry = time.monotonic()
         self._ensure_loaded()
-        sharded_mbs = packing.split_sharded(sample, mb_spec)
-        packs = [
-            packing.pack_sample(
-                mb,
-                token_key,
-                extra_keys=extra_keys,
-                n_rows_multiple=self.batch_shard,
-                max_tokens_per_row=mb_spec.max_tokens_per_mb,
-                shard_blocks=blocks,
-            )
-            for mb, blocks in sharded_mbs
-        ]
-        # 1f1b-mem row chunking slices contiguous row ranges, which would
-        # cut across the per-shard row blocks of a sharded batch; the two
-        # compose only via the grad-accum loop, so skip chunking there.
-        sharded = any(blocks for _, blocks in sharded_mbs)
-        chunks = [
-            c
-            for pk in packs
-            for c in (
-                [pk.arrays] if sharded else self._pack_row_chunks(pk.arrays)
-            )
-        ]
-        total_weight = float(sum(loss_weight_fn(c) for c in chunks))
-        total_weight = max(total_weight, 1.0)
+        with tracer.span("pack", cat="host"):
+            sharded_mbs = packing.split_sharded(sample, mb_spec)
+            packs = [
+                packing.pack_sample(
+                    mb,
+                    token_key,
+                    extra_keys=extra_keys,
+                    n_rows_multiple=self.batch_shard,
+                    max_tokens_per_row=mb_spec.max_tokens_per_mb,
+                    shard_blocks=blocks,
+                )
+                for mb, blocks in sharded_mbs
+            ]
+            # 1f1b-mem row chunking slices contiguous row ranges, which would
+            # cut across the per-shard row blocks of a sharded batch; the two
+            # compose only via the grad-accum loop, so skip chunking there.
+            sharded = any(blocks for _, blocks in sharded_mbs)
+            chunks = [
+                c
+                for pk in packs
+                for c in (
+                    [pk.arrays] if sharded else self._pack_row_chunks(pk.arrays)
+                )
+            ]
+            total_weight = float(sum(loss_weight_fn(c) for c in chunks))
+            total_weight = max(total_weight, 1.0)
 
-        # Pack efficiency diagnostics: the MFU counter charges REAL
-        # tokens, the MXU computes PADDED grids — the ratio is the
-        # first thing to check when train MFU disappoints.
-        real_tokens = sum(
-            int((c["segment_ids"] > 0).sum()) for c in chunks
-        )
-        grid_tokens = sum(
-            int(np.prod(c["segment_ids"].shape)) for c in chunks
-        )
-        self.last_pack_stats = {
-            "real_tokens": real_tokens,
-            "grid_tokens": grid_tokens,
-            "pack_efficiency": real_tokens / max(grid_tokens, 1),
-            "n_micro_batches": len(chunks),
-        }
+            # Pack efficiency diagnostics: the MFU counter charges REAL
+            # tokens, the MXU computes PADDED grids — the ratio is the
+            # first thing to check when train MFU disappoints.
+            real_tokens = sum(
+                int((c["segment_ids"] > 0).sum()) for c in chunks
+            )
+            grid_tokens = sum(
+                int(np.prod(c["segment_ids"].shape)) for c in chunks
+            )
+            self.last_pack_stats = {
+                "real_tokens": real_tokens,
+                "grid_tokens": grid_tokens,
+                "pack_efficiency": real_tokens / max(grid_tokens, 1),
+                "n_micro_batches": len(chunks),
+            }
 
         grad_fn, grad_acc_fn = self._get_grad_fn(loss_fn)
         acc = None
         losses = []
         all_stats = []
         for arrays in chunks:
-            batch = self._device_batch(arrays)
-            scale = jnp.float32(1.0 / total_weight)
-            if acc is None:
-                acc, loss, stats = grad_fn(self.params, batch, scale)
-            else:
-                acc, loss, stats = grad_acc_fn(
-                    self.params, batch, scale, acc
-                )
+            with tracer.span("mb_upload", cat="comms"):
+                batch = self._device_batch(arrays)
+            with tracer.span("grad_dispatch", cat="compute"):
+                scale = jnp.float32(1.0 / total_weight)
+                if acc is None:
+                    acc, loss, stats = grad_fn(self.params, batch, scale)
+                else:
+                    acc, loss, stats = grad_acc_fn(
+                        self.params, batch, scale, acc
+                    )
             losses.append(loss)
             all_stats.append(stats)
 
-        acc = self._poison_grads(acc)
-        loss_sum = jnp.sum(jnp.stack(losses))
-        params, opt_state, self._guard_state, packed = self._get_apply_fn()(
-            self.params, self.opt_state, acc, self._guard(), loss_sum
-        )
-        self.params, self.opt_state = params, opt_state
-        del acc  # a full grad tree: free it before the stats sync
+        with tracer.span("apply_dispatch", cat="compute"):
+            acc = self._poison_grads(acc)
+            loss_sum = jnp.sum(jnp.stack(losses))
+            params, opt_state, self._guard_state, packed = (
+                self._get_apply_fn()(
+                    self.params, self.opt_state, acc, self._guard(),
+                    loss_sum,
+                )
+            )
+            self.params, self.opt_state = params, opt_state
+            del acc  # a full grad tree: free it before the stats sync
 
         # Stats from loss_fn are summed across micro-batches then divided by
         # total weight where keys end in '_sum'; plain keys are averaged.
@@ -571,17 +582,25 @@ class TrainEngine(HostOffloadMixin, Engine):
         keys = list(all_stats[0].keys()) if all_stats else []
         vec = [packed]
         if keys:
-            vec.append(
-                jnp.stack(
-                    [
-                        jnp.sum(jnp.stack([s[k] for s in all_stats]))
-                        if k.endswith("_sum")
-                        else jnp.mean(jnp.stack([s[k] for s in all_stats]))
-                        for k in keys
-                    ]
+            with tracer.span("stats_reduce", cat="compute"):
+                vec.append(
+                    jnp.stack(
+                        [
+                            jnp.sum(jnp.stack([s[k] for s in all_stats]))
+                            if k.endswith("_sum")
+                            else jnp.mean(
+                                jnp.stack([s[k] for s in all_stats])
+                            )
+                            for k in keys
+                        ]
+                    )
                 )
-            )
-        host = np.asarray(jnp.concatenate(vec), np.float64)
+        # Seconds the host itself spent on this step: everything before
+        # it sits down to wait for the device's answer.
+        self.last_pack_stats["host_s"] = time.monotonic() - t_entry
+        tracer.counter("train_host", host_s=self.last_pack_stats["host_s"])
+        with tracer.span("stats_sync", cat="compute"):
+            host = np.asarray(jnp.concatenate(vec), np.float64)
         self.host_transfers += 1
 
         verdict = float(host[3])
@@ -632,6 +651,7 @@ class TrainEngine(HostOffloadMixin, Engine):
             "n_chunks": 0,
             "real_tokens": 0,
             "grid_tokens": 0,
+            "host_s": 0.0,
         }
 
     def train_stream_chunk(
@@ -651,40 +671,47 @@ class TrainEngine(HostOffloadMixin, Engine):
         suffix) plus `chunk_weight` / `chunk_loss_sum` so callers can
         build `*_denominator`-weighted per-chunk stats.
         """
-        sharded_mbs = packing.split_sharded(sample, mb_spec)
-        if any(blocks for _, blocks in sharded_mbs):
-            raise ValueError(
-                "streamed accumulation does not compose with shard-exact "
-                "data placement (shard_of metadata); broadcast chunk inputs "
-                "or use the barrier train_batch path"
-            )
-        packs = [
-            packing.pack_sample(
-                mb,
-                token_key,
-                extra_keys=extra_keys,
-                n_rows_multiple=self.batch_shard,
-                max_tokens_per_row=mb_spec.max_tokens_per_mb,
-            )
-            for mb, _ in sharded_mbs
-        ]
-        chunks = [
-            c for pk in packs for c in self._pack_row_chunks(pk.arrays)
-        ]
-        chunk_weight = float(sum(loss_weight_fn(c) for c in chunks))
+        t_entry = time.monotonic()
+        with tracer.span("pack", cat="host"):
+            sharded_mbs = packing.split_sharded(sample, mb_spec)
+            if any(blocks for _, blocks in sharded_mbs):
+                raise ValueError(
+                    "streamed accumulation does not compose with "
+                    "shard-exact data placement (shard_of metadata); "
+                    "broadcast chunk inputs or use the barrier train_batch "
+                    "path"
+                )
+            packs = [
+                packing.pack_sample(
+                    mb,
+                    token_key,
+                    extra_keys=extra_keys,
+                    n_rows_multiple=self.batch_shard,
+                    max_tokens_per_row=mb_spec.max_tokens_per_mb,
+                )
+                for mb, _ in sharded_mbs
+            ]
+            chunks = [
+                c for pk in packs for c in self._pack_row_chunks(pk.arrays)
+            ]
+            chunk_weight = float(sum(loss_weight_fn(c) for c in chunks))
 
         grad_fn, grad_acc_fn = self._get_grad_fn(loss_fn)
         scale = jnp.float32(1.0)  # traced arg: no retrace vs train_batch
         losses = []
         all_stats = []
         for arrays in chunks:
-            batch = self._device_batch(arrays)
-            if state["acc"] is None:
-                state["acc"], loss, stats = grad_fn(self.params, batch, scale)
-            else:
-                state["acc"], loss, stats = grad_acc_fn(
-                    self.params, batch, scale, state["acc"]
-                )
+            with tracer.span("mb_upload", cat="comms"):
+                batch = self._device_batch(arrays)
+            with tracer.span("grad_dispatch", cat="compute"):
+                if state["acc"] is None:
+                    state["acc"], loss, stats = grad_fn(
+                        self.params, batch, scale
+                    )
+                else:
+                    state["acc"], loss, stats = grad_acc_fn(
+                        self.params, batch, scale, state["acc"]
+                    )
             losses.append(loss)
             all_stats.append(stats)
             state["real_tokens"] += int((arrays["segment_ids"] > 0).sum())
@@ -701,7 +728,9 @@ class TrainEngine(HostOffloadMixin, Engine):
             vec = [jnp.sum(jnp.stack(losses))] + [
                 jnp.sum(jnp.stack([s[k] for s in all_stats])) for k in keys
             ]
-            host = np.asarray(jnp.stack(vec), np.float64)
+            state["host_s"] += time.monotonic() - t_entry
+            with tracer.span("stats_sync", cat="compute"):
+                host = np.asarray(jnp.stack(vec), np.float64)
             self.host_transfers += 1
             chunk_loss = float(host[0])
             chunk_stats = {k: float(host[1 + i]) for i, k in enumerate(keys)}
@@ -731,22 +760,24 @@ class TrainEngine(HostOffloadMixin, Engine):
         """
         if state["acc"] is None:
             raise ValueError("train_stream_end before any train_stream_chunk")
+        t_entry = time.monotonic()
         total_weight = max(state["weight"], 1.0)
-        acc = self._poison_grads(state["acc"])
-        loss_sum = jnp.float32(sum(state["loss_sums"]))
-        params, opt_state, self._guard_state, packed = (
-            self._get_scaled_apply_fn()(
-                self.params,
-                self.opt_state,
-                acc,
-                self._guard(),
-                loss_sum,
-                jnp.float32(1.0 / total_weight),
-                jnp.float32(1.0 if quarantine else 0.0),
+        with tracer.span("apply_dispatch", cat="compute"):
+            acc = self._poison_grads(state["acc"])
+            loss_sum = jnp.float32(sum(state["loss_sums"]))
+            params, opt_state, self._guard_state, packed = (
+                self._get_scaled_apply_fn()(
+                    self.params,
+                    self.opt_state,
+                    acc,
+                    self._guard(),
+                    loss_sum,
+                    jnp.float32(1.0 / total_weight),
+                    jnp.float32(1.0 if quarantine else 0.0),
+                )
             )
-        )
-        self.params, self.opt_state = params, opt_state
-        state["acc"] = None  # consumed: free the grad tree
+            self.params, self.opt_state = params, opt_state
+            state["acc"] = None  # consumed: free the grad tree
 
         self.last_pack_stats = {
             "real_tokens": state["real_tokens"],
@@ -754,8 +785,10 @@ class TrainEngine(HostOffloadMixin, Engine):
             "pack_efficiency": state["real_tokens"]
             / max(state["grid_tokens"], 1),
             "n_micro_batches": state["n_micro_batches"],
+            "host_s": state["host_s"] + time.monotonic() - t_entry,
         }
-        host = np.asarray(packed, np.float64)
+        with tracer.span("stats_sync", cat="compute"):
+            host = np.asarray(packed, np.float64)
         self.host_transfers += 1
         verdict = float(host[3])
         if verdict:

@@ -150,6 +150,7 @@ def _norm(
     return out.astype(dtype)
 
 
+@jax.named_scope("embed")
 def _embed(
     params: Params, cfg: ModelConfig, tokens: jax.Array, positions: jax.Array
 ) -> jax.Array:
@@ -181,6 +182,28 @@ def positions_from_segments(segment_ids: jax.Array) -> jax.Array:
     return idx - seg_start
 
 
+# Device-side names (PERF.md §3): one `jax.named_scope` per part of the
+# model, trace-time metadata only.  Under the layer scan one scope serves
+# all layers, so a profile's `op_name` paths read `.../layer/mlp/...`
+# whatever the depth; forward, recomputed forward and backward of a scope
+# are told apart by what JAX itself puts in the path (`jvp(...)`,
+# `checkpoint` / `rematted_computation`, `transpose(jvp(...))`).
+
+
+@jax.named_scope("layer/attn_out")
+def _attn_out(a: jax.Array, blk: Params, cfg: ModelConfig) -> jax.Array:
+    y = a @ blk["wo"]
+    if cfg.proj_bias:
+        y = y + blk["bo"]
+    return y
+
+
+@jax.named_scope("final_norm")
+def _final_norm(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
+    return _norm(x, params["final_ln"], params.get("final_ln_b"), cfg)
+
+
+@jax.named_scope("layer/mlp")
 def _mlp_dense(h: jax.Array, blk: Params, cfg: ModelConfig) -> jax.Array:
     if cfg.mlp_gated:
         gate = _act(h @ blk["wg"], cfg)
@@ -311,6 +334,7 @@ def _mlp_moe_grouped(h: jax.Array, blk: Params, cfg: ModelConfig) -> Tuple[jax.A
     return out.reshape(b, s, d), aux
 
 
+@jax.named_scope("layer/mlp")
 def _mlp_moe(h: jax.Array, blk: Params, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
     if cfg.moe_dispatch == "dense":
         return _mlp_moe_dense(h, blk, cfg)
@@ -333,52 +357,45 @@ def _block_forward(
 ) -> Tuple[jax.Array, jax.Array]:
     b, s, d = x.shape
     h = _norm(x, blk["ln1"], blk.get("ln1_b"), cfg)
-    q = h @ blk["wq"]
-    k = h @ blk["wk"]
-    v = h @ blk["wv"]
-    if cfg.qkv_bias:
-        q, k, v = q + blk["bq"], k + blk["bk"], v + blk["bv"]
-    q = q.reshape(b, s, cfg.n_q_heads, cfg.head_dim)
-    k = k.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-    v = v.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-    if cfg.pos_emb == "rope":
-        q, k = apply_rotary(q, k, cos, sin)
-    if cp_manual is not None:
-        # Already inside a manual region that includes the seq axis (the
-        # CP+PP pipeline): run the ring body DIRECTLY on this shard's
-        # chunk — nesting another shard_map over auto axes is not
-        # expressible once operands vary over the outer manual axis.
-        from areal_tpu.ops.ring_attention import _ring_shard
-
-        axis_name, axis_size, *my_idx = cp_manual
-        attn = _ring_shard(
-            q, k, v, segment_ids, axis_name, axis_size, causal=True,
-            my_index=my_idx[0] if my_idx else None,
-        )
-    elif cp_mesh is not None:
-        if cp_zigzag:
-            # Inputs already zigzag-permuted by _backbone (ONCE per
-            # forward, not per layer).
-            from areal_tpu.ops.ring_attention import (
-                zigzag_ring_packed_attention_prepermuted,
-            )
-
-            attn = zigzag_ring_packed_attention_prepermuted(
-                q, k, v, segment_ids, cp_mesh, causal=True
-            )
-        else:
-            from areal_tpu.ops.ring_attention import ring_packed_attention
-
-            attn = ring_packed_attention(
-                q, k, v, segment_ids, cp_mesh, causal=True
-            )
-    else:
+    q, k, v = _block_kv(h, blk, cfg, cos, sin)
+    if cp_manual is None and cp_mesh is None:
         attn = packed_attention(
             q, k, v, segment_ids, causal=True, use_flash=use_flash
         )
-    attn_out = attn.reshape(b, s, cfg.q_dim) @ blk["wo"]
-    if cfg.proj_bias:
-        attn_out = attn_out + blk["bo"]
+    else:
+        with jax.named_scope("layer/attn"):
+            if cp_manual is not None:
+                # Already inside a manual region that includes the seq
+                # axis (the CP+PP pipeline): run the ring body DIRECTLY on
+                # this shard's chunk — nesting another shard_map over auto
+                # axes is not expressible once operands vary over the
+                # outer manual axis.
+                from areal_tpu.ops.ring_attention import _ring_shard
+
+                axis_name, axis_size, *my_idx = cp_manual
+                attn = _ring_shard(
+                    q, k, v, segment_ids, axis_name, axis_size, causal=True,
+                    my_index=my_idx[0] if my_idx else None,
+                )
+            elif cp_zigzag:
+                # Inputs already zigzag-permuted by _backbone (ONCE per
+                # forward, not per layer).
+                from areal_tpu.ops.ring_attention import (
+                    zigzag_ring_packed_attention_prepermuted,
+                )
+
+                attn = zigzag_ring_packed_attention_prepermuted(
+                    q, k, v, segment_ids, cp_mesh, causal=True
+                )
+            else:
+                from areal_tpu.ops.ring_attention import (
+                    ring_packed_attention,
+                )
+
+                attn = ring_packed_attention(
+                    q, k, v, segment_ids, cp_mesh, causal=True
+                )
+    attn_out = _attn_out(attn.reshape(b, s, cfg.q_dim), blk, cfg)
     # Named checkpoints for remat="dots_small" (see _backbone): the
     # attention output and the MLP down-projection output are the SMALL
     # per-token dots ([*, D]) whose saving lets backward skip only the
@@ -450,7 +467,7 @@ def _backbone(
             pp_mesh, pp_microbatches, use_flash,
             cp=cp_mesh is not None,
         )
-        x = _norm(x, params["final_ln"], params.get("final_ln_b"), cfg)
+        x = _final_norm(params, cfg, x)
         return x, aux
 
     # Zigzag ring layout: permute the token order ONCE for the whole
@@ -521,12 +538,13 @@ def _backbone(
     elif remat not in (False, None, "none"):
         raise ValueError(f"unknown remat policy {remat!r}")
     x, auxes = jax.lax.scan(body, x, params["blocks"])
-    x = _norm(x, params["final_ln"], params.get("final_ln_b"), cfg)
+    x = _final_norm(params, cfg, x)
     if zz_inv is not None:
         x = jnp.take(x, zz_inv, axis=1)
     return x, jnp.sum(auxes)
 
 
+@jax.named_scope("head_logprob")
 def _head(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     if cfg.is_critic:
         v = jnp.einsum(
@@ -739,6 +757,7 @@ def init_kv_cache(
     return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
 
 
+@jax.named_scope("layer/attn_qkv")
 def _block_kv(
     h: jax.Array, blk: Params, cfg: ModelConfig, cos: jax.Array, sin: jax.Array
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -756,6 +775,7 @@ def _block_kv(
     return q, k, v
 
 
+@jax.named_scope("gen/prefill")
 def prefill(
     params: Params,
     cfg: ModelConfig,
@@ -799,9 +819,7 @@ def prefill(
         attn = packed_attention(
             q, k_at, v_at, segment_ids, causal=True, use_flash=use_flash
         )
-        y = attn.reshape(*carry.shape[:2], cfg.q_dim) @ blk["wo"]
-        if cfg.proj_bias:
-            y = y + blk["bo"]
+        y = _attn_out(attn.reshape(*carry.shape[:2], cfg.q_dim), blk, cfg)
         y = carry + y
         h2 = _norm(y, blk["ln2"], blk.get("ln2_b"), cfg)
         y = y + (_mlp_moe(h2, blk, cfg)[0] if cfg.is_moe else _mlp_dense(h2, blk, cfg))
@@ -836,7 +854,7 @@ def prefill(
                 cache.v, vs.astype(cache.v.dtype), (0, 0, 0, 0, 0)
             ),
         )
-    x = _norm(x, params["final_ln"], params.get("final_ln_b"), cfg)
+    x = _final_norm(params, cfg, x)
     # Gather each row's last valid hidden state before the (huge) head matmul.
     # (index of the last nonzero segment: works for left- and right-aligned
     # prompt layouts alike)
@@ -846,6 +864,7 @@ def prefill(
     return _head(params, cfg, x_last)[:, 0], new_cache
 
 
+@jax.named_scope("gen/decode_step")
 def decode_step(
     params: Params,
     cfg: ModelConfig,
@@ -886,9 +905,7 @@ def decode_step(
         k_layer = jax.lax.dynamic_index_in_dim(kc, li, axis=0, keepdims=False)
         v_layer = jax.lax.dynamic_index_in_dim(vc, li, axis=0, keepdims=False)
         attn = decode_attention(q, k_layer, v_layer, valid_from, slot + 1)
-        ao = attn.reshape(b, 1, cfg.q_dim) @ blk["wo"]
-        if cfg.proj_bias:
-            ao = ao + blk["bo"]
+        ao = _attn_out(attn.reshape(b, 1, cfg.q_dim), blk, cfg)
         y = y + ao
         h2 = _norm(y, blk["ln2"], blk.get("ln2_b"), cfg)
         y = y + (_mlp_moe(h2, blk, cfg)[0] if cfg.is_moe else _mlp_dense(h2, blk, cfg))
@@ -897,11 +914,12 @@ def decode_step(
     (x, kc, vc, _), _ = jax.lax.scan(
         body, (x, cache.k, cache.v, jnp.int32(0)), params["blocks"]
     )
-    x = _norm(x, params["final_ln"], params.get("final_ln_b"), cfg)
+    x = _final_norm(params, cfg, x)
     logits = _head(params, cfg, x)[:, 0]  # [B, V]
     return logits, KVCache(k=kc, v=vc)
 
 
+@jax.named_scope("gen/decode_step")
 def decode_step_inflight(
     params: Params,
     cfg: ModelConfig,
@@ -954,9 +972,7 @@ def decode_step_inflight(
             q, k_layer, v_layer, zero_from, valid_to,
             k_scale=ks_l, v_scale=vs_l,
         )
-        ao = attn.reshape(b, 1, cfg.q_dim) @ blk["wo"]
-        if cfg.proj_bias:
-            ao = ao + blk["bo"]
+        ao = _attn_out(attn.reshape(b, 1, cfg.q_dim), blk, cfg)
         y = y + ao
         h2 = _norm(y, blk["ln2"], blk.get("ln2_b"), cfg)
         y = y + (_mlp_moe(h2, blk, cfg)[0] if cfg.is_moe else _mlp_dense(h2, blk, cfg))
@@ -978,7 +994,7 @@ def decode_step_inflight(
             (x, cache.k, cache.v, ksc0, vsc0, jnp.int32(0)),
             params["blocks"],
         )
-    x = _norm(x, params["final_ln"], params.get("final_ln_b"), cfg)
+    x = _final_norm(params, cfg, x)
     logits = _head(params, cfg, x)[:, 0]
     return logits, KVCache(
         k=kc, v=vc,
@@ -987,6 +1003,7 @@ def decode_step_inflight(
     )
 
 
+@jax.named_scope("gen/decode_step")
 def decode_step_spec(
     params: Params,
     cfg: ModelConfig,
@@ -1029,9 +1046,7 @@ def decode_step_spec(
             jnp.zeros((b,), jnp.int32), slots0 + 1,
             k_scale=ks_l, v_scale=vs_l,
         )
-        ao = attn.reshape(b, q_len, cfg.q_dim) @ blk["wo"]
-        if cfg.proj_bias:
-            ao = ao + blk["bo"]
+        ao = _attn_out(attn.reshape(b, q_len, cfg.q_dim), blk, cfg)
         y = y + ao
         h2 = _norm(y, blk["ln2"], blk.get("ln2_b"), cfg)
         y = y + (
@@ -1046,7 +1061,7 @@ def decode_step_spec(
         (x, cache.k, cache.v, ksc0, vsc0, jnp.int32(0)),
         params["blocks"],
     )
-    x = _norm(x, params["final_ln"], params.get("final_ln_b"), cfg)
+    x = _final_norm(params, cfg, x)
     logits = _head(params, cfg, x)  # [B, Q, V]
     return logits, KVCache(
         k=kc, v=vc,
@@ -1055,6 +1070,7 @@ def decode_step_spec(
     )
 
 
+@jax.named_scope("gen/prefill")
 def prefill_into_slots(
     params: Params,
     cfg: ModelConfig,
@@ -1203,6 +1219,7 @@ def _page_of(page_table: jax.Array, pos: jax.Array, page_size: int):
     return pages.astype(jnp.int32), (pos % page_size).astype(jnp.int32)
 
 
+@jax.named_scope("gen/decode_step")
 def decode_step_paged(
     params: Params,
     cfg: ModelConfig,
@@ -1239,9 +1256,7 @@ def decode_step_paged(
             q, k_pool_l, v_pool_l, page_table, valid_to,
             k_scale=ks_l, v_scale=vs_l,
         )
-        ao = attn.reshape(b, 1, cfg.q_dim) @ blk["wo"]
-        if cfg.proj_bias:
-            ao = ao + blk["bo"]
+        ao = _attn_out(attn.reshape(b, 1, cfg.q_dim), blk, cfg)
         y = y + ao
         h2 = _norm(y, blk["ln2"], blk.get("ln2_b"), cfg)
         y = y + (_mlp_moe(h2, blk, cfg)[0] if cfg.is_moe else _mlp_dense(h2, blk, cfg))
@@ -1254,7 +1269,7 @@ def decode_step_paged(
         (x, cache.k, cache.v, ksc0, vsc0, jnp.int32(0)),
         params["blocks"],
     )
-    x = _norm(x, params["final_ln"], params.get("final_ln_b"), cfg)
+    x = _final_norm(params, cfg, x)
     logits = _head(params, cfg, x)[:, 0]
     return logits, PagedKVCache(
         k=kc, v=vc,
@@ -1264,6 +1279,7 @@ def decode_step_paged(
     )
 
 
+@jax.named_scope("gen/decode_step")
 def decode_step_spec_paged(
     params: Params,
     cfg: ModelConfig,
@@ -1316,9 +1332,7 @@ def decode_step_spec_paged(
             q, k_pool_l, v_pool_l, page_table, write_pos0 + 1,
             k_scale=ks_l, v_scale=vs_l, q_lens=q_lens,
         )
-        ao = attn.reshape(b, q_len, cfg.q_dim) @ blk["wo"]
-        if cfg.proj_bias:
-            ao = ao + blk["bo"]
+        ao = _attn_out(attn.reshape(b, q_len, cfg.q_dim), blk, cfg)
         y = y + ao
         h2 = _norm(y, blk["ln2"], blk.get("ln2_b"), cfg)
         y = y + (
@@ -1333,7 +1347,7 @@ def decode_step_spec_paged(
         (x, cache.k, cache.v, ksc0, vsc0, jnp.int32(0)),
         params["blocks"],
     )
-    x = _norm(x, params["final_ln"], params.get("final_ln_b"), cfg)
+    x = _final_norm(params, cfg, x)
     logits = _head(params, cfg, x)  # [B, Q, V]
     return logits, PagedKVCache(
         k=kc, v=vc,
@@ -1343,6 +1357,7 @@ def decode_step_spec_paged(
     )
 
 
+@jax.named_scope("gen/decode_step")
 def decode_step_ragged_paged(
     params: Params,
     cfg: ModelConfig,
@@ -1396,9 +1411,7 @@ def decode_step_ragged_paged(
             q[:, 0], k_pool_l, v_pool_l, pt_tok, valid_to,
             k_scale=ks_l, v_scale=vs_l,
         )
-        ao = attn.reshape(t, 1, cfg.q_dim) @ blk["wo"]
-        if cfg.proj_bias:
-            ao = ao + blk["bo"]
+        ao = _attn_out(attn.reshape(t, 1, cfg.q_dim), blk, cfg)
         y = y + ao
         h2 = _norm(y, blk["ln2"], blk.get("ln2_b"), cfg)
         y = y + (
@@ -1413,7 +1426,7 @@ def decode_step_ragged_paged(
         (x, cache.k, cache.v, ksc0, vsc0, jnp.int32(0)),
         params["blocks"],
     )
-    x = _norm(x, params["final_ln"], params.get("final_ln_b"), cfg)
+    x = _final_norm(params, cfg, x)
     logits = _head(params, cfg, x)[:, 0]  # [T, V]
     return logits, PagedKVCache(
         k=kc, v=vc,
@@ -1423,6 +1436,7 @@ def decode_step_ragged_paged(
     )
 
 
+@jax.named_scope("gen/prefill")
 def prefill_into_pages(
     params: Params,
     cfg: ModelConfig,

@@ -109,6 +109,7 @@ def _decode_kernel_enabled() -> bool:
     return _DECODE_KERNEL_SNAPSHOT
 
 
+@jax.named_scope("layer/attn")
 def decode_attention(
     q: jax.Array,  # [B, 1, n_q, d] — one new token per row
     k_cache: jax.Array,  # [B, S_max, n_kv, d]
@@ -171,6 +172,7 @@ def decode_attention(
     return out.reshape(b, 1, n_q, d).astype(q.dtype)
 
 
+@jax.named_scope("layer/attn")
 def decode_attention_chunk(
     q: jax.Array,  # [B, Q, n_q, d] — Q consecutive new tokens per row
     k_cache: jax.Array,  # [B, S_max, n_kv, d]
@@ -278,6 +280,7 @@ def paged_gather_layer(
     return g.reshape(b, mp * ps, *pool_layer.shape[2:])
 
 
+@jax.named_scope("layer/attn")
 def paged_decode_attention(
     q: jax.Array,  # [B, 1, n_q, d]
     k_pool: jax.Array,  # [P, ps, n_kv, d] — one layer's pool view
@@ -309,6 +312,7 @@ def paged_decode_attention(
     )
 
 
+@jax.named_scope("layer/attn")
 def paged_decode_attention_chunk(
     q: jax.Array,  # [B, Q, n_q, d]
     k_pool: jax.Array,  # [P, ps, n_kv, d]
@@ -344,6 +348,7 @@ def paged_decode_attention_chunk(
     )
 
 
+@jax.named_scope("layer/attn")
 def ragged_paged_attention(
     q: jax.Array,  # [T, n_q, d] — packed token stream (no batch/Q dims)
     k_pool: jax.Array,  # [P, ps, n_kv, d] — one layer's pool view
@@ -404,6 +409,7 @@ def _dispatch_ref(q, k, v, segment_ids, causal):
     return packed_attention_reference(q, k, v, segment_ids, causal=causal)
 
 
+@jax.named_scope("layer/attn")
 def packed_attention(
     q: jax.Array,
     k: jax.Array,

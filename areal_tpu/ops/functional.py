@@ -33,6 +33,7 @@ def next_token_logprobs(
     return jnp.where(shifted_label_mask(segment_ids), gathered, 0.0)
 
 
+@jax.named_scope("head_logprob")
 def fused_next_token_logprobs(
     x: jax.Array,  # [B, S, D] final hidden states (compute dtype)
     head: jax.Array,  # [D, V] LM head (embed.T when tied)

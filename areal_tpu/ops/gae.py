@@ -20,6 +20,7 @@ import numpy as np
 
 
 @functools.partial(jax.jit, static_argnames=())
+@jax.named_scope("ppo/gae")
 def gae_packed(
     rewards: jax.Array,  # [T] fp32 per-token rewards (terminal included)
     values: jax.Array,  # [T] fp32 V(s_t), 0 on padding

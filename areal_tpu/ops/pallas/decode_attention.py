@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from areal_tpu.ops.pallas.flash_attention import named_call
+
 NEG_INF = -1e30
 
 DEFAULT_BLOCK_K = 512
@@ -147,7 +149,8 @@ def decode_attention_chunk_kernel(
         nq_tok=nq_tok,
     )
     qr = nq_tok * rep
-    out = pl.pallas_call(
+    out = named_call(
+        "decode_chunk",
         kern,
         grid=(b, n_kv, nk),
         in_specs=[

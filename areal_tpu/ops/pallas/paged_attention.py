@@ -34,6 +34,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from areal_tpu.ops.pallas.flash_attention import named_call
+
 NEG_INF = -1e30
 
 
@@ -197,7 +199,8 @@ def paged_decode_attention_chunk_kernel(
             _vmem((qr, d), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
+    out = named_call(
+        "paged_chunk",
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n_kv, qr, d), jnp.float32),
@@ -350,7 +353,8 @@ def ragged_paged_attention_kernel(
             _vmem((n_kv, rep, d), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
+    out = named_call(
+        "ragged_stream",
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, n_kv, rep, d), jnp.float32),

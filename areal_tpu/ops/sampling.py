@@ -39,6 +39,7 @@ def apply_top_p(logits: jax.Array, p: float) -> jax.Array:
     return jnp.where(logits < thresh, NEG_INF, logits)
 
 
+@jax.named_scope("head_logprob")
 def sample_token(
     logits: jax.Array,  # [B, V] fp32
     key: jax.Array,

@@ -49,14 +49,16 @@ def reshard(
                 tree,
             )
         out = jax.device_put(tree, dst_shardings, donate=donate)
-        if tracer.enabled():
-            # device_put is async; block so the span measures the actual
-            # transfer rather than dispatch.  Only paid when tracing.
-            out = jax.block_until_ready(out)
-            targs["bytes"] = int(
-                sum(x.nbytes for x in jax.tree.leaves(out))
-            )
+        # The span times the dispatch and whatever of the transfer the
+        # host itself carries; it never waits for the result, traced or
+        # not (tracing must not change the schedule it observes).
+        targs["bytes"] = tree_bytes(out)
     return out
+
+
+def tree_bytes(tree: Any) -> int:
+    """Global bytes of a pytree of arrays, from shapes and dtypes alone."""
+    return int(sum(x.nbytes for x in jax.tree.leaves(tree)))
 
 
 def reshard_params(

@@ -88,6 +88,14 @@ class WorkerPool:
 
     # Per-request deadline default; None = wait forever (seed behavior).
     mfc_timeout_s: Optional[float] = None
+    # The master's current step: stamped into every request so the
+    # worker's spans name the `step` span that dispatched them.
+    step: Optional[int] = None
+
+    def stamp(self, payload: Dict[str, Any]) -> Dict[str, Any]:
+        if self.step is None or "step" in payload:
+            return payload
+        return {**payload, "step": self.step}
 
     async def request(
         self,
@@ -132,7 +140,7 @@ class InProcessPool(WorkerPool):
                 worker_id, "worker previously declared dead"
             )
         coro = asyncio.to_thread(
-            self.workers[worker_id].handle_request, payload
+            self.workers[worker_id].handle_request, self.stamp(payload)
         )
         if timeout is None:
             return await coro
@@ -492,6 +500,9 @@ class MasterWorker:
         # surfaced as transfer/* step stats — the reference's data_manager
         # redistribution timing made visible (blog/AReaL_v0_2.md:52-54).
         self._xfer_acc: Dict[str, float] = {}
+        # Per-step `<role>/sync/*` of colocated param syncs, as the worker
+        # reports them (bytes placed, seconds in device_put and handler).
+        self._sync_acc: Dict[str, float] = {}
 
     # ---------------- lifecycle ----------------
 
@@ -528,9 +539,10 @@ class MasterWorker:
                 # The "step" span marks the attribution window every other
                 # track is bucketed against (apps/trace_report.py).
                 try:
-                    with tracer.span(
-                        "step", step=self.step_info.global_step + 1
-                    ):
+                    # Every request of this step carries its number to
+                    # the worker's spans (WorkerPool.stamp).
+                    self.pool.step = self.step_info.global_step + 1
+                    with tracer.step_span(self.pool.step):
                         stats = await self.execute_step()
                 except WorkerDeadError as e:
                     await self._recover_from_worker_death(e)
@@ -817,6 +829,7 @@ class MasterWorker:
         # live dict (wall-clock attribution — a transfer counts toward the
         # step during which it actually moved bytes).
         self._xfer_acc.clear()
+        self._sync_acc.clear()
         if self._async_rl and self._source_nodes:
             await self._execute_step_async_rl(results)
         elif self.rollout_ahead > 0 and self._source_nodes:
@@ -843,6 +856,7 @@ class MasterWorker:
                 merged[f"{name}/{k}" if len(results) > 1 else k] = v
         for k, v in self._xfer_acc.items():
             merged[f"transfer/{k}"] = v
+        merged.update(self._sync_acc)
         for k, v in self.buffer.stats().items():
             merged[f"buffer/{k}"] = float(v)
         return merged
@@ -1677,7 +1691,7 @@ class MasterWorker:
                 with tracer.span(
                     f"param_sync:{hook.target}", cat="comms"
                 ):
-                    await asyncio.gather(
+                    resps = await asyncio.gather(
                         *[
                             self.pool.request(
                                 w,
@@ -1691,6 +1705,12 @@ class MasterWorker:
                             for w in group
                         ]
                     )
+                # The primary's own account of the sync: bytes placed,
+                # seconds in the device_put, seconds in the handler.
+                role = str(hook.target).split("@")[0]
+                for k, v in (resps[0].get("sync") or {}).items():
+                    key = f"{role}/sync/{k}"
+                    self._sync_acc[key] = self._sync_acc.get(key, 0.0) + v
             else:
                 # Cross-set realloc over the transfer plane (reference:
                 # param_realloc NCCL groups, model_worker.py:1009).  EVERY

@@ -248,7 +248,9 @@ class ZMQWorkerPool(WorkerPool):
         loop = asyncio.get_running_loop()
         fut = loop.create_future()
         self._pending[req_id] = (fut, worker_id)
-        msg = pickle.dumps({"req_id": req_id, "request": payload})
+        msg = pickle.dumps(
+            {"req_id": req_id, "request": self.stamp(payload)}
+        )
         await self._sock.send_multipart([self._hello[worker_id], msg])
         if timeout is _UNSET:
             timeout = self.mfc_timeout_s

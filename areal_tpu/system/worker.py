@@ -54,6 +54,68 @@ import areal_tpu.interfaces.null  # noqa: F401
 # One xprof trace at a time per process (see _handle_mfc).
 _TRACE_LOCK = threading.Lock()
 
+# Compilation seen by jax.monitoring, per thread: the listener runs on the
+# thread that compiles, which is the thread of the MFC that needed the
+# program.  One listener per process, installed by the first worker; it
+# fires only when something compiles or loads from the persistent cache.
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_compile_tls = threading.local()
+_compile_listener_installed = False
+
+
+def _compile_counts() -> List[float]:
+    """This thread's [programs, compile-or-load seconds, load seconds]."""
+    counts = getattr(_compile_tls, "counts", None)
+    if counts is None:
+        counts = _compile_tls.counts = [0.0, 0.0, 0.0]
+    return counts
+
+
+def _on_compile_event(event: str, duration: float, **kw) -> None:
+    counts = _compile_counts()
+    if event == _COMPILE_EVENT:
+        # Wraps the whole compile-or-load; the cache's own event (below,
+        # fired first) says how much of it was a load.
+        counts[0] += 1
+        counts[1] += duration
+    elif event == _CACHE_LOAD_EVENT:
+        counts[2] += duration
+    else:
+        return
+    now = time.monotonic_ns()
+    tracer.complete(
+        "compile", now - int(duration * 1e9), now, cat="host",
+        event=event.rsplit("/", 1)[-1], fun=str(kw.get("fun_name", "")),
+    )
+
+
+def _step(req: Dict[str, Any]) -> Dict[str, int]:
+    """Span argument naming the master's `step` span that sent `req`."""
+    return {"step": req["step"]} if "step" in req else {}
+
+
+def _install_compile_listener() -> None:
+    global _compile_listener_installed
+    if _compile_listener_installed:
+        return
+    import jax.monitoring
+
+    jax.monitoring.register_event_duration_secs_listener(_on_compile_event)
+    _compile_listener_installed = True
+
+
+def _take_compiles() -> Dict[str, float]:
+    """This thread's compile counters since the last call, as the `perf/*`
+    keys an MFC returns: programs compiled or loaded, the seconds that
+    took, and the part of them spent reading the persistent cache."""
+    counts = _compile_counts()
+    out = dict(zip(
+        ("perf/compiles", "perf/compile_s", "perf/cache_load_s"), counts
+    ))
+    counts[:] = [0.0, 0.0, 0.0]
+    return out
+
 
 def _zero_filled(meta_row: SequenceSample, keys) -> SequenceSample:
     """Zero-data placeholder for keys this member did not receive under
@@ -164,7 +226,9 @@ def _build_params_and_config(spec: ModelAbstraction, seed: int, mesh):
         return spec.args["config"], None
     if spec.type_ == "random":
         cfg: ModelConfig = spec.args["config"]
-        return cfg, _random_init_fn(cfg, mesh)(seed)
+        # As uint32: a Python int past 2**31 overflows jit's int32 argument,
+        # and the key is the same [0, seed] either way.
+        return cfg, _random_init_fn(cfg, mesh)(np.uint32(seed % 2**32))
     elif spec.type_ == "hf":
         from areal_tpu.models.hf import registry as hf
 
@@ -240,6 +304,7 @@ class ModelWorker:
     def _setup(self):
         import jax
 
+        _install_compile_listener()
         from areal_tpu.engines.generator import GeneratorEngine
         from areal_tpu.engines.inference import InferenceEngine
         from areal_tpu.engines.train import TrainEngine
@@ -365,6 +430,10 @@ class ModelWorker:
         Batches can come up short after difficulty filtering shrinks the
         dataset mid-epoch — top up from the stream so the master's buffer
         (which waits for exactly n_seqs) never stalls."""
+        with tracer.span("fetch", cat="host", **_step(req)):
+            return self._fetch(req)
+
+    def _fetch(self, req):
         dl_idx = req.get("dataset_index", 0)
         dl = self.dataloaders[dl_idx]
         singles: List[SequenceSample] = []
@@ -468,7 +537,11 @@ class ModelWorker:
         model = self.models[model_key]
         interface = self.interfaces[model_key]
         fn = getattr(interface, itype.value)
-        with tracer.span(f"mfc:{model_key}:{itype.value}", cat="compute") as targs:
+        _take_compiles()  # executor threads are reused: start from zero
+        with tracer.span(
+            f"mfc:{model_key}:{itype.value}", cat="compute",
+            **_step(req),
+        ) as targs:
             with self.timers.record(f"mfc_{itype.value}"):
                 t0 = time.monotonic()
                 # Env-gated xprof capture per MFC (reference: REAL_DUMP_TRACE
@@ -501,6 +574,7 @@ class ModelWorker:
             if out_sample is not None:
                 out_sample.remap_keys_(remap_out)
             perf = self._mfc_perf(model, itype, sample, out_sample, mfc_seconds)
+            perf.update(_take_compiles())
             perf.update(self.timers.drain())
             mfc_label = f"{model_key}:{itype.value}"
             self._m_mfc_seconds.labels(mfc_label).observe(mfc_seconds)
@@ -567,6 +641,7 @@ class ModelWorker:
             "seqs": 0,
             "sum_sq": 0.0,
             "n_chunks": 0,
+            "compiles": {},
         }
         return {"meta": None, "stats": {}}
 
@@ -583,12 +658,12 @@ class ModelWorker:
             req.get("shard_meta"),
             req.get("input_key_remap", {}),
         )
-        # Seed the span with one arg: the tracer only attaches its args
-        # dict to the event when non-empty at span exit, and the fields
-        # below are stamped after the block (same dict, flushed later).
+        _take_compiles()
+        # The fields below are stamped after the block: the span's event
+        # holds this same dict, flushed later.
         with tracer.span(
             f"mfc:{model_key}:train_chunk", cat="compute",
-            mfc=f"{model_key}:train_chunk",
+            **_step(req),
         ) as targs:
             with self.timers.record("mfc_train_chunk"):
                 t0 = time.monotonic()
@@ -596,6 +671,8 @@ class ModelWorker:
                     model, st["state"], sample, mb_spec
                 )
                 seconds = time.monotonic() - t0
+        for k, v in _take_compiles().items():
+            st["compiles"][k] = st["compiles"].get(k, 0.0) + v
         st["busy_s"] += seconds
         st["n_chunks"] += 1
         # Prefer the packed key (see _mfc_perf): a scalar key's seqlens
@@ -641,11 +718,10 @@ class ModelWorker:
         model = self.models[model_key]
         interface = self.interfaces[model_key]
         mb_spec: MicroBatchSpec = req.get("mb_spec") or MicroBatchSpec()
-        # Seeded like train_chunk above: args written after the block
-        # only reach the trace when the dict was non-empty at exit.
+        _take_compiles()
         with tracer.span(
             f"mfc:{model_key}:train_step", cat="compute",
-            mfc=f"{model_key}:train_step",
+            **_step(req),
         ) as targs:
             with self.timers.record("mfc_train_step"):
                 t0 = time.monotonic()
@@ -655,6 +731,8 @@ class ModelWorker:
                 seconds = time.monotonic() - t0
         busy = st["busy_s"] + seconds
         perf = {"perf/time_s": busy}
+        for k, v in _take_compiles().items():
+            perf[k] = st["compiles"].get(k, 0.0) + v
         try:
             cfg = model.config
             if cfg is not None and st["tokens"]:
@@ -983,14 +1061,30 @@ class ModelWorker:
         src = self.models[req["src"]].engine
         dst = self.models[req["dst"]].engine
         eta = float(req.get("eta", 1.0))
-        if eta >= 1.0:
-            dst.set_params(src.get_params())
-        else:
-            sp = src.get_params()
-            dp = dst.get_params()
-            mixed = jax.tree.map(lambda a, b: eta * a + (1 - eta) * b, sp, dp)
-            dst.set_params(mixed)
-        return {}
+        # The master's span of the same name times the RPC; this one is
+        # the work, and the engine's statement spans nest inside it.
+        with tracer.span(
+            f"param_sync:{req['dst']}", cat="comms", **_step(req)
+        ):
+            t0 = time.monotonic()
+            params = src.get_params()
+            if eta < 1.0:
+                params = jax.tree.map(
+                    lambda a, b: eta * a + (1 - eta) * b,
+                    params, dst.get_params(),
+                )
+            dst.set_params(params)
+            seconds = time.monotonic() - t0
+        # What the engine says it placed (global bytes, from shapes; the
+        # seconds inside its device_put); engines that keep no such record
+        # moved the tree they were handed.
+        stats = dict(getattr(dst, "last_sync_stats", None) or {})
+        if "bytes" not in stats:
+            from areal_tpu.parallel.realloc import tree_bytes
+
+            stats["bytes"] = float(tree_bytes(params))
+        stats["time_s"] = seconds
+        return {"sync": stats}
 
     def _handle_save(self, req):
         key = req["model_name"]
