@@ -164,9 +164,8 @@ def realloc_plan_bytes(
     dtype_bytes: int = 4,
 ) -> int:
     """Bytes a param_realloc plan moves: every parameter whose src and
-    dst specs differ reshards its full global size (jax.device_put
-    refetches the array; parallel/realloc.py's reshard span measures
-    exactly this)."""
+    dst specs differ reshards its full global size (parallel/realloc.py's
+    reshard span counts exactly this as `bytes_resharded` + `bytes_put`)."""
     src = match_partition_rules(src_rules, named_shapes)
     dst = match_partition_rules(dst_rules, named_shapes)
     moved = 0

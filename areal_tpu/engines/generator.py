@@ -13,8 +13,10 @@ TPU-native:
 - Chunking: requests are length-sorted and packed into fixed-size batches
   so at most a handful of shapes ever compile.
 - Weight hot-swap: `set_params` re-places the training params onto the
-  generator's mesh/dtype — the colocated-mesh equivalent of the reference's
-  save-to-disk + update_weights_from_disk dance (model_worker.py:1040-1067).
+  generator's mesh/dtype (`parallel/realloc.reshard`: in place, one
+  compiled on-device re-layout, or `device_put`, by where the bytes are)
+  — the colocated-mesh equivalent of the reference's save-to-disk +
+  update_weights_from_disk dance (model_worker.py:1040-1067).
 
 A continuous-batching (inflight) refill loop over this same decode step is
 the planned next step for the async RL path (reference:
@@ -49,7 +51,6 @@ from areal_tpu.models import transformer as tfm
 from areal_tpu.models.config import ModelConfig
 from areal_tpu.ops.sampling import sample_token
 from areal_tpu.parallel import sharding
-from areal_tpu.parallel.realloc import tree_bytes
 
 logger = logging.getLogger("generator")
 
@@ -426,9 +427,6 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         self.decode_compiles = 0
         self.cache_copy_bytes = 0
         self.last_pool_stats: Dict[str, Any] = {}
-        # What the last set_params() placed: global bytes and the host
-        # seconds of its cast / device_put / alias copy.
-        self.last_sync_stats: Dict[str, float] = {}
         # Serving-plane chunk counters of the current generate() call
         # (see _serving_counters); folded into last_pool_stats at its end.
         self._chunk_stats: Dict[str, Any] = _new_chunk_stats()
@@ -577,54 +575,11 @@ class GeneratorEngine(HostOffloadMixin, Engine):
     # ---------------- weights ----------------
 
     def set_params(self, params) -> None:
-        """Hot-swap weights (cast to compute dtype, shard onto our mesh).
-        `last_sync_stats` keeps what this call placed and how long each
-        statement held the host (the worker returns it to the master)."""
-        t0 = time.monotonic()
-        with tracer.span("params_cast", cat="comms"):
-            cast = jax.tree.map(
-                lambda x: x.astype(self.compute_dtype)
-                if jnp.issubdtype(x.dtype, jnp.floating)
-                else x,
-                params,
-            )
-        # New weights supersede any host-offloaded copy.
-        self._host_offload = None
-        self._offload_shardings = None
-        t1 = time.monotonic()
-        with tracer.span("params_put", cat="comms"):
-            placed = jax.device_put(
-                cast,
-                sharding.tree_named(self.mesh, sharding.param_pspecs(cast)),
-            )
-        t2 = time.monotonic()
-        # Donation safety: same-dtype/same-sharding astype+device_put can
-        # ALIAS the source engine's live buffers, which its optimizer step
-        # later DONATES — async rollout would then decode from deleted
-        # buffers.  Copy any leaf whose BUFFERS still alias the input
-        # (object identity alone misses distinct Arrays sharing storage).
-        # Synchronous trials opt out (donation_safe_swap=False): the alias
-        # is never read between donation and the post-step rebind, and the
-        # saved copy is a full parameter footprint of HBM.
-        if self.donation_safe_swap:
-            from areal_tpu.engines.offload import buffers_alias
-
-            with tracer.span("params_alias_copy", cat="comms"):
-                self.params = jax.tree.map(
-                    lambda p, orig: (
-                        jnp.copy(p) if buffers_alias(p, orig) else p
-                    ),
-                    placed, params,
-                )
-        else:
-            self.params = placed
-        self.last_sync_stats = {
-            "bytes": float(tree_bytes(placed)),
-            "cast_s": t1 - t0,
-            "put_s": t2 - t1,
-            "alias_copy_s": time.monotonic() - t2,
-        }
-        tracer.counter("param_sync", **self.last_sync_stats)
+        """Hot-swap weights (HostOffloadMixin._take_params).  Synchronous
+        trials opt out of the alias copy (donation_safe_swap=False): the
+        alias is never read between donation and the post-step rebind,
+        and the saved copy is a full parameter footprint of HBM."""
+        self._take_params(params, copy_aliases=self.donation_safe_swap)
 
     def get_params(self):
         self._ensure_loaded()

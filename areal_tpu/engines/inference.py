@@ -47,27 +47,8 @@ class InferenceEngine(HostOffloadMixin, Engine):
         self.set_params(params)
 
     def set_params(self, params) -> None:
-        cast = jax.tree.map(
-            lambda x: x.astype(self.compute_dtype)
-            if jnp.issubdtype(x.dtype, jnp.floating)
-            else x,
-            params,
-        )
-        # New weights supersede any host-offloaded copy (params-only).
-        self._host_offload = None
-        self._offload_shardings = None
-        placed = jax.device_put(
-            cast, sharding.tree_named(self.mesh, sharding.param_pspecs(cast))
-        )
-        # Donation safety (see GeneratorEngine.set_params): never alias the
-        # source engine's live, later-donated buffers — compared by buffer
-        # pointer, not object identity.
-        from areal_tpu.engines.offload import buffers_alias
-
-        self.params = jax.tree.map(
-            lambda p, orig: jnp.copy(p) if buffers_alias(p, orig) else p,
-            placed, params,
-        )
+        # Never alias the source engine's live, later-donated buffers.
+        self._take_params(params, copy_aliases=True)
 
     def get_params(self):
         self._ensure_loaded()

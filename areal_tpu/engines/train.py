@@ -34,7 +34,7 @@ from areal_tpu.engines import packing
 from areal_tpu.engines.offload import HostOffloadMixin
 from areal_tpu.models import transformer as tfm
 from areal_tpu.models.config import ModelConfig
-from areal_tpu.parallel import sharding
+from areal_tpu.parallel import realloc, sharding
 
 logger = logging.getLogger("train_engine")
 
@@ -959,8 +959,8 @@ class TrainEngine(HostOffloadMixin, Engine):
         # Restore any offloaded state first (the optimizer state must
         # survive; the reloaded params are immediately replaced).
         self._ensure_loaded()
-        self.params = jax.device_put(
-            _cast_tree(params, self.master_dtype), self.param_shardings
+        self.params = realloc.reshard(
+            params, self.param_shardings, self.master_dtype
         )
 
     def save_optimizer_state(self, path: str) -> None:

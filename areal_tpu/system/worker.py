@@ -1075,9 +1075,9 @@ class ModelWorker:
                 )
             dst.set_params(params)
             seconds = time.monotonic() - t0
-        # What the engine says it placed (global bytes, from shapes; the
-        # seconds inside its device_put); engines that keep no such record
-        # moved the tree they were handed.
+        # What the engine says it placed (global bytes from shapes, leaves
+        # and bytes by route, the seconds of the placement it waited for);
+        # engines that keep no such record moved the tree they were handed.
         stats = dict(getattr(dst, "last_sync_stats", None) or {})
         if "bytes" not in stats:
             from areal_tpu.parallel.realloc import tree_bytes

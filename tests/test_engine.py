@@ -436,3 +436,122 @@ def test_hotswap_never_aliases_donated_train_buffers():
         GenerationHyperparameters(n=1, max_new_tokens=4, greedy=True),
     )
     assert len(np.asarray(out.data["packed_input_ids"])) >= 7
+
+
+@pytest.fixture
+def compile_events():
+    """Names of the tracing / compile / cache-load events jax.monitoring
+    reports while the test runs."""
+    from jax import monitoring
+
+    seen = []
+
+    def on_event(event, duration, **kw):
+        seen.append(event)
+
+    monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        yield seen
+    finally:
+        monitoring.unregister_event_duration_listener(on_event)
+
+
+@pytest.mark.parametrize(
+    "train_layout,gen_layout", [("d1f2", "d1m2"), ("d1f4", "d1m4")]
+)
+def test_colocated_handback_relayouts_on_device(
+    train_layout, gen_layout, compile_events
+):
+    """Train under fsdp, generate under tensor parallelism on the SAME
+    devices: the hand-back is the held compiled re-layout, never
+    jax.device_put (which carries such a pair through the host)."""
+    from areal_tpu.engines.generator import GeneratorEngine
+    from areal_tpu.parallel import sharding
+
+    cfg = tiny_config()
+    pc = ParallelConfig.from_str(train_layout)
+    devices = jax.devices()[: pc.world_size]
+    train = TrainEngine(
+        cfg,
+        tfm.init_params(cfg, jax.random.PRNGKey(0)),
+        make_mesh(pc, devices),
+        optimizer_config=OptimizerConfig(lr=1e-3, warmup_steps_proportion=0.0),
+        ftspec=FinetuneSpec(1, 8, 8),
+    )
+    host = jax.tree.map(np.asarray, train.get_params())
+    n = len(jax.tree.leaves(host))
+    gen = GeneratorEngine(
+        cfg, host, make_mesh(ParallelConfig.from_str(gen_layout), devices),
+        eos_token_id=7,
+    )
+    # The engine's first weights came from the host: device_put.
+    assert gen.last_sync_stats["leaves_put"] == n
+
+    def hand_back():
+        gen.set_params(train.get_params())
+        stats = gen.last_sync_stats
+        assert stats["leaves_put"] == 0 and stats["bytes_put"] == 0
+        assert stats["leaves_resharded"] > 0
+        assert stats["leaves_aliased"] + stats["leaves_resharded"] == n
+        assert 0 < stats["bytes_resharded"] <= stats["bytes"]
+        want = sharding.tree_named(gen.mesh, sharding.param_pspecs(host))
+        for got, w, sh in zip(
+            jax.tree.leaves(gen.get_params()),
+            jax.tree.leaves(train.get_params()),
+            jax.tree.leaves(want),
+        ):
+            assert got.sharding == sh
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(w))
+
+    hand_back()
+    # New values under the same layouts, as after an optimizer step: the
+    # second hand-back traces, compiles and loads nothing.
+    train.params = jax.block_until_ready(
+        jax.tree.map(lambda x: x * 2, train.params)
+    )
+    del compile_events[:]
+    hand_back()
+    assert not compile_events, compile_events
+
+
+@pytest.mark.parametrize("engine", ["generator", "inference"])
+def test_donation_safe_swap_on_an_identical_layout_shares_no_buffer(engine):
+    """Same mesh, same dtype: reshard leaves every leaf in place, so the
+    engines' alias copy is what keeps them off the trainer's buffers."""
+    from areal_tpu.engines.generator import GeneratorEngine
+    from areal_tpu.engines.inference import InferenceEngine
+    from areal_tpu.engines.offload import buffers_alias
+
+    cfg = tiny_config()
+    mesh = make_mesh(ParallelConfig.from_str("d1f2"), jax.devices()[:2])
+    train = TrainEngine(
+        cfg,
+        tfm.init_params(cfg, jax.random.PRNGKey(1)),
+        mesh,
+        optimizer_config=OptimizerConfig(lr=1e-3, warmup_steps_proportion=0.0),
+        ftspec=FinetuneSpec(1, 8, 8),
+    )
+    src = train.get_params()
+    n = len(jax.tree.leaves(src))
+    if engine == "generator":
+        eng = GeneratorEngine(
+            cfg, src, mesh, eos_token_id=7, donation_safe_swap=True
+        )
+    else:
+        eng = InferenceEngine(cfg, src, mesh)
+    eng.set_params(src)
+    assert eng.last_sync_stats["leaves_aliased"] == n
+    for got, orig in zip(
+        jax.tree.leaves(eng.get_params()), jax.tree.leaves(src)
+    ):
+        assert not buffers_alias(got, orig)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(orig))
+    if engine == "generator":
+        # The synchronous opt-out keeps the alias (no second copy in HBM).
+        eng.donation_safe_swap = False
+        eng.set_params(src)
+        assert all(
+            got is orig for got, orig in zip(
+                jax.tree.leaves(eng.get_params()), jax.tree.leaves(src)
+            )
+        )

@@ -331,9 +331,11 @@ def test_engine_spans_nest_under_the_mfc(trial):
     }
     assert {"mb_upload", "grad_dispatch", "apply_dispatch"} <= set(parents)
     # (the engine's first set_params, at build, is under no span)
-    assert parents["params_put"] == parents["params_cast"] == {
-        None, "param_sync:actor_gen@0"
-    }
+    assert parents["params_put"] == {None, "param_sync:actor_gen@0"}
+    # The cast is inside realloc.reshard's placement now (in its compiled
+    # program for device leaves), not an eager pass of its own.
+    assert "params_cast" not in parents
+    assert parents["reshard"] >= {"params_put"}
 
 
 def test_serving_counters_are_filled_and_reset_per_generate(trial):
@@ -355,7 +357,15 @@ def test_pack_and_sync_counters(trial):
         assert stats["actor_gen/sync/bytes"] == sync["bytes"] > 0
         assert stats["actor_gen/sync/put_s"] == sync["put_s"]
         assert (stats["actor_gen/sync/time_s"]
-                >= sync["cast_s"] + sync["put_s"] + sync["alias_copy_s"])
+                >= sync["put_s"] + sync["alias_copy_s"])
+        # One device, one dtype: every leaf stays in place, and the
+        # route counters reach the step stats key by key.
+        assert sync["leaves_aliased"] > 0
+        assert sync["leaves_resharded"] == sync["leaves_put"] == 0
+        assert sync["bytes_resharded"] == sync["bytes_put"] == 0
+        for k in ("leaves_aliased", "leaves_resharded", "leaves_put",
+                  "bytes_resharded", "bytes_put"):
+            assert stats[f"actor_gen/sync/{k}"] == sync[k]
 
 
 def test_compiles_are_charged_to_the_mfc_that_compiled(trial):
