@@ -95,19 +95,155 @@ def reference_check(obs, rollout):
     return report
 
 
-def handback_equal(train, gen):
-    """The generator holds the trainer's weights after the last hand-back
-    (the leaves `chip_smoke.py` compares: zero at init, so they also show
-    that training moved them)."""
-    t_bq = np.asarray(train.params["blocks"]["bq"], np.float32)
-    g_bq = np.asarray(gen.params["blocks"]["bq"], np.float32)
-    ok = (np.isfinite(t_bq).all() and float(np.abs(t_bq).max()) > 0.0
-          and np.array_equal(t_bq, g_bq))
-    for leaf in ("embed", "final_ln"):
-        a = np.asarray(train.params[leaf][:64], np.float32)
-        b = np.asarray(gen.params[leaf][:64], np.float32)
-        ok = ok and np.array_equal(a, b)
-    return bool(ok)
+# --------------------------------------------------------------------------
+# The weight check: any tree, on the device, scalars only
+# --------------------------------------------------------------------------
+
+_UINT = {1: "uint8", 2: "uint16", 4: "uint32"}
+
+
+def _leaf_sums(x, dtype):
+    """[plain sum, position-weighted sum] of a leaf's bits as wrapping
+    uint32.  Both are sums of integers modulo 2**32, so they do not depend
+    on the order of addition: the same leaf gives the same pair under any
+    sharding (a floating sum would not).  The plain sum changes with any
+    single flipped bit; the second multiplies each element's bits by an
+    odd hash of its flat position, so two swapped rows show.  The position
+    is built from one iota per dimension, not by flattening, so a sharded
+    leaf is reduced where it lies and only the two scalars cross chips."""
+    import jax
+    import jax.numpy as jnp
+
+    x = x.astype(dtype)  # the hand-back's cast; a no-op for equal types
+    if x.dtype.itemsize not in _UINT:
+        raise TypeError(f"no bit sum for a leaf of dtype {x.dtype}")
+    bits = jax.lax.bitcast_convert_type(
+        x, _UINT[x.dtype.itemsize]
+    ).astype(jnp.uint32)
+    pos = jnp.zeros((), jnp.uint32)
+    stride = 1
+    for axis in reversed(range(x.ndim)):
+        pos = pos + jax.lax.broadcasted_iota(
+            jnp.uint32, x.shape, axis
+        ) * jnp.uint32(stride % 2**32)
+        stride *= x.shape[axis]
+    h = pos * jnp.uint32(0x9E3779B1)
+    h = (h ^ (h >> 15)) * jnp.uint32(0x85EBCA77)
+    h = (h ^ (h >> 13)) | jnp.uint32(1)
+    return jnp.stack([jnp.sum(bits, dtype=jnp.uint32),
+                      jnp.sum(bits * h, dtype=jnp.uint32)])
+
+
+_PROGRAMS = {}  # (leaf avals and shardings, dtypes) -> compiled executable
+
+
+def drop_programs():
+    """Unload the compiled weight-sum programs.  A loaded executable takes
+    device memory (2.1 MB for the four-chip tree: chip run, PR 25), so the
+    sums taken before the window opens would otherwise sit in every
+    later `peak_hbm_gb`; the pass after the window loads its program
+    again from the persistent compile cache."""
+    _PROGRAMS.clear()
+
+
+def tree_sums(tree, like=None):
+    """{path: (sum, weighted sum)} of every leaf of a parameter tree, as
+    Python ints: one compiled program per tree (shapes, dtypes,
+    shardings), held until `drop_programs`, computed where the leaves
+    lie; only the scalars are fetched, explicitly.  `like`: a tree whose
+    leaves give the dtype each leaf is cast to first, by path (the
+    generator's, for the trainer's tree: the hand-back casts the master
+    weights to the generator's compute type); a leaf it lacks keeps its
+    own."""
+    import jax
+
+    flat = jax.tree_util.tree_flatten_with_path(tree)[0]
+    paths = [jax.tree_util.keystr(p) for p, _ in flat]
+    want = {} if like is None else {
+        jax.tree_util.keystr(p): x.dtype
+        for p, x in jax.tree_util.tree_flatten_with_path(like)[0]
+    }
+    leaves = [x for _, x in flat]
+    dtypes = tuple(
+        str(want.get(p, x.dtype)) for p, x in zip(paths, leaves)
+    )
+    key = (tuple((x.shape, str(x.dtype), x.sharding) for x in leaves), dtypes)
+    if key not in _PROGRAMS:
+        # Compiled ahead of time, so that the executable is this dict's
+        # to drop and not jit's to keep.
+        _PROGRAMS[key] = jax.jit(
+            lambda xs: [_leaf_sums(x, d) for x, d in zip(xs, dtypes)]
+        ).lower(leaves).compile()
+    # No leaf may come to the host (5.9 GB on four chips); an implicit
+    # transfer raises here, the explicit fetch of the scalars does not.
+    with jax.transfer_guard_device_to_host("disallow"):
+        sums = jax.device_get(_PROGRAMS[key](leaves))
+    return {p: (int(s[0]), int(s[1])) for p, s in zip(paths, sums)}
+
+
+def matmul_leaves(tree):
+    """Paths of the leaves that are matrices of a matmul: two or more
+    dimensions, not counting the leading layer axis of a leaf under
+    `blocks` (the program stacks its layers there, as the references
+    read them).  Norm scales and biases are left out: a bf16 scale of 1.0
+    may not move under a small Adam step."""
+    import jax
+
+    out = set()
+    for path, x in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        stacked = getattr(path[0], "key", None) == "blocks"
+        if x.ndim - int(stacked) >= 2:
+            out.add(jax.tree_util.keystr(path))
+    return out
+
+
+def handback_problems(train_sums, gen_sums, gen_sums_before, matmul):
+    """What is wrong with the weights after the last hand-back, as text;
+    empty means the generator holds the trainer's weights and training
+    moved them.  The three arguments are `tree_sums` of the trainer's tree
+    (cast as the hand-back casts it), of the generator's, and of the
+    generator's taken before the window opened; `matmul` the paths that
+    training has to have moved."""
+    out = []
+    only = sorted(set(train_sums) ^ set(gen_sums))
+    if only:
+        out.append(
+            "leaves on one side only: "
+            + ", ".join(f"{p} ({'trainer' if p in train_sums else 'generator'})"
+                        for p in only)
+        )
+    differ = sorted(p for p in set(train_sums) & set(gen_sums)
+                    if train_sums[p] != gen_sums[p])
+    if differ:
+        out.append("generator weights differ from the trainer's after the "
+                   f"last hand-back in {len(differ)} of {len(gen_sums)} "
+                   f"leaves: {', '.join(differ)}")
+    if gen_sums_before is None:
+        out.append("no weight sums were taken before the window")
+        return out
+    still = sorted(p for p in matmul & set(gen_sums)
+                   if gen_sums[p] == gen_sums_before.get(p))
+    if still:
+        out.append("training did not move the generator's weights in: "
+                   + ", ".join(still))
+    return out
+
+
+def handback_check(train, gen, gen_sums_before):
+    """`handback_problems` for the two engines, and what it compared."""
+    import time
+
+    t0 = time.monotonic()
+    gen_params = gen.get_params()
+    gen_sums = tree_sums(gen_params)
+    train_sums = tree_sums(train.get_params(), like=gen_params)
+    problems = handback_problems(
+        train_sums, gen_sums, gen_sums_before, matmul_leaves(gen_params)
+    )
+    return {
+        "ok": not problems, "problems": problems, "leaves": len(gen_sums),
+        "seconds": round(time.monotonic() - t0, 3),
+    }
 
 
 def check_step(i, step, run):
@@ -185,9 +321,11 @@ def check_run(run):
     for i, step in enumerate(run.steps):
         out += check_step(i, step, run)
     out += check_route(run)
-    if not getattr(run, "handback_ok", False):
-        out.append("generator weights differ from the trainer's after the "
-                   "last hand-back")
+    handback = getattr(run, "handback", None)
+    if handback is None:
+        out.append("the weights were not compared after the last hand-back")
+    else:
+        out += handback["problems"]
     if not (run.reference or {}).get("ok"):
         out.append(f"reference check failed: {run.reference}")
     return out

@@ -3,11 +3,24 @@ no cell, no model, no traffic mix and no metric itself.
 
     workloads/<cell>.json      config, traffic, chips, expected route
     configs/<config>.json      the model's published keys + a "benchmark"
-                               group (reference, layout, reduced, assumed)
+                               group (reference, layout, reduced, assumed,
+                               and optionally `toy`: key overrides for
+                               --cpu-rehearsal beyond the dense keys
+                               `run.toy` shrinks itself — experts, a
+                               `head_dim` key, windows)
     traffic/<traffic>.json     parameters of one traffic mix; its
                                "generator" key names traffic/<generator>.py
     references/<reference>.py  the architecture's plain fp32 forward pass
     metrics/<metric>.py        one reader: read(run) -> number or None
+                               (`run`: `benchmark.run.Run`; its `trace`
+                               keys are listed at `run.reduce_trace`;
+                               `metrics/_program.py` has the shared
+                               readers of scopes, kernels and counters)
+
+A new cell also appends its name to the `workloads` list of every
+BENCHMARK.json metric that has one and that it reports
+(`gen_tokens_per_s`, the decode and serving metrics): `metrics_for` hands
+a cell only the entries without a list or with its name in it.
 """
 
 import importlib

@@ -38,10 +38,11 @@ what JAX itself puts in the path: `rematted_computation` marks the
 recomputation inside a `jax.checkpoint`, `transpose(jvp(...))` the
 backward pass, anything else is forward.
 
-`reduce_file` returns NEW keys only; it calls `trace.reduce` for none of
-them and changes none of its keys.  Nothing in `benchmark/run.py` calls
-this module yet (PERF.md §7 says which line would); it is run by hand on
-a trace directory:
+`reduce` returns NEW keys only (and `breakdown`, with the longer names);
+it calls `trace.reduce` for none of them and changes none of its keys.
+`benchmark/run.py` `reduce_trace` merges the two into `Run.trace`, where
+the readers under `metrics/` find them (`metrics/_program.py`
+`scope_seconds`, `scope_share`).  By hand, on a trace directory:
 
     python3 -m benchmark.program_trace <trace dir or .xplane.pb> [chips]
 """

@@ -25,6 +25,13 @@ one's place, watches the host for pauses (a ticking thread, the garbage
 collector's callbacks), and wraps the master's step-stats logger, which
 is also how it stops the run — no option of the program is involved.
 
+What a metric reader (`metrics/<name>.py`, `read(run)`) may rely on is
+the `Run` record below; of `run.trace` (traced run only) the keys
+`reduce_trace` lists.  A cell is added by files alone (`benchmark/files.py`)
+and an entry in `BENCHMARK.json`; where it reports `gen_tokens_per_s` or
+another metric whose entry carries a `workloads` list (the decode and
+serving metrics), it appends its name to that list.
+
 Without a TPU, or with fewer chips than the cell asks for, this exits
 non-zero and prints no result.  `--cpu-rehearsal` runs the same code at
 toy size on the CPU to debug the benchmark itself; it says `platform=cpu`
@@ -101,9 +108,9 @@ class Run:
     peak_bytes: int = 0
     compiles_in_window: int = 0
     programs: list = dataclasses.field(default_factory=list)
-    trace: dict = None  # benchmark/trace.py reduce(), traced run only
+    trace: dict = None  # reduce_trace() below, traced run only
     reference: dict = None  # checks.reference_check() report
-    handback_ok: bool = False
+    handback: dict = None  # checks.handback_check() report
 
     # -- helpers the readers share ----------------------------------------
     def total(self, fn):
@@ -123,12 +130,18 @@ class Run:
 
 
 def toy(config, traffic):
-    """Toy sizes for --cpu-rehearsal: same files, same routes, tiny work."""
+    """Toy sizes for --cpu-rehearsal: same files, same routes, tiny work.
+    The seven keys every dense config has are shrunk here; whatever else
+    a family sizes itself by (experts and their width, a `head_dim` key,
+    windows, ranks) the config file shrinks itself, in the `toy` dict of
+    its `benchmark` group, which is applied last and so may also override
+    the seven."""
     config = dict(
         config, hidden_size=64, intermediate_size=128, num_attention_heads=4,
         num_key_value_heads=2, num_hidden_layers=2, vocab_size=512,
         max_position_embeddings=1024,
     )
+    config.update(config["benchmark"].get("toy", {}))
     config["benchmark"] = dict(config["benchmark"], param_dtype="float32")
     pl = dict(traffic["prompt_len"])
     for k in ("lo", "hi", "median"):
@@ -309,6 +322,7 @@ class Observer:
         self.tracing = False
         self.compiles = 0
         self.setup_compiles = []  # seconds of each backend compile or load
+        self.gen_sums_before = None  # checks.tree_sums, end of the warm-up
         self.master = None
 
     # -- wiring ------------------------------------------------------------
@@ -400,11 +414,29 @@ class Observer:
     def on_step_end(self, stats):
         import jax
 
+        gen = self.models["actor_gen"].engine
+        train = self.models["actor"].engine
+        if self.n_logged == 0:
+            # End of the warm-up step, before the clock below is read, so
+            # set-up pays: what the generator holds as the window opens.
+            # The weight check after the window wants every matrix moved
+            # from here, and finds this tree's program in the compile
+            # cache.
+            t0, b0 = time.monotonic(), bytes_in_use()
+            self.gen_sums_before = checks.tree_sums(gen.get_params())
+            b1 = bytes_in_use()
+            checks.drop_programs()  # or its executable sits in the peak
+            # What tracing that program left for the collector goes now:
+            # on four chips it brought a full collection of 80 ms into
+            # the first timed step (chip runs, PR 25).
+            gc.collect()
+            log(f"weight sums of {len(self.gen_sums_before)} generator "
+                f"leaves in {time.monotonic() - t0:.3f}s; HBM in use "
+                f"{b0} before, {b1} with the program loaded, "
+                f"{bytes_in_use()} after dropping it")
         now = time.monotonic()
         self.n_logged += 1
         spans, self.spans = self.spans, []
-        gen = self.models["actor_gen"].engine
-        train = self.models["actor"].engine
         out = self.rollout
         lens = [l for g in out.seqlens["packed_input_ids"] for l in g]
         bounds = out.cu_seqlens("packed_input_ids")
@@ -483,9 +515,37 @@ class Observer:
             sig[0] if isinstance(sig[0], str) else "static"
             for sig in gen._gen_fns
         )
-        self.run.handback_ok = checks.handback_equal(
-            self.models["actor"].engine, gen
+        self.run.handback = checks.handback_check(
+            self.models["actor"].engine, gen, self.gen_sums_before
         )
+        log(f"weight check: {self.run.handback}")
+
+
+def reduce_trace(path, chips):
+    """`Run.trace` from a trace directory or an `.xplane.pb`: the profile
+    read once and reduced twice.  Every key of
+    `trace.reduce` as it was (`window_s`, `busy_s`, `busy_by_device`,
+    `n_events`, `op_seconds`, `idle_seconds`, `loop_seconds`), then what
+    the program's own names add (`program_trace.reduce`: `program_spans`,
+    `busy_by_bench_span`, `idle_by_program_span`, `scope_seconds`,
+    `kernel_seconds`, `op_seconds_scoped`), `traced_steps`, and
+    `breakdown` from the second: the first's operation names and idle
+    labels with the scope and phase, and the program span, as suffixes.
+    A program without the names gives empty dicts and bare names."""
+    from jax.profiler import ProfileData
+
+    from benchmark import program_trace
+    from benchmark import trace as trace_mod
+
+    if os.path.isdir(path):
+        path = trace_mod.find_xplane(path)
+    profile = ProfileData.from_file(path)
+    out = trace_mod.reduce(profile, chips)
+    out.update(
+        program_trace.reduce(profile, program_trace.op_paths(path), chips)
+    )
+    out["traced_steps"] = TRACE_STEPS
+    return out
 
 
 def bytes_in_use():
@@ -594,16 +654,17 @@ def main(argv=None):
         plan = build_plan(run, rows, tok, os.path.join(tmp, "trial"))
         run_experiment_inproc(plan, tokenizer=tok, inspect=obs)
         if run.traced:
-            from benchmark import trace as trace_mod
-
             t0 = time.monotonic()
             if args.cpu_rehearsal:
                 log("a CPU trace has no device planes; nothing to reduce")
             else:
-                run.trace = trace_mod.reduce_dir(trace_dir, run.chips)
+                run.trace = reduce_trace(trace_dir, run.chips)
                 log(f"trace reduced in {time.monotonic() - t0:.1f}s: "
                     f"{run.trace['n_events']} device events, longest "
-                    f"outermost loops {run.trace['loop_seconds']}, breakdown "
+                    f"outermost loops {run.trace['loop_seconds']}, device "
+                    f"busy inside each bench span "
+                    f"{ {k: round(float(v), 3) for k, v in run.trace['busy_by_bench_span'].items() if v} }"
+                    f", breakdown "
                     f"{json.dumps(run.trace['breakdown'])}")
 
     problems = checks.check_run(run)

@@ -51,12 +51,6 @@ def find_xplane(trace_dir):
     return max(paths, key=os.path.getmtime)
 
 
-def reduce_dir(trace_dir, chips):
-    from jax.profiler import ProfileData
-
-    return reduce(ProfileData.from_file(find_xplane(trace_dir)), chips)
-
-
 def _line_events(line, rename=None):
     names = {}  # the trace repeats a few thousand distinct names
 
