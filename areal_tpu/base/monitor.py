@@ -24,14 +24,14 @@ logger = logging.getLogger("monitor")
 
 def matmul_params(cfg) -> int:
     """Parameters that participate in matmuls for ONE token's forward pass
-    (active experts only for MoE; embedding lookup excluded)."""
+    (the routed experts and the router for MoE; embedding lookup excluded)."""
     h = cfg.hidden_dim
     d = cfg.head_dim
     attn = h * (cfg.n_q_heads * d + 2 * cfg.n_kv_heads * d) + cfg.n_q_heads * d * h
     n_mats = 3 if getattr(cfg, "mlp_gated", True) else 2
     if cfg.is_moe:
         inter = cfg.moe_intermediate_dim or cfg.intermediate_dim
-        mlp = n_mats * h * inter * cfg.n_experts_per_tok
+        mlp = n_mats * h * inter * cfg.n_experts_per_tok + h * cfg.n_experts
     else:
         mlp = n_mats * h * cfg.intermediate_dim
     per_layer = attn + mlp

@@ -427,6 +427,10 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         self.decode_compiles = 0
         self.cache_copy_bytes = 0
         self.last_pool_stats: Dict[str, Any] = {}
+        # MoE decode counters of the current generate() call, summed on the
+        # device inside the decode loop: [experts touched, fullest expert's
+        # rows, steps] (see _fold_moe_counters).
+        self._moe_decode_sums = np.zeros((3,))
         # Serving-plane chunk counters of the current generate() call
         # (see _serving_counters); folded into last_pool_stats at its end.
         self._chunk_stats: Dict[str, Any] = _new_chunk_stats()
@@ -658,6 +662,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         self.decode_compiles = 0
         self.cache_copy_bytes = 0
         self.last_pool_stats = {}
+        self._moe_decode_sums = np.zeros((3,))
         self.lanes_dispatched = 0
         self.lanes_live = 0
         self.lanes_slack = 0
@@ -3017,6 +3022,18 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         )
         return fn
 
+    def _fold_moe_counters(self) -> None:
+        """MoE decode counters into last_pool_stats: per decode step and MoE
+        layer, the experts with at least one row and the rows on the
+        fullest expert (means over every step of this generate())."""
+        touched, rows_max, steps = self._moe_decode_sums
+        if steps:
+            self.last_pool_stats.update(
+                moe_experts_touched=touched / steps,
+                moe_rows_per_expert_max=rows_max / steps,
+                moe_decode_steps=int(steps),
+            )
+
     # -- one fixed-shape chunk --
 
     def _generate_chunk(self, chunk, gconfig, key, results) -> None:
@@ -3039,7 +3056,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         fn = self._get_gen_fn(b, sp, s_total, gconfig)
         with tracer.span("gen_chunk", cat="compute", b=b_real, sp=sp):
             with tracer.span("gen_dispatch", cat="compute"):
-                toks, logps, gen_len = fn(
+                toks, logps, gen_len, *moe = fn(
                     self.params, prompt_tok, prompt_len, key
                 )
             with tracer.span("gen_wait", cat="compute"):
@@ -3048,6 +3065,9 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                     to_host(logps),
                     to_host(gen_len),
                 )
+                if moe:  # [experts touched, fullest expert's rows, steps]
+                    self._moe_decode_sums += to_host(moe[0]).astype(float)
+                    self._fold_moe_counters()
         for r, (i, rep, _) in enumerate(chunk):
             gl = int(gen_len[r])
             no_eos = gl == gconfig.max_new_tokens and (
@@ -3090,7 +3110,8 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                 return (step < max_new) & ~jnp.all(done)
 
             def body(state):
-                step, logits, key, done, gen_len, out_toks, out_logps, cache = state
+                (step, logits, key, done, gen_len, out_toks, out_logps,
+                 cache, *moe) = state
                 key, sub = jax.random.split(key)
                 if g.min_new_tokens > 0:
                     logits = jnp.where(
@@ -3110,18 +3131,23 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                 gen_len = gen_len + (~done).astype(jnp.int32)
                 new_done = done | (tok == eos)
                 pos = prompt_len + step  # RoPE position per row
-                next_logits, cache = tfm.decode_step(
-                    params, cfg, tok, pos, cache, sp + step, valid_from
+                next_logits, cache, *counts = tfm.decode_step(
+                    params, cfg, tok, pos, cache, sp + step, valid_from,
+                    with_moe_counts=cfg.is_moe,
                 )
+                if cfg.is_moe:
+                    moe = [moe[0] + _moe_step_counters(counts[0])]
                 return (
                     step + 1, next_logits, key, new_done, gen_len,
-                    out_toks, out_logps, cache,
+                    out_toks, out_logps, cache, *moe,
                 )
 
             state = (0, logits0, key, done, gen_len, out_toks, out_logps, cache)
+            if cfg.is_moe:  # two sums + the steps they run over
+                state += (jnp.zeros((3,), jnp.float32),)
             state = jax.lax.while_loop(cond, body, state)
-            _, _, _, _, gen_len, out_toks, out_logps, _ = state
-            return out_toks, out_logps, gen_len
+            _, _, _, _, gen_len, out_toks, out_logps, _, *moe = state
+            return (out_toks, out_logps, gen_len, *moe)
 
         self._gen_fns[sig] = gen
         logger.info(
@@ -3144,6 +3170,19 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             lambda i, r: results[(i, r)],
             prompt_lens=prompt_lens,
         )
+
+
+def _moe_step_counters(counts: jax.Array) -> jax.Array:
+    """One decode step's rows per expert [L, E] -> f32 [3]: experts with at
+    least one row and the fullest expert's rows (both means over layers),
+    and 1 for the step — what the decode loop sums with no host sync."""
+    return jnp.stack(
+        [
+            jnp.mean(jnp.sum(counts > 0, axis=-1).astype(jnp.float32)),
+            jnp.mean(jnp.max(counts, axis=-1).astype(jnp.float32)),
+            jnp.float32(1.0),
+        ]
+    )
 
 
 def assemble_rollout(

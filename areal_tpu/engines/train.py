@@ -96,6 +96,21 @@ def _cast_tree(tree, dtype):
     )
 
 
+def _moe_stats(aux, counts) -> Dict[str, jax.Array]:
+    """A micro-batch's MoE counters (plain keys: averaged over micro-batches
+    by `train_batch`): the router's load-balancing loss as added to the
+    objective, and the real tokens on the fullest expert over the mean per
+    expert, mean over layers (1.0 = perfectly balanced; `counts` is None
+    under PP, which reports the loss alone)."""
+    out = {"moe/aux_loss": jax.lax.stop_gradient(aux)}
+    if counts is not None:
+        c = counts.astype(jnp.float32)  # [L, E]
+        out["moe/load_max_over_mean"] = jnp.mean(
+            c.max(axis=-1) / jnp.maximum(c.mean(axis=-1), 1e-9)
+        )
+    return out
+
+
 def _model_out(params, cfg: ModelConfig, x, batch):
     """Per-token model output [B, S] from final hidden states (see
     transformer.per_token_output)."""
@@ -295,7 +310,7 @@ class TrainEngine(HostOffloadMixin, Engine):
         def _value_and_grad(params, batch, loss_scale):
             def losswrap(p):
                 pc = _cast_tree(p, compute_dtype)
-                x, aux = tfm.hidden_states(
+                x, aux, counts = tfm.hidden_states(
                     pc,
                     cfg,
                     batch["tokens"],
@@ -306,6 +321,7 @@ class TrainEngine(HostOffloadMixin, Engine):
                     cp_mesh=cp_mesh,
                     pp_mesh=pp_mesh,
                     pp_microbatches=pp_mbs,
+                    with_moe_counts=True,
                 )
                 # Loss fns receive per-token model outputs, never [B,S,V]
                 # logits: critic -> values; LM -> fused chunked next-token
@@ -313,6 +329,8 @@ class TrainEngine(HostOffloadMixin, Engine):
                 out = _model_out(pc, cfg, x, batch)
                 loss, stats = loss_fn(out, batch)
                 total = loss + cfg.moe_aux_loss_coef * aux
+                if cfg.is_moe:
+                    stats = {**stats, **_moe_stats(aux, counts)}
                 return total * loss_scale, stats
 
             with jax.named_scope("train/grad"):

@@ -30,6 +30,9 @@ class ModelConfig:
     rope_theta: float = 10000.0
     rms_norm_eps: float = 1e-6
     qkv_bias: bool = False  # qwen2-style attention bias
+    # olmoe: RMSNorm with a learned weight over the WHOLE projected q and k
+    # vectors (all heads at once), before the reshape into heads and rope.
+    qk_norm: bool = False
     tied_embeddings: bool = False
     is_critic: bool = False
     param_dtype: str = "bfloat16"
@@ -37,6 +40,10 @@ class ModelConfig:
     n_experts: int = 0
     n_experts_per_tok: int = 2
     moe_intermediate_dim: int = 0
+    # True (mixtral): the top-k router weights are renormalised to sum to
+    # one.  False (olmoe `norm_topk_prob: false`): the softmax
+    # probabilities over ALL experts are used as they are.
+    moe_norm_topk: bool = True
     # Router aux loss coefficient (reference: modules/moe/router.py)
     moe_aux_loss_coef: float = 0.001
     # "grouped" (default): dropless grouped-GEMM over expert-sorted
@@ -107,7 +114,10 @@ def tiny_config(
 # Published architecture presets (values from the public model cards).
 def qwen2_config(size: str, param_dtype: str = "bfloat16") -> ModelConfig:
     presets = {
-        # R1-Distill-Qwen uses the qwen2 architecture.
+        # "1.5b" holds Qwen2.5-Math-1.5B's public numbers (tied head, rope
+        # 10,000) — the base of R1-Distill-Qwen-1.5B, whose own public
+        # config is NOT tied (a separate 233 M head).  "7b"/"32b" are the
+        # R1-Distill-Qwen sizes (qwen2 architecture, untied).
         "1.5b": dict(
             n_layers=28, hidden_dim=1536, n_q_heads=12, n_kv_heads=2,
             head_dim=128, intermediate_dim=8960, vocab_size=151936,

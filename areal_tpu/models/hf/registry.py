@@ -8,7 +8,9 @@ family, used for loading pretrained checkpoints and saving HF-format outputs
 Families here (full reference parity, api/from_hf/*): llama, qwen2
 (identical tensor naming; qwen2 adds qkv bias), mistral, gemma (gelu_tanh +
 (1+w) rms offset + scaled embeddings), mixtral (MoE expert stacking), gpt2
-(learned positions, LayerNorm+bias, fused c_attn, non-gated gelu MLP).
+(learned positions, LayerNorm+bias, fused c_attn, non-gated gelu MLP), olmoe
+(64-expert MoE under `mlp.experts.{e}`, QK-norm over the whole projection,
+top-k router weights not renormalised).
 """
 
 import dataclasses
@@ -406,6 +408,132 @@ register_hf_family(
 )
 
 
+# ---------------- olmoe ----------------
+# allenai/OLMoE-1B-7B: llama-like attention without bias plus an RMSNorm
+# over the whole projected q and k (`self_attn.q_norm` / `k_norm`); every
+# layer's MLP is a MoE under `mlp.gate` (router) and `mlp.experts.{e}`
+# (SwiGLU, `gate_proj` / `up_proj` / `down_proj`).  `intermediate_size` is
+# the width of ONE expert; there is no dense MLP and no shared expert.
+
+
+def _olmoe_config_from_hf(hf: dict) -> ModelConfig:
+    if hf.get("clip_qkv") is not None:
+        raise NotImplementedError(
+            f"olmoe clip_qkv={hf['clip_qkv']!r}: clamping q/k/v is not "
+            "implemented, and ignoring it would run another model"
+        )
+    if hf.get("attention_bias", False):
+        raise NotImplementedError("olmoe attention_bias=true is not modeled")
+    base = _llama_like_config_from_hf(hf)
+    return dataclasses.replace(
+        base,
+        qkv_bias=False,
+        qk_norm=True,
+        rms_norm_eps=hf.get("rms_norm_eps", 1e-5),
+        n_experts=hf["num_experts"],
+        n_experts_per_tok=hf["num_experts_per_tok"],
+        moe_intermediate_dim=hf["intermediate_size"],
+        moe_norm_topk=bool(hf.get("norm_topk_prob", False)),
+        moe_aux_loss_coef=hf.get("router_aux_loss_coef", 0.01),
+    )
+
+
+def _olmoe_config_to_hf(cfg: ModelConfig) -> dict:
+    out = _llama_like_config_to_hf(cfg, "olmoe")
+    out.pop("head_dim")  # not an olmoe key: hidden_size / heads
+    out.update(
+        architectures=["OlmoeForCausalLM"],
+        hidden_act=cfg.hidden_act,
+        attention_bias=False,
+        clip_qkv=None,
+        num_experts=cfg.n_experts,
+        num_experts_per_tok=cfg.n_experts_per_tok,
+        intermediate_size=cfg.moe_intermediate_dim or cfg.intermediate_dim,
+        norm_topk_prob=cfg.moe_norm_topk,
+        router_aux_loss_coef=cfg.moe_aux_loss_coef,
+    )
+    return out
+
+
+_OLMOE_MLP = "model.layers.{}.mlp"
+_OLMOE_EXPERT_LEAVES = (  # ours [L, E, in, out] <- HF [out, in]
+    ("wg", "gate_proj"), ("wu", "up_proj"), ("wd", "down_proj"),
+)
+_OLMOE_NORM_LEAVES = ("q_norm", "k_norm")  # same name on both sides
+
+
+def _olmoe_params_from_sd(cfg, sd, dtype=None):
+    import jax.numpy as jnp
+
+    params = params_from_hf_state_dict(cfg, sd, dtype=dtype, skip_mlp=True)
+    dtype = dtype or cfg.dtype
+    blocks = params["blocks"]
+    layers = range(cfg.n_layers)
+    for leaf in _OLMOE_NORM_LEAVES:
+        fmt = "model.layers.{}.self_attn." + leaf + ".weight"
+        blocks[leaf] = jnp.asarray(
+            np.stack([np.asarray(sd[fmt.format(i)]) for i in layers]), dtype
+        )
+    blocks["router"] = jnp.asarray(
+        np.stack(
+            [
+                np.asarray(sd[_OLMOE_MLP.format(i) + ".gate.weight"]).T
+                for i in layers
+            ]
+        ),
+        dtype,
+    )
+    for leaf, name in _OLMOE_EXPERT_LEAVES:
+        fmt = _OLMOE_MLP + ".experts.{}." + name + ".weight"
+        blocks[leaf] = jnp.asarray(
+            np.stack(
+                [
+                    np.stack(
+                        [
+                            np.asarray(sd[fmt.format(i, e)]).T
+                            for e in range(cfg.n_experts)
+                        ]
+                    )
+                    for i in layers
+                ]
+            ),
+            dtype,
+        )
+    return params
+
+
+def _olmoe_params_to_sd(cfg, params):
+    from areal_tpu.base.distributed import to_host
+
+    out = params_to_hf_state_dict(cfg, params, skip_mlp=True)
+    host = {
+        k: to_host(params["blocks"][k]).astype(np.float32, copy=False)
+        for k in (*_OLMOE_NORM_LEAVES, "router", "wg", "wu", "wd")
+    }
+    for i in range(cfg.n_layers):
+        for leaf in _OLMOE_NORM_LEAVES:
+            out[f"model.layers.{i}.self_attn.{leaf}.weight"] = host[leaf][i]
+        mlp = _OLMOE_MLP.format(i)
+        out[mlp + ".gate.weight"] = np.ascontiguousarray(host["router"][i].T)
+        for leaf, name in _OLMOE_EXPERT_LEAVES:
+            for e in range(cfg.n_experts):
+                out[f"{mlp}.experts.{e}.{name}.weight"] = (
+                    np.ascontiguousarray(host[leaf][i, e].T)
+                )
+    return out
+
+
+register_hf_family(
+    HFFamily(
+        "olmoe",
+        _olmoe_config_from_hf,
+        _olmoe_config_to_hf,
+        params_from_sd=_olmoe_params_from_sd,
+        params_to_sd=_olmoe_params_to_sd,
+    )
+)
+
+
 # ---------------- gpt2 ----------------
 # Different lineage: learned positions, LayerNorm with bias, fused c_attn,
 # plain (non-gated) gelu MLP, biases everywhere, Conv1D weights stored
@@ -554,7 +682,7 @@ def infer_model_type(cfg: ModelConfig) -> str:
     if cfg.norm_type == "layernorm":
         return "gpt2"
     if cfg.is_moe:
-        return "mixtral"
+        return "olmoe" if cfg.qk_norm else "mixtral"
     if cfg.rms_norm_offset:
         return "gemma"
     if cfg.qkv_bias:

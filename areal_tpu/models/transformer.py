@@ -75,6 +75,9 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         blocks["bq"] = jnp.zeros((L, cfg.q_dim), dtype)
         blocks["bk"] = jnp.zeros((L, cfg.kv_dim), dtype)
         blocks["bv"] = jnp.zeros((L, cfg.kv_dim), dtype)
+    if cfg.qk_norm:
+        blocks["q_norm"] = jnp.ones((L, cfg.q_dim), dtype)
+        blocks["k_norm"] = jnp.ones((L, cfg.kv_dim), dtype)
     if cfg.norm_type == "layernorm":
         blocks["ln1_b"] = jnp.zeros((L, D), dtype)
         blocks["ln2_b"] = jnp.zeros((L, D), dtype)
@@ -97,8 +100,18 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             blocks["wu"] = dense(km[1], (L, D, F), D)
         blocks["wd"] = dense(km[2], (L, F, D), F)
 
+    # The table of an untied MoE model is drawn at UNIT variance: a lookup
+    # has fan-in one (a one-hot input).  A router reads the residual stream;
+    # with rows of RMS D**-0.5 (0.022) beside attention outputs of 0.2-0.5
+    # the stream of every position is its context's average, and a random
+    # router sends every row of a batch to the same few experts (17 of 64 at
+    # 8 rows on the chip, where a load-balanced router touches 42; PERF.md,
+    # PR 26).  At unit variance the token dominates and random routing
+    # spreads as trained routing does.  Dense models' work does not depend
+    # on their data, and a tied table is also the head: both keep D**-0.5.
+    embed_fan_in = 1 if cfg.is_moe and not cfg.tied_embeddings else D
     params: Params = {
-        "embed": dense(k_embed, (cfg.vocab_size, D), D),
+        "embed": dense(k_embed, (cfg.vocab_size, D), embed_fan_in),
         "blocks": blocks,
         "final_ln": jnp.ones((D,), dtype),
     }
@@ -222,11 +235,16 @@ def _mlp_dense(h: jax.Array, blk: Params, cfg: ModelConfig) -> jax.Array:
 
 
 def _moe_route(x: jax.Array, blk: Params, cfg: ModelConfig):
-    """Router: top-k weights/indices + switch-style load-balancing aux."""
+    """Router: top-k weights/indices + switch-style load-balancing aux.
+
+    fp32 throughout: softmax over ALL experts, then top-k.  The weights are
+    renormalised to sum to one only where the architecture says so
+    (`moe_norm_topk`: mixtral yes, olmoe no)."""
     router_logits = (x.astype(jnp.float32)) @ blk["router"].astype(jnp.float32)  # [T, E]
     probs = jax.nn.softmax(router_logits, axis=-1)
     top_w, top_idx = jax.lax.top_k(probs, cfg.n_experts_per_tok)  # [T, k]
-    top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+    if cfg.moe_norm_topk:
+        top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
     one_hot = jax.nn.one_hot(top_idx, cfg.n_experts, dtype=probs.dtype)  # [T,k,E]
     # Load-balancing aux loss (switch-style): E * sum_e f_e * P_e.
     load = jnp.mean(one_hot.sum(axis=1), axis=0)  # fraction routed per expert
@@ -235,7 +253,7 @@ def _moe_route(x: jax.Array, blk: Params, cfg: ModelConfig):
     return top_w, top_idx, one_hot, aux
 
 
-def _mlp_moe_dense(h: jax.Array, blk: Params, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
+def _experts_dense(x, top_w, top_idx, one_hot, blk: Params, cfg: ModelConfig):
     """Numerics-oracle MoE: full expert compute + weight masking.
 
     Every token runs through a dense einsum over ALL experts, then results
@@ -243,19 +261,19 @@ def _mlp_moe_dense(h: jax.Array, blk: Params, cfg: ModelConfig) -> Tuple[jax.Arr
     real dispatch, but perfectly static and exactly equal to un-dropped
     top-k routing.  Reference semantics: realhf/impl/model/modules/moe/.
     """
-    b, s, d = h.shape
-    x = h.reshape(-1, d)  # [T, D]
-    top_w, _, one_hot, aux = _moe_route(x, blk, cfg)
-    comb = jnp.einsum("tk,tke->te", top_w, one_hot)  # [T, E]
-    # All-expert compute: [E, T, F] einsums.
-    gate = jax.nn.silu(jnp.einsum("td,edf->etf", x, blk["wg"]))
-    up = jnp.einsum("td,edf->etf", x, blk["wu"])
-    expert_out = jnp.einsum("etf,efd->etd", gate * up, blk["wd"])  # [E,T,D]
-    out = jnp.einsum("te,etd->td", comb.astype(expert_out.dtype), expert_out)
-    return out.reshape(b, s, d), aux
+    with jax.named_scope("experts"):
+        # All-expert compute: [E, T, F] einsums.
+        gate = jax.nn.silu(jnp.einsum("td,edf->etf", x, blk["wg"]))
+        up = jnp.einsum("td,edf->etf", x, blk["wu"])
+        expert_out = jnp.einsum("etf,efd->etd", gate * up, blk["wd"])  # [E,T,D]
+    with jax.named_scope("combine"):
+        comb = jnp.einsum("tk,tke->te", top_w, one_hot)  # [T, E]
+        return jnp.einsum(
+            "te,etd->td", comb.astype(expert_out.dtype), expert_out
+        )
 
 
-def _mlp_moe_topk(h: jax.Array, blk: Params, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
+def _experts_topk(x, top_w, top_idx, one_hot, blk: Params, cfg: ModelConfig):
     """Capacity-based top-k dispatch (GShard-style): expert matmuls run on
     [E, C, D] gathered slots, C = ceil(T*k/E * capacity_factor), so FLOPs
     scale with top-k rather than E.  First-choice assignments claim
@@ -267,35 +285,33 @@ def _mlp_moe_topk(h: jax.Array, blk: Params, cfg: ModelConfig) -> Tuple[jax.Arra
     """
     import math
 
-    b, s, d = h.shape
-    x = h.reshape(-1, d)  # [T, D]
     T = x.shape[0]
     E, k = cfg.n_experts, cfg.n_experts_per_tok
-    top_w, _, one_hot, aux = _moe_route(x, blk, cfg)
     cap = max(int(math.ceil(T * k / E * cfg.moe_capacity_factor)), 1)
 
-    # Queue position of each (choice slot, token) in its expert, choice-
-    # slot-major so first choices win capacity.
-    sel = one_hot.transpose(1, 0, 2).reshape(k * T, E)  # [k*T, E]
-    pos = jnp.cumsum(sel, axis=0) - sel  # position BEFORE this entry
-    keep = sel * (pos < cap)
-    slot = jax.nn.one_hot(pos.astype(jnp.int32), cap, dtype=x.dtype)  # [kT,E,C]
-    disp_k = keep[..., None] * slot  # [k*T, E, C]
-    disp = disp_k.reshape(k, T, E, cap)
-    dispatch = disp.sum(axis=0)  # [T, E, C] 0/1
-    combine = jnp.einsum(
-        "tk,ktec->tec", top_w.astype(x.dtype), disp.astype(x.dtype)
-    )  # [T, E, C]
+    with jax.named_scope("dispatch"):
+        # Queue position of each (choice slot, token) in its expert,
+        # choice-slot-major so first choices win capacity.
+        sel = one_hot.transpose(1, 0, 2).reshape(k * T, E)  # [k*T, E]
+        pos = jnp.cumsum(sel, axis=0) - sel  # position BEFORE this entry
+        keep = sel * (pos < cap)
+        slot = jax.nn.one_hot(pos.astype(jnp.int32), cap, dtype=x.dtype)  # [kT,E,C]
+        disp_k = keep[..., None] * slot  # [k*T, E, C]
+        disp = disp_k.reshape(k, T, E, cap)
+        dispatch = disp.sum(axis=0)  # [T, E, C] 0/1
+        combine = jnp.einsum(
+            "tk,ktec->tec", top_w.astype(x.dtype), disp.astype(x.dtype)
+        )  # [T, E, C]
+        xe = jnp.einsum("tec,td->ecd", dispatch.astype(x.dtype), x)  # [E, C, D]
+    with jax.named_scope("experts"):
+        gate = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xe, blk["wg"]))
+        up = jnp.einsum("ecd,edf->ecf", xe, blk["wu"])
+        ye = jnp.einsum("ecf,efd->ecd", gate * up, blk["wd"])  # [E, C, D]
+    with jax.named_scope("combine"):
+        return jnp.einsum("tec,ecd->td", combine, ye)
 
-    xe = jnp.einsum("tec,td->ecd", dispatch.astype(x.dtype), x)  # [E, C, D]
-    gate = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xe, blk["wg"]))
-    up = jnp.einsum("ecd,edf->ecf", xe, blk["wu"])
-    ye = jnp.einsum("ecf,efd->ecd", gate * up, blk["wd"])  # [E, C, D]
-    out = jnp.einsum("tec,ecd->td", combine, ye)
-    return out.reshape(b, s, d), aux
 
-
-def _mlp_moe_grouped(h: jax.Array, blk: Params, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
+def _experts_grouped(x, top_w, top_idx, one_hot, blk: Params, cfg: ModelConfig):
     """Dropless grouped-GEMM dispatch (the default): tokens sorted by
     expert, expert matmuls ride `jax.lax.ragged_dot` — XLA:TPU's native
     megablox-style ragged kernel, which tiles each expert's contiguous
@@ -316,31 +332,58 @@ def _mlp_moe_grouped(h: jax.Array, blk: Params, cfg: ModelConfig) -> Tuple[jax.A
     gathering, the right trade below ~100B total expert bytes.  True
     token all-to-all EP stays on `moe_dispatch="topk"`.
     """
-    b, s, d = h.shape
-    x = h.reshape(-1, d)  # [T, D]
-    T = x.shape[0]
     k = cfg.n_experts_per_tok
-    top_w, top_idx, one_hot, aux = _moe_route(x, blk, cfg)
-    flat_e = top_idx.reshape(-1)  # [T*k], token-major
-    order = jnp.argsort(flat_e, stable=True)
-    group_sizes = jnp.sum(one_hot, axis=(0, 1)).astype(jnp.int32)  # [E]
-    tok_of = order // k
-    xs = x[tok_of]  # [T*k, D] sorted by expert
-    gate = jax.nn.silu(jax.lax.ragged_dot(xs, blk["wg"], group_sizes))
-    up = jax.lax.ragged_dot(xs, blk["wu"], group_sizes)
-    ys = jax.lax.ragged_dot(gate * up, blk["wd"], group_sizes)  # [T*k, D]
-    w_sorted = top_w.reshape(-1)[order].astype(ys.dtype)
-    out = jnp.zeros_like(x).at[tok_of].add(ys * w_sorted[:, None])
-    return out.reshape(b, s, d), aux
+    with jax.named_scope("dispatch"):
+        flat_e = top_idx.reshape(-1)  # [T*k], token-major
+        order = jnp.argsort(flat_e, stable=True)
+        group_sizes = jnp.sum(one_hot, axis=(0, 1)).astype(jnp.int32)  # [E]
+        tok_of = order // k
+        xs = x[tok_of]  # [T*k, D] sorted by expert
+    with jax.named_scope("experts"):
+        gate = jax.nn.silu(jax.lax.ragged_dot(xs, blk["wg"], group_sizes))
+        up = jax.lax.ragged_dot(xs, blk["wu"], group_sizes)
+        ys = jax.lax.ragged_dot(gate * up, blk["wd"], group_sizes)  # [T*k, D]
+    with jax.named_scope("combine"):
+        w_sorted = top_w.reshape(-1)[order].astype(ys.dtype)
+        return jnp.zeros_like(x).at[tok_of].add(ys * w_sorted[:, None])
+
+
+_MOE_EXPERTS = {
+    "dense": _experts_dense,
+    "grouped": _experts_grouped,
+    "topk": _experts_topk,
+}
 
 
 @jax.named_scope("layer/mlp")
-def _mlp_moe(h: jax.Array, blk: Params, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
-    if cfg.moe_dispatch == "dense":
-        return _mlp_moe_dense(h, blk, cfg)
-    if cfg.moe_dispatch == "grouped":
-        return _mlp_moe_grouped(h, blk, cfg)
-    return _mlp_moe_topk(h, blk, cfg)
+def _mlp_moe(
+    h: jax.Array,
+    blk: Params,
+    cfg: ModelConfig,
+    valid: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """MoE MLP -> (out [B,S,D], aux loss, rows per expert [E] int32).
+
+    One routing (`_moe_route`) feeds whichever `moe_dispatch` computes the
+    experts.  The nested scopes (`layer/mlp/router`, `/dispatch`,
+    `/experts`, `/combine`) split the device time of `layer/mlp` into what
+    sparsity costs and the matmuls themselves (PERF.md §3).  The third
+    result counts the (token, choice) rows each expert received — over the
+    rows `valid` marks ([B,S] bool; all rows when None) — for the
+    generator's and the trainer's load counters."""
+    b, s, d = h.shape
+    x = h.reshape(-1, d)  # [T, D]
+    with jax.named_scope("router"):
+        top_w, top_idx, one_hot, aux = _moe_route(x, blk, cfg)
+        if valid is None:  # the grouped dispatch's group sizes: one reduction
+            counts = jnp.sum(one_hot, axis=(0, 1))
+        else:
+            counts = jnp.einsum(
+                "tke,t->e", one_hot, valid.reshape(-1).astype(one_hot.dtype)
+            )
+        counts = jax.lax.stop_gradient(counts).astype(jnp.int32)
+    out = _MOE_EXPERTS[cfg.moe_dispatch](x, top_w, top_idx, one_hot, blk, cfg)
+    return out.reshape(b, s, d), aux, counts
 
 
 def _block_forward(
@@ -354,7 +397,9 @@ def _block_forward(
     cp_mesh=None,
     cp_manual: "Optional[Tuple[str, int]]" = None,
     cp_zigzag: bool = False,
-) -> Tuple[jax.Array, jax.Array]:
+) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
+    """One block over packed rows -> (y, MoE aux loss, rows per expert over
+    the real tokens [E] int32; None for a dense MLP)."""
     b, s, d = x.shape
     h = _norm(x, blk["ln1"], blk.get("ln1_b"), cfg)
     q, k, v = _block_kv(h, blk, cfg, cos, sin)
@@ -405,11 +450,12 @@ def _block_forward(
     x = x + attn_out
     h2 = _norm(x, blk["ln2"], blk.get("ln2_b"), cfg)
     if cfg.is_moe:
-        mlp_out, aux = _mlp_moe(h2, blk, cfg)
+        mlp_out, aux, counts = _mlp_moe(h2, blk, cfg, valid=segment_ids > 0)
     else:
         mlp_out, aux = _mlp_dense(h2, blk, cfg), jnp.zeros((), jnp.float32)
+        counts = None
     mlp_out = checkpoint_name(mlp_out, "mlp_out")
-    return x + mlp_out, aux
+    return x + mlp_out, aux, counts
 
 
 _ZIGZAG_SNAPSHOT: "Optional[bool]" = None
@@ -439,7 +485,9 @@ def _backbone(
     cp_mesh=None,
     pp_mesh=None,
     pp_microbatches: int = 4,
-) -> Tuple[jax.Array, jax.Array]:
+) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
+    """-> (final-normed hidden states, summed MoE aux loss, per-layer rows
+    per expert [L, E] int32 — None for dense models and under PP)."""
     x = _embed(params, cfg, tokens, positions)
     cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
 
@@ -468,7 +516,7 @@ def _backbone(
             cp=cp_mesh is not None,
         )
         x = _final_norm(params, cfg, x)
-        return x, aux
+        return x, aux, None
 
     # Zigzag ring layout: permute the token order ONCE for the whole
     # layer stack (every other op is per-token; attention sees original
@@ -497,11 +545,11 @@ def _backbone(
             )
 
     def body(carry, blk):
-        y, aux = _block_forward(
+        y, aux, counts = _block_forward(
             carry, blk, cfg, segment_ids, cos, sin, use_flash, cp_mesh,
             cp_zigzag=zz_inv is not None,
         )
-        return y, aux
+        return y, (aux, counts)
 
     # Remat policy per scanned layer (HBM vs recompute-FLOPs tradeoff):
     #   "full"/True — save nothing, recompute the whole layer in backward
@@ -537,11 +585,11 @@ def _backbone(
         )
     elif remat not in (False, None, "none"):
         raise ValueError(f"unknown remat policy {remat!r}")
-    x, auxes = jax.lax.scan(body, x, params["blocks"])
+    x, (auxes, counts) = jax.lax.scan(body, x, params["blocks"])
     x = _final_norm(params, cfg, x)
     if zz_inv is not None:
         x = jnp.take(x, zz_inv, axis=1)
-    return x, jnp.sum(auxes)
+    return x, jnp.sum(auxes), counts
 
 
 @jax.named_scope("head_logprob")
@@ -596,17 +644,21 @@ def hidden_states(
     cp_mesh=None,
     pp_mesh=None,
     pp_microbatches: int = 4,
-) -> Tuple[jax.Array, jax.Array]:
+    with_moe_counts: bool = False,
+) -> Tuple[jax.Array, ...]:
     """Backbone only: final-layernormed hidden states [B, S, D] (+ MoE aux
     loss), WITHOUT the LM head.  Lets engines fuse the head into a chunked
     loss (ops/functional.fused_next_token_logprobs) instead of materializing
-    [B, S, V] logits."""
+    [B, S, V] logits.  `with_moe_counts` adds a third result: the real
+    tokens' rows per expert of every layer, [L, E] int32 (None for dense
+    models and under PP) — the trainer's load counter."""
     if positions is None:
         positions = positions_from_segments(segment_ids)
-    return _backbone(
+    x, aux, counts = _backbone(
         params, cfg, tokens, segment_ids, positions, remat, use_flash,
         cp_mesh, pp_mesh, pp_microbatches,
     )
+    return (x, aux, counts) if with_moe_counts else (x, aux)
 
 
 def head_weights(params: Params, cfg: ModelConfig) -> jax.Array:
@@ -648,7 +700,7 @@ def forward_with_aux(
 ) -> Tuple[jax.Array, jax.Array]:
     if positions is None:
         positions = positions_from_segments(segment_ids)
-    x, aux = _backbone(
+    x, aux, _ = _backbone(
         params, cfg, tokens, segment_ids, positions, remat, use_flash,
         cp_mesh, pp_mesh, pp_microbatches,
     )
@@ -767,6 +819,9 @@ def _block_kv(
     v = h @ blk["wv"]
     if cfg.qkv_bias:
         q, k, v = q + blk["bq"], k + blk["bk"], v + blk["bv"]
+    if cfg.qk_norm:  # olmoe: over the WHOLE projection, not per head
+        q = rms_norm(q, blk["q_norm"], cfg.rms_norm_eps)
+        k = rms_norm(k, blk["k_norm"], cfg.rms_norm_eps)
     q = q.reshape(b, s, cfg.n_q_heads, cfg.head_dim)
     k = k.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
     v = v.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
@@ -873,12 +928,15 @@ def decode_step(
     cache: KVCache,
     slot: jax.Array,  # scalar int32 — cache slot written for ALL rows
     valid_from: jax.Array,  # [B] int32 — first valid cache slot per row
-) -> Tuple[jax.Array, KVCache]:
+    with_moe_counts: bool = False,
+) -> Tuple[jax.Array, ...]:
     """One decode step: write the new token's k/v at cache slot `slot`
     (shared by every row — the right-aligned prompt layout makes the write a
     single `dynamic_update_slice`, not a per-row scatter), attend over the
     live window `[valid_from, slot]`, return fp32 logits [B, V] and the
-    updated cache.
+    updated cache — and, `with_moe_counts`, the step's rows per expert of
+    every layer ([L, E] int32; MoE models only), which the generator's
+    counters reduce inside its decode loop.
 
     The cache rides the layer scan as CARRY (updated in place by XLA), so
     per-token HBM traffic is one (B, n_kv, d) write + one window read per
@@ -908,14 +966,19 @@ def decode_step(
         ao = _attn_out(attn.reshape(b, 1, cfg.q_dim), blk, cfg)
         y = y + ao
         h2 = _norm(y, blk["ln2"], blk.get("ln2_b"), cfg)
-        y = y + (_mlp_moe(h2, blk, cfg)[0] if cfg.is_moe else _mlp_dense(h2, blk, cfg))
-        return (y, kc, vc, li + 1), None
+        if cfg.is_moe:
+            mlp_out, _, counts = _mlp_moe(h2, blk, cfg)
+        else:
+            mlp_out, counts = _mlp_dense(h2, blk, cfg), None
+        return (y + mlp_out, kc, vc, li + 1), counts
 
-    (x, kc, vc, _), _ = jax.lax.scan(
+    (x, kc, vc, _), counts = jax.lax.scan(
         body, (x, cache.k, cache.v, jnp.int32(0)), params["blocks"]
     )
     x = _final_norm(params, cfg, x)
     logits = _head(params, cfg, x)[:, 0]  # [B, V]
+    if with_moe_counts:
+        return logits, KVCache(k=kc, v=vc), counts
     return logits, KVCache(k=kc, v=vc)
 
 

@@ -42,7 +42,7 @@ def _stage_scan(blocks_local, cfg, use_flash, cp_manual, x, seg, cos, sin):
     from areal_tpu.models.transformer import _block_forward
 
     def body(carry, blk):
-        y, aux = _block_forward(
+        y, aux, _ = _block_forward(
             carry, blk, cfg, seg, cos, sin, use_flash, cp_manual=cp_manual
         )
         return y, aux

@@ -39,6 +39,10 @@ _BLOCK_RULES: Dict[str, P] = {
     "ln2": P(PIPE_AXIS, None),
     "ln1_b": P(PIPE_AXIS, None),
     "ln2_b": P(PIPE_AXIS, None),
+    # olmoe QK-norm weights over the whole projection: layer-stacked vectors
+    # like ln1 (replicated — the norm's mean runs over every head).
+    "q_norm": P(PIPE_AXIS, None),
+    "k_norm": P(PIPE_AXIS, None),
     "bo": P(PIPE_AXIS, None),
     "bproj": P(PIPE_AXIS, None),
     "bfc": P(PIPE_AXIS, MODEL_AXIS),  # matches wg's model-sharded output

@@ -1,0 +1,187 @@
+"""The benchmark harness inside tier-1 (`tests/` is all tier-1 collects;
+`benchmark/tests/` is the harness's own suite): the any-block cases of
+`benchmark/tests/test_any_block.py` by import, BENCHMARK.json against the
+files it names, the new MoE readers on a made-up reduction, and the CPU
+rehearsal of the OLMoE cell through its config file's `toy` group."""
+
+import json
+import os
+import subprocess
+import sys
+import types
+
+import pytest
+
+from benchmark import files
+from benchmark.metrics import (
+    _moe, moe_decode_mlp_ms, moe_decode_mlp_roofline, moe_experts_touched,
+    moe_route_share, moe_train_mlp_mfu,
+)
+from benchmark.tests.test_any_block import *  # noqa: F401,F403 — the cases
+from benchmark.tests.test_any_block import OLMOE
+
+SPEC = files.benchmark_json()
+CELLS = [w["name"] for w in SPEC["workloads"]]
+
+
+def test_every_name_in_benchmark_json_is_a_cell_and_its_files_resolve():
+    configs = {c["name"]: c for c in SPEC["configs"]}
+    for cell in SPEC["workloads"]:
+        cell_file, config, traffic = files.load_cell(cell["name"])
+        assert cell_file["config"] == cell["config"] in configs
+        assert cell_file["traffic"] == cell["traffic"]
+        assert cell_file["chips"] == cell["chips"] == (
+            config["benchmark"]["layout"]["chips"])
+        entry = configs[cell["config"]]
+        assert entry["file"] == f"benchmark/configs/{cell['config']}.json"
+        assert entry["source"] == config["benchmark"]["source"]
+        assert sorted(entry["reduced"]) == sorted(config["benchmark"]["reduced"])
+        files.load_module("references", config["benchmark"]["reference"])
+        files.load_module("traffic", traffic["generator"])
+    assert {c["config"] for c in SPEC["workloads"]} == set(configs)
+    metrics = SPEC["end_to_end"] + SPEC["per_layer"]
+    end_to_end = {m["name"] for m in SPEC["end_to_end"]}
+    for m in metrics:
+        assert set(m.get("workloads", [])) <= set(CELLS), m["name"]
+        assert hasattr(files.load_module("metrics", m["name"]), "read")
+        if "moves" in m:  # reported wherever this metric is
+            target = next(e for e in SPEC["end_to_end"] if e["name"] == m["moves"])
+            assert m["moves"] in end_to_end
+            assert set(m.get("workloads", CELLS)) <= set(
+                target.get("workloads", CELLS)), m["name"]
+    assert len({m["name"] for m in metrics}) == len(metrics)
+
+
+def test_the_olmoe_config_file_holds_the_published_keys():
+    config = files.load_json("configs", "olmoe-1b-7b-0125-l3.json")
+    published = {  # catalog row OLMoE-1B-7B-0125-Instruct
+        "attention_bias": False, "clip_qkv": None, "hidden_act": "silu",
+        "hidden_size": 2048, "intermediate_size": 1024,
+        "max_position_embeddings": 4096, "model_type": "olmoe",
+        "norm_topk_prob": False, "num_attention_heads": 16,
+        "num_experts": 64, "num_experts_per_tok": 8, "num_hidden_layers": 16,
+        "num_key_value_heads": 16, "rms_norm_eps": 1e-05,
+        "rope_scaling": None, "rope_theta": 10000,
+        "tie_word_embeddings": False, "vocab_size": 50304,
+    }
+    changed = {k for k, v in published.items() if config[k] != v}
+    assert changed == set(config["benchmark"]["reduced"]) == {"num_hidden_layers"}
+    assert set(config["benchmark"]["assumed"]) == {
+        "head_dim", "expert_width", "router_aux_loss_coef"}
+    from benchmark import peaks, run
+
+    cfg = run.model_config(config)
+    assert (cfg.n_layers, cfg.qk_norm, cfg.moe_norm_topk) == (3, True, False)
+    assert peaks.mlp_params(cfg) == peaks.mlp_params(OLMOE)
+    total = 3 * (peaks.attn_params(cfg) + 64 * peaks.expert_params(cfg)
+                 + 2048 * 64 + 2 * 2048 + 2 * 2048) + 2 * 2048 * 50304 + 2048
+    assert round(total / 1e9, 3) == 1.465
+
+
+def reduced(ops, scopes):
+    return types.SimpleNamespace(
+        trace={"op_seconds_scoped": ops, "scope_seconds": scopes,
+               "traced_steps": 2, "busy_s": 100.0},
+        model_cfg=OLMOE, chips=1,
+        peaks={"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
+        cell={"route": "static"},
+        steps=[{"seq_lens": [1152] * 8, "prompt_lens": [128] * 8,
+                "gen": {"lanes_dispatched": 0},
+                "pool": {"moe_experts_touched": 40.0}}],
+    )
+
+
+def phases(fwd=0.0, recompute=0.0, bwd=0.0):
+    return {"fwd": fwd, "recompute": recompute, "bwd": bwd}
+
+
+def test_the_moe_readers_find_the_unscoped_ragged_kernels_by_their_rows():
+    """XLA's ragged-dot kernels carry no scope; the scoped activation
+    multiply beside them says which program has how many rows."""
+    call = "custom-call:tpu_custom_call"
+    ops = {
+        "mul.2 fusion bf16[64,1024] @gen/decode_step/layer/mlp/experts:fwd": 0.2,
+        "mul.9 fusion bf16[16384,1024] @gen/prefill/layer/mlp/experts:fwd": 0.1,
+        "mul.7 fusion bf16[65536,1024] @train/grad/layer/mlp/experts:fwd": 0.3,
+        "mul.8 fusion bf16[65536,1024] @train/grad/layer/mlp/experts:recompute": 0.3,
+        f"ragged-dot-none.1 {call} bf16[64,1024]": 4.0,
+        f"ragged-dot-none.2 {call} bf16[64,2048]": 2.0,
+        f"ragged-dot-none.3 {call} bf16[16384,1024]": 0.5,
+        f"ragged-dot-none.4 {call} bf16[65536,1024]": 3.0,
+        f"ragged-dot-none.6 {call} bf16[64,1024,2048]": 1.0,  # dW: train
+        f"ragged-dot-metadata {call} (s32[65], s32[64], s32[64], s32[1])": 0.01,
+        "fusion.1 fusion f32[8,64] @gen/decode_step/layer/mlp/router:fwd": 1.0,
+        "sort.1 sort s32[64] @gen/decode_step/layer/mlp/dispatch:fwd": 0.5,
+        "scatter.1 fusion bf16[8,2048] @gen/decode_step/layer/mlp/combine:fwd": 0.3,
+    }
+    scopes = {
+        "gen/decode_step/layer/mlp/experts": phases(0.2),
+        "gen/decode_step/layer/mlp/router": phases(1.0),
+        "gen/decode_step/layer/mlp/dispatch": phases(0.5),
+        "gen/decode_step/layer/mlp/combine": phases(0.3),
+        "gen/prefill/layer/mlp/experts": phases(0.1),
+        "train/grad/layer/mlp/experts": phases(0.3, 0.3),
+    }
+    run = reduced(ops, scopes)
+    assert _moe.ragged_seconds(run, _moe.DECODE) == pytest.approx(3.0)
+    assert _moe.ragged_seconds(run, _moe.PREFILL) == pytest.approx(0.25)
+    assert _moe.ragged_seconds(run, _moe.TRAIN) == pytest.approx(2.0)
+    # 2.0 s scoped + 6.0 s of kernels over two steps of 1,024 iterations.
+    assert moe_decode_mlp_ms.read(run) == pytest.approx(1e3 * 4.0 / 1024)
+    assert moe_route_share.read(run) == pytest.approx(100 * 1.8 / 8.0)
+    assert moe_experts_touched.read(run) == 40.0
+    from benchmark import peaks
+
+    floor = 16 * peaks.moe_layer_bytes(OLMOE, 8, experts_touched=40.0) / 819e9
+    assert moe_decode_mlp_roofline.read(run) == pytest.approx(
+        100 * floor / (4.0 / 1024))
+    flops = 3 * 16 * peaks.moe_layer_flops(OLMOE, 8 * 1152)
+    assert moe_train_mlp_mfu.read(run) == pytest.approx(
+        100 * flops / (0.3 + 2.0) / 197e12)
+    # Two programs with one row count cannot be told apart: say nothing.
+    clash = dict(ops)
+    clash["mul.5 fusion bf16[64,1024] @gen/prefill/layer/mlp/experts:fwd"] = 0.1
+    assert _moe.ragged_seconds(reduced(clash, scopes), _moe.DECODE) is None
+
+
+def test_the_moe_readers_say_nothing_for_a_dense_model_or_no_trace():
+    dense = types.SimpleNamespace(
+        trace={"op_seconds_scoped": {
+            "fusion.356 fusion bf16[8,8960] @gen/decode_step/layer/mlp:fwd": 2.0},
+            "scope_seconds": {"gen/decode_step/layer/mlp": phases(2.0)},
+            "traced_steps": 2, "busy_s": 10.0},
+        model_cfg=types.SimpleNamespace(n_experts=0), chips=1, peaks={},
+        cell={"route": "static"},
+        steps=[{"seq_lens": [1152] * 8, "prompt_lens": [128] * 8,
+                "gen": {"lanes_dispatched": 0}, "pool": {}}],
+    )
+    untraced = types.SimpleNamespace(
+        trace=None, model_cfg=OLMOE, chips=1, peaks=None, steps=dense.steps,
+        cell=dense.cell)
+    for run in (dense, untraced):
+        for reader in (moe_decode_mlp_ms, moe_decode_mlp_roofline,
+                       moe_experts_touched, moe_route_share,
+                       moe_train_mlp_mfu):
+            assert reader.read(run) is None, reader.__name__
+
+
+def test_cpu_rehearsal_of_the_olmoe_cell_is_correct():
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=files.ROOT)
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run(
+        [sys.executable, "-m", "benchmark.run", "--workload",
+         "olmoe-decode-tail", "--seed", "2200000011", "--seconds", "1",
+         "--trace", "0", "--cpu-rehearsal"],
+        cwd=files.ROOT, env=env, capture_output=True, text=True, timeout=600,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert proc.stdout.strip() == ""  # platform=cpu: no result line
+    lines = proc.stderr.splitlines()
+    out = json.loads(
+        [l for l in lines if "would print: " in l][-1].split("would print: ")[1])
+    assert out["correct"] is True and out["failed"] == 0 < out["attempted"]
+    assert {"gen_tokens_per_s", "train_tokens_per_s", "samples_per_s",
+            "setup_s"} <= set(out["metrics"])
+    check = [l for l in lines if "weight check: " in l][-1]
+    assert "'ok': True" in check and "'leaves': 15" in check, check
+    assert any("olmoe reference" in l and "router_flips" in l for l in lines)

@@ -383,6 +383,25 @@ def _tiny_hf_model(family):
                 router_aux_loss_coef=0.0,
             )
         )
+    if family == "olmoe":
+        # MHA, QK-norm over the whole projection, top-k weights as they
+        # are; HF initialises norm weights to one, so scatter them first.
+        model = transformers.OlmoeForCausalLM(
+            transformers.OlmoeConfig(
+                **{**llama_kw, "num_key_value_heads": 4,
+                   "intermediate_size": 32, "rms_norm_eps": 1e-5},
+                num_experts=8,
+                num_experts_per_tok=2,
+                norm_topk_prob=False,
+            )
+        )
+        import torch
+
+        with torch.no_grad():
+            for name, p in model.named_parameters():
+                if "q_norm" in name or "k_norm" in name:
+                    p.add_(0.3 * torch.randn_like(p))
+        return model
     if family == "gpt2":
         return transformers.GPT2LMHeadModel(
             transformers.GPT2Config(
@@ -397,7 +416,8 @@ def _tiny_hf_model(family):
 
 class TestHFParity:
     @pytest.mark.parametrize(
-        "family", ["llama", "qwen2", "mistral", "gemma", "mixtral", "gpt2"]
+        "family",
+        ["llama", "qwen2", "mistral", "gemma", "mixtral", "gpt2", "olmoe"],
     )
     def test_forward_matches_transformers(self, family, rng):
         torch = pytest.importorskip("torch")

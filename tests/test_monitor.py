@@ -79,7 +79,8 @@ class TestFlops:
 
     def test_matmul_params_moe_counts_active_experts(self):
         """MoE counts only routed (active) experts at the MoE intermediate
-        width — not the full expert pool, not the dense width."""
+        width — not the full expert pool, not the dense width — plus the
+        router's `hidden x n_experts` matmul every token runs."""
         dense = tiny_config()
         moe = tiny_config(n_experts=8)
         n_mats = 3  # gated mlp
@@ -88,12 +89,28 @@ class TestFlops:
             n_mats * moe.hidden_dim * moe.moe_intermediate_dim
             * moe.n_experts_per_tok
         )
+        router = moe.hidden_dim * moe.n_experts
         got_diff = monitor.matmul_params(moe) - monitor.matmul_params(dense)
-        assert got_diff == moe.n_layers * (moe_mlp - dense_mlp)
-        # Pool size must NOT enter the per-token count.
+        assert got_diff == moe.n_layers * (moe_mlp + router - dense_mlp)
+        # Pool size enters the per-token count through the router ALONE.
         moe_big_pool = tiny_config(n_experts=64)
-        assert monitor.matmul_params(moe_big_pool) == monitor.matmul_params(
+        assert monitor.matmul_params(moe_big_pool) - monitor.matmul_params(
             moe
+        ) == moe.n_layers * moe.hidden_dim * (64 - 8)
+
+    def test_matmul_params_agrees_with_the_benchmark_at_olmoe_sizes(self):
+        """The program's count and the benchmark's (`benchmark/peaks.py`,
+        a copy with causal attention halved) give one number for OLMoE-1B-
+        7B: 8 of 64 experts of width 1,024 and the router, 67.2 M a layer."""
+        from areal_tpu.models.hf.registry import HF_FAMILIES
+        from benchmark import files, peaks
+
+        cfg = HF_FAMILIES["olmoe"].config_from_hf(
+            files.load_json("configs", "olmoe-1b-7b-0125-l3.json")
+        )
+        assert peaks.mlp_params(cfg) == 8 * 3 * 2048 * 1024 + 2048 * 64
+        assert monitor.matmul_params(cfg) == peaks.matmul_params(cfg) == (
+            3 * 67_239_936 + 2048 * 50304
         )
 
     def test_matmul_params_critic_drops_lm_head(self):
