@@ -1994,10 +1994,12 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         while T % self.batch_shard:
             T += 1
         self.serving_lane_budget = T
+        in_place = self._expert_leaves_in_place
         sig = (
             "serving_chunk", n_slots, n_pages, max_pages, chunk_t, W, pbw,
             K, g.spec_ngram, T,
             g.min_new_tokens, g.greedy, g.top_p, g.top_k, g.temperature,
+            in_place,
         )
         if sig in self._gen_fns:
             return self._gen_fns[sig]
@@ -2148,7 +2150,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                 )
                 logits_pk, pool2 = tfm.decode_step_ragged_paged(
                     params, cfg, stream_tok, stream_pos, pool,
-                    page_table, row_of,
+                    page_table, row_of, experts_in_place=in_place,
                 )  # [T, V]
                 # Next-step carry = each granted row's LAST lane logits
                 # (end-of-slice for prefill, post-token for decode);
@@ -3022,16 +3024,26 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         )
         return fn
 
+    @property
+    def _expert_leaves_in_place(self) -> bool:
+        """Whether the decode programs hand the ragged kernels the stacked
+        expert leaves themselves — asked of the placed params, outside
+        the trace (`tfm.expert_leaves_in_place`); False for dense models."""
+        return tfm.expert_leaves_in_place(self.cfg, self.params["blocks"])
+
     def _fold_moe_counters(self) -> None:
         """MoE decode counters into last_pool_stats: per decode step and MoE
         layer, the experts with at least one row and the rows on the
-        fullest expert (means over every step of this generate())."""
+        fullest expert (means over every step of this generate()); and
+        which way the expert weights reached the ragged kernels (1: the
+        parameters' own buffers, 0: the layer scan's slices)."""
         touched, rows_max, steps = self._moe_decode_sums
         if steps:
             self.last_pool_stats.update(
                 moe_experts_touched=touched / steps,
                 moe_rows_per_expert_max=rows_max / steps,
                 moe_decode_steps=int(steps),
+                moe_expert_leaves_in_place=int(self._expert_leaves_in_place),
             )
 
     # -- one fixed-shape chunk --
@@ -3076,9 +3088,10 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             results[(i, rep)] = (toks[r, :gl], logps[r, :gl], no_eos)
 
     def _get_gen_fn(self, b, sp, s_total, g: GenerationHyperparameters):
+        in_place = self._expert_leaves_in_place
         sig = (
             b, sp, s_total, g.max_new_tokens, g.min_new_tokens, g.greedy,
-            g.top_p, g.top_k, g.temperature,
+            g.top_p, g.top_k, g.temperature, in_place,
         )
         if sig in self._gen_fns:
             return self._gen_fns[sig]
@@ -3133,7 +3146,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                 pos = prompt_len + step  # RoPE position per row
                 next_logits, cache, *counts = tfm.decode_step(
                     params, cfg, tok, pos, cache, sp + step, valid_from,
-                    with_moe_counts=cfg.is_moe,
+                    with_moe_counts=cfg.is_moe, experts_in_place=in_place,
                 )
                 if cfg.is_moe:
                     moe = [moe[0] + _moe_step_counters(counts[0])]

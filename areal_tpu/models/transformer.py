@@ -311,11 +311,25 @@ def _experts_topk(x, top_w, top_idx, one_hot, blk: Params, cfg: ModelConfig):
         return jnp.einsum("tec,ecd->td", combine, ye)
 
 
-def _experts_grouped(x, top_w, top_idx, one_hot, blk: Params, cfg: ModelConfig):
+def _experts_grouped(
+    x, top_w, top_idx, one_hot, blk: Params, cfg: ModelConfig, layer=None
+):
     """Dropless grouped-GEMM dispatch (the default): tokens sorted by
     expert, expert matmuls ride `jax.lax.ragged_dot` — XLA:TPU's native
-    megablox-style ragged kernel, which tiles each expert's contiguous
-    row group onto the MXU without materializing per-expert buffers.
+    megablox-style ragged kernel (a Mosaic custom call), which tiles each
+    expert's contiguous row group onto the MXU; its time follows the
+    groups that have rows.  No [E, C, D] token buffers are built.  What
+    the kernel's WEIGHT operand costs depends on the caller: a custom
+    call's operand must be a buffer of its own, so under a layer scan
+    that slices `blk` out of the stacked [L, E, in, out] leaves (train,
+    `forward`, `prefill`) XLA copies the layer's [E, in, out] slice before
+    each kernel — noise beside thousands of rows, but 3 x 268 MB a
+    layer-step at OLMoE's widths when a decode step has 8.  So the decode
+    program passes `layer` (the scan's index) and `blk` = the STACKED
+    leaves: they are viewed as [L*E, in, out] (leading contiguous axes: a
+    bitcast) and the group sizes are zero outside [layer*E, (layer+1)*E),
+    which hands the kernel the parameter's own buffer.  Same rows through
+    the same experts either way (`expert_leaves_in_place`).
 
     Expert FLOPs are exactly 3·T·k·D·F — proportional to TOKENS, where
     the dense oracle pays E/k× that and capacity dispatch pays
@@ -339,6 +353,13 @@ def _experts_grouped(x, top_w, top_idx, one_hot, blk: Params, cfg: ModelConfig):
         group_sizes = jnp.sum(one_hot, axis=(0, 1)).astype(jnp.int32)  # [E]
         tok_of = order // k
         xs = x[tok_of]  # [T*k, D] sorted by expert
+        if layer is not None:  # stacked leaves: this layer's groups of L*E
+            group_sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros((blk["wg"].shape[0] * cfg.n_experts,), jnp.int32),
+                group_sizes,
+                (layer * cfg.n_experts,),
+            )
+            blk = {n: blk[n].reshape(-1, *blk[n].shape[2:]) for n in _EXPERT_LEAVES}
     with jax.named_scope("experts"):
         gate = jax.nn.silu(jax.lax.ragged_dot(xs, blk["wg"], group_sizes))
         up = jax.lax.ragged_dot(xs, blk["wu"], group_sizes)
@@ -346,6 +367,49 @@ def _experts_grouped(x, top_w, top_idx, one_hot, blk: Params, cfg: ModelConfig):
     with jax.named_scope("combine"):
         w_sorted = top_w.reshape(-1)[order].astype(ys.dtype)
         return jnp.zeros_like(x).at[tok_of].add(ys * w_sorted[:, None])
+
+
+_EXPERT_LEAVES = ("wg", "wu", "wd")
+
+
+def expert_leaves_in_place(cfg: ModelConfig, blocks: Params) -> bool:
+    """Whether a decode program can hand `ragged_dot` the stacked expert
+    leaves themselves (`_experts_grouped(layer=...)`): grouped dispatch,
+    and the leaves' layer and expert axes not split over devices — the
+    flat [L*E] view would cross a sharded dimension (`moe_w*` rules with
+    fsdp > 1 or pipe > 1), and GSPMD would gather every layer's experts
+    to build it.  Read from the leaves' own shardings; a leaf that has
+    none to show (a tracer, a numpy array) counts as unsharded, so a
+    caller that jits over sharded params asks BEFORE tracing and passes
+    the answer on (`decode_step(experts_in_place=...)`)."""
+    if not (cfg.is_moe and cfg.moe_dispatch == "grouped"):
+        return False
+    for name in _EXPERT_LEAVES:
+        leaf = blocks[name]
+        sharding = getattr(leaf, "sharding", None)
+        if sharding is not None and (
+            tuple(sharding.shard_shape(leaf.shape)[:2]) != tuple(leaf.shape[:2])
+        ):
+            return False
+    return True
+
+
+def _scan_blocks(
+    cfg: ModelConfig, blocks: Params, experts_in_place: Optional[bool]
+) -> Tuple[Params, Optional[Params]]:
+    """A decode program's split of the stacked block leaves: what its layer
+    scan slices (`xs`), and the expert leaves its body closes over whole
+    (None where they stay in `xs`: dense models, other dispatches, a
+    sharded layer or expert axis).  `experts_in_place` None asks
+    `expert_leaves_in_place`."""
+    if experts_in_place is None:
+        experts_in_place = expert_leaves_in_place(cfg, blocks)
+    if not experts_in_place:
+        return blocks, None
+    return (
+        {n: w for n, w in blocks.items() if n not in _EXPERT_LEAVES},
+        {n: blocks[n] for n in _EXPERT_LEAVES},
+    )
 
 
 _MOE_EXPERTS = {
@@ -361,6 +425,8 @@ def _mlp_moe(
     blk: Params,
     cfg: ModelConfig,
     valid: Optional[jax.Array] = None,
+    stacked: Optional[Params] = None,
+    layer: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """MoE MLP -> (out [B,S,D], aux loss, rows per expert [E] int32).
 
@@ -370,7 +436,10 @@ def _mlp_moe(
     sparsity costs and the matmuls themselves (PERF.md §3).  The third
     result counts the (token, choice) rows each expert received — over the
     rows `valid` marks ([B,S] bool; all rows when None) — for the
-    generator's and the trainer's load counters."""
+    generator's and the trainer's load counters.  `stacked` (decode,
+    grouped dispatch): the expert weights come from these stacked
+    [L, E, in, out] leaves at layer index `layer` instead of from `blk`
+    (`_experts_grouped`); without it `layer` is not read."""
     b, s, d = h.shape
     x = h.reshape(-1, d)  # [T, D]
     with jax.named_scope("router"):
@@ -382,7 +451,10 @@ def _mlp_moe(
                 "tke,t->e", one_hot, valid.reshape(-1).astype(one_hot.dtype)
             )
         counts = jax.lax.stop_gradient(counts).astype(jnp.int32)
-    out = _MOE_EXPERTS[cfg.moe_dispatch](x, top_w, top_idx, one_hot, blk, cfg)
+    if stacked is None:
+        out = _MOE_EXPERTS[cfg.moe_dispatch](x, top_w, top_idx, one_hot, blk, cfg)
+    else:
+        out = _experts_grouped(x, top_w, top_idx, one_hot, stacked, cfg, layer)
     return out.reshape(b, s, d), aux, counts
 
 
@@ -929,6 +1001,7 @@ def decode_step(
     slot: jax.Array,  # scalar int32 — cache slot written for ALL rows
     valid_from: jax.Array,  # [B] int32 — first valid cache slot per row
     with_moe_counts: bool = False,
+    experts_in_place: Optional[bool] = None,
 ) -> Tuple[jax.Array, ...]:
     """One decode step: write the new token's k/v at cache slot `slot`
     (shared by every row — the right-aligned prompt layout makes the write a
@@ -943,11 +1016,23 @@ def decode_step(
     layer instead of a full-cache rewrite (the fix for the one-hot scatter
     this replaces).  Reference semantics: the fused decode step replayed via
     CUDA graphs, realhf/impl/model/nn/real_llm_generate.py:336-368.
+
+    The weights ride the scan as `xs`: the body gets its layer's slice of
+    every stacked leaf, which XLA fuses into the dense matmuls.  A grouped
+    MoE model's expert leaves `wg` / `wu` / `wd` are the exception
+    (`experts_in_place`; None = `expert_leaves_in_place` of these params):
+    the scan does not slice them, the body closes over the stacked
+    [L, E, in, out] leaves and `_experts_grouped` picks the layer by group
+    sizes, because a slice handed to the ragged kernel is a copy of all E
+    experts' weights per kernel and step.  Where the leaves' layer or
+    expert axis is sharded they stay in `xs`.  Dense models trace the
+    program they always did.
     """
     b = tokens.shape[0]
     x = _embed(params, cfg, tokens, positions)[:, None, :]  # [B,1,D]
     cos, sin = rope_cos_sin(positions[:, None], cfg.head_dim, cfg.rope_theta)
     slot = jnp.asarray(slot, jnp.int32)
+    blocks, stacked = _scan_blocks(cfg, params["blocks"], experts_in_place)
 
     def body(carry, blk):
         y, kc, vc, li = carry
@@ -967,13 +1052,13 @@ def decode_step(
         y = y + ao
         h2 = _norm(y, blk["ln2"], blk.get("ln2_b"), cfg)
         if cfg.is_moe:
-            mlp_out, _, counts = _mlp_moe(h2, blk, cfg)
+            mlp_out, _, counts = _mlp_moe(h2, blk, cfg, stacked=stacked, layer=li)
         else:
             mlp_out, counts = _mlp_dense(h2, blk, cfg), None
         return (y + mlp_out, kc, vc, li + 1), counts
 
     (x, kc, vc, _), counts = jax.lax.scan(
-        body, (x, cache.k, cache.v, jnp.int32(0)), params["blocks"]
+        body, (x, cache.k, cache.v, jnp.int32(0)), blocks
     )
     x = _final_norm(params, cfg, x)
     logits = _head(params, cfg, x)[:, 0]  # [B, V]
@@ -1429,6 +1514,7 @@ def decode_step_ragged_paged(
     cache: PagedKVCache,
     page_table: jax.Array,  # [B, max_pages] int32, sentinel = n_pages
     row_of: jax.Array,  # [T] int32 — owning slot per token; >= B = dead lane
+    experts_in_place: Optional[bool] = None,
 ) -> Tuple[jax.Array, PagedKVCache]:
     """The megakernel forward: one packed [T] stream of query lanes with
     per-token windows, instead of a [B, Q] slab with per-row q_lens.
@@ -1445,7 +1531,8 @@ def decode_step_ragged_paged(
     gather).  Dead lanes (row_of >= B, the stream's slack) drop their
     cache writes, emit zero attention, and produce garbage logits the
     caller never reads.  Same pool-in/pool-out single-compilation
-    contract as `decode_step_paged`."""
+    contract as `decode_step_paged`.  A grouped MoE model's expert leaves
+    reach the ragged kernels as in `decode_step` (`experts_in_place`)."""
     t = tokens.shape[0]
     b = page_table.shape[0]
     live = row_of < b
@@ -1477,17 +1564,17 @@ def decode_step_ragged_paged(
         ao = _attn_out(attn.reshape(t, 1, cfg.q_dim), blk, cfg)
         y = y + ao
         h2 = _norm(y, blk["ln2"], blk.get("ln2_b"), cfg)
-        y = y + (
-            _mlp_moe(h2, blk, cfg)[0] if cfg.is_moe else _mlp_dense(h2, blk, cfg)
-        )
+        if cfg.is_moe:
+            y = y + _mlp_moe(h2, blk, cfg, stacked=stacked, layer=li)[0]
+        else:
+            y = y + _mlp_dense(h2, blk, cfg)
         return (y, kc, vc, ksc, vsc, li + 1), None
 
+    blocks, stacked = _scan_blocks(cfg, params["blocks"], experts_in_place)
     ksc0 = cache.k_scale if quant else jnp.zeros((0,), jnp.bfloat16)
     vsc0 = cache.v_scale if quant else jnp.zeros((0,), jnp.bfloat16)
     (x, kc, vc, ksc, vsc, _), _ = jax.lax.scan(
-        body,
-        (x, cache.k, cache.v, ksc0, vsc0, jnp.int32(0)),
-        params["blocks"],
+        body, (x, cache.k, cache.v, ksc0, vsc0, jnp.int32(0)), blocks
     )
     x = _final_norm(params, cfg, x)
     logits = _head(params, cfg, x)[:, 0]  # [T, V]

@@ -420,3 +420,221 @@ def test_generate_and_train_report_the_counters_and_dense_models_none(cfg):
     # 4 rows x top-2 of 8 experts: between 2 and 8 touched, 1 to 4 rows.
     assert 2 <= moe["moe_experts_touched"] <= 8
     assert 1 <= moe["moe_rows_per_expert_max"] <= 4
+
+
+# ------------------------- decode: the expert leaves reach ragged_dot in place
+
+
+def _plain_decode_step(params, cfg, tok, pos, cache, slot, valid_from):
+    """`decode_step` written plainly: a Python loop that slices every leaf
+    at its layer and calls `_mlp_moe` on the slice — what the layer scan
+    does on the sliced path (PR 27 keeps it here as the oracle)."""
+    b = tok.shape[0]
+    x = tfm._embed(params, cfg, tok, pos)[:, None, :]
+    cos, sin = tfm.rope_cos_sin(pos[:, None], cfg.head_dim, cfg.rope_theta)
+    kc, vc, counts = cache.k, cache.v, []
+    for li in range(cfg.n_layers):
+        blk = jax.tree.map(lambda a: a[li], params["blocks"])
+        h = tfm._norm(x, blk["ln1"], blk.get("ln1_b"), cfg)
+        q, k, v = tfm._block_kv(h, blk, cfg, cos, sin)
+        kc = kc.at[li, :, slot].set(k[:, 0].astype(kc.dtype))
+        vc = vc.at[li, :, slot].set(v[:, 0].astype(vc.dtype))
+        attn = tfm.decode_attention(q, kc[li], vc[li], valid_from, slot + 1)
+        x = x + tfm._attn_out(attn.reshape(b, 1, cfg.q_dim), blk, cfg)
+        h2 = tfm._norm(x, blk["ln2"], blk.get("ln2_b"), cfg)
+        out, _, c = tfm._mlp_moe(h2, blk, cfg)
+        x, counts = x + out, counts + [c]
+    logits = tfm._head(params, cfg, tfm._final_norm(params, cfg, x))[:, 0]
+    return logits, tfm.KVCache(k=kc, v=vc), jnp.stack(counts)
+
+
+@pytest.mark.parametrize("n_layers", [1, 3])
+def test_decode_in_place_equals_the_per_layer_formulation(cfg, n_layers):
+    """Logits, cache and [L, E] counts of `decode_step` with the stacked
+    expert leaves handed to `ragged_dot` whole (zero group sizes outside
+    the layer) against slicing each layer: a step in which ONE expert pair
+    gets every row (identical rows), then steps in which experts of a
+    layer get no row."""
+    cfg = dataclasses.replace(cfg, n_layers=n_layers)
+    params = tfm.init_params(cfg, jax.random.PRNGKey(11))
+    assert tfm.expert_leaves_in_place(cfg, params["blocks"])
+    b, k, steps = 4, cfg.n_experts_per_tok, 3
+    rng = np.random.default_rng(3)
+    cache = plain = tfm.init_kv_cache(cfg, b, steps, dtype=jnp.float32)
+    valid_from = jnp.zeros((b,), jnp.int32)
+    saw_empty = False
+    for step in range(steps):
+        tok = (np.full(b, 7) if step == 0
+               else rng.integers(0, cfg.vocab_size, size=b))
+        tok = jnp.asarray(tok, jnp.int32)
+        pos = jnp.full((b,), step, jnp.int32)
+        logits, cache, counts = tfm.decode_step(
+            params, cfg, tok, pos, cache, jnp.int32(step), valid_from,
+            with_moe_counts=True)
+        want_logits, plain, want_counts = _plain_decode_step(
+            params, cfg, tok, pos, plain, step, valid_from)
+        counts = np.asarray(counts)
+        assert counts.shape == (n_layers, cfg.n_experts)
+        assert (counts == np.asarray(want_counts)).all()
+        assert (counts.sum(axis=1) == b * k).all()
+        if step == 0:  # identical rows: k experts hold all b rows each
+            assert (np.sort(counts, axis=1)[:, -k:] == b).all()
+        else:
+            spread = ((counts > 0).sum(axis=1) > k).any()  # rows differ
+            saw_empty |= bool(spread and (counts == 0).any())
+        np.testing.assert_allclose(
+            np.asarray(logits), np.asarray(want_logits), rtol=1e-5, atol=1e-5)
+        for got, want in ((cache.k, plain.k), (cache.v, plain.v)):
+            np.testing.assert_allclose(
+                np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+    assert saw_empty
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield jaxpr, eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def _decode_jaxpr(cfg, params, b=4, in_place=None):
+    cache = tfm.init_kv_cache(cfg, b, 8, dtype=jnp.float32)
+    z = jnp.zeros((b,), jnp.int32)
+    return jax.make_jaxpr(
+        lambda p, c: tfm.decode_step(
+            p, cfg, z, z, c, jnp.int32(0), z, with_moe_counts=cfg.is_moe,
+            experts_in_place=in_place)
+    )(params, cache)
+
+
+def _expert_slices(closed, cfg):
+    """Values of one layer's expert-leaf shape [E, in, out] (or [1, E, in,
+    out]) that a traced decode step makes by slicing: the scan's per-layer
+    `xs` and any (dynamic_)slice / squeeze / gather result."""
+    d, f, e = cfg.hidden_dim, cfg.intermediate_dim, cfg.n_experts
+    shapes = {(e, d, f), (e, f, d), (1, e, d, f), (1, e, f, d)}
+    found = []
+    for _, eqn in _eqns(closed.jaxpr):
+        if eqn.primitive.name == "scan":
+            n_xs = len(eqn.invars) - eqn.params["num_consts"] - eqn.params["num_carry"]
+            found += [v.aval.shape for v in eqn.invars[len(eqn.invars) - n_xs:]
+                      if v.aval.shape[1:] in shapes]
+        elif eqn.primitive.name in ("dynamic_slice", "slice", "squeeze", "gather"):
+            found += [v.aval.shape for v in eqn.outvars if v.aval.shape in shapes]
+    return found
+
+
+def test_traced_decode_step_slices_no_expert_leaf(cfg, params):
+    """Structure: in the traced step each `ragged_dot`'s right operand is
+    a reshape of a value the layer scan takes whole (a const of the scan:
+    the parameter), [L*E, in, out]; nothing of one layer's [E, in, out]
+    shape is sliced out.  The sliced path (what a sharded expert axis
+    keeps) is the control: there the same search finds all three leaves."""
+    le = cfg.n_layers * cfg.n_experts
+    closed = _decode_jaxpr(cfg, params)
+    assert _expert_slices(closed, cfg) == []
+    ragged = [(j, e) for j, e in _eqns(closed.jaxpr)
+              if e.primitive.name == "ragged_dot_general"
+              or e.primitive.name == "ragged_dot"]
+    assert len(ragged) == 3
+    scan = [e for _, e in _eqns(closed.jaxpr) if e.primitive.name == "scan"
+            and any(r[0] is e.params["jaxpr"].jaxpr for r in ragged)][0]
+    body, n_consts = scan.params["jaxpr"].jaxpr, scan.params["num_consts"]
+    for j, eqn in ragged:
+        assert j is body
+        rhs, sizes = eqn.invars[1], eqn.invars[2]
+        assert rhs.aval.shape[0] == le and sizes.aval.shape == (le,)
+        (made,) = [e for e in body.eqns if rhs in e.outvars]
+        assert made.primitive.name == "reshape"
+        src = made.invars[0]
+        assert src in body.invars[:n_consts]  # closed over, not sliced
+        outer = scan.invars[body.invars.index(src)]
+        assert outer in closed.jaxpr.invars  # the parameter itself
+        assert outer.aval.shape[:2] == (cfg.n_layers, cfg.n_experts)
+    sliced = _decode_jaxpr(cfg, params, in_place=False)
+    assert len(_expert_slices(sliced, cfg)) == 3
+
+
+def test_decode_step_lowered_for_tpu_reshapes_the_parameter(cfg, params,
+                                                            monkeypatch):
+    """The same on the module `jax.export` lowers for the TPU (no chip
+    needed): three ragged dots over [L*E, in, out], no [E, in, out]
+    tensor anywhere in the step."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cache = tfm.init_kv_cache(cfg, 4, 8, dtype=jnp.float32)
+    z = jnp.zeros((4,), jnp.int32)
+
+    def step(p, c, in_place):
+        return tfm.decode_step(p, cfg, z, z, c, jnp.int32(0), z,
+                               with_moe_counts=True, experts_in_place=in_place)
+
+    d, f, e = cfg.hidden_dim, cfg.intermediate_dim, cfg.n_experts
+    le = cfg.n_layers * e
+    one_layer = (f"tensor<{e}x{d}x{f}xf32>", f"tensor<{e}x{f}x{d}xf32>")
+    text = {
+        flag: jax.export.export(
+            jax.jit(lambda p, c: step(p, c, flag)), platforms=["tpu"]
+        )(params, cache).mlir_module()
+        for flag in (True, False)
+    }
+    assert text[True].count("ragged_dot") >= 3
+    assert f"tensor<{le}x{d}x{f}xf32>" in text[True]
+    assert f"tensor<{le}x{f}x{d}xf32>" in text[True]
+    assert not any(t in text[True] for t in one_layer)
+    assert all(t in text[False] for t in one_layer)  # the control
+
+
+def test_a_dense_decode_step_traces_what_it_did():
+    """A dense model has no expert leaves: no ragged dot, and the traced
+    step is the same whichever way the question is answered — the program
+    of the sliced path, letter for letter."""
+    from areal_tpu.models.config import tiny_config
+
+    dense = tiny_config()
+    params = tfm.init_params(dense, jax.random.PRNGKey(2))
+    assert not tfm.expert_leaves_in_place(dense, params["blocks"])
+    asked = str(_decode_jaxpr(dense, params))
+    assert asked == str(_decode_jaxpr(dense, params, in_place=False))
+    assert "ragged_dot" not in asked
+
+
+@pytest.mark.parametrize("mode,in_place", [("d1", 1), ("m2", 1), ("f2", 0)])
+def test_static_generate_is_the_same_in_place_and_says_which(
+        cfg, params, mode, in_place, monkeypatch):
+    """Greedy static generate on the toy config: the route's counter says
+    whether the ragged kernels read the parameters' own buffers (one
+    chip; hidden axis sharded) or the scan's slices (expert axis sharded
+    over fsdp), and tokens, log-probs and MoE counters are those of the
+    sliced path on one chip."""
+    from areal_tpu.api.data_api import MicroBatchSpec, SequenceSample
+    from areal_tpu.api.model_api import GenerationHyperparameters
+    from areal_tpu.engines.generator import GeneratorEngine
+
+    sample = SequenceSample(
+        keys={"packed_prompts"}, ids=["a", "b"],
+        seqlens={"packed_prompts": [[6], [9]]},
+        data={"packed_prompts": np.arange(8, 23, dtype=np.int32)},
+    )
+    g = GenerationHyperparameters(n=2, max_new_tokens=5, greedy=True)
+
+    def run(mode):
+        pc = ParallelConfig.from_str(mode)
+        mesh = make_mesh(pc, jax.devices()[: pc.world_size])
+        engine = GeneratorEngine(cfg, params, mesh, eos_token_id=cfg.vocab_size)
+        out = engine.generate(sample, MicroBatchSpec(), g, inflight=False)
+        return out, dict(engine.last_pool_stats)
+
+    out, stats = run(mode)
+    assert stats["moe_expert_leaves_in_place"] == in_place
+    monkeypatch.setattr(tfm, "expert_leaves_in_place", lambda *a: False)
+    want, want_stats = run("d1")
+    assert want_stats["moe_expert_leaves_in_place"] == 0
+    assert (np.asarray(out.data["packed_input_ids"])
+            == np.asarray(want.data["packed_input_ids"])).all()
+    np.testing.assert_allclose(
+        np.asarray(out.data["packed_logprobs"]),
+        np.asarray(want.data["packed_logprobs"]), rtol=1e-4, atol=1e-5)
+    for key in ("moe_experts_touched", "moe_rows_per_expert_max",
+                "moe_decode_steps"):
+        assert stats[key] == want_stats[key], key
