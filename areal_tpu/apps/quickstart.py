@@ -380,11 +380,10 @@ def cmd_ppo_math(args):
             {"kv_cache_dtype": args.kv_cache_dtype}
             if args.kv_cache_dtype != "auto" else {}
         ),
-        kv_paged=False if args.no_paged_kv else None,
         kv_page_size=args.kv_page_size,
         kv_pool_pages=args.kv_pool_pages,
         prefill_chunk_tokens=args.prefill_chunk_tokens,
-        kv_share_prefix=False if args.no_kv_share_prefix else None,
+        kv_share_prefix=not args.no_kv_share_prefix,
         train_backend_args={
             k: v
             for k, v in (
@@ -496,20 +495,15 @@ def main(argv=None):
                     choices=("auto", "int8"),
                     help="int8 halves KV HBM per generated token (the "
                          "capacity bound for 16k+ decodes)")
-    pp.add_argument("--no-paged-kv", action="store_true",
-                    help="use the dense grow-by-doubling KV window "
-                         "instead of the paged pool (parity/debug)")
     pp.add_argument("--kv-page-size", type=int, default=128,
-                    help="tokens per KV page in the paged decode pool")
+                    help="tokens per KV page in the serving plane's pool")
     pp.add_argument("--kv-pool-pages", type=int, default=0,
                     help="fixed KV pool size in pages (0 = auto-size "
                          "for the worst case; positive caps KV HBM and "
                          "bounds concurrent admissions)")
-    pp.add_argument("--prefill-chunk-tokens", type=int, default=None,
+    pp.add_argument("--prefill-chunk-tokens", type=int, default=8,
                     help="serving plane: prompt tokens forwarded per "
-                         "decode step inside the unified chunk (0 = "
-                         "legacy two-program admit; default from "
-                         "AREAL_PREFILL_CHUNK_TOKENS)")
+                         "decode step inside the serving chunk (>= 1)")
     pp.add_argument("--no-kv-share-prefix", action="store_true",
                     help="disable copy-on-write prompt page sharing "
                          "across a sampling group (parity/debug)")

@@ -1,31 +1,34 @@
-"""Generation engine: jitted prefill + while-loop decode over a KV cache.
+"""Generation engine: two kinds of compiled generation program.
 
 Capability parity: the reference's in-house generation stack
 (realhf/impl/model/nn/real_llm_generate.py decode loop + CUDA-graph replay,
 and the SGLang server backend realhf/impl/model/backend/sglang.py) — built
 TPU-native:
 
-- The whole (prefill → sample → decode*) pipeline is ONE jitted function per
-  (batch, prompt-bucket, total-bucket) shape; `lax.while_loop` replaces the
+- Static decode program (`_get_gen_fn`): the whole (prefill → sample →
+  decode*) pipeline is ONE jitted function per (batch, prompt-bucket,
+  total-bucket) shape over a dense KV window; `lax.while_loop` replaces the
   reference's CUDA-graph replay (XLA compiles the step once; no per-token
-  Python).
+  Python).  Requests are length-sorted and packed into fixed-size batches so
+  at most a handful of shapes ever compile.
+- Serving chunk (`_get_serving_chunk_fn`): continuous batching over a paged
+  KV pool — a fixed slot pool where finished rows retire and pending
+  requests join between jitted T-step chunks; prompt slices, decode tokens,
+  speculative verification and episode observations are all rows of one
+  ragged token stream (reference: InflightBatchingGenerator,
+  real_llm_generate.py:670).
+- `generate()` picks between the two from what it observes (request count
+  against slots, decode budget, stop sequences, `spec_decode_k`).
 - Group sampling (n responses/prompt) expands prompts before batching.
-- Chunking: requests are length-sorted and packed into fixed-size batches
-  so at most a handful of shapes ever compile.
 - Weight hot-swap: `set_params` re-places the training params onto the
   generator's mesh/dtype (`parallel/realloc.reshard`: in place, one
   compiled on-device re-layout, or `device_put`, by where the bytes are)
   — the colocated-mesh equivalent of the reference's save-to-disk +
   update_weights_from_disk dance (model_worker.py:1040-1067).
-
-A continuous-batching (inflight) refill loop over this same decode step is
-the planned next step for the async RL path (reference:
-InflightBatchingGenerator, real_llm_generate.py:670).
 """
 
 import dataclasses
 import functools
-import os
 import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -56,7 +59,7 @@ logger = logging.getLogger("generator")
 
 
 def _cache_nbytes(cache) -> int:
-    """Total byte footprint of a KV cache/pool (host-side metadata only)."""
+    """Total byte footprint of a KV page pool (host-side metadata only)."""
     total = 0
     for a in (cache.k, cache.v, cache.k_scale, cache.v_scale):
         if a is not None:
@@ -114,7 +117,7 @@ class _EpisodeSlot:
 
 @dataclasses.dataclass
 class _PagedGenSession:
-    """Parked state of an interrupted plain-paged inflight generate call.
+    """State of one serving-plane generate call (parked on interrupt).
 
     Everything the chunk loop carries between iterations, host AND device
     side, so `resume_generate()` can replay each live slot's last chunk
@@ -150,13 +153,13 @@ class _PagedGenSession:
     prompt_key: str = "packed_prompts"
     prompt_lens: Any = None
     n: int = 1
-    # ---- unified serving plane (chunked prefill, prefill_chunk > 0) ----
+    # ---- chunked prefill ----
     # Per-row prefill progress lives HERE, not in a second compiled
     # program: prompt_buf[slot] holds the not-yet-forwarded prompt
     # remainder, prefill_rem counts tokens still to consume, prompt_off
     # indexes the next prompt_buf read.  A row with prefill_rem > 0 is
     # an admitting row inside the serving chunk; 0 means decoding.
-    prefill_chunk: int = 0  # W = query lanes per row per inner step
+    prefill_chunk: int = 1  # W = query lanes per row per inner step
     prompt_buf: Any = None  # host np [n_slots, pbw] int32
     prefill_rem: Any = None  # host np [n_slots] int32
     prompt_off: Any = None  # host np [n_slots] int32
@@ -191,11 +194,10 @@ def _spec_emit(
     done, out_toks, out_logps, out_fill, tokens_buf, active=None,
     n_valid=None,
 ):
-    """Shared post-forward bookkeeping for one speculative decode step
-    (dense AND paged cache layouts — one implementation so the two can
-    never diverge in emission semantics): min-length EOS masking, exact
-    accept/reject (`spec_accept`), first-EOS truncation, appends into
-    the chunk output buffers and the device-resident history buffer.
+    """Post-forward bookkeeping for one speculative decode step:
+    min-length EOS masking, exact accept/reject (`spec_accept`), first-EOS
+    truncation, appends into the chunk output buffers and the
+    device-resident history buffer.
 
     `active` [B] bool (default: ~done) masks rows that should emit this
     step — the ragged serving chunk passes (~done) & (~is_pref) & got-
@@ -279,12 +281,11 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         max_decode_batch: int = 64,
         donation_safe_swap: bool = True,
         kv_cache_dtype: str = "auto",
-        kv_paged: Optional[bool] = None,
         kv_page_size: int = 128,
         kv_pool_pages: int = 0,
-        prefill_chunk_tokens: Optional[int] = None,
-        kv_share_prefix: Optional[bool] = None,
-        serving_admit_lanes: Optional[int] = None,
+        prefill_chunk_tokens: int = 8,
+        kv_share_prefix: bool = True,
+        serving_admit_lanes: int = 0,
     ):
         if cfg.is_critic:
             raise ValueError("cannot generate from a critic model")
@@ -303,12 +304,12 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         self.static_path_max_new = 2048
         # "auto" = compute dtype; "int8" halves KV HBM per token (the
         # long-context capacity bound — see models.transformer.KVCache).
-        # Applies to every inflight path, INCLUDING the serving plane:
-        # chunked admission quantizes fresh KV once per chunk and all
-        # query lanes attend the dequantized pool (spec stays
-        # distribution-exact because drafts and verification score
-        # against the same quantized-cache model).  The static short-
-        # decode path keeps full precision (its windows are small).
+        # Applies to the serving plane's page pool: chunked admission
+        # quantizes fresh KV once per chunk and all query lanes attend
+        # the dequantized pool (spec stays distribution-exact because
+        # drafts and verification score against the same quantized-cache
+        # model).  The static short-decode program keeps full precision
+        # (its windows are small).
         # Validated here because YAML/gen_backend_args bypass the CLI's
         # argparse choices — a silently ignored "INT8"/"int4" would OOM
         # the exact 16k decode the flag exists to make fit.
@@ -318,16 +319,10 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                 f"got {kv_cache_dtype!r}"
             )
         self.kv_cache_dtype = kv_cache_dtype
-        # Paged KV pool for the inflight family (plain + speculative):
-        # fixed-size page pool + host free-list allocator instead of the
-        # dense grow-by-doubling window — zero cache copies, exactly one
-        # decode compilation per generate call, retired slots' pages
-        # recycled into new admits.  Default ON; AREAL_PAGED_KV=0 (or
-        # kv_paged=False) falls back to the dense window (kept for
-        # parity tests and as the known-good path).
-        if kv_paged is None:
-            kv_paged = os.environ.get("AREAL_PAGED_KV", "1") != "0"
-        self.kv_paged = bool(kv_paged)
+        # The serving plane's KV lives in a fixed-size page pool with a
+        # host free-list allocator: zero cache copies, exactly one decode
+        # compilation per generate call, retired slots' pages recycled
+        # into new admits.
         if kv_page_size < 1:
             raise ValueError(f"kv_page_size must be >= 1, got {kv_page_size}")
         if kv_pool_pages < 0:
@@ -340,33 +335,27 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         # makes admission wait for freed pages (PagePoolExhausted if a
         # LIVE slot cannot grow).
         self.kv_pool_pages = int(kv_pool_pages)
-        # Unified serving plane (plain paged inflight only): admitted
-        # prompts consume their tokens in W-sized slices INSIDE the same
-        # ragged chunk step that advances live decodes — no stop-the-
-        # world prefill program, no admission-shape zoo, decode_compiles
-        # stays 1 under continuous admission.  W > 1 rides the decode
-        # step's streamed weights (decode is bandwidth-bound; extra
-        # query lanes reuse the stream, same economics as spec decode).
-        # 0 = legacy two-program admit path (kept for parity tests).
-        if prefill_chunk_tokens is None:
-            prefill_chunk_tokens = int(
-                os.environ.get("AREAL_PREFILL_CHUNK_TOKENS", "8")
-            )
-        if prefill_chunk_tokens < 0:
+        # Serving plane: admitted prompts consume their tokens in W-sized
+        # slices INSIDE the same ragged chunk step that advances live
+        # decodes — no stop-the-world prefill program, no admission-shape
+        # zoo, decode_compiles stays 1 under continuous admission.  W > 1
+        # rides the decode step's streamed weights (decode is bandwidth-
+        # bound; extra query lanes reuse the stream, same economics as
+        # spec decode).  Validated here because YAML/gen_backend_args
+        # bypass the CLI.
+        if prefill_chunk_tokens < 1:
             raise ValueError(
-                f"prefill_chunk_tokens must be >= 0 (0 = legacy admit "
-                f"path), got {prefill_chunk_tokens}"
+                f"prefill_chunk_tokens must be >= 1 (the prefill slice "
+                f"width W of the serving chunk), got "
+                f"{prefill_chunk_tokens}; the two-program admit path that "
+                f"0 selected was removed"
             )
         self.prefill_chunk_tokens = int(prefill_chunk_tokens)
         # Copy-on-write prompt sharing (serving plane only): a GRPO
         # group's k responses — and any cross-request repeat of the same
         # prompt — map the owner's full prompt pages and re-forward only
         # the sub-page tail, multiplying effective pool capacity by the
-        # group size.  AREAL_KV_SHARE_PREFIX=0 disables.
-        if kv_share_prefix is None:
-            kv_share_prefix = (
-                os.environ.get("AREAL_KV_SHARE_PREFIX", "1") != "0"
-            )
+        # group size.
         self.kv_share_prefix = bool(kv_share_prefix)
         # Serving-chunk lane budget headroom A: the packed token stream is
         # T = min(n_slots + A, n_slots * Wmax) lanes wide (rounded up to a
@@ -375,10 +364,6 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         # shared by rows that want more (prefill slices, spec verify).
         # 0 = auto (4 * Wmax).  Undersizing is graceful: contended rows
         # progress slower, never wrong.
-        if serving_admit_lanes is None:
-            serving_admit_lanes = int(
-                os.environ.get("AREAL_SERVING_ADMIT_LANES", "0")
-            )
         if serving_admit_lanes < 0:
             raise ValueError(
                 f"serving_admit_lanes must be >= 0 (0 = auto), "
@@ -448,13 +433,13 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         self.lanes_slack = 0
         self.dead_live_lanes = 0
         # Interruptible generation (async RL): interrupt() makes the
-        # plain-paged inflight loop park at its next chunk boundary
-        # (generate() then returns None); resume_generate() replays each
-        # live slot's last chunk under the CURRENT weights — rewriting
-        # the tail KV on its already-mapped pages and refreshing the
-        # next-token logits — then continues the loop.  The other decode
-        # paths (dense, spec, static) ignore the event and run to
-        # completion, so a weight push there degrades to a full drain.
+        # serving loop park at its next chunk boundary (generate() then
+        # returns None); resume_generate() replays each live slot's last
+        # chunk under the CURRENT weights — rewriting the tail KV on its
+        # already-mapped pages and refreshing the next-token logits —
+        # then continues the loop.  The static program ignores the event
+        # and runs to completion, so a weight push there degrades to a
+        # full drain.
         self._interrupt_evt = threading.Event()
         self._session: Optional[_PagedGenSession] = None
         self.resume_replays = 0
@@ -529,7 +514,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
 
     def interrupt(self) -> None:
         """Request the running generate() to park at the next chunk
-        boundary.  Safe from any thread; a no-op for non-paged paths."""
+        boundary.  Safe from any thread; a no-op for the static program."""
         self._interrupt_evt.set()
 
     def clear_interrupt(self) -> None:
@@ -552,7 +537,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         """Token capacity of an explicitly sized page pool (None when
         the pool is auto-sized) — the admission budget gen_server splits
         request groups against."""
-        if not self.kv_paged or self.kv_pool_pages == 0:
+        if self.kv_pool_pages == 0:
             return None
         return self.kv_pool_pages * self.kv_page_size
 
@@ -566,12 +551,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         gen_server splits request groups against page_budget_tokens
         using this instead of the dense n*(prompt+new) product."""
         plen, mnew, n = int(prompt_len), int(max_new_tokens), int(n)
-        if (
-            not self.kv_paged
-            or self.prefill_chunk_tokens <= 0
-            or not self.kv_share_prefix
-            or n <= 1
-        ):
+        if not self.kv_share_prefix or n <= 1:
             return n * (plen + mnew)
         sp = max(0, (plen - 1) // self.kv_page_size)
         return sp * self.kv_page_size + n * ((plen - sp * self.kv_page_size) + mnew)
@@ -632,16 +612,17 @@ class GeneratorEngine(HostOffloadMixin, Engine):
     ) -> SequenceSample:
         """Group-sample `gconfig.n` responses per prompt.
 
-        Two execution modes over the same jitted model step:
+        Two generation programs:
         - static: length-sorted fixed-shape chunks (one jitted
           prefill+while-loop program per shape) — best when lengths are
           uniform;
-        - inflight (continuous batching): a fixed slot pool where finished
-          sequences retire and pending requests join between jitted T-token
-          decode chunks — one straggler no longer stalls the whole chunk
-          (reference: InflightBatchingGenerator,
+        - inflight (the serving plane, continuous batching): a fixed slot
+          pool where finished sequences retire and pending requests join
+          between jitted T-step ragged chunks — one straggler no longer
+          stalls the whole chunk (reference: InflightBatchingGenerator,
           realhf/impl/model/nn/real_llm_generate.py:670).
-        Default: inflight when there are more requests than decode slots.
+        Default: chosen from the requests (see below); `inflight=` forces
+        either program, which is how tests reach both at toy sizes.
 
         Returns a SequenceSample (one element per prompt, `n` sequences per
         element — the reference's group layout, data_api docstring) with:
@@ -686,7 +667,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         key = jax.random.PRNGKey(seed)
         b_cap = max(self.batch_shard, self.max_decode_batch)
         if gconfig.spec_decode_k > 0:
-            inflight = True  # spec decoding lives on the inflight path
+            inflight = True  # spec verification is a row of the serving chunk
         elif gconfig.stop:
             # Stop sequences are scanned host-side at chunk boundaries;
             # the static path is one fused device program with no such
@@ -701,8 +682,8 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             # (minutes on-device at 16k+ steps, with no chunk boundary for
             # the host to act at) and allocates the full final KV
             # window from step 0, streaming depth it doesn't need yet on
-            # every step; the inflight chunk loop keeps each program
-            # ~chunk_t tokens and grows the window geometrically.
+            # every step; the serving loop keeps each program ~chunk_t
+            # steps and maps pages as rows lengthen.
             inflight = (
                 len(reqs) > b_cap
                 or gconfig.max_new_tokens > self.static_path_max_new
@@ -716,7 +697,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             inflight=bool(inflight),
         ):
             if inflight:
-                self._generate_inflight(
+                self._generate_inflight_serving(
                     [reqs[j] for j in order], gconfig, key, results
                 )
                 if self._session is not None:
@@ -752,253 +733,117 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         self._require_params()
         self._session = None
         live = [s for s in range(st.n_slots) if st.active[s] is not None]
-        if live:
-            Q = st.chunk_t
-            tokens = np.full((st.n_slots, Q), self.pad_token_id, np.int32)
-            positions = np.zeros((st.n_slots, Q), np.int32)
-            write_pos0 = np.zeros((st.n_slots,), np.int32)
-            take_idx = np.zeros((st.n_slots,), np.int32)
-            live_mask = np.zeros((st.n_slots,), bool)
-            q_lens = np.zeros((st.n_slots,), np.int32)
-            for s in live:
-                hist = np.concatenate(
-                    [st.slot_prompt[s], np.asarray(st.toks_acc[s], np.int32)]
-                )
-                # One KV per FORWARDED token: L == len(hist) for decoding
-                # rows; a serving row parked mid-prefill has only
-                # hist[:L] in cache (the rest still waits in prompt_buf)
-                # and replays from that prefix.
-                L = int(st.cache_len[s])
-                hl = hist[:L]
-                # Replay window: the last chunk's emissions (>= 1 so the
-                # fresh logits always come from a real forward).  Padding
-                # columns are DEAD queries (q_lens=r): their writes drop
-                # in-kernel, so they can never scribble pad-token k/v
-                # past the row's valid tail.  SHARED prompt pages
-                # (prefix-cache followers) are read-only: clamp the
-                # window to the slot's private region so the teacher-
-                # forced rewrite can never touch a page other rows map.
-                priv = (
-                    int(st.shared_from[s])
-                    if st.shared_from is not None
-                    else 0
-                )
-                r = int(min(max(int(st.last_emit[s]), 1), Q, L - priv))
-                if r <= 0:
-                    continue  # nothing private to replay (cannot happen
-                    # for rows that ran a chunk; kept as a guard)
-                tokens[s, :r] = hl[L - r :]
-                write_pos0[s] = L - r
-                positions[s] = (L - r) + np.arange(Q)
-                take_idx[s] = r - 1
-                live_mask[s] = True
-                q_lens[s] = r
-            with tracer.span("resume_replay", cat="compute", n=len(live)):
-                st.logits_buf, st.pool = self._get_paged_replay_fn(
-                    st.n_slots, st.n_pages, st.max_pages, st.chunk_t
-                )(
-                    self.params, jnp.asarray(tokens), jnp.asarray(positions),
-                    st.pool, jnp.asarray(st.alloc.table),
-                    jnp.asarray(write_pos0), st.logits_buf,
-                    jnp.asarray(take_idx), jnp.asarray(live_mask),
-                    jnp.asarray(q_lens),
-                )
+        with tracer.span("resume_replay", cat="compute", n=len(live)):
+            self._replay_tails(st, live)
         self.resume_replays += 1
-        if st.prefill_chunk > 0:
-            # The weight push invalidated every cached prompt KV: drop
-            # the prefix-cache holds so post-resume admissions re-prefill
-            # under the new weights instead of sharing stale pages (live
-            # followers keep their mappings — their whole history KV is
-            # equally pre-push, the accepted resume approximation).
-            st.alloc.prefix_clear()
-            if st.inflight_prefix is not None:
-                st.inflight_prefix.clear()
-            if st.slot_hash is not None:
-                # Rows live across the push carry mixed-weight KV; if one
-                # later finishes its prefill it must NOT register the
-                # prefix (followers would inherit the mix — a fresh
-                # admission re-prefills cleanly instead).
-                st.slot_hash.clear()
-            finished = self._run_serving_loop(st)
-        else:
-            finished = self._run_paged_loop(st)
-        if not finished:
+        # The weight push invalidated every cached prompt KV: drop the
+        # prefix-cache holds so post-resume admissions re-prefill under
+        # the new weights instead of sharing stale pages (live followers
+        # keep their mappings — their whole history KV is equally
+        # pre-push, the accepted resume approximation).
+        st.alloc.prefix_clear()
+        st.inflight_prefix.clear()
+        # Rows live across the push carry mixed-weight KV; if one later
+        # finishes its prefill it must NOT register the prefix (followers
+        # would inherit the mix — a fresh admission re-prefills cleanly
+        # instead).
+        st.slot_hash.clear()
+        if not self._run_serving_loop(st):
             return None
         return self._assemble(
             st.sample, st.prompt_key, st.prompt_lens, st.results, st.n
         )
 
+    def _replay_tails(self, st: "_PagedGenSession", slots) -> None:
+        """Teacher-forced tail replay for resume: each slot's last chunk
+        of history tokens goes through its existing page table again as
+        a row of the ragged step (q_len = r, the way the serving chunk
+        forwards a prefill slice), overwriting the tail KV in place and
+        refreshing the slot's next-token logits.  Consumes no PRNG keys."""
+        Q = st.chunk_t
+        T = st.n_slots * Q
+        tokens = np.zeros((T,), np.int32)
+        positions = np.zeros((T,), np.int32)
+        # Lanes past the packed tails are DEAD (row_of == n_slots): their
+        # cache writes drop and their attention is empty, so a short
+        # replay can never scribble k/v past a row's valid tail.
+        row_of = np.full((T,), st.n_slots, np.int32)
+        last_lane = np.zeros((st.n_slots,), np.int32)
+        live_mask = np.zeros((st.n_slots,), bool)
+        fill = 0
+        for s in slots:
+            hist = np.concatenate(
+                [st.slot_prompt[s], np.asarray(st.toks_acc[s], np.int32)]
+            )
+            # One KV per FORWARDED token: L == len(hist) for decoding
+            # rows; a row parked mid-prefill has only hist[:L] in cache
+            # (the rest still waits in prompt_buf) and replays from that
+            # prefix.
+            L = int(st.cache_len[s])
+            # Replay window: the last chunk's emissions (>= 1 so the
+            # fresh logits always come from a real forward).  SHARED
+            # prompt pages (prefix-cache followers) are read-only: clamp
+            # the window to the slot's private region so the teacher-
+            # forced rewrite can never touch a page other rows map.
+            priv = int(st.shared_from[s])
+            r = int(min(max(int(st.last_emit[s]), 1), Q, L - priv))
+            if r <= 0:
+                continue  # nothing private to replay (cannot happen for
+                # rows that ran a chunk; kept as a guard)
+            tokens[fill : fill + r] = hist[L - r : L]
+            positions[fill : fill + r] = np.arange(L - r, L)
+            row_of[fill : fill + r] = s
+            last_lane[s] = fill + r - 1
+            live_mask[s] = True
+            fill += r
+        if fill == 0:
+            return
+        st.logits_buf, st.pool = self._get_paged_replay_fn(
+            st.n_slots, st.n_pages, st.max_pages, Q
+        )(
+            self.params, jnp.asarray(tokens), jnp.asarray(positions),
+            st.pool, jnp.asarray(st.alloc.table), jnp.asarray(row_of),
+            st.logits_buf, jnp.asarray(last_lane), jnp.asarray(live_mask),
+        )
+
     def _get_paged_replay_fn(
         self, n_slots: int, n_pages: int, max_pages: int, chunk_t: int
     ):
-        """Teacher-forced tail replay for resume: Q history tokens per
-        row forwarded through the existing page table (KV overwritten in
-        place), next-token logits taken at each row's last valid query.
-        Inactive rows carry sentinel tables, so their writes drop and
-        their (garbage) logits are masked out by live_mask."""
-        sig = ("paged_replay", n_slots, n_pages, max_pages, chunk_t)
+        in_place = self._expert_leaves_in_place
+        sig = ("paged_replay", n_slots, n_pages, max_pages, chunk_t, in_place)
         if sig in self._gen_fns:
             return self._gen_fns[sig]
         cfg = self.cfg
 
         @functools.partial(jax.jit, donate_argnums=(3, 6))
-        def fn(params, tokens, positions, pool, page_table, write_pos0,
-               logits_buf, take_idx, live_mask, q_lens):
-            # Ragged replay: only the r real history columns per row are
-            # live.  Padding columns and parked rows are DEAD queries —
-            # their cache writes drop and their attention is fully masked,
-            # so a short replay window can never scribble garbage k/v past
-            # a row's valid tail (pages later rows would gather).
-            logits_all, pool = tfm.decode_step_spec_paged(
-                params, cfg, tokens, positions, pool, page_table, write_pos0,
-                q_lens=q_lens,
+        def fn(params, tokens, positions, pool, page_table, row_of,
+               logits_buf, last_lane, live_mask):
+            logits_pk, pool = tfm.decode_step_ragged_paged(
+                params, cfg, tokens, positions, pool, page_table, row_of,
+                experts_in_place=in_place,
             )
-            fresh = jnp.take_along_axis(
-                logits_all, take_idx[:, None, None], axis=1
-            )[:, 0]
             logits_buf = jnp.where(
-                live_mask[:, None], fresh.astype(logits_buf.dtype), logits_buf
+                live_mask[:, None],
+                logits_pk[last_lane].astype(logits_buf.dtype),
+                logits_buf,
             )
             return logits_buf, pool
 
         self._gen_fns[sig] = fn
         return fn
 
-    # -- continuous batching (inflight refill) --
-
-    def _generate_inflight(self, reqs, gconfig, key, results) -> None:
-        """Fixed slot pool; retire finished rows and admit pending requests
-        between jitted T-token decode chunks.  kv_paged (the default)
-        routes to the paged-pool variants: fixed shapes, one decode
-        compilation, zero grow copies."""
-        if self.kv_paged:
-            # ONE ragged serving chunk admits, decodes, and (K>0)
-            # spec-verifies: every row is just a q_len in the packed
-            # token stream, so spec drafts and int8 pools ride the same
-            # program as plain decode — no two-program admit carve-outs.
-            if self.prefill_chunk_tokens > 0:
-                return self._generate_inflight_serving(
-                    reqs, gconfig, key, results
-                )
-            if gconfig.spec_decode_k > 0:
-                raise ValueError(
-                    "spec_decode_k > 0 over the paged pool requires the "
-                    "serving plane (prefill_chunk_tokens > 0); the legacy "
-                    "two-program spec admit path was removed"
-                )
-            return self._generate_inflight_plain_paged(
-                reqs, gconfig, key, results
-            )
-        if gconfig.spec_decode_k > 0:
-            return self._generate_inflight_spec(reqs, gconfig, key, results)
-        return self._generate_inflight_plain(reqs, gconfig, key, results)
-
-    def _generate_inflight_plain(self, reqs, gconfig, key, results) -> None:
-        n_slots = min(max(self.batch_shard, self.max_decode_batch), len(reqs))
-        while n_slots % self.batch_shard:
-            n_slots += 1
-        max_prompt = max(len(t) for (_, _, t) in reqs)
-        chunk_t = min(32, gconfig.max_new_tokens)
-        # The cache starts at the smallest bucket covering the prompts and
-        # GROWS through buckets as sequences lengthen: every decode step
-        # streams the whole window, so depth it doesn't need yet is pure
-        # wasted HBM bandwidth (the chunk fn recompiles per bucket, a
-        # handful of shapes total).
-        cur_w = bucket_len(max_prompt + chunk_t)
-        cache = tfm.init_kv_cache(
-            self.cfg, n_slots, cur_w,
-            dtype=(
-                "int8"
-                if self.kv_cache_dtype == "int8"
-                else self.compute_dtype
-            ),
-        )
-        logits_buf = jnp.zeros((n_slots, self.cfg.vocab_size), jnp.float32)
-        cache_len = np.zeros((n_slots,), np.int32)
-        gen_count = np.zeros((n_slots,), np.int32)
-        done_host = np.ones((n_slots,), bool)  # empty slots count as done
-        active: List[Optional[Tuple[int, int]]] = [None] * n_slots
-        toks_acc: Dict[int, List[int]] = {}
-        logps_acc: Dict[int, List[float]] = {}
-        pending = list(reversed(reqs))  # pop() takes the longest first
-
-        while pending or any(a is not None for a in active):
-            # Refill ALL free slots with ONE jitted multi-row prefill
-            # (serial batch-1 admissions would cost ~2k device round-trips
-            # at 512 prompts × 4 samples before steady state).
-            admits = self._take_admits(active, pending, n_slots)
-            if admits:
-                rows, plens, slots = self._pack_admits(admits, n_slots)
-                with tracer.span("prefill", cat="compute", n=len(admits)):
-                    logits_buf, cache = self._get_prefill_slots_fn()(
-                        self.params, jnp.asarray(rows), jnp.asarray(plens),
-                        cache, logits_buf, jnp.asarray(slots),
-                    )
-                self.prefill_dispatches += 1
-                for s, i, rep, toks in admits:
-                    cache_len[s] = len(toks)
-                    gen_count[s] = 0
-                    done_host[s] = False
-                    active[s] = (i, rep)
-                    toks_acc[s] = []
-                    logps_acc[s] = []
-
-            # Grow the cache window when the next chunk could overflow it.
-            # Geometric (doubling) growth bounds recompiles + cache copies
-            # to O(log length); dead slots are excluded (cache_len resets
-            # on retirement).
-            old_bytes = _cache_nbytes(cache)
-            cache, new_w = self._grow_kv_cache(
-                cache, cur_w, int(cache_len.max()) + chunk_t
-            )
-            if new_w != cur_w:
-                self.cache_copy_bytes += old_bytes
-                cur_w = new_w
-            self._accum_pool_stats(
-                "dense", int(cache_len.sum()), n_slots * cur_w
-            )
-
-            # One jitted chunk: up to chunk_t tokens for every live slot.
-            decode_fn = self._get_inflight_decode_fn(
-                n_slots, cur_w, chunk_t, gconfig
-            )
-            key, sub = jax.random.split(key)
-            # The to_host() calls inside the span force device sync, so
-            # the span covers actual chunk execution, not just dispatch.
-            with tracer.span("decode_chunk", cat="compute", t=chunk_t):
-                (
-                    out_toks, out_logps, logits_buf, cache,
-                    new_cache_len, new_gen_count, new_done,
-                ) = decode_fn(
-                    self.params, cache, logits_buf,
-                    jnp.asarray(cache_len), jnp.asarray(gen_count),
-                    jnp.asarray(done_host), sub,
-                )
-                out_toks = to_host(out_toks)
-                out_logps = to_host(out_logps)
-            cache_len = to_host(new_cache_len).copy()
-            gen_count = to_host(new_gen_count).copy()
-            new_done = to_host(new_done)
-
-            # Host bookkeeping: append tokens, retire finished slots.
-            self._drain_chunk_outputs(
-                out_toks, out_logps, new_done, active, toks_acc, logps_acc,
-                results, done_host, cache_len, gconfig.max_new_tokens,
-                stop_seqs=gconfig.stop,
-            )
-
     def _drain_chunk_outputs(
         self, out_toks, out_logps, new_done, active, toks_acc, logps_acc,
         results, done_host, cache_len, max_new: int, on_retire=None,
         stop_seqs=(),
     ) -> None:
-        """Shared inflight bookkeeping (plain + speculative loops): append
+        """Serving-loop bookkeeping (generate() and episode turns): append
         each live slot's chunk output (rows are contiguous, -1-terminated),
         finish on EOS, a matched stop sequence (the stop tokens stay in
         the output), or the token budget, retire finished slots (a dead
-        slot must not drive cache growth).  `on_retire(slot)` fires when a
-        slot finishes — the paged loops hook it to recycle the slot's
-        pages into the free list."""
+        slot must not hold pages).  `on_retire(slot)` fires when a slot
+        finishes — the loop hooks it to recycle the slot's pages into the
+        free list."""
         for s in range(len(active)):
             if active[s] is None:
                 continue
@@ -1046,179 +891,16 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             else:
                 done_host[s] = new_done[s]
 
-    def _take_admits(self, active, pending, n_slots):
-        """Assign pending requests to free slots (longest-prompt first —
-        `pending` is kept sorted ascending so pop() takes the longest)."""
-        admits = []
-        for s in range(n_slots):
-            if active[s] is None and pending:
-                i, rep, toks = pending.pop()
-                admits.append((s, i, rep, toks))
-        self._set_live_slots(sum(a is not None for a in active) + len(admits))
-        tracer.counter(
-            "gen_slots", live=self.live_slots, pending=len(pending)
-        )
-        return admits
-
-    def _pack_admits(self, admits, n_slots):
-        """Pack one refill cycle's admissions into fixed-shape arrays.
-
-        SP buckets to the longest admitted prompt; M buckets to the next
-        power of two so only O(log slots × log prompt) admission shapes
-        ever compile.  Padding rows carry one pad token (NaN-safe through
-        attention) and an out-of-range slot id — the device-side scatters
-        drop them (`prefill_into_slots`)."""
-        sp = bucket_len(max(len(t) for (_, _, _, t) in admits))
-        m = 1
-        while m < len(admits):
-            m *= 2
-        rows = np.full((m, sp), self.pad_token_id, np.int32)
-        plens = np.ones((m,), np.int32)
-        slots = np.full((m,), n_slots, np.int32)
-        for j, (s, _, _, toks) in enumerate(admits):
-            rows[j, : len(toks)] = toks
-            plens[j] = len(toks)
-            slots[j] = s
-        return rows, plens, slots
-
-    def _get_prefill_slots_fn(self):
-        sig = ("prefill_slots",)
-        if sig in self._gen_fns:
-            return self._gen_fns[sig]
-        cfg = self.cfg
-        # Admission batches are ragged (1..n_slots rows): a Mesh
-        # (shard_map'd flash) cannot shard them over data/fsdp — fall back
-        # to dense for this path only.
-        use_flash = (
-            False if isinstance(self._use_flash, Mesh) else self._use_flash
-        )
-
-        # Cache/logits donated: the caller rebinds both from the outputs,
-        # and a non-donated multi-GB cache would be COPIED every refill.
-        @functools.partial(jax.jit, donate_argnums=(3, 4))
-        def fn(params, rows, plens, cache, logits_buf, slot_rows):
-            logits, cache = tfm.prefill_into_slots(
-                params, cfg, rows, plens, cache, slot_rows,
-                use_flash=use_flash,
-            )
-            logits_buf = logits_buf.at[slot_rows].set(logits, mode="drop")
-            return logits_buf, cache
-
-        self._gen_fns[sig] = fn
-        return fn
-
-    def _get_inflight_decode_fn(
-        self, n_slots: int, s_max: int, chunk_t: int,
-        g: GenerationHyperparameters,
-    ):
-        sig = (
-            "inflight", n_slots, s_max, chunk_t, g.min_new_tokens, g.greedy,
-            g.top_p, g.top_k, g.temperature,
-        )
-        if sig in self._gen_fns:
-            return self._gen_fns[sig]
-        cfg = self.cfg
-        eos = self.eos_token_id
-
-        # Cache/logits donated: rebound from outputs each chunk; without
-        # donation every chunk call copies the full KV cache.
-        @functools.partial(jax.jit, donate_argnums=(1, 2))
-        def fn(params, cache, logits, cache_len, gen_count, done, key):
-            out_toks = jnp.full((n_slots, chunk_t), -1, jnp.int32)
-            out_logps = jnp.zeros((n_slots, chunk_t), jnp.float32)
-
-            def body(t, st):
-                (logits, cache, cache_len, gen_count, done, out_toks,
-                 out_logps) = st
-                sub = jax.random.fold_in(key, t)
-                lg = logits
-                if g.min_new_tokens > 0:
-                    lg = jnp.where(
-                        (gen_count < g.min_new_tokens)[:, None]
-                        & (jnp.arange(cfg.vocab_size) == eos)[None, :],
-                        -1e10,
-                        lg,
-                    )
-                tok, logp = sample_token(
-                    lg, sub,
-                    temperature=g.temperature, top_k=g.top_k, top_p=g.top_p,
-                    greedy=g.greedy,
-                )
-                out_toks = jax.lax.dynamic_update_slice(
-                    out_toks, jnp.where(done, -1, tok)[:, None], (0, t)
-                )
-                out_logps = jax.lax.dynamic_update_slice(
-                    out_logps, jnp.where(done, 0.0, logp)[:, None], (0, t)
-                )
-                # Rows already done keep replaying their last slot (the
-                # write is harmless garbage past their valid window).
-                positions = cache_len
-                next_logits, cache2 = tfm.decode_step_inflight(
-                    params, cfg, jnp.where(done, eos, tok), positions, cache,
-                    slots=jnp.minimum(cache_len, s_max - 1),
-                    valid_to=jnp.minimum(cache_len + 1, s_max),
-                )
-                new_done = done | (tok == eos)
-                cache_len = cache_len + (~done).astype(jnp.int32)
-                gen_count = gen_count + (~done).astype(jnp.int32)
-                return (
-                    next_logits, cache2, cache_len, gen_count, new_done,
-                    out_toks, out_logps,
-                )
-
-            st = (logits, cache, cache_len, gen_count, done, out_toks, out_logps)
-            st = jax.lax.fori_loop(0, chunk_t, body, st)
-            logits, cache, cache_len, gen_count, done, out_toks, out_logps = st
-            return out_toks, out_logps, logits, cache, cache_len, gen_count, done
-
-        self._gen_fns[sig] = fn
-        self.decode_compiles += 1
-        self._m_decode_compiles.inc()
-        logger.info(
-            f"compiled inflight decoder n_slots={n_slots} s_max={s_max} "
-            f"chunk={chunk_t}"
-        )
-        return fn
-
-    # -- shared inflight helpers --
-
-    @staticmethod
-    def _grow_kv_cache(cache, cur_w: int, need: int):
-        """Geometric (doubling) window growth — bounds recompiles and cache
-        copies to O(log length); no-op when `need` fits."""
-        if need <= cur_w:
-            return cache, cur_w
-        new_w = bucket_len(max(need, 2 * cur_w))
-        pad = [(0, 0), (0, 0), (0, new_w - cur_w), (0, 0), (0, 0)]
-        return (
-            tfm.KVCache(
-                k=jnp.pad(cache.k, pad),
-                v=jnp.pad(cache.v, pad),
-                k_scale=(
-                    jnp.pad(cache.k_scale, pad[:-1])
-                    if cache.quantized
-                    else None
-                ),
-                v_scale=(
-                    jnp.pad(cache.v_scale, pad[:-1])
-                    if cache.quantized
-                    else None
-                ),
-            ),
-            new_w,
-        )
-
     def _accum_pool_stats(
-        self, kind: str, live_tokens: int, allocated_tokens: int
+        self, live_tokens: int, allocated_tokens: int
     ) -> None:
         """Accumulate per-chunk KV-memory utilization (live tokens /
-        allocated cache tokens) into last_pool_stats — the bench reports
-        this next to tokens/s for the dense-vs-paged comparison."""
+        allocated cache tokens) into last_pool_stats — the benchmark
+        reports this next to tokens/s."""
         st = self.last_pool_stats
-        if st.get("kind") != kind:
-            st.clear()
+        if "samples" not in st:
             st.update(
-                kind=kind, samples=0, live_tokens=0, allocated_tokens=0
+                kind="paged", samples=0, live_tokens=0, allocated_tokens=0
             )
         st["samples"] += 1
         st["live_tokens"] += int(live_tokens)
@@ -1238,316 +920,23 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             utilization=int(live_tokens) / max(int(allocated_tokens), 1),
         )
 
-    # -- paged inflight (fixed page pool + host free-list allocator) --
+    # -- serving plane (paged pool, chunked prefill, CoW page sharing) --
 
     def _paged_kv_dtype(self):
         return "int8" if self.kv_cache_dtype == "int8" else self.compute_dtype
 
-    def _generate_inflight_plain_paged(
-        self, reqs, gconfig, key, results
-    ) -> None:
-        """The plain inflight loop over a paged KV pool: the pool and the
-        decode program have ONE fixed shape for the whole generate call
-        (compiled exactly once), window growth is a host-side page-index
-        append, and retired slots' pages are recycled into new admits.
-        Replaces grow-by-doubling (`_generate_inflight_plain`), which
-        pays a full-cache copy + recompile at every bucket boundary."""
-        n_slots = min(max(self.batch_shard, self.max_decode_batch), len(reqs))
-        while n_slots % self.batch_shard:
-            n_slots += 1
-        ps = self.kv_page_size
-        chunk_t = min(32, gconfig.max_new_tokens)
-        max_prompt = max(len(t) for (_, _, t) in reqs)
-        # Page-table width: worst-case per-slot footprint (full prompt +
-        # the whole new-token budget + chunk slack — within a chunk,
-        # writes land up to chunk_t past the pre-chunk live length).
-        max_pages = -(-(max_prompt + gconfig.max_new_tokens + chunk_t) // ps)
-        n_pages = self.kv_pool_pages or n_slots * max_pages
-        st = _PagedGenSession(
-            gconfig=gconfig,
-            key=key,
-            results=results,
-            n_slots=n_slots,
-            n_pages=n_pages,
-            max_pages=max_pages,
-            chunk_t=chunk_t,
-            alloc=PageAllocator(n_pages, ps, n_slots, max_pages),
-            pool=tfm.init_paged_kv_cache(
-                self.cfg, n_pages, ps, dtype=self._paged_kv_dtype()
-            ),
-            logits_buf=jnp.zeros((n_slots, self.cfg.vocab_size), jnp.float32),
-            cache_len=np.zeros((n_slots,), np.int32),
-            gen_count=np.zeros((n_slots,), np.int32),
-            done_host=np.ones((n_slots,), bool),
-            active=[None] * n_slots,
-            toks_acc={},
-            logps_acc={},
-            pending=list(reversed(reqs)),
-            slot_prompt={},
-            last_emit=np.zeros((n_slots,), np.int32),
-        )
-        st.alloc.page_bytes = _cache_nbytes(st.pool) // n_pages
-        self._run_paged_loop(st)
-
-    def _run_paged_loop(self, st: "_PagedGenSession") -> bool:
-        """The plain-paged chunk loop, interruptible at chunk boundaries:
-        checks the interrupt event at the top of every iteration and
-        parks the whole session (device pool + host bookkeeping) when
-        set.  Returns True when all requests finished, False when
-        parked (self._session then holds the state for
-        resume_generate())."""
-        gconfig = st.gconfig
-        alloc = st.alloc
-        n_slots, ps, chunk_t = st.n_slots, alloc.page_size, st.chunk_t
-        decode_fn = self._get_paged_decode_fn(
-            n_slots, st.n_pages, st.max_pages, chunk_t, gconfig
-        )
-        while st.pending or any(a is not None for a in st.active):
-            if self._interrupt_evt.is_set():
-                self._session = st
-                tracer.counter(
-                    "gen_interrupt",
-                    parked_live=sum(a is not None for a in st.active),
-                    parked_pending=len(st.pending),
-                )
-                return False
-            admits = self._take_admits_paged(
-                st.active, st.pending, n_slots, alloc, chunk_t
-            )
-            if admits:
-                rows, plens, slots, page_rows = self._pack_admits_paged(
-                    admits, n_slots, alloc
-                )
-                with tracer.span("prefill", cat="compute", n=len(admits)):
-                    st.logits_buf, st.pool = self._get_prefill_pages_fn()(
-                        self.params, jnp.asarray(rows), jnp.asarray(plens),
-                        st.pool, st.logits_buf, jnp.asarray(slots),
-                        jnp.asarray(page_rows),
-                    )
-                self.prefill_dispatches += 1
-                for s, i, rep, toks in admits:
-                    st.cache_len[s] = len(toks)
-                    st.gen_count[s] = 0
-                    st.done_host[s] = False
-                    st.active[s] = (i, rep)
-                    st.toks_acc[s] = []
-                    st.logps_acc[s] = []
-                    st.slot_prompt[s] = np.asarray(toks, np.int32)
-
-            # Map pages covering the next chunk for every live slot —
-            # the jitted chunk must never need a page the table lacks.
-            # This is the paged replacement for _grow_kv_cache: an int
-            # append on the host, no device copy, no recompile.
-            for s in range(n_slots):
-                if st.active[s] is not None:
-                    alloc.reserve(s, int(st.cache_len[s]) + chunk_t)
-            self._accum_pool_stats(
-                "paged", int(st.cache_len.sum()), alloc.allocated_pages() * ps
-            )
-
-            st.key, sub = jax.random.split(st.key)
-            prev_gen = st.gen_count.copy()
-            with tracer.span("decode_chunk", cat="compute", t=chunk_t):
-                (
-                    out_toks, out_logps, st.logits_buf, st.pool,
-                    new_cache_len, new_gen_count, new_done,
-                ) = decode_fn(
-                    self.params, st.pool, st.logits_buf,
-                    jnp.asarray(alloc.table), jnp.asarray(st.cache_len),
-                    jnp.asarray(st.gen_count), jnp.asarray(st.done_host),
-                    sub,
-                )
-                out_toks = to_host(out_toks)
-                out_logps = to_host(out_logps)
-            st.cache_len = to_host(new_cache_len).copy()
-            st.gen_count = to_host(new_gen_count).copy()
-            # Tokens each slot emitted THIS chunk = the tail a resume
-            # must replay under fresh weights.
-            st.last_emit = st.gen_count - prev_gen
-
-            def _retire(s):
-                alloc.release(s)
-                st.slot_prompt.pop(s, None)
-
-            self._drain_chunk_outputs(
-                out_toks, out_logps, to_host(new_done), st.active,
-                st.toks_acc, st.logps_acc, st.results, st.done_host,
-                st.cache_len, gconfig.max_new_tokens, on_retire=_retire,
-                stop_seqs=gconfig.stop,
-            )
-        self.last_pool_stats.update(
-            pool_pages=st.n_pages, page_size=ps,
-            pages_recycled=alloc.pages_recycled,
-            peak_pages_used=alloc.peak_pages_used,
-            pool_bytes=alloc.pool_bytes(),
-            peak_allocated_bytes=alloc.peak_pages_used * alloc.page_bytes,
-        )
-        self._set_live_slots(0)
-        return True
-
-    def _take_admits_paged(self, active, pending, n_slots, alloc, slack):
-        """`_take_admits` against the page budget: a request is admitted
-        only when the allocator can map its prompt plus `slack` decode
-        tokens; otherwise it stays pending until retirements free pages.
-        Raises PagePoolExhausted when the pool cannot hold even ONE
-        request with nothing live to retire (undersized kv_pool_pages —
-        waiting would spin forever)."""
-        admits = []
-        for s in range(n_slots):
-            if active[s] is None and pending:
-                plen = len(pending[-1][2])
-                if not alloc.can_reserve(s, plen + slack):
-                    break
-                i, rep, toks = pending.pop()
-                alloc.reserve(s, plen + slack)
-                admits.append((s, i, rep, toks))
-        if (
-            not admits
-            and pending
-            and not any(a is not None for a in active)
-        ):
-            free_slot = next(
-                s for s in range(n_slots) if active[s] is None
-            )
-            alloc.reserve(free_slot, len(pending[-1][2]) + slack)  # raises
-        self._set_live_slots(sum(a is not None for a in active) + len(admits))
-        tracer.counter(
-            "gen_slots", live=self.live_slots, pending=len(pending)
-        )
-        return admits
-
-    def _pack_admits_paged(self, admits, n_slots, alloc):
-        """`_pack_admits` + page alignment: the prefill width SP must be
-        a whole number of pages (the row caches scatter as page-size
-        chunks), and each admitted row carries its assigned pool pages
-        (sentinel past its prompt — those chunks drop)."""
-        rows, plens, slots = self._pack_admits(admits, n_slots)
-        ps = alloc.page_size
-        sp = rows.shape[1]
-        if sp % ps:
-            rows = np.pad(
-                rows, [(0, 0), (0, ps - sp % ps)],
-                constant_values=self.pad_token_id,
-            )
-            sp = rows.shape[1]
-        page_rows = np.full(
-            (rows.shape[0], sp // ps), alloc.sentinel, np.int32
-        )
-        for j, (s, _, _, toks) in enumerate(admits):
-            np_ = alloc.pages_for(len(toks))
-            page_rows[j, :np_] = alloc.table[s, :np_]
-        return rows, plens, slots, page_rows
-
-    def _get_prefill_pages_fn(self):
-        sig = ("prefill_pages",)
-        if sig in self._gen_fns:
-            return self._gen_fns[sig]
-        cfg = self.cfg
-        use_flash = (
-            False if isinstance(self._use_flash, Mesh) else self._use_flash
-        )
-
-        @functools.partial(jax.jit, donate_argnums=(3, 4))
-        def fn(params, rows, plens, pool, logits_buf, slot_rows, page_rows):
-            logits, pool = tfm.prefill_into_pages(
-                params, cfg, rows, plens, pool, page_rows,
-                use_flash=use_flash,
-            )
-            logits_buf = logits_buf.at[slot_rows].set(logits, mode="drop")
-            return logits_buf, pool
-
-        self._gen_fns[sig] = fn
-        return fn
-
-    def _get_paged_decode_fn(
-        self, n_slots: int, n_pages: int, max_pages: int, chunk_t: int,
-        g: GenerationHyperparameters,
-    ):
-        """The paged decode chunk.  Its signature depends only on the
-        pool geometry — fixed for the whole generate call — so it
-        compiles EXACTLY ONCE (the dense variant recompiles per window
-        bucket); tests assert this via the decode_compiles counter."""
-        sig = (
-            "paged_inflight", n_slots, n_pages, max_pages, chunk_t,
-            g.min_new_tokens, g.greedy, g.top_p, g.top_k, g.temperature,
-        )
-        if sig in self._gen_fns:
-            return self._gen_fns[sig]
-        cfg = self.cfg
-        eos = self.eos_token_id
-
-        @functools.partial(jax.jit, donate_argnums=(1, 2))
-        def fn(params, pool, logits, page_table, cache_len, gen_count,
-               done, key):
-            out_toks = jnp.full((n_slots, chunk_t), -1, jnp.int32)
-            out_logps = jnp.zeros((n_slots, chunk_t), jnp.float32)
-
-            def body(t, st):
-                (logits, pool, cache_len, gen_count, done, out_toks,
-                 out_logps) = st
-                sub = jax.random.fold_in(key, t)
-                lg = logits
-                if g.min_new_tokens > 0:
-                    lg = jnp.where(
-                        (gen_count < g.min_new_tokens)[:, None]
-                        & (jnp.arange(cfg.vocab_size) == eos)[None, :],
-                        -1e10,
-                        lg,
-                    )
-                tok, logp = sample_token(
-                    lg, sub,
-                    temperature=g.temperature, top_k=g.top_k, top_p=g.top_p,
-                    greedy=g.greedy,
-                )
-                out_toks = jax.lax.dynamic_update_slice(
-                    out_toks, jnp.where(done, -1, tok)[:, None], (0, t)
-                )
-                out_logps = jax.lax.dynamic_update_slice(
-                    out_logps, jnp.where(done, 0.0, logp)[:, None], (0, t)
-                )
-                # Done rows keep rewriting their current position (the
-                # allocator keeps it mapped until the slot retires); no
-                # clamps — the reserve() before each chunk guarantees
-                # capacity, which is what makes the shape static.
-                next_logits, pool2 = tfm.decode_step_paged(
-                    params, cfg, jnp.where(done, eos, tok), cache_len,
-                    pool, page_table, cache_len, cache_len + 1,
-                )
-                new_done = done | (tok == eos)
-                cache_len = cache_len + (~done).astype(jnp.int32)
-                gen_count = gen_count + (~done).astype(jnp.int32)
-                return (
-                    next_logits, pool2, cache_len, gen_count, new_done,
-                    out_toks, out_logps,
-                )
-
-            st = (logits, pool, cache_len, gen_count, done, out_toks,
-                  out_logps)
-            st = jax.lax.fori_loop(0, chunk_t, body, st)
-            logits, pool, cache_len, gen_count, done, out_toks, out_logps = st
-            return (
-                out_toks, out_logps, logits, pool, cache_len, gen_count,
-                done,
-            )
-
-        self._gen_fns[sig] = fn
-        self.decode_compiles += 1
-        self._m_decode_compiles.inc()
-        logger.info(
-            f"compiled paged inflight decoder n_slots={n_slots} "
-            f"pool={n_pages}x{self.kv_page_size} chunk={chunk_t}"
-        )
-        return fn
-
-    # -- unified serving plane (chunked prefill + CoW page sharing) --
-
     def _generate_inflight_serving(self, reqs, gconfig, key, results) -> None:
-        """`_generate_inflight_plain_paged` with admission folded INTO the
-        chunk step: an admitted prompt is consumed in `prefill_chunk_tokens`
-        (W)-sized slices by the same ragged compiled program that advances
-        live decodes, so admission never stalls running rows behind a
-        stop-the-world prefill and never compiles a second program —
-        decode_compiles stays 1 under continuous admission.  Same-prompt
+        """Fixed slot pool; retire finished rows and admit pending requests
+        between jitted T-step chunks.  Continuous batching over a paged KV
+        pool with admission folded INTO the chunk step: the pool and the chunk program have ONE fixed
+        shape for the whole generate call (compiled exactly once), window
+        growth is a host-side page-index append, and retired slots' pages
+        are recycled into new admits.  An admitted prompt is consumed in
+        `prefill_chunk_tokens` (W)-sized slices by the same ragged
+        compiled program that advances live decodes, so admission never
+        stalls running rows behind a stop-the-world prefill and never
+        compiles a second program — decode_compiles stays 1 under
+        continuous admission.  Same-prompt
         repeats (a GRPO group's k responses) share the owner's full prompt
         pages copy-on-write via the allocator's prefix cache, multiplying
         the pool's effective concurrency by ~the group size."""
@@ -1586,7 +975,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             pending=list(reversed(reqs)),
             slot_prompt={},
             last_emit=np.zeros((n_slots,), np.int32),
-            prefill_chunk=max(1, self.prefill_chunk_tokens),
+            prefill_chunk=self.prefill_chunk_tokens,
             prompt_buf=np.full((n_slots, pbw), self.pad_token_id, np.int32),
             prefill_rem=np.zeros((n_slots,), np.int32),
             prompt_off=np.zeros((n_slots,), np.int32),
@@ -1606,8 +995,11 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         could touch (CoW safety net), then runs ONE compiled ragged chunk
         in which prefilling rows consume W prompt tokens per inner step
         while decoding rows emit one token.  Interruptible at chunk
-        boundaries exactly like `_run_paged_loop` (returns False parked,
-        True finished)."""
+        boundaries: checks the interrupt event at the top of every
+        iteration and parks the whole session (device pool + host
+        bookkeeping) when set.  Returns True when all requests finished,
+        False when parked (self._session then holds the state for
+        resume_generate())."""
         gconfig = st.gconfig
         alloc = st.alloc
         n_slots, ps, chunk_t = st.n_slots, alloc.page_size, st.chunk_t
@@ -1658,7 +1050,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                         self._reserve_with_evict(alloc, s, target)
                 self._privatize_write_windows(st)
                 self._accum_pool_stats(
-                    "paged", int(st.cache_len.sum()), alloc.allocated_pages() * ps
+                    int(st.cache_len.sum()), alloc.allocated_pages() * ps
                 )
 
                 st.key, sub = jax.random.split(st.key)
@@ -1977,11 +1369,9 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         starved spec row verifies fewer drafts (`spec_accept` n_valid
         truncation — distribution-exact at any grant).
 
-        Like the legacy decode fn the signature depends only on pool
-        geometry + hyperparameters, so it compiles EXACTLY ONCE per
-        generate call even under continuous admission of mixed
-        prefill/decode/spec rows — the admission-shape zoo AND the
-        separate spec-decode program are gone.
+        The signature depends only on pool geometry + hyperparameters,
+        so it compiles EXACTLY ONCE per generate call even under
+        continuous admission of mixed prefill/decode/spec rows.
 
         Emission is FILL-INDEXED, not step-indexed: a row's sampled
         tokens pack contiguously from column 0 of its out row whatever
@@ -2045,9 +1435,9 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                         lg,
                     )
                 # Sampling consumes one fold_in(key, t) per inner step
-                # regardless of row mode, so the key chain matches the
-                # legacy decode chunk token-for-token on decode rows
-                # (prefilling rows' samples are discarded below).
+                # regardless of row mode, so a decode row's key chain does
+                # not depend on what other rows are doing (prefilling
+                # rows' samples are discarded below).
                 tok, logp = sample_token(
                     lg, sub,
                     temperature=g.temperature, top_k=g.top_k, top_p=g.top_p,
@@ -2082,9 +1472,8 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                         tokens_buf, cache_len + 1, K, g.spec_ngram
                     )  # [n_slots, K]
                 # Per-row lane want: a done/parked row wants ZERO lanes
-                # (its compute is eliminated from the stream, the legacy
-                # EOS-rewrite-in-place is gone), a prefilling row wants
-                # its next W-slice, a decoding row 1 (plain) or K+1
+                # (its compute is eliminated from the stream), a prefilling
+                # row wants its next W-slice, a decoding row 1 (plain) or K+1
                 # (pending + drafts).  Everybody gets their base lane
                 # (T >= n_slots); the spare splits front-to-back.
                 want = jnp.where(
@@ -2217,13 +1606,6 @@ class GeneratorEngine(HostOffloadMixin, Engine):
 
     # -- agent-serving episodes (multi-turn tool use on persistent KV) --
 
-    def _require_serving_plane(self) -> None:
-        if not (self.kv_paged and self.prefill_chunk_tokens > 0):
-            raise RuntimeError(
-                "episodes require the serving plane: kv_paged=True and "
-                "prefill_chunk_tokens > 0"
-            )
-
     def _episode_session_get(
         self, gconfig: GenerationHyperparameters, token_budget: int,
         seed: int,
@@ -2273,7 +1655,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             pending=[],
             slot_prompt={},
             last_emit=np.zeros((n_slots,), np.int32),
-            prefill_chunk=max(1, self.prefill_chunk_tokens),
+            prefill_chunk=self.prefill_chunk_tokens,
             prompt_buf=np.full((n_slots, pbw), self.pad_token_id, np.int32),
             prefill_rem=np.zeros((n_slots,), np.int32),
             prompt_off=np.zeros((n_slots,), np.int32),
@@ -2311,7 +1693,6 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         the call mid-turn (episode_resume() continues it)."""
         self._ensure_loaded()
         self._require_params()
-        self._require_serving_plane()
         st = self._episode_session_get(gconfig, token_budget, seed)
         if ep_id in st.episodes:
             raise ValueError(f"episode {ep_id!r} already live")
@@ -2413,37 +1794,8 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                 f"episode {ep_id!r} is not parked mid-turn"
             )
         ep.parked_mid_turn = False
-        s = ep.slot
-        Q = st.chunk_t
-        hist = np.concatenate(
-            [st.slot_prompt[s], np.asarray(st.toks_acc[s], np.int32)]
-        )
-        L = int(st.cache_len[s])
-        priv = int(st.shared_from[s])
-        r = int(min(max(int(st.last_emit[s]), 1), Q, L - priv))
-        if r > 0:
-            tokens = np.full((st.n_slots, Q), self.pad_token_id, np.int32)
-            positions = np.zeros((st.n_slots, Q), np.int32)
-            write_pos0 = np.zeros((st.n_slots,), np.int32)
-            take_idx = np.zeros((st.n_slots,), np.int32)
-            live_mask = np.zeros((st.n_slots,), bool)
-            q_lens = np.zeros((st.n_slots,), np.int32)
-            tokens[s, :r] = hist[L - r : L]
-            write_pos0[s] = L - r
-            positions[s] = (L - r) + np.arange(Q)
-            take_idx[s] = r - 1
-            live_mask[s] = True
-            q_lens[s] = r
-            with tracer.span("episode_resume_replay", cat="compute", n=1):
-                st.logits_buf, st.pool = self._get_paged_replay_fn(
-                    st.n_slots, st.n_pages, st.max_pages, Q
-                )(
-                    self.params, jnp.asarray(tokens),
-                    jnp.asarray(positions), st.pool,
-                    jnp.asarray(st.alloc.table), jnp.asarray(write_pos0),
-                    st.logits_buf, jnp.asarray(take_idx),
-                    jnp.asarray(live_mask), jnp.asarray(q_lens),
-                )
+        with tracer.span("episode_resume_replay", cat="compute", n=1):
+            self._replay_tails(st, [ep.slot])
         self.resume_replays += 1
         st.alloc.prefix_clear()
         st.inflight_prefix.clear()
@@ -2674,7 +2026,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                 self._reserve_with_evict(alloc, s, target)
             self._privatize_write_windows(st)
             self._accum_pool_stats(
-                "paged", int(st.cache_len.sum()),
+                int(st.cache_len.sum()),
                 alloc.allocated_pages() * alloc.page_size,
             )
             st.key, sub = jax.random.split(st.key)
@@ -2801,228 +2153,6 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             b"ep:" + st.slot_prompt[s][: sp * alloc.page_size].tobytes(),
             alloc.table[s, :sp],
         )
-
-    # -- speculative inflight (n-gram drafts + exact verification) --
-
-    def _generate_inflight_spec(self, reqs, g, key, results) -> None:
-        """Continuous batching with speculative decoding: each jitted step
-        consumes [pending, K drafts] in ONE forward (weight stream amortized
-        over up to K+1 emitted tokens); drafts come from self n-gram lookup
-        (ops/ngram.py) and are verified by exact rejection sampling
-        (ops/sampling.py spec_accept), so the emitted distribution equals
-        plain sampling.  Reference role: the SGLang server's speculative
-        decode config; correctness contract from ops/sampling tests."""
-        K = g.spec_decode_k
-        n_slots = min(max(self.batch_shard, self.max_decode_batch), len(reqs))
-        while n_slots % self.batch_shard:
-            n_slots += 1
-        max_prompt = max(len(t) for (_, _, t) in reqs)
-        n_steps = max(1, min(32, g.max_new_tokens) // (K + 1))
-        step_cap = n_steps * (K + 1)
-
-        cur_w = bucket_len(max_prompt + step_cap + K + 1)
-        # int8 stays distribution-exact here: drafts AND their exact
-        # verification both score against the quantized-cache model, so
-        # the emitted distribution equals plain decoding with this cache.
-        cache = tfm.init_kv_cache(
-            self.cfg, n_slots, cur_w,
-            dtype=(
-                "int8"
-                if self.kv_cache_dtype == "int8"
-                else self.compute_dtype
-            ),
-        )
-        # History buffer: prompt + emitted tokens per row (device-resident;
-        # the in-chunk n-gram proposal reads it).
-        tokens_buf = jnp.zeros((n_slots, cur_w + K + 2), jnp.int32)
-        pending = jnp.zeros((n_slots,), jnp.int32)
-        cache_len = np.zeros((n_slots,), np.int32)
-        gen_count = np.zeros((n_slots,), np.int32)
-        done_host = np.ones((n_slots,), bool)
-        active: List[Optional[Tuple[int, int]]] = [None] * n_slots
-        toks_acc: Dict[int, List[int]] = {}
-        logps_acc: Dict[int, List[float]] = {}
-        pending_list = list(reversed(reqs))
-
-        while pending_list or any(a is not None for a in active):
-            admits = self._take_admits(active, pending_list, n_slots)
-            if admits:
-                rows, plens, slots = self._pack_admits(admits, n_slots)
-                key, sub = jax.random.split(key)
-                with tracer.span("prefill", cat="compute", n=len(admits)):
-                    toks0, logps0, cache, tokens_buf, pending = (
-                        self._get_spec_admit_fn(g)(
-                            self.params, jnp.asarray(rows),
-                            jnp.asarray(plens), cache, tokens_buf, pending,
-                            jnp.asarray(slots), sub,
-                        )
-                    )
-                    self.prefill_dispatches += 1
-                    # ONE host sync per refill cycle (the eos/done flag must
-                    # be exact before the next chunk) — not one per
-                    # admission.
-                    toks0 = to_host(toks0)
-                    logps0 = to_host(logps0)
-                for j, (s, i, rep, toks) in enumerate(admits):
-                    t0 = int(toks0[j])
-                    cache_len[s] = len(toks)
-                    gen_count[s] = 1  # the sampled pending token
-                    done_host[s] = t0 == self.eos_token_id
-                    active[s] = (i, rep)
-                    toks_acc[s] = [t0]
-                    logps_acc[s] = [float(logps0[j])]
-
-            # Growth: a chunk can add up to step_cap entries (+K scratch).
-            need = int(cache_len.max()) + step_cap + K + 1
-            old_bytes = _cache_nbytes(cache)
-            cache, new_w = self._grow_kv_cache(cache, cur_w, need)
-            if new_w != cur_w:
-                self.cache_copy_bytes += old_bytes
-                tokens_buf = jnp.pad(
-                    tokens_buf,
-                    [(0, 0), (0, new_w + K + 2 - tokens_buf.shape[1])],
-                )
-                cur_w = new_w
-            self._accum_pool_stats(
-                "dense", int(cache_len.sum()), n_slots * cur_w
-            )
-
-            fn = self._get_spec_decode_fn(n_slots, cur_w, n_steps, g)
-            key, sub = jax.random.split(key)
-            with tracer.span("decode_chunk", cat="compute", t=step_cap):
-                (
-                    out_toks, out_logps, tokens_buf, cache, pending,
-                    new_cache_len, new_gen_count, new_done,
-                ) = fn(
-                    self.params, cache, tokens_buf, pending,
-                    jnp.asarray(cache_len), jnp.asarray(gen_count),
-                    jnp.asarray(done_host), sub,
-                )
-                out_toks = to_host(out_toks)
-                out_logps = to_host(out_logps)
-            cache_len = to_host(new_cache_len).copy()
-            gen_count = to_host(new_gen_count).copy()
-
-            self._drain_chunk_outputs(
-                out_toks, out_logps, to_host(new_done), active, toks_acc,
-                logps_acc, results, done_host, cache_len, g.max_new_tokens,
-                stop_seqs=g.stop,
-            )
-
-    def _get_spec_admit_fn(self, g):
-        sig = ("spec_admit", g.greedy, g.top_p, g.top_k, g.temperature,
-               g.min_new_tokens)
-        if sig in self._gen_fns:
-            return self._gen_fns[sig]
-        cfg = self.cfg
-        eos = self.eos_token_id
-        use_flash = (
-            False if isinstance(self._use_flash, Mesh) else self._use_flash
-        )
-
-        # Batched admission (see _pack_admits): prefill every admitted
-        # prompt, sample its first pending token, and record prompt+token
-        # into the device-resident history buffer — all in one dispatch.
-        # jit re-specializes per (M, SP, buf_w) shape; padding rows scatter
-        # out of range and are dropped.
-        @functools.partial(jax.jit, donate_argnums=(3, 4, 5))
-        def fn(params, rows, plens, cache, tokens_buf, pending, slot_rows,
-               key):
-            sp = rows.shape[1]
-            logits, cache = tfm.prefill_into_slots(
-                params, cfg, rows, plens, cache, slot_rows,
-                use_flash=use_flash,
-            )
-            lg = logits
-            if g.min_new_tokens > 0:
-                lg = jnp.where(
-                    (jnp.arange(cfg.vocab_size) == eos)[None, :], -1e10, lg
-                )
-            tok, logp = sample_token(
-                lg, key, temperature=g.temperature, top_k=g.top_k,
-                top_p=g.top_p, greedy=g.greedy,
-            )
-            tokens_buf = tokens_buf.at[slot_rows, :sp].set(rows, mode="drop")
-            tokens_buf = tokens_buf.at[slot_rows, plens].set(tok, mode="drop")
-            pending = pending.at[slot_rows].set(tok, mode="drop")
-            return tok, logp, cache, tokens_buf, pending
-
-        self._gen_fns[sig] = fn
-        return fn
-
-    def _get_spec_decode_fn(
-        self, n_slots: int, s_max: int, n_steps: int,
-        g: GenerationHyperparameters,
-    ):
-        K = g.spec_decode_k
-        sig = (
-            "spec_decode", n_slots, s_max, n_steps, K, g.spec_ngram,
-            g.min_new_tokens, g.greedy, g.top_p, g.top_k, g.temperature,
-        )
-        if sig in self._gen_fns:
-            return self._gen_fns[sig]
-        cfg = self.cfg
-        eos = self.eos_token_id
-        from areal_tpu.ops.ngram import propose_ngram
-
-        out_w = n_steps * (K + 1)
-        rows = jnp.arange(n_slots)
-
-        @functools.partial(jax.jit, donate_argnums=(1, 2))
-        def fn(params, cache, tokens_buf, pending, cache_len, gen_count,
-               done, key):
-            out_toks = jnp.full((n_slots, out_w), -1, jnp.int32)
-            out_logps = jnp.zeros((n_slots, out_w), jnp.float32)
-            out_fill = jnp.zeros((n_slots,), jnp.int32)
-
-            def body(t, st):
-                (cache, tokens_buf, pending, cache_len, gen_count, done,
-                 out_toks, out_logps, out_fill) = st
-                drafts = propose_ngram(
-                    tokens_buf, cache_len + 1, K, g.spec_ngram
-                )  # [B, K]
-                inputs = jnp.concatenate(
-                    [pending[:, None], drafts], axis=1
-                )  # [B, K+1]
-                slots0 = jnp.minimum(cache_len, s_max - 1 - K)
-                positions = slots0[:, None] + jnp.arange(K + 1)[None, :]
-                logits, cache2 = tfm.decode_step_spec(
-                    params, cfg,
-                    jnp.where(done[:, None], eos, inputs),
-                    positions, cache, slots0,
-                )  # [B, K+1, V]
-                sub = jax.random.fold_in(key, t)
-                (
-                    tokens_buf, pending2, cache_len2, gen_count2, new_done,
-                    out_toks, out_logps, out_fill,
-                ) = _spec_emit(
-                    cfg, g, eos, rows, logits, drafts, sub, pending,
-                    cache_len, gen_count, done, out_toks, out_logps,
-                    out_fill, tokens_buf,
-                )
-                return (
-                    cache2, tokens_buf, pending2, cache_len2, gen_count2,
-                    new_done, out_toks, out_logps, out_fill,
-                )
-
-            st = (cache, tokens_buf, pending, cache_len, gen_count, done,
-                  out_toks, out_logps, out_fill)
-            st = jax.lax.fori_loop(0, n_steps, body, st)
-            (cache, tokens_buf, pending, cache_len, gen_count, done,
-             out_toks, out_logps, _) = st
-            return (
-                out_toks, out_logps, tokens_buf, cache, pending,
-                cache_len, gen_count, done,
-            )
-
-        self._gen_fns[sig] = fn
-        self.decode_compiles += 1
-        self._m_decode_compiles.inc()
-        logger.info(
-            f"compiled spec decoder n_slots={n_slots} s_max={s_max} "
-            f"steps={n_steps} K={K}"
-        )
-        return fn
 
     @property
     def _expert_leaves_in_place(self) -> bool:

@@ -250,24 +250,43 @@ def check_ppo_math(cfg) -> None:
             f"kv_pool_pages must be >= 0 (0 = auto-size), got "
             f"{cfg.kv_pool_pages}"
         )
-    pct = getattr(cfg, "prefill_chunk_tokens", None)
-    if pct is not None and pct < 0:
+    gba = getattr(cfg, "gen_backend_args", None) or {}
+    if gba and not cfg.gen_server_url:
+        # gen_backend_args reach GeneratorEngine(**kwargs) inside a worker;
+        # an option that no longer exists (or a typo) fails here instead.
+        import inspect
+
+        from areal_tpu.engines.generator import GeneratorEngine
+
+        known = set(inspect.signature(GeneratorEngine.__init__).parameters)
+        unknown = sorted(set(gba) - known)
+        if unknown:
+            _fail(
+                f"gen_backend_args {unknown} are not GeneratorEngine "
+                f"options (continuous batching always runs on the paged "
+                f"serving plane; the options that selected the dense and "
+                f"two-program inflight paths were removed)"
+            )
+    pct = gba.get(
+        "prefill_chunk_tokens", getattr(cfg, "prefill_chunk_tokens", 8)
+    )
+    if pct < 1:
         _fail(
-            f"prefill_chunk_tokens must be >= 0 (0 = legacy two-program "
-            f"admit, None = env default), got {pct}"
+            f"prefill_chunk_tokens must be >= 1 (the serving chunk's "
+            f"prefill slice width; the two-program admit path that 0 "
+            f"selected was removed), got {pct}"
         )
     if cfg.gen_server_url and (
-        getattr(cfg, "kv_paged", None) is not None
-        or getattr(cfg, "kv_page_size", 128) != 128
+        getattr(cfg, "kv_page_size", 128) != 128
         or getattr(cfg, "kv_pool_pages", 0)
-        or getattr(cfg, "prefill_chunk_tokens", None) is not None
-        or getattr(cfg, "kv_share_prefix", None) is not None
+        or getattr(cfg, "prefill_chunk_tokens", 8) != 8
+        or not getattr(cfg, "kv_share_prefix", True)
     ):
         # Same reasoning as gen_backend_args below: these configure the
         # in-process GeneratorEngine, which decoupled serving never
         # builds — a silently ignored capacity knob is a footgun.
         _fail(
-            "kv_paged/kv_page_size/kv_pool_pages/prefill_chunk_tokens/"
+            "kv_page_size/kv_pool_pages/prefill_chunk_tokens/"
             "kv_share_prefix apply to the in-process GeneratorEngine "
             "and are ignored under gen_server_url (configure the "
             "standalone gen_server instead)"

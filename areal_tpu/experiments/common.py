@@ -296,25 +296,22 @@ class PPOMathConfig:
     gen_backend_args: Dict[str, Any] = dataclasses.field(
         default_factory=dict
     )
-    # Paged-KV decode knobs (engines/generator.py): None = env default
-    # (AREAL_PAGED_KV, on unless "0"); False = dense grow-by-doubling
-    # window.  kv_pool_pages=0 auto-sizes the pool for the worst case;
-    # a positive value caps KV HBM and makes admission wait for freed
-    # pages (gen_server splits request groups against the resulting
-    # token budget).  gen_backend_args may still override all three.
-    kv_paged: Optional[bool] = None
+    # Serving-plane page pool (engines/generator.py): kv_pool_pages=0
+    # auto-sizes the pool for the worst case; a positive value caps KV
+    # HBM and makes admission wait for freed pages (gen_server splits
+    # request groups against the resulting token budget).
+    # gen_backend_args may still override both.
     kv_page_size: int = 128
     kv_pool_pages: int = 0
-    # Serving-plane knobs: prefill_chunk_tokens>0 folds admission
-    # prefill INTO the decode chunk (one compiled program, no admission
-    # stall); 0 = legacy two-program admit; None = env default
-    # (AREAL_PREFILL_CHUNK_TOKENS).  kv_share_prefix maps a group's common
-    # prompt pages copy-on-write across rows (None = on when serving).
-    prefill_chunk_tokens: Optional[int] = None
-    kv_share_prefix: Optional[bool] = None
+    # prefill_chunk_tokens (>= 1) is the slice width W in which an
+    # admitted prompt is consumed inside the serving chunk (one compiled
+    # program, no admission stall).  kv_share_prefix maps a group's
+    # common prompt pages copy-on-write across rows.
+    prefill_chunk_tokens: int = 8
+    kv_share_prefix: bool = True
     # Extra TrainEngine kwargs for actor/critic (remat_policy,
     # master_dtype, pipe_schedule) — the single-chip 1.5B fit needs
-    # master_dtype="bfloat16" here, exactly like bench.py.
+    # master_dtype="bfloat16" here (as benchmark/run.py's PLAN sets it).
     train_backend_args: Dict[str, Any] = dataclasses.field(
         default_factory=dict
     )
@@ -705,7 +702,6 @@ def build_ppo_math(cfg: PPOMathConfig, tokenizer=None) -> ExperimentPlan:
                         "donation_safe_swap": cfg.rollout_ahead > 0
                         or cfg.max_head_offpolicyness is not None
                         or cfg.pipeline_overlap,
-                        "kv_paged": cfg.kv_paged,
                         "kv_page_size": cfg.kv_page_size,
                         "kv_pool_pages": cfg.kv_pool_pages,
                         "prefill_chunk_tokens": cfg.prefill_chunk_tokens,

@@ -20,6 +20,11 @@ Functions:
     init_kv_cache(cfg, b, s_max)                           -> cache
     prefill(params, cfg, tokens, segment_ids, cache)
     decode_step(params, cfg, tokens, positions, cache, slot, valid_from)
+        — the static decode program's step over a dense window
+    init_paged_kv_cache(cfg, n_pages, page_size)           -> pool
+    decode_step_ragged_paged(params, cfg, tokens, positions, pool,
+                             page_table, row_of)
+        — the serving plane's step over a packed token stream
 """
 
 import dataclasses
@@ -33,10 +38,7 @@ from jax.ad_checkpoint import checkpoint_name
 from areal_tpu.models.config import ModelConfig
 from areal_tpu.ops.attention import (
     decode_attention,
-    decode_attention_chunk,
     packed_attention,
-    paged_decode_attention,
-    paged_decode_attention_chunk,
     ragged_paged_attention,
     repeat_kv,
 )
@@ -786,35 +788,20 @@ def forward_with_aux(
 
 @dataclasses.dataclass
 class KVCache:
-    """Dense per-layer KV cache: k/v [L, B, S_max, n_kv, head_dim].
-
-    int8 mode (k/v int8 + per-(layer,row,slot,head) bf16 scales in
-    k_scale/v_scale): HALVES the HBM bytes per cached token.  At long
-    context the decode batch × window product is capacity-bound — a 1.5B
-    model's bf16 cache at batch 32 × 16k window is ~15 GB and does not
-    fit a 16 GB chip at all; int8 does.  Scales add 1/head_dim overhead.
-    (Bandwidth parity, not win: without a fused dequant-attention kernel
-    the read path materializes a bf16 layer view — the saving is
-    capacity and the cache WRITE stream.)  Reference role: KV-cache
-    quantization knobs in serving engines (sglang).
-    """
+    """Dense per-layer KV cache of the static decode program: k/v
+    [L, B, S_max, n_kv, head_dim], full precision (its windows are small;
+    the int8 mode lives on the serving plane's `PagedKVCache`)."""
 
     k: jax.Array
     v: jax.Array
-    k_scale: "jax.Array | None" = None  # [L, B, S_max, n_kv] bf16
-    v_scale: "jax.Array | None" = None
 
     @property
     def s_max(self) -> int:
         return self.k.shape[2]
 
-    @property
-    def quantized(self) -> bool:
-        return self.k_scale is not None
-
 
 jax.tree_util.register_dataclass(
-    KVCache, data_fields=["k", "v", "k_scale", "v_scale"], meta_fields=[]
+    KVCache, data_fields=["k", "v"], meta_fields=[]
 )
 
 
@@ -823,26 +810,16 @@ jax.tree_util.register_dataclass(
 from areal_tpu.ops.quant import kv_dequant, kv_quant  # noqa: E402,F401
 
 
-def _cache_update_read(
-    kc, vc, ksc, vsc, k, v, li, idx, quant: bool, read_dtype,
-    dequant: bool = True,
-):
-    """Shared cache write + layer read for the decode steps: scatter the
-    new K/V entries at `(li, *idx)` (quantizing when the cache is int8)
-    and return the layer's K/V views.  One implementation for the plain
-    and speculative paths so a quantization change can never silently
-    diverge their distributions.
+def _cache_update_read(kc, vc, ksc, vsc, k, v, li, idx, quant: bool):
+    """Pool write + layer read for the paged decode step: scatter the new
+    K/V entries at `(li, *idx)` (quantizing when the pool is int8) and
+    return the layer's RAW K/V views plus the layer's scales (or None) —
+    the attention op dequantizes itself (in-kernel under
+    AREAL_DECODE_KERNEL=1, saving the extra bf16 window materialization
+    where bandwidth is the bottleneck).
 
-    dequant=True materializes bf16/f32 layer views (consumers that only
-    take dense operands); dequant=False returns the RAW views plus the
-    layer's scales (or None) — for `decode_attention`, which dequantizes
-    itself (in-kernel under AREAL_DECODE_KERNEL=1, saving the extra
-    bf16 window materialization where bandwidth is the bottleneck).
-
-    Out-of-range indices are DROPPED (the paged path writes through a
-    page table whose unmapped entries are the sentinel `n_pages`; the
-    dense paths always index in bounds, where `mode="drop"` is a
-    no-op)."""
+    Out-of-range indices are DROPPED (writes go through a page table whose
+    unmapped entries are the sentinel `n_pages`)."""
     if quant:
         kq, ks = kv_quant(k)
         vq, vs = kv_quant(v)
@@ -854,10 +831,6 @@ def _cache_update_read(
         vs_l = jax.lax.dynamic_index_in_dim(vsc, li, axis=0, keepdims=False)
         k_raw = jax.lax.dynamic_index_in_dim(kc, li, axis=0, keepdims=False)
         v_raw = jax.lax.dynamic_index_in_dim(vc, li, axis=0, keepdims=False)
-        if dequant:
-            k_layer = kv_dequant(k_raw, ks_l, read_dtype)
-            v_layer = kv_dequant(v_raw, vs_l, read_dtype)
-            return kc, vc, ksc, vsc, k_layer, v_layer, None, None
         return kc, vc, ksc, vsc, k_raw, v_raw, ks_l, vs_l
     kc = kc.at[(li, *idx)].set(k.astype(kc.dtype), mode="drop")
     vc = vc.at[(li, *idx)].set(v.astype(vc.dtype), mode="drop")
@@ -871,13 +844,6 @@ def init_kv_cache(
 ) -> KVCache:
     shape = (cfg.n_layers, batch, s_max, cfg.n_kv_heads, cfg.head_dim)
     dtype = dtype or cfg.dtype
-    if dtype in (jnp.int8, "int8"):
-        return KVCache(
-            k=jnp.zeros(shape, jnp.int8),
-            v=jnp.zeros(shape, jnp.int8),
-            k_scale=jnp.zeros(shape[:-1], jnp.bfloat16),
-            v_scale=jnp.zeros(shape[:-1], jnp.bfloat16),
-        )
     return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
 
 
@@ -910,22 +876,12 @@ def prefill(
     segment_ids: jax.Array,  # [B, S] 1 where valid, 0 pad (single segment/row)
     cache: KVCache,
     use_flash: "bool | None" = None,
-    quantize_kv: bool = False,
 ) -> Tuple[jax.Array, KVCache]:
     """Run the prompt through the model, filling cache[:, :, :S] and
     returning fp32 logits [B, V] at each row's LAST VALID position (the
     distribution over the first generated token).  Computing the head only
     there keeps prefill memory at [B, V] instead of [B, S, V] — at a 152k
-    vocab that is the difference between 40 MB and 10 GB.
-
-    quantize_kv=True (requires an int8 `cache` with scales) quantizes each
-    layer's fresh K/V ONCE and attends over the DEQUANTIZED values —
-    "quantize once, attend dequantized".  That makes prefill numerically
-    identical to feeding the same tokens through the chunked decode path
-    (which always reads its just-written quantized pool): every attention
-    read anywhere sees dequant(quant(fresh)), so int8 generation is
-    chunk-boundary-invariant instead of depending on how much of the
-    prompt was prefilled in one shot."""
+    vocab that is the difference between 40 MB and 10 GB."""
     positions = positions_from_segments(segment_ids)
     x = _embed(params, cfg, tokens, positions)
     cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
@@ -934,53 +890,24 @@ def prefill(
         blk = layer_in
         h = _norm(carry, blk["ln1"], blk.get("ln1_b"), cfg)
         q, k, v = _block_kv(h, blk, cfg, cos, sin)
-        if quantize_kv:
-            kq, ksc = kv_quant(k)
-            vq, vsc = kv_quant(v)
-            k_at = kv_dequant(kq, ksc, k.dtype)
-            v_at = kv_dequant(vq, vsc, v.dtype)
-            out = (kq, ksc, vq, vsc)
-        else:
-            k_at, v_at = k, v
-            out = (k, v)
         attn = packed_attention(
-            q, k_at, v_at, segment_ids, causal=True, use_flash=use_flash
+            q, k, v, segment_ids, causal=True, use_flash=use_flash
         )
         y = _attn_out(attn.reshape(*carry.shape[:2], cfg.q_dim), blk, cfg)
         y = carry + y
         h2 = _norm(y, blk["ln2"], blk.get("ln2_b"), cfg)
         y = y + (_mlp_moe(h2, blk, cfg)[0] if cfg.is_moe else _mlp_dense(h2, blk, cfg))
-        return y, out
+        return y, (k, v)
 
-    if quantize_kv:
-        x, (kq, ksc, vq, vsc) = jax.lax.scan(body, x, params["blocks"])
-        # Emit int8 + scales DIRECTLY: re-quantizing a dequantized value
-        # is not idempotent (round(126*s/127 / s') flips codes), so the
-        # codes produced here are the ones every later read must see.
-        new_cache = KVCache(
-            k=jax.lax.dynamic_update_slice(
-                cache.k, kq.astype(cache.k.dtype), (0, 0, 0, 0, 0)
-            ),
-            v=jax.lax.dynamic_update_slice(
-                cache.v, vq.astype(cache.v.dtype), (0, 0, 0, 0, 0)
-            ),
-            k_scale=jax.lax.dynamic_update_slice(
-                cache.k_scale, ksc.astype(cache.k_scale.dtype), (0, 0, 0, 0)
-            ),
-            v_scale=jax.lax.dynamic_update_slice(
-                cache.v_scale, vsc.astype(cache.v_scale.dtype), (0, 0, 0, 0)
-            ),
-        )
-    else:
-        x, (ks, vs) = jax.lax.scan(body, x, params["blocks"])
-        new_cache = KVCache(
-            k=jax.lax.dynamic_update_slice(
-                cache.k, ks.astype(cache.k.dtype), (0, 0, 0, 0, 0)
-            ),
-            v=jax.lax.dynamic_update_slice(
-                cache.v, vs.astype(cache.v.dtype), (0, 0, 0, 0, 0)
-            ),
-        )
+    x, (ks, vs) = jax.lax.scan(body, x, params["blocks"])
+    new_cache = KVCache(
+        k=jax.lax.dynamic_update_slice(
+            cache.k, ks.astype(cache.k.dtype), (0, 0, 0, 0, 0)
+        ),
+        v=jax.lax.dynamic_update_slice(
+            cache.v, vs.astype(cache.v.dtype), (0, 0, 0, 0, 0)
+        ),
+    )
     x = _final_norm(params, cfg, x)
     # Gather each row's last valid hidden state before the (huge) head matmul.
     # (index of the last nonzero segment: works for left- and right-aligned
@@ -1067,220 +994,6 @@ def decode_step(
     return logits, KVCache(k=kc, v=vc)
 
 
-@jax.named_scope("gen/decode_step")
-def decode_step_inflight(
-    params: Params,
-    cfg: ModelConfig,
-    tokens: jax.Array,  # [B] int32
-    positions: jax.Array,  # [B] int32 RoPE positions
-    cache: KVCache,
-    slots: jax.Array,  # [B] int32 — per-row cache write slot
-    valid_to: jax.Array,  # [B] int32 — one past the last valid slot (incl. new)
-    unroll: bool = False,
-) -> Tuple[jax.Array, KVCache]:
-    """Decode step with PER-ROW write slots (left-aligned rows), for the
-    continuous-batching generator where rows start/stop independently and
-    therefore sit at different cache depths.  The per-row write is a vmapped
-    `dynamic_update_slice` (a small scatter — [B, n_kv, d] per layer), not a
-    full-cache rewrite.  Reference: InflightBatchingGenerator's per-slot
-    cache bookkeeping (realhf/impl/model/nn/real_llm_generate.py:670).
-
-    unroll=True trades compile time for HBM traffic: the scan's dynamic
-    per-layer cache read (`dynamic_index_in_dim` with a traced index)
-    cannot fuse into the attention dot on TPU, so every layer's K and V
-    windows are materialized as full HLO temps EVERY step — at 1.5B/b=32
-    that extra write+read is comparable to streaming the weights and is
-    the measured gap between decode and its roofline.  A python-level
-    layer loop with STATIC indices lets XLA read the cache windows in
-    place (leading-axis static slices alias) and update them in place."""
-    b = tokens.shape[0]
-    x = _embed(params, cfg, tokens, positions)[:, None, :]
-    cos, sin = rope_cos_sin(positions[:, None], cfg.head_dim, cfg.rope_theta)
-    zero_from = jnp.zeros((b,), jnp.int32)
-
-    rows = jnp.arange(b)
-    quant = cache.quantized  # trace-time static
-
-    def body(carry, blk, li=None):
-        y, kc, vc, ksc, vsc, dyn_li = carry
-        li_ = dyn_li if li is None else li
-        h = _norm(y, blk["ln1"], blk.get("ln1_b"), cfg)
-        q, k, v = _block_kv(h, blk, cfg, cos, sin)
-        # Direct scatter of the B new entries at (layer, row, slots[row]) —
-        # in place on the scan carry.  The earlier formulation materialized
-        # and wrote back a WHOLE [B, S, h, d] layer per token (~GBs/token
-        # of pure HBM traffic at 1.5B scale).
-        kc, vc, ksc, vsc, k_layer, v_layer, ks_l, vs_l = (
-            _cache_update_read(
-                kc, vc, ksc, vsc, k[:, 0], v[:, 0], li_, (rows, slots),
-                quant, q.dtype, dequant=False,
-            )
-        )
-        attn = decode_attention(
-            q, k_layer, v_layer, zero_from, valid_to,
-            k_scale=ks_l, v_scale=vs_l,
-        )
-        ao = _attn_out(attn.reshape(b, 1, cfg.q_dim), blk, cfg)
-        y = y + ao
-        h2 = _norm(y, blk["ln2"], blk.get("ln2_b"), cfg)
-        y = y + (_mlp_moe(h2, blk, cfg)[0] if cfg.is_moe else _mlp_dense(h2, blk, cfg))
-        return (y, kc, vc, ksc, vsc, dyn_li + 1), None
-
-    # Scale carries: zero-size placeholders when unquantized keep ONE
-    # carry structure for both modes.
-    ksc0 = cache.k_scale if quant else jnp.zeros((0,), jnp.bfloat16)
-    vsc0 = cache.v_scale if quant else jnp.zeros((0,), jnp.bfloat16)
-    if unroll:
-        carry = (x, cache.k, cache.v, ksc0, vsc0, jnp.int32(0))
-        for li in range(cfg.n_layers):
-            blk = jax.tree.map(lambda a: a[li], params["blocks"])
-            carry, _ = body(carry, blk, li=li)
-        x, kc, vc, ksc, vsc, _ = carry
-    else:
-        (x, kc, vc, ksc, vsc, _), _ = jax.lax.scan(
-            body,
-            (x, cache.k, cache.v, ksc0, vsc0, jnp.int32(0)),
-            params["blocks"],
-        )
-    x = _final_norm(params, cfg, x)
-    logits = _head(params, cfg, x)[:, 0]
-    return logits, KVCache(
-        k=kc, v=vc,
-        k_scale=ksc if quant else None,
-        v_scale=vsc if quant else None,
-    )
-
-
-@jax.named_scope("gen/decode_step")
-def decode_step_spec(
-    params: Params,
-    cfg: ModelConfig,
-    tokens: jax.Array,  # [B, Q] int32 — pending token + Q-1 drafts per row
-    positions: jax.Array,  # [B, Q] int32 — RoPE positions
-    cache: KVCache,
-    slots0: jax.Array,  # [B] int32 — write slot of tokens[:, 0]
-) -> Tuple[jax.Array, KVCache]:
-    """Speculative decode step: consume Q consecutive tokens per row in ONE
-    forward, writing their k/v at slots0..slots0+Q-1 and returning fp32
-    logits [B, Q, V] (logits[:, j] = next-token distribution after
-    tokens[:, :j+1]).  The Q-1 drafted inputs amortize a full weight stream
-    over up to Q accepted tokens — the decode-bandwidth win speculative
-    decoding exists for.  Rejected drafts leave stale cache entries past
-    the accepted prefix; they are overwritten when those positions are
-    consumed for real (left-aligned per-row layout, as
-    `decode_step_inflight`)."""
-    b, q_len = tokens.shape
-    x = _embed(params, cfg, tokens.reshape(-1), positions.reshape(-1))
-    x = x.reshape(b, q_len, cfg.hidden_dim)
-    cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
-    rows = jnp.arange(b)
-    col_idx = slots0[:, None] + jnp.arange(q_len)[None, :]  # [B, Q]
-    quant = cache.quantized  # int8 is SOUND here: drafts and exact
-    # verification both score against the quantized-cache model, so the
-    # emitted distribution equals plain decoding with the same cache.
-
-    def body(carry, blk):
-        y, kc, vc, ksc, vsc, li = carry
-        h = _norm(y, blk["ln1"], blk.get("ln1_b"), cfg)
-        q, k, v = _block_kv(h, blk, cfg, cos, sin)  # [B, Q, h, d]
-        kc, vc, ksc, vsc, k_layer, v_layer, ks_l, vs_l = (
-            _cache_update_read(
-                kc, vc, ksc, vsc, k, v, li, (rows[:, None], col_idx),
-                quant, q.dtype, dequant=False,
-            )
-        )
-        attn = decode_attention_chunk(
-            q, k_layer, v_layer,
-            jnp.zeros((b,), jnp.int32), slots0 + 1,
-            k_scale=ks_l, v_scale=vs_l,
-        )
-        ao = _attn_out(attn.reshape(b, q_len, cfg.q_dim), blk, cfg)
-        y = y + ao
-        h2 = _norm(y, blk["ln2"], blk.get("ln2_b"), cfg)
-        y = y + (
-            _mlp_moe(h2, blk, cfg)[0] if cfg.is_moe else _mlp_dense(h2, blk, cfg)
-        )
-        return (y, kc, vc, ksc, vsc, li + 1), None
-
-    ksc0 = cache.k_scale if quant else jnp.zeros((0,), jnp.bfloat16)
-    vsc0 = cache.v_scale if quant else jnp.zeros((0,), jnp.bfloat16)
-    (x, kc, vc, ksc, vsc, _), _ = jax.lax.scan(
-        body,
-        (x, cache.k, cache.v, ksc0, vsc0, jnp.int32(0)),
-        params["blocks"],
-    )
-    x = _final_norm(params, cfg, x)
-    logits = _head(params, cfg, x)  # [B, Q, V]
-    return logits, KVCache(
-        k=kc, v=vc,
-        k_scale=ksc if quant else None,
-        v_scale=vsc if quant else None,
-    )
-
-
-@jax.named_scope("gen/prefill")
-def prefill_into_slots(
-    params: Params,
-    cfg: ModelConfig,
-    tokens: jax.Array,  # [M, SP] left-aligned prompts (padding right)
-    prompt_lens: jax.Array,  # [M] int32
-    cache: KVCache,  # [L, n_slots, s_max, h, d]
-    slot_rows: jax.Array,  # [M] int32 — target cache row per prompt
-    use_flash: "bool | None" = None,
-) -> Tuple[jax.Array, KVCache]:
-    """Prefill M requests into their cache rows in ONE forward; returns fp32
-    logits [M, V] at each row's last prompt token.  The inflight generator
-    admits every freed slot of a refill cycle through one call here instead
-    of M serial batch-1 prefills (the reference batches admissions the same
-    way inside SGLang's scheduler, sglang.py:267-352).  Rows whose
-    `slot_rows` entry is out of range (>= n_slots) are compile-shape padding:
-    their cache/notebook scatters are dropped (`mode="drop"`) and their
-    logits are garbage the caller ignores."""
-    m, sp = tokens.shape
-    seg = (
-        jnp.arange(sp)[None, :] < prompt_lens[:, None]
-    ).astype(jnp.int32)
-    row_cache = _prefill_row_cache(cfg, m, sp, cache)
-    logits, row_cache = prefill(
-        params, cfg, tokens, seg, row_cache, use_flash=use_flash,
-        quantize_kv=cache.quantized,
-    )
-    if cache.quantized:
-        # The prefill already quantized once and attended dequantized —
-        # scatter its CODES as-is (re-quantizing here would flip codes
-        # and break parity with the chunked serving admission).
-        return logits, KVCache(
-            k=cache.k.at[:, slot_rows, :sp].set(row_cache.k, mode="drop"),
-            v=cache.v.at[:, slot_rows, :sp].set(row_cache.v, mode="drop"),
-            k_scale=cache.k_scale.at[:, slot_rows, :sp].set(
-                row_cache.k_scale, mode="drop"
-            ),
-            v_scale=cache.v_scale.at[:, slot_rows, :sp].set(
-                row_cache.v_scale, mode="drop"
-            ),
-        )
-    new_k = cache.k.at[:, slot_rows, :sp].set(row_cache.k, mode="drop")
-    new_v = cache.v.at[:, slot_rows, :sp].set(row_cache.v, mode="drop")
-    return logits, KVCache(k=new_k, v=new_v)
-
-
-def _prefill_row_cache(cfg: ModelConfig, m: int, sp: int, cache) -> KVCache:
-    """Scratch per-row dense cache for a batched admission prefill,
-    matching the target cache's quantization (int8 codes + scales when
-    the target pool is int8, so the scatters move codes verbatim)."""
-    shape = (cfg.n_layers, m, sp, cfg.n_kv_heads, cfg.head_dim)
-    if cache.quantized:
-        return KVCache(
-            k=jnp.zeros(shape, jnp.int8),
-            v=jnp.zeros(shape, jnp.int8),
-            k_scale=jnp.zeros(shape[:-1], jnp.bfloat16),
-            v_scale=jnp.zeros(shape[:-1], jnp.bfloat16),
-        )
-    return KVCache(
-        k=jnp.zeros(shape, cache.k.dtype), v=jnp.zeros(shape, cache.k.dtype)
-    )
-
-
 # --------------------------------------------------------------------------
 # Paged KV-cache generation path
 # --------------------------------------------------------------------------
@@ -1290,15 +1003,14 @@ def _prefill_row_cache(cfg: ModelConfig, m: int, sp: int, cache) -> KVCache:
 class PagedKVCache:
     """Block-paged KV pool: k/v [L, n_pages, page_size, n_kv, head_dim].
 
-    The dense inflight cache (`KVCache` at [L, n_slots, s_max, ...])
-    couples every slot to the batch-max window: growth is a full-cache
-    `jnp.pad` copy plus a decode recompile per bucket, and a finished
-    short row keeps holding s_max worth of HBM until the batch drains.
-    Paging breaks the coupling: the pool is allocated ONCE per generate
-    call, each slot owns an ordered list of pages (the host-side page
-    table), growth appends a page index, and a retired slot's pages are
-    recycled into new admits — fixed memory, fixed shapes, one decode
-    compilation.  Reference: TPU ragged paged attention / vLLM
+    A dense cache at [L, n_slots, s_max, ...] would couple every slot
+    to the batch-max window: growth a full-cache copy plus a decode
+    recompile per bucket, and a finished short row holding s_max worth
+    of HBM until the batch drains.  Paging breaks the coupling: the pool
+    is allocated ONCE per generate call, each slot owns an ordered list
+    of pages (the host-side page table), growth appends a page index,
+    and a retired slot's pages are recycled into new admits — fixed
+    memory, fixed shapes, one decode compilation.  Reference: TPU ragged paged attention / vLLM
     PagedAttention block tables.
 
     Page index `n_pages` is the UNMAPPED sentinel: writes through it are
@@ -1306,9 +1018,15 @@ class PagedKVCache:
     (pages are mapped contiguously from position 0, so any position
     beyond the mapped prefix is also beyond the live window).
 
-    int8 mode mirrors `KVCache`: int8 k/v + bf16 per-(layer,page,pos,
-    head) scales — same capacity halving, same quantizer
-    (`ops/quant.py`), so paged and dense int8 cannot diverge.
+    int8 mode (k/v int8 + bf16 per-(layer,page,pos,head) scales in
+    k_scale/v_scale, quantizer `ops/quant.py`) HALVES the HBM bytes per
+    cached token: at long context the decode batch × window product is
+    capacity-bound — a 1.5B model's bf16 KV at batch 32 × 16k window is
+    ~15 GB and does not fit a 16 GB chip at all; int8 does.  Scales add
+    1/head_dim overhead.  Fresh K/V is quantized ONCE when written and
+    every later read sees the stored codes, so chunk boundaries cannot
+    move the numerics.  Reference role: KV-cache quantization knobs in
+    serving engines (sglang).
     """
 
     k: jax.Array
@@ -1353,156 +1071,17 @@ def init_paged_kv_cache(
 
 
 def _page_of(page_table: jax.Array, pos: jax.Array, page_size: int):
-    """Per-row (page, offset) write coordinates for flat positions `pos`
-    ([B] or [B, Q]) through `page_table` [B, max_pages]."""
-    pos2 = pos if pos.ndim == 2 else pos[:, None]
+    """Per-token (page, offset) write coordinates for flat positions
+    `pos` [T] through the per-token `page_table` [T, max_pages]."""
+    pos2 = pos[:, None]
     pages = jnp.take_along_axis(
         page_table, pos2 // page_size, axis=1, mode="clip"
     )
     # Positions addressing beyond the table width must DROP, not alias
     # the clipped last entry (2**30 is out of range of any pool axis).
     oob = pos2 // page_size >= page_table.shape[1]
-    pages = jnp.where(oob, jnp.int32(2**30), pages)
-    pages = pages if pos.ndim == 2 else pages[:, 0]
+    pages = jnp.where(oob, jnp.int32(2**30), pages)[:, 0]
     return pages.astype(jnp.int32), (pos % page_size).astype(jnp.int32)
-
-
-@jax.named_scope("gen/decode_step")
-def decode_step_paged(
-    params: Params,
-    cfg: ModelConfig,
-    tokens: jax.Array,  # [B] int32
-    positions: jax.Array,  # [B] int32 RoPE positions
-    cache: PagedKVCache,
-    page_table: jax.Array,  # [B, max_pages] int32, sentinel = n_pages
-    write_pos: jax.Array,  # [B] int32 — flat cache position to write
-    valid_to: jax.Array,  # [B] int32 — one past the last valid position
-) -> Tuple[jax.Array, PagedKVCache]:
-    """`decode_step_inflight` over a paged pool: identical math, but the
-    per-row write lands at (page_table[row, pos // ps], pos % ps) in the
-    shared pool and the read side attends through the page table
-    (`paged_decode_attention`: Pallas ragged kernel or XLA gather
-    fallback).  The pool shape never changes during a generate call, so
-    the enclosing program compiles exactly once."""
-    b = tokens.shape[0]
-    x = _embed(params, cfg, tokens, positions)[:, None, :]
-    cos, sin = rope_cos_sin(positions[:, None], cfg.head_dim, cfg.rope_theta)
-    wp_page, wp_off = _page_of(page_table, write_pos, cache.page_size)
-    quant = cache.quantized
-
-    def body(carry, blk):
-        y, kc, vc, ksc, vsc, li = carry
-        h = _norm(y, blk["ln1"], blk.get("ln1_b"), cfg)
-        q, k, v = _block_kv(h, blk, cfg, cos, sin)
-        kc, vc, ksc, vsc, k_pool_l, v_pool_l, ks_l, vs_l = (
-            _cache_update_read(
-                kc, vc, ksc, vsc, k[:, 0], v[:, 0], li, (wp_page, wp_off),
-                quant, q.dtype, dequant=False,
-            )
-        )
-        attn = paged_decode_attention(
-            q, k_pool_l, v_pool_l, page_table, valid_to,
-            k_scale=ks_l, v_scale=vs_l,
-        )
-        ao = _attn_out(attn.reshape(b, 1, cfg.q_dim), blk, cfg)
-        y = y + ao
-        h2 = _norm(y, blk["ln2"], blk.get("ln2_b"), cfg)
-        y = y + (_mlp_moe(h2, blk, cfg)[0] if cfg.is_moe else _mlp_dense(h2, blk, cfg))
-        return (y, kc, vc, ksc, vsc, li + 1), None
-
-    ksc0 = cache.k_scale if quant else jnp.zeros((0,), jnp.bfloat16)
-    vsc0 = cache.v_scale if quant else jnp.zeros((0,), jnp.bfloat16)
-    (x, kc, vc, ksc, vsc, _), _ = jax.lax.scan(
-        body,
-        (x, cache.k, cache.v, ksc0, vsc0, jnp.int32(0)),
-        params["blocks"],
-    )
-    x = _final_norm(params, cfg, x)
-    logits = _head(params, cfg, x)[:, 0]
-    return logits, PagedKVCache(
-        k=kc, v=vc,
-        k_scale=ksc if quant else None,
-        v_scale=vsc if quant else None,
-        page_size=cache.page_size,
-    )
-
-
-@jax.named_scope("gen/decode_step")
-def decode_step_spec_paged(
-    params: Params,
-    cfg: ModelConfig,
-    tokens: jax.Array,  # [B, Q] int32 — pending token + Q-1 drafts per row
-    positions: jax.Array,  # [B, Q] int32 — RoPE positions
-    cache: PagedKVCache,
-    page_table: jax.Array,  # [B, max_pages] int32, sentinel = n_pages
-    write_pos0: jax.Array,  # [B] int32 — flat position of tokens[:, 0]
-    q_lens: "jax.Array | None" = None,  # [B] int32 — live queries per row
-) -> Tuple[jax.Array, PagedKVCache]:
-    """`decode_step_spec` over a paged pool: Q consecutive tokens per row
-    in one forward, k/v written at flat positions write_pos0..+Q-1
-    through the page table, fp32 logits [B, Q, V].  Same exact-
-    verification semantics (quantized cache included) as the dense
-    speculative step.
-
-    `q_lens` makes the step RAGGED — the unified serving chunk's mixed
-    prefill+decode forward: row b's queries i >= q_lens[b] are dead
-    (their cache writes DROP and their attention is fully masked), so a
-    decoding row contributes 1 query, an admitting row a prompt slice of
-    up to Q, and a parked row 0, all in one compiled program.  Dead-
-    query logits are garbage the caller ignores, exactly like padding
-    rows in `prefill_into_pages`."""
-    b, q_len = tokens.shape
-    x = _embed(params, cfg, tokens.reshape(-1), positions.reshape(-1))
-    x = x.reshape(b, q_len, cfg.hidden_dim)
-    cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
-    col = write_pos0[:, None] + jnp.arange(q_len)[None, :]  # [B, Q]
-    wp_page, wp_off = _page_of(page_table, col, cache.page_size)
-    if q_lens is not None:
-        # Dead queries must not scatter: route their page index out of
-        # range (2**30, the `_page_of` OOB convention) so mode="drop"
-        # discards them — this is what keeps garbage lanes from ever
-        # touching pool pages (shared ones included).
-        dead = jnp.arange(q_len)[None, :] >= q_lens[:, None]
-        wp_page = jnp.where(dead, jnp.int32(2**30), wp_page)
-    quant = cache.quantized
-
-    def body(carry, blk):
-        y, kc, vc, ksc, vsc, li = carry
-        h = _norm(y, blk["ln1"], blk.get("ln1_b"), cfg)
-        q, k, v = _block_kv(h, blk, cfg, cos, sin)  # [B, Q, h, d]
-        kc, vc, ksc, vsc, k_pool_l, v_pool_l, ks_l, vs_l = (
-            _cache_update_read(
-                kc, vc, ksc, vsc, k, v, li, (wp_page, wp_off),
-                quant, q.dtype, dequant=False,
-            )
-        )
-        attn = paged_decode_attention_chunk(
-            q, k_pool_l, v_pool_l, page_table, write_pos0 + 1,
-            k_scale=ks_l, v_scale=vs_l, q_lens=q_lens,
-        )
-        ao = _attn_out(attn.reshape(b, q_len, cfg.q_dim), blk, cfg)
-        y = y + ao
-        h2 = _norm(y, blk["ln2"], blk.get("ln2_b"), cfg)
-        y = y + (
-            _mlp_moe(h2, blk, cfg)[0] if cfg.is_moe else _mlp_dense(h2, blk, cfg)
-        )
-        return (y, kc, vc, ksc, vsc, li + 1), None
-
-    ksc0 = cache.k_scale if quant else jnp.zeros((0,), jnp.bfloat16)
-    vsc0 = cache.v_scale if quant else jnp.zeros((0,), jnp.bfloat16)
-    (x, kc, vc, ksc, vsc, _), _ = jax.lax.scan(
-        body,
-        (x, cache.k, cache.v, ksc0, vsc0, jnp.int32(0)),
-        params["blocks"],
-    )
-    x = _final_norm(params, cfg, x)
-    logits = _head(params, cfg, x)  # [B, Q, V]
-    return logits, PagedKVCache(
-        k=kc, v=vc,
-        k_scale=ksc if quant else None,
-        v_scale=vsc if quant else None,
-        page_size=cache.page_size,
-    )
 
 
 @jax.named_scope("gen/decode_step")
@@ -1516,22 +1095,23 @@ def decode_step_ragged_paged(
     row_of: jax.Array,  # [T] int32 — owning slot per token; >= B = dead lane
     experts_in_place: Optional[bool] = None,
 ) -> Tuple[jax.Array, PagedKVCache]:
-    """The megakernel forward: one packed [T] stream of query lanes with
-    per-token windows, instead of a [B, Q] slab with per-row q_lens.
+    """The serving plane's forward: one packed [T] stream of query lanes
+    with per-token windows, instead of a [B, Q] slab with per-row q_lens.
 
-    `decode_step_spec_paged(q_lens=...)` pays B*Q query lanes of embed /
-    QKV / MLP / head compute per step and MASKS the dead ones; here the
-    serving chunk packs only live lanes (decode rows contribute 1,
-    chunked-prefill / episode-observation rows their granted slice,
-    spec-verify rows pending+drafts) so the whole transformer stack —
-    not just attention — runs at ∝ T.  Token t writes its K/V at flat
+    A slab would pay B*Q query lanes of embed / QKV / MLP / head compute
+    per step and MASK the dead ones; here the caller packs only live
+    lanes (decode rows contribute 1, chunked-prefill / episode-
+    observation / resume-replay rows their slice, spec-verify rows
+    pending+drafts) so the whole transformer stack — not just attention
+    — runs at ∝ T.  Token t writes its K/V at flat
     position `positions[t]` of slot `row_of[t]` and attends
     [0, positions[t]] through that slot's page-table row
     (`ragged_paged_attention`: Pallas stream kernel or XLA per-token
     gather).  Dead lanes (row_of >= B, the stream's slack) drop their
     cache writes, emit zero attention, and produce garbage logits the
-    caller never reads.  Same pool-in/pool-out single-compilation
-    contract as `decode_step_paged`.  A grouped MoE model's expert leaves
+    caller never reads.  The pool shape never changes during a generate
+    call, so the enclosing program compiles exactly once.  A grouped MoE
+    model's expert leaves
     reach the ragged kernels as in `decode_step` (`experts_in_place`)."""
     t = tokens.shape[0]
     b = page_table.shape[0]
@@ -1554,7 +1134,7 @@ def decode_step_ragged_paged(
         kc, vc, ksc, vsc, k_pool_l, v_pool_l, ks_l, vs_l = (
             _cache_update_read(
                 kc, vc, ksc, vsc, k[:, 0], v[:, 0], li, (wp_page, wp_off),
-                quant, q.dtype, dequant=False,
+                quant,
             )
         )
         attn = ragged_paged_attention(
@@ -1583,63 +1163,6 @@ def decode_step_ragged_paged(
         k_scale=ksc if quant else None,
         v_scale=vsc if quant else None,
         page_size=cache.page_size,
-    )
-
-
-@jax.named_scope("gen/prefill")
-def prefill_into_pages(
-    params: Params,
-    cfg: ModelConfig,
-    tokens: jax.Array,  # [M, SP] left-aligned prompts (SP % page_size == 0)
-    prompt_lens: jax.Array,  # [M] int32
-    cache: PagedKVCache,
-    page_rows: jax.Array,  # [M, SP // page_size] int32 pool page ids
-    use_flash: "bool | None" = None,
-) -> Tuple[jax.Array, PagedKVCache]:
-    """`prefill_into_slots` for the paged pool: one batched forward for M
-    admitted prompts, then the dense per-row caches are reshaped into
-    page_size chunks and scattered at their assigned pool pages in one
-    op.  `page_rows` entries >= n_pages (the sentinel) are compile-shape
-    padding — those chunks drop, exactly like out-of-range `slot_rows`
-    in the dense path.  The tail of a prompt's last page holds garbage
-    past `prompt_lens`; it is overwritten by decode writes and masked by
-    `valid_to` until then."""
-    m, sp = tokens.shape
-    ps = cache.page_size
-    if sp % ps:
-        raise ValueError(f"prefill width {sp} not a multiple of page_size {ps}")
-    n_chunks = sp // ps
-    seg = (
-        jnp.arange(sp)[None, :] < prompt_lens[:, None]
-    ).astype(jnp.int32)
-    row_cache = _prefill_row_cache(cfg, m, sp, cache)
-    logits, row_cache = prefill(
-        params, cfg, tokens, seg, row_cache, use_flash=use_flash,
-        quantize_kv=cache.quantized,
-    )
-
-    def chunked(a):  # [L, M, SP, ...] -> [L, M * n_chunks, ps, ...]
-        return a.reshape(a.shape[0], m * n_chunks, ps, *a.shape[3:])
-
-    flat = page_rows.reshape(-1)
-    if cache.quantized:
-        # Codes + scales scatter verbatim (quantized once inside the
-        # prefill, attended dequantized there — see `prefill`).
-        return logits, PagedKVCache(
-            k=cache.k.at[:, flat].set(chunked(row_cache.k), mode="drop"),
-            v=cache.v.at[:, flat].set(chunked(row_cache.v), mode="drop"),
-            k_scale=cache.k_scale.at[:, flat].set(
-                chunked(row_cache.k_scale), mode="drop"
-            ),
-            v_scale=cache.v_scale.at[:, flat].set(
-                chunked(row_cache.v_scale), mode="drop"
-            ),
-            page_size=ps,
-        )
-    return logits, PagedKVCache(
-        k=cache.k.at[:, flat].set(chunked(row_cache.k), mode="drop"),
-        v=cache.v.at[:, flat].set(chunked(row_cache.v), mode="drop"),
-        page_size=ps,
     )
 
 

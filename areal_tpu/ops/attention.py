@@ -181,21 +181,16 @@ def decode_attention_chunk(
     valid_to0: jax.Array,  # [B] int — one past query 0's last visible slot
     k_scale: "Optional[jax.Array]" = None,  # [B, S_max, n_kv]: int8 cache
     v_scale: "Optional[jax.Array]" = None,
-    q_lens: "Optional[jax.Array]" = None,  # [B] int — live queries per row
 ) -> jax.Array:
-    """Multi-query decode attention for speculative decoding: query i
-    attends the window [valid_from, valid_to0 + i) — the causal extension
-    of `decode_attention` to a chunk of Q drafted positions (each draft
-    sees the cache up to and including its own just-written slot).
-    Same GQA-grouped, bf16-operand/fp32-accumulate formulation.
-
-    `q_lens` makes the chunk RAGGED: only row queries i < q_lens[row]
-    are live (a decoding slot contributes 1, an admitting slot its
-    prompt slice, a parked slot 0); dead queries are fully masked and
-    emit exact zeros.  The dense Pallas chunk kernel stays uniform-Q, so
-    ragged calls take the XLA formulation (only the paged pool path —
-    which has its own ragged kernel — passes q_lens)."""
-    if _decode_kernel_enabled() and q_lens is None:
+    """Multi-query decode attention over a dense window: query i attends
+    [valid_from, valid_to0 + i) — the causal extension of
+    `decode_attention` to a chunk of Q consecutive positions (each sees
+    the cache up to and including its own just-written slot).  Same
+    GQA-grouped, bf16-operand/fp32-accumulate formulation.  No generation
+    program calls it (the serving chunk packs such rows into
+    `ragged_paged_attention` lanes); it stays as the arithmetic reference
+    of the tests and of `decode_attention_chunk_kernel`."""
+    if _decode_kernel_enabled():
         from areal_tpu.ops.pallas.decode_attention import (
             decode_attention_chunk_kernel,
         )
@@ -227,11 +222,6 @@ def decode_attention_chunk(
         idx[None, None, :]
         < (valid_to0[:, None] + jnp.arange(nq_tok)[None, :])[:, :, None]
     )  # [B, Q, S]
-    if q_lens is not None:
-        valid = valid & (
-            jnp.arange(nq_tok)[None, :, None]
-            < jnp.broadcast_to(q_lens, (b,))[:, None, None]
-        )
     logits = jnp.where(valid[:, None, :, None, :], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     # Zero fully-masked (empty-window) rows: see decode_attention.
@@ -246,9 +236,9 @@ def decode_attention_chunk(
 
 
 # --------------------------------------------------------------------------
-# Paged decode attention (block-paged KV pool, models/transformer.py
-# PagedKVCache): Pallas ragged kernel on TPU (AREAL_DECODE_KERNEL=1),
-# gather-based XLA fallback elsewhere.
+# Ragged paged attention (block-paged KV pool, models/transformer.py
+# PagedKVCache): Pallas stream kernel under AREAL_DECODE_KERNEL=1,
+# gather-based XLA form otherwise.
 # --------------------------------------------------------------------------
 
 
@@ -278,74 +268,6 @@ def paged_gather_layer(
     g = jnp.take(pool_layer, pt, axis=0)  # [B, mp, ps, ...]
     b, mp, ps = g.shape[:3]
     return g.reshape(b, mp * ps, *pool_layer.shape[2:])
-
-
-@jax.named_scope("layer/attn")
-def paged_decode_attention(
-    q: jax.Array,  # [B, 1, n_q, d]
-    k_pool: jax.Array,  # [P, ps, n_kv, d] — one layer's pool view
-    v_pool: jax.Array,
-    page_table: jax.Array,  # [B, max_pages] int32
-    valid_to: jax.Array,  # [B] int — one past the last valid position
-    k_scale: "Optional[jax.Array]" = None,  # [P, ps, n_kv]: int8 pool
-    v_scale: "Optional[jax.Array]" = None,
-) -> jax.Array:
-    """Single-token decode attention through a page table.  Paged rows
-    are left-aligned from flat position 0, so the live window is
-    [0, valid_to)."""
-    if _decode_kernel_enabled():
-        from areal_tpu.ops.pallas.paged_attention import (
-            paged_decode_attention_kernel,
-        )
-
-        return paged_decode_attention_kernel(
-            q, k_pool, v_pool, page_table, valid_to, k_scale, v_scale
-        )
-    b = q.shape[0]
-    k_cache = paged_gather_layer(k_pool, page_table)
-    v_cache = paged_gather_layer(v_pool, page_table)
-    ks = None if k_scale is None else paged_gather_layer(k_scale, page_table)
-    vs = None if v_scale is None else paged_gather_layer(v_scale, page_table)
-    return decode_attention(
-        q, k_cache, v_cache, jnp.zeros((b,), jnp.int32), valid_to,
-        k_scale=ks, v_scale=vs,
-    )
-
-
-@jax.named_scope("layer/attn")
-def paged_decode_attention_chunk(
-    q: jax.Array,  # [B, Q, n_q, d]
-    k_pool: jax.Array,  # [P, ps, n_kv, d]
-    v_pool: jax.Array,
-    page_table: jax.Array,  # [B, max_pages] int32
-    valid_to0: jax.Array,  # [B] int — one past query 0's window
-    k_scale: "Optional[jax.Array]" = None,
-    v_scale: "Optional[jax.Array]" = None,
-    q_lens: "Optional[jax.Array]" = None,  # [B] int live queries per row
-) -> jax.Array:
-    """Chunk decode attention through a page table: query i attends
-    [0, valid_to0 + i).  With `q_lens` the chunk is RAGGED — row b
-    contributes q_lens[b] live queries (mixed prefill+decode serving
-    chunks); dead queries emit exact zeros on both the Pallas kernel and
-    the XLA gather fallback."""
-    if _decode_kernel_enabled():
-        from areal_tpu.ops.pallas.paged_attention import (
-            paged_decode_attention_chunk_kernel,
-        )
-
-        return paged_decode_attention_chunk_kernel(
-            q, k_pool, v_pool, page_table, valid_to0, k_scale, v_scale,
-            q_lens=q_lens,
-        )
-    b = q.shape[0]
-    k_cache = paged_gather_layer(k_pool, page_table)
-    v_cache = paged_gather_layer(v_pool, page_table)
-    ks = None if k_scale is None else paged_gather_layer(k_scale, page_table)
-    vs = None if v_scale is None else paged_gather_layer(v_scale, page_table)
-    return decode_attention_chunk(
-        q, k_cache, v_cache, jnp.zeros((b,), jnp.int32), valid_to0,
-        k_scale=ks, v_scale=vs, q_lens=q_lens,
-    )
 
 
 @jax.named_scope("layer/attn")

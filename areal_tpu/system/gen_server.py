@@ -859,8 +859,8 @@ class GenerationServer:
         eng = self.engine
         if not hasattr(eng, "episode_start"):
             raise RuntimeError(
-                "engine has no episode support (agent episodes need the "
-                "paged serving plane: kv_paged + prefill_chunk_tokens)"
+                "engine has no episode support (agent episodes run on "
+                "GeneratorEngine's serving plane)"
             )
         op = str(req.get("op", ""))
         ep_id = str(req.get("episode_id", ""))
@@ -1729,26 +1729,21 @@ def main():
     p.add_argument("--eos-token-id", type=int, default=None)
     p.add_argument("--max-decode-batch", type=int, default=64)
     p.add_argument("--kv-page-size", type=int, default=128,
-                   help="tokens per KV page in the paged decode pool")
+                   help="tokens per KV page in the serving plane's pool")
     p.add_argument("--kv-pool-pages", type=int, default=0,
                    help="fixed KV pool size in pages (0 = auto-size); "
                         "positive values bound concurrent admissions "
                         "via the page budget")
-    p.add_argument("--no-paged-kv", action="store_true",
-                   help="dense grow-by-doubling KV window instead of "
-                        "the paged pool")
-    p.add_argument("--prefill-chunk-tokens", type=int, default=None,
+    p.add_argument("--prefill-chunk-tokens", type=int, default=8,
                    help="prompt tokens consumed per inner step inside "
-                        "the serving chunk (0 = legacy two-program "
-                        "admit; default 8, or AREAL_PREFILL_CHUNK_TOKENS)")
+                        "the serving chunk (>= 1)")
     p.add_argument("--no-kv-share-prefix", action="store_true",
                    help="disable copy-on-write prompt page sharing "
                         "(prefix cache) in the serving plane")
-    p.add_argument("--serving-admit-lanes", type=int, default=None,
+    p.add_argument("--serving-admit-lanes", type=int, default=0,
                    help="extra packed-stream query lanes above one-per-"
                         "slot in the ragged serving chunk (0 = auto: "
-                        "4x the widest per-row q_len; or "
-                        "AREAL_SERVING_ADMIT_LANES). More lanes admit "
+                        "4x the widest per-row q_len). More lanes admit "
                         "prompts faster per chunk at a wider compiled "
                         "stream")
     p.add_argument("--token", default="",
@@ -1787,11 +1782,10 @@ def main():
     engine = GeneratorEngine(
         cfg, params, mesh, eos_token_id=eos,
         max_decode_batch=args.max_decode_batch,
-        kv_paged=False if args.no_paged_kv else None,
         kv_page_size=args.kv_page_size,
         kv_pool_pages=args.kv_pool_pages,
         prefill_chunk_tokens=args.prefill_chunk_tokens,
-        kv_share_prefix=False if args.no_kv_share_prefix else None,
+        kv_share_prefix=not args.no_kv_share_prefix,
         serving_admit_lanes=args.serving_admit_lanes,
     )
     server = GenerationServer(

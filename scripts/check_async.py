@@ -2639,8 +2639,8 @@ def check_agents(n_episodes: int = 3) -> int:
 
     def mk_engine(p):
         return GeneratorEngine(
-            cfg, p, mesh, eos_token_id=eos, kv_paged=True,
-            kv_page_size=8, prefill_chunk_tokens=4, max_decode_batch=2,
+            cfg, p, mesh, eos_token_id=eos, kv_page_size=8,
+            prefill_chunk_tokens=4, max_decode_batch=2,
         )
 
     def sample_of(toks):

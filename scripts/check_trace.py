@@ -73,7 +73,7 @@ def check_lineage(trace_dir: str) -> int:
     # agent-serving leg of check_async).
     engine = GeneratorEngine(
         cfg, params, mesh, eos_token_id=cfg.vocab_size + 7,
-        kv_paged=True, kv_page_size=8, prefill_chunk_tokens=4,
+        kv_page_size=8, prefill_chunk_tokens=4,
         max_decode_batch=2,
     )
     srv = GenerationServer(engine, max_wait_ms=20.0, zmq_port=None)

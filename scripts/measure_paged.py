@@ -1,55 +1,29 @@
-"""Dense-vs-paged KV decode measurement on the CPU test cluster.
+"""Serving-plane counts on the CPU test cluster (8 virtual devices, the
+tests' fake-cluster configuration, tests/conftest.py).  Counts only — a
+CPU run gives no time or rate of the device.
 
-Runs the SAME long-decode workload (mixed-length prompts, >=4k new
-tokens per row, greedy, oversubscribed slots) through the inflight
-generator twice — dense grow-by-doubling window, then the paged pool —
-on 8 virtual CPU devices (the tests' fake-cluster configuration,
-tests/conftest.py), and emits one JSON line per leg plus a comparison
-line with the contract metrics:
-
-  - decode_compiles:    paged must pay exactly 1; dense pays one per
-                        window bucket the decode crosses
-  - cache_copy_bytes:   paged must be 0; dense copies the whole cache
-                        at every doubling
-  - kv_pool_utilization: live tokens / allocated cache tokens (chunk-
-                        averaged) — paged must be >= dense
-
-Serving-plane legs ride along (--mode stall / sweep / ragged / all):
-
-  - stall: the SAME oversubscribed workload traced twice — legacy
-    two-program admit (prefill_chunk_tokens=0, a separate prefill
-    dispatch stalls the decode stream at every admission) vs the
-    serving plane (chunked prefill inside the decode chunk, zero
-    prefill dispatches, decode_compiles == 1) — and prints both
-    stall-attribution reports (areal_tpu.apps.trace_report).
   - sweep: group-size sweep (n in {1,4,8}) of one long prompt at a
     FIXED kv_pool_pages, kv_share_prefix on vs off: with copy-on-write
     prefix sharing the group's prompt pages are mapped once, so the
     same pool holds >= 3x as many concurrently live rows
     (peak_live_slots) at group size 8.
-  - ragged: packed-stream lane accounting for the fused ragged serving
+  - ragged: packed-stream lane accounting for the ragged serving
     chunk.  Three legs (plain K=0, spec K=2, int8) run the same
-    workload through the unified admit; each reports the lane counters
-    (lanes_dispatched / lanes_live / lanes_slack / dead_live_lanes)
-    plus the masked-slab lane count the legacy [n_slots, W] layout
-    would have paid.  The ragged_compare invariants: dead-lane compute
-    is exactly 0, one compiled program, zero standalone prefills, the
-    packed stream is strictly narrower than the slab, and greedy spec
-    output is token-identical to greedy plain (the argmax chain does
-    not care how tokens were grouped into drafts).
+    workload; each reports the lane counters (lanes_dispatched /
+    lanes_live / lanes_slack / dead_live_lanes) plus the lane count a
+    masked [n_slots, W] slab would have paid.  The ragged_compare
+    invariants: dead-lane compute is exactly 0, one compiled program,
+    zero standalone prefills, the packed stream is strictly narrower
+    than the slab, and greedy spec output is token-identical to greedy
+    plain (the argmax chain does not care how tokens were grouped into
+    drafts).
 
 Runs with AREAL_PAGING_CHECK=1 so every allocator transition is
 invariant-checked while the numbers are gathered.
 
 Usage (from the repo root; takes a few minutes):
-    python scripts/measure_paged.py [--mode all] [--max-new 4096]
+    python scripts/measure_paged.py [--mode all] [--max-new 192]
                                     [--out FILE]
-
-The committed artifact is the stdout of one run, saved under a
-timestamped name (bench_paged_cpu8_<UTC>.log for the compare leg,
-bench_serving_cpu8_<UTC>.log for stall+sweep,
-bench_ragged_cpu8_<UTC>.log for the ragged lane legs) and cited from
-PERF.md.
 """
 
 import argparse
@@ -78,10 +52,10 @@ PROMPT_LENS = (37, 120, 64, 230, 91, 333, 180, 45, 260, 150, 77, 410)
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--max-new", type=int, default=4096)
+    ap.add_argument("--max-new", type=int, default=192)
     ap.add_argument("--page-size", type=int, default=128)
     ap.add_argument("--mode", default="all",
-                    choices=("compare", "stall", "sweep", "ragged", "all"))
+                    choices=("sweep", "ragged", "all"))
     ap.add_argument("--out", default=None,
                     help="also append JSON lines to this file")
     args = ap.parse_args()
@@ -113,13 +87,6 @@ def main():
         seqlens={"packed_prompts": [[l] for l in PROMPT_LENS]},
         data={"packed_prompts": data},
     )
-    # min_new == max_new masks EOS: every row decodes the full budget,
-    # so the dense window is guaranteed to cross bucket boundaries.
-    g = GenerationHyperparameters(
-        n=1, max_new_tokens=args.max_new, min_new_tokens=args.max_new,
-        greedy=True,
-    )
-
     lines = []
 
     def emit(obj):
@@ -127,156 +94,11 @@ def main():
         print(line, flush=True)
         lines.append(line)
 
-    def leg(paged: bool):
-        eng = GeneratorEngine(
-            cfg, params, mesh, eos_token_id=EOS, max_decode_batch=8,
-            kv_paged=paged, kv_page_size=args.page_size,
-        )
-        t0 = time.time()
-        out = eng.generate(sample, MicroBatchSpec(), g, inflight=True)
-        dt = time.time() - t0
-        gen_tokens = int(
-            sum(t for row in out.seqlens["packed_input_ids"] for t in row)
-        ) - sum(PROMPT_LENS)
-        st = eng.last_pool_stats
-        emit({
-            "leg": "paged" if paged else "dense",
-            "devices": len(jax.devices()),
-            "prompts": len(PROMPT_LENS),
-            "max_new_tokens": args.max_new,
-            "gen_tokens": gen_tokens,
-            "wall_seconds": round(dt, 2),
-            "gen_tokens_per_sec": round(gen_tokens / dt, 1),
-            "decode_compiles": eng.decode_compiles,
-            "cache_copy_bytes": eng.cache_copy_bytes,
-            "kv_pool_utilization": round(st.get("utilization", 0.0), 4),
-            "pool_pages": st.get("pool_pages"),
-            "page_size": st.get("page_size"),
-            "pages_recycled": st.get("pages_recycled"),
-            "peak_pages_used": st.get("peak_pages_used"),
-        })
-        return out, eng, dt
-
     ok = True
-
-    def run_compare():
-        out_d, eng_d, _ = leg(paged=False)
-        out_p, eng_p, _ = leg(paged=True)
-        toks_equal = bool(
-            np.array_equal(
-                np.asarray(out_d.data["packed_input_ids"]),
-                np.asarray(out_p.data["packed_input_ids"]),
-            )
-        )
-        emit({
-            "leg": "compare",
-            "greedy_tokens_identical": toks_equal,
-            "paged_compiles_once": eng_p.decode_compiles == 1,
-            "paged_zero_copy": eng_p.cache_copy_bytes == 0,
-            "dense_copy_bytes": eng_d.cache_copy_bytes,
-            "dense_decode_compiles": eng_d.decode_compiles,
-            "utilization_paged_ge_dense": (
-                eng_p.last_pool_stats.get("utilization", 0.0)
-                >= eng_d.last_pool_stats.get("utilization", 0.0)
-            ),
-        })
-        return (
-            toks_equal
-            and eng_p.decode_compiles == 1
-            and eng_p.cache_copy_bytes == 0
-        )
-
-    def run_stall():
-        """Admission-stall attribution: legacy two-program admit vs the
-        serving plane, same oversubscribed workload, traced."""
-        import tempfile
-
-        from areal_tpu.apps import trace_report
-        from areal_tpu.base import tracer
-
-        stall_new = min(args.max_new, 192)
-        gs = GenerationHyperparameters(
-            n=1, max_new_tokens=stall_new, min_new_tokens=stall_new,
-            greedy=True,
-        )
-        results = {}
-        for name, chunk_tokens in (("two_program", 0), ("serving", None)):
-            tdir = tempfile.mkdtemp(prefix=f"areal_tpu_stall_{name}_")
-            tracer.configure(
-                role=name, rank=0, dir=tdir, enabled=True, force=True
-            )
-            eng = GeneratorEngine(
-                cfg, params, mesh, eos_token_id=EOS, max_decode_batch=8,
-                kv_paged=True, kv_page_size=args.page_size,
-                prefill_chunk_tokens=chunk_tokens,
-            )
-            t0 = time.time()
-            out = eng.generate(sample, MicroBatchSpec(), gs, inflight=True)
-            dt = time.time() - t0
-            tracer.flush()
-            trace = tracer.merge_shards(
-                tdir, out_path=os.path.join(tdir, "trace.json")
-            )
-            evs = trace["traceEvents"]
-            spans = [e for e in evs if e.get("ph") == "X"]
-            n_prefill = sum(1 for e in spans if e["name"] == "prefill")
-            prefill_us = sum(
-                e.get("dur", 0) for e in spans if e["name"] == "prefill"
-            )
-            results[name] = (out, eng, n_prefill)
-            emit({
-                "leg": f"stall_{name}",
-                "prompts": len(PROMPT_LENS),
-                "max_new_tokens": stall_new,
-                "wall_seconds": round(dt, 2),
-                "decode_compiles": eng.decode_compiles,
-                "prefill_dispatches": eng.prefill_dispatches,
-                "admission_prefill_spans": n_prefill,
-                "admission_prefill_ms": round(prefill_us / 1000.0, 1),
-                # Packed-stream lane counters (0 on the two_program leg,
-                # which has no serving chunk).
-                "lanes_dispatched": eng.lanes_dispatched,
-                "lanes_live": eng.lanes_live,
-                "dead_live_lanes": eng.dead_live_lanes,
-            })
-            print(f"--- stall attribution: {name} ---", flush=True)
-            print(trace_report.format_report(trace), flush=True)
-        tracer.configure(
-            role="measure", rank=0, enabled=False, force=True
-        )
-        out_b, eng_b, n_prefill_b = results["two_program"]
-        out_a, eng_a, n_prefill_a = results["serving"]
-        toks_equal = bool(
-            np.array_equal(
-                np.asarray(out_b.data["packed_input_ids"]),
-                np.asarray(out_a.data["packed_input_ids"]),
-            )
-        )
-        emit({
-            "leg": "stall_compare",
-            "greedy_tokens_identical": toks_equal,
-            "admission_bubble_eliminated": (
-                n_prefill_b > 0
-                and n_prefill_a == 0
-                and eng_a.prefill_dispatches == 0
-            ),
-            "serving_decode_compiles": eng_a.decode_compiles,
-            # Dead query lanes are ELIMINATED by the packed stream, not
-            # masked: a live lane assigned outside its row's grant would
-            # count here, and the contract is exactly zero.
-            "dead_query_lanes_zero": eng_a.dead_live_lanes == 0,
-        })
-        return (
-            toks_equal
-            and n_prefill_b > 0
-            and n_prefill_a == 0
-            and eng_a.decode_compiles == 1
-            and eng_a.dead_live_lanes == 0
-        )
 
     def run_ragged():
         """Ragged packed-stream lane accounting: plain / spec / int8
-        legs through the ONE unified serving admit, plus the invariant
+        legs through the serving chunk, plus the invariant
         leg the regression gate pins (dead-lane compute exactly 0)."""
         rnew = min(args.max_new, 192)
 
@@ -287,7 +109,7 @@ def main():
             )
             eng = GeneratorEngine(
                 cfg, params, mesh, eos_token_id=EOS, max_decode_batch=8,
-                kv_paged=True, kv_page_size=args.page_size,
+                kv_page_size=args.page_size,
                 kv_cache_dtype=kv_dtype,
             )
             t0 = time.time()
@@ -296,9 +118,9 @@ def main():
             gen_tokens = int(
                 sum(t for r in out.seqlens["packed_input_ids"] for t in r)
             ) - sum(PROMPT_LENS)
-            # The masked-slab lane count the legacy [n_slots, W] layout
-            # pays per inner step, reconstructed the way the engine
-            # sizes its session.
+            # The lane count a masked [n_slots, W] slab would pay per
+            # inner step, reconstructed the way the engine sizes its
+            # session.
             n_slots = min(
                 max(eng.batch_shard, eng.max_decode_batch),
                 len(PROMPT_LENS),
@@ -387,7 +209,7 @@ def main():
                 )
                 eng = GeneratorEngine(
                     cfg, params, mesh, eos_token_id=EOS,
-                    max_decode_batch=8, kv_paged=True, kv_page_size=ps,
+                    max_decode_batch=8, kv_page_size=ps,
                     kv_pool_pages=pool, prefill_chunk_tokens=8,
                     kv_share_prefix=share,
                 )
@@ -427,10 +249,6 @@ def main():
         })
         return ratio >= 3.0
 
-    if args.mode in ("compare", "all"):
-        ok = run_compare() and ok
-    if args.mode in ("stall", "all"):
-        ok = run_stall() and ok
     if args.mode in ("sweep", "all"):
         ok = run_sweep() and ok
     if args.mode in ("ragged", "all"):

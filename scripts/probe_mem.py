@@ -1,7 +1,7 @@
-"""Probe resident device memory at each bench stage (live jax.Arrays).
+"""Probe resident device memory stage by stage (live jax.Arrays).
 
-Replicates bench.py's engine setup and prints the live-array total after
-each stage — separates "resident set too big" from "XLA transient peak
+Builds the 1.5B train and generation engines by hand and prints the
+live-array total after each stage — separates "resident set too big" from "XLA transient peak
 too big" when diagnosing single-chip OOMs.  (The production path —
 build_ppo_math + run_experiment_inproc — is chip_smoke.py's `static` phase,
 which prints live and peak bytes per chip.)

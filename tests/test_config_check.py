@@ -163,3 +163,26 @@ class TestAliasSwapChecks:
             gen_server_url="http://h:1",
             gen_backend_args={"kv_cache_dtype": "int8"},
         )
+
+
+class TestRemovedGenerationPaths:
+    """The options that selected the dense and two-program inflight paths
+    are refused at build time, with the knob named."""
+
+    @pytest.mark.parametrize(
+        "kw, msg",
+        [
+            ({"prefill_chunk_tokens": 0}, "two-program admit path"),
+            (
+                {"gen_backend_args": {"prefill_chunk_tokens": 0}},
+                "two-program admit path",
+            ),
+            ({"gen_backend_args": {"kv_paged": False}}, "kv_paged"),
+        ],
+    )
+    def test_rejected_by_check(self, kw, msg):
+        _expect(msg, **kw)
+
+    def test_kv_paged_is_not_a_config_field(self):
+        with pytest.raises(TypeError, match="kv_paged"):
+            _ppo_cfg(kv_paged=False)

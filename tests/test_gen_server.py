@@ -393,7 +393,7 @@ class TestEpisodeServing:
         # turns end on the probe-derived stop sequence instead.
         eng = GeneratorEngine(
             cfg, params, mesh, eos_token_id=cfg.vocab_size + 7,
-            kv_paged=True, kv_page_size=8, prefill_chunk_tokens=4,
+            kv_page_size=8, prefill_chunk_tokens=4,
             max_decode_batch=2,
         )
         srv = GenerationServer(eng, max_wait_ms=2.0, zmq_port=0)
@@ -639,7 +639,7 @@ class TestLineagePropagation:
         params = tfm.init_params(cfg, jax.random.PRNGKey(13))
         eng = GeneratorEngine(
             cfg, params, mesh, eos_token_id=cfg.vocab_size + 7,
-            kv_paged=True, kv_page_size=8, prefill_chunk_tokens=4,
+            kv_page_size=8, prefill_chunk_tokens=4,
             max_decode_batch=2,
         )
         srv = GenerationServer(eng, max_wait_ms=2.0)

@@ -202,13 +202,9 @@ class TestGenerate:
                 si += 1
 
     def test_inflight_admissions_are_batched(self, cfg, params, rng):
-        """Admission dispatch contract, both serving-plane generations:
-        the default unified serving plane admits INSIDE the chunk step
-        (ZERO standalone prefill dispatches, ever); the legacy two-
-        program path (prefill_chunk_tokens=0) batches one jitted prefill
-        per refill cycle — 12 uniform requests through 4 slots with a
-        uniform token budget retire in lockstep, exactly ⌈12/4⌉ = 3
-        dispatches (the serial-admission formulation paid 12)."""
+        """Admission dispatch contract: the serving plane admits INSIDE
+        the chunk step — 12 uniform requests through 4 slots pay ZERO
+        standalone prefill dispatches and one compilation."""
         mesh = make_mesh(ParallelConfig.from_str("d1"), jax.devices()[:1])
         sample = _prompt_sample(rng, cfg, lens=(6,) * 12)
         # min_new == max_new masks EOS for the whole budget, so every slot
@@ -222,19 +218,13 @@ class TestGenerate:
         eng.generate(sample, MicroBatchSpec(), g, inflight=True)
         assert eng.prefill_dispatches == 0
         assert eng.decode_compiles == 1
-        legacy = GeneratorEngine(
-            cfg, params, mesh, eos_token_id=EOS, max_decode_batch=4,
-            prefill_chunk_tokens=0,
-        )
-        legacy.generate(sample, MicroBatchSpec(), g, inflight=True)
-        assert legacy.prefill_dispatches == 3
+        assert eng.last_pool_stats["admitted"] == 12
 
     def test_spec_admissions_are_batched(self, cfg, params, rng):
         """The strongest form of the contract on the speculative path:
         spec rows are just ragged q_lens in the serving chunk, so
         admission prefill happens INSIDE the one compiled program —
-        zero standalone prefill dispatches (a fortiori batched; the
-        old two-program spec admit paid one dispatch per wave)."""
+        zero standalone prefill dispatches."""
         mesh = make_mesh(ParallelConfig.from_str("d1"), jax.devices()[:1])
         eng = GeneratorEngine(
             cfg, params, mesh, eos_token_id=EOS, max_decode_batch=4
@@ -335,9 +325,10 @@ class TestInt8KVCache:
         # int8 admission scores in-prompt attention against the stored
         # codes (quantize-once), so later prompt positions see the same
         # quantization error decode sees — slightly more near-tie flips
-        # vs bf16 than the old full-precision one-shot prefill.  The
-        # exact contract is int8-serving == dense-int8-window, pinned
-        # by tests/test_paged_kv.py::test_int8_rides_serving_plane.
+        # vs bf16 than a full-precision one-shot prefill would.  The
+        # exact contract — an int8 pool's tokens do not depend on the
+        # chunk geometry — is pinned by tests/test_paged_kv.py
+        # (test_plain_greedy_int8, test_spec_greedy_int8).
         assert a.shape == b.shape
         agree = float((a == b).mean())
         assert agree >= 0.85, f"token agreement {agree:.2f}"
@@ -346,8 +337,8 @@ class TestInt8KVCache:
         ).all()
 
     def test_int8_cache_halves_bytes(self, cfg):
-        c8 = tfm.init_kv_cache(cfg, 2, 64, dtype="int8")
-        c16 = tfm.init_kv_cache(cfg, 2, 64, dtype=jnp.bfloat16)
+        c8 = tfm.init_paged_kv_cache(cfg, 16, 8, dtype="int8")
+        c16 = tfm.init_paged_kv_cache(cfg, 16, 8, dtype=jnp.bfloat16)
         b8 = sum(
             a.nbytes
             for a in (c8.k, c8.v, c8.k_scale, c8.v_scale)
@@ -356,9 +347,8 @@ class TestInt8KVCache:
 
 
 def test_inflight_with_decode_kernel(cfg, params, rng, monkeypatch):
-    """The fused decode-attention kernel (AREAL_DECODE_KERNEL=1) slots
-    into the inflight loop transparently: greedy outputs equal the dense
-    path's."""
+    """The Pallas stream kernel (AREAL_DECODE_KERNEL=1) slots into the
+    serving loop transparently: greedy outputs equal the XLA form's."""
     from areal_tpu.ops import attention
 
     mesh = make_mesh(ParallelConfig.from_str("d1"), jax.devices()[:1])

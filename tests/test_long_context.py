@@ -3,10 +3,9 @@
 The reference's flagship config decodes up to 27,648 new tokens with
 max_tokens_per_mb=30720 (examples/configs/7B-distill/
 ppo-7B-distill-gpus-128.yaml:58-70).  These tests drive the same
-machinery — inflight KV-window bucket growth past 16k, token-budget
+machinery — serving-plane page mapping under an 8k prompt, token-budget
 micro-batching at 16k tokens per microbatch, ring attention over long
-sharded rows — on the CPU cluster; bench.py's longctx mode measures the
-16k+-new-token path on the real chip.
+sharded rows — on the CPU cluster; nothing here measures the chip.
 """
 
 import numpy as np
@@ -23,7 +22,6 @@ from areal_tpu.api.model_api import (
 )
 from areal_tpu.base.topology import ParallelConfig, make_mesh
 from areal_tpu.engines.generator import GeneratorEngine
-from areal_tpu.engines.packing import decode_bucket_len
 from areal_tpu.engines.train import TrainEngine
 from areal_tpu.models import transformer as tfm
 from areal_tpu.models.config import tiny_config
@@ -43,18 +41,15 @@ def params(cfg):
 
 
 def test_generate_from_8k_prompt(cfg, params, rng):
-    """Long-context generation through the inflight path: an 8k-token
-    prompt prefills into a bucketed KV window that then GROWS across a
-    bucket boundary during decode; the response must extend the full
-    prompt with aligned logprobs.  (The single-core CI budget caps this
-    at 8k; the same window mechanics at 16k+ are pinned by
-    test_kv_window_growth_buckets_past_16k, and bench.py's longctx mode
-    measures real ≥16k decode on the chip.)"""
+    """Long-context generation through the serving plane: an 8k-token
+    prompt is consumed in W-token slices into 64 pool pages, then decode
+    maps a further page; the response must extend the full prompt with
+    aligned logprobs.  (The single-core CI budget caps this at 8k.)"""
     mesh = make_mesh(ParallelConfig.from_str("d1"), jax.devices()[:1])
     eng = GeneratorEngine(
         cfg, params, mesh, eos_token_id=EOS, max_decode_batch=1
     )
-    plen = 8150  # bucket_len(8150+chunk) rounds to 8448: decode crosses it
+    plen = 8150
     toks = rng.integers(8, cfg.vocab_size, size=plen).astype(np.int32)
     sample = SequenceSample(
         keys={"packed_prompts"},
@@ -74,28 +69,6 @@ def test_generate_from_8k_prompt(cfg, params, rng):
     lp = np.asarray(out.data["packed_logprobs"])
     assert len(lp) == L - 1
     assert np.all(lp[plen - 1 : plen - 1 + 24] <= 0.0)
-
-
-def test_kv_window_growth_buckets_past_16k(cfg):
-    """Window growth is geometric through decode buckets: reaching a 16k+
-    requirement from a small window costs O(log) recompiles/copies and
-    preserves cache contents."""
-    eng = GeneratorEngine.__new__(GeneratorEngine)  # growth is static
-    cache = tfm.init_kv_cache(cfg, 2, 512, dtype=jnp.float32)
-    cache = tfm.KVCache(
-        k=cache.k.at[:, :, :512].set(1.5), v=cache.v.at[:, :, :512].set(-2.5)
-    )
-    widths = [512]
-    need = 16384 + 64
-    w = 512
-    while w < need:
-        cache, w = eng._grow_kv_cache(cache, w, min(2 * w, need))
-        widths.append(w)
-    assert w >= need
-    assert len(widths) <= 8  # geometric, not linear
-    assert w == decode_bucket_len(w)
-    np.testing.assert_array_equal(np.asarray(cache.k[:, :, :512]), 1.5)
-    np.testing.assert_array_equal(np.asarray(cache.v[:, :, 512:]), 0.0)
 
 
 def _packed(rng, cfg, lens):

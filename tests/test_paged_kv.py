@@ -1,12 +1,13 @@
-"""Paged KV cache tests: the compile-once / zero-copy decode contract.
+"""Paged KV cache tests: the compile-once / zero-copy serving contract.
 
-The paged inflight path (engines/generator.py + engines/paging.py +
-models/transformer.py PagedKVCache) must be BIT-IDENTICAL to the dense
-grow-by-doubling window under greedy decoding (bf16/f32 and int8), while
-compiling its decode program exactly once per generate call and copying
-zero cache bytes — the two regressions the dense window pays at every
-bucket boundary.  Page recycling and pool exhaustion round out the
-allocator contract.
+The serving plane (engines/generator.py + engines/paging.py +
+models/transformer.py PagedKVCache) must produce the STATIC decode
+program's greedy tokens (the static program itself is pinned to
+`tfm.forward`'s argmax by tests/test_generator.py), while compiling its
+chunk program exactly once per generate call and copying zero cache
+bytes.  int8 pools are pinned to themselves across chunk geometries
+(quantise once, so chunk boundaries cannot move the numerics).  Page
+recycling and pool exhaustion round out the allocator contract.
 """
 
 import numpy as np
@@ -53,15 +54,27 @@ def _prompt_sample(rng, cfg, lens):
     )
 
 
-def _engines(cfg, params, mesh, **kw):
-    dense = GeneratorEngine(
-        cfg, params, mesh, eos_token_id=EOS, kv_paged=False, **kw
+def _engine(cfg, params, mesh, **kw):
+    kw.setdefault("kv_page_size", 8)
+    return GeneratorEngine(cfg, params, mesh, eos_token_id=EOS, **kw)
+
+
+def _static_and_serving(eng, sample, g, g_static=None):
+    """The same requests through both programs of ONE engine: the static
+    decode program is the reference, the serving plane the subject."""
+    ref = eng.generate(
+        sample, MicroBatchSpec(), g_static or g, inflight=False
     )
-    paged = GeneratorEngine(
-        cfg, params, mesh, eos_token_id=EOS, kv_paged=True,
-        kv_page_size=8, **kw
+    out = eng.generate(sample, MicroBatchSpec(), g, inflight=True)
+    return ref, out
+
+
+def _assert_same_tokens(a, b):
+    assert a.seqlens["packed_input_ids"] == b.seqlens["packed_input_ids"]
+    np.testing.assert_array_equal(
+        np.asarray(a.data["packed_input_ids"]),
+        np.asarray(b.data["packed_input_ids"]),
     )
-    return dense, paged
 
 
 def _assert_same_output(a, b):
@@ -114,84 +127,113 @@ class TestPageAllocator:
             a.reserve(0, 12)
 
 
+INT8_GEOMETRIES = [(w, ps) for w in (1, 4, 16) for ps in (4, 8)]
+
+
 class TestPagedParity:
-    """Token-for-token greedy parity against the dense window, over slot
-    retirement + re-admission (5 requests, 2 slots)."""
+    """Token-for-token greedy parity against the static program, over
+    slot retirement + re-admission (5 requests, 2 slots)."""
 
     LENS = (4, 11, 6, 9, 5)
 
-    def _run(self, cfg, params, mesh, rng, g, **kw):
-        dense, paged = _engines(
-            cfg, params, mesh, max_decode_batch=2, **kw
-        )
+    def _run(self, cfg, params, mesh, rng, g, g_static=None, **kw):
+        eng = _engine(cfg, params, mesh, max_decode_batch=2, **kw)
         sample = _prompt_sample(rng, cfg, self.LENS)
-        od = dense.generate(sample, MicroBatchSpec(), g, inflight=True)
-        op = paged.generate(sample, MicroBatchSpec(), g, inflight=True)
-        _assert_same_output(od, op)
-        assert paged.decode_compiles == 1
-        assert paged.cache_copy_bytes == 0
-        return dense, paged
+        ref, out = _static_and_serving(eng, sample, g, g_static)
+        _assert_same_output(ref, out)
+        assert eng.decode_compiles == 1
+        assert eng.cache_copy_bytes == 0
+        return eng
+
+    def _int8_self_identity(self, cfg, params, mesh, rng, k):
+        """Quantise once: fresh KV is quantised when first written and
+        every later read sees the stored codes, so the slice width W and
+        the page size — which only move chunk and page boundaries —
+        cannot change an int8 pool's greedy tokens."""
+        sample = _prompt_sample(rng, cfg, self.LENS)
+        g = GenerationHyperparameters(
+            n=1, max_new_tokens=10, greedy=True, spec_decode_k=k
+        )
+        outs = {}
+        for w, ps in INT8_GEOMETRIES:
+            eng = _engine(
+                cfg, params, mesh, max_decode_batch=2,
+                kv_cache_dtype="int8", prefill_chunk_tokens=w,
+                kv_page_size=ps,
+            )
+            outs[(w, ps)] = eng.generate(
+                sample, MicroBatchSpec(), g, inflight=True
+            )
+            assert eng.decode_compiles == 1
+            assert eng.prefill_dispatches == 0
+        first = outs[INT8_GEOMETRIES[0]]
+        for geom in INT8_GEOMETRIES[1:]:
+            _assert_same_output(first, outs[geom])
+        return first
 
     def test_plain_greedy(self, cfg, params, mesh, rng):
         g = GenerationHyperparameters(n=1, max_new_tokens=8, greedy=True)
         self._run(cfg, params, mesh, rng, g)
 
     def test_plain_greedy_int8(self, cfg, params, mesh, rng):
-        g = GenerationHyperparameters(n=1, max_new_tokens=8, greedy=True)
-        self._run(cfg, params, mesh, rng, g, kv_cache_dtype="int8")
+        self._int8_self_identity(cfg, params, mesh, rng, k=0)
 
     def test_spec_greedy(self, cfg, params, mesh, rng):
+        """Greedy speculation is the argmax chain whatever the draft
+        grouping: the spec rows of the serving chunk reproduce the
+        static program's (non-speculative) tokens."""
         g = GenerationHyperparameters(
             n=1, max_new_tokens=10, greedy=True, spec_decode_k=2
         )
-        self._run(cfg, params, mesh, rng, g)
+        gs = GenerationHyperparameters(n=1, max_new_tokens=10, greedy=True)
+        self._run(cfg, params, mesh, rng, g, g_static=gs)
 
     def test_spec_greedy_int8(self, cfg, params, mesh, rng):
-        g = GenerationHyperparameters(
-            n=1, max_new_tokens=10, greedy=True, spec_decode_k=2
-        )
-        self._run(cfg, params, mesh, rng, g, kv_cache_dtype="int8")
+        self._int8_self_identity(cfg, params, mesh, rng, k=2)
 
     def test_paged_pallas_kernel_parity(
         self, cfg, params, mesh, rng, monkeypatch
     ):
-        """AREAL_DECODE_KERNEL=1 routes paged decode through the Pallas
-        ragged paged-attention kernel (interpret mode on CPU) — same
-        greedy tokens as the gather-based XLA fallback AND the dense
-        window."""
+        """AREAL_DECODE_KERNEL=1 routes the serving chunk through the
+        Pallas ragged stream kernel (interpret mode on CPU) — same
+        greedy tokens as the static program traced on the XLA form."""
         from areal_tpu.ops import attention
 
         g = GenerationHyperparameters(n=1, max_new_tokens=8, greedy=True)
+        eng = _engine(cfg, params, mesh, max_decode_batch=2)
+        sample = _prompt_sample(rng, cfg, self.LENS)
+        monkeypatch.setattr(attention, "_DECODE_KERNEL_SNAPSHOT", False)
+        ref = eng.generate(sample, MicroBatchSpec(), g, inflight=False)
         monkeypatch.setattr(attention, "_DECODE_KERNEL_SNAPSHOT", True)
         try:
-            self._run(cfg, params, mesh, rng, g)
+            out = eng.generate(sample, MicroBatchSpec(), g, inflight=True)
         finally:
             monkeypatch.setattr(attention, "_DECODE_KERNEL_SNAPSHOT", None)
+        _assert_same_output(ref, out)
+        assert eng.decode_compiles == 1
 
 
 class TestCompileOnceContract:
-    def test_dense_recompiles_paged_does_not(self, cfg, params, mesh, rng):
-        """A decode long enough to cross window buckets: the dense path
-        pays >1 decode compilation and >0 copied cache bytes (the
-        grow-by-doubling tax); the paged path pays exactly one
-        compilation and zero copies for the SAME tokens."""
-        dense, paged = _engines(cfg, params, mesh, max_decode_batch=2)
+    def test_long_decode_compiles_once_copies_nothing(
+        self, cfg, params, mesh, rng
+    ):
+        """A 160-token decode maps new pages as rows lengthen: exactly
+        one compilation and zero copied cache bytes, for the static
+        program's tokens."""
+        eng = _engine(cfg, params, mesh, max_decode_batch=2)
         sample = _prompt_sample(rng, cfg, (6, 9))
-        # min_new == max_new masks EOS: rows must decode far enough to
-        # cross the first dense bucket boundary (128 -> 256).
+        # min_new == max_new masks EOS: rows decode the whole budget.
         g = GenerationHyperparameters(
             n=1, max_new_tokens=160, min_new_tokens=160, greedy=True
         )
-        od = dense.generate(sample, MicroBatchSpec(), g, inflight=True)
-        op = paged.generate(sample, MicroBatchSpec(), g, inflight=True)
-        _assert_same_output(od, op)
-        assert dense.decode_compiles > 1
-        assert dense.cache_copy_bytes > 0
-        assert paged.decode_compiles == 1
-        assert paged.cache_copy_bytes == 0
+        ref, out = _static_and_serving(eng, sample, g)
+        _assert_same_output(ref, out)
+        assert eng.decode_compiles == 1
+        assert eng.cache_copy_bytes == 0
+        assert eng.last_pool_stats["peak_pages_used"] >= 2 * (160 // 8)
 
     def test_pool_stats_reported(self, cfg, params, mesh, rng):
-        _, paged = _engines(cfg, params, mesh, max_decode_batch=2)
+        paged = _engine(cfg, params, mesh, max_decode_batch=2)
         sample = _prompt_sample(rng, cfg, (5, 8, 6))
         g = GenerationHyperparameters(n=1, max_new_tokens=6, greedy=True)
         paged.generate(sample, MicroBatchSpec(), g, inflight=True)
@@ -206,14 +248,9 @@ class TestPageRecycling:
     def test_bounded_pool_recycles_and_matches(self, cfg, params, mesh, rng):
         """A pool too small for all slots at once: retirement must
         recycle pages into later admissions (throttling them, never
-        corrupting them) — outputs still match the dense window."""
-        dense = GeneratorEngine(
-            cfg, params, mesh, eos_token_id=EOS, kv_paged=False,
-            max_decode_batch=2,
-        )
-        paged = GeneratorEngine(
-            cfg, params, mesh, eos_token_id=EOS, kv_paged=True,
-            kv_page_size=8, kv_pool_pages=4, max_decode_batch=2,
+        corrupting them) — outputs still match the static program."""
+        paged = _engine(
+            cfg, params, mesh, kv_pool_pages=4, max_decode_batch=2
         )
         # Worst case per slot: ceil((11 + 8 + 8) / 8) = 4 pages — the
         # pool holds exactly ONE slot's worst case, so the second slot
@@ -221,9 +258,8 @@ class TestPageRecycling:
         lens = (4, 11, 6, 9, 5, 7)
         sample = _prompt_sample(rng, cfg, lens)
         g = GenerationHyperparameters(n=1, max_new_tokens=8, greedy=True)
-        od = dense.generate(sample, MicroBatchSpec(), g, inflight=True)
-        op = paged.generate(sample, MicroBatchSpec(), g, inflight=True)
-        _assert_same_output(od, op)
+        ref, out = _static_and_serving(paged, sample, g)
+        _assert_same_output(ref, out)
         assert paged.last_pool_stats["pages_recycled"] > 0
         assert paged.last_pool_stats["pool_pages"] == 4
 
@@ -232,9 +268,8 @@ class TestPageRecycling:
     ):
         """A pool that cannot hold even one request must fail fast with
         the capacity message, not deadlock the admission loop."""
-        paged = GeneratorEngine(
-            cfg, params, mesh, eos_token_id=EOS, kv_paged=True,
-            kv_page_size=8, kv_pool_pages=1, max_decode_batch=2,
+        paged = _engine(
+            cfg, params, mesh, kv_pool_pages=1, max_decode_batch=2
         )
         sample = _prompt_sample(rng, cfg, (20,))
         g = GenerationHyperparameters(n=1, max_new_tokens=16, greedy=True)
@@ -277,16 +312,10 @@ class TestGenServerPageBudget:
         assert calls == [3]
 
     def test_engine_budget_property(self, cfg, params, mesh):
-        dense = GeneratorEngine(
-            cfg, params, mesh, eos_token_id=EOS, kv_paged=False
-        )
-        assert dense.page_budget_tokens is None
-        auto = GeneratorEngine(
-            cfg, params, mesh, eos_token_id=EOS, kv_paged=True
-        )
+        auto = GeneratorEngine(cfg, params, mesh, eos_token_id=EOS)
         assert auto.page_budget_tokens is None  # auto-sized pool
         capped = GeneratorEngine(
-            cfg, params, mesh, eos_token_id=EOS, kv_paged=True,
+            cfg, params, mesh, eos_token_id=EOS,
             kv_page_size=16, kv_pool_pages=8,
         )
         assert capped.page_budget_tokens == 128
@@ -381,106 +410,27 @@ class TestPageSharing:
             a.check()
 
 
-class TestSentinelAlignment:
-    """Unmapped (sentinel) page-table entries must contribute ZERO
-    attention mass in BOTH paged read paths — the Pallas kernel clamps
-    the prefetched index and masks, the XLA fallback clamps the gather
-    and masks; poisoning the clamp-target page must not change any live
-    row's output (the rule lives in ops.attention.clamp_page_table)."""
-
-    def _setup(self, rng):
-        b, nq, n_kv, d, ps, n_pool, mp = 2, 4, 2, 8, 4, 6, 3
-        q = jnp.asarray(rng.standard_normal((b, nq, n_kv, d)), jnp.float32)
-        k = jnp.asarray(
-            rng.standard_normal((n_pool, ps, n_kv, d)), jnp.float32
-        )
-        v = jnp.asarray(
-            rng.standard_normal((n_pool, ps, n_kv, d)), jnp.float32
-        )
-        # Row 0 lives in page 2 only (one mapped entry); row 1 in pages
-        # 0 and 4.  Everything else is the sentinel (= n_pool).
-        pt = np.full((b, mp), n_pool, np.int32)
-        pt[0, 0] = 2
-        pt[1, :2] = (0, 4)
-        # Caller contract: the widest query's window hi0 + nq - 1 stays
-        # within each row's MAPPED pages (row 0: 1+3 <= 4 tokens, row 1:
-        # 5+3 <= 8); sentinel entries only ever cover positions past it.
-        hi0 = np.array([1, 5], np.int32)
-        return q, k, v, jnp.asarray(pt), jnp.asarray(hi0)
-
-    def test_sentinel_rows_add_no_mass_xla_and_kernel(self, rng):
-        from areal_tpu.ops.attention import (
-            decode_attention_chunk,
-            paged_gather_layer,
-        )
-        from areal_tpu.ops.pallas.paged_attention import (
-            paged_decode_attention_chunk_kernel,
-        )
-
-        q, k, v, pt, hi0 = self._setup(rng)
-        n_pool = k.shape[0]
-        # Poison the clamp target (page n_pool - 1, where sentinel
-        # entries land after clamping) with huge values: if either path
-        # let a sentinel row through its mask, the output would explode.
-        k_bad = k.at[n_pool - 1].set(1e9)
-        v_bad = v.at[n_pool - 1].set(1e9)
-
-        out_kern = paged_decode_attention_chunk_kernel(q, k, v, pt, hi0)
-        out_kern_bad = paged_decode_attention_chunk_kernel(
-            q, k_bad, v_bad, pt, hi0
-        )
-        np.testing.assert_array_equal(
-            np.asarray(out_kern), np.asarray(out_kern_bad)
-        )
-
-        # XLA fallback: gather the pages then run the dense chunk math.
-        def xla(kp, vp):
-            kk = paged_gather_layer(kp, pt)
-            vv = paged_gather_layer(vp, pt)
-            return decode_attention_chunk(
-                q, kk, vv, jnp.zeros_like(hi0), hi0
-            )
-
-        out_xla = xla(k, v)
-        out_xla_bad = xla(k_bad, v_bad)
-        np.testing.assert_array_equal(
-            np.asarray(out_xla), np.asarray(out_xla_bad)
-        )
-        # And the two paths agree on the clean pool.
-        np.testing.assert_allclose(
-            np.asarray(out_kern), np.asarray(out_xla), rtol=2e-5, atol=2e-5
-        )
-
-
 class TestServingPlaneEquivalence:
-    """The unified serving plane (chunked prefill inside the decode
-    chunk + CoW page sharing, the default) must be token-identical to
-    the legacy two-program admit path it replaces — while dispatching
-    ZERO standalone prefills and compiling exactly ONE program."""
+    """The serving plane (chunked prefill inside the decode chunk + CoW
+    page sharing) must be token-identical to the static decode program —
+    while dispatching ZERO standalone prefills and compiling exactly ONE
+    program."""
 
     LENS = (4, 11, 6, 9, 5)
 
-    def _pair(self, cfg, params, mesh, **kw):
-        legacy = GeneratorEngine(
-            cfg, params, mesh, eos_token_id=EOS, kv_paged=True,
-            kv_page_size=8, prefill_chunk_tokens=0, **kw
-        )
-        serving = GeneratorEngine(
-            cfg, params, mesh, eos_token_id=EOS, kv_paged=True,
-            kv_page_size=8, prefill_chunk_tokens=4, **kw
-        )
-        return legacy, serving
+    def _serving(self, cfg, params, mesh, **kw):
+        kw.setdefault("prefill_chunk_tokens", 4)
+        kw.setdefault("max_decode_batch", 2)
+        return _engine(cfg, params, mesh, **kw)
 
-    def test_token_identical_to_two_program_path(
+    def test_token_identical_to_static_program(
         self, cfg, params, mesh, rng
     ):
-        legacy, serving = self._pair(cfg, params, mesh, max_decode_batch=2)
+        serving = self._serving(cfg, params, mesh)
         sample = _prompt_sample(rng, cfg, self.LENS)
         g = GenerationHyperparameters(n=1, max_new_tokens=8, greedy=True)
-        ol = legacy.generate(sample, MicroBatchSpec(), g, inflight=True)
-        os_ = serving.generate(sample, MicroBatchSpec(), g, inflight=True)
-        _assert_same_output(ol, os_)
-        assert legacy.prefill_dispatches > 0  # the zoo being replaced
+        ref, out = _static_and_serving(serving, sample, g)
+        _assert_same_output(ref, out)
         assert serving.prefill_dispatches == 0
         assert serving.decode_compiles == 1
         assert serving.cache_copy_bytes == 0
@@ -488,16 +438,15 @@ class TestServingPlaneEquivalence:
     def test_group_sampling_shares_prompt_pages(
         self, cfg, params, mesh, rng
     ):
-        """n=4 same-prompt responses: identical tokens to the legacy
-        path, but the prompt's full pages are mapped (not copied) into
-        the followers via the prefix cache — visible as shared mappings
-        and prefix hits in the pool stats."""
-        legacy, serving = self._pair(cfg, params, mesh, max_decode_batch=2)
+        """n=4 same-prompt responses: identical tokens to the static
+        program, but the prompt's full pages are mapped (not copied)
+        into the followers via the prefix cache — visible as shared
+        mappings and prefix hits in the pool stats."""
+        serving = self._serving(cfg, params, mesh)
         sample = _prompt_sample(rng, cfg, (17, 9))
         g = GenerationHyperparameters(n=4, max_new_tokens=8, greedy=True)
-        ol = legacy.generate(sample, MicroBatchSpec(), g, inflight=True)
-        os_ = serving.generate(sample, MicroBatchSpec(), g, inflight=True)
-        _assert_same_output(ol, os_)
+        ref, out = _static_and_serving(serving, sample, g)
+        _assert_same_output(ref, out)
         st = serving.last_pool_stats
         assert st["shared_mappings"] > 0
         assert st["prefix_hits"] > 0
@@ -507,18 +456,41 @@ class TestServingPlaneEquivalence:
     def test_share_disabled_still_token_identical(
         self, cfg, params, mesh, rng
     ):
-        legacy, _ = self._pair(cfg, params, mesh, max_decode_batch=2)
-        noshare = GeneratorEngine(
-            cfg, params, mesh, eos_token_id=EOS, kv_paged=True,
-            kv_page_size=8, prefill_chunk_tokens=4, kv_share_prefix=False,
-            max_decode_batch=2,
-        )
+        noshare = self._serving(cfg, params, mesh, kv_share_prefix=False)
         sample = _prompt_sample(rng, cfg, (17, 9))
         g = GenerationHyperparameters(n=4, max_new_tokens=8, greedy=True)
-        ol = legacy.generate(sample, MicroBatchSpec(), g, inflight=True)
-        on = noshare.generate(sample, MicroBatchSpec(), g, inflight=True)
-        _assert_same_output(ol, on)
+        ref, out = _static_and_serving(noshare, sample, g)
+        _assert_same_output(ref, out)
         assert noshare.last_pool_stats["shared_mappings"] == 0
+
+    def _interrupted_then_resumed(self, build, sample, g, at_chunk, parked):
+        """generate() interrupted when the `at_chunk`-th chunk is
+        dispatched (the loop parks at the next chunk boundary),
+        `parked(session)` asserted on the parked state, then resumed
+        under UNCHANGED weights."""
+        eng = build()
+        real_get = eng._get_serving_chunk_fn
+        calls = {"n": 0}
+
+        def hooked(*a, **kw):
+            fn = real_get(*a, **kw)
+
+            def wrapped(*fa, **fkw):
+                calls["n"] += 1
+                if calls["n"] == at_chunk:
+                    eng.interrupt()
+                return fn(*fa, **fkw)
+
+            return wrapped
+
+        eng._get_serving_chunk_fn = hooked
+        out = eng.generate(sample, MicroBatchSpec(), g, seed=0)
+        assert out is None and eng.interrupted
+        parked(eng._session)
+        eng.clear_interrupt()
+        out = eng.resume_generate()
+        assert out is not None and eng.resume_replays == 1
+        return eng, out
 
     def test_resume_on_shared_pages_token_identical(
         self, cfg, params, mesh, rng
@@ -533,7 +505,7 @@ class TestServingPlaneEquivalence:
             # forces slot reuse so the interrupt lands with live shares.
             return GeneratorEngine(
                 cfg, params, mesh, eos_token_id=cfg.vocab_size + 7,
-                kv_paged=True, kv_page_size=8, prefill_chunk_tokens=4,
+                kv_page_size=8, prefill_chunk_tokens=4,
                 max_decode_batch=2,
             )
 
@@ -541,36 +513,55 @@ class TestServingPlaneEquivalence:
         g = GenerationHyperparameters(n=4, max_new_tokens=24, greedy=True)
         ref = build().generate(sample, MicroBatchSpec(), g, seed=0)
 
-        eng = build()
-        real_get = eng._get_serving_chunk_fn
-        calls = {"n": 0}
+        def parked(st):
+            # The interrupt parked mid-flight with at least one follower
+            # still mapping shared pages (the scenario under test).
+            assert any(
+                st.alloc.is_shared(s, 0)
+                for s in range(st.n_slots)
+                if st.active[s] is not None and int(st.shared_from[s]) > 0
+            )
 
-        def hooked(*a, **kw):
-            fn = real_get(*a, **kw)
-
-            def wrapped(*fa, **fkw):
-                calls["n"] += 1
-                if calls["n"] == 2:
-                    eng.interrupt()
-                return fn(*fa, **fkw)
-
-            return wrapped
-
-        eng._get_serving_chunk_fn = hooked
-        out = eng.generate(sample, MicroBatchSpec(), g, seed=0)
-        assert out is None and eng.interrupted
-        st = eng._session
-        # The interrupt parked mid-flight with at least one follower
-        # still mapping shared pages (the scenario under test).
-        assert any(
-            st.alloc.is_shared(s, 0)
-            for s in range(st.n_slots)
-            if st.active[s] is not None and int(st.shared_from[s]) > 0
+        _, out = self._interrupted_then_resumed(
+            build, sample, g, at_chunk=2, parked=parked
         )
-        eng.clear_interrupt()
-        out = eng.resume_generate()
-        assert out is not None and eng.resume_replays == 1
         _assert_same_output(ref, out)
+
+    def test_resume_with_row_parked_mid_prefill(
+        self, cfg, params, mesh, rng
+    ):
+        """A row whose prompt is still being consumed when the interrupt
+        lands has only a prefix of it in cache: the replay re-forwards
+        the tail of THAT prefix and the loop goes on consuming the rest,
+        token for token as if never interrupted."""
+
+        def build():
+            # chunk_t = max_new = 4 steps of W = 2 lanes: one chunk
+            # consumes at most 8 of the 30 prompt tokens.
+            return GeneratorEngine(
+                cfg, params, mesh, eos_token_id=cfg.vocab_size + 7,
+                kv_page_size=8, prefill_chunk_tokens=2,
+                max_decode_batch=2,
+            )
+
+        sample = _prompt_sample(rng, cfg, (30, 5, 21))  # 3 reqs, 2 slots
+        g = GenerationHyperparameters(n=1, max_new_tokens=4, greedy=True)
+        ref = build().generate(sample, MicroBatchSpec(), g, seed=0)
+
+        def parked(st):
+            mid = [
+                s for s in range(st.n_slots)
+                if st.active[s] is not None and int(st.prefill_rem[s]) > 0
+            ]
+            assert mid and all(int(st.cache_len[s]) > 0 for s in mid)
+
+        eng, out = self._interrupted_then_resumed(
+            build, sample, g, at_chunk=1, parked=parked
+        )
+        _assert_same_output(ref, out)
+        assert {
+            sig[0] for sig in eng._gen_fns if isinstance(sig[0], str)
+        } == {"serving_chunk", "paged_replay"}
 
     def test_spec_rides_serving_plane(self, cfg, params, mesh, rng):
         """Speculative decoding is just another ragged q_len in the
@@ -579,14 +570,8 @@ class TestServingPlaneEquivalence:
         admits (5 requests, 2 slots), and its greedy output is token-
         identical to the plain serving path — greedy speculation is the
         argmax chain whatever the draft grouping."""
-        spec = GeneratorEngine(
-            cfg, params, mesh, eos_token_id=EOS, kv_paged=True,
-            kv_page_size=8, prefill_chunk_tokens=4, max_decode_batch=2,
-        )
-        plain = GeneratorEngine(
-            cfg, params, mesh, eos_token_id=EOS, kv_paged=True,
-            kv_page_size=8, prefill_chunk_tokens=4, max_decode_batch=2,
-        )
+        spec = self._serving(cfg, params, mesh)
+        plain = self._serving(cfg, params, mesh)
         sample = _prompt_sample(rng, cfg, self.LENS)
         gs = GenerationHyperparameters(
             n=1, max_new_tokens=10, greedy=True, spec_decode_k=2
@@ -600,38 +585,32 @@ class TestServingPlaneEquivalence:
         assert spec.cache_copy_bytes == 0
 
     def test_int8_rides_serving_plane(self, cfg, params, mesh, rng):
-        """int8 KV rides the same chunked admission: token-identical to
-        the dense int8 window.  Chunk boundaries cannot shift the
-        numerics because fresh KV is quantized ONCE when first written
-        and every later chunk re-reads the stored codes — re-quantizing
-        a dequantized value is NOT idempotent, so the prefill emits
-        codes directly (models/transformer.py prefill quantize_kv)."""
-        dense = GeneratorEngine(
-            cfg, params, mesh, eos_token_id=EOS, kv_paged=False,
-            max_decode_batch=2, kv_cache_dtype="int8",
-        )
-        serving = GeneratorEngine(
-            cfg, params, mesh, eos_token_id=EOS, kv_paged=True,
-            kv_page_size=8, prefill_chunk_tokens=4, max_decode_batch=2,
-            kv_cache_dtype="int8",
-        )
+        """int8 KV rides the same chunked admission and the same spec
+        verification: fresh KV is quantized ONCE when first written and
+        every later read sees the stored codes, so greedy speculation
+        over an int8 pool emits the plain int8 pool's tokens (drafts and
+        verification score against the same quantized-cache model)."""
         sample = _prompt_sample(rng, cfg, self.LENS)
-        g = GenerationHyperparameters(n=1, max_new_tokens=8, greedy=True)
-        od = dense.generate(sample, MicroBatchSpec(), g, inflight=True)
-        os_ = serving.generate(sample, MicroBatchSpec(), g, inflight=True)
-        _assert_same_output(od, os_)
-        assert serving.prefill_dispatches == 0
-        assert serving.decode_compiles == 1
+        outs = []
+        for k in (0, 2):
+            eng = self._serving(cfg, params, mesh, kv_cache_dtype="int8")
+            g = GenerationHyperparameters(
+                n=1, max_new_tokens=8, greedy=True, spec_decode_k=k
+            )
+            outs.append(
+                eng.generate(sample, MicroBatchSpec(), g, inflight=True)
+            )
+            assert eng.prefill_dispatches == 0
+            assert eng.decode_compiles == 1
+            assert 0 < eng.last_pool_stats["pool_bytes"]
+        _assert_same_tokens(outs[0], outs[1])
 
     def test_lane_accounting_dead_lanes_zero(self, cfg, params, mesh, rng):
         """The packed stream's lane counters: every dispatched lane is
         either live or budgeted slack (they partition T*steps), and the
         live-but-misassigned count — a packing bug detector — is
         exactly 0.  Dead query lanes are eliminated, not masked."""
-        eng = GeneratorEngine(
-            cfg, params, mesh, eos_token_id=EOS, kv_paged=True,
-            kv_page_size=8, prefill_chunk_tokens=4, max_decode_batch=2,
-        )
+        eng = self._serving(cfg, params, mesh)
         sample = _prompt_sample(rng, cfg, self.LENS)
         g = GenerationHyperparameters(
             n=1, max_new_tokens=10, greedy=True, spec_decode_k=2
@@ -643,22 +622,66 @@ class TestServingPlaneEquivalence:
         assert eng.lanes_live + eng.lanes_slack == eng.lanes_dispatched
         assert eng.dead_live_lanes == 0
 
-    def test_spec_without_serving_plane_is_rejected(
+
+class TestTwoProgramsOnly:
+    """What went with the dense and two-program inflight paths stays
+    gone: the options that selected them are rejected, the variables
+    that shadowed the constructor are inert, and an engine only ever
+    builds the static program and the serving plane's three."""
+
+    SERVING_PROGRAMS = {"serving_chunk", "copy_pages", "paged_replay"}
+
+    def test_prefill_chunk_tokens_zero_is_rejected(self, cfg, params, mesh):
+        with pytest.raises(ValueError, match="two-program admit path"):
+            GeneratorEngine(
+                cfg, params, mesh, eos_token_id=EOS, prefill_chunk_tokens=0
+            )
+
+    def test_kv_paged_is_no_longer_an_option(self, cfg, params, mesh):
+        with pytest.raises(TypeError, match="kv_paged"):
+            GeneratorEngine(
+                cfg, params, mesh, eos_token_id=EOS, kv_paged=False
+            )
+
+    def test_removed_environment_shadows_are_inert(
+        self, cfg, params, mesh, monkeypatch
+    ):
+        monkeypatch.setenv("AREAL_PAGED_KV", "0")
+        monkeypatch.setenv("AREAL_PREFILL_CHUNK_TOKENS", "3")
+        monkeypatch.setenv("AREAL_KV_SHARE_PREFIX", "0")
+        monkeypatch.setenv("AREAL_SERVING_ADMIT_LANES", "5")
+        eng = GeneratorEngine(cfg, params, mesh, eos_token_id=EOS)
+        assert eng.prefill_chunk_tokens == 8
+        assert eng.kv_share_prefix is True
+        assert eng.serving_admit_lanes == 0
+        eng = GeneratorEngine(
+            cfg, params, mesh, eos_token_id=EOS, prefill_chunk_tokens=4,
+            kv_share_prefix=False, serving_admit_lanes=2,
+        )
+        assert eng.prefill_chunk_tokens == 4
+        assert eng.kv_share_prefix is False
+        assert eng.serving_admit_lanes == 2
+
+    def test_engine_builds_only_static_and_serving_programs(
         self, cfg, params, mesh, rng
     ):
-        """The legacy two-program spec admit is gone: spec decoding over
-        the paged pool with the serving plane disabled must fail fast
-        with a clear message, not silently fall back."""
-        eng = GeneratorEngine(
-            cfg, params, mesh, eos_token_id=EOS, kv_paged=True,
-            kv_page_size=8, prefill_chunk_tokens=0, max_decode_batch=2,
+        eng = _engine(cfg, params, mesh, max_decode_batch=2)
+        sample = _prompt_sample(rng, cfg, (4, 11, 6))
+        g = GenerationHyperparameters(n=2, max_new_tokens=6, greedy=True)
+        eng.generate(sample, MicroBatchSpec(), g, inflight=False)
+        n_static = len(eng._gen_fns)
+        assert n_static and all(
+            not isinstance(sig[0], str) for sig in eng._gen_fns
         )
-        sample = _prompt_sample(rng, cfg, (5,))
-        g = GenerationHyperparameters(
-            n=1, max_new_tokens=4, greedy=True, spec_decode_k=2
+        eng.generate(sample, MicroBatchSpec(), g, inflight=True)
+        gs = GenerationHyperparameters(
+            n=2, max_new_tokens=6, greedy=True, spec_decode_k=2
         )
-        with pytest.raises(ValueError, match="serving plane"):
-            eng.generate(sample, MicroBatchSpec(), g)
+        eng.generate(sample, MicroBatchSpec(), gs)
+        named = [sig[0] for sig in eng._gen_fns if isinstance(sig[0], str)]
+        assert len(named) == len(eng._gen_fns) - n_static
+        assert set(named) <= self.SERVING_PROGRAMS
+        assert named.count("serving_chunk") == 2  # K=0 and K=2
 
 
 class TestRaggedStreamKernel:
@@ -797,7 +820,7 @@ class TestGenServerBudgetValidation:
         4*(60 + max_new) — so a budget that the dense formula would
         split (or reject) admits the group WHOLE."""
         eng = GeneratorEngine(
-            cfg, params, mesh, eos_token_id=EOS, kv_paged=True,
+            cfg, params, mesh, eos_token_id=EOS,
             kv_page_size=8, kv_pool_pages=20,  # budget: 160 tokens
         )
         # sp = (60-1)//8 = 7 full pages -> 56 + 4*(4 + 10) = 112 <= 160;

@@ -162,34 +162,37 @@ class TestSpecDecodeStep:
                 rtol=1e-5, atol=1e-5,
             )
 
-    def test_spec_step_matches_sequential_inflight_steps(self):
-        """Feeding Q known tokens through decode_step_spec must give the
-        same logits and cache as Q decode_step_inflight calls."""
+    def test_ragged_step_q_len_q_matches_q_single_lane_steps(self):
+        """A row that owns Q lanes of one `decode_step_ragged_paged` call
+        (a spec-verify row, a prefill slice, a resume replay) must get the
+        same logits and pool contents as Q calls in which it owns one
+        lane each."""
         cfg = tiny_config()
         params = tfm.init_params(cfg, jax.random.PRNGKey(0))
-        B, S, Q = 2, 24, 3
+        B, Q, ps, mp = 2, 3, 2, 4
         rng = np.random.default_rng(6)
         toks = jnp.asarray(rng.integers(1, cfg.vocab_size, (B, Q)), jnp.int32)
-        # Fresh rows, positions 0..Q-1 (the exact-equality scenario).
-        cache = tfm.init_kv_cache(cfg, B, S, jnp.float32)
-        positions = jnp.broadcast_to(jnp.arange(Q)[None, :], (B, Q))
-        spec_logits, spec_cache = tfm.decode_step_spec(
-            params, cfg, toks, positions, cache, jnp.zeros((B,), jnp.int32)
+        # Row b owns pages b*mp .. b*mp+mp-1; fresh rows, positions 0..Q-1.
+        table = jnp.arange(B * mp, dtype=jnp.int32).reshape(B, mp)
+        pool = tfm.init_paged_kv_cache(cfg, B * mp, ps, jnp.float32)
+        wide_logits, wide_pool = tfm.decode_step_ragged_paged(
+            params, cfg, toks.reshape(-1),
+            jnp.tile(jnp.arange(Q, dtype=jnp.int32), B), pool, table,
+            jnp.repeat(jnp.arange(B, dtype=jnp.int32), Q),
         )
-        cache2 = tfm.init_kv_cache(cfg, B, S, jnp.float32)
+        wide_logits = wide_logits.reshape(B, Q, -1)
+        pool2 = tfm.init_paged_kv_cache(cfg, B * mp, ps, jnp.float32)
         for t in range(Q):
-            lg, cache2 = tfm.decode_step_inflight(
+            lg, pool2 = tfm.decode_step_ragged_paged(
                 params, cfg, toks[:, t], jnp.full((B,), t, jnp.int32),
-                cache2,
-                slots=jnp.full((B,), t, jnp.int32),
-                valid_to=jnp.full((B,), t + 1, jnp.int32),
+                pool2, table, jnp.arange(B, dtype=jnp.int32),
             )
             np.testing.assert_allclose(
-                np.asarray(spec_logits[:, t]), np.asarray(lg),
+                np.asarray(wide_logits[:, t]), np.asarray(lg),
                 rtol=2e-4, atol=2e-4,
             )
         np.testing.assert_allclose(
-            np.asarray(spec_cache.k), np.asarray(cache2.k),
+            np.asarray(wide_pool.k), np.asarray(pool2.k),
             rtol=1e-5, atol=1e-5,
         )
 
