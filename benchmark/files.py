@@ -1,13 +1,20 @@
 """Find a cell's files by the names in BENCHMARK.json — the harness names
 no cell, no model, no traffic mix and no metric itself.
 
-    workloads/<cell>.json      config, traffic, chips, expected route
+    workloads/<cell>.json      config, traffic, chips, expected route,
+                               and optionally `timed_steps` (the window
+                               closes after that many timed steps or on
+                               the clock, whichever comes first) and
+                               `traffic_seed` (draws the rows whatever
+                               `--seed` is)
     configs/<config>.json      the model's published keys + a "benchmark"
                                group (reference, layout, reduced, assumed,
                                and optionally `toy`: key overrides for
                                --cpu-rehearsal beyond the dense keys
                                `run.toy` shrinks itself — experts, a
-                               `head_dim` key, windows)
+                               `head_dim` key, windows; `weights_seed`:
+                               the trial's seed whatever `--seed` is,
+                               where the model's speed follows its draw)
     traffic/<traffic>.json     parameters of one traffic mix; its
                                "generator" key names traffic/<generator>.py
     references/<reference>.py  the architecture's plain fp32 forward pass

@@ -1,5 +1,9 @@
 """Generated tokens over the seconds of the generate request, per chip:
-the median over the timed steps."""
+the median over the timed steps.  Where a step's pace follows the weights
+and the tokens and drifts with the updates (`olmoe-decode-tail`), the cell's
+`timed_steps` and `traffic_seed` and its configuration's `weights_seed`
+make those the same steps of the same trajectory in every run
+(`benchmark/run.py`)."""
 import statistics
 
 from benchmark.metrics._labels import GEN

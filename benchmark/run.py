@@ -10,6 +10,23 @@ WHOLE steps on the benchmark's own clock until `--seconds` have passed
 since the first timed step began (the step in flight finishes; at least
 two timed steps).  The last line of stdout is one JSON object.
 
+Three keys are data, for a cell whose step is not fixed by its shapes (a
+MoE decode step takes as long as the experts its rows touch, so its pace
+follows the weights drawn and the tokens sampled, and it drifts as the
+updates move the router; PERF.md section 6, PR 30):
+
+  * `"timed_steps": N` in `workloads/<cell>.json`: the window closes
+    after N timed steps, or on the clock, whichever comes first, so every
+    run, and a faster program beside its parent, times the same N points
+    of the drift.  Without the key the clock alone decides.
+  * `"weights_seed": n` in the `benchmark` group of `configs/<config>.json`
+    is the trial's seed (the program has one: weights and sampling), as a
+    deployed model is one set of weights.  Without the key it is `--seed`.
+  * `"traffic_seed": n` in `workloads/<cell>.json` draws the cell's rows
+    whatever `--seed` is.  Without the key `--seed` draws them: which row
+    gets which length, the operands, the filler.  A cell with both seeds
+    fixed runs the same computation under every `--seed`.
+
 The program is driven as `chip_smoke.py` drives it (bf16 master + Adam,
 lr 1e-4, two minibatches, micro-batches of 8,192 tokens, a zero value
 baseline without advantage normalisation so that the verifier's constant
@@ -216,10 +233,24 @@ def build_plan(run, rows, tok, fileroot):
         ctrl=ExperimentSaveEvalControl(),
         fileroot=fileroot,
         train_backend_args={"master_dtype": PLAN["master_dtype"]},
-        seed=run.seed,
+        seed=trial_seed(run.config, run.seed),
         **overrides,
     )
     return build_ppo_math(cfg, tok)
+
+
+def traffic_rows(cell, traffic, seed):
+    """The cell's rows from the traffic mix's generator: drawn from the
+    cell's own `traffic_seed` where its file has one, else from `--seed`."""
+    generator = files.load_module("traffic", traffic["generator"])
+    return generator.generate(traffic, cell.get("traffic_seed", seed))
+
+
+def trial_seed(config, seed):
+    """The one seed the program takes (weights and sampling): the
+    configuration's own draw where its speed depends on the weights
+    (`weights_seed`, module docstring), else `--seed`."""
+    return int(config["benchmark"].get("weights_seed", seed))
 
 
 # --------------------------------------------------------------------------
@@ -309,6 +340,7 @@ class Observer:
     def __init__(self, run, seconds, trace_dir, row_ids):
         self.run = run
         self.seconds = seconds
+        self.timed_steps = run.cell.get("timed_steps")  # None: the clock
         self.trace_dir = trace_dir
         self.row_ids = row_ids  # the traffic's rows, in the order generated
         self.host = HostWatch()
@@ -491,7 +523,9 @@ class Observer:
                 jax.profiler.stop_trace()
                 self.stop()  # a traced run ends with its traced steps
             return
-        if n_timed >= 2 and now - self.t_window >= self.seconds:
+        if n_timed == self.timed_steps or (
+            n_timed >= 2 and now - self.t_window >= self.seconds
+        ):
             self.stop()
 
     def stop(self):
@@ -609,6 +643,8 @@ def main(argv=None):
         )
     if cell["chips"] != config["benchmark"]["layout"]["chips"]:
         raise SystemExit("cell and config disagree about the chips")
+    if cell.get("timed_steps", 2) < 2:
+        raise SystemExit("a cell's timed_steps is two or more")
 
     import logging
 
@@ -630,6 +666,9 @@ def main(argv=None):
     }
     log(f"platform={device['platform']} kind={device['kind']!r} "
         f"devices={device['count']} cell={args.workload} seed={args.seed} "
+        f"trial_seed={trial_seed(config, args.seed)} "
+        f"traffic_seed={cell.get('traffic_seed', args.seed)} "
+        f"timed_steps={cell.get('timed_steps')} "
         f"seconds={seconds} trace={args.trace} cache={cache_dir} "
         f"({len(os.listdir(cache_dir))} entries)")
     run = Run(
@@ -640,9 +679,7 @@ def main(argv=None):
                if backend == "tpu" else None),
         seed=args.seed, traced=bool(args.trace),
     )
-    rows = files.load_module("traffic", traffic["generator"]).generate(
-        traffic, args.seed
-    )
+    rows = traffic_rows(cell, traffic, args.seed)
     # Past the vocabulary no sampled token is EOS (benchmark/tokenizer.py).
     tok = ByteTokenizer(
         eos_token_id=EOS_FIXTURE if traffic.get("eos_reachable")

@@ -1,7 +1,8 @@
 """What lets the harness take a block it has not met: the weight check
 over whatever leaves a tree has, FLOPs and bytes from the config's
 structure, `toy()` driven by the config file, and the readers of the
-program's scopes.  No subprocess; seconds."""
+program's scopes: no subprocess, seconds.  Also carries the cases of
+`fixed_work_cases.py` into tier-1 (the import at the end)."""
 
 import os
 import types
@@ -269,3 +270,13 @@ def test_scope_readers_say_nothing_without_scopes_or_without_a_trace():
     for run in (bare, types.SimpleNamespace(trace=None)):
         for reader in READERS:
             assert reader.read(run) is None, reader.__name__
+
+
+# ------------------------------------------------------- the fixed work
+# The cases of `fixed_work_cases.py` (a cell's `timed_steps` and
+# `traffic_seed`, a configuration's `weights_seed`, the dense cells' build
+# arguments) reach tier-1 through this module, which
+# `tests/test_benchmark_harness.py` imports whole; two of them are CPU
+# rehearsals, 20 s each.
+
+from benchmark.tests.fixed_work_cases import *  # noqa: E402,F401,F403
