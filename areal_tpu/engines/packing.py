@@ -72,8 +72,6 @@ def split_sharded(
     key = sample.main_key()
     lens = [sum(sample.seqlens[key][i]) for i in range(sample.bs)]
     cap = mb_spec.max_tokens_per_mb or (sum(lens) + 1)
-    from areal_tpu.base import datapack
-
     k = max(mb_spec.n_mbs, 1)
     while True:
         per = [
@@ -136,6 +134,36 @@ class RowPack:
         return np.concatenate(parts, axis=0)
 
 
+def _rows_over_mesh(
+    sizes: Sequence[int], ffd: List[List[int]], n_rows: int
+) -> List[List[int]]:
+    """Lay `sizes` out over exactly `n_rows` rows, given their FFD packing.
+
+    FFD packs as few rows as fit; where the mesh needs more (`n_rows` >
+    len(ffd)) the extra rows would be empty, and under batch sharding an
+    empty row is a chip that trains zeros while another trains a full
+    row.  So the sequences are spread over all `n_rows` by load instead
+    (LPT), which also shortens the heaviest row and with it the bucketed
+    row length.  FFD's layout is kept, padded with empty rows, in the
+    rare case where the balanced layout's heaviest row would be heavier
+    (the grid never grows), and as it is when it already has `n_rows`.
+    Depends on nothing but `sizes` and `n_rows`: every SPMD member
+    derives the same layout from metadata alone.
+    """
+    if len(ffd) >= n_rows:
+        return ffd
+
+    def heaviest(groups):
+        return max((sum(sizes[i] for i in g) for g in groups), default=0)
+
+    balanced = datapack.partition_balanced(sizes, n_rows)
+    if heaviest(balanced) > heaviest(ffd):
+        return ffd + [[] for _ in range(n_rows - len(ffd))]
+    # FFD's row order: by smallest contained index, empty rows last.
+    balanced.sort(key=lambda g: g[0] if g else 1 << 62)
+    return balanced
+
+
 def pack_sample(
     sample: SequenceSample,
     token_key: str,
@@ -148,8 +176,12 @@ def pack_sample(
     """Pack every sequence of `sample[token_key]` into dense rows.
 
     extra_keys must be token-aligned with token_key (same seqlens).  The
-    number of rows is padded to a multiple of `n_rows_multiple` (the mesh's
-    batch-sharding degree) with empty rows if needed.
+    number of rows is FFD's count under `max_tokens_per_row`, rounded up to
+    a multiple of `n_rows_multiple` (the mesh's batch-sharding degree);
+    where that rounding adds rows, the sequences are balanced over all of
+    them (`_rows_over_mesh`), so a row is empty only when there are fewer
+    sequences than rows.  With a multiple of 1, or an FFD count that is
+    already a multiple, the layout is FFD's own.
 
     shard_blocks (per-shard lists of sequence indices, together covering
     every sequence exactly once) pins each shard's sequences to its own
@@ -157,7 +189,9 @@ def pack_sample(
     batch-coordinate layout `_device_batch` shards rows by.  On a
     process-spanning mesh each process then materializes real data only
     for its own block (the sharded data plane zero-fills the rest), and
-    identical metadata yields an identical layout on every member.
+    identical metadata yields an identical layout on every member.  A
+    shard whose FFD count is short of the common block size is balanced
+    over its block by the same rule.
     """
     lens = sample.seqlens_of(token_key)
     for k in extra_keys:
@@ -188,14 +222,15 @@ def pack_sample(
             rows_per_shard += 1
         groups = []
         for block, gs in zip(shard_blocks, per_groups):
-            local = [[block[i] for i in g] for g in gs]
-            local += [[] for _ in range(rows_per_shard - len(local))]
-            groups.extend(local)
+            gs = _rows_over_mesh(
+                [lens[i] for i in block], gs, rows_per_shard
+            )
+            groups.extend([block[i] for i in g] for g in gs)
     else:
         groups = datapack.ffd_allocate(lens, capacity=cap)
-        # Pad row count up to a multiple.
-        while len(groups) % max(n_rows_multiple, 1):
-            groups.append([])
+        # Round the row count up to a multiple.
+        mult = max(n_rows_multiple, 1)
+        groups = _rows_over_mesh(lens, groups, -(-len(groups) // mult) * mult)
     n_rows = len(groups)
     s_pad = row_len or bucket_len(
         max((sum(lens[i] for i in g) for g in groups), default=1)

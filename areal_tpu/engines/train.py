@@ -119,6 +119,23 @@ def _model_out(params, cfg: ModelConfig, x, batch):
     )
 
 
+def _grid_counts(chunks: Sequence[Dict[str, np.ndarray]]) -> Dict[str, int]:
+    """What a call's packed grids hold: real tokens against grid cells,
+    and rows against rows with no real token (under batch sharding an
+    empty row is a chip that trains zeros).  The rows also go to the
+    tracer's `pack` counter track."""
+    real = [c["segment_ids"] > 0 for c in chunks]
+    n_rows = sum(r.shape[0] for r in real)
+    empty_rows = sum(int((~r.any(axis=1)).sum()) for r in real)
+    tracer.counter("pack", n_rows=n_rows, empty_rows=empty_rows)
+    return {
+        "real_tokens": sum(int(r.sum()) for r in real),
+        "grid_tokens": sum(r.size for r in real),
+        "n_rows": n_rows,
+        "empty_rows": empty_rows,
+    }
+
+
 class TrainEngine(HostOffloadMixin, Engine):
     """Engine holding fp32 master params + optimizer state on a mesh."""
 
@@ -550,16 +567,11 @@ class TrainEngine(HostOffloadMixin, Engine):
             # Pack efficiency diagnostics: the MFU counter charges REAL
             # tokens, the MXU computes PADDED grids — the ratio is the
             # first thing to check when train MFU disappoints.
-            real_tokens = sum(
-                int((c["segment_ids"] > 0).sum()) for c in chunks
-            )
-            grid_tokens = sum(
-                int(np.prod(c["segment_ids"].shape)) for c in chunks
-            )
+            grid = _grid_counts(chunks)
             self.last_pack_stats = {
-                "real_tokens": real_tokens,
-                "grid_tokens": grid_tokens,
-                "pack_efficiency": real_tokens / max(grid_tokens, 1),
+                **grid,
+                "pack_efficiency": grid["real_tokens"]
+                / max(grid["grid_tokens"], 1),
                 "n_micro_batches": len(chunks),
             }
 
@@ -669,6 +681,8 @@ class TrainEngine(HostOffloadMixin, Engine):
             "n_chunks": 0,
             "real_tokens": 0,
             "grid_tokens": 0,
+            "n_rows": 0,
+            "empty_rows": 0,
             "host_s": 0.0,
         }
 
@@ -713,6 +727,8 @@ class TrainEngine(HostOffloadMixin, Engine):
                 c for pk in packs for c in self._pack_row_chunks(pk.arrays)
             ]
             chunk_weight = float(sum(loss_weight_fn(c) for c in chunks))
+            for k, v in _grid_counts(chunks).items():
+                state[k] += v
 
         grad_fn, grad_acc_fn = self._get_grad_fn(loss_fn)
         scale = jnp.float32(1.0)  # traced arg: no retrace vs train_batch
@@ -732,8 +748,6 @@ class TrainEngine(HostOffloadMixin, Engine):
                     )
             losses.append(loss)
             all_stats.append(stats)
-            state["real_tokens"] += int((arrays["segment_ids"] > 0).sum())
-            state["grid_tokens"] += int(np.prod(arrays["segment_ids"].shape))
         # Host conversion AFTER the dispatch loop, as ONE batched
         # transfer (loss sum + every stat sum in a single stacked
         # vector): one sync per chunk, not per micro-batch or per stat;
@@ -800,6 +814,8 @@ class TrainEngine(HostOffloadMixin, Engine):
         self.last_pack_stats = {
             "real_tokens": state["real_tokens"],
             "grid_tokens": state["grid_tokens"],
+            "n_rows": state["n_rows"],
+            "empty_rows": state["empty_rows"],
             "pack_efficiency": state["real_tokens"]
             / max(state["grid_tokens"], 1),
             "n_micro_batches": state["n_micro_batches"],

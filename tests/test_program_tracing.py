@@ -368,6 +368,26 @@ def test_pack_and_sync_counters(trial):
             assert stats[f"actor_gen/sync/{k}"] == sync[k]
 
 
+def test_pack_counter_carries_rows_and_empty_rows(trial):
+    """How often balancing over the mesh's rows engages: `last_pack_stats`
+    and the `pack` counter track say how many rows a call packed and how
+    many hold no real token (one device here: FFD's rows, none empty)."""
+    for pack in trial["pack"]:
+        assert pack["n_rows"] >= pack["n_micro_batches"] >= 1
+        assert pack["empty_rows"] == 0
+        assert pack["grid_tokens"] % pack["n_rows"] == 0
+    counters = [
+        e["args"] for e in trial["events"]
+        if e.get("ph") == "C" and e["name"] == "pack"
+    ]
+    # One per train_batch call: two PPO minibatches a step, three steps.
+    assert len(counters) == 6
+    assert all(set(c) == {"n_rows", "empty_rows"} for c in counters)
+    assert counters[-1] == {
+        k: trial["pack"][-1][k] for k in ("n_rows", "empty_rows")
+    }
+
+
 def test_compiles_are_charged_to_the_mfc_that_compiled(trial):
     first, _, third = trial["stats"]
     assert first["actor_gen/perf/compiles"] >= 1
