@@ -22,34 +22,65 @@ logger = logging.getLogger("monitor")
 # ---------------- FLOPs ----------------
 
 
+def _attn_layers(cfg) -> int:
+    """Layers with softmax attention: one per period of a hybrid pattern."""
+    return getattr(cfg, "n_periods", cfg.n_layers)
+
+
 def matmul_params(cfg) -> int:
     """Parameters that participate in matmuls for ONE token's forward pass
-    (the routed experts and the router for MoE; embedding lookup excluded)."""
+    (the routed experts and the router for MoE; embedding lookup excluded).
+
+    Per layer KIND in a hybrid pattern: a softmax-attention layer's
+    projections (the query's twice where it also gives the output gate), a
+    Gated DeltaNet layer's projections plus its recurrence counted as the
+    3 * d_k * d_v multiply-adds a value head's state takes per token
+    (S^T k, S^T q, k d^T).  Of the routed experts the ones HELD here: a
+    rank's share computes n_experts / router_width of a token's k choices
+    in expectation, beside the whole router and the shared expert."""
     h = cfg.hidden_dim
     d = cfg.head_dim
-    attn = h * (cfg.n_q_heads * d + 2 * cfg.n_kv_heads * d) + cfg.n_q_heads * d * h
+    q_mats = 2 if getattr(cfg, "attn_gate", False) else 1
+    attn = (
+        h * (q_mats * cfg.n_q_heads * d + 2 * cfg.n_kv_heads * d)
+        + cfg.n_q_heads * d * h
+    )
+    n_attn = _attn_layers(cfg)
+    mixers = n_attn * attn
+    if n_attn != cfg.n_layers:
+        hv = cfg.linear_n_v_heads
+        linear = (
+            h * (cfg.linear_conv_dim + cfg.linear_value_dim + 2 * hv)
+            + cfg.linear_value_dim * h
+            + 3 * hv * cfg.linear_k_head_dim * cfg.linear_v_head_dim
+        )
+        mixers += (cfg.n_layers - n_attn) * linear
     n_mats = 3 if getattr(cfg, "mlp_gated", True) else 2
     if cfg.is_moe:
         inter = cfg.moe_intermediate_dim or cfg.intermediate_dim
-        mlp = n_mats * h * inter * cfg.n_experts_per_tok + h * cfg.n_experts
+        width = getattr(cfg, "router_width", cfg.n_experts)
+        held = cfg.n_experts_per_tok * cfg.n_experts / width
+        mlp = n_mats * h * inter * held + h * width
+        shared = getattr(cfg, "shared_expert_dim", 0)
+        if shared:
+            mlp += 3 * h * shared + h
     else:
         mlp = n_mats * h * cfg.intermediate_dim
-    per_layer = attn + mlp
     head = 0 if cfg.is_critic else h * cfg.vocab_size
-    return cfg.n_layers * per_layer + head
+    return int(mixers + cfg.n_layers * mlp + head)
 
 
 def flops_forward(
     cfg, n_tokens: int, sum_sq_seqlens: Optional[float] = None
 ) -> float:
     """Forward-pass FLOPs over packed sequences: 2*N per token for matmuls
-    plus the quadratic attention term 4*h_q*sum_i(s_i^2) per layer (QK^T
-    and attn@V, causal factor folded into the constant the same way the
-    reference counts it, flops_counter.py)."""
+    plus the quadratic attention term 4*h_q*sum_i(s_i^2) per softmax-
+    attention layer (QK^T and attn@V, causal factor folded into the
+    constant the same way the reference counts it, flops_counter.py)."""
     mm = 2.0 * matmul_params(cfg) * n_tokens
     if sum_sq_seqlens is None:
         sum_sq_seqlens = float(n_tokens) ** 2
-    attn = 2.0 * 2.0 * cfg.n_q_heads * cfg.head_dim * sum_sq_seqlens * cfg.n_layers
+    attn = 2.0 * 2.0 * cfg.n_q_heads * cfg.head_dim * sum_sq_seqlens * _attn_layers(cfg)
     return mm + attn
 
 
@@ -69,7 +100,7 @@ def flops_generate(
     p_sq = float(sum(p * p for p in prompt_lens))
     total = flops_forward(cfg, int(p_tokens), p_sq)
     n = 2.0 * matmul_params(cfg)
-    attn_c = 4.0 * cfg.n_q_heads * cfg.head_dim * cfg.n_layers
+    attn_c = 4.0 * cfg.n_q_heads * cfg.head_dim * _attn_layers(cfg)
     for p, g in zip(prompt_lens, gen_lens):
         total += n * g
         # sum over decode steps of (p + t) ~ g*p + g^2/2

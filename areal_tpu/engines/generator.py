@@ -643,7 +643,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         self.decode_compiles = 0
         self.cache_copy_bytes = 0
         self.last_pool_stats = {}
-        self._moe_decode_sums = np.zeros((3,))
+        self._moe_decode_sums = np.zeros((_n_moe_counters(self.cfg),))
         self.lanes_dispatched = 0
         self.lanes_live = 0
         self.lanes_slack = 0
@@ -687,6 +687,15 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             inflight = (
                 len(reqs) > b_cap
                 or gconfig.max_new_tokens > self.static_path_max_new
+            )
+        if inflight and self.cfg.is_hybrid:
+            # Never a silent fallback to a plane that would drop the state.
+            raise tfm.HybridLayoutError(
+                f"{tfm._NO_SERVING_STATE}; this call has {len(reqs)} requests "
+                f"for {b_cap} slots, max_new_tokens "
+                f"{gconfig.max_new_tokens} (static_path_max_new "
+                f"{self.static_path_max_new}), stop={bool(gconfig.stop)}, "
+                f"spec_decode_k={gconfig.spec_decode_k}, inflight={inflight}"
             )
         # Uncategorized envelope span (the inner prefill/decode spans carry
         # cat="compute"; host assembly gaps inside show as idle).
@@ -2167,7 +2176,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         fullest expert (means over every step of this generate()); and
         which way the expert weights reached the ragged kernels (1: the
         parameters' own buffers, 0: the layer scan's slices)."""
-        touched, rows_max, steps = self._moe_decode_sums
+        touched, rows_max, steps, *share = self._moe_decode_sums
         if steps:
             self.last_pool_stats.update(
                 moe_experts_touched=touched / steps,
@@ -2175,6 +2184,15 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                 moe_decode_steps=int(steps),
                 moe_expert_leaves_in_place=int(self._expert_leaves_in_place),
             )
+            if share:
+                # One expert-parallel rank's share: (row, choice) pairs
+                # that fell to experts held here, of all the router made,
+                # over every decode step and layer.  Balanced routing
+                # reads n_experts / router_width.
+                self.last_pool_stats.update(
+                    moe_rows_local=float(share[0]),
+                    moe_rows_routed=float(share[1]),
+                )
 
     # -- one fixed-shape chunk --
 
@@ -2196,6 +2214,20 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             prompt_len[r] = len(toks)
 
         fn = self._get_gen_fn(b, sp, s_total, gconfig)
+        if self.cfg.is_hybrid:  # the two kinds of state, from shapes alone
+            cache = jax.eval_shape(
+                lambda: tfm.init_kv_cache(
+                    self.cfg, b, s_total, dtype=self.compute_dtype
+                )
+            )
+
+            def nbytes(*xs):
+                return sum(x.size * x.dtype.itemsize for x in xs)
+
+            self.last_pool_stats.update(
+                kv_cache_bytes=nbytes(cache.k, cache.v),
+                state_cache_bytes=nbytes(cache.state, cache.conv),
+            )
         with tracer.span("gen_chunk", cat="compute", b=b_real, sp=sp):
             with tracer.span("gen_dispatch", cat="compute"):
                 toks, logps, gen_len, *moe = fn(
@@ -2279,15 +2311,15 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                     with_moe_counts=cfg.is_moe, experts_in_place=in_place,
                 )
                 if cfg.is_moe:
-                    moe = [moe[0] + _moe_step_counters(counts[0])]
+                    moe = [moe[0] + _moe_step_counters(counts[0], cfg, bsz)]
                 return (
                     step + 1, next_logits, key, new_done, gen_len,
                     out_toks, out_logps, cache, *moe,
                 )
 
             state = (0, logits0, key, done, gen_len, out_toks, out_logps, cache)
-            if cfg.is_moe:  # two sums + the steps they run over
-                state += (jnp.zeros((3,), jnp.float32),)
+            if cfg.is_moe:  # two sums + the steps they run over (+ share)
+                state += (jnp.zeros((_n_moe_counters(cfg),), jnp.float32),)
             state = jax.lax.while_loop(cond, body, state)
             _, _, _, _, gen_len, out_toks, out_logps, _, *moe = state
             return (out_toks, out_logps, gen_len, *moe)
@@ -2315,17 +2347,29 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         )
 
 
-def _moe_step_counters(counts: jax.Array) -> jax.Array:
+def _n_moe_counters(cfg) -> int:
+    """Length of `_moe_step_counters`' vector for this config."""
+    return 5 if cfg.expert_share else 3
+
+
+def _moe_step_counters(counts: jax.Array, cfg, n_rows: int) -> jax.Array:
     """One decode step's rows per expert [L, E] -> f32 [3]: experts with at
     least one row and the fullest expert's rows (both means over layers),
-    and 1 for the step — what the decode loop sums with no host sync."""
-    return jnp.stack(
-        [
-            jnp.mean(jnp.sum(counts > 0, axis=-1).astype(jnp.float32)),
-            jnp.mean(jnp.max(counts, axis=-1).astype(jnp.float32)),
-            jnp.float32(1.0),
+    and 1 for the step — what the decode loop sums with no host sync.  A
+    rank's share of the experts (`cfg.expert_share`) adds two: the rows
+    that reached experts held here, and the rows the router sent anywhere
+    (`n_rows` tokens x k choices x L layers)."""
+    out = [
+        jnp.mean(jnp.sum(counts > 0, axis=-1).astype(jnp.float32)),
+        jnp.mean(jnp.max(counts, axis=-1).astype(jnp.float32)),
+        jnp.float32(1.0),
+    ]
+    if cfg.expert_share:
+        out += [
+            jnp.sum(counts).astype(jnp.float32),
+            jnp.float32(n_rows * cfg.n_experts_per_tok * counts.shape[0]),
         ]
-    )
+    return jnp.stack(out)
 
 
 def assemble_rollout(

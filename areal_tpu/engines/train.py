@@ -348,6 +348,17 @@ class TrainEngine(HostOffloadMixin, Engine):
                 total = loss + cfg.moe_aux_loss_coef * aux
                 if cfg.is_moe:
                     stats = {**stats, **_moe_stats(aux, counts)}
+                if cfg.is_hybrid:
+                    # Every segment start is a restart of the recurrence
+                    # and of the conv inside a packed row.
+                    seg = batch["segment_ids"]
+                    starts = (seg[:, 1:] != seg[:, :-1]) & (seg[:, 1:] > 0)
+                    stats = {
+                        **stats,
+                        "linear_attn/segments_per_row": jnp.mean(
+                            (seg[:, 0] > 0) + jnp.sum(starts, axis=-1)
+                        ).astype(jnp.float32),
+                    }
                 return total * loss_scale, stats
 
             with jax.named_scope("train/grad"):

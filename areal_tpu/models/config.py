@@ -65,6 +65,48 @@ class ModelConfig:
     pos_emb: str = "rope"  # rope | learned (gpt2 wpe)
     mlp_gated: bool = True  # False = plain fc/act/proj (gpt2)
     proj_bias: bool = False  # biases on attn-out + mlp matmuls (gpt2)
+    # ---- hybrid layer pattern (qwen3_next) ----
+    # Layer i is softmax attention when (i + 1) % full_attn_interval == 0,
+    # else a Gated DeltaNet (linear attention) block: the stack is scanned
+    # by PERIODS of `full_attn_interval` layers.  1 = every layer is full
+    # attention, a period of one (every other family).
+    full_attn_interval: int = 1
+    linear_n_k_heads: int = 0
+    linear_n_v_heads: int = 0
+    linear_k_head_dim: int = 0
+    linear_v_head_dim: int = 0
+    linear_conv_kernel: int = 4
+    # Rotary embedding on the first `rotary_dim` of head_dim only (HF
+    # `partial_rotary_factor`); 0 = all of head_dim.
+    rotary_dim: int = 0
+    # `qk_norm` per HEAD (a [head_dim] weight, after the reshape) instead
+    # of olmoe's norm over the whole projection.
+    qk_norm_per_head: bool = False
+    # The query projection also gives a per-head gate: o_proj(attn *
+    # sigmoid(gate)).
+    attn_gate: bool = False
+    # A SwiGLU expert every token goes through, scaled by a sigmoid gate
+    # (0 = none).
+    shared_expert_dim: int = 0
+    # One expert-parallel rank's share: the router scores `n_router_experts`
+    # (0 = n_experts) and this program holds experts [expert_offset,
+    # expert_offset + n_experts) of them.  Choices that fall elsewhere add
+    # nothing here: the layer's output is this rank's PART of the sum.
+    n_router_experts: int = 0
+    expert_offset: int = 0
+
+    def __post_init__(self):
+        if self.n_layers % self.full_attn_interval:
+            raise ValueError(
+                f"{self.n_layers} layers are not whole periods of "
+                f"{self.full_attn_interval} (full_attn_interval)"
+            )
+        if self.expert_offset + self.n_experts > self.router_width:
+            raise ValueError(
+                f"experts [{self.expert_offset}, "
+                f"{self.expert_offset + self.n_experts}) lie outside the "
+                f"router's {self.router_width} outputs"
+            )
 
     @property
     def dtype(self):
@@ -73,6 +115,40 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def is_hybrid(self) -> bool:
+        return self.full_attn_interval > 1
+
+    @property
+    def n_periods(self) -> int:
+        return self.n_layers // self.full_attn_interval
+
+    @property
+    def n_linear_layers(self) -> int:
+        return self.n_periods * (self.full_attn_interval - 1)
+
+    @property
+    def linear_key_dim(self) -> int:
+        return self.linear_n_k_heads * self.linear_k_head_dim
+
+    @property
+    def linear_value_dim(self) -> int:
+        return self.linear_n_v_heads * self.linear_v_head_dim
+
+    @property
+    def linear_conv_dim(self) -> int:
+        """Channels the causal conv runs over: q, k and v."""
+        return 2 * self.linear_key_dim + self.linear_value_dim
+
+    @property
+    def router_width(self) -> int:
+        return self.n_router_experts or self.n_experts
+
+    @property
+    def expert_share(self) -> bool:
+        """Whether this program holds only some of the routed experts."""
+        return self.router_width != self.n_experts
 
     @property
     def q_dim(self) -> int:

@@ -534,6 +534,272 @@ register_hf_family(
 )
 
 
+# ---------------- qwen3_next ----------------
+# A hybrid layer pattern: layer i is gated softmax attention when (i + 1) %
+# full_attention_interval == 0, else Gated DeltaNet (linear attention);
+# every layer's MLP is a mixture of experts plus one gated shared expert.
+# HF fuses the DeltaNet projections per KEY head (`in_proj_qkvz`: [q, k,
+# v x r, z x r], `in_proj_ba`: [b x r, a x r], r = value heads per key
+# head) and the attention query with its gate per head (`q_proj`: [q,
+# gate]); ours keep q | k | v, z, b | a and wq, wqg apart, heads contiguous.
+#
+# A `share` group in the config (not an HF key; benchmark configurations
+# carry it) cuts the model to one expert-parallel rank: `num_experts` is
+# then the number HELD here, of `share.router_num_experts` the router
+# scores, starting at expert `share.rank * num_experts`.  A published
+# config.json has no such group: share 1 of 1, the whole model.
+
+
+def _qwen3_next_config_from_hf(hf: dict) -> ModelConfig:
+    for key, fine in (
+        ("mlp_only_layers", []), ("decoder_sparse_step", 1),
+        ("rope_scaling", None), ("attention_bias", False),
+        ("use_sliding_window", False), ("hidden_act", "silu"),
+    ):
+        if hf.get(key, fine) != fine:
+            raise NotImplementedError(
+                f"qwen3_next {key}={hf[key]!r} is not modelled"
+            )
+    share = hf.get("share") or {}
+    n_experts = hf["num_experts"]
+    width = share.get("router_num_experts", n_experts)
+    return ModelConfig(
+        n_layers=hf["num_hidden_layers"],
+        hidden_dim=hf["hidden_size"],
+        n_q_heads=hf["num_attention_heads"],
+        n_kv_heads=hf["num_key_value_heads"],
+        head_dim=hf["head_dim"],
+        intermediate_dim=hf["intermediate_size"],
+        vocab_size=hf["vocab_size"],
+        max_position_embeddings=hf.get("max_position_embeddings", 262144),
+        rope_theta=hf.get("rope_theta", 10000000.0),
+        rms_norm_eps=hf.get("rms_norm_eps", 1e-6),
+        rms_norm_offset=True,
+        qk_norm=True,
+        qk_norm_per_head=True,
+        attn_gate=True,
+        rotary_dim=int(hf["head_dim"] * hf.get("partial_rotary_factor", 1.0)),
+        tied_embeddings=hf.get("tie_word_embeddings", False),
+        n_experts=n_experts,
+        n_experts_per_tok=hf["num_experts_per_tok"],
+        moe_intermediate_dim=hf["moe_intermediate_size"],
+        moe_norm_topk=bool(hf.get("norm_topk_prob", True)),
+        moe_aux_loss_coef=hf.get("router_aux_loss_coef", 0.001),
+        shared_expert_dim=hf.get("shared_expert_intermediate_size", 0),
+        n_router_experts=0 if width == n_experts else width,
+        expert_offset=share.get("rank", 0) * n_experts,
+        full_attn_interval=hf["full_attention_interval"],
+        linear_n_k_heads=hf["linear_num_key_heads"],
+        linear_n_v_heads=hf["linear_num_value_heads"],
+        linear_k_head_dim=hf["linear_key_head_dim"],
+        linear_v_head_dim=hf["linear_value_head_dim"],
+        linear_conv_kernel=hf["linear_conv_kernel_dim"],
+    )
+
+
+def _qwen3_next_config_to_hf(cfg: ModelConfig) -> dict:
+    out = _llama_like_config_to_hf(cfg, "qwen3_next")
+    out.update(
+        architectures=["Qwen3NextForCausalLM"],
+        hidden_act=cfg.hidden_act,
+        attention_bias=False,
+        partial_rotary_factor=(cfg.rotary_dim or cfg.head_dim) / cfg.head_dim,
+        full_attention_interval=cfg.full_attn_interval,
+        linear_num_key_heads=cfg.linear_n_k_heads,
+        linear_num_value_heads=cfg.linear_n_v_heads,
+        linear_key_head_dim=cfg.linear_k_head_dim,
+        linear_value_head_dim=cfg.linear_v_head_dim,
+        linear_conv_kernel_dim=cfg.linear_conv_kernel,
+        decoder_sparse_step=1,
+        mlp_only_layers=[],
+        num_experts=cfg.n_experts,
+        num_experts_per_tok=cfg.n_experts_per_tok,
+        moe_intermediate_size=cfg.moe_intermediate_dim,
+        shared_expert_intermediate_size=cfg.shared_expert_dim,
+        norm_topk_prob=cfg.moe_norm_topk,
+        router_aux_loss_coef=cfg.moe_aux_loss_coef,
+    )
+    if cfg.expert_share:
+        out["share"] = {
+            "router_num_experts": cfg.router_width,
+            "rank": cfg.expert_offset // cfg.n_experts,
+        }
+    return out
+
+
+_Q3N = "model.layers.{}."
+# ours <- HF name under the layer, transposed ([out, in] -> [in, out]).
+_Q3N_LAYER = (  # every layer
+    ("ln1", "input_layernorm.weight", False),
+    ("ln2", "post_attention_layernorm.weight", False),
+    ("router", "mlp.gate.weight", True),
+    ("ws_g", "mlp.shared_expert.gate_proj.weight", True),
+    ("ws_u", "mlp.shared_expert.up_proj.weight", True),
+    ("ws_d", "mlp.shared_expert.down_proj.weight", True),
+    ("ws_gate", "mlp.shared_expert_gate.weight", True),
+)
+_Q3N_FULL = (  # full-attention layers (wq / wqg: the fused q_proj, below)
+    ("wk", "self_attn.k_proj.weight", True),
+    ("wv", "self_attn.v_proj.weight", True),
+    ("wo", "self_attn.o_proj.weight", True),
+    ("q_norm", "self_attn.q_norm.weight", False),
+    ("k_norm", "self_attn.k_norm.weight", False),
+)
+_Q3N_LINEAR = (  # linear layers (the fused in_proj_*: below)
+    ("la_A_log", "linear_attn.A_log", False),
+    ("la_dt_bias", "linear_attn.dt_bias", False),
+    ("la_norm", "linear_attn.norm.weight", False),
+    ("la_wo", "linear_attn.out_proj.weight", True),
+)
+
+
+def _q3n_layers(cfg):
+    """(all, full-attention, linear) HF layer numbers."""
+    n = cfg.full_attn_interval
+    every = list(range(cfg.n_layers))
+    full = [i for i in every if (i + 1) % n == 0]
+    return every, full, [i for i in every if (i + 1) % n]
+
+
+def _q3n_fused_sizes(cfg):
+    """Per key head, the rows of in_proj_qkvz ([q, k, v, z]) and of
+    in_proj_ba ([b, a])."""
+    r = cfg.linear_n_v_heads // cfg.linear_n_k_heads
+    dk, dv = cfg.linear_k_head_dim, cfg.linear_v_head_dim
+    return (dk, dk, r * dv, r * dv), (r, r)
+
+
+def _qwen3_next_params_from_sd(cfg, sd, dtype=None):
+    import jax.numpy as jnp
+
+    dtype = dtype or cfg.dtype
+
+    def get(name):
+        if name not in sd:
+            raise KeyError(f"missing tensor {name!r} in checkpoint")
+        return np.asarray(sd[name], np.float32)
+
+    def stack(layers, fn):
+        return jnp.asarray(np.stack([fn(_Q3N.format(i)) for i in layers]), dtype)
+
+    every, full, linear = _q3n_layers(cfg)
+    blocks = {}
+    for group, layers in (
+        (_Q3N_LAYER, every), (_Q3N_FULL, full), (_Q3N_LINEAR, linear)
+    ):
+        for ours, theirs, transpose in group:
+            blocks[ours] = stack(
+                layers,
+                lambda pre: get(pre + theirs).T if transpose else get(pre + theirs),
+            )
+    hq, hd, d = cfg.n_q_heads, cfg.head_dim, cfg.hidden_dim
+
+    def q_proj(pre, part):  # [hq, (q | gate), hd, D] -> [D, hq * hd]
+        w = get(pre + "self_attn.q_proj.weight").reshape(hq, 2, hd, d)
+        return w[:, part].reshape(hq * hd, d).T
+
+    blocks["wq"] = stack(full, lambda pre: q_proj(pre, 0))
+    blocks["wqg"] = stack(full, lambda pre: q_proj(pre, 1))
+    hk = cfg.linear_n_k_heads
+    qkvz_sizes, ba_sizes = _q3n_fused_sizes(cfg)
+
+    def unfuse(pre, name, sizes, parts):
+        """Rows [hk, sum(sizes), D] of a per-key-head fused projection ->
+        the chosen parts, each with its heads contiguous: [D, sum]."""
+        w = get(pre + name).reshape(hk, sum(sizes), d)
+        cuts = np.cumsum((0,) + sizes)
+        return np.concatenate(
+            [w[:, cuts[j]: cuts[j + 1]].reshape(-1, d) for j in parts]
+        ).T
+
+    qkvz, ba = "linear_attn.in_proj_qkvz.weight", "linear_attn.in_proj_ba.weight"
+    blocks["la_wqkv"] = stack(
+        linear, lambda pre: unfuse(pre, qkvz, qkvz_sizes, (0, 1, 2)))
+    blocks["la_wz"] = stack(
+        linear, lambda pre: unfuse(pre, qkvz, qkvz_sizes, (3,)))
+    blocks["la_wba"] = stack(
+        linear, lambda pre: unfuse(pre, ba, ba_sizes, (0, 1)))
+    blocks["la_conv"] = stack(  # [C, 1, K] -> [K, C]
+        linear, lambda pre: get(pre + "linear_attn.conv1d.weight")[:, 0].T)
+    for ours, theirs in _OLMOE_EXPERT_LEAVES:
+        blocks[ours] = stack(
+            every,
+            lambda pre: np.stack([
+                get(f"{pre}mlp.experts.{cfg.expert_offset + e}.{theirs}.weight").T
+                for e in range(cfg.n_experts)
+            ]),
+        )
+    return {
+        "embed": jnp.asarray(get("model.embed_tokens.weight"), dtype),
+        "blocks": blocks,
+        "final_ln": jnp.asarray(get("model.norm.weight"), dtype),
+        "lm_head": jnp.asarray(get("lm_head.weight").T, dtype),
+    }
+
+
+def _qwen3_next_params_to_sd(cfg, params):
+    from areal_tpu.base.distributed import to_host
+
+    def host(x):
+        return to_host(x).astype(np.float32, copy=False)
+
+    blocks = {k: host(v) for k, v in params["blocks"].items()}
+    out = {
+        "model.embed_tokens.weight": host(params["embed"]),
+        "model.norm.weight": host(params["final_ln"]),
+        "lm_head.weight": np.ascontiguousarray(host(params["lm_head"]).T),
+    }
+    every, full, linear = _q3n_layers(cfg)
+    for group, layers in (
+        (_Q3N_LAYER, every), (_Q3N_FULL, full), (_Q3N_LINEAR, linear)
+    ):
+        for ours, theirs, transpose in group:
+            for j, i in enumerate(layers):
+                w = blocks[ours][j]
+                out[_Q3N.format(i) + theirs] = (
+                    np.ascontiguousarray(w.T) if transpose else w
+                )
+    hq, hd, d = cfg.n_q_heads, cfg.head_dim, cfg.hidden_dim
+    for j, i in enumerate(full):
+        parts = [blocks[n][j].T.reshape(hq, 1, hd, d) for n in ("wq", "wqg")]
+        out[_Q3N.format(i) + "self_attn.q_proj.weight"] = np.concatenate(
+            parts, axis=1).reshape(2 * hq * hd, d)
+    hk = cfg.linear_n_k_heads
+    kd, vd, hv = cfg.linear_key_dim, cfg.linear_value_dim, cfg.linear_n_v_heads
+
+    def fuse(parts):  # each [sum over heads, D] -> per key head, side by side
+        return np.concatenate(
+            [p.reshape(hk, -1, d) for p in parts], axis=1).reshape(-1, d)
+
+    for j, i in enumerate(linear):
+        pre = _Q3N.format(i) + "linear_attn."
+        qkv, ba = blocks["la_wqkv"][j].T, blocks["la_wba"][j].T
+        out[pre + "in_proj_qkvz.weight"] = fuse(
+            [qkv[:kd], qkv[kd: 2 * kd], qkv[2 * kd:], blocks["la_wz"][j].T])
+        out[pre + "in_proj_ba.weight"] = fuse([ba[:hv], ba[hv:]])
+        out[pre + "conv1d.weight"] = np.ascontiguousarray(
+            blocks["la_conv"][j].T)[:, None]
+    for ours, theirs in _OLMOE_EXPERT_LEAVES:
+        for i in every:
+            for e in range(cfg.n_experts):
+                out[
+                    f"{_Q3N.format(i)}mlp.experts.{cfg.expert_offset + e}."
+                    f"{theirs}.weight"
+                ] = np.ascontiguousarray(blocks[ours][i, e].T)
+    return out
+
+
+register_hf_family(
+    HFFamily(
+        "qwen3_next",
+        _qwen3_next_config_from_hf,
+        _qwen3_next_config_to_hf,
+        params_from_sd=_qwen3_next_params_from_sd,
+        params_to_sd=_qwen3_next_params_to_sd,
+    )
+)
+
+
 # ---------------- gpt2 ----------------
 # Different lineage: learned positions, LayerNorm with bias, fused c_attn,
 # plain (non-gated) gelu MLP, biases everywhere, Conv1D weights stored
@@ -681,6 +947,8 @@ def infer_model_type(cfg: ModelConfig) -> str:
     when the caller didn't record where the weights came from."""
     if cfg.norm_type == "layernorm":
         return "gpt2"
+    if cfg.is_hybrid:
+        return "qwen3_next"
     if cfg.is_moe:
         return "olmoe" if cfg.qk_norm else "mixtral"
     if cfg.rms_norm_offset:

@@ -1,0 +1,313 @@
+"""Gated DeltaNet: the linear-attention mixer of a hybrid layer pattern
+(qwen3_next: three of these to one softmax-attention block).
+
+Per value head, with a state S [d_k, d_v] kept in fp32:
+
+    S <- exp(g_t) S                      (decay, g_t <= 0)
+    d  = beta_t (v_t - S^T k_t)          (delta rule: what S gets wrong)
+    S <- S + k_t d^T
+    o_t = S^T q_t
+
+before it a causal depthwise conv + SiLU over the (q, k, v) channels, L2-
+normalised q and k, and after it a per-head RMSNorm gated by silu(z).
+
+Two forms of the same recurrence:
+- `linear_attn_forward` (training, `forward`, prefill): the chunked (WY)
+  form over chunks of CHUNK tokens under `lax.scan` — everything that does
+  not depend on the carried state is computed for all chunks at once, the
+  scan carries S through [C, d] x [d, d] matmuls.
+- `linear_attn_step` (decode): one token against the carried S and the
+  conv's last K-1 inputs.
+
+Packed rows: S and the conv restart at every segment start (attention
+masks by segment; a recurrence has to be told).  Inside a chunk that is a
+same-segment mask on the pairwise decays; across chunks the carried state
+is dropped for every token whose segment is not the one the previous chunk
+ended in.  Pads (segment 0) are a segment like any other: what they
+compute is never read.
+
+Parameters (leaves of `params["blocks"]`, stacked [n_linear_layers, ...]):
+    la_wqkv  [D, 2*key_dim + value_dim]   q | k | v, heads contiguous
+    la_wz    [D, value_dim]               the output gate
+    la_wba   [D, 2*n_v_heads]             b (-> beta) | a (-> g)
+    la_conv  [K, 2*key_dim + value_dim]   depthwise taps, oldest first
+    la_A_log, la_dt_bias [n_v_heads]
+    la_norm  [d_v]                        gated norm's weight (plain, not 1+w)
+    la_wo    [value_dim, D]
+"""
+
+from typing import Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from areal_tpu.models.config import ModelConfig
+
+CHUNK = 64
+Params = Dict[str, jax.Array]
+
+
+def init_linear_attn(cfg: ModelConfig, key: jax.Array, n: int, dense) -> Params:
+    """`n` layers' leaves.  `A_log` and `dt_bias` as the published module
+    initialises them (A uniform on (0, 16), dt_bias ones), the gated norm
+    at one; `dense(key, shape, fan_in)` is the caller's matrix init."""
+    D, C, VD = cfg.hidden_dim, cfg.linear_conv_dim, cfg.linear_value_dim
+    hv, K = cfg.linear_n_v_heads, cfg.linear_conv_kernel
+    ks = jax.random.split(key, 6)
+    a = jax.random.uniform(ks[5], (n, hv), jnp.float32, 1e-3, 16.0)
+    return {
+        "la_wqkv": dense(ks[0], (n, D, C), D),
+        "la_wz": dense(ks[1], (n, D, VD), D),
+        "la_wba": dense(ks[2], (n, D, 2 * hv), D),
+        "la_conv": dense(ks[3], (n, K, C), K),
+        "la_A_log": jnp.log(a).astype(cfg.dtype),
+        "la_dt_bias": jnp.ones((n, hv), cfg.dtype),
+        "la_norm": jnp.ones((n, cfg.linear_v_head_dim), cfg.dtype),
+        "la_wo": dense(ks[4], (n, VD, D), VD),
+    }
+
+
+LINEAR_LEAVES = (
+    "la_wqkv", "la_wz", "la_wba", "la_conv", "la_A_log", "la_dt_bias",
+    "la_norm", "la_wo",
+)
+
+
+def _l2norm(x: jax.Array, eps: float = 1e-6) -> jax.Array:
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+
+
+def _gates(ba: jax.Array, blk: Params, hv: int):
+    """beta = sigmoid(b), g = -exp(A_log) softplus(a + dt_bias), fp32."""
+    with jax.named_scope("gates"):
+        ba = ba.astype(jnp.float32)
+        b, a = ba[..., :hv], ba[..., hv:]
+        beta = jax.nn.sigmoid(b)
+        g = -jnp.exp(blk["la_A_log"].astype(jnp.float32)) * jax.nn.softplus(
+            a + blk["la_dt_bias"].astype(jnp.float32)
+        )
+        return beta, g
+
+
+def _split_heads(qkv: jax.Array, cfg: ModelConfig):
+    """conv output [..., C] -> q, k [..., hv, dk] (normalised, q scaled, each
+    key head repeated for its value heads) and v [..., hv, dv], all fp32."""
+    hk, hv = cfg.linear_n_k_heads, cfg.linear_n_v_heads
+    dk, dv, kd = cfg.linear_k_head_dim, cfg.linear_v_head_dim, cfg.linear_key_dim
+    lead = qkv.shape[:-1]
+    qkv = qkv.astype(jnp.float32)
+    q = _l2norm(qkv[..., :kd].reshape(*lead, hk, dk)) * dk**-0.5
+    k = _l2norm(qkv[..., kd: 2 * kd].reshape(*lead, hk, dk))
+    v = qkv[..., 2 * kd:].reshape(*lead, hv, dv)
+    rep = hv // hk
+    if rep > 1:  # key head i serves value heads [i*rep, (i+1)*rep)
+        q = jnp.repeat(q, rep, axis=-2)
+        k = jnp.repeat(k, rep, axis=-2)
+    return q, k, v
+
+
+def _out(o: jax.Array, z: jax.Array, blk: Params, cfg: ModelConfig):
+    """out_proj(w * rmsnorm(o) * silu(z)), the norm per head over d_v."""
+    with jax.named_scope("out_norm_proj"):
+        lead = o.shape[:-2]
+        of = o.astype(jnp.float32)
+        var = jnp.mean(jnp.square(of), axis=-1, keepdims=True)
+        of = of * jax.lax.rsqrt(var + cfg.rms_norm_eps)
+        of = (blk["la_norm"].astype(jnp.float32) * of).astype(z.dtype)
+        zf = z.reshape(*lead, cfg.linear_n_v_heads, cfg.linear_v_head_dim)
+        of = of.astype(jnp.float32) * jax.nn.silu(zf.astype(jnp.float32))
+        y = of.astype(z.dtype).reshape(*lead, cfg.linear_value_dim)
+        return y @ blk["la_wo"]
+
+
+def causal_conv(
+    x: jax.Array, taps: jax.Array, segment_ids: jax.Array
+) -> jax.Array:
+    """Depthwise causal conv over packed rows: out[t] = sum_j taps[j] *
+    x[t - (K-1) + j], an input counted only where it lies in t's own
+    segment.  x [B, S, C], taps [K, C] (oldest first), fp32 out."""
+    kk = taps.shape[0]
+    xf = x.astype(jnp.float32)
+    tf = taps.astype(jnp.float32)
+    out = xf * tf[kk - 1]
+    for back in range(1, kk):
+        xs = jnp.pad(xf[:, :-back], ((0, 0), (back, 0), (0, 0)))
+        ss = jnp.pad(
+            segment_ids[:, :-back], ((0, 0), (back, 0)), constant_values=-1
+        )
+        same = (ss == segment_ids)[..., None]
+        out = out + jnp.where(same, xs, 0.0) * tf[kk - 1 - back]
+    return out
+
+
+def conv_tail(x: jax.Array, segment_ids: jax.Array, kk: int) -> jax.Array:
+    """The last K-1 conv inputs of each row, zero where they belong to
+    another segment than the row's last token: what `linear_attn_step`
+    carries on from.  [B, K-1, C]."""
+    tail = x[:, -(kk - 1):]
+    seg = segment_ids[:, -(kk - 1):]
+    return jnp.where((seg == segment_ids[:, -1:])[..., None], tail, 0)
+
+
+def gated_delta_chunked(
+    q: jax.Array,  # [B, S, H, dk] fp32, normalised and scaled
+    k: jax.Array,  # [B, S, H, dk] fp32, normalised
+    v: jax.Array,  # [B, S, H, dv]
+    g: jax.Array,  # [B, S, H] fp32 log-decay (<= 0)
+    beta: jax.Array,  # [B, S, H]
+    segment_ids: jax.Array,  # [B, S]
+    chunk: int = CHUNK,
+) -> Tuple[jax.Array, jax.Array]:
+    """The gated delta rule over packed rows in chunked (WY) form ->
+    (o [B, S, H, dv] fp32, final state [B, H, dk, dv] fp32: the state after
+    each row's last token).
+
+    Inside a chunk, with G the running sum of g and A the strictly lower
+    triangle of (beta k k^T) * exp(G_i - G_j): U = (I + A)^-1 (beta v) and
+    W = (I + A)^-1 (beta k exp(G)) are what the chunk would write given the
+    state it starts from; the scan then needs only v_new = U - W S,
+    o = (q exp(G)) S + (q k^T * decay) v_new and the state's update."""
+    b, s, h, dk = q.shape
+    dv = v.shape[-1]
+    pad = -s % chunk
+    if pad:
+        # Neutral tokens (beta 0, g 0) of the last token's segment: the
+        # state passes through them unchanged.
+        def zpad(x):
+            return jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+
+        q, k, v, g, beta = (zpad(x) for x in (q, k, v, g, beta))
+        segment_ids = jnp.pad(segment_ids, ((0, 0), (0, pad)), mode="edge")
+    n = (s + pad) // chunk
+
+    def chunks(x):  # [B, S, H, ...] -> [B, H, N, C, ...]
+        x = x.reshape(b, n, chunk, *x.shape[2:])
+        return jnp.moveaxis(x, 3, 1)
+
+    q, k, v, g, beta = (
+        chunks(x.astype(jnp.float32)) for x in (q, k, v, g, beta)
+    )
+    seg = segment_ids.reshape(b, 1, n, chunk)
+    same = seg[..., :, None] == seg[..., None, :]  # [B, 1, N, C, C]
+    idx = jnp.arange(chunk)
+    tril = idx[:, None] >= idx[None, :]
+    # The state a chunk starts from belongs to the segment the previous
+    # chunk ended in; the first chunk starts from nothing.
+    prev_last = jnp.concatenate(
+        [jnp.full((b, 1, 1), -1, seg.dtype), seg[:, :, :-1, -1]], axis=2
+    )
+    carry_ok = (seg == prev_last[..., None]).astype(jnp.float32)  # [B,1,N,C]
+    same_as_last = (seg == seg[..., -1:]).astype(jnp.float32)
+
+    gc = jnp.cumsum(g, axis=-1)  # [B, H, N, C]
+    diff = gc[..., :, None] - gc[..., None, :]
+    decay = jnp.exp(jnp.where(tril, diff, 0.0)) * (tril & same)
+    kb = k * beta[..., None]
+    a_mat = jnp.einsum("...id,...jd->...ij", kb, k) * decay
+    a_mat = jnp.where(idx[:, None] > idx[None, :], a_mat, 0.0)
+    g_in = jnp.exp(gc) * carry_ok  # decay of the incoming state per token
+    rhs = jnp.concatenate(
+        [v * beta[..., None], kb * g_in[..., None]], axis=-1
+    )
+    sol = jax.scipy.linalg.solve_triangular(
+        a_mat + jnp.eye(chunk, dtype=a_mat.dtype), rhs,
+        lower=True, unit_diagonal=True,
+    )
+    u, w = sol[..., :dv], sol[..., dv:]
+    qk = jnp.einsum("...id,...jd->...ij", q, k) * decay
+    q_in = q * g_in[..., None]
+    g_last = gc[..., -1:]
+    k_out = k * (jnp.exp(g_last - gc) * same_as_last)[..., None]
+    s_keep = jnp.exp(g_last[..., 0]) * carry_ok[..., -1]  # [B, H, N]
+
+    def body(state, xs):
+        u_i, w_i, qk_i, q_i, k_i, keep_i = xs
+        v_new = u_i - w_i @ state
+        o_i = q_i @ state + qk_i @ v_new
+        state = state * keep_i[..., None, None] + jnp.einsum(
+            "bhck,bhcv->bhkv", k_i, v_new
+        )
+        return state, o_i
+
+    xs = tuple(
+        jnp.moveaxis(x, 2, 0) for x in (u, w, qk, q_in, k_out, s_keep)
+    )
+    state, o = jax.lax.scan(
+        body, jnp.zeros((b, h, dk, dv), jnp.float32), xs
+    )
+    o = jnp.moveaxis(o, 0, 2)  # [B, H, N, C, dv]
+    o = jnp.moveaxis(o, 1, 3).reshape(b, s + pad, h, dv)
+    return o[:, :s], state
+
+
+@jax.named_scope("layer/linear_attn")
+def linear_attn_forward(
+    h: jax.Array,  # [B, S, D] normed block input
+    blk: Params,
+    cfg: ModelConfig,
+    segment_ids: jax.Array,
+    with_state: bool = False,
+):
+    """-> y [B, S, D]; `with_state` (prefill) adds the state after each
+    row's last token [B, hv, dk, dv] fp32 and the conv's tail [B, K-1, C]."""
+    with jax.named_scope("in_proj"):
+        qkv = h @ blk["la_wqkv"]
+        z = h @ blk["la_wz"]
+        ba = h @ blk["la_wba"]
+    with jax.named_scope("conv"):
+        conv = jax.nn.silu(causal_conv(qkv, blk["la_conv"], segment_ids))
+    beta, g = _gates(ba, blk, cfg.linear_n_v_heads)
+    with jax.named_scope("delta_rule"):
+        q, k, v = _split_heads(conv, cfg)
+        o, state = gated_delta_chunked(q, k, v, g, beta, segment_ids)
+    y = _out(o, z, blk, cfg)
+    if with_state:
+        return y, state, conv_tail(qkv, segment_ids, cfg.linear_conv_kernel)
+    return y
+
+
+@jax.named_scope("layer/linear_attn")
+def linear_attn_step(
+    h: jax.Array,  # [B, 1, D]
+    blk: Params,
+    cfg: ModelConfig,
+    states: jax.Array,  # [n_linear, B, hv, dk, dv] fp32, every linear layer's
+    tails: jax.Array,  # [n_linear, B, K-1, C] the convs' last inputs
+    li,  # this layer's index into both
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """One decode token per row -> (y [B, 1, D], states, tails), layer
+    `li` of both stepped in place.  The whole caches come in and go out so
+    that the state's read and its write lie under this scope: XLA fuses the
+    update into the `dynamic-update-slice`, and an update made by the
+    caller would be timed outside `layer/linear_attn`."""
+    state = jax.lax.dynamic_index_in_dim(states, li, axis=0, keepdims=False)
+    tail = jax.lax.dynamic_index_in_dim(tails, li, axis=0, keepdims=False)
+    with jax.named_scope("in_proj"):
+        x = h[:, 0]
+        qkv = x @ blk["la_wqkv"]  # [B, C]
+        z = x @ blk["la_wz"]
+        ba = x @ blk["la_wba"]
+    with jax.named_scope("conv"):
+        taps = blk["la_conv"].astype(jnp.float32)
+        window = jnp.concatenate([tail, qkv[:, None].astype(tail.dtype)], 1)
+        conv = jax.nn.silu(
+            jnp.einsum("bkc,kc->bc", window.astype(jnp.float32), taps)
+        )
+        tails = jax.lax.dynamic_update_index_in_dim(
+            tails, window[:, 1:], li, axis=0
+        )
+    beta, g = _gates(ba, blk, cfg.linear_n_v_heads)
+    with jax.named_scope("delta_step"):
+        q, k, v = _split_heads(conv, cfg)  # [B, hv, d]
+        # One pass reads S (S^T k and S^T q of the OLD state), one
+        # rewrites it: o = S_new^T q with S_new = e^g S + k d^T.
+        decay = jnp.exp(g)[..., None]  # [B, hv, 1]
+        sk = jnp.sum(state * k[..., None], axis=-2)  # [B, hv, dv]
+        sq = jnp.sum(state * q[..., None], axis=-2)
+        d = beta[..., None] * (v - decay * sk)
+        kq = jnp.sum(k * q, axis=-1, keepdims=True)
+        o = decay * sq + kq * d
+        state = state * decay[..., None] + k[..., None] * d[..., None, :]
+        states = jax.lax.dynamic_update_index_in_dim(states, state, li, axis=0)
+    y = _out(o, z, blk, cfg)
+    return y[:, None], states, tails

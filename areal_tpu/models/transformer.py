@@ -36,6 +36,12 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from areal_tpu.models.config import ModelConfig
+from areal_tpu.models.linear_attention import (
+    LINEAR_LEAVES,
+    init_linear_attn,
+    linear_attn_forward,
+    linear_attn_step,
+)
 from areal_tpu.ops.attention import (
     decode_attention,
     packed_attention,
@@ -64,22 +70,37 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         ).astype(dtype)
 
     L, D, F = cfg.n_layers, cfg.hidden_dim, cfg.intermediate_dim
+    # A hybrid stack keeps ONE leading stack axis on every leaf (what the
+    # sharding rules, the hand-back and the references read): the norms and
+    # the MLP of all L layers, the attention leaves of its LA = n_periods
+    # full layers, the `la_*` leaves of its L - LA linear layers.  A period
+    # of one has LA = L.
+    LA = cfg.n_periods
+    # A (1 + w) norm starts at w = 0, a plain one at w = 1: scale one.
+    norm_init = jnp.zeros if cfg.rms_norm_offset else jnp.ones
     ks = jax.random.split(k_blocks, 8)
     blocks = {
-        "ln1": jnp.ones((L, D), dtype),
-        "wq": dense(ks[0], (L, D, cfg.q_dim), D),
-        "wk": dense(ks[1], (L, D, cfg.kv_dim), D),
-        "wv": dense(ks[2], (L, D, cfg.kv_dim), D),
-        "wo": dense(ks[3], (L, cfg.q_dim, D), cfg.q_dim),
-        "ln2": jnp.ones((L, D), dtype),
+        "ln1": norm_init((L, D), dtype),
+        "wq": dense(ks[0], (LA, D, cfg.q_dim), D),
+        "wk": dense(ks[1], (LA, D, cfg.kv_dim), D),
+        "wv": dense(ks[2], (LA, D, cfg.kv_dim), D),
+        "wo": dense(ks[3], (LA, cfg.q_dim, D), cfg.q_dim),
+        "ln2": norm_init((L, D), dtype),
     }
     if cfg.qkv_bias:
-        blocks["bq"] = jnp.zeros((L, cfg.q_dim), dtype)
-        blocks["bk"] = jnp.zeros((L, cfg.kv_dim), dtype)
-        blocks["bv"] = jnp.zeros((L, cfg.kv_dim), dtype)
-    if cfg.qk_norm:
-        blocks["q_norm"] = jnp.ones((L, cfg.q_dim), dtype)
-        blocks["k_norm"] = jnp.ones((L, cfg.kv_dim), dtype)
+        blocks["bq"] = jnp.zeros((LA, cfg.q_dim), dtype)
+        blocks["bk"] = jnp.zeros((LA, cfg.kv_dim), dtype)
+        blocks["bv"] = jnp.zeros((LA, cfg.kv_dim), dtype)
+    if cfg.qk_norm and cfg.qk_norm_per_head:
+        blocks["q_norm"] = norm_init((LA, cfg.head_dim), dtype)
+        blocks["k_norm"] = norm_init((LA, cfg.head_dim), dtype)
+    elif cfg.qk_norm:
+        blocks["q_norm"] = jnp.ones((LA, cfg.q_dim), dtype)
+        blocks["k_norm"] = jnp.ones((LA, cfg.kv_dim), dtype)
+    if cfg.attn_gate:
+        blocks["wqg"] = dense(ks[5], (LA, D, cfg.q_dim), D)
+    if cfg.is_hybrid:
+        blocks.update(init_linear_attn(cfg, ks[6], L - LA, dense))
     if cfg.norm_type == "layernorm":
         blocks["ln1_b"] = jnp.zeros((L, D), dtype)
         blocks["ln2_b"] = jnp.zeros((L, D), dtype)
@@ -91,10 +112,17 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     if cfg.is_moe:
         E, FM = cfg.n_experts, cfg.moe_intermediate_dim
         km = jax.random.split(ks[4], 4)
-        blocks["router"] = dense(km[0], (L, D, E), D)
+        blocks["router"] = dense(km[0], (L, D, cfg.router_width), D)
         blocks["wg"] = dense(km[1], (L, E, D, FM), D)
         blocks["wu"] = dense(km[2], (L, E, D, FM), D)
         blocks["wd"] = dense(km[3], (L, E, FM, D), FM)
+        if cfg.shared_expert_dim:
+            FS = cfg.shared_expert_dim
+            kx = jax.random.split(ks[7], 4)
+            blocks["ws_g"] = dense(kx[0], (L, D, FS), D)
+            blocks["ws_u"] = dense(kx[1], (L, D, FS), D)
+            blocks["ws_d"] = dense(kx[2], (L, FS, D), FS)
+            blocks["ws_gate"] = dense(kx[3], (L, D, 1), D)
     else:
         km = jax.random.split(ks[4], 3)
         blocks["wg"] = dense(km[0], (L, D, F), D)
@@ -115,7 +143,7 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     params: Params = {
         "embed": dense(k_embed, (cfg.vocab_size, D), embed_fan_in),
         "blocks": blocks,
-        "final_ln": jnp.ones((D,), dtype),
+        "final_ln": norm_init((D,), dtype),
     }
     if cfg.norm_type == "layernorm":
         params["final_ln_b"] = jnp.zeros((D,), dtype)
@@ -206,7 +234,14 @@ def positions_from_segments(segment_ids: jax.Array) -> jax.Array:
 
 
 @jax.named_scope("layer/attn_out")
-def _attn_out(a: jax.Array, blk: Params, cfg: ModelConfig) -> jax.Array:
+def _attn_out(
+    a: jax.Array,
+    blk: Params,
+    cfg: ModelConfig,
+    gate: Optional[jax.Array] = None,
+) -> jax.Array:
+    if gate is not None:  # qwen3_next: o_proj(attn * sigmoid(gate))
+        a = a * jax.nn.sigmoid(gate)
     y = a @ blk["wo"]
     if cfg.proj_bias:
         y = y + blk["bo"]
@@ -241,12 +276,30 @@ def _moe_route(x: jax.Array, blk: Params, cfg: ModelConfig):
 
     fp32 throughout: softmax over ALL experts, then top-k.  The weights are
     renormalised to sum to one only where the architecture says so
-    (`moe_norm_topk`: mixtral yes, olmoe no)."""
+    (`moe_norm_topk`: mixtral yes, olmoe no).
+
+    One expert-parallel rank's share (`cfg.expert_share`): the router
+    scores all `router_width` experts and the aux loss is over all of
+    them, but `top_idx` and `one_hot` come back in LOCAL numbering over the
+    `n_experts` held here — a choice that fell to an expert held elsewhere
+    is index `n_experts` (sorts last, one-hot all zero), so every dispatch
+    below computes this rank's part of the sum and nothing for the rest."""
     router_logits = (x.astype(jnp.float32)) @ blk["router"].astype(jnp.float32)  # [T, E]
     probs = jax.nn.softmax(router_logits, axis=-1)
     top_w, top_idx = jax.lax.top_k(probs, cfg.n_experts_per_tok)  # [T, k]
     if cfg.moe_norm_topk:
         top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+    if cfg.expert_share:
+        width = cfg.router_width
+        load = jnp.zeros((width,), probs.dtype).at[top_idx.reshape(-1)].add(
+            1.0 / x.shape[0]
+        )
+        aux = width * jnp.sum(load * jnp.mean(probs, axis=0))
+        local = top_idx - cfg.expert_offset
+        held = (local >= 0) & (local < cfg.n_experts)
+        top_idx = jnp.where(held, local, cfg.n_experts)
+        one_hot = jax.nn.one_hot(top_idx, cfg.n_experts, dtype=probs.dtype)
+        return top_w, top_idx, one_hot, aux
     one_hot = jax.nn.one_hot(top_idx, cfg.n_experts, dtype=probs.dtype)  # [T,k,E]
     # Load-balancing aux loss (switch-style): E * sum_e f_e * P_e.
     load = jnp.mean(one_hot.sum(axis=1), axis=0)  # fraction routed per expert
@@ -363,9 +416,24 @@ def _experts_grouped(
             )
             blk = {n: blk[n].reshape(-1, *blk[n].shape[2:]) for n in _EXPERT_LEAVES}
     with jax.named_scope("experts"):
-        gate = jax.nn.silu(jax.lax.ragged_dot(xs, blk["wg"], group_sizes))
-        up = jax.lax.ragged_dot(xs, blk["wu"], group_sizes)
-        ys = jax.lax.ragged_dot(gate * up, blk["wd"], group_sizes)  # [T*k, D]
+        # A rank's share: rows whose expert is held elsewhere sort past
+        # every group.  The ragged kernels do no work for them, and what
+        # they leave in those rows (forward, and as a cotangent on the way
+        # back) is not a result: `held` zeroes it at every step, so nothing
+        # of it reaches a sum or a gradient.
+        held = None
+        if cfg.expert_share:
+            held = (jnp.arange(xs.shape[0]) < jnp.sum(group_sizes))[:, None]
+
+        def ragged(lhs, w):
+            if held is None:
+                return jax.lax.ragged_dot(lhs, w, group_sizes)
+            out = jax.lax.ragged_dot(jnp.where(held, lhs, 0), w, group_sizes)
+            return jnp.where(held, out, 0)
+
+        gate = jax.nn.silu(ragged(xs, blk["wg"]))
+        up = ragged(xs, blk["wu"])
+        ys = ragged(gate * up, blk["wd"])  # [T*k, D]
     with jax.named_scope("combine"):
         w_sorted = top_w.reshape(-1)[order].astype(ys.dtype)
         return jnp.zeros_like(x).at[tok_of].add(ys * w_sorted[:, None])
@@ -457,6 +525,10 @@ def _mlp_moe(
         out = _MOE_EXPERTS[cfg.moe_dispatch](x, top_w, top_idx, one_hot, blk, cfg)
     else:
         out = _experts_grouped(x, top_w, top_idx, one_hot, stacked, cfg, layer)
+    if cfg.shared_expert_dim:
+        with jax.named_scope("shared"):
+            hid = jax.nn.silu(x @ blk["ws_g"]) * (x @ blk["ws_u"])
+            out = out + jax.nn.sigmoid(x @ blk["ws_gate"]) * (hid @ blk["ws_d"])
     return out.reshape(b, s, d), aux, counts
 
 
@@ -514,7 +586,29 @@ def _block_forward(
                 attn = ring_packed_attention(
                     q, k, v, segment_ids, cp_mesh, causal=True
                 )
-    attn_out = _attn_out(attn.reshape(b, s, cfg.q_dim), blk, cfg)
+    attn_out = _attn_out(
+        attn.reshape(b, s, cfg.q_dim), blk, cfg, _attn_gate(h, blk, cfg)
+    )
+    return _block_mlp(x, attn_out, blk, cfg, segment_ids)
+
+
+def _linear_block_forward(
+    x: jax.Array, blk: Params, cfg: ModelConfig, segment_ids: jax.Array
+) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
+    """`_block_forward` with the Gated DeltaNet mixer in attention's place."""
+    h = _norm(x, blk["ln1"], blk.get("ln1_b"), cfg)
+    mixed = linear_attn_forward(h, blk, cfg, segment_ids)
+    return _block_mlp(x, mixed, blk, cfg, segment_ids)
+
+
+def _block_mlp(
+    x: jax.Array,
+    attn_out: jax.Array,
+    blk: Params,
+    cfg: ModelConfig,
+    segment_ids: jax.Array,
+) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
+    """A block from its mixer's output on: residual, norm, MLP, residual."""
     # Named checkpoints for remat="dots_small" (see _backbone): the
     # attention output and the MLP down-projection output are the SMALL
     # per-token dots ([*, D]) whose saving lets backward skip only the
@@ -563,7 +657,15 @@ def _backbone(
     """-> (final-normed hidden states, summed MoE aux loss, per-layer rows
     per expert [L, E] int32 — None for dense models and under PP)."""
     x = _embed(params, cfg, tokens, positions)
-    cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
+    cos, sin = rope_cos_sin(positions, _rope_dim(cfg), cfg.rope_theta)
+
+    if cfg.is_hybrid and (cp_mesh is not None or pp_mesh is not None):
+        raise HybridLayoutError(
+            "a hybrid layer pattern (full_attn_interval "
+            f"{cfg.full_attn_interval}) runs under data and fsdp "
+            "sharding only: the chunked delta rule has no ring over a "
+            "split sequence, and the pipeline has no stage of periods"
+        )
 
     if pp_mesh is not None:
         from areal_tpu.parallel.pipeline import pipelined_blocks
@@ -618,55 +720,158 @@ def _backbone(
                 f"divisible by 2*seq={2 * cp_mesh.shape[_SEQ]}"
             )
 
-    def body(carry, blk):
-        y, aux, counts = _block_forward(
-            carry, blk, cfg, segment_ids, cos, sin, use_flash, cp_mesh,
-            cp_zigzag=zz_inv is not None,
-        )
-        return y, (aux, counts)
-
-    # Remat policy per scanned layer (HBM vs recompute-FLOPs tradeoff):
-    #   "full"/True — save nothing, recompute the whole layer in backward
-    #     (minimum activation memory; ~1/3 extra forward FLOPs);
-    #   "dots" — save matmul outputs, recompute elementwise/norms only
-    #     (more memory, near-zero recompute — the right default when the
-    #     activations fit);
-    #   "dots_small" — save only the per-layer residual-branch outputs
-    #     (attn_out, mlp_out): ~1/8 the memory of "dots", recomputes
-    #     most of the layer — for models where "dots" overflows HBM;
-    #   "none"/False — plain autodiff residuals.
-    if remat is True or remat == "full":
-        body = jax.checkpoint(
-            body, policy=jax.checkpoint_policies.nothing_saveable
-        )
-    elif remat == "dots":
-        body = jax.checkpoint(
-            body,
-            policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-        )
-    elif remat == "dots_small":
-        # Middle ground when "dots" (~46 KB/token/layer of saved matmul
-        # outputs at 1.5B) overflows HBM but "full" recompute caps MFU:
-        # save only the two [*, D] residual-branch outputs per layer
-        # (~6 KB/token/layer) — backward recomputes qkv/attention and
-        # the fat gate/up matmuls, but the residual stream itself is
-        # never recomputed.
-        body = jax.checkpoint(
-            body,
-            policy=jax.checkpoint_policies.save_only_these_names(
-                "attn_out", "mlp_out"
-            ),
-        )
-    elif remat not in (False, None, "none"):
-        raise ValueError(f"unknown remat policy {remat!r}")
-    x, (auxes, counts) = jax.lax.scan(body, x, params["blocks"])
+    x, auxes, counts = _period_blocks(
+        params["blocks"], cfg, x, segment_ids, cos, sin, remat, use_flash,
+        cp_mesh, cp_zigzag=zz_inv is not None,
+    )
     x = _final_norm(params, cfg, x)
     if zz_inv is not None:
         x = jnp.take(x, zz_inv, axis=1)
     return x, jnp.sum(auxes), counts
 
 
-@jax.named_scope("head_logprob")
+def _remat_layer(body, remat):
+    """`body` (one layer) under the remat policy (HBM vs recompute FLOPs):
+      "full"/True — save nothing, recompute the whole layer in backward
+        (minimum activation memory; ~1/3 extra forward FLOPs);
+      "dots" — save matmul outputs, recompute elementwise/norms only
+        (more memory, near-zero recompute — the right default when the
+        activations fit);
+      "dots_small" — save only the per-layer residual-branch outputs
+        (attn_out, mlp_out): ~1/8 the memory of "dots", recomputes
+        most of the layer — for models where "dots" overflows HBM;
+      "none"/False — plain autodiff residuals."""
+    if remat is True or remat == "full":
+        return jax.checkpoint(
+            body, policy=jax.checkpoint_policies.nothing_saveable
+        )
+    if remat == "dots":
+        return jax.checkpoint(
+            body,
+            policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+        )
+    if remat == "dots_small":
+        # Middle ground when "dots" (~46 KB/token/layer of saved matmul
+        # outputs at 1.5B) overflows HBM but "full" recompute caps MFU:
+        # save only the two [*, D] residual-branch outputs per layer
+        # (~6 KB/token/layer) — backward recomputes qkv/attention and
+        # the fat gate/up matmuls, but the residual stream itself is
+        # never recomputed.
+        return jax.checkpoint(
+            body,
+            policy=jax.checkpoint_policies.save_only_these_names(
+                "attn_out", "mlp_out"
+            ),
+        )
+    if remat not in (False, None, "none"):
+        raise ValueError(f"unknown remat policy {remat!r}")
+    return body
+
+
+class HybridLayoutError(NotImplementedError):
+    """A layout or plane a hybrid layer pattern (linear-attention layers
+    with recurrent state beside softmax-attention layers) cannot run on
+    yet, refused by name rather than run wrong."""
+
+
+# Leaves only a period's full-attention layer has (stacked [n_periods, ...]
+# in a hybrid model); `LINEAR_LEAVES` are the linear layers' ([n_linear,
+# ...]); every other block leaf is per layer ([n_layers, ...]).
+_FULL_ATTN_LEAVES = (
+    "wq", "wk", "wv", "wo", "wqg", "bq", "bk", "bv", "bo", "q_norm", "k_norm",
+)
+
+
+def _period_view(cfg: ModelConfig, blocks: Params) -> Params:
+    """The block leaves with the stack axis split by period: per-layer
+    leaves [P, n, ...], linear ones [P, n - 1, ...], the full layer's
+    [P, ...] (n = full_attn_interval).  Leading-axis reshapes: no data
+    moves.  What a scan over periods slices; a period of one is its layer
+    and the leaves are what they were."""
+    n, p = cfg.full_attn_interval, cfg.n_periods
+    if n == 1:
+        return blocks
+    out = {}
+    for name, w in blocks.items():
+        if name in _FULL_ATTN_LEAVES:
+            out[name] = w
+        elif name in LINEAR_LEAVES:
+            out[name] = w.reshape(p, n - 1, *w.shape[1:])
+        else:
+            out[name] = w.reshape(p, n, *w.shape[1:])
+    return out
+
+
+def _period_layer(cfg: ModelConfig, pblk: Params, j: int) -> Params:
+    """Layer j's leaves out of one period's slice of `_period_view`:
+    positions 0..n-2 are linear layers, n-1 the full-attention layer."""
+    if cfg.full_attn_interval == 1:
+        return pblk
+    last = j == cfg.full_attn_interval - 1
+    blk = {}
+    for name, w in pblk.items():
+        if name in _FULL_ATTN_LEAVES:
+            if last:
+                blk[name] = w
+        elif name in LINEAR_LEAVES:
+            if not last:
+                blk[name] = w[j]
+        else:
+            blk[name] = w[j]
+    return blk
+
+
+def _period_stack(cfg: ModelConfig, per_layer: list):
+    """One period's per-layer values (rows per expert; None for a dense
+    MLP) as the scan's output: [n, ...], or the layer's own for a period of
+    one.  `_all_layers` undoes it after the scan."""
+    if per_layer[0] is None or cfg.full_attn_interval == 1:
+        return per_layer[0]
+    return jnp.stack(per_layer)
+
+
+def _all_layers(cfg: ModelConfig, stacked):
+    """[P, n, ...] off a scan over periods -> [L, ...]."""
+    if stacked is None or cfg.full_attn_interval == 1:
+        return stacked
+    return stacked.reshape(cfg.n_layers, *stacked.shape[2:])
+
+
+def _period_blocks(
+    blocks: Params, cfg: ModelConfig, x, segment_ids, cos, sin, remat,
+    use_flash, cp_mesh=None, cp_zigzag: bool = False,
+):
+    """The block stack of every model: ONE `lax.scan` over periods, each
+    period's layers unrolled inside it (n - 1 Gated DeltaNet blocks, then
+    one softmax-attention block; a model of one kind of layer is a period
+    of one), every layer under the remat policy on its own.
+    -> (x, aux loss per period [P], rows per expert [L, E])."""
+    n = cfg.full_attn_interval
+
+    def linear(y, blk):
+        return _linear_block_forward(y, blk, cfg, segment_ids)
+
+    def full(y, blk):
+        return _block_forward(
+            y, blk, cfg, segment_ids, cos, sin, use_flash, cp_mesh,
+            cp_zigzag=cp_zigzag,
+        )
+
+    linear, full = _remat_layer(linear, remat), _remat_layer(full, remat)
+
+    def body(y, pblk):
+        aux, counts = None, []
+        for j in range(n):
+            layer = full if j == n - 1 else linear
+            y, a, c = layer(y, _period_layer(cfg, pblk, j))
+            aux = a if aux is None else aux + a
+            counts.append(c)
+        return y, (aux, _period_stack(cfg, counts))
+
+    x, (auxes, counts) = jax.lax.scan(body, x, _period_view(cfg, blocks))
+    return x, auxes, _all_layers(cfg, counts)
+
+
 def _head(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     if cfg.is_critic:
         v = jnp.einsum(
@@ -790,10 +995,18 @@ def forward_with_aux(
 class KVCache:
     """Dense per-layer KV cache of the static decode program: k/v
     [L, B, S_max, n_kv, head_dim], full precision (its windows are small;
-    the int8 mode lives on the serving plane's `PagedKVCache`)."""
+    the int8 mode lives on the serving plane's `PagedKVCache`).
+
+    A hybrid layer pattern keeps two kinds of state side by side: k/v for
+    its softmax-attention layers alone (L = n_periods), and for each Gated
+    DeltaNet layer a recurrent `state` [n_linear, B, hv, dk, dv] in fp32
+    plus the causal conv's last inputs `conv` [n_linear, B, K-1, C].  Both
+    are None for every other model."""
 
     k: jax.Array
     v: jax.Array
+    state: Optional[jax.Array] = None
+    conv: Optional[jax.Array] = None
 
     @property
     def s_max(self) -> int:
@@ -801,7 +1014,7 @@ class KVCache:
 
 
 jax.tree_util.register_dataclass(
-    KVCache, data_fields=["k", "v"], meta_fields=[]
+    KVCache, data_fields=["k", "v", "state", "conv"], meta_fields=[]
 )
 
 
@@ -842,9 +1055,20 @@ def _cache_update_read(kc, vc, ksc, vsc, k, v, li, idx, quant: bool):
 def init_kv_cache(
     cfg: ModelConfig, batch: int, s_max: int, dtype=None
 ) -> KVCache:
-    shape = (cfg.n_layers, batch, s_max, cfg.n_kv_heads, cfg.head_dim)
+    shape = (cfg.n_periods, batch, s_max, cfg.n_kv_heads, cfg.head_dim)
     dtype = dtype or cfg.dtype
-    return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
+    cache = KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
+    if cfg.is_hybrid:
+        nl = cfg.n_linear_layers
+        cache.state = jnp.zeros(
+            (nl, batch, cfg.linear_n_v_heads, cfg.linear_k_head_dim,
+             cfg.linear_v_head_dim),
+            jnp.float32,
+        )
+        cache.conv = jnp.zeros(
+            (nl, batch, cfg.linear_conv_kernel - 1, cfg.linear_conv_dim), dtype
+        )
+    return cache
 
 
 @jax.named_scope("layer/attn_qkv")
@@ -857,15 +1081,37 @@ def _block_kv(
     v = h @ blk["wv"]
     if cfg.qkv_bias:
         q, k, v = q + blk["bq"], k + blk["bk"], v + blk["bv"]
-    if cfg.qk_norm:  # olmoe: over the WHOLE projection, not per head
+    per_head = cfg.qk_norm and cfg.qk_norm_per_head
+    if cfg.qk_norm and not per_head:  # olmoe: over the WHOLE projection
         q = rms_norm(q, blk["q_norm"], cfg.rms_norm_eps)
         k = rms_norm(k, blk["k_norm"], cfg.rms_norm_eps)
     q = q.reshape(b, s, cfg.n_q_heads, cfg.head_dim)
     k = k.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
     v = v.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+    if per_head:  # qwen3_next: over each head's head_dim, scale per cfg
+        q = _norm(q, blk["q_norm"], None, cfg)
+        k = _norm(k, blk["k_norm"], None, cfg)
     if cfg.pos_emb == "rope":
-        q, k = apply_rotary(q, k, cos, sin)
+        r = cfg.rotary_dim
+        if r and r < cfg.head_dim:  # partial rotary: the first r dims only
+            qr, kr = apply_rotary(q[..., :r], k[..., :r], cos, sin)
+            q = jnp.concatenate([qr, q[..., r:]], axis=-1)
+            k = jnp.concatenate([kr, k[..., r:]], axis=-1)
+        else:
+            q, k = apply_rotary(q, k, cos, sin)
     return q, k, v
+
+
+def _attn_gate(h: jax.Array, blk: Params, cfg: ModelConfig):
+    """The attention output gate's pre-activation [.., q_dim], or None."""
+    if not cfg.attn_gate:
+        return None
+    with jax.named_scope("layer/attn_qkv"):
+        return h @ blk["wqg"]
+
+
+def _rope_dim(cfg: ModelConfig) -> int:
+    return cfg.rotary_dim or cfg.head_dim
 
 
 @jax.named_scope("gen/prefill")
@@ -884,7 +1130,11 @@ def prefill(
     vocab that is the difference between 40 MB and 10 GB."""
     positions = positions_from_segments(segment_ids)
     x = _embed(params, cfg, tokens, positions)
-    cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
+    cos, sin = rope_cos_sin(positions, _rope_dim(cfg), cfg.rope_theta)
+
+    def mlp(y, blk):
+        h2 = _norm(y, blk["ln2"], blk.get("ln2_b"), cfg)
+        return y + (_mlp_moe(h2, blk, cfg)[0] if cfg.is_moe else _mlp_dense(h2, blk, cfg))
 
     def body(carry, layer_in):
         blk = layer_in
@@ -893,13 +1143,38 @@ def prefill(
         attn = packed_attention(
             q, k, v, segment_ids, causal=True, use_flash=use_flash
         )
-        y = _attn_out(attn.reshape(*carry.shape[:2], cfg.q_dim), blk, cfg)
-        y = carry + y
-        h2 = _norm(y, blk["ln2"], blk.get("ln2_b"), cfg)
-        y = y + (_mlp_moe(h2, blk, cfg)[0] if cfg.is_moe else _mlp_dense(h2, blk, cfg))
-        return y, (k, v)
+        y = _attn_out(
+            attn.reshape(*carry.shape[:2], cfg.q_dim), blk, cfg,
+            _attn_gate(h, blk, cfg),
+        )
+        return mlp(carry + y, blk), (k, v)
 
-    x, (ks, vs) = jax.lax.scan(body, x, params["blocks"])
+    def period_body(carry, pblk):
+        """A period: its linear layers leave their final state and conv
+        tail, its full layer its k/v.  A period of one is `body`."""
+        n, y, states, tails = cfg.full_attn_interval, carry, [], []
+        for j in range(n - 1):
+            blk = _period_layer(cfg, pblk, j)
+            h = _norm(y, blk["ln1"], blk.get("ln1_b"), cfg)
+            mixed, state, tail = linear_attn_forward(
+                h, blk, cfg, segment_ids, with_state=True
+            )
+            y = mlp(y + mixed, blk)
+            states.append(state)
+            tails.append(tail)
+        y, kv = body(y, _period_layer(cfg, pblk, n - 1))
+        left = (jnp.stack(states), jnp.stack(tails)) if states else ()
+        return y, (*kv, *left)
+
+    x, (ks, vs, *left) = jax.lax.scan(
+        period_body, x, _period_view(cfg, params["blocks"])
+    )
+    extra = {}
+    if left:  # [P, n - 1, B, ...] -> [n_linear, B, ...]: the cache's layout
+        extra = dict(
+            state=left[0].reshape(cache.state.shape),
+            conv=left[1].reshape(cache.conv.shape).astype(cache.conv.dtype),
+        )
     new_cache = KVCache(
         k=jax.lax.dynamic_update_slice(
             cache.k, ks.astype(cache.k.dtype), (0, 0, 0, 0, 0)
@@ -907,6 +1182,7 @@ def prefill(
         v=jax.lax.dynamic_update_slice(
             cache.v, vs.astype(cache.v.dtype), (0, 0, 0, 0, 0)
         ),
+        **extra,
     )
     x = _final_norm(params, cfg, x)
     # Gather each row's last valid hidden state before the (huge) head matmul.
@@ -957,12 +1233,24 @@ def decode_step(
     """
     b = tokens.shape[0]
     x = _embed(params, cfg, tokens, positions)[:, None, :]  # [B,1,D]
-    cos, sin = rope_cos_sin(positions[:, None], cfg.head_dim, cfg.rope_theta)
+    cos, sin = rope_cos_sin(positions[:, None], _rope_dim(cfg), cfg.rope_theta)
     slot = jnp.asarray(slot, jnp.int32)
     blocks, stacked = _scan_blocks(cfg, params["blocks"], experts_in_place)
 
-    def body(carry, blk):
-        y, kc, vc, li = carry
+    def mlp(y, blk, layer):
+        """-> (y + mlp, rows per expert); `layer` indexes the stacked
+        expert leaves (all n_layers of them)."""
+        h2 = _norm(y, blk["ln2"], blk.get("ln2_b"), cfg)
+        if cfg.is_moe:
+            mlp_out, _, counts = _mlp_moe(
+                h2, blk, cfg, stacked=stacked, layer=layer
+            )
+        else:
+            mlp_out, counts = _mlp_dense(h2, blk, cfg), None
+        return y + mlp_out, counts
+
+    def attend(y, kc, vc, blk, li):
+        """Softmax attention of one token per row through k/v layer li."""
         h = _norm(y, blk["ln1"], blk.get("ln1_b"), cfg)
         q, k, v = _block_kv(h, blk, cfg, cos, sin)  # q/k/v [B,1,h,d]
         # k/v [B,1,h,d] -> [1,B,1,h,d] written at (layer, :, slot).
@@ -975,23 +1263,46 @@ def decode_step(
         k_layer = jax.lax.dynamic_index_in_dim(kc, li, axis=0, keepdims=False)
         v_layer = jax.lax.dynamic_index_in_dim(vc, li, axis=0, keepdims=False)
         attn = decode_attention(q, k_layer, v_layer, valid_from, slot + 1)
-        ao = _attn_out(attn.reshape(b, 1, cfg.q_dim), blk, cfg)
-        y = y + ao
-        h2 = _norm(y, blk["ln2"], blk.get("ln2_b"), cfg)
-        if cfg.is_moe:
-            mlp_out, _, counts = _mlp_moe(h2, blk, cfg, stacked=stacked, layer=li)
-        else:
-            mlp_out, counts = _mlp_dense(h2, blk, cfg), None
-        return (y + mlp_out, kc, vc, li + 1), counts
+        ao = _attn_out(
+            attn.reshape(b, 1, cfg.q_dim), blk, cfg, _attn_gate(h, blk, cfg)
+        )
+        return y + ao, kc, vc
 
-    (x, kc, vc, _), counts = jax.lax.scan(
-        body, (x, cache.k, cache.v, jnp.int32(0)), blocks
+    def period_body(carry, pblk):
+        """A period: each linear layer steps its recurrent state and conv
+        tail in place (carried like k/v; None where no layer is linear),
+        the full layer attends through the period's k/v."""
+        y, kc, vc, sc, cc, pi = carry
+        n, counts = cfg.full_attn_interval, []
+
+        def layer(j):  # the period's layer j among all the layers
+            return pi if n == 1 else pi * n + j
+
+        for j in range(n - 1):
+            blk = _period_layer(cfg, pblk, j)
+            li = pi * (n - 1) + j
+            h = _norm(y, blk["ln1"], blk.get("ln1_b"), cfg)
+            mixed, sc, cc = linear_attn_step(h, blk, cfg, sc, cc, li)
+            y, c = mlp(y + mixed, blk, layer(j))
+            counts.append(c)
+        blk = _period_layer(cfg, pblk, n - 1)
+        y, kc, vc = attend(y, kc, vc, blk, pi)
+        y, c = mlp(y, blk, layer(n - 1))
+        counts.append(c)
+        return (y, kc, vc, sc, cc, pi + 1), _period_stack(cfg, counts)
+
+    (x, kc, vc, sc, cc, _), counts = jax.lax.scan(
+        period_body,
+        (x, cache.k, cache.v, cache.state, cache.conv, jnp.int32(0)),
+        _period_view(cfg, blocks),
     )
+    counts = _all_layers(cfg, counts)
+    new_cache = KVCache(k=kc, v=vc, state=sc, conv=cc)
     x = _final_norm(params, cfg, x)
     logits = _head(params, cfg, x)[:, 0]  # [B, V]
     if with_moe_counts:
-        return logits, KVCache(k=kc, v=vc), counts
-    return logits, KVCache(k=kc, v=vc)
+        return logits, new_cache, counts
+    return logits, new_cache
 
 
 # --------------------------------------------------------------------------
@@ -1084,6 +1395,14 @@ def _page_of(page_table: jax.Array, pos: jax.Array, page_size: int):
     return pages.astype(jnp.int32), (pos % page_size).astype(jnp.int32)
 
 
+_NO_SERVING_STATE = (
+    "recurrent state has no slot on the serving plane yet: a hybrid layer "
+    "pattern (linear-attention layers) generates on the static decode "
+    "program only (at most max_decode_batch requests, no stop sequences, "
+    "no speculative decoding, max_new_tokens within static_path_max_new)"
+)
+
+
 @jax.named_scope("gen/decode_step")
 def decode_step_ragged_paged(
     params: Params,
@@ -1113,6 +1432,8 @@ def decode_step_ragged_paged(
     call, so the enclosing program compiles exactly once.  A grouped MoE
     model's expert leaves
     reach the ragged kernels as in `decode_step` (`experts_in_place`)."""
+    if cfg.is_hybrid:
+        raise HybridLayoutError(_NO_SERVING_STATE)
     t = tokens.shape[0]
     b = page_table.shape[0]
     live = row_of < b
@@ -1120,7 +1441,7 @@ def decode_step_ragged_paged(
     pt_tok = jnp.take(page_table, rid, axis=0)  # [T, max_pages]
     positions = jnp.where(live, positions, 0).astype(jnp.int32)
     x = _embed(params, cfg, tokens, positions)[:, None, :]  # [T, 1, D]
-    cos, sin = rope_cos_sin(positions[:, None], cfg.head_dim, cfg.rope_theta)
+    cos, sin = rope_cos_sin(positions[:, None], _rope_dim(cfg), cfg.rope_theta)
     wp_page, wp_off = _page_of(pt_tok, positions, cache.page_size)
     # Dead lanes must not scatter (2**30 = the `_page_of` OOB drop).
     wp_page = jnp.where(live, wp_page, jnp.int32(2**30))

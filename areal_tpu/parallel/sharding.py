@@ -62,6 +62,26 @@ _BLOCK_RULES: Dict[str, P] = {
     "moe_wg": P(PIPE_AXIS, FSDP_AXIS, None, MODEL_AXIS),
     "moe_wu": P(PIPE_AXIS, FSDP_AXIS, None, MODEL_AXIS),
     "moe_wd": P(PIPE_AXIS, FSDP_AXIS, MODEL_AXIS, None),
+    # qwen3_next.  Per-head QK-norm weights ([head_dim]) take the q_norm /
+    # k_norm rule above; the attention gate is a second query projection;
+    # the shared expert is a dense MLP of its own.
+    "wqg": P(PIPE_AXIS, FSDP_AXIS, MODEL_AXIS),
+    "ws_g": P(PIPE_AXIS, FSDP_AXIS, MODEL_AXIS),
+    "ws_u": P(PIPE_AXIS, FSDP_AXIS, MODEL_AXIS),
+    "ws_d": P(PIPE_AXIS, MODEL_AXIS, FSDP_AXIS),
+    "ws_gate": P(PIPE_AXIS, FSDP_AXIS, None),
+    # Gated DeltaNet leaves: ZeRO-sharded over fsdp, NOT split over `model`
+    # — the conv, the per-head gates and the state's heads would all have
+    # to split with q | k | v's packed output axis, and `attn_dispatch`
+    # refuses a hybrid model on a mesh with model > 1 by name instead.
+    "la_wqkv": P(PIPE_AXIS, FSDP_AXIS, None),
+    "la_wz": P(PIPE_AXIS, FSDP_AXIS, None),
+    "la_wba": P(PIPE_AXIS, FSDP_AXIS, None),
+    "la_conv": P(PIPE_AXIS, None, None),
+    "la_A_log": P(PIPE_AXIS, None),
+    "la_dt_bias": P(PIPE_AXIS, None),
+    "la_norm": P(PIPE_AXIS, None),
+    "la_wo": P(PIPE_AXIS, None, FSDP_AXIS),
 }
 
 _TOP_RULES: Dict[str, P] = {
@@ -131,6 +151,18 @@ def attn_dispatch(mesh: Mesh, cfg=None):
 
     from areal_tpu.base.topology import BATCH_AXES
 
+    if cfg is not None and cfg.is_hybrid and any(
+        mesh.shape[a] > 1 for a in (MODEL_AXIS, SEQ_AXIS, PIPE_AXIS)
+    ):
+        from areal_tpu.models.transformer import HybridLayoutError
+
+        raise HybridLayoutError(
+            f"mesh {dict(mesh.shape)}: a hybrid layer pattern "
+            f"(full_attn_interval {cfg.full_attn_interval}) runs under data "
+            "and fsdp sharding only — no tensor parallelism over DeltaNet "
+            "heads, no ring over a split sequence, and a pipeline stage "
+            "would have to be whole periods (PERF.md section 7)"
+        )
     if mesh.devices.size == 1:
         use_flash = None
     else:

@@ -185,3 +185,147 @@ def test_cpu_rehearsal_of_the_olmoe_cell_is_correct():
     check = [l for l in lines if "weight check: " in l][-1]
     assert "'ok': True" in check and "'leaves': 15" in check, check
     assert any("olmoe reference" in l and "router_flips" in l for l in lines)
+
+
+def test_cpu_rehearsal_of_the_qwen3_next_cell_is_correct():
+    """The hybrid cell end to end at toy size: the static program through
+    both kinds of cache, the chunked scan in the train step, the hand-back
+    of all 28 leaves, the token-by-token reference for generator and
+    trainer."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=files.ROOT)
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run(
+        [sys.executable, "-m", "benchmark.run", "--workload",
+         "q3next-rollout64-512", "--seed", "3000000007", "--seconds", "1",
+         "--trace", "0", "--cpu-rehearsal"],
+        cwd=files.ROOT, env=env, capture_output=True, text=True, timeout=600,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert proc.stdout.strip() == ""  # platform=cpu: no result line
+    lines = proc.stderr.splitlines()
+    out = json.loads(
+        [l for l in lines if "would print: " in l][-1].split("would print: ")[1])
+    assert out["correct"] is True and out["failed"] == 0
+    assert out["attempted"] in (2 * 64, 3 * 64, 4 * 64)  # whole steps of 64
+    assert {"gen_tokens_per_s", "train_tokens_per_s", "samples_per_s",
+            "setup_s"} <= set(out["metrics"])
+    check = [l for l in lines if "weight check: " in l][-1]
+    assert "'ok': True" in check and "'leaves': 28" in check, check
+    assert any("qwen3_next reference" in l and "[0, 4) of 8" in l
+               for l in lines)
+
+
+def test_the_hybrid_readers_say_nothing_without_their_scopes_or_counters():
+    """On a program that lacks what PR 32 added (the parent, or any other
+    configuration) every new reader returns None and does not raise."""
+    from benchmark.metrics import (
+        decode_hbm_share_hybrid, gdn_decode_ms, gdn_decode_roofline,
+        gdn_train_mfu, gdn_train_share, mfu_gen_hybrid, mfu_train_hybrid,
+        moe_decode_mlp_roofline_hybrid, moe_local_rows_share,
+        moe_train_mlp_mfu_hybrid,
+    )
+    from benchmark.run import Run
+    from areal_tpu.models.config import tiny_config
+
+    step = {"pool": {}, "gen": {"lanes_dispatched": 0}, "seq_lens": [8, 8],
+            "prompt_lens": [4, 4], "spans": {"actor:train_step": 1.0}}
+    bare = Run(
+        cell_name="x", cell={"route": "static"}, config={}, traffic={},
+        model_cfg=tiny_config(), chips=1, device_kind="TPU v5 lite",
+        peaks={"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}, seed=0,
+        traced=True, steps=[step],
+        trace={"scope_seconds": {"train/grad/layer/mlp": {
+            "fwd": 1.0, "recompute": 0.0, "bwd": 1.0}},
+            "busy_s": 2.0, "traced_steps": 1},
+    )
+    for reader in (gdn_decode_ms, gdn_decode_roofline, gdn_train_mfu,
+                   gdn_train_share, mfu_train_hybrid, moe_local_rows_share,
+                   moe_decode_mlp_roofline_hybrid, moe_train_mlp_mfu_hybrid,
+                   mfu_gen_hybrid, decode_hbm_share_hybrid):
+        assert reader.read(bare) is None, reader.__name__
+    bare.trace["scope_seconds"].update({
+        "gen/decode_step/layer/linear_attn/delta_step": {
+            "fwd": 0.008, "recompute": 0.0, "bwd": 0.0},
+        "train/grad/layer/linear_attn/delta_rule": {
+            "fwd": 0.5, "recompute": 0.5, "bwd": 1.0},
+    })
+    assert gdn_decode_ms.read(bare) == 2.0  # 8 ms over 4 decode steps
+    assert gdn_train_share.read(bare) == 50.0
+
+
+def test_the_hybrid_rooflines_of_the_expert_half_count_the_share():
+    """`peaks_hybrid` at the published widths (64 of 512 experts held, a
+    shared expert, three recurrent states to one K/V layer): what a decode
+    step's MLPs and the whole step must move, what a train step's MLPs
+    and a generate request must compute — and the four readers over a
+    hand-made run divide them by what the run says it took."""
+    import dataclasses
+
+    from benchmark import peaks_hybrid as ph
+    from benchmark.metrics import (
+        decode_hbm_share_hybrid, mfu_gen_hybrid,
+        moe_decode_mlp_roofline_hybrid, moe_train_mlp_mfu_hybrid,
+    )
+    from benchmark.run import Run, model_config
+
+    cfg = model_config(
+        files.load_json("configs", "qwen3-next-80b-a3b-l4-e64.json"))
+    expert = 3 * 2048 * 512
+    assert ph.experts_expected(cfg, 64) == pytest.approx(45.889, abs=1e-3)
+    # A layer's MLP at 64 rows: 45.6 experts touched (287 MB of the 298),
+    # the 512-wide router, the shared expert; 80 local rows are nothing.
+    layer = ph.experts_decode_bytes(cfg, 64, 45.6, 80) / cfg.n_layers
+    weights = 2 * (45.6 * expert + 2048 * 512 + expert + 2048)
+    assert weights < layer < 1.02 * weights
+    # The step: ISSUE 32's floor, 2.4 GB.
+    ctx = [386.0] * 64
+    step = ph.decode_bytes(cfg, ctx, 45.6, 80)
+    assert step == pytest.approx(
+        ph.gdn_decode_bytes(cfg, 64) + 4 * layer
+        + 2 * (ph.full_attn_params(cfg) + 2048 * 18992)
+        + 2 * 2 * 256 * 2 * 386.0 * 64)
+    assert step == pytest.approx(2.40e9, rel=0.01)
+    # Counters absent: the expectations (45.9 experts, 1.25 rows a token).
+    assert ph.decode_bytes(cfg, ctx) == pytest.approx(step, rel=0.01)
+    # Train: 1.25 of a token's 10 choices are multiplied here.
+    per_token = 2 * (1.25 * expert + 2048 * 512 + expert + 2048)
+    assert ph.experts_train_flops(cfg, 1000) == pytest.approx(
+        3 * 4 * per_token * 1000, rel=0.01)
+    gen = ph.flops_generate(cfg, [130], [512])
+    # Token by token or at once, the causal half of the scores is the same.
+    assert gen == pytest.approx(ph.flops_forward(cfg, [642]))
+
+    pool = {"moe_experts_touched": 45.6, "moe_rows_local": 80.0 * 512 * 4,
+            "moe_decode_steps": 512}
+    step_rec = {"pool": pool, "gen": {"lanes_dispatched": 0, "decode_steps": 512},
+                "seq_lens": [642] * 64, "prompt_lens": [130] * 64,
+                "spans": {"actor:train_step": 3.0, "actor_gen:generate": 3.0}}
+    run = Run(
+        cell_name="x", cell={"route": "static"}, config={}, traffic={},
+        model_cfg=cfg, chips=1, device_kind="TPU v5 lite",
+        peaks={"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}, seed=0,
+        traced=True, steps=[step_rec],
+        trace={"scope_seconds": {
+            "gen/decode_step/layer/mlp/router": {
+                "fwd": 512 * 2.0e-3, "recompute": 0.0, "bwd": 0.0},
+            "train/grad/layer/mlp/shared": {
+                "fwd": 0.1, "recompute": 0.1, "bwd": 0.2}},
+            "busy_s": 6.0, "traced_steps": 1,
+            "loop_seconds": {"actor_gen:generate": [512 * 5.0e-3]}},
+    )
+    assert moe_decode_mlp_roofline_hybrid.read(run) == pytest.approx(
+        100 * 4 * layer / 819e9 / 2.0e-3)
+    assert decode_hbm_share_hybrid.read(run) == pytest.approx(
+        100 * step / 819e9 / 5.0e-3)
+    assert moe_train_mlp_mfu_hybrid.read(run) == pytest.approx(
+        100 * ph.experts_train_flops(cfg, 64 * 642) / 0.4 / 197e12)
+    assert mfu_gen_hybrid.read(run) == pytest.approx(
+        100 * 64 * gen / 3.0 / 197e12)
+    # Every one a share of a peak: under 100%.
+    for reader in (moe_decode_mlp_roofline_hybrid, decode_hbm_share_hybrid,
+                   moe_train_mlp_mfu_hybrid, mfu_gen_hybrid):
+        assert 0 < reader.read(run) < 100
+    # Another configuration's run reads nothing.
+    from areal_tpu.models.config import tiny_config
+    other = dataclasses.replace(run, model_cfg=tiny_config())
+    assert moe_decode_mlp_roofline_hybrid.read(other) is None

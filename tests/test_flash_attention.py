@@ -420,10 +420,14 @@ class TestTPULowering:
         assert "tpu_custom_call" in text  # Mosaic kernel, not interpreted
         return text
 
-    def test_flash_forward_and_backward(self):
+    @pytest.mark.parametrize("n_q,n_kv,d", [
+        (12, 2, 128),
+        (16, 2, 256),  # qwen3_next's gated attention: never 256 before
+    ])
+    def test_flash_forward_and_backward(self, n_q, n_kv, d):
         b, s = 2, 256
-        q = jax.ShapeDtypeStruct((b, s, self.N_Q, self.D), jnp.bfloat16)
-        kv = jax.ShapeDtypeStruct((b, s, self.N_KV, self.D), jnp.bfloat16)
+        q = jax.ShapeDtypeStruct((b, s, n_q, d), jnp.bfloat16)
+        kv = jax.ShapeDtypeStruct((b, s, n_kv, d), jnp.bfloat16)
         seg = jax.ShapeDtypeStruct((b, s), jnp.int32)
 
         def loss(q, k, v, seg):

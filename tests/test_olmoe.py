@@ -384,7 +384,7 @@ def test_counters_on_a_hand_made_routing():
     valid = jnp.asarray([[True, True, True, False]])
     _, _, real = tfm._mlp_moe(jnp.asarray(h), blk, cfg, valid=valid)
     assert np.asarray(real).tolist() == [3, 2, 0, 0, 0, 1, 0, 0]
-    step = np.asarray(_moe_step_counters(jnp.stack([counts, real])))
+    step = np.asarray(_moe_step_counters(jnp.stack([counts, real]), cfg, 4))
     assert step.tolist() == [(4 + 3) / 2, (4 + 3) / 2, 1.0]
     stats = _moe_stats(aux, jnp.stack([counts, real]))
     assert float(stats["moe/aux_loss"]) == float(aux)
