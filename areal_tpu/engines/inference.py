@@ -102,7 +102,7 @@ class InferenceEngine(HostOffloadMixin, Engine):
     def _get_fwd_fn(self, post_fn):
         if post_fn in self._fwd_fns:
             return self._fwd_fns[post_fn]
-        cfg = self.cfg
+        cfg, mesh = self.cfg, self.mesh
         use_flash = self._use_flash
         cp_mesh = self._cp_mesh
         pp_mesh, pp_mbs = self._pp_mesh, self._pp_microbatches
@@ -122,7 +122,12 @@ class InferenceEngine(HostOffloadMixin, Engine):
             )
             return post_fn(
                 tfm.per_token_output(
-                    params, cfg, x, batch["tokens"], batch["segment_ids"]
+                    params,
+                    cfg,
+                    x,
+                    batch["tokens"],
+                    batch["segment_ids"],
+                    mesh=mesh,
                 ),
                 batch,
             )

@@ -111,11 +111,11 @@ def _moe_stats(aux, counts) -> Dict[str, jax.Array]:
     return out
 
 
-def _model_out(params, cfg: ModelConfig, x, batch):
+def _model_out(params, cfg: ModelConfig, x, batch, mesh: Mesh):
     """Per-token model output [B, S] from final hidden states (see
     transformer.per_token_output)."""
     return tfm.per_token_output(
-        params, cfg, x, batch["tokens"], batch["segment_ids"]
+        params, cfg, x, batch["tokens"], batch["segment_ids"], mesh=mesh
     )
 
 
@@ -253,6 +253,14 @@ class TrainEngine(HostOffloadMixin, Engine):
             self._pp_microbatches = self._pp_mesh.shape[
                 sharding.PIPE_AXIS
             ]
+        # Ways the log-prob head splits the vocabulary under this mesh
+        # (ops/functional.fused_next_token_logprobs decides the same way
+        # when traced); a critic has no such head.
+        self.head_vocab_shards = (
+            1
+            if cfg.is_critic
+            else sharding.head_vocab_shards(mesh, cfg.vocab_size)
+        )
         # Lazy byte-size cache for perf_counters(): param/opt global
         # bytes never change shape after init, so sum the leaves once.
         self._tree_bytes: Optional[Tuple[int, int]] = None
@@ -296,6 +304,12 @@ class TrainEngine(HostOffloadMixin, Engine):
             "compiles": compiles,
         }
 
+    def _head_counter(self) -> Dict[str, float]:
+        """Whether the vocabulary-parallel head engaged, to the tracer's
+        `head` counter track and into the step's train stats."""
+        tracer.counter("head", vocab_shards=self.head_vocab_shards)
+        return {"head/vocab_shards": float(self.head_vocab_shards)}
+
     # ---------------- core jitted fns ----------------
 
     def _pack_row_chunks(self, arrays):
@@ -318,7 +332,7 @@ class TrainEngine(HostOffloadMixin, Engine):
     def _get_grad_fn(self, loss_fn: Callable):
         if loss_fn in self._grad_fns:
             return self._grad_fns[loss_fn]
-        cfg, compute_dtype = self.cfg, self.compute_dtype
+        cfg, compute_dtype, mesh = self.cfg, self.compute_dtype, self.mesh
         use_flash = self._use_flash
         cp_mesh = self._cp_mesh
         pp_mesh, pp_mbs = self._pp_mesh, self._pp_microbatches
@@ -343,7 +357,7 @@ class TrainEngine(HostOffloadMixin, Engine):
                 # Loss fns receive per-token model outputs, never [B,S,V]
                 # logits: critic -> values; LM -> fused chunked next-token
                 # logprobs (the 152k-vocab memory/bandwidth fix).
-                out = _model_out(pc, cfg, x, batch)
+                out = _model_out(pc, cfg, x, batch, mesh)
                 loss, stats = loss_fn(out, batch)
                 total = loss + cfg.moe_aux_loss_coef * aux
                 if cfg.is_moe:
@@ -654,6 +668,7 @@ class TrainEngine(HostOffloadMixin, Engine):
             "anomaly_verdict": verdict,
             "quarantined": 1.0 if verdict else 0.0,
             "n_micro_batches": float(len(chunks)),
+            **self._head_counter(),
         }
         for i, k in enumerate(keys):
             v = float(host[4 + i])
@@ -846,6 +861,7 @@ class TrainEngine(HostOffloadMixin, Engine):
             "quarantined": 1.0 if (verdict or quarantine) else 0.0,
             "n_micro_batches": float(state["n_micro_batches"]),
             "n_stream_chunks": float(state["n_chunks"]),
+            **self._head_counter(),
         }
         for k, v in state["stat_sums"].items():
             if k.endswith("_sum"):
@@ -946,7 +962,7 @@ class TrainEngine(HostOffloadMixin, Engine):
     def _get_fwd_fn(self, post_fn):
         if post_fn in self._fwd_fns:
             return self._fwd_fns[post_fn]
-        cfg, compute_dtype = self.cfg, self.compute_dtype
+        cfg, compute_dtype, mesh = self.cfg, self.compute_dtype, self.mesh
         use_flash = self._use_flash
         cp_mesh = self._cp_mesh
         pp_mesh, pp_mbs = self._pp_mesh, self._pp_microbatches
@@ -965,7 +981,7 @@ class TrainEngine(HostOffloadMixin, Engine):
                 pp_mesh=pp_mesh,
                 pp_microbatches=pp_mbs,
             )
-            return post_fn(_model_out(pc, cfg, x, batch), batch)
+            return post_fn(_model_out(pc, cfg, x, batch, mesh), batch)
 
         self._fwd_fns[post_fn] = fwd
         return fwd

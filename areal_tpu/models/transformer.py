@@ -952,16 +952,18 @@ def per_token_output(
     tokens: jax.Array,
     segment_ids: jax.Array,
     chunk_size: int = 512,
+    mesh=None,
 ) -> jax.Array:
     """The engine-facing per-token model output [B, S] fp32: critic values
     (via the value head) or fused chunked next-token logprobs for LMs —
-    never [B, S, V] logits."""
+    never [B, S, V] logits.  `mesh`: the mesh the caller's program is
+    partitioned over; the log-prob head splits the vocabulary over it."""
     if cfg.is_critic:
         return _head(params, cfg, x)
     from areal_tpu.ops.functional import fused_next_token_logprobs
 
     return fused_next_token_logprobs(
-        x, head_weights(params, cfg), tokens, segment_ids, chunk_size
+        x, head_weights(params, cfg), tokens, segment_ids, chunk_size, mesh
     )
 
 

@@ -5,10 +5,13 @@ Capability parity: realhf/impl/model/utils/functional.py
 [B, S] packed-row layout (segment_ids delimit sequences, 0 = pad).
 """
 
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh
+
+from areal_tpu.parallel import sharding
 
 
 def shifted_label_mask(segment_ids: jax.Array) -> jax.Array:
@@ -40,6 +43,7 @@ def fused_next_token_logprobs(
     tokens: jax.Array,  # [B, S] int32
     segment_ids: jax.Array,  # [B, S] int32, 0 = pad
     chunk_size: int = 512,
+    mesh: Optional[Mesh] = None,
 ) -> jax.Array:
     """log p(tokens[t+1] | prefix) at each position t, WITHOUT materializing
     [B, S, V] logits: the head matmul + logsumexp run per position-chunk
@@ -49,8 +53,29 @@ def fused_next_token_logprobs(
     TPU-native counterpart of the reference's fused vocab-parallel
     cross-entropy (realhf model_parallel/modules.py:1060-1180).
 
+    Traced under a `mesh` whose parameter-sharding axes (model x fsdp)
+    divide V, the head is vocabulary-parallel: the [D, V] weight is re-laid
+    once to V split over those axes with D whole, and a chunk's logits stay
+    [chunk, V / (m f)] a chip, so the partitioner reduces only [chunk]
+    statistics (max, sum-exp, target logit) forward and one [chunk, D] block
+    of dx backward.  Left to the stored layout (D over fsdp) it all-reduces
+    the fp32 [chunk, V] logits themselves, every chunk, forward and
+    recomputed.  With no mesh, a product of 1 or an indivisible V nothing
+    is constrained.
+
     [B, S] fp32; 0 at the last position of every segment and padding.
     """
+    vocab_parallel = None
+    if sharding.head_vocab_shards(mesh, head.shape[1]) > 1:
+        # The stored layout first: nothing moves forward, and its transpose
+        # brings dhead back inside the gradient program.  Without it the
+        # gradient leaves V-sharded and the optimizer step re-lays the
+        # weight and both moments there and back (6 all-to-alls, not 1).
+        head = jax.lax.with_sharding_constraint(
+            head, sharding.named(mesh, sharding.HEAD_STORED)
+        )
+        vocab_parallel = sharding.named(mesh, sharding.HEAD_VOCAB_PARALLEL)
+        head = jax.lax.with_sharding_constraint(head, vocab_parallel)
     b, s, d = x.shape
     labels = jnp.pad(tokens[:, 1:], ((0, 0), (0, 1)), constant_values=0)
     t = b * s
@@ -70,6 +95,8 @@ def fused_next_token_logprobs(
         logits = jnp.einsum(
             "cd,dv->cv", xi, head, preferred_element_type=jnp.float32
         )
+        if vocab_parallel is not None:
+            logits = jax.lax.with_sharding_constraint(logits, vocab_parallel)
         lse = jax.scipy.special.logsumexp(logits, axis=-1)
         tgt = jnp.take_along_axis(logits, li[:, None], axis=-1)[:, 0]
         return carry, tgt - lse

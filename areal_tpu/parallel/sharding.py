@@ -10,17 +10,23 @@ automatically).  One rule table replaces ~2.5k LoC of TP modules.
 Conventions (mesh axes from areal_tpu/base/topology.py):
 - `model`  — tensor parallel: attention heads + MLP hidden + vocab.
 - `fsdp`   — ZeRO-style: remaining param dim sharded; batch also sharded.
+  The STORED head keeps its hidden dim here; the log-prob head re-lays its
+  compute copy so that the vocabulary is split over `model` AND `fsdp`
+  (`HEAD_VOCAB_PARALLEL`): each chip holds a slice of the vocabulary and
+  no [chunk, V] block of logits crosses the chips.
 - `data`   — pure DP: params replicated, batch sharded.
 - `seq`    — context parallel: sequence dim of activations (ring attention).
 - `pipe`   — pipeline stages (layer-stacked leading axis).
 """
 
+import functools
 from typing import Any, Dict, Optional
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from areal_tpu.base import logging
 from areal_tpu.base.topology import (
     DATA_AXIS,
     FSDP_AXIS,
@@ -28,6 +34,8 @@ from areal_tpu.base.topology import (
     PIPE_AXIS,
     SEQ_AXIS,
 )
+
+logger = logging.getLogger("sharding")
 
 BATCH = (DATA_AXIS, FSDP_AXIS)
 
@@ -94,6 +102,14 @@ _TOP_RULES: Dict[str, P] = {
 }
 
 
+# The [D, V] LM head (`lm_head`, and equally the tied `embed` transposed):
+# its stored layout, and the log-prob head's vocabulary-parallel one — V
+# over every axis that shards parameters, rows whole; a chunk's [chunk, V]
+# logits take the same.
+HEAD_STORED = _TOP_RULES["lm_head"]
+HEAD_VOCAB_PARALLEL = P(None, (MODEL_AXIS, FSDP_AXIS))
+
+
 def param_pspecs(params: Dict[str, Any]) -> Dict[str, Any]:
     """PartitionSpec pytree matching the transformer params structure."""
     out: Dict[str, Any] = {}
@@ -123,6 +139,30 @@ def act_pspec() -> P:
 
 def logits_pspec() -> P:
     return P(BATCH, SEQ_AXIS, MODEL_AXIS)
+
+
+@functools.lru_cache(maxsize=None)
+def _vocab_shards(model: int, fsdp: int, vocab: int) -> int:
+    n = model * fsdp
+    if n > 1 and vocab % n:
+        # Cached per (mesh sizes, V): said once, not at every trace.
+        logger.warning(
+            f"vocabulary {vocab} does not divide by model x fsdp = {n}: "
+            "the log-prob head keeps its stored layout (not "
+            "vocabulary-parallel)"
+        )
+        return 1
+    return n
+
+
+def head_vocab_shards(mesh: Optional[Mesh], vocab: int) -> int:
+    """Ways the log-prob head splits a vocabulary of `vocab` under `mesh`:
+    model x fsdp, or 1 (no mesh, a product of 1, or V not divisible) — the
+    caller then adds no constraint and traces the program it traced
+    without a mesh."""
+    if mesh is None:
+        return 1
+    return _vocab_shards(mesh.shape[MODEL_AXIS], mesh.shape[FSDP_AXIS], vocab)
 
 
 def kv_cache_pspec() -> P:
