@@ -40,6 +40,26 @@ def bucket_len(n: int, quantum: int = _BUCKET_QUANTUM, large_step: int = 0) -> i
     return ((n + step - 1) // step) * step
 
 
+def flash_tile_counts(
+    segment_ids: np.ndarray, block: int = _BUCKET_QUANTUM
+) -> Tuple[int, int]:
+    """(live, grid) tiles of the rows' `block` x `block` attention squares:
+    how many hold an unmasked (causal, same-sequence) element, against all
+    of them.  The flash kernels visit the live ones
+    (`ops/pallas/flash_attention.live_schedule` derives the same set on
+    the device); times heads and layers it is their work for a call."""
+    seg = np.asarray(segment_ids)
+    block = min(block, seg.shape[1])
+    n = seg.shape[1] // block
+    blocks = seg[:, : n * block].reshape(len(seg), n, block)
+    hi = blocks.max(axis=-1)  # [rows, n]; a block of padding: lo > hi
+    lo = np.where(blocks > 0, blocks, np.iinfo(seg.dtype).max).min(axis=-1)
+    meet = (lo[:, None, :] <= hi[:, :, None]) & (
+        hi[:, None, :] >= lo[:, :, None]
+    )
+    return int(np.tril(meet).sum()), len(seg) * n * n
+
+
 def decode_bucket_len(n: int) -> int:
     """Finer buckets (256 above 1024) for DECODE cache windows: every
     decode step streams the whole window, so coarse buckets directly tax

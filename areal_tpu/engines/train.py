@@ -119,20 +119,31 @@ def _model_out(params, cfg: ModelConfig, x, batch, mesh: Mesh):
     )
 
 
+_GRID_COUNTS = (
+    "real_tokens", "grid_tokens", "n_rows", "empty_rows",
+    "flash_live_tiles", "flash_grid_tiles",
+)
+
+
 def _grid_counts(chunks: Sequence[Dict[str, np.ndarray]]) -> Dict[str, int]:
     """What a call's packed grids hold: real tokens against grid cells,
-    and rows against rows with no real token (under batch sharding an
-    empty row is a chip that trains zeros).  The rows also go to the
-    tracer's `pack` counter track."""
+    rows against rows with no real token (under batch sharding an empty
+    row is a chip that trains zeros), and the attention tiles the flash
+    kernels visit against the rows' full squares.  Rows and tiles also go
+    to the tracer's `pack` counter track."""
     real = [c["segment_ids"] > 0 for c in chunks]
-    n_rows = sum(r.shape[0] for r in real)
-    empty_rows = sum(int((~r.any(axis=1)).sum()) for r in real)
-    tracer.counter("pack", n_rows=n_rows, empty_rows=empty_rows)
+    tiles = [packing.flash_tile_counts(c["segment_ids"]) for c in chunks]
+    counted = {
+        "n_rows": sum(r.shape[0] for r in real),
+        "empty_rows": sum(int((~r.any(axis=1)).sum()) for r in real),
+        "flash_live_tiles": sum(live for live, _ in tiles),
+        "flash_grid_tiles": sum(grid for _, grid in tiles),
+    }
+    tracer.counter("pack", **counted)
     return {
         "real_tokens": sum(int(r.sum()) for r in real),
         "grid_tokens": sum(r.size for r in real),
-        "n_rows": n_rows,
-        "empty_rows": empty_rows,
+        **counted,
     }
 
 
@@ -705,10 +716,7 @@ class TrainEngine(HostOffloadMixin, Engine):
             "weight": 0.0,
             "n_micro_batches": 0,
             "n_chunks": 0,
-            "real_tokens": 0,
-            "grid_tokens": 0,
-            "n_rows": 0,
-            "empty_rows": 0,
+            **dict.fromkeys(_GRID_COUNTS, 0),
             "host_s": 0.0,
         }
 
@@ -838,10 +846,7 @@ class TrainEngine(HostOffloadMixin, Engine):
             state["acc"] = None  # consumed: free the grad tree
 
         self.last_pack_stats = {
-            "real_tokens": state["real_tokens"],
-            "grid_tokens": state["grid_tokens"],
-            "n_rows": state["n_rows"],
-            "empty_rows": state["empty_rows"],
+            **{k: state[k] for k in _GRID_COUNTS},
             "pack_efficiency": state["real_tokens"]
             / max(state["grid_tokens"], 1),
             "n_micro_batches": state["n_micro_batches"],

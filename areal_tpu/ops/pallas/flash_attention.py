@@ -5,25 +5,47 @@ dependency (realhf/impl/model/modules/attn.py:24) with a TPU kernel built
 for the [B, S] packed-row layout (segment_ids delimit sequences; attention
 is causal-within-segment).
 
-Design (standard flash attention v2 tiling, adapted to Mosaic/TPU):
-- forward: grid (B*H, nq, nk); online-softmax accumulators (m, l, acc) live
-  in VMEM scratch and persist across the sequential nk dimension; output and
-  logsumexp are written on the last nk step.
-- backward: two kernels — dq with grid (B*H, nq, nk) and dkv with grid
-  (B*H, nk, nq) — both recompute the probability tiles from the saved
-  logsumexp instead of materializing [S, S] (O(S) memory).
-- block-level early-out via @pl.when: tiles entirely above the causal
-  diagonal AND tiles whose q/k segment-id ranges cannot overlap are
-  skipped — packed rows concatenate unrelated sequences with
-  non-decreasing ids, so the work is near block-diagonal in the number
-  of packed sequences rather than O(row_len^2).
+Design (flash attention v2 tiling at 128 x 128, adapted to Mosaic/TPU):
+- A packed row concatenates unrelated sequences with non-decreasing ids and
+  attention is causal within a sequence, so of the row's (q block, k block)
+  square only a band along the diagonal can hold an unmasked element: for a
+  q block the live k blocks are ONE interval (from the block where its
+  first sequence starts to the diagonal), and for a k block the live q
+  blocks are one interval.  `live_schedule` derives both interval tables
+  from `segment_ids` on the device — per block the smallest real id and the
+  largest id, a tile being live when the two ranges meet at or below the
+  diagonal — as traced int32 data (O(S / 128) integers a row, a function of
+  the ids alone, so the same for every layer of a program).  A schedule is
+  never a static argument: a new batch compiles nothing.
+- The kernels get the tables by scalar prefetch and spend grid steps and
+  K/V (Q/dO) fetches on live tiles only.  forward and dq: grid
+  (B*H, nq, chunks), one step per q block, with the row's K/V resident in
+  VMEM and an in-kernel loop `k_lo..k_hi` over its 128-row tiles; dkv:
+  grid (B*H, nk, chunks), one step per k block, with the row's Q, dO,
+  logsumexp and delta resident and a loop `q_lo..q_hi`.  A dead tile costs
+  no step, no fetch and no arithmetic; a tile inside an interval that is
+  masked all the same (ids that are not monotonic) is an exact no-op, so
+  the tables decide speed, never results.
+- `chunks` is 1 for every row whose resident operands fit `RESIDENT_BYTES`
+  of VMEM: in bf16 10,240 tokens for dkv at head_dim 128 and 8,192 at 256
+  (its three column operands pad to 512 bytes a token), about 38,000 and
+  19,000 for forward and dq, which hold K/V alone.  A longer row is cut into equal
+  chunks of whole blocks (the largest divisor of its block count that
+  fits): the accumulators persist across
+  the chunk steps, each step loops over the part of the interval inside its
+  chunk, and the chunk index is clamped to the interval's chunks, so a step
+  with nothing to do re-uses the resident block and fetches nothing.
+- Online-softmax accumulators (m, l, acc) live in VMEM scratch; output and
+  logsumexp are written on the last chunk step.  The backward kernels
+  recompute the probability tiles from the saved logsumexp instead of
+  materializing [S, S] (O(S) memory).  GQA stays in the index maps.
 
 Interpret mode (CPU) is used automatically off-TPU, which is how the unit
 tests exercise the same kernel code path hermetically.
 """
 
 import functools
-from typing import Optional, Tuple
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -34,16 +56,18 @@ NEG_INF = -1e30
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 
+# VMEM one kernel may spend on the operands its inner loop walks (one
+# buffer of each; Pallas double-buffers them).  A row that needs more is
+# cut into chunks.
+RESIDENT_BYTES = 20 << 20
+# A [rows, 1] fp32 or [rows, 8] int32 operand pads to 128 lanes in VMEM.
+_COLUMN_BYTES = 512
+
 
 def _interpret() -> bool:
     from areal_tpu.base.distributed import is_tpu_backend
 
     return not is_tpu_backend()
-
-
-# ---------------------------------------------------------------------------
-# Forward
-# ---------------------------------------------------------------------------
 
 
 def named_call(name: str, kernel, **kw):
@@ -59,61 +83,187 @@ def named_call(name: str, kernel, **kw):
     return named
 
 
+# ---------------------------------------------------------------------------
+# The live tiles of a packed row
+# ---------------------------------------------------------------------------
+
+
+class Schedule(NamedTuple):
+    """Inclusive block intervals, flat over (batch row, block): q block i
+    of row b visits k blocks k_lo[b * nq + i] .. k_hi[...], k block j
+    visits q blocks q_lo[b * nk + j] .. q_hi[...].  An empty interval is
+    (0, -1).  Flat because a 2-D int32 table in SMEM pads its minor dim to
+    128 words a row."""
+
+    k_lo: jax.Array
+    k_hi: jax.Array
+    q_lo: jax.Array
+    q_hi: jax.Array
+
+
+def live_schedule(
+    seg: jax.Array, block_q: int, block_k: int, causal: bool
+) -> Schedule:
+    """seg [B, S] int32 -> the intervals of tiles that can hold an unmasked
+    element.  A block's real ids span [smallest id > 0, largest id]; a tile
+    is live when its q and k spans meet and, under `causal`, its first key
+    is not past its last query.  With ids non-decreasing along the row that
+    is exact and the live tiles of a block are contiguous; with any other
+    ids the interval from the first live tile to the last still covers
+    them."""
+    b, s = seg.shape
+    big = jnp.iinfo(jnp.int32).max
+
+    def spans(block):
+        x = seg.reshape(b, s // block, block)
+        return jnp.min(jnp.where(x > 0, x, big), axis=-1), jnp.max(x, axis=-1)
+
+    q_min, q_max = spans(block_q)  # [B, nq]; a block of padding: (big, 0)
+    k_min, k_max = spans(block_k)
+    nq, nk = q_min.shape[1], k_min.shape[1]
+    live = (k_min[:, None, :] <= q_max[:, :, None]) & (
+        k_max[:, None, :] >= q_min[:, :, None]
+    )  # [B, nq, nk]
+    qi = jnp.arange(nq, dtype=jnp.int32)[:, None]
+    ki = jnp.arange(nk, dtype=jnp.int32)[None, :]
+    if causal:
+        live &= ki * block_k <= qi * block_q + block_q - 1
+
+    def interval(idx, n, axis):
+        lo = jnp.min(jnp.where(live, idx, n), axis=axis)
+        hi = jnp.max(jnp.where(live, idx, -1), axis=axis)
+        return jnp.where(hi < 0, 0, lo).reshape(-1), hi.reshape(-1)
+
+    return Schedule(*interval(ki, nk, 2), *interval(qi, nq, 1))
+
+
+def all_tiles_schedule(rows: int, nq: int, nk: int) -> Schedule:
+    """Every tile of every row's square: what the tests and `chip_smoke.py`
+    hold the live schedule's results to, bit for bit."""
+    zq = jnp.zeros((rows * nq,), jnp.int32)
+    zk = jnp.zeros((rows * nk,), jnp.int32)
+    return Schedule(zq, zq + nk - 1, zk, zk + nq - 1)
+
+
+def _resident_blocks(n_blocks: int, block: int, token_bytes: int) -> int:
+    """Blocks of the inner loop's operands one grid step holds in VMEM: the
+    largest divisor of `n_blocks` within RESIDENT_BYTES (chunks are equal,
+    so no step reads past the row)."""
+    fit = max(RESIDENT_BYTES // (block * token_bytes), 1)
+    return max(t for t in range(1, n_blocks + 1)
+               if n_blocks % t == 0 and t <= fit)
+
+
+def _live_in_chunk(lo_ref, hi_ref, row, c, tiles):
+    """The part of row's interval inside chunk c, as loop bounds."""
+    return (
+        jnp.maximum(lo_ref[row], c * tiles),
+        jnp.minimum(hi_ref[row], (c + 1) * tiles - 1) + 1,
+    )
+
+
+def _chunk_index(n_chunks: int, tiles: int):
+    """Index-map helper: chunk c clamped into the chunks row's interval
+    touches, so a step with no live tile keeps the resident block."""
+
+    def idx(lo_ref, hi_ref, row, c):
+        if n_chunks == 1:
+            return 0
+        return jnp.maximum(
+            lo_ref[row] // tiles, jnp.minimum(c, hi_ref[row] // tiles)
+        )
+
+    return idx
+
+
+def _tile_mask(seg_q, seg_k, qi, ki, block_q, block_k, causal):
+    """seg_q [bq, 1], seg_k [1, bk] -> the tile's [bq, bk] mask."""
+    mask = (seg_q == seg_k) & (seg_q > 0)
+    if causal:
+        q_pos = qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0
+        )
+        k_pos = ki * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1
+        )
+        mask &= q_pos >= k_pos
+    return mask
+
+
+def _tile_rows(i, block):
+    return pl.ds(pl.multiple_of(i * block, block), block)
+
+
+def _vmem(shape, dtype):
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.VMEM(shape, dtype)
+
+
+def _call(name, kernel, sched_refs, args, *, grid, in_specs, out_specs,
+          out_shape, scratch_shapes, resident_bytes):
+    """The kernels' common `pallas_call`: two schedule tables by scalar
+    prefetch, and a VMEM limit that holds the double-buffered resident
+    operands beside Mosaic's default 16 MiB for everything else."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return named_call(
+        name,
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=scratch_shapes,
+        ),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=2 * resident_bytes + (16 << 20)
+        ),
+        interpret=_interpret(),
+    )(*sched_refs, *args)
+
+
+# ---------------------------------------------------------------------------
+# Forward
+# ---------------------------------------------------------------------------
+
+
 def _fwd_kernel(
+    k_lo_ref, k_hi_ref,  # scalar prefetch
     seg_q_ref, seg_k_ref, q_ref, k_ref, v_ref,  # inputs
     o_ref, lse_ref,  # outputs
     m_scr, l_scr, acc_scr,  # scratch
-    *, scale: float, block_q: int, block_k: int, nk: int, causal: bool,
+    *, scale: float, block_q: int, block_k: int, hq: int, nq: int,
+    tiles: int, causal: bool,
 ):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    b, qi, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
-    @pl.when(ki == 0)
+    @pl.when(c == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0
-    )
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1
-    )
+    q = q_ref[0].astype(jnp.float32)  # [bq, d]
+    # Segment ids arrive sublane/lane-broadcast (Mosaic needs >=2D tiles
+    # with aligned minor dims): q ids [bq, 8] -> [bq, 1], k ids
+    # [8, bk] -> [1, bk].
+    seg_q = seg_q_ref[0][:, 0:1]
 
-    # Skip tiles strictly above the causal diagonal, and tiles whose q/k
-    # SEGMENTS cannot overlap (packed rows concatenate unrelated sequences;
-    # ids are non-decreasing along the row, so a disjoint id range means
-    # the whole tile is masked — this turns O(row^2) into near
-    # block-diagonal work).
-    causal_ok = (not causal) or (ki * block_k <= qi * block_q + block_q - 1)
-    sq = seg_q_ref[0][:, 0]
-    sk = seg_k_ref[0][0, :]
-    overlap = (
-        (jnp.min(sk) <= jnp.max(sq))
-        & (jnp.max(sk) >= jnp.min(sq))
-        & (jnp.max(sq) > 0)
-    )
-    run = causal_ok & overlap
-
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)  # [bq, d]
-        k = k_ref[0].astype(jnp.float32)  # [bk, d]
-        v = v_ref[0].astype(jnp.float32)  # [bk, d]
+    def tile(ki, _):
+        j = ki - c * tiles  # the tile's place in the resident chunk
+        rows = _tile_rows(j, block_k)
+        k = k_ref[0, rows, :].astype(jnp.float32)  # [bk, d]
+        v = v_ref[0, rows, :].astype(jnp.float32)  # [bk, d]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale  # [bq, bk]
-
-        # Segment ids arrive sublane/lane-broadcast (Mosaic needs >=2D tiles
-        # with aligned minor dims): q ids [bq, 8] -> [bq, 1], k ids
-        # [8, bk] -> [1, bk].
-        seg_q = seg_q_ref[0][:, 0:1]
-        seg_k = seg_k_ref[0][0:1, :]
-        mask = (seg_q == seg_k) & (seg_q > 0)
-        if causal:
-            mask &= q_pos >= k_pos
+        mask = _tile_mask(
+            seg_q, seg_k_ref[0, j][0:1, :], qi, ki, block_q, block_k, causal
+        )
         s = jnp.where(mask, s, NEG_INF)
 
         m_prev = m_scr[:]  # [bq, 1]
@@ -130,7 +280,12 @@ def _fwd_kernel(
         m_scr[:] = m_new
         l_scr[:] = l_new
 
-    @pl.when(ki == nk - 1)
+    jax.lax.fori_loop(
+        *_live_in_chunk(k_lo_ref, k_hi_ref, (b // hq) * nq + qi, c, tiles),
+        tile, None,
+    )
+
+    @pl.when(c == pl.num_programs(2) - 1)
     def _finalize():
         l = l_scr[:]
         safe_l = jnp.where(l > 0, l, 1.0)
@@ -138,63 +293,95 @@ def _fwd_kernel(
         lse_ref[0] = jnp.where(l > 0, m_scr[:] + jnp.log(safe_l), NEG_INF)
 
 
-def _seg_layouts(seg: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """[B, S] int32 -> (q ids [B, S, 8], k ids [B, 8, S]).
+def _seg_layouts(
+    seg: jax.Array, block_k: int
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """[B, S] int32 -> (q ids [B, S, 8], k ids [B, 8, S], k ids by block
+    [B, nk, 8, bk]).
 
     Mosaic requires >=2D tiles whose minor dims are 8/128-aligned or span the
     array; broadcasting ids over 8 sublanes/lanes (the official TPU flash
-    kernel's trick) satisfies that at 8x int32 cost.  Ids are per-BATCH (not
-    per-head): the BlockSpec index maps divide the b*h grid index by the
-    head count, so no H-fold copy is materialized.
+    kernel's trick) satisfies that at 8x int32 cost.  The blocked form is
+    what a kernel holds resident and indexes by tile (a dynamic index on a
+    leading dim, where a dynamic lane offset would not lower).  Ids are
+    per-BATCH (not per-head): the BlockSpec index maps divide the b*h grid
+    index by the head count, so no H-fold copy is materialized.
     """
     b, s = seg.shape
     seg_q = jnp.broadcast_to(seg[:, :, None], (b, s, 8))
     seg_k = jnp.broadcast_to(seg[:, None, :], (b, 8, s))
-    return seg_q, seg_k
+    seg_kb = jnp.broadcast_to(
+        seg.reshape(b, s // block_k, 1, block_k), (b, s // block_k, 8, block_k)
+    )
+    return seg_q, seg_k, seg_kb
 
 
-def _kv_index(hq: int, hkv: int):
+def _kv_row(hq: int, hkv: int):
     """Grid index (batch-major b*hq) -> kv row in the UNEXPANDED [B*hkv]
     array: in-kernel GQA — q head h reads kv head h // (hq//hkv), so the
     7x repeat_kv materialization never happens."""
     n_rep = hq // hkv
+    return lambda b: (b // hq) * hkv + (b % hq) // n_rep
 
-    def idx(b, qi, ki):
-        return (b // hq) * hkv + (b % hq) // n_rep, ki, 0
 
-    return idx
+def _q_major_specs(hq, hkv, nq, nk, d, block_q, block_k, itemsize):
+    """The kernels that walk q blocks (forward, dq): K/V tiles a step holds
+    resident and their bytes, and the block specs — what a step holds of
+    the q side, and the resident K/V chunk with its ids."""
+    token_bytes = 2 * d * itemsize + 8 * 4
+    tiles = _resident_blocks(nk, block_k, token_bytes)
+    kv_row = _kv_row(hq, hkv)
+    chunk = _chunk_index(nk // tiles, tiles)
+
+    def q_side(width):
+        return pl.BlockSpec(
+            (1, block_q, width), lambda b, qi, c, lo, hi: (b, qi, 0)
+        )
+
+    seg_q = pl.BlockSpec(
+        (1, block_q, 8), lambda b, qi, c, lo, hi: (b // hq, qi, 0)
+    )
+    seg_kb = pl.BlockSpec(
+        (1, tiles, 8, block_k),
+        lambda b, qi, c, lo, hi: (
+            b // hq, chunk(lo, hi, (b // hq) * nq + qi, c), 0, 0
+        ),
+    )
+    kv = pl.BlockSpec(
+        (1, tiles * block_k, d),
+        lambda b, qi, c, lo, hi: (
+            kv_row(b), chunk(lo, hi, (b // hq) * nq + qi, c), 0
+        ),
+    )
+    return tiles, tiles * block_k * token_bytes, q_side, seg_q, seg_kb, kv
 
 
 def _fwd(
-    q, k, v, seg, hq, scale, block_q, block_k, causal
+    q, k, v, seg, sched, hq, scale, block_q, block_k, causal
 ) -> Tuple[jax.Array, jax.Array]:
     """q: [B*hq, S, D]; k/v: [B*hkv, S, D] (unexpanded GQA); seg: [B, S]
-    int32.  Returns (o [B*hq,S,D], lse [B*hq,S,1])."""
+    int32; sched: the tiles to visit.  Returns (o [B*hq,S,D],
+    lse [B*hq,S,1])."""
     bh, s, d = q.shape
     hkv = k.shape[0] // seg.shape[0]
-    kv_idx = _kv_index(hq, hkv)
     nq = pl.cdiv(s, block_q)
     nk = pl.cdiv(s, block_k)
-    kernel = functools.partial(
-        _fwd_kernel,
-        scale=scale, block_q=block_q, block_k=block_k, nk=nk, causal=causal,
+    tiles, resident, q_side, seg_q_spec, seg_kb_spec, kv_spec = (
+        _q_major_specs(hq, hkv, nq, nk, d, block_q, block_k, k.dtype.itemsize)
     )
-    seg_q, seg_k = _seg_layouts(seg)
-    return named_call(
+    seg_q, _, seg_kb = _seg_layouts(seg, block_k)
+    return _call(
         "flash_fwd",
-        kernel,
-        grid=(bh, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, 8), lambda b, qi, ki: (b // hq, qi, 0)),
-            pl.BlockSpec((1, 8, block_k), lambda b, qi, ki: (b // hq, 0, ki)),
-            pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_k, d), kv_idx),
-            pl.BlockSpec((1, block_k, d), kv_idx),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, qi, ki: (b, qi, 0)),
-        ],
+        functools.partial(
+            _fwd_kernel,
+            scale=scale, block_q=block_q, block_k=block_k, hq=hq, nq=nq,
+            tiles=tiles, causal=causal,
+        ),
+        (sched.k_lo, sched.k_hi),
+        (seg_q, seg_kb, q, k, v),
+        grid=(bh, nq, nk // tiles),
+        in_specs=[seg_q_spec, seg_kb_spec, q_side(d), kv_spec, kv_spec],
+        out_specs=[q_side(d), q_side(1)],
         out_shape=[
             jax.ShapeDtypeStruct((bh, s, d), q.dtype),
             jax.ShapeDtypeStruct((bh, s, 1), jnp.float32),
@@ -204,14 +391,8 @@ def _fwd(
             _vmem((block_q, 1), jnp.float32),
             _vmem((block_q, d), jnp.float32),
         ],
-        interpret=_interpret(),
-    )(seg_q, seg_k, q, k, v)
-
-
-def _vmem(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.VMEM(shape, dtype)
+        resident_bytes=resident,
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -220,50 +401,35 @@ def _vmem(shape, dtype):
 
 
 def _dq_kernel(
+    k_lo_ref, k_hi_ref,
     seg_q_ref, seg_k_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dq_ref,
     dq_scr,
-    *, scale, block_q, block_k, nk, causal,
+    *, scale, block_q, block_k, hq, nq, tiles, causal,
 ):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    b, qi, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
-    @pl.when(ki == 0)
+    @pl.when(c == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    causal_ok = (not causal) or (ki * block_k <= qi * block_q + block_q - 1)
-    sq = seg_q_ref[0][:, 0]
-    sk = seg_k_ref[0][0, :]
-    overlap = (
-        (jnp.min(sk) <= jnp.max(sq))
-        & (jnp.max(sk) >= jnp.min(sq))
-        & (jnp.max(sq) > 0)
-    )
-    run = causal_ok & overlap
+    q = q_ref[0].astype(jnp.float32)
+    do = do_ref[0].astype(jnp.float32)
+    lse = lse_ref[0]  # [bq, 1]
+    delta = delta_ref[0]  # [bq, 1]
+    seg_q = seg_q_ref[0][:, 0:1]
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]  # [bq, 1]
-        delta = delta_ref[0]  # [bq, 1]
+    def tile(ki, _):
+        j = ki - c * tiles
+        rows = _tile_rows(j, block_k)
+        k = k_ref[0, rows, :].astype(jnp.float32)
+        v = v_ref[0, rows, :].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
-        seg_q = seg_q_ref[0][:, 0:1]
-        seg_k = seg_k_ref[0][0:1, :]
-        mask = (seg_q == seg_k) & (seg_q > 0)
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            mask &= q_pos >= k_pos
+        mask = _tile_mask(
+            seg_q, seg_k_ref[0, j][0:1, :], qi, ki, block_q, block_k, causal
+        )
         p = jnp.where(mask, jnp.exp(s - lse), 0.0)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -273,57 +439,47 @@ def _dq_kernel(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    @pl.when(ki == nk - 1)
+    jax.lax.fori_loop(
+        *_live_in_chunk(k_lo_ref, k_hi_ref, (b // hq) * nq + qi, c, tiles),
+        tile, None,
+    )
+
+    @pl.when(c == pl.num_programs(2) - 1)
     def _finalize():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(
+    q_lo_ref, q_hi_ref,
     seg_q_ref, seg_k_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dk_ref, dv_ref,
     dk_scr, dv_scr,
-    *, scale, block_q, block_k, nq, causal,
+    *, scale, block_q, block_k, hq, nk, tiles, causal,
 ):
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    b, ki, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
-    @pl.when(qi == 0)
+    @pl.when(c == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    causal_ok = (not causal) or (ki * block_k <= qi * block_q + block_q - 1)
-    sq = seg_q_ref[0][:, 0]
-    sk = seg_k_ref[0][0, :]
-    overlap = (
-        (jnp.min(sk) <= jnp.max(sq))
-        & (jnp.max(sk) >= jnp.min(sq))
-        & (jnp.max(sq) > 0)
-    )
-    run = causal_ok & overlap
+    k = k_ref[0].astype(jnp.float32)
+    v = v_ref[0].astype(jnp.float32)
+    seg_k = seg_k_ref[0][0:1, :]
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]  # [bq, 1]
-        delta = delta_ref[0]  # [bq, 1]
+    def tile(qi, _):
+        rows = _tile_rows(qi - c * tiles, block_q)
+        q = q_ref[0, rows, :].astype(jnp.float32)
+        do = do_ref[0, rows, :].astype(jnp.float32)
+        lse = lse_ref[0, rows, :]  # [bq, 1]
+        delta = delta_ref[0, rows, :]  # [bq, 1]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
-        seg_q = seg_q_ref[0][:, 0:1]
-        seg_k = seg_k_ref[0][0:1, :]
-        mask = (seg_q == seg_k) & (seg_q > 0)
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            mask &= q_pos >= k_pos
+        mask = _tile_mask(
+            seg_q_ref[0, rows, :][:, 0:1], seg_k, qi, ki, block_q, block_k,
+            causal,
+        )
         p = jnp.where(mask, jnp.exp(s - lse), 0.0)  # [bq, bk]
         dv_scr[:] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
@@ -336,7 +492,12 @@ def _dkv_kernel(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    @pl.when(qi == nq - 1)
+    jax.lax.fori_loop(
+        *_live_in_chunk(q_lo_ref, q_hi_ref, (b // hq) * nk + ki, c, tiles),
+        tile, None,
+    )
+
+    @pl.when(c == pl.num_programs(2) - 1)
     def _finalize():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -344,76 +505,89 @@ def _dkv_kernel(
 
 def _bwd(
     scale, block_q, block_k, causal, res, do
-) -> Tuple[jax.Array, jax.Array, jax.Array, None]:
-    q, k, v, o, lse, seg = res
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    q, k, v, o, lse, seg, sched = res
     bh, s, d = q.shape
     b = seg.shape[0]
     hq = bh // b
     hkv = k.shape[0] // b
     n_rep = hq // hkv
-    kv_idx_q = _kv_index(hq, hkv)  # grid order (b, qi, ki)
-
-    def kv_idx_k(bi, ki, qi):  # grid order (b, ki, qi): s-block is ki
-        row, _, _ = kv_idx_q(bi, qi, ki)
-        return row, ki, 0
-
     nq = pl.cdiv(s, block_q)
     nk = pl.cdiv(s, block_k)
     delta = jnp.sum(
         o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1, keepdims=True
     )  # [BH, S, 1]
 
-    seg_q, seg_k = _seg_layouts(seg)
-    common_in = [seg_q, seg_k, q, k, v, do, lse, delta]
+    seg_q, seg_k, seg_kb = _seg_layouts(seg, block_k)
 
-    dq = named_call(
+    tiles, resident, q_side, seg_q_spec, seg_kb_spec, kv_spec = (
+        _q_major_specs(hq, hkv, nq, nk, d, block_q, block_k, k.dtype.itemsize)
+    )
+    dq = _call(
         "flash_dq",
         functools.partial(
             _dq_kernel,
-            scale=scale, block_q=block_q, block_k=block_k, nk=nk,
-            causal=causal,
+            scale=scale, block_q=block_q, block_k=block_k, hq=hq, nq=nq,
+            tiles=tiles, causal=causal,
         ),
-        grid=(bh, nq, nk),
+        (sched.k_lo, sched.k_hi),
+        (seg_q, seg_kb, q, k, v, do, lse, delta),
+        grid=(bh, nq, nk // tiles),
         in_specs=[
-            pl.BlockSpec((1, block_q, 8), lambda b, qi, ki: (b // hq, qi, 0)),
-            pl.BlockSpec((1, 8, block_k), lambda b, qi, ki: (b // hq, 0, ki)),
-            pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_k, d), kv_idx_q),
-            pl.BlockSpec((1, block_k, d), kv_idx_q),
-            pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, qi, ki: (b, qi, 0)),
+            seg_q_spec, seg_kb_spec, q_side(d), kv_spec, kv_spec,
+            q_side(d), q_side(1), q_side(1),
         ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
+        out_specs=q_side(d),
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
         scratch_shapes=[_vmem((block_q, d), jnp.float32)],
-        interpret=_interpret(),
-    )(*common_in)
+        resident_bytes=resident,
+    )
+
+    # dkv walks k blocks: K/V and the k ids by step, the q side resident
+    # (Q and dO, and three [rows, 1 or 8] columns that pad to 128 lanes).
+    token_bytes = 2 * d * q.dtype.itemsize + 3 * _COLUMN_BYTES
+    tiles = _resident_blocks(nq, block_q, token_bytes)
+    kv_row = _kv_row(hq, hkv)
+    chunk = _chunk_index(nq // tiles, tiles)
+
+    def k_side(rows, width=d):
+        return pl.BlockSpec(
+            (1, block_k, width), lambda b, ki, c, lo, hi: (rows(b), ki, 0)
+        )
+
+    def q_resident(width, rows=lambda b: b):
+        return pl.BlockSpec(
+            (1, tiles * block_q, width),
+            lambda b, ki, c, lo, hi: (
+                rows(b), chunk(lo, hi, (b // hq) * nk + ki, c), 0
+            ),
+        )
 
     # dk/dv come out per Q-HEAD (the grid walks q heads); the n_rep grads
     # sharing one kv head are group-summed after the kernel.
-    dk_x, dv_x = named_call(
+    dk_x, dv_x = _call(
         "flash_dkv",
         functools.partial(
             _dkv_kernel,
-            scale=scale, block_q=block_q, block_k=block_k, nq=nq,
-            causal=causal,
+            scale=scale, block_q=block_q, block_k=block_k, hq=hq, nk=nk,
+            tiles=tiles, causal=causal,
         ),
-        grid=(bh, nk, nq),
+        (sched.q_lo, sched.q_hi),
+        (seg_q, seg_k, q, k, v, do, lse, delta),
+        grid=(bh, nk, nq // tiles),
         in_specs=[
-            pl.BlockSpec((1, block_q, 8), lambda b, ki, qi: (b // hq, qi, 0)),
-            pl.BlockSpec((1, 8, block_k), lambda b, ki, qi: (b // hq, 0, ki)),
-            pl.BlockSpec((1, block_q, d), lambda b, ki, qi: (b, qi, 0)),
-            pl.BlockSpec((1, block_k, d), kv_idx_k),
-            pl.BlockSpec((1, block_k, d), kv_idx_k),
-            pl.BlockSpec((1, block_q, d), lambda b, ki, qi: (b, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, ki, qi: (b, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, ki, qi: (b, qi, 0)),
+            q_resident(8, lambda b: b // hq),
+            pl.BlockSpec(
+                (1, 8, block_k), lambda b, ki, c, lo, hi: (b // hq, 0, ki)
+            ),
+            q_resident(d),
+            k_side(kv_row),
+            k_side(kv_row),
+            q_resident(d),
+            q_resident(1),
+            q_resident(1),
         ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
-        ],
+        out_specs=[k_side(lambda b: b), k_side(lambda b: b)],
         out_shape=[
             jax.ShapeDtypeStruct((bh, s, d), jnp.float32),
             jax.ShapeDtypeStruct((bh, s, d), jnp.float32),
@@ -422,8 +596,8 @@ def _bwd(
             _vmem((block_k, d), jnp.float32),
             _vmem((block_k, d), jnp.float32),
         ],
-        interpret=_interpret(),
-    )(*common_in)
+        resident_bytes=tiles * block_q * token_bytes,
+    )
 
     def group_sum(g):
         return (
@@ -434,7 +608,7 @@ def _bwd(
 
     dk = group_sum(dk_x).astype(k.dtype)
     dv = group_sum(dv_x).astype(v.dtype)
-    return dq, dk, dv, None
+    return dq, dk, dv
 
 
 # ---------------------------------------------------------------------------
@@ -442,21 +616,21 @@ def _bwd(
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash_bhsd(q, k, v, seg, scale, block_q, block_k, causal):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _flash_bhsd(q, k, v, seg, sched, scale, block_q, block_k, causal):
     hq = q.shape[0] // seg.shape[0]
-    o, _ = _fwd(q, k, v, seg, hq, scale, block_q, block_k, causal)
+    o, _ = _fwd(q, k, v, seg, sched, hq, scale, block_q, block_k, causal)
     return o
 
 
-def _flash_fwd_rule(q, k, v, seg, scale, block_q, block_k, causal):
+def _flash_fwd_rule(q, k, v, seg, sched, scale, block_q, block_k, causal):
     hq = q.shape[0] // seg.shape[0]
-    o, lse = _fwd(q, k, v, seg, hq, scale, block_q, block_k, causal)
-    return o, (q, k, v, o, lse, seg)
+    o, lse = _fwd(q, k, v, seg, sched, hq, scale, block_q, block_k, causal)
+    return o, (q, k, v, o, lse, seg, sched)
 
 
 def _flash_bwd_rule(scale, block_q, block_k, causal, res, do):
-    return _bwd(scale, block_q, block_k, causal, res, do)
+    return *_bwd(scale, block_q, block_k, causal, res, do), None, None
 
 
 _flash_bhsd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -489,8 +663,10 @@ def flash_attention(
         h = x.shape[2]
         return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
 
+    seg = segment_ids.astype(jnp.int32)
     o = _flash_bhsd(
-        to_bhsd(q), to_bhsd(k), to_bhsd(v), segment_ids.astype(jnp.int32),
+        to_bhsd(q), to_bhsd(k), to_bhsd(v), seg,
+        live_schedule(seg, block_q, block_k, causal),
         d**-0.5, block_q, block_k, causal,
     )
     return o.reshape(b, hq, s, d).transpose(0, 2, 1, 3)

@@ -136,6 +136,80 @@ def _max_err(a, b):
     )
 
 
+# The flash kernels' calls in the benchmark's cells: name -> (rows, row
+# length, q heads, kv heads, head_dim, the sequences packed into each row).
+FLASH_CELLS = {
+    "long-prompt train row [12,8192,128]":
+        (1, 8192, 12, 2, 128, [2598, 2598, 1263, 740, 384, 384]),
+    "long-prompt train rows [24,8192,128]":
+        (2, 8192, 12, 2, 128, [2598, 1263, 1263, 740, 740, 384, 384]),
+    "serving train row [12,8192,128]": (1, 8192, 12, 2, 128, [210] * 39),
+    "prefill [192,2560,128]": (16, 2560, 12, 2, 128, [2534]),
+    "qwen3-next train row [16,8192,256]": (1, 8192, 16, 2, 256, [640] * 12),
+    "7B per-chip rows [28,2048,128]": (1, 2048, 28, 4, 128, [400] * 5),
+    "a row in two chunks [12,16384,128]":
+        (1, 16384, 12, 2, 128, [9000, 4000, 3000]),
+}
+
+
+def _flash_cell_shapes(fa, dt, on_tpu, reps=5):
+    """The kernels at the cells' sizes (about 1/16 the lengths in rehearsal):
+    under the live schedule they give the bits they give when every tile
+    of the square is visited, and a call's time goes to the log."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    blk = fa.DEFAULT_BLOCK_Q
+    for name, (b, s, hq, hkv, d, lens) in FLASH_CELLS.items():
+        if not on_tpu:
+            s, lens = max(s // 16 // blk, 1) * blk, [n // 16 for n in lens]
+        rng = np.random.default_rng(s + hq)
+        q, k, v, do = (
+            jnp.asarray(rng.standard_normal((b * h, s, d)), dt)
+            for h in (hq, hkv, hkv, hq)
+        )
+        ids = np.repeat(np.arange(len(lens)) + 1, lens)[:s]
+        seg = jnp.asarray(np.tile(np.pad(ids, (0, s - len(ids))), (b, 1)))
+        scale = d ** -0.5
+
+        @jax.jit
+        def fwd(sched):
+            return fa._fwd(q, k, v, seg, sched, hq, scale, blk, blk, True)
+
+        @jax.jit
+        def fwd_bwd(sched):
+            o, lse = fa._fwd(q, k, v, seg, sched, hq, scale, blk, blk, True)
+            res = (q, k, v, o, lse, seg, sched)
+            return (o, lse) + fa._bwd(scale, blk, blk, True, res, do)
+
+        live = jax.jit(fa.live_schedule, static_argnums=(1, 2, 3))(
+            seg, blk, blk, True
+        )
+        n = s // blk
+        got = fwd_bwd(live)
+        want = fwd_bwd(fa.all_tiles_schedule(b, n, n))
+        check(
+            all(bool(jnp.array_equal(a, c)) for a, c in zip(got, want)),
+            f"flash {name}: o, lse, dq, dk, dv under the live schedule == "
+            f"under all tiles, bit for bit "
+            f"({int(jnp.sum(live.k_hi - live.k_lo + 1))} of {b * n * n} "
+            f"tiles a head)",
+        )
+        ms = {}
+        for what, fn in (("fwd", fwd), ("fwd+bwd", fwd_bwd)):
+            jax.block_until_ready(fn(live))
+            t0 = time.perf_counter()
+            for _ in range(reps):
+                out = fn(live)
+            jax.block_until_ready(out)
+            ms[what] = (time.perf_counter() - t0) / reps * 1e3
+        log(f"  flash {name}: fwd {ms['fwd']:.3f} ms, fwd + dq + dkv "
+            f"{ms['fwd+bwd']:.3f} ms a call (host clock, {reps} calls"
+            + ("" if on_tpu else "; interpreted on the cpu, no device time")
+            + ")")
+
+
 def phase_kernels(geom, on_tpu):
     import jax
     import jax.numpy as jnp
@@ -203,6 +277,8 @@ def phase_kernels(geom, on_tpu):
     err = _max_err(o_ref, o_sh)
     check(err <= tol,
           f"shard_mapped flash ({layout}) == reference (max err {err:.2e})")
+
+    _flash_cell_shapes(fa, dt, on_tpu)
 
     # ---- ragged stream kernel (bf16 and int8 pools) vs the XLA gather path
     t, n_pool, ps, mp = 40, 64, geom["page"], 4

@@ -382,10 +382,12 @@ def test_pack_counter_carries_rows_and_empty_rows(trial):
     ]
     # One per train_batch call: two PPO minibatches a step, three steps.
     assert len(counters) == 6
-    assert all(set(c) == {"n_rows", "empty_rows"} for c in counters)
-    assert counters[-1] == {
-        k: trial["pack"][-1][k] for k in ("n_rows", "empty_rows")
-    }
+    keys = {"n_rows", "empty_rows", "flash_live_tiles", "flash_grid_tiles"}
+    assert all(set(c) == keys for c in counters)
+    assert counters[-1] == {k: trial["pack"][-1][k] for k in keys}
+    # What the flash kernels visit of the rows' squares.
+    for pack in trial["pack"]:
+        assert 0 < pack["flash_live_tiles"] <= pack["flash_grid_tiles"]
 
 
 def test_compiles_are_charged_to_the_mfc_that_compiled(trial):
