@@ -265,7 +265,7 @@ def _spec_emit(
 def _new_chunk_stats() -> Dict[str, Any]:
     return {
         "chunks": 0, "admitted": 0, "retired": 0, "chunk_host_s": 0.0,
-        "admit_waits": [], "n_waited": 0,
+        "admit_waits": [], "n_waited": 0, "admit_passed_over": 0,
     }
 
 
@@ -1155,23 +1155,35 @@ class GeneratorEngine(HostOffloadMixin, Engine):
 
     def _take_admits_serving(self, st: "_PagedGenSession") -> int:
         """Admission for the serving loop: pure host bookkeeping (the
-        compiled chunk does the prompt forwards).  A request whose prompt
-        hash is in the prefix cache maps the cached FULL prompt pages
-        (refcount bump, zero copies) and re-forwards only the sub-page
-        tail — its marginal footprint is tail + decode budget instead of
-        prompt + decode budget.  A request whose hash an in-flight owner
-        is still prefilling WAITS (admitting it now would duplicate the
-        owner's pages); the owner is live, so waiting cannot deadlock.
-        Raises PagePoolExhausted via reserve() when nothing is live and
-        the head request still cannot fit (undersized pool)."""
+        compiled chunk does the prompt forwards).  Each free slot takes
+        the FIRST queued request that can be admitted now, in queue order
+        (one cursor walks the queue once a round: what a round passes
+        over stays unadmittable for the rest of it).  A request whose
+        prompt hash is in the prefix cache maps the cached FULL prompt
+        pages (refcount bump, zero copies) and re-forwards only the
+        sub-page tail — its marginal footprint is tail + decode budget
+        instead of prompt + decode budget.  A request whose hash an
+        in-flight owner is still prefilling is PASSED OVER (admitting it
+        now would duplicate the owner's pages): it keeps its place in
+        the queue, the round goes on with the requests behind it, and it
+        is admitted, sharing, in the first round after the owner
+        registers.  The owner is live, so that wait is bounded by one
+        prefill and cannot deadlock.  It costs no allocator look-up:
+        prefix_misses counts owners, not rounds waited.  A request that
+        does not fit the POOL still ends the round (first come, first
+        served under memory pressure).  Raises PagePoolExhausted via
+        reserve() when nothing is live and that request still cannot fit
+        (undersized pool)."""
         alloc, gconfig = st.alloc, st.gconfig
         n_slots, ps, chunk_t = st.n_slots, alloc.page_size, st.chunk_t
         slack = chunk_t + gconfig.spec_decode_k
-        admitted = 0
-        for s in range(n_slots):
-            if st.active[s] is not None or not st.pending:
-                continue
-            i, rep, toks = st.pending[-1]
+        admitted = passed_over = 0
+        free_slots = (s for s in range(n_slots) if st.active[s] is None)
+        s = next(free_slots, None)
+        at = len(st.pending) - 1  # the queue's head is the list's tail
+        unfit = None  # prompt length that ended the round on pool pressure
+        while s is not None and at >= 0:
+            i, rep, toks = st.pending[at]
             toks = np.asarray(toks, np.int32)
             plen = len(toks)
             # Only FULL pages are shareable, and the tail must keep >= 1
@@ -1180,14 +1192,17 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             # positions [0, sp*ps), the follower prefills [sp*ps, plen).
             sp = (plen - 1) // ps
             h = toks.tobytes() if (self.kv_share_prefix and sp > 0) else None
+            if h is not None and h in st.inflight_prefix:
+                passed_over += 1  # its owner registers at a chunk's end
+                at -= 1
+                continue
             shared = alloc.prefix_lookup(h) if h is not None else None
-            if shared is None and h is not None and h in st.inflight_prefix:
-                break  # wait one chunk for the owner to register
             if shared is not None:
                 need = alloc.pages_for(plen + slack) - len(shared)
                 if need > len(alloc.free):
                     alloc.prefix_evict(need)
                 if need > len(alloc.free):
+                    unfit = plen
                     break
                 alloc.share(s, shared)
                 start = sp * ps
@@ -1198,13 +1213,15 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                         alloc.pages_for(plen + slack) - int(alloc.used[s])
                     )
                 if not alloc.can_reserve(s, plen + slack):
+                    unfit = plen
                     break
                 alloc.reserve(s, plen + slack)
                 start = 0
                 if h is not None:
                     st.inflight_prefix[h] = s
                     st.slot_hash[s] = h
-            st.pending.pop()
+            del st.pending[at]
+            at -= 1
             st.active[s] = (i, rep)
             st.cache_len[s] = start
             st.gen_count[s] = 0
@@ -1220,27 +1237,21 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             st.prompt_off[s] = 0
             st.last_emit[s] = 0
             admitted += 1
+            s = next(free_slots, None)
         self._note_admits(admitted)
-        if (
-            admitted == 0
-            and st.pending
-            and not any(a is not None for a in st.active)
-        ):
-            # Nothing live to retire and the head request does not fit:
+        self._chunk_stats["admit_passed_over"] += passed_over
+        if unfit is not None and not any(a is not None for a in st.active):
+            # Nothing live to retire and this request does not fit:
             # waiting would spin forever.  (The admission loop above
-            # already tried prefix eviction, and inflight_prefix cannot
-            # block here — owners are by definition live.)  reserve()
-            # raises the clean capacity error.
-            free_slot = next(
-                s2 for s2 in range(n_slots) if st.active[s2] is None
-            )
-            alloc.reserve(
-                free_slot, len(st.pending[-1][2]) + slack
-            )  # raises
+            # already tried prefix eviction, and nothing is passed over
+            # here — owners are by definition live.)  reserve() raises
+            # the clean capacity error.
+            alloc.reserve(0, unfit + slack)  # raises
         self._set_live_slots(sum(a is not None for a in st.active))
         st.peak_live = max(st.peak_live, self.live_slots)
         tracer.counter(
-            "gen_slots", live=self.live_slots, pending=len(st.pending)
+            "gen_slots", live=self.live_slots, pending=len(st.pending),
+            passed_over=passed_over,
         )
         return admitted
 
@@ -1268,6 +1279,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             "chunks": cs["chunks"], "admitted": cs["admitted"],
             "retired": cs["retired"], "chunk_host_s": cs["chunk_host_s"],
             "n_waited": cs["n_waited"],
+            "admit_passed_over": cs["admit_passed_over"],
             "admit_wait_mean_s": sum(waits) / max(len(waits), 1),
             "admit_wait_p50_s": waits[len(waits) // 2] if waits else 0.0,
             "admit_wait_max_s": waits[-1] if waits else 0.0,

@@ -245,34 +245,50 @@ class TestCompileOnceContract:
 
 
 class TestPageRecycling:
-    def test_bounded_pool_recycles_and_matches(self, cfg, params, mesh, rng):
+    @pytest.mark.parametrize(
+        "lens,n",
+        [((4, 11, 6, 9, 5, 7), 1), ((11, 4, 9), 2)],
+        ids=["singles", "groups"],
+    )
+    def test_bounded_pool_recycles_and_matches(
+        self, cfg, params, mesh, rng, lens, n
+    ):
         """A pool too small for all slots at once: retirement must
         recycle pages into later admissions (throttling them, never
-        corrupting them) — outputs still match the static program."""
+        corrupting them) — outputs still match the static program.  In
+        groups the 11-token owner's follower is passed over and the
+        request behind it does not fit the pool: that still ends the
+        round (first come, first served under memory pressure)."""
         paged = _engine(
             cfg, params, mesh, kv_pool_pages=4, max_decode_batch=2
         )
         # Worst case per slot: ceil((11 + 8 + 8) / 8) = 4 pages — the
         # pool holds exactly ONE slot's worst case, so the second slot
         # waits for the first to retire (admission against the budget).
-        lens = (4, 11, 6, 9, 5, 7)
         sample = _prompt_sample(rng, cfg, lens)
-        g = GenerationHyperparameters(n=1, max_new_tokens=8, greedy=True)
+        g = GenerationHyperparameters(n=n, max_new_tokens=8, greedy=True)
         ref, out = _static_and_serving(paged, sample, g)
         _assert_same_output(ref, out)
-        assert paged.last_pool_stats["pages_recycled"] > 0
-        assert paged.last_pool_stats["pool_pages"] == 4
+        st = paged.last_pool_stats
+        assert st["pages_recycled"] > 0
+        assert st["pool_pages"] == 4
+        assert (st["admit_passed_over"] > 0) == (n > 1)
 
+    @pytest.mark.parametrize(
+        "lens,n", [((20,), 1), ((20, 12), 4)], ids=["single", "groups"]
+    )
     def test_undersized_pool_raises_clear_error(
-        self, cfg, params, mesh, rng
+        self, cfg, params, mesh, rng, lens, n
     ):
         """A pool that cannot hold even one request must fail fast with
-        the capacity message, not deadlock the admission loop."""
+        the capacity message, not deadlock the admission loop: a request
+        the pool cannot take still ends the round, with or without
+        followers queued behind it."""
         paged = _engine(
             cfg, params, mesh, kv_pool_pages=1, max_decode_batch=2
         )
-        sample = _prompt_sample(rng, cfg, (20,))
-        g = GenerationHyperparameters(n=1, max_new_tokens=16, greedy=True)
+        sample = _prompt_sample(rng, cfg, lens)
+        g = GenerationHyperparameters(n=n, max_new_tokens=16, greedy=True)
         with pytest.raises(PagePoolExhausted, match="kv_pool_pages"):
             paged.generate(sample, MicroBatchSpec(), g, inflight=True)
 
@@ -423,33 +439,56 @@ class TestServingPlaneEquivalence:
         kw.setdefault("max_decode_batch", 2)
         return _engine(cfg, params, mesh, **kw)
 
+    @pytest.mark.parametrize(
+        "lens,n",
+        [
+            pytest.param(LENS, 1, id="singles"),
+            # Groups of 2 on 2 slots: round 1 admits the 17-token owner,
+            # passes over its follower and admits the 11-token owner.
+            pytest.param((17, 4, 11, 6), 2, id="waiter-passed-over"),
+        ],
+    )
     def test_token_identical_to_static_program(
-        self, cfg, params, mesh, rng
+        self, cfg, params, mesh, rng, lens, n
     ):
         serving = self._serving(cfg, params, mesh)
-        sample = _prompt_sample(rng, cfg, self.LENS)
-        g = GenerationHyperparameters(n=1, max_new_tokens=8, greedy=True)
+        sample = _prompt_sample(rng, cfg, lens)
+        g = GenerationHyperparameters(n=n, max_new_tokens=8, greedy=True)
         ref, out = _static_and_serving(serving, sample, g)
         _assert_same_output(ref, out)
         assert serving.prefill_dispatches == 0
         assert serving.decode_compiles == 1
         assert serving.cache_copy_bytes == 0
+        assert (serving.last_pool_stats["admit_passed_over"] > 0) == (n > 1)
 
+    @pytest.mark.parametrize(
+        "lens",
+        [
+            pytest.param((17, 9), id="two-groups"),
+            # Shareable and one-page prompts interleaved: followers are
+            # passed over while requests behind them take the slots.
+            pytest.param((21, 6, 13, 4), id="mixed-lengths"),
+        ],
+    )
     def test_group_sampling_shares_prompt_pages(
-        self, cfg, params, mesh, rng
+        self, cfg, params, mesh, rng, lens
     ):
         """n=4 same-prompt responses: identical tokens to the static
         program, but the prompt's full pages are mapped (not copied)
         into the followers via the prefix cache — visible as shared
-        mappings and prefix hits in the pool stats."""
+        mappings and prefix hits in the pool stats.  A follower whose
+        owner is still prefilling is passed over, never duplicated: one
+        miss per owner, one hit per follower."""
         serving = self._serving(cfg, params, mesh)
-        sample = _prompt_sample(rng, cfg, (17, 9))
+        sample = _prompt_sample(rng, cfg, lens)
         g = GenerationHyperparameters(n=4, max_new_tokens=8, greedy=True)
         ref, out = _static_and_serving(serving, sample, g)
         _assert_same_output(ref, out)
         st = serving.last_pool_stats
         assert st["shared_mappings"] > 0
-        assert st["prefix_hits"] > 0
+        assert st["admit_passed_over"] > 0
+        owners = sum((l - 1) // 8 > 0 for l in lens)
+        assert (st["prefix_misses"], st["prefix_hits"]) == (owners, 3 * owners)
         assert st["cow_copies"] == 0  # steady state: no write ever lands
         # on a shared page, so the CoW safety net stays idle
 
@@ -502,7 +541,10 @@ class TestServingPlaneEquivalence:
 
         def build():
             # Unreachable EOS keeps rows decoding; max_decode_batch=2
-            # forces slot reuse so the interrupt lands with live shares.
+            # forces slot reuse so the interrupt lands with live shares:
+            # the two owners fill both slots in round 1 (the followers
+            # are passed over), retire after chunk 2, and two followers
+            # run chunk 3 on the owner's pages.
             return GeneratorEngine(
                 cfg, params, mesh, eos_token_id=cfg.vocab_size + 7,
                 kv_page_size=8, prefill_chunk_tokens=4,
@@ -523,17 +565,22 @@ class TestServingPlaneEquivalence:
             )
 
         _, out = self._interrupted_then_resumed(
-            build, sample, g, at_chunk=2, parked=parked
+            build, sample, g, at_chunk=3, parked=parked
         )
         _assert_same_output(ref, out)
 
+    @pytest.mark.parametrize("n", [1, 3], ids=["singles", "waiter-pending"])
     def test_resume_with_row_parked_mid_prefill(
-        self, cfg, params, mesh, rng
+        self, cfg, params, mesh, rng, n
     ):
         """A row whose prompt is still being consumed when the interrupt
         lands has only a prefix of it in cache: the replay re-forwards
         the tail of THAT prefix and the loop goes on consuming the rest,
-        token for token as if never interrupted."""
+        token for token as if never interrupted.  In groups of 3 the
+        interrupt also finds the owner's followers passed over and still
+        queued: resume drops the pre-push owner's claim (its KV is never
+        published), so the first of them is admitted as the prompt's
+        owner under the current weights and the other shares its pages."""
 
         def build():
             # chunk_t = max_new = 4 steps of W = 2 lanes: one chunk
@@ -544,8 +591,8 @@ class TestServingPlaneEquivalence:
                 max_decode_batch=2,
             )
 
-        sample = _prompt_sample(rng, cfg, (30, 5, 21))  # 3 reqs, 2 slots
-        g = GenerationHyperparameters(n=1, max_new_tokens=4, greedy=True)
+        sample = _prompt_sample(rng, cfg, (30, 5, 21))  # 3n reqs, 2 slots
+        g = GenerationHyperparameters(n=n, max_new_tokens=4, greedy=True)
         ref = build().generate(sample, MicroBatchSpec(), g, seed=0)
 
         def parked(st):
@@ -554,11 +601,24 @@ class TestServingPlaneEquivalence:
                 if st.active[s] is not None and int(st.prefill_rem[s]) > 0
             ]
             assert mid and all(int(st.cache_len[s]) > 0 for s in mid)
+            waiting = [
+                q for q in st.pending
+                if np.asarray(q[2], np.int32).tobytes() in st.inflight_prefix
+            ]
+            assert len(waiting) == 2 * (n - 1)  # of the 30 and the 21
+            assert st.alloc.shared_mappings == 0
 
         eng, out = self._interrupted_then_resumed(
             build, sample, g, at_chunk=1, parked=parked
         )
         _assert_same_output(ref, out)
+        if n > 1:
+            # Per prompt over a page: the parked owner and the follower
+            # admitted as owner after resume miss, the last follower hits.
+            st = eng.last_pool_stats
+            assert st["admit_passed_over"] > 0
+            assert (st["prefix_misses"], st["prefix_hits"]) == (4, 2)
+            assert st["shared_mappings"] > 0 and st["cow_copies"] == 0
         assert {
             sig[0] for sig in eng._gen_fns if isinstance(sig[0], str)
         } == {"serving_chunk", "paged_replay"}
@@ -621,6 +681,153 @@ class TestServingPlaneEquivalence:
         assert 0 < eng.lanes_live <= eng.lanes_dispatched
         assert eng.lanes_live + eng.lanes_slack == eng.lanes_dispatched
         assert eng.dead_live_lanes == 0
+
+
+def _model_rounds(lens, n, n_slots, ps, chunk_t, W, max_new, pass_over):
+    """The admission rounds in plain Python, for a lane budget that
+    grants every prefilling row its W lanes: a row admitted with `rem`
+    prompt tokens to forward prefills for ceil(rem / W) inner steps,
+    registers its prompt at the end of that chunk, emits one token a
+    step and retires at the end of the chunk in which it emitted
+    `max_new`.  `pass_over=False` is the rule this replaced (the first
+    waiting follower ends the round).  Returns, per round, the
+    (prompt, repeat)s admitted and the live slots, and how often a
+    round passed over a request."""
+    queue = sorted(
+        ((i, r) for i in range(len(lens)) for r in range(n)),
+        key=lambda q: -lens[q[0]],
+    )
+    live = {}  # (prompt, repeat) -> [prefill steps left, budget steps left]
+    owners, inflight, cached = set(), set(), set()
+    rounds, passed = [], 0
+    while queue or live:
+        took = []
+        for q in list(queue):
+            if len(live) == n_slots:
+                break
+            i = q[0]
+            sp = (lens[i] - 1) // ps
+            if sp and i in inflight:
+                if not pass_over:
+                    break
+                passed += 1
+                continue
+            rem = lens[i] - (sp * ps if i in cached else 0)
+            if sp and i not in cached:
+                inflight.add(i)
+                owners.add(q)
+            live[q] = [-(-rem // W), -(-rem // W) + max_new]
+            queue.remove(q)
+            took.append(q)
+        rounds.append((took, len(live)))
+        for q, left in list(live.items()):
+            left[0] -= chunk_t
+            left[1] -= chunk_t
+            if q in owners and left[0] <= 0 and q[0] in inflight:
+                inflight.remove(q[0])
+                cached.add(q[0])
+            if left[1] <= 0:
+                del live[q]
+    return rounds, passed
+
+
+class TestAdmissionPassesOverWaiters:
+    """An admission round fills each free slot with the FIRST queued
+    request that can be admitted now: a follower whose owner is still
+    prefilling keeps its place and is passed over, the requests behind
+    it stop waiting with it.  The engine's rounds are held to the plain
+    model above, request by request."""
+
+    CASES = {
+        # lens, page size, slots, W, max_new
+        # Every prompt fits one page: nothing is shareable, nothing
+        # waits, and the schedule is the replaced rule's.
+        "none-shareable": ((7, 5, 6, 4, 3, 8), 8, 8, 2, 4),
+        # Every prompt is over a page: round 1 admits the four owners
+        # (the replaced rule admitted one) and passes over all twelve
+        # followers; the 30-token prompt prefills for four chunks.
+        "all-shareable": ((30, 21, 17, 12), 8, 8, 2, 4),
+        # q1p5b-serving-waves scaled down: 24 requests over 16 slots,
+        # two of six prompts over a page, the queue's head a waiting
+        # follower, retirement two chunks after the first.
+        "cell-mix": ((27, 10, 8, 6, 5, 4), 8, 16, 4, 64),
+    }
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_rounds_match_the_plain_model(self, cfg, params, mesh, rng, case):
+        lens, ps, n_slots, W, max_new = self.CASES[case]
+        n = 4
+        eng = GeneratorEngine(
+            cfg, params, mesh, eos_token_id=cfg.vocab_size + 7,
+            kv_page_size=ps, prefill_chunk_tokens=W,
+            max_decode_batch=n_slots,
+            # Every prefilling row gets its W lanes (the model's premise).
+            serving_admit_lanes=n_slots * W,
+        )
+        sample = _prompt_sample(rng, cfg, lens)
+        g = GenerationHyperparameters(
+            n=n, max_new_tokens=max_new, greedy=True
+        )
+        chunk_t = min(32, max_new)
+        rounds = []
+        real = eng._take_admits_serving
+
+        def recorded(st):
+            before = {a for a in st.active if a is not None}
+            admitted = real(st)
+            now = [a for a in st.active if a is not None]
+            rounds.append(
+                (sorted(a for a in now if a not in before), len(now))
+            )
+            return admitted
+
+        eng._take_admits_serving = recorded
+        ref, out = _static_and_serving(eng, sample, g)
+        _assert_same_output(ref, out)
+
+        want, passed = _model_rounds(
+            lens, n, n_slots, ps, chunk_t, W, max_new, pass_over=True
+        )
+        old, _ = _model_rounds(
+            lens, n, n_slots, ps, chunk_t, W, max_new, pass_over=False
+        )
+        assert [(sorted(t), l) for t, l in want] == rounds
+        st = eng.last_pool_stats
+        assert st["chunks"] == len(want)
+        assert st["admit_passed_over"] == passed
+        # One miss per owner — a passed-over look costs none — one hit
+        # per follower, no page duplicated or copied.
+        shareable = sum((l - 1) // ps > 0 for l in lens)
+        assert st["prefix_misses"] == shareable
+        assert st["prefix_hits"] == shareable * (n - 1)
+        assert st["cow_copies"] == 0
+        assert st["peak_live_slots"] == max(l for _, l in want)
+        if case == "none-shareable":
+            assert st["admit_passed_over"] == 0
+            assert want == old
+            return
+        assert st["admit_passed_over"] > 0
+        assert len(want) < len(old)
+        assert old[0][1] == 1  # the replaced rule: the owner, alone
+        assert rounds[0][1] == min(
+            n_slots, len(lens) * n - shareable * (n - 1)
+        )
+        if case == "cell-mix":
+            assert rounds[0][1] == n_slots  # every slot, in round 1
+        # No follower joins before its owner registered, and the queue's
+        # head — the longest prompt's first follower, passed over in
+        # round 1 — joins in the first round after that with a free slot.
+        joined = {q: k for k, (took, _) in enumerate(rounds) for q in took}
+        registered = {  # the owner's round + its chunks of prefill
+            i: joined[(i, 0)] + -(-l // (W * chunk_t))
+            for i, l in enumerate(lens) if (l - 1) // ps
+        }
+        for i, k in registered.items():
+            assert all(joined[(i, r)] >= k for r in range(1, n))
+        head = max(registered, key=lambda i: lens[i])
+        assert joined[(head, 1)] == next(
+            k for k in range(registered[head], len(rounds)) if rounds[k][0]
+        )
 
 
 class TestTwoProgramsOnly:
