@@ -687,11 +687,27 @@ def format_flight(trace_dir: str, window_s: float = 10.0) -> str:
         rest = {
             k: v for k, v in ev.items() if k not in ("t_us", "kind")
         }
+        # A host_pause's stacks and frames and a slow_step's spans and
+        # host record are tables: one indented line an entry.
+        nested = {
+            k: rest.pop(k) for k in sorted(rest)
+            if isinstance(rest[k], (dict, list)) and rest[k]
+        }
         detail = " ".join(f"{k}={v}" for k, v in sorted(rest.items()))
         lines.append(
             f"  {(t - fault_us) / 1e6:+9.3f}s {who:<16} "
             f"{ev.get('kind', '?'):<10} {detail}"
         )
+        for k, v in nested.items():
+            if isinstance(v, dict) and not any(
+                isinstance(x, (str, dict, list)) for x in v.values()
+            ):  # a record of numbers (the host's): one line
+                row = " ".join(f"{n}={x:.6g}" for n, x in v.items())
+                lines.append(f"{'':>32}{k}: {row}")
+                continue
+            rows = v.items() if isinstance(v, dict) else enumerate(v)
+            for name, row in rows:
+                lines.append(f"{'':>32}{k}[{name}] {row}")
     return "\n".join(lines)
 
 

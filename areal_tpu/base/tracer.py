@@ -11,9 +11,14 @@ single Perfetto-loadable ``trace.json`` — one track per process, one
 thread lane per tid, counter tracks for sampled gauges.
 
 Design constraints:
-- Near-zero overhead when disabled: ``span()`` costs one dict build, one
-  bool check and one inert profiler annotation; no clock reads, no
-  buffer traffic (PERF.md has the measured nanoseconds per span).
+- Every span has a duration, with no switch: ``span()`` reads the
+  monotonic clock on entry and exit, keeps the per-thread stack of open
+  spans and adds ``(n, total_ns, self_ns)`` under its name to the
+  process's *step ledger*, whether or not ``AREAL_TRACE`` is set.  The
+  ring entry, the shard and the event dict stay behind the switch.  A
+  span with tracing off costs 2.5 microseconds on the chip machine's
+  host (PERF.md section 6, PR 36; 1.5 on the same host before it read
+  a clock): half a millisecond of a step of 200 spans.
 - One clock with the device: every span also opens a
   ``jax.profiler.TraceAnnotation("areal:<name>")`` (the master's step a
   ``StepTraceAnnotation``), whether or not ``AREAL_TRACE`` is set.
@@ -66,22 +71,52 @@ Two planes ride on top of the span stream:
   :func:`validate_trace` rejects child events whose trace_id never
   appears on a root.
 - **Flight recorder**: an always-on bounded ring of recent structured
-  events (span closures when tracing is enabled, plus explicit
-  :func:`flight_event` calls for dispatch decisions, breaker
-  transitions, quarantine verdicts, weight pushes — those record even
-  with ``AREAL_TRACE=0``).  It costs a deque append until a fault:
-  :func:`flight_dump` writes the ring as ``flightrec_<role>_<rank>.json``
-  next to the trace shards for ``trace_report --flight``.
+  events: explicit :func:`flight_event` calls (dispatch decisions,
+  breaker transitions, quarantine verdicts, weight pushes), ``lineage``
+  stamps, and the two kinds this module writes itself, ``host_pause``
+  and ``slow_step`` (below).  Span closures do NOT go into it: 512
+  entries were 2.6 steps of spans and evicted what the ring is for; the
+  step ledger is the record of what the last steps spent.  It costs a
+  deque append until a fault: :func:`flight_dump` writes the ring as
+  ``flightrec_<role>_<rank>.json`` next to the trace shards for
+  ``trace_report --flight``.
+
+Always on, with no switch (PR 36) — what a step that ran long has to
+say for itself:
+
+- **Step ledger**: :func:`close_step` (the master calls it at the end of
+  each step, a worker in a process of its own when the master clears
+  its caches) moves what every thread's spans added since the last
+  close into one record ``{step, wall_s, spans: {name: (n, total_s,
+  self_s)}, host}``; the last LEDGER_STEPS records stay in memory
+  (:func:`step_ledger`).  Self time is duration minus what child spans
+  on the same thread cover.
+- **Host watch** (``base/hostwatch.py``, started by :func:`configure`,
+  one per process): per step, seconds a 20 ms ticker woke > 100 ms late,
+  the collector's seconds, run-queue wait against CPU seconds over all
+  threads, involuntary switches, page faults and the host's pressure
+  totals.  A late wake writes a ``host_pause`` flight event carrying
+  ``late_ms``, the span stack open on every thread and every thread's
+  innermost Python frame, a ``host_pause`` span into the ring (tracing
+  on) and an ``areal:host_pause`` annotation for a live profiler.
+- **Slow step**: a step whose wall lies over the median of the last
+  <= 8 by max(0.1 s, 3%) gets a ``slow_step`` flight event naming every
+  span whose self seconds grew by > 10 ms over that span's own median,
+  with the host record beside it; :func:`close_step` returns the step
+  stats ``host/<key>`` and ``time/slow_excess_s``.
 """
 
 import atexit
 import collections
 import json
 import os
+import statistics
 import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+from areal_tpu.base import hostwatch
 
 # Per-thread ring capacity.  A step emits O(100) events per process;
 # 65536 absorbs many steps between flushes before dropping the oldest.
@@ -92,10 +127,26 @@ _RING_CAP = 65536
 # enough context around a fault instant without unbounded memory.
 _FLIGHT_CAP = 512
 
+# Step ledger: closed steps kept in memory, and what makes a step slow:
+# its wall over the median of the last SLOW_WINDOW closed steps (at least
+# SLOW_MIN_HISTORY of them) by max(SLOW_ABS_S, SLOW_REL of that median).
+# Dense steps repeat to 0.01-0.1% and a MoE cell drifts 0.8% a step
+# downward, so neither is flagged; a 0.13 s pause in a 2.6 s step is.
+LEDGER_STEPS = 64
+SLOW_WINDOW = 8
+SLOW_MIN_HISTORY = 3
+SLOW_ABS_S = 0.1
+SLOW_REL = 0.03
+GROWN_SELF_S = 0.01  # a span "grew": self seconds this far over its median
+DUMP_EVERY_STEPS = 50  # flight_dump("slow_step") at most this often
+
 _lock = threading.Lock()
 _buffers: List[collections.deque] = []  # every thread's ring, for flush
 _flight: collections.deque = collections.deque(maxlen=_FLIGHT_CAP)
 _tls = threading.local()
+_steps: collections.deque = collections.deque(maxlen=LEDGER_STEPS)
+_watch: Optional[hostwatch.HostWatch] = None
+_ledger_state: Dict[str, Any] = {"t_close_ns": None, "dump_step": None}
 
 _state: Dict[str, Any] = {
     "enabled": False,
@@ -152,6 +203,7 @@ def configure(
         _state["path"] = (
             os.path.join(d, f"trace_{role}_{rank}.jsonl") if d else None
         )
+        _start_watch_locked()
         return _state["enabled"]
 
 
@@ -212,25 +264,53 @@ def _annotate(name: str, args: Dict):
     return classes and classes[0]("areal:" + name, **args)
 
 
-def _stack() -> list:
-    st = getattr(_tls, "stack", None)
-    if st is None:
-        st = _tls.stack = []
-    return st
+class _ThreadState:
+    """One thread's open spans and what its closed spans added to the
+    step ledger since the last close_step."""
+
+    __slots__ = ("thread", "stack", "ledger")
+
+    def __init__(self):
+        self.thread = threading.current_thread()
+        self.stack: list = []
+        self.ledger: Dict[str, list] = {}  # name -> [n, total_ns, self_ns]
+
+
+_threads: Dict[int, _ThreadState] = {}  # by thread ident, for the watch
+
+
+def _thread_state() -> _ThreadState:
+    try:
+        return _tls.ts
+    except AttributeError:
+        ts = _tls.ts = _ThreadState()
+        with _lock:
+            _threads[threading.get_ident()] = ts
+        return ts
 
 
 class _Span:
-    __slots__ = ("name", "cat", "args", "t0", "ann", "parent")
+    """Always timed: clock reads, the thread's stack and the step ledger
+    with or without AREAL_TRACE; the event dict, the ring and the shard
+    only with it."""
+
+    __slots__ = (
+        "name", "cat", "args", "t0", "ann", "parent", "child_ns", "self_ns",
+        "ts",
+    )
 
     def __init__(self, name: str, cat: Optional[str], args: Dict, ann):
         self.name = name
         self.cat = cat
         self.args = args
         self.ann = ann
+        self.child_ns = 0
+        self.self_ns = 0
 
     def __enter__(self) -> Dict:
-        st = _stack()
-        self.parent = st[-1].name if st else None
+        ts = self.ts = _thread_state()
+        st = ts.stack
+        self.parent = st[-1] if st else None
         st.append(self)
         if self.ann is not None:
             self.ann.__enter__()
@@ -241,68 +321,50 @@ class _Span:
         t1 = time.monotonic_ns()
         if self.ann is not None:
             self.ann.__exit__(*exc)
-        st = _stack()
+        ts = self.ts
+        st = ts.stack
         if st and st[-1] is self:
             st.pop()
         elif self in st:  # coroutines on one thread close out of order
             st.remove(self)
-        ev = {
-            "ph": "X",
-            "name": self.name,
-            "ts": self.t0 // 1000,
-            "dur": max((t1 - self.t0) // 1000, 1),
-            "tid": threading.get_ident(),
-            # The caller's own dict, also when still empty: values written
-            # after the block (the MFC's token counts) reach the shard.
-            "args": self.args,
-        }
-        if self.cat:
-            ev["cat"] = self.cat
-        if self.parent:
-            ev["parent"] = self.parent
-        _buf().append(ev)
-        _flight.append(
-            {
-                "t_us": int(time.time() * 1e6),
-                "kind": "span",
+        dur = t1 - self.t0
+        parent = self.parent
+        if parent is not None:
+            parent.child_ns += dur
+        # Coroutines interleaved on one thread overlap without nesting:
+        # their "children" can cover more than the span itself.
+        self.self_ns = own = max(dur - self.child_ns, 0)
+        row = ts.ledger.get(self.name)
+        if row is None:
+            ts.ledger[self.name] = [1, dur, own]
+        else:
+            row[0] += 1
+            row[1] += dur
+            row[2] += own
+        if _state["enabled"]:
+            ev = {
+                "ph": "X",
                 "name": self.name,
-                "dur_us": ev["dur"],
-                "tid": ev["tid"],
+                "ts": self.t0 // 1000,
+                "dur": max(dur // 1000, 1),
+                "tid": threading.get_ident(),
+                # The caller's own dict, also when still empty: values
+                # written after the block (the MFC's token counts) reach
+                # the shard.
+                "args": self.args,
             }
-        )
+            if self.cat:
+                ev["cat"] = self.cat
+            if parent is not None:
+                ev["parent"] = parent.name
+            _buf().append(ev)
         return False
 
 
-class _NoopSpan:
-    """Disabled-path span: no clock read, no ring entry.  __enter__ hands
-    back the caller's own args dict so post-hoc ``args[...] = v`` writes
-    stay valid and cheap; the annotation is all that runs."""
-
-    __slots__ = ("args", "ann")
-
-    def __init__(self, args: Dict, ann):
-        self.args = args
-        self.ann = ann
-
-    def __enter__(self) -> Dict:
-        if self.ann is not None:
-            self.ann.__enter__()
-        return self.args
-
-    def __exit__(self, *exc) -> bool:
-        if self.ann is not None:
-            self.ann.__exit__(*exc)
-        return False
-
-
-def _span(name: str, cat: Optional[str], args: Dict, ann) -> Any:
-    if not _state["enabled"]:
-        return _NoopSpan(args, ann)
-    return _Span(name, cat, args, ann)
-
-
-def span(name: str, cat: Optional[str] = None, **args) -> Any:
-    return _span(name, cat, args, _annotate(name, args))
+def span(name: str, cat: Optional[str] = None, **args) -> _Span:
+    """A timed span.  After the block, ``self_ns`` on the returned object
+    is its duration minus what child spans on the same thread covered."""
+    return _Span(name, cat, args, _annotate(name, args))
 
 
 def step_span(step: int) -> Any:
@@ -311,7 +373,7 @@ def step_span(step: int) -> Any:
     training steps."""
     classes = _annotations()
     ann = classes and classes[1]("areal:step", step_num=int(step))
-    return _span("step", None, {"step": int(step)}, ann)
+    return _Span("step", None, {"step": int(step)}, ann)
 
 
 def trace(name: Optional[str] = None, cat: Optional[str] = None):
@@ -386,7 +448,7 @@ def complete(
         ev["cat"] = cat
     if args:
         ev["args"] = args
-    st = _stack()
+    st = _thread_state().stack
     if st:
         ev["parent"] = st[-1].name
     _buf().append(ev)
@@ -512,6 +574,173 @@ def read_flight_dumps(trace_dir: str) -> List[Dict[str, Any]]:
     return dumps
 
 
+# ---------------- step ledger, host watch, slow steps ----------------
+
+
+def _start_watch_locked() -> None:
+    global _watch
+    if _watch is None:
+        _watch = hostwatch.HostWatch(on_pause=_on_host_pause)
+        _ledger_state["t_close_ns"] = time.monotonic_ns()
+
+
+def role() -> Optional[str]:
+    """The identity this process was configured with (first one wins):
+    a worker whose process answers "master" shares the master's ledger
+    and host watch."""
+    return _state["role"]
+
+
+def open_spans() -> Dict[str, List[str]]:
+    """The names of the spans open on every thread, outermost first."""
+    out = {}
+    for ts in list(_threads.values()):
+        names = [sp.name for sp in list(ts.stack)]
+        if names:
+            out[ts.thread.name] = names
+    return out
+
+
+_LIBRARY = os.sep + "lib" + os.sep + "python"  # stdlib and site-packages
+
+
+def _frame_label(frame) -> str:
+    code = frame.f_code
+    where = os.sep.join(code.co_filename.split(os.sep)[-2:])
+    return f"{where}:{frame.f_lineno} {code.co_name}"
+
+
+def _thread_frames() -> Dict[str, str]:
+    """Every Python thread's innermost frame, as "file:line function";
+    where that lies in a library, also (after " < ") the nearest caller
+    that does not."""
+    names = {t.ident: t.name for t in threading.enumerate()}
+    me = threading.get_ident()
+    out = {}
+    for ident, frame in sys._current_frames().items():
+        if ident == me:
+            continue
+        label = _frame_label(frame)
+        caller = frame
+        while caller is not None and _LIBRARY in caller.f_code.co_filename:
+            caller = caller.f_back
+        if caller is not None and caller is not frame:
+            label += " < " + _frame_label(caller)
+        out[names.get(ident, str(ident))] = label
+    return out
+
+
+def _on_host_pause(due_ns: int, woke_ns: int, cpu_ns: int = 0) -> None:
+    """The watch's ticker woke late: say where every thread stood, and
+    how much CPU the process used while it slept (``cpu_ms``: none means
+    the process was not scheduled, the usual rate or more that threads of
+    ours ran and the ticker could not).  Runs on the ticker's thread, at
+    the wake."""
+    late_ms = round((woke_ns - due_ns) / 1e6, 3)
+    ann = _annotate("host_pause", {"late_ms": late_ms})
+    if ann:
+        # The pause is [wake - late_ms, wake] on the profiler's clock.
+        with ann:
+            pass
+    flight_event(
+        "host_pause", late_ms=late_ms, cpu_ms=round(cpu_ns / 1e6, 3),
+        stacks=open_spans(), frames=_thread_frames(),
+    )
+    complete("host_pause", due_ns, woke_ns, cat="host", late_ms=late_ms)
+
+
+def host_take() -> Dict[str, float]:
+    """The host watch's record since the last take, as ``host/<key>``
+    stats (a worker in a process of its own adds them to its reply)."""
+    if _watch is None:
+        return {}
+    return {f"host/{k}": v for k, v in _watch.take().items()}
+
+
+def close_step(step: int, wall_s: Optional[float] = None) -> Dict[str, float]:
+    """Close the step ledger: everything the process's spans added since
+    the last close becomes one record (``step_ledger()`` keeps the last
+    LEDGER_STEPS), the host watch hands over its record, and a step that
+    ran long writes its ``slow_step`` flight event.  ``wall_s`` defaults
+    to the seconds since the last close.  Returns the step stats:
+    ``host/<key>`` and ``time/slow_excess_s`` (0 for a step not
+    flagged)."""
+    now = time.monotonic_ns()
+    if wall_s is None:
+        wall_s = (now - (_ledger_state["t_close_ns"] or now)) / 1e9
+    _ledger_state["t_close_ns"] = now
+    spans: Dict[str, list] = {}
+    with _lock:
+        states = list(_threads.items())
+    for ident, ts in states:
+        closed, ts.ledger = ts.ledger, {}
+        for name, (n, total, own) in list(closed.items()):
+            row = spans.setdefault(name, [0, 0, 0])
+            row[0] += n
+            row[1] += total
+            row[2] += own
+        if not closed and not ts.stack and not ts.thread.is_alive():
+            with _lock:
+                _threads.pop(ident, None)
+    host = _watch.take() if _watch is not None else {}
+    record = {
+        "step": int(step),
+        "t_us": int(time.time() * 1e6),  # the flight events' clock
+        "wall_s": float(wall_s),
+        "spans": {
+            name: (n, total / 1e9, own / 1e9)
+            for name, (n, total, own) in spans.items()
+        },
+        "host": host,
+    }
+    excess = _judge_step(record)
+    _steps.append(record)
+    stats = {f"host/{k}": v for k, v in host.items()}
+    stats["time/slow_excess_s"] = excess
+    return stats
+
+
+def _judge_step(record: Dict[str, Any]) -> float:
+    """Seconds this step's wall lies over its neighbours' median, or 0.0
+    where that is not enough to call it slow; a slow step gets its flight
+    event and, at most every DUMP_EVERY_STEPS, a dump."""
+    history = list(_steps)[-SLOW_WINDOW:]
+    if len(history) < SLOW_MIN_HISTORY:
+        return 0.0
+    median = statistics.median(h["wall_s"] for h in history)
+    excess = record["wall_s"] - median
+    if excess < max(SLOW_ABS_S, SLOW_REL * median):
+        return 0.0
+    grown = []
+    for name, (n, _, own) in record["spans"].items():
+        usual = statistics.median(
+            h["spans"].get(name, (0, 0.0, 0.0))[2] for h in history
+        )
+        if own - usual > GROWN_SELF_S:
+            grown.append({
+                "name": name, "self_s": round(own, 6),
+                "median_self_s": round(usual, 6), "n": n,
+            })
+    grown.sort(key=lambda g: g["median_self_s"] - g["self_s"])
+    flight_event(
+        "slow_step", step=record["step"], wall_s=round(record["wall_s"], 6),
+        median_s=round(median, 6), excess_s=round(excess, 6),
+        host=record["host"], spans=grown,
+    )
+    last = _ledger_state["dump_step"]
+    if last is None or record["step"] - last >= DUMP_EVERY_STEPS:
+        if flight_dump("slow_step") is not None:
+            _ledger_state["dump_step"] = record["step"]
+    return excess
+
+
+def step_ledger() -> List[Dict[str, Any]]:
+    """The last LEDGER_STEPS closed steps, oldest first: ``step``, ``t_us``
+    (epoch microseconds at the close), ``wall_s``, ``spans`` (name -> (n,
+    total_s, self_s)) and ``host``."""
+    return list(_steps)
+
+
 # ---------------- flush / shard IO ----------------
 
 
@@ -575,9 +804,14 @@ def flush() -> Optional[str]:
 
 
 def _reset_for_tests() -> None:
-    """Disable tracing and drop all buffered events/identity (test
-    isolation; not part of the public surface)."""
+    """Disable tracing, stop the host watch and drop all buffered
+    events, closed steps and identity (test isolation; not part of the
+    public surface)."""
+    global _watch
     with _lock:
+        if _watch is not None:
+            _watch.stop()
+            _watch = None
         _close_file_locked()
         _state.update(
             enabled=False,
@@ -591,7 +825,11 @@ def _reset_for_tests() -> None:
         for b in _buffers:
             b.clear()
         _flight.clear()
-    _tls.stack = []
+        _steps.clear()
+        _ledger_state.update(t_close_ns=None, dump_step=None)
+        for ts in _threads.values():
+            ts.stack.clear()
+            ts.ledger.clear()
 
 
 atexit.register(flush)

@@ -315,12 +315,6 @@ class TrainEngine(HostOffloadMixin, Engine):
             "compiles": compiles,
         }
 
-    def _head_counter(self) -> Dict[str, float]:
-        """Whether the vocabulary-parallel head engaged, to the tracer's
-        `head` counter track and into the step's train stats."""
-        tracer.counter("head", vocab_shards=self.head_vocab_shards)
-        return {"head/vocab_shards": float(self.head_vocab_shards)}
-
     # ---------------- core jitted fns ----------------
 
     def _pack_row_chunks(self, arrays):
@@ -679,7 +673,7 @@ class TrainEngine(HostOffloadMixin, Engine):
             "anomaly_verdict": verdict,
             "quarantined": 1.0 if verdict else 0.0,
             "n_micro_batches": float(len(chunks)),
-            **self._head_counter(),
+            "head/vocab_shards": float(self.head_vocab_shards),
         }
         for i, k in enumerate(keys):
             v = float(host[4 + i])
@@ -866,7 +860,7 @@ class TrainEngine(HostOffloadMixin, Engine):
             "quarantined": 1.0 if (verdict or quarantine) else 0.0,
             "n_micro_batches": float(state["n_micro_batches"]),
             "n_stream_chunks": float(state["n_chunks"]),
-            **self._head_counter(),
+            "head/vocab_shards": float(self.head_vocab_shards),
         }
         for k, v in state["stat_sums"].items():
             if k.endswith("_sum"):

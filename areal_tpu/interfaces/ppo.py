@@ -28,7 +28,7 @@ from areal_tpu.api.model_api import (
     ModelInterface,
     register_interface,
 )
-from areal_tpu.base import integrity, logging
+from areal_tpu.base import integrity, logging, tracer
 from areal_tpu.base.stats import merge_stats
 from areal_tpu.ops import functional as F
 from areal_tpu.ops.gae import gae_packed
@@ -549,15 +549,17 @@ class PPOActorInterface(ModelInterface):
                 lens_resp.append(n)
             if r_parts:
                 r1 = np.concatenate(r_parts)
-                adv1, ret1 = gae_packed(
-                    jnp.asarray(r1),
-                    jnp.asarray(np.concatenate(v_parts)),
-                    jnp.asarray(np.concatenate(seg_parts)),
-                    jnp.asarray(np.concatenate(boot_parts)),
-                    self.discount,
-                    self.gae_lambda,
-                )
-                adv1 = np.asarray(adv1)
+                # Upload, the `ppo/gae` program and the wait for it.
+                with tracer.span("gae", cat="compute"):
+                    adv1, ret1 = gae_packed(
+                        jnp.asarray(r1),
+                        jnp.asarray(np.concatenate(v_parts)),
+                        jnp.asarray(np.concatenate(seg_parts)),
+                        jnp.asarray(np.concatenate(boot_parts)),
+                        self.discount,
+                        self.gae_lambda,
+                    )
+                    adv1 = np.asarray(adv1)
                 off = 0
                 for si, (lo, hi) in enumerate(seq_slices):
                     n = hi - lo
@@ -663,9 +665,10 @@ class PPOActorInterface(ModelInterface):
     def train_step(
         self, model: Model, sample: SequenceSample, mb_spec: MicroBatchSpec
     ) -> Dict[str, float]:
-        train_sample, extra_keys, aux = self._prepare_train_sample(
-            model, sample, mb_spec
-        )
+        with tracer.span("ppo_prepare", cat="host"):
+            train_sample, extra_keys, aux = self._prepare_train_sample(
+                model, sample, mb_spec
+            )
         loss_mask = aux["loss_mask"]
         old_logp, ref_logp = aux["old_logp"], aux["ref_logp"]
 
@@ -698,9 +701,10 @@ class PPOActorInterface(ModelInterface):
         loss_fn = self._get_loss_fn()
         all_stats = []
         n_skipped = 0
-        mbs_list = train_sample.split_balanced(
-            min(self.n_minibatches, train_sample.bs)
-        )
+        with tracer.span("mb_split", cat="host"):
+            mbs_list = train_sample.split_balanced(
+                min(self.n_minibatches, train_sample.bs)
+            )
         for mi, mb in enumerate(mbs_list):
             stats = model.engine.train_batch(
                 mb,

@@ -17,7 +17,7 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from areal_tpu.api.data_api import MicroBatchSpec, SequenceSample
-from areal_tpu.base import logging
+from areal_tpu.base import logging, tracer
 from areal_tpu.api.model_api import Model, ModelInterface, register_interface
 
 logger = logging.getLogger("reward")
@@ -83,10 +83,35 @@ class MultiTaskRewardInterface(ModelInterface):
         reference's rw interface also runs verification, not a model)."""
         tokenizer = model.tokenizer if model is not None else None
         assert tokenizer is not None, "reward interface needs a tokenizer"
+        seqlens_r: List[List[int]] = []
+        with tracer.span("reward_decode", cat="host"):
+            todo = self._decode_responses(sample, tokenizer, seqlens_r)
+        with tracer.span("reward_verify", cat="host", n=len(todo)):
+            oks = self._verify_all(todo)
+        n_correct = sum(map(int, oks))
+        rewards = [
+            self.reward_value if ok else -self.reward_value for ok in oks
+        ]
+        logger.info(
+            f"reward verification: {n_correct}/{len(rewards)} correct"
+        )
+        return SequenceSample(
+            keys={"rewards"},
+            ids=list(sample.ids),
+            seqlens={"rewards": seqlens_r},
+            data={"rewards": np.asarray(rewards, np.float32)},
+            metadata={},
+        )
+
+    def _decode_responses(
+        self, sample: SequenceSample, tokenizer, seqlens_r: List[List[int]]
+    ) -> List[Dict[str, Any]]:
+        """One verifier item per sequence (its response's text and the
+        row's payload); appends each group's reward lengths to
+        `seqlens_r`."""
         tokens = np.asarray(sample.data["packed_input_ids"])
         pmask = np.asarray(sample.data["prompt_mask"])
         bounds = sample.cu_seqlens("packed_input_ids")
-        seqlens_r: List[List[int]] = []
         todo: List[Dict[str, Any]] = []
         si = 0
         for ei, group in enumerate(sample.seqlens["packed_input_ids"]):
@@ -115,33 +140,22 @@ class MultiTaskRewardInterface(ModelInterface):
                     }
                 )
                 si += 1
+        return todo
+
+    def _verify_all(self, todo: List[Dict[str, Any]]) -> List[bool]:
+        """Dispatch to the configured verifier and wait for every verdict."""
         if self.verifier_pool:
-            oks = self._verifier_pool().verify_batch(todo)
-        elif self.remote_url:
+            return self._verifier_pool().verify_batch(todo)
+        if self.remote_url:
             from areal_tpu.interfaces.reward_service import RemoteVerifier
 
-            oks = RemoteVerifier(
+            return RemoteVerifier(
                 self.remote_url, timeout_s=self.remote_timeout_s
             ).verify_batch(todo)
-        else:
-            oks = [
-                self.verify(it["task"], it["text"], it["payload"])
-                for it in todo
-            ]
-        n_correct = sum(map(int, oks))
-        rewards = [
-            self.reward_value if ok else -self.reward_value for ok in oks
+        return [
+            self.verify(it["task"], it["text"], it["payload"])
+            for it in todo
         ]
-        logger.info(
-            f"reward verification: {n_correct}/{len(rewards)} correct"
-        )
-        return SequenceSample(
-            keys={"rewards"},
-            ids=list(sample.ids),
-            seqlens={"rewards": seqlens_r},
-            data={"rewards": np.asarray(rewards, np.float32)},
-            metadata={},
-        )
 
     def _verifier_pool(self):
         """Lazily build (and cache) the fleet-discovering pool client —

@@ -872,6 +872,7 @@ def _period_blocks(
     return x, auxes, _all_layers(cfg, counts)
 
 
+@jax.named_scope("head_logprob")
 def _head(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     if cfg.is_critic:
         v = jnp.einsum(

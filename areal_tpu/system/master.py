@@ -533,6 +533,10 @@ class MasterWorker:
         )
         if self._restore_pending:
             await self._restore_worker_state()
+        # A step's wall for the ledger runs from close to close, so what
+        # happens between two steps (checkpoints, a hook of the caller's
+        # on the stats logger) lands in the step that follows it.
+        t_closed = time.monotonic()
         try:
             while self.step_info.global_step < total_steps:
                 t0 = time.monotonic()
@@ -547,8 +551,15 @@ class MasterWorker:
                 except WorkerDeadError as e:
                     await self._recover_from_worker_death(e)
                     continue
-                dt = time.monotonic() - t0
+                now = time.monotonic()
+                dt = now - t0
                 stats["time/step_s"] = dt
+                # host/<key> and time/slow_excess_s: what the host did to
+                # this step, and whether it ran long (base/tracer.py).
+                stats.update(
+                    tracer.close_step(self.pool.step, now - t_closed)
+                )
+                t_closed = now
                 self._export_step_metrics(stats, dt)
                 quarantined = self._note_quarantine(stats)
                 self.stats_history.append(stats)

@@ -526,22 +526,24 @@ class ModelWorker:
         # real values live on the processes whose devices consume them —
         # identical PACK layout everywhere, local VALUES only where they
         # land; see api/dfg.py MFCDef.shard_keys).
-        sample = self._assemble_sample(
-            ids,
-            set(req["input_keys"]),
-            req.get("shard_of") or {},
-            req.get("shard_meta"),
-            req.get("input_key_remap", {}),
-        )
+        with tracer.span("mfc_gather", cat="host", **_step(req)):
+            sample = self._assemble_sample(
+                ids,
+                set(req["input_keys"]),
+                req.get("shard_of") or {},
+                req.get("shard_meta"),
+                req.get("input_key_remap", {}),
+            )
 
         model = self.models[model_key]
         interface = self.interfaces[model_key]
         fn = getattr(interface, itype.value)
         _take_compiles()  # executor threads are reused: start from zero
-        with tracer.span(
+        mfc_span = tracer.span(
             f"mfc:{model_key}:{itype.value}", cat="compute",
             **_step(req),
-        ) as targs:
+        )
+        with mfc_span as targs:
             with self.timers.record(f"mfc_{itype.value}"):
                 t0 = time.monotonic()
                 # Env-gated xprof capture per MFC (reference: REAL_DUMP_TRACE
@@ -573,7 +575,10 @@ class ModelWorker:
             out_sample = result if isinstance(result, SequenceSample) else None
             if out_sample is not None:
                 out_sample.remap_keys_(remap_out)
-            perf = self._mfc_perf(model, itype, sample, out_sample, mfc_seconds)
+            with tracer.span("mfc_perf", cat="host"):
+                perf = self._mfc_perf(
+                    model, itype, sample, out_sample, mfc_seconds
+                )
             perf.update(_take_compiles())
             perf.update(self.timers.drain())
             mfc_label = f"{model_key}:{itype.value}"
@@ -603,15 +608,29 @@ class ModelWorker:
                     targs["mfu"] = perf["perf/mfu"]
                 self._span_profile_fields(model_key, model, targs)
 
+        # Seconds of the handler's own: the mfc span under no child span.
+        perf["perf/self_s"] = mfc_span.self_ns / 1e9
+        perf.update(self._own_host_record())
         if out_sample is not None:
-            for one in out_sample.unpack():
-                sid = one.ids[0]
-                if sid in self.data_cache:
-                    self.data_cache[sid].update_(one)
-                else:
-                    self.data_cache[sid] = one
-            return {"meta": out_sample.meta(), "stats": perf}
+            with tracer.span("mfc_scatter", cat="host", **_step(req)):
+                for one in out_sample.unpack():
+                    sid = one.ids[0]
+                    if sid in self.data_cache:
+                        self.data_cache[sid].update_(one)
+                    else:
+                        self.data_cache[sid] = one
+                meta = out_sample.meta()
+            return {"meta": meta, "stats": perf}
         return {"meta": None, "stats": {**dict(result or {}), **perf}}
+
+    @staticmethod
+    def _own_host_record() -> Dict[str, float]:
+        """`host/<key>` stats for an MFC's reply where this worker runs in
+        a process of its own; under the master's roof the master's step
+        close reports the one host watch they share."""
+        if tracer.role() == "master":
+            return {}
+        return tracer.host_take()
 
     # ------------- pipeline-overlapped train stream -------------
     #
@@ -719,10 +738,11 @@ class ModelWorker:
         interface = self.interfaces[model_key]
         mb_spec: MicroBatchSpec = req.get("mb_spec") or MicroBatchSpec()
         _take_compiles()
-        with tracer.span(
+        mfc_span = tracer.span(
             f"mfc:{model_key}:train_step", cat="compute",
             **_step(req),
-        ) as targs:
+        )
+        with mfc_span as targs:
             with self.timers.record("mfc_train_step"):
                 t0 = time.monotonic()
                 result = interface.train_stream_end(
@@ -730,7 +750,8 @@ class ModelWorker:
                 )
                 seconds = time.monotonic() - t0
         busy = st["busy_s"] + seconds
-        perf = {"perf/time_s": busy}
+        perf = {"perf/time_s": busy, "perf/self_s": mfc_span.self_ns / 1e9}
+        perf.update(self._own_host_record())
         for k, v in _take_compiles().items():
             perf[k] = st["compiles"].get(k, 0.0) + v
         try:
@@ -1169,7 +1190,12 @@ class ModelWorker:
             if sid not in keep:
                 del self.data_cache[sid]
         # Once-per-step broadcast from the master: a natural trace flush
-        # point so shards stay current even if the worker later crashes.
+        # point so shards stay current even if the worker later crashes,
+        # and where a worker in a process of its own closes its step
+        # ledger (under the master's roof the master closes the one they
+        # share).
+        if tracer.role() != "master":
+            tracer.close_step(req.get("step", 0))
         tracer.flush()
         return {}
 

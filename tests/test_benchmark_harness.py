@@ -1,7 +1,9 @@
 """The benchmark harness inside tier-1 (`tests/` is all tier-1 collects;
 `benchmark/tests/` is the harness's own suite): the any-block cases of
-`benchmark/tests/test_any_block.py` by import, BENCHMARK.json against the
-files it names, the new MoE readers on a made-up reduction, and the CPU
+`benchmark/tests/test_any_block.py` and of
+`benchmark/tests/test_ledger_readers.py` (the readers of the program's own
+host watch, step ledger and counters, PR 36) by import, BENCHMARK.json
+against the files it names, the new MoE readers on a made-up reduction, and the CPU
 rehearsal of the OLMoE cell through its config file's `toy` group."""
 
 import json
@@ -19,6 +21,7 @@ from benchmark.metrics import (
 )
 from benchmark.tests.test_any_block import *  # noqa: F401,F403 — the cases
 from benchmark.tests.test_any_block import OLMOE
+from benchmark.tests.test_ledger_readers import *  # noqa: F401,F403 — the cases
 
 SPEC = files.benchmark_json()
 CELLS = [w["name"] for w in SPEC["workloads"]]

@@ -179,6 +179,36 @@ class TestPPOMathExperiment:
             assert np.isfinite(s["critic_train/value_loss"])
         # Ratio sanity on the on-policy first step.
         assert abs(stats[0]["actor_train/importance_weight"] - 1.0) < 5e-2
+        # Every step says what the host did to it and whether it ran long
+        # (base/tracer.close_step, no switch), and every node what of its
+        # handler no inner span covers.
+        from areal_tpu.base import tracer
+
+        for s in stats:
+            for key in ("host/late_s", "host/late_max_s", "host/gc_s",
+                        "host/gc_gen2", "host/read_s", "time/slow_excess_s"):
+                assert isinstance(s[key], float) and s[key] >= 0, key
+            # In the master's process the one host watch reports once.
+            assert not [k for k in s if "/host/" in k]
+            for node in ("actor_gen", "rew_inf", "actor_train"):
+                assert 0 <= s[f"{node}/perf/self_s"] < s[f"{node}/perf/time_s"]
+        closed = tracer.step_ledger()[-2:]
+        assert [c["step"] for c in closed] == [1, 2]
+        for c, s in zip(closed, stats):
+            assert c["wall_s"] >= s["time/step_s"]
+            names = set(c["spans"])
+            assert {"step", "load_data", "mfc:actor_gen", "mfc_gather",
+                    "mfc_scatter", "mfc_perf", "ppo_prepare", "mb_split",
+                    "reward_decode", "reward_verify", "pack",
+                    "stats_sync"} <= names, names
+            if mode == "value":
+                assert "gae" in names
+            # The handler's self time is its span's, from the same ledger.
+            (mfc,) = [k for k in names if k.startswith("mfc:actor@")
+                      and k.endswith("train_step")]
+            assert c["spans"][mfc][2] == pytest.approx(
+                s["actor_train/perf/self_s"], abs=1e-9
+            )
 
     def test_ppo_offload_and_difficulty_filter(self, tmp_path):
         """OffloadHook frees the ref model after each ref_inf call (it
@@ -265,7 +295,8 @@ class TestPPOMathExperiment:
             tokenizer=tok,
         )
         for k, v in stats1[-1].items():
-            if "perf/" in k or "time/" in k or "/sync/" in k:
+            if ("perf/" in k or "time/" in k or "/sync/" in k
+                    or k.startswith("host/")):  # the host's own record
                 continue
             assert np.isclose(stats[-1][k], v, rtol=1e-3, atol=1e-5), (
                 k, stats[-1][k], v,
@@ -340,7 +371,8 @@ class TestPPOMathExperiment:
         )
         for k, v in stats1[-1].items():
             # wall-clock differs by layout; a colocated sync reports its own
-            if "perf/" in k or "time/" in k or "/sync/" in k:
+            if ("perf/" in k or "time/" in k or "/sync/" in k
+                    or k.startswith("host/")):  # the host's own record
                 continue
             assert np.isclose(stats[-1][k], v, rtol=1e-3, atol=1e-5), (
                 k, stats[-1][k], v,
@@ -558,7 +590,8 @@ class TestGlobalReshard:
             tokenizer=tok,
         )
         for k, v in stats1[-1].items():
-            if "perf/" in k or "time/" in k or "/sync/" in k:
+            if ("perf/" in k or "time/" in k or "/sync/" in k
+                    or k.startswith("host/")):  # the host's own record
                 continue
             assert np.isclose(stats[-1][k], v, rtol=1e-3, atol=1e-5), (
                 k, stats[-1][k], v,

@@ -441,7 +441,7 @@ def test_serving_chunk_program_carries_every_scope(trial):
     )
 
 
-def test_static_generate_program_carries_every_scope():
+def _static_generate_text():
     from areal_tpu.api.data_api import MicroBatchSpec, SequenceSample
     from areal_tpu.api.model_api import GenerationHyperparameters
     from areal_tpu.base.topology import ParallelConfig, make_mesh
@@ -467,10 +467,43 @@ def test_static_generate_program_carries_every_scope():
         GenerationHyperparameters(n=1, max_new_tokens=4), inflight=False,
     )
     assert eng.last_pool_stats.get("chunks") is None  # not the serving loop
+    return spies["_get_gen_fn"][0].text()
+
+
+def test_static_generate_program_carries_every_scope():
     _assert_scopes(
-        spies["_get_gen_fn"][0].text(),
+        _static_generate_text(),
         ("gen/prefill", "gen/decode_step") + MODEL_SCOPES,
     )
+
+
+def _head_locations(text):
+    """The `loc(...)` names of the operations that make the `[*, V]`
+    logits: the einsum of `transformer._head`."""
+    import re
+
+    return [
+        m.group(1) for m in re.finditer(r'loc\("([^"]*bsd,dv->bsv[^"]*)"', text)
+    ]
+
+
+@pytest.mark.parametrize("program", ["static", "serving"])
+def test_decode_head_lowers_under_head_logprob(program, trial):
+    """The decode head (`transformer._head` of `prefill`, `decode_step`
+    and the serving chunk) carries the scope `head_logprob`, as the train
+    head and the sampler do: `head_share` measures both heads."""
+    if program == "serving":
+        text = trial["spies"]["_get_serving_chunk_fn"][0].text()
+        want = ("gen/decode_step/",)  # the chunk's own decode step
+    else:
+        text = _static_generate_text()
+        want = ("gen/prefill/", "gen/decode_step/")
+    locs = _head_locations(text)
+    assert locs, "no logits einsum in the lowered program"
+    for loc in locs:
+        assert "/head_logprob/" in loc, loc
+    for scope in want:
+        assert any(scope in loc for loc in locs), (scope, locs)
 
 
 def test_gae_program_is_scoped():

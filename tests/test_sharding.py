@@ -261,12 +261,12 @@ def test_one_device_grad_program_is_the_parents(name):
     ids=["one-device", "f4", "f4-indivisible"],
 )
 def test_head_counter_says_how_the_vocabulary_was_split(
-        mode, vocab, shards, tmp_path, rng):
-    """The train stats and the tracer's `head` counter carry vocab_shards:
-    1 on one device, 4 under f4, and 1 — the stored layout, no error —
-    where V does not divide by model x fsdp."""
+        mode, vocab, shards, rng):
+    """The train stats carry vocab_shards: 1 on one device, 4 under f4,
+    and 1 — the stored layout, no error — where V does not divide by
+    model x fsdp.  (The tracer's `head` counter track, which nobody
+    read, went in PR 36.)"""
     from areal_tpu.api.data_api import MicroBatchSpec
-    from areal_tpu.base import tracer
     from areal_tpu.ops import functional as F
     from tests import fixtures
 
@@ -279,17 +279,9 @@ def test_head_counter_says_how_the_vocabulary_was_split(
     sample.data["prompt_mask"] = np.zeros(
         len(sample.data["packed_input_ids"]), bool
     )
-    tracer._reset_for_tests()
-    tracer.configure("t", dir=str(tmp_path), enabled=True, force=True)
-    try:
-        stats = engine.train_batch(
-            sample, MicroBatchSpec(), loss_fn=F.sft_loss,
-            loss_weight_fn=F.sft_label_count, extra_keys=("prompt_mask",),
-        )
-        _, events = tracer.read_shard(tracer.flush())
-    finally:
-        tracer._reset_for_tests()
+    stats = engine.train_batch(
+        sample, MicroBatchSpec(), loss_fn=F.sft_loss,
+        loss_weight_fn=F.sft_label_count, extra_keys=("prompt_mask",),
+    )
     assert stats["head/vocab_shards"] == shards
     assert np.isfinite(stats["loss"]) and stats["grad_norm"] > 0
-    (head,) = [e for e in events if e["ph"] == "C" and e["name"] == "head"]
-    assert head["args"] == {"vocab_shards": shards}

@@ -33,15 +33,213 @@ def _configure(tmp_path, role="test", rank=0):
 
 
 def test_disabled_is_noop(tmp_path):
-    # Unconfigured/disabled: spans yield the caller's args dict (post-hoc
-    # writes stay valid) and nothing is buffered or written.
+    # Unconfigured/disabled now means: nothing in the ring, nothing on
+    # disk, nothing in the flight recorder -- and the step ledger filled.
+    # Spans yield the caller's args dict (post-hoc writes stay valid).
     with tracer.span("x", cat="compute", a=1) as args:
         args["b"] = 2
+    assert args == {"a": 1, "b": 2}
     tracer.counter("c", v=1)
     tracer.instant("i")
     tracer.complete("r", start_ns=0)
     assert tracer.flush() is None
     assert list(tmp_path.iterdir()) == []
+    assert not any(tracer._buffers) and tracer.flight_events() == []
+    tracer.close_step(1, 0.5)
+    (closed,) = tracer.step_ledger()
+    n, total_s, self_s = closed["spans"]["x"]
+    assert n == 1 and total_s > 0 and self_s == total_s
+
+
+# ---------------- the step ledger (always on) ----------------
+
+
+def _busy(seconds):
+    import time
+
+    t_end = time.monotonic() + seconds
+    while time.monotonic() < t_end:
+        pass
+
+
+def test_ledger_self_time_is_duration_minus_children():
+    outer = tracer.span("outer")
+    with outer:
+        _busy(0.01)
+        with tracer.span("inner"):
+            _busy(0.02)
+            with tracer.span("leaf"):
+                _busy(0.01)
+        with tracer.span("inner"):
+            _busy(0.01)
+    tracer.close_step(7, 1.0)
+    (closed,) = tracer.step_ledger()
+    assert closed["step"] == 7 and closed["wall_s"] == 1.0
+    spans = closed["spans"]
+    assert [spans[k][0] for k in ("outer", "inner", "leaf")] == [1, 2, 1]
+    o, i, l = spans["outer"], spans["inner"], spans["leaf"]
+    assert l[1] == l[2] >= 0.01
+    assert i[1] >= 0.04 and abs(i[2] - (i[1] - l[1])) < 1e-6
+    assert abs(o[2] - (o[1] - i[1])) < 1e-6 and 0.01 <= o[2] < 0.02
+    # The object the caller holds says the same after the block.
+    assert abs(outer.self_ns / 1e9 - o[2]) < 1e-6
+
+
+def test_ledger_sums_threads_and_keeps_their_stacks_apart():
+    seen = {}
+    gate = threading.Event()
+
+    def work():
+        with tracer.span("w"):
+            with tracer.span("w_inner"):
+                seen["open"] = tracer.open_spans()
+                gate.wait(5)
+
+    t = threading.Thread(target=work, name="worker-thread")
+    with tracer.span("main_outer"):
+        t.start()
+        while "open" not in seen:
+            pass
+        with tracer.span("w"):
+            pass
+        gate.set()
+        t.join()
+    # The other thread's spans were never this thread's children.
+    assert seen["open"]["worker-thread"] == ["w", "w_inner"]
+    assert seen["open"][threading.current_thread().name] == ["main_outer"]
+    tracer.close_step(1, 1.0)
+    spans = tracer.step_ledger()[-1]["spans"]
+    assert spans["w"][0] == 2 and spans["w_inner"][0] == 1
+    assert spans["main_outer"][2] >= spans["main_outer"][1] - spans["w"][1]
+
+
+def test_close_step_rolls_over_and_caps_at_64_steps():
+    for step in range(1, 71):
+        with tracer.span("a"):
+            pass
+        if step % 2:
+            with tracer.span("odd"):
+                pass
+        stats = tracer.close_step(step, 1.0)
+        assert stats["time/slow_excess_s"] == 0.0
+        assert "host/late_s" not in stats  # unconfigured: no host watch
+    led = tracer.step_ledger()
+    assert len(led) == tracer.LEDGER_STEPS == 64
+    assert [r["step"] for r in led] == list(range(7, 71))
+    # Each record holds its own step's spans only.
+    assert all(r["spans"]["a"][0] == 1 for r in led)
+    assert all(("odd" in r["spans"]) == bool(r["step"] % 2) for r in led)
+    tracer.close_step(71, 1.0)
+    assert tracer.step_ledger()[-1]["spans"] == {}
+
+
+def test_close_step_default_wall_is_the_time_since_the_last_close():
+    tracer.close_step(1, 2.0)
+    _busy(0.02)
+    tracer.close_step(2)
+    assert 0.02 <= tracer.step_ledger()[-1]["wall_s"] < 1.0
+
+
+def test_configured_process_reports_its_host_record(tmp_path):
+    tracer.configure(role="master", dir=str(tmp_path), enabled=False,
+                     force=True)
+    stats = tracer.close_step(1, 1.0)
+    for key in ("host/late_s", "host/late_max_s", "host/gc_s",
+                "host/gc_gen2", "host/read_s", "time/slow_excess_s"):
+        assert isinstance(stats[key], float), key
+    assert tracer.step_ledger()[-1]["host"]["late_s"] == stats["host/late_s"]
+    assert tracer.role() == "master"
+
+
+def test_flight_ring_holds_no_span_closures(tmp_path):
+    _configure(tmp_path)
+    with tracer.span("outer", cat="host"):
+        with tracer.span("inner", cat="compute"):
+            pass
+    tracer.flight_event("dispatch", qid="q0")
+    assert [e["kind"] for e in tracer.flight_events()] == ["dispatch"]
+    _, events = tracer.read_shard(tracer.flush())
+    assert {e["name"] for e in events} == {"outer", "inner"}
+
+
+def _series(walls, spans=None):
+    """Close one step per wall; `spans` maps a step's index to the busy
+    seconds of a span named "work" inside it."""
+    out = []
+    for i, wall in enumerate(walls):
+        with tracer.span("steady"):
+            pass
+        if spans and i in spans:
+            with tracer.span("work"):
+                _busy(spans[i])
+        out.append(tracer.close_step(i + 1, wall)["time/slow_excess_s"])
+    return out
+
+
+@pytest.mark.parametrize(
+    "walls,flagged",
+    [
+        # 0.13 s on 2.63 s (q7b-realloc-4chip's pause): 3% is 0.079 s,
+        # so the 0.1 s floor decides.
+        ([2.63, 2.63, 2.631, 2.76, 2.63], {3: 0.13}),
+        # 0.2 s on 5.5 s (q3next-rollout64-512): 3% is 0.165 s.
+        ([5.5, 5.5, 5.5, 5.7], {3: 0.2}),
+        # Under both thresholds: 0.09 s on 2.63 s, 0.15 s on 5.5 s.
+        ([2.63, 2.63, 2.63, 2.72], {}),
+        ([5.5, 5.5, 5.5, 5.65], {}),
+        # Fewer than three steps of history: never flagged.
+        ([6.0, 6.0, 9.0], {}),
+        # Dense steps repeating to 0.1%.
+        ([6.0, 6.006, 5.994, 6.003, 6.0, 6.005], {}),
+        # A MoE cell drifting 0.8% a step downward, twenty steps.
+        ([4.6 * 0.992 ** i for i in range(20)], {}),
+        # A long warm-up step in the history does not hide a stall, and
+        # is not itself judged.
+        ([40.0, 6.0, 6.0, 6.0, 8.5, 6.0], {4: 2.5}),
+    ],
+    ids=["q7b-0.13s", "q3next-0.2s", "under-floor", "under-3pct",
+         "short-history", "dense-repeat", "moe-drift", "after-warmup"],
+)
+def test_slow_step_is_flagged_by_the_median_of_its_neighbours(walls, flagged):
+    excess = _series(walls)
+    for i, got in enumerate(excess):
+        assert got == pytest.approx(flagged.get(i, 0.0), abs=2e-3), (i, excess)
+    events = [e for e in tracer.flight_events() if e["kind"] == "slow_step"]
+    assert [e["step"] for e in events] == [i + 1 for i in sorted(flagged)]
+
+
+def test_slow_step_event_names_the_spans_that_grew(tmp_path, monkeypatch):
+    monkeypatch.setenv("AREAL_TRACE_DIR", str(tmp_path))
+    walls = [1.0, 1.0, 1.0, 1.0, 1.3, 1.0]
+    busy = {i: 0.002 for i in range(6)}
+    busy[4] = 0.05
+    _series(walls, busy)
+    (ev,) = [e for e in tracer.flight_events() if e["kind"] == "slow_step"]
+    assert ev["step"] == 5 and ev["wall_s"] == 1.3 and ev["median_s"] == 1.0
+    assert ev["excess_s"] == pytest.approx(0.3)
+    assert ev["host"] == {}  # unconfigured: no host watch to ask
+    (grown,) = ev["spans"]  # "steady" did not grow by 10 ms
+    assert grown["name"] == "work" and grown["n"] == 1
+    assert grown["self_s"] >= 0.05 > 0.01 > grown["median_self_s"] > 0
+    # The first slow step leaves a dump where a trace dir is known, and
+    # the report renders it.
+    (dump,) = tracer.read_flight_dumps(str(tmp_path))
+    assert dump["reason"] == "slow_step"
+    rendered = trace_report.format_flight(str(tmp_path), window_s=60.0)
+    assert "slow_step" in rendered and "work" in rendered
+
+
+def test_slow_step_dumps_at_most_once_in_fifty_steps(tmp_path, monkeypatch):
+    monkeypatch.setenv("AREAL_TRACE_DIR", str(tmp_path))
+    dumps = []
+    real = tracer.flight_dump
+    monkeypatch.setattr(
+        tracer, "flight_dump", lambda *a, **k: dumps.append(a) or real(*a, **k)
+    )
+    walls = [1.0] * 4 + [1.5] + [1.0] * 10 + [1.5] + [1.0] * 45 + [1.5]
+    excess = _series(walls)
+    assert [i for i, e in enumerate(excess) if e] == [4, 15, 61]
+    assert len(dumps) == 2  # steps 5 and 62; step 16 came too soon
 
 
 def test_span_nesting_and_mutable_args(tmp_path):
@@ -371,8 +569,11 @@ def test_lineage_stamps_roundtrip_through_shards(tmp_path):
 def test_flight_ring_always_on_and_bounded(tmp_path):
     # Tracer fully disabled: the ring still records (that's the point —
     # a chaos dump must work with AREAL_TRACE=0) and nothing hits disk.
+    # Spans, however many, evict nothing from it.
     for i in range(600):
         tracer.flight_event("dispatch", qid=f"q{i}")
+        with tracer.span("noise"):
+            pass
     tracer.lineage("dispatch", "tr-x", root=True, qid="q600")
     ring = tracer.flight_events()
     assert len(ring) == 512  # bounded: oldest entries evicted
