@@ -428,10 +428,16 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         # dead_live_lanes = lanes that were live but mapped to no row /
         # an out-of-grant qpos.  The last is structurally zero; the bench
         # invariant leg asserts it ("dead-lane compute exactly 0").
+        # pages_addressed = lanes x page-table width, pages_live = the
+        # pages under the live lanes' windows (sum of ceil(window /
+        # page_size)): the share of the addressed pages the chunk's
+        # attention had to read.
         self.lanes_dispatched = 0
         self.lanes_live = 0
         self.lanes_slack = 0
         self.dead_live_lanes = 0
+        self.pages_live = 0
+        self.pages_addressed = 0
         # Interruptible generation (async RL): interrupt() makes the
         # serving loop park at its next chunk boundary (generate() then
         # returns None); resume_generate() replays each live slot's last
@@ -648,6 +654,8 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         self.lanes_live = 0
         self.lanes_slack = 0
         self.dead_live_lanes = 0
+        self.pages_live = 0
+        self.pages_addressed = 0
         self._gen_t0 = time.monotonic()
         self._chunk_stats = _new_chunk_stats()
         prompt_lens = sample.seqlens_of(prompt_key)
@@ -819,6 +827,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         self, n_slots: int, n_pages: int, max_pages: int, chunk_t: int
     ):
         in_place = self._expert_leaves_in_place
+        paged_kernel = self._paged_kernel
         sig = ("paged_replay", n_slots, n_pages, max_pages, chunk_t, in_place)
         if sig in self._gen_fns:
             return self._gen_fns[sig]
@@ -829,7 +838,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                logits_buf, last_lane, live_mask):
             logits_pk, pool = tfm.decode_step_ragged_paged(
                 params, cfg, tokens, positions, pool, page_table, row_of,
-                experts_in_place=in_place,
+                experts_in_place=in_place, paged_kernel=paged_kernel,
             )
             logits_buf = jnp.where(
                 live_mask[:, None],
@@ -1101,10 +1110,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                 st.prefill_rem = to_host(new_rem).copy()
                 st.prompt_off = to_host(new_off).copy()
                 st.last_emit = st.gen_count - prev_gen
-                self.lanes_dispatched += chunk_t * self.serving_lane_budget
-                self.lanes_live += int(lane_acc[0])
-                self.lanes_slack += int(lane_acc[1])
-                self.dead_live_lanes += int(lane_acc[2])
+                self._count_lanes(lane_acc, chunk_t, st.max_pages)
 
                 # Register prefixes that FINISHED prefilling this chunk,
                 # before any retirement below can release the owner's pages:
@@ -1268,11 +1274,23 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         if cs["chunks"]:
             cs["n_waited"] += admitted
 
+    def _count_lanes(self, lane_acc, chunk_t: int, max_pages: int) -> None:
+        """One chunk's `lane_acc` (live lanes, slack lanes, misassigned
+        lanes, live pages) into the lane and page counters."""
+        lanes = chunk_t * self.serving_lane_budget
+        self.lanes_dispatched += lanes
+        self.lanes_live += int(lane_acc[0])
+        self.lanes_slack += int(lane_acc[1])
+        self.dead_live_lanes += int(lane_acc[2])
+        self.pages_live += int(lane_acc[3])
+        self.pages_addressed += lanes * max_pages
+
     def _serving_counters(self) -> Dict[str, float]:
         """The serving loop's per-generate() counters as last_pool_stats
         keys: chunks run, requests admitted and retired, host seconds at
-        chunk boundaries, and the spread of the seconds from generate()'s
-        start to each request's admission."""
+        chunk boundaries, the spread of the seconds from generate()'s
+        start to each request's admission, and the pages the chunks'
+        attention addressed and had to read."""
         cs = self._chunk_stats
         waits = sorted(cs["admit_waits"])
         out = {
@@ -1283,6 +1301,8 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             "admit_wait_mean_s": sum(waits) / max(len(waits), 1),
             "admit_wait_p50_s": waits[len(waits) // 2] if waits else 0.0,
             "admit_wait_max_s": waits[-1] if waits else 0.0,
+            "pages_live": self.pages_live,
+            "pages_addressed": self.pages_addressed,
         }
         tracer.counter("serving_chunks", **out)
         return out
@@ -1406,6 +1426,8 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             T += 1
         self.serving_lane_budget = T
         in_place = self._expert_leaves_in_place
+        paged_kernel = self._paged_kernel
+        ps = self.kv_page_size
         sig = (
             "serving_chunk", n_slots, n_pages, max_pages, chunk_t, W, pbw,
             K, g.spec_ngram, T,
@@ -1430,10 +1452,11 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             out_toks = jnp.full((n_slots, out_w), -1, jnp.int32)
             out_logps = jnp.zeros((n_slots, out_w), jnp.float32)
             out_fill = jnp.zeros((n_slots,), jnp.int32)
-            # (live lanes, slack lanes, live-but-misassigned lanes) —
-            # the third is structurally zero; the bench invariant leg
-            # asserts it stays so ("dead-lane compute exactly 0").
-            lane_acc = jnp.zeros((3,), jnp.int32)
+            # (live lanes, slack lanes, live-but-misassigned lanes, live
+            # pages) — the third is structurally zero; the bench
+            # invariant leg asserts it stays so ("dead-lane compute
+            # exactly 0").
+            lane_acc = jnp.zeros((4,), jnp.int32)
             rows = jnp.arange(n_slots)
             lanes = jnp.arange(Wmax)
             lane_ids = jnp.arange(T)
@@ -1522,9 +1545,15 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                 badlane = lane_live & (
                     (row_of >= n_slots) | (qpos < 0) | (qpos >= c[rid])
                 )
+                qv = jnp.clip(qpos, 0, Wmax - 1)
+                stream_pos = jnp.where(
+                    lane_live, cache_len[rid] + qv, 0
+                )
                 lane_acc = lane_acc + jnp.stack([
                     total, T - total,
                     jnp.sum(badlane.astype(jnp.int32)),
+                    # A live lane's window is [0, its position].
+                    jnp.sum(jnp.where(lane_live, stream_pos // ps + 1, 0)),
                 ])
                 # Per-row lane-token slab, gathered into the stream.
                 idx = jnp.minimum(
@@ -1553,14 +1582,11 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                     slab = slab.at[:, 0].set(
                         jnp.where(is_pref, pref_toks[:, 0], tok)
                     )
-                qv = jnp.clip(qpos, 0, Wmax - 1)
                 stream_tok = jnp.where(lane_live, slab[rid, qv], 0)
-                stream_pos = jnp.where(
-                    lane_live, cache_len[rid] + qv, 0
-                )
                 logits_pk, pool2 = tfm.decode_step_ragged_paged(
                     params, cfg, stream_tok, stream_pos, pool,
                     page_table, row_of, experts_in_place=in_place,
+                    paged_kernel=paged_kernel,
                 )  # [T, V]
                 # Next-step carry = each granted row's LAST lane logits
                 # (end-of-slice for prefill, post-token for decode);
@@ -2071,10 +2097,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                 out_toks = to_host(out_toks)
                 out_logps = to_host(out_logps)
                 lane_acc = to_host(lane_acc)
-            self.lanes_dispatched += chunk_t * self.serving_lane_budget
-            self.lanes_live += int(lane_acc[0])
-            self.lanes_slack += int(lane_acc[1])
-            self.dead_live_lanes += int(lane_acc[2])
+            self._count_lanes(lane_acc, chunk_t, st.max_pages)
             st.cache_len = to_host(new_cache_len).copy()
             st.gen_count = to_host(new_gen_count).copy()
             st.prefill_rem = to_host(new_rem).copy()
@@ -2174,6 +2197,14 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             b"ep:" + st.slot_prompt[s][: sp * alloc.page_size].tobytes(),
             alloc.table[s, :sp],
         )
+
+    @property
+    def _paged_kernel(self) -> Optional[bool]:
+        """Which form of the paged attention the serving programs take:
+        None, the platform's, on one device; the XLA form where the mesh
+        spreads the pool's heads or the stream's lanes over more — the
+        kernel is one device's program and is not `shard_map`ped yet."""
+        return None if self.mesh.size == 1 else False
 
     @property
     def _expert_leaves_in_place(self) -> bool:

@@ -1026,33 +1026,38 @@ jax.tree_util.register_dataclass(
 from areal_tpu.ops.quant import kv_dequant, kv_quant  # noqa: E402,F401
 
 
-def _cache_update_read(kc, vc, ksc, vsc, k, v, li, idx, quant: bool):
-    """Pool write + layer read for the paged decode step: scatter the new
-    K/V entries at `(li, *idx)` (quantizing when the pool is int8) and
-    return the layer's RAW K/V views plus the layer's scales (or None) —
-    the attention op dequantizes itself (in-kernel under
-    AREAL_DECODE_KERNEL=1, saving the extra bf16 window materialization
-    where bandwidth is the bottleneck).
+def _cache_update(kc, vc, ksc, vsc, k, v, rows, rows_s, quant: bool):
+    """Pool write of the paged decode step: scatter the new K/V entries
+    [T, n_kv, d] into the pool [L, P, ps, n_kv*d] at its flat token rows
+    `rows` [T] (`_pool_rows`, the layer's offset added), quantizing when
+    the pool is int8 (scales at `rows_s` [T, n_kv]).  The pool is written
+    as the [L*P*ps, n_kv*d] rows it is in memory — a bitcast, and one
+    index a token whatever `n_kv` is (PERF.md, PR 37).  There is no layer
+    read: the attention op takes the stacked pool and the layer index and
+    reads the live pages in place (`ops/attention.ragged_paged_attention`),
+    dequantizing an int8 pool itself.
 
-    Out-of-range indices are DROPPED (writes go through a page table whose
-    unmapped entries are the sentinel `n_pages`)."""
+    Rows past the pool are DROPPED (dead lanes, and writes through a page
+    table's unmapped entries)."""
+
+    def put(pool, new, at, n_lead):
+        flat = pool.reshape(-1, *pool.shape[n_lead:])
+        return flat.at[at].set(new, mode="drop").reshape(pool.shape)
+
+    t = k.shape[0]
     if quant:
         kq, ks = kv_quant(k)
         vq, vs = kv_quant(v)
-        kc = kc.at[(li, *idx)].set(kq, mode="drop")
-        vc = vc.at[(li, *idx)].set(vq, mode="drop")
-        ksc = ksc.at[(li, *idx)].set(ks, mode="drop")
-        vsc = vsc.at[(li, *idx)].set(vs, mode="drop")
-        ks_l = jax.lax.dynamic_index_in_dim(ksc, li, axis=0, keepdims=False)
-        vs_l = jax.lax.dynamic_index_in_dim(vsc, li, axis=0, keepdims=False)
-        k_raw = jax.lax.dynamic_index_in_dim(kc, li, axis=0, keepdims=False)
-        v_raw = jax.lax.dynamic_index_in_dim(vc, li, axis=0, keepdims=False)
-        return kc, vc, ksc, vsc, k_raw, v_raw, ks_l, vs_l
-    kc = kc.at[(li, *idx)].set(k.astype(kc.dtype), mode="drop")
-    vc = vc.at[(li, *idx)].set(v.astype(vc.dtype), mode="drop")
-    k_layer = jax.lax.dynamic_index_in_dim(kc, li, axis=0, keepdims=False)
-    v_layer = jax.lax.dynamic_index_in_dim(vc, li, axis=0, keepdims=False)
-    return kc, vc, ksc, vsc, k_layer, v_layer, None, None
+        return (
+            put(kc, kq.reshape(t, -1), rows, 3),
+            put(vc, vq.reshape(t, -1), rows, 3),
+            put(ksc, ks, rows_s, 4), put(vsc, vs, rows_s, 4),
+        )
+    return (
+        put(kc, k.astype(kc.dtype).reshape(t, -1), rows, 3),
+        put(vc, v.astype(vc.dtype).reshape(t, -1), rows, 3),
+        ksc, vsc,
+    )
 
 
 def init_kv_cache(
@@ -1315,7 +1320,13 @@ def decode_step(
 
 @dataclasses.dataclass
 class PagedKVCache:
-    """Block-paged KV pool: k/v [L, n_pages, page_size, n_kv, head_dim].
+    """Block-paged KV pool: k/v [L, n_pages, page_size, n_kv * head_dim] —
+    a token's heads side by side in one row, so that a page is whole
+    (page_size, n_kv * head_dim) tiles the attention kernel copies as
+    they lie, one head a lane-aligned slice of them, and a new token is
+    one row to scatter (with (n_kv, head_dim) as the minor dims, `n_kv =
+    2` pads to a 16-row tile or the pool is re-laid around the kernel).
+    The shape is private to this module and the generator.
 
     A dense cache at [L, n_slots, s_max, ...] would couple every slot
     to the batch-max window: growth a full-cache copy plus a decode
@@ -1345,7 +1356,7 @@ class PagedKVCache:
 
     k: jax.Array
     v: jax.Array
-    k_scale: "jax.Array | None" = None  # [L, n_pages, page_size, n_kv] bf16
+    k_scale: "jax.Array | None" = None  # [L, n_pages, n_kv, page_size] bf16
     v_scale: "jax.Array | None" = None
     page_size: int = 128  # static metadata (pytree aux)
 
@@ -1368,14 +1379,15 @@ jax.tree_util.register_dataclass(
 def init_paged_kv_cache(
     cfg: ModelConfig, n_pages: int, page_size: int, dtype=None
 ) -> PagedKVCache:
-    shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
+    shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads * cfg.head_dim)
     dtype = dtype or cfg.dtype
     if dtype in (jnp.int8, "int8"):
+        s_shape = (cfg.n_layers, n_pages, cfg.n_kv_heads, page_size)
         return PagedKVCache(
             k=jnp.zeros(shape, jnp.int8),
             v=jnp.zeros(shape, jnp.int8),
-            k_scale=jnp.zeros(shape[:-1], jnp.bfloat16),
-            v_scale=jnp.zeros(shape[:-1], jnp.bfloat16),
+            k_scale=jnp.zeros(s_shape, jnp.bfloat16),
+            v_scale=jnp.zeros(s_shape, jnp.bfloat16),
             page_size=page_size,
         )
     return PagedKVCache(
@@ -1398,6 +1410,21 @@ def _page_of(page_table: jax.Array, pos: jax.Array, page_size: int):
     return pages.astype(jnp.int32), (pos % page_size).astype(jnp.int32)
 
 
+def _pool_rows(cache: "PagedKVCache", n_kv: int, page, off):
+    """Layer 0's flat rows of the entries (page, off): the pool's token
+    rows [T] and the head-major scales' rows [T, n_kv].  Layer li's are
+    `li * stride` (`li * stride * n_kv`) on.  A page that is not in the
+    pool (the unmapped sentinel, `_page_of`'s drop, a dead lane) gives a
+    row past the whole pool, in every layer."""
+    n_layers, n_pool, ps = cache.k.shape[:3]
+    stride = n_pool * ps
+    gone = page >= n_pool
+    rows = jnp.where(gone, n_layers * stride, page * ps + off)
+    rows_s = (page[:, None] * n_kv + jnp.arange(n_kv)[None, :]) * ps + off[:, None]
+    rows_s = jnp.where(gone[:, None], n_layers * stride * n_kv, rows_s)
+    return rows.astype(jnp.int32), rows_s.astype(jnp.int32), stride
+
+
 _NO_SERVING_STATE = (
     "recurrent state has no slot on the serving plane yet: a hybrid layer "
     "pattern (linear-attention layers) generates on the static decode "
@@ -1416,6 +1443,7 @@ def decode_step_ragged_paged(
     page_table: jax.Array,  # [B, max_pages] int32, sentinel = n_pages
     row_of: jax.Array,  # [T] int32 — owning slot per token; >= B = dead lane
     experts_in_place: Optional[bool] = None,
+    paged_kernel: Optional[bool] = None,
 ) -> Tuple[jax.Array, PagedKVCache]:
     """The serving plane's forward: one packed [T] stream of query lanes
     with per-token windows, instead of a [B, Q] slab with per-row q_lens.
@@ -1428,8 +1456,11 @@ def decode_step_ragged_paged(
     — runs at ∝ T.  Token t writes its K/V at flat
     position `positions[t]` of slot `row_of[t]` and attends
     [0, positions[t]] through that slot's page-table row
-    (`ragged_paged_attention`: Pallas stream kernel or XLA per-token
-    gather).  Dead lanes (row_of >= B, the stream's slack) drop their
+    (`ragged_paged_attention`, which reads the STACKED pool at
+    (layer, page): the Pallas kernel on a TPU backend, the XLA per-token
+    gather elsewhere; a caller whose mesh spreads the pool or the lanes
+    over more than one device passes `paged_kernel=False`, the kernel is
+    one device's program).  Dead lanes (row_of >= B, the stream's slack) drop their
     cache writes, emit zero attention, and produce garbage logits the
     caller never reads.  The pool shape never changes during a generate
     call, so the enclosing program compiles exactly once.  A grouped MoE
@@ -1448,22 +1479,37 @@ def decode_step_ragged_paged(
     wp_page, wp_off = _page_of(pt_tok, positions, cache.page_size)
     # Dead lanes must not scatter (2**30 = the `_page_of` OOB drop).
     wp_page = jnp.where(live, wp_page, jnp.int32(2**30))
+    rows0, rows_s0, stride = _pool_rows(
+        cache, cfg.n_kv_heads, wp_page, wp_off
+    )
     valid_to = jnp.where(live, positions + 1, 0).astype(jnp.int32)
     quant = cache.quantized
+    if paged_kernel is None:
+        from areal_tpu.base.distributed import is_tpu_backend
+
+        paged_kernel = is_tpu_backend()
+    schedule = None
+    if paged_kernel:  # the same live pages for every layer: list them once
+        from areal_tpu.ops.pallas.paged_attention import live_page_schedule
+
+        schedule = live_page_schedule(
+            pt_tok, valid_to, cache.n_pages, cache.page_size,
+            cfg.n_q_heads // cfg.n_kv_heads,
+        )
 
     def body(carry, blk):
         y, kc, vc, ksc, vsc, li = carry
         h = _norm(y, blk["ln1"], blk.get("ln1_b"), cfg)
         q, k, v = _block_kv(h, blk, cfg, cos, sin)  # [T, 1, h, d]
-        kc, vc, ksc, vsc, k_pool_l, v_pool_l, ks_l, vs_l = (
-            _cache_update_read(
-                kc, vc, ksc, vsc, k[:, 0], v[:, 0], li, (wp_page, wp_off),
-                quant,
-            )
+        kc, vc, ksc, vsc = _cache_update(
+            kc, vc, ksc, vsc, k[:, 0], v[:, 0], li * stride + rows0,
+            li * stride * cfg.n_kv_heads + rows_s0, quant,
         )
         attn = ragged_paged_attention(
-            q[:, 0], k_pool_l, v_pool_l, pt_tok, valid_to,
-            k_scale=ks_l, v_scale=vs_l,
+            q[:, 0], kc, vc, li, pt_tok, valid_to,
+            k_scale=ksc if quant else None,
+            v_scale=vsc if quant else None,
+            use_kernel=paged_kernel, schedule=schedule,
         )
         ao = _attn_out(attn.reshape(t, 1, cfg.q_dim), blk, cfg)
         y = y + ao

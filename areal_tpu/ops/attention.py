@@ -138,6 +138,16 @@ def decode_attention(
             jnp.asarray(valid_from, jnp.int32),
             valid_to, k_scale, v_scale,
         )
+    return _decode_attention_xla(
+        q, k_cache, v_cache, valid_from, valid_to, k_scale, v_scale
+    )
+
+
+def _decode_attention_xla(
+    q, k_cache, v_cache, valid_from, valid_to, k_scale=None, v_scale=None
+):
+    """`decode_attention` as XLA ops, whatever AREAL_DECODE_KERNEL says:
+    the arithmetic both decode kernels and the paged kernel are held to."""
     if k_scale is not None:
         from areal_tpu.ops.quant import kv_dequant
 
@@ -237,14 +247,14 @@ def decode_attention_chunk(
 
 # --------------------------------------------------------------------------
 # Ragged paged attention (block-paged KV pool, models/transformer.py
-# PagedKVCache): Pallas stream kernel under AREAL_DECODE_KERNEL=1,
-# gather-based XLA form otherwise.
+# PagedKVCache): the Pallas kernel on a TPU backend, the gather-based XLA
+# form elsewhere.  Both read the STACKED pool at (layer, page).
 # --------------------------------------------------------------------------
 
 
 def clamp_page_table(page_table: jax.Array, n_pool: int) -> jax.Array:
     """The ONE sentinel rule for paged reads, shared by the Pallas
-    kernel and the XLA gather fallback: unmapped entries (>= n_pool)
+    kernel and the XLA gather form: unmapped entries (>= n_pool)
     clamp to the LAST pool page so every dereference is a legal index,
     and correctness comes from masking — pages are mapped contiguously
     from flat position 0, so any position addressed through a sentinel
@@ -254,72 +264,92 @@ def clamp_page_table(page_table: jax.Array, n_pool: int) -> jax.Array:
     return jnp.minimum(page_table.astype(jnp.int32), n_pool - 1)
 
 
-def paged_gather_layer(
-    pool_layer: jax.Array,  # [P, ps, ...] one layer's pool view
+def paged_gather(
+    pool: jax.Array,  # [L, P, ...] the stacked pool (or its scales)
+    layer: jax.Array,  # int32 scalar
     page_table: jax.Array,  # [B, max_pages] int32 (sentinel >= P)
 ) -> jax.Array:
-    """Gather a row-major dense window [B, max_pages*ps, ...] from the
-    pool through the page table.  Sentinel (unmapped) entries clamp to
-    the last page (`clamp_page_table`) — their positions lie past every
-    row's live window, so the attention mask removes them.  This reads
-    each slot's MAPPED pages only (plus the clamped repeats for unmapped
-    slots), not the whole pool."""
-    pt = clamp_page_table(page_table, pool_layer.shape[0])
-    g = jnp.take(pool_layer, pt, axis=0)  # [B, mp, ps, ...]
-    b, mp, ps = g.shape[:3]
-    return g.reshape(b, mp * ps, *pool_layer.shape[2:])
+    """Gather each row's pages [B, max_pages, ...] of one layer from the
+    stacked pool through the page table, indexed `layer * P + page` —
+    the layer's pool is never sliced out of the stack.  Sentinel
+    (unmapped) entries clamp to the last page (`clamp_page_table`) —
+    their positions lie past every row's live window, so the attention
+    mask removes them.  This reads each slot's MAPPED pages only (plus
+    the clamped repeats for unmapped slots), not the whole pool."""
+    n_layers, n_pool = pool.shape[:2]
+    pt = layer.astype(jnp.int32) * n_pool + clamp_page_table(page_table, n_pool)
+    return jnp.take(
+        pool.reshape(n_layers * n_pool, *pool.shape[2:]), pt, axis=0
+    )
 
 
 @jax.named_scope("layer/attn")
 def ragged_paged_attention(
     q: jax.Array,  # [T, n_q, d] — packed token stream (no batch/Q dims)
-    k_pool: jax.Array,  # [P, ps, n_kv, d] — one layer's pool view
+    k_pool: jax.Array,  # [L, P, ps, n_kv * d] — the STACKED pool
     v_pool: jax.Array,
+    layer: jax.Array,  # int32 scalar — the layer whose pages are read
     page_table_tok: jax.Array,  # [T, max_pages] int32 — PER-TOKEN tables
     valid_to: jax.Array,  # [T] int — one past each token's window; 0 = dead
-    k_scale: "Optional[jax.Array]" = None,  # [P, ps, n_kv]: int8 pool
+    k_scale: "Optional[jax.Array]" = None,  # [L, P, n_kv, ps]: int8 pool
     v_scale: "Optional[jax.Array]" = None,
+    use_kernel=None,  # None = the platform picks | bool
+    schedule=None,  # the kernel's `live_page_schedule`, made once a forward
 ) -> jax.Array:
     """Ragged paged attention over a PACKED token stream.
 
-    The serving megakernel's attention op: instead of a [n_slots, W] slab
+    The serving chunk's attention op: instead of a [n_slots, W] slab
     where every row pays W query lanes, the caller packs all live query
     lanes of the chunk — decode rows (1 lane), chunked-prefill /
     episode-observation rows (their granted slice), spec-verify rows
     (pending + drafts) — into one [T] stream.  Token t attends its own
     window [0, valid_to[t]) of the row it belongs to, addressed through
     its own (pre-gathered) page-table row.  Dead stream lanes carry
-    valid_to == 0 and emit exact zeros; the Pallas kernel skips their
-    pages entirely (eliminated, not masked), the XLA fallback gathers
-    per-token windows so its compute is ∝ T rather than ∝ n_slots * W.
+    valid_to == 0 and emit exact zeros.
+
+    On a TPU backend this is the Pallas kernel
+    (`ops/pallas/paged_attention.py`): it reads each lane's live pages in
+    place from the stacked pool, dead lanes and pages past a window are
+    not read at all.  Elsewhere — and where the caller says the operands
+    are sharded over more than one device (`use_kernel=False`) — it is
+    the XLA form below, the arithmetic reference of the tests: per-token
+    windows gathered at `layer * P + page`, bf16 operands with fp32
+    accumulation and an fp32 softmax (`decode_attention`).
 
     Returns [T, n_q, d] in q.dtype.
     """
-    if _decode_kernel_enabled():
+    if use_kernel is None:
+        from areal_tpu.base.distributed import is_tpu_backend
+
+        use_kernel = is_tpu_backend()
+    if use_kernel:
         from areal_tpu.ops.pallas.paged_attention import (
             ragged_paged_attention_kernel,
         )
 
         return ragged_paged_attention_kernel(
-            q, k_pool, v_pool, page_table_tok, valid_to, k_scale, v_scale
+            q, k_pool, v_pool, layer, page_table_tok, valid_to,
+            k_scale, v_scale, schedule,
         )
-    t = q.shape[0]
-    k_cache = paged_gather_layer(k_pool, page_table_tok)  # [T, mp*ps, ...]
-    v_cache = paged_gather_layer(v_pool, page_table_tok)
-    ks = (
-        None
-        if k_scale is None
-        else paged_gather_layer(k_scale, page_table_tok)
-    )
-    vs = (
-        None
-        if v_scale is None
-        else paged_gather_layer(v_scale, page_table_tok)
-    )
+    t, _, d = q.shape
+    n_kv = k_pool.shape[-1] // d
+    layer = jnp.asarray(layer, jnp.int32)
+
+    def window(pool):  # pages [T, mp, ps, n_kv*d] -> [T, mp*ps, n_kv, d]
+        return paged_gather(pool, layer, page_table_tok).reshape(t, -1, n_kv, d)
+
+    def scales(pool):  # pages [T, mp, n_kv, ps] -> [T, mp*ps, n_kv]
+        if pool is None:
+            return None
+        pages = paged_gather(pool, layer, page_table_tok)
+        return jnp.swapaxes(pages, 2, 3).reshape(t, -1, n_kv)
+
+    k_cache, v_cache = window(k_pool), window(v_pool)
+    ks, vs = scales(k_scale), scales(v_scale)
     # Q=1 decode formulation with T "rows": each packed token is its own
-    # attention problem.  decode_attention zeroes empty-window rows, which
+    # attention problem.  It zeroes empty-window rows, which
     # is exactly the dead-lane (valid_to == 0) contract.
-    out = decode_attention(
+    out = _decode_attention_xla(
         q[:, None], k_cache, v_cache, jnp.zeros((t,), jnp.int32),
         jnp.asarray(valid_to, jnp.int32), k_scale=ks, v_scale=vs,
     )

@@ -280,38 +280,89 @@ def phase_kernels(geom, on_tpu):
 
     _flash_cell_shapes(fa, dt, on_tpu)
 
-    # ---- ragged stream kernel (bf16 and int8 pools) vs the XLA gather path
-    t, n_pool, ps, mp = 40, 64, geom["page"], 4
-    check(not attention._decode_kernel_enabled(),
-          "AREAL_DECODE_KERNEL unset: ragged_paged_attention is the XLA path")
-    qs = jnp.asarray(rng.standard_normal((t, n_q, d)), dt)
-    pt = rng.integers(0, n_pool - 1, size=(t, mp)).astype(np.int32)
-    vt = rng.integers(1, mp * ps + 1, size=t).astype(np.int32)
-    vt[-6:] = 0  # stream slack lanes: dead, must come out exact zeros
-    vt[0], vt[1] = 1, mp * ps  # shortest and longest windows
-    for i in range(t):  # pages past the window are unmapped (sentinel)
-        pt[i, -(-int(vt[i]) // ps):] = n_pool
-    pt, vt = jnp.asarray(pt), jnp.asarray(vt)
-    kp = rng.standard_normal((n_pool, ps, n_kv, d))
-    vp = rng.standard_normal((n_pool, ps, n_kv, d))
+    _paged_cell_shape(attention, geom, dt, tol, on_tpu)
+
+
+def _paged_cell_shape(attention, geom, dt, tol, on_tpu, reps=5):
+    """The paged attention kernel against the XLA gather form on a STACKED
+    pool at the serving cell's shape (96 lanes, 3 table columns, 192 pages,
+    28 layers; 4 layers in rehearsal): bf16 and int8 pools, every layer,
+    dead lanes exact zeros; and the time of one sweep over the layers in
+    each form goes to the log."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
     from areal_tpu.ops.quant import kv_quant
 
-    k8, ks = kv_quant(jnp.asarray(kp, jnp.float32))
-    v8, vs = kv_quant(jnp.asarray(vp, jnp.float32))
-    for name, args in (
-        ("bf16" if on_tpu else "fp32",
-         (jnp.asarray(kp, dt), jnp.asarray(vp, dt))),
+    n_q, n_kv, d, ps = geom["n_q"], geom["n_kv"], geom["d"], geom["page"]
+    t, mp, n_pool = 96, 3, 192
+    n_layers = 28 if on_tpu else 4
+    rng = np.random.default_rng(5)
+    # 40 decode rows, 4 prefilling rows of 8 lanes (windows 1 apart, one
+    # table row), 24 slack lanes: what an inner step of the cell looks like.
+    windows = list(rng.integers(1, mp * ps + 1, size=40))
+    windows[0], windows[1] = 1, mp * ps  # shortest and longest
+    for _ in range(4):
+        w0 = int(rng.integers(8, mp * ps - 8))
+        windows += [(w0 + i, i > 0) for i in range(8)]
+    perm, nxt = rng.permutation(n_pool - 1), 0
+    pt = np.full((t, mp), n_pool, np.int32)  # past the window: unmapped
+    vt = np.zeros((t,), np.int32)
+    for i, w in enumerate(windows):
+        w, follows = w if isinstance(w, tuple) else (int(w), False)
+        vt[i] = w
+        if follows:
+            pt[i] = pt[i - 1]
+            continue
+        n = min(mp, -(-(w + 7) // ps))  # a prefilling row's last lane
+        pt[i, :n], nxt = perm[nxt:nxt + n], nxt + n
+    pt, vt = jnp.asarray(pt), jnp.asarray(vt)
+    qs = jnp.asarray(rng.standard_normal((t, n_q, d)), dt)
+    shape = (n_layers, n_pool, ps, n_kv, d)
+    kp = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    (k8, ks), (v8, vs) = kv_quant(kp), kv_quant(vp)
+    # The pool's layout: a token's heads in one row, scales head-major.
+    kp, vp, k8, v8 = (a.reshape(*shape[:3], -1) for a in (kp, vp, k8, v8))
+    ks, vs = jnp.swapaxes(ks, 2, 3), jnp.swapaxes(vs, 2, 3)
+
+    def sweep(use_kernel):
+        @jax.jit
+        def run(q, *pool):
+            def layer(li, acc):
+                return acc + attention.ragged_paged_attention(
+                    q, pool[0], pool[1], li, pt, vt, *pool[2:],
+                    use_kernel=use_kernel,
+                ).astype(jnp.float32)
+            return jax.lax.fori_loop(
+                0, n_layers, layer, jnp.zeros(q.shape, jnp.float32)
+            )
+        return run
+
+    for name, pool in (
+        ("bf16" if on_tpu else "fp32", (kp.astype(dt), vp.astype(dt))),
         ("int8", (k8, v8, ks, vs)),
     ):
-        o_x = attention.ragged_paged_attention(qs, args[0], args[1], pt, vt,
-                                               *args[2:])
-        o_k = pa.ragged_paged_attention_kernel(qs, args[0], args[1], pt, vt,
-                                               *args[2:])
-        err = _max_err(o_x, o_k)
-        check(err <= tol, f"ragged stream kernel ({name} pool) == XLA "
-                          f"gather path (max err {err:.2e})")
-        check(float(jnp.max(jnp.abs(o_k[-6:].astype(jnp.float32)))) == 0.0,
-              f"ragged stream kernel ({name} pool): dead lanes exact zeros")
+        outs, ms = {}, {}
+        for form, fn in (("xla", sweep(False)), ("kernel", sweep(True))):
+            outs[form] = jax.block_until_ready(fn(qs, *pool))
+            t0 = time.perf_counter()
+            for _ in range(reps):
+                out = fn(qs, *pool)
+            jax.block_until_ready(out)
+            ms[form] = (time.perf_counter() - t0) / reps * 1e3
+        err = _max_err(outs["xla"], outs["kernel"])
+        check(err <= tol * n_layers,
+              f"paged attention kernel ({name} stacked pool, {n_layers} "
+              f"layers summed) == XLA gather form (max err {err:.2e})")
+        check(float(jnp.max(jnp.abs(outs["kernel"][-24:]))) == 0.0,
+              f"paged attention kernel ({name} pool): dead lanes exact zeros")
+        log(f"  paged attention {name}: {n_layers} layers, kernel "
+            f"{ms['kernel']:.3f} ms, XLA gather form {ms['xla']:.3f} ms a "
+            f"sweep (host clock, {reps} sweeps"
+            + ("" if on_tpu else "; interpreted on the cpu, no device time")
+            + ")")
 
 
 # --------------------------------------------------------------------------

@@ -21,10 +21,55 @@ from benchmark.metrics import (
 )
 from benchmark.tests.test_any_block import *  # noqa: F401,F403 — the cases
 from benchmark.tests.test_any_block import OLMOE
+from benchmark.tests import test_ledger_readers as ledger_cases
 from benchmark.tests.test_ledger_readers import *  # noqa: F401,F403 — the cases
 
 SPEC = files.benchmark_json()
 CELLS = [w["name"] for w in SPEC["workloads"]]
+
+
+def test_the_new_entries_are_where_the_issue_put_them(monkeypatch):  # noqa: F811
+    """PR 36's case pins ITS eight entries as the last of `per_layer`; an
+    entry appended since (PR 37's `paged_attn_live_page_share`) moves
+    them up.  So: the appended entry where its issue put it, then PR
+    36's case on the list as it stood before — `benchmark/tests/` is not
+    a perf PR's to edit (PERF.md §7)."""
+    last = SPEC["per_layer"][-1]
+    assert last == {
+        "name": "paged_attn_live_page_share", "unit": "%", "better": "lower",
+        "source": "program_counter", "layer": "generator",
+        "moves": "gen_tokens_per_s", "workloads": ["q1p5b-serving-waves"],
+    }
+    assert [
+        c for c in CELLS if last in files.metrics_for(c, traced=True)
+    ] == ["q1p5b-serving-waves"]
+    before = dict(SPEC, per_layer=SPEC["per_layer"][:-1])
+    monkeypatch.setattr(files, "benchmark_json", lambda: before)
+    ledger_cases.test_the_new_entries_are_where_the_issue_put_them()
+
+
+@pytest.mark.parametrize(
+    "pools,want",
+    [
+        ([{"pages_live": 30, "pages_addressed": 120},
+          {"pages_live": 50, "pages_addressed": 200}], 25.0),
+        ([{"pages_live": 0, "pages_addressed": 288}], 0.0),
+        # the static program: no chunk ran, nothing was addressed
+        ([{"pages_live": 0, "pages_addressed": 0}], None),
+        # a program that keeps no such counter (the parent of PR 37)
+        ([{"chunks": 11}], None),
+    ],
+    ids=["serving", "all_dead", "static_program", "no_counter"],
+)
+def test_live_page_share_is_live_over_addressed_pages(pools, want):
+    from benchmark.metrics import paged_attn_live_page_share
+
+    run = ledger_cases.recorded(
+        ledger_cases.QUIET, walls=(2.0,) * len(pools)
+    )
+    for step, pool in zip(run.steps, pools):
+        step["pool"] = pool
+    assert paged_attn_live_page_share.read(run) == want
 
 
 def test_every_name_in_benchmark_json_is_a_cell_and_its_files_resolve():

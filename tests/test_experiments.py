@@ -25,6 +25,18 @@ from areal_tpu.system.master import ExperimentSaveEvalControl
 from tests import fixtures
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _fresh_tracer():
+    """The first `tracer.configure` of a process wins, and an in-process
+    verifier or reward server of a file that ran before in this worker
+    process may have been it: the steps here are the master's."""
+    from areal_tpu.base import tracer
+
+    tracer._reset_for_tests()
+    yield
+    tracer._reset_for_tests()
+
+
 def _sft_cfg(tmp_path, parallel="d1", epochs=2):
     return SFTConfig(
         model=ModelAbstraction("random", {"config": tiny_config()}),

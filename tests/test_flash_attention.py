@@ -635,23 +635,93 @@ class TestTPULowering:
         for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
             assert f"%{kernel}" in text
 
-    @pytest.mark.parametrize("pool", ["bf16", "int8"])
-    def test_ragged_stream_kernel(self, pool):
-        from areal_tpu.ops.pallas.paged_attention import (
-            ragged_paged_attention_kernel,
+    # The paged attention kernel's calls: (lanes, table columns, pool pages,
+    # layers, q heads, kv heads, pool dtype).
+    PAGED_SHAPES = {
+        "serving_cell_96x3": (96, 3, 192, 28, 12, 2, jnp.bfloat16),
+        "serving_cell_int8": (96, 3, 192, 28, 12, 2, jnp.int8),
+        "long_window_mp128": (96, 128, 2048, 4, 12, 2, jnp.bfloat16),
+        "olmoe_16x16": (96, 3, 192, 3, 16, 16, jnp.bfloat16),
+        "q7b_28x4": (96, 3, 192, 8, 28, 4, jnp.bfloat16),
+    }
+
+    def _paged_step(self, shape, sharding=None):
+        """One layer scan of the serving step's cache path — the new K/V
+        scattered into the stacked pool, the kernel reading it at
+        (layer, page) — and its arguments' shapes."""
+        from areal_tpu.models import transformer as tfm
+        from areal_tpu.ops.attention import ragged_paged_attention
+        from areal_tpu.ops.pallas.paged_attention import live_page_schedule
+
+        t, mp, n_pool, n_layers, n_q, n_kv, dt = shape
+        ps, d = 128, self.D
+        quant = dt == jnp.int8
+
+        def sds(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+        pool = sds((n_layers, n_pool, ps, n_kv * d), dt)
+        scale = sds((n_layers, n_pool, n_kv, ps) if quant else (0,),
+                    jnp.bfloat16)
+        new = sds((n_layers, t, n_kv, d), jnp.bfloat16)
+        args = (
+            tfm.PagedKVCache(pool, pool, scale, scale, ps),
+            sds((n_layers, t, n_q, d), jnp.bfloat16), new, new,
+            sds((t, mp), jnp.int32), sds((t,), jnp.int32),
+            sds((t,), jnp.int32), sds((t,), jnp.int32),
         )
 
-        t, n_pool, ps, mp = 40, 64, 128, 4
-        dt = jnp.int8 if pool == "int8" else jnp.bfloat16
-        args = [
-            jax.ShapeDtypeStruct((t, self.N_Q, self.D), jnp.bfloat16),
-            jax.ShapeDtypeStruct((n_pool, ps, self.N_KV, self.D), dt),
-            jax.ShapeDtypeStruct((n_pool, ps, self.N_KV, self.D), dt),
-            jax.ShapeDtypeStruct((t, mp), jnp.int32),
-            jax.ShapeDtypeStruct((t,), jnp.int32),
+        def step(cache, qs, k_new, v_new, pt, vt, page, off):
+            sched = live_page_schedule(pt, vt, n_pool, ps, n_q // n_kv)
+            rows, rows_s, stride = tfm._pool_rows(cache, n_kv, page, off)
+
+            def layer(carry, x):
+                kc, vc, ksc, vsc, li = carry
+                q, k, v = x
+                kc, vc, ksc, vsc = tfm._cache_update(
+                    kc, vc, ksc, vsc, k, v, li * stride + rows,
+                    li * stride * n_kv + rows_s, quant,
+                )
+                out = ragged_paged_attention(
+                    q, kc, vc, li, pt, vt,
+                    k_scale=ksc if quant else None,
+                    v_scale=vsc if quant else None, schedule=sched,
+                )
+                return (kc, vc, ksc, vsc, li + 1), out
+
+            return jax.lax.scan(
+                layer,
+                (cache.k, cache.v, cache.k_scale, cache.v_scale, jnp.int32(0)),
+                (qs, k_new, v_new),
+            )
+
+        return jax.jit(step, donate_argnums=(0,)), args
+
+    @pytest.mark.parametrize("cell", list(PAGED_SHAPES))
+    def test_paged_attention_kernel(self, cell):
+        fn, args = self._paged_step(self.PAGED_SHAPES[cell])
+        self._lowered(fn, *args)
+
+    @pytest.mark.parametrize("cell", list(PAGED_SHAPES))
+    def test_paged_attention_compiles_for_v5e(
+        self, cell, one_chip, _no_persistent_cache
+    ):
+        """Mosaic takes the kernel at every geometry, and XLA:TPU hands it
+        the stacked pool AS IT LIES: no copy or re-layout of the pool
+        around the scatter or the call (PERF.md, PR 37), whose temporaries
+        would be whole pools."""
+        shape = self.PAGED_SHAPES[cell]
+        fn, args = self._paged_step(shape, one_chip)
+        compiled = fn.lower(*args).compile()
+        text = compiled.as_text()
+        assert "%ragged_paged" in text
+        _, _, n_pool, n_layers, _, n_kv, dt = shape
+        pool = f"[{n_layers},{n_pool},128,{n_kv * self.D}]"
+        copies = [
+            line for line in text.splitlines()
+            if pool in line.split(" = ")[-1].split("(")[0]
+            and " copy(" in line
         ]
-        if pool == "int8":
-            args += [
-                jax.ShapeDtypeStruct((n_pool, ps, self.N_KV), jnp.bfloat16)
-            ] * 2
-        self._lowered(ragged_paged_attention_kernel, *args)
+        assert not copies, copies[:2]
+        layer_bytes = n_pool * 128 * n_kv * self.D * jnp.dtype(dt).itemsize
+        assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes

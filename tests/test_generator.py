@@ -346,27 +346,27 @@ class TestInt8KVCache:
         assert b8 < 0.6 * (c16.k.nbytes + c16.v.nbytes)
 
 
-def test_inflight_with_decode_kernel(cfg, params, rng, monkeypatch):
-    """The Pallas stream kernel (AREAL_DECODE_KERNEL=1) slots into the
-    serving loop transparently: greedy outputs equal the XLA form's."""
-    from areal_tpu.ops import attention
-
+def test_inflight_with_paged_kernel(cfg, params, rng, monkeypatch):
+    """The Pallas paged attention kernel (what a TPU backend takes;
+    interpreted here) slots into the serving loop transparently: greedy
+    outputs equal the XLA form's."""
     mesh = make_mesh(ParallelConfig.from_str("d1"), jax.devices()[:1])
     sample = _prompt_sample(rng, cfg, lens=(4, 9, 6))
     g = GenerationHyperparameters(n=1, max_new_tokens=6, greedy=True)
 
-    monkeypatch.setattr(attention, "_DECODE_KERNEL_SNAPSHOT", False)
     eng_dense = GeneratorEngine(
         cfg, params, mesh, eos_token_id=EOS, max_decode_batch=2
     )
+    assert eng_dense._paged_kernel is None  # one device: the platform picks
     out_dense = eng_dense.generate(sample, MicroBatchSpec(), g, inflight=True)
 
-    monkeypatch.setattr(attention, "_DECODE_KERNEL_SNAPSHOT", True)
+    monkeypatch.setattr(
+        GeneratorEngine, "_paged_kernel", property(lambda self: True)
+    )
     eng_kern = GeneratorEngine(
         cfg, params, mesh, eos_token_id=EOS, max_decode_batch=2
     )
     out_kern = eng_kern.generate(sample, MicroBatchSpec(), g, inflight=True)
-    monkeypatch.setattr(attention, "_DECODE_KERNEL_SNAPSHOT", None)
 
     np.testing.assert_array_equal(
         np.asarray(out_kern.data["packed_input_ids"]),

@@ -194,21 +194,17 @@ class TestPagedParity:
     def test_paged_pallas_kernel_parity(
         self, cfg, params, mesh, rng, monkeypatch
     ):
-        """AREAL_DECODE_KERNEL=1 routes the serving chunk through the
-        Pallas ragged stream kernel (interpret mode on CPU) — same
-        greedy tokens as the static program traced on the XLA form."""
-        from areal_tpu.ops import attention
-
+        """The serving chunk through the Pallas paged attention kernel
+        (what a TPU backend takes; interpret mode on CPU) — same greedy
+        tokens as the static program on the XLA form."""
         g = GenerationHyperparameters(n=1, max_new_tokens=8, greedy=True)
         eng = _engine(cfg, params, mesh, max_decode_batch=2)
         sample = _prompt_sample(rng, cfg, self.LENS)
-        monkeypatch.setattr(attention, "_DECODE_KERNEL_SNAPSHOT", False)
         ref = eng.generate(sample, MicroBatchSpec(), g, inflight=False)
-        monkeypatch.setattr(attention, "_DECODE_KERNEL_SNAPSHOT", True)
-        try:
-            out = eng.generate(sample, MicroBatchSpec(), g, inflight=True)
-        finally:
-            monkeypatch.setattr(attention, "_DECODE_KERNEL_SNAPSHOT", None)
+        monkeypatch.setattr(
+            GeneratorEngine, "_paged_kernel", property(lambda self: True)
+        )
+        out = eng.generate(sample, MicroBatchSpec(), g, inflight=True)
         _assert_same_output(ref, out)
         assert eng.decode_compiles == 1
 
@@ -891,92 +887,191 @@ class TestTwoProgramsOnly:
         assert named.count("serving_chunk") == 2  # K=0 and K=2
 
 
-class TestRaggedStreamKernel:
-    """The fused ragged megakernel (`ragged_paged_attention_kernel`):
-    one grid over per-lane q_lens — decode, chunked-prefill, and
-    spec-verify lanes mixed in one stream — must match the XLA gather
-    fallback, contribute ZERO output for dead lanes (valid_to == 0:
-    the kernel's flash loop runs no KV blocks and the unconditional
-    finish normalises the empty accumulator to exact zeros), and obey
+class TestRaggedPagedKernel:
+    """The paged attention kernel (`ragged_paged_attention_kernel`,
+    interpreted here): decode, chunked-prefill and spec-verify lanes
+    mixed in one stream, read in place from a STACKED pool at a nonzero
+    layer index, must match the XLA gather form, emit exact zeros for
+    dead lanes (valid_to == 0: no item of the work list touches them and
+    the unconditional finish normalises the empty accumulator), and obey
     the sentinel page rule under poisoning."""
 
-    def _stream(self, rng):
-        n_pool, ps, n_kv, d, rep = 10, 8, 2, 16, 3
-        n_q = n_kv * rep
-        k = jnp.asarray(
-            rng.standard_normal((n_pool, ps, n_kv, d)), jnp.float32
-        )
-        v = jnp.asarray(
-            rng.standard_normal((n_pool, ps, n_kv, d)), jnp.float32
-        )
-        # 4 rows: decode (1 lane), prefill slice (4 lanes), spec verify
-        # (3 lanes), dead row (0 lanes) + 4 slack lanes -> T = 12.
-        pt = np.full((4, 3), n_pool, np.int32)
-        pt[0] = (0, 1, 2)
-        pt[1, :2] = (3, 4)
-        pt[2, 0] = 5
-        pt[3] = (6, 7, 8)
-        row_of = np.array([0, 1, 1, 1, 1, 2, 2, 2, 4, 4, 4, 4], np.int32)
-        pos = np.array([19, 9, 10, 11, 12, 2, 3, 4, 0, 0, 0, 0], np.int32)
-        live = row_of < 4
-        pt_tok = np.take(pt, np.minimum(row_of, 3), axis=0)
-        vt = np.where(live, pos + 1, 0).astype(np.int32)
-        q = jnp.asarray(
-            rng.standard_normal((12, n_q, d)), jnp.float32
-        )
-        return q, k, v, jnp.asarray(pt_tok), jnp.asarray(vt)
+    N_LAYERS, LAYER, PS, MP = 3, 2, 8, 3
 
-    def test_kernel_matches_fallback_and_kills_dead_lanes(self, rng):
+    def _stream(self, rng, n_q=6, n_kv=2, d=16, dtype=jnp.float32):
+        ps, mp = self.PS, self.MP
+        # Windows at the page edges, dead lanes in between, then the 4
+        # lanes of ONE prefilling row (one table row, windows 1 apart)
+        # and the 3 of a spec-verify row: T = 16, two tiles of 8 lanes
+        # at rep 6, the prefilling row across their boundary.
+        windows = [1, ps - 1, ps, ps + 1, mp * ps, 0, 0]
+        shared = [(10, 11, 12, 13), (3, 4, 5)]
+        rows = [[w] for w in windows] + [list(g) for g in shared] + [[0], [20]]
+        n_pool = sum(-(-max(r) // ps) for r in rows) + 2
+        perm = rng.permutation(n_pool - 1)  # the last page stays unmapped
+        pt_tok, vt, nxt = [], [], 0
+        for r in rows:
+            n = -(-max(r) // ps)
+            row = np.full((mp,), n_pool, np.int32)  # sentinel past the window
+            row[:n] = perm[nxt:nxt + n]
+            nxt += n
+            pt_tok += [row] * len(r)
+            vt += r
+        shape = (self.N_LAYERS, n_pool, ps, n_kv * d)
+        k = jnp.asarray(rng.standard_normal(shape), dtype)
+        v = jnp.asarray(rng.standard_normal(shape), dtype)
+        q = jnp.asarray(rng.standard_normal((len(vt), n_q, d)), dtype)
+        return (
+            q, k, v, jnp.int32(self.LAYER), jnp.asarray(np.stack(pt_tok)),
+            jnp.asarray(np.array(vt, np.int32)),
+        )
+
+    @pytest.mark.parametrize(
+        "n_q,n_kv,d", [(12, 2, 128), (16, 16, 128), (28, 4, 128), (6, 2, 16)],
+        ids=["q1p5b_12x2", "olmoe_16x16", "q7b_28x4", "toy_6x2"],
+    )
+    def test_kernel_matches_xla_form_and_kills_dead_lanes(
+        self, rng, n_q, n_kv, d
+    ):
         from areal_tpu.ops.attention import ragged_paged_attention
+
+        args = self._stream(rng, n_q, n_kv, d)
+        out_x = ragged_paged_attention(*args, use_kernel=False)
+        out_k = ragged_paged_attention(*args, use_kernel=True)
+        np.testing.assert_allclose(
+            np.asarray(out_x), np.asarray(out_k), rtol=2e-5, atol=2e-5
+        )
+        # Dead lanes (valid_to == 0): exact zeros from BOTH forms.
+        dead = np.asarray(args[-1]) == 0
+        assert dead.sum() == 3
+        assert float(jnp.max(jnp.abs(out_x[dead]))) == 0.0
+        assert float(jnp.max(jnp.abs(out_k[dead]))) == 0.0
+
+    def test_reads_the_layer_it_is_given(self, rng):
+        """Another layer's pages are another answer; a layer's own pages
+        alone decide it."""
         from areal_tpu.ops.pallas.paged_attention import (
             ragged_paged_attention_kernel,
         )
 
-        q, k, v, pt_tok, vt = self._stream(rng)
-        out_fb = ragged_paged_attention(q, k, v, pt_tok, vt)
-        out_kn = ragged_paged_attention_kernel(q, k, v, pt_tok, vt)
-        np.testing.assert_allclose(
-            np.asarray(out_fb), np.asarray(out_kn), rtol=2e-5, atol=2e-5
-        )
-        # Dead lanes (valid_to == 0): exact zeros from BOTH paths.
-        assert float(jnp.max(jnp.abs(out_fb[8:]))) == 0.0
-        assert float(jnp.max(jnp.abs(out_kn[8:]))) == 0.0
+        q, k, v, li, pt_tok, vt = self._stream(rng)
+        out = ragged_paged_attention_kernel(q, k, v, li, pt_tok, vt)
+        others = jnp.arange(self.N_LAYERS) != self.LAYER
+        k_bad = jnp.where(others[:, None, None, None], 1e9, k)
+        out_bad = ragged_paged_attention_kernel(q, k_bad, v, li, pt_tok, vt)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(out_bad))
+        out_0 = ragged_paged_attention_kernel(q, k, v, jnp.int32(0), pt_tok, vt)
+        assert float(jnp.max(jnp.abs(out - out_0))) > 1e-2
 
     def test_sentinel_pages_add_no_mass(self, rng):
         from areal_tpu.ops.pallas.paged_attention import (
             ragged_paged_attention_kernel,
         )
 
-        q, k, v, pt_tok, vt = self._stream(rng)
-        n_pool = k.shape[0]
-        k_bad = k.at[n_pool - 1].set(1e9)
-        v_bad = v.at[n_pool - 1].set(1e9)
-        out = ragged_paged_attention_kernel(q, k, v, pt_tok, vt)
-        out_bad = ragged_paged_attention_kernel(q, k_bad, v_bad, pt_tok, vt)
+        q, k, v, li, pt_tok, vt = self._stream(rng)
+        n_pool = k.shape[1]
+        assert int(jnp.max(pt_tok)) == n_pool  # sentinel entries exist
+        k_bad = k.at[:, n_pool - 1].set(1e9)
+        v_bad = v.at[:, n_pool - 1].set(1e9)
+        out = ragged_paged_attention_kernel(q, k, v, li, pt_tok, vt)
+        out_bad = ragged_paged_attention_kernel(
+            q, k_bad, v_bad, li, pt_tok, vt
+        )
         np.testing.assert_array_equal(np.asarray(out), np.asarray(out_bad))
 
     def test_int8_pool_parity(self, rng):
         from areal_tpu.ops.attention import ragged_paged_attention
-        from areal_tpu.ops.pallas.paged_attention import (
-            ragged_paged_attention_kernel,
-        )
 
-        q, _, _, pt_tok, vt = self._stream(rng)
-        n_pool, ps, n_kv, d = 10, 8, 2, 16
+        q, k, _, li, pt_tok, vt = self._stream(rng)
         r = np.random.default_rng(3)
-        k8 = jnp.asarray(r.integers(-127, 128, (n_pool, ps, n_kv, d)), jnp.int8)
-        v8 = jnp.asarray(r.integers(-127, 128, (n_pool, ps, n_kv, d)), jnp.int8)
+        k8 = jnp.asarray(r.integers(-127, 128, k.shape), jnp.int8)
+        v8 = jnp.asarray(r.integers(-127, 128, k.shape), jnp.int8)
+        s_shape = (*k.shape[:2], 2, self.PS)  # head-major [L, P, n_kv, ps]
         ks = jnp.asarray(
-            np.abs(r.standard_normal((n_pool, ps, n_kv))) + 0.1, jnp.bfloat16
+            np.abs(r.standard_normal(s_shape)) + 0.1, jnp.bfloat16
         )
         vs = jnp.asarray(
-            np.abs(r.standard_normal((n_pool, ps, n_kv))) + 0.1, jnp.bfloat16
+            np.abs(r.standard_normal(s_shape)) + 0.1, jnp.bfloat16
         )
-        o_fb = ragged_paged_attention(q, k8, v8, pt_tok, vt, ks, vs)
-        o_kn = ragged_paged_attention_kernel(q, k8, v8, pt_tok, vt, ks, vs)
+        o_x, o_k = (
+            ragged_paged_attention(
+                q, k8, v8, li, pt_tok, vt, ks, vs, use_kernel=use
+            )
+            for use in (False, True)
+        )
+        # The kernel scales the fp32 scores and probabilities where the
+        # XLA form scales the codes: the same numbers, rounded elsewhere.
         np.testing.assert_allclose(
-            np.asarray(o_fb), np.asarray(o_kn), rtol=3e-5, atol=3e-5
+            np.asarray(o_x), np.asarray(o_k), rtol=1e-4, atol=1e-4
         )
+
+    def test_schedule_lists_live_pages_once_a_run(self, rng):
+        """The work list: one item per (run of lanes sharing a page,
+        page), nothing for dead lanes or pages past a window."""
+        from areal_tpu.ops.pallas.paged_attention import (
+            lane_tile, live_page_schedule,
+        )
+
+        _, k, _, _, pt_tok, vt = self._stream(rng)
+        ps, rep = self.PS, 6
+        assert lane_tile(3) == 16 and lane_tile(1) == 16 and lane_tile(7) == 16
+        sch = live_page_schedule(pt_tok, vt, k.shape[1], ps, rep)
+        tl = lane_tile(rep)
+        assert tl == 8 and sch.valid_rows.shape == (2, tl * rep, 1)
+        lo = np.asarray(sch.tile_lo)
+        n_work = int(lo[-1])
+        # Lanes 7..10 are one row of 2 pages, cut by the tile boundary
+        # into runs of 1 and 3 lanes a page: 4 items for 8 (lane, page)
+        # pairs; lanes 11..13 another of 1 page: 1 item for 3.
+        pages_live = int(np.sum(-(-np.asarray(vt) // ps)))
+        assert n_work == pages_live - 4 - 2
+        meta = np.asarray(sch.meta)[:n_work]
+        col, first, n = meta >> 16, (meta >> 8) & 0xFF, meta & 0xFF
+        assert n.sum() == pages_live and n.max() == 3
+        assert ((first + n) <= tl).all() and (col < self.MP).all()
+        pages = np.asarray(sch.page)[:n_work]
+        lanes = np.arange(len(vt))
+        for w in range(n_work):
+            tile = np.searchsorted(lo, w, side="right") - 1
+            run = lanes[tile * tl + first[w]: tile * tl + first[w] + n[w]]
+            assert (np.asarray(pt_tok)[run, col[w]] == pages[w]).all()
+            assert (np.asarray(vt)[run] > col[w] * ps).all()
+
+    def test_a_mesh_of_many_devices_keeps_the_xla_form(self, cfg, params):
+        """The kernel is one device's program: where the generator's mesh
+        spreads the lanes or the pool's heads, it asks for the XLA form
+        whatever the platform."""
+        for layout, want in (("d1", None), ("d2", False), ("m2", False)):
+            pc = ParallelConfig.from_str(layout)
+            eng = _engine(
+                cfg, params, make_mesh(pc, jax.devices()[: pc.world_size])
+            )
+            assert eng._paged_kernel is want, layout
+
+    def test_generate_kernel_token_for_token(
+        self, rng, cfg, params, mesh, monkeypatch
+    ):
+        """One `generate()` on the serving plane with the kernel
+        (interpreted) against the XLA form, token for token in fp32:
+        waves of admission, chunked prefill lanes beside decode lanes."""
+        sample = _prompt_sample(rng, cfg, [5, 19, 11, 3, 26, 9])
+        g = GenerationHyperparameters(n=1, max_new_tokens=12, greedy=True)
+        outs = []
+        for use in (False, True):
+            monkeypatch.setattr(
+                GeneratorEngine, "_paged_kernel", property(lambda self: use)
+            )
+            eng = _engine(
+                cfg, params, mesh, max_decode_batch=4,
+                prefill_chunk_tokens=4,
+            )
+            outs.append(
+                eng.generate(sample, MicroBatchSpec(), g, inflight=True)
+            )
+            assert eng.lanes_live > 0
+            assert 0 < eng.pages_live <= eng.pages_addressed
+            assert eng.last_pool_stats["pages_live"] == eng.pages_live
+        _assert_same_output(*outs)
 
 
 class TestGenServerBudgetValidation:

@@ -516,21 +516,21 @@ def test_gae_program_is_scoped():
 
 
 @pytest.mark.parametrize(
-    "kernel", ["flash_fwd", "flash_dq", "flash_dkv", "ragged_stream"]
+    "kernel", ["flash_fwd", "flash_dq", "flash_dkv", "ragged_paged"]
 )
 def test_every_pallas_call_is_named_and_scoped(kernel, monkeypatch):
     # Lowered for the TPU, as on a chip: the Mosaic call itself is there.
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    if kernel == "ragged_stream":
+    if kernel == "ragged_paged":
         from areal_tpu.ops.pallas.paged_attention import (
             ragged_paged_attention_kernel as fn,
         )
 
-        t, n_pool, ps, mp = 40, 64, 128, 4
+        t, n_layers, n_pool, ps, mp = 40, 3, 64, 128, 4
+        pool = jax.ShapeDtypeStruct((n_layers, n_pool, ps, 2 * 128), jnp.bfloat16)
         args = (
-            jax.ShapeDtypeStruct((t, 12, 128), jnp.bfloat16),
-            jax.ShapeDtypeStruct((n_pool, ps, 2, 128), jnp.bfloat16),
-            jax.ShapeDtypeStruct((n_pool, ps, 2, 128), jnp.bfloat16),
+            jax.ShapeDtypeStruct((t, 12, 128), jnp.bfloat16), pool, pool,
+            jax.ShapeDtypeStruct((), jnp.int32),
             jax.ShapeDtypeStruct((t, mp), jnp.int32),
             jax.ShapeDtypeStruct((t,), jnp.int32),
         )
