@@ -23,8 +23,31 @@ logger = logging.getLogger("monitor")
 
 
 def _attn_layers(cfg) -> int:
-    """Layers with softmax attention: one per period of a hybrid pattern."""
-    return getattr(cfg, "n_periods", cfg.n_layers)
+    """Layers with softmax attention: one per period of a hybrid pattern,
+    and every leading dense layer (those lie outside the periods)."""
+    return getattr(cfg, "n_periods", cfg.n_layers) + getattr(
+        cfg, "first_k_dense", 0)
+
+
+def _attn_params(cfg) -> int:
+    """Matmul parameters of ONE softmax-attention layer's projections.
+    Latent attention: the two low-rank query projections, the latent and
+    shared-rope projection, the key and value up-projections and the
+    output projection (the materialised form training and prefill run)."""
+    h, d = cfg.hidden_dim, cfg.head_dim
+    if getattr(cfg, "is_latent", False):
+        hq, c = cfg.n_q_heads, cfg.kv_lora_rank
+        return (
+            h * cfg.q_lora_rank + cfg.q_lora_rank * hq * d
+            + h * cfg.latent_dim
+            + c * hq * (cfg.qk_nope_head_dim + cfg.v_head_dim)
+            + hq * cfg.v_head_dim * h
+        )
+    q_mats = 2 if getattr(cfg, "attn_gate", False) else 1
+    return (
+        h * (q_mats * cfg.n_q_heads * d + 2 * cfg.n_kv_heads * d)
+        + cfg.n_q_heads * d * h
+    )
 
 
 def matmul_params(cfg) -> int:
@@ -37,16 +60,12 @@ def matmul_params(cfg) -> int:
     3 * d_k * d_v multiply-adds a value head's state takes per token
     (S^T k, S^T q, k d^T).  Of the routed experts the ones HELD here: a
     rank's share computes n_experts / router_width of a token's k choices
-    in expectation, beside the whole router and the shared expert."""
+    in expectation, beside the whole router and the shared expert (its
+    gate where it has one).  Leading dense layers (`first_k_dense`) count
+    the dense MLP, the others the mixture."""
     h = cfg.hidden_dim
-    d = cfg.head_dim
-    q_mats = 2 if getattr(cfg, "attn_gate", False) else 1
-    attn = (
-        h * (q_mats * cfg.n_q_heads * d + 2 * cfg.n_kv_heads * d)
-        + cfg.n_q_heads * d * h
-    )
     n_attn = _attn_layers(cfg)
-    mixers = n_attn * attn
+    mixers = n_attn * _attn_params(cfg)
     if n_attn != cfg.n_layers:
         hv = cfg.linear_n_v_heads
         linear = (
@@ -63,11 +82,15 @@ def matmul_params(cfg) -> int:
         mlp = n_mats * h * inter * held + h * width
         shared = getattr(cfg, "shared_expert_dim", 0)
         if shared:
-            mlp += 3 * h * shared + h
+            mlp += 3 * h * shared + (
+                h if getattr(cfg, "shared_expert_gated", True) else 0)
     else:
         mlp = n_mats * h * cfg.intermediate_dim
+    n_lead = getattr(cfg, "first_k_dense", 0)
+    mlps = (cfg.n_layers - n_lead) * mlp + (
+        n_lead * n_mats * h * cfg.intermediate_dim)
     head = 0 if cfg.is_critic else h * cfg.vocab_size
-    return int(mixers + cfg.n_layers * mlp + head)
+    return int(mixers + mlps + head)
 
 
 def flops_forward(

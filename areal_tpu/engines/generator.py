@@ -415,7 +415,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         # MoE decode counters of the current generate() call, summed on the
         # device inside the decode loop: [experts touched, fullest expert's
         # rows, steps] (see _fold_moe_counters).
-        self._moe_decode_sums = np.zeros((3,))
+        self._moe_decode_sums = np.zeros((_n_moe_counters(cfg),))
         # Serving-plane chunk counters of the current generate() call
         # (see _serving_counters); folded into last_pool_stats at its end.
         self._chunk_stats: Dict[str, Any] = _new_chunk_stats()
@@ -695,6 +695,15 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             inflight = (
                 len(reqs) > b_cap
                 or gconfig.max_new_tokens > self.static_path_max_new
+            )
+        if inflight and self.cfg.is_latent:
+            # As for a hybrid pattern below: never a silent fallback.
+            raise tfm.LatentLayoutError(
+                f"{tfm._NO_SERVING_LATENT}; this call has {len(reqs)} "
+                f"requests for {b_cap} slots, max_new_tokens "
+                f"{gconfig.max_new_tokens} (static_path_max_new "
+                f"{self.static_path_max_new}), stop={bool(gconfig.stop)}, "
+                f"spec_decode_k={gconfig.spec_decode_k}, inflight={inflight}"
             )
         if inflight and self.cfg.is_hybrid:
             # Never a silent fallback to a plane that would drop the state.
@@ -2207,6 +2216,14 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         return None if self.mesh.size == 1 else False
 
     @property
+    def _latent_kernel(self):
+        """What the static program's latent decode attention takes: None,
+        the backend's form, on one device; the MESH where the rows are
+        spread over more, so that the kernel runs per device on its own
+        rows (`ops/attention.latent_decode_attention`)."""
+        return None if self.mesh.size == 1 else self.mesh
+
+    @property
     def _expert_leaves_in_place(self) -> bool:
         """Whether the decode programs hand the ragged kernels the stacked
         expert leaves themselves — asked of the placed params, outside
@@ -2240,11 +2257,31 @@ class GeneratorEngine(HostOffloadMixin, Engine):
     # -- one fixed-shape chunk --
 
     def _generate_chunk(self, chunk, gconfig, key, results) -> None:
-        b_real = len(chunk)
+        toks, logps, gen_len = self.static_rollout(
+            [t for (_, _, t) in chunk], gconfig, key
+        )
+        for r, (i, rep, _) in enumerate(chunk):
+            gl = int(gen_len[r])
+            no_eos = gl == gconfig.max_new_tokens and (
+                gl == 0 or toks[r, gl - 1] != self.eos_token_id
+            )
+            results[(i, rep)] = (toks[r, :gl], logps[r, :gl], no_eos)
+
+    def static_rollout(self, prompts, gconfig, key, with_cache=False):
+        """One call of the static decode program over `prompts` (token
+        arrays, at most one batch of them) -> host arrays (tokens [b,
+        max_new], their log-probs, generated lengths [b]); rows past
+        `len(prompts)` pad the batch to the mesh's batch sharding.
+
+        `with_cache`: also the `KVCache` the program leaves, on the device
+        (one more output of the same program, for a check that holds the
+        cache to a reference): row r's prompt lies in slots [sp - len, sp),
+        sp = `bucket_len` of the longest prompt, its new tokens from sp."""
+        b_real = len(prompts)
         b = b_real
         while b % self.batch_shard:
             b += 1
-        sp = bucket_len(max(len(t) for (_, _, t) in chunk))
+        sp = bucket_len(max(len(t) for t in prompts))
         s_total = bucket_len(sp + gconfig.max_new_tokens)
 
         # Right-aligned prompts: every row's next token lands at the SAME
@@ -2252,12 +2289,13 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         # dynamic_update_slice instead of a per-row scatter.
         prompt_tok = np.full((b, sp), self.pad_token_id, np.int32)
         prompt_len = np.zeros((b,), np.int32)
-        for r, (_, _, toks) in enumerate(chunk):
+        for r, toks in enumerate(prompts):
             prompt_tok[r, sp - len(toks):] = toks
             prompt_len[r] = len(toks)
 
-        fn = self._get_gen_fn(b, sp, s_total, gconfig)
-        if self.cfg.is_hybrid:  # the two kinds of state, from shapes alone
+        fn = self._get_gen_fn(b, sp, s_total, gconfig, with_cache)
+        if self.cfg.is_hybrid or self.cfg.is_latent:
+            # What the cache holds, from shapes alone.
             cache = jax.eval_shape(
                 lambda: tfm.init_kv_cache(
                     self.cfg, b, s_total, dtype=self.compute_dtype
@@ -2267,42 +2305,53 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             def nbytes(*xs):
                 return sum(x.size * x.dtype.itemsize for x in xs)
 
-            self.last_pool_stats.update(
-                kv_cache_bytes=nbytes(cache.k, cache.v),
-                state_cache_bytes=nbytes(cache.state, cache.conv),
-            )
+            if self.cfg.is_hybrid:  # the two kinds of state
+                self.last_pool_stats.update(
+                    kv_cache_bytes=nbytes(cache.k, cache.v),
+                    state_cache_bytes=nbytes(cache.state, cache.conv),
+                )
+            else:  # latent rows, beside what per-head k/v would have taken
+                cfg = self.cfg
+                self.last_pool_stats.update(
+                    latent_cache_bytes=nbytes(cache.latent),
+                    kv_cache_bytes_as_heads=cache.latent.dtype.itemsize * (
+                        cfg.n_layers * b * s_total * cfg.n_kv_heads
+                        * (cfg.head_dim + cfg.v_head_dim)
+                    ),
+                )
         with tracer.span("gen_chunk", cat="compute", b=b_real, sp=sp):
             with tracer.span("gen_dispatch", cat="compute"):
-                toks, logps, gen_len, *moe = fn(
+                toks, logps, gen_len, *rest = fn(
                     self.params, prompt_tok, prompt_len, key
                 )
+                cache = rest.pop() if with_cache else None
             with tracer.span("gen_wait", cat="compute"):
                 toks, logps, gen_len = (
                     to_host(toks),
                     to_host(logps),
                     to_host(gen_len),
                 )
-                if moe:  # [experts touched, fullest expert's rows, steps]
-                    self._moe_decode_sums += to_host(moe[0]).astype(float)
+                if rest:  # [experts touched, fullest expert's rows, steps]
+                    self._moe_decode_sums += to_host(rest[0]).astype(float)
                     self._fold_moe_counters()
-        for r, (i, rep, _) in enumerate(chunk):
-            gl = int(gen_len[r])
-            no_eos = gl == gconfig.max_new_tokens and (
-                gl == 0 or toks[r, gl - 1] != self.eos_token_id
-            )
-            results[(i, rep)] = (toks[r, :gl], logps[r, :gl], no_eos)
+        if with_cache:
+            return toks, logps, gen_len, cache
+        return toks, logps, gen_len
 
-    def _get_gen_fn(self, b, sp, s_total, g: GenerationHyperparameters):
+    def _get_gen_fn(
+        self, b, sp, s_total, g: GenerationHyperparameters, with_cache=False
+    ):
         in_place = self._expert_leaves_in_place
         sig = (
             b, sp, s_total, g.max_new_tokens, g.min_new_tokens, g.greedy,
-            g.top_p, g.top_k, g.temperature, in_place,
+            g.top_p, g.top_k, g.temperature, in_place, with_cache,
         )
         if sig in self._gen_fns:
             return self._gen_fns[sig]
         cfg = self.cfg
         eos = self.eos_token_id
         max_new = g.max_new_tokens
+        latent_kernel = self._latent_kernel
 
         @jax.jit
         def gen(params, prompt_tok, prompt_len, key):
@@ -2352,6 +2401,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                 next_logits, cache, *counts = tfm.decode_step(
                     params, cfg, tok, pos, cache, sp + step, valid_from,
                     with_moe_counts=cfg.is_moe, experts_in_place=in_place,
+                    latent_kernel=latent_kernel,
                 )
                 if cfg.is_moe:
                     moe = [moe[0] + _moe_step_counters(counts[0], cfg, bsz)]
@@ -2364,8 +2414,11 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             if cfg.is_moe:  # two sums + the steps they run over (+ share)
                 state += (jnp.zeros((_n_moe_counters(cfg),), jnp.float32),)
             state = jax.lax.while_loop(cond, body, state)
-            _, _, _, _, gen_len, out_toks, out_logps, _, *moe = state
-            return (out_toks, out_logps, gen_len, *moe)
+            _, _, _, _, gen_len, out_toks, out_logps, cache, *moe = state
+            # `with_cache`: what the loop leaves in the cache, last.
+            return (out_toks, out_logps, gen_len, *moe) + (
+                (cache,) if with_cache else ()
+            )
 
         self._gen_fns[sig] = gen
         logger.info(

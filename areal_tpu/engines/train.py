@@ -33,7 +33,7 @@ from areal_tpu.base.distributed import is_primary, to_host
 from areal_tpu.engines import packing
 from areal_tpu.engines.offload import HostOffloadMixin
 from areal_tpu.models import transformer as tfm
-from areal_tpu.models.config import ModelConfig
+from areal_tpu.models.config import FROZEN_LEAVES, ModelConfig
 from areal_tpu.parallel import realloc, sharding
 
 logger = logging.getLogger("train_engine")
@@ -58,21 +58,40 @@ def make_lr_schedule(cfg: OptimizerConfig, total_steps: int):
     )
 
 
-def make_optimizer(cfg: OptimizerConfig, total_steps: int) -> optax.GradientTransformation:
+def make_optimizer(
+    cfg: OptimizerConfig, total_steps: int, trainable=None
+) -> optax.GradientTransformation:
+    """`trainable`: a pytree of bools over the params, False for the leaves
+    no optimizer may move (`_trainable_mask`); Adam then keeps no moment
+    for them, no weight decay reaches them, and their update is their
+    gradient, which is zero.  None: every leaf is trained."""
     sched = make_lr_schedule(cfg, total_steps)
     chain = []
     if cfg.gradient_clipping and cfg.gradient_clipping > 0:
         chain.append(optax.clip_by_global_norm(cfg.gradient_clipping))
-    chain.append(
-        optax.adamw(
-            learning_rate=sched,
-            b1=cfg.beta1,
-            b2=cfg.beta2,
-            eps=cfg.eps,
-            weight_decay=cfg.weight_decay,
-        )
+    adamw = optax.adamw(
+        learning_rate=sched,
+        b1=cfg.beta1,
+        b2=cfg.beta2,
+        eps=cfg.eps,
+        weight_decay=cfg.weight_decay,
     )
+    chain.append(adamw if trainable is None else optax.masked(adamw, trainable))
     return optax.chain(*chain)
+
+
+def _trainable_mask(params):
+    """`make_optimizer`'s mask: False for the block leaves of
+    `FROZEN_LEAVES`; None where the model has none (every family but the
+    sigmoid-routed one), so their optimizer is what it was."""
+    blocks = params.get("blocks", {})
+    if not any(n in blocks for n in FROZEN_LEAVES):
+        return None
+    mask = jax.tree.map(lambda _: True, params)
+    for n in FROZEN_LEAVES:
+        if n in blocks:
+            mask["blocks"][n] = False
+    return mask
 
 
 @functools.lru_cache(maxsize=None)
@@ -213,7 +232,8 @@ class TrainEngine(HostOffloadMixin, Engine):
         params = _cast_tree(params, master_dtype)
         self.params = jax.device_put(params, self.param_shardings)
         self.optimizer = make_optimizer(
-            self.optimizer_config, max(self.ftspec.total_train_steps, 1)
+            self.optimizer_config, max(self.ftspec.total_train_steps, 1),
+            _trainable_mask(params),
         )
 
         # Optimizer state mirrors the params (ZeRO-1): every param-shaped

@@ -10,6 +10,14 @@ from typing import Optional
 
 import jax.numpy as jnp
 
+# Block leaves of the leading dense layers (`first_k_dense`): a layer's own
+# leaf names under this prefix, stacked [first_k_dense, ...].
+DENSE_PREFIX = "dense_"
+# Block leaves no gradient reaches and no optimizer moves (the sigmoid
+# router's choice bias): the train engine keeps no moment for them and the
+# hand-back returns them as they were.
+FROZEN_LEAVES = ("router_bias",)
+
 _DTYPES = {
     "bfloat16": jnp.bfloat16,
     "float32": jnp.float32,
@@ -94,13 +102,72 @@ class ModelConfig:
     # nothing here: the layer's output is this rank's PART of the sum.
     n_router_experts: int = 0
     expert_offset: int = 0
+    # ---- latent attention (MLA: deepseek_v3, glm4_moe_lite) ----
+    # `kv_lora_rank` > 0: keys and values are up-projections of ONE normed
+    # latent vector a token (`kv_lora_rank` wide) beside one roped key part
+    # all heads share (`qk_rope_head_dim`); the query is low-rank too
+    # (`q_lora_rank`), its heads `qk_nope_head_dim` + `qk_rope_head_dim`
+    # wide.  The cache keeps the latent row, not per-head K/V.  `head_dim`
+    # is the q/k width and has to equal `v_head_dim`: the attention kernels
+    # take one width (unequal widths are refused by name).
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # The first `first_k_dense` layers have a dense MLP (`intermediate_dim`)
+    # where the others have the mixture of experts; they run before the
+    # layer scan, their leaves stacked on their own under `dense_*`.
+    first_k_dense: int = 0
+    # Router scores over all experts: "softmax", or "sigmoid" with a per-
+    # layer bias (`router_bias`, not trained) that enters the CHOICE of the
+    # top k and not their weights (deepseek_v3 `noaux_tc`), the chosen
+    # weights renormalised per `moe_norm_topk` and scaled by
+    # `moe_routed_scale`; no auxiliary loss.
+    moe_score_func: str = "softmax"
+    moe_routed_scale: float = 1.0
+    # Random weights only (`init_params`): the standard deviation of the
+    # draw of `router_bias`.  0, a bias of zeros, is how the family starts
+    # one; a configuration that wants choice-by-score-plus-bias told from
+    # weight-by-score on random weights states its own draw.  A checkpoint
+    # brings its bias and never reads this.
+    router_bias_init_std: float = 0.0
+    # False: the shared expert's output is added as it is (no sigmoid gate).
+    shared_expert_gated: bool = True
 
     def __post_init__(self):
-        if self.n_layers % self.full_attn_interval:
+        if self.n_scan_layers % self.full_attn_interval:
             raise ValueError(
                 f"{self.n_layers} layers are not whole periods of "
                 f"{self.full_attn_interval} (full_attn_interval)"
             )
+        if self.first_k_dense and not (
+            self.is_latent and self.is_moe
+            and self.first_k_dense < self.n_layers
+        ):
+            raise NotImplementedError(
+                f"first_k_dense {self.first_k_dense}: leading dense layers "
+                "come before the scanned sparse layers of a latent-attention "
+                "mixture-of-experts model (their cache layers are latent "
+                "rows)"
+            )
+        if self.moe_score_func not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown moe_score_func {self.moe_score_func!r}")
+        if self.is_latent:
+            qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+            if not (qk == self.v_head_dim == self.head_dim):
+                raise NotImplementedError(
+                    f"latent attention with q/k heads {qk} wide and v heads "
+                    f"{self.v_head_dim} (head_dim {self.head_dim}): the "
+                    "attention kernels take one width for q, k and v"
+                )
+            if self.is_hybrid or self.n_kv_heads != self.n_q_heads or not (
+                self.q_lora_rank and self.pos_emb == "rope"
+            ):
+                raise NotImplementedError(
+                    "latent attention is one kind of layer with a low-rank "
+                    "query, rotary positions and as many key as query heads"
+                )
         if self.expert_offset + self.n_experts > self.router_width:
             raise ValueError(
                 f"experts [{self.expert_offset}, "
@@ -121,8 +188,22 @@ class ModelConfig:
         return self.full_attn_interval > 1
 
     @property
+    def is_latent(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_dim(self) -> int:
+        """Values a token's row of the latent cache holds."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def n_scan_layers(self) -> int:
+        """Layers the layer scan runs over: all but the leading dense."""
+        return self.n_layers - self.first_k_dense
+
+    @property
     def n_periods(self) -> int:
-        return self.n_layers // self.full_attn_interval
+        return self.n_scan_layers // self.full_attn_interval
 
     @property
     def n_linear_layers(self) -> int:

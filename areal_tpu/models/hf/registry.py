@@ -10,7 +10,10 @@ Families here (full reference parity, api/from_hf/*): llama, qwen2
 (1+w) rms offset + scaled embeddings), mixtral (MoE expert stacking), gpt2
 (learned positions, LayerNorm+bias, fused c_attn, non-gated gelu MLP), olmoe
 (64-expert MoE under `mlp.experts.{e}`, QK-norm over the whole projection,
-top-k router weights not renormalised).
+top-k router weights not renormalised), qwen3_next (a hybrid period of
+Gated DeltaNet and gated attention, a gated shared expert), glm4_moe_lite
+(the deepseek_v3 block: latent attention, a sigmoid router with an
+untrained choice bias, an ungated shared expert, leading dense layers).
 """
 
 import dataclasses
@@ -22,7 +25,7 @@ import jax
 import numpy as np
 
 from areal_tpu.base import logging
-from areal_tpu.models.config import ModelConfig
+from areal_tpu.models.config import DENSE_PREFIX, ModelConfig
 
 logger = logging.getLogger("hf_registry")
 
@@ -800,6 +803,274 @@ register_hf_family(
 )
 
 
+# ---------------- glm4_moe_lite ----------------
+# zai-org/GLM-4.7-Flash: the deepseek_v3 block.  Latent attention (MLA):
+# `q_a_proj` -> `q_a_layernorm` -> `q_b_proj` gives each head a query of
+# [nope | rope] columns; `kv_a_proj_with_mqa` gives [latent | one rope key
+# all heads share], the latent goes through `kv_a_layernorm` and `kv_b_proj`
+# gives each head [k_nope | v].  The first `first_k_dense_replace` layers
+# have a dense MLP; the others a sigmoid router (`mlp.gate.weight`, with the
+# untrained choice bias `mlp.gate.e_score_correction_bias`), routed experts
+# and `n_shared_experts` ungated shared experts fused into one MLP.
+#
+# HF applies the rotary embedding to INTERLEAVED pairs (`rope_interleave`:
+# columns (0, 1), (2, 3), ...); ours rotates halves (columns j and j + r/2).
+# The converter permutes the rope columns of `wq_b` and `wkv_a` on the way
+# in ([0, 2, 4, ..., 1, 3, 5, ...]) and back on the way out: q_pe . k_pe is
+# the same sum either way, and the cache's rope columns are in OUR order.
+# Ours keep `kv_b_proj` as two leaves, `wk_b` and `wv_b`, heads contiguous:
+# the absorbed decode step multiplies by each alone.
+#
+# The multi-token-prediction layer (`num_nextn_predict_layers`, HF layer
+# `num_hidden_layers`) is not modelled: it enters no next-token logit, and
+# its tensors are neither read nor written.  A `share` group cuts the model
+# to one expert-parallel rank as for qwen3_next: `n_routed_experts` is then
+# the number HELD here of `share.router_num_experts`.
+
+
+def _glm4_moe_lite_config_from_hf(hf: dict) -> ModelConfig:
+    for key, fine in (
+        ("rope_scaling", None), ("attention_bias", False),
+        ("hidden_act", "silu"), ("n_group", 1), ("topk_group", 1),
+        ("topk_method", "noaux_tc"), ("partial_rotary_factor", 1),
+        ("scoring_func", "sigmoid"), ("moe_layer_freq", 1),
+    ):
+        if hf.get(key, fine) != fine:
+            raise NotImplementedError(
+                f"glm4_moe_lite {key}={hf[key]!r} is not modelled"
+            )
+    share = hf.get("share") or {}
+    n_experts = hf["n_routed_experts"]
+    width = share.get("router_num_experts", n_experts)
+    nope, rope = hf["qk_nope_head_dim"], hf["qk_rope_head_dim"]
+    # What a benchmark configuration assumes where the published file is
+    # silent (`benchmark.assumed`); a checkpoint's config.json has none.
+    assumed = (hf.get("benchmark") or {}).get("assumed") or {}
+    return ModelConfig(
+        n_layers=hf["num_hidden_layers"],
+        hidden_dim=hf["hidden_size"],
+        n_q_heads=hf["num_attention_heads"],
+        n_kv_heads=hf.get("num_key_value_heads", hf["num_attention_heads"]),
+        head_dim=nope + rope,
+        intermediate_dim=hf["intermediate_size"],
+        vocab_size=hf["vocab_size"],
+        max_position_embeddings=hf.get("max_position_embeddings", 202752),
+        rope_theta=hf.get("rope_theta", 1000000.0),
+        rms_norm_eps=hf.get("rms_norm_eps", 1e-5),
+        tied_embeddings=hf.get("tie_word_embeddings", False),
+        q_lora_rank=hf["q_lora_rank"],
+        kv_lora_rank=hf["kv_lora_rank"],
+        qk_nope_head_dim=nope,
+        qk_rope_head_dim=rope,
+        v_head_dim=hf["v_head_dim"],
+        first_k_dense=hf.get("first_k_dense_replace", 0),
+        n_experts=n_experts,
+        n_experts_per_tok=hf["num_experts_per_tok"],
+        moe_intermediate_dim=hf["moe_intermediate_size"],
+        moe_norm_topk=bool(hf.get("norm_topk_prob", True)),
+        moe_aux_loss_coef=0.0,
+        moe_score_func="sigmoid",
+        moe_routed_scale=float(hf.get("routed_scaling_factor", 1.0)),
+        router_bias_init_std=float(assumed.get("router_bias_init_std", 0.0)),
+        shared_expert_dim=(
+            hf.get("n_shared_experts", 0) * hf["moe_intermediate_size"]
+        ),
+        shared_expert_gated=False,
+        n_router_experts=0 if width == n_experts else width,
+        expert_offset=share.get("rank", 0) * n_experts,
+    )
+
+
+def _glm4_moe_lite_config_to_hf(cfg: ModelConfig) -> dict:
+    out = _llama_like_config_to_hf(cfg, "glm4_moe_lite")
+    out.pop("head_dim")  # not a key of this family: nope + rope
+    out.update(
+        architectures=["Glm4MoeLiteForCausalLM"],
+        hidden_act=cfg.hidden_act,
+        attention_bias=False,
+        rope_scaling=None,
+        partial_rotary_factor=1,
+        q_lora_rank=cfg.q_lora_rank,
+        kv_lora_rank=cfg.kv_lora_rank,
+        qk_nope_head_dim=cfg.qk_nope_head_dim,
+        qk_rope_head_dim=cfg.qk_rope_head_dim,
+        v_head_dim=cfg.v_head_dim,
+        first_k_dense_replace=cfg.first_k_dense,
+        n_routed_experts=cfg.n_experts,
+        num_experts_per_tok=cfg.n_experts_per_tok,
+        moe_intermediate_size=cfg.moe_intermediate_dim,
+        n_shared_experts=cfg.shared_expert_dim // cfg.moe_intermediate_dim,
+        norm_topk_prob=cfg.moe_norm_topk,
+        routed_scaling_factor=cfg.moe_routed_scale,
+        topk_method="noaux_tc",
+        n_group=1,
+        topk_group=1,
+    )
+    if cfg.expert_share:
+        out["share"] = {
+            "router_num_experts": cfg.router_width,
+            "rank": cfg.expert_offset // cfg.n_experts,
+        }
+    return out
+
+
+_GLM = "model.layers.{}."
+# ours <- HF name under the layer, transposed ([out, in] -> [in, out]).
+_GLM_LAYER = (  # every layer (`wq_b`, `wkv_a`, `wk_b`, `wv_b`: below)
+    ("ln1", "input_layernorm.weight", False),
+    ("ln2", "post_attention_layernorm.weight", False),
+    ("wq_a", "self_attn.q_a_proj.weight", True),
+    ("q_a_norm", "self_attn.q_a_layernorm.weight", False),
+    ("kv_a_norm", "self_attn.kv_a_layernorm.weight", False),
+    ("wo", "self_attn.o_proj.weight", True),
+)
+_GLM_DENSE = (
+    ("wg", "mlp.gate_proj.weight", True),
+    ("wu", "mlp.up_proj.weight", True),
+    ("wd", "mlp.down_proj.weight", True),
+)
+_GLM_SPARSE = (
+    ("router", "mlp.gate.weight", True),
+    ("router_bias", "mlp.gate.e_score_correction_bias", False),
+    ("ws_g", "mlp.shared_experts.gate_proj.weight", True),
+    ("ws_u", "mlp.shared_experts.up_proj.weight", True),
+    ("ws_d", "mlp.shared_experts.down_proj.weight", True),
+)
+
+
+def _glm_rope_order(cfg, inverse=False):
+    """Columns of a rope part: HF's interleaved pairs -> our halves."""
+    r = cfg.qk_rope_head_dim
+    order = np.concatenate([np.arange(0, r, 2), np.arange(1, r, 2)])
+    return np.argsort(order) if inverse else order
+
+
+def _glm4_moe_lite_params_from_sd(cfg, sd, dtype=None):
+    import jax.numpy as jnp
+
+    dtype = dtype or cfg.dtype
+    h = cfg.n_q_heads
+    nope, c, vd = cfg.qk_nope_head_dim, cfg.kv_lora_rank, cfg.v_head_dim
+    order = _glm_rope_order(cfg)
+
+    def get(name):
+        if name not in sd:
+            raise KeyError(f"missing tensor {name!r} in checkpoint")
+        return np.asarray(sd[name], np.float32)
+
+    def wq_b(pre):  # [h, (nope | rope), rq] -> [rq, h * (nope | rope')]
+        w = get(pre + "self_attn.q_b_proj.weight").reshape(h, cfg.head_dim, -1)
+        w = np.concatenate([w[:, :nope], w[:, nope:][:, order]], axis=1)
+        return w.reshape(h * cfg.head_dim, -1).T
+
+    def wkv_a(pre):  # [(latent | rope), D] -> [D, (latent | rope')]
+        w = get(pre + "self_attn.kv_a_proj_with_mqa.weight")
+        return np.concatenate([w[:c], w[c:][order]]).T
+
+    def kv_b(pre, part):  # [h, (nope | v), c] -> [c, h * nope] or [c, h * v]
+        w = get(pre + "self_attn.kv_b_proj.weight").reshape(h, nope + vd, c)
+        w = w[:, :nope] if part == 0 else w[:, nope:]
+        return w.reshape(-1, c).T
+
+    def layer_leaves(layers, extra):
+        def stack(fn):
+            return jnp.asarray(
+                np.stack([fn(_GLM.format(i)) for i in layers]), dtype)
+
+        out = {
+            ours: stack(
+                lambda pre: get(pre + theirs).T if t else get(pre + theirs))
+            for ours, theirs, t in _GLM_LAYER + extra
+        }
+        out.update(
+            wq_b=stack(wq_b), wkv_a=stack(wkv_a),
+            wk_b=stack(lambda pre: kv_b(pre, 0)),
+            wv_b=stack(lambda pre: kv_b(pre, 1)),
+        )
+        return out, stack
+
+    k = cfg.first_k_dense
+    blocks, stack = layer_leaves(range(k, cfg.n_layers), _GLM_SPARSE)
+    for ours, theirs in _OLMOE_EXPERT_LEAVES:
+        blocks[ours] = stack(
+            lambda pre: np.stack([
+                get(f"{pre}mlp.experts.{cfg.expert_offset + e}.{theirs}.weight").T
+                for e in range(cfg.n_experts)
+            ]))
+    if k:
+        lead, _ = layer_leaves(range(k), _GLM_DENSE)
+        blocks.update({DENSE_PREFIX + n: w for n, w in lead.items()})
+    return {
+        "embed": jnp.asarray(get("model.embed_tokens.weight"), dtype),
+        "blocks": blocks,
+        "final_ln": jnp.asarray(get("model.norm.weight"), dtype),
+        "lm_head": jnp.asarray(get("lm_head.weight").T, dtype),
+    }
+
+
+def _glm4_moe_lite_params_to_sd(cfg, params):
+    from areal_tpu.base.distributed import to_host
+
+    def host(x):
+        return to_host(x).astype(np.float32, copy=False)
+
+    h, nope, c = cfg.n_q_heads, cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    back = _glm_rope_order(cfg, inverse=True)
+    every = {n: host(w) for n, w in params["blocks"].items()}
+    out = {
+        "model.embed_tokens.weight": host(params["embed"]),
+        "model.norm.weight": host(params["final_ln"]),
+        "lm_head.weight": np.ascontiguousarray(host(params["lm_head"]).T),
+    }
+
+    def write(blocks, layers, extra):
+        for j, i in enumerate(layers):
+            pre = _GLM.format(i)
+            for ours, theirs, t in _GLM_LAYER + extra:
+                w = blocks[ours][j]
+                out[pre + theirs] = np.ascontiguousarray(w.T) if t else w
+            w = blocks["wq_b"][j].T.reshape(h, cfg.head_dim, -1)
+            out[pre + "self_attn.q_b_proj.weight"] = np.concatenate(
+                [w[:, :nope], w[:, nope:][:, back]], axis=1
+            ).reshape(h * cfg.head_dim, -1)
+            w = blocks["wkv_a"][j].T
+            out[pre + "self_attn.kv_a_proj_with_mqa.weight"] = np.concatenate(
+                [w[:c], w[c:][back]])
+            out[pre + "self_attn.kv_b_proj.weight"] = np.concatenate(
+                [blocks["wk_b"][j].T.reshape(h, nope, c),
+                 blocks["wv_b"][j].T.reshape(h, cfg.v_head_dim, c)], axis=1
+            ).reshape(-1, c)
+
+    k = cfg.first_k_dense
+    sparse = range(k, cfg.n_layers)
+    write(every, sparse, _GLM_SPARSE)
+    for ours, theirs in _OLMOE_EXPERT_LEAVES:
+        for j, i in enumerate(sparse):
+            for e in range(cfg.n_experts):
+                out[
+                    f"{_GLM.format(i)}mlp.experts.{cfg.expert_offset + e}."
+                    f"{theirs}.weight"
+                ] = np.ascontiguousarray(every[ours][j, e].T)
+    if k:
+        lead = {
+            n[len(DENSE_PREFIX):]: w for n, w in every.items()
+            if n.startswith(DENSE_PREFIX)
+        }
+        write(lead, range(k), _GLM_DENSE)
+    return out
+
+
+register_hf_family(
+    HFFamily(
+        "glm4_moe_lite",
+        _glm4_moe_lite_config_from_hf,
+        _glm4_moe_lite_config_to_hf,
+        params_from_sd=_glm4_moe_lite_params_from_sd,
+        params_to_sd=_glm4_moe_lite_params_to_sd,
+    )
+)
+
+
 # ---------------- gpt2 ----------------
 # Different lineage: learned positions, LayerNorm with bias, fused c_attn,
 # plain (non-gated) gelu MLP, biases everywhere, Conv1D weights stored
@@ -949,6 +1220,8 @@ def infer_model_type(cfg: ModelConfig) -> str:
         return "gpt2"
     if cfg.is_hybrid:
         return "qwen3_next"
+    if cfg.is_latent:
+        return "glm4_moe_lite"
     if cfg.is_moe:
         return "olmoe" if cfg.qk_norm else "mixtral"
     if cfg.rms_norm_offset:

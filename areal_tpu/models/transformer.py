@@ -20,7 +20,8 @@ Functions:
     init_kv_cache(cfg, b, s_max)                           -> cache
     prefill(params, cfg, tokens, segment_ids, cache)
     decode_step(params, cfg, tokens, positions, cache, slot, valid_from)
-        — the static decode program's step over a dense window
+        — the static decode program's step over a dense window (per-head
+          k/v, or one latent row a token: `cfg.is_latent`)
     init_paged_kv_cache(cfg, n_pages, page_size)           -> pool
     decode_step_ragged_paged(params, cfg, tokens, positions, pool,
                              page_table, row_of)
@@ -35,7 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from areal_tpu.models.config import ModelConfig
+from areal_tpu.models.config import DENSE_PREFIX, ModelConfig
 from areal_tpu.models.linear_attention import (
     LINEAR_LEAVES,
     init_linear_attn,
@@ -44,6 +45,7 @@ from areal_tpu.models.linear_attention import (
 )
 from areal_tpu.ops.attention import (
     decode_attention,
+    latent_decode_attention,
     packed_attention,
     ragged_paged_attention,
     repeat_kv,
@@ -69,7 +71,8 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             * (fan_in**-0.5)
         ).astype(dtype)
 
-    L, D, F = cfg.n_layers, cfg.hidden_dim, cfg.intermediate_dim
+    # The SCANNED layers: all of them but `first_k_dense` leading ones.
+    L, D, F = cfg.n_scan_layers, cfg.hidden_dim, cfg.intermediate_dim
     # A hybrid stack keeps ONE leading stack axis on every leaf (what the
     # sharding rules, the hand-back and the references read): the norms and
     # the MLP of all L layers, the attention leaves of its LA = n_periods
@@ -79,26 +82,61 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     # A (1 + w) norm starts at w = 0, a plain one at w = 1: scale one.
     norm_init = jnp.zeros if cfg.rms_norm_offset else jnp.ones
     ks = jax.random.split(k_blocks, 8)
+
+    def attn_leaves(n, ks):
+        """The attention leaves of `n` stacked layers."""
+        if cfg.is_latent:
+            H, rq, rkv = cfg.n_q_heads, cfg.q_lora_rank, cfg.kv_lora_rank
+            kl = jax.random.split(ks[5], 3)
+            return {
+                "wq_a": dense(ks[0], (n, D, rq), D),
+                "q_a_norm": norm_init((n, rq), dtype),
+                "wq_b": dense(kl[0], (n, rq, cfg.q_dim), rq),
+                "wkv_a": dense(ks[1], (n, D, cfg.latent_dim), D),
+                "kv_a_norm": norm_init((n, rkv), dtype),
+                "wk_b": dense(kl[1], (n, rkv, H * cfg.qk_nope_head_dim), rkv),
+                "wv_b": dense(kl[2], (n, rkv, H * cfg.v_head_dim), rkv),
+                "wo": dense(ks[3], (n, cfg.q_dim, D), cfg.q_dim),
+            }
+        out = {
+            "wq": dense(ks[0], (n, D, cfg.q_dim), D),
+            "wk": dense(ks[1], (n, D, cfg.kv_dim), D),
+            "wv": dense(ks[2], (n, D, cfg.kv_dim), D),
+            "wo": dense(ks[3], (n, cfg.q_dim, D), cfg.q_dim),
+        }
+        if cfg.qkv_bias:
+            out["bq"] = jnp.zeros((n, cfg.q_dim), dtype)
+            out["bk"] = jnp.zeros((n, cfg.kv_dim), dtype)
+            out["bv"] = jnp.zeros((n, cfg.kv_dim), dtype)
+        if cfg.qk_norm and cfg.qk_norm_per_head:
+            out["q_norm"] = norm_init((n, cfg.head_dim), dtype)
+            out["k_norm"] = norm_init((n, cfg.head_dim), dtype)
+        elif cfg.qk_norm:
+            out["q_norm"] = jnp.ones((n, cfg.q_dim), dtype)
+            out["k_norm"] = jnp.ones((n, cfg.kv_dim), dtype)
+        if cfg.attn_gate:
+            out["wqg"] = dense(ks[5], (n, D, cfg.q_dim), D)
+        return out
+
     blocks = {
         "ln1": norm_init((L, D), dtype),
-        "wq": dense(ks[0], (LA, D, cfg.q_dim), D),
-        "wk": dense(ks[1], (LA, D, cfg.kv_dim), D),
-        "wv": dense(ks[2], (LA, D, cfg.kv_dim), D),
-        "wo": dense(ks[3], (LA, cfg.q_dim, D), cfg.q_dim),
+        **attn_leaves(LA, ks),
         "ln2": norm_init((L, D), dtype),
     }
-    if cfg.qkv_bias:
-        blocks["bq"] = jnp.zeros((LA, cfg.q_dim), dtype)
-        blocks["bk"] = jnp.zeros((LA, cfg.kv_dim), dtype)
-        blocks["bv"] = jnp.zeros((LA, cfg.kv_dim), dtype)
-    if cfg.qk_norm and cfg.qk_norm_per_head:
-        blocks["q_norm"] = norm_init((LA, cfg.head_dim), dtype)
-        blocks["k_norm"] = norm_init((LA, cfg.head_dim), dtype)
-    elif cfg.qk_norm:
-        blocks["q_norm"] = jnp.ones((LA, cfg.q_dim), dtype)
-        blocks["k_norm"] = jnp.ones((LA, cfg.kv_dim), dtype)
-    if cfg.attn_gate:
-        blocks["wqg"] = dense(ks[5], (LA, D, cfg.q_dim), D)
+    if cfg.first_k_dense:
+        # The leading dense layers: their own leaves, stacked [K, ...]
+        # under `dense_*`, the MLP at the dense width.
+        K = cfg.first_k_dense
+        kd = jax.random.split(jax.random.fold_in(k_blocks, 1), 9)
+        lead = {
+            "ln1": norm_init((K, D), dtype),
+            **attn_leaves(K, kd),
+            "ln2": norm_init((K, D), dtype),
+            "wg": dense(kd[6], (K, D, F), D),
+            "wu": dense(kd[7], (K, D, F), D),
+            "wd": dense(kd[8], (K, F, D), F),
+        }
+        blocks.update({DENSE_PREFIX + n: w for n, w in lead.items()})
     if cfg.is_hybrid:
         blocks.update(init_linear_attn(cfg, ks[6], L - LA, dense))
     if cfg.norm_type == "layernorm":
@@ -113,6 +151,14 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         E, FM = cfg.n_experts, cfg.moe_intermediate_dim
         km = jax.random.split(ks[4], 4)
         blocks["router"] = dense(km[0], (L, D, cfg.router_width), D)
+        if cfg.moe_score_func == "sigmoid":
+            # Not trained; drawn as the configuration says (zeros unless it
+            # states a draw: `router_bias_init_std`).
+            blocks["router_bias"] = (
+                cfg.router_bias_init_std * jax.random.normal(
+                    jax.random.fold_in(km[0], 1), (L, cfg.router_width)
+                )
+            ).astype(dtype)
         blocks["wg"] = dense(km[1], (L, E, D, FM), D)
         blocks["wu"] = dense(km[2], (L, E, D, FM), D)
         blocks["wd"] = dense(km[3], (L, E, FM, D), FM)
@@ -122,7 +168,8 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             blocks["ws_g"] = dense(kx[0], (L, D, FS), D)
             blocks["ws_u"] = dense(kx[1], (L, D, FS), D)
             blocks["ws_d"] = dense(kx[2], (L, FS, D), FS)
-            blocks["ws_gate"] = dense(kx[3], (L, D, 1), D)
+            if cfg.shared_expert_gated:
+                blocks["ws_gate"] = dense(kx[3], (L, D, 1), D)
     else:
         km = jax.random.split(ks[4], 3)
         blocks["wg"] = dense(km[0], (L, D, F), D)
@@ -239,9 +286,21 @@ def _attn_out(
     blk: Params,
     cfg: ModelConfig,
     gate: Optional[jax.Array] = None,
+    absorbed: bool = False,
 ) -> jax.Array:
     if gate is not None:  # qwen3_next: o_proj(attn * sigmoid(gate))
         a = a * jax.nn.sigmoid(gate)
+    if absorbed:
+        # Latent attention's absorbed decode step: `a` is the weighted sum
+        # of latent rows per head [.., H * kv_lora_rank]; the value
+        # up-projection comes after it.
+        with jax.named_scope("absorb_out"):
+            h, c = cfg.n_q_heads, cfg.kv_lora_rank
+            a = jnp.einsum(
+                "...hc,chv->...hv",
+                a.reshape(*a.shape[:-1], h, c),
+                blk["wv_b"].reshape(c, h, cfg.v_head_dim),
+            ).reshape(*a.shape[:-1], cfg.q_dim)
     y = a @ blk["wo"]
     if cfg.proj_bias:
         y = y + blk["bo"]
@@ -285,6 +344,8 @@ def _moe_route(x: jax.Array, blk: Params, cfg: ModelConfig):
     is index `n_experts` (sorts last, one-hot all zero), so every dispatch
     below computes this rank's part of the sum and nothing for the rest."""
     router_logits = (x.astype(jnp.float32)) @ blk["router"].astype(jnp.float32)  # [T, E]
+    if cfg.moe_score_func == "sigmoid":
+        return _moe_route_sigmoid(router_logits, blk, cfg)
     probs = jax.nn.softmax(router_logits, axis=-1)
     top_w, top_idx = jax.lax.top_k(probs, cfg.n_experts_per_tok)  # [T, k]
     if cfg.moe_norm_topk:
@@ -295,9 +356,7 @@ def _moe_route(x: jax.Array, blk: Params, cfg: ModelConfig):
             1.0 / x.shape[0]
         )
         aux = width * jnp.sum(load * jnp.mean(probs, axis=0))
-        local = top_idx - cfg.expert_offset
-        held = (local >= 0) & (local < cfg.n_experts)
-        top_idx = jnp.where(held, local, cfg.n_experts)
+        top_idx = _local_numbering(top_idx, cfg)
         one_hot = jax.nn.one_hot(top_idx, cfg.n_experts, dtype=probs.dtype)
         return top_w, top_idx, one_hot, aux
     one_hot = jax.nn.one_hot(top_idx, cfg.n_experts, dtype=probs.dtype)  # [T,k,E]
@@ -306,6 +365,35 @@ def _moe_route(x: jax.Array, blk: Params, cfg: ModelConfig):
     importance = jnp.mean(probs, axis=0)
     aux = cfg.n_experts * jnp.sum(load * importance)
     return top_w, top_idx, one_hot, aux
+
+
+def _local_numbering(top_idx: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """A rank's share: the chosen experts numbered over the `n_experts`
+    held here; a choice held elsewhere is `n_experts` (sorts last, one-hot
+    all zero)."""
+    local = top_idx - cfg.expert_offset
+    held = (local >= 0) & (local < cfg.n_experts)
+    return jnp.where(held, local, cfg.n_experts)
+
+
+def _moe_route_sigmoid(router_logits: jax.Array, blk: Params, cfg: ModelConfig):
+    """`_moe_route` for sigmoid scores with a choice bias (deepseek_v3
+    `noaux_tc`, one group): the top k are chosen by score + `router_bias`
+    and weighted by their SCORE, renormalised where `moe_norm_topk` says
+    so, then scaled by `moe_routed_scale`.  The bias takes no gradient (it
+    reaches the indices only) and there is no auxiliary loss.  A rank's
+    share numbers its choices as `_moe_route` does."""
+    scores = jax.nn.sigmoid(router_logits)
+    bias = jax.lax.stop_gradient(blk["router_bias"]).astype(jnp.float32)
+    _, top_idx = jax.lax.top_k(scores + bias, cfg.n_experts_per_tok)
+    top_w = jnp.take_along_axis(scores, top_idx, axis=-1)
+    if cfg.moe_norm_topk:
+        top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
+    top_w = top_w * cfg.moe_routed_scale
+    if cfg.expert_share:
+        top_idx = _local_numbering(top_idx, cfg)
+    one_hot = jax.nn.one_hot(top_idx, cfg.n_experts, dtype=scores.dtype)
+    return top_w, top_idx, one_hot, jnp.zeros((), jnp.float32)
 
 
 def _experts_dense(x, top_w, top_idx, one_hot, blk: Params, cfg: ModelConfig):
@@ -528,7 +616,10 @@ def _mlp_moe(
     if cfg.shared_expert_dim:
         with jax.named_scope("shared"):
             hid = jax.nn.silu(x @ blk["ws_g"]) * (x @ blk["ws_u"])
-            out = out + jax.nn.sigmoid(x @ blk["ws_gate"]) * (hid @ blk["ws_d"])
+            if cfg.shared_expert_gated:
+                out = out + jax.nn.sigmoid(x @ blk["ws_gate"]) * (hid @ blk["ws_d"])
+            else:
+                out = out + hid @ blk["ws_d"]
     return out.reshape(b, s, d), aux, counts
 
 
@@ -548,7 +639,10 @@ def _block_forward(
     the real tokens [E] int32; None for a dense MLP)."""
     b, s, d = x.shape
     h = _norm(x, blk["ln1"], blk.get("ln1_b"), cfg)
-    q, k, v = _block_kv(h, blk, cfg, cos, sin)
+    if cfg.is_latent:
+        q, k, v, _ = _latent_qkv(h, blk, cfg, cos, sin)
+    else:
+        q, k, v = _block_kv(h, blk, cfg, cos, sin)
     if cp_manual is None and cp_mesh is None:
         attn = packed_attention(
             q, k, v, segment_ids, causal=True, use_flash=use_flash
@@ -617,13 +711,38 @@ def _block_mlp(
     attn_out = checkpoint_name(attn_out, "attn_out")
     x = x + attn_out
     h2 = _norm(x, blk["ln2"], blk.get("ln2_b"), cfg)
-    if cfg.is_moe:
+    if _is_sparse(cfg, blk):
         mlp_out, aux, counts = _mlp_moe(h2, blk, cfg, valid=segment_ids > 0)
     else:
         mlp_out, aux = _mlp_dense(h2, blk, cfg), jnp.zeros((), jnp.float32)
         counts = None
     mlp_out = checkpoint_name(mlp_out, "mlp_out")
     return x + mlp_out, aux, counts
+
+
+def _is_sparse(cfg: ModelConfig, blk: Params) -> bool:
+    """Whether this layer's MLP is the mixture of experts: every layer of
+    a MoE model but its leading dense ones, which carry no router."""
+    return cfg.is_moe and (not cfg.first_k_dense or "router" in blk)
+
+
+def _lead_layers(cfg: ModelConfig, blocks: Params) -> list:
+    """The leading dense layers' leaves, one dict a layer under the
+    layer's own names; [] for a model without any."""
+    return [
+        {
+            n[len(DENSE_PREFIX):]: w[i]
+            for n, w in blocks.items() if n.startswith(DENSE_PREFIX)
+        }
+        for i in range(cfg.first_k_dense)
+    ]
+
+
+def _scanned(cfg: ModelConfig, blocks: Params) -> Params:
+    """The leaves the layer scan slices: all but the leading dense layers'."""
+    if not cfg.first_k_dense:
+        return blocks
+    return {n: w for n, w in blocks.items() if not n.startswith(DENSE_PREFIX)}
 
 
 _ZIGZAG_SNAPSHOT: "Optional[bool]" = None
@@ -666,6 +785,9 @@ def _backbone(
             "sharding only: the chunked delta rule has no ring over a "
             "split sequence, and the pipeline has no stage of periods"
         )
+
+    if cfg.is_latent and (cp_mesh is not None or pp_mesh is not None):
+        raise LatentLayoutError(_NO_LATENT_LAYOUT)
 
     if pp_mesh is not None:
         from areal_tpu.parallel.pipeline import pipelined_blocks
@@ -774,6 +896,20 @@ class HybridLayoutError(NotImplementedError):
     yet, refused by name rather than run wrong."""
 
 
+class LatentLayoutError(NotImplementedError):
+    """A layout or plane latent attention (a cache of latent rows, an
+    absorbed decode step, leading dense layers before the scan) cannot run
+    on yet, refused by name rather than run wrong."""
+
+
+_NO_LATENT_LAYOUT = (
+    "latent attention and leading dense layers run under data and fsdp "
+    "sharding only: the heads of the low-rank projections are not split "
+    "over `model`, the ring over a split sequence and the pipeline's "
+    "stages were not tested with them (PERF.md section 7)"
+)
+
+
 # Leaves only a period's full-attention layer has (stacked [n_periods, ...]
 # in a hybrid model); `LINEAR_LEAVES` are the linear layers' ([n_linear,
 # ...]); every other block leaf is per layer ([n_layers, ...]).
@@ -834,7 +970,7 @@ def _all_layers(cfg: ModelConfig, stacked):
     """[P, n, ...] off a scan over periods -> [L, ...]."""
     if stacked is None or cfg.full_attn_interval == 1:
         return stacked
-    return stacked.reshape(cfg.n_layers, *stacked.shape[2:])
+    return stacked.reshape(cfg.n_scan_layers, *stacked.shape[2:])
 
 
 def _period_blocks(
@@ -868,7 +1004,11 @@ def _period_blocks(
             counts.append(c)
         return y, (aux, _period_stack(cfg, counts))
 
-    x, (auxes, counts) = jax.lax.scan(body, x, _period_view(cfg, blocks))
+    for blk in _lead_layers(cfg, blocks):  # dense MLP: no aux, no counts
+        x, _, _ = full(x, blk)
+    x, (auxes, counts) = jax.lax.scan(
+        body, x, _period_view(cfg, _scanned(cfg, blocks))
+    )
     return x, auxes, _all_layers(cfg, counts)
 
 
@@ -1004,20 +1144,26 @@ class KVCache:
     its softmax-attention layers alone (L = n_periods), and for each Gated
     DeltaNet layer a recurrent `state` [n_linear, B, hv, dk, dv] in fp32
     plus the causal conv's last inputs `conv` [n_linear, B, K-1, C].  Both
-    are None for every other model."""
+    are None for every other model.
 
-    k: jax.Array
-    v: jax.Array
+    Latent attention keeps neither k nor v: `latent` [L, B, S_max,
+    kv_lora_rank + qk_rope_head_dim] holds ONE row a token and layer, the
+    normed latent vector beside the roped key part all heads share, and
+    the decode step attends over the rows themselves (`decode_step`)."""
+
+    k: Optional[jax.Array]
+    v: Optional[jax.Array]
     state: Optional[jax.Array] = None
     conv: Optional[jax.Array] = None
+    latent: Optional[jax.Array] = None
 
     @property
     def s_max(self) -> int:
-        return self.k.shape[2]
+        return (self.latent if self.k is None else self.k).shape[2]
 
 
 jax.tree_util.register_dataclass(
-    KVCache, data_fields=["k", "v", "state", "conv"], meta_fields=[]
+    KVCache, data_fields=["k", "v", "state", "conv", "latent"], meta_fields=[]
 )
 
 
@@ -1063,8 +1209,11 @@ def _cache_update(kc, vc, ksc, vsc, k, v, rows, rows_s, quant: bool):
 def init_kv_cache(
     cfg: ModelConfig, batch: int, s_max: int, dtype=None
 ) -> KVCache:
-    shape = (cfg.n_periods, batch, s_max, cfg.n_kv_heads, cfg.head_dim)
     dtype = dtype or cfg.dtype
+    if cfg.is_latent:
+        return KVCache(k=None, v=None, latent=jnp.zeros(
+            (cfg.n_layers, batch, s_max, cfg.latent_dim), dtype))
+    shape = (cfg.n_periods, batch, s_max, cfg.n_kv_heads, cfg.head_dim)
     cache = KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
     if cfg.is_hybrid:
         nl = cfg.n_linear_layers
@@ -1119,7 +1268,72 @@ def _attn_gate(h: jax.Array, blk: Params, cfg: ModelConfig):
 
 
 def _rope_dim(cfg: ModelConfig) -> int:
+    if cfg.is_latent:
+        return cfg.qk_rope_head_dim
     return cfg.rotary_dim or cfg.head_dim
+
+
+def _latent_q_row(
+    h: jax.Array, blk: Params, cfg: ModelConfig, cos: jax.Array, sin: jax.Array
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Latent attention's two low-rank projections of h [B, S, D] ->
+    (q_nope [B, S, H, nope], roped q_pe [B, S, H, rope], the cache's row
+    [B, S, kv_lora_rank + rope]: the normed latent vector beside the roped
+    key part all heads share)."""
+    b, s, _ = h.shape
+    nope, c = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    with jax.named_scope("q_lora"):
+        c_q = rms_norm(h @ blk["wq_a"], blk["q_a_norm"], cfg.rms_norm_eps)
+        q = (c_q @ blk["wq_b"]).reshape(b, s, cfg.n_q_heads, cfg.head_dim)
+    with jax.named_scope("kv_lora"):
+        kv = h @ blk["wkv_a"]
+        c_kv = rms_norm(kv[..., :c], blk["kv_a_norm"], cfg.rms_norm_eps)
+        q_pe, k_pe = apply_rotary(
+            q[..., nope:], kv[..., None, c:], cos, sin
+        )
+        row = jnp.concatenate([c_kv, k_pe[..., 0, :]], axis=-1)
+    return q[..., :nope], q_pe, row
+
+
+@jax.named_scope("layer/attn_qkv")
+def _latent_qkv(
+    h: jax.Array, blk: Params, cfg: ModelConfig, cos: jax.Array, sin: jax.Array
+) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """The MATERIALISED form (train, prefill): keys and values of every
+    head up-projected from the latent vector -> (q, k, v [B, S, H,
+    head_dim], the cache's row).  The kernels see plain multi-head
+    attention of one width."""
+    b, s, _ = h.shape
+    hq, c = cfg.n_q_heads, cfg.kv_lora_rank
+    q_nope, q_pe, row = _latent_q_row(h, blk, cfg, cos, sin)
+    with jax.named_scope("up_kv"):
+        c_kv, k_pe = row[..., :c], row[..., None, c:]
+        k_nope = (c_kv @ blk["wk_b"]).reshape(b, s, hq, cfg.qk_nope_head_dim)
+        v = (c_kv @ blk["wv_b"]).reshape(b, s, hq, cfg.v_head_dim)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_pe, (b, s, hq, k_pe.shape[-1]))],
+            axis=-1,
+        )
+        q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    return q, k, v, row
+
+
+@jax.named_scope("layer/attn_qkv")
+def _latent_q_absorbed(
+    h: jax.Array, blk: Params, cfg: ModelConfig, cos: jax.Array, sin: jax.Array
+) -> Tuple[jax.Array, jax.Array]:
+    """The ABSORBED form (decode): the query carried into the latent space,
+    q~_h = q_nope,h W_k,h^T, beside its roped part -> (a query over latent
+    rows [B, S, H, kv_lora_rank + rope], the new token's row).  Its scores
+    against the rows are q_nope . k_nope + q_pe . k_pe in another order."""
+    q_nope, q_pe, row = _latent_q_row(h, blk, cfg, cos, sin)
+    with jax.named_scope("absorb_q"):
+        c = cfg.kv_lora_rank
+        q_lat = jnp.einsum(
+            "bshn,chn->bshc", q_nope,
+            blk["wk_b"].reshape(c, cfg.n_q_heads, cfg.qk_nope_head_dim),
+        )
+        return jnp.concatenate([q_lat, q_pe], axis=-1), row
 
 
 @jax.named_scope("gen/prefill")
@@ -1142,12 +1356,19 @@ def prefill(
 
     def mlp(y, blk):
         h2 = _norm(y, blk["ln2"], blk.get("ln2_b"), cfg)
-        return y + (_mlp_moe(h2, blk, cfg)[0] if cfg.is_moe else _mlp_dense(h2, blk, cfg))
+        return y + (_mlp_moe(h2, blk, cfg)[0] if _is_sparse(cfg, blk) else _mlp_dense(h2, blk, cfg))
 
     def body(carry, layer_in):
+        """-> (y, what the layer leaves in the cache: (k, v), or the one
+        latent row a token of latent attention)."""
         blk = layer_in
         h = _norm(carry, blk["ln1"], blk.get("ln1_b"), cfg)
-        q, k, v = _block_kv(h, blk, cfg, cos, sin)
+        if cfg.is_latent:
+            q, k, v, row = _latent_qkv(h, blk, cfg, cos, sin)
+            left = (row,)
+        else:
+            q, k, v = _block_kv(h, blk, cfg, cos, sin)
+            left = (k, v)
         attn = packed_attention(
             q, k, v, segment_ids, causal=True, use_flash=use_flash
         )
@@ -1155,7 +1376,7 @@ def prefill(
             attn.reshape(*carry.shape[:2], cfg.q_dim), blk, cfg,
             _attn_gate(h, blk, cfg),
         )
-        return mlp(carry + y, blk), (k, v)
+        return mlp(carry + y, blk), left
 
     def period_body(carry, pblk):
         """A period: its linear layers leave their final state and conv
@@ -1174,24 +1395,32 @@ def prefill(
         left = (jnp.stack(states), jnp.stack(tails)) if states else ()
         return y, (*kv, *left)
 
-    x, (ks, vs, *left) = jax.lax.scan(
-        period_body, x, _period_view(cfg, params["blocks"])
+    lead = []  # the leading dense layers' rows come first in the cache
+    for blk in _lead_layers(cfg, params["blocks"]):
+        x, (row,) = body(x, blk)
+        lead.append(row)
+    x, (ks, *left) = jax.lax.scan(
+        period_body, x, _period_view(cfg, _scanned(cfg, params["blocks"]))
     )
-    extra = {}
-    if left:  # [P, n - 1, B, ...] -> [n_linear, B, ...]: the cache's layout
-        extra = dict(
-            state=left[0].reshape(cache.state.shape),
-            conv=left[1].reshape(cache.conv.shape).astype(cache.conv.dtype),
+
+    def fill(buf, new):
+        """The cache buffer with the prompt's entries of every layer."""
+        return jax.lax.dynamic_update_slice(
+            buf, new.astype(buf.dtype), (0,) * buf.ndim
         )
-    new_cache = KVCache(
-        k=jax.lax.dynamic_update_slice(
-            cache.k, ks.astype(cache.k.dtype), (0, 0, 0, 0, 0)
-        ),
-        v=jax.lax.dynamic_update_slice(
-            cache.v, vs.astype(cache.v.dtype), (0, 0, 0, 0, 0)
-        ),
-        **extra,
-    )
+
+    if cfg.is_latent:
+        rows = jnp.concatenate([jnp.stack(lead), ks]) if lead else ks
+        new_cache = KVCache(k=None, v=None, latent=fill(cache.latent, rows))
+    else:
+        vs, *left = left
+        extra = {}
+        if left:  # [P, n - 1, B, ...] -> [n_linear, B, ...]: the cache's layout
+            extra = dict(
+                state=left[0].reshape(cache.state.shape),
+                conv=left[1].reshape(cache.conv.shape).astype(cache.conv.dtype),
+            )
+        new_cache = KVCache(k=fill(cache.k, ks), v=fill(cache.v, vs), **extra)
     x = _final_norm(params, cfg, x)
     # Gather each row's last valid hidden state before the (huge) head matmul.
     # (index of the last nonzero segment: works for left- and right-aligned
@@ -1213,6 +1442,7 @@ def decode_step(
     valid_from: jax.Array,  # [B] int32 — first valid cache slot per row
     with_moe_counts: bool = False,
     experts_in_place: Optional[bool] = None,
+    latent_kernel=None,  # None | bool | Mesh
 ) -> Tuple[jax.Array, ...]:
     """One decode step: write the new token's k/v at cache slot `slot`
     (shared by every row — the right-aligned prompt layout makes the write a
@@ -1238,6 +1468,19 @@ def decode_step(
     experts' weights per kernel and step.  Where the leaves' layer or
     expert axis is sharded they stay in `xs`.  Dense models trace the
     program they always did.
+
+    Latent attention (`cfg.is_latent`) runs its ABSORBED form here: the
+    cache holds one latent row a token (`KVCache.latent`), the query is
+    carried into the latent space, scores and the weighted sum are taken
+    against the rows themselves and the value up-projection comes after
+    (`_latent_q_absorbed`, `latent_decode_attention`, `_attn_out`) — the
+    numbers of the materialised form `prefill` and training run, in
+    another order, and no per-head k/v is ever built (`latent_kernel`:
+    None = the Pallas kernel over the stacked rows on a TPU backend, the
+    XLA form elsewhere; a caller whose mesh spreads the rows over devices
+    passes the MESH and the kernel runs per device on its rows; a bool
+    forces either form).  Leading dense layers step before the scan, through the first
+    layers of the cache.
     """
     b = tokens.shape[0]
     x = _embed(params, cfg, tokens, positions)[:, None, :]  # [B,1,D]
@@ -1247,9 +1490,9 @@ def decode_step(
 
     def mlp(y, blk, layer):
         """-> (y + mlp, rows per expert); `layer` indexes the stacked
-        expert leaves (all n_layers of them)."""
+        expert leaves (all the scanned layers of them)."""
         h2 = _norm(y, blk["ln2"], blk.get("ln2_b"), cfg)
-        if cfg.is_moe:
+        if _is_sparse(cfg, blk):
             mlp_out, _, counts = _mlp_moe(
                 h2, blk, cfg, stacked=stacked, layer=layer
             )
@@ -1257,8 +1500,25 @@ def decode_step(
             mlp_out, counts = _mlp_dense(h2, blk, cfg), None
         return y + mlp_out, counts
 
+    def attend_latent(y, rows, blk, li):
+        """Absorbed latent attention of one token per row through the
+        latent rows of layer li."""
+        h = _norm(y, blk["ln1"], blk.get("ln1_b"), cfg)
+        q, row = _latent_q_absorbed(h, blk, cfg, cos, sin)
+        rows = jax.lax.dynamic_update_slice(
+            rows, row.astype(rows.dtype)[None], (li, 0, slot, 0)
+        )
+        attn = latent_decode_attention(
+            q[:, 0], rows, li, valid_from, slot + 1, cfg.kv_lora_rank,
+            cfg.head_dim**-0.5, use_kernel=latent_kernel,
+        )
+        ao = _attn_out(attn.reshape(b, 1, -1), blk, cfg, absorbed=True)
+        return y + ao, rows, None
+
     def attend(y, kc, vc, blk, li):
         """Softmax attention of one token per row through k/v layer li."""
+        if cfg.is_latent:
+            return attend_latent(y, kc, blk, li)
         h = _norm(y, blk["ln1"], blk.get("ln1_b"), cfg)
         q, k, v = _block_kv(h, blk, cfg, cos, sin)  # q/k/v [B,1,h,d]
         # k/v [B,1,h,d] -> [1,B,1,h,d] written at (layer, :, slot).
@@ -1294,18 +1554,27 @@ def decode_step(
             y, c = mlp(y + mixed, blk, layer(j))
             counts.append(c)
         blk = _period_layer(cfg, pblk, n - 1)
-        y, kc, vc = attend(y, kc, vc, blk, pi)
+        y, kc, vc = attend(y, kc, vc, blk, pi + n_lead if n_lead else pi)
         y, c = mlp(y, blk, layer(n - 1))
         counts.append(c)
         return (y, kc, vc, sc, cc, pi + 1), _period_stack(cfg, counts)
 
+    n_lead = cfg.first_k_dense
+    # k/v, or latent attention's one buffer of rows in k's place.
+    kc, vc = (cache.latent, None) if cfg.is_latent else (cache.k, cache.v)
+    for i, blk in enumerate(_lead_layers(cfg, blocks)):
+        x, kc, vc = attend(x, kc, vc, blk, i)
+        x, _ = mlp(x, blk, None)
     (x, kc, vc, sc, cc, _), counts = jax.lax.scan(
         period_body,
-        (x, cache.k, cache.v, cache.state, cache.conv, jnp.int32(0)),
-        _period_view(cfg, blocks),
+        (x, kc, vc, cache.state, cache.conv, jnp.int32(0)),
+        _period_view(cfg, _scanned(cfg, blocks)),
     )
     counts = _all_layers(cfg, counts)
-    new_cache = KVCache(k=kc, v=vc, state=sc, conv=cc)
+    if cfg.is_latent:
+        new_cache = KVCache(k=None, v=None, latent=kc)
+    else:
+        new_cache = KVCache(k=kc, v=vc, state=sc, conv=cc)
     x = _final_norm(params, cfg, x)
     logits = _head(params, cfg, x)[:, 0]  # [B, V]
     if with_moe_counts:
@@ -1379,6 +1648,8 @@ jax.tree_util.register_dataclass(
 def init_paged_kv_cache(
     cfg: ModelConfig, n_pages: int, page_size: int, dtype=None
 ) -> PagedKVCache:
+    if cfg.is_latent:
+        raise LatentLayoutError(_NO_SERVING_LATENT)
     shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads * cfg.head_dim)
     dtype = dtype or cfg.dtype
     if dtype in (jnp.int8, "int8"):
@@ -1433,6 +1704,15 @@ _NO_SERVING_STATE = (
 )
 
 
+_NO_SERVING_LATENT = (
+    "latent rows have no pages on the serving plane yet, and its chunk has "
+    "no layer before the scan: latent attention and leading dense layers "
+    "generate on the static decode program only (at most max_decode_batch "
+    "requests, no stop sequences, no speculative decoding, max_new_tokens "
+    "within static_path_max_new)"
+)
+
+
 @jax.named_scope("gen/decode_step")
 def decode_step_ragged_paged(
     params: Params,
@@ -1468,6 +1748,8 @@ def decode_step_ragged_paged(
     reach the ragged kernels as in `decode_step` (`experts_in_place`)."""
     if cfg.is_hybrid:
         raise HybridLayoutError(_NO_SERVING_STATE)
+    if cfg.is_latent:
+        raise LatentLayoutError(_NO_SERVING_LATENT)
     t = tokens.shape[0]
     b = page_table.shape[0]
     live = row_of < b

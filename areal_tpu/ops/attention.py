@@ -183,6 +183,74 @@ def _decode_attention_xla(
 
 
 @jax.named_scope("layer/attn")
+def latent_decode_attention(
+    q: jax.Array,  # [B, n_q, c + r] — absorbed queries over latent rows
+    cache: jax.Array,  # [L, B, S_max, c + r] — one latent row a token
+    layer: jax.Array,  # scalar int32 — the layer whose rows are read
+    valid_from: jax.Array,  # [B] int — first valid cache slot per row
+    valid_to: jax.Array,  # scalar/[B] int — one past the last valid slot
+    n_value: int,  # c: the leading columns of a row that are its value
+    scale: float,
+    use_kernel=None,  # None=by backend | bool | Mesh (shard_map the kernel)
+) -> jax.Array:
+    """Single-token decode attention of latent attention's ABSORBED form:
+    every head scores the SAME rows of the STACKED cache's layer `layer`
+    (the normed latent vector beside the shared roped key part) with its
+    own absorbed query, and the weighted sum is taken over the rows' first
+    `n_value` columns -> [B, n_q, c] (the value up-projection comes after,
+    `transformer._attn_out`).  bf16 operands, fp32 accumulation and
+    softmax, as `decode_attention`; no per-head key or value exists
+    anywhere.
+
+    On a TPU backend the Pallas kernel `latent_decode` (`ops/pallas/
+    latent_attention.py`: the rows read in place and once for both
+    products), elsewhere the XLA form below.  `use_kernel`: None, by the
+    backend; a bool forces either; a MESH whose batch axes spread the rows
+    over several devices — rows are independent, so the kernel is
+    `shard_map`ped over (data, fsdp) and each device runs it on its own
+    rows of the cache (the XLA form off a TPU backend, as on one device)."""
+    from jax.sharding import Mesh
+
+    mesh = use_kernel if isinstance(use_kernel, Mesh) else None
+    if use_kernel is None or mesh is not None:
+        from areal_tpu.base.distributed import is_tpu_backend
+
+        use_kernel = is_tpu_backend()
+    if use_kernel:
+        from areal_tpu.ops.pallas.latent_attention import (
+            latent_decode_kernel,
+            latent_decode_kernel_sharded,
+        )
+
+        args = (q, cache, layer, jnp.asarray(valid_from, jnp.int32), valid_to)
+        if mesh is not None:
+            return latent_decode_kernel_sharded(
+                *args, mesh, n_value=n_value, scale=scale)
+        return latent_decode_kernel(*args, n_value=n_value, scale=scale)
+    rows = jax.lax.dynamic_index_in_dim(cache, layer, axis=0, keepdims=False)
+    b = q.shape[0]
+    with jax.named_scope("latent_scores"):
+        logits = jnp.einsum(
+            "bhc,bsc->bhs", q, rows.astype(q.dtype),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [B, n_q, S] fp32
+        idx = jnp.arange(rows.shape[1])
+        valid = (idx[None, :] >= valid_from[:, None]) & (
+            idx[None, :] < jnp.broadcast_to(valid_to, (b,))[:, None]
+        )  # [B, S]
+        logits = jnp.where(valid[:, None, :], logits, NEG_INF)
+        probs = jax.nn.softmax(logits, axis=-1)
+        # Empty live windows: exact zeros, as `decode_attention`.
+        probs = jnp.where(valid.any(axis=-1)[:, None, None], probs, 0.0)
+    with jax.named_scope("latent_out"):
+        out = jnp.einsum(
+            "bhs,bsc->bhc", probs.astype(rows.dtype), rows[..., :n_value],
+            preferred_element_type=jnp.float32,
+        )
+    return out.astype(q.dtype)
+
+
+@jax.named_scope("layer/attn")
 def decode_attention_chunk(
     q: jax.Array,  # [B, Q, n_q, d] — Q consecutive new tokens per row
     k_cache: jax.Array,  # [B, S_max, n_kv, d]

@@ -27,6 +27,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from areal_tpu.base import logging
+from areal_tpu.models.config import DENSE_PREFIX
 from areal_tpu.base.topology import (
     DATA_AXIS,
     FSDP_AXIS,
@@ -90,6 +91,20 @@ _BLOCK_RULES: Dict[str, P] = {
     "la_dt_bias": P(PIPE_AXIS, None),
     "la_norm": P(PIPE_AXIS, None),
     "la_wo": P(PIPE_AXIS, None, FSDP_AXIS),
+    # Latent attention (MLA): ZeRO-sharded over fsdp on the input dim, NOT
+    # split over `model` — the heads sit in the packed output axes of
+    # `wq_b` / `wk_b` / `wv_b` and the latent row is shared by all of them;
+    # `attn_dispatch` refuses a latent model on a mesh with model > 1 by
+    # name.  `wo` takes the rule above.  The sigmoid router's choice bias
+    # is a vector per layer.
+    "wq_a": P(PIPE_AXIS, FSDP_AXIS, None),
+    "q_a_norm": P(PIPE_AXIS, None),
+    "wq_b": P(PIPE_AXIS, FSDP_AXIS, None),
+    "wkv_a": P(PIPE_AXIS, FSDP_AXIS, None),
+    "kv_a_norm": P(PIPE_AXIS, None),
+    "wk_b": P(PIPE_AXIS, FSDP_AXIS, None),
+    "wv_b": P(PIPE_AXIS, FSDP_AXIS, None),
+    "router_bias": P(PIPE_AXIS, None),
 }
 
 _TOP_RULES: Dict[str, P] = {
@@ -117,10 +132,12 @@ def param_pspecs(params: Dict[str, Any]) -> Dict[str, Any]:
         if k == "blocks":
             blocks = {}
             for bk, bv in v.items():
-                if bk in ("wg", "wu", "wd") and np.ndim(bv) == 4:
-                    blocks[bk] = _BLOCK_RULES["moe_" + bk]
+                # A leading dense layer's leaf takes its layer's own rule.
+                name = bk.removeprefix(DENSE_PREFIX)
+                if name in ("wg", "wu", "wd") and np.ndim(bv) == 4:
+                    blocks[bk] = _BLOCK_RULES["moe_" + name]
                 else:
-                    blocks[bk] = _BLOCK_RULES[bk]
+                    blocks[bk] = _BLOCK_RULES[name]
             out[k] = blocks
         else:
             out[k] = _TOP_RULES[k]
@@ -203,6 +220,15 @@ def attn_dispatch(mesh: Mesh, cfg=None):
             "heads, no ring over a split sequence, and a pipeline stage "
             "would have to be whole periods (PERF.md section 7)"
         )
+    if cfg is not None and cfg.is_latent and any(
+        mesh.shape[a] > 1 for a in (MODEL_AXIS, SEQ_AXIS, PIPE_AXIS)
+    ):
+        from areal_tpu.models.transformer import (
+            _NO_LATENT_LAYOUT,
+            LatentLayoutError,
+        )
+
+        raise LatentLayoutError(f"mesh {dict(mesh.shape)}: {_NO_LATENT_LAYOUT}")
     if mesh.devices.size == 1:
         use_flash = None
     else:

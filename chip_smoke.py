@@ -282,6 +282,91 @@ def phase_kernels(geom, on_tpu):
 
     _paged_cell_shape(attention, geom, dt, tol, on_tpu)
 
+    _latent_cell_shape(attention, dt, tol, on_tpu)
+
+
+def _latent_cell_shape(attention, dt, tol, on_tpu, reps=10):
+    """The latent decode kernel against the XLA form on a STACKED latent
+    cache at `glm47f-rollout64-1k`'s shape (64 rows, a 1,280-slot window of
+    576-wide rows, 7 layers, 20 heads; 2 layers of 256 slots in rehearsal):
+    every layer, windows that start late and end early, an empty window's
+    exact zeros; and the time of one sweep over the layers in each form
+    goes to the log."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    b, h, c, r = 64, 20, 512, 64
+    n_layers, s_max = (7, 1280) if on_tpu else (2, 256)
+    rng = np.random.default_rng(7)
+    cache = jnp.asarray(
+        rng.standard_normal((n_layers, b, s_max, c + r)), dt)
+    q = jnp.asarray(rng.standard_normal((b, h, c + r)), dt)
+    lo = rng.integers(0, 160, size=b)
+    hi = rng.integers(256, s_max + 1, size=b)
+    lo[0], hi[0], lo[1], hi[1] = 0, s_max, 9, 9  # whole window; empty
+    lo, hi = jnp.asarray(lo, jnp.int32), jnp.asarray(hi, jnp.int32)
+
+    def sweep(use_kernel):
+        @jax.jit
+        def run(q, cache):
+            def layer(li, acc):
+                return acc + attention.latent_decode_attention(
+                    q, cache, li, lo, hi, c, (c + r) ** -0.5,
+                    use_kernel=use_kernel,
+                ).astype(jnp.float32)
+            return jax.lax.fori_loop(
+                0, n_layers, layer, jnp.zeros((b, h, c), jnp.float32)
+            )
+        return run
+
+    def timed(use_kernel, sweeps):
+        """`sweeps` sweeps over a cache made INSIDE the program, as the
+        decode loop's is: a cache passed in keeps the layout of an entry
+        parameter and XLA copies all 660 MB of it in front of the kernel
+        (`copy bf16[7,64,1280,576]` in a deviceless v5e compile: most of
+        the 3.4 ms a sweep the first form of this log read, PR 38)."""
+        @jax.jit
+        def run(q, key):
+            rows = jax.random.normal(key, cache.shape, dt)
+
+            def layer(i, acc):
+                return acc + attention.latent_decode_attention(
+                    q, rows, i % n_layers, lo, hi, c, (c + r) ** -0.5,
+                    use_kernel=use_kernel,
+                ).astype(jnp.float32)
+            return jax.lax.fori_loop(
+                0, sweeps * n_layers, layer,
+                jnp.zeros((b, h, c), jnp.float32),
+            )
+        return run
+
+    outs, ms = {}, {}
+    key = jax.random.PRNGKey(7)
+    for form, use_kernel in (("xla", False), ("kernel", True)):
+        outs[form] = jax.block_until_ready(sweep(use_kernel)(q, cache))
+        # One sweep's time: the difference of 1 + reps sweeps and 1, so the
+        # cache's making and the dispatch cancel.
+        wall = {}
+        for sweeps in (1, 1 + reps):
+            fn = timed(use_kernel, sweeps)
+            jax.block_until_ready(fn(q, key))
+            t0 = time.perf_counter()
+            jax.block_until_ready(fn(q, key))
+            wall[sweeps] = time.perf_counter() - t0
+        ms[form] = (wall[1 + reps] - wall[1]) / reps * 1e3
+    err = _max_err(outs["xla"], outs["kernel"])
+    check(err <= tol * n_layers,
+          f"latent decode kernel ({n_layers} layers of a stacked cache "
+          f"summed) == XLA form (max err {err:.2e})")
+    check(float(jnp.max(jnp.abs(outs["kernel"][1]))) == 0.0,
+          "latent decode kernel: an empty window's exact zeros")
+    log(f"  latent decode: {n_layers} layers x {b} rows x {s_max} slots, "
+        f"kernel {ms['kernel']:.3f} ms, XLA form {ms['xla']:.3f} ms a sweep "
+        f"(host clock, {reps} sweeps over a cache made in the program"
+        + ("" if on_tpu else "; interpreted on the cpu, no device time")
+        + ")")
+
 
 def _paged_cell_shape(attention, geom, dt, tol, on_tpu, reps=5):
     """The paged attention kernel against the XLA gather form on a STACKED

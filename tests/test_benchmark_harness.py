@@ -4,7 +4,8 @@
 `benchmark/tests/test_ledger_readers.py` (the readers of the program's own
 host watch, step ledger and counters, PR 36) by import, BENCHMARK.json
 against the files it names, the new MoE readers on a made-up reduction, and the CPU
-rehearsal of the OLMoE cell through its config file's `toy` group."""
+rehearsals of the OLMoE, hybrid and latent-attention cells through their
+config files' `toy` groups."""
 
 import json
 import os
@@ -28,13 +29,48 @@ SPEC = files.benchmark_json()
 CELLS = [w["name"] for w in SPEC["workloads"]]
 
 
+GLM_CELL = "glm47f-rollout64-1k"
+# PR 38's entries, the last of `per_layer`: the issue's eight in its
+# order, then the two twins the review asked for (`mfu_gen` and
+# `moe_train_mlp_mfu` over `benchmark/peaks_mla.py`, as the hybrid cell has).
+GLM_ENTRIES = [
+    ("mla_decode_ms", "ms", "lower", "device_trace", "model step",
+     "gen_tokens_per_s"),
+    ("mla_decode_roofline", "%", "higher", "device_trace", "kernels",
+     "gen_tokens_per_s"),
+    ("latent_cache_share", "%", "lower", "program_counter", "generator",
+     "gen_tokens_per_s"),
+    ("mla_train_share", "%", "lower", "device_trace", "model step",
+     "train_tokens_per_s"),
+    ("mla_train_mfu", "%", "higher", "device_trace", "kernels",
+     "train_tokens_per_s"),
+    ("decode_hbm_share_mla", "%", "higher", "device_trace", "model step",
+     "gen_tokens_per_s"),
+    ("mfu_train_mla", "%", "higher", "host_clock", "model step",
+     "train_tokens_per_s"),
+    ("moe_decode_mlp_roofline_mla", "%", "higher", "device_trace", "kernels",
+     "gen_tokens_per_s"),
+    ("mfu_gen_mla", "%", "higher", "host_clock", "model step",
+     "gen_tokens_per_s"),
+    ("moe_train_mlp_mfu_mla", "%", "higher", "device_trace", "kernels",
+     "train_tokens_per_s"),
+]
+
+
 def test_the_new_entries_are_where_the_issue_put_them(monkeypatch):  # noqa: F811
-    """PR 36's case pins ITS eight entries as the last of `per_layer`; an
-    entry appended since (PR 37's `paged_attn_live_page_share`) moves
-    them up.  So: the appended entry where its issue put it, then PR
-    36's case on the list as it stood before — `benchmark/tests/` is not
-    a perf PR's to edit (PERF.md §7)."""
-    last = SPEC["per_layer"][-1]
+    """PR 36's case pins ITS eight entries as the last of `per_layer`;
+    entries appended since (PR 37's `paged_attn_live_page_share`, PR 38's
+    ten for the latent-attention cell) move them up.  So: the appended
+    entries where their issues put them, then PR 36's case on the list as
+    it stood before — `benchmark/tests/` is not a perf or a model_config
+    PR's to edit (PERF.md §7)."""
+    n = len(GLM_ENTRIES)
+    assert SPEC["per_layer"][-n:] == [
+        {"name": name, "unit": unit, "better": better, "source": source,
+         "layer": layer, "moves": moves, "workloads": [GLM_CELL]}
+        for name, unit, better, source, layer, moves in GLM_ENTRIES
+    ]
+    last = SPEC["per_layer"][-n - 1]
     assert last == {
         "name": "paged_attn_live_page_share", "unit": "%", "better": "lower",
         "source": "program_counter", "layer": "generator",
@@ -43,9 +79,49 @@ def test_the_new_entries_are_where_the_issue_put_them(monkeypatch):  # noqa: F81
     assert [
         c for c in CELLS if last in files.metrics_for(c, traced=True)
     ] == ["q1p5b-serving-waves"]
-    before = dict(SPEC, per_layer=SPEC["per_layer"][:-1])
+    before = dict(SPEC, per_layer=SPEC["per_layer"][:-n - 1])
     monkeypatch.setattr(files, "benchmark_json", lambda: before)
     ledger_cases.test_the_new_entries_are_where_the_issue_put_them()
+
+
+def test_the_glm_cell_is_as_the_issue_parametrised_it():
+    """ISSUE 38: one configuration, one cell, eight metrics of its own
+    (and the review's two twins) and its name appended to the lists whose arithmetic holds for it — and to
+    none whose arithmetic (`benchmark/peaks.py`: a GQA layer everywhere,
+    every chosen expert local) is wrong for it."""
+    cell, config, traffic = files.load_cell(GLM_CELL)
+    assert SPEC["workloads"][-1] == {
+        "name": GLM_CELL, "config": "glm-4.7-flash-l7-e8",
+        "traffic": "rollout64-1k", "chips": 1,
+        "why": SPEC["workloads"][-1]["why"],
+    }
+    assert len(SPEC["workloads"][-1]["why"]) <= 200
+    assert len(SPEC["configs"][-1]["why"]) <= 200
+    assert SPEC["configs"][-1]["name"] == "glm-4.7-flash-l7-e8"
+    assert SPEC["configs"][-1]["reduced"] == [
+        "num_hidden_layers", "n_routed_experts", "vocab_size"]
+    assert (cell["route"], cell["timed_steps"], cell["traffic_seed"]) == (
+        "static", 3, 38)
+    assert traffic["n_prompts"] * traffic["group"] == 64
+    assert (traffic["group"], traffic["max_new_tokens"]) == (4, 1024)
+    assert traffic["prompt_len"] == {"dist": "uniform", "lo": 96, "hi": 160}
+    assert traffic["dataset_max_length"] == 256
+    assert traffic["generator"] == "math_prompts"
+    listed = {
+        m["name"] for m in SPEC["end_to_end"] + SPEC["per_layer"]
+        if GLM_CELL in m.get("workloads", [])
+    }
+    assert listed == {name for name, *_ in GLM_ENTRIES} | {
+        "gen_tokens_per_s", "decode_ms_per_step", "decode_loop_ms",
+        "moe_experts_touched", "moe_decode_mlp_ms", "moe_route_share",
+        "moe_local_rows_share",
+    }
+    # Appended, and nothing else of those lists changed: the cell is last.
+    for m in SPEC["end_to_end"] + SPEC["per_layer"]:
+        if GLM_CELL in m.get("workloads", []):
+            assert m["workloads"][-1] == GLM_CELL, m["name"]
+    assert len(SPEC["workloads"]) == 7 and sum(
+        w["chips"] == 4 for w in SPEC["workloads"]) == 1
 
 
 @pytest.mark.parametrize(
@@ -377,3 +453,120 @@ def test_the_hybrid_rooflines_of_the_expert_half_count_the_share():
     from areal_tpu.models.config import tiny_config
     other = dataclasses.replace(run, model_cfg=tiny_config())
     assert moe_decode_mlp_roofline_hybrid.read(other) is None
+
+
+def test_cpu_rehearsal_of_the_glm_cell_is_correct():
+    """The latent-attention cell end to end at toy size (the config's `toy`
+    group shrinks the five MLA sizes, the experts and the share): the
+    static program through the latent cache, the leading dense layer
+    outside the scan, the hand-back of all 34 leaves with the router's
+    bias unchanged, the reference and its check of the generator's own
+    64-slot program for generator and trainer."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=files.ROOT)
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run(
+        [sys.executable, "-m", "benchmark.run", "--workload", GLM_CELL,
+         "--seed", "3000000011", "--seconds", "1", "--trace", "0",
+         "--cpu-rehearsal"],
+        cwd=files.ROOT, env=env, capture_output=True, text=True, timeout=600,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert proc.stdout.strip() == ""  # platform=cpu: no result line
+    lines = proc.stderr.splitlines()
+    out = json.loads(
+        [l for l in lines if "would print: " in l][-1].split("would print: ")[1])
+    assert out["correct"] is True and out["failed"] == 0
+    assert out["attempted"] in (2 * 64, 3 * 64)  # whole steps of 64
+    assert {"gen_tokens_per_s", "train_tokens_per_s", "samples_per_s",
+            "setup_s"} <= set(out["metrics"])
+    check = [l for l in lines if "weight check: " in l][-1]
+    assert "'ok': True" in check and "'leaves': 34" in check, check
+    assert any("glm4_moe_lite reference" in l and "[0, 4) of 8" in l
+               for l in lines)
+    assert any("glm4_moe_lite generator check" in l and l.endswith(" ok")
+               for l in lines)
+
+
+def _glm_run(model_cfg, pool, scopes):
+    from benchmark.run import Run
+
+    step = {"pool": pool, "gen": {"lanes_dispatched": 0},
+            "seq_lens": [12, 12], "prompt_lens": [4, 4],
+            "spans": {"actor:train_step": 1.0, "actor_gen:generate": 1.0}}
+    return Run(
+        cell_name="x", cell={"route": "static"}, config={}, traffic={},
+        model_cfg=model_cfg, chips=1, device_kind="TPU v5 lite",
+        peaks={"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}, seed=0,
+        traced=True, steps=[step],
+        trace={"scope_seconds": scopes, "busy_s": 4.0, "traced_steps": 1,
+               "loop_seconds": {"actor_gen:generate": [0.016]}},
+    )
+
+
+def _glm_readers():
+    from benchmark.metrics import (
+        decode_hbm_share_mla, latent_cache_share, mfu_gen_mla, mfu_train_mla,
+        mla_decode_ms, mla_decode_roofline, mla_train_mfu, mla_train_share,
+        moe_decode_mlp_roofline_mla, moe_train_mlp_mfu_mla,
+    )
+    return locals()
+
+
+def test_the_mla_readers_say_nothing_without_their_scopes_or_counters():
+    """On a program that lacks what PR 38 added (the parent, or any other
+    configuration) every new reader returns None and does not raise; on a
+    latent configuration each reads its scopes and counters."""
+    from areal_tpu.models.config import tiny_config
+    from benchmark import peaks_mla
+    from benchmark.run import model_config
+
+    r = _glm_readers()
+    assert sorted(r) == sorted(name for name, *_ in GLM_ENTRIES)
+    scopes = {
+        "train/grad/layer/mlp": {"fwd": 1.0, "recompute": 0.0, "bwd": 1.0},
+        "train/grad/layer/attn": {"fwd": 0.5, "recompute": 0.5, "bwd": 1.0},
+        "gen/decode_step/layer/attn": {"fwd": 0.008, "recompute": 0.0, "bwd": 0.0},
+    }
+    bare = _glm_run(tiny_config(), {}, scopes)
+    for name, reader in r.items():
+        assert reader.read(bare) is None, name
+    big = model_config(files.load_json("configs", "glm-4.7-flash-l7-e8.json"))
+    pool = {"latent_cache_bytes": 576.0, "kv_cache_bytes_as_heads": 10240.0,
+            "moe_experts_touched": 7.5, "moe_rows_local": 8 * 6 * 1.0,
+            "moe_decode_steps": 8}
+    scopes.update({
+        "gen/decode_step/layer/attn_qkv/absorb_q": {
+            "fwd": 0.004, "recompute": 0.0, "bwd": 0.0},
+        "gen/decode_step/layer/attn_out/absorb_out": {
+            "fwd": 0.004, "recompute": 0.0, "bwd": 0.0},
+        "gen/decode_step/layer/mlp/shared": {
+            "fwd": 0.008, "recompute": 0.0, "bwd": 0.0},
+    })
+    run = _glm_run(big, pool, scopes)
+    assert r["latent_cache_share"].read(run) == 5.625
+    assert r["mla_decode_ms"].read(run) == 2.0  # 16 ms over 8 decode steps
+    assert r["mla_train_share"].read(run) == 50.0
+    floor_ms = 1e3 * peaks_mla.mla_decode_bytes(big, [8.0, 8.0]) / 819e9
+    assert r["mla_decode_roofline"].read(run) == pytest.approx(
+        100 * floor_ms / 2.0)
+    assert r["mla_train_mfu"].read(run) == pytest.approx(
+        100 * peaks_mla.mla_train_flops(big, [12, 12]) / 2.0 / 197e12)
+    assert r["mfu_train_mla"].read(run) == pytest.approx(
+        100 * peaks_mla.flops_train(big, [12, 12]) / 197e12)
+    loop_ms, mlp_ms = 16.0 / 8, 8.0 / 8
+    assert r["decode_hbm_share_mla"].read(run) == pytest.approx(
+        100 * 1e3 * peaks_mla.decode_bytes(big, [8.0, 8.0], 7.5, 1.0)
+        / 819e9 / loop_ms)
+    assert r["moe_decode_mlp_roofline_mla"].read(run) == pytest.approx(
+        100 * 1e3 * peaks_mla.mlp_decode_bytes(big, 2, 7.5, 1.0)
+        / 819e9 / mlp_ms)
+    assert 0 < r["mla_decode_roofline"].read(run) < 100
+    assert r["mfu_gen_mla"].read(run) == pytest.approx(
+        100 * peaks_mla.flops_generate(big, [4, 4], [8, 8]) / 197e12)
+    # Two seconds of `train/grad` + `layer/mlp` (no ragged kernel in this
+    # made-up trace): the MLPs' forward + backward over 24 trained tokens.
+    assert r["moe_train_mlp_mfu_mla"].read(run) == pytest.approx(
+        100 * peaks_mla.mlps_train_flops(big, 24) / 2.0 / 197e12)
+    assert peaks_mla.mlps_train_flops(big, 24) == pytest.approx(3 * 2 * 24 * (
+        6 * (peaks_mla.sparse_mlp_params(big) + 0.5 * big.hidden_dim / 2)
+        + peaks_mla.dense_mlp_params(big)), rel=1e-3)
