@@ -61,30 +61,95 @@ def sample_token(
         tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     else:
         warped = apply_top_p(apply_top_k(scaled, top_k), top_p)
-        # Inverse-CDF draw: ONE uniform per row + a cumsum pass.  The
-        # gumbel-max trick (jax.random.categorical) generates B*V threefry
-        # values — ~3.4 ms/step at a 152k vocab on v5e, the single largest
+        # Inverse-CDF draw: ONE uniform per row.  The gumbel-max trick
+        # (jax.random.categorical) generates B*V threefry values —
+        # ~3.4 ms/step at a 152k vocab on v5e, the single largest
         # decode-step cost outside the weight streaming.
         u = jax.random.uniform(key, (logits.shape[0],), jnp.float32)
-        tok = _inverse_cdf_draw(warped, u)
+        tok, logp = _inverse_cdf_draw(warped, u)
+        if top_k <= 0 and top_p >= 1.0:
+            # No warper: `warped` IS `scaled`, and the draw's own max and
+            # total are the chosen token's logsumexp.
+            return tok, logp
     # Chosen-token logprob via logsumexp (no full-vocab log_softmax write).
     lse = jax.nn.logsumexp(scaled, axis=-1)
     chosen = jnp.take_along_axis(scaled, tok[:, None], axis=-1)[:, 0]
     return tok, chosen - lse
 
 
-def _inverse_cdf_draw(warped: jax.Array, u: jax.Array) -> jax.Array:
-    """One inverse-CDF draw per row from warped logits [B, V], u in [0,1).
+# Tokens to a group of the two-level draw: a vector register's lanes.
+_GROUP = 128
 
-    `r` is kept strictly below the total mass: u*total can round UP to
-    total in fp32, which would select past the last in-support token (and
-    the position clamp would then emit a warper-masked token)."""
-    m = jnp.max(warped, axis=-1, keepdims=True)
-    p = jnp.exp(warped - m)
-    cdf = jnp.cumsum(p, axis=-1)
-    r = jnp.minimum(u * cdf[:, -1], cdf[:, -1] * (1.0 - 1e-6))
-    tok = jnp.sum(cdf <= r[:, None], axis=-1).astype(jnp.int32)
-    return jnp.minimum(tok, warped.shape[-1] - 1)
+
+def _first_above(cum: jax.Array, mass: jax.Array, r: jax.Array) -> jax.Array:
+    """Per row the first entry of positive mass whose running sum `cum`
+    exceeds `r`; the last entry of positive mass where none does (0 for a
+    row of no mass at all).  Never an entry of zero mass, whatever order
+    the scan behind `cum` rounded in."""
+    n = cum.shape[-1]
+    idx = jnp.arange(n, dtype=jnp.int32)
+    live = mass > 0
+    first = jnp.min(jnp.where(live & (cum > r[:, None]), idx, n), axis=-1)
+    last = jnp.max(jnp.where(live, idx, 0), axis=-1)
+    return jnp.minimum(first, last)
+
+
+@jax.named_scope("sample_draw")
+def _inverse_cdf_draw(
+    warped: jax.Array, u: jax.Array
+) -> Tuple[jax.Array, jax.Array]:
+    """One inverse-CDF draw per row from warped logits [B, V], u in [0,1),
+    in two levels; returns (token [B] int32, its log-probability [B] under
+    `warped`'s own softmax).
+
+    The vocabulary is enumerated in its own order and cut into contiguous
+    groups of `_GROUP` tokens (V padded up with columns of no mass).  With
+    p = exp(warped - max) in fp32: (1) every group's mass, one reducing
+    pass; (2) a scan over the V / 128 group masses picks group j with
+    C[j-1] <= r < C[j], r = min(u * total, total * (1 - 1e-6)), total =
+    C[-1]; (3) a scan over group j's own 128 masses picks the token with
+    r - C[j-1].  Only ONE position of a cumulative sum over V was ever
+    used; nothing here scans V.
+
+    The guarantee, at both levels: a token of zero mass (warper-masked,
+    an `NEG_INF` residual, a padding column) is never returned.  `r` is
+    kept strictly below each scan's OWN last value — u * total can round
+    up to total in fp32, and a group's reduced mass and the last value of
+    its scan round apart — and the pick (`_first_above`) only looks at
+    entries of positive mass, so neither that mismatch nor a scan that
+    rounds non-monotonically can select past the last token in support.
+
+    Group j's logits are taken as a sum over the groups masked to j (one
+    non-zero term: exact), not gathered: under a vocabulary-sharded
+    `warped` every pass is local to a shard and only [B, V / 128] masses
+    and one [B, 128] group cross chips."""
+    b, v = warped.shape
+    m = jnp.max(warped, axis=-1)
+    pad = -v % _GROUP
+    if pad:
+        warped = jnp.pad(
+            warped, ((0, 0), (0, pad)), constant_values=-jnp.inf
+        )
+    g = (v + pad) // _GROUP
+    x = warped.reshape(b, g, _GROUP)
+    mass = jnp.sum(jnp.exp(x - m[:, None, None]), axis=-1)  # [B, G]
+    cum = jnp.cumsum(mass, axis=-1)
+    total = cum[:, -1]
+    r = jnp.minimum(u * total, total * (1.0 - 1e-6))
+    j = _first_above(cum, mass, r)
+    groups = jnp.arange(g, dtype=jnp.int32)
+    below = jnp.sum(
+        jnp.where(groups[None, :] == j[:, None] - 1, cum, 0.0), axis=-1
+    )
+    x_j = jnp.sum(
+        jnp.where(groups[None, :, None] == j[:, None, None], x, 0.0), axis=1
+    )  # [B, 128]
+    p_j = jnp.exp(x_j - m[:, None])
+    cum_j = jnp.cumsum(p_j, axis=-1)
+    r_j = jnp.clip(r - below, 0.0, cum_j[:, -1] * (1.0 - 1e-6))
+    k = _first_above(cum_j, p_j, r_j)
+    chosen = jnp.take_along_axis(x_j, k[:, None], axis=-1)[:, 0]
+    return j * _GROUP + k, chosen - (m + jnp.log(total))
 
 
 def spec_accept(
@@ -165,7 +230,7 @@ def spec_accept(
         ) & mask_draft[:, None]
         close_logits = jnp.where(onehot, NEG_INF, close_logits)
         u_res = jax.random.uniform(k_res, (b,))
-        close = _inverse_cdf_draw(close_logits, u_res)
+        close, _ = _inverse_cdf_draw(close_logits, u_res)
         emitted = jnp.concatenate(
             [drafts, jnp.zeros((b, 1), jnp.int32)], axis=1
         )

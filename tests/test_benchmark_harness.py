@@ -30,8 +30,8 @@ CELLS = [w["name"] for w in SPEC["workloads"]]
 
 
 GLM_CELL = "glm47f-rollout64-1k"
-# PR 38's entries, the last of `per_layer`: the issue's eight in its
-# order, then the two twins the review asked for (`mfu_gen` and
+# PR 38's entries, the last of `per_layer` but PR 39's one: the issue's
+# eight in its order, then the two twins the review asked for (`mfu_gen` and
 # `moe_train_mlp_mfu` over `benchmark/peaks_mla.py`, as the hybrid cell has).
 GLM_ENTRIES = [
     ("mla_decode_ms", "ms", "lower", "device_trace", "model step",
@@ -60,17 +60,26 @@ GLM_ENTRIES = [
 def test_the_new_entries_are_where_the_issue_put_them(monkeypatch):  # noqa: F811
     """PR 36's case pins ITS eight entries as the last of `per_layer`;
     entries appended since (PR 37's `paged_attn_live_page_share`, PR 38's
-    ten for the latent-attention cell) move them up.  So: the appended
-    entries where their issues put them, then PR 36's case on the list as
-    it stood before — `benchmark/tests/` is not a perf or a model_config
-    PR's to edit (PERF.md §7)."""
+    ten for the latent-attention cell, PR 39's `sample_draw_ms`) move them
+    up.  So: the appended entries where their issues put them, then PR
+    36's case on the list as it stood before — `benchmark/tests/` is not
+    a perf or a model_config PR's to edit (PERF.md §7)."""
     n = len(GLM_ENTRIES)
-    assert SPEC["per_layer"][-n:] == [
+    assert SPEC["per_layer"][-1] == {
+        "name": "sample_draw_ms", "unit": "ms", "better": "lower",
+        "source": "device_trace", "layer": "model step",
+        "moves": "gen_tokens_per_s",
+        "workloads": next(
+            m["workloads"] for m in SPEC["end_to_end"]
+            if m["name"] == "gen_tokens_per_s"
+        ),
+    }
+    assert SPEC["per_layer"][-n - 1:-1] == [
         {"name": name, "unit": unit, "better": better, "source": source,
          "layer": layer, "moves": moves, "workloads": [GLM_CELL]}
         for name, unit, better, source, layer, moves in GLM_ENTRIES
     ]
-    last = SPEC["per_layer"][-n - 1]
+    last = SPEC["per_layer"][-n - 2]
     assert last == {
         "name": "paged_attn_live_page_share", "unit": "%", "better": "lower",
         "source": "program_counter", "layer": "generator",
@@ -79,7 +88,7 @@ def test_the_new_entries_are_where_the_issue_put_them(monkeypatch):  # noqa: F81
     assert [
         c for c in CELLS if last in files.metrics_for(c, traced=True)
     ] == ["q1p5b-serving-waves"]
-    before = dict(SPEC, per_layer=SPEC["per_layer"][:-n - 1])
+    before = dict(SPEC, per_layer=SPEC["per_layer"][:-n - 2])
     monkeypatch.setattr(files, "benchmark_json", lambda: before)
     ledger_cases.test_the_new_entries_are_where_the_issue_put_them()
 
@@ -114,7 +123,7 @@ def test_the_glm_cell_is_as_the_issue_parametrised_it():
     assert listed == {name for name, *_ in GLM_ENTRIES} | {
         "gen_tokens_per_s", "decode_ms_per_step", "decode_loop_ms",
         "moe_experts_touched", "moe_decode_mlp_ms", "moe_route_share",
-        "moe_local_rows_share",
+        "moe_local_rows_share", "sample_draw_ms",
     }
     # Appended, and nothing else of those lists changed: the cell is last.
     for m in SPEC["end_to_end"] + SPEC["per_layer"]:
@@ -146,6 +155,43 @@ def test_live_page_share_is_live_over_addressed_pages(pools, want):
     for step, pool in zip(run.steps, pools):
         step["pool"] = pool
     assert paged_attn_live_page_share.read(run) == want
+
+
+@pytest.mark.parametrize(
+    "trace,gen,want",
+    [
+        # the serving plane: 704 lanes over a budget of 2 = 352 inner steps
+        ({"scope_seconds": {
+            "gen/serving_chunk/gen/decode_step/head_logprob/sample_draw":
+                {"fwd": 0.1056, "recompute": 0.0, "bwd": 0.0},
+            "gen/serving_chunk/gen/decode_step/head_logprob":
+                {"fwd": 0.5, "recompute": 0.0, "bwd": 0.0}},
+          "traced_steps": 2, "busy_s": 9.0},
+         {"lanes_dispatched": 704, "serving_lane_budget": 2}, 0.15),
+        # the static program: 1,024 new tokens a row
+        ({"scope_seconds": {"gen/decode_step/head_logprob/sample_draw":
+                            {"fwd": 0.0512, "recompute": 0.0, "bwd": 0.0}},
+          "traced_steps": 2, "busy_s": 9.0},
+         {"lanes_dispatched": 0}, 0.025),
+        # a program without the scope (the parent of PR 39)
+        ({"scope_seconds": {"gen/decode_step/head_logprob":
+                            {"fwd": 0.5, "recompute": 0.0, "bwd": 0.0}},
+          "traced_steps": 2, "busy_s": 9.0},
+         {"lanes_dispatched": 0}, None),
+        # an untraced run
+        (None, {"lanes_dispatched": 0}, None),
+    ],
+    ids=["serving", "static", "no_scope", "untraced"],
+)
+def test_sample_draw_ms_is_the_scopes_seconds_per_decode_step(trace, gen, want):
+    from benchmark.metrics import sample_draw_ms
+
+    run = ledger_cases.recorded(ledger_cases.QUIET, walls=(2.0,))
+    run.trace = trace
+    run.steps[-1].update(
+        gen=gen, seq_lens=[1124, 1184], prompt_lens=[100, 160])
+    got = sample_draw_ms.read(run)
+    assert got == (want if want is None else pytest.approx(want))
 
 
 def test_every_name_in_benchmark_json_is_a_cell_and_its_files_resolve():
