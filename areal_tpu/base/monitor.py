@@ -24,9 +24,21 @@ logger = logging.getLogger("monitor")
 
 def _attn_layers(cfg) -> int:
     """Layers with softmax attention: one per period of a hybrid pattern,
-    and every leading dense layer (those lie outside the periods)."""
+    and every leading dense layer (those lie outside the periods); the '*'
+    layers of a pattern of one-branch layers."""
+    if getattr(cfg, "layer_pattern", ""):
+        return cfg.n_attn_layers
     return getattr(cfg, "n_periods", cfg.n_layers) + getattr(
         cfg, "first_k_dense", 0)
+
+
+def _ssm_params(cfg) -> int:
+    """ONE Mamba-2 layer's matmul parameters, its recurrence counted as
+    the multiply-adds a token takes: 2 * d_inner * N for the state (add the
+    token's outer product, read y = S C) — what the decode step does; the
+    chunked form's extra in-chunk products are not counted as useful."""
+    h, di = cfg.hidden_dim, cfg.ssm_inner_dim
+    return h * cfg.ssm_in_dim + di * h + 2 * di * cfg.ssm_state_dim
 
 
 def _attn_params(cfg) -> int:
@@ -54,7 +66,9 @@ def matmul_params(cfg) -> int:
     """Parameters that participate in matmuls for ONE token's forward pass
     (the routed experts and the router for MoE; embedding lookup excluded).
 
-    Per layer KIND in a hybrid pattern: a softmax-attention layer's
+    A pattern of one-branch layers counts each KIND over its own layers
+    (`_ssm_params`, the attention projections, the mixture); per layer
+    KIND in a hybrid pattern: a softmax-attention layer's
     projections (the query's twice where it also gives the output gate), a
     Gated DeltaNet layer's projections plus its recurrence counted as the
     3 * d_k * d_v multiply-adds a value head's state takes per token
@@ -66,7 +80,11 @@ def matmul_params(cfg) -> int:
     h = cfg.hidden_dim
     n_attn = _attn_layers(cfg)
     mixers = n_attn * _attn_params(cfg)
-    if n_attn != cfg.n_layers:
+    pattern = getattr(cfg, "layer_pattern", "")
+    if pattern:
+        # A layer is ONE branch: count each kind over its own layers.
+        mixers += cfg.n_ssm_layers * _ssm_params(cfg)
+    elif n_attn != cfg.n_layers:
         hv = cfg.linear_n_v_heads
         linear = (
             h * (cfg.linear_conv_dim + cfg.linear_value_dim + 2 * hv)
@@ -82,13 +100,13 @@ def matmul_params(cfg) -> int:
         mlp = n_mats * h * inter * held + h * width
         shared = getattr(cfg, "shared_expert_dim", 0)
         if shared:
-            mlp += 3 * h * shared + (
+            mlp += n_mats * h * shared + (
                 h if getattr(cfg, "shared_expert_gated", True) else 0)
     else:
         mlp = n_mats * h * cfg.intermediate_dim
     n_lead = getattr(cfg, "first_k_dense", 0)
-    mlps = (cfg.n_layers - n_lead) * mlp + (
-        n_lead * n_mats * h * cfg.intermediate_dim)
+    n_mlp = cfg.n_moe_layers if pattern else cfg.n_layers - n_lead
+    mlps = n_mlp * mlp + n_lead * n_mats * h * cfg.intermediate_dim
     head = 0 if cfg.is_critic else h * cfg.vocab_size
     return int(mixers + mlps + head)
 

@@ -705,10 +705,14 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                 f"{self.static_path_max_new}), stop={bool(gconfig.stop)}, "
                 f"spec_decode_k={gconfig.spec_decode_k}, inflight={inflight}"
             )
-        if inflight and self.cfg.is_hybrid:
+        if inflight and self.cfg.has_recurrent_state:
             # Never a silent fallback to a plane that would drop the state.
+            why = (
+                tfm._NO_SERVING_STATE if self.cfg.is_hybrid
+                else tfm._NO_SERVING_PATTERN
+            )
             raise tfm.HybridLayoutError(
-                f"{tfm._NO_SERVING_STATE}; this call has {len(reqs)} requests "
+                f"{why}; this call has {len(reqs)} requests "
                 f"for {b_cap} slots, max_new_tokens "
                 f"{gconfig.max_new_tokens} (static_path_max_new "
                 f"{self.static_path_max_new}), stop={bool(gconfig.stop)}, "
@@ -2224,6 +2228,13 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         return None if self.mesh.size == 1 else self.mesh
 
     @property
+    def _expert_kernel(self) -> Optional[bool]:
+        """What the static program's in-place expert matmuls take: None,
+        the backend's choice, on one device; XLA's ragged kernel on a mesh
+        (the Pallas grouped matmul is one device's program)."""
+        return None if self.mesh.size == 1 else False
+
+    @property
     def _expert_leaves_in_place(self) -> bool:
         """Whether the decode programs hand the ragged kernels the stacked
         expert leaves themselves — asked of the placed params, outside
@@ -2294,7 +2305,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             prompt_len[r] = len(toks)
 
         fn = self._get_gen_fn(b, sp, s_total, gconfig, with_cache)
-        if self.cfg.is_hybrid or self.cfg.is_latent:
+        if self.cfg.has_recurrent_state or self.cfg.is_latent:
             # What the cache holds, from shapes alone.
             cache = jax.eval_shape(
                 lambda: tfm.init_kv_cache(
@@ -2305,7 +2316,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             def nbytes(*xs):
                 return sum(x.size * x.dtype.itemsize for x in xs)
 
-            if self.cfg.is_hybrid:  # the two kinds of state
+            if self.cfg.has_recurrent_state:  # the two kinds of state
                 self.last_pool_stats.update(
                     kv_cache_bytes=nbytes(cache.k, cache.v),
                     state_cache_bytes=nbytes(cache.state, cache.conv),
@@ -2352,6 +2363,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         eos = self.eos_token_id
         max_new = g.max_new_tokens
         latent_kernel = self._latent_kernel
+        expert_kernel = self._expert_kernel
 
         @jax.jit
         def gen(params, prompt_tok, prompt_len, key):
@@ -2401,7 +2413,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                 next_logits, cache, *counts = tfm.decode_step(
                     params, cfg, tok, pos, cache, sp + step, valid_from,
                     with_moe_counts=cfg.is_moe, experts_in_place=in_place,
-                    latent_kernel=latent_kernel,
+                    latent_kernel=latent_kernel, expert_kernel=expert_kernel,
                 )
                 if cfg.is_moe:
                     moe = [moe[0] + _moe_step_counters(counts[0], cfg, bsz)]

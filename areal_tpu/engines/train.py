@@ -387,15 +387,27 @@ class TrainEngine(HostOffloadMixin, Engine):
                 total = loss + cfg.moe_aux_loss_coef * aux
                 if cfg.is_moe:
                     stats = {**stats, **_moe_stats(aux, counts)}
-                if cfg.is_hybrid:
+                if cfg.has_recurrent_state:
                     # Every segment start is a restart of the recurrence
                     # and of the conv inside a packed row.
                     seg = batch["segment_ids"]
                     starts = (seg[:, 1:] != seg[:, :-1]) & (seg[:, 1:] > 0)
+                if cfg.is_hybrid:
                     stats = {
                         **stats,
                         "linear_attn/segments_per_row": jnp.mean(
                             (seg[:, 0] > 0) + jnp.sum(starts, axis=-1)
+                        ).astype(jnp.float32),
+                    }
+                if cfg.n_ssm_layers:
+                    # What the chunked scan ran over, summed over the Mamba
+                    # layers: chunks, and the restarts.
+                    n_chunks = seg.shape[0] * -(-seg.shape[1] // cfg.ssm_chunk)
+                    stats = {
+                        **stats,
+                        "ssm/chunks": jnp.float32(cfg.n_ssm_layers * n_chunks),
+                        "ssm/segment_restarts": cfg.n_ssm_layers * (
+                            jnp.sum(seg[:, 0] > 0) + jnp.sum(starts)
                         ).astype(jnp.float32),
                     }
                 return total * loss_scale, stats

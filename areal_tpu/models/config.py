@@ -66,12 +66,17 @@ class ModelConfig:
     # Expert capacity = ceil(T * k / E * this); 1.0 = perfectly balanced.
     moe_capacity_factor: float = 1.25
     # ---- architecture family switches (reference: api/from_hf/*) ----
-    hidden_act: str = "silu"  # silu | gelu | gelu_tanh
+    hidden_act: str = "silu"  # silu | gelu | gelu_tanh | relu2 (relu(x)^2)
     norm_type: str = "rms"  # rms | layernorm (layernorm adds bias params)
     rms_norm_offset: bool = False  # gemma: scale by (1 + w)
     embed_scale: bool = False  # gemma: embeddings scaled by sqrt(hidden)
-    pos_emb: str = "rope"  # rope | learned (gpt2 wpe)
-    mlp_gated: bool = True  # False = plain fc/act/proj (gpt2)
+    # rope | learned (gpt2 wpe) | none (nemotron_h: attention takes no
+    # positions; they reach the model through its Mamba layers)
+    pos_emb: str = "rope"
+    # False = plain fc/act/proj (gpt2).  In a mixture of experts: every
+    # expert and the shared one are down(act(up(x))), two matrices (`wu`,
+    # `wd`) where a gated expert has three.
+    mlp_gated: bool = True
     proj_bias: bool = False  # biases on attn-out + mlp matmuls (gpt2)
     # ---- hybrid layer pattern (qwen3_next) ----
     # Layer i is softmax attention when (i + 1) % full_attn_interval == 0,
@@ -134,6 +139,29 @@ class ModelConfig:
     router_bias_init_std: float = 0.0
     # False: the shared expert's output is added as it is (no sigmoid gate).
     shared_expert_gated: bool = True
+    # ---- a pattern of ONE-BRANCH layers (nemotron_h) ----
+    # One character a layer, in order: "M" a Mamba-2 mixer, "E" the mixture
+    # of experts, "*" softmax attention.  Every layer is x += f(norm(x))
+    # with f that ONE kind (an expert layer has no mixer, a mixer layer no
+    # MLP); the stack is scanned by repeats of the pattern's smallest unit
+    # (`pattern_unit`), a unit's layers unrolled.  "" = every layer is a
+    # mixer and an MLP (every other family).
+    layer_pattern: str = ""
+    # Mamba-2 (SSD): `ssm_n_heads` heads of `ssm_head_dim` channels, each
+    # with a state [head_dim, ssm_state_dim] in fp32; B and C are shared by
+    # the heads of a group (`ssm_n_groups`); a depthwise causal conv WITH
+    # bias over x | B | C; training and prefill run chunks of `ssm_chunk`.
+    ssm_n_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_n_groups: int = 1
+    ssm_state_dim: int = 0
+    ssm_conv_kernel: int = 4
+    ssm_chunk: int = 128
+    # Random weights only (`init_params`): dt_bias is the inverse softplus
+    # of a log-uniform draw on [min, max] floored at `floor`.
+    ssm_dt_min: float = 0.001
+    ssm_dt_max: float = 0.1
+    ssm_dt_floor: float = 0.0001
 
     def __post_init__(self):
         if self.n_scan_layers % self.full_attn_interval:
@@ -151,6 +179,8 @@ class ModelConfig:
                 "mixture-of-experts model (their cache layers are latent "
                 "rows)"
             )
+        if self.layer_pattern:
+            self._check_pattern()
         if self.moe_score_func not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown moe_score_func {self.moe_score_func!r}")
         if self.is_latent:
@@ -175,9 +205,93 @@ class ModelConfig:
                 f"router's {self.router_width} outputs"
             )
 
+    def _check_pattern(self):
+        pattern = self.layer_pattern
+        if "-" in pattern:
+            raise NotImplementedError(
+                f"layer_pattern {pattern!r}: the dense MLP layer kind '-' "
+                "is not built (a layer is 'M', 'E' or '*')"
+            )
+        if set(pattern) - set("ME*") or len(pattern) != self.n_layers:
+            raise ValueError(
+                f"layer_pattern {pattern!r} is not {self.n_layers} "
+                "characters of 'M' (Mamba-2), 'E' (experts), '*' (attention)"
+            )
+        if self.is_hybrid or self.is_latent or self.first_k_dense:
+            raise NotImplementedError(
+                "a pattern of one-branch layers has no Gated DeltaNet "
+                "layers, no latent attention and no leading dense layers"
+            )
+        if "E" in pattern and not self.is_moe:
+            raise ValueError("an 'E' layer needs n_experts > 0")
+        if "M" in pattern and (
+            not (self.ssm_n_heads and self.ssm_head_dim and self.ssm_state_dim)
+            or self.ssm_n_heads % self.ssm_n_groups
+            or self.ssm_inner_dim % self.ssm_n_groups
+        ):
+            raise ValueError(
+                f"an 'M' layer needs ssm_n_heads ({self.ssm_n_heads}) x "
+                f"ssm_head_dim ({self.ssm_head_dim}) channels in whole "
+                f"groups ({self.ssm_n_groups}) and a state "
+                f"({self.ssm_state_dim})"
+            )
+
     @property
     def dtype(self):
         return _DTYPES[self.param_dtype]
+
+    @property
+    def is_pattern(self) -> bool:
+        return bool(self.layer_pattern)
+
+    @property
+    def pattern_unit(self) -> str:
+        """The shortest string the pattern is whole repeats of: what one
+        step of the layer scan unrolls."""
+        pattern = self.layer_pattern
+        for n in range(1, len(pattern) + 1):
+            if len(pattern) % n == 0 and pattern[:n] * (len(pattern) // n) == pattern:
+                return pattern[:n]
+        return pattern
+
+    @property
+    def n_ssm_layers(self) -> int:
+        return self.layer_pattern.count("M")
+
+    @property
+    def n_moe_layers(self) -> int:
+        """Layers with the mixture of experts."""
+        if self.is_pattern:
+            return self.layer_pattern.count("E")
+        return self.n_scan_layers if self.is_moe else 0
+
+    @property
+    def n_attn_layers(self) -> int:
+        """Layers that keep k/v (or latent rows) in the cache."""
+        if self.is_pattern:
+            return self.layer_pattern.count("*")
+        return self.n_periods + self.first_k_dense
+
+    @property
+    def has_recurrent_state(self) -> bool:
+        """Whether some layer carries a state from token to token (Gated
+        DeltaNet, Mamba-2): no slot on the serving plane, no split over
+        `model`, `seq` or `pipe` yet."""
+        return self.is_hybrid or self.n_ssm_layers > 0
+
+    @property
+    def ssm_inner_dim(self) -> int:
+        return self.ssm_n_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels the Mamba conv runs over: x, B and C."""
+        return self.ssm_inner_dim + 2 * self.ssm_n_groups * self.ssm_state_dim
+
+    @property
+    def ssm_in_dim(self) -> int:
+        """in_proj's outputs: z | x B C | dt."""
+        return self.ssm_inner_dim + self.ssm_conv_dim + self.ssm_n_heads
 
     @property
     def is_moe(self) -> bool:
@@ -203,6 +317,8 @@ class ModelConfig:
 
     @property
     def n_periods(self) -> int:
+        if self.is_pattern:
+            return self.n_layers // len(self.pattern_unit)
         return self.n_scan_layers // self.full_attn_interval
 
     @property

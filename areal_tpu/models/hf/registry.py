@@ -13,7 +13,9 @@ Families here (full reference parity, api/from_hf/*): llama, qwen2
 top-k router weights not renormalised), qwen3_next (a hybrid period of
 Gated DeltaNet and gated attention, a gated shared expert), glm4_moe_lite
 (the deepseek_v3 block: latent attention, a sigmoid router with an
-untrained choice bias, an ungated shared expert, leading dense layers).
+untrained choice bias, an ungated shared expert, leading dense layers),
+nemotron_h (a pattern of one-branch layers: Mamba-2 mixers, ungated relu²
+experts behind the sigmoid router, attention without positions).
 """
 
 import dataclasses
@@ -1071,6 +1073,256 @@ register_hf_family(
 )
 
 
+# ---------------- nemotron_h ----------------
+# nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B: `hybrid_override_pattern` gives
+# every layer ONE kind of `mixer` behind ONE norm (`backbone.layers.N.norm`):
+# "M" Mamba-2 (`in_proj` -> [z | x B C | dt], a depthwise `conv1d` WITH
+# bias, `A_log` / `D` / `dt_bias` per head, a gated RMSNorm over groups,
+# `out_proj`), "E" a mixture of ungated relu² experts (`up_proj`,
+# `down_proj`) behind the deepseek_v3 sigmoid router (`gate.weight`,
+# `gate.e_score_correction_bias`) plus one ungated shared expert, "*"
+# attention WITHOUT a positional embedding (`rope_theta` and
+# `partial_rotary_factor` are keys the published module does not read; they
+# are kept as published).  d_inner = mamba_num_heads x mamba_head_dim
+# (`expand` sizes nothing).  The dense-MLP kind "-" is refused by name.  A
+# `share` group cuts the model to one expert-parallel rank as for
+# glm4_moe_lite: `n_routed_experts` is then the number HELD here of
+# `share.router_num_experts`.
+
+
+def _nemotron_h_config_from_hf(hf: dict) -> ModelConfig:
+    for key, fine in (
+        ("attention_bias", False), ("mlp_bias", False), ("use_bias", False),
+        ("mamba_proj_bias", False), ("use_conv_bias", True),
+        ("mlp_hidden_act", "relu2"), ("mamba_hidden_act", "silu"),
+        ("n_group", 1), ("topk_group", 1), ("sliding_window", None),
+        ("residual_in_fp32", False), ("n_shared_experts", 1),
+        ("tie_word_embeddings", False),
+    ):
+        if hf.get(key, fine) != fine:
+            raise NotImplementedError(
+                f"nemotron_h {key}={hf[key]!r} is not modelled"
+            )
+    share = hf.get("share") or {}
+    n_experts = hf["n_routed_experts"]
+    width = share.get("router_num_experts", n_experts)
+    assumed = (hf.get("benchmark") or {}).get("assumed") or {}
+    return ModelConfig(
+        n_layers=hf["num_hidden_layers"],
+        hidden_dim=hf["hidden_size"],
+        n_q_heads=hf["num_attention_heads"],
+        n_kv_heads=hf["num_key_value_heads"],
+        head_dim=hf["head_dim"],
+        intermediate_dim=hf["intermediate_size"],
+        vocab_size=hf["vocab_size"],
+        max_position_embeddings=hf.get("max_position_embeddings", 262144),
+        rope_theta=hf.get("rope_theta", 10000.0),
+        rms_norm_eps=hf.get("layer_norm_epsilon", 1e-5),
+        hidden_act="relu2",
+        mlp_gated=False,
+        pos_emb="none",
+        layer_pattern=hf["hybrid_override_pattern"],
+        ssm_n_heads=hf["mamba_num_heads"],
+        ssm_head_dim=hf["mamba_head_dim"],
+        ssm_n_groups=hf["n_groups"],
+        ssm_state_dim=hf["ssm_state_size"],
+        ssm_conv_kernel=hf["conv_kernel"],
+        ssm_chunk=hf.get("chunk_size", 128),
+        ssm_dt_min=hf.get("time_step_min", 0.001),
+        ssm_dt_max=hf.get("time_step_max", 0.1),
+        ssm_dt_floor=hf.get("time_step_floor", 1e-4),
+        n_experts=n_experts,
+        n_experts_per_tok=hf["num_experts_per_tok"],
+        moe_intermediate_dim=hf["moe_intermediate_size"],
+        moe_norm_topk=bool(hf.get("norm_topk_prob", True)),
+        moe_aux_loss_coef=0.0,
+        moe_score_func="sigmoid",
+        moe_routed_scale=float(hf.get("routed_scaling_factor", 1.0)),
+        router_bias_init_std=float(assumed.get("router_bias_init_std", 0.0)),
+        shared_expert_dim=hf["moe_shared_expert_intermediate_size"],
+        shared_expert_gated=False,
+        n_router_experts=0 if width == n_experts else width,
+        expert_offset=share.get("rank", 0) * n_experts,
+    )
+
+
+def _nemotron_h_config_to_hf(cfg: ModelConfig) -> dict:
+    out = {
+        "model_type": "nemotron_h",
+        "architectures": ["NemotronHForCausalLM"],
+        "torch_dtype": "bfloat16",
+        "num_hidden_layers": cfg.n_layers,
+        "hybrid_override_pattern": cfg.layer_pattern,
+        "hidden_size": cfg.hidden_dim,
+        "num_attention_heads": cfg.n_q_heads,
+        "num_key_value_heads": cfg.n_kv_heads,
+        "head_dim": cfg.head_dim,
+        "intermediate_size": cfg.intermediate_dim,
+        "vocab_size": cfg.vocab_size,
+        "max_position_embeddings": cfg.max_position_embeddings,
+        "rope_theta": cfg.rope_theta,
+        "partial_rotary_factor": 1,
+        "layer_norm_epsilon": cfg.rms_norm_eps,
+        "norm_eps": cfg.rms_norm_eps,
+        "tie_word_embeddings": False,
+        "attention_bias": False,
+        "mlp_bias": False,
+        "use_bias": False,
+        "mamba_proj_bias": False,
+        "use_conv_bias": True,
+        "mlp_hidden_act": "relu2",
+        "mamba_hidden_act": "silu",
+        "mamba_num_heads": cfg.ssm_n_heads,
+        "mamba_head_dim": cfg.ssm_head_dim,
+        "n_groups": cfg.ssm_n_groups,
+        "ssm_state_size": cfg.ssm_state_dim,
+        "conv_kernel": cfg.ssm_conv_kernel,
+        "chunk_size": cfg.ssm_chunk,
+        "time_step_min": cfg.ssm_dt_min,
+        "time_step_max": cfg.ssm_dt_max,
+        "time_step_floor": cfg.ssm_dt_floor,
+        "n_routed_experts": cfg.n_experts,
+        "num_experts_per_tok": cfg.n_experts_per_tok,
+        "moe_intermediate_size": cfg.moe_intermediate_dim,
+        "moe_shared_expert_intermediate_size": cfg.shared_expert_dim,
+        "n_shared_experts": 1,
+        "norm_topk_prob": cfg.moe_norm_topk,
+        "routed_scaling_factor": cfg.moe_routed_scale,
+        "n_group": 1,
+        "topk_group": 1,
+    }
+    if cfg.expert_share:
+        out["share"] = {
+            "router_num_experts": cfg.router_width,
+            "rank": cfg.expert_offset // cfg.n_experts,
+        }
+    return out
+
+
+_NEMO = "backbone.layers.{}."
+# A layer kind's leaves: ours <- the name under `mixer.`, transposed ([out,
+# in] -> [in, out]); the conv's taps and the experts are handled below.
+_NEMO_MIXER = {
+    "M": (
+        ("ssm_in", "in_proj.weight", True),
+        ("ssm_conv_b", "conv1d.bias", False),
+        ("ssm_A_log", "A_log", False),
+        ("ssm_D", "D", False),
+        ("ssm_dt_bias", "dt_bias", False),
+        ("ssm_norm", "norm.weight", False),
+        ("ssm_out", "out_proj.weight", True),
+    ),
+    "E": (
+        ("router", "gate.weight", True),
+        ("router_bias", "gate.e_score_correction_bias", False),
+        ("ws_u", "shared_experts.up_proj.weight", True),
+        ("ws_d", "shared_experts.down_proj.weight", True),
+    ),
+    "*": (
+        ("wq", "q_proj.weight", True),
+        ("wk", "k_proj.weight", True),
+        ("wv", "v_proj.weight", True),
+        ("wo", "o_proj.weight", True),
+    ),
+}
+_NEMO_EXPERT = (("wu", "up_proj"), ("wd", "down_proj"))
+
+
+def _nemo_layers(cfg, kind):
+    return [i for i, c in enumerate(cfg.layer_pattern) if c == kind]
+
+
+def _nemotron_h_params_from_sd(cfg, sd, dtype=None):
+    import jax.numpy as jnp
+
+    dtype = dtype or cfg.dtype
+
+    def get(name):
+        if name not in sd:
+            raise KeyError(f"missing tensor {name!r} in checkpoint")
+        return np.asarray(sd[name], np.float32)
+
+    def stack(layers, fn):
+        return jnp.asarray(
+            np.stack([fn(_NEMO.format(i) + "mixer.") for i in layers]), dtype)
+
+    blocks = {
+        "ln1": jnp.asarray(np.stack([
+            get(_NEMO.format(i) + "norm.weight") for i in range(cfg.n_layers)
+        ]), dtype)
+    }
+    for kind, leaves in _NEMO_MIXER.items():
+        layers = _nemo_layers(cfg, kind)
+        if not layers:
+            continue
+        for ours, theirs, t in leaves:
+            blocks[ours] = stack(
+                layers,
+                lambda pre: get(pre + theirs).T if t else get(pre + theirs))
+    if cfg.n_ssm_layers:  # [C, 1, K] -> [K, C], oldest tap first
+        blocks["ssm_conv"] = stack(
+            _nemo_layers(cfg, "M"),
+            lambda pre: get(pre + "conv1d.weight")[:, 0, :].T)
+    if cfg.n_moe_layers:
+        for ours, theirs in _NEMO_EXPERT:
+            blocks[ours] = stack(
+                _nemo_layers(cfg, "E"),
+                lambda pre: np.stack([
+                    get(f"{pre}experts.{cfg.expert_offset + e}.{theirs}.weight").T
+                    for e in range(cfg.n_experts)
+                ]))
+    return {
+        "embed": jnp.asarray(get("backbone.embeddings.weight"), dtype),
+        "blocks": blocks,
+        "final_ln": jnp.asarray(get("backbone.norm_f.weight"), dtype),
+        "lm_head": jnp.asarray(get("lm_head.weight").T, dtype),
+    }
+
+
+def _nemotron_h_params_to_sd(cfg, params):
+    from areal_tpu.base.distributed import to_host
+
+    def host(x):
+        return to_host(x).astype(np.float32, copy=False)
+
+    blocks = {n: host(w) for n, w in params["blocks"].items()}
+    out = {
+        "backbone.embeddings.weight": host(params["embed"]),
+        "backbone.norm_f.weight": host(params["final_ln"]),
+        "lm_head.weight": np.ascontiguousarray(host(params["lm_head"]).T),
+    }
+    for i in range(cfg.n_layers):
+        out[_NEMO.format(i) + "norm.weight"] = blocks["ln1"][i]
+    for kind, leaves in _NEMO_MIXER.items():
+        for j, i in enumerate(_nemo_layers(cfg, kind)):
+            pre = _NEMO.format(i) + "mixer."
+            for ours, theirs, t in leaves:
+                w = blocks[ours][j]
+                out[pre + theirs] = np.ascontiguousarray(w.T) if t else w
+            if kind == "M":
+                out[pre + "conv1d.weight"] = np.ascontiguousarray(
+                    blocks["ssm_conv"][j].T[:, None, :])
+            if kind == "E":
+                for ours, theirs in _NEMO_EXPERT:
+                    for e in range(cfg.n_experts):
+                        out[
+                            f"{pre}experts.{cfg.expert_offset + e}."
+                            f"{theirs}.weight"
+                        ] = np.ascontiguousarray(blocks[ours][j, e].T)
+    return out
+
+
+register_hf_family(
+    HFFamily(
+        "nemotron_h",
+        _nemotron_h_config_from_hf,
+        _nemotron_h_config_to_hf,
+        params_from_sd=_nemotron_h_params_from_sd,
+        params_to_sd=_nemotron_h_params_to_sd,
+    )
+)
+
+
 # ---------------- gpt2 ----------------
 # Different lineage: learned positions, LayerNorm with bias, fused c_attn,
 # plain (non-gated) gelu MLP, biases everywhere, Conv1D weights stored
@@ -1220,6 +1472,8 @@ def infer_model_type(cfg: ModelConfig) -> str:
         return "gpt2"
     if cfg.is_hybrid:
         return "qwen3_next"
+    if cfg.is_pattern:
+        return "nemotron_h"
     if cfg.is_latent:
         return "glm4_moe_lite"
     if cfg.is_moe:

@@ -1,0 +1,305 @@
+"""Mamba-2 (SSD): the state-space mixer of a pattern of one-branch layers
+(nemotron_h: `M` layers beside expert-only and attention-only layers).
+
+Per head h of `ssm_head_dim` channels, with a state S [head_dim, N] kept in
+fp32, B and C [N] shared by the heads of a group:
+
+    dt_t = softplus(dt_t + dt_bias_h)        A_h = -exp(A_log_h)
+    S_t  = exp(dt_t A_h) S_{t-1} + dt_t x_t (x) B_t
+    y_t  = S_t C_t + D_h x_t
+
+before it ONE input projection [z | x B C | dt] and a causal depthwise conv
+WITH bias + SiLU over the x | B | C channels; after it y * silu(z), an
+RMSNorm over each GROUP of channels times a weight (gate first, then norm)
+and the output projection.
+
+Two forms of the same recurrence:
+- `ssm_forward` (training, `forward`, prefill): the chunked (SSD) form.
+  Inside a chunk of `ssm_chunk` tokens y = ((C B^T) * decay) (dt x); each
+  chunk's own contribution to the state and the read of the state it
+  starts from are batched matmuls over all chunks, and a `lax.scan` over
+  chunks carries S through an elementwise update alone.
+- `ssm_step` (decode): one token against the carried S and the conv's last
+  K-1 inputs.
+
+Packed rows: S and the conv restart at every segment start — the decay
+across a segment boundary is zero, the carried state is dropped for every
+token whose segment is not the one the previous chunk ended in, and the
+conv (`linear_attention.causal_conv`) does not reach back.  Pads (segment
+0) are NEUTRAL: dt = 0 there, and a pad after a real token counts to that
+token's segment, so the state passes through trailing pads unchanged and
+what a row ends on is the state at its last valid token.
+
+Parameters (leaves of `params["blocks"]`, stacked [n_ssm_layers, ...]):
+    ssm_in      [D, d_inner + conv_dim + H]   z | x B C | dt
+    ssm_conv    [K, conv_dim]                 depthwise taps, oldest first
+    ssm_conv_b  [conv_dim]
+    ssm_A_log, ssm_D, ssm_dt_bias [H]
+    ssm_norm    [d_inner]                     gated norm's weight (plain)
+    ssm_out     [d_inner, D]
+"""
+
+import math
+from typing import Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from areal_tpu.models.config import ModelConfig
+from areal_tpu.models.linear_attention import causal_conv
+
+Params = Dict[str, jax.Array]
+
+SSM_LEAVES = (
+    "ssm_in", "ssm_conv", "ssm_conv_b", "ssm_A_log", "ssm_D", "ssm_dt_bias",
+    "ssm_norm", "ssm_out",
+)
+
+
+def init_ssm(cfg: ModelConfig, key: jax.Array, n: int, dense) -> Params:
+    """`n` layers' leaves as the Mamba-2 initialisers draw them: A uniform
+    on (1, 16), dt log-uniform on [ssm_dt_min, ssm_dt_max] floored at
+    `ssm_dt_floor` with dt_bias its inverse softplus, D ones, the conv's
+    bias zero, the gated norm at one; `dense(key, shape, fan_in)` is the
+    caller's matrix init."""
+    D, C, H, K = cfg.hidden_dim, cfg.ssm_conv_dim, cfg.ssm_n_heads, cfg.ssm_conv_kernel
+    ks = jax.random.split(key, 5)
+    a = jax.random.uniform(ks[3], (n, H), jnp.float32, 1.0, 16.0)
+    dt = jnp.exp(
+        jax.random.uniform(ks[4], (n, H), jnp.float32)
+        * (math.log(cfg.ssm_dt_max) - math.log(cfg.ssm_dt_min))
+        + math.log(cfg.ssm_dt_min)
+    )
+    dt = jnp.maximum(dt, cfg.ssm_dt_floor)
+    return {
+        "ssm_in": dense(ks[0], (n, D, cfg.ssm_in_dim), D),
+        "ssm_conv": dense(ks[1], (n, K, C), K),
+        "ssm_conv_b": jnp.zeros((n, C), cfg.dtype),
+        "ssm_A_log": jnp.log(a).astype(cfg.dtype),
+        "ssm_D": jnp.ones((n, H), cfg.dtype),
+        "ssm_dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(cfg.dtype),
+        "ssm_norm": jnp.ones((n, cfg.ssm_inner_dim), cfg.dtype),
+        "ssm_out": dense(ks[2], (n, cfg.ssm_inner_dim, D), cfg.ssm_inner_dim),
+    }
+
+
+def _split_in(zxbcdt: jax.Array, cfg: ModelConfig):
+    """in_proj's output [..., ssm_in_dim] -> z, xBC, dt."""
+    di, c = cfg.ssm_inner_dim, cfg.ssm_conv_dim
+    return zxbcdt[..., :di], zxbcdt[..., di: di + c], zxbcdt[..., di + c:]
+
+
+def _split_conv(xbc: jax.Array, cfg: ModelConfig):
+    """conv output [..., conv_dim] fp32 -> x [..., H, P], B, C [..., G, N]."""
+    di, g, n = cfg.ssm_inner_dim, cfg.ssm_n_groups, cfg.ssm_state_dim
+    lead = xbc.shape[:-1]
+    x = xbc[..., :di].reshape(*lead, cfg.ssm_n_heads, cfg.ssm_head_dim)
+    bm = xbc[..., di: di + g * n].reshape(*lead, g, n)
+    cm = xbc[..., di + g * n:].reshape(*lead, g, n)
+    return x, bm, cm
+
+
+def _dt_a(dt: jax.Array, blk: Params):
+    """softplus(dt + dt_bias) and A = -exp(A_log), fp32."""
+    dt = jax.nn.softplus(
+        dt.astype(jnp.float32) + blk["ssm_dt_bias"].astype(jnp.float32)
+    )
+    return dt, -jnp.exp(blk["ssm_A_log"].astype(jnp.float32))
+
+
+def _out(y: jax.Array, z: jax.Array, blk: Params, cfg: ModelConfig):
+    """out_proj(w * rmsnorm_per_group(y * silu(z))): y fp32 [..., d_inner]."""
+    with jax.named_scope("out_norm_proj"):
+        lead = y.shape[:-1]
+        g = cfg.ssm_n_groups
+        yf = y * jax.nn.silu(z.astype(jnp.float32))
+        yg = yf.reshape(*lead, g, cfg.ssm_inner_dim // g)
+        var = jnp.mean(jnp.square(yg), axis=-1, keepdims=True)
+        yg = yg * jax.lax.rsqrt(var + cfg.rms_norm_eps)
+        yf = yg.reshape(*lead, -1) * blk["ssm_norm"].astype(jnp.float32)
+        return yf.astype(z.dtype) @ blk["ssm_out"]
+
+
+def _fill_pads(segment_ids: jax.Array) -> jax.Array:
+    """Segment ids with every pad (0) that follows a real token counted to
+    that token's segment; leading pads stay 0."""
+    idx = jnp.arange(segment_ids.shape[-1], dtype=jnp.int32)
+    last = jax.lax.associative_scan(
+        jnp.maximum, jnp.where(segment_ids > 0, idx, -1), axis=-1
+    )
+    filled = jnp.take_along_axis(segment_ids, jnp.maximum(last, 0), axis=-1)
+    return jnp.where(last >= 0, filled, 0)
+
+
+def ssd_chunked(
+    x: jax.Array,  # [B, S, H, P] fp32
+    dt: jax.Array,  # [B, S, H] fp32, after softplus; 0 = a neutral token
+    a: jax.Array,  # [H] fp32, negative
+    bm: jax.Array,  # [B, S, G, N] fp32
+    cm: jax.Array,  # [B, S, G, N] fp32
+    segment_ids: jax.Array,  # [B, S]
+    chunk: int,
+) -> Tuple[jax.Array, jax.Array]:
+    """The SSD recurrence over packed rows in chunked form -> (y [B, S, H,
+    P] fp32 without the D skip, the state after each row's last token [B,
+    H, P, N] fp32)."""
+    b, s, h, p = x.shape
+    g, n = bm.shape[2:]
+    r = h // g  # heads a group
+    pad = -s % chunk
+    if pad:
+        # Neutral tokens (dt 0) of the last token's segment.
+        def zpad(v):
+            return jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
+
+        x, dt, bm, cm = (zpad(v) for v in (x, dt, bm, cm))
+        segment_ids = jnp.pad(segment_ids, ((0, 0), (0, pad)), mode="edge")
+    nc = (s + pad) // chunk
+
+    # [B, S, ...] -> [B, NC, C, ...]; heads as (group, head in group).
+    xd = (x * dt[..., None]).reshape(b, nc, chunk, g, r, p)
+    bm = bm.reshape(b, nc, chunk, g, n)
+    cm = cm.reshape(b, nc, chunk, g, n)
+    seg = segment_ids.reshape(b, nc, chunk)
+    la = (dt * a).reshape(b, nc, chunk, g, r)
+    ga = jnp.cumsum(la, axis=2)  # log-decay from the chunk's start, inclusive
+
+    same = seg[:, :, :, None] == seg[:, :, None, :]  # [B, NC, C, C]
+    idx = jnp.arange(chunk)
+    tril = idx[:, None] >= idx[None, :]
+    # The state a chunk starts from belongs to the segment the previous
+    # chunk ended in; the first chunk starts from nothing.
+    prev_last = jnp.concatenate(
+        [jnp.full((b, 1), -1, seg.dtype), seg[:, :-1, -1]], axis=1
+    )
+    carry_ok = (seg == prev_last[..., None]).astype(jnp.float32)  # [B,NC,C]
+    same_as_last = (seg == seg[..., -1:]).astype(jnp.float32)
+
+    # Inside a chunk: y_i = sum_{j<=i} (C_i . B_j) exp(ga_i - ga_j) dt_j x_j,
+    # the [C, C] blocks with the heads leading (a head's block is a tile).
+    gat = jnp.moveaxis(ga, 2, -1)  # [B, NC, G, R, C]
+    keep = (tril & same)[:, :, None, None]  # [B, NC, 1, 1, Ci, Cj]
+    diff = gat[..., :, None] - gat[..., None, :]
+    decay = jnp.where(keep, jnp.exp(jnp.where(keep, diff, 0.0)), 0.0)
+    cb = jnp.einsum("bkign,bkjgn->bkgij", cm, bm)
+    y = jnp.einsum("bkgrij,bkjgrp->bkigrp", cb[:, :, :, None] * decay, xd)
+
+    # A chunk's own contribution to the state it ends on ...
+    g_last = ga[:, :, -1:]  # [B, NC, 1, G, R]
+    w_out = jnp.exp(g_last - ga) * same_as_last[..., None, None]
+    own = jnp.einsum("bkjgrp,bkjgn->bkgrpn", xd * w_out[..., None], bm)
+    s_keep = jnp.exp(g_last[:, :, 0]) * carry_ok[:, :, -1, None, None]
+
+    # ... carried from chunk to chunk: elementwise, the scan's only work.
+    def body(state, xs):
+        own_k, keep_k = xs
+        return state * keep_k[..., None, None] + own_k, state
+
+    state, s_in = jax.lax.scan(
+        body,
+        jnp.zeros((b, g, r, p, n), jnp.float32),
+        (jnp.moveaxis(own, 1, 0), jnp.moveaxis(s_keep, 1, 0)),
+    )
+    # ... and read by every token of the segment it belongs to.
+    w_in = jnp.exp(ga) * carry_ok[..., None, None]  # [B, NC, C, G, R]
+    y = y + jnp.einsum(
+        "bkign,kbgrpn->bkigrp", cm, s_in
+    ) * w_in[..., None]
+    y = y.reshape(b, s + pad, h, p)[:, :s]
+    return y, state.reshape(b, h, p, n)
+
+
+def _tail_at(
+    x: jax.Array, segment_ids: jax.Array, last: jax.Array, kk: int
+) -> jax.Array:
+    """The K-1 conv inputs that end at each row's position `last` [B], zero
+    where they lie before the row or in another segment than `last`'s:
+    what `ssm_step` carries on from.  [B, K-1, C]."""
+    pos = last[:, None] - jnp.arange(kk - 2, -1, -1)[None, :]  # [B, K-1]
+    at = jnp.maximum(pos, 0)
+    tail = jnp.take_along_axis(x, at[..., None], axis=1)
+    seg = jnp.take_along_axis(segment_ids, at, axis=1)
+    seg_last = jnp.take_along_axis(segment_ids, last[:, None], axis=1)
+    return jnp.where(((pos >= 0) & (seg == seg_last))[..., None], tail, 0)
+
+
+@jax.named_scope("layer/ssm")
+def ssm_forward(
+    h: jax.Array,  # [B, S, D] normed layer input
+    blk: Params,
+    cfg: ModelConfig,
+    segment_ids: jax.Array,
+    with_state: bool = False,
+):
+    """-> y [B, S, D]; `with_state` (prefill) adds the state at each row's
+    last VALID token [B, H, P, N] fp32 and the conv's tail there [B, K-1,
+    conv_dim]."""
+    with jax.named_scope("in_proj"):
+        z, xbc, dt = _split_in(h @ blk["ssm_in"], cfg)
+    with jax.named_scope("conv"):
+        conv = jax.nn.silu(
+            causal_conv(xbc, blk["ssm_conv"], segment_ids)
+            + blk["ssm_conv_b"].astype(jnp.float32)
+        )
+    with jax.named_scope("ssd_scan"):
+        x, bm, cm = _split_conv(conv, cfg)
+        dt, a = _dt_a(dt, blk)
+        dt = jnp.where((segment_ids > 0)[..., None], dt, 0.0)
+        y, state = ssd_chunked(
+            x, dt, a, bm, cm, _fill_pads(segment_ids), cfg.ssm_chunk
+        )
+        y = y + blk["ssm_D"].astype(jnp.float32)[:, None] * x
+    out = _out(y.reshape(*y.shape[:2], cfg.ssm_inner_dim), z, blk, cfg)
+    if with_state:
+        idx = jnp.arange(segment_ids.shape[-1])
+        last = jnp.max(jnp.where(segment_ids > 0, idx, 0), axis=-1)
+        return out, state, _tail_at(xbc, segment_ids, last, cfg.ssm_conv_kernel)
+    return out
+
+
+@jax.named_scope("layer/ssm")
+def ssm_step(
+    h: jax.Array,  # [B, 1, D]
+    blk: Params,
+    cfg: ModelConfig,
+    states: jax.Array,  # [n_ssm, B, H, P, N] fp32, every Mamba layer's
+    tails: jax.Array,  # [n_ssm, B, K-1, conv_dim] the convs' last inputs
+    li,  # this layer's index into both
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """One decode token per row -> (y [B, 1, D], states, tails), layer `li`
+    of both stepped in place.  The whole caches come in and go out so that
+    the state's read and its write lie under this scope (an update made by
+    the caller would be timed outside `layer/ssm`)."""
+    state = jax.lax.dynamic_index_in_dim(states, li, axis=0, keepdims=False)
+    tail = jax.lax.dynamic_index_in_dim(tails, li, axis=0, keepdims=False)
+    with jax.named_scope("in_proj"):
+        z, xbc, dt = _split_in(h[:, 0] @ blk["ssm_in"], cfg)
+    with jax.named_scope("conv"):
+        window = jnp.concatenate([tail, xbc[:, None].astype(tail.dtype)], 1)
+        conv = jax.nn.silu(
+            jnp.einsum(
+                "bkc,kc->bc", window.astype(jnp.float32),
+                blk["ssm_conv"].astype(jnp.float32),
+            )
+            + blk["ssm_conv_b"].astype(jnp.float32)
+        )
+        tails = jax.lax.dynamic_update_index_in_dim(
+            tails, window[:, 1:], li, axis=0
+        )
+    with jax.named_scope("ssm_step"):
+        x, bm, cm = _split_conv(conv, cfg)  # [B, H, P], [B, G, N]
+        dt, a = _dt_a(dt, blk)  # [B, H], [H]
+        b, hh, p = x.shape
+        g, n = bm.shape[1:]
+        # One pass over S: decay, add the token's outer product, read y.
+        sg = state.reshape(b, g, hh // g, p, n)
+        da = jnp.exp(dt * a).reshape(b, g, hh // g, 1, 1)
+        xd = (x * dt[..., None]).reshape(b, g, hh // g, p, 1)
+        sg = sg * da + xd * bm[:, :, None, None, :]
+        y = jnp.sum(sg * cm[:, :, None, None, :], axis=-1).reshape(b, hh, p)
+        y = y + blk["ssm_D"].astype(jnp.float32)[:, None] * x
+        states = jax.lax.dynamic_update_index_in_dim(
+            states, sg.reshape(state.shape), li, axis=0
+        )
+    out = _out(y.reshape(b, cfg.ssm_inner_dim), z, blk, cfg)
+    return out[:, None], states, tails

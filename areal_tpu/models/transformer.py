@@ -13,6 +13,10 @@ real_llm_base.py (blocks, heads) — re-designed for XLA:
   `jax.sharding` rules over this pytree (areal_tpu/parallel/sharding.py).
 - `is_critic` swaps the LM head for a scalar value head
   (reference: real_llm_base.py:358-453).
+- A layer is a mixer and an MLP, two residual branches — or, under
+  `cfg.layer_pattern` (nemotron_h), ONE branch of one kind (a Mamba-2
+  mixer `models/mamba.py`, the mixture of experts, or attention), each
+  kind's leaves stacked over its own layers (`_pattern_blocks`).
 
 Functions:
     init_params(cfg, key)                                  -> params
@@ -43,6 +47,7 @@ from areal_tpu.models.linear_attention import (
     linear_attn_forward,
     linear_attn_step,
 )
+from areal_tpu.models.mamba import SSM_LEAVES, init_ssm, ssm_forward, ssm_step
 from areal_tpu.ops.attention import (
     decode_attention,
     latent_decode_attention,
@@ -118,11 +123,22 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             out["wqg"] = dense(ks[5], (n, D, cfg.q_dim), D)
         return out
 
-    blocks = {
-        "ln1": norm_init((L, D), dtype),
-        **attn_leaves(LA, ks),
-        "ln2": norm_init((L, D), dtype),
-    }
+    # Layers with the mixture of experts: all the scanned ones, or a
+    # pattern's 'E' layers.
+    LM = cfg.n_moe_layers
+    if cfg.is_pattern:
+        # ONE norm a layer; each kind's leaves stacked over its own layers.
+        blocks = {
+            "ln1": norm_init((L, D), dtype),
+            **attn_leaves(cfg.n_attn_layers, ks),
+            **init_ssm(cfg, ks[6], cfg.n_ssm_layers, dense),
+        }
+    else:
+        blocks = {
+            "ln1": norm_init((L, D), dtype),
+            **attn_leaves(LA, ks),
+            "ln2": norm_init((L, D), dtype),
+        }
     if cfg.first_k_dense:
         # The leading dense layers: their own leaves, stacked [K, ...]
         # under `dense_*`, the MLP at the dense width.
@@ -150,27 +166,29 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     if cfg.is_moe:
         E, FM = cfg.n_experts, cfg.moe_intermediate_dim
         km = jax.random.split(ks[4], 4)
-        blocks["router"] = dense(km[0], (L, D, cfg.router_width), D)
+        blocks["router"] = dense(km[0], (LM, D, cfg.router_width), D)
         if cfg.moe_score_func == "sigmoid":
             # Not trained; drawn as the configuration says (zeros unless it
             # states a draw: `router_bias_init_std`).
             blocks["router_bias"] = (
                 cfg.router_bias_init_std * jax.random.normal(
-                    jax.random.fold_in(km[0], 1), (L, cfg.router_width)
+                    jax.random.fold_in(km[0], 1), (LM, cfg.router_width)
                 )
             ).astype(dtype)
-        blocks["wg"] = dense(km[1], (L, E, D, FM), D)
-        blocks["wu"] = dense(km[2], (L, E, D, FM), D)
-        blocks["wd"] = dense(km[3], (L, E, FM, D), FM)
+        if cfg.mlp_gated:
+            blocks["wg"] = dense(km[1], (LM, E, D, FM), D)
+        blocks["wu"] = dense(km[2], (LM, E, D, FM), D)
+        blocks["wd"] = dense(km[3], (LM, E, FM, D), FM)
         if cfg.shared_expert_dim:
             FS = cfg.shared_expert_dim
             kx = jax.random.split(ks[7], 4)
-            blocks["ws_g"] = dense(kx[0], (L, D, FS), D)
-            blocks["ws_u"] = dense(kx[1], (L, D, FS), D)
-            blocks["ws_d"] = dense(kx[2], (L, FS, D), FS)
+            if cfg.mlp_gated:
+                blocks["ws_g"] = dense(kx[0], (LM, D, FS), D)
+            blocks["ws_u"] = dense(kx[1], (LM, D, FS), D)
+            blocks["ws_d"] = dense(kx[2], (LM, FS, D), FS)
             if cfg.shared_expert_gated:
-                blocks["ws_gate"] = dense(kx[3], (L, D, 1), D)
-    else:
+                blocks["ws_gate"] = dense(kx[3], (LM, D, 1), D)
+    elif not cfg.is_pattern:
         km = jax.random.split(ks[4], 3)
         blocks["wg"] = dense(km[0], (L, D, F), D)
         if cfg.mlp_gated:
@@ -219,6 +237,8 @@ def _act(x: jax.Array, cfg: ModelConfig) -> jax.Array:
         return jax.nn.gelu(x, approximate=False)
     if cfg.hidden_act == "gelu_tanh":
         return jax.nn.gelu(x, approximate=True)
+    if cfg.hidden_act == "relu2":
+        return jnp.square(jax.nn.relu(x))
     raise ValueError(f"unknown hidden_act {cfg.hidden_act!r}")
 
 
@@ -406,9 +426,12 @@ def _experts_dense(x, top_w, top_idx, one_hot, blk: Params, cfg: ModelConfig):
     """
     with jax.named_scope("experts"):
         # All-expert compute: [E, T, F] einsums.
-        gate = jax.nn.silu(jnp.einsum("td,edf->etf", x, blk["wg"]))
-        up = jnp.einsum("td,edf->etf", x, blk["wu"])
-        expert_out = jnp.einsum("etf,efd->etd", gate * up, blk["wd"])  # [E,T,D]
+        if cfg.mlp_gated:
+            gate = jax.nn.silu(jnp.einsum("td,edf->etf", x, blk["wg"]))
+            hid = gate * jnp.einsum("td,edf->etf", x, blk["wu"])
+        else:  # down(act(up(x))): two matrices an expert
+            hid = _act(jnp.einsum("td,edf->etf", x, blk["wu"]), cfg)
+        expert_out = jnp.einsum("etf,efd->etd", hid, blk["wd"])  # [E,T,D]
     with jax.named_scope("combine"):
         comb = jnp.einsum("tk,tke->te", top_w, one_hot)  # [T, E]
         return jnp.einsum(
@@ -447,15 +470,19 @@ def _experts_topk(x, top_w, top_idx, one_hot, blk: Params, cfg: ModelConfig):
         )  # [T, E, C]
         xe = jnp.einsum("tec,td->ecd", dispatch.astype(x.dtype), x)  # [E, C, D]
     with jax.named_scope("experts"):
-        gate = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xe, blk["wg"]))
-        up = jnp.einsum("ecd,edf->ecf", xe, blk["wu"])
-        ye = jnp.einsum("ecf,efd->ecd", gate * up, blk["wd"])  # [E, C, D]
+        if cfg.mlp_gated:
+            gate = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xe, blk["wg"]))
+            hid = gate * jnp.einsum("ecd,edf->ecf", xe, blk["wu"])
+        else:
+            hid = _act(jnp.einsum("ecd,edf->ecf", xe, blk["wu"]), cfg)
+        ye = jnp.einsum("ecf,efd->ecd", hid, blk["wd"])  # [E, C, D]
     with jax.named_scope("combine"):
         return jnp.einsum("tec,ecd->td", combine, ye)
 
 
 def _experts_grouped(
-    x, top_w, top_idx, one_hot, blk: Params, cfg: ModelConfig, layer=None
+    x, top_w, top_idx, one_hot, blk: Params, cfg: ModelConfig, layer=None,
+    kernel: bool = False,
 ):
     """Dropless grouped-GEMM dispatch (the default): tokens sorted by
     expert, expert matmuls ride `jax.lax.ragged_dot` — XLA:TPU's native
@@ -472,7 +499,11 @@ def _experts_grouped(
     leaves: they are viewed as [L*E, in, out] (leading contiguous axes: a
     bitcast) and the group sizes are zero outside [layer*E, (layer+1)*E),
     which hands the kernel the parameter's own buffer.  Same rows through
-    the same experts either way (`expert_leaves_in_place`).
+    the same experts either way (`expert_leaves_in_place`).  `kernel`
+    (decode, stacked leaves): the Pallas kernel `grouped_decode_matmul` in
+    `ragged_dot`'s place — XLA's kernel tiles by the divisors of the two
+    weight dimensions and streams a [2,688, 1,856] expert at a ninth of
+    the bandwidth (`ops/pallas/grouped_matmul.py`).
 
     Expert FLOPs are exactly 3·T·k·D·F — proportional to TOKENS, where
     the dense oracle pays E/k× that and capacity dispatch pays
@@ -496,13 +527,17 @@ def _experts_grouped(
         group_sizes = jnp.sum(one_hot, axis=(0, 1)).astype(jnp.int32)  # [E]
         tok_of = order // k
         xs = x[tok_of]  # [T*k, D] sorted by expert
+        layer_sizes = group_sizes
         if layer is not None:  # stacked leaves: this layer's groups of L*E
             group_sizes = jax.lax.dynamic_update_slice(
-                jnp.zeros((blk["wg"].shape[0] * cfg.n_experts,), jnp.int32),
+                jnp.zeros((blk["wd"].shape[0] * cfg.n_experts,), jnp.int32),
                 group_sizes,
                 (layer * cfg.n_experts,),
             )
-            blk = {n: blk[n].reshape(-1, *blk[n].shape[2:]) for n in _EXPERT_LEAVES}
+            blk = {
+                n: blk[n].reshape(-1, *blk[n].shape[2:])
+                for n in _expert_leaves(cfg)
+            }
     with jax.named_scope("experts"):
         # A rank's share: rows whose expert is held elsewhere sort past
         # every group.  The ragged kernels do no work for them, and what
@@ -514,20 +549,35 @@ def _experts_grouped(
             held = (jnp.arange(xs.shape[0]) < jnp.sum(group_sizes))[:, None]
 
         def ragged(lhs, w):
+            if kernel and layer is not None:
+                # Rows past every group come out zero: nothing to mask.
+                from areal_tpu.ops.pallas.grouped_matmul import (
+                    grouped_decode_matmul,
+                )
+
+                return grouped_decode_matmul(
+                    lhs, w, layer_sizes, layer, max_rows=x.shape[0]
+                )
             if held is None:
                 return jax.lax.ragged_dot(lhs, w, group_sizes)
             out = jax.lax.ragged_dot(jnp.where(held, lhs, 0), w, group_sizes)
             return jnp.where(held, out, 0)
 
-        gate = jax.nn.silu(ragged(xs, blk["wg"]))
-        up = ragged(xs, blk["wu"])
-        ys = ragged(gate * up, blk["wd"])  # [T*k, D]
+        if cfg.mlp_gated:
+            gate = jax.nn.silu(ragged(xs, blk["wg"]))
+            up = ragged(xs, blk["wu"])
+            ys = ragged(gate * up, blk["wd"])  # [T*k, D]
+        else:  # down(act(up(x))): two matrices an expert
+            ys = ragged(_act(ragged(xs, blk["wu"]), cfg), blk["wd"])
     with jax.named_scope("combine"):
         w_sorted = top_w.reshape(-1)[order].astype(ys.dtype)
         return jnp.zeros_like(x).at[tok_of].add(ys * w_sorted[:, None])
 
 
-_EXPERT_LEAVES = ("wg", "wu", "wd")
+def _expert_leaves(cfg: ModelConfig) -> Tuple[str, ...]:
+    """The routed experts' matrices: three for a gated expert, two for
+    down(act(up(x)))."""
+    return ("wg", "wu", "wd") if cfg.mlp_gated else ("wu", "wd")
 
 
 def expert_leaves_in_place(cfg: ModelConfig, blocks: Params) -> bool:
@@ -542,7 +592,7 @@ def expert_leaves_in_place(cfg: ModelConfig, blocks: Params) -> bool:
     the answer on (`decode_step(experts_in_place=...)`)."""
     if not (cfg.is_moe and cfg.moe_dispatch == "grouped"):
         return False
-    for name in _EXPERT_LEAVES:
+    for name in _expert_leaves(cfg):
         leaf = blocks[name]
         sharding = getattr(leaf, "sharding", None)
         if sharding is not None and (
@@ -564,9 +614,10 @@ def _scan_blocks(
         experts_in_place = expert_leaves_in_place(cfg, blocks)
     if not experts_in_place:
         return blocks, None
+    names = _expert_leaves(cfg)
     return (
-        {n: w for n, w in blocks.items() if n not in _EXPERT_LEAVES},
-        {n: blocks[n] for n in _EXPERT_LEAVES},
+        {n: w for n, w in blocks.items() if n not in names},
+        {n: blocks[n] for n in names},
     )
 
 
@@ -585,6 +636,7 @@ def _mlp_moe(
     valid: Optional[jax.Array] = None,
     stacked: Optional[Params] = None,
     layer: Optional[jax.Array] = None,
+    kernel: bool = False,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """MoE MLP -> (out [B,S,D], aux loss, rows per expert [E] int32).
 
@@ -597,7 +649,8 @@ def _mlp_moe(
     generator's and the trainer's load counters.  `stacked` (decode,
     grouped dispatch): the expert weights come from these stacked
     [L, E, in, out] leaves at layer index `layer` instead of from `blk`
-    (`_experts_grouped`); without it `layer` is not read."""
+    (`_experts_grouped`, which `kernel` hands the Pallas grouped matmul);
+    without it `layer` is not read."""
     b, s, d = h.shape
     x = h.reshape(-1, d)  # [T, D]
     with jax.named_scope("router"):
@@ -612,10 +665,15 @@ def _mlp_moe(
     if stacked is None:
         out = _MOE_EXPERTS[cfg.moe_dispatch](x, top_w, top_idx, one_hot, blk, cfg)
     else:
-        out = _experts_grouped(x, top_w, top_idx, one_hot, stacked, cfg, layer)
+        out = _experts_grouped(
+            x, top_w, top_idx, one_hot, stacked, cfg, layer, kernel
+        )
     if cfg.shared_expert_dim:
         with jax.named_scope("shared"):
-            hid = jax.nn.silu(x @ blk["ws_g"]) * (x @ blk["ws_u"])
+            if cfg.mlp_gated:
+                hid = jax.nn.silu(x @ blk["ws_g"]) * (x @ blk["ws_u"])
+            else:
+                hid = _act(x @ blk["ws_u"], cfg)
             if cfg.shared_expert_gated:
                 out = out + jax.nn.sigmoid(x @ blk["ws_gate"]) * (hid @ blk["ws_d"])
             else:
@@ -789,6 +847,9 @@ def _backbone(
     if cfg.is_latent and (cp_mesh is not None or pp_mesh is not None):
         raise LatentLayoutError(_NO_LATENT_LAYOUT)
 
+    if cfg.is_pattern and (cp_mesh is not None or pp_mesh is not None):
+        raise HybridLayoutError(_NO_PATTERN_LAYOUT)
+
     if pp_mesh is not None:
         from areal_tpu.parallel.pipeline import pipelined_blocks
 
@@ -910,6 +971,15 @@ _NO_LATENT_LAYOUT = (
 )
 
 
+_NO_PATTERN_LAYOUT = (
+    "a pattern of one-branch layers (Mamba-2, experts or attention alone) "
+    "runs under data and fsdp sharding only: the Mamba heads, their conv "
+    "channels and their state are not split over `model`, the chunked scan "
+    "has no ring over a split sequence, and the pipeline has no stage of a "
+    "pattern's layers (PERF.md section 7)"
+)
+
+
 # Leaves only a period's full-attention layer has (stacked [n_periods, ...]
 # in a hybrid model); `LINEAR_LEAVES` are the linear layers' ([n_linear,
 # ...]); every other block leaf is per layer ([n_layers, ...]).
@@ -957,6 +1027,99 @@ def _period_layer(cfg: ModelConfig, pblk: Params, j: int) -> Params:
     return blk
 
 
+# Leaves only a pattern's 'E' layers have; `SSM_LEAVES` are its 'M'
+# layers', `_FULL_ATTN_LEAVES` its '*' layers'; `ln1` is every layer's.
+_MOE_LEAVES = (
+    "router", "router_bias", "wg", "wu", "wd", "ws_g", "ws_u", "ws_d",
+    "ws_gate",
+)
+
+
+def _leaf_kind(name: str) -> Optional[str]:
+    """The pattern character of the layers a block leaf belongs to; None
+    for a leaf every layer has."""
+    if name in SSM_LEAVES:
+        return "M"
+    if name in _MOE_LEAVES:
+        return "E"
+    return "*" if name in _FULL_ATTN_LEAVES else None
+
+
+def _pattern_view(cfg: ModelConfig, blocks: Params) -> Params:
+    """`_period_view` for a pattern of one-branch layers: each leaf's stack
+    axis split [repeats, its layers in one unit, ...]."""
+    unit, p = cfg.pattern_unit, cfg.n_periods
+    return {
+        name: w.reshape(
+            p, unit.count(_leaf_kind(name)) if _leaf_kind(name) else len(unit),
+            *w.shape[1:],
+        )
+        for name, w in blocks.items()
+    }
+
+
+def _pattern_layer(cfg: ModelConfig, pblk: Params, j: int) -> Tuple[str, int, Params]:
+    """Layer j of one unit's slice of `_pattern_view` -> (its kind, its
+    index among the unit's layers of that kind, its leaves)."""
+    unit = cfg.pattern_unit
+    kind, i = unit[j], unit[:j].count(unit[j])
+    blk = {"ln1": pblk["ln1"][j]}
+    blk.update(
+        {n: w[i] for n, w in pblk.items() if _leaf_kind(n) == kind}
+    )
+    return kind, i, blk
+
+
+def _pattern_blocks(
+    blocks: Params, cfg: ModelConfig, x, segment_ids, cos, sin, remat, use_flash
+):
+    """`_period_blocks` for a pattern of one-branch layers: a scan over the
+    repeats of the pattern's unit, every layer x += f(norm(x)) with f its
+    ONE kind, under the remat policy on its own.
+    -> (x, aux loss per repeat, rows per expert [n_moe_layers, E])."""
+
+    def layer(kind, y, blk):
+        h = _norm(y, blk["ln1"], None, cfg)
+        aux, counts = jnp.zeros((), jnp.float32), None
+        if kind == "M":
+            out = checkpoint_name(
+                ssm_forward(h, blk, cfg, segment_ids), "attn_out"
+            )
+        elif kind == "*":
+            q, k, v = _block_kv(h, blk, cfg, cos, sin)
+            attn = packed_attention(
+                q, k, v, segment_ids, causal=True, use_flash=use_flash
+            )
+            out = checkpoint_name(
+                _attn_out(attn.reshape(*y.shape[:2], cfg.q_dim), blk, cfg),
+                "attn_out",
+            )
+        else:
+            out, aux, counts = _mlp_moe(h, blk, cfg, valid=segment_ids > 0)
+            out = checkpoint_name(out, "mlp_out")
+        return y + out, aux, counts
+
+    layers = {
+        kind: _remat_layer(functools.partial(layer, kind), remat)
+        for kind in set(cfg.pattern_unit)
+    }
+
+    def body(y, pblk):
+        aux, counts = jnp.zeros((), jnp.float32), []
+        for j in range(len(cfg.pattern_unit)):
+            kind, _, blk = _pattern_layer(cfg, pblk, j)
+            y, a, c = layers[kind](y, blk)
+            aux = aux + a
+            if c is not None:
+                counts.append(c)
+        return y, (aux, jnp.stack(counts) if counts else None)
+
+    x, (auxes, counts) = jax.lax.scan(body, x, _pattern_view(cfg, blocks))
+    if counts is not None:  # [repeats, 'E' layers a unit, E] -> [n_moe, E]
+        counts = counts.reshape(-1, counts.shape[-1])
+    return x, auxes, counts
+
+
 def _period_stack(cfg: ModelConfig, per_layer: list):
     """One period's per-layer values (rows per expert; None for a dense
     MLP) as the scan's output: [n, ...], or the layer's own for a period of
@@ -982,6 +1145,10 @@ def _period_blocks(
     one softmax-attention block; a model of one kind of layer is a period
     of one), every layer under the remat policy on its own.
     -> (x, aux loss per period [P], rows per expert [L, E])."""
+    if cfg.is_pattern:
+        return _pattern_blocks(
+            blocks, cfg, x, segment_ids, cos, sin, remat, use_flash
+        )
     n = cfg.full_attn_interval
 
     def linear(y, blk):
@@ -1146,6 +1313,11 @@ class KVCache:
     plus the causal conv's last inputs `conv` [n_linear, B, K-1, C].  Both
     are None for every other model.
 
+    A pattern of one-branch layers keeps k/v for its attention layers
+    alone (L = n_attn_layers), for each Mamba-2 layer a `state` [n_ssm, B,
+    H, head_dim, N] in fp32 and the conv's last inputs `conv` [n_ssm, B,
+    K-1, conv_dim], and nothing for an expert layer.
+
     Latent attention keeps neither k nor v: `latent` [L, B, S_max,
     kv_lora_rank + qk_rope_head_dim] holds ONE row a token and layer, the
     normed latent vector beside the roped key part all heads share, and
@@ -1213,8 +1385,17 @@ def init_kv_cache(
     if cfg.is_latent:
         return KVCache(k=None, v=None, latent=jnp.zeros(
             (cfg.n_layers, batch, s_max, cfg.latent_dim), dtype))
-    shape = (cfg.n_periods, batch, s_max, cfg.n_kv_heads, cfg.head_dim)
+    shape = (cfg.n_attn_layers, batch, s_max, cfg.n_kv_heads, cfg.head_dim)
     cache = KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
+    if cfg.n_ssm_layers:
+        nm = cfg.n_ssm_layers
+        cache.state = jnp.zeros(
+            (nm, batch, cfg.ssm_n_heads, cfg.ssm_head_dim, cfg.ssm_state_dim),
+            jnp.float32,
+        )
+        cache.conv = jnp.zeros(
+            (nm, batch, cfg.ssm_conv_kernel - 1, cfg.ssm_conv_dim), dtype
+        )
     if cfg.is_hybrid:
         nl = cfg.n_linear_layers
         cache.state = jnp.zeros(
@@ -1395,6 +1576,60 @@ def prefill(
         left = (jnp.stack(states), jnp.stack(tails)) if states else ()
         return y, (*kv, *left)
 
+    def pattern_body(carry, pblk):
+        """A unit of one-branch layers: a Mamba layer leaves its state and
+        conv tail at the row's last valid token, an attention layer its
+        k/v, an expert layer nothing."""
+        y, ks, vs, states, tails = carry, [], [], [], []
+        for j in range(len(cfg.pattern_unit)):
+            kind, _, blk = _pattern_layer(cfg, pblk, j)
+            h = _norm(y, blk["ln1"], None, cfg)
+            if kind == "M":
+                out, state, tail = ssm_forward(
+                    h, blk, cfg, segment_ids, with_state=True
+                )
+                states.append(state)
+                tails.append(tail)
+            elif kind == "*":
+                q, k, v = _block_kv(h, blk, cfg, cos, sin)
+                attn = packed_attention(
+                    q, k, v, segment_ids, causal=True, use_flash=use_flash
+                )
+                out = _attn_out(attn.reshape(*y.shape[:2], cfg.q_dim), blk, cfg)
+                ks.append(k)
+                vs.append(v)
+            else:
+                out = _mlp_moe(h, blk, cfg)[0]
+            y = y + out
+        return y, tuple(
+            jnp.stack(a) if a else None for a in (ks, vs, states, tails)
+        )
+
+    def fill(buf, new):
+        """The cache buffer with the prompt's entries of every layer."""
+        return jax.lax.dynamic_update_slice(
+            buf, new.astype(buf.dtype), (0,) * buf.ndim
+        )
+
+    def flat(a, buf):
+        """[repeats, a kind's layers a unit, ...] -> the cache's [layers of
+        that kind, ...]; the buffer as it is where no layer has the kind."""
+        return buf if a is None else a.reshape(-1, *a.shape[2:])
+
+    if cfg.is_pattern:
+        x, by_kind = jax.lax.scan(
+            pattern_body, x, _pattern_view(cfg, params["blocks"])
+        )
+        ks, vs, states, tails = (
+            flat(a, buf) for a, buf in
+            zip(by_kind, (cache.k, cache.v, cache.state, cache.conv))
+        )
+        new_cache = KVCache(
+            k=fill(cache.k, ks), v=fill(cache.v, vs), state=states,
+            conv=None if tails is None else tails.astype(cache.conv.dtype),
+        )
+        return _prefill_head(params, cfg, x, segment_ids), new_cache
+
     lead = []  # the leading dense layers' rows come first in the cache
     for blk in _lead_layers(cfg, params["blocks"]):
         x, (row,) = body(x, blk)
@@ -1402,12 +1637,6 @@ def prefill(
     x, (ks, *left) = jax.lax.scan(
         period_body, x, _period_view(cfg, _scanned(cfg, params["blocks"]))
     )
-
-    def fill(buf, new):
-        """The cache buffer with the prompt's entries of every layer."""
-        return jax.lax.dynamic_update_slice(
-            buf, new.astype(buf.dtype), (0,) * buf.ndim
-        )
 
     if cfg.is_latent:
         rows = jnp.concatenate([jnp.stack(lead), ks]) if lead else ks
@@ -1421,6 +1650,11 @@ def prefill(
                 conv=left[1].reshape(cache.conv.shape).astype(cache.conv.dtype),
             )
         new_cache = KVCache(k=fill(cache.k, ks), v=fill(cache.v, vs), **extra)
+    return _prefill_head(params, cfg, x, segment_ids), new_cache
+
+
+def _prefill_head(params: Params, cfg: ModelConfig, x, segment_ids):
+    """fp32 logits [B, V] at each row's last valid position."""
     x = _final_norm(params, cfg, x)
     # Gather each row's last valid hidden state before the (huge) head matmul.
     # (index of the last nonzero segment: works for left- and right-aligned
@@ -1428,7 +1662,7 @@ def prefill(
     idx = jnp.arange(segment_ids.shape[-1])
     last = jnp.max(jnp.where(segment_ids > 0, idx, 0), axis=-1)  # [B]
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)  # [B,1,D]
-    return _head(params, cfg, x_last)[:, 0], new_cache
+    return _head(params, cfg, x_last)[:, 0]
 
 
 @jax.named_scope("gen/decode_step")
@@ -1443,6 +1677,7 @@ def decode_step(
     with_moe_counts: bool = False,
     experts_in_place: Optional[bool] = None,
     latent_kernel=None,  # None | bool | Mesh
+    expert_kernel: Optional[bool] = None,
 ) -> Tuple[jax.Array, ...]:
     """One decode step: write the new token's k/v at cache slot `slot`
     (shared by every row — the right-aligned prompt layout makes the write a
@@ -1469,6 +1704,13 @@ def decode_step(
     expert axis is sharded they stay in `xs`.  Dense models trace the
     program they always did.
 
+    `expert_kernel`: whether the in-place expert matmuls are the Pallas
+    kernel `grouped_decode_matmul` (None: on
+    a TPU backend where XLA's ragged kernel tiles the expert's [in, out]
+    badly, `grouped_matmul.ragged_tiles_badly`; a caller whose mesh
+    spreads the rows over devices passes False, the kernel is one
+    device's program).
+
     Latent attention (`cfg.is_latent`) runs its ABSORBED form here: the
     cache holds one latent row a token (`KVCache.latent`), the query is
     carried into the latent space, scores and the weighted sum are taken
@@ -1487,6 +1729,13 @@ def decode_step(
     cos, sin = rope_cos_sin(positions[:, None], _rope_dim(cfg), cfg.rope_theta)
     slot = jnp.asarray(slot, jnp.int32)
     blocks, stacked = _scan_blocks(cfg, params["blocks"], experts_in_place)
+    if expert_kernel is None and stacked is not None:
+        from areal_tpu.base.distributed import is_tpu_backend
+        from areal_tpu.ops.pallas.grouped_matmul import ragged_tiles_badly
+
+        expert_kernel = is_tpu_backend() and ragged_tiles_badly(
+            cfg.hidden_dim, cfg.moe_intermediate_dim
+        )
 
     def mlp(y, blk, layer):
         """-> (y + mlp, rows per expert); `layer` indexes the stacked
@@ -1494,7 +1743,8 @@ def decode_step(
         h2 = _norm(y, blk["ln2"], blk.get("ln2_b"), cfg)
         if _is_sparse(cfg, blk):
             mlp_out, _, counts = _mlp_moe(
-                h2, blk, cfg, stacked=stacked, layer=layer
+                h2, blk, cfg, stacked=stacked, layer=layer,
+                kernel=bool(expert_kernel),
             )
         else:
             mlp_out, counts = _mlp_dense(h2, blk, cfg), None
@@ -1558,6 +1808,46 @@ def decode_step(
         y, c = mlp(y, blk, layer(n - 1))
         counts.append(c)
         return (y, kc, vc, sc, cc, pi + 1), _period_stack(cfg, counts)
+
+    def pattern_body(carry, pblk):
+        """A unit of one-branch layers: a Mamba layer steps its state and
+        conv tail in place, an attention layer attends through its k/v, an
+        expert layer reads no cache; each indexes its own kind's stack."""
+        y, kc, vc, sc, cc, pi = carry
+        unit, counts = cfg.pattern_unit, []
+        for j in range(len(unit)):
+            kind, i, blk = _pattern_layer(cfg, pblk, j)
+            li = pi * unit.count(kind) + i
+            if kind == "*":
+                y, kc, vc = attend(y, kc, vc, blk, li)
+                continue
+            h = _norm(y, blk["ln1"], None, cfg)
+            if kind == "M":
+                out, sc, cc = ssm_step(h, blk, cfg, sc, cc, li)
+            else:
+                out, _, c = _mlp_moe(
+                    h, blk, cfg, stacked=stacked, layer=li,
+                    kernel=bool(expert_kernel),
+                )
+                counts.append(c)
+            y = y + out
+        return (y, kc, vc, sc, cc, pi + 1), (
+            jnp.stack(counts) if counts else None
+        )
+
+    if cfg.is_pattern:
+        (x, kc, vc, sc, cc, _), counts = jax.lax.scan(
+            pattern_body,
+            (x, cache.k, cache.v, cache.state, cache.conv, jnp.int32(0)),
+            _pattern_view(cfg, blocks),
+        )
+        if counts is not None:
+            counts = counts.reshape(-1, counts.shape[-1])
+        logits = _head(params, cfg, _final_norm(params, cfg, x))[:, 0]
+        new_cache = KVCache(k=kc, v=vc, state=sc, conv=cc)
+        if with_moe_counts:
+            return logits, new_cache, counts
+        return logits, new_cache
 
     n_lead = cfg.first_k_dense
     # k/v, or latent attention's one buffer of rows in k's place.
@@ -1650,6 +1940,8 @@ def init_paged_kv_cache(
 ) -> PagedKVCache:
     if cfg.is_latent:
         raise LatentLayoutError(_NO_SERVING_LATENT)
+    if cfg.is_pattern:
+        raise HybridLayoutError(_NO_SERVING_PATTERN)
     shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads * cfg.head_dim)
     dtype = dtype or cfg.dtype
     if dtype in (jnp.int8, "int8"):
@@ -1704,6 +1996,15 @@ _NO_SERVING_STATE = (
 )
 
 
+_NO_SERVING_PATTERN = (
+    "a Mamba-2 layer's recurrent state and conv tail have no slot on the "
+    "serving plane yet, and its chunk has one kind of layer: a pattern of "
+    "one-branch layers generates on the static decode program only (at "
+    "most max_decode_batch requests, no stop sequences, no speculative "
+    "decoding, max_new_tokens within static_path_max_new)"
+)
+
+
 _NO_SERVING_LATENT = (
     "latent rows have no pages on the serving plane yet, and its chunk has "
     "no layer before the scan: latent attention and leading dense layers "
@@ -1750,6 +2051,8 @@ def decode_step_ragged_paged(
         raise HybridLayoutError(_NO_SERVING_STATE)
     if cfg.is_latent:
         raise LatentLayoutError(_NO_SERVING_LATENT)
+    if cfg.is_pattern:
+        raise HybridLayoutError(_NO_SERVING_PATTERN)
     t = tokens.shape[0]
     b = page_table.shape[0]
     live = row_of < b

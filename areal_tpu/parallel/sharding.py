@@ -105,6 +105,19 @@ _BLOCK_RULES: Dict[str, P] = {
     "wk_b": P(PIPE_AXIS, FSDP_AXIS, None),
     "wv_b": P(PIPE_AXIS, FSDP_AXIS, None),
     "router_bias": P(PIPE_AXIS, None),
+    # Mamba-2 leaves: ZeRO-sharded over fsdp, NOT split over `model` — the
+    # heads sit in the packed output axis of `ssm_in` beside the groups'
+    # B | C and the per-head dt, and the conv, the gated norm's groups and
+    # the state would all have to split with them; `attn_dispatch` refuses
+    # a pattern of one-branch layers on a mesh with model > 1 by name.
+    "ssm_in": P(PIPE_AXIS, FSDP_AXIS, None),
+    "ssm_conv": P(PIPE_AXIS, None, None),
+    "ssm_conv_b": P(PIPE_AXIS, None),
+    "ssm_A_log": P(PIPE_AXIS, None),
+    "ssm_D": P(PIPE_AXIS, None),
+    "ssm_dt_bias": P(PIPE_AXIS, None),
+    "ssm_norm": P(PIPE_AXIS, None),
+    "ssm_out": P(PIPE_AXIS, None, FSDP_AXIS),
 }
 
 _TOP_RULES: Dict[str, P] = {
@@ -220,6 +233,15 @@ def attn_dispatch(mesh: Mesh, cfg=None):
             "heads, no ring over a split sequence, and a pipeline stage "
             "would have to be whole periods (PERF.md section 7)"
         )
+    if cfg is not None and cfg.is_pattern and any(
+        mesh.shape[a] > 1 for a in (MODEL_AXIS, SEQ_AXIS, PIPE_AXIS)
+    ):
+        from areal_tpu.models.transformer import (
+            _NO_PATTERN_LAYOUT,
+            HybridLayoutError,
+        )
+
+        raise HybridLayoutError(f"mesh {dict(mesh.shape)}: {_NO_PATTERN_LAYOUT}")
     if cfg is not None and cfg.is_latent and any(
         mesh.shape[a] > 1 for a in (MODEL_AXIS, SEQ_AXIS, PIPE_AXIS)
     ):

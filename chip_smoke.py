@@ -284,6 +284,109 @@ def phase_kernels(geom, on_tpu):
 
     _latent_cell_shape(attention, dt, tol, on_tpu)
 
+    _ssm_cell_shape(dt, tol, on_tpu)
+
+
+def _ssm_cell_shape(dt, tol, on_tpu, reps=10):
+    """The Mamba-2 decode step (`models/mamba.ssm_step`) at
+    `nemo3n-rollout64-512`'s shape (64 rows, 4 layers of 64 heads x 64
+    channels with an fp32 state of 128, conv over 6,144 channels; 2 layers
+    of 4 heads x 16 with a state of 16 in rehearsal): a few tokens stepped
+    one at a time end on the state and the outputs of the chunked scan over
+    the same tokens (`ssm_forward`), and the time of one sweep over the
+    layers goes to the log beside what the bandwidth allows.  The sweep
+    runs over a state MADE INSIDE the program, as the decode loop's is (a
+    cache handed in from outside keeps an entry parameter's layout and is
+    copied in front of the step: PR 38's threefold misreading)."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.models import mamba
+    from areal_tpu.models.config import ModelConfig
+    from benchmark import peaks_ssm
+
+    sizes = dict(hidden_dim=2688, ssm_n_heads=64, ssm_head_dim=64,
+                 ssm_n_groups=8, ssm_state_dim=128) if on_tpu else dict(
+        hidden_dim=64, ssm_n_heads=4, ssm_head_dim=16, ssm_n_groups=2,
+        ssm_state_dim=16, ssm_chunk=4)
+    n_layers, b, t = (4, 64, 6) if on_tpu else (2, 4, 6)
+    cfg = ModelConfig(
+        n_layers=n_layers, n_q_heads=2, n_kv_heads=2, head_dim=16,
+        intermediate_dim=16, vocab_size=16, rms_norm_eps=1e-5,
+        layer_pattern="M" * n_layers,
+        param_dtype="bfloat16" if on_tpu else "float32", **sizes)
+
+    def dense(key, shape, fan_in):
+        return (jax.random.normal(key, shape) * fan_in**-0.5).astype(dt)
+
+    blocks = mamba.init_ssm(cfg, jax.random.PRNGKey(3), n_layers, dense)
+    h = jax.random.normal(
+        jax.random.PRNGKey(4), (b, t, cfg.hidden_dim)).astype(dt)
+
+    @jax.jit
+    def both(blocks, h):
+        blk = {n: w[0] for n, w in blocks.items()}
+        want, state, _ = mamba.ssm_forward(
+            h, blk, cfg, jnp.ones((b, t), jnp.int32), with_state=True)
+        states = jnp.zeros(
+            (n_layers, b, cfg.ssm_n_heads, cfg.ssm_head_dim,
+             cfg.ssm_state_dim), jnp.float32)
+        tails = jnp.zeros(
+            (n_layers, b, cfg.ssm_conv_kernel - 1, cfg.ssm_conv_dim), dt)
+        got = []
+        for i in range(t):
+            y, states, tails = mamba.ssm_step(
+                h[:, i: i + 1], blk, cfg, states, tails, 0)
+            got.append(y[:, 0])
+        return want, jnp.stack(got, 1), state, states[0]
+
+    want, got, state, stepped = both(blocks, h)
+    scale = float(jnp.max(jnp.abs(want.astype(jnp.float32)))) or 1.0
+    err = _max_err(want, got) / scale
+    check(err <= tol, f"mamba decode steps == chunked scan (max rel err {err:.2e})")
+    err = _max_err(state, stepped) / (float(jnp.max(jnp.abs(state))) or 1.0)
+    check(err <= tol, f"mamba state after the steps == the scan's (max rel err {err:.2e})")
+
+    def timed(sweeps):
+        @jax.jit
+        def run(blocks, x, key):
+            states = jax.random.normal(
+                key, (n_layers, b, cfg.ssm_n_heads, cfg.ssm_head_dim,
+                      cfg.ssm_state_dim), jnp.float32)
+            tails = jnp.zeros(
+                (n_layers, b, cfg.ssm_conv_kernel - 1, cfg.ssm_conv_dim), dt)
+
+            def layer(i, carry):
+                y, states, tails = carry
+                li = i % n_layers
+                blk = {n: jax.lax.dynamic_index_in_dim(w, li, 0, False)
+                       for n, w in blocks.items()}
+                out, states, tails = mamba.ssm_step(
+                    y, blk, cfg, states, tails, li)
+                return 0.5 * (y + out), states, tails
+
+            y, states, _ = jax.lax.fori_loop(
+                0, sweeps * n_layers, layer, (x, states, tails))
+            return y, states[0, 0, 0, 0, 0]
+        return run
+
+    wall, key = {}, jax.random.PRNGKey(5)
+    for sweeps in (1, 1 + reps):
+        fn = timed(sweeps)
+        jax.block_until_ready(fn(blocks, h[:, :1], key))
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(blocks, h[:, :1], key))
+        wall[sweeps] = time.perf_counter() - t0
+    ms = (wall[1 + reps] - wall[1]) / reps * 1e3
+    floor_ms = peaks_ssm.ssm_decode_bytes(cfg, b) / 819e9 * 1e3
+    log(f"  mamba decode step: {n_layers} layers x {b} rows, state "
+        f"[{cfg.ssm_n_heads}, {cfg.ssm_head_dim}, {cfg.ssm_state_dim}] fp32: "
+        f"{ms:.3f} ms a sweep where a v5e's 819 GB/s allow {floor_ms:.3f} "
+        f"(host clock, {reps} sweeps over a state made in the program"
+        + ("" if on_tpu else "; on the cpu, no device time") + ")")
+
 
 def _latent_cell_shape(attention, dt, tol, on_tpu, reps=10):
     """The latent decode kernel against the XLA form on a STACKED latent

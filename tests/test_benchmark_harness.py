@@ -57,15 +57,57 @@ GLM_ENTRIES = [
 ]
 
 
+NEMO_CELL = "nemo3n-rollout64-512"
+NEMO_CONFIG = "nemotron-3-nano-30b-a3b-l9-e16"
+# PR 40's entries, in ISSUE 40's order: five of the Mamba layers' own and
+# the five twins the other two share cells have, over `benchmark/peaks_ssm.py`.
+NEMO_ENTRIES = [
+    ("ssm_decode_ms", "ms", "lower", "device_trace", "model step",
+     "gen_tokens_per_s"),
+    ("ssm_decode_roofline", "%", "higher", "device_trace", "kernels",
+     "gen_tokens_per_s"),
+    ("ssm_state_share", "%", "lower", "program_counter", "generator",
+     "gen_tokens_per_s"),
+    ("ssm_train_share", "%", "lower", "device_trace", "model step",
+     "train_tokens_per_s"),
+    ("ssm_train_mfu", "%", "higher", "device_trace", "kernels",
+     "train_tokens_per_s"),
+    ("decode_hbm_share_ssm", "%", "higher", "device_trace", "model step",
+     "gen_tokens_per_s"),
+    ("mfu_train_ssm", "%", "higher", "host_clock", "model step",
+     "train_tokens_per_s"),
+    ("moe_decode_mlp_roofline_ssm", "%", "higher", "device_trace", "kernels",
+     "gen_tokens_per_s"),
+    ("mfu_gen_ssm", "%", "higher", "host_clock", "model step",
+     "gen_tokens_per_s"),
+    ("moe_train_mlp_mfu_ssm", "%", "higher", "device_trace", "kernels",
+     "train_tokens_per_s"),
+]
+
+
+def _at(entries, name):
+    """Index of the entry called `name`: the benchmark is pinned by NAME,
+    so that what a later PR appends moves no case."""
+    return next(i for i, m in enumerate(entries) if m["name"] == name)
+
+
 def test_the_new_entries_are_where_the_issue_put_them(monkeypatch):  # noqa: F811
     """PR 36's case pins ITS eight entries as the last of `per_layer`;
     entries appended since (PR 37's `paged_attn_live_page_share`, PR 38's
-    ten for the latent-attention cell, PR 39's `sample_draw_ms`) move them
-    up.  So: the appended entries where their issues put them, then PR
-    36's case on the list as it stood before — `benchmark/tests/` is not
-    a perf or a model_config PR's to edit (PERF.md §7)."""
+    ten for the latent-attention cell, PR 39's `sample_draw_ms`, PR 40's
+    ten for the Mamba cell) move them up.  So: the appended entries where
+    their issues put them, found by name, then PR 36's case on the list as
+    it stood before — `benchmark/tests/` is not a perf or a model_config
+    PR's to edit (PERF.md §7)."""
     n = len(GLM_ENTRIES)
-    assert SPEC["per_layer"][-1] == {
+    per_layer = SPEC["per_layer"]
+    draw = _at(per_layer, "sample_draw_ms")
+    assert per_layer[draw + 1: draw + 1 + len(NEMO_ENTRIES)] == [
+        {"name": name, "unit": unit, "better": better, "source": source,
+         "layer": layer, "moves": moves, "workloads": [NEMO_CELL]}
+        for name, unit, better, source, layer, moves in NEMO_ENTRIES
+    ]
+    assert per_layer[draw] == {
         "name": "sample_draw_ms", "unit": "ms", "better": "lower",
         "source": "device_trace", "layer": "model step",
         "moves": "gen_tokens_per_s",
@@ -74,12 +116,12 @@ def test_the_new_entries_are_where_the_issue_put_them(monkeypatch):  # noqa: F81
             if m["name"] == "gen_tokens_per_s"
         ),
     }
-    assert SPEC["per_layer"][-n - 1:-1] == [
+    assert per_layer[draw - n: draw] == [
         {"name": name, "unit": unit, "better": better, "source": source,
          "layer": layer, "moves": moves, "workloads": [GLM_CELL]}
         for name, unit, better, source, layer, moves in GLM_ENTRIES
     ]
-    last = SPEC["per_layer"][-n - 2]
+    last = per_layer[draw - n - 1]
     assert last == {
         "name": "paged_attn_live_page_share", "unit": "%", "better": "lower",
         "source": "program_counter", "layer": "generator",
@@ -88,7 +130,7 @@ def test_the_new_entries_are_where_the_issue_put_them(monkeypatch):  # noqa: F81
     assert [
         c for c in CELLS if last in files.metrics_for(c, traced=True)
     ] == ["q1p5b-serving-waves"]
-    before = dict(SPEC, per_layer=SPEC["per_layer"][:-n - 2])
+    before = dict(SPEC, per_layer=per_layer[: draw - n - 1])
     monkeypatch.setattr(files, "benchmark_json", lambda: before)
     ledger_cases.test_the_new_entries_are_where_the_issue_put_them()
 
@@ -99,15 +141,14 @@ def test_the_glm_cell_is_as_the_issue_parametrised_it():
     none whose arithmetic (`benchmark/peaks.py`: a GQA layer everywhere,
     every chosen expert local) is wrong for it."""
     cell, config, traffic = files.load_cell(GLM_CELL)
-    assert SPEC["workloads"][-1] == {
+    entry = SPEC["workloads"][_at(SPEC["workloads"], GLM_CELL)]
+    assert entry == {
         "name": GLM_CELL, "config": "glm-4.7-flash-l7-e8",
-        "traffic": "rollout64-1k", "chips": 1,
-        "why": SPEC["workloads"][-1]["why"],
+        "traffic": "rollout64-1k", "chips": 1, "why": entry["why"],
     }
-    assert len(SPEC["workloads"][-1]["why"]) <= 200
-    assert len(SPEC["configs"][-1]["why"]) <= 200
-    assert SPEC["configs"][-1]["name"] == "glm-4.7-flash-l7-e8"
-    assert SPEC["configs"][-1]["reduced"] == [
+    conf = SPEC["configs"][_at(SPEC["configs"], "glm-4.7-flash-l7-e8")]
+    assert len(entry["why"]) <= 200 and len(conf["why"]) <= 200
+    assert conf["reduced"] == [
         "num_hidden_layers", "n_routed_experts", "vocab_size"]
     assert (cell["route"], cell["timed_steps"], cell["traffic_seed"]) == (
         "static", 3, 38)
@@ -125,12 +166,67 @@ def test_the_glm_cell_is_as_the_issue_parametrised_it():
         "moe_experts_touched", "moe_decode_mlp_ms", "moe_route_share",
         "moe_local_rows_share", "sample_draw_ms",
     }
-    # Appended, and nothing else of those lists changed: the cell is last.
+    # Appended, and nothing else of those lists changed: the cell is the
+    # last of the seven cells the benchmark then had, in every list.
+    then = CELLS[: CELLS.index(GLM_CELL) + 1]
     for m in SPEC["end_to_end"] + SPEC["per_layer"]:
         if GLM_CELL in m.get("workloads", []):
-            assert m["workloads"][-1] == GLM_CELL, m["name"]
-    assert len(SPEC["workloads"]) == 7 and sum(
+            assert [w for w in m["workloads"] if w in then][-1] == GLM_CELL, (
+                m["name"])
+    assert len(then) == 7 and sum(
         w["chips"] == 4 for w in SPEC["workloads"]) == 1
+
+
+def test_the_nemotron_cell_is_as_the_issue_parametrised_it():
+    """ISSUE 40: one configuration, one cell on the traffic file the hybrid
+    cell uses, ten metrics of its own, and its name appended, last, to the
+    eight lists whose arithmetic holds for it — and to none whose
+    arithmetic (`benchmark/peaks.py`, `peaks_hybrid.py`, `peaks_mla.py`)
+    is wrong for it."""
+    cell, config, traffic = files.load_cell(NEMO_CELL)
+    entry = SPEC["workloads"][_at(SPEC["workloads"], NEMO_CELL)]
+    assert entry == {
+        "name": NEMO_CELL, "config": NEMO_CONFIG,
+        "traffic": "rollout64-512", "chips": 1, "why": entry["why"],
+    }
+    conf = SPEC["configs"][_at(SPEC["configs"], NEMO_CONFIG)]
+    assert conf == {
+        "name": NEMO_CONFIG,
+        "source": config["benchmark"]["source"],
+        "file": f"benchmark/configs/{NEMO_CONFIG}.json",
+        "reduced": ["num_hidden_layers", "n_routed_experts", "vocab_size"],
+        "why": conf["why"],
+    }
+    assert len(entry["why"]) <= 200 and len(conf["why"]) <= 200
+    assert CELLS.index(NEMO_CELL) == CELLS.index(GLM_CELL) + 1 == 7
+    assert (cell["route"], cell["timed_steps"], cell["traffic_seed"]) == (
+        "static", 4, 40)
+    assert config["model_type"] == "nemotron_h"
+    assert config["benchmark"]["reference"] == "nemotron_h"
+    assert config["benchmark"]["layout"] == {
+        "chips": 1, "actor_parallel": "d1", "gen_parallel": None}
+    # The traffic file is the hybrid cell's, unchanged.
+    assert files.load_cell("q3next-rollout64-512")[2] == traffic
+    assert traffic["n_prompts"] * traffic["group"] == 64
+    assert (traffic["group"], traffic["max_new_tokens"]) == (4, 512)
+    assert traffic["prompt_len"] == {"dist": "uniform", "lo": 96, "hi": 160}
+    listed = {
+        m["name"] for m in SPEC["end_to_end"] + SPEC["per_layer"]
+        if NEMO_CELL in m.get("workloads", [])
+    }
+    assert listed == {name for name, *_ in NEMO_ENTRIES} | {
+        "gen_tokens_per_s", "decode_ms_per_step", "decode_loop_ms",
+        "moe_experts_touched", "moe_decode_mlp_ms", "moe_route_share",
+        "moe_local_rows_share", "sample_draw_ms",
+    }
+    then = CELLS[: CELLS.index(NEMO_CELL) + 1]
+    for m in SPEC["end_to_end"] + SPEC["per_layer"]:
+        if NEMO_CELL in m.get("workloads", []):
+            assert [w for w in m["workloads"] if w in then][-1] == NEMO_CELL, (
+                m["name"])
+    # Every reader the cell is listed under exists, and so does every file.
+    for name in listed:
+        assert callable(files.load_module("metrics", name).read), name
 
 
 @pytest.mark.parametrize(
@@ -616,3 +712,109 @@ def test_the_mla_readers_say_nothing_without_their_scopes_or_counters():
     assert peaks_mla.mlps_train_flops(big, 24) == pytest.approx(3 * 2 * 24 * (
         6 * (peaks_mla.sparse_mlp_params(big) + 0.5 * big.hidden_dim / 2)
         + peaks_mla.dense_mlp_params(big)), rel=1e-3)
+
+
+def test_cpu_rehearsal_of_the_nemotron_cell_is_correct():
+    """The Mamba cell end to end at toy size (the config's `toy` group keeps
+    the pattern MEMEM*EME whole: 4 heads x 16, state 16, 2 groups, 4 of 8
+    experts): the static program through the three populations of the
+    cache, the hand-back of all 22 leaves with the router's bias unchanged,
+    the reference and its check of the generator's own 64-slot program for
+    generator and trainer."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=files.ROOT)
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run(
+        [sys.executable, "-m", "benchmark.run", "--workload", NEMO_CELL,
+         "--seed", "3000000013", "--seconds", "1", "--trace", "0",
+         "--cpu-rehearsal"],
+        cwd=files.ROOT, env=env, capture_output=True, text=True, timeout=600,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert proc.stdout.strip() == ""  # platform=cpu: no result line
+    lines = proc.stderr.splitlines()
+    out = json.loads(
+        [l for l in lines if "would print: " in l][-1].split("would print: ")[1])
+    assert out["correct"] is True and out["failed"] == 0
+    assert out["attempted"] in (2 * 64, 3 * 64, 4 * 64)  # whole steps of 64
+    assert {"gen_tokens_per_s", "train_tokens_per_s", "samples_per_s",
+            "setup_s"} <= set(out["metrics"])
+    check = [l for l in lines if "weight check: " in l][-1]
+    assert "'ok': True" in check and "'leaves': 22" in check, check
+    assert any("nemotron_h reference" in l and "[0, 4) of 8" in l
+               for l in lines)
+    assert any("nemotron_h generator check" in l and l.endswith(" ok")
+               for l in lines)
+
+
+def _nemo_readers():
+    from benchmark.metrics import (
+        decode_hbm_share_ssm, mfu_gen_ssm, mfu_train_ssm,
+        moe_decode_mlp_roofline_ssm, moe_train_mlp_mfu_ssm, ssm_decode_ms,
+        ssm_decode_roofline, ssm_state_share, ssm_train_mfu, ssm_train_share,
+    )
+    return locals()
+
+
+def test_the_ssm_readers_say_nothing_without_their_scopes_or_counters():
+    """On a program that lacks what PR 40 added (the parent, or any other
+    configuration) every new reader returns None and does not raise; on a
+    pattern of one-branch layers each reads its scopes and counters."""
+    from areal_tpu.models.config import tiny_config
+    from benchmark import peaks_ssm
+    from benchmark.run import model_config
+
+    r = _nemo_readers()
+    assert sorted(r) == sorted(name for name, *_ in NEMO_ENTRIES)
+    scopes = {
+        "train/grad/layer/mlp": {"fwd": 1.0, "recompute": 0.0, "bwd": 1.0},
+        "train/grad/layer/attn": {"fwd": 0.5, "recompute": 0.5, "bwd": 1.0},
+        "gen/decode_step/layer/mlp": {"fwd": 0.008, "recompute": 0.0, "bwd": 0.0},
+    }
+    bare = _glm_run(tiny_config(), {}, scopes)
+    for name, reader in r.items():
+        assert reader.read(bare) is None, name
+    # A hybrid configuration's counters are not this family's either.
+    hybrid = _glm_run(
+        model_config(files.load_json("configs", "qwen3-next-80b-a3b-l4-e64.json")),
+        {"state_cache_bytes": 9.0, "kv_cache_bytes": 1.0}, scopes)
+    for name, reader in r.items():
+        assert reader.read(hybrid) is None, name
+    big = model_config(files.load_json("configs", f"{NEMO_CONFIG}.json"))
+    pool = {"state_cache_bytes": 546.0, "kv_cache_bytes": 50.0,
+            "moe_experts_touched": 15.25, "moe_rows_local": 8 * 4 * 1.5,
+            "moe_decode_steps": 8}
+    scopes.update({
+        "gen/decode_step/layer/ssm/ssm_step": {
+            "fwd": 0.012, "recompute": 0.0, "bwd": 0.0},
+        "gen/decode_step/layer/ssm/in_proj": {
+            "fwd": 0.004, "recompute": 0.0, "bwd": 0.0},
+        "train/grad/layer/ssm/ssd_scan": {
+            "fwd": 0.5, "recompute": 0.5, "bwd": 1.0},
+    })
+    run = _glm_run(big, pool, scopes)
+    assert r["ssm_state_share"].read(run) == pytest.approx(100 * 546 / 596)
+    assert r["ssm_decode_ms"].read(run) == 2.0  # 16 ms over 8 decode steps
+    assert r["ssm_train_share"].read(run) == pytest.approx(100 * 2.0 / 6.0)
+    assert r["ssm_decode_roofline"].read(run) == pytest.approx(
+        100 * 1e3 * peaks_ssm.ssm_decode_bytes(big, 2) / 819e9 / 2.0)
+    assert r["ssm_train_mfu"].read(run) == pytest.approx(
+        100 * peaks_ssm.ssm_train_flops(big, 24) / 2.0 / 197e12)
+    assert r["mfu_train_ssm"].read(run) == pytest.approx(
+        100 * peaks_ssm.flops_train(big, [12, 12]) / 197e12)
+    loop_ms, mlp_ms = 16.0 / 8, 8.0 / 8
+    assert r["decode_hbm_share_ssm"].read(run) == pytest.approx(
+        100 * 1e3 * peaks_ssm.decode_bytes(big, [8.0, 8.0], 15.25, 1.5)
+        / 819e9 / loop_ms)
+    assert r["moe_decode_mlp_roofline_ssm"].read(run) == pytest.approx(
+        100 * 1e3 * peaks_ssm.experts_decode_bytes(big, 2, 15.25, 1.5)
+        / 819e9 / mlp_ms)
+    assert r["mfu_gen_ssm"].read(run) == pytest.approx(
+        100 * peaks_ssm.flops_generate(big, [4, 4], [8, 8]) / 197e12)
+    assert r["moe_train_mlp_mfu_ssm"].read(run) == pytest.approx(
+        100 * peaks_ssm.experts_train_flops(big, 24) / 2.0 / 197e12)
+    # Two matrices an expert, 0.75 of a token's 6 choices held here, the
+    # router's whole width and the shared expert, over the FOUR layers.
+    h, f = big.hidden_dim, big.moe_intermediate_dim
+    assert peaks_ssm.experts_train_flops(big, 24) == pytest.approx(
+        3 * 2 * 24 * 4 * (0.75 * (2 * h * f + h) + h * 128 + 2 * h * 3712),
+        rel=1e-3)
