@@ -115,18 +115,34 @@ def _cast_tree(tree, dtype):
     )
 
 
-def _moe_stats(aux, counts) -> Dict[str, jax.Array]:
+def _moe_stats(aux, counts, cfg: ModelConfig, pairs: int) -> Dict[str, jax.Array]:
     """A micro-batch's MoE counters (plain keys: averaged over micro-batches
     by `train_batch`): the router's load-balancing loss as added to the
     objective, and the real tokens on the fullest expert over the mean per
     expert, mean over layers (1.0 = perfectly balanced; `counts` is None
-    under PP, which reports the loss alone)."""
+    under PP, which reports the loss alone).  Where the grouped dispatch
+    works on a slab of the layer's `pairs` (row, choice) pairs — a rank's
+    share of under half its router, `transformer._experts_grouped` — two
+    more: the real tokens' pairs held here over the slab's rows, max over
+    layers (past 1.0 the overflow ran), and the rows gathered for them over
+    `pairs` in %, mean over layers (the dispatch's own loop bound,
+    `expert_slabs_run`: one slab, and one more for every slab's worth held
+    beyond it).  A slab's dispatch leaves pads out (`_mlp_moe`), so `counts`
+    there is its group sizes and these count what it did."""
     out = {"moe/aux_loss": jax.lax.stop_gradient(aux)}
     if counts is not None:
         c = counts.astype(jnp.float32)  # [L, E]
         out["moe/load_max_over_mean"] = jnp.mean(
             c.max(axis=-1) / jnp.maximum(c.mean(axis=-1), 1e-9)
         )
+        slab = tfm.expert_slab_rows(cfg, pairs)
+        if slab < pairs and cfg.moe_dispatch == "grouped":
+            held = counts.sum(axis=-1)
+            slabs = tfm.expert_slabs_run(slab, pairs, held)
+            out["moe/slab_fill_max"] = jnp.max(held / slab)
+            out["moe/rows_gathered_share"] = jnp.mean(
+                jnp.minimum(slabs * slab, pairs) * (100.0 / pairs)
+            )
     return out
 
 
@@ -386,7 +402,13 @@ class TrainEngine(HostOffloadMixin, Engine):
                 loss, stats = loss_fn(out, batch)
                 total = loss + cfg.moe_aux_loss_coef * aux
                 if cfg.is_moe:
-                    stats = {**stats, **_moe_stats(aux, counts)}
+                    stats = {
+                        **stats,
+                        **_moe_stats(
+                            aux, counts, cfg,
+                            batch["tokens"].size * cfg.n_experts_per_tok,
+                        ),
+                    }
                 if cfg.has_recurrent_state:
                     # Every segment start is a restart of the recurrence
                     # and of the conv inside a packed row.

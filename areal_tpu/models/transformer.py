@@ -362,7 +362,12 @@ def _moe_route(x: jax.Array, blk: Params, cfg: ModelConfig):
     them, but `top_idx` and `one_hot` come back in LOCAL numbering over the
     `n_experts` held here — a choice that fell to an expert held elsewhere
     is index `n_experts` (sorts last, one-hot all zero), so every dispatch
-    below computes this rank's part of the sum and nothing for the rest."""
+    below computes this rank's part of the sum and nothing for the rest.
+    What that costs is the dispatch's: `_experts_dense` and `_experts_topk`
+    pass over every (row, choice) pair, and so does `_experts_grouped` in a
+    decode step; over packed rows and in prefill it touches
+    `expert_slab_rows` of them — twice a balanced router's — and the rest
+    only where more than that many are held (its overflow, never a drop)."""
     router_logits = (x.astype(jnp.float32)) @ blk["router"].astype(jnp.float32)  # [T, E]
     if cfg.moe_score_func == "sigmoid":
         return _moe_route_sigmoid(router_logits, blk, cfg)
@@ -390,7 +395,9 @@ def _moe_route(x: jax.Array, blk: Params, cfg: ModelConfig):
 def _local_numbering(top_idx: jax.Array, cfg: ModelConfig) -> jax.Array:
     """A rank's share: the chosen experts numbered over the `n_experts`
     held here; a choice held elsewhere is `n_experts` (sorts last, one-hot
-    all zero)."""
+    all zero).  Sorting last is what the grouped dispatch's slab rests on:
+    under its stable sort the held pairs are the first
+    `sum(group_sizes)` of the order, in the positions they always had."""
     local = top_idx - cfg.expert_offset
     held = (local >= 0) & (local < cfg.n_experts)
     return jnp.where(held, local, cfg.n_experts)
@@ -480,6 +487,34 @@ def _experts_topk(x, top_w, top_idx, one_hot, blk: Params, cfg: ModelConfig):
         return jnp.einsum("tec,ecd->td", combine, ye)
 
 
+# A share's slab over the pairs a balanced router sends the rank.  Two: the
+# slab's rows are paid for on every call and the overflow only when it is
+# taken, and the fullest rank of a router trained under the load-balancing
+# loss (or the choice bias) stays well under twice the mean, so the
+# overflow is for the batch that is out of the ordinary, not for a tail of
+# every batch.  A multiple of 512 rows keeps the ragged kernels' row tiles
+# whole.
+_SLAB_OVER_BALANCED = 2
+_SLAB_ROW_MULTIPLE = 512
+
+
+def expert_slab_rows(cfg: ModelConfig, pairs: int) -> int:
+    """Of `pairs` (row, choice) pairs, how many the grouped dispatch
+    gathers before it asks whether more are held here (`_experts_grouped`):
+    all of them unless `cfg` is a rank's share of under half the router."""
+    if not cfg.expert_share:
+        return pairs
+    rows = -(-_SLAB_OVER_BALANCED * pairs * cfg.n_experts // cfg.router_width)
+    return min(pairs, -(-rows // _SLAB_ROW_MULTIPLE) * _SLAB_ROW_MULTIPLE)
+
+
+def expert_slabs_run(slab: int, pairs: int, held: jax.Array) -> jax.Array:
+    """How many slabs of `slab` rows the grouped dispatch runs over `pairs`
+    sorted pairs of which the first `held` are held here: the first always,
+    then as many as hold a held pair."""
+    return jnp.clip(-(-held // slab), 1, -(-pairs // slab))
+
+
 def _experts_grouped(
     x, top_w, top_idx, one_hot, blk: Params, cfg: ModelConfig, layer=None,
     kernel: bool = False,
@@ -519,12 +554,139 @@ def _experts_grouped(
     ragged_dot by gathering the expert dim — ZeRO-style weight
     gathering, the right trade below ~100B total expert bytes.  True
     token all-to-all EP stays on `moe_dispatch="topk"`.
+
+    One expert-parallel rank's share (`cfg.expert_share`) holds an eighth
+    of the pairs, say, and they are the FIRST `sum(group_sizes)` of the
+    stable order (`_local_numbering`).  On the unstacked path (`layer` is
+    None: the gradient program, `forward`, `prefill`) it therefore runs on
+    a slab of the order's first `expert_slab_rows` pairs — twice what a
+    balanced router sends here — and not on all T*k: same rows at the same
+    offsets in the same groups, and what is left out of the scatter-add
+    were exact zeros.  Pairs held past the slab go through the same path a
+    slab at a time, every group's sizes cut to the slab's window, and their
+    sum is added (`_grouped_slabs`): a router that sends this rank more
+    than twice its share costs as many slabs as hold its pairs, never a
+    dropped pair.  The first slab stays OUTSIDE the loop: the benchmark's
+    readers find a program's ragged kernels by the row count of the scoped
+    activation product under `layer/mlp/experts`
+    (`benchmark/metrics/_moe.py`).  A decode step (stacked leaves, a few
+    hundred pairs: latency, not bytes) keeps every pair on the one path.
     """
-    k = cfg.n_experts_per_tok
     with jax.named_scope("dispatch"):
         flat_e = top_idx.reshape(-1)  # [T*k], token-major
         order = jnp.argsort(flat_e, stable=True)
         group_sizes = jnp.sum(one_hot, axis=(0, 1)).astype(jnp.int32)  # [E]
+    pairs = order.shape[0]
+    slab = pairs if layer is not None else expert_slab_rows(cfg, pairs)
+    if slab == pairs:  # every expert held, a decode step, a share of half
+        return _grouped_rows(
+            x, top_w, order, group_sizes, blk, cfg, layer, kernel
+        )
+    experts = {n: blk[n] for n in _expert_leaves(cfg)}
+    return _grouped_slabs(cfg, slab, x, top_w, experts, order, group_sizes)
+
+
+def _slabs(cfg: ModelConfig, slab: int, order, group_sizes):
+    """The sorted pairs in slabs of `slab` -> (rows(i, x, top_w, experts,
+    into): `_grouped_rows` over pairs [i*slab, (i+1)*slab) with every
+    group's sizes cut to that window; later(body, first): `body(i, carry)`
+    from `first` on over the slabs after the first that hold a held pair,
+    a loop with a traced bound and, as a rule, no trip)."""
+    with jax.named_scope("dispatch"):
+        # Past the last pair: index 0, beyond every group, so zeroed.
+        padded = jnp.pad(order, (0, -order.shape[0] % slab))
+        ends = jnp.cumsum(group_sizes)
+        starts = ends - group_sizes
+
+    def rows(i, x, top_w, experts, into=None):
+        with jax.named_scope("dispatch"):
+            lo = i * slab
+            mine = jax.lax.dynamic_slice(padded, (lo,), (slab,))
+            sizes = jnp.clip(ends, lo, lo + slab) - jnp.clip(
+                starts, lo, lo + slab
+            )
+        return _grouped_rows(x, top_w, mine, sizes, experts, cfg, into=into)
+
+    def later(body, first):
+        with jax.named_scope("overflow"):
+            return jax.lax.fori_loop(
+                1, expert_slabs_run(slab, order.shape[0], ends[-1]), body, first
+            )
+
+    return rows, later
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _grouped_slabs(cfg: ModelConfig, slab: int, x, top_w, experts, order, group_sizes):
+    """A share's dispatch: `_grouped_rows` over the first `slab` pairs of
+    the order, outside any control flow, plus the sum of the slabs after it
+    for as long as they hold a held pair (`lax.fori_loop` with a traced
+    bound: no trip unless more than `slab` pairs are held here).  One
+    algorithm for every program, with or without a gradient.
+
+    The later slabs add up from zero and NOT from the first slab's result:
+    the first slab's scatter-add then meets what follows it — the shared
+    expert's sum — as the full gather's did, XLA:TPU fuses the two as it
+    did, and a batch that makes no trip gets the full gather's bits (with
+    the first slab as the loop's carry it no longer did, and in the
+    latent-attention cell one flipped top-4-of-64 choice put a token's
+    log-prob 0.91 from the reference where the limit is 0.6 and the full
+    gather reads 0.41: chip runs, PR 41).
+
+    The gradient rule is its own because of what autodiff of control flow
+    keeps and returns: a `cond` around the rest would make every residual
+    of the taken branch an output of both, and hand back the experts'
+    gradients as fresh [E, in, out] buffers of zeros from the branch not
+    taken, on every call and live until the layer's gradients are stacked
+    (chip run, PR 41: the hybrid cell's gradient program no longer fitted,
+    8.77 GB of temporaries where 8.09 GB were free); a loop with a traced
+    bound has no reverse mode at all.  Here the first slab's backward pass
+    is autodiff's own (`jax.vjp`, its residuals kept as autodiff would), and
+    the loop runs again on the way back, each later slab's forward
+    recomputed inside it and its gradients added in place."""
+    rows, later = _slabs(cfg, slab, order, group_sizes)
+    return _slab_sum(rows, later, rows(0, x, top_w, experts), x, top_w, experts)
+
+
+def _slab_sum(rows, later, first, x, top_w, experts):
+    return first + later(
+        lambda i, out: rows(i, x, top_w, experts, out), jnp.zeros_like(first)
+    )
+
+
+def _grouped_slabs_fwd(cfg, slab, x, top_w, experts, order, group_sizes):
+    rows, later = _slabs(cfg, slab, order, group_sizes)
+    first, first_vjp = jax.vjp(functools.partial(rows, 0), x, top_w, experts)
+    out = _slab_sum(rows, later, first, x, top_w, experts)
+    return out, (first_vjp, x, top_w, experts, order, group_sizes)
+
+
+def _grouped_slabs_bwd(cfg, slab, res, ct):
+    first_vjp, x, top_w, experts, order, group_sizes = res
+    rows, later = _slabs(cfg, slab, order, group_sizes)
+
+    def add(i, grads):
+        more = jax.vjp(functools.partial(rows, i), x, top_w, experts)[1](ct)
+        return jax.tree.map(jnp.add, grads, more)
+
+    return (*later(add, first_vjp(ct)), None, None)
+
+
+_grouped_slabs.defvjp(_grouped_slabs_fwd, _grouped_slabs_bwd)
+
+
+def _grouped_rows(
+    x, top_w, order, group_sizes, blk: Params, cfg: ModelConfig, layer=None,
+    kernel: bool = False, into=None,
+):
+    """`_experts_grouped` from the sort on, over the (row, choice) pairs
+    `order` names — indices into the token-major [T*k] pairs, sorted by
+    expert — in groups of `group_sizes`: gather, the experts, weight,
+    scatter-add -> [T, D], zero for a row none of whose pairs is here.
+    `into`: a sum to add these pairs' to (the later slabs of a share add
+    up in one buffer)."""
+    k = cfg.n_experts_per_tok
+    with jax.named_scope("dispatch"):
         tok_of = order // k
         xs = x[tok_of]  # [T*k, D] sorted by expert
         layer_sizes = group_sizes
@@ -571,7 +733,8 @@ def _experts_grouped(
             ys = ragged(_act(ragged(xs, blk["wu"]), cfg), blk["wd"])
     with jax.named_scope("combine"):
         w_sorted = top_w.reshape(-1)[order].astype(ys.dtype)
-        return jnp.zeros_like(x).at[tok_of].add(ys * w_sorted[:, None])
+        out = jnp.zeros_like(x) if into is None else into
+        return out.at[tok_of].add(ys * w_sorted[:, None])
 
 
 def _expert_leaves(cfg: ModelConfig) -> Tuple[str, ...]:
@@ -650,11 +813,28 @@ def _mlp_moe(
     grouped dispatch): the expert weights come from these stacked
     [L, E, in, out] leaves at layer index `layer` instead of from `blk`
     (`_experts_grouped`, which `kernel` hands the Pallas grouped matmul);
-    without it `layer` is not read."""
+    without it `layer` is not read.
+
+    Where the grouped dispatch works on a slab (`expert_slab_rows`), the
+    rows `valid` does not mark are left out of it: the slab is sized for
+    what a router sends here, and pads are one vector many times over — all
+    of them on the same experts.  A prompt batch's pads (up to half its
+    rows) tripped the overflow in prefill (chip runs, PR 41); the rows that
+    are read come out the same bit for bit, a pad's expert output is zero."""
     b, s, d = h.shape
     x = h.reshape(-1, d)  # [T, D]
     with jax.named_scope("router"):
         top_w, top_idx, one_hot, aux = _moe_route(x, blk, cfg)
+        if (
+            valid is not None
+            and stacked is None
+            and cfg.moe_dispatch == "grouped"
+            and expert_slab_rows(cfg, top_idx.size) < top_idx.size
+        ):
+            real = valid.reshape(-1)
+            top_idx = jnp.where(real[:, None], top_idx, cfg.n_experts)
+            one_hot = one_hot * real[:, None, None].astype(one_hot.dtype)
+            valid = None  # what is left IS the real rows'
         if valid is None:  # the grouped dispatch's group sizes: one reduction
             counts = jnp.sum(one_hot, axis=(0, 1))
         else:
@@ -1537,7 +1717,9 @@ def prefill(
 
     def mlp(y, blk):
         h2 = _norm(y, blk["ln2"], blk.get("ln2_b"), cfg)
-        return y + (_mlp_moe(h2, blk, cfg)[0] if _is_sparse(cfg, blk) else _mlp_dense(h2, blk, cfg))
+        if not _is_sparse(cfg, blk):
+            return y + _mlp_dense(h2, blk, cfg)
+        return y + _mlp_moe(h2, blk, cfg, valid=segment_ids > 0)[0]
 
     def body(carry, layer_in):
         """-> (y, what the layer leaves in the cache: (k, v), or the one
@@ -1599,7 +1781,7 @@ def prefill(
                 ks.append(k)
                 vs.append(v)
             else:
-                out = _mlp_moe(h, blk, cfg)[0]
+                out = _mlp_moe(h, blk, cfg, valid=segment_ids > 0)[0]
             y = y + out
         return y, tuple(
             jnp.stack(a) if a else None for a in (ks, vs, states, tails)

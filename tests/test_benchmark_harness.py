@@ -23,6 +23,7 @@ from benchmark.metrics import (
 from benchmark.tests.test_any_block import *  # noqa: F401,F403 — the cases
 from benchmark.tests.test_any_block import OLMOE
 from benchmark.tests import test_ledger_readers as ledger_cases
+from benchmark.tests.test_moe_train_rows_gathered_share import *  # noqa: F401,F403 — the cases
 from benchmark.tests.test_ledger_readers import *  # noqa: F401,F403 — the cases
 
 SPEC = files.benchmark_json()
@@ -165,6 +166,7 @@ def test_the_glm_cell_is_as_the_issue_parametrised_it():
         "gen_tokens_per_s", "decode_ms_per_step", "decode_loop_ms",
         "moe_experts_touched", "moe_decode_mlp_ms", "moe_route_share",
         "moe_local_rows_share", "sample_draw_ms",
+        "moe_train_rows_gathered_share",
     }
     # Appended, and nothing else of those lists changed: the cell is the
     # last of the seven cells the benchmark then had, in every list.
@@ -218,6 +220,7 @@ def test_the_nemotron_cell_is_as_the_issue_parametrised_it():
         "gen_tokens_per_s", "decode_ms_per_step", "decode_loop_ms",
         "moe_experts_touched", "moe_decode_mlp_ms", "moe_route_share",
         "moe_local_rows_share", "sample_draw_ms",
+        "moe_train_rows_gathered_share",
     }
     then = CELLS[: CELLS.index(NEMO_CELL) + 1]
     for m in SPEC["end_to_end"] + SPEC["per_layer"]:

@@ -386,12 +386,14 @@ def test_counters_on_a_hand_made_routing():
     assert np.asarray(real).tolist() == [3, 2, 0, 0, 0, 1, 0, 0]
     step = np.asarray(_moe_step_counters(jnp.stack([counts, real]), cfg, 4))
     assert step.tolist() == [(4 + 3) / 2, (4 + 3) / 2, 1.0]
-    stats = _moe_stats(aux, jnp.stack([counts, real]))
+    stats = _moe_stats(aux, jnp.stack([counts, real]), cfg, 8)
     assert float(stats["moe/aux_loss"]) == float(aux)
     # fullest expert over the mean per expert: 4 / (8/8) and 3 / (6/8).
     np.testing.assert_allclose(
         float(stats["moe/load_max_over_mean"]), (4.0 + 4.0) / 2)
-    assert set(_moe_stats(aux, None)) == {"moe/aux_loss"}  # under PP
+    # Every expert is held here: no slab, and no counter of one.
+    assert set(stats) == {"moe/aux_loss", "moe/load_max_over_mean"}
+    assert set(_moe_stats(aux, None, cfg, 8)) == {"moe/aux_loss"}  # under PP
 
 
 def test_generate_and_train_report_the_counters_and_dense_models_none(cfg):
