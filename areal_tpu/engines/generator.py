@@ -2220,11 +2220,13 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         return None if self.mesh.size == 1 else False
 
     @property
-    def _latent_kernel(self):
-        """What the static program's latent decode attention takes: None,
+    def _row_kernel(self):
+        """What the static program's per-row cache kernels take (latent
+        decode attention, `ops/attention.latent_decode_attention`; the
+        Gated DeltaNet step, `linear_attention.linear_attn_step`): None,
         the backend's form, on one device; the MESH where the rows are
         spread over more, so that the kernel runs per device on its own
-        rows (`ops/attention.latent_decode_attention`)."""
+        rows."""
         return None if self.mesh.size == 1 else self.mesh
 
     @property
@@ -2362,7 +2364,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         cfg = self.cfg
         eos = self.eos_token_id
         max_new = g.max_new_tokens
-        latent_kernel = self._latent_kernel
+        row_kernel = self._row_kernel
         expert_kernel = self._expert_kernel
 
         @jax.jit
@@ -2413,7 +2415,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                 next_logits, cache, *counts = tfm.decode_step(
                     params, cfg, tok, pos, cache, sp + step, valid_from,
                     with_moe_counts=cfg.is_moe, experts_in_place=in_place,
-                    latent_kernel=latent_kernel, expert_kernel=expert_kernel,
+                    row_kernel=row_kernel, expert_kernel=expert_kernel,
                 )
                 if cfg.is_moe:
                     moe = [moe[0] + _moe_step_counters(counts[0], cfg, bsz)]

@@ -17,7 +17,13 @@ Two forms of the same recurrence:
   not depend on the carried state is computed for all chunks at once, the
   scan carries S through [C, d] x [d, d] matmuls.
 - `linear_attn_step` (decode): one token against the carried S and the
-  conv's last K-1 inputs.
+  conv's last K-1 inputs.  The recurrence itself has one form per backend:
+  on a TPU the Pallas kernel `gdn_delta_step` (`ops/pallas/delta_step.py`),
+  which keeps a head's [d_k, d_v] tile in VMEM for S^T k, S^T q and the
+  update and so reads the state once; elsewhere (CPU, toy head widths) the
+  same fp32 arithmetic as `jnp` ops, which is also the kernel's oracle.
+  The code picks by what it can see (`row_kernel_form`); there is no
+  switch.
 
 Packed rows: S and the conv restart at every segment start (attention
 masks by segment; a recurrence has to be told).  Inside a chunk that is a
@@ -266,6 +272,21 @@ def linear_attn_forward(
     return y
 
 
+def delta_step_jnp(state, q, k, v, g, beta):
+    """One token of the gated delta rule as `jnp` ops -> (state, o): state
+    [B, hv, dk, dv] fp32, q, k [B, hv, dk], v [B, hv, dv], g, beta [B, hv].
+    The path off a TPU backend and the oracle of `gdn_delta_step`."""
+    # One pass reads S (S^T k and S^T q of the OLD state), one rewrites
+    # it: o = S_new^T q with S_new = e^g S + k d^T.
+    decay = jnp.exp(g)[..., None]  # [B, hv, 1]
+    sk = jnp.sum(state * k[..., None], axis=-2)  # [B, hv, dv]
+    sq = jnp.sum(state * q[..., None], axis=-2)
+    d = beta[..., None] * (v - decay * sk)
+    kq = jnp.sum(k * q, axis=-1, keepdims=True)
+    o = decay * sq + kq * d
+    return state * decay[..., None] + k[..., None] * d[..., None, :], o
+
+
 @jax.named_scope("layer/linear_attn")
 def linear_attn_step(
     h: jax.Array,  # [B, 1, D]
@@ -274,13 +295,30 @@ def linear_attn_step(
     states: jax.Array,  # [n_linear, B, hv, dk, dv] fp32, every linear layer's
     tails: jax.Array,  # [n_linear, B, K-1, C] the convs' last inputs
     li,  # this layer's index into both
+    kernel=None,  # None | bool | Mesh: `flash_attention.row_kernel_form`
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One decode token per row -> (y [B, 1, D], states, tails), layer
     `li` of both stepped in place.  The whole caches come in and go out so
-    that the state's read and its write lie under this scope: XLA fuses the
-    update into the `dynamic-update-slice`, and an update made by the
-    caller would be timed outside `layer/linear_attn`."""
-    state = jax.lax.dynamic_index_in_dim(states, li, axis=0, keepdims=False)
+    that the state's read and its write lie under this scope — an update
+    made by the caller would be timed outside `layer/linear_attn` — and
+    because that is what in place means on either backend:
+
+    - on a TPU backend (head widths whole 128-lane tiles) the Pallas kernel
+      `gdn_delta_step` takes the stack, reads layer `li`'s tiles where they
+      lie, ONCE, and writes them where they lie (the output aliases the
+      input, the layer is a prefetched scalar in both index maps): no
+      slice, no `dynamic-update-slice`, no other layer's byte touched;
+    - elsewhere `delta_step_jnp`, the kernel's oracle: XLA fuses the
+      update into the `dynamic-update-slice`, but the reduction that `d`
+      needs is a fusion of its own, so on a TPU it streamed the state from
+      HBM twice (PERF.md §6, PR 42)."""
+    from areal_tpu.ops.pallas import delta_step
+    from areal_tpu.ops.pallas.flash_attention import row_kernel_form
+
+    use_kernel, mesh = row_kernel_form(kernel, delta_step.fits(
+        cfg.linear_k_head_dim, cfg.linear_v_head_dim))
+    if not use_kernel:
+        state = jax.lax.dynamic_index_in_dim(states, li, axis=0, keepdims=False)
     tail = jax.lax.dynamic_index_in_dim(tails, li, axis=0, keepdims=False)
     with jax.named_scope("in_proj"):
         x = h[:, 0]
@@ -299,15 +337,16 @@ def linear_attn_step(
     beta, g = _gates(ba, blk, cfg.linear_n_v_heads)
     with jax.named_scope("delta_step"):
         q, k, v = _split_heads(conv, cfg)  # [B, hv, d]
-        # One pass reads S (S^T k and S^T q of the OLD state), one
-        # rewrites it: o = S_new^T q with S_new = e^g S + k d^T.
-        decay = jnp.exp(g)[..., None]  # [B, hv, 1]
-        sk = jnp.sum(state * k[..., None], axis=-2)  # [B, hv, dv]
-        sq = jnp.sum(state * q[..., None], axis=-2)
-        d = beta[..., None] * (v - decay * sk)
-        kq = jnp.sum(k * q, axis=-1, keepdims=True)
-        o = decay * sq + kq * d
-        state = state * decay[..., None] + k[..., None] * d[..., None, :]
-        states = jax.lax.dynamic_update_index_in_dim(states, state, li, axis=0)
+        if use_kernel:
+            if mesh is None:
+                states, o = delta_step.gdn_delta_step(
+                    states, li, q, k, v, g, beta)
+            else:
+                states, o = delta_step.gdn_delta_step_sharded(
+                    states, li, q, k, v, g, beta, mesh)
+        else:
+            state, o = delta_step_jnp(state, q, k, v, g, beta)
+            states = jax.lax.dynamic_update_index_in_dim(
+                states, state, li, axis=0)
     y = _out(o, z, blk, cfg)
     return y[:, None], states, tails

@@ -1858,7 +1858,7 @@ def decode_step(
     valid_from: jax.Array,  # [B] int32 — first valid cache slot per row
     with_moe_counts: bool = False,
     experts_in_place: Optional[bool] = None,
-    latent_kernel=None,  # None | bool | Mesh
+    row_kernel=None,  # None | bool | Mesh
     expert_kernel: Optional[bool] = None,
 ) -> Tuple[jax.Array, ...]:
     """One decode step: write the new token's k/v at cache slot `slot`
@@ -1893,18 +1893,23 @@ def decode_step(
     spreads the rows over devices passes False, the kernel is one
     device's program).
 
+    `row_kernel`: the form of the two per-row cache kernels, in which a
+    row reads and writes nothing of another's — latent attention's
+    `latent_decode` over the stacked rows and the Gated DeltaNet step's
+    `gdn_delta_step` over the stacked recurrent state.  None = the Pallas
+    kernel on a TPU backend, the XLA form elsewhere; a caller whose mesh
+    spreads the rows over devices passes the MESH and the kernel runs per
+    device on its rows (`shard_map` over the batch axes); a bool forces
+    either form.
+
     Latent attention (`cfg.is_latent`) runs its ABSORBED form here: the
     cache holds one latent row a token (`KVCache.latent`), the query is
     carried into the latent space, scores and the weighted sum are taken
     against the rows themselves and the value up-projection comes after
     (`_latent_q_absorbed`, `latent_decode_attention`, `_attn_out`) — the
     numbers of the materialised form `prefill` and training run, in
-    another order, and no per-head k/v is ever built (`latent_kernel`:
-    None = the Pallas kernel over the stacked rows on a TPU backend, the
-    XLA form elsewhere; a caller whose mesh spreads the rows over devices
-    passes the MESH and the kernel runs per device on its rows; a bool
-    forces either form).  Leading dense layers step before the scan, through the first
-    layers of the cache.
+    another order, and no per-head k/v is ever built.  Leading dense
+    layers step before the scan, through the first layers of the cache.
     """
     b = tokens.shape[0]
     x = _embed(params, cfg, tokens, positions)[:, None, :]  # [B,1,D]
@@ -1942,7 +1947,7 @@ def decode_step(
         )
         attn = latent_decode_attention(
             q[:, 0], rows, li, valid_from, slot + 1, cfg.kv_lora_rank,
-            cfg.head_dim**-0.5, use_kernel=latent_kernel,
+            cfg.head_dim**-0.5, use_kernel=row_kernel,
         )
         ao = _attn_out(attn.reshape(b, 1, -1), blk, cfg, absorbed=True)
         return y + ao, rows, None
@@ -1982,7 +1987,8 @@ def decode_step(
             blk = _period_layer(cfg, pblk, j)
             li = pi * (n - 1) + j
             h = _norm(y, blk["ln1"], blk.get("ln1_b"), cfg)
-            mixed, sc, cc = linear_attn_step(h, blk, cfg, sc, cc, li)
+            mixed, sc, cc = linear_attn_step(
+                h, blk, cfg, sc, cc, li, row_kernel)
             y, c = mlp(y + mixed, blk, layer(j))
             counts.append(c)
         blk = _period_layer(cfg, pblk, n - 1)

@@ -209,13 +209,9 @@ def latent_decode_attention(
     over several devices — rows are independent, so the kernel is
     `shard_map`ped over (data, fsdp) and each device runs it on its own
     rows of the cache (the XLA form off a TPU backend, as on one device)."""
-    from jax.sharding import Mesh
+    from areal_tpu.ops.pallas.flash_attention import row_kernel_form
 
-    mesh = use_kernel if isinstance(use_kernel, Mesh) else None
-    if use_kernel is None or mesh is not None:
-        from areal_tpu.base.distributed import is_tpu_backend
-
-        use_kernel = is_tpu_backend()
+    use_kernel, mesh = row_kernel_form(use_kernel)
     if use_kernel:
         from areal_tpu.ops.pallas.latent_attention import (
             latent_decode_kernel,

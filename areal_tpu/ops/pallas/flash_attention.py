@@ -70,6 +70,22 @@ def _interpret() -> bool:
     return not is_tpu_backend()
 
 
+def row_kernel_form(choice, fits: bool = True):
+    """Which form a per-row cache kernel takes (rows independent: latent
+    decode attention, the Gated DeltaNet step) -> (use the Pallas kernel,
+    the mesh to `shard_map` it over or None).  `choice`: None, by what the
+    code can see — the kernel on a TPU backend where the shapes fit it
+    (`fits`), the XLA form elsewhere; a MESH whose batch axes spread the
+    rows, the same choice with the kernel run per device on its own rows;
+    a bool forces either (interpreted off a TPU)."""
+    from jax.sharding import Mesh
+
+    mesh = choice if isinstance(choice, Mesh) else None
+    if choice is None or mesh is not None:
+        choice = fits and not _interpret()
+    return bool(choice), mesh
+
+
 def named_call(name: str, kernel, **kw):
     """`pl.pallas_call` under its stable device name: the kernel's `name=`
     and a `jax.named_scope` of the same name around the call.  The scope

@@ -718,7 +718,7 @@ def test_static_generation_on_a_mesh_equals_one_device(cfg, params, mode):
         engine = GeneratorEngine(
             cfg, sharding.shard_params(params, mesh), mesh,
             eos_token_id=cfg.vocab_size)
-        assert engine._latent_kernel is (None if name == "d1" else mesh)
+        assert engine._row_kernel is (None if name == "d1" else mesh)
         toks, logps, _ = engine.static_rollout(
             prompts, g, jax.random.PRNGKey(0))
         out[name] = (toks, logps)
@@ -883,9 +883,9 @@ def test_the_decode_loop_compiles_for_v5e_without_a_copy_of_the_cache(
         lambda: tfm.init_params(big, jax.random.PRNGKey(0)))
     params = jax.tree.map(placed, shapes, sharding.param_pspecs(shapes))
     rows = placed(jax.ShapeDtypeStruct((b,), jnp.int32), P(BATCH_AXES))
-    # What the engine hands the decode step (`_latent_kernel`), and whether
+    # What the engine hands the decode step (`_row_kernel`), and whether
     # the expert leaves can be read in place (not where fsdp splits them).
-    latent_kernel = None if pc.world_size == 1 else mesh
+    row_kernel = None if pc.world_size == 1 else mesh
 
     def loop(params, tok, plen):
         cache = tfm.init_kv_cache(big, b, st, dtype=jnp.bfloat16)
@@ -894,7 +894,7 @@ def test_the_decode_loop_compiles_for_v5e_without_a_copy_of_the_cache(
             step, tok, cache = state
             logits, cache = tfm.decode_step(
                 params, big, tok, plen + step, cache, sp + step, sp - plen,
-                experts_in_place=mode != "f2", latent_kernel=latent_kernel)
+                experts_in_place=mode != "f2", row_kernel=row_kernel)
             return step + 1, jnp.argmax(logits, -1).astype(jnp.int32), cache
 
         return jax.lax.while_loop(

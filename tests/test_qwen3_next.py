@@ -388,8 +388,8 @@ def test_a_decode_step_that_keeps_its_state_in_bf16_is_not_correct(
     assert np.isfinite(reference.next_token_logprobs(params, cfg, seq)).all()
     inner = tfm.linear_attn_step
 
-    def rounded(h, blk, c, states, tails, li):
-        y, states, tails = inner(h, blk, c, states, tails, li)
+    def rounded(h, blk, c, states, tails, li, *kernel):
+        y, states, tails = inner(h, blk, c, states, tails, li, *kernel)
         return y, jax.lax.reduce_precision(states, 8, 7), tails
 
     monkeypatch.setattr(tfm, "linear_attn_step", rounded)
@@ -544,6 +544,114 @@ def test_the_train_step_counts_segment_starts_and_flops_follow_the_kinds(cfg):
     assert peaks_hybrid.gdn_decode_bytes(big, 64) == pytest.approx(
         3 * (2 * 33.75e6 + 2 * 134.2e6 + 2 * 3.1e6), rel=0.01)
     assert peaks_hybrid.experts_per_token_held(big) == 1.25
+
+
+# ------------------------------------------- the decode loop compiled for v5e
+
+
+@pytest.fixture(scope="module")
+def v5e_chips():
+    """The devices of a described v5e host to compile for (libtpu is
+    installed here; no chip is attached).  Built inside the fixture, never
+    at import: only the worker that runs this file may load the TPU's
+    library."""
+    import os
+
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever libtpu raises
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return topo.devices
+
+
+@pytest.mark.parametrize("mode", ["d1", "d2", "f2"])
+def test_the_decode_loop_compiles_for_v5e_with_the_state_stepped_in_place(
+        v5e_chips, monkeypatch, mode):
+    """Mosaic and XLA:TPU for real, at the cell's size (64 rows, a
+    768-slot window, one period, the published widths), on one chip and
+    with the rows spread over two (`shard_map`, data or fsdp): each of the
+    three Gated DeltaNet layers steps its tiles of the stacked fp32 state
+    through the Pallas kernel `gdn_delta_step` under `layer/linear_attn/
+    delta_step` — the stack is the kernel's operand AND its result, so the
+    loop holds no `dynamic-update-slice` on the state and no copy, re-layout
+    or gather of the stack, of a device's part of it or of a layer's part
+    (an alias that did not take would show as a copy of 402 MB an
+    iteration)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import PartitionSpec as P
+
+    from areal_tpu.base.topology import BATCH_AXES
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    big = bench_run.model_config(
+        files.load_json("configs", "qwen3-next-80b-a3b-l4-e64.json"))
+    b, sp, st = 64, 256, 768
+    pc = ParallelConfig.from_str(mode)
+    mesh = make_mesh(pc, v5e_chips[: pc.world_size])
+
+    def placed(x, spec):
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=sharding.named(mesh, spec))
+
+    shapes = jax.eval_shape(
+        lambda: tfm.init_params(big, jax.random.PRNGKey(0)))
+    params = jax.tree.map(placed, shapes, sharding.param_pspecs(shapes))
+    rows = placed(jax.ShapeDtypeStruct((b,), jnp.int32), P(BATCH_AXES))
+    # What the engine hands the decode step (`_row_kernel`), and whether
+    # the expert leaves can be read in place (not where fsdp splits them).
+    row_kernel = None if pc.world_size == 1 else mesh
+
+    def loop(params, tok, plen):
+        cache = tfm.init_kv_cache(big, b, st, dtype=jnp.bfloat16)
+
+        def body(state):
+            step, tok, cache = state
+            logits, cache = tfm.decode_step(
+                params, big, tok, plen + step, cache, sp + step, sp - plen,
+                experts_in_place=mode != "f2", row_kernel=row_kernel)
+            return step + 1, jnp.argmax(logits, -1).astype(jnp.int32), cache
+
+        return jax.lax.while_loop(
+            lambda s: s[0] < 512, body, (0, tok, cache))[1]
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(loop).lower(params, rows, rows).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    n, hv = big.n_linear_layers, big.linear_n_v_heads
+    dk, dv = big.linear_k_head_dim, big.linear_v_head_dim
+    assert (n, hv, dk, dv) == (3, 32, 128, 128)
+    here = b // pc.world_size  # a device's rows
+    stack = f"f32[{n},{here},{hv},{dk},{dv}]"
+    shapes = {stack} | {
+        f"f32[{r},{hv},{dk},{dv}]" for r in (b, here)} | {
+        f"f32[{n},{b},{hv},{dk},{dv}]"}
+    calls = [line for line in text.splitlines()
+             if "%gdn_delta_step" in line.split(" = ")[0]]
+    assert len(calls) == n, len(calls)
+    for line in calls:
+        assert "tpu_custom_call" in line and stack in line.split(" = ")[1]
+        scope = line.split('op_name="')[1].split('"')[0]
+        assert "gen/decode_step" in scope
+        assert "layer/linear_attn/delta_step/" in scope
+    copies = [
+        line.strip()[:160] for line in text.splitlines()
+        if any(s in line.split(" = ")[-1].split("(")[0] for s in shapes)
+        and (" copy(" in line or " transpose(" in line
+             or " all-gather(" in line)
+    ]
+    assert not copies, copies[:3]
+    updates = [line.strip()[:160] for line in text.splitlines()
+               if any(s in line for s in shapes)
+               and "dynamic-update-slice(" in line]
+    assert not updates, updates[:3]
 
 
 # ------------------------------------- every other family is a period of one
