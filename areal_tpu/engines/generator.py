@@ -696,23 +696,12 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                 len(reqs) > b_cap
                 or gconfig.max_new_tokens > self.static_path_max_new
             )
-        if inflight and self.cfg.is_latent:
-            # As for a hybrid pattern below: never a silent fallback.
-            raise tfm.LatentLayoutError(
-                f"{tfm._NO_SERVING_LATENT}; this call has {len(reqs)} "
-                f"requests for {b_cap} slots, max_new_tokens "
-                f"{gconfig.max_new_tokens} (static_path_max_new "
-                f"{self.static_path_max_new}), stop={bool(gconfig.stop)}, "
-                f"spec_decode_k={gconfig.spec_decode_k}, inflight={inflight}"
-            )
-        if inflight and self.cfg.has_recurrent_state:
-            # Never a silent fallback to a plane that would drop the state.
-            why = (
-                tfm._NO_SERVING_STATE if self.cfg.is_hybrid
-                else tfm._NO_SERVING_PATTERN
-            )
-            raise tfm.HybridLayoutError(
-                f"{why}; this call has {len(reqs)} requests "
+        refusal = tfm.plan_refusal(self.cfg, serving=True)
+        if inflight and refusal:
+            # Never a silent fallback to a plane that would drop a state
+            # or has no pages for a population.
+            raise type(refusal)(
+                f"{refusal}; this call has {len(reqs)} requests "
                 f"for {b_cap} slots, max_new_tokens "
                 f"{gconfig.max_new_tokens} (static_path_max_new "
                 f"{self.static_path_max_new}), stop={bool(gconfig.stop)}, "
@@ -2307,31 +2296,30 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             prompt_len[r] = len(toks)
 
         fn = self._get_gen_fn(b, sp, s_total, gconfig, with_cache)
-        if self.cfg.has_recurrent_state or self.cfg.is_latent:
-            # What the cache holds, from shapes alone.
-            cache = jax.eval_shape(
-                lambda: tfm.init_kv_cache(
-                    self.cfg, b, s_total, dtype=self.compute_dtype
-                )
+        # What the cache holds beside k/v, from shapes alone.
+        cache = jax.eval_shape(
+            lambda: tfm.init_kv_cache(
+                self.cfg, b, s_total, dtype=self.compute_dtype
             )
+        )
 
-            def nbytes(*xs):
-                return sum(x.size * x.dtype.itemsize for x in xs)
+        def nbytes(*xs):
+            return sum(x.size * x.dtype.itemsize for x in xs)
 
-            if self.cfg.has_recurrent_state:  # the two kinds of state
-                self.last_pool_stats.update(
-                    kv_cache_bytes=nbytes(cache.k, cache.v),
-                    state_cache_bytes=nbytes(cache.state, cache.conv),
-                )
-            else:  # latent rows, beside what per-head k/v would have taken
-                cfg = self.cfg
-                self.last_pool_stats.update(
-                    latent_cache_bytes=nbytes(cache.latent),
-                    kv_cache_bytes_as_heads=cache.latent.dtype.itemsize * (
-                        cfg.n_layers * b * s_total * cfg.n_kv_heads
-                        * (cfg.head_dim + cfg.v_head_dim)
-                    ),
-                )
+        if cache.state is not None:  # the two kinds of state
+            self.last_pool_stats.update(
+                kv_cache_bytes=nbytes(cache.k, cache.v),
+                state_cache_bytes=nbytes(cache.state, cache.conv),
+            )
+        if cache.latent is not None:  # beside what per-head k/v would take
+            cfg = self.cfg
+            self.last_pool_stats.update(
+                latent_cache_bytes=nbytes(cache.latent),
+                kv_cache_bytes_as_heads=cache.latent.dtype.itemsize * (
+                    cfg.n_layers * b * s_total * cfg.n_kv_heads
+                    * (cfg.head_dim + cfg.v_head_dim)
+                ),
+            )
         with tracer.span("gen_chunk", cat="compute", b=b_real, sp=sp):
             with tracer.span("gen_dispatch", cat="compute"):
                 toks, logps, gen_len, *rest = fn(

@@ -414,7 +414,7 @@ class TrainEngine(HostOffloadMixin, Engine):
                     # and of the conv inside a packed row.
                     seg = batch["segment_ids"]
                     starts = (seg[:, 1:] != seg[:, :-1]) & (seg[:, 1:] > 0)
-                if cfg.is_hybrid:
+                if cfg.n_linear_layers:
                     stats = {
                         **stats,
                         "linear_attn/segments_per_row": jnp.mean(

@@ -6,7 +6,8 @@ MoE and critic variants.
 """
 
 import dataclasses
-from typing import Optional
+import functools
+from typing import Tuple
 
 import jax.numpy as jnp
 
@@ -17,6 +18,49 @@ DENSE_PREFIX = "dense_"
 # router's choice bias): the train engine keeps no moment for them and the
 # hand-back returns them as they were.
 FROZEN_LEAVES = ("router_bias",)
+
+# The residual branches x += f(norm(x)) a layer is made of: softmax attention
+# over per-head K/V, latent attention over one row a token, Gated DeltaNet,
+# Mamba-2, a dense MLP, the mixture of experts.
+ATTENTION, LATENT, GDN, SSM, MLP, MOE = (
+    "attention", "latent", "gdn", "ssm", "mlp", "moe",
+)
+# One character of `layer_pattern` -> that layer's ONE branch.
+_PATTERN_KINDS = {"M": (SSM,), "E": (MOE,), "*": (ATTENTION,)}
+
+LayerKind = Tuple[str, ...]  # a layer's branches, in order
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerPlan:
+    """A model's layers: `prefix`, stepped one by one, then `repeats` scan
+    steps of `unit`, a step's layers unrolled.  A leaf of the stored tree
+    is stacked over the layers that own it, in layer order — the prefix's
+    on their own under `DENSE_PREFIX`."""
+
+    prefix: Tuple[LayerKind, ...]
+    unit: Tuple[LayerKind, ...]
+    repeats: int
+
+    def in_prefix(self, *branches: str) -> int:
+        """Leading layers with one of `branches`."""
+        return _layers_with(self.prefix, branches)
+
+    def in_unit(self, *branches: str) -> int:
+        return _layers_with(self.unit, branches)
+
+    def count(self, *branches: str) -> int:
+        """Layers of the whole model with one of `branches`."""
+        return self.in_prefix(*branches) + self.repeats * self.in_unit(*branches)
+
+    @property
+    def kinds(self) -> frozenset:
+        return frozenset(self.prefix + self.unit)
+
+
+def _layers_with(layers: Tuple[LayerKind, ...], branches) -> int:
+    return sum(any(b in kind for b in branches) for kind in layers)
+
 
 _DTYPES = {
     "bfloat16": jnp.bfloat16,
@@ -164,7 +208,8 @@ class ModelConfig:
     ssm_dt_floor: float = 0.0001
 
     def __post_init__(self):
-        if self.n_scan_layers % self.full_attn_interval:
+        # The checks read the fields as given: `plan` is for what passed.
+        if (self.n_layers - self.first_k_dense) % self.full_attn_interval:
             raise ValueError(
                 f"{self.n_layers} layers are not whole periods of "
                 f"{self.full_attn_interval} (full_attn_interval)"
@@ -191,7 +236,7 @@ class ModelConfig:
                     f"{self.v_head_dim} (head_dim {self.head_dim}): the "
                     "attention kernels take one width for q, k and v"
                 )
-            if self.is_hybrid or self.n_kv_heads != self.n_q_heads or not (
+            if self.full_attn_interval > 1 or self.n_kv_heads != self.n_q_heads or not (
                 self.q_lora_rank and self.pos_emb == "rope"
             ):
                 raise NotImplementedError(
@@ -217,7 +262,7 @@ class ModelConfig:
                 f"layer_pattern {pattern!r} is not {self.n_layers} "
                 "characters of 'M' (Mamba-2), 'E' (experts), '*' (attention)"
             )
-        if self.is_hybrid or self.is_latent or self.first_k_dense:
+        if self.full_attn_interval > 1 or self.is_latent or self.first_k_dense:
             raise NotImplementedError(
                 "a pattern of one-branch layers has no Gated DeltaNet "
                 "layers, no latent attention and no leading dense layers"
@@ -240,9 +285,30 @@ class ModelConfig:
     def dtype(self):
         return _DTYPES[self.param_dtype]
 
+    @functools.cached_property
+    def plan(self) -> LayerPlan:
+        """The layers as `prefix + unit x repeats`, from the three fields
+        that state them: `layer_pattern` (one character a layer, ONE
+        branch each; the unit is the shortest string the pattern repeats),
+        else periods of `full_attn_interval` - 1 Gated DeltaNet layers and
+        one attention layer, a mixer and an MLP each, behind
+        `first_k_dense` leading layers with a dense MLP."""
+        if self.layer_pattern:
+            unit = tuple(_PATTERN_KINDS[c] for c in self.pattern_unit)
+            return LayerPlan((), unit, len(self.layer_pattern) // len(unit))
+        mixer = LATENT if self.is_latent else ATTENTION
+        mlp = MOE if self.is_moe else MLP
+        n = self.full_attn_interval
+        return LayerPlan(
+            prefix=((mixer, MLP),) * self.first_k_dense,
+            unit=((GDN, mlp),) * (n - 1) + ((mixer, mlp),),
+            repeats=(self.n_layers - self.first_k_dense) // n,
+        )
+
     @property
     def is_pattern(self) -> bool:
-        return bool(self.layer_pattern)
+        """Whether every layer is ONE branch."""
+        return all(len(kind) == 1 for kind in self.plan.kinds)
 
     @property
     def pattern_unit(self) -> str:
@@ -256,28 +322,24 @@ class ModelConfig:
 
     @property
     def n_ssm_layers(self) -> int:
-        return self.layer_pattern.count("M")
+        return self.plan.count(SSM)
 
     @property
     def n_moe_layers(self) -> int:
         """Layers with the mixture of experts."""
-        if self.is_pattern:
-            return self.layer_pattern.count("E")
-        return self.n_scan_layers if self.is_moe else 0
+        return self.plan.count(MOE)
 
     @property
     def n_attn_layers(self) -> int:
         """Layers that keep k/v (or latent rows) in the cache."""
-        if self.is_pattern:
-            return self.layer_pattern.count("*")
-        return self.n_periods + self.first_k_dense
+        return self.plan.count(ATTENTION, LATENT)
 
     @property
     def has_recurrent_state(self) -> bool:
         """Whether some layer carries a state from token to token (Gated
         DeltaNet, Mamba-2): no slot on the serving plane, no split over
         `model`, `seq` or `pipe` yet."""
-        return self.is_hybrid or self.n_ssm_layers > 0
+        return self.plan.count(GDN, SSM) > 0
 
     @property
     def ssm_inner_dim(self) -> int:
@@ -299,7 +361,7 @@ class ModelConfig:
 
     @property
     def is_hybrid(self) -> bool:
-        return self.full_attn_interval > 1
+        return self.plan.count(GDN) > 0
 
     @property
     def is_latent(self) -> bool:
@@ -313,17 +375,16 @@ class ModelConfig:
     @property
     def n_scan_layers(self) -> int:
         """Layers the layer scan runs over: all but the leading dense."""
-        return self.n_layers - self.first_k_dense
+        return self.plan.repeats * len(self.plan.unit)
 
     @property
     def n_periods(self) -> int:
-        if self.is_pattern:
-            return self.n_layers // len(self.pattern_unit)
-        return self.n_scan_layers // self.full_attn_interval
+        """Steps of the layer scan."""
+        return self.plan.repeats
 
     @property
     def n_linear_layers(self) -> int:
-        return self.n_periods * (self.full_attn_interval - 1)
+        return self.plan.count(GDN)
 
     @property
     def linear_key_dim(self) -> int:

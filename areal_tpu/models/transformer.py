@@ -13,10 +13,12 @@ real_llm_base.py (blocks, heads) — re-designed for XLA:
   `jax.sharding` rules over this pytree (areal_tpu/parallel/sharding.py).
 - `is_critic` swaps the LM head for a scalar value head
   (reference: real_llm_base.py:358-453).
-- A layer is a mixer and an MLP, two residual branches — or, under
-  `cfg.layer_pattern` (nemotron_h), ONE branch of one kind (a Mamba-2
-  mixer `models/mamba.py`, the mixture of experts, or attention), each
-  kind's leaves stacked over its own layers (`_pattern_blocks`).
+- A layer is a tuple of residual branches x += f(norm(x)) and the model is
+  `cfg.plan`: prefix + unit x repeats.  Every program (the train stack
+  `_blocks`, `prefill`, `decode_step`) steps the prefix, then scans the
+  repeats with a unit's layers unrolled, each branch through the program's
+  own table; each branch's leaves are stacked over the layers that have it
+  (`_unit_view`, `_unit_layer`, `_LEAF_BRANCHES`).
 
 Functions:
     init_params(cfg, key)                                  -> params
@@ -40,7 +42,17 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from areal_tpu.models.config import DENSE_PREFIX, ModelConfig
+from areal_tpu.models.config import (
+    ATTENTION,
+    DENSE_PREFIX,
+    GDN,
+    LATENT,
+    MLP,
+    MOE,
+    SSM,
+    LayerKind,
+    ModelConfig,
+)
 from areal_tpu.models.linear_attention import (
     LINEAR_LEAVES,
     init_linear_attn,
@@ -76,14 +88,16 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             * (fan_in**-0.5)
         ).astype(dtype)
 
-    # The SCANNED layers: all of them but `first_k_dense` leading ones.
+    # The SCANNED layers (all but the prefix): every leaf keeps ONE leading
+    # stack axis (what the sharding rules, the hand-back and the references
+    # read), over the layers with the branch that owns it — `scanned` of
+    # them; the norms over all L.
+    plan = cfg.plan
     L, D, F = cfg.n_scan_layers, cfg.hidden_dim, cfg.intermediate_dim
-    # A hybrid stack keeps ONE leading stack axis on every leaf (what the
-    # sharding rules, the hand-back and the references read): the norms and
-    # the MLP of all L layers, the attention leaves of its LA = n_periods
-    # full layers, the `la_*` leaves of its L - LA linear layers.  A period
-    # of one has LA = L.
-    LA = cfg.n_periods
+
+    def scanned(*branches):
+        return plan.repeats * plan.in_unit(*branches)
+
     # A (1 + w) norm starts at w = 0, a plain one at w = 1: scale one.
     norm_init = jnp.zeros if cfg.rms_norm_offset else jnp.ones
     ks = jax.random.split(k_blocks, 8)
@@ -123,22 +137,17 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             out["wqg"] = dense(ks[5], (n, D, cfg.q_dim), D)
         return out
 
-    # Layers with the mixture of experts: all the scanned ones, or a
-    # pattern's 'E' layers.
-    LM = cfg.n_moe_layers
-    if cfg.is_pattern:
-        # ONE norm a layer; each kind's leaves stacked over its own layers.
-        blocks = {
-            "ln1": norm_init((L, D), dtype),
-            **attn_leaves(cfg.n_attn_layers, ks),
-            **init_ssm(cfg, ks[6], cfg.n_ssm_layers, dense),
-        }
-    else:
-        blocks = {
-            "ln1": norm_init((L, D), dtype),
-            **attn_leaves(LA, ks),
-            "ln2": norm_init((L, D), dtype),
-        }
+    LM = scanned(MOE)
+    blocks = {
+        "ln1": norm_init((L, D), dtype),
+        **attn_leaves(scanned(ATTENTION, LATENT), ks),
+    }
+    if not cfg.is_pattern:  # a second branch a layer: a second norm
+        blocks["ln2"] = norm_init((L, D), dtype)
+    if scanned(SSM):
+        blocks.update(init_ssm(cfg, ks[6], scanned(SSM), dense))
+    if scanned(GDN):
+        blocks.update(init_linear_attn(cfg, ks[6], scanned(GDN), dense))
     if cfg.first_k_dense:
         # The leading dense layers: their own leaves, stacked [K, ...]
         # under `dense_*`, the MLP at the dense width.
@@ -153,8 +162,6 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             "wd": dense(kd[8], (K, F, D), F),
         }
         blocks.update({DENSE_PREFIX + n: w for n, w in lead.items()})
-    if cfg.is_hybrid:
-        blocks.update(init_linear_attn(cfg, ks[6], L - LA, dense))
     if cfg.norm_type == "layernorm":
         blocks["ln1_b"] = jnp.zeros((L, D), dtype)
         blocks["ln2_b"] = jnp.zeros((L, D), dtype)
@@ -188,7 +195,7 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             blocks["ws_d"] = dense(kx[2], (LM, FS, D), FS)
             if cfg.shared_expert_gated:
                 blocks["ws_gate"] = dense(kx[3], (LM, D, 1), D)
-    elif not cfg.is_pattern:
+    elif scanned(MLP):
         km = jax.random.split(ks[4], 3)
         blocks["wg"] = dense(km[0], (L, D, F), D)
         if cfg.mlp_gated:
@@ -861,8 +868,8 @@ def _mlp_moe(
     return out.reshape(b, s, d), aux, counts
 
 
-def _block_forward(
-    x: jax.Array,
+def _attention(
+    h: jax.Array,
     blk: Params,
     cfg: ModelConfig,
     segment_ids: jax.Array,
@@ -872,15 +879,17 @@ def _block_forward(
     cp_mesh=None,
     cp_manual: "Optional[Tuple[str, int]]" = None,
     cp_zigzag: bool = False,
-) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
-    """One block over packed rows -> (y, MoE aux loss, rows per expert over
-    the real tokens [E] int32; None for a dense MLP)."""
-    b, s, d = x.shape
-    h = _norm(x, blk["ln1"], blk.get("ln1_b"), cfg)
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The attention branch (softmax over per-head k/v, or latent) over
+    packed rows of normed `h` -> (its output, what it leaves in the cache:
+    k and v, or the one latent row a token)."""
+    b, s, _ = h.shape
     if cfg.is_latent:
-        q, k, v, _ = _latent_qkv(h, blk, cfg, cos, sin)
+        q, k, v, row = _latent_qkv(h, blk, cfg, cos, sin)
+        left = {"latent": row}
     else:
         q, k, v = _block_kv(h, blk, cfg, cos, sin)
+        left = {"k": k, "v": v}
     if cp_manual is None and cp_mesh is None:
         attn = packed_attention(
             q, k, v, segment_ids, causal=True, use_flash=use_flash
@@ -918,69 +927,103 @@ def _block_forward(
                 attn = ring_packed_attention(
                     q, k, v, segment_ids, cp_mesh, causal=True
                 )
-    attn_out = _attn_out(
+    out = _attn_out(
         attn.reshape(b, s, cfg.q_dim), blk, cfg, _attn_gate(h, blk, cfg)
     )
-    return _block_mlp(x, attn_out, blk, cfg, segment_ids)
+    return out, left
 
 
-def _linear_block_forward(
-    x: jax.Array, blk: Params, cfg: ModelConfig, segment_ids: jax.Array
+def _packed_branches(
+    cfg: ModelConfig, segment_ids: jax.Array, *attn_args, with_state=False
+):
+    """The table of the programs over packed rows (the train stack,
+    `prefill`): branch -> f(h, blk) -> (its output, what else it gives by
+    name — `aux`, the MoE aux loss; `counts`, rows per expert over the real
+    tokens [E] int32; and what it leaves in the cache, by `KVCache` field:
+    k and v, the one latent row a token and, `with_state`, a recurrent
+    branch's final state and conv tail at the row's last valid token).
+    `attn_args`: `_attention`'s from `cos` on."""
+
+    def recurrent(forward):
+        def branch(h, blk):
+            if not with_state:
+                return forward(h, blk, cfg, segment_ids), {}
+            out, state, tail = forward(
+                h, blk, cfg, segment_ids, with_state=True
+            )
+            return out, {"state": state, "conv": tail}
+
+        return branch
+
+    def experts(h, blk):
+        out, aux, counts = _mlp_moe(h, blk, cfg, valid=segment_ids > 0)
+        return out, {"aux": aux, "counts": counts}
+
+    def attention(h, blk):
+        return _attention(h, blk, cfg, segment_ids, *attn_args)
+
+    return {
+        ATTENTION: attention,
+        LATENT: attention,
+        GDN: recurrent(linear_attn_forward),
+        SSM: recurrent(ssm_forward),
+        MLP: lambda h, blk: (
+            _mlp_dense(h, blk, cfg), {"aux": jnp.zeros((), jnp.float32)}),
+        MOE: experts,
+    }
+
+
+# Named checkpoints for remat="dots_small" (see `_remat_layer`): a mixer's
+# output and an MLP's are the SMALL per-token dots ([*, D]) whose saving
+# lets backward skip only the fat gate/up recompute candidates' DOWNSTREAM
+# — memory ~2x "full" remat instead of the ~7x of "dots".
+_SAVED_AS = {MLP: "mlp_out", MOE: "mlp_out"}
+
+
+def _packed_layer(
+    cfg: ModelConfig, branches, kind: LayerKind, x: jax.Array, blk: Params,
+    named: bool = False,
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """One layer over packed rows, x += f(norm(x)) a branch -> (y, what
+    else its branches gave: `_packed_branches`).  `named`: the branches'
+    outputs are the named checkpoints of a remat policy."""
+    gave = {}
+    for branch, ln in zip(kind, _BRANCH_NORMS):
+        h = _norm(x, blk[ln], blk.get(ln + "_b"), cfg)
+        out, more = branches[branch](h, blk)
+        if named:
+            out = checkpoint_name(out, _SAVED_AS.get(branch, "attn_out"))
+        x = x + out
+        gave.update(more)
+    return x, gave
+
+
+def _layer_forward(
+    cfg: ModelConfig, branches, kind: LayerKind, x: jax.Array, blk: Params
 ) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
-    """`_block_forward` with the Gated DeltaNet mixer in attention's place."""
-    h = _norm(x, blk["ln1"], blk.get("ln1_b"), cfg)
-    mixed = linear_attn_forward(h, blk, cfg, segment_ids)
-    return _block_mlp(x, mixed, blk, cfg, segment_ids)
+    """`_packed_layer` for the train stack -> (y, MoE aux loss, rows per
+    expert; None without experts): what one remat region puts out."""
+    x, gave = _packed_layer(cfg, branches, kind, x, blk, named=True)
+    aux = gave["aux"] if "aux" in gave else jnp.zeros((), jnp.float32)
+    return x, aux, gave.get("counts")
 
 
-def _block_mlp(
+def _block_forward(
     x: jax.Array,
-    attn_out: jax.Array,
     blk: Params,
     cfg: ModelConfig,
     segment_ids: jax.Array,
+    cos: jax.Array,
+    sin: jax.Array,
+    use_flash: "bool | None" = None,
+    cp_manual: "Optional[Tuple[str, int]]" = None,
 ) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
-    """A block from its mixer's output on: residual, norm, MLP, residual."""
-    # Named checkpoints for remat="dots_small" (see _backbone): the
-    # attention output and the MLP down-projection output are the SMALL
-    # per-token dots ([*, D]) whose saving lets backward skip only the
-    # fat gate/up recompute candidates' DOWNSTREAM — memory ~2x "full"
-    # remat instead of the ~7x of "dots".
-    attn_out = checkpoint_name(attn_out, "attn_out")
-    x = x + attn_out
-    h2 = _norm(x, blk["ln2"], blk.get("ln2_b"), cfg)
-    if _is_sparse(cfg, blk):
-        mlp_out, aux, counts = _mlp_moe(h2, blk, cfg, valid=segment_ids > 0)
-    else:
-        mlp_out, aux = _mlp_dense(h2, blk, cfg), jnp.zeros((), jnp.float32)
-        counts = None
-    mlp_out = checkpoint_name(mlp_out, "mlp_out")
-    return x + mlp_out, aux, counts
-
-
-def _is_sparse(cfg: ModelConfig, blk: Params) -> bool:
-    """Whether this layer's MLP is the mixture of experts: every layer of
-    a MoE model but its leading dense ones, which carry no router."""
-    return cfg.is_moe and (not cfg.first_k_dense or "router" in blk)
-
-
-def _lead_layers(cfg: ModelConfig, blocks: Params) -> list:
-    """The leading dense layers' leaves, one dict a layer under the
-    layer's own names; [] for a model without any."""
-    return [
-        {
-            n[len(DENSE_PREFIX):]: w[i]
-            for n, w in blocks.items() if n.startswith(DENSE_PREFIX)
-        }
-        for i in range(cfg.first_k_dense)
-    ]
-
-
-def _scanned(cfg: ModelConfig, blocks: Params) -> Params:
-    """The leaves the layer scan slices: all but the leading dense layers'."""
-    if not cfg.first_k_dense:
-        return blocks
-    return {n: w for n, w in blocks.items() if not n.startswith(DENSE_PREFIX)}
+    """The (attention, MLP) layer a pipeline stage scans
+    (`parallel/pipeline.py`), the ring's manual region handed through."""
+    branches = _packed_branches(
+        cfg, segment_ids, cos, sin, use_flash, None, cp_manual
+    )
+    return _layer_forward(cfg, branches, cfg.plan.unit[-1], x, blk)
 
 
 _ZIGZAG_SNAPSHOT: "Optional[bool]" = None
@@ -1016,19 +1059,9 @@ def _backbone(
     x = _embed(params, cfg, tokens, positions)
     cos, sin = rope_cos_sin(positions, _rope_dim(cfg), cfg.rope_theta)
 
-    if cfg.is_hybrid and (cp_mesh is not None or pp_mesh is not None):
-        raise HybridLayoutError(
-            "a hybrid layer pattern (full_attn_interval "
-            f"{cfg.full_attn_interval}) runs under data and fsdp "
-            "sharding only: the chunked delta rule has no ring over a "
-            "split sequence, and the pipeline has no stage of periods"
-        )
-
-    if cfg.is_latent and (cp_mesh is not None or pp_mesh is not None):
-        raise LatentLayoutError(_NO_LATENT_LAYOUT)
-
-    if cfg.is_pattern and (cp_mesh is not None or pp_mesh is not None):
-        raise HybridLayoutError(_NO_PATTERN_LAYOUT)
+    refusal = plan_refusal(cfg, serving=False)
+    if refusal and (cp_mesh is not None or pp_mesh is not None):
+        raise refusal
 
     if pp_mesh is not None:
         from areal_tpu.parallel.pipeline import pipelined_blocks
@@ -1083,7 +1116,7 @@ def _backbone(
                 f"divisible by 2*seq={2 * cp_mesh.shape[_SEQ]}"
             )
 
-    x, auxes, counts = _period_blocks(
+    x, auxes, counts = _blocks(
         params["blocks"], cfg, x, segment_ids, cos, sin, remat, use_flash,
         cp_mesh, cp_zigzag=zz_inv is not None,
     )
@@ -1143,6 +1176,15 @@ class LatentLayoutError(NotImplementedError):
     on yet, refused by name rather than run wrong."""
 
 
+_NO_HYBRID_LAYOUT = (
+    "a hybrid layer pattern (Gated DeltaNet layers beside attention "
+    "layers) runs under data and fsdp sharding only: no tensor parallelism "
+    "over DeltaNet heads, the chunked delta rule has no ring over a split "
+    "sequence, and a pipeline stage would have to be whole periods "
+    "(PERF.md section 7)"
+)
+
+
 _NO_LATENT_LAYOUT = (
     "latent attention and leading dense layers run under data and fsdp "
     "sharding only: the heads of the low-rank projections are not split "
@@ -1160,203 +1202,160 @@ _NO_PATTERN_LAYOUT = (
 )
 
 
-# Leaves only a period's full-attention layer has (stacked [n_periods, ...]
-# in a hybrid model); `LINEAR_LEAVES` are the linear layers' ([n_linear,
-# ...]); every other block leaf is per layer ([n_layers, ...]).
+def plan_refusal(cfg: ModelConfig, serving: bool):
+    """What of `cfg.plan` the serving plane (`serving`; its chunk
+    `decode_step_ragged_paged` is a scan of (attention, MLP) layers over
+    pages of per-head k/v) or else a mesh split over `model`, `seq` or
+    `pipe` cannot run yet -> the error to raise, by name, or None for a
+    plan both can: every refusal of a plane or a layout asks here."""
+    plan = cfg.plan
+    if plan.count(GDN):
+        return HybridLayoutError(
+            _NO_SERVING_STATE if serving else _NO_HYBRID_LAYOUT)
+    if plan.count(LATENT) or plan.prefix:
+        return LatentLayoutError(
+            _NO_SERVING_LATENT if serving else _NO_LATENT_LAYOUT)
+    if plan.count(SSM) or cfg.is_pattern:
+        return HybridLayoutError(
+            _NO_SERVING_PATTERN if serving else _NO_PATTERN_LAYOUT)
+    return None
+
+
+# The block leaves by the branch that owns them: a leaf is stacked over the
+# layers with that branch, in layer order (attention's and latent
+# attention's share `wo`, a dense MLP's and the experts' `wg` / `wu` /
+# `wd`: one of the two a model).  `ln1` is every layer's, `ln2` every
+# layer's with a second branch (`_owns`).
 _FULL_ATTN_LEAVES = (
     "wq", "wk", "wv", "wo", "wqg", "bq", "bk", "bv", "bo", "q_norm", "k_norm",
 )
-
-
-def _period_view(cfg: ModelConfig, blocks: Params) -> Params:
-    """The block leaves with the stack axis split by period: per-layer
-    leaves [P, n, ...], linear ones [P, n - 1, ...], the full layer's
-    [P, ...] (n = full_attn_interval).  Leading-axis reshapes: no data
-    moves.  What a scan over periods slices; a period of one is its layer
-    and the leaves are what they were."""
-    n, p = cfg.full_attn_interval, cfg.n_periods
-    if n == 1:
-        return blocks
-    out = {}
-    for name, w in blocks.items():
-        if name in _FULL_ATTN_LEAVES:
-            out[name] = w
-        elif name in LINEAR_LEAVES:
-            out[name] = w.reshape(p, n - 1, *w.shape[1:])
-        else:
-            out[name] = w.reshape(p, n, *w.shape[1:])
-    return out
-
-
-def _period_layer(cfg: ModelConfig, pblk: Params, j: int) -> Params:
-    """Layer j's leaves out of one period's slice of `_period_view`:
-    positions 0..n-2 are linear layers, n-1 the full-attention layer."""
-    if cfg.full_attn_interval == 1:
-        return pblk
-    last = j == cfg.full_attn_interval - 1
-    blk = {}
-    for name, w in pblk.items():
-        if name in _FULL_ATTN_LEAVES:
-            if last:
-                blk[name] = w
-        elif name in LINEAR_LEAVES:
-            if not last:
-                blk[name] = w[j]
-        else:
-            blk[name] = w[j]
-    return blk
-
-
-# Leaves only a pattern's 'E' layers have; `SSM_LEAVES` are its 'M'
-# layers', `_FULL_ATTN_LEAVES` its '*' layers'; `ln1` is every layer's.
+_LATENT_LEAVES = (
+    "wq_a", "q_a_norm", "wq_b", "wkv_a", "kv_a_norm", "wk_b", "wv_b",
+)
 _MOE_LEAVES = (
     "router", "router_bias", "wg", "wu", "wd", "ws_g", "ws_u", "ws_d",
     "ws_gate",
 )
+_LEAF_BRANCHES = {
+    **dict.fromkeys(_FULL_ATTN_LEAVES + _LATENT_LEAVES, (ATTENTION, LATENT)),
+    **dict.fromkeys(LINEAR_LEAVES, (GDN,)),
+    **dict.fromkeys(SSM_LEAVES, (SSM,)),
+    **dict.fromkeys(_MOE_LEAVES + ("bproj", "bfc"), (MLP, MOE)),
+}
+# The norm in front of a layer's first and second branch.
+_BRANCH_NORMS = ("ln1", "ln2")
 
 
-def _leaf_kind(name: str) -> Optional[str]:
-    """The pattern character of the layers a block leaf belongs to; None
-    for a leaf every layer has."""
-    if name in SSM_LEAVES:
-        return "M"
-    if name in _MOE_LEAVES:
-        return "E"
-    return "*" if name in _FULL_ATTN_LEAVES else None
+def _owns(kind: LayerKind, leaf: str) -> bool:
+    """Whether a layer of this kind has the block leaf."""
+    if leaf.startswith("ln2"):
+        return len(kind) > 1
+    return leaf not in _LEAF_BRANCHES or any(
+        b in kind for b in _LEAF_BRANCHES[leaf])
 
 
-def _pattern_view(cfg: ModelConfig, blocks: Params) -> Params:
-    """`_period_view` for a pattern of one-branch layers: each leaf's stack
-    axis split [repeats, its layers in one unit, ...]."""
-    unit, p = cfg.pattern_unit, cfg.n_periods
-    return {
-        name: w.reshape(
-            p, unit.count(_leaf_kind(name)) if _leaf_kind(name) else len(unit),
-            *w.shape[1:],
+def _unit_view(cfg: ModelConfig, blocks: Params) -> Params:
+    """What the layer scan slices: the scanned block leaves with the stack
+    axis split by scan step, [repeats, the unit's layers that own the
+    leaf, ...] — a leaf ONE layer of the unit owns stays [repeats, ...].
+    Leading-axis reshapes: no data moves, and a unit of one layer gets the
+    leaves as they are.  The prefix's leaves are not in it."""
+    plan, out = cfg.plan, {}
+    for name, w in blocks.items():
+        if name.startswith(DENSE_PREFIX):
+            continue
+        n = sum(_owns(kind, name) for kind in plan.unit)
+        out[name] = w if n == 1 else w.reshape(plan.repeats, n, *w.shape[1:])
+    return out
+
+
+def _unit_layer(cfg: ModelConfig, step: Params, j: int):
+    """Layer j of one scan step's slice of `_unit_view` -> (its kind, for
+    each of its branches the layer's index among the unit's layers with
+    that branch, its leaves)."""
+    unit = cfg.plan.unit
+    blk = {}
+    for name, w in step.items():
+        owners = [i for i, kind in enumerate(unit) if _owns(kind, name)]
+        if j in owners:
+            blk[name] = w if len(owners) == 1 else w[owners.index(j)]
+    index = {b: sum(b in kind for kind in unit[:j]) for b in unit[j]}
+    return unit[j], index, blk
+
+
+def _prefix_layers(cfg: ModelConfig, blocks: Params) -> list:
+    """The prefix's layers, each as `_unit_layer` gives a unit's — (kind,
+    its index among the prefix's layers with each of its branches, its
+    leaves under the layer's own names); [] without leading layers."""
+    prefix = cfg.plan.prefix
+    return [
+        (
+            kind,
+            {b: sum(b in k for k in prefix[:i]) for b in kind},
+            {
+                n[len(DENSE_PREFIX):]: w[i]
+                for n, w in blocks.items() if n.startswith(DENSE_PREFIX)
+            },
         )
-        for name, w in blocks.items()
-    }
+        for i, kind in enumerate(prefix)
+    ]
 
 
-def _pattern_layer(cfg: ModelConfig, pblk: Params, j: int) -> Tuple[str, int, Params]:
-    """Layer j of one unit's slice of `_pattern_view` -> (its kind, its
-    index among the unit's layers of that kind, its leaves)."""
-    unit = cfg.pattern_unit
-    kind, i = unit[j], unit[:j].count(unit[j])
-    blk = {"ln1": pblk["ln1"][j]}
-    blk.update(
-        {n: w[i] for n, w in pblk.items() if _leaf_kind(n) == kind}
-    )
-    return kind, i, blk
+def _unit_outputs(per_layer: list):
+    """What the unit's layers put out in one scan step (rows per expert,
+    what a branch leaves in the cache; None from a layer without) as the
+    scan's output: stacked over the layers that have one, the layer's own
+    where that is one layer of the unit, None where none.  `_layer_outputs`
+    undoes it after the scan."""
+    have = [x for x in per_layer if x is not None]
+    if len(have) <= 1:
+        return have[0] if have else None
+    return jnp.stack(have)
 
 
-def _pattern_blocks(
-    blocks: Params, cfg: ModelConfig, x, segment_ids, cos, sin, remat, use_flash
-):
-    """`_period_blocks` for a pattern of one-branch layers: a scan over the
-    repeats of the pattern's unit, every layer x += f(norm(x)) with f its
-    ONE kind, under the remat policy on its own.
-    -> (x, aux loss per repeat, rows per expert [n_moe_layers, E])."""
-
-    def layer(kind, y, blk):
-        h = _norm(y, blk["ln1"], None, cfg)
-        aux, counts = jnp.zeros((), jnp.float32), None
-        if kind == "M":
-            out = checkpoint_name(
-                ssm_forward(h, blk, cfg, segment_ids), "attn_out"
-            )
-        elif kind == "*":
-            q, k, v = _block_kv(h, blk, cfg, cos, sin)
-            attn = packed_attention(
-                q, k, v, segment_ids, causal=True, use_flash=use_flash
-            )
-            out = checkpoint_name(
-                _attn_out(attn.reshape(*y.shape[:2], cfg.q_dim), blk, cfg),
-                "attn_out",
-            )
-        else:
-            out, aux, counts = _mlp_moe(h, blk, cfg, valid=segment_ids > 0)
-            out = checkpoint_name(out, "mlp_out")
-        return y + out, aux, counts
-
-    layers = {
-        kind: _remat_layer(functools.partial(layer, kind), remat)
-        for kind in set(cfg.pattern_unit)
-    }
-
-    def body(y, pblk):
-        aux, counts = jnp.zeros((), jnp.float32), []
-        for j in range(len(cfg.pattern_unit)):
-            kind, _, blk = _pattern_layer(cfg, pblk, j)
-            y, a, c = layers[kind](y, blk)
-            aux = aux + a
-            if c is not None:
-                counts.append(c)
-        return y, (aux, jnp.stack(counts) if counts else None)
-
-    x, (auxes, counts) = jax.lax.scan(body, x, _pattern_view(cfg, blocks))
-    if counts is not None:  # [repeats, 'E' layers a unit, E] -> [n_moe, E]
-        counts = counts.reshape(-1, counts.shape[-1])
-    return x, auxes, counts
-
-
-def _period_stack(cfg: ModelConfig, per_layer: list):
-    """One period's per-layer values (rows per expert; None for a dense
-    MLP) as the scan's output: [n, ...], or the layer's own for a period of
-    one.  `_all_layers` undoes it after the scan."""
-    if per_layer[0] is None or cfg.full_attn_interval == 1:
-        return per_layer[0]
-    return jnp.stack(per_layer)
-
-
-def _all_layers(cfg: ModelConfig, stacked):
-    """[P, n, ...] off a scan over periods -> [L, ...]."""
-    if stacked is None or cfg.full_attn_interval == 1:
+def _layer_outputs(n_in_unit: int, stacked, lead=()):
+    """A scan's `_unit_outputs` [repeats, layers of the unit with one, ...]
+    -> [layers with one, ...], the prefix's `lead` first."""
+    if stacked is not None and n_in_unit > 1:
+        stacked = stacked.reshape(-1, *stacked.shape[2:])
+    if not lead:
         return stacked
-    return stacked.reshape(cfg.n_scan_layers, *stacked.shape[2:])
+    return jnp.concatenate([jnp.stack(lead), stacked])
 
 
-def _period_blocks(
+def _blocks(
     blocks: Params, cfg: ModelConfig, x, segment_ids, cos, sin, remat,
     use_flash, cp_mesh=None, cp_zigzag: bool = False,
 ):
-    """The block stack of every model: ONE `lax.scan` over periods, each
-    period's layers unrolled inside it (n - 1 Gated DeltaNet blocks, then
-    one softmax-attention block; a model of one kind of layer is a period
-    of one), every layer under the remat policy on its own.
-    -> (x, aux loss per period [P], rows per expert [L, E])."""
-    if cfg.is_pattern:
-        return _pattern_blocks(
-            blocks, cfg, x, segment_ids, cos, sin, remat, use_flash
+    """The block stack of every model: the prefix's layers, then ONE
+    `lax.scan` over the repeats of the plan's unit, a unit's layers
+    unrolled inside it, every layer (all its branches) under the remat
+    policy on its own.
+    -> (x, aux loss per repeat, rows per expert [n_moe_layers, E])."""
+    plan = cfg.plan
+    branches = _packed_branches(
+        cfg, segment_ids, cos, sin, use_flash, cp_mesh, None, cp_zigzag
+    )
+    layers = {
+        kind: _remat_layer(
+            functools.partial(_layer_forward, cfg, branches, kind), remat
         )
-    n = cfg.full_attn_interval
+        for kind in plan.kinds
+    }
 
-    def linear(y, blk):
-        return _linear_block_forward(y, blk, cfg, segment_ids)
-
-    def full(y, blk):
-        return _block_forward(
-            y, blk, cfg, segment_ids, cos, sin, use_flash, cp_mesh,
-            cp_zigzag=cp_zigzag,
-        )
-
-    linear, full = _remat_layer(linear, remat), _remat_layer(full, remat)
-
-    def body(y, pblk):
+    def body(y, step):
         aux, counts = None, []
-        for j in range(n):
-            layer = full if j == n - 1 else linear
-            y, a, c = layer(y, _period_layer(cfg, pblk, j))
+        for j in range(len(plan.unit)):
+            kind, _, blk = _unit_layer(cfg, step, j)
+            y, a, c = layers[kind](y, blk)
             aux = a if aux is None else aux + a
             counts.append(c)
-        return y, (aux, _period_stack(cfg, counts))
+        return y, (aux, _unit_outputs(counts))
 
-    for blk in _lead_layers(cfg, blocks):  # dense MLP: no aux, no counts
-        x, _, _ = full(x, blk)
-    x, (auxes, counts) = jax.lax.scan(
-        body, x, _period_view(cfg, _scanned(cfg, blocks))
-    )
-    return x, auxes, _all_layers(cfg, counts)
+    for kind, _, blk in _prefix_layers(cfg, blocks):  # dense MLP: no aux
+        x, _, _ = layers[kind](x, blk)
+    x, (auxes, counts) = jax.lax.scan(body, x, _unit_view(cfg, blocks))
+    return x, auxes, _layer_outputs(plan.in_unit(MOE), counts)
 
 
 @jax.named_scope("head_logprob")
@@ -1483,25 +1482,21 @@ def forward_with_aux(
 
 @dataclasses.dataclass
 class KVCache:
-    """Dense per-layer KV cache of the static decode program: k/v
-    [L, B, S_max, n_kv, head_dim], full precision (its windows are small;
-    the int8 mode lives on the serving plane's `PagedKVCache`).
+    """The static decode program's cache, one population a kind of branch
+    (`cfg.plan`), each stacked over the layers that have the branch and
+    None where none does; an MLP or expert branch keeps nothing.
 
-    A hybrid layer pattern keeps two kinds of state side by side: k/v for
-    its softmax-attention layers alone (L = n_periods), and for each Gated
-    DeltaNet layer a recurrent `state` [n_linear, B, hv, dk, dv] in fp32
-    plus the causal conv's last inputs `conv` [n_linear, B, K-1, C].  Both
-    are None for every other model.
-
-    A pattern of one-branch layers keeps k/v for its attention layers
-    alone (L = n_attn_layers), for each Mamba-2 layer a `state` [n_ssm, B,
-    H, head_dim, N] in fp32 and the conv's last inputs `conv` [n_ssm, B,
-    K-1, conv_dim], and nothing for an expert layer.
-
-    Latent attention keeps neither k nor v: `latent` [L, B, S_max,
-    kv_lora_rank + qk_rope_head_dim] holds ONE row a token and layer, the
-    normed latent vector beside the roped key part all heads share, and
-    the decode step attends over the rows themselves (`decode_step`)."""
+    - softmax attention: `k` / `v` [layers, B, S_max, n_kv, head_dim], full
+      precision (its windows are small; the int8 mode lives on the serving
+      plane's `PagedKVCache`);
+    - latent attention, in their place: `latent` [layers, B, S_max,
+      kv_lora_rank + qk_rope_head_dim], ONE row a token and layer — the
+      normed latent vector beside the roped key part all heads share — and
+      the decode step attends over the rows themselves (`decode_step`);
+    - a recurrent branch: `state` in fp32 and the causal conv's last inputs
+      `conv` — Gated DeltaNet [layers, B, hv, dk, dv] and [layers, B, K-1,
+      C], Mamba-2 [layers, B, H, head_dim, N] and [layers, B, K-1,
+      conv_dim] (`_RECURRENT_SHAPES`)."""
 
     k: Optional[jax.Array]
     v: Optional[jax.Array]
@@ -1514,8 +1509,16 @@ class KVCache:
         return (self.latent if self.k is None else self.k).shape[2]
 
 
+# The cache's populations: field -> the branches that keep it.
+_CACHE_FIELDS = {
+    "k": (ATTENTION,),
+    "v": (ATTENTION,),
+    "state": (GDN, SSM),
+    "conv": (GDN, SSM),
+    "latent": (LATENT,),
+}
 jax.tree_util.register_dataclass(
-    KVCache, data_fields=["k", "v", "state", "conv", "latent"], meta_fields=[]
+    KVCache, data_fields=list(_CACHE_FIELDS), meta_fields=[]
 )
 
 
@@ -1558,34 +1561,35 @@ def _cache_update(kc, vc, ksc, vsc, k, v, rows, rows_s, quant: bool):
     )
 
 
+# A recurrent branch's (state, conv tail) shapes for one layer and row.
+_RECURRENT_SHAPES = {
+    GDN: lambda cfg: (
+        (cfg.linear_n_v_heads, cfg.linear_k_head_dim, cfg.linear_v_head_dim),
+        (cfg.linear_conv_kernel - 1, cfg.linear_conv_dim),
+    ),
+    SSM: lambda cfg: (
+        (cfg.ssm_n_heads, cfg.ssm_head_dim, cfg.ssm_state_dim),
+        (cfg.ssm_conv_kernel - 1, cfg.ssm_conv_dim),
+    ),
+}
+
+
 def init_kv_cache(
     cfg: ModelConfig, batch: int, s_max: int, dtype=None
 ) -> KVCache:
-    dtype = dtype or cfg.dtype
-    if cfg.is_latent:
+    dtype, plan = dtype or cfg.dtype, cfg.plan
+    if plan.count(LATENT):
         return KVCache(k=None, v=None, latent=jnp.zeros(
-            (cfg.n_layers, batch, s_max, cfg.latent_dim), dtype))
-    shape = (cfg.n_attn_layers, batch, s_max, cfg.n_kv_heads, cfg.head_dim)
+            (plan.count(LATENT), batch, s_max, cfg.latent_dim), dtype))
+    # Without an attention layer: no layers of k/v, the window's length.
+    shape = (plan.count(ATTENTION), batch, s_max, cfg.n_kv_heads, cfg.head_dim)
     cache = KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
-    if cfg.n_ssm_layers:
-        nm = cfg.n_ssm_layers
-        cache.state = jnp.zeros(
-            (nm, batch, cfg.ssm_n_heads, cfg.ssm_head_dim, cfg.ssm_state_dim),
-            jnp.float32,
-        )
-        cache.conv = jnp.zeros(
-            (nm, batch, cfg.ssm_conv_kernel - 1, cfg.ssm_conv_dim), dtype
-        )
-    if cfg.is_hybrid:
-        nl = cfg.n_linear_layers
-        cache.state = jnp.zeros(
-            (nl, batch, cfg.linear_n_v_heads, cfg.linear_k_head_dim,
-             cfg.linear_v_head_dim),
-            jnp.float32,
-        )
-        cache.conv = jnp.zeros(
-            (nl, batch, cfg.linear_conv_kernel - 1, cfg.linear_conv_dim), dtype
-        )
+    for branch, shapes in _RECURRENT_SHAPES.items():
+        if plan.count(branch):
+            state, conv = shapes(cfg)
+            cache.state = jnp.zeros(
+                (plan.count(branch), batch, *state), jnp.float32)
+            cache.conv = jnp.zeros((plan.count(branch), batch, *conv), dtype)
     return cache
 
 
@@ -1715,124 +1719,50 @@ def prefill(
     x = _embed(params, cfg, tokens, positions)
     cos, sin = rope_cos_sin(positions, _rope_dim(cfg), cfg.rope_theta)
 
-    def mlp(y, blk):
-        h2 = _norm(y, blk["ln2"], blk.get("ln2_b"), cfg)
-        if not _is_sparse(cfg, blk):
-            return y + _mlp_dense(h2, blk, cfg)
-        return y + _mlp_moe(h2, blk, cfg, valid=segment_ids > 0)[0]
+    plan = cfg.plan
+    branches = _packed_branches(
+        cfg, segment_ids, cos, sin, use_flash, with_state=True
+    )
 
-    def body(carry, layer_in):
-        """-> (y, what the layer leaves in the cache: (k, v), or the one
-        latent row a token of latent attention)."""
-        blk = layer_in
-        h = _norm(carry, blk["ln1"], blk.get("ln1_b"), cfg)
-        if cfg.is_latent:
-            q, k, v, row = _latent_qkv(h, blk, cfg, cos, sin)
-            left = (row,)
-        else:
-            q, k, v = _block_kv(h, blk, cfg, cos, sin)
-            left = (k, v)
-        attn = packed_attention(
-            q, k, v, segment_ids, causal=True, use_flash=use_flash
-        )
-        y = _attn_out(
-            attn.reshape(*carry.shape[:2], cfg.q_dim), blk, cfg,
-            _attn_gate(h, blk, cfg),
-        )
-        return mlp(carry + y, blk), left
-
-    def period_body(carry, pblk):
-        """A period: its linear layers leave their final state and conv
-        tail, its full layer its k/v.  A period of one is `body`."""
-        n, y, states, tails = cfg.full_attn_interval, carry, [], []
-        for j in range(n - 1):
-            blk = _period_layer(cfg, pblk, j)
-            h = _norm(y, blk["ln1"], blk.get("ln1_b"), cfg)
-            mixed, state, tail = linear_attn_forward(
-                h, blk, cfg, segment_ids, with_state=True
-            )
-            y = mlp(y + mixed, blk)
-            states.append(state)
-            tails.append(tail)
-        y, kv = body(y, _period_layer(cfg, pblk, n - 1))
-        left = (jnp.stack(states), jnp.stack(tails)) if states else ()
-        return y, (*kv, *left)
-
-    def pattern_body(carry, pblk):
-        """A unit of one-branch layers: a Mamba layer leaves its state and
-        conv tail at the row's last valid token, an attention layer its
-        k/v, an expert layer nothing."""
-        y, ks, vs, states, tails = carry, [], [], [], []
-        for j in range(len(cfg.pattern_unit)):
-            kind, _, blk = _pattern_layer(cfg, pblk, j)
-            h = _norm(y, blk["ln1"], None, cfg)
-            if kind == "M":
-                out, state, tail = ssm_forward(
-                    h, blk, cfg, segment_ids, with_state=True
-                )
-                states.append(state)
-                tails.append(tail)
-            elif kind == "*":
-                q, k, v = _block_kv(h, blk, cfg, cos, sin)
-                attn = packed_attention(
-                    q, k, v, segment_ids, causal=True, use_flash=use_flash
-                )
-                out = _attn_out(attn.reshape(*y.shape[:2], cfg.q_dim), blk, cfg)
-                ks.append(k)
-                vs.append(v)
-            else:
-                out = _mlp_moe(h, blk, cfg, valid=segment_ids > 0)[0]
-            y = y + out
+    def body(y, step):
+        per_layer = []
+        for j in range(len(plan.unit)):
+            kind, _, blk = _unit_layer(cfg, step, j)
+            y, left = _packed_layer(cfg, branches, kind, y, blk)
+            per_layer.append(left)
         return y, tuple(
-            jnp.stack(a) if a else None for a in (ks, vs, states, tails)
+            _unit_outputs([left.get(f) for left in per_layer])
+            for f in _CACHE_FIELDS
         )
 
-    def fill(buf, new):
-        """The cache buffer with the prompt's entries of every layer."""
+    lead = []  # the prefix's layers come first in their populations
+    for kind, _, blk in _prefix_layers(cfg, params["blocks"]):
+        x, left = _packed_layer(cfg, branches, kind, x, blk)
+        lead.append(left)
+    x, by_field = jax.lax.scan(body, x, _unit_view(cfg, params["blocks"]))
+
+    # Each population's new entries [its layers, ...] ...
+    entries = {
+        field: _layer_outputs(
+            plan.in_unit(*keepers), stacked,
+            [left[field] for left in lead if field in left],
+        )
+        for (field, keepers), stacked in zip(_CACHE_FIELDS.items(), by_field)
+    }
+
+    def place(buf, new):
+        """... in its buffer: a state is all new, a window gets the
+        prompt's entries of every layer."""
+        if buf is None or new is None:
+            return buf
+        if new.shape == buf.shape:
+            return new.astype(buf.dtype)
         return jax.lax.dynamic_update_slice(
             buf, new.astype(buf.dtype), (0,) * buf.ndim
         )
 
-    def flat(a, buf):
-        """[repeats, a kind's layers a unit, ...] -> the cache's [layers of
-        that kind, ...]; the buffer as it is where no layer has the kind."""
-        return buf if a is None else a.reshape(-1, *a.shape[2:])
-
-    if cfg.is_pattern:
-        x, by_kind = jax.lax.scan(
-            pattern_body, x, _pattern_view(cfg, params["blocks"])
-        )
-        ks, vs, states, tails = (
-            flat(a, buf) for a, buf in
-            zip(by_kind, (cache.k, cache.v, cache.state, cache.conv))
-        )
-        new_cache = KVCache(
-            k=fill(cache.k, ks), v=fill(cache.v, vs), state=states,
-            conv=None if tails is None else tails.astype(cache.conv.dtype),
-        )
-        return _prefill_head(params, cfg, x, segment_ids), new_cache
-
-    lead = []  # the leading dense layers' rows come first in the cache
-    for blk in _lead_layers(cfg, params["blocks"]):
-        x, (row,) = body(x, blk)
-        lead.append(row)
-    x, (ks, *left) = jax.lax.scan(
-        period_body, x, _period_view(cfg, _scanned(cfg, params["blocks"]))
-    )
-
-    if cfg.is_latent:
-        rows = jnp.concatenate([jnp.stack(lead), ks]) if lead else ks
-        new_cache = KVCache(k=None, v=None, latent=fill(cache.latent, rows))
-    else:
-        vs, *left = left
-        extra = {}
-        if left:  # [P, n - 1, B, ...] -> [n_linear, B, ...]: the cache's layout
-            extra = dict(
-                state=left[0].reshape(cache.state.shape),
-                conv=left[1].reshape(cache.conv.shape).astype(cache.conv.dtype),
-            )
-        new_cache = KVCache(k=fill(cache.k, ks), v=fill(cache.v, vs), **extra)
-    return _prefill_head(params, cfg, x, segment_ids), new_cache
+    new_cache = {f: place(getattr(cache, f), entries[f]) for f in _CACHE_FIELDS}
+    return _prefill_head(params, cfg, x, segment_ids), KVCache(**new_cache)
 
 
 def _prefill_head(params: Params, cfg: ModelConfig, x, segment_ids):
@@ -1924,46 +1854,32 @@ def decode_step(
             cfg.hidden_dim, cfg.moe_intermediate_dim
         )
 
-    def mlp(y, blk, layer):
-        """-> (y + mlp, rows per expert); `layer` indexes the stacked
-        expert leaves (all the scanned layers of them)."""
-        h2 = _norm(y, blk["ln2"], blk.get("ln2_b"), cfg)
-        if _is_sparse(cfg, blk):
-            mlp_out, _, counts = _mlp_moe(
-                h2, blk, cfg, stacked=stacked, layer=layer,
-                kernel=bool(expert_kernel),
-            )
-        else:
-            mlp_out, counts = _mlp_dense(h2, blk, cfg), None
-        return y + mlp_out, counts
+    plan = cfg.plan
 
-    def attend_latent(y, rows, blk, li):
+    def attend_latent(h, blk, cache, li):
         """Absorbed latent attention of one token per row through the
         latent rows of layer li."""
-        h = _norm(y, blk["ln1"], blk.get("ln1_b"), cfg)
         q, row = _latent_q_absorbed(h, blk, cfg, cos, sin)
         rows = jax.lax.dynamic_update_slice(
-            rows, row.astype(rows.dtype)[None], (li, 0, slot, 0)
+            cache.latent, row.astype(cache.latent.dtype)[None],
+            (li, 0, slot, 0),
         )
         attn = latent_decode_attention(
             q[:, 0], rows, li, valid_from, slot + 1, cfg.kv_lora_rank,
             cfg.head_dim**-0.5, use_kernel=row_kernel,
         )
         ao = _attn_out(attn.reshape(b, 1, -1), blk, cfg, absorbed=True)
-        return y + ao, rows, None
+        return ao, dataclasses.replace(cache, latent=rows), None
 
-    def attend(y, kc, vc, blk, li):
+    def attend(h, blk, cache, li):
         """Softmax attention of one token per row through k/v layer li."""
-        if cfg.is_latent:
-            return attend_latent(y, kc, blk, li)
-        h = _norm(y, blk["ln1"], blk.get("ln1_b"), cfg)
         q, k, v = _block_kv(h, blk, cfg, cos, sin)  # q/k/v [B,1,h,d]
         # k/v [B,1,h,d] -> [1,B,1,h,d] written at (layer, :, slot).
         kc = jax.lax.dynamic_update_slice(
-            kc, k.astype(kc.dtype)[None], (li, 0, slot, 0, 0)
+            cache.k, k.astype(cache.k.dtype)[None], (li, 0, slot, 0, 0)
         )
         vc = jax.lax.dynamic_update_slice(
-            vc, v.astype(vc.dtype)[None], (li, 0, slot, 0, 0)
+            cache.v, v.astype(cache.v.dtype)[None], (li, 0, slot, 0, 0)
         )
         k_layer = jax.lax.dynamic_index_in_dim(kc, li, axis=0, keepdims=False)
         v_layer = jax.lax.dynamic_index_in_dim(vc, li, axis=0, keepdims=False)
@@ -1971,88 +1887,68 @@ def decode_step(
         ao = _attn_out(
             attn.reshape(b, 1, cfg.q_dim), blk, cfg, _attn_gate(h, blk, cfg)
         )
-        return y + ao, kc, vc
+        return ao, dataclasses.replace(cache, k=kc, v=vc), None
 
-    def period_body(carry, pblk):
-        """A period: each linear layer steps its recurrent state and conv
-        tail in place (carried like k/v; None where no layer is linear),
-        the full layer attends through the period's k/v."""
-        y, kc, vc, sc, cc, pi = carry
-        n, counts = cfg.full_attn_interval, []
+    def recurrent(step, *kernel):
+        """A recurrent branch steps layer li of the state and the conv
+        tail in place (carried like k/v)."""
 
-        def layer(j):  # the period's layer j among all the layers
-            return pi if n == 1 else pi * n + j
+        def branch(h, blk, cache, li):
+            out, sc, cc = step(h, blk, cfg, cache.state, cache.conv, li, *kernel)
+            return out, dataclasses.replace(cache, state=sc, conv=cc), None
 
-        for j in range(n - 1):
-            blk = _period_layer(cfg, pblk, j)
-            li = pi * (n - 1) + j
-            h = _norm(y, blk["ln1"], blk.get("ln1_b"), cfg)
-            mixed, sc, cc = linear_attn_step(
-                h, blk, cfg, sc, cc, li, row_kernel)
-            y, c = mlp(y + mixed, blk, layer(j))
-            counts.append(c)
-        blk = _period_layer(cfg, pblk, n - 1)
-        y, kc, vc = attend(y, kc, vc, blk, pi + n_lead if n_lead else pi)
-        y, c = mlp(y, blk, layer(n - 1))
-        counts.append(c)
-        return (y, kc, vc, sc, cc, pi + 1), _period_stack(cfg, counts)
+        return branch
 
-    def pattern_body(carry, pblk):
-        """A unit of one-branch layers: a Mamba layer steps its state and
-        conv tail in place, an attention layer attends through its k/v, an
-        expert layer reads no cache; each indexes its own kind's stack."""
-        y, kc, vc, sc, cc, pi = carry
-        unit, counts = cfg.pattern_unit, []
-        for j in range(len(unit)):
-            kind, i, blk = _pattern_layer(cfg, pblk, j)
-            li = pi * unit.count(kind) + i
-            if kind == "*":
-                y, kc, vc = attend(y, kc, vc, blk, li)
-                continue
-            h = _norm(y, blk["ln1"], None, cfg)
-            if kind == "M":
-                out, sc, cc = ssm_step(h, blk, cfg, sc, cc, li)
-            else:
-                out, _, c = _mlp_moe(
-                    h, blk, cfg, stacked=stacked, layer=li,
-                    kernel=bool(expert_kernel),
-                )
-                counts.append(c)
+    def experts(h, blk, cache, li):
+        out, _, counts = _mlp_moe(
+            h, blk, cfg, stacked=stacked, layer=li, kernel=bool(expert_kernel)
+        )
+        return out, cache, counts
+
+    # branch -> f(h, blk, cache, li) -> (its output, the cache, rows per
+    # expert or None); li: the layer's index among all the layers with the
+    # branch, which is its place in the branch's population of the cache
+    # and in the stacked expert leaves.
+    branches = {
+        ATTENTION: attend,
+        LATENT: attend_latent,
+        GDN: recurrent(linear_attn_step, row_kernel),
+        SSM: recurrent(ssm_step),
+        MLP: lambda h, blk, cache, li: (_mlp_dense(h, blk, cfg), cache, None),
+        MOE: experts,
+    }
+
+    def layer(kind, nth, y, blk, cache, pi=None):
+        """`nth`: the layer's index, a branch, among the prefix's layers
+        with the branch or, in scan step `pi`, among the unit's."""
+        counts = None
+        for branch, ln in zip(kind, _BRANCH_NORMS):
+            li = nth[branch]
+            if pi is not None:  # behind the prefix's and the earlier steps'
+                n, lead = plan.in_unit(branch), plan.in_prefix(branch)
+                li = pi if n == 1 else pi * n + li
+                li = li + lead if lead else li
+            h = _norm(y, blk[ln], blk.get(ln + "_b"), cfg)
+            out, cache, c = branches[branch](h, blk, cache, li)
             y = y + out
-        return (y, kc, vc, sc, cc, pi + 1), (
-            jnp.stack(counts) if counts else None
-        )
+            counts = c if c is not None else counts
+        return y, cache, counts
 
-    if cfg.is_pattern:
-        (x, kc, vc, sc, cc, _), counts = jax.lax.scan(
-            pattern_body,
-            (x, cache.k, cache.v, cache.state, cache.conv, jnp.int32(0)),
-            _pattern_view(cfg, blocks),
-        )
-        if counts is not None:
-            counts = counts.reshape(-1, counts.shape[-1])
-        logits = _head(params, cfg, _final_norm(params, cfg, x))[:, 0]
-        new_cache = KVCache(k=kc, v=vc, state=sc, conv=cc)
-        if with_moe_counts:
-            return logits, new_cache, counts
-        return logits, new_cache
+    def body(carry, step):
+        y, cache, pi = carry
+        counts = []
+        for j in range(len(plan.unit)):
+            kind, nth, blk = _unit_layer(cfg, step, j)
+            y, cache, c = layer(kind, nth, y, blk, cache, pi)
+            counts.append(c)
+        return (y, cache, pi + 1), _unit_outputs(counts)
 
-    n_lead = cfg.first_k_dense
-    # k/v, or latent attention's one buffer of rows in k's place.
-    kc, vc = (cache.latent, None) if cfg.is_latent else (cache.k, cache.v)
-    for i, blk in enumerate(_lead_layers(cfg, blocks)):
-        x, kc, vc = attend(x, kc, vc, blk, i)
-        x, _ = mlp(x, blk, None)
-    (x, kc, vc, sc, cc, _), counts = jax.lax.scan(
-        period_body,
-        (x, kc, vc, cache.state, cache.conv, jnp.int32(0)),
-        _period_view(cfg, _scanned(cfg, blocks)),
+    for kind, nth, blk in _prefix_layers(cfg, blocks):
+        x, cache, _ = layer(kind, nth, x, blk, cache)
+    (x, new_cache, _), counts = jax.lax.scan(
+        body, (x, cache, jnp.int32(0)), _unit_view(cfg, blocks)
     )
-    counts = _all_layers(cfg, counts)
-    if cfg.is_latent:
-        new_cache = KVCache(k=None, v=None, latent=kc)
-    else:
-        new_cache = KVCache(k=kc, v=vc, state=sc, conv=cc)
+    counts = _layer_outputs(plan.in_unit(MOE), counts)
     x = _final_norm(params, cfg, x)
     logits = _head(params, cfg, x)[:, 0]  # [B, V]
     if with_moe_counts:
@@ -2126,10 +2022,9 @@ jax.tree_util.register_dataclass(
 def init_paged_kv_cache(
     cfg: ModelConfig, n_pages: int, page_size: int, dtype=None
 ) -> PagedKVCache:
-    if cfg.is_latent:
-        raise LatentLayoutError(_NO_SERVING_LATENT)
-    if cfg.is_pattern:
-        raise HybridLayoutError(_NO_SERVING_PATTERN)
+    refusal = plan_refusal(cfg, serving=True)
+    if refusal:
+        raise refusal
     shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads * cfg.head_dim)
     dtype = dtype or cfg.dtype
     if dtype in (jnp.int8, "int8"):
@@ -2235,12 +2130,9 @@ def decode_step_ragged_paged(
     call, so the enclosing program compiles exactly once.  A grouped MoE
     model's expert leaves
     reach the ragged kernels as in `decode_step` (`experts_in_place`)."""
-    if cfg.is_hybrid:
-        raise HybridLayoutError(_NO_SERVING_STATE)
-    if cfg.is_latent:
-        raise LatentLayoutError(_NO_SERVING_LATENT)
-    if cfg.is_pattern:
-        raise HybridLayoutError(_NO_SERVING_PATTERN)
+    refusal = plan_refusal(cfg, serving=True)
+    if refusal:
+        raise refusal
     t = tokens.shape[0]
     b = page_table.shape[0]
     live = row_of < b

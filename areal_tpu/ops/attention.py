@@ -92,23 +92,6 @@ def decode_attention_reference(
     return out.astype(q.dtype)
 
 
-_DECODE_KERNEL_SNAPSHOT = None
-
-
-def _decode_kernel_enabled() -> bool:
-    """AREAL_DECODE_KERNEL=1 switches decode attention to the fused
-    Pallas kernel (ops/pallas/decode_attention.py).  Read once: jit
-    caches don't key on env vars."""
-    global _DECODE_KERNEL_SNAPSHOT
-    if _DECODE_KERNEL_SNAPSHOT is None:
-        import os
-
-        _DECODE_KERNEL_SNAPSHOT = (
-            os.environ.get("AREAL_DECODE_KERNEL") == "1"
-        )
-    return _DECODE_KERNEL_SNAPSHOT
-
-
 @jax.named_scope("layer/attn")
 def decode_attention(
     q: jax.Array,  # [B, 1, n_q, d] — one new token per row
@@ -123,31 +106,21 @@ def decode_attention(
     (query heads grouped per KV head) and no fp32 materialization of the
     cache — bf16 operands with fp32 MXU accumulation.  `[valid_from,
     valid_to)` is the live window (right-aligned prompt layout).
-    With `k_scale`/`v_scale` the caches are int8 and dequantized here
-    (in-kernel when AREAL_DECODE_KERNEL=1 — the bandwidth-saving path).
+    With `k_scale`/`v_scale` the caches are int8 and dequantized here.
+    XLA ops on every backend: the arithmetic the paged kernel is held to.
 
     Replaces the reference's flash_attn_with_kvcache decode path
     (realhf/impl/model/modules/attn.py:251)."""
-    if _decode_kernel_enabled():
-        from areal_tpu.ops.pallas.decode_attention import (
-            decode_attention_kernel,
-        )
-
-        return decode_attention_kernel(
-            q, k_cache, v_cache,
-            jnp.asarray(valid_from, jnp.int32),
-            valid_to, k_scale, v_scale,
-        )
-    return _decode_attention_xla(
+    return _decode_attention(
         q, k_cache, v_cache, valid_from, valid_to, k_scale, v_scale
     )
 
 
-def _decode_attention_xla(
+def _decode_attention(
     q, k_cache, v_cache, valid_from, valid_to, k_scale=None, v_scale=None
 ):
-    """`decode_attention` as XLA ops, whatever AREAL_DECODE_KERNEL says:
-    the arithmetic both decode kernels and the paged kernel are held to."""
+    """`decode_attention` outside its scope: `ragged_paged_attention`'s
+    XLA form runs it under its own."""
     if k_scale is not None:
         from areal_tpu.ops.quant import kv_dequant
 
@@ -172,8 +145,7 @@ def _decode_attention_xla(
     logits = jnp.where(valid[:, None, None, :], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     # Fully-masked rows (empty live window) softmax all-NEG_INF into a
-    # uniform distribution over garbage; zero them instead — matching
-    # the Pallas kernel, which emits exact zeros there.
+    # uniform distribution over garbage; zero them instead.
     probs = jnp.where(valid.any(axis=-1)[:, None, None, None], probs, 0.0)
     out = jnp.einsum(
         "bgrs,bsgd->bgrd", probs.astype(v_cache.dtype), v_cache,
@@ -263,17 +235,7 @@ def decode_attention_chunk(
     GQA-grouped, bf16-operand/fp32-accumulate formulation.  No generation
     program calls it (the serving chunk packs such rows into
     `ragged_paged_attention` lanes); it stays as the arithmetic reference
-    of the tests and of `decode_attention_chunk_kernel`."""
-    if _decode_kernel_enabled():
-        from areal_tpu.ops.pallas.decode_attention import (
-            decode_attention_chunk_kernel,
-        )
-
-        return decode_attention_chunk_kernel(
-            q, k_cache, v_cache,
-            jnp.asarray(valid_from, jnp.int32), valid_to0,
-            k_scale, v_scale,
-        )
+    of the serving and speculative-decoding tests."""
     if k_scale is not None:
         from areal_tpu.ops.quant import kv_dequant
 
@@ -413,7 +375,7 @@ def ragged_paged_attention(
     # Q=1 decode formulation with T "rows": each packed token is its own
     # attention problem.  It zeroes empty-window rows, which
     # is exactly the dead-lane (valid_to == 0) contract.
-    out = _decode_attention_xla(
+    out = _decode_attention(
         q[:, None], k_cache, v_cache, jnp.zeros((t,), jnp.int32),
         jnp.asarray(valid_to, jnp.int32), k_scale=ks, v_scale=vs,
     )

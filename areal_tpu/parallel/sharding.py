@@ -221,36 +221,14 @@ def attn_dispatch(mesh: Mesh, cfg=None):
 
     from areal_tpu.base.topology import BATCH_AXES
 
-    if cfg is not None and cfg.is_hybrid and any(
+    if cfg is not None and any(
         mesh.shape[a] > 1 for a in (MODEL_AXIS, SEQ_AXIS, PIPE_AXIS)
     ):
-        from areal_tpu.models.transformer import HybridLayoutError
+        from areal_tpu.models.transformer import plan_refusal
 
-        raise HybridLayoutError(
-            f"mesh {dict(mesh.shape)}: a hybrid layer pattern "
-            f"(full_attn_interval {cfg.full_attn_interval}) runs under data "
-            "and fsdp sharding only — no tensor parallelism over DeltaNet "
-            "heads, no ring over a split sequence, and a pipeline stage "
-            "would have to be whole periods (PERF.md section 7)"
-        )
-    if cfg is not None and cfg.is_pattern and any(
-        mesh.shape[a] > 1 for a in (MODEL_AXIS, SEQ_AXIS, PIPE_AXIS)
-    ):
-        from areal_tpu.models.transformer import (
-            _NO_PATTERN_LAYOUT,
-            HybridLayoutError,
-        )
-
-        raise HybridLayoutError(f"mesh {dict(mesh.shape)}: {_NO_PATTERN_LAYOUT}")
-    if cfg is not None and cfg.is_latent and any(
-        mesh.shape[a] > 1 for a in (MODEL_AXIS, SEQ_AXIS, PIPE_AXIS)
-    ):
-        from areal_tpu.models.transformer import (
-            _NO_LATENT_LAYOUT,
-            LatentLayoutError,
-        )
-
-        raise LatentLayoutError(f"mesh {dict(mesh.shape)}: {_NO_LATENT_LAYOUT}")
+        refusal = plan_refusal(cfg, serving=False)
+        if refusal:
+            raise type(refusal)(f"mesh {dict(mesh.shape)}: {refusal}")
     if mesh.devices.size == 1:
         use_flash = None
     else:

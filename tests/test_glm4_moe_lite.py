@@ -770,12 +770,26 @@ _PARENT_PROGRAMS = {
     ("olmoe", "gen"): "08cbd3bb4ad48f2c785f063336a25c436de0607d188b8119167fffbbe1b0deee",
     ("hybrid", "grad"): "c94f9667bcd928b7694a5c86362e323e51525b2aecc938ad2990c614fd0505ff",
     ("hybrid", "gen"): "6d889c1ff867f33718fa1985775c0ab9ad73a4f437a5b8cdd37191d84c0dcc6c",
+    # The Nemotron-H toy (tests/test_nemotron_h.py `_cfg()`), from PR 43 on.
+    # At PR 43's parent (750d69c) its programs hashed dd2e0ac17f0794d4...
+    # 55fb17b (grad) and 2b37b189ac4323f4...8966f1de (gen); PR 43's one
+    # view leaves a leaf ONE layer of the unit owns unreshaped (the lone '*'
+    # layer's: [P, ...], no longer [P, 1, ...] then [0]), which no one rule
+    # could keep beside the hybrid's text.  Regenerated after loss, every
+    # gradient leaf, prefill logits, every cache field and eight decode
+    # steps were `np.array_equal` between the two commits (CHANGES.md).
+    ("pattern", "grad"): "33a433005e3ffd64d893d919ad2e005cc010a521cb552a44a626b5510fc46c3d",
+    ("pattern", "gen"): "3eb31cd67dfb15d13e7ad4060e8cba9d098840d07c23eb100f459ea1893b347e",
 }
 
 
 def _other_toy(name):
     if name == "dense":
         return tiny_config()
+    if name == "pattern":
+        from tests.test_nemotron_h import _cfg as nemotron_toy
+
+        return nemotron_toy()
     if name == "olmoe":
         from tests.test_olmoe import HF_TOY
 
