@@ -25,9 +25,14 @@ logger = logging.getLogger("monitor")
 def _attn_layers(cfg) -> int:
     """Layers with softmax attention: one per period of a hybrid pattern,
     and every leading dense layer (those lie outside the periods); the '*'
-    layers of a pattern of one-branch layers."""
+    layers of a pattern of one-branch layers; every layer of a window /
+    full mix (a window layer is counted as a full one: this count bounds
+    the program's own estimate, the benchmark's `peaks_swa.py` counts the
+    band)."""
     if getattr(cfg, "layer_pattern", ""):
         return cfg.n_attn_layers
+    if getattr(cfg, "window_pattern", ""):
+        return cfg.n_layers
     return getattr(cfg, "n_periods", cfg.n_layers) + getattr(
         cfg, "first_k_dense", 0)
 

@@ -57,6 +57,10 @@ from areal_tpu.parallel import sharding
 
 logger = logging.getLogger("generator")
 
+# Tokens one prefill of the static decode program holds at most (b x sp,
+# pads included): past it the rows go in waves (`_prefill_wave_rows`).
+PREFILL_WAVE_TOKENS = 48 * 1024
+
 
 def _cache_nbytes(cache) -> int:
     """Total byte footprint of a KV page pool (host-side metadata only)."""
@@ -416,6 +420,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         # device inside the decode loop: [experts touched, fullest expert's
         # rows, steps] (see _fold_moe_counters).
         self._moe_decode_sums = np.zeros((_n_moe_counters(cfg),))
+        self._window_live_sums = np.zeros((2,))
         # Serving-plane chunk counters of the current generate() call
         # (see _serving_counters); folded into last_pool_stats at its end.
         self._chunk_stats: Dict[str, Any] = _new_chunk_stats()
@@ -650,6 +655,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         self.cache_copy_bytes = 0
         self.last_pool_stats = {}
         self._moe_decode_sums = np.zeros((_n_moe_counters(self.cfg),))
+        self._window_live_sums = np.zeros((2,))
         self.lanes_dispatched = 0
         self.lanes_live = 0
         self.lanes_slack = 0
@@ -2306,6 +2312,18 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         def nbytes(*xs):
             return sum(x.size * x.dtype.itemsize for x in xs)
 
+        if cache.wk is not None:  # rings beside the full layers' windows
+            cfg = self.cfg
+            self.last_pool_stats.update(
+                window_cache_bytes=nbytes(cache.wk, cache.wv),
+                kv_cache_bytes=nbytes(cache.k, cache.v),
+                # every attention layer at s_max, as a cache without rings
+                kv_cache_bytes_unwindowed=2 * cache.wk.dtype.itemsize * (
+                    (cfg.n_attn_layers + cfg.n_window_layers) * b * s_total
+                    * cfg.kv_dim
+                ),
+                window_slots=b * cache.wk.shape[2],
+            )
         if cache.state is not None:  # the two kinds of state
             self.last_pool_stats.update(
                 kv_cache_bytes=nbytes(cache.k, cache.v),
@@ -2332,6 +2350,13 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                     to_host(logps),
                     to_host(gen_len),
                 )
+                if self.cfg.n_window_layers:  # [live ring entries, steps]
+                    live, steps = to_host(rest.pop()).astype(float)
+                    self._window_live_sums += (live, steps)
+                    self.last_pool_stats["window_slots_live"] = float(
+                        self._window_live_sums[0]
+                        / max(self._window_live_sums[1], 1.0)
+                    )
                 if rest:  # [experts touched, fullest expert's rows, steps]
                     self._moe_decode_sums += to_host(rest[0]).astype(float)
                     self._fold_moe_counters()
@@ -2354,6 +2379,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         max_new = g.max_new_tokens
         row_kernel = self._row_kernel
         expert_kernel = self._expert_kernel
+        wave = self._prefill_wave_rows(b, sp)
 
         @jax.jit
         def gen(params, prompt_tok, prompt_len, key):
@@ -2365,9 +2391,15 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             cache = tfm.init_kv_cache(cfg, bsz, s_total, dtype=self.compute_dtype)
             # prefill returns logits at each row's last prompt token — the
             # distribution over the first response token.
-            logits0, cache = tfm.prefill(
-                params, cfg, prompt_tok, seg, cache, use_flash=self._use_flash
-            )
+            if wave == bsz:
+                logits0, cache = tfm.prefill(
+                    params, cfg, prompt_tok, seg, cache,
+                    use_flash=self._use_flash,
+                )
+            else:
+                logits0, cache = self._prefill_in_waves(
+                    params, prompt_tok, seg, cache, wave
+                )
 
             out_toks = jnp.zeros((bsz, max_new), jnp.int32)
             out_logps = jnp.zeros((bsz, max_new), jnp.float32)
@@ -2380,7 +2412,9 @@ class GeneratorEngine(HostOffloadMixin, Engine):
 
             def body(state):
                 (step, logits, key, done, gen_len, out_toks, out_logps,
-                 cache, *moe) = state
+                 cache, *more) = state
+                ring_live = more.pop() if cfg.n_window_layers else None
+                moe = more
                 key, sub = jax.random.split(key)
                 if g.min_new_tokens > 0:
                     logits = jnp.where(
@@ -2407,6 +2441,13 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                 )
                 if cfg.is_moe:
                     moe = [moe[0] + _moe_step_counters(counts[0], cfg, bsz)]
+                if ring_live is not None:
+                    # (row, ring entry) pairs this step's window layers
+                    # each read, and 1 for the step: summed here, no sync.
+                    live = tfm.ring_valid(
+                        sp + step, valid_from, cache.wk.shape[2])
+                    moe = [*moe, ring_live + jnp.stack(
+                        [jnp.sum(live).astype(jnp.float32), jnp.float32(1.0)])]
                 return (
                     step + 1, next_logits, key, new_done, gen_len,
                     out_toks, out_logps, cache, *moe,
@@ -2415,6 +2456,8 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             state = (0, logits0, key, done, gen_len, out_toks, out_logps, cache)
             if cfg.is_moe:  # two sums + the steps they run over (+ share)
                 state += (jnp.zeros((_n_moe_counters(cfg),), jnp.float32),)
+            if cfg.n_window_layers:  # live ring entries + the steps
+                state += (jnp.zeros((2,), jnp.float32),)
             state = jax.lax.while_loop(cond, body, state)
             _, _, _, _, gen_len, out_toks, out_logps, cache, *moe = state
             # `with_cache`: what the loop leaves in the cache, last.
@@ -2427,6 +2470,53 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             f"compiled generator for shape b={b} sp={sp} s_total={s_total}"
         )
         return gen
+
+    def _prefill_wave_rows(self, b: int, sp: int) -> int:
+        """Rows the static program prefills at a time: all `b` where b x sp
+        tokens fit `PREFILL_WAVE_TOKENS` (every batch of short prompts:
+        the one prefill it always was), else the largest divisor of b that
+        does.  A prefill's temporaries grow with its tokens — the q/k/v
+        and attention output of every row, and under a mixture of experts
+        the gathered (row, choice) pairs: 0.36 GB a 4,096-token row at 8
+        choices of 2,304 wide, 11.6 GB at 32 rows where the chip has 16
+        (compiled for a described v5e, PR 44) — and a wave bounds them.
+        One device only: a wave slices the batch axis a mesh shards."""
+        if self.mesh.size > 1 or b * sp <= PREFILL_WAVE_TOKENS:
+            return b
+        return max(
+            (r for r in range(1, b) if b % r == 0
+             and r * sp <= PREFILL_WAVE_TOKENS),
+            default=1,
+        )
+
+    def _prefill_in_waves(self, params, prompt_tok, seg, cache, rows: int):
+        """`tfm.prefill` over `rows` rows at a time -> (logits [B, V], the
+        cache): a scan over the waves, each through a cache of its own
+        rows which lands in the whole one at its rows (axis 1 of every
+        population)."""
+        cfg = self.cfg
+        bsz, sp = prompt_tok.shape
+        waves = bsz // rows
+
+        def wave(cache, xs):
+            i, tok, sg = xs
+            part = tfm.init_kv_cache(
+                cfg, rows, cache.s_max, dtype=self.compute_dtype)
+            logits, part = tfm.prefill(
+                params, cfg, tok, sg, part, use_flash=self._use_flash)
+            cache = jax.tree.map(
+                lambda whole, new: jax.lax.dynamic_update_slice_in_dim(
+                    whole, new, i * rows, axis=1),
+                cache, part,
+            )
+            return cache, logits
+
+        cache, logits = jax.lax.scan(
+            wave, cache,
+            (jnp.arange(waves), prompt_tok.reshape(waves, rows, sp),
+             seg.reshape(waves, rows, sp)),
+        )
+        return logits.reshape(bsz, -1), cache
 
     # -- output assembly --
 

@@ -41,13 +41,16 @@ def bucket_len(n: int, quantum: int = _BUCKET_QUANTUM, large_step: int = 0) -> i
 
 
 def flash_tile_counts(
-    segment_ids: np.ndarray, block: int = _BUCKET_QUANTUM
+    segment_ids: np.ndarray, block: int = _BUCKET_QUANTUM,
+    window: Optional[int] = None,
 ) -> Tuple[int, int]:
     """(live, grid) tiles of the rows' `block` x `block` attention squares:
     how many hold an unmasked (causal, same-sequence) element, against all
     of them.  The flash kernels visit the live ones
     (`ops/pallas/flash_attention.live_schedule` derives the same set on
-    the device); times heads and layers it is their work for a call."""
+    the device); times heads and layers it is their work for a call.
+    `window`: a sliding-window layer's schedule, the tiles whose last key
+    lies within `window` places of their first query."""
     seg = np.asarray(segment_ids)
     block = min(block, seg.shape[1])
     n = seg.shape[1] // block
@@ -57,7 +60,11 @@ def flash_tile_counts(
     meet = (lo[:, None, :] <= hi[:, :, None]) & (
         hi[:, None, :] >= lo[:, :, None]
     )
-    return int(np.tril(meet).sum()), len(seg) * n * n
+    meet = np.tril(meet)
+    if window is not None:
+        i = np.arange(n)
+        meet &= (i[:, None] * block - (i[None, :] * block + block - 1)) < window
+    return int(meet.sum()), len(seg) * n * n
 
 
 def decode_bucket_len(n: int) -> int:

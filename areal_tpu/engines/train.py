@@ -158,14 +158,19 @@ _GRID_COUNTS = (
     "real_tokens", "grid_tokens", "n_rows", "empty_rows",
     "flash_live_tiles", "flash_grid_tiles",
 )
+_WINDOW_TILES = "flash_live_tiles_window"  # a plan with window layers only
 
 
-def _grid_counts(chunks: Sequence[Dict[str, np.ndarray]]) -> Dict[str, int]:
+def _grid_counts(
+    chunks: Sequence[Dict[str, np.ndarray]], window: Optional[int] = None
+) -> Dict[str, int]:
     """What a call's packed grids hold: real tokens against grid cells,
     rows against rows with no real token (under batch sharding an empty
     row is a chip that trains zeros), and the attention tiles the flash
-    kernels visit against the rows' full squares.  Rows and tiles also go
-    to the tracer's `pack` counter track."""
+    kernels visit against the rows' full squares — `window`: also the
+    tiles a sliding-window layer's schedule visits
+    (`flash_live_tiles_window`; `flash_live_tiles` stays the full layers').
+    Rows and tiles also go to the tracer's `pack` counter track."""
     real = [c["segment_ids"] > 0 for c in chunks]
     tiles = [packing.flash_tile_counts(c["segment_ids"]) for c in chunks]
     counted = {
@@ -174,6 +179,11 @@ def _grid_counts(chunks: Sequence[Dict[str, np.ndarray]]) -> Dict[str, int]:
         "flash_live_tiles": sum(live for live, _ in tiles),
         "flash_grid_tiles": sum(grid for _, grid in tiles),
     }
+    if window is not None:
+        counted[_WINDOW_TILES] = sum(
+            packing.flash_tile_counts(c["segment_ids"], window=window)[0]
+            for c in chunks
+        )
     tracer.counter("pack", **counted)
     return {
         "real_tokens": sum(int(r.sum()) for r in real),
@@ -232,6 +242,11 @@ class TrainEngine(HostOffloadMixin, Engine):
         anomaly_ewma_warmup: int = 5,
     ):
         self.cfg = cfg
+        # The band the window layers' flash schedule keeps, and with it one
+        # more of `_grid_counts`' keys.
+        self._flash_window = cfg.attn_window if cfg.n_window_layers else None
+        self._grid_keys = _GRID_COUNTS + (
+            (_WINDOW_TILES,) if self._flash_window else ())
         self.mesh = mesh
         self.optimizer_config = optimizer_config or OptimizerConfig()
         self.ftspec = ftspec or FinetuneSpec()
@@ -651,7 +666,7 @@ class TrainEngine(HostOffloadMixin, Engine):
             # Pack efficiency diagnostics: the MFU counter charges REAL
             # tokens, the MXU computes PADDED grids — the ratio is the
             # first thing to check when train MFU disappoints.
-            grid = _grid_counts(chunks)
+            grid = _grid_counts(chunks, self._flash_window)
             self.last_pack_stats = {
                 **grid,
                 "pack_efficiency": grid["real_tokens"]
@@ -764,7 +779,7 @@ class TrainEngine(HostOffloadMixin, Engine):
             "weight": 0.0,
             "n_micro_batches": 0,
             "n_chunks": 0,
-            **dict.fromkeys(_GRID_COUNTS, 0),
+            **dict.fromkeys(self._grid_keys, 0),
             "host_s": 0.0,
         }
 
@@ -809,7 +824,7 @@ class TrainEngine(HostOffloadMixin, Engine):
                 c for pk in packs for c in self._pack_row_chunks(pk.arrays)
             ]
             chunk_weight = float(sum(loss_weight_fn(c) for c in chunks))
-            for k, v in _grid_counts(chunks).items():
+            for k, v in _grid_counts(chunks, self._flash_window).items():
                 state[k] += v
 
         grad_fn, grad_acc_fn = self._get_grad_fn(loss_fn)
@@ -894,7 +909,7 @@ class TrainEngine(HostOffloadMixin, Engine):
             state["acc"] = None  # consumed: free the grad tree
 
         self.last_pack_stats = {
-            **{k: state[k] for k in _GRID_COUNTS},
+            **{k: state[k] for k in self._grid_keys},
             "pack_efficiency": state["real_tokens"]
             / max(state["grid_tokens"], 1),
             "n_micro_batches": state["n_micro_batches"],

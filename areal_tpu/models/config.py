@@ -20,13 +20,16 @@ DENSE_PREFIX = "dense_"
 FROZEN_LEAVES = ("router_bias",)
 
 # The residual branches x += f(norm(x)) a layer is made of: softmax attention
-# over per-head K/V, latent attention over one row a token, Gated DeltaNet,
-# Mamba-2, a dense MLP, the mixture of experts.
-ATTENTION, LATENT, GDN, SSM, MLP, MOE = (
-    "attention", "latent", "gdn", "ssm", "mlp", "moe",
+# over per-head K/V, the same over the last `attn_window` keys alone, latent
+# attention over one row a token, Gated DeltaNet, Mamba-2, a dense MLP, the
+# mixture of experts.
+ATTENTION, WINDOW, LATENT, GDN, SSM, MLP, MOE = (
+    "attention", "window", "latent", "gdn", "ssm", "mlp", "moe",
 )
 # One character of `layer_pattern` -> that layer's ONE branch.
 _PATTERN_KINDS = {"M": (SSM,), "E": (MOE,), "*": (ATTENTION,)}
+# One character of `window_pattern` -> that layer's mixer.
+_WINDOW_KINDS = {"S": WINDOW, "F": ATTENTION}
 
 LayerKind = Tuple[str, ...]  # a layer's branches, in order
 
@@ -60,6 +63,14 @@ class LayerPlan:
 
 def _layers_with(layers: Tuple[LayerKind, ...], branches) -> int:
     return sum(any(b in kind for b in branches) for kind in layers)
+
+
+def _shortest_unit(pattern: str) -> str:
+    """The shortest string `pattern` is whole repeats of."""
+    for n in range(1, len(pattern) + 1):
+        if len(pattern) % n == 0 and pattern[:n] * (len(pattern) // n) == pattern:
+            return pattern[:n]
+    return pattern
 
 
 _DTYPES = {
@@ -206,6 +217,28 @@ class ModelConfig:
     ssm_dt_min: float = 0.001
     ssm_dt_max: float = 0.1
     ssm_dt_floor: float = 0.0001
+    # ---- sliding-window layers beside full-attention layers (mellum) ----
+    # One character a layer, in order: "S" softmax attention in which a
+    # token sees the last `attn_window` keys of its sequence, itself
+    # included; "F" full causal attention.  Both kinds have the same leaves
+    # and an MLP (or the experts) behind them; the stack is scanned by
+    # repeats of the pattern's smallest unit.  "" = every attention layer
+    # is full.
+    window_pattern: str = ""
+    attn_window: int = 0
+    # The rotary embedding by kind of layer: a window layer takes plain
+    # rope at `window_rope_theta`; a full layer takes YaRN where `rope_yarn_factor`
+    # > 0 (inverse frequencies blended between theta's and theta's over
+    # `factor`, by how many turns a dimension makes over
+    # `rope_yarn_original` positions: `ops/norms.yarn_inv_freq`), its cos
+    # and sin scaled by `rope_yarn_attention_factor` (0 = 0.1 ln(factor) +
+    # 1, what the published rule gives).
+    window_rope_theta: float = 0.0  # 0 = rope_theta
+    rope_yarn_factor: float = 0.0
+    rope_yarn_original: int = 0
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    rope_yarn_attention_factor: float = 0.0
 
     def __post_init__(self):
         # The checks read the fields as given: `plan` is for what passed.
@@ -226,6 +259,17 @@ class ModelConfig:
             )
         if self.layer_pattern:
             self._check_pattern()
+        if self.window_pattern:
+            self._check_windows()
+        if self.rope_yarn_factor and not (
+            self.rope_yarn_original and self.pos_emb == "rope"
+            and not self.is_latent and not self.rotary_dim
+        ):
+            raise NotImplementedError(
+                "YaRN scales the whole-head rotary embedding of softmax "
+                "attention and needs the positions it was trained over "
+                "(rope_yarn_original)"
+            )
         if self.moe_score_func not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown moe_score_func {self.moe_score_func!r}")
         if self.is_latent:
@@ -281,23 +325,49 @@ class ModelConfig:
                 f"({self.ssm_state_dim})"
             )
 
+    def _check_windows(self):
+        pattern = self.window_pattern
+        if set(pattern) - set(_WINDOW_KINDS) or len(pattern) != self.n_layers:
+            raise ValueError(
+                f"window_pattern {pattern!r} is not {self.n_layers} "
+                "characters of 'S' (sliding window), 'F' (full attention)"
+            )
+        if "S" in pattern and self.attn_window < 1:
+            raise ValueError("an 'S' layer needs attn_window >= 1")
+        if (
+            self.layer_pattern or self.full_attn_interval > 1
+            or self.is_latent or self.first_k_dense or self.attn_gate
+        ):
+            raise NotImplementedError(
+                "sliding-window layers stand beside plain softmax-attention "
+                "layers only: no one-branch pattern, Gated DeltaNet layers, "
+                "latent attention, leading dense layers or output gate"
+            )
+
     @property
     def dtype(self):
         return _DTYPES[self.param_dtype]
 
     @functools.cached_property
     def plan(self) -> LayerPlan:
-        """The layers as `prefix + unit x repeats`, from the three fields
+        """The layers as `prefix + unit x repeats`, from the four fields
         that state them: `layer_pattern` (one character a layer, ONE
         branch each; the unit is the shortest string the pattern repeats),
-        else periods of `full_attn_interval` - 1 Gated DeltaNet layers and
+        else `window_pattern` (window or full attention, an MLP each), else
+        periods of `full_attn_interval` - 1 Gated DeltaNet layers and
         one attention layer, a mixer and an MLP each, behind
         `first_k_dense` leading layers with a dense MLP."""
         if self.layer_pattern:
             unit = tuple(_PATTERN_KINDS[c] for c in self.pattern_unit)
             return LayerPlan((), unit, len(self.layer_pattern) // len(unit))
-        mixer = LATENT if self.is_latent else ATTENTION
         mlp = MOE if self.is_moe else MLP
+        if self.window_pattern:
+            unit = tuple(
+                (_WINDOW_KINDS[c], mlp)
+                for c in _shortest_unit(self.window_pattern)
+            )
+            return LayerPlan((), unit, self.n_layers // len(unit))
+        mixer = LATENT if self.is_latent else ATTENTION
         n = self.full_attn_interval
         return LayerPlan(
             prefix=((mixer, MLP),) * self.first_k_dense,
@@ -314,11 +384,12 @@ class ModelConfig:
     def pattern_unit(self) -> str:
         """The shortest string the pattern is whole repeats of: what one
         step of the layer scan unrolls."""
-        pattern = self.layer_pattern
-        for n in range(1, len(pattern) + 1):
-            if len(pattern) % n == 0 and pattern[:n] * (len(pattern) // n) == pattern:
-                return pattern[:n]
-        return pattern
+        return _shortest_unit(self.layer_pattern)
+
+    @property
+    def n_window_layers(self) -> int:
+        """Layers whose cache is a ring of `attn_window` slots."""
+        return self.plan.count(WINDOW)
 
     @property
     def n_ssm_layers(self) -> int:
@@ -331,7 +402,8 @@ class ModelConfig:
 
     @property
     def n_attn_layers(self) -> int:
-        """Layers that keep k/v (or latent rows) in the cache."""
+        """Layers that keep k/v (or latent rows) for every slot of the
+        cache: a window layer keeps a ring (`n_window_layers`)."""
         return self.plan.count(ATTENTION, LATENT)
 
     @property

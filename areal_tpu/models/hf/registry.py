@@ -242,14 +242,20 @@ def params_to_hf_state_dict(
 
 
 # ---------------- mistral ----------------
-# Llama tensor naming; sliding-window attention is NOT modeled (full causal
-# attention — exact for sequences within the window, reference api/from_hf/
-# mistral.py maps the same fields).
+# Llama tensor naming.  A non-null `sliding_window` makes EVERY layer a
+# sliding-window layer (`window_pattern` all "S": a token sees its last
+# `sliding_window` keys, the ring cache of the static decode program); null
+# is full causal attention.
 
 
 def _mistral_config_from_hf(hf: dict) -> ModelConfig:
-    cfg = _llama_like_config_from_hf(hf)
-    return dataclasses.replace(cfg, qkv_bias=False)
+    cfg = dataclasses.replace(_llama_like_config_from_hf(hf), qkv_bias=False)
+    window = hf.get("sliding_window")
+    if not window:
+        return cfg
+    return dataclasses.replace(
+        cfg, window_pattern="S" * cfg.n_layers, attn_window=int(window)
+    )
 
 
 register_hf_family(
@@ -260,10 +266,21 @@ register_hf_family(
             **_llama_like_config_to_hf(cfg, "mistral"),
             "model_type": "mistral",
             "architectures": ["MistralForCausalLM"],
-            "sliding_window": None,
+            "sliding_window": _mistral_window(cfg),
         },
     )
 )
+
+
+def _mistral_window(cfg: ModelConfig):
+    """`sliding_window` of a config the mistral family can state: every
+    layer's window or null, never a mix of kinds."""
+    if "F" in cfg.window_pattern:
+        raise NotImplementedError(
+            f"window_pattern {cfg.window_pattern!r}: the mistral family has "
+            "one `sliding_window` for all layers (mellum has `layer_types`)"
+        )
+    return cfg.attn_window if cfg.window_pattern else None
 
 
 # ---------------- gemma ----------------
@@ -533,6 +550,154 @@ register_hf_family(
         "olmoe",
         _olmoe_config_from_hf,
         _olmoe_config_to_hf,
+        params_from_sd=_olmoe_params_from_sd,
+        params_to_sd=_olmoe_params_to_sd,
+    )
+)
+
+
+# ---------------- mellum ----------------
+# JetBrains/Mellum2: the Qwen3-MoE block (per-head q/k RMSNorm, a softmax
+# router over `num_experts` with the top-k weights renormalised, SwiGLU
+# experts, no shared expert, no biases; olmoe's tensor names) with
+# `layer_types` mixing "sliding_attention" layers (a token sees its last
+# `sliding_window` keys; plain rope) and "full_attention" layers (causal;
+# YaRN), each kind's rotary parameters under `rope_parameters`.  Every
+# layer is sparse (`mlp_layer_types`); `intermediate_size` sizes nothing.
+# A `share` group cuts the model to one expert-parallel rank, as
+# qwen3_next's does.
+
+_MELLUM_LAYER_TYPES = {"sliding_attention": "S", "full_attention": "F"}
+
+
+def _mellum_config_from_hf(hf: dict) -> ModelConfig:
+    for key, fine in (("attention_bias", False), ("hidden_act", "silu")):
+        if hf.get(key, fine) != fine:
+            raise NotImplementedError(f"mellum {key}={hf[key]!r} is not modelled")
+    n_layers = hf["num_hidden_layers"]
+    types = hf["layer_types"]
+    if len(types) != n_layers or set(types) - set(_MELLUM_LAYER_TYPES):
+        raise ValueError(
+            f"mellum layer_types {types!r}: {n_layers} of "
+            f"{sorted(_MELLUM_LAYER_TYPES)} are wanted"
+        )
+    sparse = ["sparse"] * n_layers
+    if hf.get("mlp_layer_types", sparse) != sparse:
+        raise NotImplementedError(
+            f"mellum mlp_layer_types {hf['mlp_layer_types']!r}: a dense MLP "
+            "layer among the sparse ones is not built"
+        )
+    pattern = "".join(_MELLUM_LAYER_TYPES[t] for t in types)
+    if "S" in pattern and not hf.get("use_sliding_window", True):
+        raise NotImplementedError(
+            "mellum use_sliding_window=false with sliding_attention layers: "
+            "which of the two holds is not stated"
+        )
+    rope = hf["rope_parameters"]
+    full, sliding = rope["full_attention"], rope.get("sliding_attention", {})
+    if sliding.get("rope_type", "default") != "default":
+        raise NotImplementedError(
+            f"mellum sliding_attention rope_type {sliding['rope_type']!r}: "
+            "the window layers take plain rope"
+        )
+    yarn = {}
+    if full.get("rope_type", "default") == "yarn":
+        yarn = dict(
+            rope_yarn_factor=float(full["factor"]),
+            rope_yarn_original=int(full["original_max_position_embeddings"]),
+            rope_yarn_beta_fast=float(full.get("beta_fast", 32)),
+            rope_yarn_beta_slow=float(full.get("beta_slow", 1)),
+            rope_yarn_attention_factor=float(full.get("attention_factor") or 0),
+        )
+    elif full.get("rope_type", "default") != "default":
+        raise NotImplementedError(
+            f"mellum full_attention rope_type {full['rope_type']!r}")
+    share = hf.get("share") or {}
+    n_experts = hf["num_experts"]
+    width = share.get("router_num_experts", n_experts)
+    theta = float(full["rope_theta"])
+    window_theta = float(sliding.get("rope_theta", theta))
+    return ModelConfig(
+        n_layers=n_layers,
+        hidden_dim=hf["hidden_size"],
+        n_q_heads=hf["num_attention_heads"],
+        n_kv_heads=hf["num_key_value_heads"],
+        head_dim=hf["head_dim"],
+        intermediate_dim=hf["intermediate_size"],
+        vocab_size=hf["vocab_size"],
+        max_position_embeddings=hf.get("max_position_embeddings", 131072),
+        rope_theta=theta,
+        window_rope_theta=0.0 if window_theta == theta else window_theta,
+        rms_norm_eps=hf.get("rms_norm_eps", 1e-6),
+        qk_norm=True,
+        qk_norm_per_head=True,
+        tied_embeddings=hf.get("tie_word_embeddings", False),
+        n_experts=n_experts,
+        n_experts_per_tok=hf["num_experts_per_tok"],
+        moe_intermediate_dim=hf["moe_intermediate_size"],
+        moe_norm_topk=bool(hf.get("norm_topk_prob", True)),
+        moe_aux_loss_coef=hf.get("router_aux_loss_coef", 0.001),
+        n_router_experts=0 if width == n_experts else width,
+        expert_offset=share.get("rank", 0) * n_experts,
+        window_pattern=pattern if "S" in pattern else "",
+        attn_window=int(hf.get("sliding_window") or 0) if "S" in pattern else 0,
+        **yarn,
+    )
+
+
+def _mellum_config_to_hf(cfg: ModelConfig) -> dict:
+    out = _llama_like_config_to_hf(cfg, "mellum")
+    out.pop("rope_theta")  # under rope_parameters, a kind of layer each
+    pattern = cfg.window_pattern or "F" * cfg.n_layers
+    names = {c: t for t, c in _MELLUM_LAYER_TYPES.items()}
+    full = {"rope_type": "default", "rope_theta": cfg.rope_theta}
+    if cfg.rope_yarn_factor:
+        full = {
+            "rope_type": "yarn",
+            "rope_theta": cfg.rope_theta,
+            "factor": cfg.rope_yarn_factor,
+            "original_max_position_embeddings": cfg.rope_yarn_original,
+            "beta_fast": cfg.rope_yarn_beta_fast,
+            "beta_slow": cfg.rope_yarn_beta_slow,
+            "attention_factor": cfg.rope_yarn_attention_factor or None,
+        }
+    out.update(
+        architectures=["MellumForCausalLM"],
+        hidden_act=cfg.hidden_act,
+        attention_bias=False,
+        layer_types=[names[c] for c in pattern],
+        mlp_layer_types=["sparse"] * cfg.n_layers,
+        sliding_window=cfg.attn_window or None,
+        use_sliding_window="S" in pattern,
+        max_window_layers=0,
+        rope_parameters={
+            "full_attention": full,
+            "sliding_attention": {
+                "rope_type": "default",
+                "rope_theta": cfg.window_rope_theta or cfg.rope_theta,
+            },
+        },
+        num_experts=cfg.n_experts,
+        num_experts_per_tok=cfg.n_experts_per_tok,
+        moe_intermediate_size=cfg.moe_intermediate_dim,
+        norm_topk_prob=cfg.moe_norm_topk,
+        router_aux_loss_coef=cfg.moe_aux_loss_coef,
+    )
+    if cfg.expert_share:
+        out["share"] = {
+            "router_num_experts": cfg.router_width,
+            "rank": cfg.expert_offset // cfg.n_experts,
+        }
+    return out
+
+
+register_hf_family(
+    HFFamily(
+        "mellum",
+        _mellum_config_from_hf,
+        _mellum_config_to_hf,
+        # olmoe's names: q_norm / k_norm under self_attn (here [head_dim]),
+        # mlp.gate, mlp.experts.{e}.{gate,up,down}_proj.
         params_from_sd=_olmoe_params_from_sd,
         params_to_sd=_olmoe_params_to_sd,
     )
@@ -1476,6 +1641,8 @@ def infer_model_type(cfg: ModelConfig) -> str:
         return "nemotron_h"
     if cfg.is_latent:
         return "glm4_moe_lite"
+    if cfg.window_pattern or cfg.rope_yarn_factor:
+        return "mellum" if cfg.is_moe else "mistral"
     if cfg.is_moe:
         return "olmoe" if cfg.qk_norm else "mixtral"
     if cfg.rms_norm_offset:

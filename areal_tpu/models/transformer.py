@@ -27,7 +27,8 @@ Functions:
     prefill(params, cfg, tokens, segment_ids, cache)
     decode_step(params, cfg, tokens, positions, cache, slot, valid_from)
         — the static decode program's step over a dense window (per-head
-          k/v, or one latent row a token: `cfg.is_latent`)
+          k/v, or one latent row a token: `cfg.is_latent`; a sliding-
+          window layer's k/v in a RING of `attn_window` slots)
     init_paged_kv_cache(cfg, n_pages, page_size)           -> pool
     decode_step_ragged_paged(params, cfg, tokens, positions, pool,
                              page_table, row_of)
@@ -36,6 +37,7 @@ Functions:
 
 import dataclasses
 import functools
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -50,6 +52,7 @@ from areal_tpu.models.config import (
     MLP,
     MOE,
     SSM,
+    WINDOW,
     LayerKind,
     ModelConfig,
 )
@@ -62,6 +65,7 @@ from areal_tpu.models.linear_attention import (
 from areal_tpu.models.mamba import SSM_LEAVES, init_ssm, ssm_forward, ssm_step
 from areal_tpu.ops.attention import (
     decode_attention,
+    inner_scope,
     latent_decode_attention,
     packed_attention,
     ragged_paged_attention,
@@ -140,7 +144,7 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     LM = scanned(MOE)
     blocks = {
         "ln1": norm_init((L, D), dtype),
-        **attn_leaves(scanned(ATTENTION, LATENT), ks),
+        **attn_leaves(scanned(ATTENTION, WINDOW, LATENT), ks),
     }
     if not cfg.is_pattern:  # a second branch a layer: a second norm
         blocks["ln2"] = norm_init((L, D), dtype)
@@ -314,7 +318,13 @@ def _attn_out(
     cfg: ModelConfig,
     gate: Optional[jax.Array] = None,
     absorbed: bool = False,
+    scope: Optional[str] = None,
 ) -> jax.Array:
+    with inner_scope(scope):
+        return _attn_out_proj(a, blk, cfg, gate, absorbed)
+
+
+def _attn_out_proj(a, blk, cfg, gate, absorbed):
     if gate is not None:  # qwen3_next: o_proj(attn * sigmoid(gate))
         a = a * jax.nn.sigmoid(gate)
     if absorbed:
@@ -879,20 +889,25 @@ def _attention(
     cp_mesh=None,
     cp_manual: "Optional[Tuple[str, int]]" = None,
     cp_zigzag: bool = False,
+    window: Optional[int] = None,
+    scope: Optional[str] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """The attention branch (softmax over per-head k/v, or latent) over
     packed rows of normed `h` -> (its output, what it leaves in the cache:
-    k and v, or the one latent row a token)."""
+    k and v, or the one latent row a token).  `window`: a query sees the
+    last `window` keys of its sequence (a window layer; None, all of
+    them); `scope`: the layer's inner name in a mixed plan (`inner_scope`)."""
     b, s, _ = h.shape
     if cfg.is_latent:
         q, k, v, row = _latent_qkv(h, blk, cfg, cos, sin)
         left = {"latent": row}
     else:
-        q, k, v = _block_kv(h, blk, cfg, cos, sin)
+        q, k, v = _block_kv(h, blk, cfg, cos, sin, scope)
         left = {"k": k, "v": v}
     if cp_manual is None and cp_mesh is None:
         attn = packed_attention(
-            q, k, v, segment_ids, causal=True, use_flash=use_flash
+            q, k, v, segment_ids, causal=True, use_flash=use_flash,
+            window=window, scope=scope,
         )
     else:
         with jax.named_scope("layer/attn"):
@@ -928,13 +943,62 @@ def _attention(
                     q, k, v, segment_ids, cp_mesh, causal=True
                 )
     out = _attn_out(
-        attn.reshape(b, s, cfg.q_dim), blk, cfg, _attn_gate(h, blk, cfg)
+        attn.reshape(b, s, cfg.q_dim), blk, cfg, _attn_gate(h, blk, cfg),
+        scope=scope,
     )
     return out, left
 
 
+def _ring_tail(x: jax.Array, ring: int) -> jax.Array:
+    """x [B, S, ...] by slot -> the ring [B, ring, ...] a window layer's
+    cache keeps once slots [0, S) are written: slot s at entry s mod ring,
+    the last `ring` slots where S is more."""
+    s = x.shape[1]
+    if s <= ring:
+        return jnp.pad(x, ((0, 0), (0, ring - s)) + ((0, 0),) * (x.ndim - 2))
+    return jnp.roll(x[:, s - ring:], (s - ring) % ring, axis=1)
+
+
+def ring_valid(slot: jax.Array, valid_from: jax.Array, ring: int) -> jax.Array:
+    """[B, ring] bool: the entries of a window layer's ring a decode step
+    reads once slot `slot` is written.  Entry j holds the last slot at or
+    before `slot` that is j modulo `ring`; it is live where that slot is
+    one the row has written (not before `valid_from`, not before the
+    cache's first).  Every such slot lies within `ring` <= attn_window of
+    `slot`, so the ring's live entries ARE the window."""
+    j = jnp.arange(ring, dtype=jnp.int32)
+    held = slot - (slot - j) % ring
+    return held[None, :] >= jnp.maximum(valid_from, 0)[:, None]
+
+
+def _rope(cfg: ModelConfig, positions: jax.Array):
+    """The rotary tables of one forward -> ((cos, sin) of the full layers
+    and of every plan without window layers, (cos, sin) of the window
+    layers or None without one).  YaRN (`cfg.rope_yarn_factor`) is the
+    full layers'; a window layer takes plain rope."""
+    yarn = None
+    if cfg.rope_yarn_factor:
+        yarn = (
+            cfg.rope_yarn_factor, cfg.rope_yarn_original,
+            cfg.rope_yarn_beta_fast, cfg.rope_yarn_beta_slow,
+            cfg.rope_yarn_attention_factor
+            or 0.1 * math.log(cfg.rope_yarn_factor) + 1.0,
+        )
+    dim, theta = _rope_dim(cfg), cfg.rope_theta
+    with jax.named_scope("rope/yarn" if yarn else "rope/plain"):
+        full = rope_cos_sin(positions, dim, theta, yarn)
+    if not cfg.plan.count(WINDOW):
+        return full, None
+    window_theta = cfg.window_rope_theta or theta
+    if yarn is None and window_theta == theta:
+        return full, full
+    with jax.named_scope("rope/plain"):
+        return full, rope_cos_sin(positions, dim, window_theta)
+
+
 def _packed_branches(
-    cfg: ModelConfig, segment_ids: jax.Array, *attn_args, with_state=False
+    cfg: ModelConfig, segment_ids: jax.Array, *attn_args, with_state=False,
+    window_rope=None, ring: Optional[int] = None,
 ):
     """The table of the programs over packed rows (the train stack,
     `prefill`): branch -> f(h, blk) -> (its output, what else it gives by
@@ -942,7 +1006,10 @@ def _packed_branches(
     tokens [E] int32; and what it leaves in the cache, by `KVCache` field:
     k and v, the one latent row a token and, `with_state`, a recurrent
     branch's final state and conv tail at the row's last valid token).
-    `attn_args`: `_attention`'s from `cos` on."""
+    `attn_args`: `_attention`'s from `cos` on.  `window_rope`: the window
+    layers' (cos, sin) in a plan that has them (`_rope`); `ring`: the
+    entries of their cache, where the caller keeps what they leave (the
+    ring a row's last slots fill: `_ring_tail`)."""
 
     def recurrent(forward):
         def branch(h, blk):
@@ -960,10 +1027,25 @@ def _packed_branches(
         return out, {"aux": aux, "counts": counts}
 
     def attention(h, blk):
-        return _attention(h, blk, cfg, segment_ids, *attn_args)
+        return _attention(
+            h, blk, cfg, segment_ids, *attn_args,
+            scope="full" if window_rope else None,
+        )
+
+    def window(h, blk):
+        out, left = _attention(
+            h, blk, cfg, segment_ids, *window_rope, *attn_args[2:],
+            window=cfg.attn_window, scope="window",
+        )
+        if ring is None:
+            return out, {}
+        return out, {
+            "wk": _ring_tail(left["k"], ring), "wv": _ring_tail(left["v"], ring)
+        }
 
     return {
         ATTENTION: attention,
+        WINDOW: window,
         LATENT: attention,
         GDN: recurrent(linear_attn_forward),
         SSM: recurrent(ssm_forward),
@@ -1057,7 +1139,7 @@ def _backbone(
     """-> (final-normed hidden states, summed MoE aux loss, per-layer rows
     per expert [L, E] int32 — None for dense models and under PP)."""
     x = _embed(params, cfg, tokens, positions)
-    cos, sin = rope_cos_sin(positions, _rope_dim(cfg), cfg.rope_theta)
+    (cos, sin), window_rope = _rope(cfg, positions)
 
     refusal = plan_refusal(cfg, serving=False)
     if refusal and (cp_mesh is not None or pp_mesh is not None):
@@ -1118,7 +1200,7 @@ def _backbone(
 
     x, auxes, counts = _blocks(
         params["blocks"], cfg, x, segment_ids, cos, sin, remat, use_flash,
-        cp_mesh, cp_zigzag=zz_inv is not None,
+        cp_mesh, cp_zigzag=zz_inv is not None, window_rope=window_rope,
     )
     x = _final_norm(params, cfg, x)
     if zz_inv is not None:
@@ -1193,6 +1275,22 @@ _NO_LATENT_LAYOUT = (
 )
 
 
+class WindowLayoutError(NotImplementedError):
+    """A layout or plane a mix of sliding-window and full-attention layers
+    (a ring of `attn_window` slots beside a cache of every slot, two
+    rotary tables) cannot run on yet, refused by name rather than run as
+    full attention."""
+
+
+_NO_WINDOW_LAYOUT = (
+    "sliding-window layers beside full-attention layers run under data and "
+    "fsdp sharding only: the ring attention over a split sequence has no "
+    "window, the pipeline's stage scans one kind of layer with one rotary "
+    "table, and the window layers' heads were not tested split over "
+    "`model` (PERF.md section 7)"
+)
+
+
 _NO_PATTERN_LAYOUT = (
     "a pattern of one-branch layers (Mamba-2, experts or attention alone) "
     "runs under data and fsdp sharding only: the Mamba heads, their conv "
@@ -1218,13 +1316,17 @@ def plan_refusal(cfg: ModelConfig, serving: bool):
     if plan.count(SSM) or cfg.is_pattern:
         return HybridLayoutError(
             _NO_SERVING_PATTERN if serving else _NO_PATTERN_LAYOUT)
+    if plan.count(WINDOW):
+        return WindowLayoutError(
+            _NO_SERVING_WINDOW if serving else _NO_WINDOW_LAYOUT)
     return None
 
 
 # The block leaves by the branch that owns them: a leaf is stacked over the
 # layers with that branch, in layer order (attention's and latent
 # attention's share `wo`, a dense MLP's and the experts' `wg` / `wu` /
-# `wd`: one of the two a model).  `ln1` is every layer's, `ln2` every
+# `wd`: one of the two a model; a window layer has a full layer's leaves,
+# stacked with them in layer order).  `ln1` is every layer's, `ln2` every
 # layer's with a second branch (`_owns`).
 _FULL_ATTN_LEAVES = (
     "wq", "wk", "wv", "wo", "wqg", "bq", "bk", "bv", "bo", "q_norm", "k_norm",
@@ -1237,7 +1339,8 @@ _MOE_LEAVES = (
     "ws_gate",
 )
 _LEAF_BRANCHES = {
-    **dict.fromkeys(_FULL_ATTN_LEAVES + _LATENT_LEAVES, (ATTENTION, LATENT)),
+    **dict.fromkeys(
+        _FULL_ATTN_LEAVES + _LATENT_LEAVES, (ATTENTION, WINDOW, LATENT)),
     **dict.fromkeys(LINEAR_LEAVES, (GDN,)),
     **dict.fromkeys(SSM_LEAVES, (SSM,)),
     **dict.fromkeys(_MOE_LEAVES + ("bproj", "bfc"), (MLP, MOE)),
@@ -1325,7 +1428,7 @@ def _layer_outputs(n_in_unit: int, stacked, lead=()):
 
 def _blocks(
     blocks: Params, cfg: ModelConfig, x, segment_ids, cos, sin, remat,
-    use_flash, cp_mesh=None, cp_zigzag: bool = False,
+    use_flash, cp_mesh=None, cp_zigzag: bool = False, window_rope=None,
 ):
     """The block stack of every model: the prefix's layers, then ONE
     `lax.scan` over the repeats of the plan's unit, a unit's layers
@@ -1334,7 +1437,8 @@ def _blocks(
     -> (x, aux loss per repeat, rows per expert [n_moe_layers, E])."""
     plan = cfg.plan
     branches = _packed_branches(
-        cfg, segment_ids, cos, sin, use_flash, cp_mesh, None, cp_zigzag
+        cfg, segment_ids, cos, sin, use_flash, cp_mesh, None, cp_zigzag,
+        window_rope=window_rope,
     )
     layers = {
         kind: _remat_layer(
@@ -1496,13 +1600,20 @@ class KVCache:
     - a recurrent branch: `state` in fp32 and the causal conv's last inputs
       `conv` — Gated DeltaNet [layers, B, hv, dk, dv] and [layers, B, K-1,
       C], Mamba-2 [layers, B, H, head_dim, N] and [layers, B, K-1,
-      conv_dim] (`_RECURRENT_SHAPES`)."""
+      conv_dim] (`_RECURRENT_SHAPES`);
+    - sliding-window attention: `wk` / `wv` [layers, B, ring, n_kv,
+      head_dim], ring = min(attn_window, S_max): slot s of the row lies at
+      entry s mod ring, so the ring holds the last `ring` slots written
+      and nothing older — what a window layer can still see
+      (`ring_valid`)."""
 
     k: Optional[jax.Array]
     v: Optional[jax.Array]
     state: Optional[jax.Array] = None
     conv: Optional[jax.Array] = None
     latent: Optional[jax.Array] = None
+    wk: Optional[jax.Array] = None
+    wv: Optional[jax.Array] = None
 
     @property
     def s_max(self) -> int:
@@ -1516,6 +1627,8 @@ _CACHE_FIELDS = {
     "state": (GDN, SSM),
     "conv": (GDN, SSM),
     "latent": (LATENT,),
+    "wk": (WINDOW,),
+    "wv": (WINDOW,),
 }
 jax.tree_util.register_dataclass(
     KVCache, data_fields=list(_CACHE_FIELDS), meta_fields=[]
@@ -1590,13 +1703,23 @@ def init_kv_cache(
             cache.state = jnp.zeros(
                 (plan.count(branch), batch, *state), jnp.float32)
             cache.conv = jnp.zeros((plan.count(branch), batch, *conv), dtype)
+    if plan.count(WINDOW):
+        ring = (plan.count(WINDOW), batch, min(cfg.attn_window, s_max),
+                cfg.n_kv_heads, cfg.head_dim)
+        cache.wk, cache.wv = jnp.zeros(ring, dtype), jnp.zeros(ring, dtype)
     return cache
 
 
 @jax.named_scope("layer/attn_qkv")
 def _block_kv(
-    h: jax.Array, blk: Params, cfg: ModelConfig, cos: jax.Array, sin: jax.Array
+    h: jax.Array, blk: Params, cfg: ModelConfig, cos: jax.Array,
+    sin: jax.Array, scope: Optional[str] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    with inner_scope(scope):
+        return _qkv(h, blk, cfg, cos, sin)
+
+
+def _qkv(h, blk, cfg, cos, sin):
     b, s, _ = h.shape
     q = h @ blk["wq"]
     k = h @ blk["wk"]
@@ -1717,11 +1840,13 @@ def prefill(
     vocab that is the difference between 40 MB and 10 GB."""
     positions = positions_from_segments(segment_ids)
     x = _embed(params, cfg, tokens, positions)
-    cos, sin = rope_cos_sin(positions, _rope_dim(cfg), cfg.rope_theta)
+    (cos, sin), window_rope = _rope(cfg, positions)
 
     plan = cfg.plan
     branches = _packed_branches(
-        cfg, segment_ids, cos, sin, use_flash, with_state=True
+        cfg, segment_ids, cos, sin, use_flash, with_state=True,
+        window_rope=window_rope,
+        ring=None if cache.wk is None else cache.wk.shape[2],
     )
 
     def body(y, step):
@@ -1751,8 +1876,8 @@ def prefill(
     }
 
     def place(buf, new):
-        """... in its buffer: a state is all new, a window gets the
-        prompt's entries of every layer."""
+        """... in its buffer: a state and a ring are all new, a window
+        gets the prompt's entries of every layer."""
         if buf is None or new is None:
             return buf
         if new.shape == buf.shape:
@@ -1832,6 +1957,15 @@ def decode_step(
     device on its rows (`shard_map` over the batch axes); a bool forces
     either form.
 
+    A sliding-window layer (`cfg.window_pattern`) keeps its k/v in a ring
+    of min(attn_window, S_max) slots (`KVCache.wk` / `wv`): the token's
+    entry goes to `slot` mod ring, over the slot that just left the window,
+    and attention reads the ring's live entries where they lie
+    (`ring_valid`: from `slot` and `valid_from` alone, the same for every
+    window layer of the step) with the window layers' own rotary table
+    (`_rope`); the full layers beside it keep every slot and read
+    `[valid_from, slot]` as ever.
+
     Latent attention (`cfg.is_latent`) runs its ABSORBED form here: the
     cache holds one latent row a token (`KVCache.latent`), the query is
     carried into the latent space, scores and the weighted sum are taken
@@ -1843,7 +1977,7 @@ def decode_step(
     """
     b = tokens.shape[0]
     x = _embed(params, cfg, tokens, positions)[:, None, :]  # [B,1,D]
-    cos, sin = rope_cos_sin(positions[:, None], _rope_dim(cfg), cfg.rope_theta)
+    (cos, sin), window_rope = _rope(cfg, positions[:, None])
     slot = jnp.asarray(slot, jnp.int32)
     blocks, stacked = _scan_blocks(cfg, params["blocks"], experts_in_place)
     if expert_kernel is None and stacked is not None:
@@ -1871,9 +2005,11 @@ def decode_step(
         ao = _attn_out(attn.reshape(b, 1, -1), blk, cfg, absorbed=True)
         return ao, dataclasses.replace(cache, latent=rows), None
 
+    full = "full" if window_rope else None  # the inner scope, a mixed plan
+
     def attend(h, blk, cache, li):
         """Softmax attention of one token per row through k/v layer li."""
-        q, k, v = _block_kv(h, blk, cfg, cos, sin)  # q/k/v [B,1,h,d]
+        q, k, v = _block_kv(h, blk, cfg, cos, sin, full)  # [B,1,h,d]
         # k/v [B,1,h,d] -> [1,B,1,h,d] written at (layer, :, slot).
         kc = jax.lax.dynamic_update_slice(
             cache.k, k.astype(cache.k.dtype)[None], (li, 0, slot, 0, 0)
@@ -1883,11 +2019,38 @@ def decode_step(
         )
         k_layer = jax.lax.dynamic_index_in_dim(kc, li, axis=0, keepdims=False)
         v_layer = jax.lax.dynamic_index_in_dim(vc, li, axis=0, keepdims=False)
-        attn = decode_attention(q, k_layer, v_layer, valid_from, slot + 1)
+        attn = decode_attention(
+            q, k_layer, v_layer, valid_from, slot + 1, scope=full
+        )
         ao = _attn_out(
-            attn.reshape(b, 1, cfg.q_dim), blk, cfg, _attn_gate(h, blk, cfg)
+            attn.reshape(b, 1, cfg.q_dim), blk, cfg, _attn_gate(h, blk, cfg),
+            scope=full,
         )
         return ao, dataclasses.replace(cache, k=kc, v=vc), None
+
+    if window_rope:  # the same entries of every window layer's ring
+        ring = cache.wk.shape[2]
+        live = ring_valid(slot, valid_from, ring)
+
+    def attend_window(h, blk, cache, li):
+        """Sliding-window attention of one token per row through ring li:
+        the token's k/v go to entry `slot` mod ring, over the slot that
+        left the window, and the live entries are read where they lie —
+        softmax does not ask for their order."""
+        q, k, v = _block_kv(h, blk, cfg, *window_rope, "window")
+        at = (li, 0, slot % ring, 0, 0)
+        kc = jax.lax.dynamic_update_slice(
+            cache.wk, k.astype(cache.wk.dtype)[None], at)
+        vc = jax.lax.dynamic_update_slice(
+            cache.wv, v.astype(cache.wv.dtype)[None], at)
+        attn = decode_attention(
+            q,
+            jax.lax.dynamic_index_in_dim(kc, li, axis=0, keepdims=False),
+            jax.lax.dynamic_index_in_dim(vc, li, axis=0, keepdims=False),
+            valid_from, slot + 1, valid=live, scope="window",
+        )
+        ao = _attn_out(attn.reshape(b, 1, cfg.q_dim), blk, cfg, scope="window")
+        return ao, dataclasses.replace(cache, wk=kc, wv=vc), None
 
     def recurrent(step, *kernel):
         """A recurrent branch steps layer li of the state and the conv
@@ -1911,6 +2074,7 @@ def decode_step(
     # and in the stacked expert leaves.
     branches = {
         ATTENTION: attend,
+        WINDOW: attend_window,
         LATENT: attend_latent,
         GDN: recurrent(linear_attn_step, row_kernel),
         SSM: recurrent(ssm_step),
@@ -2085,6 +2249,16 @@ _NO_SERVING_PATTERN = (
     "one-branch layers generates on the static decode program only (at "
     "most max_decode_batch requests, no stop sequences, no speculative "
     "decoding, max_new_tokens within static_path_max_new)"
+)
+
+
+_NO_SERVING_WINDOW = (
+    "the serving plane's chunk scans one kind of attention over pages of "
+    "every slot, with one rotary table: it would run the sliding-window "
+    "layers as full ones.  A mix of window and full attention layers "
+    "generates on the static decode program only (at most max_decode_batch "
+    "requests, no stop sequences, no speculative decoding, max_new_tokens "
+    "within static_path_max_new)"
 )
 
 

@@ -13,6 +13,7 @@ Two implementations:
   areal_tpu/ops/pallas/flash_attention.py), dispatched on TPU.
 """
 
+import contextlib
 import functools
 from typing import Optional
 
@@ -22,8 +23,20 @@ import jax.numpy as jnp
 NEG_INF = -2.3819763e38  # close to bf16 min, the usual TPU mask value
 
 
-def make_packed_mask(segment_ids: jax.Array, causal: bool = True) -> jax.Array:
-    """[B, S] segment ids -> [B, 1, S, S] boolean mask (True = attend)."""
+def inner_scope(scope: Optional[str]):
+    """An inner scope under a part's own: a plan that mixes window and
+    full attention layers tells them apart by `window` / `full` inside
+    `layer/attn_qkv`, `layer/attn` and `layer/attn_out`; None (every other
+    plan) adds nothing to the names."""
+    return jax.named_scope(scope) if scope else contextlib.nullcontext()
+
+
+def make_packed_mask(
+    segment_ids: jax.Array, causal: bool = True, window: Optional[int] = None
+) -> jax.Array:
+    """[B, S] segment ids -> [B, 1, S, S] boolean mask (True = attend).
+    `window` (with `causal`): a query sees the last `window` keys of its
+    sequence, itself included."""
     seg_q = segment_ids[:, :, None]
     seg_k = segment_ids[:, None, :]
     mask = (seg_q == seg_k) & (seg_q > 0)
@@ -31,6 +44,8 @@ def make_packed_mask(segment_ids: jax.Array, causal: bool = True) -> jax.Array:
         s = segment_ids.shape[-1]
         idx = jnp.arange(s)
         mask &= idx[:, None] >= idx[None, :]
+        if window is not None:
+            mask &= idx[:, None] - idx[None, :] < window
     return mask[:, None, :, :]
 
 
@@ -51,6 +66,7 @@ def packed_attention_reference(
     segment_ids: jax.Array,  # [B, S] int, 0 = pad
     causal: bool = True,
     logits_soft_cap: Optional[float] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     n_q, n_kv = q.shape[2], k.shape[2]
     k = repeat_kv(k, n_q // n_kv)
@@ -61,7 +77,7 @@ def packed_attention_reference(
     ) * scale
     if logits_soft_cap is not None:
         logits = logits_soft_cap * jnp.tanh(logits / logits_soft_cap)
-    mask = make_packed_mask(segment_ids, causal=causal)
+    mask = make_packed_mask(segment_ids, causal=causal, window=window)
     logits = jnp.where(mask, logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     # Fully-masked (padding) rows produce uniform probs; zero them out.
@@ -101,23 +117,29 @@ def decode_attention(
     valid_to: jax.Array,  # scalar/[B] int — one past the last valid slot
     k_scale: "Optional[jax.Array]" = None,  # [B, S_max, n_kv]: int8 cache
     v_scale: "Optional[jax.Array]" = None,
+    valid: "Optional[jax.Array]" = None,  # [B, S_max] bool, in the range's place
+    scope: Optional[str] = None,  # an inner name under `layer/attn`
 ) -> jax.Array:
     """Single-token GQA decode attention, HBM-lean: no repeat_kv expansion
     (query heads grouped per KV head) and no fp32 materialization of the
     cache — bf16 operands with fp32 MXU accumulation.  `[valid_from,
-    valid_to)` is the live window (right-aligned prompt layout).
+    valid_to)` is the live window (right-aligned prompt layout); a cache
+    whose live entries are no one range (a ring that has wrapped) gives
+    `valid`, the entries to read, and the range is not looked at.
     With `k_scale`/`v_scale` the caches are int8 and dequantized here.
     XLA ops on every backend: the arithmetic the paged kernel is held to.
 
     Replaces the reference's flash_attn_with_kvcache decode path
     (realhf/impl/model/modules/attn.py:251)."""
-    return _decode_attention(
-        q, k_cache, v_cache, valid_from, valid_to, k_scale, v_scale
-    )
+    with inner_scope(scope):
+        return _decode_attention(
+            q, k_cache, v_cache, valid_from, valid_to, k_scale, v_scale, valid
+        )
 
 
 def _decode_attention(
-    q, k_cache, v_cache, valid_from, valid_to, k_scale=None, v_scale=None
+    q, k_cache, v_cache, valid_from, valid_to, k_scale=None, v_scale=None,
+    valid=None,
 ):
     """`decode_attention` outside its scope: `ragged_paged_attention`'s
     XLA form runs it under its own."""
@@ -138,10 +160,11 @@ def _decode_attention(
         )
         * scale
     )  # [B, n_kv, n_rep, S] fp32
-    idx = jnp.arange(k_cache.shape[1])
-    valid = (idx[None, :] >= valid_from[:, None]) & (
-        idx[None, :] < jnp.broadcast_to(valid_to, (b,))[:, None]
-    )  # [B, S]
+    if valid is None:
+        idx = jnp.arange(k_cache.shape[1])
+        valid = (idx[None, :] >= valid_from[:, None]) & (
+            idx[None, :] < jnp.broadcast_to(valid_to, (b,))[:, None]
+        )  # [B, S]
     logits = jnp.where(valid[:, None, None, :], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     # Fully-masked rows (empty live window) softmax all-NEG_INF into a
@@ -395,10 +418,21 @@ def packed_attention(
     segment_ids: jax.Array,
     causal: bool = True,
     use_flash=None,  # None=auto | bool | Mesh (shard_map the kernel)
+    window: Optional[int] = None,
+    scope: Optional[str] = None,
 ) -> jax.Array:
     """Dispatch: Pallas flash kernel on TPU, dense reference elsewhere.
     A Mesh value runs the kernel under shard_map with the standard layout
-    (batch over data/fsdp, heads over model) — the multi-chip flash path."""
+    (batch over data/fsdp, heads over model) — the multi-chip flash path.
+    `window`: a Python int, a query sees the last `window` keys of its
+    sequence (None: all of them, the program it always was).  `scope`: an
+    inner name under `layer/attn` (a mixed plan tells its kinds apart)."""
+    with inner_scope(scope):
+        return _packed_attention(
+            q, k, v, segment_ids, causal, use_flash, window)
+
+
+def _packed_attention(q, k, v, segment_ids, causal, use_flash, window):
     from jax.sharding import Mesh
 
     if isinstance(use_flash, Mesh):
@@ -407,7 +441,7 @@ def packed_attention(
         )
 
         return flash_attention_sharded(
-            q, k, v, segment_ids, use_flash, causal=causal
+            q, k, v, segment_ids, use_flash, causal=causal, window=window
         )
     if use_flash is None:
         from areal_tpu.base.distributed import is_tpu_backend
@@ -416,5 +450,7 @@ def packed_attention(
     if use_flash:
         from areal_tpu.ops.pallas.flash_attention import flash_attention
 
-        return flash_attention(q, k, v, segment_ids, causal=causal)
-    return packed_attention_reference(q, k, v, segment_ids, causal=causal)
+        return flash_attention(q, k, v, segment_ids, causal=causal, window=window)
+    return packed_attention_reference(
+        q, k, v, segment_ids, causal=causal, window=window
+    )

@@ -118,7 +118,8 @@ class Schedule(NamedTuple):
 
 
 def live_schedule(
-    seg: jax.Array, block_q: int, block_k: int, causal: bool
+    seg: jax.Array, block_q: int, block_k: int, causal: bool,
+    window: "int | None" = None,
 ) -> Schedule:
     """seg [B, S] int32 -> the intervals of tiles that can hold an unmasked
     element.  A block's real ids span [smallest id > 0, largest id]; a tile
@@ -126,7 +127,11 @@ def live_schedule(
     is not past its last query.  With ids non-decreasing along the row that
     is exact and the live tiles of a block are contiguous; with any other
     ids the interval from the first live tile to the last still covers
-    them."""
+    them.  `window` (causal only; a trace-time constant, None = none): a
+    query sees the last `window` keys, itself included, so a tile is live
+    only if its LAST key lies within `window` of its first query — the
+    band raises a q block's `k_lo` and lowers a k block's `q_hi`, and the
+    kernels' grids are the same."""
     b, s = seg.shape
     big = jnp.iinfo(jnp.int32).max
 
@@ -144,6 +149,8 @@ def live_schedule(
     ki = jnp.arange(nk, dtype=jnp.int32)[None, :]
     if causal:
         live &= ki * block_k <= qi * block_q + block_q - 1
+    if window is not None:
+        live &= qi * block_q - (ki * block_k + block_k - 1) < window
 
     def interval(idx, n, axis):
         lo = jnp.min(jnp.where(live, idx, n), axis=axis)
@@ -192,8 +199,11 @@ def _chunk_index(n_chunks: int, tiles: int):
     return idx
 
 
-def _tile_mask(seg_q, seg_k, qi, ki, block_q, block_k, causal):
-    """seg_q [bq, 1], seg_k [1, bk] -> the tile's [bq, bk] mask."""
+def _tile_mask(seg_q, seg_k, qi, ki, block_q, block_k, causal, window=None):
+    """seg_q [bq, 1], seg_k [1, bk] -> the tile's [bq, bk] mask.  A
+    sequence's tokens are contiguous in the row, so the distance between
+    two of its positions is the distance between their places in the row:
+    `window` masks keys `window` or more places behind the query."""
     mask = (seg_q == seg_k) & (seg_q > 0)
     if causal:
         q_pos = qi * block_q + jax.lax.broadcasted_iota(
@@ -203,6 +213,8 @@ def _tile_mask(seg_q, seg_k, qi, ki, block_q, block_k, causal):
             jnp.int32, (block_q, block_k), 1
         )
         mask &= q_pos >= k_pos
+        if window is not None:
+            mask &= q_pos - k_pos < window
     return mask
 
 
@@ -252,7 +264,7 @@ def _fwd_kernel(
     o_ref, lse_ref,  # outputs
     m_scr, l_scr, acc_scr,  # scratch
     *, scale: float, block_q: int, block_k: int, hq: int, nq: int,
-    tiles: int, causal: bool,
+    tiles: int, causal: bool, window=None,
 ):
     b, qi, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
@@ -278,7 +290,8 @@ def _fwd_kernel(
             preferred_element_type=jnp.float32,
         ) * scale  # [bq, bk]
         mask = _tile_mask(
-            seg_q, seg_k_ref[0, j][0:1, :], qi, ki, block_q, block_k, causal
+            seg_q, seg_k_ref[0, j][0:1, :], qi, ki, block_q, block_k, causal,
+            window,
         )
         s = jnp.where(mask, s, NEG_INF)
 
@@ -373,7 +386,7 @@ def _q_major_specs(hq, hkv, nq, nk, d, block_q, block_k, itemsize):
 
 
 def _fwd(
-    q, k, v, seg, sched, hq, scale, block_q, block_k, causal
+    q, k, v, seg, sched, hq, scale, block_q, block_k, causal, window=None
 ) -> Tuple[jax.Array, jax.Array]:
     """q: [B*hq, S, D]; k/v: [B*hkv, S, D] (unexpanded GQA); seg: [B, S]
     int32; sched: the tiles to visit.  Returns (o [B*hq,S,D],
@@ -391,7 +404,7 @@ def _fwd(
         functools.partial(
             _fwd_kernel,
             scale=scale, block_q=block_q, block_k=block_k, hq=hq, nq=nq,
-            tiles=tiles, causal=causal,
+            tiles=tiles, causal=causal, window=window,
         ),
         (sched.k_lo, sched.k_hi),
         (seg_q, seg_kb, q, k, v),
@@ -421,7 +434,7 @@ def _dq_kernel(
     seg_q_ref, seg_k_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dq_ref,
     dq_scr,
-    *, scale, block_q, block_k, hq, nq, tiles, causal,
+    *, scale, block_q, block_k, hq, nq, tiles, causal, window=None,
 ):
     b, qi, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
@@ -444,7 +457,8 @@ def _dq_kernel(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
         mask = _tile_mask(
-            seg_q, seg_k_ref[0, j][0:1, :], qi, ki, block_q, block_k, causal
+            seg_q, seg_k_ref[0, j][0:1, :], qi, ki, block_q, block_k, causal,
+            window,
         )
         p = jnp.where(mask, jnp.exp(s - lse), 0.0)
         dp = jax.lax.dot_general(
@@ -470,7 +484,7 @@ def _dkv_kernel(
     seg_q_ref, seg_k_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dk_ref, dv_ref,
     dk_scr, dv_scr,
-    *, scale, block_q, block_k, hq, nk, tiles, causal,
+    *, scale, block_q, block_k, hq, nk, tiles, causal, window=None,
 ):
     b, ki, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
@@ -494,7 +508,7 @@ def _dkv_kernel(
         ) * scale
         mask = _tile_mask(
             seg_q_ref[0, rows, :][:, 0:1], seg_k, qi, ki, block_q, block_k,
-            causal,
+            causal, window,
         )
         p = jnp.where(mask, jnp.exp(s - lse), 0.0)  # [bq, bk]
         dv_scr[:] += jax.lax.dot_general(
@@ -520,7 +534,7 @@ def _dkv_kernel(
 
 
 def _bwd(
-    scale, block_q, block_k, causal, res, do
+    scale, block_q, block_k, causal, res, do, window=None
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     q, k, v, o, lse, seg, sched = res
     bh, s, d = q.shape
@@ -544,7 +558,7 @@ def _bwd(
         functools.partial(
             _dq_kernel,
             scale=scale, block_q=block_q, block_k=block_k, hq=hq, nq=nq,
-            tiles=tiles, causal=causal,
+            tiles=tiles, causal=causal, window=window,
         ),
         (sched.k_lo, sched.k_hi),
         (seg_q, seg_kb, q, k, v, do, lse, delta),
@@ -586,7 +600,7 @@ def _bwd(
         functools.partial(
             _dkv_kernel,
             scale=scale, block_q=block_q, block_k=block_k, hq=hq, nk=nk,
-            tiles=tiles, causal=causal,
+            tiles=tiles, causal=causal, window=window,
         ),
         (sched.q_lo, sched.q_hi),
         (seg_q, seg_k, q, k, v, do, lse, delta),
@@ -632,21 +646,29 @@ def _bwd(
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
-def _flash_bhsd(q, k, v, seg, sched, scale, block_q, block_k, causal):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _flash_bhsd(q, k, v, seg, sched, scale, block_q, block_k, causal, window):
     hq = q.shape[0] // seg.shape[0]
-    o, _ = _fwd(q, k, v, seg, sched, hq, scale, block_q, block_k, causal)
+    o, _ = _fwd(
+        q, k, v, seg, sched, hq, scale, block_q, block_k, causal, window
+    )
     return o
 
 
-def _flash_fwd_rule(q, k, v, seg, sched, scale, block_q, block_k, causal):
+def _flash_fwd_rule(
+    q, k, v, seg, sched, scale, block_q, block_k, causal, window
+):
     hq = q.shape[0] // seg.shape[0]
-    o, lse = _fwd(q, k, v, seg, sched, hq, scale, block_q, block_k, causal)
+    o, lse = _fwd(
+        q, k, v, seg, sched, hq, scale, block_q, block_k, causal, window
+    )
     return o, (q, k, v, o, lse, seg, sched)
 
 
-def _flash_bwd_rule(scale, block_q, block_k, causal, res, do):
-    return *_bwd(scale, block_q, block_k, causal, res, do), None, None
+def _flash_bwd_rule(scale, block_q, block_k, causal, window, res, do):
+    return (
+        *_bwd(scale, block_q, block_k, causal, res, do, window), None, None
+    )
 
 
 _flash_bhsd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -660,10 +682,17 @@ def flash_attention(
     causal: bool = True,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
+    window: "int | None" = None,
 ) -> jax.Array:
     """Segment-aware causal flash attention over packed rows.  GQA is
     native: kv stays at n_kv heads and the kernel's BlockSpec index maps
-    route q head h to kv head h // n_rep — no repeat_kv materialization."""
+    route q head h to kv head h // n_rep — no repeat_kv materialization.
+    `window` (a Python int, with `causal`): a query sees the last `window`
+    keys of its sequence, itself included — one more term in the tile mask
+    and a band in the schedule (`live_schedule`); None traces the program
+    it always did."""
+    if window is not None and not causal:
+        raise ValueError("a sliding window is causal")
     b, s, hq, d = q.shape
     hkv = k.shape[2]
 
@@ -682,8 +711,8 @@ def flash_attention(
     seg = segment_ids.astype(jnp.int32)
     o = _flash_bhsd(
         to_bhsd(q), to_bhsd(k), to_bhsd(v), seg,
-        live_schedule(seg, block_q, block_k, causal),
-        d**-0.5, block_q, block_k, causal,
+        live_schedule(seg, block_q, block_k, causal, window),
+        d**-0.5, block_q, block_k, causal, window,
     )
     return o.reshape(b, hq, s, d).transpose(0, 2, 1, 3)
 
@@ -695,6 +724,7 @@ def flash_attention_sharded(
     segment_ids: jax.Array,  # [B, S]
     mesh,
     causal: bool = True,
+    window: "int | None" = None,
 ) -> jax.Array:
     """The multi-chip wrapper: Pallas kernels are not GSPMD-partitionable,
     so `shard_map` pins the layout — batch over (data, fsdp), heads over
@@ -734,6 +764,6 @@ def flash_attention_sharded(
         check_vma=False,  # pallas_call outputs carry no vma metadata
     )
     def inner(ql, kl, vl, segl):
-        return flash_attention(ql, kl, vl, segl, causal=causal)
+        return flash_attention(ql, kl, vl, segl, causal=causal, window=window)
 
     return inner(q, k, v, segment_ids)

@@ -86,6 +86,38 @@ NEMO_ENTRIES = [
 ]
 
 
+MELLUM_CELL = "mellum2-coderl32-4k"
+MELLUM_CONFIG = "mellum2-12b-a2.5b-l4-e16"
+# PR 44's entries, in ISSUE 44's order: seven of the window / full mix's own
+# and the five twins the share cells have, over `benchmark/peaks_swa.py`.
+MELLUM_ENTRIES = [
+    ("swa_decode_ms", "ms", "lower", "device_trace", "model step",
+     "gen_tokens_per_s"),
+    ("swa_window_decode_ms", "ms", "lower", "device_trace", "model step",
+     "gen_tokens_per_s"),
+    ("swa_decode_roofline", "%", "higher", "device_trace", "kernels",
+     "gen_tokens_per_s"),
+    ("window_cache_share", "%", "lower", "program_counter", "generator",
+     "gen_tokens_per_s"),
+    ("flash_window_live_tile_share", "%", "lower", "program_counter",
+     "trainer", "train_tokens_per_s"),
+    ("swa_train_share", "%", "lower", "device_trace", "model step",
+     "train_tokens_per_s"),
+    ("swa_flash_mfu", "%", "higher", "device_trace", "kernels",
+     "train_tokens_per_s"),
+    ("mfu_train_swa", "%", "higher", "host_clock", "model step",
+     "train_tokens_per_s"),
+    ("mfu_gen_swa", "%", "higher", "host_clock", "model step",
+     "gen_tokens_per_s"),
+    ("decode_hbm_share_swa", "%", "higher", "device_trace", "model step",
+     "gen_tokens_per_s"),
+    ("moe_decode_mlp_roofline_swa", "%", "higher", "device_trace", "kernels",
+     "gen_tokens_per_s"),
+    ("moe_train_mlp_mfu_swa", "%", "higher", "device_trace", "kernels",
+     "train_tokens_per_s"),
+]
+
+
 def _at(entries, name):
     """Index of the entry called `name`: the benchmark is pinned by NAME,
     so that what a later PR appends moves no case."""
@@ -291,6 +323,88 @@ def test_sample_draw_ms_is_the_scopes_seconds_per_decode_step(trace, gen, want):
         gen=gen, seq_lens=[1124, 1184], prompt_lens=[100, 160])
     got = sample_draw_ms.read(run)
     assert got == (want if want is None else pytest.approx(want))
+
+
+def test_its_entry_is_the_last_and_lists_the_share_cells(monkeypatch):  # noqa: F811
+    """PR 41's case pins `moe_train_rows_gathered_share` to the three share
+    cells it had; PR 44's cell is a fourth (it trains on the slab too) and
+    is appended, last.  So: the case on the benchmark as it stood before
+    that cell — `benchmark/tests/` is not a model_config PR's to edit."""
+    from benchmark.tests import test_moe_train_rows_gathered_share as cases
+
+    entry = SPEC["per_layer"][_at(SPEC["per_layer"], cases.reader.__name__.rsplit(".", 1)[1])]
+    assert entry["workloads"] == cases.SHARE_CELLS + [MELLUM_CELL]
+    before = json.loads(json.dumps(SPEC))
+    before["workloads"] = [
+        w for w in before["workloads"] if w["name"] != MELLUM_CELL]
+    for m in before["end_to_end"] + before["per_layer"]:
+        if MELLUM_CELL in m.get("workloads", []):
+            m["workloads"].remove(MELLUM_CELL)
+    monkeypatch.setattr(files, "benchmark_json", lambda: before)
+    cases.test_its_entry_is_the_last_and_lists_the_share_cells()
+
+
+def test_the_mellum_cell_is_as_the_issue_parametrised_it():
+    """ISSUE 44: one configuration, one cell on a traffic file of its own,
+    twelve metrics of its own at the end of `per_layer`, and its name
+    appended, last, to the nine lists whose arithmetic holds for a static
+    MoE share cell — and to none that divides by `benchmark/peaks.py`."""
+    from benchmark.traffic.math_prompts import quantile_lengths
+
+    cell, config, traffic = files.load_cell(MELLUM_CELL)
+    assert SPEC["workloads"][-1] == {
+        "name": MELLUM_CELL, "config": MELLUM_CONFIG,
+        "traffic": "rollout32-ctx4k-512", "chips": 1,
+        "why": SPEC["workloads"][-1]["why"],
+    }
+    conf = SPEC["configs"][-1]
+    assert conf == {
+        "name": MELLUM_CONFIG, "source": config["benchmark"]["source"],
+        "file": f"benchmark/configs/{MELLUM_CONFIG}.json",
+        "reduced": ["num_hidden_layers", "num_experts", "vocab_size"],
+        "why": conf["why"],
+    }
+    assert len(SPEC["workloads"][-1]["why"]) <= 200 and len(conf["why"]) <= 200
+    assert len(CELLS) == 9 and len(SPEC["configs"]) == 7
+    assert [w["name"] for w in SPEC["workloads"] if w["chips"] == 4] == [
+        "q7b-realloc-4chip"]
+    assert (cell["route"], cell["timed_steps"], cell["traffic_seed"]) == (
+        "static", 4, 44)
+    assert config["model_type"] == "mellum"
+    assert config["benchmark"]["layout"] == {
+        "chips": 1, "actor_parallel": "d1", "gen_parallel": None}
+    assert traffic["prompt_len"] == {
+        "dist": "lognormal", "median": 2048, "sigma": 0.6, "lo": 768,
+        "hi": 4096}
+    assert (traffic["n_prompts"], traffic["group"], traffic["max_new_tokens"],
+            traffic["dataset_max_length"], traffic["batches"],
+            traffic["eos_reachable"], traffic["generator"]) == (
+        8, 4, 512, 4096, 1, False, "math_prompts")
+    lengths = quantile_lengths(traffic["prompt_len"], 8)
+    assert lengths == [816, 1203, 1527, 1864, 2251, 2746, 3487, 4096]
+    assert 4 * (sum(lengths) + 8 * 512) == 88344  # trained tokens a step
+    n = len(MELLUM_ENTRIES)
+    assert SPEC["per_layer"][-n:] == [
+        {"name": name, "unit": unit, "better": better, "source": source,
+         "layer": layer, "moves": moves, "workloads": [MELLUM_CELL]}
+        for name, unit, better, source, layer, moves in MELLUM_ENTRIES
+    ]
+    assert SPEC["per_layer"][-n - 1]["name"] == "moe_train_rows_gathered_share"
+    listed = {
+        m["name"] for m in SPEC["end_to_end"] + SPEC["per_layer"]
+        if MELLUM_CELL in m.get("workloads", [])
+    }
+    assert listed == {name for name, *_ in MELLUM_ENTRIES} | {
+        "gen_tokens_per_s", "decode_ms_per_step", "decode_loop_ms",
+        "moe_experts_touched", "moe_decode_mlp_ms", "moe_route_share",
+        "moe_local_rows_share", "sample_draw_ms",
+        "moe_train_rows_gathered_share",
+    }
+    for m in SPEC["end_to_end"] + SPEC["per_layer"]:
+        if MELLUM_CELL in m.get("workloads", []):
+            assert m["workloads"][-1] == MELLUM_CELL, m["name"]
+    for name in listed:
+        assert callable(files.load_module("metrics", name).read), name
 
 
 def test_every_name_in_benchmark_json_is_a_cell_and_its_files_resolve():
@@ -821,3 +935,145 @@ def test_the_ssm_readers_say_nothing_without_their_scopes_or_counters():
     assert peaks_ssm.experts_train_flops(big, 24) == pytest.approx(
         3 * 2 * 24 * 4 * (0.75 * (2 * h * f + h) + h * 128 + 2 * h * 3712),
         rel=1e-3)
+
+
+def test_cpu_rehearsal_of_the_mellum_cell_is_correct():
+    """The window / full cell end to end at toy size (the config's `toy`
+    group keeps the periods SSSF SSSF whole: a window of 16 under prompts
+    of 48-256 tokens, heads of 16, 4 of 8 experts): the static program
+    through rings and cache, the hand-back of all 15 leaves, the reference
+    and its check of the generator's own 32-slot program (rings wrapped in
+    prefill and in decode) for generator and trainer."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=files.ROOT)
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run(
+        [sys.executable, "-m", "benchmark.run", "--workload", MELLUM_CELL,
+         "--seed", "3000000013", "--seconds", "1", "--trace", "0",
+         "--cpu-rehearsal"],
+        cwd=files.ROOT, env=env, capture_output=True, text=True, timeout=900,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert proc.stdout.strip() == ""  # platform=cpu: no result line
+    lines = proc.stderr.splitlines()
+    out = json.loads(
+        [l for l in lines if "would print: " in l][-1].split("would print: ")[1])
+    assert out["correct"] is True and out["failed"] == 0
+    assert out["attempted"] in (2 * 32, 3 * 32, 4 * 32)  # whole steps of 32
+    assert {"gen_tokens_per_s", "train_tokens_per_s", "samples_per_s",
+            "setup_s"} <= set(out["metrics"])
+    check = [l for l in lines if "weight check: " in l][-1]
+    assert "'ok': True" in check and "'leaves': 15" in check, check
+    assert any("mellum reference" in l and "[0, 4) of 8" in l for l in lines)
+    assert any("mellum generator check" in l and l.endswith(" ok")
+               for l in lines)
+
+
+def _mellum_readers():
+    from benchmark.metrics import (
+        decode_hbm_share_swa, flash_window_live_tile_share, mfu_gen_swa,
+        mfu_train_swa, moe_decode_mlp_roofline_swa, moe_train_mlp_mfu_swa,
+        swa_decode_ms, swa_decode_roofline, swa_flash_mfu, swa_train_share,
+        swa_window_decode_ms, window_cache_share,
+    )
+    return locals()
+
+
+def test_the_swa_readers_say_nothing_without_their_scopes_or_counters():
+    """On a program that lacks what PR 44 added (the parent, or any other
+    configuration) every new reader returns None and does not raise; on a
+    window / full mix each reads its scopes and counters."""
+    from areal_tpu.models.config import tiny_config
+    from benchmark import peaks_swa
+    from benchmark.run import model_config
+
+    r = _mellum_readers()
+    assert sorted(r) == sorted(name for name, *_ in MELLUM_ENTRIES)
+    phase = lambda fwd=0.0, recompute=0.0, bwd=0.0: {  # noqa: E731
+        "fwd": fwd, "recompute": recompute, "bwd": bwd}
+    scopes = {
+        "train/grad/layer/mlp": phase(1.0, 0.0, 1.0),
+        "train/grad/layer/attn/flash_fwd": phase(0.25, 0.25),
+        "train/grad/layer/attn/flash_dq": phase(bwd=0.5),
+        "train/grad/layer/attn/flash_dkv": phase(bwd=0.5),
+        "gen/decode_step/layer/mlp": phase(0.008),
+        "gen/decode_step/layer/attn": phase(0.004),
+    }
+    pack = {"flash_live_tiles": 120, "flash_grid_tiles": 480,
+            "real_tokens": 12}
+    bare = _glm_run(tiny_config(), {}, scopes)
+    bare.steps[0]["pack"] = pack
+    for name, reader in r.items():
+        assert reader.read(bare) is None, name
+    # Another share cell's counters are not this family's either.
+    other = _glm_run(
+        model_config(files.load_json("configs", f"{NEMO_CONFIG}.json")),
+        {"state_cache_bytes": 9.0, "kv_cache_bytes": 1.0,
+         "moe_experts_touched": 15.0, "moe_rows_local": 8.0,
+         "moe_decode_steps": 8}, scopes)
+    other.steps[0]["pack"] = pack
+    for name, reader in r.items():
+        assert reader.read(other) is None, name
+    big = model_config(files.load_json("configs", f"{MELLUM_CONFIG}.json"))
+    slot = peaks_swa.kv_token_bytes(big)
+    assert slot == 2048
+    pool = {"window_cache_bytes": 3 * 2 * 1024 * slot,
+            "kv_cache_bytes": 1 * 2 * 4608 * slot,
+            "kv_cache_bytes_unwindowed": 4 * 2 * 4608 * slot,
+            "window_slots": 2 * 1024, "window_slots_live": 1500.0,
+            "moe_experts_touched": 12.5, "moe_rows_local": 8 * 4 * 3.5,
+            "moe_decode_steps": 8}
+    scopes.update({
+        "gen/decode_step/layer/attn_qkv/window": phase(0.006),
+        "gen/decode_step/layer/attn/window": phase(0.009),
+        "gen/decode_step/layer/attn_out/window": phase(0.003),
+        "gen/decode_step/layer/attn_qkv/full": phase(0.002),
+        "gen/decode_step/layer/attn/full": phase(0.011),
+        "gen/decode_step/layer/attn_out/full": phase(0.001),
+        "train/grad/layer/attn_qkv/window": phase(0.25, 0.25, 0.5),
+        "train/grad/layer/attn/window/flash_fwd": phase(0.125, 0.125),
+        "train/grad/layer/attn/window/flash_dq": phase(bwd=0.25),
+        "train/grad/layer/attn/full/flash_dkv": phase(bwd=0.5),
+    })
+    del scopes["gen/decode_step/layer/attn"]
+    for k in [k for k in scopes if k.startswith("train/grad/layer/attn/flash")]:
+        del scopes[k]
+    run = _glm_run(big, pool, scopes)
+    run.steps[0]["pack"] = dict(pack, flash_live_tiles_window=60)
+    assert r["window_cache_share"].read(run) == pytest.approx(
+        100 * (3 * 1024 + 4608) / (4 * 4608))
+    assert r["window_cache_share"].read(run) == pytest.approx(41.667, abs=1e-3)
+    assert r["flash_window_live_tile_share"].read(run) == 12.5
+    assert r["swa_decode_ms"].read(run) == pytest.approx(4.0)  # 32 ms / 8
+    assert r["swa_window_decode_ms"].read(run) == pytest.approx(18.0 / 8)
+    assert r["swa_train_share"].read(run) == pytest.approx(100 * 2.0 / 4.0)
+    assert r["swa_decode_roofline"].read(run) == pytest.approx(
+        100 * 1e3 * peaks_swa.attn_decode_bytes(big, 1500.0, 2 * 4608, 2)
+        / 819e9 / 4.0)
+    # 24 trained tokens against the last minibatch's 12: tiles x 2; the
+    # forward kernel ran in two phases.
+    assert r["swa_flash_mfu"].read(run) == pytest.approx(
+        100 * 2 * peaks_swa.flash_tile_flops(big, 120, 60, 2) / 1.0 / 197e12)
+    assert peaks_swa.flash_tile_flops(big, 120, 60, 2) == (
+        22 * 128 ** 3 * 32 * (1 * 120 + 3 * 60))
+    assert r["mfu_train_swa"].read(run) == pytest.approx(
+        100 * peaks_swa.flops_train(big, [12, 12]) / 197e12)
+    assert r["mfu_gen_swa"].read(run) == pytest.approx(
+        100 * peaks_swa.flops_generate(big, [4, 4], [8, 8]) / 197e12)
+    loop_ms, mlp_ms = 16.0 / 8, 8.0 / 8
+    assert r["decode_hbm_share_swa"].read(run) == pytest.approx(
+        100 * 1e3 * peaks_swa.decode_bytes(big, [8.0, 8.0], 12.5, 3.5)
+        / 819e9 / loop_ms)
+    assert r["moe_decode_mlp_roofline_swa"].read(run) == pytest.approx(
+        100 * 1e3 * peaks_swa.experts_decode_bytes(big, 2, 12.5, 3.5)
+        / 819e9 / mlp_ms)
+    assert r["moe_train_mlp_mfu_swa"].read(run) == pytest.approx(
+        100 * peaks_swa.experts_train_flops(big, 24) / 2.0 / 197e12)
+    # A 4,096-token sequence: 1,024 x 4,096 - 1,024 x 1,023 / 2 pairs in a
+    # window layer, half the square in a full one.
+    assert peaks_swa.window_pairs(4096, 1024) == 1024 * 4096 - 1024 * 1023 / 2
+    assert peaks_swa.window_pairs(500, 1024) == 500 * 501 / 2
+    h, f = big.hidden_dim, big.moe_intermediate_dim
+    assert peaks_swa.flops_forward(big, [4096]) == pytest.approx(
+        2 * 4096 * (4 * (21_233_664 + 2 * 3 * h * f + h * 64) + h * 24576)
+        + 4 * 32 * 128 * (1 * 4096 ** 2 / 2
+                          + 3 * peaks_swa.window_pairs(4096, 1024)))

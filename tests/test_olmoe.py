@@ -266,7 +266,7 @@ def test_qk_norm_on_and_off_differ_and_each_matches_its_oracle(cfg, params):
 
 def _per_head_qk_norm(params, cfg):
     """The wrong reading: the norm's mean taken over each head's values."""
-    def kv(h, blk, c, cos, sin):
+    def kv(h, blk, c, cos, sin, scope=None):
         b, s, _ = h.shape
 
         def norm(x, w, heads):
