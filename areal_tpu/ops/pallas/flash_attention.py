@@ -5,7 +5,8 @@ dependency (realhf/impl/model/modules/attn.py:24) with a TPU kernel built
 for the [B, S] packed-row layout (segment_ids delimit sequences; attention
 is causal-within-segment).
 
-Design (flash attention v2 tiling at 128 x 128, adapted to Mosaic/TPU):
+Design (flash attention v2; a schedule of 128 x 128 blocks, walked in
+trips of up to 512 keys):
 - A packed row concatenates unrelated sequences with non-decreasing ids and
   attention is causal within a sequence, so of the row's (q block, k block)
   square only a band along the diagonal can hold an unmasked element: for a
@@ -19,26 +20,60 @@ Design (flash attention v2 tiling at 128 x 128, adapted to Mosaic/TPU):
   never a static argument: a new batch compiles nothing.
 - The kernels get the tables by scalar prefetch and spend grid steps and
   K/V (Q/dO) fetches on live tiles only.  forward and dq: grid
-  (B*H, nq, chunks), one step per q block, with the row's K/V resident in
-  VMEM and an in-kernel loop `k_lo..k_hi` over its 128-row tiles; dkv:
-  grid (B*H, nk, chunks), one step per k block, with the row's Q, dO,
-  logsumexp and delta resident and a loop `q_lo..q_hi`.  A dead tile costs
-  no step, no fetch and no arithmetic; a tile inside an interval that is
-  masked all the same (ids that are not monotonic) is an exact no-op, so
-  the tables decide speed, never results.
+  (B*H, nq, chunks), one step per q block of 128, with the row's K/V
+  resident in VMEM and an in-kernel loop over its live keys; dkv: grid
+  (B*H, nk, chunks), one step per k block of 128, with the row's Q, dO,
+  logsumexp and delta resident and a loop over its live queries.  A dead
+  tile costs no step, no fetch and no arithmetic; a tile inside an
+  interval that is masked all the same (ids that are not monotonic) is an
+  exact no-op, so the tables decide speed, never results.
+- The loop walks a TRIP of several schedule blocks at a time (`_widen`):
+  512 keys (queries, in dkv), and one block of 128 in the forward kernel
+  at head_dim 256 (`_trip_blocks`: a function of the shapes alone, cut to
+  a divisor of the row's blocks).  What a tile costs on a v5e is not its products but the
+  vector work around them — the mask, the exponentials, and per trip the
+  softmax's column operations (a [128, 1] fp32 column is 16 vregs with
+  one lane in use, as dear as a whole 128 x 128 operation), the lane
+  broadcasts and the accumulator's rescale — so a trip four blocks wide
+  pays the per-trip part once for four (forward 0.48 -> 0.18-0.25 us a
+  128 x 128 tile, dq 0.33 -> 0.14-0.21: PERF.md section 6, PR 46).  The
+  mask inside a trip is exact and a trip with no live block is the same
+  exact no-op, so the width moves the order of the sums and nothing else.
+  Wider (1,024) measured slower, and so did a narrower trip under a
+  `window` of 1,024 or over rows of many short sequences.
+- dkv holds every tile TRANSPOSED, [keys, queries]: S^T = K Q^T, dP^T =
+  V dO^T, dV += P^T dO, dK += dS^T Q are then plain products with no
+  operand to turn, and the q ids, logsumexp and delta lie along the lanes,
+  a trip to a row ([1 or 8, 512]: 32 bytes a token each in VMEM where a
+  [512, 1] column took 512 and 64 vregs a load).  0.46 -> 0.16-0.26 us a
+  tile, where the q-major form gained nothing from a wider trip.
 - `chunks` is 1 for every row whose resident operands fit `RESIDENT_BYTES`
-  of VMEM: in bf16 10,240 tokens for dkv at head_dim 128 and 8,192 at 256
-  (its three column operands pad to 512 bytes a token), about 38,000 and
-  19,000 for forward and dq, which hold K/V alone.  A longer row is cut into equal
-  chunks of whole blocks (the largest divisor of its block count that
-  fits): the accumulators persist across
-  the chunk steps, each step loops over the part of the interval inside its
-  chunk, and the chunk index is clamped to the interval's chunks, so a step
-  with nothing to do re-uses the resident block and fetches nothing.
+  of VMEM: in bf16 about 38,000 tokens at head_dim 128 and 19,000 at 256
+  for forward and dq, which hold K/V and the k ids, 34,000 and 18,000 for
+  dkv (Q, dO and three rows).  A longer row is cut into equal chunks of
+  whole trips (the largest divisor of its trip count that fits): the
+  accumulators persist across the chunk steps, each step loops over the
+  part of the interval inside its chunk, and the chunk index is clamped to
+  the interval's chunks, so a step with nothing to do re-uses the resident
+  block and fetches nothing.
 - Online-softmax accumulators (m, l, acc) live in VMEM scratch; output and
   logsumexp are written on the last chunk step.  The backward kernels
   recompute the probability tiles from the saved logsumexp instead of
   materializing [S, S] (O(S) memory).  GQA stays in the index maps.
+
+Precision.  q, k, v and dO go to the MXU in the type they arrive in, and
+every product accumulates in fp32 (`preferred_element_type`); P and dS,
+fp32 inside the kernel, are rounded to that type right before the product
+that consumes them (PV, P^T dO, dS K, dS^T Q), as the generator's two
+attention kernels do on every decode step and serving chunk
+(`paged_attention._ragged_paged_kernel`,
+`latent_attention._latent_decode_kernel`).  The scores, the
+mask, `exp`, the softmax statistics (m, l, alpha, logsumexp), dP - delta
+and the four accumulators stay fp32.  With bf16 inputs this is what the
+kernels always computed on the chip: Mosaic runs an fp32 x fp32 product
+at one bf16 pass, so the casts that used to stand in front of every
+product bought no digit (results bit-equal with and without them, PR 46).
+With fp32 inputs (the CPU tests) nothing is rounded.
 
 Interpret mode (CPU) is used automatically off-TPU, which is how the unit
 tests exercise the same kernel code path hermetically.
@@ -55,13 +90,16 @@ NEG_INF = -1e30
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
+# Keys (forward, dq) or queries (dkv) one trip of a kernel's inner loop
+# walks at head_dim 128 (`_trip_blocks`).
+TRIP_ROWS = 512
 
 # VMEM one kernel may spend on the operands its inner loop walks (one
 # buffer of each; Pallas double-buffers them).  A row that needs more is
 # cut into chunks.
 RESIDENT_BYTES = 20 << 20
-# A [rows, 1] fp32 or [rows, 8] int32 operand pads to 128 lanes in VMEM.
-_COLUMN_BYTES = 512
+# A [1, rows] fp32 or [8, rows] int32 operand: 8 sublanes of 4 bytes a lane.
+_ROW_BYTES = 32
 
 
 def _interpret() -> bool:
@@ -168,13 +206,41 @@ def all_tiles_schedule(rows: int, nq: int, nk: int) -> Schedule:
     return Schedule(zq, zq + nk - 1, zk, zk + nq - 1)
 
 
+def _largest_divisor(n: int, limit: int) -> int:
+    """The largest divisor of n within `limit` (at least 1)."""
+    return max(t for t in range(1, max(min(limit, n), 1) + 1) if n % t == 0)
+
+
 def _resident_blocks(n_blocks: int, block: int, token_bytes: int) -> int:
     """Blocks of the inner loop's operands one grid step holds in VMEM: the
     largest divisor of `n_blocks` within RESIDENT_BYTES (chunks are equal,
     so no step reads past the row)."""
-    fit = max(RESIDENT_BYTES // (block * token_bytes), 1)
-    return max(t for t in range(1, n_blocks + 1)
-               if n_blocks % t == 0 and t <= fit)
+    return _largest_divisor(
+        n_blocks, RESIDENT_BYTES // (block * token_bytes)
+    )
+
+
+def _trip_blocks(n_blocks: int, block: int, head_dim: int,
+                 backward: bool) -> int:
+    """Schedule blocks one trip of a kernel's inner loop walks, from what
+    the call can see: `TRIP_ROWS` keys (queries, in dkv), but ONE block in
+    the forward kernel past head_dim 128 — there the forward keeps the
+    order of its sums, and with it the bits of every log-prob the trainer
+    and prefill compute, as they were before trips (PERF.md section 6,
+    PR 46: what the wider orders did to GLM's reference check); then the
+    largest divisor of the row's `n_blocks` within that, so trips are
+    whole."""
+    rows = TRIP_ROWS if backward or head_dim <= 128 else block
+    return _largest_divisor(n_blocks, rows // block)
+
+
+def _widen(lo, hi, n_blocks, block, head_dim, backward, unit):
+    """A schedule's intervals and block in the inner loop's trips: the
+    trips that hold a live block (an empty (0, -1) stays empty), and the
+    scope that tells a trace how wide a trip the call took (`unit`: "keys"
+    or "queries")."""
+    r = _trip_blocks(n_blocks, block, head_dim, backward)
+    return lo // r, hi // r, block * r, f"{unit}{block * r}"
 
 
 def _live_in_chunk(lo_ref, hi_ref, row, c, tiles):
@@ -199,18 +265,22 @@ def _chunk_index(n_chunks: int, tiles: int):
     return idx
 
 
-def _tile_mask(seg_q, seg_k, qi, ki, block_q, block_k, causal, window=None):
-    """seg_q [bq, 1], seg_k [1, bk] -> the tile's [bq, bk] mask.  A
-    sequence's tokens are contiguous in the row, so the distance between
-    two of its positions is the distance between their places in the row:
-    `window` masks keys `window` or more places behind the query."""
+def _tile_mask(seg_q, seg_k, qi, ki, block_q, block_k, causal, window=None,
+               k_major=False):
+    """seg_q [bq, 1], seg_k [1, bk] -> the tile's [bq, bk] mask; `k_major`:
+    seg_q [1, bq], seg_k [bk, 1] -> the same mask transposed, [bk, bq], for
+    the kernel that walks k blocks (dkv).  A sequence's tokens are
+    contiguous in the row, so the distance between two of its positions is
+    the distance between their places in the row: `window` masks keys
+    `window` or more places behind the query."""
     mask = (seg_q == seg_k) & (seg_q > 0)
     if causal:
+        shape = (block_k, block_q) if k_major else (block_q, block_k)
         q_pos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
+            jnp.int32, shape, 1 if k_major else 0
         )
         k_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
+            jnp.int32, shape, 0 if k_major else 1
         )
         mask &= q_pos >= k_pos
         if window is not None:
@@ -228,14 +298,15 @@ def _vmem(shape, dtype):
     return pltpu.VMEM(shape, dtype)
 
 
-def _call(name, kernel, sched_refs, args, *, grid, in_specs, out_specs,
+def _call(name, trip, kernel, sched_refs, args, *, grid, in_specs, out_specs,
           out_shape, scratch_shapes, resident_bytes):
     """The kernels' common `pallas_call`: two schedule tables by scalar
-    prefetch, and a VMEM limit that holds the double-buffered resident
-    operands beside Mosaic's default 16 MiB for everything else."""
+    prefetch, a VMEM limit that holds the double-buffered resident
+    operands beside Mosaic's default 16 MiB for everything else, and
+    around the kernel's own scope the one that names its trip (`_widen`)."""
     from jax.experimental.pallas import tpu as pltpu
 
-    return named_call(
+    call = named_call(
         name,
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -250,7 +321,9 @@ def _call(name, kernel, sched_refs, args, *, grid, in_specs, out_specs,
             vmem_limit_bytes=2 * resident_bytes + (16 << 20)
         ),
         interpret=_interpret(),
-    )(*sched_refs, *args)
+    )
+    with jax.named_scope(trip):
+        return call(*sched_refs, *args)
 
 
 # ---------------------------------------------------------------------------
@@ -274,7 +347,7 @@ def _fwd_kernel(
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0].astype(jnp.float32)  # [bq, d]
+    q = q_ref[0]  # [bq, d], in the inputs' type
     # Segment ids arrive sublane/lane-broadcast (Mosaic needs >=2D tiles
     # with aligned minor dims): q ids [bq, 8] -> [bq, 1], k ids
     # [8, bk] -> [1, bk].
@@ -283,12 +356,12 @@ def _fwd_kernel(
     def tile(ki, _):
         j = ki - c * tiles  # the tile's place in the resident chunk
         rows = _tile_rows(j, block_k)
-        k = k_ref[0, rows, :].astype(jnp.float32)  # [bk, d]
-        v = v_ref[0, rows, :].astype(jnp.float32)  # [bk, d]
+        k = k_ref[0, rows, :]  # [bk, d]
+        v = v_ref[0, rows, :]  # [bk, d]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) * scale  # [bq, bk]
+        ) * scale  # [bq, bk] fp32
         mask = _tile_mask(
             seg_q, seg_k_ref[0, j][0:1, :], qi, ki, block_q, block_k, causal,
             window,
@@ -303,7 +376,7 @@ def _fwd_kernel(
         alpha = jnp.exp(m_prev - m_new)  # [bq, 1]
         l_new = alpha * l_scr[:] + jnp.sum(p, axis=1, keepdims=True)
         acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         m_scr[:] = m_new
@@ -322,27 +395,24 @@ def _fwd_kernel(
         lse_ref[0] = jnp.where(l > 0, m_scr[:] + jnp.log(safe_l), NEG_INF)
 
 
-def _seg_layouts(
-    seg: jax.Array, block_k: int
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """[B, S] int32 -> (q ids [B, S, 8], k ids [B, 8, S], k ids by block
-    [B, nk, 8, bk]).
+def _seg_layouts(seg: jax.Array, block: int) -> Tuple[jax.Array, jax.Array]:
+    """[B, S] int32 -> (ids as a column [B, S, 8], ids along the lanes by
+    block [B, S / block, 8, block]).
 
     Mosaic requires >=2D tiles whose minor dims are 8/128-aligned or span the
     array; broadcasting ids over 8 sublanes/lanes (the official TPU flash
     kernel's trick) satisfies that at 8x int32 cost.  The blocked form is
-    what a kernel holds resident and indexes by tile (a dynamic index on a
+    what a kernel holds resident and indexes by trip (a dynamic index on a
     leading dim, where a dynamic lane offset would not lower).  Ids are
     per-BATCH (not per-head): the BlockSpec index maps divide the b*h grid
     index by the head count, so no H-fold copy is materialized.
     """
     b, s = seg.shape
-    seg_q = jnp.broadcast_to(seg[:, :, None], (b, s, 8))
-    seg_k = jnp.broadcast_to(seg[:, None, :], (b, 8, s))
-    seg_kb = jnp.broadcast_to(
-        seg.reshape(b, s // block_k, 1, block_k), (b, s // block_k, 8, block_k)
+    column = jnp.broadcast_to(seg[:, :, None], (b, s, 8))
+    rows = jnp.broadcast_to(
+        seg.reshape(b, s // block, 1, block), (b, s // block, 8, block)
     )
-    return seg_q, seg_k, seg_kb
+    return column, rows
 
 
 def _kv_row(hq: int, hkv: int):
@@ -394,19 +464,24 @@ def _fwd(
     bh, s, d = q.shape
     hkv = k.shape[0] // seg.shape[0]
     nq = pl.cdiv(s, block_q)
+    k_lo, k_hi, block_k, trip = _widen(
+        sched.k_lo, sched.k_hi, pl.cdiv(s, block_k), block_k, d, False,
+        "keys",
+    )
     nk = pl.cdiv(s, block_k)
     tiles, resident, q_side, seg_q_spec, seg_kb_spec, kv_spec = (
         _q_major_specs(hq, hkv, nq, nk, d, block_q, block_k, k.dtype.itemsize)
     )
-    seg_q, _, seg_kb = _seg_layouts(seg, block_k)
+    seg_q, seg_kb = _seg_layouts(seg, block_k)
     return _call(
         "flash_fwd",
+        trip,
         functools.partial(
             _fwd_kernel,
             scale=scale, block_q=block_q, block_k=block_k, hq=hq, nq=nq,
             tiles=tiles, causal=causal, window=window,
         ),
-        (sched.k_lo, sched.k_hi),
+        (k_lo, k_hi),
         (seg_q, seg_kb, q, k, v),
         grid=(bh, nq, nk // tiles),
         in_specs=[seg_q_spec, seg_kb_spec, q_side(d), kv_spec, kv_spec],
@@ -442,8 +517,8 @@ def _dq_kernel(
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    q = q_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
+    q = q_ref[0]
+    do = do_ref[0]
     lse = lse_ref[0]  # [bq, 1]
     delta = delta_ref[0]  # [bq, 1]
     seg_q = seg_q_ref[0][:, 0:1]
@@ -451,8 +526,8 @@ def _dq_kernel(
     def tile(ki, _):
         j = ki - c * tiles
         rows = _tile_rows(j, block_k)
-        k = k_ref[0, rows, :].astype(jnp.float32)
-        v = v_ref[0, rows, :].astype(jnp.float32)
+        k = k_ref[0, rows, :]
+        v = v_ref[0, rows, :]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
@@ -466,7 +541,8 @@ def _dq_kernel(
         )
         ds = p * (dp - delta) * scale
         dq_scr[:] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
 
     jax.lax.fori_loop(
@@ -493,33 +569,36 @@ def _dkv_kernel(
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    seg_k = seg_k_ref[0][0:1, :]
+    k = k_ref[0]
+    v = v_ref[0]
+    seg_k = seg_k_ref[0][:, 0:1]  # [bk, 1]
 
     def tile(qi, _):
-        rows = _tile_rows(qi - c * tiles, block_q)
-        q = q_ref[0, rows, :].astype(jnp.float32)
-        do = do_ref[0, rows, :].astype(jnp.float32)
-        lse = lse_ref[0, rows, :]  # [bq, 1]
-        delta = delta_ref[0, rows, :]  # [bq, 1]
+        j = qi - c * tiles
+        rows = _tile_rows(j, block_q)
+        q = q_ref[0, rows, :]
+        do = do_ref[0, rows, :]
+        lse = lse_ref[0, j]  # [1, bq]
+        delta = delta_ref[0, j]  # [1, bq]
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
+            k, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale  # [bk, bq]: the scores' transpose, as every tile below
         mask = _tile_mask(
-            seg_q_ref[0, rows, :][:, 0:1], seg_k, qi, ki, block_q, block_k,
-            causal, window,
+            seg_q_ref[0, j][0:1, :], seg_k, qi, ki, block_q, block_k, causal,
+            window, k_major=True,
         )
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)  # [bq, bk]
+        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
         dv_scr[:] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            p.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
         dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            v, do, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
         ds = p * (dp - delta) * scale
         dk_scr[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            ds.astype(q.dtype), q, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
 
     jax.lax.fori_loop(
@@ -533,34 +612,31 @@ def _dkv_kernel(
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _bwd(
-    scale, block_q, block_k, causal, res, do, window=None
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    q, k, v, o, lse, seg, sched = res
+def _dq(q, k, v, do, lse, delta, seg, sched, hq, scale, block_q, block_k,
+        causal, window=None) -> jax.Array:
+    """dq [B*hq, S, D] in q's type: one grid step a q block, the loop over
+    its live keys a trip at a time (`_widen`)."""
     bh, s, d = q.shape
-    b = seg.shape[0]
-    hq = bh // b
-    hkv = k.shape[0] // b
-    n_rep = hq // hkv
+    hkv = k.shape[0] // seg.shape[0]
     nq = pl.cdiv(s, block_q)
+    k_lo, k_hi, block_k, trip = _widen(
+        sched.k_lo, sched.k_hi, pl.cdiv(s, block_k), block_k, d, True,
+        "keys",
+    )
     nk = pl.cdiv(s, block_k)
-    delta = jnp.sum(
-        o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1, keepdims=True
-    )  # [BH, S, 1]
-
-    seg_q, seg_k, seg_kb = _seg_layouts(seg, block_k)
-
     tiles, resident, q_side, seg_q_spec, seg_kb_spec, kv_spec = (
         _q_major_specs(hq, hkv, nq, nk, d, block_q, block_k, k.dtype.itemsize)
     )
-    dq = _call(
+    seg_q, seg_kb = _seg_layouts(seg, block_k)
+    return _call(
         "flash_dq",
+        trip,
         functools.partial(
             _dq_kernel,
             scale=scale, block_q=block_q, block_k=block_k, hq=hq, nq=nq,
             tiles=tiles, causal=causal, window=window,
         ),
-        (sched.k_lo, sched.k_hi),
+        (k_lo, k_hi),
         (seg_q, seg_kb, q, k, v, do, lse, delta),
         grid=(bh, nq, nk // tiles),
         in_specs=[
@@ -573,9 +649,27 @@ def _bwd(
         resident_bytes=resident,
     )
 
-    # dkv walks k blocks: K/V and the k ids by step, the q side resident
-    # (Q and dO, and three [rows, 1 or 8] columns that pad to 128 lanes).
-    token_bytes = 2 * d * q.dtype.itemsize + 3 * _COLUMN_BYTES
+
+def _dkv(q, k, v, do, lse, delta, seg, sched, hq, scale, block_q, block_k,
+         causal, window=None) -> Tuple[jax.Array, jax.Array]:
+    """dk, dv [B*hq, S, D] fp32, per Q-HEAD (the grid walks q heads; the
+    caller sums the heads that share a kv head): one grid step a k block,
+    K/V and the k ids by step, the q side resident, the loop over its live
+    queries a trip at a time."""
+    bh, s, d = q.shape
+    hkv = k.shape[0] // seg.shape[0]
+    nk = pl.cdiv(s, block_k)
+    q_lo, q_hi, block_q, trip = _widen(
+        sched.q_lo, sched.q_hi, pl.cdiv(s, block_q), block_q, d, True,
+        "queries",
+    )
+    nq = pl.cdiv(s, block_q)
+    # The kernel holds the scores' TRANSPOSE, [keys, queries]: the k ids a
+    # column, and along the lanes, a trip to a row, the q ids, lse and
+    # delta — 32 bytes a token each in VMEM where a column takes 512.
+    seg_k, seg_qb = _seg_layouts(seg, block_q)
+    lse, delta = (x.reshape(bh, nq, 1, block_q) for x in (lse, delta))
+    token_bytes = 2 * d * q.dtype.itemsize + 3 * _ROW_BYTES
     tiles = _resident_blocks(nq, block_q, token_bytes)
     kv_row = _kv_row(hq, hkv)
     chunk = _chunk_index(nq // tiles, tiles)
@@ -585,37 +679,42 @@ def _bwd(
             (1, block_k, width), lambda b, ki, c, lo, hi: (rows(b), ki, 0)
         )
 
-    def q_resident(width, rows=lambda b: b):
+    def q_resident(rows=lambda b: b):
         return pl.BlockSpec(
-            (1, tiles * block_q, width),
+            (1, tiles * block_q, d),
             lambda b, ki, c, lo, hi: (
                 rows(b), chunk(lo, hi, (b // hq) * nk + ki, c), 0
             ),
         )
 
-    # dk/dv come out per Q-HEAD (the grid walks q heads); the n_rep grads
-    # sharing one kv head are group-summed after the kernel.
-    dk_x, dv_x = _call(
+    def q_rows(sublanes, rows=lambda b: b):
+        return pl.BlockSpec(
+            (1, tiles, sublanes, block_q),
+            lambda b, ki, c, lo, hi: (
+                rows(b), chunk(lo, hi, (b // hq) * nk + ki, c), 0, 0
+            ),
+        )
+
+    return _call(
         "flash_dkv",
+        trip,
         functools.partial(
             _dkv_kernel,
             scale=scale, block_q=block_q, block_k=block_k, hq=hq, nk=nk,
             tiles=tiles, causal=causal, window=window,
         ),
-        (sched.q_lo, sched.q_hi),
-        (seg_q, seg_k, q, k, v, do, lse, delta),
+        (q_lo, q_hi),
+        (seg_qb, seg_k, q, k, v, do, lse, delta),
         grid=(bh, nk, nq // tiles),
         in_specs=[
-            q_resident(8, lambda b: b // hq),
-            pl.BlockSpec(
-                (1, 8, block_k), lambda b, ki, c, lo, hi: (b // hq, 0, ki)
-            ),
-            q_resident(d),
+            q_rows(8, lambda b: b // hq),
+            k_side(lambda b: b // hq, 8),
+            q_resident(),
             k_side(kv_row),
             k_side(kv_row),
-            q_resident(d),
-            q_resident(1),
-            q_resident(1),
+            q_resident(),
+            q_rows(1),
+            q_rows(1),
         ],
         out_specs=[k_side(lambda b: b), k_side(lambda b: b)],
         out_shape=[
@@ -629,16 +728,31 @@ def _bwd(
         resident_bytes=tiles * block_q * token_bytes,
     )
 
+
+def _bwd(
+    scale, block_q, block_k, causal, res, do, window=None
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    q, k, v, o, lse, seg, sched = res
+    bh, s, d = q.shape
+    b = seg.shape[0]
+    hq = bh // b
+    hkv = k.shape[0] // b
+    delta = jnp.sum(
+        o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1, keepdims=True
+    )  # [BH, S, 1]
+    args = (q, k, v, do, lse, delta, seg, sched, hq, scale, block_q, block_k,
+            causal, window)
+    dq = _dq(*args)
+    dk_x, dv_x = _dkv(*args)
+
     def group_sum(g):
         return (
-            g.reshape(b, hkv, n_rep, s, d)
+            g.reshape(b, hkv, hq // hkv, s, d)
             .sum(axis=2)
             .reshape(b * hkv, s, d)
         )
 
-    dk = group_sum(dk_x).astype(k.dtype)
-    dv = group_sum(dv_x).astype(v.dtype)
-    return dq, dk, dv
+    return dq, group_sum(dk_x).astype(k.dtype), group_sum(dv_x).astype(v.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -147,8 +147,8 @@ FLASH_CELLS = {
     "prefill [192,2560,128]": (16, 2560, 12, 2, 128, [2534]),
     "qwen3-next train row [16,8192,256]": (1, 8192, 16, 2, 256, [640] * 12),
     "7B per-chip rows [28,2048,128]": (1, 2048, 28, 4, 128, [400] * 5),
-    "a row in two chunks [12,16384,128]":
-        (1, 16384, 12, 2, 128, [9000, 4000, 3000]),
+    "a row in two chunks [12,65536,128]":
+        (1, 65536, 12, 2, 128, [30000, 20000, 9000, 4000]),
 }
 
 

@@ -5,6 +5,8 @@ Models the reference's CUDA-extension parity tests
 kernel code in pallas interpret mode on CPU.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -13,6 +15,34 @@ import jax.numpy as jnp
 
 from areal_tpu.ops.attention import packed_attention_reference
 from areal_tpu.ops.pallas.flash_attention import flash_attention
+
+
+# How far a result may lie from the plain fp32 reference on the SAME inputs.
+# fp32 inputs: the kernels' products are fp32 and only the order of the sums
+# differs.  bf16 inputs: the operands reach the MXU as they are, P and dS
+# are rounded to bf16 before the product that consumes them, and the result
+# is rounded once more on the way out — 2**-9 = 0.2% a rounding, so 2e-2
+# (of the largest element, for gradients) is ten roundings' room.
+TOL = {"float32": 2e-4, "bfloat16": 2e-2}
+DTYPES = [jnp.float32, jnp.bfloat16]
+
+
+def _tol(x):
+    return TOL[jnp.dtype(x.dtype).name]
+
+
+def _f32(*xs):
+    return tuple(x.astype(jnp.float32) for x in xs)
+
+
+def _force_trip(monkeypatch, blocks):
+    """The kernels' inner loops in trips of at most `blocks` schedule
+    blocks (cut to a divisor of the row's blocks, as the chooser's own)."""
+    from areal_tpu.ops.pallas import flash_attention as fa
+
+    monkeypatch.setattr(
+        fa, "_trip_blocks", lambda n, *_: fa._largest_divisor(n, blocks)
+    )
 
 
 def _inputs(rng, b=2, s=256, hq=4, hkv=2, d=32, dtype=jnp.float32):
@@ -30,28 +60,35 @@ def _inputs(rng, b=2, s=256, hq=4, hkv=2, d=32, dtype=jnp.float32):
 
 
 class TestFlashForward:
-    def test_matches_reference(self, rng):
-        q, k, v, seg = _inputs(rng)
+    @pytest.mark.parametrize("dtype", DTYPES)
+    def test_matches_reference(self, rng, dtype):
+        q, k, v, seg = _inputs(rng, dtype=dtype)
         out = flash_attention(q, k, v, seg, block_q=64, block_k=64)
-        ref = packed_attention_reference(q, k, v, seg)
+        assert out.dtype == dtype
+        ref = packed_attention_reference(*_f32(q, k, v), seg)
         np.testing.assert_allclose(
-            np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-4
+            np.asarray(out, np.float32), np.asarray(ref),
+            rtol=_tol(q), atol=_tol(q),
         )
 
-    def test_single_block(self, rng):
-        q, k, v, seg = _inputs(rng, s=128)
+    @pytest.mark.parametrize("dtype", DTYPES)
+    def test_single_block(self, rng, dtype):
+        q, k, v, seg = _inputs(rng, s=128, dtype=dtype)
         out = flash_attention(q, k, v, seg, block_q=128, block_k=128)
-        ref = packed_attention_reference(q, k, v, seg)
+        ref = packed_attention_reference(*_f32(q, k, v), seg)
         np.testing.assert_allclose(
-            np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-4
+            np.asarray(out, np.float32), np.asarray(ref),
+            rtol=_tol(q), atol=_tol(q),
         )
 
-    def test_non_causal(self, rng):
-        q, k, v, seg = _inputs(rng, s=128)
+    @pytest.mark.parametrize("dtype", DTYPES)
+    def test_non_causal(self, rng, dtype):
+        q, k, v, seg = _inputs(rng, s=128, dtype=dtype)
         out = flash_attention(q, k, v, seg, causal=False, block_q=64, block_k=64)
-        ref = packed_attention_reference(q, k, v, seg, causal=False)
+        ref = packed_attention_reference(*_f32(q, k, v), seg, causal=False)
         np.testing.assert_allclose(
-            np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-4
+            np.asarray(out, np.float32), np.asarray(ref),
+            rtol=_tol(q), atol=_tol(q),
         )
 
     def test_padding_rows_zero(self, rng):
@@ -65,12 +102,29 @@ class TestFlashForward:
             flash_attention(q, k, v, seg, block_q=128, block_k=128)
 
 
+def _assert_grads_close(got, want, tol32):
+    """fp32 inputs: element for element at `tol32`, as these tests always
+    held them; bf16 inputs: within TOL of the gradient's largest element."""
+    for a, b, name in zip(got, want, "qkv"):
+        if a.dtype == jnp.float32:
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=tol32, atol=tol32,
+                err_msg=f"d{name}",
+            )
+            continue
+        a, b = np.asarray(a, np.float32), np.asarray(b)
+        assert np.abs(a - b).max() <= TOL["bfloat16"] * np.abs(b).max(), (
+            f"d{name}", np.abs(a - b).max(), np.abs(b).max())
+
+
 class TestFlashBackward:
-    def test_grads_match_reference(self, rng):
-        q, k, v, seg = _inputs(rng, b=1, s=128, hq=2, hkv=1, d=16)
+    @pytest.mark.parametrize("dtype", DTYPES)
+    def test_grads_match_reference(self, rng, dtype):
+        q, k, v, seg = _inputs(rng, b=1, s=128, hq=2, hkv=1, d=16, dtype=dtype)
 
         def loss_flash(q, k, v):
             o = flash_attention(q, k, v, seg, block_q=64, block_k=64)
+            o = o.astype(jnp.float32)
             return jnp.sum(o * o)
 
         def loss_ref(q, k, v):
@@ -78,19 +132,22 @@ class TestFlashBackward:
             return jnp.sum(o * o)
 
         gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-        gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-        for a, b, name in zip(gf, gr, "qkv"):
-            np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-4,
-                err_msg=f"d{name}",
-            )
+        gr = jax.grad(loss_ref, argnums=(0, 1, 2))(*_f32(q, k, v))
+        assert all(g.dtype == dtype for g in gf)
+        _assert_grads_close(gf, gr, 5e-4)
 
-    def test_grad_multi_segment(self, rng):
-        q, k, v, seg = _inputs(rng, b=2, s=256, hq=2, hkv=2, d=32)
+    @pytest.mark.parametrize("dtype", DTYPES)
+    def test_grad_multi_segment(self, rng, dtype):
+        q, k, v, seg = _inputs(rng, b=2, s=256, hq=2, hkv=2, d=32, dtype=dtype)
+
+        # |o| has a kink at 0: a bf16 o rounded across it flips a whole
+        # cotangent, so the bf16 case takes the signs from the reference.
+        sign = jnp.sign(packed_attention_reference(*_f32(q, k, v), seg))
 
         def loss(fn):
             def f(q, k, v):
-                return jnp.sum(jnp.abs(fn(q, k, v)))
+                o = fn(q, k, v).astype(jnp.float32)
+                return jnp.sum(jnp.abs(o) if dtype == jnp.float32 else o * sign)
 
             return f
 
@@ -101,12 +158,77 @@ class TestFlashBackward:
         gr = jax.grad(
             loss(lambda q, k, v: packed_attention_reference(q, k, v, seg)),
             argnums=(0, 1, 2),
-        )(q, k, v)
-        for a, b, name in zip(gf, gr, "qkv"):
-            np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), rtol=1e-3, atol=1e-3,
-                err_msg=f"d{name}",
-            )
+        )(*_f32(q, k, v))
+        _assert_grads_close(gf, gr, 1e-3)
+
+
+# name -> (S, n_q, n_kv, head_dim, window, sequence lengths): bf16 inputs at
+# the head widths, head groupings and bands the cells run.
+_BF16_CASES = {
+    "gqa_d128": (1024, 4, 2, 128, None, [400, 300, 200]),
+    "mha_d256": (512, 2, 2, 256, None, [200, 250]),
+    "window_gqa_d128": (1024, 4, 1, 128, 256, [600, 400]),
+    "window_d256": (512, 2, 1, 256, 128, [500]),
+}
+
+
+class TestTripWidths:
+    """The inner loops walk a trip of several schedule blocks; the mask
+    inside a trip is exact, so a width moves the order of the sums and
+    nothing else.  Every width the chooser can return (1, 2 or 4 blocks of
+    128), bf16 inputs, forward and gradients, against the plain fp32
+    reference on the same values."""
+
+    @pytest.mark.parametrize("blocks", [1, 2, 4])
+    @pytest.mark.parametrize("name", list(_BF16_CASES))
+    def test_bf16_matches_reference_at_every_width(
+        self, name, blocks, monkeypatch
+    ):
+        s, hq, hkv, d, window, lens = _BF16_CASES[name]
+        _force_trip(monkeypatch, blocks)
+        rng = np.random.default_rng(sum(map(ord, name)))
+        q, k, v, w = (
+            jnp.asarray(rng.normal(size=(1, s, h, d)), jnp.bfloat16)
+            for h in (hq, hkv, hkv, hq)
+        )
+        seg = jnp.asarray(_packed_row(s, lens))
+
+        def loss(fn):
+            def f(q, k, v):
+                o = fn(q, k, v, seg, window=window)
+                return jnp.sum(o.astype(jnp.float32) * w), o
+
+            return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+
+        (_, o), g = loss(flash_attention)(q, k, v)
+        (_, o_ref), g_ref = loss(packed_attention_reference)(*_f32(q, k, v))
+        np.testing.assert_allclose(
+            np.asarray(o, np.float32), np.asarray(o_ref),
+            rtol=TOL["bfloat16"], atol=TOL["bfloat16"],
+        )
+        _assert_grads_close(g, g_ref, None)
+
+    def test_the_chooser_sees_shapes_alone(self):
+        from areal_tpu.ops.pallas.flash_attention import _trip_blocks
+
+        # (row blocks, block, head_dim, backward kernel) -> blocks a trip
+        for backward in (False, True):
+            assert _trip_blocks(64, 128, 128, backward) == 4  # 8,192 tokens
+            assert _trip_blocks(20, 128, 128, backward) == 4  # prefill, 2,560
+            assert _trip_blocks(10, 128, 128, backward) == 2  # 4 is no divisor
+            assert _trip_blocks(3, 128, 128, backward) == 3
+            assert _trip_blocks(1, 64, 32, backward) == 1
+        # GLM, qwen3-next: the forward keeps the order of its sums
+        assert _trip_blocks(40, 128, 256, False) == 1
+        assert _trip_blocks(40, 128, 256, True) == 4
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for e in jaxpr.eqns:
+        yield e
+        for sub in jax.core.jaxprs_in_params(e.params):
+            yield from _eqns(sub)
 
 
 def _packed_row(s, lens, ids=None):
@@ -164,11 +286,17 @@ class TestLiveSchedule:
         seg = jnp.asarray(_packed_row(s, lens, ids))
         return q, k, v, w, seg, hq, causal, resident
 
+    @pytest.mark.parametrize("blocks", [1, 2, 4])
     @pytest.mark.parametrize("name", list(_SCHEDULE_CASES))
-    def test_live_tiles_equal_all_tiles_bit_for_bit(self, name, monkeypatch):
+    def test_live_tiles_equal_all_tiles_bit_for_bit(
+        self, name, blocks, monkeypatch
+    ):
+        """At every trip width: a trip with no live block is as exact a
+        no-op as a dead tile was."""
         from areal_tpu.ops.pallas import flash_attention as fa
 
         q, k, v, do, seg, hq, causal, resident = self._case(name)
+        _force_trip(monkeypatch, blocks)
         if resident:
             monkeypatch.setattr(fa, "RESIDENT_BYTES", resident)
         s, d = q.shape[1:]
@@ -395,23 +523,77 @@ class TestTPULowering:
         # qwen3_next's gated attention: never 256 before it
         "q3next_16x8192x256": (1, 8192, 16, 2, 256),
         "q7b_per_chip_28x2048x128": (1, 2048, 28, 4, 128),
-        # past the resident limit: the q side of dkv in two chunks
-        "row_in_chunks_12x16384x128": (1, 16384, 12, 2, 128),
+        # past the resident limit (38,000 tokens of K/V, 34,000 of Q/dO):
+        # every kernel's resident side in two chunks
+        "row_in_chunks_12x65536x128": (1, 65536, 12, 2, 128),
+        # mellum's window layers (32 / 4 heads, a band of 1,024 keys) and
+        # GLM's materialised latent attention (20 heads of 256)
+        "mellum_window_32x8192x128": (1, 8192, 32, 4, 128, 1024),
+        "glm_20x5120x256": (1, 5120, 20, 20, 256),
     }
+    # The trip each cell's FORWARD calls take (`_trip_blocks`), as the scope
+    # around the kernel says it (dq's keys and dkv's queries: 512 everywhere).
+    FORWARD_TRIPS = {"glm_20x5120x256": 128, "q3next_16x8192x256": 128}
+
+    def _cell(self, cell, sharding=None):
+        b, s, n_q, n_kv, d, *window = self.CELL_SHAPES[cell]
+        window = window[0] if window else None
+        q = jax.ShapeDtypeStruct((b, s, n_q, d), jnp.bfloat16,
+                                 sharding=sharding)
+        kv = jax.ShapeDtypeStruct((b, s, n_kv, d), jnp.bfloat16,
+                                  sharding=sharding)
+        seg = jax.ShapeDtypeStruct((b, s), jnp.int32, sharding=sharding)
+
+        def attend(q, k, v, seg):
+            return flash_attention(q, k, v, seg, window=window)
+
+        def loss(q, k, v, seg):
+            return attend(q, k, v, seg).astype(jnp.float32).sum()
+
+        trip = self.FORWARD_TRIPS.get(cell, 512)
+        # in an op_name; a transform wraps the scope: `jvp(keys512)/flash_fwd`
+        scopes = [rf"keys{trip}\)*/flash_fwd/", r"keys512\)*/flash_dq/",
+                  r"queries512\)*/flash_dkv/"]
+        return attend, jax.grad(loss, argnums=(0, 1, 2)), (q, kv, kv, seg), scopes
 
     @pytest.mark.parametrize("cell", list(CELL_SHAPES))
     def test_flash_forward_and_backward(self, cell):
-        b, s, n_q, n_kv, d = self.CELL_SHAPES[cell]
-        q = jax.ShapeDtypeStruct((b, s, n_q, d), jnp.bfloat16)
-        kv = jax.ShapeDtypeStruct((b, s, n_kv, d), jnp.bfloat16)
-        seg = jax.ShapeDtypeStruct((b, s), jnp.int32)
-
-        def loss(q, k, v, seg):
-            return flash_attention(q, k, v, seg).astype(jnp.float32).sum()
-
-        self._lowered(flash_attention, q, kv, kv, seg)
-        text = self._lowered(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv, seg)
+        attend, grad, args, _ = self._cell(cell)
+        self._lowered(attend, *args)
+        text = self._lowered(grad, *args)
         assert text.count("tpu_custom_call") >= 3  # fwd, dq, dkv
+
+    @pytest.mark.parametrize("cell", list(CELL_SHAPES))
+    def test_flash_products_take_bf16_and_sum_in_fp32(self, cell):
+        """What the three kernels feed the MXU, read from the traced
+        kernels (the lowered module keeps a Mosaic kernel as bytecode):
+        every product's operands are the inputs' bf16 and its result fp32;
+        every `exp`, the softmax statistics and every accumulator fp32."""
+        _, grad, args, _ = self._cell(cell)
+        kernels = [
+            e for e in _eqns(jax.make_jaxpr(grad)(*args).jaxpr)
+            if e.primitive.name == "pallas_call"
+        ]
+        assert [e.params["name"] for e in kernels] == [
+            "flash_fwd", "flash_dq", "flash_dkv"]
+        for kernel, n_dots in zip(kernels, (2, 3, 4)):
+            body = kernel.params["jaxpr"]
+            inner = list(_eqns(body))
+            dots = [e for e in inner if e.primitive.name == "dot_general"]
+            assert len(dots) == n_dots
+            for e in dots:
+                assert [v.aval.dtype for v in e.invars] == [jnp.bfloat16] * 2
+                assert e.outvars[0].aval.dtype == jnp.float32
+            exps = [e for e in inner if e.primitive.name == "exp"]
+            assert exps and all(
+                e.invars[0].aval.dtype == jnp.float32 for e in exps)
+            # the kernel's refs: bf16 are q, k, v, dO and the outputs in
+            # their type; ids int32; lse, delta, m, l and the accumulators
+            # (the scratch: the last refs) fp32
+            kinds = [v.aval.dtype for v in body.invars]
+            n_scratch = {"flash_fwd": 3, "flash_dq": 1, "flash_dkv": 2}[
+                kernel.params["name"]]
+            assert all(k == jnp.float32 for k in kinds[-n_scratch:])
 
     @pytest.fixture(scope="class")
     def one_chip(self):
@@ -448,22 +630,13 @@ class TestTPULowering:
     def test_flash_compiles_for_v5e(self, cell, one_chip, _no_persistent_cache):
         """Mosaic and XLA:TPU for real, at the cells' sizes: lowering does
         not see the VMEM the resident operands take, the compiler does."""
-        b, s, n_q, n_kv, d = self.CELL_SHAPES[cell]
-        q = jax.ShapeDtypeStruct((b, s, n_q, d), jnp.bfloat16,
-                                 sharding=one_chip)
-        kv = jax.ShapeDtypeStruct((b, s, n_kv, d), jnp.bfloat16,
-                                  sharding=one_chip)
-        seg = jax.ShapeDtypeStruct((b, s), jnp.int32, sharding=one_chip)
-
-        def loss(q, k, v, seg):
-            return flash_attention(q, k, v, seg).astype(jnp.float32).sum()
-
-        text = (
-            jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
-            .lower(q, kv, kv, seg).compile().as_text()
-        )
-        for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
+        _, grad, args, scopes = self._cell(cell, one_chip)
+        text = jax.jit(grad).lower(*args).compile().as_text()
+        for kernel, scope in zip(("flash_fwd", "flash_dq", "flash_dkv"),
+                                 scopes):
             assert f"%{kernel}" in text
+            # the trip the chooser gave this shape
+            assert re.search(scope, text), scope
 
     # The paged attention kernel's calls: (lanes, table columns, pool pages,
     # layers, q heads, kv heads, pool dtype).
