@@ -555,7 +555,10 @@ def _experts_grouped(
     (decode, stacked leaves): the Pallas kernel `grouped_decode_matmul` in
     `ragged_dot`'s place — XLA's kernel tiles by the divisors of the two
     weight dimensions and streams a [2,688, 1,856] expert at a ninth of
-    the bandwidth (`ops/pallas/grouped_matmul.py`).
+    the bandwidth; the Pallas kernel walks an expert in contiguous pieces
+    of 0.6 to 8 MB whatever the divisors (`ops/pallas/grouped_matmul.py`,
+    `tiles`: [384, 1856] there, [384, 896] and all of [896, 2304] at
+    mellum's widths).
 
     Expert FLOPs are exactly 3·T·k·D·F — proportional to TOKENS, where
     the dense oracle pays E/k× that and capacity dispatch pays

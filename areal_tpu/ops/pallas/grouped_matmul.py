@@ -22,6 +22,20 @@ the block index of the step before it, which Pallas does not fetch again.
 Rows past every group (a rank's share: choices held elsewhere) come out
 zero.  Forward only: the decode programs' kernel; training keeps
 `ragged_dot`.
+
+The tile is chosen by BYTES (`tiles`, PR 47), not by the divisors of a
+dimension.  A grid step costs 0.33 us whatever it moves, and a step's
+fetch runs under the step before it: a tile whose fetch is shorter than
+that leaves the call bound by the count of its steps.  By lane divisors up
+to 512, mellum's [2,304, 896] experts (896 = 7 x 128 has no wider divisor
+than 128) took 672 steps of 96 KiB, 222 us a call where the bytes take 50;
+in [384, 896] pieces (96 steps) and as [896, 2,304] whole (16) — both
+contiguous in the row-major matrix — 70-72 us, and every contiguous tile
+from 0.7 to 4 MB read within 2% of that (chip runs, PR 47).  Nemotron's
+[384, 1856] / [1856, 384] tiles of 1.4 MB already were of that size, and
+are what the rule picks there.  K is cut where it was (or not at all where
+its pieces were single lanes' 128 rows, which a whole product adds up in
+the same order), so no result changed a bit.
 """
 
 import functools
@@ -34,16 +48,60 @@ from jax.experimental.pallas import tpu as pltpu
 from areal_tpu.ops.pallas.flash_attention import _interpret, named_call
 
 ROW_TILE = 16  # a window starts on a whole bf16 sublane tile
-MAX_TILE = 512  # widest K or N tile: [512, 2,688] bf16 is 2.75 MB a buffer
+VMEM_LIMIT = 64 << 20  # what the kernel asks Mosaic for (a v5e has 128 MiB)
+# A step's weight tile, in bytes (chip runs, PR 47: `scripts/
+# grouped_tile_bench.py`).  Under MIN_TILE a step's fixed cost (0.33 us)
+# shows beside its fetch: [384, 128] pieces of 96 KiB ran a call at 23% of
+# its bytes, [128, 2304] of 576 KiB still 3% behind the best.  Over MAX_TILE
+# the first fetch, which nothing runs under, shows: [2688, 1856] whole
+# (10 MB) 4% behind [384, 1856].  Between them every contiguous tile
+# measured reads the same to 2%, so the smallest is taken — and MIN_TILE
+# stands just under [384, 896] (672 KiB), whose K pieces are the 384 rows
+# PR 40's lane divisors took: the sums add up in the order they did, and
+# every cell's results keep their bits.
+MIN_TILE = 640 << 10
+MAX_TILE = 8 << 20
 
 
-def tile_for(dim: int) -> int:
-    """The widest whole-lane divisor of `dim` up to MAX_TILE, or the whole
-    dimension where it has none (1,856 = 14.5 x 128: taken whole)."""
-    for n in range(min(MAX_TILE, dim) // 128, 0, -1):
-        if dim % (128 * n) == 0:
-            return 128 * n
-    return dim
+def _pieces(dim: int):
+    """The whole-lane divisors of `dim`, narrowest first, then `dim`."""
+    return [
+        128 * m for m in range(1, dim // 128) if dim % (128 * m) == 0
+    ] + [dim]
+
+
+def step_bytes(tk: int, tn: int, rows: int, itemsize: int) -> int:
+    """What a grid step holds in VMEM: the weight tile, the rows' K piece
+    and the result block twice each (Pallas double-buffers), the sums."""
+    return (2 * (tk * tn + rows * tk + rows * tn) * itemsize
+            + rows * tn * 4)
+
+
+def tiles(k: int, n: int, rows: int, itemsize: int):
+    """(tk, tn): the tile a grid step takes of an expert's row-major [k, n]
+    matrix — a function of the operands' shapes alone.  The smallest
+    CONTIGUOUS piece of MIN_TILE to MAX_TILE bytes: n whole, k whole or cut
+    in whole lanes ([384, 896] of [2304, 896]; all of [896, 2304];
+    [384, 1856] of [2688, 1856]).  Where there is none (k = 1,856 is 14.5
+    lanes and the matrix is 10 MB), the smallest piece of all k and whole
+    lanes of n — strided runs — of at least MIN_TILE ([1856, 384]).  Else
+    the largest piece there is (a toy's whole matrix).  A step's buffers
+    take at most half the limit: the rest is Mosaic's own (the window's
+    fp32 product)."""
+    rows += -rows % ROW_TILE
+    pieces = [(tk, n) for tk in _pieces(k)] + [(k, tn) for tn in _pieces(n)]
+    pieces = [
+        t for t in pieces
+        if step_bytes(*t, rows, itemsize) <= VMEM_LIMIT // 2
+    ]
+
+    def size(tile):
+        return tile[0] * tile[1] * itemsize
+
+    for tile in pieces:  # contiguous ones first, each kind smallest first
+        if size(tile) >= MIN_TILE and (tile[1] < n or size(tile) <= MAX_TILE):
+            return tile
+    return max(pieces, key=size, default=(k, n))
 
 
 def ragged_tiles_badly(k: int, n: int) -> bool:
@@ -89,16 +147,9 @@ def _kernel(
         o_ref[...] = acc_scr[:].astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("max_rows",))
-def grouped_decode_matmul(
-    xs: jax.Array,  # [R, K] rows sorted by expert; rows past the groups: any
-    w: jax.Array,  # [G, K, N] the experts' matrices, STACKED over layers
-    group_sizes: jax.Array,  # [E] int32: this layer's rows per expert
-    layer: jax.Array,  # scalar int32: the layer's experts are [layer*E, ..)
-    max_rows: int,  # no group has more rows (the step's tokens)
-) -> jax.Array:
-    """-> [R, N]: row r times the matrix of the expert whose group holds
-    it (groups in order from row 0), zero for rows past every group."""
+def _call(xs, w, group_sizes, layer, max_rows: int, tk: int, tn: int):
+    """`grouped_decode_matmul` walking every expert in [tk, tn] tiles (the
+    tile bench and the tests force one; `tiles` picks the program's)."""
     r, k = xs.shape
     n = w.shape[2]
     n_experts = group_sizes.shape[0]
@@ -107,9 +158,6 @@ def grouped_decode_matmul(
         xs = jnp.pad(xs, ((0, pad), (0, 0)))
     rows = r + pad
     window = min(rows, -(-max_rows // ROW_TILE) * ROW_TILE + ROW_TILE)
-    tk, tn = tile_for(k), tile_for(n)
-    if tk == k and tn == n:  # neither splits: halve what a step holds
-        tn = tile_for(n // 2) if n % 256 == 0 else n
     nk, nn = k // tk, n // tn
     sizes = group_sizes.astype(jnp.int32)
     starts = jnp.cumsum(sizes) - sizes
@@ -128,7 +176,7 @@ def grouped_decode_matmul(
 
     kern = functools.partial(
         _kernel, window=window, n_experts=n_experts, nk=nk)
-    out = named_call(
+    call = named_call(
         "grouped_decode_matmul",
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -144,8 +192,24 @@ def grouped_decode_matmul(
         out_shape=jax.ShapeDtypeStruct((rows, n), xs.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-            vmem_limit_bytes=64 << 20,
+            vmem_limit_bytes=VMEM_LIMIT,
         ),
         interpret=_interpret(),
-    )(first, starts, sizes, fetch, xs, w)
+    )
+    with jax.named_scope(f"w{tk}x{tn}"):
+        out = call(first, starts, sizes, fetch, xs, w)
     return out[:r]
+
+
+@functools.partial(jax.jit, static_argnames=("max_rows",))
+def grouped_decode_matmul(
+    xs: jax.Array,  # [R, K] rows sorted by expert; rows past the groups: any
+    w: jax.Array,  # [G, K, N] the experts' matrices, STACKED over layers
+    group_sizes: jax.Array,  # [E] int32: this layer's rows per expert
+    layer: jax.Array,  # scalar int32: the layer's experts are [layer*E, ..)
+    max_rows: int,  # no group has more rows (the step's tokens)
+) -> jax.Array:
+    """-> [R, N]: row r times the matrix of the expert whose group holds
+    it (groups in order from row 0), zero for rows past every group."""
+    tk, tn = tiles(*w.shape[1:], xs.shape[0], w.dtype.itemsize)
+    return _call(xs, w, group_sizes, layer, max_rows, tk, tn)

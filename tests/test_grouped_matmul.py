@@ -1,0 +1,347 @@
+"""The decode step's grouped expert matmul (`ops/pallas/grouped_matmul.py`):
+the kernel, interpreted, against a per-group dense product in fp32 at the
+cells' shapes and at every shape of group sizes a step can make; the rule
+that picks a step's tile (`tiles`: by bytes, PR 47) over a sweep of
+shapes, and its grids at the four shapes the benchmark runs; the tile in
+the trace's scope names, and that the benchmark's readers read `layer/mlp`
+of such names as before; Mosaic and XLA:TPU for real on the mellum cell's
+decode loop."""
+import hashlib
+import re
+import types
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from areal_tpu.models import transformer as tfm
+from areal_tpu.ops.pallas import grouped_matmul as gm
+from benchmark import files, program_trace
+from benchmark import run as bench_run
+from benchmark.metrics import _moe, _program
+
+# name -> (rows R, tokens T, experts E, K, N): a step's call in the cell
+SHAPES = {
+    "mellum_up": (256, 32, 16, 2304, 896),
+    "mellum_down": (256, 32, 16, 896, 2304),
+    "nemotron_up": (384, 64, 16, 2688, 1856),
+    "nemotron_down": (384, 64, 16, 1856, 2688),
+    "toy": (48, 12, 4, 256, 192),
+}
+
+
+def _sizes(kind, e, t, r):
+    """A step's rows per expert, by what the step looks like."""
+    some = np.minimum(np.arange(e) % 5 + 1, t)  # 1..5 rows, every expert
+    if kind == "every_expert":
+        return some
+    if kind == "none_at_the_front":
+        return np.where(np.arange(e) < 2, 0, some)
+    if kind == "none_in_the_middle":
+        return np.where((np.arange(e) > 0) & (np.arange(e) < e - 1), 0, some)
+    if kind == "none_at_the_end":
+        return np.where(np.arange(e) >= e - 2, 0, some)
+    if kind == "one_expert_takes_every_token":  # a group of `max_rows`
+        return np.where(np.arange(e) == e // 2, t, some)
+    assert kind == "every_row_held"  # no row past the groups
+    sizes = np.full(e, r // e)
+    assert r % e == 0 and r // e <= t
+    return sizes
+
+
+def _dense(xs, w, sizes, layer):
+    """Row by row in fp32: group g's rows times expert `layer * E + g`."""
+    e = len(sizes)
+    want = np.zeros((xs.shape[0], w.shape[2]), np.float32)
+    lo = 0
+    for g, size in enumerate(sizes):
+        want[lo: lo + size] = (
+            np.asarray(xs[lo: lo + size], np.float32)
+            @ np.asarray(w[layer * e + g], np.float32))
+        lo += size
+    return want
+
+
+_KINDS = [
+    "every_expert", "none_at_the_front", "none_in_the_middle",
+    "none_at_the_end", "one_expert_takes_every_token", "every_row_held"]
+
+
+@pytest.mark.parametrize("shape,kind", [
+    *((shape, kind) for shape in ("toy", "mellum_up", "mellum_down")
+      for kind in _KINDS),
+    # interpreted, a Nemotron call takes 20-40 s: one kind each
+    ("nemotron_up", "one_expert_takes_every_token"),
+    ("nemotron_down", "none_at_the_front"),
+])
+def test_the_kernel_is_the_per_group_dense_product(shape, kind):
+    """bf16 operands as the cells', the tile `tiles` picks, the SECOND
+    layer of a stacked leaf: every held row is its expert's product to
+    bf16's rounding of an fp32 sum, every row past the groups zero."""
+    r, t, e, k, n = SHAPES[shape]
+    sizes = _sizes(kind, e, t, r)
+    rng = np.random.default_rng(k + len(kind))
+    xs = jnp.asarray(rng.standard_normal((r, k)), jnp.bfloat16)
+    w = jnp.asarray(
+        rng.standard_normal((2 * e, k, n)) * k**-0.5, jnp.bfloat16)
+    got = np.asarray(gm.grouped_decode_matmul(
+        xs, w, jnp.asarray(sizes, jnp.int32), jnp.int32(1), max_rows=t
+    ).astype(jnp.float32))
+    want = _dense(xs, w, sizes, 1)
+    np.testing.assert_allclose(got, want, rtol=2**-7, atol=2**-7)
+    held = int(sizes.sum())
+    assert np.abs(want[:held]).max() > 1 and not got[held:].any()
+
+
+@pytest.mark.parametrize("r,t,sizes,tile", [
+    # rows no multiple of 16; K and N both cut
+    (30, 5, (5, 0, 4, 1), (128, 256)),
+    (30, 5, (0, 5, 5, 0), (512, 128)),
+    (44, 11, (11, 0, 11, 7), (256, 512)),  # whole N, two K pieces
+    (16, 16, (0, 0, 16, 0), (512, 512)),  # one window is every row
+])
+def test_any_tile_gives_the_same_rows(r, t, sizes, tile):
+    """`_call` at a forced tile, fp32, R no multiple of 16, layer 0 and
+    the last: the tile moves the order of a sum and nothing else."""
+    e, k, n = 4, 512, 512
+    rng = np.random.default_rng(r)
+    sizes = np.asarray(sizes)
+    xs = jnp.asarray(rng.standard_normal((r, k)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((3 * e, k, n)) * k**-0.5, jnp.float32)
+    for layer in (0, 2):
+        got = gm._call(xs, w, jnp.asarray(sizes, jnp.int32), layer, t, *tile)
+        np.testing.assert_allclose(
+            got, _dense(xs, w, sizes, layer), rtol=1e-4, atol=1e-4)
+        assert not np.asarray(got[int(sizes.sum()):]).any()
+
+
+# --------------------------------------------------------------- the chooser
+
+
+@pytest.mark.parametrize("itemsize", [2, 4])
+@pytest.mark.parametrize("rows", [8, 64, 256, 384, 640])
+def test_a_tile_divides_the_matrix_in_whole_lanes_and_fits_the_limit(
+        rows, itemsize):
+    """Over every [k, n] of multiples of 64 up to 4,096: a tile divides
+    its dimension and is whole lanes of it or all of it; what a step holds
+    in VMEM (2 x weight tile + 2 x the rows' piece + 2 x the result block
+    + the sums) stays under half of what the kernel asks Mosaic for
+    wherever any tile does; it is the smallest contiguous piece of
+    MIN_TILE to MAX_TILE bytes wherever the matrix has one, and N is cut
+    only where it has none."""
+    dims = range(64, 4097, 64)
+    padded = rows + -rows % gm.ROW_TILE
+    for k in dims:
+        for n in dims:
+            tk, tn = gm.tiles(k, n, rows, itemsize)
+            assert k % tk == 0 and n % tn == 0
+            assert (tk % 128 == 0 or tk == k) and (tn % 128 == 0 or tn == n)
+            assert tk == k or tn == n  # one dimension is cut, never both
+            size = tk * tn * itemsize
+            legal = [(p, n) for p in gm._pieces(k)] + [
+                (k, p) for p in gm._pieces(n)]
+            legal = [t for t in legal if gm.step_bytes(
+                *t, padded, itemsize) <= gm.VMEM_LIMIT // 2]
+            assert (tk, tn) in legal or not legal
+            contiguous = [
+                t for t in legal if t[1] == n
+                and gm.MIN_TILE <= t[0] * n * itemsize <= gm.MAX_TILE]
+            if contiguous:
+                assert (tk, tn) == contiguous[0]
+            elif size < gm.MIN_TILE:  # nothing larger would do
+                assert all(t[0] * t[1] <= tk * tn for t in legal)
+            else:
+                assert tk == k
+
+
+def test_the_four_grids_the_benchmark_runs():
+    """Grid steps a call = K pieces x N pieces x 16 experts.  Mellum's
+    were 672 a call by lane divisors (PR 40's `tile_for`: 896 = 7 x 128
+    gave 128); Nemotron's are as PR 40 committed them."""
+    grids = {}
+    for name, (r, _t, e, k, n) in SHAPES.items():
+        tk, tn = gm.tiles(k, n, r, 2)
+        grids[name] = ((tk, tn), (k // tk) * (n // tn) * e)
+    assert grids == {
+        "mellum_up": ((384, 896), 96),
+        "mellum_down": ((896, 2304), 16),
+        "nemotron_up": ((384, 1856), 112),
+        "nemotron_down": ((1856, 384), 112),
+        "toy": ((256, 192), 4),
+    }
+
+
+# The traced call at Nemotron's two shapes — prologue, grid, block shapes,
+# index maps, the kernel's body, its compiler parameters — as the parent
+# of PR 47 (a264bd5) traced it: sha256 of `str(jax.make_jaxpr(...))`, which
+# holds no source location (the lowered module's kernel bytecode does).
+_PARENT_CALLS = {
+    "nemotron_up":
+        "4628336a5d2ee8e5ffe662e3a625182efbc498b88c427fd7d18d36fab8d0c654",
+    "nemotron_down":
+        "3c0d6abd386390eef25d68568b15f6084005528ad647a56e6ea17e6d7e771854",
+}
+
+
+@pytest.mark.parametrize("shape", sorted(_PARENT_CALLS))
+def test_nemotrons_call_traces_to_the_parents_program(shape, monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    r, t, e, k, n = SHAPES[shape]
+    spec = jax.ShapeDtypeStruct
+    text = str(jax.make_jaxpr(
+        lambda *a: gm.grouped_decode_matmul.__wrapped__(*a, max_rows=t))(
+        spec((r, k), jnp.bfloat16), spec((4 * e, k, n), jnp.bfloat16),
+        spec((e,), jnp.int32), spec((), jnp.int32)))
+    assert "vmem_limit_bytes=67108864" in text and "block_size=384" in text
+    assert hashlib.sha256(text.encode()).hexdigest() == _PARENT_CALLS[shape]
+
+
+# ------------------------------------------------------ the tile in the trace
+
+
+def test_the_readers_take_layer_mlp_of_a_tiled_name_as_before():
+    """`_program.scope_seconds` and `_moe.mlp_seconds` match scopes by
+    runs of elements: one more element between `experts` and the kernel
+    changes no sum (2 traced steps; the kernel, the activation between
+    the calls, the router, and an attention scope that is not the MLP's)."""
+    phases = lambda s: {"fwd": s, "recompute": 0.0, "bwd": 0.0}  # noqa: E731
+    decode = "gen/generate/gen/decode_step/layer/mlp"
+    scopes = {
+        f"{decode}/experts/w384x896/grouped_decode_matmul": phases(0.8),
+        f"{decode}/experts/w896x2304/grouped_decode_matmul": phases(0.4),
+        f"{decode}/experts": phases(0.2),
+        f"{decode}/router": phases(0.1),
+        "gen/generate/gen/decode_step/layer/attn/window": phases(9.0),
+        "train/grad/layer/mlp/experts": phases(3.0),
+    }
+    run = types.SimpleNamespace(trace={
+        "scope_seconds": scopes, "busy_s": 20.0, "traced_steps": 2,
+        "op_seconds_scoped": {
+            "grouped_decode_matmul.3 = bf16[256,896] @w384x896:fwd": 0.8}})
+    assert _program.scope_seconds(
+        run, "gen/decode_step", "layer/mlp") == pytest.approx(0.75)
+    assert _moe.mlp_seconds(run, _moe.DECODE) == pytest.approx(0.75)
+    assert _program.scope_seconds(
+        run, "gen/decode_step", "layer/mlp/experts") == pytest.approx(0.7)
+    assert _moe.mlp_seconds(run, _moe.TRAIN) == pytest.approx(1.5)
+    assert _moe.ragged_seconds(run, _moe.DECODE) is None  # no ragged kernel
+
+
+# ----------------------------------------- the decode loop compiled for v5e
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """A device of a described v5e host to compile for (libtpu is
+    installed here; no chip is attached).  Built inside the fixture, never
+    at import: only the worker that runs this file may load the TPU's
+    library."""
+    import os
+
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever libtpu raises
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return topo.devices[0]
+
+
+def _kernel_scopes(text):
+    """The scope `benchmark/program_trace.py` gives each call of the
+    kernel in a compiled program's text."""
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "%grouped_decode_matmul" in line.split(" = ")[0]]
+    return [program_trace.scope_of(
+        re.search(r'op_name="([^"]+)"', c).group(1))[0] for c in calls]
+
+
+def test_the_scope_around_the_call_states_the_tile(v5e_chip, monkeypatch):
+    """`.../experts/w384x896/grouped_decode_matmul`: an outer scope names
+    the step's tile, the kernel's own name (what `flash_time_share` and
+    the ledger's breakdown key on) stays — read as the benchmark's trace
+    reader reads a compiled operation's `op_name`."""
+    from jax.sharding import SingleDeviceSharding
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    r, t, e, k, n = SHAPES["mellum_up"]
+    one = SingleDeviceSharding(v5e_chip)
+
+    def experts(xs, w, sizes, layer):
+        with jax.named_scope("gen/decode_step/layer/mlp/experts"):
+            return gm.grouped_decode_matmul(xs, w, sizes, layer, max_rows=t)
+
+    text = jax.jit(experts).lower(*(
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+        for shape, dtype in (((r, k), jnp.bfloat16), ((e, k, n), jnp.bfloat16),
+                             ((e,), jnp.int32), ((), jnp.int32))
+    )).compile().as_text()
+    assert "%grouped_decode_matmul" in text
+    assert _kernel_scopes(text) == [
+        "gen/decode_step/layer/mlp/experts/w384x896/grouped_decode_matmul"]
+
+
+def test_mellums_decode_loop_compiles_for_v5e_with_the_leaves_in_place(
+        v5e_chip, monkeypatch):
+    """Mosaic and XLA:TPU for real, at the cell's size (32 rows, 4,608
+    slots, four layers, the published widths): the twelve calls an
+    iteration compile at [384, 896] and [896, 2,304] tiles, under scopes
+    that say so, and read the stacked [4, 16, 2304, 896] / [4, 16, 896,
+    2304] leaves where they lie — no copy, transpose or re-layout of a
+    leaf or of a layer's experts anywhere in the program (both minor
+    dimensions are whole lanes: there is nothing to re-lay)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    big = bench_run.model_config(
+        files.load_json("configs", "mellum2-12b-a2.5b-l4-e16.json"))
+    b, sp, st = 32, 4096, 4608
+    one = SingleDeviceSharding(v5e_chip)
+
+    def placed(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)
+
+    params = jax.tree.map(placed, jax.eval_shape(
+        lambda: tfm.init_params(big, jax.random.PRNGKey(0))))
+    rows = placed(jax.ShapeDtypeStruct((b,), jnp.int32))
+
+    def loop(params, tok, plen):
+        cache = tfm.init_kv_cache(big, b, st, dtype=jnp.bfloat16)
+
+        def body(state):
+            step, tok, cache = state
+            logits, cache = tfm.decode_step(
+                params, big, tok, plen + step, cache, sp + step, sp - plen,
+                experts_in_place=True)
+            return step + 1, jnp.argmax(logits, -1).astype(jnp.int32), cache
+
+        return jax.lax.while_loop(
+            lambda s: s[0] < 512, body, (0, tok, cache))[1]
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(loop).lower(params, rows, rows).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    assert "%ragged-dot" not in text  # the TPU's own pick at these widths
+    scopes = _kernel_scopes(text)
+    assert len(scopes) == 3 * len(big.plan.unit)  # wg, wu, wd a layer
+    assert all(s.startswith("gen/decode_step/layer/mlp/experts/w")
+               for s in scopes)
+    assert sorted(s.split("/")[-2] for s in scopes) == sorted(
+        ["w384x896", "w384x896", "w896x2304"] * len(big.plan.unit))
+    leaves = ("2304,896]", "896,2304]")
+    copies = [
+        line.strip()[:160] for line in text.splitlines()
+        if any(s in line.split(" = ")[-1].split("(")[0] for s in leaves)
+        and any(f" {op}(" in line for op in ("copy", "transpose", "bitcast-convert"))
+    ]
+    assert not copies, copies[:3]

@@ -575,8 +575,8 @@ def test_the_decode_step_with_the_kernel_equals_the_ragged_form(cfg, params):
     assert not gm.ragged_tiles_badly(2048, 1536)  # glm, olmoe, qwen3_next:
     assert not gm.ragged_tiles_badly(2048, 1024)  # XLA's kernel stays
     assert not gm.ragged_tiles_badly(2048, 512)
-    assert (gm.tile_for(2688), gm.tile_for(1856), gm.tile_for(2048)) == (
-        384, 1856, 512)
+    assert gm.tiles(2688, 1856, 384, 2) == (384, 1856)  # as PR 40 left them
+    assert gm.tiles(1856, 2688, 384, 2) == (1856, 384)
 
 
 # -------------------------------------------------- sharding, refusals, counters
