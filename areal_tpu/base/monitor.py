@@ -31,8 +31,8 @@ def _attn_layers(cfg) -> int:
     band)."""
     if getattr(cfg, "layer_pattern", ""):
         return cfg.n_attn_layers
-    if getattr(cfg, "window_pattern", ""):
-        return cfg.n_layers
+    if getattr(cfg, "window_pattern", ""):  # a short convolution is none
+        return cfg.n_attn_layers + cfg.n_window_layers
     return getattr(cfg, "n_periods", cfg.n_layers) + getattr(
         cfg, "first_k_dense", 0)
 
@@ -44,6 +44,14 @@ def _ssm_params(cfg) -> int:
     chunked form's extra in-chunk products are not counted as useful."""
     h, di = cfg.hidden_dim, cfg.ssm_inner_dim
     return h * cfg.ssm_in_dim + di * h + 2 * di * cfg.ssm_state_dim
+
+
+def _sconv_params(cfg) -> int:
+    """ONE gated short-convolution layer's matmul parameters: in_proj [D,
+    3 D] and out_proj [D, D], and the depthwise conv's K multiply-adds a
+    channel."""
+    h = cfg.hidden_dim
+    return 4 * h * h + cfg.sconv_kernel * h
 
 
 def _attn_params(cfg) -> int:
@@ -72,8 +80,9 @@ def matmul_params(cfg) -> int:
     (the routed experts and the router for MoE; embedding lookup excluded).
 
     A pattern of one-branch layers counts each KIND over its own layers
-    (`_ssm_params`, the attention projections, the mixture); per layer
-    KIND in a hybrid pattern: a softmax-attention layer's
+    (`_ssm_params`, the attention projections, the mixture), a mix of
+    short-convolution and attention layers likewise (`_sconv_params`); per
+    layer KIND in a hybrid pattern: a softmax-attention layer's
     projections (the query's twice where it also gives the output gate), a
     Gated DeltaNet layer's projections plus its recurrence counted as the
     3 * d_k * d_v multiply-adds a value head's state takes per token
@@ -89,6 +98,8 @@ def matmul_params(cfg) -> int:
     if pattern:
         # A layer is ONE branch: count each kind over its own layers.
         mixers += cfg.n_ssm_layers * _ssm_params(cfg)
+    elif getattr(cfg, "n_sconv_layers", 0):
+        mixers += cfg.n_sconv_layers * _sconv_params(cfg)
     elif n_attn != cfg.n_layers:
         hv = cfg.linear_n_v_heads
         linear = (

@@ -2329,6 +2329,16 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                 kv_cache_bytes=nbytes(cache.k, cache.v),
                 state_cache_bytes=nbytes(cache.state, cache.conv),
             )
+        if self.cfg.n_sconv_layers:  # tails beside the attention layers' k/v
+            cfg = self.cfg
+            self.last_pool_stats.update(
+                conv_cache_bytes=nbytes(cache.conv),
+                kv_cache_bytes=nbytes(cache.k, cache.v),
+                # k/v at every layer of the plan, as an all-attention model
+                kv_cache_bytes_all_attention=2 * cache.k.dtype.itemsize * (
+                    cfg.n_layers * b * s_total * cfg.kv_dim
+                ),
+            )
         if cache.latent is not None:  # beside what per-head k/v would take
             cfg = self.cfg
             self.last_pool_stats.update(

@@ -424,7 +424,7 @@ class TrainEngine(HostOffloadMixin, Engine):
                             batch["tokens"].size * cfg.n_experts_per_tok,
                         ),
                     }
-                if cfg.has_recurrent_state:
+                if cfg.has_recurrent_state or cfg.n_sconv_layers:
                     # Every segment start is a restart of the recurrence
                     # and of the conv inside a packed row.
                     seg = batch["segment_ids"]
@@ -444,6 +444,14 @@ class TrainEngine(HostOffloadMixin, Engine):
                         **stats,
                         "ssm/chunks": jnp.float32(cfg.n_ssm_layers * n_chunks),
                         "ssm/segment_restarts": cfg.n_ssm_layers * (
+                            jnp.sum(seg[:, 0] > 0) + jnp.sum(starts)
+                        ).astype(jnp.float32),
+                    }
+                if cfg.n_sconv_layers:
+                    # The short convolutions' restarts, summed over them.
+                    stats = {
+                        **stats,
+                        "sconv/segment_restarts": cfg.n_sconv_layers * (
                             jnp.sum(seg[:, 0] > 0) + jnp.sum(starts)
                         ).astype(jnp.float32),
                     }

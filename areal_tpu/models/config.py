@@ -21,15 +21,15 @@ FROZEN_LEAVES = ("router_bias",)
 
 # The residual branches x += f(norm(x)) a layer is made of: softmax attention
 # over per-head K/V, the same over the last `attn_window` keys alone, latent
-# attention over one row a token, Gated DeltaNet, Mamba-2, a dense MLP, the
-# mixture of experts.
-ATTENTION, WINDOW, LATENT, GDN, SSM, MLP, MOE = (
-    "attention", "window", "latent", "gdn", "ssm", "mlp", "moe",
+# attention over one row a token, Gated DeltaNet, Mamba-2, the gated short
+# convolution, a dense MLP, the mixture of experts.
+ATTENTION, WINDOW, LATENT, GDN, SSM, SCONV, MLP, MOE = (
+    "attention", "window", "latent", "gdn", "ssm", "sconv", "mlp", "moe",
 )
 # One character of `layer_pattern` -> that layer's ONE branch.
 _PATTERN_KINDS = {"M": (SSM,), "E": (MOE,), "*": (ATTENTION,)}
 # One character of `window_pattern` -> that layer's mixer.
-_WINDOW_KINDS = {"S": WINDOW, "F": ATTENTION}
+_WINDOW_KINDS = {"S": WINDOW, "F": ATTENTION, "C": SCONV}
 
 LayerKind = Tuple[str, ...]  # a layer's branches, in order
 
@@ -192,6 +192,9 @@ class ModelConfig:
     # weight-by-score on random weights states its own draw.  A checkpoint
     # brings its bias and never reads this.
     router_bias_init_std: float = 0.0
+    # What the sigmoid router adds to the chosen scores' sum before it
+    # divides by it (`moe_norm_topk`): deepseek_v3's 1e-20, lfm2_moe's 1e-6.
+    moe_norm_topk_eps: float = 1e-20
     # False: the shared expert's output is added as it is (no sigmoid gate).
     shared_expert_gated: bool = True
     # ---- a pattern of ONE-BRANCH layers (nemotron_h) ----
@@ -217,15 +220,20 @@ class ModelConfig:
     ssm_dt_min: float = 0.001
     ssm_dt_max: float = 0.1
     ssm_dt_floor: float = 0.0001
-    # ---- sliding-window layers beside full-attention layers (mellum) ----
+    # ---- a mixer a layer, by pattern (mellum, lfm2_moe) ----
     # One character a layer, in order: "S" softmax attention in which a
     # token sees the last `attn_window` keys of its sequence, itself
-    # included; "F" full causal attention.  Both kinds have the same leaves
-    # and an MLP (or the experts) behind them; the stack is scanned by
-    # repeats of the pattern's smallest unit.  "" = every attention layer
-    # is full.
+    # included; "F" full causal attention; "C" the gated short convolution
+    # (`models/short_conv.py`: no keys, a cache of the row's last
+    # `sconv_kernel` - 1 gated inputs).  Every layer has an MLP (or the
+    # experts) behind its mixer; "S" and "F" have the same leaves.  The
+    # first `first_k_dense` characters are the leading dense layers'
+    # mixers; the stack is scanned by repeats of the smallest unit of the
+    # rest.  "" = every attention layer is full.
     window_pattern: str = ""
     attn_window: int = 0
+    # Taps of the short convolution's depthwise causal kernel.
+    sconv_kernel: int = 3
     # The rotary embedding by kind of layer: a window layer takes plain
     # rope at `window_rope_theta`; a full layer takes YaRN where `rope_yarn_factor`
     # > 0 (inverse frequencies blended between theta's and theta's over
@@ -248,14 +256,14 @@ class ModelConfig:
                 f"{self.full_attn_interval} (full_attn_interval)"
             )
         if self.first_k_dense and not (
-            self.is_latent and self.is_moe
+            (self.is_latent or self.window_pattern) and self.is_moe
             and self.first_k_dense < self.n_layers
         ):
             raise NotImplementedError(
                 f"first_k_dense {self.first_k_dense}: leading dense layers "
-                "come before the scanned sparse layers of a latent-attention "
-                "mixture-of-experts model (their cache layers are latent "
-                "rows)"
+                "come before the scanned sparse layers of a mixture-of-"
+                "experts model whose mixers are latent attention or stated "
+                "by `window_pattern`"
             )
         if self.layer_pattern:
             self._check_pattern()
@@ -330,18 +338,26 @@ class ModelConfig:
         if set(pattern) - set(_WINDOW_KINDS) or len(pattern) != self.n_layers:
             raise ValueError(
                 f"window_pattern {pattern!r} is not {self.n_layers} "
-                "characters of 'S' (sliding window), 'F' (full attention)"
+                "characters of 'S' (sliding window), 'F' (full attention), "
+                "'C' (gated short convolution)"
             )
         if "S" in pattern and self.attn_window < 1:
             raise ValueError("an 'S' layer needs attn_window >= 1")
+        if "C" in pattern and self.sconv_kernel < 2:
+            raise ValueError("a 'C' layer needs sconv_kernel >= 2")
         if (
             self.layer_pattern or self.full_attn_interval > 1
-            or self.is_latent or self.first_k_dense or self.attn_gate
+            or self.is_latent or self.attn_gate
         ):
             raise NotImplementedError(
-                "sliding-window layers stand beside plain softmax-attention "
+                "a pattern of mixers stands beside plain softmax-attention "
                 "layers only: no one-branch pattern, Gated DeltaNet layers, "
-                "latent attention, leading dense layers or output gate"
+                "latent attention or output gate"
+            )
+        if "S" in pattern[:self.first_k_dense]:
+            raise NotImplementedError(
+                f"window_pattern {pattern!r}: a leading dense layer's mixer "
+                "is 'F' or 'C' (a ring before the scan was not tested)"
             )
 
     @property
@@ -350,23 +366,35 @@ class ModelConfig:
 
     @functools.cached_property
     def plan(self) -> LayerPlan:
-        """The layers as `prefix + unit x repeats`, from the four fields
-        that state them: `layer_pattern` (one character a layer, ONE
-        branch each; the unit is the shortest string the pattern repeats),
-        else `window_pattern` (window or full attention, an MLP each), else
-        periods of `full_attn_interval` - 1 Gated DeltaNet layers and
-        one attention layer, a mixer and an MLP each, behind
-        `first_k_dense` leading layers with a dense MLP."""
+        """The layers as `prefix + unit x repeats`, from the fields that
+        state them, in four shapes: `layer_pattern` (one character a
+        layer, ONE branch each; the unit is the shortest string the
+        pattern repeats); else `window_pattern` (one character a layer's
+        MIXER — window or full attention, the gated short convolution — an
+        MLP each: `first_k_dense` leading layers with a dense MLP and the
+        pattern's first mixers, then repeats of the shortest unit of the
+        rest); else periods of `full_attn_interval` - 1 Gated DeltaNet
+        layers and one attention layer, a mixer and an MLP each, behind
+        `first_k_dense` leading layers with a dense MLP whose mixer is the
+        model's (attention or latent attention); else, a period of one,
+        every layer that mixer and an MLP."""
         if self.layer_pattern:
             unit = tuple(_PATTERN_KINDS[c] for c in self.pattern_unit)
             return LayerPlan((), unit, len(self.layer_pattern) // len(unit))
         mlp = MOE if self.is_moe else MLP
         if self.window_pattern:
+            k = self.first_k_dense
+            rest = self.window_pattern[k:]
             unit = tuple(
-                (_WINDOW_KINDS[c], mlp)
-                for c in _shortest_unit(self.window_pattern)
+                (_WINDOW_KINDS[c], mlp) for c in _shortest_unit(rest)
             )
-            return LayerPlan((), unit, self.n_layers // len(unit))
+            return LayerPlan(
+                prefix=tuple(
+                    (_WINDOW_KINDS[c], MLP) for c in self.window_pattern[:k]
+                ),
+                unit=unit,
+                repeats=len(rest) // len(unit),
+            )
         mixer = LATENT if self.is_latent else ATTENTION
         n = self.full_attn_interval
         return LayerPlan(
@@ -394,6 +422,11 @@ class ModelConfig:
     @property
     def n_ssm_layers(self) -> int:
         return self.plan.count(SSM)
+
+    @property
+    def n_sconv_layers(self) -> int:
+        """Layers whose cache is the short convolution's last inputs."""
+        return self.plan.count(SCONV)
 
     @property
     def n_moe_layers(self) -> int:

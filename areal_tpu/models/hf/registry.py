@@ -15,7 +15,9 @@ Gated DeltaNet and gated attention, a gated shared expert), glm4_moe_lite
 (the deepseek_v3 block: latent attention, a sigmoid router with an
 untrained choice bias, an ungated shared expert, leading dense layers),
 nemotron_h (a pattern of one-branch layers: Mamba-2 mixers, ungated relu²
-experts behind the sigmoid router, attention without positions).
+experts behind the sigmoid router, attention without positions), lfm2_moe
+(gated short-convolution layers beside attention layers with per-head q/k
+norms, leading dense layers, the sigmoid router with its choice bias).
 """
 
 import dataclasses
@@ -1488,6 +1490,239 @@ register_hf_family(
 )
 
 
+# ---------------- lfm2_moe ----------------
+# LiquidAI/LFM2-8B-A1B: `layer_types` gives every layer its mixer behind
+# `operator_norm` — "conv", the gated short convolution (`conv.in_proj` ->
+# [B | C | u], a depthwise `conv.conv` of `conv_L_cache` taps without bias
+# over B * u, `conv.out_proj` of C * that), or "full_attention" (q/k/v/
+# `out_proj` without bias, an RMSNorm per head over q and k —
+# `q_layernorm` / `k_layernorm` — before rope on rotated halves) — and an
+# MLP behind `ffn_norm`: SwiGLU `feed_forward.w2(silu(w1 x) * w3 x)` in the
+# first `num_dense_layers`, after them a mixture of such experts behind a
+# sigmoid router (`feed_forward.gate`) whose top k are chosen by score +
+# `feed_forward.expert_bias` and weighted by their scores over (their sum +
+# 1e-6).  No shared expert; `model.embedding_norm` before a head tied to
+# the embedding.  A `share` group cuts the model to one expert-parallel
+# rank as for glm4_moe_lite: `num_experts` is then the number HELD here of
+# `share.router_num_experts`.
+
+_LFM2_LAYER_TYPES = {"conv": "C", "full_attention": "F"}
+
+
+def _lfm2_moe_config_from_hf(hf: dict) -> ModelConfig:
+    for key, fine in (("conv_bias", False), ("use_expert_bias", True)):
+        if hf.get(key, fine) != fine:
+            raise NotImplementedError(
+                f"lfm2_moe {key}={hf[key]!r} is not modelled")
+    n_layers = hf["num_hidden_layers"]
+    types = hf["layer_types"]
+    if len(types) != n_layers or set(types) - set(_LFM2_LAYER_TYPES):
+        raise ValueError(
+            f"lfm2_moe layer_types {types!r}: {n_layers} of "
+            f"{sorted(_LFM2_LAYER_TYPES)} are wanted"
+        )
+    share = hf.get("share") or {}
+    n_experts = hf["num_experts"]
+    width = share.get("router_num_experts", n_experts)
+    assumed = (hf.get("benchmark") or {}).get("assumed") or {}
+    return ModelConfig(
+        n_layers=n_layers,
+        hidden_dim=hf["hidden_size"],
+        n_q_heads=hf["num_attention_heads"],
+        n_kv_heads=hf["num_key_value_heads"],
+        head_dim=(
+            hf.get("head_dim")
+            or hf["hidden_size"] // hf["num_attention_heads"]
+        ),
+        intermediate_dim=hf["intermediate_size"],
+        vocab_size=hf["vocab_size"],
+        max_position_embeddings=hf.get("max_position_embeddings", 128000),
+        rope_theta=float(hf.get("rope_theta", 1000000.0)),
+        rms_norm_eps=hf.get("norm_eps", 1e-5),
+        qk_norm=True,
+        qk_norm_per_head=True,
+        tied_embeddings=hf.get("tie_word_embeddings", True),
+        first_k_dense=hf.get("num_dense_layers", 0),
+        window_pattern="".join(_LFM2_LAYER_TYPES[t] for t in types),
+        sconv_kernel=hf["conv_L_cache"],
+        n_experts=n_experts,
+        n_experts_per_tok=hf["num_experts_per_tok"],
+        moe_intermediate_dim=hf["moe_intermediate_size"],
+        moe_norm_topk=bool(hf.get("norm_topk_prob", True)),
+        moe_norm_topk_eps=1e-6,
+        moe_aux_loss_coef=0.0,
+        moe_score_func="sigmoid",
+        moe_routed_scale=float(hf.get("routed_scaling_factor", 1.0)),
+        router_bias_init_std=float(assumed.get("router_bias_init_std", 0.0)),
+        n_router_experts=0 if width == n_experts else width,
+        expert_offset=share.get("rank", 0) * n_experts,
+    )
+
+
+def _lfm2_moe_config_to_hf(cfg: ModelConfig) -> dict:
+    names = {c: t for t, c in _LFM2_LAYER_TYPES.items()}
+    out = {
+        "model_type": "lfm2_moe",
+        "architectures": ["Lfm2MoeForCausalLM"],
+        "torch_dtype": "bfloat16",
+        "num_hidden_layers": cfg.n_layers,
+        "layer_types": [names[c] for c in cfg.window_pattern],
+        "hidden_size": cfg.hidden_dim,
+        "num_attention_heads": cfg.n_q_heads,
+        "num_key_value_heads": cfg.n_kv_heads,
+        "intermediate_size": cfg.intermediate_dim,
+        "vocab_size": cfg.vocab_size,
+        "max_position_embeddings": cfg.max_position_embeddings,
+        "rope_theta": cfg.rope_theta,
+        "norm_eps": cfg.rms_norm_eps,
+        "tie_word_embeddings": cfg.tied_embeddings,
+        "conv_L_cache": cfg.sconv_kernel,
+        "conv_bias": False,
+        "num_dense_layers": cfg.first_k_dense,
+        "num_experts": cfg.n_experts,
+        "num_experts_per_tok": cfg.n_experts_per_tok,
+        "moe_intermediate_size": cfg.moe_intermediate_dim,
+        "norm_topk_prob": cfg.moe_norm_topk,
+        "routed_scaling_factor": cfg.moe_routed_scale,
+        "use_expert_bias": True,
+    }
+    if cfg.head_dim * cfg.n_q_heads != cfg.hidden_dim:
+        out["head_dim"] = cfg.head_dim
+    if cfg.expert_share:
+        out["share"] = {
+            "router_num_experts": cfg.router_width,
+            "rank": cfg.expert_offset // cfg.n_experts,
+        }
+    return out
+
+
+_LFM2 = "model.layers.{}."
+# ours <- the HF name under the layer, and how: "T" transposed ([out, in]
+# -> [in, out]), "" as it is, "taps" the conv's [C, 1, K] -> [K, C].
+_LFM2_NORMS = (
+    ("ln1", "operator_norm.weight", ""), ("ln2", "ffn_norm.weight", ""),
+)
+_LFM2_MIXER = {
+    "C": (
+        ("sc_in", "conv.in_proj.weight", "T"),
+        ("sc_conv", "conv.conv.weight", "taps"),
+        ("sc_out", "conv.out_proj.weight", "T"),
+    ),
+    "F": (
+        ("wq", "self_attn.q_proj.weight", "T"),
+        ("wk", "self_attn.k_proj.weight", "T"),
+        ("wv", "self_attn.v_proj.weight", "T"),
+        ("wo", "self_attn.out_proj.weight", "T"),
+        ("q_norm", "self_attn.q_layernorm.weight", ""),
+        ("k_norm", "self_attn.k_layernorm.weight", ""),
+    ),
+}
+_LFM2_DENSE = (
+    ("wg", "feed_forward.w1.weight", "T"),
+    ("wu", "feed_forward.w3.weight", "T"),
+    ("wd", "feed_forward.w2.weight", "T"),
+)
+_LFM2_SPARSE = (
+    ("router", "feed_forward.gate.weight", "T"),
+    ("router_bias", "feed_forward.expert_bias", ""),
+)
+_LFM2_EXPERT = (("wg", "w1"), ("wu", "w3"), ("wd", "w2"))
+_LFM2_READ = {
+    "": lambda w: w, "T": lambda w: w.T, "taps": lambda w: w[:, 0, :].T,
+}
+_LFM2_WRITE = {
+    "": lambda w: w, "T": lambda w: w.T, "taps": lambda w: w.T[:, None, :],
+}
+
+
+def _lfm2_tensors(cfg):
+    """Every tensor of the layers as (our leaf under `blocks`, its place in
+    the leaf's stack — (layer,) or (layer, expert) —, the HF name, how):
+    the leading dense layers' under `dense_*`, a mixer's leaf stacked over
+    its group's layers with that mixer, in layer order."""
+    k = cfg.first_k_dense
+    for pre, layers, mlp in (
+        (DENSE_PREFIX, range(k), _LFM2_DENSE),
+        ("", range(k, cfg.n_layers), _LFM2_SPARSE),
+    ):
+        seen = {c: 0 for c in _LFM2_MIXER}
+        for j, i in enumerate(layers):
+            base, kind = _LFM2.format(i), cfg.window_pattern[i]
+            for ours, theirs, how in _LFM2_NORMS + mlp:
+                yield pre + ours, (j,), base + theirs, how
+            for ours, theirs, how in _LFM2_MIXER[kind]:
+                yield pre + ours, (seen[kind],), base + theirs, how
+            seen[kind] += 1
+            if pre:
+                continue
+            for ours, theirs in _LFM2_EXPERT:
+                for e in range(cfg.n_experts):
+                    yield ours, (j, e), (
+                        f"{base}feed_forward.experts."
+                        f"{cfg.expert_offset + e}.{theirs}.weight"
+                    ), "T"
+
+
+def _lfm2_moe_params_from_sd(cfg, sd, dtype=None):
+    import jax.numpy as jnp
+
+    dtype = dtype or cfg.dtype
+
+    def get(name):
+        if name not in sd:
+            raise KeyError(f"missing tensor {name!r} in checkpoint")
+        return np.asarray(sd[name], np.float32)
+
+    parts: Dict[str, dict] = {}
+    for leaf, at, name, how in _lfm2_tensors(cfg):
+        parts.setdefault(leaf, {})[at] = _LFM2_READ[how](get(name))
+
+    def stack(by_place):  # {(layer,) or (layer, expert): tensor}
+        lead = tuple(np.max(list(by_place), axis=0) + 1)
+        flat = np.stack([by_place[at] for at in np.ndindex(*lead)])
+        return flat.reshape(*lead, *flat.shape[1:])
+
+    params = {
+        "embed": jnp.asarray(get("model.embed_tokens.weight"), dtype),
+        "blocks": {
+            leaf: jnp.asarray(stack(p), dtype) for leaf, p in parts.items()
+        },
+        "final_ln": jnp.asarray(get("model.embedding_norm.weight"), dtype),
+    }
+    if not cfg.tied_embeddings:
+        params["lm_head"] = jnp.asarray(get("lm_head.weight").T, dtype)
+    return params
+
+
+def _lfm2_moe_params_to_sd(cfg, params):
+    from areal_tpu.base.distributed import to_host
+
+    def host(x):
+        return to_host(x).astype(np.float32, copy=False)
+
+    blocks = {n: host(w) for n, w in params["blocks"].items()}
+    out = {
+        "model.embed_tokens.weight": host(params["embed"]),
+        "model.embedding_norm.weight": host(params["final_ln"]),
+    }
+    if not cfg.tied_embeddings:
+        out["lm_head.weight"] = np.ascontiguousarray(host(params["lm_head"]).T)
+    for leaf, at, name, how in _lfm2_tensors(cfg):
+        out[name] = np.ascontiguousarray(_LFM2_WRITE[how](blocks[leaf][at]))
+    return out
+
+
+register_hf_family(
+    HFFamily(
+        "lfm2_moe",
+        _lfm2_moe_config_from_hf,
+        _lfm2_moe_config_to_hf,
+        params_from_sd=_lfm2_moe_params_from_sd,
+        params_to_sd=_lfm2_moe_params_to_sd,
+    )
+)
+
+
 # ---------------- gpt2 ----------------
 # Different lineage: learned positions, LayerNorm with bias, fused c_attn,
 # plain (non-gated) gelu MLP, biases everywhere, Conv1D weights stored
@@ -1641,6 +1876,8 @@ def infer_model_type(cfg: ModelConfig) -> str:
         return "nemotron_h"
     if cfg.is_latent:
         return "glm4_moe_lite"
+    if cfg.n_sconv_layers:
+        return "lfm2_moe"
     if cfg.window_pattern or cfg.rope_yarn_factor:
         return "mellum" if cfg.is_moe else "mistral"
     if cfg.is_moe:

@@ -155,6 +155,21 @@ def conv_tail(x: jax.Array, segment_ids: jax.Array, kk: int) -> jax.Array:
     return jnp.where((seg == segment_ids[:, -1:])[..., None], tail, 0)
 
 
+def conv_tail_at(
+    x: jax.Array, segment_ids: jax.Array, last: jax.Array, kk: int
+) -> jax.Array:
+    """The K-1 conv inputs that end at each row's position `last` [B], zero
+    where they lie before the row or in another segment than `last`'s:
+    what a decode step carries on from, whichever side the row's pads lie
+    on.  [B, K-1, C]."""
+    pos = last[:, None] - jnp.arange(kk - 2, -1, -1)[None, :]  # [B, K-1]
+    at = jnp.maximum(pos, 0)
+    tail = jnp.take_along_axis(x, at[..., None], axis=1)
+    seg = jnp.take_along_axis(segment_ids, at, axis=1)
+    seg_last = jnp.take_along_axis(segment_ids, last[:, None], axis=1)
+    return jnp.where(((pos >= 0) & (seg == seg_last))[..., None], tail, 0)
+
+
 def gated_delta_chunked(
     q: jax.Array,  # [B, S, H, dk] fp32, normalised and scaled
     k: jax.Array,  # [B, S, H, dk] fp32, normalised

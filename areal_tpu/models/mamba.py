@@ -46,7 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from areal_tpu.models.config import ModelConfig
-from areal_tpu.models.linear_attention import causal_conv
+from areal_tpu.models.linear_attention import causal_conv, conv_tail_at
 
 Params = Dict[str, jax.Array]
 
@@ -209,20 +209,6 @@ def ssd_chunked(
     return y, state.reshape(b, h, p, n)
 
 
-def _tail_at(
-    x: jax.Array, segment_ids: jax.Array, last: jax.Array, kk: int
-) -> jax.Array:
-    """The K-1 conv inputs that end at each row's position `last` [B], zero
-    where they lie before the row or in another segment than `last`'s:
-    what `ssm_step` carries on from.  [B, K-1, C]."""
-    pos = last[:, None] - jnp.arange(kk - 2, -1, -1)[None, :]  # [B, K-1]
-    at = jnp.maximum(pos, 0)
-    tail = jnp.take_along_axis(x, at[..., None], axis=1)
-    seg = jnp.take_along_axis(segment_ids, at, axis=1)
-    seg_last = jnp.take_along_axis(segment_ids, last[:, None], axis=1)
-    return jnp.where(((pos >= 0) & (seg == seg_last))[..., None], tail, 0)
-
-
 @jax.named_scope("layer/ssm")
 def ssm_forward(
     h: jax.Array,  # [B, S, D] normed layer input
@@ -253,7 +239,8 @@ def ssm_forward(
     if with_state:
         idx = jnp.arange(segment_ids.shape[-1])
         last = jnp.max(jnp.where(segment_ids > 0, idx, 0), axis=-1)
-        return out, state, _tail_at(xbc, segment_ids, last, cfg.ssm_conv_kernel)
+        return out, state, conv_tail_at(
+            xbc, segment_ids, last, cfg.ssm_conv_kernel)
     return out
 
 

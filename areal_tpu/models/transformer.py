@@ -51,6 +51,7 @@ from areal_tpu.models.config import (
     LATENT,
     MLP,
     MOE,
+    SCONV,
     SSM,
     WINDOW,
     LayerKind,
@@ -63,6 +64,12 @@ from areal_tpu.models.linear_attention import (
     linear_attn_step,
 )
 from areal_tpu.models.mamba import SSM_LEAVES, init_ssm, ssm_forward, ssm_step
+from areal_tpu.models.short_conv import (
+    SCONV_LEAVES,
+    init_sconv,
+    sconv_forward,
+    sconv_step,
+)
 from areal_tpu.ops.attention import (
     decode_attention,
     inner_scope,
@@ -152,19 +159,27 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         blocks.update(init_ssm(cfg, ks[6], scanned(SSM), dense))
     if scanned(GDN):
         blocks.update(init_linear_attn(cfg, ks[6], scanned(GDN), dense))
-    if cfg.first_k_dense:
-        # The leading dense layers: their own leaves, stacked [K, ...]
-        # under `dense_*`, the MLP at the dense width.
-        K = cfg.first_k_dense
+    if scanned(SCONV):
+        blocks.update(init_sconv(cfg, ks[6], scanned(SCONV), dense))
+    if plan.prefix:
+        # The leading dense layers: their own leaves, stacked over the
+        # leading layers that own them under `dense_*`, the mixers the
+        # plan's, the MLP at the dense width.
+        K, n_attn = len(plan.prefix), plan.in_prefix(ATTENTION, LATENT)
         kd = jax.random.split(jax.random.fold_in(k_blocks, 1), 9)
         lead = {
             "ln1": norm_init((K, D), dtype),
-            **attn_leaves(K, kd),
+            **(attn_leaves(n_attn, kd) if n_attn else {}),
             "ln2": norm_init((K, D), dtype),
             "wg": dense(kd[6], (K, D, F), D),
             "wu": dense(kd[7], (K, D, F), D),
             "wd": dense(kd[8], (K, F, D), F),
         }
+        if plan.in_prefix(SCONV):
+            lead.update(init_sconv(
+                cfg, jax.random.fold_in(k_blocks, 2), plan.in_prefix(SCONV),
+                dense,
+            ))
         blocks.update({DENSE_PREFIX + n: w for n, w in lead.items()})
     if cfg.norm_type == "layernorm":
         blocks["ln1_b"] = jnp.zeros((L, D), dtype)
@@ -432,7 +447,9 @@ def _moe_route_sigmoid(router_logits: jax.Array, blk: Params, cfg: ModelConfig):
     _, top_idx = jax.lax.top_k(scores + bias, cfg.n_experts_per_tok)
     top_w = jnp.take_along_axis(scores, top_idx, axis=-1)
     if cfg.moe_norm_topk:
-        top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
+        top_w = top_w / (
+            jnp.sum(top_w, axis=-1, keepdims=True) + cfg.moe_norm_topk_eps
+        )
     top_w = top_w * cfg.moe_routed_scale
     if cfg.expert_share:
         top_idx = _local_numbering(top_idx, cfg)
@@ -1008,7 +1025,8 @@ def _packed_branches(
     name — `aux`, the MoE aux loss; `counts`, rows per expert over the real
     tokens [E] int32; and what it leaves in the cache, by `KVCache` field:
     k and v, the one latent row a token and, `with_state`, a recurrent
-    branch's final state and conv tail at the row's last valid token).
+    branch's final state and conv tail at the row's last valid token, the
+    short convolution's tail there).
     `attn_args`: `_attention`'s from `cos` on.  `window_rope`: the window
     layers' (cos, sin) in a plan that has them (`_rope`); `ring`: the
     entries of their cache, where the caller keeps what they leave (the
@@ -1046,12 +1064,19 @@ def _packed_branches(
             "wk": _ring_tail(left["k"], ring), "wv": _ring_tail(left["v"], ring)
         }
 
+    def short_conv(h, blk):
+        if not with_state:
+            return sconv_forward(h, blk, cfg, segment_ids), {}
+        out, tail = sconv_forward(h, blk, cfg, segment_ids, with_state=True)
+        return out, {"conv": tail}
+
     return {
         ATTENTION: attention,
         WINDOW: window,
         LATENT: attention,
         GDN: recurrent(linear_attn_forward),
         SSM: recurrent(ssm_forward),
+        SCONV: short_conv,
         MLP: lambda h, blk: (
             _mlp_dense(h, blk, cfg), {"aux": jnp.zeros((), jnp.float32)}),
         MOE: experts,
@@ -1294,6 +1319,14 @@ _NO_WINDOW_LAYOUT = (
 )
 
 
+_NO_SCONV_LAYOUT = (
+    "gated short-convolution layers beside attention layers run under data "
+    "and fsdp sharding only: the conv's channels and its cached tail are "
+    "not split over `model`, the conv has no halo over a split sequence, "
+    "and the pipeline's stage scans one kind of layer (PERF.md section 7)"
+)
+
+
 _NO_PATTERN_LAYOUT = (
     "a pattern of one-branch layers (Mamba-2, experts or attention alone) "
     "runs under data and fsdp sharding only: the Mamba heads, their conv "
@@ -1313,6 +1346,9 @@ def plan_refusal(cfg: ModelConfig, serving: bool):
     if plan.count(GDN):
         return HybridLayoutError(
             _NO_SERVING_STATE if serving else _NO_HYBRID_LAYOUT)
+    if plan.count(SCONV):
+        return HybridLayoutError(
+            _NO_SERVING_SCONV if serving else _NO_SCONV_LAYOUT)
     if plan.count(LATENT) or plan.prefix:
         return LatentLayoutError(
             _NO_SERVING_LATENT if serving else _NO_LATENT_LAYOUT)
@@ -1346,6 +1382,7 @@ _LEAF_BRANCHES = {
         _FULL_ATTN_LEAVES + _LATENT_LEAVES, (ATTENTION, WINDOW, LATENT)),
     **dict.fromkeys(LINEAR_LEAVES, (GDN,)),
     **dict.fromkeys(SSM_LEAVES, (SSM,)),
+    **dict.fromkeys(SCONV_LEAVES, (SCONV,)),
     **dict.fromkeys(_MOE_LEAVES + ("bproj", "bfc"), (MLP, MOE)),
 }
 # The norm in front of a layer's first and second branch.
@@ -1394,13 +1431,17 @@ def _prefix_layers(cfg: ModelConfig, blocks: Params) -> list:
     its index among the prefix's layers with each of its branches, its
     leaves under the layer's own names); [] without leading layers."""
     prefix = cfg.plan.prefix
+    lead = {
+        n[len(DENSE_PREFIX):]: w
+        for n, w in blocks.items() if n.startswith(DENSE_PREFIX)
+    }
     return [
         (
             kind,
             {b: sum(b in k for k in prefix[:i]) for b in kind},
-            {
-                n[len(DENSE_PREFIX):]: w[i]
-                for n, w in blocks.items() if n.startswith(DENSE_PREFIX)
+            {  # a leaf is stacked over the leading layers that own it
+                n: w[sum(_owns(k, n) for k in prefix[:i])]
+                for n, w in lead.items() if _owns(kind, n)
             },
         )
         for i, kind in enumerate(prefix)
@@ -1604,6 +1645,8 @@ class KVCache:
       `conv` — Gated DeltaNet [layers, B, hv, dk, dv] and [layers, B, K-1,
       C], Mamba-2 [layers, B, H, head_dim, N] and [layers, B, K-1,
       conv_dim] (`_RECURRENT_SHAPES`);
+    - the gated short convolution: `conv` alone, [layers, B, K-1, D] in the
+      compute type — the row's last gated inputs — and no `state`;
     - sliding-window attention: `wk` / `wv` [layers, B, ring, n_kv,
       head_dim], ring = min(attn_window, S_max): slot s of the row lies at
       entry s mod ring, so the ring holds the last `ring` slots written
@@ -1628,7 +1671,7 @@ _CACHE_FIELDS = {
     "k": (ATTENTION,),
     "v": (ATTENTION,),
     "state": (GDN, SSM),
-    "conv": (GDN, SSM),
+    "conv": (GDN, SSM, SCONV),
     "latent": (LATENT,),
     "wk": (WINDOW,),
     "wv": (WINDOW,),
@@ -1706,6 +1749,11 @@ def init_kv_cache(
             cache.state = jnp.zeros(
                 (plan.count(branch), batch, *state), jnp.float32)
             cache.conv = jnp.zeros((plan.count(branch), batch, *conv), dtype)
+    if plan.count(SCONV):  # a tail and no state
+        cache.conv = jnp.zeros(
+            (plan.count(SCONV), batch, cfg.sconv_kernel - 1, cfg.hidden_dim),
+            dtype,
+        )
     if plan.count(WINDOW):
         ring = (plan.count(WINDOW), batch, min(cfg.attn_window, s_max),
                 cfg.n_kv_heads, cfg.head_dim)
@@ -2065,6 +2113,12 @@ def decode_step(
 
         return branch
 
+    def short_conv(h, blk, cache, li):
+        """The gated short convolution shifts layer li of the tails in
+        place."""
+        out, cc = sconv_step(h, blk, cfg, cache.conv, li)
+        return out, dataclasses.replace(cache, conv=cc), None
+
     def experts(h, blk, cache, li):
         out, _, counts = _mlp_moe(
             h, blk, cfg, stacked=stacked, layer=li, kernel=bool(expert_kernel)
@@ -2081,6 +2135,7 @@ def decode_step(
         LATENT: attend_latent,
         GDN: recurrent(linear_attn_step, row_kernel),
         SSM: recurrent(ssm_step),
+        SCONV: short_conv,
         MLP: lambda h, blk, cache, li: (_mlp_dense(h, blk, cfg), cache, None),
         MOE: experts,
     }
@@ -2252,6 +2307,16 @@ _NO_SERVING_PATTERN = (
     "one-branch layers generates on the static decode program only (at "
     "most max_decode_batch requests, no stop sequences, no speculative "
     "decoding, max_new_tokens within static_path_max_new)"
+)
+
+
+_NO_SERVING_SCONV = (
+    "a short convolution's tail has no slot beside the page pool on the "
+    "serving plane yet, and its chunk has one kind of layer and none before "
+    "the scan: gated short-convolution layers beside attention layers "
+    "generate on the static decode program only (at most max_decode_batch "
+    "requests, no stop sequences, no speculative decoding, max_new_tokens "
+    "within static_path_max_new)"
 )
 
 

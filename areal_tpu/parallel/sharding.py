@@ -118,6 +118,14 @@ _BLOCK_RULES: Dict[str, P] = {
     "ssm_dt_bias": P(PIPE_AXIS, None),
     "ssm_norm": P(PIPE_AXIS, None),
     "ssm_out": P(PIPE_AXIS, None, FSDP_AXIS),
+    # Gated short convolution: ZeRO-sharded over fsdp on the projections'
+    # hidden dimension, NOT split over `model` — B | C | u sit in `sc_in`'s
+    # packed output axis and the conv's channels and cached tail would have
+    # to split with them; `attn_dispatch` refuses the plan on a mesh with
+    # model > 1 by name.
+    "sc_in": P(PIPE_AXIS, FSDP_AXIS, None),
+    "sc_conv": P(PIPE_AXIS, None, None),
+    "sc_out": P(PIPE_AXIS, None, FSDP_AXIS),
 }
 
 _TOP_RULES: Dict[str, P] = {

@@ -149,7 +149,46 @@ FLASH_CELLS = {
     "7B per-chip rows [28,2048,128]": (1, 2048, 28, 4, 128, [400] * 5),
     "a row in two chunks [12,65536,128]":
         (1, 65536, 12, 2, 128, [30000, 20000, 9000, 4000]),
+    "lfm2 train row, heads of 64 [32,8192,64]":
+        (1, 8192, 32, 8, 64, [4608, 2763, 821]),
 }
+
+
+def _flash_head_64(fa, attention, dt, tol, on_tpu):
+    """The three flash kernels at heads of 64 — half a lane tile, lfm2_moe's
+    and no other configuration's — against the dense mask, ragged packed
+    rows, 32 query heads over 8 key/value heads."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    s = 1024 if on_tpu else 256
+    rng = np.random.default_rng(64)
+    q, k, v, do = (
+        jnp.asarray(rng.standard_normal((2, s, h, 64)), dt)
+        for h in (32, 8, 8, 32)
+    )
+    ids = np.zeros((2, s), np.int32)
+    ids[0, : s // 2], ids[0, s // 2: s - 40] = 1, 2
+    ids[1, :60], ids[1, 60: s - 100], ids[1, s - 100: s - 10] = 1, 2, 3
+    seg = jnp.asarray(ids)
+    real = (seg > 0)[..., None, None]
+
+    def pulled(attend):
+        def f(q, k, v):
+            return jnp.where(real, attend(q, k, v, seg), 0).astype(jnp.float32)
+
+        out, pull = jax.vjp(f, q, k, v)
+        return (out,) + pull(do.astype(jnp.float32))
+
+    want = jax.jit(lambda: pulled(attention.packed_attention_reference))()
+    got = jax.jit(lambda: pulled(fa.flash_attention))()
+    for name, a, b in zip(("o", "dq", "dk", "dv"), got, want):
+        err = float(jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32))))
+        scale = float(jnp.max(jnp.abs(b.astype(jnp.float32))))
+        check(err <= tol * max(scale, 1.0),
+              f"flash at head_dim 64, {name} == dense mask "
+              f"(max err {err:.2e} of {scale:.2e})")
 
 
 def _flash_cell_shapes(fa, dt, on_tpu, reps=5):
@@ -278,6 +317,7 @@ def phase_kernels(geom, on_tpu):
     check(err <= tol,
           f"shard_mapped flash ({layout}) == reference (max err {err:.2e})")
 
+    _flash_head_64(fa, attention, dt, tol, on_tpu)
     _flash_cell_shapes(fa, dt, on_tpu)
 
     _paged_cell_shape(attention, geom, dt, tol, on_tpu)

@@ -118,6 +118,43 @@ MELLUM_ENTRIES = [
 ]
 
 
+LFM2_CELL = "lfm2-ctxrl32-4k"
+LFM2_CONFIG = "lfm2-8b-a1b-e8"
+# PR 48's entries, in ISSUE 48's order: six of the short-convolution /
+# attention mix's own and the five twins the share cells have, over
+# `benchmark/peaks_sconv.py`.
+LFM2_ENTRIES = [
+    ("sconv_decode_ms", "ms", "lower", "device_trace", "model step",
+     "gen_tokens_per_s"),
+    ("sconv_decode_roofline", "%", "higher", "device_trace", "kernels",
+     "gen_tokens_per_s"),
+    ("sconv_train_share", "%", "lower", "device_trace", "model step",
+     "train_tokens_per_s"),
+    ("sconv_train_mfu", "%", "higher", "device_trace", "kernels",
+     "train_tokens_per_s"),
+    ("sconv_cache_share", "%", "lower", "program_counter", "generator",
+     "gen_tokens_per_s"),
+    ("flash_mfu_d64", "%", "higher", "device_trace", "kernels",
+     "train_tokens_per_s"),
+    ("mfu_train_sconv", "%", "higher", "host_clock", "model step",
+     "train_tokens_per_s"),
+    ("mfu_gen_sconv", "%", "higher", "host_clock", "model step",
+     "gen_tokens_per_s"),
+    ("decode_hbm_share_sconv", "%", "higher", "device_trace", "model step",
+     "gen_tokens_per_s"),
+    ("moe_decode_mlp_roofline_sconv", "%", "higher", "device_trace",
+     "kernels", "gen_tokens_per_s"),
+    ("moe_train_mlp_mfu_sconv", "%", "higher", "device_trace", "kernels",
+     "train_tokens_per_s"),
+]
+# The lists a static MoE share cell joins (ISSUEs 38, 40, 44, 48).
+SHARE_CELL_LISTS = {
+    "gen_tokens_per_s", "decode_ms_per_step", "decode_loop_ms",
+    "moe_experts_touched", "moe_decode_mlp_ms", "moe_route_share",
+    "moe_local_rows_share", "sample_draw_ms", "moe_train_rows_gathered_share",
+}
+
+
 def _at(entries, name):
     """Index of the entry called `name`: the benchmark is pinned by NAME,
     so that what a later PR appends moves no case."""
@@ -327,19 +364,21 @@ def test_sample_draw_ms_is_the_scopes_seconds_per_decode_step(trace, gen, want):
 
 def test_its_entry_is_the_last_and_lists_the_share_cells(monkeypatch):  # noqa: F811
     """PR 41's case pins `moe_train_rows_gathered_share` to the three share
-    cells it had; PR 44's cell is a fourth (it trains on the slab too) and
-    is appended, last.  So: the case on the benchmark as it stood before
-    that cell — `benchmark/tests/` is not a model_config PR's to edit."""
+    cells it had; PR 44's cell is a fourth and PR 48's a fifth (they train
+    on the slab too), each appended, last.  So: the case on the benchmark
+    as it stood before those cells — `benchmark/tests/` is not a
+    model_config PR's to edit."""
     from benchmark.tests import test_moe_train_rows_gathered_share as cases
 
     entry = SPEC["per_layer"][_at(SPEC["per_layer"], cases.reader.__name__.rsplit(".", 1)[1])]
-    assert entry["workloads"] == cases.SHARE_CELLS + [MELLUM_CELL]
+    later = [MELLUM_CELL, LFM2_CELL]
+    assert entry["workloads"] == cases.SHARE_CELLS + later
     before = json.loads(json.dumps(SPEC))
     before["workloads"] = [
-        w for w in before["workloads"] if w["name"] != MELLUM_CELL]
+        w for w in before["workloads"] if w["name"] not in later]
     for m in before["end_to_end"] + before["per_layer"]:
-        if MELLUM_CELL in m.get("workloads", []):
-            m["workloads"].remove(MELLUM_CELL)
+        if "workloads" in m:
+            m["workloads"] = [c for c in m["workloads"] if c not in later]
     monkeypatch.setattr(files, "benchmark_json", lambda: before)
     cases.test_its_entry_is_the_last_and_lists_the_share_cells()
 
@@ -352,20 +391,20 @@ def test_the_mellum_cell_is_as_the_issue_parametrised_it():
     from benchmark.traffic.math_prompts import quantile_lengths
 
     cell, config, traffic = files.load_cell(MELLUM_CELL)
-    assert SPEC["workloads"][-1] == {
+    entry = SPEC["workloads"][_at(SPEC["workloads"], MELLUM_CELL)]
+    assert entry == {
         "name": MELLUM_CELL, "config": MELLUM_CONFIG,
-        "traffic": "rollout32-ctx4k-512", "chips": 1,
-        "why": SPEC["workloads"][-1]["why"],
+        "traffic": "rollout32-ctx4k-512", "chips": 1, "why": entry["why"],
     }
-    conf = SPEC["configs"][-1]
+    conf = SPEC["configs"][_at(SPEC["configs"], MELLUM_CONFIG)]
     assert conf == {
         "name": MELLUM_CONFIG, "source": config["benchmark"]["source"],
         "file": f"benchmark/configs/{MELLUM_CONFIG}.json",
         "reduced": ["num_hidden_layers", "num_experts", "vocab_size"],
         "why": conf["why"],
     }
-    assert len(SPEC["workloads"][-1]["why"]) <= 200 and len(conf["why"]) <= 200
-    assert len(CELLS) == 9 and len(SPEC["configs"]) == 7
+    assert len(entry["why"]) <= 200 and len(conf["why"]) <= 200
+    assert CELLS.index(MELLUM_CELL) == 8
     assert [w["name"] for w in SPEC["workloads"] if w["chips"] == 4] == [
         "q7b-realloc-4chip"]
     assert (cell["route"], cell["timed_steps"], cell["traffic_seed"]) == (
@@ -384,27 +423,81 @@ def test_the_mellum_cell_is_as_the_issue_parametrised_it():
     assert lengths == [816, 1203, 1527, 1864, 2251, 2746, 3487, 4096]
     assert 4 * (sum(lengths) + 8 * 512) == 88344  # trained tokens a step
     n = len(MELLUM_ENTRIES)
-    assert SPEC["per_layer"][-n:] == [
+    first = _at(SPEC["per_layer"], MELLUM_ENTRIES[0][0])
+    assert SPEC["per_layer"][first: first + n] == [
         {"name": name, "unit": unit, "better": better, "source": source,
          "layer": layer, "moves": moves, "workloads": [MELLUM_CELL]}
         for name, unit, better, source, layer, moves in MELLUM_ENTRIES
     ]
-    assert SPEC["per_layer"][-n - 1]["name"] == "moe_train_rows_gathered_share"
+    assert SPEC["per_layer"][first - 1]["name"] == "moe_train_rows_gathered_share"
     listed = {
         m["name"] for m in SPEC["end_to_end"] + SPEC["per_layer"]
         if MELLUM_CELL in m.get("workloads", [])
     }
-    assert listed == {name for name, *_ in MELLUM_ENTRIES} | {
-        "gen_tokens_per_s", "decode_ms_per_step", "decode_loop_ms",
-        "moe_experts_touched", "moe_decode_mlp_ms", "moe_route_share",
-        "moe_local_rows_share", "sample_draw_ms",
-        "moe_train_rows_gathered_share",
-    }
+    assert listed == {name for name, *_ in MELLUM_ENTRIES} | SHARE_CELL_LISTS
+    then = CELLS[: CELLS.index(MELLUM_CELL) + 1]
     for m in SPEC["end_to_end"] + SPEC["per_layer"]:
         if MELLUM_CELL in m.get("workloads", []):
-            assert m["workloads"][-1] == MELLUM_CELL, m["name"]
+            assert [w for w in m["workloads"] if w in then][-1] == MELLUM_CELL, (
+                m["name"])
     for name in listed:
         assert callable(files.load_module("metrics", name).read), name
+
+
+def test_the_lfm2_cell_is_as_the_issue_parametrised_it():
+    """ISSUE 48: one configuration, one cell on the EXISTING traffic file
+    of the window / full cell, eleven metrics of its own at the end of
+    `per_layer`, and its name appended, last, to the nine lists whose
+    arithmetic holds for a static MoE share cell — and to none that
+    divides by another family's `peaks*.py`."""
+    cell, config, traffic = files.load_cell(LFM2_CELL)
+    assert SPEC["workloads"][-1] == {
+        "name": LFM2_CELL, "config": LFM2_CONFIG,
+        "traffic": "rollout32-ctx4k-512", "chips": 1,
+        "why": SPEC["workloads"][-1]["why"],
+    }
+    conf = SPEC["configs"][-1]
+    assert conf == {
+        "name": LFM2_CONFIG, "source": config["benchmark"]["source"],
+        "file": f"benchmark/configs/{LFM2_CONFIG}.json",
+        "reduced": ["num_hidden_layers", "num_experts", "vocab_size"],
+        "why": conf["why"],
+    }
+    assert conf["source"] == (
+        "https://huggingface.co/LiquidAI/LFM2-8B-A1B/blob/main/config.json")
+    assert len(SPEC["workloads"][-1]["why"]) <= 200 and len(conf["why"]) <= 200
+    assert len(CELLS) == 10 and len(SPEC["configs"]) == 8
+    assert [w["name"] for w in SPEC["workloads"] if w["chips"] == 4] == [
+        "q7b-realloc-4chip"]
+    assert (cell["route"], cell["timed_steps"], cell["traffic_seed"]) == (
+        "static", 4, 48)
+    assert config["model_type"] == "lfm2_moe"
+    assert config["benchmark"]["reference"] == "lfm2_moe"
+    assert config["benchmark"]["weights_seed"] == 48
+    assert config["benchmark"]["layout"] == {
+        "chips": 1, "actor_parallel": "d1", "gen_parallel": None}
+    # The traffic file is the window / full cell's, unchanged.
+    assert files.load_cell(MELLUM_CELL)[2] == traffic
+    n = len(LFM2_ENTRIES)
+    assert SPEC["per_layer"][-n:] == [
+        {"name": name, "unit": unit, "better": better, "source": source,
+         "layer": layer, "moves": moves, "workloads": [LFM2_CELL]}
+        for name, unit, better, source, layer, moves in LFM2_ENTRIES
+    ]
+    assert SPEC["per_layer"][-n - 1]["name"] == MELLUM_ENTRIES[-1][0]
+    listed = {
+        m["name"] for m in SPEC["end_to_end"] + SPEC["per_layer"]
+        if LFM2_CELL in m.get("workloads", [])
+    }
+    assert listed == {name for name, *_ in LFM2_ENTRIES} | SHARE_CELL_LISTS
+    for m in SPEC["end_to_end"] + SPEC["per_layer"]:
+        if LFM2_CELL in m.get("workloads", []):
+            assert m["workloads"][-1] == LFM2_CELL, m["name"]
+    for name in listed:
+        assert callable(files.load_module("metrics", name).read), name
+    # Every metric without a list is the cell's too, and its reader loads.
+    for m in files.metrics_for(LFM2_CELL, traced=True):
+        assert hasattr(files.load_module("metrics", m["name"]), "read")
 
 
 def test_every_name_in_benchmark_json_is_a_cell_and_its_files_resolve():
@@ -1077,3 +1170,148 @@ def test_the_swa_readers_say_nothing_without_their_scopes_or_counters():
         2 * 4096 * (4 * (21_233_664 + 2 * 3 * h * f + h * 64) + h * 24576)
         + 4 * 32 * 128 * (1 * 4096 ** 2 / 2
                           + 3 * peaks_swa.window_pairs(4096, 1024)))
+
+
+def test_cpu_rehearsal_of_the_lfm2_cell_is_correct():
+    """The short-convolution / attention cell end to end at toy size (the
+    config's `toy` group keeps the plan c c A c c c: both leading dense
+    layers and the period whole, heads of 16, 4 of 8 experts): the static
+    program through tails and cache, the hand-back of all 26 leaves, the
+    reference and its check of the generator's own 32-slot program (tails
+    and K/V rows) for generator and trainer."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=files.ROOT)
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run(
+        [sys.executable, "-m", "benchmark.run", "--workload", LFM2_CELL,
+         "--seed", "3000000013", "--seconds", "1", "--trace", "0",
+         "--cpu-rehearsal"],
+        cwd=files.ROOT, env=env, capture_output=True, text=True, timeout=900,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert proc.stdout.strip() == ""  # platform=cpu: no result line
+    lines = proc.stderr.splitlines()
+    out = json.loads(
+        [l for l in lines if "would print: " in l][-1].split("would print: ")[1])
+    assert out["correct"] is True and out["failed"] == 0
+    assert out["attempted"] in (2 * 32, 3 * 32, 4 * 32)  # whole steps of 32
+    assert {"gen_tokens_per_s", "train_tokens_per_s", "samples_per_s",
+            "setup_s"} <= set(out["metrics"])
+    check = [l for l in lines if "weight check: " in l][-1]
+    assert "'ok': True" in check and "'leaves': 26" in check, check
+    assert any("lfm2_moe reference" in l and "[0, 4) of 8" in l for l in lines)
+    assert any("lfm2_moe generator check" in l and l.endswith(" ok")
+               for l in lines)
+
+
+def _lfm2_readers():
+    from benchmark.metrics import (
+        decode_hbm_share_sconv, flash_mfu_d64, mfu_gen_sconv, mfu_train_sconv,
+        moe_decode_mlp_roofline_sconv, moe_train_mlp_mfu_sconv,
+        sconv_cache_share, sconv_decode_ms, sconv_decode_roofline,
+        sconv_train_mfu, sconv_train_share,
+    )
+    return locals()
+
+
+def test_the_sconv_readers_say_nothing_without_their_scopes_or_counters():
+    """On a program that lacks what PR 48 added (the parent, or any other
+    configuration) every new reader returns None and does not raise; on a
+    short-convolution / attention mix each reads its scopes and counters."""
+    import dataclasses
+
+    from areal_tpu.models.config import tiny_config
+    from benchmark import peaks_sconv
+    from benchmark.run import model_config
+
+    r = _lfm2_readers()
+    assert sorted(r) == sorted(name for name, *_ in LFM2_ENTRIES)
+    phase = lambda fwd=0.0, recompute=0.0, bwd=0.0: {  # noqa: E731
+        "fwd": fwd, "recompute": recompute, "bwd": bwd}
+    scopes = {
+        "train/grad/layer/mlp": phase(1.0, 0.0, 1.0),
+        "train/grad/layer/attn/flash_fwd": phase(0.125, 0.125),
+        "train/grad/layer/attn/flash_dq": phase(bwd=0.25),
+        "train/grad/layer/attn/flash_dkv": phase(bwd=0.5),
+        "gen/decode_step/layer/mlp": phase(0.008),
+        "gen/decode_step/layer/attn": phase(0.004),
+    }
+    pack = {"flash_live_tiles": 120, "flash_grid_tiles": 480,
+            "real_tokens": 12}
+    bare = _glm_run(tiny_config(), {}, scopes)
+    bare.steps[0]["pack"] = pack
+    for name, reader in r.items():
+        assert reader.read(bare) is None, name
+    # Another share cell's counters and scopes are not this family's
+    # either: the window / full mix states its plan in the same field.
+    other = _glm_run(
+        model_config(files.load_json("configs", f"{MELLUM_CONFIG}.json")),
+        {"window_cache_bytes": 9.0, "kv_cache_bytes": 1.0,
+         "kv_cache_bytes_unwindowed": 20.0, "moe_experts_touched": 12.5,
+         "moe_rows_local": 8.0, "moe_decode_steps": 8}, scopes)
+    other.steps[0]["pack"] = dict(pack, flash_live_tiles_window=60)
+    for name, reader in r.items():
+        assert reader.read(other) is None, name
+    big = model_config(files.load_json("configs", f"{LFM2_CONFIG}.json"))
+    slot = peaks_sconv.kv_token_bytes(big)
+    assert slot == 2048 and big.n_layers == 6
+    assert (peaks_sconv.n_sconv(big), peaks_sconv.n_attn(big),
+            peaks_sconv.n_sparse(big)) == (5, 1, 4)
+    pool = {"conv_cache_bytes": 5 * 2 * 2 * 2048 * 2,
+            "kv_cache_bytes": 1 * 2 * 4608 * slot,
+            "kv_cache_bytes_all_attention": 6 * 2 * 4608 * slot,
+            "moe_experts_touched": 7.5, "moe_rows_local": 8 * 4 * 2.5,
+            "moe_decode_steps": 8}
+    scopes.update({
+        "gen/decode_step/layer/sconv/in_proj": phase(0.009),
+        "gen/decode_step/layer/sconv/conv": phase(0.002),
+        "gen/decode_step/layer/sconv/out_proj": phase(0.005),
+        "train/grad/layer/sconv/in_proj": phase(0.25, 0.25, 0.5),
+        "train/grad/layer/sconv/conv": phase(0.125, 0.125, 0.25),
+        "train/grad/layer/sconv/out_proj": phase(0.125, 0.125, 0.25),
+    })
+    run = _glm_run(big, pool, scopes)
+    run.steps[0]["pack"] = pack
+    assert r["sconv_cache_share"].read(run) == pytest.approx(
+        100 * peaks_sconv.cache_share(big, 4608))
+    assert r["sconv_cache_share"].read(run) == pytest.approx(16.739, abs=1e-3)
+    ten = dataclasses.replace(  # ISSUE 48's first depth: two periods
+        big, n_layers=10, window_pattern=big.window_pattern + "FCCC")
+    assert 100 * peaks_sconv.cache_share(ten, 4608) == pytest.approx(
+        20.069, abs=1e-3)
+    assert r["sconv_decode_ms"].read(run) == pytest.approx(2.0)  # 16 ms / 8
+    # Of train/grad's 2 (mlp) + 1 (flash) + 2 (sconv) seconds.
+    assert r["sconv_train_share"].read(run) == pytest.approx(100 * 2.0 / 5.0)
+    assert r["sconv_decode_roofline"].read(run) == pytest.approx(
+        100 * 1e3 * peaks_sconv.sconv_decode_bytes(big, 2) / 819e9 / 2.0)
+    assert peaks_sconv.sconv_decode_bytes(big, 2) == 5 * 2 * (
+        16_777_216 + 3 * 2048 + 2 * 2 * 2 * 2048 + 2 * 2 * 2048)
+    assert r["sconv_train_mfu"].read(run) == pytest.approx(
+        100 * peaks_sconv.sconv_train_flops(big, 24) / 2.0 / 197e12)
+    assert peaks_sconv.sconv_train_flops(big, 24) == (
+        3 * 5 * 2 * 16_777_216 * 24)
+    # 24 trained tokens against the last minibatch's 12: tiles x 2; the
+    # forward kernel ran in two phases; a tile's product is 128 x 128 x 64.
+    assert r["flash_mfu_d64"].read(run) == pytest.approx(
+        100 * 2 * peaks_sconv.flash_tile_flops(big, 120, 2) / 1.0 / 197e12)
+    assert peaks_sconv.flash_tile_flops(big, 120, 2) == (
+        22 * 128 * 128 * 64 * 32 * 1 * 120)
+    assert r["mfu_train_sconv"].read(run) == pytest.approx(
+        100 * peaks_sconv.flops_train(big, [12, 12]) / 197e12)
+    assert r["mfu_gen_sconv"].read(run) == pytest.approx(
+        100 * peaks_sconv.flops_generate(big, [4, 4], [8, 8]) / 197e12)
+    loop_ms, mlp_ms = 16.0 / 8, 8.0 / 8
+    assert r["decode_hbm_share_sconv"].read(run) == pytest.approx(
+        100 * 1e3 * peaks_sconv.decode_bytes(big, [8.0, 8.0], 7.5, 2.5)
+        / 819e9 / loop_ms)
+    assert r["moe_decode_mlp_roofline_sconv"].read(run) == pytest.approx(
+        100 * 1e3 * peaks_sconv.mlps_decode_bytes(big, 2, 7.5, 2.5)
+        / 819e9 / mlp_ms)
+    assert r["moe_train_mlp_mfu_sconv"].read(run) == pytest.approx(
+        100 * peaks_sconv.mlps_train_flops(big, 24) / 2.0 / 197e12)
+    h, f, fm = big.hidden_dim, big.intermediate_dim, big.moe_intermediate_dim
+    assert peaks_sconv.matmul_params(big) == (
+        5 * 16_777_216 + 10_485_760 + 2 * 3 * h * f
+        + 4 * (1 * 3 * h * fm + h * 32) + h * 16384)
+    assert peaks_sconv.flops_forward(big, [4096]) == pytest.approx(
+        2 * 4096 * peaks_sconv.matmul_params(big)
+        + 4 * 32 * 64 * 1 * 4096 ** 2 / 2)

@@ -530,6 +530,8 @@ class TestTPULowering:
         # GLM's materialised latent attention (20 heads of 256)
         "mellum_window_32x8192x128": (1, 8192, 32, 4, 128, 1024),
         "glm_20x5120x256": (1, 5120, 20, 20, 256),
+        # lfm2_moe: heads of 64, half a lane tile, never before it
+        "lfm2_32x8192x64": (1, 8192, 32, 8, 64),
     }
     # The trip each cell's FORWARD calls take (`_trip_blocks`), as the scope
     # around the kernel says it (dq's keys and dkv's queries: 512 everywhere).
