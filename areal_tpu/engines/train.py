@@ -308,6 +308,14 @@ class TrainEngine(HostOffloadMixin, Engine):
             self._pp_microbatches,
             self.batch_shard,
         ) = sharding.attn_dispatch(mesh, cfg)
+        # What the gradient program's expert matmuls take (a grouped MoE
+        # model's): None, the backend's choice, on one device; XLA's ragged
+        # kernel on a mesh (the Pallas grouped matmul is one device's
+        # program).
+        self._expert_kernel = None if mesh.devices.size == 1 else False
+        # (expert matmuls, those of them on `grouped_matmul`) of the
+        # gradient program as traced last: counted while tracing.
+        self._expert_matmuls = (0, 0)
         if pipe_schedule not in ("gpipe", "1f1b-mem"):
             raise ValueError(f"unknown pipe_schedule {pipe_schedule!r}")
         self.pipe_schedule = pipe_schedule
@@ -393,10 +401,12 @@ class TrainEngine(HostOffloadMixin, Engine):
         cp_mesh = self._cp_mesh
         pp_mesh, pp_mbs = self._pp_mesh, self._pp_microbatches
         remat = self.remat_policy
+        expert_kernel = self._expert_kernel
 
         def _value_and_grad(params, batch, loss_scale):
             def losswrap(p):
                 pc = _cast_tree(p, compute_dtype)
+                traced = tfm.expert_matmuls_traced()
                 x, aux, counts = tfm.hidden_states(
                     pc,
                     cfg,
@@ -409,6 +419,10 @@ class TrainEngine(HostOffloadMixin, Engine):
                     pp_mesh=pp_mesh,
                     pp_microbatches=pp_mbs,
                     with_moe_counts=True,
+                    expert_kernel=expert_kernel,
+                )
+                self._expert_matmuls = tuple(
+                    b - a for a, b in zip(traced, tfm.expert_matmuls_traced())
                 )
                 # Loss fns receive per-token model outputs, never [B,S,V]
                 # logits: critic -> values; LM -> fused chunked next-token
@@ -752,6 +766,17 @@ class TrainEngine(HostOffloadMixin, Engine):
             "n_micro_batches": float(len(chunks)),
             "head/vocab_shards": float(self.head_vocab_shards),
         }
+        if self.cfg.is_moe and self.cfg.moe_dispatch == "grouped":
+            # Which kernel the gradient program's expert matmuls run on
+            # (`grouped_matmul` where XLA's ragged kernel tiles the widths
+            # badly): trace-time counts, no device work.
+            calls, on_kernel = self._expert_matmuls
+            out["moe/expert_matmul_calls"] = float(calls)
+            out["moe/grouped_kernel_calls"] = float(on_kernel)
+            tracer.counter(
+                "moe_expert_kernel", expert_matmul_calls=calls,
+                grouped_kernel_calls=on_kernel,
+            )
         for i, k in enumerate(keys):
             v = float(host[4 + i])
             if k.endswith("_sum"):

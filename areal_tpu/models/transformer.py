@@ -568,14 +568,27 @@ def _experts_grouped(
     leaves: they are viewed as [L*E, in, out] (leading contiguous axes: a
     bitcast) and the group sizes are zero outside [layer*E, (layer+1)*E),
     which hands the kernel the parameter's own buffer.  Same rows through
-    the same experts either way (`expert_leaves_in_place`).  `kernel`
-    (decode, stacked leaves): the Pallas kernel `grouped_decode_matmul` in
-    `ragged_dot`'s place — XLA's kernel tiles by the divisors of the two
-    weight dimensions and streams a [2,688, 1,856] expert at a ninth of
-    the bandwidth; the Pallas kernel walks an expert in contiguous pieces
-    of 0.6 to 8 MB whatever the divisors (`ops/pallas/grouped_matmul.py`,
-    `tiles`: [384, 1856] there, [384, 896] and all of [896, 2304] at
-    mellum's widths).
+    the same experts either way (`expert_leaves_in_place`).  `kernel`:
+    this repo's Pallas kernels in `ragged_dot`'s place, where XLA's kernel
+    — which tiles by the divisors of the two weight dimensions — is far
+    off its roofline at the expert's widths (`expert_kernel_choice`: one
+    TPU device and `ragged_tiles_badly`; [2304, 896], [2688, 1856] and
+    [2048, 1792] of the benchmark's six).  In a decode step (stacked
+    leaves) `grouped_decode_matmul`: XLA streams a [2,688, 1,856] expert at
+    a ninth of the bandwidth, the kernel walks an expert in contiguous
+    pieces of 0.6 to 8 MB whatever the divisors
+    (`ops/pallas/grouped_matmul.py`, `tiles`).  Over packed rows (`layer`
+    None) in the GRADIENT program `grouped_matmul`, which brings its own
+    gradient rule — forward, dx and dw each walk (row tile, group) pairs in
+    tiles of 256 rows against the expert's whole matrix, 2 to 5 times
+    `ragged_dot`'s speed at mellum's and nemotron's widths and 1.2 to 1.5
+    times at lfm2's (my chip runs, PR 50), under the caller's scope and
+    phase where `ragged-dot-none.N` has neither — and zeroes the rows past
+    every group itself, so a rank's share drops the `held` masks around
+    every call (`_kernel_rows`).  Every other width, a mesh of more than
+    one device, every other backend, the forward-only programs over
+    packed rows (`forward`, `prefill`: `hidden_states`) and the later-slab
+    loop (`_slabs`) keep `ragged_dot`.
 
     Expert FLOPs are exactly 3·T·k·D·F — proportional to TOKENS, where
     the dense oracle pays E/k× that and capacity dispatch pays
@@ -620,10 +633,12 @@ def _experts_grouped(
             x, top_w, order, group_sizes, blk, cfg, layer, kernel
         )
     experts = {n: blk[n] for n in _expert_leaves(cfg)}
-    return _grouped_slabs(cfg, slab, x, top_w, experts, order, group_sizes)
+    return _grouped_slabs(
+        cfg, slab, kernel, x, top_w, experts, order, group_sizes
+    )
 
 
-def _slabs(cfg: ModelConfig, slab: int, order, group_sizes):
+def _slabs(cfg: ModelConfig, slab: int, kernel: bool, order, group_sizes):
     """The sorted pairs in slabs of `slab` -> (rows(i, x, top_w, experts,
     into): `_grouped_rows` over pairs [i*slab, (i+1)*slab) with every
     group's sizes cut to that window; later(body, first): `body(i, carry)`
@@ -642,7 +657,17 @@ def _slabs(cfg: ModelConfig, slab: int, order, group_sizes):
             sizes = jnp.clip(ends, lo, lo + slab) - jnp.clip(
                 starts, lo, lo + slab
             )
-        return _grouped_rows(x, top_w, mine, sizes, experts, cfg, into=into)
+        # The kernel is the FIRST slab's (a Python 0): the loop's body, a
+        # path that balanced routing never trips, keeps `ragged_dot`.  With
+        # the kernels in the loop as well a gradient program took 5.4 s to
+        # LOAD from the compile cache where the parent's takes 3.5 and this
+        # one 4.3 — eight such programs a cell, + 14 s of a warm set-up of
+        # 121 s where this reads + 10 (my chip runs, PR 50, `mellum2-coderl32-
+        # 4k`).  A batch that overflows its slab runs XLA's kernel there.
+        return _grouped_rows(
+            x, top_w, mine, sizes, experts, cfg,
+            kernel=kernel and isinstance(i, int), into=into,
+        )
 
     def later(body, first):
         with jax.named_scope("overflow"):
@@ -653,8 +678,11 @@ def _slabs(cfg: ModelConfig, slab: int, order, group_sizes):
     return rows, later
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _grouped_slabs(cfg: ModelConfig, slab: int, x, top_w, experts, order, group_sizes):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _grouped_slabs(
+    cfg: ModelConfig, slab: int, kernel: bool, x, top_w, experts, order,
+    group_sizes,
+):
     """A share's dispatch: `_grouped_rows` over the first `slab` pairs of
     the order, outside any control flow, plus the sum of the slabs after it
     for as long as they hold a held pair (`lax.fori_loop` with a traced
@@ -680,8 +708,10 @@ def _grouped_slabs(cfg: ModelConfig, slab: int, x, top_w, experts, order, group_
     bound has no reverse mode at all.  Here the first slab's backward pass
     is autodiff's own (`jax.vjp`, its residuals kept as autodiff would), and
     the loop runs again on the way back, each later slab's forward
-    recomputed inside it and its gradients added in place."""
-    rows, later = _slabs(cfg, slab, order, group_sizes)
+    recomputed inside it and its gradients added in place (`kernel`: the
+    first slab's expert matmuls are `grouped_matmul`, whose own rule
+    composes under `jax.vjp`; the loop's stay `ragged_dot`: `_slabs`)."""
+    rows, later = _slabs(cfg, slab, kernel, order, group_sizes)
     return _slab_sum(rows, later, rows(0, x, top_w, experts), x, top_w, experts)
 
 
@@ -691,16 +721,16 @@ def _slab_sum(rows, later, first, x, top_w, experts):
     )
 
 
-def _grouped_slabs_fwd(cfg, slab, x, top_w, experts, order, group_sizes):
-    rows, later = _slabs(cfg, slab, order, group_sizes)
+def _grouped_slabs_fwd(cfg, slab, kernel, x, top_w, experts, order, group_sizes):
+    rows, later = _slabs(cfg, slab, kernel, order, group_sizes)
     first, first_vjp = jax.vjp(functools.partial(rows, 0), x, top_w, experts)
     out = _slab_sum(rows, later, first, x, top_w, experts)
     return out, (first_vjp, x, top_w, experts, order, group_sizes)
 
 
-def _grouped_slabs_bwd(cfg, slab, res, ct):
+def _grouped_slabs_bwd(cfg, slab, kernel, res, ct):
     first_vjp, x, top_w, experts, order, group_sizes = res
-    rows, later = _slabs(cfg, slab, order, group_sizes)
+    rows, later = _slabs(cfg, slab, kernel, order, group_sizes)
 
     def add(i, grads):
         more = jax.vjp(functools.partial(rows, i), x, top_w, experts)[1](ct)
@@ -712,6 +742,40 @@ def _grouped_slabs_bwd(cfg, slab, res, ct):
 _grouped_slabs.defvjp(_grouped_slabs_fwd, _grouped_slabs_bwd)
 
 
+# The expert matmuls traced so far on the unstacked grouped dispatch (the
+# programs over packed rows), and those of them that were the Pallas kernel
+# `grouped_matmul`: Python counts, taken while a program is traced
+# (`expert_matmuls_traced`).
+_EXPERT_MATMULS = {"calls": 0, "kernel": 0}
+
+
+def expert_matmuls_traced() -> Tuple[int, int]:
+    """(expert matmuls traced on the unstacked grouped dispatch since the
+    process started, those handed to `grouped_matmul`): read before and
+    after tracing a program, the difference says which kernel its experts
+    run on (the train engine's `moe/expert_matmul_calls` and
+    `moe/grouped_kernel_calls`)."""
+    return _EXPERT_MATMULS["calls"], _EXPERT_MATMULS["kernel"]
+
+
+def expert_kernel_choice(cfg: ModelConfig, choice: Optional[bool]) -> bool:
+    """Whether a program's expert matmuls are this repo's Pallas kernels
+    (`ops/pallas/grouped_matmul.py`) in `ragged_dot`'s place.  `choice`
+    None: by what the code can see — a TPU backend and an expert matrix
+    that XLA's ragged-dot kernel tiles badly (`ragged_tiles_badly`); a
+    caller whose mesh has more than one device passes False (the kernels
+    are one device's program); a bool forces either (interpreted off a
+    TPU)."""
+    if choice is not None:
+        return bool(choice)
+    from areal_tpu.base.distributed import is_tpu_backend
+    from areal_tpu.ops.pallas.grouped_matmul import ragged_tiles_badly
+
+    return is_tpu_backend() and ragged_tiles_badly(
+        cfg.hidden_dim, cfg.moe_intermediate_dim
+    )
+
+
 def _grouped_rows(
     x, top_w, order, group_sizes, blk: Params, cfg: ModelConfig, layer=None,
     kernel: bool = False, into=None,
@@ -721,7 +785,24 @@ def _grouped_rows(
     expert — in groups of `group_sizes`: gather, the experts, weight,
     scatter-add -> [T, D], zero for a row none of whose pairs is here.
     `into`: a sum to add these pairs' to (the later slabs of a share add
-    up in one buffer)."""
+    up in one buffer).  `kernel`: the expert matmuls are the Pallas kernels
+    `grouped_matmul` (`layer` None; forward, dx and dw: `_kernel_rows`) or
+    `grouped_decode_matmul` (stacked leaves) and not `ragged_dot`."""
+    if kernel and layer is None:
+        from areal_tpu.ops.pallas.flash_attention import _interpret
+
+        experts = {n: blk[n] for n in _expert_leaves(cfg)}
+        _EXPERT_MATMULS["calls"] += len(experts)
+        _EXPERT_MATMULS["kernel"] += len(experts)
+        # The mesh context spelled out: JAX keys a `jit`'s cached trace on
+        # it, and reads "none" (a layer's first trace) and "the empty mesh"
+        # (the same layer under autodiff of the traced scan) as two — the
+        # block, its tables and its forward kernels traced twice a program.
+        assert into is None  # the first slab's, or every pair's
+        with jax.sharding.use_abstract_mesh(jax.sharding.get_abstract_mesh()):
+            return _kernel_rows(
+                cfg, _interpret(), x, top_w, order, group_sizes, experts
+            )
     k = cfg.n_experts_per_tok
     with jax.named_scope("dispatch"):
         tok_of = order // k
@@ -744,11 +825,13 @@ def _grouped_rows(
         # back) is not a result: `held` zeroes it at every step, so nothing
         # of it reaches a sum or a gradient.
         held = None
-        if cfg.expert_share:
+        if cfg.expert_share and not kernel:
             held = (jnp.arange(xs.shape[0]) < jnp.sum(group_sizes))[:, None]
 
         def ragged(lhs, w):
-            if kernel and layer is not None:
+            if layer is None:
+                _EXPERT_MATMULS["calls"] += 1
+            if kernel:
                 # Rows past every group come out zero: nothing to mask.
                 from areal_tpu.ops.pallas.grouped_matmul import (
                     grouped_decode_matmul,
@@ -762,16 +845,60 @@ def _grouped_rows(
             out = jax.lax.ragged_dot(jnp.where(held, lhs, 0), w, group_sizes)
             return jnp.where(held, out, 0)
 
-        if cfg.mlp_gated:
-            gate = jax.nn.silu(ragged(xs, blk["wg"]))
-            up = ragged(xs, blk["wu"])
-            ys = ragged(gate * up, blk["wd"])  # [T*k, D]
-        else:  # down(act(up(x))): two matrices an expert
-            ys = ragged(_act(ragged(xs, blk["wu"]), cfg), blk["wd"])
+        ys = _expert_mlp(cfg, ragged, xs, blk)  # [T*k, D]
     with jax.named_scope("combine"):
         w_sorted = top_w.reshape(-1)[order].astype(ys.dtype)
         out = jnp.zeros_like(x) if into is None else into
         return out.at[tok_of].add(ys * w_sorted[:, None])
+
+
+def _expert_mlp(cfg: ModelConfig, ragged, xs, blk: Params):
+    """An expert's matrices over its rows: `ragged(lhs, w)` the grouped
+    matmul."""
+    if cfg.mlp_gated:
+        gate = jax.nn.silu(ragged(xs, blk["wg"]))
+        return ragged(gate * ragged(xs, blk["wu"]), blk["wd"])
+    # down(act(up(x))): two matrices an expert
+    return ragged(_act(ragged(xs, blk["wu"]), cfg), blk["wd"])
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _kernel_rows(
+    cfg: ModelConfig, interpret: bool, x, top_w, order, group_sizes, experts
+):
+    """`_grouped_rows` on the Pallas kernel `grouped_matmul`, as ONE
+    function of the program: the same block of the same shapes in each of
+    a unit's unrolled layers and in the remat's forward is one traced
+    jaxpr (and its linearisation and transpose one each, found in JAX's
+    caches by it) and one private function of the lowered module, called
+    from every site.  Traced at every site — 96
+    expert matmuls a gradient program, each a gradient rule, its visit
+    tables and a kernel body lowered to a Mosaic module — the host's work
+    before the compile cache is asked took + 3.4 s a program and a warm
+    set-up + 93% (PR 49's tree on a CPU host, and the driver's runs of it;
+    PERF.md section 6, PR 50).  The visit tables are made once for the
+    slab's two or three matrices and every pass over them.  Rows past
+    every group come out of the kernels ZERO, forward and as a cotangent
+    on the way back, so a rank's share needs no `held` mask here.
+    `interpret`: the backend's answer, asked by the caller — outside this
+    `jit`, whose cached trace must not be another backend's."""
+    from areal_tpu.ops.pallas.grouped_matmul import grouped_matmul, visits
+
+    with jax.named_scope("dispatch"):
+        tok_of = order // cfg.n_experts_per_tok
+        xs = x[tok_of]  # [T*k, D] sorted by expert
+    with jax.named_scope("experts"):
+        tables = visits(group_sizes, xs.shape[0])
+        ys = _expert_mlp(
+            cfg,
+            lambda lhs, w: grouped_matmul(
+                lhs, w, group_sizes, tables, interpret
+            ),
+            xs, experts,
+        )
+    with jax.named_scope("combine"):
+        w_sorted = top_w.reshape(-1)[order].astype(ys.dtype)
+        return jnp.zeros_like(x).at[tok_of].add(ys * w_sorted[:, None])
 
 
 def _expert_leaves(cfg: ModelConfig) -> Tuple[str, ...]:
@@ -821,11 +948,9 @@ def _scan_blocks(
     )
 
 
-_MOE_EXPERTS = {
-    "dense": _experts_dense,
-    "grouped": _experts_grouped,
-    "topk": _experts_topk,
-}
+# The dispatches beside the grouped one (`_experts_grouped`, which `_mlp_moe`
+# calls itself: it alone takes stacked leaves and a kernel).
+_MOE_EXPERTS = {"dense": _experts_dense, "topk": _experts_topk}
 
 
 @jax.named_scope("layer/mlp")
@@ -849,8 +974,9 @@ def _mlp_moe(
     generator's and the trainer's load counters.  `stacked` (decode,
     grouped dispatch): the expert weights come from these stacked
     [L, E, in, out] leaves at layer index `layer` instead of from `blk`
-    (`_experts_grouped`, which `kernel` hands the Pallas grouped matmul);
-    without it `layer` is not read.
+    (`_experts_grouped`); without it `layer` is not read.  `kernel`: the
+    grouped dispatch's matmuls are the Pallas kernels of
+    `ops/pallas/grouped_matmul.py` and not `ragged_dot`.
 
     Where the grouped dispatch works on a slab (`expert_slab_rows`), the
     rows `valid` does not mark are left out of it: the slab is sized for
@@ -879,11 +1005,12 @@ def _mlp_moe(
                 "tke,t->e", one_hot, valid.reshape(-1).astype(one_hot.dtype)
             )
         counts = jax.lax.stop_gradient(counts).astype(jnp.int32)
-    if stacked is None:
+    if stacked is None and cfg.moe_dispatch != "grouped":
         out = _MOE_EXPERTS[cfg.moe_dispatch](x, top_w, top_idx, one_hot, blk, cfg)
     else:
         out = _experts_grouped(
-            x, top_w, top_idx, one_hot, stacked, cfg, layer, kernel
+            x, top_w, top_idx, one_hot, blk if stacked is None else stacked,
+            cfg, None if stacked is None else layer, kernel,
         )
     if cfg.shared_expert_dim:
         with jax.named_scope("shared"):
@@ -1019,6 +1146,7 @@ def _rope(cfg: ModelConfig, positions: jax.Array):
 def _packed_branches(
     cfg: ModelConfig, segment_ids: jax.Array, *attn_args, with_state=False,
     window_rope=None, ring: Optional[int] = None,
+    expert_kernel: Optional[bool] = False,
 ):
     """The table of the programs over packed rows (the train stack,
     `prefill`): branch -> f(h, blk) -> (its output, what else it gives by
@@ -1030,7 +1158,10 @@ def _packed_branches(
     `attn_args`: `_attention`'s from `cos` on.  `window_rope`: the window
     layers' (cos, sin) in a plan that has them (`_rope`); `ring`: the
     entries of their cache, where the caller keeps what they leave (the
-    ring a row's last slots fill: `_ring_tail`)."""
+    ring a row's last slots fill: `_ring_tail`).  `expert_kernel`: whether
+    the grouped dispatch's matmuls are the Pallas kernel `grouped_matmul`
+    (`expert_kernel_choice`)."""
+    kernel = cfg.is_moe and expert_kernel_choice(cfg, expert_kernel)
 
     def recurrent(forward):
         def branch(h, blk):
@@ -1044,7 +1175,9 @@ def _packed_branches(
         return branch
 
     def experts(h, blk):
-        out, aux, counts = _mlp_moe(h, blk, cfg, valid=segment_ids > 0)
+        out, aux, counts = _mlp_moe(
+            h, blk, cfg, valid=segment_ids > 0, kernel=kernel
+        )
         return out, {"aux": aux, "counts": counts}
 
     def attention(h, blk):
@@ -1163,6 +1296,7 @@ def _backbone(
     cp_mesh=None,
     pp_mesh=None,
     pp_microbatches: int = 4,
+    expert_kernel: Optional[bool] = False,
 ) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
     """-> (final-normed hidden states, summed MoE aux loss, per-layer rows
     per expert [L, E] int32 — None for dense models and under PP)."""
@@ -1229,6 +1363,7 @@ def _backbone(
     x, auxes, counts = _blocks(
         params["blocks"], cfg, x, segment_ids, cos, sin, remat, use_flash,
         cp_mesh, cp_zigzag=zz_inv is not None, window_rope=window_rope,
+        expert_kernel=expert_kernel,
     )
     x = _final_norm(params, cfg, x)
     if zz_inv is not None:
@@ -1473,6 +1608,7 @@ def _layer_outputs(n_in_unit: int, stacked, lead=()):
 def _blocks(
     blocks: Params, cfg: ModelConfig, x, segment_ids, cos, sin, remat,
     use_flash, cp_mesh=None, cp_zigzag: bool = False, window_rope=None,
+    expert_kernel: Optional[bool] = False,
 ):
     """The block stack of every model: the prefix's layers, then ONE
     `lax.scan` over the repeats of the plan's unit, a unit's layers
@@ -1482,7 +1618,7 @@ def _blocks(
     plan = cfg.plan
     branches = _packed_branches(
         cfg, segment_ids, cos, sin, use_flash, cp_mesh, None, cp_zigzag,
-        window_rope=window_rope,
+        window_rope=window_rope, expert_kernel=expert_kernel,
     )
     layers = {
         kind: _remat_layer(
@@ -1559,18 +1695,27 @@ def hidden_states(
     pp_mesh=None,
     pp_microbatches: int = 4,
     with_moe_counts: bool = False,
+    expert_kernel: Optional[bool] = False,
 ) -> Tuple[jax.Array, ...]:
     """Backbone only: final-layernormed hidden states [B, S, D] (+ MoE aux
     loss), WITHOUT the LM head.  Lets engines fuse the head into a chunked
     loss (ops/functional.fused_next_token_logprobs) instead of materializing
     [B, S, V] logits.  `with_moe_counts` adds a third result: the real
     tokens' rows per expert of every layer, [L, E] int32 (None for dense
-    models and under PP) — the trainer's load counter."""
+    models and under PP) — the trainer's load counter.  `expert_kernel`:
+    whether a grouped MoE model's expert matmuls are the Pallas kernel
+    `grouped_matmul` and not `ragged_dot` — the GRADIENT program's, which
+    passes None on one device (`expert_kernel_choice`); every forward-only
+    program (`forward`, `prefill`, this function's other callers) keeps
+    `ragged_dot`: it holds a quarter of the kernels' seconds, it is most of
+    the programs that would carry them at set-up, and its text stays the
+    parent's, so what a generator samples does not move (PERF.md section
+    6, PR 50)."""
     if positions is None:
         positions = positions_from_segments(segment_ids)
     x, aux, counts = _backbone(
         params, cfg, tokens, segment_ids, positions, remat, use_flash,
-        cp_mesh, pp_mesh, pp_microbatches,
+        cp_mesh, pp_mesh, pp_microbatches, expert_kernel,
     )
     return (x, aux, counts) if with_moe_counts else (x, aux)
 
@@ -2031,13 +2176,9 @@ def decode_step(
     (cos, sin), window_rope = _rope(cfg, positions[:, None])
     slot = jnp.asarray(slot, jnp.int32)
     blocks, stacked = _scan_blocks(cfg, params["blocks"], experts_in_place)
-    if expert_kernel is None and stacked is not None:
-        from areal_tpu.base.distributed import is_tpu_backend
-        from areal_tpu.ops.pallas.grouped_matmul import ragged_tiles_badly
-
-        expert_kernel = is_tpu_backend() and ragged_tiles_badly(
-            cfg.hidden_dim, cfg.moe_intermediate_dim
-        )
+    expert_kernel = stacked is not None and expert_kernel_choice(
+        cfg, expert_kernel
+    )
 
     plan = cfg.plan
 
@@ -2121,7 +2262,7 @@ def decode_step(
 
     def experts(h, blk, cache, li):
         out, _, counts = _mlp_moe(
-            h, blk, cfg, stacked=stacked, layer=li, kernel=bool(expert_kernel)
+            h, blk, cfg, stacked=stacked, layer=li, kernel=expert_kernel
         )
         return out, cache, counts
 

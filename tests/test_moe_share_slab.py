@@ -161,6 +161,56 @@ def test_slab_and_overflow_are_the_dense_oracle(gated, score, regime):
         float(stats["moe/slab_fill_max"]), int(held) / slab, rtol=1e-6)
 
 
+@pytest.mark.parametrize("regime", ["balanced", "all_held", "none_held"])
+@pytest.mark.parametrize("gated", [True, False], ids=["gated", "ungated"])
+def test_the_grouped_kernel_under_the_slabs_gives_ragged_dots_gradients(
+        gated, regime):
+    """`kernel=True`: the expert matmuls of the FIRST slab are the Pallas
+    kernel `grouped_matmul` (interpreted here) under `_grouped_slabs`'s own
+    gradient rule, by `jax.vjp`; the later slabs (three of them where every
+    choice is held here: each expert's rows split over two slabs) keep
+    `ragged_dot`, recomputed inside the loop on the way back.  Output, the rows'
+    gradient and every leaf's against the same layer on `ragged_dot` and
+    against the dense oracle."""
+    cfg = _cfg(gated)
+    h, blk = _layer(cfg, REGIMES[regime])
+    cot = jnp.asarray(
+        np.random.default_rng(1).normal(size=h.shape), jnp.float32)
+
+    def run(kernel, c=cfg):
+        def loss(h, blk):
+            out, _, counts = tfm._mlp_moe(h, blk, c, kernel=kernel)
+            return jnp.sum(out * cot), (out, counts)
+
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True))
+
+    before = tfm.expert_matmuls_traced()
+    with jax.default_matmul_precision("highest"):
+        (_, (got, counts)), (dh, dblk) = run(True)(h, blk)
+        calls, on_kernel = (b - a for a, b in zip(
+            before, tfm.expert_matmuls_traced()))
+        (_, (want, _)), (dh_want, dblk_want) = run(False)(h, blk)
+        (_, (dense, _)), (dh_dense, dblk_dense) = run(
+            False, dataclasses.replace(cfg, moe_dispatch="dense"))(h, blk)
+    # the first slab's: the later slabs' loop keeps `ragged_dot`
+    assert 0 < on_kernel < calls
+    assert tfm.expert_matmuls_traced()[1] == before[1] + on_kernel
+    slabs = int(tfm.expert_slabs_run(512, T * K, counts.sum()))
+    assert slabs == {"balanced": 1, "all_held": 4, "none_held": 1}[regime]
+    # ... against the same layer on `ragged_dot`, and against the dense
+    # oracle (every expert over every row)
+    for other, dh_other, dblk_other in (
+            (want, dh_want, dblk_want), (dense, dh_dense, dblk_dense)):
+        np.testing.assert_allclose(got, other, **TOL)
+        np.testing.assert_allclose(dh, dh_other, **TOL)
+        for name in blk:
+            np.testing.assert_allclose(
+                dblk[name], dblk_other[name], err_msg=name, **TOL)
+    if regime != "none_held":
+        assert all(float(jnp.abs(dblk[n]).max()) > 1e-3
+                   for n in tfm._expert_leaves(cfg))
+
+
 def test_the_first_slab_is_outside_the_loop_and_the_loop_saves_nothing():
     """In every program of the unstacked path the first slab's gather,
     kernels and scatter-add stay outside any control flow (the benchmark's
