@@ -110,10 +110,11 @@ async def _run_master_zmq(plan: ExperimentPlan, n_workers: int, sched):
 
 async def _drive_master(plan: ExperimentPlan, pool: ZMQWorkerPool):
     await pool.wait_workers()
-    master = _make_master(plan, pool)
-    # Resume step counters / freq-ctl state from a recover checkpoint if one
-    # exists (written every ckpt_freq; no-op on fresh trials).
-    master.load_recover_info()
+    with tracer.setup_span("build"), tracer.setup_span("master"):
+        master = _make_master(plan, pool)
+        # Resume step counters / freq-ctl state from a recover checkpoint
+        # if one exists (written every ckpt_freq; no-op on fresh trials).
+        master.load_recover_info()
     stats = await master.run()
     await pool.broadcast({"type": "exit"})
     return stats

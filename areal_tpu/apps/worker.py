@@ -70,7 +70,8 @@ def main():
     # Bulk worker-to-worker plane (data/param transfers planned by the
     # master); bound before model build so peers can connect early.
     transfer = ZMQTransfer(args.experiment, args.trial, args.index)
-    worker = ModelWorker(config, transfer=transfer)
+    with tracer.setup_span("build"):
+        worker = ModelWorker(config, transfer=transfer)
     control.state = WorkerState.RUNNING
     logger.info(f"worker {args.index} ready, serving stream")
     try:

@@ -8,7 +8,6 @@ attention term uses the exact sum of per-sequence s^2) and a jsonl +
 optional tensorboard/wandb sink instead of CUDA counters.
 """
 
-import contextlib
 import json
 import os
 import time
@@ -201,43 +200,6 @@ def mfu(flops: float, seconds: float, n_devices: int) -> Optional[float]:
     if peak is None or seconds <= 0 or n_devices <= 0:
         return None
     return flops / seconds / (peak * 1e12 * n_devices)
-
-
-# ---------------- timing marks ----------------
-
-
-class Timers:
-    """Named wall-clock marks (reference: base/monitor.py time_mark /
-    tmark decorators) — accumulate durations, drain as a stats dict."""
-
-    def __init__(self):
-        self._acc: Dict[str, float] = {}
-        self._count: Dict[str, int] = {}
-
-    @contextlib.contextmanager
-    def record(self, name: str):
-        t0 = time.monotonic()
-        try:
-            yield
-        finally:
-            dt = time.monotonic() - t0
-            self._acc[name] = self._acc.get(name, 0.0) + dt
-            self._count[name] = self._count.get(name, 0) + 1
-
-    def drain(self, prefix: str = "time/") -> Dict[str, float]:
-        """Export accumulated marks and reset.  Per key: the total
-        seconds, the call count (``<key>_cnt``) and the mean per call
-        (``<key>_avg``) — counts used to be accumulated then silently
-        discarded, hiding e.g. how many micro-batches a total covered."""
-        out: Dict[str, float] = {}
-        for k, total in self._acc.items():
-            n = self._count.get(k, 0)
-            out[f"{prefix}{k}"] = total
-            out[f"{prefix}{k}_cnt"] = float(n)
-            out[f"{prefix}{k}_avg"] = total / n if n else 0.0
-        self._acc.clear()
-        self._count.clear()
-        return out
 
 
 # ---------------- stats sinks ----------------

@@ -104,6 +104,22 @@ say for itself:
   span whose self seconds grew by > 10 ms over that span's own median,
   with the host record beside it; :func:`close_step` returns the step
   stats ``host/<key>`` and ``time/slow_excess_s``.
+- **Set-up and program ledger** (PR 51) -- what the time before the
+  first timed step is made of.  :func:`setup_span` times a phase of the
+  build (``setup:build`` around a runner's construction, inside it
+  ``setup:worker``, ``setup:mesh``, ``setup:weights``, ``setup:engine``,
+  ``setup:datasets``, ``setup:master``): host seconds, with no added
+  synchronisation, so device work a phase dispatched lands where the
+  host next waits.  :func:`program_event` is the callback of the
+  process's one ``jax.monitoring`` registration (the worker installs it;
+  this module is handed the events and never imports jax): one row a
+  compiled or loaded program, ``{fun, trace_s, lower_s, compile_s,
+  cache_load_s, hit, written, span}``, joined on the compiling thread.
+  The rows since the last close are the step record's ``programs``; the
+  FIRST :func:`close_step` of a process also returns ``setup/<key>``
+  (:func:`setup_take`), the totals from process start, and never again.
+  Under a live profiler each phase writes an ``areal:compile``
+  annotation at its end carrying ``dur_ms``, ``phase`` and ``fun``.
 """
 
 import atexit
@@ -118,6 +134,10 @@ from typing import Any, Dict, List, Optional
 
 from areal_tpu.base import hostwatch
 
+# The tracer's import on its own clock: what `setup/to_import_s` ends at,
+# and the process's start itself where /proc cannot be read.
+_T_IMPORT_NS = time.monotonic_ns()
+
 # Per-thread ring capacity.  A step emits O(100) events per process;
 # 65536 absorbs many steps between flushes before dropping the oldest.
 _RING_CAP = 65536
@@ -126,6 +146,13 @@ _RING_CAP = 65536
 # (no lock); 512 recent events is several seconds of fleet activity —
 # enough context around a fault instant without unbounded memory.
 _FLIGHT_CAP = 512
+
+# Program ledger: rows kept between two closes (a process that never
+# closes a step keeps the newest), and a thread's ended compile phases
+# not yet under an enclosing one (a gradient program's trace has some
+# hundreds of nested jit traces as direct children).
+_PROGRAM_CAP = 4096
+_PHASE_CAP = 4096
 
 # Step ledger: closed steps kept in memory, and what makes a step slow:
 # its wall over the median of the last SLOW_WINDOW closed steps (at least
@@ -265,15 +292,24 @@ def _annotate(name: str, args: Dict):
 
 
 class _ThreadState:
-    """One thread's open spans and what its closed spans added to the
-    step ledger since the last close_step."""
+    """One thread's open spans, what its closed spans added to the step
+    ledger since the last close_step, and the program it is compiling."""
 
-    __slots__ = ("thread", "stack", "ledger")
+    __slots__ = ("thread", "stack", "ledger", "compiles", "phases", "pending")
 
     def __init__(self):
         self.thread = threading.current_thread()
         self.stack: list = []
         self.ledger: Dict[str, list] = {}  # name -> [n, total_ns, self_ns]
+        # Since the last take_compiles(): programs, backend seconds, cache
+        # read seconds, trace seconds, lowering seconds.
+        self.compiles = [0.0] * 5
+        # Compile phases that ended on this thread, (start_ns, seconds),
+        # under no later one: a phase that encloses them takes them out.
+        self.phases: collections.deque = collections.deque(maxlen=_PHASE_CAP)
+        # Of the program not yet through its backend phase:
+        # [trace_s, lower_s, cache read seconds, hit, written].
+        self.pending = [0.0, 0.0, 0.0, False, False]
 
 
 _threads: Dict[int, _ThreadState] = {}  # by thread ident, for the watch
@@ -374,39 +410,6 @@ def step_span(step: int) -> Any:
     classes = _annotations()
     ann = classes and classes[1]("areal:step", step_num=int(step))
     return _Span("step", None, {"step": int(step)}, ann)
-
-
-def trace(name: Optional[str] = None, cat: Optional[str] = None):
-    """Decorator form: @tracer.trace("load_data", cat="host")."""
-
-    def deco(fn):
-        import functools
-
-        label = name or fn.__qualname__
-
-        @functools.wraps(fn)
-        def wrapped(*a, **kw):
-            with span(label, cat=cat):
-                return fn(*a, **kw)
-
-        return wrapped
-
-    return deco
-
-
-def instant(name: str, **args) -> None:
-    if not _state["enabled"]:
-        return
-    ev = {
-        "ph": "i",
-        "name": name,
-        "ts": time.monotonic_ns() // 1000,
-        "tid": threading.get_ident(),
-        "s": "t",
-    }
-    if args:
-        ev["args"] = args
-    _buf().append(ev)
 
 
 def counter(name: str, **values) -> None:
@@ -663,8 +666,9 @@ def close_step(step: int, wall_s: Optional[float] = None) -> Dict[str, float]:
     LEDGER_STEPS), the host watch hands over its record, and a step that
     ran long writes its ``slow_step`` flight event.  ``wall_s`` defaults
     to the seconds since the last close.  Returns the step stats:
-    ``host/<key>`` and ``time/slow_excess_s`` (0 for a step not
-    flagged)."""
+    ``host/<key>``, ``time/slow_excess_s`` (0 for a step not flagged)
+    and, from the process's first close alone, ``setup/<key>``
+    (:func:`setup_take`)."""
     now = time.monotonic_ns()
     if wall_s is None:
         wall_s = (now - (_ledger_state["t_close_ns"] or now)) / 1e9
@@ -683,6 +687,9 @@ def close_step(step: int, wall_s: Optional[float] = None) -> Dict[str, float]:
             with _lock:
                 _threads.pop(ident, None)
     host = _watch.take() if _watch is not None else {}
+    with _lock:
+        programs = list(_programs)
+        _programs.clear()
     record = {
         "step": int(step),
         "t_us": int(time.time() * 1e6),  # the flight events' clock
@@ -692,11 +699,13 @@ def close_step(step: int, wall_s: Optional[float] = None) -> Dict[str, float]:
             for name, (n, total, own) in spans.items()
         },
         "host": host,
+        "programs": programs,
     }
     excess = _judge_step(record)
     _steps.append(record)
     stats = {f"host/{k}": v for k, v in host.items()}
     stats["time/slow_excess_s"] = excess
+    stats.update(setup_take())
     return stats
 
 
@@ -737,8 +746,257 @@ def _judge_step(record: Dict[str, Any]) -> float:
 def step_ledger() -> List[Dict[str, Any]]:
     """The last LEDGER_STEPS closed steps, oldest first: ``step``, ``t_us``
     (epoch microseconds at the close), ``wall_s``, ``spans`` (name -> (n,
-    total_s, self_s)) and ``host``."""
+    total_s, self_s)), ``host`` and ``programs`` (the rows of the programs
+    compiled or loaded since the close before: :func:`program_event`)."""
     return list(_steps)
+
+
+# ---------------- set-up and program ledger ----------------
+
+# jax.monitoring's duration events of a program's way to the device, by
+# the phase each closes; all but the cache's carry `fun_name`.  The plain
+# event is fired when a compiled program is written to the persistent
+# cache: a program the cache should have served and did not.
+_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "load",
+}
+_CACHE_WRITE_EVENT = "/jax/compilation_cache/cache_misses"
+
+_programs: collections.deque = collections.deque(maxlen=_PROGRAM_CAP)
+
+
+def _fresh_setup() -> Dict[str, Any]:
+    return {
+        "t_run_ns": None,  # the first setup span entered
+        "phase_ns": {},  # "setup:<phase>" -> nanoseconds, all threads
+        "taken": False,
+        # From process start, over every thread (program_event).
+        "totals": {
+            "programs": 0, "trace_s": 0.0, "lower_s": 0.0, "compile_s": 0.0,
+            "cache_load_s": 0.0, "cache_hits": 0, "cache_misses": 0,
+            "load_max_s": 0.0,
+        },
+    }
+
+
+_setup = _fresh_setup()
+
+
+class _SetupSpan(_Span):
+    """A phase of the build: a span whose seconds are also kept for the
+    process's ``setup/*`` stats, whichever close comes first."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> Dict:
+        args = super().__enter__()
+        if _setup["t_run_ns"] is None:
+            _setup["t_run_ns"] = self.t0
+        return args
+
+    def __exit__(self, *exc) -> bool:
+        dur = time.monotonic_ns() - self.t0
+        with _lock:
+            phases = _setup["phase_ns"]
+            phases[self.name] = phases.get(self.name, 0) + dur
+        return super().__exit__(*exc)
+
+
+def setup_span(phase: str, **args) -> _Span:
+    """``span("setup:<phase>", cat="host")`` around a phase of the build.
+    What the host spent there and no more: nothing waits for the device,
+    so work a phase dispatched is paid where the host next waits.  The
+    first one a process enters ends ``setup/to_run_s``."""
+    name = "setup:" + phase
+    return _SetupSpan(name, "host", args, _annotate(name, args))
+
+
+def _process_start_ns() -> int:
+    """The process's start on the monotonic clock: the kernel's start
+    time of ``/proc/self/stat`` (clock ticks since boot) against the boot
+    clock, or this module's import where that cannot be read."""
+    try:
+        with open("/proc/self/stat") as f:
+            # Past the command's closing parenthesis the state is field
+            # 3 of the file and the start time field 22.
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        age_s = (
+            time.clock_gettime(time.CLOCK_BOOTTIME)
+            - ticks / os.sysconf("SC_CLK_TCK")
+        )
+    except (OSError, ValueError, IndexError, AttributeError):
+        return _T_IMPORT_NS
+    return min(time.monotonic_ns() - int(age_s * 1e9), _T_IMPORT_NS)
+
+
+def program_event(
+    event: str, duration: Optional[float] = None, **kw
+) -> None:
+    """The callback of the process's one ``jax.monitoring`` registration
+    (``system/worker.py`` installs it, for duration events and for plain
+    ones).  It runs on the thread that compiles, at the END of a phase:
+    trace, lowering, then the backend phase with the persistent cache's
+    read inside it.  A phase's seconds are its own: what phases that
+    ended inside it took (a nested jit's trace, an eager operation
+    compiled while tracing) is taken out.  The backend phase closes the
+    program's row."""
+    ts = _thread_state()
+    phase = _PHASES.get(event)
+    if phase is None:
+        if event == _CACHE_WRITE_EVENT:
+            ts.pending[4] = True
+        return
+    now = time.monotonic_ns()
+    start = now - int(duration * 1e9)
+    fun = str(kw.get("fun_name", ""))
+    pending, counts = ts.pending, ts.compiles
+    if phase == "load":  # inside the backend phase, which fires next
+        pending[2] += duration
+        pending[3] = True
+        counts[2] += duration
+    else:
+        own = duration
+        ended = ts.phases
+        while ended and ended[-1][0] >= start:
+            own -= ended.pop()[1]
+        ended.append((start, duration))
+        own = max(own, 0.0)
+        if phase == "trace":
+            pending[0] += own
+            counts[3] += own
+        elif phase == "lower":
+            pending[1] += own
+            counts[4] += own
+        else:
+            counts[0] += 1
+            counts[1] += duration
+            _close_program(ts, fun, duration)
+    # The listener cannot open an annotation around a phase that has
+    # ended: like `host_pause`, this one marks [end - dur_ms, end] on a
+    # live profiler's clock.
+    ann = _annotate(
+        "compile",
+        {"dur_ms": round(duration * 1e3, 3), "phase": phase, "fun": fun},
+    )
+    if ann:
+        with ann:
+            pass
+    complete(
+        "compile" if phase in ("compile", "load") else "compile:" + phase,
+        start, now, cat="host", event=event.rsplit("/", 1)[-1], fun=fun,
+        phase=phase,
+    )
+
+
+def _close_program(ts: _ThreadState, fun: str, backend_s: float) -> None:
+    trace_s, lower_s, load_s, hit, written = ts.pending
+    ts.pending = [0.0, 0.0, 0.0, False, False]
+    row = {
+        "fun": fun,
+        "trace_s": trace_s,
+        "lower_s": lower_s,
+        # The backend phase less the cache's read: the compilation (and
+        # the write) of a program the cache did not serve, the key's
+        # hash of one it did.
+        "compile_s": max(backend_s - load_s, 0.0),
+        "cache_load_s": load_s,
+        "hit": hit,
+        "written": written,
+        "span": ts.stack[-1].name if ts.stack else "",
+    }
+    with _lock:
+        _programs.append(row)
+        totals = _setup["totals"]
+        totals["programs"] += 1
+        totals["trace_s"] += trace_s
+        totals["lower_s"] += lower_s
+        if hit:
+            totals["cache_hits"] += 1
+            totals["cache_load_s"] += backend_s
+            totals["load_max_s"] = max(totals["load_max_s"], backend_s)
+        else:
+            totals["compile_s"] += backend_s
+            totals["cache_misses"] += written
+
+
+def take_compiles() -> Dict[str, float]:
+    """This thread's compile counters since the last call, as the
+    ``perf/*`` keys an MFC returns: programs compiled or loaded, the
+    seconds of their backend phase, the part of those spent reading the
+    persistent cache, and the seconds of tracing and of lowering."""
+    ts = _thread_state()
+    counts, ts.compiles = ts.compiles, [0.0] * 5
+    return dict(zip(
+        ("perf/compiles", "perf/compile_s", "perf/cache_load_s",
+         "perf/trace_s", "perf/lower_s"),
+        counts,
+    ))
+
+
+def setup_take() -> Dict[str, float]:
+    """``setup/<key>`` stats, ONCE a process (and only of a process that
+    built something under :func:`setup_span`): seconds from the process's
+    start to this module's import (``to_import_s``) and to the first
+    set-up span (``to_run_s``), the seconds of the ``build``, ``weights``
+    and ``engine`` phases, and the program ledger's totals from process
+    start over every thread -- ``programs``, ``trace_s``, ``lower_s``,
+    ``compile_s`` (the backend phase of programs the cache did not
+    serve), ``cache_load_s`` (of those it did: key, read and all),
+    ``cache_hits``, ``cache_misses`` (compiled AND written: the cache
+    should have held them), ``load_max_s``."""
+    with _lock:
+        if _setup["taken"] or _setup["t_run_ns"] is None:
+            return {}
+        _setup["taken"] = True
+        phase_ns = dict(_setup["phase_ns"])
+        totals = dict(_setup["totals"])
+    start = _process_start_ns()
+    out = {
+        "setup/to_import_s": (_T_IMPORT_NS - start) / 1e9,
+        "setup/to_run_s": (_setup["t_run_ns"] - start) / 1e9,
+        "setup/build_s": phase_ns.get("setup:build", 0) / 1e9,
+        "setup/weights_s": phase_ns.get("setup:weights", 0) / 1e9,
+        "setup/engines_s": phase_ns.get("setup:engine", 0) / 1e9,
+    }
+    out.update({f"setup/{k}": float(v) for k, v in totals.items()})
+    return out
+
+
+def program_table(rows: List[Dict[str, Any]], n: int = 10) -> str:
+    """The ``n`` rows that took longest, one a line."""
+    def seconds(r):
+        return r["trace_s"] + r["lower_s"] + r["compile_s"] + r["cache_load_s"]
+
+    return "\n".join(
+        f"  {seconds(r):7.2f}s  trace {r['trace_s']:.2f} lower "
+        f"{r['lower_s']:.2f} compile {r['compile_s']:.2f} load "
+        f"{r['cache_load_s']:.2f}  {'hit ' if r['hit'] else 'miss'}  "
+        f"{r['fun']}  [{r['span']}]"
+        for r in sorted(rows, key=seconds, reverse=True)[:n]
+    )
+
+
+def setup_report(stats: Dict[str, float], rows: List[Dict[str, Any]]) -> str:
+    """What a log says of a set-up: the ``setup/<key>`` of ``stats``, the
+    rows that took longest and, where the cache served some programs and
+    not others, the longest of those it had not kept."""
+    totals = {k: round(v, 3) for k, v in stats.items() if "setup/" in k}
+    text = (
+        f"set-up ledger: {totals}\nthe longest of {len(rows)} programs "
+        f"(seconds; hit or miss of the cache; the span open on the "
+        f"compiling thread):\n" + program_table(rows)
+    )
+    written = [r for r in rows if r["written"]]
+    if written and any(r["hit"] for r in rows):
+        text += (
+            f"\n{len(written)} programs were compiled and written, so the "
+            f"cache had not kept them; the longest:\n"
+            + program_table(written)
+        )
+    return text
 
 
 # ---------------- flush / shard IO ----------------
@@ -827,9 +1085,14 @@ def _reset_for_tests() -> None:
         _flight.clear()
         _steps.clear()
         _ledger_state.update(t_close_ns=None, dump_step=None)
+        _programs.clear()
+        _setup.update(_fresh_setup())
         for ts in _threads.values():
             ts.stack.clear()
             ts.ledger.clear()
+            ts.phases.clear()
+            ts.compiles = [0.0] * 5
+            ts.pending = [0.0, 0.0, 0.0, False, False]
 
 
 atexit.register(flush)

@@ -825,36 +825,41 @@ def run_experiment(plan: ExperimentPlan, tokenizer=None, inspect=None):
     tracer.default_dir(
         plan.fileroot, plan.experiment_name, plan.trial_name
     )
-    planes = InProcTransfer.make_group(len(plan.worker_configs))
-    workers = [
-        ModelWorker(wc, tokenizer=tokenizer, transfer=planes[i])
-        for i, wc in enumerate(plan.worker_configs)
-    ]
-    pool = InProcessPool(workers, mfc_timeout_s=plan.mfc_timeout_s)
-    master = MasterWorker(
-        dfg=plan.dfg,
-        pool=pool,
-        model_placement=plan.model_placement,
-        data_worker_ids=plan.data_worker_ids,
-        ctrl=plan.ctrl,
-        fileroot=plan.fileroot,
-        experiment_name=plan.experiment_name,
-        trial_name=plan.trial_name,
-        model_groups=plan.model_groups,
-        model_replicas=plan.model_replicas,
-        difficulty_filter=plan.difficulty_filter,
-        rollout_ahead=plan.rollout_ahead,
-        max_head_offpolicyness=plan.max_head_offpolicyness,
-        replay_capacity=plan.replay_capacity,
-        buffer_max_age_steps=plan.buffer_max_age_steps,
-        pipeline_overlap=plan.pipeline_overlap,
-        overlap_window=plan.overlap_window,
-        pipeline_chunk_seqs=plan.pipeline_chunk_seqs,
-        max_recoveries=plan.max_recoveries,
-        max_consecutive_quarantines=plan.max_consecutive_quarantines,
-        weight_push_checksum=plan.weight_push_checksum,
-    )
-    master.load_recover_info()
+    # What step 1's `setup/*` stats are made of (base/tracer.py): this
+    # span and, inside it, each worker's `setup:worker`, `:mesh`,
+    # `:weights`, `:engine` and `:datasets`.
+    with tracer.setup_span("build"):
+        planes = InProcTransfer.make_group(len(plan.worker_configs))
+        workers = [
+            ModelWorker(wc, tokenizer=tokenizer, transfer=planes[i])
+            for i, wc in enumerate(plan.worker_configs)
+        ]
+        pool = InProcessPool(workers, mfc_timeout_s=plan.mfc_timeout_s)
+        with tracer.setup_span("master"):
+            master = MasterWorker(
+                dfg=plan.dfg,
+                pool=pool,
+                model_placement=plan.model_placement,
+                data_worker_ids=plan.data_worker_ids,
+                ctrl=plan.ctrl,
+                fileroot=plan.fileroot,
+                experiment_name=plan.experiment_name,
+                trial_name=plan.trial_name,
+                model_groups=plan.model_groups,
+                model_replicas=plan.model_replicas,
+                difficulty_filter=plan.difficulty_filter,
+                rollout_ahead=plan.rollout_ahead,
+                max_head_offpolicyness=plan.max_head_offpolicyness,
+                replay_capacity=plan.replay_capacity,
+                buffer_max_age_steps=plan.buffer_max_age_steps,
+                pipeline_overlap=plan.pipeline_overlap,
+                overlap_window=plan.overlap_window,
+                pipeline_chunk_seqs=plan.pipeline_chunk_seqs,
+                max_recoveries=plan.max_recoveries,
+                max_consecutive_quarantines=plan.max_consecutive_quarantines,
+                weight_push_checksum=plan.weight_push_checksum,
+            )
+            master.load_recover_info()
     if inspect is not None:
         inspect(master, "built")
     stats = asyncio.run(master.run())

@@ -560,6 +560,10 @@ class MasterWorker:
                     tracer.close_step(self.pool.step, now - t_closed)
                 )
                 t_closed = now
+                if "setup/programs" in stats:  # this process's first close
+                    logger.info(tracer.setup_report(
+                        stats, tracer.step_ledger()[-1]["programs"]
+                    ))
                 self._export_step_metrics(stats, dt)
                 quarantined = self._note_quarantine(stats)
                 self.stats_history.append(stats)

@@ -39,7 +39,6 @@ from areal_tpu.api.model_api import (
     make_interface,
 )
 from areal_tpu.base import faults, logging, metrics, tracer
-from areal_tpu.base.monitor import Timers
 from areal_tpu.base.topology import ParallelConfig, make_mesh
 from areal_tpu.models.config import ModelConfig
 
@@ -54,40 +53,13 @@ import areal_tpu.interfaces.null  # noqa: F401
 # One xprof trace at a time per process (see _handle_mfc).
 _TRACE_LOCK = threading.Lock()
 
-# Compilation seen by jax.monitoring, per thread: the listener runs on the
-# thread that compiles, which is the thread of the MFC that needed the
-# program.  One listener per process, installed by the first worker; it
-# fires only when something compiles or loads from the persistent cache.
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
-_compile_tls = threading.local()
+# Compilation as jax.monitoring reports it goes to the tracer's program
+# ledger (base/tracer.program_event: one row a program, the per-thread
+# counts behind `perf/compiles`, `compile_s`, `cache_load_s`, `trace_s`
+# and `lower_s`, the process's set-up totals).  One registration per
+# process, made by the first worker; the callbacks fire only when
+# something is traced, lowered, compiled or loaded.
 _compile_listener_installed = False
-
-
-def _compile_counts() -> List[float]:
-    """This thread's [programs, compile-or-load seconds, load seconds]."""
-    counts = getattr(_compile_tls, "counts", None)
-    if counts is None:
-        counts = _compile_tls.counts = [0.0, 0.0, 0.0]
-    return counts
-
-
-def _on_compile_event(event: str, duration: float, **kw) -> None:
-    counts = _compile_counts()
-    if event == _COMPILE_EVENT:
-        # Wraps the whole compile-or-load; the cache's own event (below,
-        # fired first) says how much of it was a load.
-        counts[0] += 1
-        counts[1] += duration
-    elif event == _CACHE_LOAD_EVENT:
-        counts[2] += duration
-    else:
-        return
-    now = time.monotonic_ns()
-    tracer.complete(
-        "compile", now - int(duration * 1e9), now, cat="host",
-        event=event.rsplit("/", 1)[-1], fun=str(kw.get("fun_name", "")),
-    )
 
 
 def _step(req: Dict[str, Any]) -> Dict[str, int]:
@@ -101,20 +73,11 @@ def _install_compile_listener() -> None:
         return
     import jax.monitoring
 
-    jax.monitoring.register_event_duration_secs_listener(_on_compile_event)
+    # jax hands durations and plain events (the cache's "written after a
+    # miss") to separate lists of listeners: the one callback is on both.
+    jax.monitoring.register_event_duration_secs_listener(tracer.program_event)
+    jax.monitoring.register_event_listener(tracer.program_event)
     _compile_listener_installed = True
-
-
-def _take_compiles() -> Dict[str, float]:
-    """This thread's compile counters since the last call, as the `perf/*`
-    keys an MFC returns: programs compiled or loaded, the seconds that
-    took, and the part of them spent reading the persistent cache."""
-    counts = _compile_counts()
-    out = dict(zip(
-        ("perf/compiles", "perf/compile_s", "perf/cache_load_s"), counts
-    ))
-    counts[:] = [0.0, 0.0, 0.0]
-    return out
 
 
 def _zero_filled(meta_row: SequenceSample, keys) -> SequenceSample:
@@ -270,10 +233,6 @@ class ModelWorker:
         self._streams: Dict[str, Dict[str, Any]] = {}
         self.datasets = []
         self.dataloaders = []
-        # Per-phase wall-clock marks, drained into each MFC's stats reply
-        # (time/mfc_<itype>, _cnt, _avg) so the master's per-step log shows
-        # where worker time went without a tracer attached.
-        self.timers = Timers()
         reg = metrics.default_registry()
         self._m_mfc_seconds = reg.histogram(
             "areal_worker_mfc_seconds",
@@ -297,11 +256,16 @@ class ModelWorker:
         # path.  None when unset — the fault-free hot path pays one
         # attribute check per request.
         self._faults = faults.FaultInjector.from_env()
-        self._setup()
+        with tracer.setup_span("worker", worker=config.worker_index):
+            self._setup()
 
     # ---------------- setup ----------------
 
     def _setup(self):
+        """Build every shard's model and the datasets, each phase under a
+        `setup:*` span (host seconds: nothing here waits for the device,
+        so what the initialiser dispatched is paid where the host next
+        waits, as a rule in the first MFC)."""
         import jax
 
         _install_compile_listener()
@@ -320,40 +284,49 @@ class ModelWorker:
                 if shard.device_offset is not None
                 else self.config.device_offset
             )
-            devices = jax.devices()[off : off + shard.parallel.world_size]
-            mesh = make_mesh(shard.parallel, devices)
-            cfg, params = _build_params_and_config(
-                shard.model, seed=self.config.seed, mesh=mesh
-            )
-            btype = shard.backend.type_
-            if btype in ("train", "mock"):
-                engine = TrainEngine(
-                    cfg, params, mesh,
-                    optimizer_config=shard.optimizer or OptimizerConfig(),
-                    ftspec=self.config.ftspec,
-                    **shard.backend.args,
-                )
-            elif btype == "inference":
-                engine = InferenceEngine(cfg, params, mesh, **shard.backend.args)
-            elif btype == "generator":
-                engine = GeneratorEngine(
-                    cfg, params, mesh,
-                    eos_token_id=self.tokenizer.eos_token_id,
-                    pad_token_id=getattr(self.tokenizer, "pad_token_id", None),
-                    **shard.backend.args,
-                )
-            elif btype == "remote_generator":
-                # Decoupled allocation: generation served by a standalone
-                # GenerationServer; this worker holds no gen weights
-                # (reference: sglang backend, backend/sglang.py:354).
-                from areal_tpu.system.gen_server import RemoteGeneratorEngine
-
-                engine = RemoteGeneratorEngine(cfg, **shard.backend.args)
-            elif btype == "null":
-                engine = None
-            else:
-                raise ValueError(f"unknown backend {btype!r}")
             key = str(shard.name)
+            with tracer.setup_span("mesh", model=key):
+                devices = jax.devices()[off : off + shard.parallel.world_size]
+                mesh = make_mesh(shard.parallel, devices)
+            with tracer.setup_span("weights", model=key):
+                cfg, params = _build_params_and_config(
+                    shard.model, seed=self.config.seed, mesh=mesh
+                )
+            btype = shard.backend.type_
+            with tracer.setup_span("engine", model=key, backend=btype):
+                if btype in ("train", "mock"):
+                    engine = TrainEngine(
+                        cfg, params, mesh,
+                        optimizer_config=shard.optimizer or OptimizerConfig(),
+                        ftspec=self.config.ftspec,
+                        **shard.backend.args,
+                    )
+                elif btype == "inference":
+                    engine = InferenceEngine(
+                        cfg, params, mesh, **shard.backend.args
+                    )
+                elif btype == "generator":
+                    engine = GeneratorEngine(
+                        cfg, params, mesh,
+                        eos_token_id=self.tokenizer.eos_token_id,
+                        pad_token_id=getattr(
+                            self.tokenizer, "pad_token_id", None
+                        ),
+                        **shard.backend.args,
+                    )
+                elif btype == "remote_generator":
+                    # Decoupled allocation: generation served by a standalone
+                    # GenerationServer; this worker holds no gen weights
+                    # (reference: sglang backend, backend/sglang.py:354).
+                    from areal_tpu.system.gen_server import (
+                        RemoteGeneratorEngine,
+                    )
+
+                    engine = RemoteGeneratorEngine(cfg, **shard.backend.args)
+                elif btype == "null":
+                    engine = None
+                else:
+                    raise ValueError(f"unknown backend {btype!r}")
             self.models[key] = Model(
                 name=key, engine=engine, tokenizer=self.tokenizer, config=cfg
             )
@@ -366,27 +339,28 @@ class ModelWorker:
                 f"({shard.backend.type_}, mesh {shard.parallel.to_str()})"
             )
 
-        for ds_spec in self.config.datasets:
-            ds = make_dataset(
-                ds_spec,
-                seed=self.config.seed,
-                dp_rank=self.config.dataset_dp_rank,
-                world_size=self.config.dataset_dp_size,
-                tokenizer=self.tokenizer,
-            )
+        with tracer.setup_span("datasets", n=len(self.config.datasets)):
             from areal_tpu.data.datasets import PackedDataLoader
 
-            self.datasets.append(ds)
-            self.dataloaders.append(
-                iter(
-                    _Cycler(
-                        PackedDataLoader(
-                            ds, batch_size=self.config.batch_size,
-                            seed=self.config.seed,
+            for ds_spec in self.config.datasets:
+                ds = make_dataset(
+                    ds_spec,
+                    seed=self.config.seed,
+                    dp_rank=self.config.dataset_dp_rank,
+                    world_size=self.config.dataset_dp_size,
+                    tokenizer=self.tokenizer,
+                )
+                self.datasets.append(ds)
+                self.dataloaders.append(
+                    iter(
+                        _Cycler(
+                            PackedDataLoader(
+                                ds, batch_size=self.config.batch_size,
+                                seed=self.config.seed,
+                            )
                         )
                     )
                 )
-            )
 
     # ---------------- request handling ----------------
 
@@ -538,37 +512,36 @@ class ModelWorker:
         model = self.models[model_key]
         interface = self.interfaces[model_key]
         fn = getattr(interface, itype.value)
-        _take_compiles()  # executor threads are reused: start from zero
+        tracer.take_compiles()  # executor threads are reused: start from zero
         mfc_span = tracer.span(
             f"mfc:{model_key}:{itype.value}", cat="compute",
             **_step(req),
         )
         with mfc_span as targs:
-            with self.timers.record(f"mfc_{itype.value}"):
-                t0 = time.monotonic()
-                # Env-gated xprof capture per MFC (reference: REAL_DUMP_TRACE
-                # torch profiler export, model_worker.py:84-99,788-869).  Each
-                # MFC call writes a TensorBoard-viewable trace under
-                # $AREAL_DUMP_TRACE/<model>_<itype>/.
-                trace_root = os.environ.get("AREAL_DUMP_TRACE")
-                # JAX allows ONE active trace per process; concurrent MFCs (the
-                # in-process runner overlaps independent graph nodes) contend,
-                # so whoever holds the lock traces and the rest run untraced.
-                if trace_root and _TRACE_LOCK.acquire(blocking=False):
-                    import jax
+            t0 = time.monotonic()
+            # Env-gated xprof capture per MFC (reference: REAL_DUMP_TRACE
+            # torch profiler export, model_worker.py:84-99,788-869).  Each
+            # MFC call writes a TensorBoard-viewable trace under
+            # $AREAL_DUMP_TRACE/<model>_<itype>/.
+            trace_root = os.environ.get("AREAL_DUMP_TRACE")
+            # JAX allows ONE active trace per process; concurrent MFCs (the
+            # in-process runner overlaps independent graph nodes) contend,
+            # so whoever holds the lock traces and the rest run untraced.
+            if trace_root and _TRACE_LOCK.acquire(blocking=False):
+                import jax
 
-                    tdir = os.path.join(
-                        trace_root,
-                        f"{model_key.replace('/', '-')}_{itype.value}",
-                    )
-                    try:
-                        with jax.profiler.trace(tdir):
-                            result = fn(model, sample, mb_spec)
-                    finally:
-                        _TRACE_LOCK.release()
-                else:
-                    result = fn(model, sample, mb_spec)
-                mfc_seconds = time.monotonic() - t0
+                tdir = os.path.join(
+                    trace_root,
+                    f"{model_key.replace('/', '-')}_{itype.value}",
+                )
+                try:
+                    with jax.profiler.trace(tdir):
+                        result = fn(model, sample, mb_spec)
+                finally:
+                    _TRACE_LOCK.release()
+            else:
+                result = fn(model, sample, mb_spec)
+            mfc_seconds = time.monotonic() - t0
             if itype == ModelInterfaceType.GENERATE:
                 model.inc_version()  # advances the sampling seed per step
 
@@ -579,8 +552,7 @@ class ModelWorker:
                 perf = self._mfc_perf(
                     model, itype, sample, out_sample, mfc_seconds
                 )
-            perf.update(_take_compiles())
-            perf.update(self.timers.drain())
+            perf.update(tracer.take_compiles())
             mfc_label = f"{model_key}:{itype.value}"
             self._m_mfc_seconds.labels(mfc_label).observe(mfc_seconds)
             if "perf/mfu" in perf:
@@ -625,12 +597,13 @@ class ModelWorker:
 
     @staticmethod
     def _own_host_record() -> Dict[str, float]:
-        """`host/<key>` stats for an MFC's reply where this worker runs in
-        a process of its own; under the master's roof the master's step
-        close reports the one host watch they share."""
+        """`host/<key>` stats (and, in the process's first reply,
+        `setup/<key>`) for an MFC's reply where this worker runs in a
+        process of its own; under the master's roof the master's step
+        close reports the one host watch and the one set-up they share."""
         if tracer.role() == "master":
             return {}
-        return tracer.host_take()
+        return {**tracer.host_take(), **tracer.setup_take()}
 
     # ------------- pipeline-overlapped train stream -------------
     #
@@ -677,20 +650,19 @@ class ModelWorker:
             req.get("shard_meta"),
             req.get("input_key_remap", {}),
         )
-        _take_compiles()
+        tracer.take_compiles()
         # The fields below are stamped after the block: the span's event
         # holds this same dict, flushed later.
         with tracer.span(
             f"mfc:{model_key}:train_chunk", cat="compute",
             **_step(req),
         ) as targs:
-            with self.timers.record("mfc_train_chunk"):
-                t0 = time.monotonic()
-                stats = interface.train_stream_chunk(
-                    model, st["state"], sample, mb_spec
-                )
-                seconds = time.monotonic() - t0
-        for k, v in _take_compiles().items():
+            t0 = time.monotonic()
+            stats = interface.train_stream_chunk(
+                model, st["state"], sample, mb_spec
+            )
+            seconds = time.monotonic() - t0
+        for k, v in tracer.take_compiles().items():
             st["compiles"][k] = st["compiles"].get(k, 0.0) + v
         st["busy_s"] += seconds
         st["n_chunks"] += 1
@@ -737,22 +709,21 @@ class ModelWorker:
         model = self.models[model_key]
         interface = self.interfaces[model_key]
         mb_spec: MicroBatchSpec = req.get("mb_spec") or MicroBatchSpec()
-        _take_compiles()
+        tracer.take_compiles()
         mfc_span = tracer.span(
             f"mfc:{model_key}:train_step", cat="compute",
             **_step(req),
         )
         with mfc_span as targs:
-            with self.timers.record("mfc_train_step"):
-                t0 = time.monotonic()
-                result = interface.train_stream_end(
-                    model, st["state"], mb_spec
-                )
-                seconds = time.monotonic() - t0
+            t0 = time.monotonic()
+            result = interface.train_stream_end(
+                model, st["state"], mb_spec
+            )
+            seconds = time.monotonic() - t0
         busy = st["busy_s"] + seconds
         perf = {"perf/time_s": busy, "perf/self_s": mfc_span.self_ns / 1e9}
         perf.update(self._own_host_record())
-        for k, v in _take_compiles().items():
+        for k, v in tracer.take_compiles().items():
             perf[k] = st["compiles"].get(k, 0.0) + v
         try:
             cfg = model.config
@@ -769,7 +740,6 @@ class ModelWorker:
                     perf["perf/mfu"] = u
         except Exception as e:  # perf accounting must never fail the MFC
             logger.warning(f"perf accounting failed: {e!r}")
-        perf.update(self.timers.drain())
         mfc_label = f"{model_key}:train_step"
         self._m_mfc_seconds.labels(mfc_label).observe(busy)
         if "perf/mfu" in perf:

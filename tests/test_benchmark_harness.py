@@ -147,6 +147,15 @@ LFM2_ENTRIES = [
     ("moe_train_mlp_mfu_sconv", "%", "higher", "device_trace", "kernels",
      "train_tokens_per_s"),
 ]
+# PR 51's entries, in ISSUE 51's order: what `setup_s` is made of, from
+# the program's own set-up ledger, in every cell.
+SETUP_ENTRIES = [
+    ("setup_import_s", "s"), ("setup_build_s", "s"),
+    ("setup_weights_s", "s"), ("setup_trace_lower_s", "s"),
+    ("setup_cache_load_s", "s"), ("setup_compile_s", "s"),
+    ("setup_programs", "count"), ("setup_cache_misses", "count"),
+    ("setup_first_step_other_s", "s"), ("setup_unaccounted_s", "s"),
+]
 # The lists a static MoE share cell joins (ISSUEs 38, 40, 44, 48).
 SHARE_CELL_LISTS = {
     "gen_tokens_per_s", "decode_ms_per_step", "decode_loop_ms",
@@ -479,12 +488,13 @@ def test_the_lfm2_cell_is_as_the_issue_parametrised_it():
     # The traffic file is the window / full cell's, unchanged.
     assert files.load_cell(MELLUM_CELL)[2] == traffic
     n = len(LFM2_ENTRIES)
-    assert SPEC["per_layer"][-n:] == [
+    first = _at(SPEC["per_layer"], LFM2_ENTRIES[0][0])
+    assert SPEC["per_layer"][first: first + n] == [
         {"name": name, "unit": unit, "better": better, "source": source,
          "layer": layer, "moves": moves, "workloads": [LFM2_CELL]}
         for name, unit, better, source, layer, moves in LFM2_ENTRIES
     ]
-    assert SPEC["per_layer"][-n - 1]["name"] == MELLUM_ENTRIES[-1][0]
+    assert SPEC["per_layer"][first - 1]["name"] == MELLUM_ENTRIES[-1][0]
     listed = {
         m["name"] for m in SPEC["end_to_end"] + SPEC["per_layer"]
         if LFM2_CELL in m.get("workloads", [])
@@ -498,6 +508,140 @@ def test_the_lfm2_cell_is_as_the_issue_parametrised_it():
     # Every metric without a list is the cell's too, and its reader loads.
     for m in files.metrics_for(LFM2_CELL, traced=True):
         assert hasattr(files.load_module("metrics", m["name"]), "read")
+
+
+def test_the_set_up_entries_are_the_last_ten_and_every_cells():
+    """ISSUE 51: ten readers of the program's set-up ledger appended to
+    `per_layer` in the issue's order, each without a `workloads` list (all
+    ten cells report them), right after PR 48's."""
+    n = len(SETUP_ENTRIES)
+    assert SPEC["per_layer"][-n:] == [
+        {"name": name, "unit": unit, "better": "lower",
+         "source": "program_counter", "layer": "build", "moves": "setup_s"}
+        for name, unit in SETUP_ENTRIES
+    ]
+    assert SPEC["per_layer"][-n - 1]["name"] == LFM2_ENTRIES[-1][0]
+    assert not [m["name"] for m in SPEC["per_layer"][:-n]
+                if m["moves"] == "setup_s"]
+    for cell in CELLS:
+        mine = [m["name"] for m in files.metrics_for(cell, traced=True)]
+        assert mine[-n:] == [name for name, _ in SETUP_ENTRIES], cell
+    for name, _ in SETUP_ENTRIES:
+        assert callable(files.load_module("metrics", name).read), name
+
+
+def _setup_run(stats, setup_s=100.0):
+    from benchmark.run import Run
+
+    return Run(
+        cell_name="x", cell={}, config={}, traffic={}, model_cfg=None,
+        chips=1, device_kind="TPU v5 lite", peaks=None, seed=0, traced=True,
+        warmup={"stats": stats}, setup_s=setup_s,
+    )
+
+
+# Step 1's stats as one process writes them: its own `setup/*`, and each
+# node's `perf/*` with the node's name in front.
+_SETUP_STATS = {
+    "time/step_s": 60.0,
+    "setup/to_import_s": 3.0, "setup/to_run_s": 12.0, "setup/build_s": 20.0,
+    "setup/weights_s": 8.0, "setup/engines_s": 5.0, "setup/programs": 40.0,
+    "setup/trace_s": 9.0, "setup/lower_s": 6.0, "setup/compile_s": 0.5,
+    "setup/cache_load_s": 14.0, "setup/cache_hits": 30.0,
+    "setup/cache_misses": 2.0, "setup/load_max_s": 4.0,
+    "actor_gen/perf/trace_s": 4.0, "actor_gen/perf/lower_s": 3.0,
+    "actor_gen/perf/compile_s": 7.0, "actor_gen/perf/cache_load_s": 6.0,
+    "actor_train/perf/trace_s": 4.5, "actor_train/perf/lower_s": 2.5,
+    "actor_train/perf/compile_s": 6.0, "actor_train/perf/cache_load_s": 5.0,
+    "rew_inf/perf/trace_s": 0.0, "rew_inf/perf/lower_s": 0.0,
+    "rew_inf/perf/compile_s": 0.0, "rew_inf/perf/cache_load_s": 0.0,
+}
+
+
+@pytest.mark.parametrize("name,want", [
+    ("setup_import_s", 12.0), ("setup_build_s", 20.0),
+    ("setup_weights_s", 8.0), ("setup_trace_lower_s", 15.0),
+    ("setup_cache_load_s", 14.0), ("setup_compile_s", 0.5),
+    ("setup_programs", 40.0), ("setup_cache_misses", 2.0),
+    # 60 s of step less 8.5 traced, 5.5 lowered and 13 in the backend
+    # phase, which holds the loads.
+    ("setup_first_step_other_s", 33.0),
+    # 100 s of set-up less 12 to the build, 20 of build, 60 of step.
+    ("setup_unaccounted_s", 8.0),
+])
+def test_a_set_up_reader_reads_step_ones_stats(name, want, capsys):
+    from areal_tpu.base import tracer
+
+    read = files.load_module("metrics", name).read
+    assert read(_setup_run(_SETUP_STATS)) == pytest.approx(want)
+    # The parent of PR 51 keeps no such ledger: the line leaves them out.
+    bare = {k: v for k, v in _SETUP_STATS.items()
+            if "setup/" not in k and "trace_s" not in k and "lower_s" not in k}
+    assert read(_setup_run(bare)) is None
+    assert read(_setup_run({})) is None
+    run = _setup_run({})
+    run.warmup = None  # a run that never reached its first step's end
+    assert read(run) is None
+    # Workers in processes of their own reply for themselves: summed.
+    apart = {
+        (f"{node}/{k}" if k.startswith("setup/") else k): v
+        for node in ("actor_gen", "actor_train")
+        for k, v in _SETUP_STATS.items()
+    }
+    want_apart = {"setup_first_step_other_s": want,
+                  "setup_unaccounted_s": 100.0 - 2 * 32.0 - 60.0}
+    assert read(_setup_run(apart)) == pytest.approx(
+        want_apart.get(name, 2 * want))
+    if name == "setup_programs":
+        # ... and writes step 1's longest rows where the run's log is.
+        tracer._reset_for_tests()
+        tracer.program_event(
+            "/jax/core/compile/backend_compile_duration", 2.5,
+            fun_name="jit_gen")
+        tracer.close_step(1, 1.0)
+        capsys.readouterr()
+        read(_setup_run(_SETUP_STATS))
+        err = capsys.readouterr().err
+        tracer._reset_for_tests()
+        assert err.startswith("[benchmark] set-up ledger: {'setup/to_import_s")
+        assert "the longest of 1 programs" in err
+        assert "compile 2.50" in err and "jit_gen" in err
+        assert "compiled and written" not in err  # nothing was served
+
+
+def test_cpu_rehearsal_of_a_dense_cell_reports_the_whole_set_up():
+    """A traced rehearsal of `q1p5b-decode-static`: all ten readers find
+    their keys, the four parts make `setup_s` by construction, and the
+    table of programs is in the log."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=files.ROOT)
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run(
+        [sys.executable, "-m", "benchmark.run", "--workload",
+         "q1p5b-decode-static", "--seed", "2200000051", "--seconds", "1",
+         "--trace", "1", "--cpu-rehearsal"],
+        cwd=files.ROOT, env=env, capture_output=True, text=True, timeout=600,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    lines = proc.stderr.splitlines()
+    out = json.loads(
+        [l for l in lines if "would print: " in l][-1].split("would print: ")[1])
+    assert out["correct"] is True
+    got = {k: v["value"] for k, v in out["metrics"].items()
+           if k.startswith("setup_")}
+    assert set(got) == {name for name, _ in SETUP_ENTRIES}
+    assert all(v >= 0 for k, v in got.items() if k != "setup_unaccounted_s")
+    assert got["setup_weights_s"] <= got["setup_build_s"]
+    assert got["setup_programs"] >= 3 and got["setup_trace_lower_s"] > 0
+    said = [l for l in lines if "(warm-up step " in l][-1]
+    warmup_s = float(said.split("(warm-up step ")[1].split("s;")[0])
+    setup_s = float(said.split("] set-up ")[1].split("s ")[0])
+    assert (got["setup_import_s"] + got["setup_build_s"] + warmup_s
+            + got["setup_unaccounted_s"]) == pytest.approx(setup_s, abs=0.2)
+    assert abs(got["setup_unaccounted_s"]) < 0.5 * setup_s
+    assert any("] set-up ledger: {'setup/to_import_s'" in l for l in lines)
+    table = lines.index(next(
+        l for l in lines if l.startswith("the longest of ")))
+    assert " trace " in lines[table + 1] and " lower " in lines[table + 1]
 
 
 def test_every_name_in_benchmark_json_is_a_cell_and_its_files_resolve():

@@ -209,19 +209,6 @@ class TestMergeStats:
         assert out["acc"] == pytest.approx(2.0)
 
 
-def test_timers_accumulate():
-    t = monitor.Timers()
-    with t.record("a"):
-        pass
-    with t.record("a"):
-        pass
-    out = t.drain()
-    assert set(out) == {"time/a", "time/a_cnt", "time/a_avg"}
-    assert out["time/a_cnt"] == 2
-    assert out["time/a_avg"] == pytest.approx(out["time/a"] / 2)
-    assert t.drain() == {}
-
-
 def test_stats_logger_jsonl(tmp_path):
     sl = monitor.StatsLogger(str(tmp_path), "e", "t", use_tensorboard=False)
     sl.log(1, {"loss": 0.5})

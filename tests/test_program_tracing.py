@@ -4,8 +4,11 @@ the master's step number crosses the hop to the workers, the engines keep
 the counters the benchmark reads, compilation is charged to the MFC that
 needed it, and every part of the device programs carries a stable name."""
 
+import contextlib
 import threading
+import time
 import timeit
+import types
 
 import jax
 import jax.numpy as jnp
@@ -52,6 +55,27 @@ def annotations(monkeypatch):
     return _FakeAnnotation.log
 
 
+@contextlib.contextmanager
+def _own_cache(path):
+    """jax's persistent cache in a directory of the caller's own, keeping
+    every program however quickly it compiled; the suite's cache and its
+    threshold are put back."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs")
+    before = [getattr(jax.config, k) for k in keys]
+    jax.config.update(keys[0], str(path))
+    jax.config.update(keys[1], 0.0)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        for k, v in zip(keys, before):
+            jax.config.update(k, v)
+        cc.reset_cache()
+
+
 # ---------------- spans on the profiler's clock ----------------
 
 
@@ -72,12 +96,9 @@ def test_span_opens_one_areal_annotation(tmp_path, annotations, enabled):
     ]
 
 
-def test_decorator_annotates_with_tracing_off(annotations):
-    @tracer.trace("load_data", cat="host")
-    def f(x):
-        return x + 1
-
-    assert f(1) == 2
+def test_span_annotates_with_tracing_off(annotations):
+    with tracer.span("load_data", cat="host"):
+        pass
     assert [a[:2] for a in annotations] == [
         ("open", "areal:load_data"), ("close", "areal:load_data")
     ]
@@ -290,9 +311,12 @@ def trial(tmp_path_factory):
 
         master.stats_logger.log = log
 
-    _, stats = run_experiment(
-        build_ppo_math(cfg, tok), tokenizer=tok, inspect=inspect
-    )
+    with _own_cache(root / "jax_cache"):
+        _, stats = run_experiment(
+            build_ppo_math(cfg, tok), tokenizer=tok, inspect=inspect
+        )
+    seen["age_s"] = (time.monotonic_ns() - tracer._process_start_ns()) / 1e9
+    seen["ledger"] = tracer.step_ledger()
     tracer.flush()
     events = []
     for path in sorted((root / "trace").glob("trace_*.jsonl")):
@@ -330,8 +354,8 @@ def test_engine_spans_nest_under_the_mfc(trial):
         "mfc:actor@0:train_step"
     }
     assert {"mb_upload", "grad_dispatch", "apply_dispatch"} <= set(parents)
-    # (the engine's first set_params, at build, is under no span)
-    assert parents["params_put"] == {None, "param_sync:actor_gen@0"}
+    # (the engine's first set_params is its constructor's, at build)
+    assert parents["params_put"] == {"setup:engine", "param_sync:actor_gen@0"}
     # The cast is inside realloc.reshard's placement now (in its compiled
     # program for device leaves), not an eager pass of its own.
     assert "params_cast" not in parents
@@ -407,6 +431,207 @@ def test_compiles_are_charged_to_the_mfc_that_compiled(trial):
                                "cache_retrieval_time_sec")
         for e in compiles
     )
+
+
+def test_step_one_carries_the_set_up_once(trial):
+    first, second, third = trial["stats"]
+    keys = {k for k in first if k.startswith("setup/")}
+    assert keys == {"setup/" + k for k in (
+        "to_import_s", "to_run_s", "build_s", "weights_s", "engines_s",
+        "programs", "trace_s", "lower_s", "compile_s", "cache_load_s",
+        "cache_hits", "cache_misses", "load_max_s",
+    )}
+    assert not any(k.startswith("setup/") for k in {**second, **third})
+    # The process's life so far holds its way to the build, the build and
+    # the step, one after another.
+    assert 0 <= first["setup/to_import_s"] <= first["setup/to_run_s"]
+    assert (first["setup/to_run_s"] + first["setup/build_s"]
+            + first["time/step_s"] <= trial["age_s"])
+    assert (0 < first["setup/weights_s"] + first["setup/engines_s"]
+            <= first["setup/build_s"])
+    # Every program was looked up in the cache of the trial's own: read
+    # from it, or compiled and written to it.
+    assert first["setup/programs"] >= 3  # generate, gradient, apply
+    assert first["setup/programs"] == (
+        first["setup/cache_hits"] + first["setup/cache_misses"])
+    assert first["setup/trace_s"] > 0 and first["setup/lower_s"] > 0
+    assert first["setup/compile_s"] > 0 or first["setup/cache_load_s"] > 0
+    assert first["setup/load_max_s"] <= first["setup/cache_load_s"]
+
+
+def test_the_ledger_has_a_row_a_program_under_the_span_that_compiled(trial):
+    step1, _, step3 = trial["ledger"]
+    assert len(step1["programs"]) == trial["stats"][0]["setup/programs"]
+    spans = {r["span"] for r in step1["programs"]}
+    # (the initialiser under `setup:weights` is cached a process: another
+    # file's trial may have compiled it)
+    assert {"grad_dispatch", "apply_dispatch"} <= spans
+    for row in step1["programs"] + step3["programs"]:
+        assert set(row) == {"fun", "trace_s", "lower_s", "compile_s",
+                            "cache_load_s", "hit", "written", "span"}
+        assert row["hit"] == (row["cache_load_s"] > 0)
+        assert not (row["hit"] and row["written"])
+    # Step 3 built the generator's chunk again: traced and lowered in
+    # full, and the executable read from the cache step 1 wrote it to.
+    chunks = [r for r in step3["programs"] if r["span"] == "chunk_dispatch"]
+    assert chunks and all(
+        r["hit"] and r["trace_s"] > 0 and r["lower_s"] > 0 for r in chunks
+    )
+
+
+def test_trace_and_lower_seconds_reach_the_mfc_that_compiled(trial):
+    first, second, third = trial["stats"]
+    for node in ("actor_gen", "actor_train"):
+        assert first[f"{node}/perf/trace_s"] > 0
+        assert first[f"{node}/perf/lower_s"] > 0
+    # Nothing new to the trainer in step 3; the generator's chunk is.
+    assert third["actor_train/perf/trace_s"] == 0
+    assert third["actor_train/perf/lower_s"] == 0
+    assert third["actor_gen/perf/trace_s"] > 0
+    # The phases are the step's own: they fit into its wall.
+    for stats in trial["stats"]:
+        spent = sum(
+            v for k, v in stats.items()
+            if k.endswith(("perf/trace_s", "perf/lower_s", "perf/compile_s"))
+        )
+        assert spent <= stats["time/step_s"]
+    phased = [e for e in trial["events"] if e["name"].startswith("compile")]
+    assert {(e["name"], e["args"]["phase"]) for e in phased} == {
+        ("compile:trace", "trace"), ("compile:lower", "lower"),
+        ("compile", "compile"), ("compile", "load"),
+    }
+
+
+def test_a_program_is_one_row_cold_and_then_warm(tmp_path):
+    from areal_tpu.system import worker
+
+    worker._install_compile_listener()
+
+    def scaled_sum(x):
+        return (x * 3.0).sum()
+
+    x = np.arange(8, dtype=np.float32)
+    rows = []
+    with _own_cache(tmp_path / "jax_cache"):
+        for step in (1, 2):
+            with tracer.span("setup:weights"):
+                assert float(jax.jit(scaled_sum)(x)) == 84.0
+            jax.clear_caches()  # the next call starts over, cache aside
+            tracer.close_step(step, 1.0)
+            (row,) = [r for r in tracer.step_ledger()[-1]["programs"]
+                      if "scaled_sum" in r["fun"]]
+            rows.append(row)
+    cold, warm = rows
+    assert not cold["hit"] and cold["written"]
+    assert cold["compile_s"] > 0 and cold["cache_load_s"] == 0
+    assert warm["hit"] and not warm["written"] and warm["cache_load_s"] > 0
+    for row in rows:
+        assert row["trace_s"] > 0 and row["lower_s"] > 0
+        assert row["span"] == "setup:weights"
+    counts = tracer.take_compiles()
+    assert counts["perf/compiles"] >= 2
+    assert counts["perf/cache_load_s"] >= warm["cache_load_s"]
+    assert tracer.take_compiles() == dict.fromkeys(counts, 0.0)
+
+
+_TRACE, _LOWER, _BACKEND, _LOAD = (
+    "/jax/core/compile/jaxpr_trace_duration",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration",
+    "/jax/core/compile/backend_compile_duration",
+    "/jax/compilation_cache/cache_retrieval_time_sec",
+)
+
+
+@pytest.fixture
+def clock(monkeypatch):
+    """The tracer's monotonic clock in the test's hands (seconds)."""
+    now = [100.0]
+    monkeypatch.setattr(tracer, "time", types.SimpleNamespace(
+        monotonic_ns=lambda: int(now[0] * 1e9), time=time.time,
+    ))
+    return now
+
+
+def test_a_phase_keeps_its_own_seconds(clock):
+    """A jit traced inside another's trace, and an eager operation
+    compiled while tracing, are inside the outer trace's seconds as jax
+    reports them; the ledger counts each second once."""
+    def end(event, at, seconds, **kw):
+        clock[0] = at
+        tracer.program_event(event, seconds, **kw)
+
+    end(_TRACE, 100.3, 0.2, fun_name="inner")  # [100.1, 100.3]
+    end(_TRACE, 100.5, 0.1, fun_name="eager")  # an eager op: a program
+    end(_LOWER, 100.6, 0.1, fun_name="jit_eager")
+    end(_BACKEND, 100.9, 0.3, fun_name="jit_eager")
+    end(_TRACE, 101.0, 1.0, fun_name="outer")  # [100.0, 101.0], all of it
+    end(_LOWER, 101.5, 0.5, fun_name="jit_outer")
+    end(_LOAD, 101.9, 0.3)
+    tracer.program_event("/jax/compilation_cache/cache_hits")
+    end(_BACKEND, 102.0, 0.5, fun_name="jit_outer")
+    tracer.close_step(1, 2.0)
+    eager, outer = tracer.step_ledger()[-1]["programs"]
+    assert eager["fun"] == "jit_eager" and outer["fun"] == "jit_outer"
+    # The nested jit's trace ended first and went to the first row closed.
+    assert eager["trace_s"] == pytest.approx(0.3)
+    assert outer["trace_s"] == pytest.approx(1.0 - 0.2 - 0.1 - 0.1 - 0.3)
+    assert outer["lower_s"] == pytest.approx(0.5)
+    assert (outer["compile_s"], outer["cache_load_s"], outer["hit"]) == (
+        pytest.approx(0.2), pytest.approx(0.3), True)
+    counts = tracer.take_compiles()
+    assert counts == {
+        "perf/compiles": 2.0, "perf/compile_s": pytest.approx(0.8),
+        "perf/cache_load_s": pytest.approx(0.3),
+        "perf/trace_s": pytest.approx(0.6), "perf/lower_s": pytest.approx(0.6),
+    }
+    # trace + lower + backend phases: the two seconds that passed.
+    assert sum(counts[k] for k in (
+        "perf/trace_s", "perf/lower_s", "perf/compile_s"
+    )) == pytest.approx(2.0)
+
+
+def test_a_compile_phase_is_annotated_only_for_a_live_profiler(
+        annotations, monkeypatch):
+    """The listener fires when a phase has ENDED: like `host_pause`, the
+    annotation is written at the end and carries the duration."""
+    tracer.program_event(_LOWER, 0.25, fun_name="jit_gen")
+    assert annotations == [
+        ("open", "areal:compile",
+         {"dur_ms": 250.0, "phase": "lower", "fun": "jit_gen"}),
+        ("close", "areal:compile"),
+    ]
+    del annotations[:]
+    tracer.program_event("/jax/compilation_cache/cache_misses")  # no phase
+    tracer.program_event("/jax/core/compile/some_other_duration", 1.0)
+    assert annotations == []
+    # A process without jax has no profiler to write to; with jax and no
+    # session the real annotation is inert.
+    import sys
+
+    monkeypatch.setattr(tracer, "_ANNOTATIONS", None)
+    with monkeypatch.context() as m:
+        m.setitem(sys.modules, "jax", None)
+        tracer.program_event(_TRACE, 0.1, fun_name="f")
+        assert tracer._ANNOTATIONS is None
+    tracer.program_event(_TRACE, 0.1, fun_name="f")
+    assert tracer._ANNOTATIONS[0] is jax.profiler.TraceAnnotation
+
+
+def test_a_process_that_built_nothing_reports_no_set_up():
+    with tracer.span("step"):
+        pass
+    assert not any(
+        k.startswith("setup/") for k in tracer.close_step(1, 1.0)
+    )
+    with tracer.setup_span("build"):
+        with tracer.setup_span("weights", model="actor"):
+            pass
+    stats = tracer.close_step(2, 1.0)
+    assert 0 <= stats["setup/weights_s"] <= stats["setup/build_s"]
+    assert stats["setup/to_run_s"] >= stats["setup/to_import_s"] >= 0
+    assert stats["setup/programs"] == 0
+    assert tracer.setup_take() == {}
+    assert tracer.step_ledger()[-1]["spans"]["setup:weights"][0] == 1
 
 
 MODEL_SCOPES = ("embed", "layer/attn_qkv", "layer/attn", "layer/attn_out",

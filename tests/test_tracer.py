@@ -40,7 +40,6 @@ def test_disabled_is_noop(tmp_path):
         args["b"] = 2
     assert args == {"a": 1, "b": 2}
     tracer.counter("c", v=1)
-    tracer.instant("i")
     tracer.complete("r", start_ns=0)
     assert tracer.flush() is None
     assert list(tmp_path.iterdir()) == []
@@ -49,6 +48,32 @@ def test_disabled_is_noop(tmp_path):
     (closed,) = tracer.step_ledger()
     n, total_s, self_s = closed["spans"]["x"]
     assert n == 1 and total_s > 0 and self_s == total_s
+    assert closed["programs"] == []  # nothing compiled
+
+
+@pytest.mark.parametrize(
+    "stat", [None, "", "7 (python3) S 1 2", "7 (a b) c) S " + "x " * 30],
+    ids=["unreadable", "empty", "short", "garbled"],
+)
+def test_process_start_is_the_tracers_import_where_proc_cannot_say(
+        monkeypatch, tmp_path, stat):
+    """`setup/to_import_s` and `setup/to_run_s` count from the kernel's
+    start time of the process; a host without a readable
+    `/proc/self/stat` counts from the tracer's import."""
+    import builtins
+
+    start = tracer._process_start_ns()
+    assert 0 <= tracer._T_IMPORT_NS - start < 3600e9  # this process's own
+    path = tmp_path / "stat"
+    if stat is not None:
+        path.write_text(stat)
+    real = builtins.open
+    monkeypatch.setattr(
+        builtins, "open",
+        lambda f, *a, **k: real(
+            path if f == "/proc/self/stat" else f, *a, **k),
+    )
+    assert tracer._process_start_ns() == tracer._T_IMPORT_NS
 
 
 # ---------------- the step ledger (always on) ----------------
@@ -286,18 +311,14 @@ def test_spans_from_threads_get_distinct_tids(tmp_path):
     assert len({e["tid"] for e in events}) == 5
 
 
-def test_decorator_and_numpy_args_serialize(tmp_path):
+def test_numpy_args_serialize(tmp_path):
     _configure(tmp_path)
-
-    @tracer.trace("decorated", cat="host")
-    def fn():
-        return 3
-
-    assert fn() == 3
+    with tracer.span("spanned", cat="host", n=np.int64(3)):
+        pass
     tracer.counter("gauge", v=np.float32(0.5), n=np.int64(3))
     _, events = tracer.read_shard(tracer.flush())
     names = [e["name"] for e in events]
-    assert "decorated" in names and "gauge" in names
+    assert "spanned" in names and "gauge" in names
     # numpy scalars must have been coerced to plain JSON numbers
     gauge = next(e for e in events if e["name"] == "gauge")
     assert json.loads(json.dumps(gauge))["args"]["v"] == 0.5
