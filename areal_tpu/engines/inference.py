@@ -106,6 +106,9 @@ class InferenceEngine(HostOffloadMixin, Engine):
         use_flash = self._use_flash
         cp_mesh = self._cp_mesh
         pp_mesh, pp_mbs = self._pp_mesh, self._pp_microbatches
+        # A Gated DeltaNet layer's chunked rule: the backend's form on one
+        # device, the `jnp` form on a mesh (`linear_attn_forward`).
+        row_kernel = None if mesh.devices.size == 1 else mesh
 
         @jax.jit
         def fwd(params, batch):
@@ -119,6 +122,7 @@ class InferenceEngine(HostOffloadMixin, Engine):
                 cp_mesh=cp_mesh,
                 pp_mesh=pp_mesh,
                 pp_microbatches=pp_mbs,
+                row_kernel=row_kernel,
             )
             return post_fn(
                 tfm.per_token_output(

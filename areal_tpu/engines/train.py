@@ -313,6 +313,10 @@ class TrainEngine(HostOffloadMixin, Engine):
         # kernel on a mesh (the Pallas grouped matmul is one device's
         # program).
         self._expert_kernel = None if mesh.devices.size == 1 else False
+        # What a Gated DeltaNet layer's chunked rule takes over packed rows
+        # (`linear_attention.linear_attn_forward`): None, the backend's
+        # form, on one device; the MESH where there are more.
+        self._row_kernel = None if mesh.devices.size == 1 else mesh
         # (expert matmuls, those of them on `grouped_matmul`) of the
         # gradient program as traced last: counted while tracing.
         self._expert_matmuls = (0, 0)
@@ -393,6 +397,25 @@ class TrainEngine(HostOffloadMixin, Engine):
             for i in range(0, b, cap)
         ]
 
+    def _grad_compiler_options(self) -> Dict[str, Any]:
+        """What the gradient programs are compiled with beside the defaults:
+        where they run the Gated DeltaNet's rule on its Pallas sweep
+        (`linear_attention.chunk_kernel_form`), XLA:TPU's scheduler is held to
+        half of the memory it may spend on its own overlap.  With the
+        rule's blocks in VMEM the 8,192-token program's temporaries fall
+        from 10.2 GB to 5.8, and with that room the scheduler writes three
+        times the instructions for the same work — 284 MB where the `jnp`
+        form's program, compiled against its own memory need, takes 98 —
+        and a loaded program is resident HBM (`peak_hbm_gb` + 1.6%).  Held
+        to half, the two programs take 96 + 87 MB and a step 0.3% longer
+        (PERF.md section 6, PR 52)."""
+        from areal_tpu.models.linear_attention import chunk_kernel_form
+
+        if self.cfg.n_linear_layers and chunk_kernel_form(
+                self.cfg, self._row_kernel):
+            return {"xla_tpu_scheduler_percent_shared_memory_limit": 50}
+        return {}
+
     def _get_grad_fn(self, loss_fn: Callable):
         if loss_fn in self._grad_fns:
             return self._grad_fns[loss_fn]
@@ -402,6 +425,7 @@ class TrainEngine(HostOffloadMixin, Engine):
         pp_mesh, pp_mbs = self._pp_mesh, self._pp_microbatches
         remat = self.remat_policy
         expert_kernel = self._expert_kernel
+        row_kernel = self._row_kernel
 
         def _value_and_grad(params, batch, loss_scale):
             def losswrap(p):
@@ -420,6 +444,7 @@ class TrainEngine(HostOffloadMixin, Engine):
                     pp_microbatches=pp_mbs,
                     with_moe_counts=True,
                     expert_kernel=expert_kernel,
+                    row_kernel=row_kernel,
                 )
                 self._expert_matmuls = tuple(
                     b - a for a, b in zip(traced, tfm.expert_matmuls_traced())
@@ -474,7 +499,9 @@ class TrainEngine(HostOffloadMixin, Engine):
             with jax.named_scope("train/grad"):
                 return jax.value_and_grad(losswrap, has_aux=True)(params)
 
-        @jax.jit
+        compact = self._grad_compiler_options()
+
+        @functools.partial(jax.jit, compiler_options=compact)
         def grad_fn(params, batch, loss_scale):
             (loss, stats), grads = _value_and_grad(params, batch, loss_scale)
             return grads, loss, stats
@@ -482,7 +509,8 @@ class TrainEngine(HostOffloadMixin, Engine):
         # Fused accumulate: the running grad sum is DONATED and updated
         # in-graph, so accumulation never holds two full grad trees — the
         # term that pushes large single-chip configs out of HBM.
-        @functools.partial(jax.jit, donate_argnums=(3,))
+        @functools.partial(
+            jax.jit, donate_argnums=(3,), compiler_options=compact)
         def grad_acc_fn(params, batch, loss_scale, acc):
             (loss, stats), grads = _value_and_grad(params, batch, loss_scale)
             return jax.tree.map(jnp.add, acc, grads), loss, stats
@@ -1067,6 +1095,7 @@ class TrainEngine(HostOffloadMixin, Engine):
         use_flash = self._use_flash
         cp_mesh = self._cp_mesh
         pp_mesh, pp_mbs = self._pp_mesh, self._pp_microbatches
+        row_kernel = self._row_kernel
 
         @jax.jit
         def fwd(params, batch):
@@ -1081,6 +1110,7 @@ class TrainEngine(HostOffloadMixin, Engine):
                 cp_mesh=cp_mesh,
                 pp_mesh=pp_mesh,
                 pp_microbatches=pp_mbs,
+                row_kernel=row_kernel,
             )
             return post_fn(_model_out(pc, cfg, x, batch, mesh), batch)
 

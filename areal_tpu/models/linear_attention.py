@@ -13,9 +13,15 @@ normalised q and k, and after it a per-head RMSNorm gated by silu(z).
 
 Two forms of the same recurrence:
 - `linear_attn_forward` (training, `forward`, prefill): the chunked (WY)
-  form over chunks of CHUNK tokens under `lax.scan` — everything that does
-  not depend on the carried state is computed for all chunks at once, the
-  scan carries S through [C, d] x [d, d] matmuls.
+  form over chunks of CHUNK tokens.  The gradient program and `forward` on
+  one TPU device take the Pallas sweep `gdn_chunk`
+  (`ops/pallas/delta_chunk.py`): a chunk's [C, C] blocks and the carried
+  state stay in VMEM, q and k at their own 16 heads, and the backward is a
+  reverse sweep of its own.  Prefill (`with_state`: it reads the final
+  state), a mesh of more devices and every backend that is no TPU take
+  `gated_delta_chunked`, the same rule as `jnp` ops and the sweep's oracle:
+  everything that does not depend on the carried state for all chunks at
+  once, then a `lax.scan` that carries S through [C, d] x [d, d] matmuls.
 - `linear_attn_step` (decode): one token against the carried S and the
   conv's last K-1 inputs.  The recurrence itself has one form per backend:
   on a TPU the Pallas kernel `gdn_delta_step` (`ops/pallas/delta_step.py`),
@@ -95,9 +101,10 @@ def _gates(ba: jax.Array, blk: Params, hv: int):
         return beta, g
 
 
-def _split_heads(qkv: jax.Array, cfg: ModelConfig):
+def _split_heads(qkv: jax.Array, cfg: ModelConfig, repeat: bool = True):
     """conv output [..., C] -> q, k [..., hv, dk] (normalised, q scaled, each
-    key head repeated for its value heads) and v [..., hv, dv], all fp32."""
+    key head repeated for its value heads; `repeat` False: [..., hk, dk], the
+    key heads as they are) and v [..., hv, dv], all fp32."""
     hk, hv = cfg.linear_n_k_heads, cfg.linear_n_v_heads
     dk, dv, kd = cfg.linear_k_head_dim, cfg.linear_v_head_dim, cfg.linear_key_dim
     lead = qkv.shape[:-1]
@@ -106,7 +113,7 @@ def _split_heads(qkv: jax.Array, cfg: ModelConfig):
     k = _l2norm(qkv[..., kd: 2 * kd].reshape(*lead, hk, dk))
     v = qkv[..., 2 * kd:].reshape(*lead, hv, dv)
     rep = hv // hk
-    if rep > 1:  # key head i serves value heads [i*rep, (i+1)*rep)
+    if repeat and rep > 1:  # key head i serves value heads [i*rep, (i+1)*rep)
         q = jnp.repeat(q, rep, axis=-2)
         k = jnp.repeat(k, rep, axis=-2)
     return q, k, v
@@ -261,6 +268,22 @@ def gated_delta_chunked(
     return o[:, :s], state
 
 
+def chunk_kernel_form(cfg: ModelConfig, kernel=None, with_state=False) -> bool:
+    """Whether `linear_attn_forward` runs the delta rule on the Pallas sweep
+    `gdn_chunk`: by what the code can see (`flash_attention.
+    row_kernel_form`: a TPU backend, head widths of whole 128-lane tiles; a
+    bool forces either) on ONE device — `kernel` a Mesh: a `pallas_call`
+    is one device's program — and without `with_state`: prefill is the one
+    caller that reads the final state, and its three chunks a row give the
+    sweep nothing to win."""
+    from areal_tpu.ops.pallas import delta_chunk
+    from areal_tpu.ops.pallas.flash_attention import row_kernel_form
+
+    use_kernel, mesh = row_kernel_form(kernel, delta_chunk.fits(
+        cfg.linear_k_head_dim, cfg.linear_v_head_dim))
+    return use_kernel and mesh is None and not with_state
+
+
 @jax.named_scope("layer/linear_attn")
 def linear_attn_forward(
     h: jax.Array,  # [B, S, D] normed block input
@@ -268,9 +291,16 @@ def linear_attn_forward(
     cfg: ModelConfig,
     segment_ids: jax.Array,
     with_state: bool = False,
+    kernel=None,  # None | bool | Mesh: `flash_attention.row_kernel_form`
 ):
     """-> y [B, S, D]; `with_state` (prefill) adds the state after each
-    row's last token [B, hv, dk, dv] fp32 and the conv's tail [B, K-1, C]."""
+    row's last token [B, hv, dk, dv] fp32 and the conv's tail [B, K-1, C].
+
+    The delta rule has one form per backend and caller
+    (`chunk_kernel_form`): the Pallas sweep `gdn_chunk` in the gradient
+    program and `forward` on one TPU device, `gated_delta_chunked`
+    elsewhere."""
+    use_kernel = chunk_kernel_form(cfg, kernel, with_state)
     with jax.named_scope("in_proj"):
         qkv = h @ blk["la_wqkv"]
         z = h @ blk["la_wz"]
@@ -279,8 +309,13 @@ def linear_attn_forward(
         conv = jax.nn.silu(causal_conv(qkv, blk["la_conv"], segment_ids))
     beta, g = _gates(ba, blk, cfg.linear_n_v_heads)
     with jax.named_scope("delta_rule"):
-        q, k, v = _split_heads(conv, cfg)
-        o, state = gated_delta_chunked(q, k, v, g, beta, segment_ids)
+        q, k, v = _split_heads(conv, cfg, repeat=not use_kernel)
+        if use_kernel:
+            from areal_tpu.ops.pallas import delta_chunk
+
+            o = delta_chunk.gdn_chunk(q, k, v, g, beta, segment_ids)
+        else:
+            o, state = gated_delta_chunked(q, k, v, g, beta, segment_ids)
     y = _out(o, z, blk, cfg)
     if with_state:
         return y, state, conv_tail(qkv, segment_ids, cfg.linear_conv_kernel)

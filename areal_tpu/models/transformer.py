@@ -1146,7 +1146,7 @@ def _rope(cfg: ModelConfig, positions: jax.Array):
 def _packed_branches(
     cfg: ModelConfig, segment_ids: jax.Array, *attn_args, with_state=False,
     window_rope=None, ring: Optional[int] = None,
-    expert_kernel: Optional[bool] = False,
+    expert_kernel: Optional[bool] = False, row_kernel=None,
 ):
     """The table of the programs over packed rows (the train stack,
     `prefill`): branch -> f(h, blk) -> (its output, what else it gives by
@@ -1160,7 +1160,9 @@ def _packed_branches(
     entries of their cache, where the caller keeps what they leave (the
     ring a row's last slots fill: `_ring_tail`).  `expert_kernel`: whether
     the grouped dispatch's matmuls are the Pallas kernel `grouped_matmul`
-    (`expert_kernel_choice`)."""
+    (`expert_kernel_choice`).  `row_kernel`: the form of the Gated DeltaNet's
+    chunked rule (`linear_attn_forward`'s `kernel`: None, by what the code
+    can see; the caller's MESH where it has more than one device)."""
     kernel = cfg.is_moe and expert_kernel_choice(cfg, expert_kernel)
 
     def recurrent(forward):
@@ -1207,7 +1209,8 @@ def _packed_branches(
         ATTENTION: attention,
         WINDOW: window,
         LATENT: attention,
-        GDN: recurrent(linear_attn_forward),
+        GDN: recurrent(
+            functools.partial(linear_attn_forward, kernel=row_kernel)),
         SSM: recurrent(ssm_forward),
         SCONV: short_conv,
         MLP: lambda h, blk: (
@@ -1297,6 +1300,7 @@ def _backbone(
     pp_mesh=None,
     pp_microbatches: int = 4,
     expert_kernel: Optional[bool] = False,
+    row_kernel=None,
 ) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
     """-> (final-normed hidden states, summed MoE aux loss, per-layer rows
     per expert [L, E] int32 — None for dense models and under PP)."""
@@ -1363,7 +1367,7 @@ def _backbone(
     x, auxes, counts = _blocks(
         params["blocks"], cfg, x, segment_ids, cos, sin, remat, use_flash,
         cp_mesh, cp_zigzag=zz_inv is not None, window_rope=window_rope,
-        expert_kernel=expert_kernel,
+        expert_kernel=expert_kernel, row_kernel=row_kernel,
     )
     x = _final_norm(params, cfg, x)
     if zz_inv is not None:
@@ -1608,7 +1612,7 @@ def _layer_outputs(n_in_unit: int, stacked, lead=()):
 def _blocks(
     blocks: Params, cfg: ModelConfig, x, segment_ids, cos, sin, remat,
     use_flash, cp_mesh=None, cp_zigzag: bool = False, window_rope=None,
-    expert_kernel: Optional[bool] = False,
+    expert_kernel: Optional[bool] = False, row_kernel=None,
 ):
     """The block stack of every model: the prefix's layers, then ONE
     `lax.scan` over the repeats of the plan's unit, a unit's layers
@@ -1619,6 +1623,7 @@ def _blocks(
     branches = _packed_branches(
         cfg, segment_ids, cos, sin, use_flash, cp_mesh, None, cp_zigzag,
         window_rope=window_rope, expert_kernel=expert_kernel,
+        row_kernel=row_kernel,
     )
     layers = {
         kind: _remat_layer(
@@ -1696,6 +1701,7 @@ def hidden_states(
     pp_microbatches: int = 4,
     with_moe_counts: bool = False,
     expert_kernel: Optional[bool] = False,
+    row_kernel=None,
 ) -> Tuple[jax.Array, ...]:
     """Backbone only: final-layernormed hidden states [B, S, D] (+ MoE aux
     loss), WITHOUT the LM head.  Lets engines fuse the head into a chunked
@@ -1710,12 +1716,14 @@ def hidden_states(
     `ragged_dot`: it holds a quarter of the kernels' seconds, it is most of
     the programs that would carry them at set-up, and its text stays the
     parent's, so what a generator samples does not move (PERF.md section
-    6, PR 50)."""
+    6, PR 50).  `row_kernel`: the form of a Gated DeltaNet layer's chunked
+    rule (`linear_attention.linear_attn_forward`): None, the backend's, on
+    one device; a caller whose mesh has more passes the MESH."""
     if positions is None:
         positions = positions_from_segments(segment_ids)
     x, aux, counts = _backbone(
         params, cfg, tokens, segment_ids, positions, remat, use_flash,
-        cp_mesh, pp_mesh, pp_microbatches, expert_kernel,
+        cp_mesh, pp_mesh, pp_microbatches, expert_kernel, row_kernel,
     )
     return (x, aux, counts) if with_moe_counts else (x, aux)
 

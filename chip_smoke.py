@@ -326,6 +326,45 @@ def phase_kernels(geom, on_tpu):
 
     _ssm_cell_shape(dt, tol, on_tpu)
 
+    _gdn_chunk_cell_shape(on_tpu)
+
+
+def _gdn_chunk_cell_shape(on_tpu, reps=5):
+    """The Gated DeltaNet's chunked delta rule in training at
+    `q3next-rollout64-512`'s micro-batch (one packed row of 8,192 tokens,
+    32 value heads over 16 key heads of 128, eleven segments of 642 tokens
+    and pads; 256 tokens and 4 heads in rehearsal): the Pallas sweep
+    `ops/pallas/delta_chunk.gdn_chunk` and its own backward against the
+    `jnp` form `linear_attention.gated_delta_chunked` and its `jax.grad` —
+    o and the five gradients within what bf16 operands move (both forms
+    round every product's operands to bf16 on the chip, in another order)
+    — and a call's time, both forms, to the log."""
+    import jax
+    import jax.numpy as jnp
+
+    sys.path.insert(0, os.path.join(
+        os.path.dirname(os.path.abspath(__file__)), "scripts"))
+    import delta_chunk_bench as bench
+
+    s, hk, hv, d, seg_len = (8192, 16, 32, 128, 642) if on_tpu else (
+        256, 2, 4, 128, 100)
+    ops, seg, w = bench.operands(s, hk, hv, d, seg_len)
+    got = {}
+    for kind in ("jnp", "kernel"):
+        fwd, fwd_bwd = bench.variant_fn(kind, hv // hk)
+        got[kind] = jax.block_until_ready(fwd_bwd(ops, seg, w))
+        if on_tpu:
+            log(f"gdn_chunk cell shape, {kind} form: forward "
+                f"{bench.ms_per_call(fwd, (ops, seg, w), reps):.2f} ms, "
+                f"forward + backward "
+                f"{bench.ms_per_call(fwd_bwd, (ops, seg, w), reps):.2f} ms")
+    tol = 2e-2 if on_tpu else 3e-2  # the kernel's operands are bf16 here too
+    for name, a, c in zip(bench.NAMES, got["jnp"], got["kernel"]):
+        scale = float(jnp.max(jnp.abs(a))) or 1.0
+        err = _max_err(a, c) / scale
+        check(err <= tol, f"gdn_chunk {name} == jnp form "
+                          f"(max err {err:.2e} of the largest entry)")
+
 
 def _ssm_cell_shape(dt, tol, on_tpu, reps=10):
     """The Mamba-2 decode step (`models/mamba.ssm_step`) at

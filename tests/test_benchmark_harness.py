@@ -513,21 +513,69 @@ def test_the_lfm2_cell_is_as_the_issue_parametrised_it():
 def test_the_set_up_entries_are_the_last_ten_and_every_cells():
     """ISSUE 51: ten readers of the program's set-up ledger appended to
     `per_layer` in the issue's order, each without a `workloads` list (all
-    ten cells report them), right after PR 48's."""
+    ten cells report them), right after PR 48's; pinned by name, so what a
+    later PR appends (PR 52's one) moves nothing."""
     n = len(SETUP_ENTRIES)
-    assert SPEC["per_layer"][-n:] == [
+    first = _at(SPEC["per_layer"], SETUP_ENTRIES[0][0])
+    assert SPEC["per_layer"][first: first + n] == [
         {"name": name, "unit": unit, "better": "lower",
          "source": "program_counter", "layer": "build", "moves": "setup_s"}
         for name, unit in SETUP_ENTRIES
     ]
-    assert SPEC["per_layer"][-n - 1]["name"] == LFM2_ENTRIES[-1][0]
-    assert not [m["name"] for m in SPEC["per_layer"][:-n]
-                if m["moves"] == "setup_s"]
+    assert SPEC["per_layer"][first - 1]["name"] == LFM2_ENTRIES[-1][0]
+    setup = {name for name, _ in SETUP_ENTRIES}
+    assert not [m["name"] for m in SPEC["per_layer"]
+                if m["moves"] == "setup_s" and m["name"] not in setup]
     for cell in CELLS:
         mine = [m["name"] for m in files.metrics_for(cell, traced=True)]
-        assert mine[-n:] == [name for name, _ in SETUP_ENTRIES], cell
+        at = mine.index(SETUP_ENTRIES[0][0])
+        assert mine[at: at + n] == [name for name, _ in SETUP_ENTRIES], cell
     for name, _ in SETUP_ENTRIES:
         assert callable(files.load_module("metrics", name).read), name
+
+
+def test_the_delta_rule_share_is_the_last_entry_and_the_hybrid_cells():
+    """ISSUE 52: one per-layer metric appended, last — the share of the
+    Gated DeltaNet mixer's device seconds that lie under its `delta_rule`
+    scope, in the gradient program — listed for the one cell with such
+    layers; it reads scopes the program has had since PR 32, and returns
+    None, never raises, where there is no trace or no such scope."""
+    from benchmark.metrics import gdn_delta_rule_share
+    from benchmark.run import Run
+
+    assert SPEC["per_layer"][-1] == {
+        "name": "gdn_delta_rule_share", "unit": "%", "better": "lower",
+        "source": "device_trace", "layer": "model step",
+        "moves": "train_tokens_per_s", "workloads": ["q3next-rollout64-512"],
+    }
+    assert SPEC["per_layer"][-2]["name"] == SETUP_ENTRIES[-1][0]
+
+    def run(scopes):
+        return Run(
+            cell_name="x", cell={}, config={}, traffic={}, model_cfg=None,
+            chips=1, device_kind="TPU v5 lite", peaks=None, seed=0,
+            traced=True,
+            trace={"scope_seconds": scopes, "traced_steps": 2, "busy_s": 9.0},
+        )
+
+    def phases(fwd, recompute, bwd):
+        return {"fwd": fwd, "recompute": recompute, "bwd": bwd}
+
+    mixer = "train/grad/layer/linear_attn"
+    got = gdn_delta_rule_share.read(run({
+        f"{mixer}/in_proj": phases(0.1, 0.1, 0.2),
+        f"{mixer}/delta_rule": phases(0.1, 0.1, 0.1),
+        f"{mixer}/delta_rule/gdn_chunk_bwd": phases(0.0, 0.0, 0.3),
+        "gen/decode_step/layer/linear_attn/delta_step": phases(5.0, 0, 0),
+        "train/grad/layer/mlp": phases(1.0, 1.0, 1.0),
+    }))
+    assert got == pytest.approx(100.0 * 0.6 / 1.0)
+    assert gdn_delta_rule_share.read(run({})) is None
+    assert gdn_delta_rule_share.read(run(
+        {"train/grad/layer/attn": phases(1.0, 0.0, 1.0)})) is None
+    untraced = run({})
+    untraced.trace = None
+    assert gdn_delta_rule_share.read(untraced) is None
 
 
 def _setup_run(stats, setup_s=100.0):

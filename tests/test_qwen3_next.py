@@ -654,6 +654,84 @@ def test_the_decode_loop_compiles_for_v5e_with_the_state_stepped_in_place(
     assert not updates, updates[:3]
 
 
+# `memory_analysis().temp_size_in_bytes` of the same program on the `jnp`
+# form (`row_kernel=False`, what the commit before the kernel compiled),
+# as this test's compile read it when the kernel came (PR 52).
+_GRAD_TEMP_BYTES_ON_THE_JNP_FORM = 8_367_263_744
+
+
+def test_the_gradient_program_compiles_for_v5e_with_the_rule_on_its_kernels(
+        v5e_chips, monkeypatch):
+    """Mosaic and XLA:TPU for real, at the cell's micro-batch (one packed
+    row of 8,192 tokens, the published widths, `remat="full"` as the train
+    engine has it): each of the three Gated DeltaNet layers runs its
+    chunked delta rule on the Pallas sweep — `gdn_chunk_fwd` in the forward
+    and in the recomputed forward, `gdn_chunk_bwd` in the backward, all
+    under `layer/linear_attn/delta_rule` — with no `while` and no
+    `InvertDiagBlocksLowerTriangular` left under that scope (the `jnp`
+    form's two loops of 128 trips and its solve), and the program's
+    temporaries are not above the `jnp` form's.  Prefill keeps the `jnp`
+    form: its lowered text holds no kernel of the rule."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    from areal_tpu.ops.pallas import delta_chunk
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    big = bench_run.model_config(
+        files.load_json("configs", "qwen3-next-80b-a3b-l4-e64.json"))
+    chip = SingleDeviceSharding(v5e_chips[0])
+    shapes = jax.eval_shape(
+        lambda: tfm.init_params(big, jax.random.PRNGKey(0)))
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, jnp.bfloat16, sharding=chip),
+        shapes)
+    row = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=chip)
+
+    def loss(p, tokens, seg):
+        x, aux = tfm.hidden_states(p, big, tokens, seg, remat="full")
+        return jnp.sum(x.astype(jnp.float32)) + aux
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(jax.grad(loss)).trace(
+            params, row, row).lower().compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    under = [line for line in compiled.as_text().splitlines()
+             if "layer/linear_attn/delta_rule" in line]
+    kernels = {}
+    for line in under:
+        if "tpu_custom_call" in line:
+            scope = line.split('op_name="')[1].split('"')[0]
+            phase = ("recompute" if "rematted_computation" in scope
+                     else "bwd" if "transpose(" in scope else "fwd")
+            name = scope.split("/")[-2]
+            kernels[name, phase] = kernels.get((name, phase), 0) + 1
+    n = big.n_linear_layers
+    assert kernels == {
+        ("gdn_chunk_fwd", "fwd"): n, ("gdn_chunk_fwd", "recompute"): n,
+        ("gdn_chunk_bwd", "bwd"): n}, kernels
+    assert not [line[:120] for line in under if " while(" in line]
+    assert "InvertDiagBlocksLowerTriangular" not in compiled.as_text()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= _GRAD_TEMP_BYTES_ON_THE_JNP_FORM, temp
+
+    # Prefill (64 rows, a 256-slot prompt window): `with_state`, so the
+    # `jnp` form.
+    monkeypatch.setattr(delta_chunk, "gdn_chunk", None)
+    prompts = jax.ShapeDtypeStruct((64, 256), jnp.int32, sharding=chip)
+
+    def prefill(p, tokens, seg):
+        cache = tfm.init_kv_cache(big, 64, 768, dtype=jnp.bfloat16)
+        return tfm.prefill(p, big, tokens, seg, cache)[0]
+
+    text = jax.jit(prefill).trace(params, prompts, prompts).lower().as_text()
+    assert "gdn_chunk" not in text
+
+
 # ------------------------------------- every other family is a period of one
 
 
