@@ -94,8 +94,9 @@ def matmul_params(cfg) -> int:
     n_attn = _attn_layers(cfg)
     mixers = n_attn * _attn_params(cfg)
     pattern = getattr(cfg, "layer_pattern", "")
-    if pattern:
-        # A layer is ONE branch: count each kind over its own layers.
+    if pattern or getattr(cfg, "n_ssm_layers", 0):
+        # Each kind over its own layers: a pattern's ONE branch a layer,
+        # or Mamba-2 mixers in two-branch layers (granitemoehybrid).
         mixers += cfg.n_ssm_layers * _ssm_params(cfg)
     elif getattr(cfg, "n_sconv_layers", 0):
         mixers += cfg.n_sconv_layers * _sconv_params(cfg)

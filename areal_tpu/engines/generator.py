@@ -95,6 +95,38 @@ def _find_stop_end(toks, scan_from: int, stop_seqs) -> Optional[int]:
     return best
 
 
+# What a plan with recurrent state on the serving plane cannot take yet:
+# a slot's state is one value a request, with no snapshot to roll back to
+# or to share (ROADMAP B-I 3).
+_NO_STATE_SPEC = (
+    "speculative decoding (spec_decode_k > 0) on a plan with Mamba-2 "
+    "layers: a rejected draft would have to roll the slot's recurrent "
+    "state back, and the serving plane keeps no snapshot of it"
+)
+_NO_STATE_EPISODES = (
+    "agent episodes on a plan with Mamba-2 layers: an episode's prefix "
+    "pages are shared and republished across turns, and a slot's recurrent "
+    "state has no snapshot at a page boundary to share"
+)
+_NO_STATE_REPLAY = (
+    "the push-time resume replay on a plan with Mamba-2 layers: "
+    "`_replay_tails` recomputes a tail's K/V from its tokens, and a slot's "
+    "recurrent state would have to be recomputed from position 0 or from a "
+    "snapshot; the serving loop does not park such a plan (an interrupt "
+    "drains the call, as on the static program)"
+)
+
+
+def _new_state_stats() -> Dict[str, int]:
+    """Per-generate() counters of the slots' recurrent state (a plan with
+    Mamba-2 layers on the serving plane; `last_pool_stats` `ssm_*`)."""
+    return {
+        "ssm_live_slot_chunks": 0, "ssm_slots_zeroed": 0,
+        "ssm_prefix_would_share": 0, "ssm_lanes_decode": 0,
+        "ssm_lanes_prefill": 0, "ssm_interrupts_drained": 0,
+    }
+
+
 @dataclasses.dataclass
 class _EpisodeSlot:
     """Host bookkeeping for one live episode pinned to a serving slot.
@@ -191,6 +223,9 @@ class _PagedGenSession:
     episodes: Any = None  # Dict[str, _EpisodeSlot]
     ep_seq: int = 0  # monotonic LRU tick source
     ep_budget: int = 0  # session default per-episode token budget
+    # The requests each slot served, in order (`serving_rollout`'s check
+    # of what the chunk leaves in a reused slot).
+    served: Any = None  # Dict[slot, List[(prompt index, repeat)]]
 
 
 def _spec_emit(
@@ -424,6 +459,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         # Serving-plane chunk counters of the current generate() call
         # (see _serving_counters); folded into last_pool_stats at its end.
         self._chunk_stats: Dict[str, Any] = _new_chunk_stats()
+        self._state_stats: Dict[str, int] = _new_state_stats()
         self._gen_t0 = time.monotonic()
         # Ragged-stream lane accounting (serving chunk only; reset in
         # generate()): lanes_dispatched = query lanes launched (chunk
@@ -650,20 +686,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                 "an interrupted generation is parked; call "
                 "resume_generate() before starting a new one"
             )
-        self.prefill_dispatches = 0
-        self.decode_compiles = 0
-        self.cache_copy_bytes = 0
-        self.last_pool_stats = {}
-        self._moe_decode_sums = np.zeros((_n_moe_counters(self.cfg),))
-        self._window_live_sums = np.zeros((2,))
-        self.lanes_dispatched = 0
-        self.lanes_live = 0
-        self.lanes_slack = 0
-        self.dead_live_lanes = 0
-        self.pages_live = 0
-        self.pages_addressed = 0
-        self._gen_t0 = time.monotonic()
-        self._chunk_stats = _new_chunk_stats()
+        self._reset_call_counters()
         prompt_lens = sample.seqlens_of(prompt_key)
         bounds = sample.cu_seqlens(prompt_key)
         prompts = np.asarray(sample.data[prompt_key])
@@ -713,6 +736,8 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                 f"{self.static_path_max_new}), stop={bool(gconfig.stop)}, "
                 f"spec_decode_k={gconfig.spec_decode_k}, inflight={inflight}"
             )
+        if gconfig.spec_decode_k > 0 and self._has_state:
+            raise tfm.HybridLayoutError(_NO_STATE_SPEC)
         # Uncategorized envelope span (the inner prefill/decode spans carry
         # cat="compute"; host assembly gaps inside show as idle).
         with tracer.span(
@@ -742,6 +767,44 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                     self._generate_chunk(chunk, gconfig, sub, results)
 
             return self._assemble(sample, prompt_key, prompt_lens, results, n)
+
+    def _reset_call_counters(self) -> None:
+        """The per-call counters, at the start of a generate call."""
+        self.prefill_dispatches = 0
+        self.decode_compiles = 0
+        self.cache_copy_bytes = 0
+        self.last_pool_stats = {}
+        self._moe_decode_sums = np.zeros((_n_moe_counters(self.cfg),))
+        self._window_live_sums = np.zeros((2,))
+        self.lanes_dispatched = 0
+        self.lanes_live = 0
+        self.lanes_slack = 0
+        self.dead_live_lanes = 0
+        self.pages_live = 0
+        self.pages_addressed = 0
+        self._gen_t0 = time.monotonic()
+        self._chunk_stats = _new_chunk_stats()
+        self._state_stats = _new_state_stats()
+
+    def serving_rollout(self, prompts, gconfig, key):
+        """One call of the SERVING plane over `prompts` (token arrays, one
+        response each, admitted in the order given; more of them than
+        slots go in waves) -> ({prompt index: (new tokens, their
+        log-probs)}, the `PagedKVCache` the chunk loop left on the device —
+        pages and, for a plan with state, every slot's recurrent state and
+        conv tail as its LAST request left them — and {slot: the prompt
+        indices it served, in order}).  For a check that holds what the
+        chunk leaves to a reference, as `static_rollout(with_cache=True)`
+        is for the static program."""
+        self._ensure_loaded()
+        self._require_params()
+        self._reset_call_counters()
+        reqs = [(i, 0, np.asarray(t, np.int32)) for i, t in enumerate(prompts)]
+        results: Dict = {}
+        st = self._generate_inflight_serving(reqs, gconfig, key, results)
+        out = {i: (toks, logps) for (i, _), (toks, logps, _) in results.items()}
+        served = {s: [i for i, _ in took] for s, took in st.served.items()}
+        return out, st.pool, served
 
     def resume_generate(self) -> Optional[SequenceSample]:
         """Continue a parked generate() under the engine's CURRENT
@@ -785,6 +848,8 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         a row of the ragged step (q_len = r, the way the serving chunk
         forwards a prefill slice), overwriting the tail KV in place and
         refreshing the slot's next-token logits.  Consumes no PRNG keys."""
+        if self._has_state:
+            raise tfm.HybridLayoutError(_NO_STATE_REPLAY)
         Q = st.chunk_t
         T = st.n_slots * Q
         tokens = np.zeros((T,), np.int32)
@@ -951,7 +1016,9 @@ class GeneratorEngine(HostOffloadMixin, Engine):
     def _paged_kv_dtype(self):
         return "int8" if self.kv_cache_dtype == "int8" else self.compute_dtype
 
-    def _generate_inflight_serving(self, reqs, gconfig, key, results) -> None:
+    def _generate_inflight_serving(
+        self, reqs, gconfig, key, results
+    ) -> "_PagedGenSession":
         """Fixed slot pool; retire finished rows and admit pending requests
         between jitted T-step chunks.  Continuous batching over a paged KV
         pool with admission folded INTO the chunk step: the pool and the chunk program have ONE fixed
@@ -989,7 +1056,8 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             chunk_t=chunk_t,
             alloc=PageAllocator(n_pages, ps, n_slots, max_pages),
             pool=tfm.init_paged_kv_cache(
-                self.cfg, n_pages, ps, dtype=self._paged_kv_dtype()
+                self.cfg, n_pages, ps, dtype=self._paged_kv_dtype(),
+                n_slots=n_slots,
             ),
             logits_buf=jnp.zeros((n_slots, self.cfg.vocab_size), jnp.float32),
             cache_len=np.zeros((n_slots,), np.int32),
@@ -1010,9 +1078,11 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             inflight_prefix={},
             tokens_buf=jnp.zeros((n_slots, buf_w), jnp.int32),
             pending_tok=jnp.zeros((n_slots,), jnp.int32),
+            served={},
         )
         st.alloc.page_bytes = _cache_nbytes(st.pool) // n_pages
         self._run_serving_loop(st)
+        return st
 
     def _run_serving_loop(self, st: "_PagedGenSession") -> bool:
         """The serving chunk loop: every iteration admits into free slots
@@ -1035,7 +1105,11 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             n_slots, st.n_pages, st.max_pages, chunk_t, W, pbw, gconfig
         )
         while st.pending or any(a is not None for a in st.active):
-            if self._interrupt_evt.is_set():
+            if self._interrupt_evt.is_set() and self._has_state:
+                # No replay can refresh a slot's state (`_NO_STATE_REPLAY`):
+                # the call drains under the weights it holds.
+                self._state_stats["ssm_interrupts_drained"] = 1
+            elif self._interrupt_evt.is_set():
                 self._session = st
                 tracer.counter(
                     "gen_interrupt",
@@ -1119,6 +1193,11 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                 st.prompt_off = to_host(new_off).copy()
                 st.last_emit = st.gen_count - prev_gen
                 self._count_lanes(lane_acc, chunk_t, st.max_pages)
+                if self._has_state:
+                    ss = self._state_stats
+                    ss["ssm_live_slot_chunks"] += self.live_slots
+                    ss["ssm_lanes_decode"] += int(lane_acc[4])
+                    ss["ssm_lanes_prefill"] += int(lane_acc[5])
 
                 # Register prefixes that FINISHED prefilling this chunk,
                 # before any retirement below can release the owner's pages:
@@ -1164,6 +1243,13 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             pool_bytes=alloc.pool_bytes(),
             peak_allocated_bytes=alloc.peak_pages_used * alloc.page_bytes,
         )
+        if self._has_state:
+            nbytes = {
+                "ssm_state_bytes": sum(int(a.nbytes) for a in st.pool.state),
+                "ssm_conv_bytes": sum(int(a.nbytes) for a in st.pool.conv),
+            }
+            self.last_pool_stats.update(self._state_stats, **nbytes)
+            tracer.counter("ssm_slots", **self._state_stats, **nbytes)
         self._set_live_slots(0)
         return True
 
@@ -1206,6 +1292,12 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             # positions [0, sp*ps), the follower prefills [sp*ps, plen).
             sp = (plen - 1) // ps
             h = toks.tobytes() if (self.kv_share_prefix and sp > 0) else None
+            dup = None
+            if h is not None and self._has_state:
+                # A follower would need the owner's STATE at the page
+                # boundary beside its pages, and there is no snapshot: it
+                # maps no page and prefills its whole prompt.
+                dup, h = h, None
             if h is not None and h in st.inflight_prefix:
                 passed_over += 1  # its owner registers at a chunk's end
                 at -= 1
@@ -1237,6 +1329,8 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             del st.pending[at]
             at -= 1
             st.active[s] = (i, rep)
+            if st.served is not None:
+                st.served.setdefault(s, []).append((i, rep))
             st.cache_len[s] = start
             st.gen_count[s] = 0
             st.done_host[s] = False
@@ -1251,6 +1345,16 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             st.prompt_off[s] = 0
             st.last_emit[s] = 0
             admitted += 1
+            if self._has_state:
+                # The slot's first lane is at position 0: the recurrence
+                # starts it from zero state and tail (`mamba.ssm_ragged`).
+                self._state_stats["ssm_slots_zeroed"] += 1
+            if dup is not None and dup in st.inflight_prefix:
+                # Counted: admitted requests whose prompt an earlier
+                # request of this call had (what sharing would have hit).
+                self._state_stats["ssm_prefix_would_share"] += 1
+            elif dup is not None:
+                st.inflight_prefix[dup] = -1  # seen; owns no page
             s = next(free_slots, None)
         self._note_admits(admitted)
         self._chunk_stats["admit_passed_over"] += passed_over
@@ -1440,11 +1544,12 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             "serving_chunk", n_slots, n_pages, max_pages, chunk_t, W, pbw,
             K, g.spec_ngram, T,
             g.min_new_tokens, g.greedy, g.top_p, g.top_k, g.temperature,
-            in_place,
+            in_place, g.max_new_tokens if self._has_state else None,
         )
         if sig in self._gen_fns:
             return self._gen_fns[sig]
         cfg = self.cfg
+        has_state = self._has_state
         eos = self.eos_token_id
         # A spec row can emit up to K+1 tokens per inner step, plus one
         # fresh first token the step it leaves prefill.
@@ -1463,8 +1568,9 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             # (live lanes, slack lanes, live-but-misassigned lanes, live
             # pages) — the third is structurally zero; the bench
             # invariant leg asserts it stays so ("dead-lane compute
-            # exactly 0").
-            lane_acc = jnp.zeros((4,), jnp.int32)
+            # exactly 0").  A plan with state adds the lanes its
+            # recurrence took, by kind: (decode, prefill).
+            lane_acc = jnp.zeros((6 if has_state else 4,), jnp.int32)
             rows = jnp.arange(n_slots)
             lanes = jnp.arange(Wmax)
             lane_ids = jnp.arange(T)
@@ -1562,7 +1668,10 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                     jnp.sum(badlane.astype(jnp.int32)),
                     # A live lane's window is [0, its position].
                     jnp.sum(jnp.where(lane_live, stream_pos // ps + 1, 0)),
-                ])
+                ] + ([
+                    jnp.sum(jnp.where(is_pref, 0, c)),
+                    jnp.sum(jnp.where(is_pref, c, 0)),
+                ] if has_state else []))
                 # Per-row lane-token slab, gathered into the stream.
                 idx = jnp.minimum(
                     prompt_off[:, None] + lanes[None, :], pbw - 1
@@ -1595,6 +1704,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                     params, cfg, stream_tok, stream_pos, pool,
                     page_table, row_of, experts_in_place=in_place,
                     paged_kernel=paged_kernel,
+                    slot_lanes=Wmax if has_state else None,
                 )  # [T, V]
                 # Next-step carry = each granted row's LAST lane logits
                 # (end-of-slice for prefill, post-token for decode);
@@ -1630,6 +1740,11 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                     # hold zero lanes and stay put.
                     cache_len = cache_len + c
                     gen_count = gen_count + emitting.astype(jnp.int32)
+                    if has_state:
+                        # A row at its budget holds no further lane: what
+                        # it would emit is drained away, and the state it
+                        # leaves is the one after its last kept token.
+                        done = done | (gen_count >= g.max_new_tokens)
                 prompt_off = prompt_off + jnp.where(is_pref, c, 0)
                 prefill_rem = prefill_rem - jnp.where(is_pref, c, 0)
                 return (logits, pool2, cache_len, gen_count, done,
@@ -1673,6 +1788,8 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         multi-episode run."""
         if self._ep_session is not None:
             return self._ep_session
+        if self._has_state:
+            raise tfm.HybridLayoutError(_NO_STATE_EPISODES)
         n_slots = max(self.batch_shard, self.max_decode_batch)
         while n_slots % self.batch_shard:
             n_slots += 1
@@ -2205,6 +2322,12 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             b"ep:" + st.slot_prompt[s][: sp * alloc.page_size].tobytes(),
             alloc.table[s, :sp],
         )
+
+    @property
+    def _has_state(self) -> bool:
+        """Whether a request holds a slot of recurrent state beside its
+        pages on the serving plane (a plan with Mamba-2 layers)."""
+        return self.cfg.n_ssm_layers > 0
 
     @property
     def _paged_kernel(self) -> Optional[bool]:

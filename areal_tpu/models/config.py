@@ -29,7 +29,7 @@ ATTENTION, WINDOW, LATENT, GDN, SSM, SCONV, MLP, MOE = (
 # One character of `layer_pattern` -> that layer's ONE branch.
 _PATTERN_KINDS = {"M": (SSM,), "E": (MOE,), "*": (ATTENTION,)}
 # One character of `window_pattern` -> that layer's mixer.
-_WINDOW_KINDS = {"S": WINDOW, "F": ATTENTION, "C": SCONV}
+_WINDOW_KINDS = {"S": WINDOW, "F": ATTENTION, "C": SCONV, "M": SSM}
 
 LayerKind = Tuple[str, ...]  # a layer's branches, in order
 
@@ -225,8 +225,10 @@ class ModelConfig:
     # token sees the last `attn_window` keys of its sequence, itself
     # included; "F" full causal attention; "C" the gated short convolution
     # (`models/short_conv.py`: no keys, a cache of the row's last
-    # `sconv_kernel` - 1 gated inputs).  Every layer has an MLP (or the
-    # experts) behind its mixer; "S" and "F" have the same leaves.  The
+    # `sconv_kernel` - 1 gated inputs); "M" a Mamba-2 mixer (`ssm_*`, as in
+    # a one-branch pattern, here with an MLP behind it: granitemoehybrid).
+    # Every layer has an MLP (or the experts) behind its mixer; "S" and
+    # "F" have the same leaves.  The
     # first `first_k_dense` characters are the leading dense layers'
     # mixers; the stack is scanned by repeats of the smallest unit of the
     # rest.  "" = every attention layer is full.
@@ -247,6 +249,15 @@ class ModelConfig:
     rope_yarn_beta_fast: float = 32.0
     rope_yarn_beta_slow: float = 1.0
     rope_yarn_attention_factor: float = 0.0
+    # ---- muP-style multipliers (granitemoehybrid); 1.0 / 0.0 = none ----
+    # x0 = E[ids] * embedding_multiplier; every residual add is x +
+    # residual_multiplier * f(norm(x)); the attention scores are q k^T *
+    # attention_multiplier (0.0 = head_dim ** -0.5); the logits are divided
+    # by logits_scaling before any softmax, temperature or top-k/p.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
 
     def __post_init__(self):
         # The checks read the fields as given: `plan` is for what passed.
@@ -280,6 +291,12 @@ class ModelConfig:
             )
         if self.moe_score_func not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown moe_score_func {self.moe_score_func!r}")
+        if self.attention_multiplier and self.is_latent:
+            raise NotImplementedError(
+                "attention_multiplier is folded into the per-head q of "
+                "softmax attention; latent attention's absorbed decode step "
+                "takes head_dim ** -0.5"
+            )
         if self.is_latent:
             qk = self.qk_nope_head_dim + self.qk_rope_head_dim
             if not (qk == self.v_head_dim == self.head_dim):
@@ -321,7 +338,11 @@ class ModelConfig:
             )
         if "E" in pattern and not self.is_moe:
             raise ValueError("an 'E' layer needs n_experts > 0")
-        if "M" in pattern and (
+        if "M" in pattern:
+            self._check_ssm()
+
+    def _check_ssm(self):
+        if (
             not (self.ssm_n_heads and self.ssm_head_dim and self.ssm_state_dim)
             or self.ssm_n_heads % self.ssm_n_groups
             or self.ssm_inner_dim % self.ssm_n_groups
@@ -339,12 +360,20 @@ class ModelConfig:
             raise ValueError(
                 f"window_pattern {pattern!r} is not {self.n_layers} "
                 "characters of 'S' (sliding window), 'F' (full attention), "
-                "'C' (gated short convolution)"
+                "'C' (gated short convolution), 'M' (Mamba-2)"
             )
         if "S" in pattern and self.attn_window < 1:
             raise ValueError("an 'S' layer needs attn_window >= 1")
         if "C" in pattern and self.sconv_kernel < 2:
             raise ValueError("a 'C' layer needs sconv_kernel >= 2")
+        if "M" in pattern:
+            self._check_ssm()
+            if "M" in pattern[:self.first_k_dense]:
+                raise NotImplementedError(
+                    f"window_pattern {pattern!r}: a leading dense layer's "
+                    "mixer is 'F' or 'C' (a state before the scan was not "
+                    "tested)"
+                )
         if (
             self.layer_pattern or self.full_attn_interval > 1
             or self.is_latent or self.attn_gate
@@ -442,9 +471,15 @@ class ModelConfig:
     @property
     def has_recurrent_state(self) -> bool:
         """Whether some layer carries a state from token to token (Gated
-        DeltaNet, Mamba-2): no slot on the serving plane, no split over
-        `model`, `seq` or `pipe` yet."""
+        DeltaNet, Mamba-2): no split over `model`, `seq` or `pipe` yet; on
+        the serving plane a slot beside the page pool for Mamba-2 in
+        two-branch layers alone (`transformer.plan_refusal`)."""
         return self.plan.count(GDN, SSM) > 0
+
+    @property
+    def attn_scale(self) -> float:
+        """What q k^T is multiplied by before the softmax."""
+        return self.attention_multiplier or self.head_dim**-0.5
 
     @property
     def ssm_inner_dim(self) -> int:

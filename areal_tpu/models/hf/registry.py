@@ -1490,6 +1490,246 @@ register_hf_family(
 )
 
 
+# ---------------- granitemoehybrid ----------------
+# ibm-granite/granite-4.0-h-micro: `layer_types` gives every layer its
+# mixer behind `input_layernorm` — "mamba", a Mamba-2 mixer (`mamba.*`, the
+# tensors nemotron_h's have), or "attention" (q/k/v/o without bias, NO
+# positional embedding at `position_embedding_type: nope`) — and a dense
+# SwiGLU MLP behind `post_attention_layernorm`: `shared_mlp.output_linear(
+# silu(gate) * up)` with [gate | up] = `shared_mlp.input_linear`.  Four
+# multipliers: the embedding's, every residual add's, the attention
+# scores' (in place of head_dim ** -0.5) and `logits_scaling`, which
+# DIVIDES the logits; the head is tied to the embedding.  The MoE siblings
+# (`num_local_experts` > 0: routed experts beside the shared MLP) are not
+# modelled and are refused by name.  The tensor names are assumed (no
+# network to re-read the module; `benchmark/configs/granite-4.0-h-micro-
+# l10.json`, `assumed`).
+
+_GRANITE_LAYER_TYPES = {"mamba": "M", "attention": "F"}
+# The chunk of the program's SSD scan: the published `mamba_chunk_size`
+# (256) sizes a kernel's tiling and nothing of the function; the program
+# takes its own, at most this (`nemotron_h`'s 128: a [128, 128] decay
+# block a head, half the memory of 256 at a packed row of 8,192 tokens).
+_GRANITE_MAX_CHUNK = 128
+
+
+def _granite_config_from_hf(hf: dict) -> ModelConfig:
+    if hf.get("num_local_experts", 0):
+        raise NotImplementedError(
+            f"granitemoehybrid num_local_experts={hf['num_local_experts']}: "
+            "routed experts beside the shared MLP (the MoE siblings of "
+            "granite-4.0-h-micro) are not modelled"
+        )
+    for key, fine in (
+        ("attention_bias", False), ("mamba_conv_bias", True),
+        ("mamba_proj_bias", False), ("hidden_act", "silu"),
+        ("normalization_function", "rmsnorm"), ("rope_scaling", None),
+    ):
+        if hf.get(key, fine) != fine:
+            raise NotImplementedError(
+                f"granitemoehybrid {key}={hf[key]!r} is not modelled"
+            )
+    positions = hf.get("position_embedding_type", "nope")
+    if positions not in ("nope", "rope"):
+        raise NotImplementedError(
+            f"granitemoehybrid position_embedding_type={positions!r}"
+        )
+    types = hf["layer_types"]
+    if len(types) != hf["num_hidden_layers"] or set(types) - set(
+        _GRANITE_LAYER_TYPES
+    ):
+        raise ValueError(
+            f"layer_types {types!r} is not {hf['num_hidden_layers']} of "
+            f"{sorted(_GRANITE_LAYER_TYPES)}"
+        )
+    return ModelConfig(
+        n_layers=hf["num_hidden_layers"],
+        hidden_dim=hf["hidden_size"],
+        n_q_heads=hf["num_attention_heads"],
+        n_kv_heads=hf["num_key_value_heads"],
+        head_dim=hf["hidden_size"] // hf["num_attention_heads"],
+        intermediate_dim=hf["shared_intermediate_size"],
+        vocab_size=hf["vocab_size"],
+        max_position_embeddings=hf.get("max_position_embeddings", 131072),
+        rope_theta=hf.get("rope_theta", 10000.0),
+        rms_norm_eps=hf.get("rms_norm_eps", 1e-5),
+        tied_embeddings=bool(hf.get("tie_word_embeddings", True)),
+        pos_emb="none" if positions == "nope" else "rope",
+        window_pattern="".join(_GRANITE_LAYER_TYPES[t] for t in types),
+        ssm_n_heads=hf["mamba_n_heads"],
+        ssm_head_dim=hf["mamba_d_head"],
+        ssm_n_groups=hf.get("mamba_n_groups", 1),
+        ssm_state_dim=hf["mamba_d_state"],
+        ssm_conv_kernel=hf.get("mamba_d_conv", 4),
+        ssm_chunk=min(hf.get("mamba_chunk_size", 256), _GRANITE_MAX_CHUNK),
+        embedding_multiplier=float(hf.get("embedding_multiplier", 1.0)),
+        residual_multiplier=float(hf.get("residual_multiplier", 1.0)),
+        attention_multiplier=float(hf.get("attention_multiplier", 0.0)),
+        logits_scaling=float(hf.get("logits_scaling", 1.0)),
+    )
+
+
+def _granite_config_to_hf(cfg: ModelConfig) -> dict:
+    kinds = {v: k for k, v in _GRANITE_LAYER_TYPES.items()}
+    return {
+        "model_type": "granitemoehybrid",
+        "architectures": ["GraniteMoeHybridForCausalLM"],
+        "torch_dtype": "bfloat16",
+        "num_hidden_layers": cfg.n_layers,
+        "layer_types": [kinds[c] for c in cfg.window_pattern],
+        "hidden_size": cfg.hidden_dim,
+        "num_attention_heads": cfg.n_q_heads,
+        "num_key_value_heads": cfg.n_kv_heads,
+        "intermediate_size": cfg.intermediate_dim,
+        "shared_intermediate_size": cfg.intermediate_dim,
+        "vocab_size": cfg.vocab_size,
+        "max_position_embeddings": cfg.max_position_embeddings,
+        "rope_theta": cfg.rope_theta,
+        "rope_scaling": None,
+        "rms_norm_eps": cfg.rms_norm_eps,
+        "tie_word_embeddings": cfg.tied_embeddings,
+        "position_embedding_type": (
+            "nope" if cfg.pos_emb == "none" else "rope"),
+        "attention_bias": False,
+        "hidden_act": "silu",
+        "normalization_function": "rmsnorm",
+        "num_local_experts": 0,
+        "num_experts_per_tok": 0,
+        "mamba_n_heads": cfg.ssm_n_heads,
+        "mamba_d_head": cfg.ssm_head_dim,
+        "mamba_n_groups": cfg.ssm_n_groups,
+        "mamba_d_state": cfg.ssm_state_dim,
+        "mamba_d_conv": cfg.ssm_conv_kernel,
+        "mamba_expand": cfg.ssm_inner_dim // cfg.hidden_dim,
+        "mamba_chunk_size": cfg.ssm_chunk,
+        "mamba_conv_bias": True,
+        "mamba_proj_bias": False,
+        "embedding_multiplier": cfg.embedding_multiplier,
+        "residual_multiplier": cfg.residual_multiplier,
+        "attention_multiplier": cfg.attn_scale,
+        "logits_scaling": cfg.logits_scaling,
+    }
+
+
+_GRANITE = "model.layers.{}."
+# A mixer's leaves: ours <- the name behind the layer's prefix, transposed
+# ([out, in] -> [in, out]); the conv's taps and the MLP are handled below.
+_GRANITE_MIXER = {
+    "M": (
+        ("ssm_in", "mamba.in_proj.weight", True),
+        ("ssm_conv_b", "mamba.conv1d.bias", False),
+        ("ssm_A_log", "mamba.A_log", False),
+        ("ssm_D", "mamba.D", False),
+        ("ssm_dt_bias", "mamba.dt_bias", False),
+        ("ssm_norm", "mamba.norm.weight", False),
+        ("ssm_out", "mamba.out_proj.weight", True),
+    ),
+    "F": (
+        ("wq", "self_attn.q_proj.weight", True),
+        ("wk", "self_attn.k_proj.weight", True),
+        ("wv", "self_attn.v_proj.weight", True),
+        ("wo", "self_attn.o_proj.weight", True),
+    ),
+}
+
+
+def _granite_layers(cfg, kind):
+    return [i for i, c in enumerate(cfg.window_pattern) if c == kind]
+
+
+def _granite_params_from_sd(cfg, sd, dtype=None):
+    import jax.numpy as jnp
+
+    dtype = dtype or cfg.dtype
+    f = cfg.intermediate_dim
+
+    def get(name):
+        if name not in sd:
+            raise KeyError(f"missing tensor {name!r} in checkpoint")
+        return np.asarray(sd[name], np.float32)
+
+    def stack(layers, fn):
+        return jnp.asarray(
+            np.stack([fn(_GRANITE.format(i)) for i in layers]), dtype)
+
+    every = range(cfg.n_layers)
+    blocks = {
+        "ln1": stack(every, lambda pre: get(pre + "input_layernorm.weight")),
+        "ln2": stack(
+            every, lambda pre: get(pre + "post_attention_layernorm.weight")),
+        # input_linear [2F, D]: gate's rows, then up's.
+        "wg": stack(every, lambda pre: get(
+            pre + "shared_mlp.input_linear.weight")[:f].T),
+        "wu": stack(every, lambda pre: get(
+            pre + "shared_mlp.input_linear.weight")[f:].T),
+        "wd": stack(every, lambda pre: get(
+            pre + "shared_mlp.output_linear.weight").T),
+    }
+    for kind, leaves in _GRANITE_MIXER.items():
+        layers = _granite_layers(cfg, kind)
+        for ours, theirs, t in leaves if layers else ():
+            blocks[ours] = stack(
+                layers,
+                lambda pre: get(pre + theirs).T if t else get(pre + theirs))
+    if cfg.n_ssm_layers:  # [C, 1, K] -> [K, C], oldest tap first
+        blocks["ssm_conv"] = stack(
+            _granite_layers(cfg, "M"),
+            lambda pre: get(pre + "mamba.conv1d.weight")[:, 0, :].T)
+    params = {
+        "embed": jnp.asarray(get("model.embed_tokens.weight"), dtype),
+        "blocks": blocks,
+        "final_ln": jnp.asarray(get("model.norm.weight"), dtype),
+    }
+    if not cfg.tied_embeddings:
+        params["lm_head"] = jnp.asarray(get("lm_head.weight").T, dtype)
+    return params
+
+
+def _granite_params_to_sd(cfg, params):
+    from areal_tpu.base.distributed import to_host
+
+    def host(x):
+        return to_host(x).astype(np.float32, copy=False)
+
+    blocks = {n: host(w) for n, w in params["blocks"].items()}
+    out = {
+        "model.embed_tokens.weight": host(params["embed"]),
+        "model.norm.weight": host(params["final_ln"]),
+    }
+    if not cfg.tied_embeddings:
+        out["lm_head.weight"] = np.ascontiguousarray(
+            host(params["lm_head"]).T)
+    for i in range(cfg.n_layers):
+        pre = _GRANITE.format(i)
+        out[pre + "input_layernorm.weight"] = blocks["ln1"][i]
+        out[pre + "post_attention_layernorm.weight"] = blocks["ln2"][i]
+        out[pre + "shared_mlp.input_linear.weight"] = np.ascontiguousarray(
+            np.concatenate([blocks["wg"][i].T, blocks["wu"][i].T]))
+        out[pre + "shared_mlp.output_linear.weight"] = np.ascontiguousarray(
+            blocks["wd"][i].T)
+    for kind, leaves in _GRANITE_MIXER.items():
+        for j, i in enumerate(_granite_layers(cfg, kind)):
+            pre = _GRANITE.format(i)
+            for ours, theirs, t in leaves:
+                w = blocks[ours][j]
+                out[pre + theirs] = np.ascontiguousarray(w.T) if t else w
+            if kind == "M":
+                out[pre + "mamba.conv1d.weight"] = np.ascontiguousarray(
+                    blocks["ssm_conv"][j].T[:, None, :])
+    return out
+
+
+register_hf_family(
+    HFFamily(
+        "granitemoehybrid",
+        _granite_config_from_hf,
+        _granite_config_to_hf,
+        params_from_sd=_granite_params_from_sd,
+        params_to_sd=_granite_params_to_sd,
+    )
+)
+
+
 # ---------------- lfm2_moe ----------------
 # LiquidAI/LFM2-8B-A1B: `layer_types` gives every layer its mixer behind
 # `operator_norm` — "conv", the gated short convolution (`conv.in_proj` ->
@@ -1878,6 +2118,8 @@ def infer_model_type(cfg: ModelConfig) -> str:
         return "glm4_moe_lite"
     if cfg.n_sconv_layers:
         return "lfm2_moe"
+    if cfg.n_ssm_layers:  # Mamba-2 mixers in two-branch layers
+        return "granitemoehybrid"
     if cfg.window_pattern or cfg.rope_yarn_factor:
         return "mellum" if cfg.is_moe else "mistral"
     if cfg.is_moe:

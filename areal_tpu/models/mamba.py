@@ -13,7 +13,7 @@ WITH bias + SiLU over the x | B | C channels; after it y * silu(z), an
 RMSNorm over each GROUP of channels times a weight (gate first, then norm)
 and the output projection.
 
-Two forms of the same recurrence:
+Three forms of the same recurrence:
 - `ssm_forward` (training, `forward`, prefill): the chunked (SSD) form.
   Inside a chunk of `ssm_chunk` tokens y = ((C B^T) * decay) (dt x); each
   chunk's own contribution to the state and the read of the state it
@@ -21,6 +21,18 @@ Two forms of the same recurrence:
   chunks carries S through an elementwise update alone.
 - `ssm_step` (decode): one token against the carried S and the conv's last
   K-1 inputs.
+- `ssm_ragged` (the serving plane's chunk): a PACKED RAGGED stream in which
+  a slot owns 0 (done, parked, lane-starved), 1 (decoding) or up to W
+  (prefilling) consecutive lanes of one inner step.  The lanes are
+  gathered into a slab [n_slots, W] (`slot_lanes_of`) and a slot's lanes
+  are ONE short SSD chunk that starts from the slot's carried S and conv
+  tail and leaves both behind: y_t = C_t . decay(start -> t) S0 + the
+  chunk's own lower triangle, S_new = decay(start -> end) S0 + sum_t
+  decay(t -> end) dt_t x_t (x) B_t, the conv with the tail as its left
+  halo.  A slot whose first lane is at position 0 starts from ZERO state
+  and tail (a reused slot never sees its previous request); a slot with no
+  lane, and every dead lane, leaves state and tail bit-identical.  A
+  decoding slot is the one-lane case of the same code.
 
 Packed rows: S and the conv restart at every segment start — the decay
 across a segment boundary is zero, the carried state is dropped for every
@@ -40,7 +52,7 @@ Parameters (leaves of `params["blocks"]`, stacked [n_ssm_layers, ...]):
 """
 
 import math
-from typing import Dict, Tuple
+from typing import Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -290,3 +302,139 @@ def ssm_step(
         )
     out = _out(y.reshape(b, cfg.ssm_inner_dim), z, blk, cfg)
     return out[:, None], states, tails
+
+
+class SlotLanes(NamedTuple):
+    """One stream's lanes by slot, the same for every Mamba layer of a
+    step (`slot_lanes_of`)."""
+
+    idx: jax.Array  # [R, W] int32: the stream lane of slot r's w-th lane
+    valid: jax.Array  # [R, W] bool: w < the slot's lanes this step
+    count: jax.Array  # [R] int32: the slot's lanes this step
+    fresh: jax.Array  # [R] bool: the slot's first lane is at position 0
+    rid: jax.Array  # [T] int32: a lane's slot (dead lanes clipped)
+    q: jax.Array  # [T] int32: a lane's place among its slot's lanes
+
+
+def slot_lanes_of(
+    row_of: jax.Array,  # [T] int32: a lane's slot; >= n_slots = dead
+    positions: jax.Array,  # [T] int32: a lane's position in its sequence
+    n_slots: int,
+    width: int,  # W: the most lanes a slot holds in one stream
+) -> SlotLanes:
+    """Where each slot's lanes lie in a packed stream.  A slot's lanes are
+    CONTIGUOUS and in position order (the serving chunk packs row r at
+    [starts[r], starts[r] + c[r])); no slot holds more than `width`."""
+    t = row_of.shape[0]
+    lane = jnp.arange(t, dtype=jnp.int32)
+    row_of = row_of.astype(jnp.int32)
+    count = jnp.zeros((n_slots,), jnp.int32).at[row_of].add(1, mode="drop")
+    start = jnp.full((n_slots,), t, jnp.int32).at[row_of].min(lane, mode="drop")
+    w = jnp.arange(width, dtype=jnp.int32)
+    idx = jnp.minimum(start[:, None] + w[None, :], t - 1)
+    valid = w[None, :] < count[:, None]
+    rid = jnp.minimum(row_of, n_slots - 1)
+    fresh = valid[:, 0] & (positions[idx[:, 0]] == 0)
+    q = jnp.clip(lane - start[rid], 0, width - 1)
+    return SlotLanes(idx, valid, count, fresh, rid, q)
+
+
+def ssd_slab(
+    x: jax.Array,  # [R, W, H, P] fp32
+    dt: jax.Array,  # [R, W, H] fp32 after softplus; 0 = no lane
+    a: jax.Array,  # [H] fp32, negative
+    bm: jax.Array,  # [R, W, G, N] fp32
+    cm: jax.Array,  # [R, W, G, N] fp32
+    s0: jax.Array,  # [R, H, P, N] fp32: the state each slot carries
+    carried: jax.Array,  # [R] fp32: 1 = start from s0, 0 = from zero
+) -> Tuple[jax.Array, jax.Array]:
+    """One SSD chunk a slot, from a carried state -> (y [R, W, H, P] fp32
+    without the D skip, the state after each slot's last lane).  A slot's
+    lanes are its first ones (dt = 0 behind them: the log-decay stops
+    growing, so the slab's last column IS the last lane's).  `carried`
+    scales the two places the old state enters (its read and its decay)
+    instead of the state itself: zeroing [R, H, P, N] would be a pass over
+    it of its own."""
+    r, w, h, p = x.shape
+    g, n = bm.shape[2:]
+    hg = h // g
+    xd = (x * dt[..., None]).reshape(r, w, g, hg, p)
+    ga = jnp.cumsum((dt * a).reshape(r, w, g, hg), axis=1)  # inclusive
+    gat = jnp.moveaxis(ga, 1, -1)  # [R, G, HG, W]
+    i = jnp.arange(w)
+    keep = i[:, None] >= i[None, :]
+    diff = gat[..., :, None] - gat[..., None, :]
+    decay = jnp.where(keep, jnp.exp(jnp.where(keep, diff, 0.0)), 0.0)
+    cb = jnp.einsum("rign,rjgn->rgij", cm, bm)
+    y = jnp.einsum("rgkij,rjgkp->rigkp", cb[:, :, None] * decay, xd)
+    # The read of the carried state, by every lane of its slot ...
+    sg = s0.reshape(r, g, hg, p, n)
+    kept = carried[:, None, None]  # over [R, G, HG]
+    y = y + jnp.einsum("rign,rgkpn->rigkp", cm, sg) * (
+        jnp.exp(ga) * kept[:, None])[..., None]
+    # ... and what the slot leaves: the carried state decayed over all its
+    # lanes plus each lane's outer product decayed to the last.
+    g_last = ga[:, -1]  # [R, G, HG]
+    w_out = jnp.exp(g_last[:, None] - ga)  # [R, W, G, HG]
+    own = jnp.einsum("rjgkp,rjgn->rgkpn", xd * w_out[..., None], bm)
+    new = sg * (jnp.exp(g_last) * kept)[..., None, None] + own
+    return y.reshape(r, w, h, p), new.reshape(r, h, p, n)
+
+
+@jax.named_scope("layer/ssm")
+def ssm_ragged(
+    h: jax.Array,  # [T, D] normed layer input, a packed ragged stream
+    blk: Params,
+    cfg: ModelConfig,
+    states: jax.Array,  # [steps, R, H, P, N] fp32: this layer of the unit's
+    tails: jax.Array,  # [steps, R, K-1, conv_dim] its conv's last inputs
+    li,  # the scan step: this layer's place in both
+    lanes: SlotLanes,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The serving chunk's step over a packed stream -> (y [T, D], states,
+    tails), entry `li` of both stepped in place for the slots that hold a
+    lane (module docstring).  The buffers are ONE Mamba layer of the
+    plan's unit's, stacked over the scan's steps
+    (`transformer.PagedKVCache`); they come in and go out whole so that
+    the state's read and write lie under this scope, as in `ssm_step`."""
+    kk = cfg.ssm_conv_kernel
+    with jax.named_scope("in_proj"):
+        z, xbc, dt = _split_in(h @ blk["ssm_in"], cfg)
+    with jax.named_scope("ssm_ragged"):
+        state = jax.lax.dynamic_index_in_dim(states, li, axis=0, keepdims=False)
+        tail = jax.lax.dynamic_index_in_dim(tails, li, axis=0, keepdims=False)
+        held = lanes.count > 0
+        with jax.named_scope("conv"):
+            # The slot's tail is the conv's left halo: [tail | its lanes].
+            tail0 = jnp.where(lanes.fresh[:, None, None], 0, tail)
+            cat = jnp.concatenate(
+                [tail0, xbc[lanes.idx].astype(tail.dtype)], axis=1)
+            taps = blk["ssm_conv"].astype(jnp.float32)
+            width = lanes.idx.shape[1]
+            conv = sum(
+                cat[:, k: k + width].astype(jnp.float32) * taps[k]
+                for k in range(kk)
+            )
+            conv = jax.nn.silu(conv + blk["ssm_conv_b"].astype(jnp.float32))
+            # What the slot's last lane leaves: the last K-1 of [tail |
+            # lanes]; with no lane, the tail as it was.
+            at = lanes.count[:, None] + jnp.arange(kk - 1)[None, :]
+            tails = jax.lax.dynamic_update_index_in_dim(
+                tails, jnp.take_along_axis(cat, at[..., None], axis=1),
+                li, axis=0,
+            )
+        with jax.named_scope("ssd_scan"):
+            x, bm, cm = _split_conv(conv, cfg)  # [R, W, H, P], [R, W, G, N]
+            dts, a = _dt_a(dt[lanes.idx], blk)
+            dts = jnp.where(lanes.valid[..., None], dts, 0.0)
+            y, new = ssd_slab(
+                x, dts, a, bm, cm, state, 1.0 - lanes.fresh.astype(jnp.float32))
+            y = y + blk["ssm_D"].astype(jnp.float32)[:, None] * x
+            states = jax.lax.dynamic_update_index_in_dim(
+                states,
+                jnp.where(held[:, None, None, None], new, state),
+                li, axis=0,
+            )
+        # Back to the stream: lane t is its slot's q-th.
+        y = y[lanes.rid, lanes.q].reshape(h.shape[0], cfg.ssm_inner_dim)
+    return _out(y, z, blk, cfg), states, tails

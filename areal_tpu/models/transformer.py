@@ -63,7 +63,14 @@ from areal_tpu.models.linear_attention import (
     linear_attn_forward,
     linear_attn_step,
 )
-from areal_tpu.models.mamba import SSM_LEAVES, init_ssm, ssm_forward, ssm_step
+from areal_tpu.models.mamba import (
+    SSM_LEAVES,
+    init_ssm,
+    slot_lanes_of,
+    ssm_forward,
+    ssm_ragged,
+    ssm_step,
+)
 from areal_tpu.models.short_conv import (
     SCONV_LEAVES,
     init_sconv,
@@ -298,6 +305,8 @@ def _embed(
     x = jnp.take(params["embed"], tokens, axis=0, mode="clip")
     if cfg.embed_scale:  # gemma normalizer, computed in fp32
         x = (x.astype(jnp.float32) * (cfg.hidden_dim**0.5)).astype(x.dtype)
+    if cfg.embedding_multiplier != 1.0:  # granitemoehybrid, in fp32
+        x = (x.astype(jnp.float32) * cfg.embedding_multiplier).astype(x.dtype)
     if cfg.pos_emb == "learned":
         x = x + jnp.take(params["pos_embed"], positions, axis=0, mode="clip")
     return x
@@ -316,6 +325,15 @@ def positions_from_segments(segment_ids: jax.Array) -> jax.Array:
     start_idx = jnp.where(is_start, idx, 0)
     seg_start = jax.lax.associative_scan(jnp.maximum, start_idx, axis=-1)
     return idx - seg_start
+
+
+def _residual(x: jax.Array, out: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """x + residual_multiplier * out: every program's residual add (the
+    multiplier in fp32, rounded once to the stream's type; 1.0, every
+    other family, is the plain add)."""
+    if cfg.residual_multiplier == 1.0:
+        return x + out
+    return x + (out.astype(jnp.float32) * cfg.residual_multiplier).astype(x.dtype)
 
 
 # Device-side names (PERF.md §3): one `jax.named_scope` per part of the
@@ -1239,7 +1257,7 @@ def _packed_layer(
         out, more = branches[branch](h, blk)
         if named:
             out = checkpoint_name(out, _SAVED_AS.get(branch, "attn_out"))
-        x = x + out
+        x = _residual(x, out, cfg)
         gave.update(more)
     return x, gave
 
@@ -1475,12 +1493,23 @@ _NO_PATTERN_LAYOUT = (
 )
 
 
+_NO_SSM_LAYOUT = (
+    "Mamba-2 mixers in two-branch layers run under data and fsdp sharding "
+    "only: the Mamba heads, their conv channels and their state are not "
+    "split over `model`, the chunked scan has no ring over a split "
+    "sequence, and the pipeline's stage scans one kind of layer (PERF.md "
+    "section 7)"
+)
+
+
 def plan_refusal(cfg: ModelConfig, serving: bool):
     """What of `cfg.plan` the serving plane (`serving`; its chunk
-    `decode_step_ragged_paged` is a scan of (attention, MLP) layers over
-    pages of per-head k/v) or else a mesh split over `model`, `seq` or
-    `pipe` cannot run yet -> the error to raise, by name, or None for a
-    plan both can: every refusal of a plane or a layout asks here."""
+    `decode_step_ragged_paged` walks a plan of two-branch layers whose
+    mixers are softmax attention over pages of per-head k/v or Mamba-2
+    with a slot of state beside the pool) or else a mesh split over
+    `model`, `seq` or `pipe` cannot run yet -> the error to raise, by
+    name, or None for a plan both can: every refusal of a plane or a
+    layout asks here."""
     plan = cfg.plan
     if plan.count(GDN):
         return HybridLayoutError(
@@ -1491,9 +1520,11 @@ def plan_refusal(cfg: ModelConfig, serving: bool):
     if plan.count(LATENT) or plan.prefix:
         return LatentLayoutError(
             _NO_SERVING_LATENT if serving else _NO_LATENT_LAYOUT)
-    if plan.count(SSM) or cfg.is_pattern:
+    if cfg.is_pattern:
         return HybridLayoutError(
             _NO_SERVING_PATTERN if serving else _NO_PATTERN_LAYOUT)
+    if plan.count(SSM) and not serving:
+        return HybridLayoutError(_NO_SSM_LAYOUT)
     if plan.count(WINDOW):
         return WindowLayoutError(
             _NO_SERVING_WINDOW if serving else _NO_WINDOW_LAYOUT)
@@ -1656,9 +1687,12 @@ def _head(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
         )
         return v[..., 0]  # [B, S] fp32 values
     head = params["embed"].T if cfg.tied_embeddings else params["lm_head"]
-    return jnp.einsum(
+    logits = jnp.einsum(
         "bsd,dv->bsv", x, head, preferred_element_type=jnp.float32
     )  # [B, S, V] fp32 logits
+    if cfg.logits_scaling != 1.0:  # before any softmax, temperature, top-k/p
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 def forward(
@@ -1750,6 +1784,10 @@ def per_token_output(
         return _head(params, cfg, x)
     from areal_tpu.ops.functional import fused_next_token_logprobs
 
+    if cfg.logits_scaling != 1.0:
+        # The fused head never holds the logits: the hidden states take
+        # the division (granite's 8 is a power of two: exact in bf16).
+        x = (x.astype(jnp.float32) / cfg.logits_scaling).astype(x.dtype)
     return fused_next_token_logprobs(
         x, head_weights(params, cfg), tokens, segment_ids, chunk_size, mesh
     )
@@ -1948,6 +1986,13 @@ def _qkv(h, blk, cfg, cos, sin):
             k = jnp.concatenate([kr, k[..., r:]], axis=-1)
         else:
             q, k = apply_rotary(q, k, cos, sin)
+    if cfg.attention_multiplier:
+        # Every attention kernel scales q k^T by head_dim ** -0.5: the
+        # stated multiplier is FOLDED INTO q as multiplier * sqrt(head_dim)
+        # (granite: 2 ** -6 * 8 = 2 ** -3, exact in bf16), so no kernel
+        # takes a scale of its own.
+        q = q * jnp.asarray(
+            cfg.attention_multiplier * cfg.head_dim**0.5, q.dtype)
     return q, k, v
 
 
@@ -2301,7 +2346,7 @@ def decode_step(
                 li = li + lead if lead else li
             h = _norm(y, blk[ln], blk.get(ln + "_b"), cfg)
             out, cache, c = branches[branch](h, blk, cache, li)
-            y = y + out
+            y = _residual(y, out, cfg)
             counts = c if c is not None else counts
         return y, cache, counts
 
@@ -2352,6 +2397,23 @@ class PagedKVCache:
     memory, fixed shapes, one decode compilation.  Reference: TPU ragged paged attention / vLLM
     PagedAttention block tables.
 
+    Pages exist for the plan's ATTENTION layers alone (`L` above is their
+    count, not `n_layers`).  A plan with Mamba-2 layers keeps, beside the
+    pool and for the same generate call, one SLOT of recurrent state a
+    request: `state`, fp32 [n_slots, H, head_dim, N] a Mamba layer, and the
+    conv's last inputs `conv`, [n_slots, K-1, conv_dim] a Mamba layer
+    (`init_paged_kv_cache(n_slots=)`; None for every other plan).  Both are
+    TUPLES over the Mamba layers of the plan's unit, each array stacked
+    [repeats, n_slots, ...] over the scan's steps — one buffer a layer of
+    the unit, not one stack of all layers: the matmul that reads a layer's
+    state takes a whole buffer as it lies, where XLA copies a 134 MB slice
+    out of a stack of layers in front of it (compiled for a described
+    v5e, PR 53; with repeats > 1 the step's slice of a buffer is copied
+    the same way until the recurrence is a kernel of its own).
+    `slot_state(s)` stacks a slot's in layer order.  A slot's state is not
+    paged, shared or copied: it restarts from zero at the slot's lane of
+    position 0 (`mamba.ssm_ragged`) and is dead with the request.
+
     Page index `n_pages` is the UNMAPPED sentinel: writes through it are
     dropped (`mode="drop"`), reads clamp and are masked by `valid_to`
     (pages are mapped contiguously from position 0, so any position
@@ -2372,6 +2434,8 @@ class PagedKVCache:
     v: jax.Array
     k_scale: "jax.Array | None" = None  # [L, n_pages, n_kv, page_size] bf16
     v_scale: "jax.Array | None" = None
+    state: "tuple | None" = None  # per unit layer [repeats, n_slots, H, P, N] fp32
+    conv: "tuple | None" = None  # per unit layer [repeats, n_slots, K-1, conv_dim]
     page_size: int = 128  # static metadata (pytree aux)
 
     @property
@@ -2382,34 +2446,68 @@ class PagedKVCache:
     def quantized(self) -> bool:
         return self.k_scale is not None
 
+    def slot_state(self, slot: int) -> Tuple[jax.Array, jax.Array]:
+        """Slot `slot`'s (state [L_ssm, H, P, N], conv tail [L_ssm, K-1,
+        conv_dim]) in layer order, for a check of what the chunk left."""
+        def stacked(per_layer):
+            return jnp.stack([
+                a[p, slot] for p in range(per_layer[0].shape[0])
+                for a in per_layer
+            ])
+
+        return stacked(self.state), stacked(self.conv)
+
 
 jax.tree_util.register_dataclass(
     PagedKVCache,
-    data_fields=["k", "v", "k_scale", "v_scale"],
+    data_fields=["k", "v", "k_scale", "v_scale", "state", "conv"],
     meta_fields=["page_size"],
 )
 
 
 def init_paged_kv_cache(
-    cfg: ModelConfig, n_pages: int, page_size: int, dtype=None
+    cfg: ModelConfig, n_pages: int, page_size: int, dtype=None,
+    n_slots: int = 0,
 ) -> PagedKVCache:
+    """`n_slots`: the generator's slots, for a plan with Mamba-2 layers (a
+    slot of state and a conv tail a request; the tail in the compute type,
+    as `init_kv_cache` keeps it)."""
     refusal = plan_refusal(cfg, serving=True)
     if refusal:
         raise refusal
-    shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads * cfg.head_dim)
+    n_attn = cfg.plan.count(ATTENTION)
+    shape = (n_attn, n_pages, page_size, cfg.n_kv_heads * cfg.head_dim)
     dtype = dtype or cfg.dtype
+    slots = {}
+    if cfg.n_ssm_layers:
+        if n_slots < 1:
+            raise ValueError(
+                "a plan with Mamba-2 layers keeps a slot of state a request "
+                "beside the page pool: init_paged_kv_cache needs n_slots"
+            )
+        state, conv = _RECURRENT_SHAPES[SSM](cfg)
+        plan = cfg.plan
+        tail_dtype = cfg.dtype if dtype in (jnp.int8, "int8") else dtype
+        slots = dict(
+            state=tuple(
+                jnp.zeros((plan.repeats, n_slots, *state), jnp.float32)
+                for _ in range(plan.in_unit(SSM))),
+            conv=tuple(
+                jnp.zeros((plan.repeats, n_slots, *conv), tail_dtype)
+                for _ in range(plan.in_unit(SSM))),
+        )
     if dtype in (jnp.int8, "int8"):
-        s_shape = (cfg.n_layers, n_pages, cfg.n_kv_heads, page_size)
+        s_shape = (n_attn, n_pages, cfg.n_kv_heads, page_size)
         return PagedKVCache(
             k=jnp.zeros(shape, jnp.int8),
             v=jnp.zeros(shape, jnp.int8),
             k_scale=jnp.zeros(s_shape, jnp.bfloat16),
             v_scale=jnp.zeros(s_shape, jnp.bfloat16),
-            page_size=page_size,
+            page_size=page_size, **slots,
         )
     return PagedKVCache(
         k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
-        page_size=page_size,
+        page_size=page_size, **slots,
     )
 
 
@@ -2499,6 +2597,7 @@ def decode_step_ragged_paged(
     row_of: jax.Array,  # [T] int32 — owning slot per token; >= B = dead lane
     experts_in_place: Optional[bool] = None,
     paged_kernel: Optional[bool] = None,
+    slot_lanes: Optional[int] = None,
 ) -> Tuple[jax.Array, PagedKVCache]:
     """The serving plane's forward: one packed [T] stream of query lanes
     with per-token windows, instead of a [B, Q] slab with per-row q_lens.
@@ -2520,7 +2619,17 @@ def decode_step_ragged_paged(
     caller never reads.  The pool shape never changes during a generate
     call, so the enclosing program compiles exactly once.  A grouped MoE
     model's expert leaves
-    reach the ragged kernels as in `decode_step` (`experts_in_place`)."""
+    reach the ragged kernels as in `decode_step` (`experts_in_place`).
+
+    The layers are `cfg.plan`'s, walked as `decode_step` walks them: the
+    scan steps the plan's unit, a unit's layers unrolled, each branch
+    through the table below.  An ATTENTION branch reads and writes the
+    pages of its own layer among the attention layers; a Mamba-2 branch
+    (`mamba.ssm_ragged`) steps its layer of the slots' state and conv
+    tails over the slot's lanes of this step — `slot_lanes`, the most
+    lanes one slot may hold in a stream (the caller's W), sizes its slab
+    and is required for a plan with state; a slot's lanes are contiguous
+    in the stream and in position order."""
     refusal = plan_refusal(cfg, serving=True)
     if refusal:
         raise refusal
@@ -2552,10 +2661,23 @@ def decode_step_ragged_paged(
             pt_tok, valid_to, cache.n_pages, cache.page_size,
             cfg.n_q_heads // cfg.n_kv_heads,
         )
+    plan = cfg.plan
+    lanes = None
+    if plan.count(SSM):  # the same slab of lanes for every Mamba layer
+        if not slot_lanes:
+            raise ValueError(
+                "a plan with Mamba-2 layers needs slot_lanes: the most "
+                "lanes one slot holds in a stream"
+            )
+        lanes = slot_lanes_of(row_of, positions, b, slot_lanes)
 
-    def body(carry, blk):
-        y, kc, vc, ksc, vsc, li = carry
-        h = _norm(y, blk["ln1"], blk.get("ln1_b"), cfg)
+    # The carry: (y, k pool, v pool, their scales, [the unit's Mamba
+    # layers' states, then their conv tails,] the scan step).  A plan
+    # without state carries what it always did.
+    n_ssm = plan.in_unit(SSM)
+
+    def attend(h, blk, pools, li, at):
+        kc, vc, ksc, vsc, *slots = pools
         q, k, v = _block_kv(h, blk, cfg, cos, sin)  # [T, 1, h, d]
         kc, vc, ksc, vsc = _cache_update(
             kc, vc, ksc, vsc, k[:, 0], v[:, 0], li * stride + rows0,
@@ -2567,27 +2689,66 @@ def decode_step_ragged_paged(
             v_scale=vsc if quant else None,
             use_kernel=paged_kernel, schedule=schedule,
         )
-        ao = _attn_out(attn.reshape(t, 1, cfg.q_dim), blk, cfg)
-        y = y + ao
-        h2 = _norm(y, blk["ln2"], blk.get("ln2_b"), cfg)
-        if cfg.is_moe:
-            y = y + _mlp_moe(h2, blk, cfg, stacked=stacked, layer=li)[0]
-        else:
-            y = y + _mlp_dense(h2, blk, cfg)
-        return (y, kc, vc, ksc, vsc, li + 1), None
+        return _attn_out(attn.reshape(t, 1, cfg.q_dim), blk, cfg), (
+            kc, vc, ksc, vsc, *slots)
+
+    def recur(h, blk, pools, li, at):
+        """`at`: (the layer's place among the unit's Mamba layers, the
+        scan step): its own buffers, and its place in them."""
+        j, pi = at
+        s_at, c_at = 4 + j, 4 + n_ssm + j  # behind k, v and their scales
+        pools = list(pools)
+        out, pools[s_at], pools[c_at] = ssm_ragged(
+            h[:, 0], blk, cfg, pools[s_at], pools[c_at], pi, lanes)
+        return out[:, None], tuple(pools)
+
+    def experts(h, blk, pools, li, at):
+        return _mlp_moe(h, blk, cfg, stacked=stacked, layer=li)[0], pools
+
+    branches = {
+        ATTENTION: attend,
+        SSM: recur,
+        MLP: lambda h, blk, pools, li, at: (_mlp_dense(h, blk, cfg), pools),
+        MOE: experts,
+    }
+
+    def body(carry, step):
+        y, *pools, pi = carry
+        for j in range(len(plan.unit)):
+            kind, nth, blk = _unit_layer(cfg, step, j)
+            for branch, ln in zip(kind, _BRANCH_NORMS):
+                n = plan.in_unit(branch)
+                li = pi if n == 1 else pi * n + nth[branch]
+                h = _norm(y, blk[ln], blk.get(ln + "_b"), cfg)
+                out, pools = branches[branch](
+                    h, blk, pools, li, (nth[branch], pi))
+                y = _residual(y, out, cfg)
+        return (y, *pools, pi + 1), None
 
     blocks, stacked = _scan_blocks(cfg, params["blocks"], experts_in_place)
     ksc0 = cache.k_scale if quant else jnp.zeros((0,), jnp.bfloat16)
     vsc0 = cache.v_scale if quant else jnp.zeros((0,), jnp.bfloat16)
-    (x, kc, vc, ksc, vsc, _), _ = jax.lax.scan(
-        body, (x, cache.k, cache.v, ksc0, vsc0, jnp.int32(0)), blocks
-    )
+    slots = () if lanes is None else (*cache.state, *cache.conv)
+    carry = (x, cache.k, cache.v, ksc0, vsc0, *slots)
+    if slots and plan.repeats == 1:
+        # One step of the unit: no scan, so a layer's place in its buffers
+        # is STATIC, step 0, and the buffer is read as it lies
+        # (`PagedKVCache`).
+        step = jax.tree.map(lambda w: w[0], _unit_view(cfg, blocks))
+        (x, kc, vc, ksc, vsc, *slots, _), _ = body((*carry, 0), step)
+    else:
+        (x, kc, vc, ksc, vsc, *slots, _), _ = jax.lax.scan(
+            body, (*carry, jnp.int32(0)), _unit_view(cfg, blocks),
+        )
     x = _final_norm(params, cfg, x)
     logits = _head(params, cfg, x)[:, 0]  # [T, V]
+    state = tuple(slots[:n_ssm]) if slots else None
+    conv = tuple(slots[n_ssm:]) if slots else None
     return logits, PagedKVCache(
         k=kc, v=vc,
         k_scale=ksc if quant else None,
         v_scale=vsc if quant else None,
+        state=state, conv=conv,
         page_size=cache.page_size,
     )
 
@@ -2610,10 +2771,10 @@ def copy_pages(
         jnp.int32(2**30),
         dst_pages.astype(jnp.int32),
     )
-    new = PagedKVCache(
+    new = dataclasses.replace(
+        cache,
         k=cache.k.at[:, dst].set(cache.k[:, src], mode="drop"),
         v=cache.v.at[:, dst].set(cache.v[:, src], mode="drop"),
-        page_size=cache.page_size,
     )
     if cache.quantized:
         new = dataclasses.replace(

@@ -25,6 +25,7 @@ from benchmark.tests.test_any_block import OLMOE
 from benchmark.tests import test_ledger_readers as ledger_cases
 from benchmark.tests.test_moe_train_rows_gathered_share import *  # noqa: F401,F403 — the cases
 from benchmark.tests.test_ledger_readers import *  # noqa: F401,F403 — the cases
+from benchmark.tests.test_ssmd import *  # noqa: F401,F403 — the cases (PR 53)
 
 SPEC = files.benchmark_json()
 CELLS = [w["name"] for w in SPEC["workloads"]]
@@ -200,16 +201,22 @@ def test_the_new_entries_are_where_the_issue_put_them(monkeypatch):  # noqa: F81
          "layer": layer, "moves": moves, "workloads": [GLM_CELL]}
         for name, unit, better, source, layer, moves in GLM_ENTRIES
     ]
+    # Both cells of the serving plane (PR 53 appended the second).
+    serving = ["q1p5b-serving-waves", "granite4hm-serving-waves"]
     last = per_layer[draw - n - 1]
     assert last == {
         "name": "paged_attn_live_page_share", "unit": "%", "better": "lower",
         "source": "program_counter", "layer": "generator",
-        "moves": "gen_tokens_per_s", "workloads": ["q1p5b-serving-waves"],
+        "moves": "gen_tokens_per_s", "workloads": serving,
     }
     assert [
         c for c in CELLS if last in files.metrics_for(c, traced=True)
-    ] == ["q1p5b-serving-waves"]
-    before = dict(SPEC, per_layer=per_layer[: draw - n - 1])
+    ] == serving
+    # ... and before PR 53 appended the second serving cell to the lists.
+    before = dict(SPEC, per_layer=[
+        dict(m, workloads=[w for w in m["workloads"] if w != serving[1]])
+        if "workloads" in m else m for m in per_layer[: draw - n - 1]
+    ])
     monkeypatch.setattr(files, "benchmark_json", lambda: before)
     ledger_cases.test_the_new_entries_are_where_the_issue_put_them()
 
@@ -460,12 +467,13 @@ def test_the_lfm2_cell_is_as_the_issue_parametrised_it():
     arithmetic holds for a static MoE share cell — and to none that
     divides by another family's `peaks*.py`."""
     cell, config, traffic = files.load_cell(LFM2_CELL)
-    assert SPEC["workloads"][-1] == {
+    # The tenth cell and the eighth configuration (PR 53 appended behind).
+    entry = SPEC["workloads"][9]
+    assert entry == {
         "name": LFM2_CELL, "config": LFM2_CONFIG,
-        "traffic": "rollout32-ctx4k-512", "chips": 1,
-        "why": SPEC["workloads"][-1]["why"],
+        "traffic": "rollout32-ctx4k-512", "chips": 1, "why": entry["why"],
     }
-    conf = SPEC["configs"][-1]
+    conf = SPEC["configs"][7]
     assert conf == {
         "name": LFM2_CONFIG, "source": config["benchmark"]["source"],
         "file": f"benchmark/configs/{LFM2_CONFIG}.json",
@@ -474,8 +482,8 @@ def test_the_lfm2_cell_is_as_the_issue_parametrised_it():
     }
     assert conf["source"] == (
         "https://huggingface.co/LiquidAI/LFM2-8B-A1B/blob/main/config.json")
-    assert len(SPEC["workloads"][-1]["why"]) <= 200 and len(conf["why"]) <= 200
-    assert len(CELLS) == 10 and len(SPEC["configs"]) == 8
+    assert len(entry["why"]) <= 200 and len(conf["why"]) <= 200
+    assert len(CELLS) == 11 and len(SPEC["configs"]) == 9
     assert [w["name"] for w in SPEC["workloads"] if w["chips"] == 4] == [
         "q7b-realloc-4chip"]
     assert (cell["route"], cell["timed_steps"], cell["traffic_seed"]) == (
@@ -501,8 +509,9 @@ def test_the_lfm2_cell_is_as_the_issue_parametrised_it():
     }
     assert listed == {name for name, *_ in LFM2_ENTRIES} | SHARE_CELL_LISTS
     for m in SPEC["end_to_end"] + SPEC["per_layer"]:
-        if LFM2_CELL in m.get("workloads", []):
-            assert m["workloads"][-1] == LFM2_CELL, m["name"]
+        if LFM2_CELL in m.get("workloads", []):  # the last static cell's
+            static = [w for w in m["workloads"] if "serving" not in w]
+            assert static[-1] == LFM2_CELL, m["name"]
     for name in listed:
         assert callable(files.load_module("metrics", name).read), name
     # Every metric without a list is the cell's too, and its reader loads.
@@ -543,12 +552,15 @@ def test_the_delta_rule_share_is_the_last_entry_and_the_hybrid_cells():
     from benchmark.metrics import gdn_delta_rule_share
     from benchmark.run import Run
 
-    assert SPEC["per_layer"][-1] == {
+    at = _at(SPEC["per_layer"], "gdn_delta_rule_share")
+    assert SPEC["per_layer"][at] == {
         "name": "gdn_delta_rule_share", "unit": "%", "better": "lower",
         "source": "device_trace", "layer": "model step",
         "moves": "train_tokens_per_s", "workloads": ["q3next-rollout64-512"],
     }
-    assert SPEC["per_layer"][-2]["name"] == SETUP_ENTRIES[-1][0]
+    assert SPEC["per_layer"][at - 1]["name"] == SETUP_ENTRIES[-1][0]
+    # PR 53's eight follow it (`benchmark/tests/test_ssmd.py`).
+    assert SPEC["per_layer"][at + 1]["name"] == "mfu_train_ssmd"
 
     def run(scopes):
         return Run(

@@ -563,7 +563,6 @@ class TestAsyncServing:
         in-flight requests halt at a chunk boundary, the swap happens,
         and they resume on their existing KV pages — finishing under the
         new version while keeping their original head version stamp."""
-        import time as _time
         import threading as _t
 
         mesh = make_mesh(ParallelConfig.from_str("d1"), jax.devices()[:1])
@@ -591,15 +590,27 @@ class TestAsyncServing:
             def run():
                 box["outs"] = client.generate_batch(inps)
 
+            # The push has to land MID-decode.  Polling `live_slots` and
+            # then pushing raced the three chunks of a toy decode (under
+            # six test workers the decode could finish first: no request
+            # spanned two versions).  Instead the serving loop holds at the
+            # end of its first chunk until the interrupt the push sets has
+            # arrived: a wait on the engine's own event, not a sleep.
+            first_chunk = _t.Event()
+            drain = eng._drain_chunk_outputs
+
+            def drain_then_hold(*a, **k):
+                out = drain(*a, **k)
+                if not first_chunk.is_set():
+                    first_chunk.set()
+                    assert eng._interrupt_evt.wait(timeout=60)
+                return out
+
+            eng._drain_chunk_outputs = drain_then_hold
             th = _t.Thread(target=run)
             th.start()
-            # Wait for decode to actually be in flight, then push.
-            deadline = _time.monotonic() + 60
-            while _time.monotonic() < deadline:
-                if client.health()["live_slots"] > 0:
-                    break
-                _time.sleep(0.002)
-            assert client.health()["live_slots"] > 0, "decode never started"
+            assert first_chunk.wait(timeout=60), "decode never started"
+            assert client.health()["live_slots"] > 0
             v = srv.update_weights_inmem(
                 tfm.init_params(cfg, jax.random.PRNGKey(99))
             )
