@@ -123,7 +123,8 @@ def _new_state_stats() -> Dict[str, int]:
     return {
         "ssm_live_slot_chunks": 0, "ssm_slots_zeroed": 0,
         "ssm_prefix_would_share": 0, "ssm_lanes_decode": 0,
-        "ssm_lanes_prefill": 0, "ssm_interrupts_drained": 0,
+        "ssm_lanes_prefill": 0, "ssm_slot_steps_live": 0,
+        "ssm_interrupts_drained": 0,
     }
 
 
@@ -1198,6 +1199,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                     ss["ssm_live_slot_chunks"] += self.live_slots
                     ss["ssm_lanes_decode"] += int(lane_acc[4])
                     ss["ssm_lanes_prefill"] += int(lane_acc[5])
+                    ss["ssm_slot_steps_live"] += int(lane_acc[6])
 
                 # Register prefixes that FINISHED prefilling this chunk,
                 # before any retirement below can release the owner's pages:
@@ -1569,8 +1571,11 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             # pages) — the third is structurally zero; the bench
             # invariant leg asserts it stays so ("dead-lane compute
             # exactly 0").  A plan with state adds the lanes its
-            # recurrence took, by kind: (decode, prefill).
-            lane_acc = jnp.zeros((6 if has_state else 4,), jnp.int32)
+            # recurrence took, by kind: (decode, prefill), and the (slot,
+            # inner step) pairs in which the slot held a lane — the state
+            # tiles the recurrence has to step; a slot without one is
+            # skipped (`ops/pallas/ssm_slab.py`).
+            lane_acc = jnp.zeros((7 if has_state else 4,), jnp.int32)
             rows = jnp.arange(n_slots)
             lanes = jnp.arange(Wmax)
             lane_ids = jnp.arange(T)
@@ -1671,6 +1676,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                 ] + ([
                     jnp.sum(jnp.where(is_pref, 0, c)),
                     jnp.sum(jnp.where(is_pref, c, 0)),
+                    jnp.sum((c > 0).astype(jnp.int32)),
                 ] if has_state else []))
                 # Per-row lane-token slab, gathered into the stream.
                 idx = jnp.minimum(

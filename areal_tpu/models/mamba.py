@@ -32,7 +32,19 @@ Three forms of the same recurrence:
   halo.  A slot whose first lane is at position 0 starts from ZERO state
   and tail (a reused slot never sees its previous request); a slot with no
   lane, and every dead lane, leaves state and tail bit-identical.  A
-  decoding slot is the one-lane case of the same code.
+  decoding slot is the one-lane case of the same code.  The two places
+  that TOUCH the carried state — the lanes' read C . S0 and the rewrite
+  S_new — run, on a TPU backend on one device where a head's [P, N] tile
+  is whole (8, 128) tiles, as ONE Pallas kernel (`ops/pallas/ssm_slab.py`,
+  `ssd_slab_in_place`): the layer's buffer aliased to itself, a live
+  slot's tiles read once and rewritten where they lie, a slot with no
+  lane not visited at all.  Elsewhere (off a TPU, on a mesh, other
+  widths) they are the two `einsum`s of `ssd_slab`, the kernel's oracle,
+  and `where(held, new, state)` keeps the slots without a lane.  The
+  conv, the cumulative decays, the chunk's own lower triangle
+  (`slab_terms`), the D skip and the gather back to the stream are `jnp`
+  in both.  `ssm_step`, the static program's decode step, keeps its XLA
+  fusion: it already reads the state once.
 
 Packed rows: S and the conv restart at every segment start — the decay
 across a segment boundary is zero, the carried state is dropped for every
@@ -52,7 +64,7 @@ Parameters (leaves of `params["blocks"]`, stacked [n_ssm_layers, ...]):
 """
 
 import math
-from typing import Dict, NamedTuple, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -314,6 +326,9 @@ class SlotLanes(NamedTuple):
     fresh: jax.Array  # [R] bool: the slot's first lane is at position 0
     rid: jax.Array  # [T] int32: a lane's slot (dead lanes clipped)
     q: jax.Array  # [T] int32: a lane's place among its slot's lanes
+    # The state kernel's work list (`ssm_slab.live_slots`):
+    live: jax.Array  # [R] int32: the slots that hold a lane, first
+    n_live: jax.Array  # int32: their number
 
 
 def slot_lanes_of(
@@ -336,7 +351,47 @@ def slot_lanes_of(
     rid = jnp.minimum(row_of, n_slots - 1)
     fresh = valid[:, 0] & (positions[idx[:, 0]] == 0)
     q = jnp.clip(lane - start[rid], 0, width - 1)
-    return SlotLanes(idx, valid, count, fresh, rid, q)
+    from areal_tpu.ops.pallas.ssm_slab import live_slots
+
+    return SlotLanes(idx, valid, count, fresh, rid, q, *live_slots(count))
+
+
+def slab_terms(
+    x: jax.Array,  # [R, W, H, P] fp32
+    dt: jax.Array,  # [R, W, H] fp32 after softplus; 0 = no lane
+    a: jax.Array,  # [H] fp32, negative
+    bm: jax.Array,  # [R, W, G, N] fp32
+    cm: jax.Array,  # [R, W, G, N] fp32
+    carried: jax.Array,  # [R] fp32: 1 = start from s0, 0 = from zero
+):
+    """What one SSD chunk a slot is made of BESIDE the carried state,
+    heads as (group, head in group) -> (y [R, W, G, HG, P]: the chunk's own
+    lower triangle; w_in [R, W, G, HG]: the decay from the chunk's start to
+    each lane, what scales a lane's read of the state; xw [R, W, G, HG, P]:
+    each lane's dt x decayed to the slot's last lane, whose outer products
+    with B enter the state; s_keep [R, G, HG]: the decay of the state
+    itself).  A slot's lanes are its first ones (dt = 0 behind them: the
+    log-decay stops growing, so the slab's last column IS the last
+    lane's).  `carried` scales the two places the old state enters (w_in
+    and s_keep) instead of the state itself: zeroing [R, H, P, N] would be
+    a pass over it of its own."""
+    r, w, h, p = x.shape
+    g = bm.shape[2]
+    hg = h // g
+    xd = (x * dt[..., None]).reshape(r, w, g, hg, p)
+    ga = jnp.cumsum((dt * a).reshape(r, w, g, hg), axis=1)  # inclusive
+    gat = jnp.moveaxis(ga, 1, -1)  # [R, G, HG, W]
+    i = jnp.arange(w)
+    keep = i[:, None] >= i[None, :]
+    diff = gat[..., :, None] - gat[..., None, :]
+    decay = jnp.where(keep, jnp.exp(jnp.where(keep, diff, 0.0)), 0.0)
+    cb = jnp.einsum("rign,rjgn->rgij", cm, bm)
+    y = jnp.einsum("rgkij,rjgkp->rigkp", cb[:, :, None] * decay, xd)
+    kept = carried[:, None, None]  # over [R, G, HG]
+    w_in = jnp.exp(ga) * kept[:, None]
+    g_last = ga[:, -1]  # [R, G, HG]
+    w_out = jnp.exp(g_last[:, None] - ga)  # [R, W, G, HG]
+    return y, w_in, xd * w_out[..., None], jnp.exp(g_last) * kept
 
 
 def ssd_slab(
@@ -349,36 +404,59 @@ def ssd_slab(
     carried: jax.Array,  # [R] fp32: 1 = start from s0, 0 = from zero
 ) -> Tuple[jax.Array, jax.Array]:
     """One SSD chunk a slot, from a carried state -> (y [R, W, H, P] fp32
-    without the D skip, the state after each slot's last lane).  A slot's
-    lanes are its first ones (dt = 0 behind them: the log-decay stops
-    growing, so the slab's last column IS the last lane's).  `carried`
-    scales the two places the old state enters (its read and its decay)
-    instead of the state itself: zeroing [R, H, P, N] would be a pass over
-    it of its own."""
+    without the D skip, the state after each slot's last lane): the `jnp`
+    form, and the oracle of `ops/pallas/ssm_slab.py`."""
     r, w, h, p = x.shape
     g, n = bm.shape[2:]
-    hg = h // g
-    xd = (x * dt[..., None]).reshape(r, w, g, hg, p)
-    ga = jnp.cumsum((dt * a).reshape(r, w, g, hg), axis=1)  # inclusive
-    gat = jnp.moveaxis(ga, 1, -1)  # [R, G, HG, W]
-    i = jnp.arange(w)
-    keep = i[:, None] >= i[None, :]
-    diff = gat[..., :, None] - gat[..., None, :]
-    decay = jnp.where(keep, jnp.exp(jnp.where(keep, diff, 0.0)), 0.0)
-    cb = jnp.einsum("rign,rjgn->rgij", cm, bm)
-    y = jnp.einsum("rgkij,rjgkp->rigkp", cb[:, :, None] * decay, xd)
+    y, w_in, xw, s_keep = slab_terms(x, dt, a, bm, cm, carried)
+    sg = s0.reshape(r, g, h // g, p, n)
     # The read of the carried state, by every lane of its slot ...
-    sg = s0.reshape(r, g, hg, p, n)
-    kept = carried[:, None, None]  # over [R, G, HG]
-    y = y + jnp.einsum("rign,rgkpn->rigkp", cm, sg) * (
-        jnp.exp(ga) * kept[:, None])[..., None]
+    y = y + jnp.einsum("rign,rgkpn->rigkp", cm, sg) * w_in[..., None]
     # ... and what the slot leaves: the carried state decayed over all its
     # lanes plus each lane's outer product decayed to the last.
-    g_last = ga[:, -1]  # [R, G, HG]
-    w_out = jnp.exp(g_last[:, None] - ga)  # [R, W, G, HG]
-    own = jnp.einsum("rjgkp,rjgn->rgkpn", xd * w_out[..., None], bm)
-    new = sg * (jnp.exp(g_last) * kept)[..., None, None] + own
+    new = sg * s_keep[..., None, None] + jnp.einsum(
+        "rjgkp,rjgn->rgkpn", xw, bm)
     return y.reshape(r, w, h, p), new.reshape(r, h, p, n)
+
+
+def _to_stream(v: jax.Array, lanes: "SlotLanes") -> jax.Array:
+    """A slab [R, W, ...] back in the stream [T, ...]: lane t is its
+    slot's q-th."""
+    return v[lanes.rid, lanes.q]
+
+
+def ssd_slab_in_place(
+    x, dt, a, bm, cm,  # as `ssd_slab`
+    states: jax.Array,  # [steps, R, H, P, N] fp32: the layer's whole buffer
+    li,  # the scan step that steps
+    lanes: "SlotLanes",
+    block_h: int = 0,  # heads a grid step (0: the kernel's own choice)
+) -> Tuple[jax.Array, jax.Array]:
+    """`ssd_slab` with the state's part on the Pallas kernel
+    `ssm_slab.ssm_slab_step` -> (y of the STREAM's lanes [T, H, P],
+    states): step `li` of the slots that hold a lane read once and
+    rewritten where it lies, no other byte of the buffer touched.  The
+    kernel's read of the state comes back to the stream BEFORE it is
+    scaled and added: the slab has R x W lanes, the stream a fifth of
+    them, and the rows of a slot without a lane, which the kernel never
+    writes, are then never read either."""
+    from areal_tpu.ops.pallas import ssm_slab
+
+    r, w, h, p = x.shape
+    y, w_in, xw, s_keep = slab_terms(
+        x, dt, a, bm, cm, 1.0 - lanes.fresh.astype(jnp.float32))
+    states, y_raw = ssm_slab.ssm_slab_step(
+        states, li, lanes.live, lanes.n_live,
+        jnp.swapaxes(cm, 1, 2), jnp.swapaxes(bm, 1, 2),
+        xw.reshape(r, w, h * p), s_keep.reshape(r, h), block_h=block_h,
+    )
+    # A dead lane of the stream is clipped onto some slot's lane, written
+    # or not: what lies there may not be a number to scale.
+    y_raw = jnp.where(
+        _to_stream(lanes.valid, lanes)[:, None],
+        _to_stream(y_raw, lanes), 0.0).reshape(-1, *y.shape[2:])
+    y = _to_stream(y, lanes) + y_raw * _to_stream(w_in, lanes)[..., None]
+    return y.reshape(-1, h, p), states
 
 
 @jax.named_scope("layer/ssm")
@@ -390,20 +468,39 @@ def ssm_ragged(
     tails: jax.Array,  # [steps, R, K-1, conv_dim] its conv's last inputs
     li,  # the scan step: this layer's place in both
     lanes: SlotLanes,
+    kernel: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """The serving chunk's step over a packed stream -> (y [T, D], states,
     tails), entry `li` of both stepped in place for the slots that hold a
     lane (module docstring).  The buffers are ONE Mamba layer of the
     plan's unit's, stacked over the scan's steps
     (`transformer.PagedKVCache`); they come in and go out whole so that
-    the state's read and write lie under this scope, as in `ssm_step`."""
+    the state's read and write lie under this scope, as in `ssm_step`.
+
+    The state's part of the chunk — the lanes' read of S0 and its rewrite
+    — takes one of two forms, picked by what the code can see: on a TPU
+    backend, where a head's [P, N] tile is whole (8, 128) tiles
+    (`ssm_slab.fits`), the Pallas kernel `ssm_slab_step`
+    (`ssd_slab_in_place`: the buffer aliased to itself, a live slot's
+    tiles read once and rewritten where they lie, a slot with no lane
+    never touched); elsewhere `ssd_slab`, the kernel's oracle, whose new
+    state is written back under `where(held, new, state)`.  `kernel`: None,
+    that choice; a bool forces either where the shapes fit (interpreted
+    off a TPU) — a caller on a mesh passes False, the kernel is one
+    device's program.  The conv, the chunk's own lower triangle, the D
+    skip and the gather back to the stream are `jnp` in both."""
+    from areal_tpu.base.distributed import is_tpu_backend
+    from areal_tpu.ops.pallas import ssm_slab
+
     kk = cfg.ssm_conv_kernel
+    if kernel is None:
+        kernel = is_tpu_backend()
+    kernel = kernel and ssm_slab.fits(
+        cfg.ssm_n_heads, cfg.ssm_n_groups, cfg.ssm_head_dim, cfg.ssm_state_dim)
     with jax.named_scope("in_proj"):
         z, xbc, dt = _split_in(h @ blk["ssm_in"], cfg)
     with jax.named_scope("ssm_ragged"):
-        state = jax.lax.dynamic_index_in_dim(states, li, axis=0, keepdims=False)
         tail = jax.lax.dynamic_index_in_dim(tails, li, axis=0, keepdims=False)
-        held = lanes.count > 0
         with jax.named_scope("conv"):
             # The slot's tail is the conv's left halo: [tail | its lanes].
             tail0 = jnp.where(lanes.fresh[:, None, None], 0, tail)
@@ -427,14 +524,23 @@ def ssm_ragged(
             x, bm, cm = _split_conv(conv, cfg)  # [R, W, H, P], [R, W, G, N]
             dts, a = _dt_a(dt[lanes.idx], blk)
             dts = jnp.where(lanes.valid[..., None], dts, 0.0)
-            y, new = ssd_slab(
-                x, dts, a, bm, cm, state, 1.0 - lanes.fresh.astype(jnp.float32))
-            y = y + blk["ssm_D"].astype(jnp.float32)[:, None] * x
-            states = jax.lax.dynamic_update_index_in_dim(
-                states,
-                jnp.where(held[:, None, None, None], new, state),
-                li, axis=0,
-            )
-        # Back to the stream: lane t is its slot's q-th.
-        y = y[lanes.rid, lanes.q].reshape(h.shape[0], cfg.ssm_inner_dim)
+            if kernel:
+                y, states = ssd_slab_in_place(
+                    x, dts, a, bm, cm, states, li, lanes)
+            else:
+                state = jax.lax.dynamic_index_in_dim(
+                    states, li, axis=0, keepdims=False)
+                y, new = ssd_slab(
+                    x, dts, a, bm, cm, state,
+                    1.0 - lanes.fresh.astype(jnp.float32))
+                held = lanes.count > 0
+                states = jax.lax.dynamic_update_index_in_dim(
+                    states,
+                    jnp.where(held[:, None, None, None], new, state),
+                    li, axis=0,
+                )
+                y = _to_stream(y, lanes)
+            y = y + blk["ssm_D"].astype(jnp.float32)[:, None] * _to_stream(
+                x, lanes)
+        y = y.reshape(h.shape[0], cfg.ssm_inner_dim)
     return _out(y, z, blk, cfg), states, tails

@@ -2614,7 +2614,8 @@ def decode_step_ragged_paged(
     (layer, page): the Pallas kernel on a TPU backend, the XLA per-token
     gather elsewhere; a caller whose mesh spreads the pool or the lanes
     over more than one device passes `paged_kernel=False`, the kernel is
-    one device's program).  Dead lanes (row_of >= B, the stream's slack) drop their
+    one device's program, as is the Mamba-2 state kernel `ssm_slab_step`,
+    which takes the same choice).  Dead lanes (row_of >= B, the stream's slack) drop their
     cache writes, emit zero attention, and produce garbage logits the
     caller never reads.  The pool shape never changes during a generate
     call, so the enclosing program compiles exactly once.  A grouped MoE
@@ -2699,7 +2700,8 @@ def decode_step_ragged_paged(
         s_at, c_at = 4 + j, 4 + n_ssm + j  # behind k, v and their scales
         pools = list(pools)
         out, pools[s_at], pools[c_at] = ssm_ragged(
-            h[:, 0], blk, cfg, pools[s_at], pools[c_at], pi, lanes)
+            h[:, 0], blk, cfg, pools[s_at], pools[c_at], pi, lanes,
+            kernel=paged_kernel)
         return out[:, None], tuple(pools)
 
     def experts(h, blk, pools, li, at):

@@ -7,8 +7,11 @@ proper over one sequence of the cell's compared length: mean and max
 SERVING PLANE itself (`references.granitemoehybrid.check_generator`: 96
 requests over 64 slots) as it is — the first readings — and with the ragged
 recurrence's new state rounded to bfloat16 before it is written back (the
-control the state limit has to refuse).  Weights as the cell draws them
-(the configuration's `weights_seed`, bfloat16).
+control the state limit has to refuse): in BOTH forms of the state's part,
+the `jnp` `mamba.ssd_slab` and the Pallas kernel `ssm_slab.ssm_slab_step`
+that takes its place on a TPU backend (its buffer rounded as it comes out).
+Weights as the cell draws them (the configuration's `weights_seed`,
+bfloat16).
 
     chiprun -- python3 scripts/granite_controls.py [n_tokens]
 
@@ -25,6 +28,7 @@ import numpy as np  # noqa: E402
 
 from areal_tpu.models import mamba  # noqa: E402
 from areal_tpu.models import transformer as tfm  # noqa: E402
+from areal_tpu.ops.pallas import ssm_slab  # noqa: E402
 from benchmark import files  # noqa: E402
 from benchmark.references import granitemoehybrid as ref  # noqa: E402
 from benchmark.references.qwen3_next import state_readings  # noqa: E402
@@ -56,17 +60,21 @@ def main():
     out["serving_plane"] = {**readings, "problems": problems}
     print("serving plane", readings, problems or "inside", flush=True)
 
-    slab = mamba.ssd_slab
+    slab, step = mamba.ssd_slab, ssm_slab.ssm_slab_step
 
     def rounded(*args):
         y, new = slab(*args)
         return y, jax.lax.reduce_precision(new, 8, 7)
 
-    mamba.ssd_slab = rounded
+    def rounded_step(*args, **kw):
+        states, y = step(*args, **kw)
+        return jax.lax.reduce_precision(states, 8, 7), y
+
+    mamba.ssd_slab, ssm_slab.ssm_slab_step = rounded, rounded_step
     try:
         readings, problems = ref.check_generator(params, cfg, tokens)
     finally:
-        mamba.ssd_slab = slab
+        mamba.ssd_slab, ssm_slab.ssm_slab_step = slab, step
     out["serving_plane_state_bf16"] = {**readings, "problems": problems}
     print("serving plane, state rounded to bfloat16", readings,
           "REFUSED by" if problems else "INSIDE (the limit does not hold)",

@@ -481,6 +481,62 @@ def test_what_the_serving_plane_leaves_in_its_slots_is_the_references(
     assert any("bfloat16" in p for p in problems), problems
 
 
+def test_the_chunk_on_the_state_kernel_equals_the_chunk_on_the_jnp_form(
+        monkeypatch):
+    """Six requests over four slots (two waves: two slots serve a second
+    request from position 0), prompts in slices of W = 4, greedy: the
+    serving chunk with the state's part of the recurrence on the Pallas
+    kernel `ssm_slab_step` (interpreted; a state of 128 columns so that a
+    head's tile fits it) against the chunk on `ssd_slab` — same tokens,
+    log-probs and what every slot is left with; the kernel is what ran;
+    and the counter of (slot, inner step) pairs with a lane equals the
+    host's count of the same run."""
+    from areal_tpu.ops.pallas import ssm_slab
+
+    cfg = _cfg(ssm_state_dim=128)
+    assert ssm_slab.fits(
+        cfg.ssm_n_heads, cfg.ssm_n_groups, cfg.ssm_head_dim, cfg.ssm_state_dim)
+    params = _params(cfg, seed=8)
+    lens, new, width = (21, 9, 17, 12, 5, 14), 10, 4
+    prompts = _sequences(cfg, lens=lens, seed=13)
+    g = GenerationHyperparameters(n=1, max_new_tokens=new, greedy=True)
+    ragged, step = mamba.ssm_ragged, ssm_slab.ssm_slab_step
+    traced = []
+    monkeypatch.setattr(
+        ssm_slab, "ssm_slab_step",
+        lambda *a, **kw: traced.append(1) or step(*a, **kw))
+
+    def roll(kernel):
+        monkeypatch.setattr(
+            tfm, "ssm_ragged",
+            lambda *a, kernel_=kernel, **kw: ragged(*a, kernel=kernel_))
+        eng = _engine(cfg, params, slots=4, prefill_chunk_tokens=width)
+        out, pool, served = eng.serving_rollout(
+            prompts, g, jax.random.PRNGKey(0))
+        return out, pool, served, eng.last_pool_stats
+
+    want, want_pool, want_served, _ = roll(False)
+    assert not traced
+    got, got_pool, got_served, stats = roll(True)
+    # Once a layer a trace of the chunk's loop body.
+    assert traced and len(traced) % cfg.n_ssm_layers == 0
+    assert got_served == want_served
+    assert max(len(v) for v in got_served.values()) == 2  # a reused slot
+    for i in range(len(prompts)):
+        np.testing.assert_array_equal(got[i][0], want[i][0])
+        np.testing.assert_allclose(got[i][1], want[i][1], **TOL)
+    for a, b in zip(got_pool.state + got_pool.conv,
+                    want_pool.state + want_pool.conv):
+        np.testing.assert_allclose(a, b, **TOL)
+    # Every slot gets the lanes it wants here (4 slots x W = the stream), so
+    # a request holds a lane for ceil(prompt / W) prefill steps and one
+    # step a new token.
+    assert stats["ssm_lanes_decode"] == len(lens) * new
+    assert stats["ssm_lanes_prefill"] == sum(lens)
+    assert stats["ssm_slot_steps_live"] == sum(
+        -(-n // width) + new for n in lens)
+
+
 # ----------------------------------------------------------------- refusals
 
 
