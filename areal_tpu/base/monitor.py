@@ -53,6 +53,28 @@ def _sconv_params(cfg) -> int:
     return 4 * h * h + cfg.sconv_kernel * h
 
 
+def _lightning_params(cfg) -> int:
+    """ONE Lightning-attention layer's matmul parameters (q, k, v, the
+    gate, the output projection), its recurrence counted as the 2 * d * d
+    multiply-adds a head's state takes per token (add k^T v, read q S)."""
+    w = cfg.lightning_dim
+    return 5 * cfg.hidden_dim * w + 2 * w * cfg.lightning_head_dim
+
+
+def _sparse_attn_flops(cfg, n_tokens: int, sum_sq_seqlens: float) -> float:
+    """The score-and-value FLOPs of ONE block-sparse layer: a token's
+    SELECTED keys (topk blocks, never more than its sequence has), not all
+    of them, and its scores against one compressed key per stride."""
+    n_sparse = getattr(cfg, "n_sparse_layers", 0)
+    if not n_sparse:
+        return 0.0
+    hd = cfg.n_q_heads * cfg.head_dim
+    chosen = min(
+        sum_sq_seqlens, float(n_tokens) * cfg.sparse_topk * cfg.sparse_block_size)
+    return n_sparse * (
+        4.0 * hd * chosen + 2.0 * hd * sum_sq_seqlens / cfg.sparse_kernel_stride)
+
+
 def _attn_params(cfg) -> int:
     """Matmul parameters of ONE softmax-attention layer's projections.
     Latent attention: the two low-rank query projections, the latent and
@@ -94,7 +116,13 @@ def matmul_params(cfg) -> int:
     n_attn = _attn_layers(cfg)
     mixers = n_attn * _attn_params(cfg)
     pattern = getattr(cfg, "layer_pattern", "")
-    if pattern or getattr(cfg, "n_ssm_layers", 0):
+    if getattr(cfg, "n_sparse_layers", 0) or getattr(
+            cfg, "n_lightning_layers", 0):
+        # minicpm_sala: a block-sparse layer has a softmax layer's
+        # projections; its selected keys are `_sparse_attn_flops`'s.
+        mixers += cfg.n_sparse_layers * _attn_params(cfg)
+        mixers += cfg.n_lightning_layers * _lightning_params(cfg)
+    elif pattern or getattr(cfg, "n_ssm_layers", 0):
         # Each kind over its own layers: a pattern's ONE branch a layer,
         # or Mamba-2 mixers in two-branch layers (granitemoehybrid).
         mixers += cfg.n_ssm_layers * _ssm_params(cfg)
@@ -138,7 +166,7 @@ def flops_forward(
     if sum_sq_seqlens is None:
         sum_sq_seqlens = float(n_tokens) ** 2
     attn = 2.0 * 2.0 * cfg.n_q_heads * cfg.head_dim * sum_sq_seqlens * _attn_layers(cfg)
-    return mm + attn
+    return mm + attn + _sparse_attn_flops(cfg, n_tokens, sum_sq_seqlens)
 
 
 def flops_train(cfg, n_tokens: int, sum_sq_seqlens: Optional[float] = None) -> float:
@@ -162,6 +190,7 @@ def flops_generate(
         total += n * g
         # sum over decode steps of (p + t) ~ g*p + g^2/2
         total += attn_c * (g * p + g * g / 2.0)
+        total += _sparse_attn_flops(cfg, g, g * p + g * g / 2.0)
     return total
 
 

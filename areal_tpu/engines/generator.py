@@ -457,6 +457,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         # rows, steps] (see _fold_moe_counters).
         self._moe_decode_sums = np.zeros((_n_moe_counters(cfg),))
         self._window_live_sums = np.zeros((2,))
+        self._sparse_sums = np.zeros((3,))
         # Serving-plane chunk counters of the current generate() call
         # (see _serving_counters); folded into last_pool_stats at its end.
         self._chunk_stats: Dict[str, Any] = _new_chunk_stats()
@@ -777,6 +778,10 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         self.last_pool_stats = {}
         self._moe_decode_sums = np.zeros((_n_moe_counters(self.cfg),))
         self._window_live_sums = np.zeros((2,))
+        # Block-sparse layers, summed over a call's decode iterations and
+        # layers: keys read (chosen blocks x block + compressed rows, a key
+        # head's), keys cached, rows still under `sparse_dense_len`.
+        self._sparse_sums = np.zeros((3,))
         self.lanes_dispatched = 0
         self.lanes_live = 0
         self.lanes_slack = 0
@@ -2453,10 +2458,16 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                 ),
                 window_slots=b * cache.wk.shape[2],
             )
-        if cache.state is not None:  # the two kinds of state
-            self.last_pool_stats.update(
+        if cache.state is not None and cache.conv is not None:
+            self.last_pool_stats.update(  # the two kinds of state
                 kv_cache_bytes=nbytes(cache.k, cache.v),
                 state_cache_bytes=nbytes(cache.state, cache.conv),
+            )
+        if cache.ck is not None:  # k/v, compressed keys, Lightning state
+            self.last_pool_stats.update(
+                kv_cache_bytes=nbytes(cache.k, cache.v),
+                compressed_cache_bytes=nbytes(cache.ck),
+                lightning_state_bytes=nbytes(cache.state),
             )
         if self.cfg.n_sconv_layers:  # tails beside the attention layers' k/v
             cfg = self.cfg
@@ -2489,6 +2500,14 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                     to_host(logps),
                     to_host(gen_len),
                 )
+                if self.cfg.n_sparse_layers:  # [read, cached, dense rows]
+                    self._sparse_sums += to_host(rest.pop()).astype(float)
+                    read, cached, dense = self._sparse_sums
+                    self.last_pool_stats.update(
+                        sparse_keys_read=float(read),
+                        sparse_keys_cached=float(cached),
+                        sparse_dense_rows=float(dense),
+                    )
                 if self.cfg.n_window_layers:  # [live ring entries, steps]
                     live, steps = to_host(rest.pop()).astype(float)
                     self._window_live_sums += (live, steps)
@@ -2552,6 +2571,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             def body(state):
                 (step, logits, key, done, gen_len, out_toks, out_logps,
                  cache, *more) = state
+                sparse = more.pop() if cfg.n_sparse_layers else None
                 ring_live = more.pop() if cfg.n_window_layers else None
                 moe = more
                 key, sub = jax.random.split(key)
@@ -2577,7 +2597,11 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                     params, cfg, tok, pos, cache, sp + step, valid_from,
                     with_moe_counts=cfg.is_moe, experts_in_place=in_place,
                     row_kernel=row_kernel, expert_kernel=expert_kernel,
+                    with_sparse_counts=cfg.n_sparse_layers > 0,
                 )
+                if sparse is not None:  # [layers, 3] of this iteration
+                    sparse = sparse + jnp.sum(
+                        counts.pop().reshape(-1, 3), axis=0)
                 if cfg.is_moe:
                     moe = [moe[0] + _moe_step_counters(counts[0], cfg, bsz)]
                 if ring_live is not None:
@@ -2587,6 +2611,8 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                         sp + step, valid_from, cache.wk.shape[2])
                     moe = [*moe, ring_live + jnp.stack(
                         [jnp.sum(live).astype(jnp.float32), jnp.float32(1.0)])]
+                if sparse is not None:
+                    moe = [*moe, sparse]
                 return (
                     step + 1, next_logits, key, new_done, gen_len,
                     out_toks, out_logps, cache, *moe,
@@ -2597,6 +2623,8 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                 state += (jnp.zeros((_n_moe_counters(cfg),), jnp.float32),)
             if cfg.n_window_layers:  # live ring entries + the steps
                 state += (jnp.zeros((2,), jnp.float32),)
+            if cfg.n_sparse_layers:  # keys read, keys cached, dense rows
+                state += (jnp.zeros((3,), jnp.float32),)
             state = jax.lax.while_loop(cond, body, state)
             _, _, _, _, gen_len, out_toks, out_logps, cache, *moe = state
             # `with_cache`: what the loop leaves in the cache, last.

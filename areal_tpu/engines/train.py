@@ -380,16 +380,24 @@ class TrainEngine(HostOffloadMixin, Engine):
 
     # ---------------- core jitted fns ----------------
 
-    def _pack_row_chunks(self, arrays):
-        """1f1b-mem schedule: cap rows per jitted step at batch_shard
-        (= batch_axes x P, i.e. exactly P in-flight microbatches of
-        minimal size) so peak activation memory per step sits at the 1F1B
-        bound; the surrounding grad-accumulation loop supplies the
-        amortization GPipe gets from 4P in-flight microbatches."""
-        if self.pipe_schedule != "1f1b-mem" or self._pp_mesh is None:
+    def _pack_row_chunks(self, arrays, max_tokens: Optional[int] = None):
+        """Rows per jitted step, capped at batch_shard in two cases.
+        1f1b-mem schedule (batch_axes x P, i.e. exactly P in-flight
+        microbatches of minimal size): peak activation memory per step sits
+        at the 1F1B bound; the surrounding grad-accumulation loop supplies
+        the amortization GPipe gets from 4P in-flight microbatches.  And a
+        micro-batch whose rows are LONGER than `max_tokens` (the plan's
+        tokens a micro-batch: `pack_sample` widens a row to the longest
+        sequence, and a sample's group of sequences stays one micro-batch,
+        so a group of four 13 k-token sequences is four rows of 13,312): a
+        step then takes one row a device, and the step's tokens stay near
+        the bound the plan set (the same loop accumulates)."""
+        b, row_len = next(iter(arrays.values())).shape[:2]
+        long_rows = bool(max_tokens) and row_len > max_tokens
+        pipelined = self.pipe_schedule == "1f1b-mem" and self._pp_mesh is not None
+        if not (long_rows or pipelined):
             return [arrays]
         cap = self.batch_shard
-        b = next(iter(arrays.values())).shape[0]
         if b <= cap:
             return [arrays]
         return [
@@ -707,7 +715,8 @@ class TrainEngine(HostOffloadMixin, Engine):
                 c
                 for pk in packs
                 for c in (
-                    [pk.arrays] if sharded else self._pack_row_chunks(pk.arrays)
+                    [pk.arrays] if sharded else self._pack_row_chunks(
+                        pk.arrays, mb_spec.max_tokens_per_mb)
                 )
             ]
             total_weight = float(sum(loss_weight_fn(c) for c in chunks))
@@ -882,7 +891,8 @@ class TrainEngine(HostOffloadMixin, Engine):
                 for mb, _ in sharded_mbs
             ]
             chunks = [
-                c for pk in packs for c in self._pack_row_chunks(pk.arrays)
+                c for pk in packs for c in self._pack_row_chunks(
+                    pk.arrays, mb_spec.max_tokens_per_mb)
             ]
             chunk_weight = float(sum(loss_weight_fn(c) for c in chunks))
             for k, v in _grid_counts(chunks, self._flash_window).items():

@@ -22,14 +22,20 @@ FROZEN_LEAVES = ("router_bias",)
 # The residual branches x += f(norm(x)) a layer is made of: softmax attention
 # over per-head K/V, the same over the last `attn_window` keys alone, latent
 # attention over one row a token, Gated DeltaNet, Mamba-2, the gated short
-# convolution, a dense MLP, the mixture of experts.
+# convolution, a dense MLP, the mixture of experts; softmax attention that
+# reads the blocks of keys a query SELECTS (InfLLM-V2) and Lightning linear
+# attention, a recurrence with one constant decay a head (minicpm_sala).
 ATTENTION, WINDOW, LATENT, GDN, SSM, SCONV, MLP, MOE = (
     "attention", "window", "latent", "gdn", "ssm", "sconv", "mlp", "moe",
 )
+SPARSE, LIGHTNING = "sparse", "lightning"
 # One character of `layer_pattern` -> that layer's ONE branch.
 _PATTERN_KINDS = {"M": (SSM,), "E": (MOE,), "*": (ATTENTION,)}
 # One character of `window_pattern` -> that layer's mixer.
-_WINDOW_KINDS = {"S": WINDOW, "F": ATTENTION, "C": SCONV, "M": SSM}
+_WINDOW_KINDS = {
+    "S": WINDOW, "F": ATTENTION, "C": SCONV, "M": SSM, "B": SPARSE,
+    "L": LIGHTNING,
+}
 
 LayerKind = Tuple[str, ...]  # a layer's branches, in order
 
@@ -228,7 +234,9 @@ class ModelConfig:
     # `sconv_kernel` - 1 gated inputs); "M" a Mamba-2 mixer (`ssm_*`, as in
     # a one-branch pattern, here with an MLP behind it: granitemoehybrid).
     # Every layer has an MLP (or the experts) behind its mixer; "S" and
-    # "F" have the same leaves.  The
+    # "F" have the same leaves; "B" block-sparse softmax attention (the
+    # `sparse_*` sizes; a full layer's leaves) and "L" Lightning linear
+    # attention (`lightning_*`), minicpm_sala's two mixers.  The
     # first `first_k_dense` characters are the leading dense layers'
     # mixers; the stack is scanned by repeats of the smallest unit of the
     # rest.  "" = every attention layer is full.
@@ -258,6 +266,29 @@ class ModelConfig:
     residual_multiplier: float = 1.0
     attention_multiplier: float = 0.0
     logits_scaling: float = 1.0
+    # ---- block-sparse attention (InfLLM-V2: minicpm4, minicpm_sala "B") ----
+    # A sequence of at least `sparse_dense_len` tokens: a query attends over
+    # `sparse_topk` blocks of `sparse_block_size` keys — the sequence's
+    # first `sparse_init_blocks`, the `sparse_window` / block_size ending at
+    # its own, and the highest by score against COMPRESSED keys (the mean
+    # of `sparse_kernel_size` keys every `sparse_kernel_stride`; a block's
+    # score the largest, summed over a key head's query heads, of the
+    # kernels that overlap it).  Shorter sequences: plain causal attention.
+    sparse_kernel_size: int = 32
+    sparse_kernel_stride: int = 16
+    sparse_block_size: int = 64
+    sparse_topk: int = 64
+    sparse_init_blocks: int = 1
+    sparse_window: int = 2048
+    sparse_dense_len: int = 8192
+    # ---- Lightning attention (minicpm_sala "L") ----
+    # `lightning_n_heads` heads of `lightning_head_dim`, q and k normed per
+    # head and roped (the ONLY positions of a plan whose `pos_emb` is
+    # "none"), S_t = lambda_h S_{t-1} + k_t^T v_t in fp32 with lambda_h =
+    # exp(-2 ** (-8 (h + 1) / H)), y_t = q_t S_t / sqrt(d), an RMSNorm per
+    # head over y and a sigmoid output gate.
+    lightning_n_heads: int = 0
+    lightning_head_dim: int = 0
 
     def __post_init__(self):
         # The checks read the fields as given: `plan` is for what passed.
@@ -360,8 +391,11 @@ class ModelConfig:
             raise ValueError(
                 f"window_pattern {pattern!r} is not {self.n_layers} "
                 "characters of 'S' (sliding window), 'F' (full attention), "
-                "'C' (gated short convolution), 'M' (Mamba-2)"
+                "'C' (gated short convolution), 'M' (Mamba-2), 'B' (block-"
+                "sparse attention), 'L' (Lightning attention)"
             )
+        if "B" in pattern or "L" in pattern:
+            self._check_sala()
         if "S" in pattern and self.attn_window < 1:
             raise ValueError("an 'S' layer needs attn_window >= 1")
         if "C" in pattern and self.sconv_kernel < 2:
@@ -376,17 +410,53 @@ class ModelConfig:
                 )
         if (
             self.layer_pattern or self.full_attn_interval > 1
-            or self.is_latent or self.attn_gate
+            or self.is_latent or (self.attn_gate and "B" not in pattern)
         ):
             raise NotImplementedError(
                 "a pattern of mixers stands beside plain softmax-attention "
                 "layers only: no one-branch pattern, Gated DeltaNet layers, "
-                "latent attention or output gate"
+                "latent attention or output gate (but a block-sparse "
+                "layer's)"
             )
         if "S" in pattern[:self.first_k_dense]:
             raise NotImplementedError(
                 f"window_pattern {pattern!r}: a leading dense layer's mixer "
                 "is 'F' or 'C' (a ring before the scan was not tested)"
+            )
+
+    def _check_sala(self):
+        pattern = self.window_pattern
+        if set(pattern) - set("BL") or self.first_k_dense or self.is_moe:
+            raise NotImplementedError(
+                f"window_pattern {pattern!r}: block-sparse ('B') and "
+                "Lightning ('L') layers stand beside each other alone, a "
+                "dense MLP behind each"
+            )
+        if "L" in pattern and not (
+            self.lightning_n_heads and self.lightning_head_dim == self.head_dim
+            and self.qk_norm and self.qk_norm_per_head
+        ):
+            raise NotImplementedError(
+                "an 'L' layer needs lightning_n_heads heads as wide as the "
+                f"attention heads ({self.lightning_head_dim} against "
+                f"{self.head_dim}: one rotary table) and the per-head q/k "
+                "norm"
+            )
+        ks, st, bs = (self.sparse_kernel_size, self.sparse_kernel_stride,
+                      self.sparse_block_size)
+        if "B" in pattern and not (
+            ks == 2 * st and bs == 4 * st and self.sparse_window % bs == 0
+            and self.sparse_init_blocks >= 0
+            and self.sparse_topk >= (
+                self.sparse_init_blocks + self.sparse_window // bs)
+            and self.sparse_dense_len >= ks
+        ):
+            raise NotImplementedError(
+                f"block selection with kernel {ks} / stride {st} / block {bs}"
+                f" / window {self.sparse_window} / topk {self.sparse_topk}: "
+                "the kernels are two strides, a block four (a max-pool of 5 "
+                "by 4), the window whole blocks and the forced blocks within "
+                "topk"
             )
 
     @property
@@ -453,6 +523,19 @@ class ModelConfig:
         return self.plan.count(SSM)
 
     @property
+    def n_sparse_layers(self) -> int:
+        """Layers that keep k/v AND compressed keys (block selection)."""
+        return self.plan.count(SPARSE)
+
+    @property
+    def n_lightning_layers(self) -> int:
+        return self.plan.count(LIGHTNING)
+
+    @property
+    def lightning_dim(self) -> int:
+        return self.lightning_n_heads * self.lightning_head_dim
+
+    @property
     def n_sconv_layers(self) -> int:
         """Layers whose cache is the short convolution's last inputs."""
         return self.plan.count(SCONV)
@@ -473,8 +556,9 @@ class ModelConfig:
         """Whether some layer carries a state from token to token (Gated
         DeltaNet, Mamba-2): no split over `model`, `seq` or `pipe` yet; on
         the serving plane a slot beside the page pool for Mamba-2 in
-        two-branch layers alone (`transformer.plan_refusal`)."""
-        return self.plan.count(GDN, SSM) > 0
+        two-branch layers alone (`transformer.plan_refusal`).  Lightning
+        attention's constant-decay state counts."""
+        return self.plan.count(GDN, SSM, LIGHTNING) > 0
 
     @property
     def attn_scale(self) -> float:

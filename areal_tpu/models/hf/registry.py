@@ -1730,6 +1730,252 @@ register_hf_family(
 )
 
 
+# ---------------- minicpm_sala ----------------
+# openbmb/MiniCPM-SALA: `mixer_types` gives every layer its mixer behind
+# `input_layernorm` — "minicpm4", softmax attention WITHOUT positions
+# (`attn_use_rope: false`) over the blocks of keys a query selects
+# (InfLLM-V2: `ops/block_sparse.py`; the selection's sizes are a
+# `sparse_config` group, MiniCPM4's where the published file has none) with
+# a per-head q/k RMSNorm and a sigmoid output gate (`o_gate`), or
+# "lightning-attn", Lightning linear attention with rope (`models/
+# lightning.py`) — and a dense SwiGLU MLP behind `post_attention_layernorm`.
+# muP: the embedding times `scale_emb`, every residual add times
+# `scale_depth / sqrt(mup_denominator)` (the PUBLISHED depth, 32: a cut in
+# depth keeps it), the final hidden state divided by `hidden_size /
+# dim_model_base` before the head — `logits_scaling`, the same product in
+# another order.  The tensor names are assumed (no network to re-read the
+# module; `benchmark/configs/minicpm-sala-l4-v8.json`, `assumed`).
+
+_SALA_MIXERS = {"minicpm4": "B", "lightning-attn": "L"}
+_SALA_SPARSE = {  # MiniCPM4 / MiniCPM4.1's published `sparse_config`
+    "kernel_size": 32, "kernel_stride": 16, "block_size": 64, "topk": 64,
+    "init_blocks": 1, "window_size": 2048, "dense_len": 8192,
+}
+
+
+def _sala_config_from_hf(hf: dict) -> ModelConfig:
+    for key, fine in (
+        ("attention_bias", False), ("hidden_act", "silu"),
+        ("attn_use_rope", False), ("lightning_use_rope", True),
+        ("qk_norm", True), ("use_output_gate", True),
+        ("use_output_norm", True), ("attn_use_output_gate", True),
+        ("lightning_scale", "1/sqrt(d)"),
+    ):
+        if hf.get(key, fine) != fine:
+            raise NotImplementedError(
+                f"minicpm_sala {key}={hf[key]!r} is not modelled"
+            )
+    types = hf["mixer_types"]
+    if len(types) != hf["num_hidden_layers"] or set(types) - set(_SALA_MIXERS):
+        raise ValueError(
+            f"mixer_types {types!r} is not {hf['num_hidden_layers']} of "
+            f"{sorted(_SALA_MIXERS)}"
+        )
+    if hf.get("lightning_nkv", hf["lightning_nh"]) != hf["lightning_nh"]:
+        raise NotImplementedError(
+            f"minicpm_sala lightning_nkv={hf['lightning_nkv']} of "
+            f"lightning_nh={hf['lightning_nh']}: grouped Lightning heads "
+            "are not modelled"
+        )
+    sparse = {**_SALA_SPARSE, **hf.get("sparse_config", {})}
+    return ModelConfig(
+        n_layers=hf["num_hidden_layers"],
+        hidden_dim=hf["hidden_size"],
+        n_q_heads=hf["num_attention_heads"],
+        n_kv_heads=hf["num_key_value_heads"],
+        head_dim=hf.get(
+            "head_dim", hf["hidden_size"] // hf["num_attention_heads"]),
+        intermediate_dim=hf["intermediate_size"],
+        vocab_size=hf["vocab_size"],
+        max_position_embeddings=hf.get("max_position_embeddings", 524288),
+        rope_theta=hf.get("rope_theta", 10000.0),
+        rms_norm_eps=hf.get("rms_norm_eps", 1e-6),
+        tied_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        pos_emb="none",  # the attention layers'; Lightning ropes itself
+        qk_norm=True,
+        qk_norm_per_head=True,
+        attn_gate=True,
+        window_pattern="".join(_SALA_MIXERS[t] for t in types),
+        lightning_n_heads=hf["lightning_nh"],
+        lightning_head_dim=hf["lightning_head_dim"],
+        sparse_kernel_size=sparse["kernel_size"],
+        sparse_kernel_stride=sparse["kernel_stride"],
+        sparse_block_size=sparse["block_size"],
+        sparse_topk=sparse["topk"],
+        sparse_init_blocks=sparse["init_blocks"],
+        sparse_window=sparse["window_size"],
+        sparse_dense_len=sparse["dense_len"],
+        embedding_multiplier=float(hf.get("scale_emb", 1.0)),
+        residual_multiplier=float(
+            hf.get("scale_depth", 1.0)
+            / hf.get("mup_denominator", hf["num_hidden_layers"]) ** 0.5),
+        logits_scaling=float(
+            hf["hidden_size"] / hf.get("dim_model_base", hf["hidden_size"])),
+    )
+
+
+def _sala_config_to_hf(cfg: ModelConfig) -> dict:
+    kinds = {v: k for k, v in _SALA_MIXERS.items()}
+    base = 256  # dim_model_base: hidden_size / logits_scaling
+    if cfg.logits_scaling != 1.0:
+        base = round(cfg.hidden_dim / cfg.logits_scaling)
+    # scale_depth / sqrt(mup_denominator) is ONE number here: written back
+    # over the published denominator, 32.
+    denominator = 32
+    return {
+        "model_type": "minicpm_sala",
+        "architectures": ["MiniCPMSALAForCausalLM"],
+        "torch_dtype": "bfloat16",
+        "num_hidden_layers": cfg.n_layers,
+        "mixer_types": [kinds[c] for c in cfg.window_pattern],
+        "hidden_size": cfg.hidden_dim,
+        "num_attention_heads": cfg.n_q_heads,
+        "num_key_value_heads": cfg.n_kv_heads,
+        "head_dim": cfg.head_dim,
+        "intermediate_size": cfg.intermediate_dim,
+        "vocab_size": cfg.vocab_size,
+        "max_position_embeddings": cfg.max_position_embeddings,
+        "rope_theta": cfg.rope_theta,
+        "rms_norm_eps": cfg.rms_norm_eps,
+        "tie_word_embeddings": cfg.tied_embeddings,
+        "attention_bias": False,
+        "hidden_act": "silu",
+        "attn_use_rope": False,
+        "lightning_use_rope": True,
+        "lightning_nh": cfg.lightning_n_heads,
+        "lightning_nkv": cfg.lightning_n_heads,
+        "lightning_head_dim": cfg.lightning_head_dim,
+        "lightning_scale": "1/sqrt(d)",
+        "qk_norm": True,
+        "use_output_gate": True,
+        "use_output_norm": True,
+        "attn_use_output_gate": True,
+        "scale_emb": cfg.embedding_multiplier,
+        "scale_depth": cfg.residual_multiplier * denominator**0.5,
+        # arealint: ignore[stats-keys] -- a published config key, no stat
+        "mup_denominator": denominator,
+        "dim_model_base": base,
+        "sparse_config": {
+            "kernel_size": cfg.sparse_kernel_size,
+            "kernel_stride": cfg.sparse_kernel_stride,
+            "block_size": cfg.sparse_block_size,
+            "topk": cfg.sparse_topk,
+            "init_blocks": cfg.sparse_init_blocks,
+            "window_size": cfg.sparse_window,
+            "dense_len": cfg.sparse_dense_len,
+        },
+    }
+
+
+_SALA = "model.layers.{}."
+# A mixer's leaves: ours <- the name behind the layer's prefix, transposed
+# ([out, in] -> [in, out]) where a matrix.
+_SALA_MIXER = {
+    "B": (
+        ("wq", "self_attn.q_proj.weight", True),
+        ("wk", "self_attn.k_proj.weight", True),
+        ("wv", "self_attn.v_proj.weight", True),
+        ("wo", "self_attn.o_proj.weight", True),
+        ("wqg", "self_attn.o_gate.weight", True),
+        ("q_norm", "self_attn.q_norm.weight", False),
+        ("k_norm", "self_attn.k_norm.weight", False),
+    ),
+    "L": (
+        ("lt_wq", "self_attn.q_proj.weight", True),
+        ("lt_wk", "self_attn.k_proj.weight", True),
+        ("lt_wv", "self_attn.v_proj.weight", True),
+        ("lt_wo", "self_attn.o_proj.weight", True),
+        ("lt_wg", "self_attn.z_proj.weight", True),
+        ("lt_q_norm", "self_attn.q_norm.weight", False),
+        ("lt_k_norm", "self_attn.k_norm.weight", False),
+        ("lt_norm", "self_attn.o_norm.weight", False),
+    ),
+}
+_SALA_EVERY = (
+    ("ln1", "input_layernorm.weight", False),
+    ("ln2", "post_attention_layernorm.weight", False),
+    ("wg", "mlp.gate_proj.weight", True),
+    ("wu", "mlp.up_proj.weight", True),
+    ("wd", "mlp.down_proj.weight", True),
+)
+
+
+def _sala_layers(cfg, kind):
+    return [i for i, c in enumerate(cfg.window_pattern) if c == kind]
+
+
+def _sala_params_from_sd(cfg, sd, dtype=None):
+    import jax.numpy as jnp
+
+    dtype = dtype or cfg.dtype
+
+    def get(name):
+        if name not in sd:
+            raise KeyError(f"missing tensor {name!r} in checkpoint")
+        return np.asarray(sd[name], np.float32)
+
+    def stack(layers, theirs, t):
+        return jnp.asarray(np.stack([
+            get(_SALA.format(i) + theirs).T if t
+            else get(_SALA.format(i) + theirs) for i in layers
+        ]), dtype)
+
+    blocks = {
+        ours: stack(range(cfg.n_layers), theirs, t)
+        for ours, theirs, t in _SALA_EVERY
+    }
+    for kind, leaves in _SALA_MIXER.items():
+        layers = _sala_layers(cfg, kind)
+        for ours, theirs, t in leaves if layers else ():
+            blocks[ours] = stack(layers, theirs, t)
+    params = {
+        "embed": jnp.asarray(get("model.embed_tokens.weight"), dtype),
+        "blocks": blocks,
+        "final_ln": jnp.asarray(get("model.norm.weight"), dtype),
+    }
+    if not cfg.tied_embeddings:
+        params["lm_head"] = jnp.asarray(get("lm_head.weight").T, dtype)
+    return params
+
+
+def _sala_params_to_sd(cfg, params):
+    from areal_tpu.base.distributed import to_host
+
+    def host(x):
+        return to_host(x).astype(np.float32, copy=False)
+
+    def put(out, name, w, t):
+        out[name] = np.ascontiguousarray(w.T) if t else w
+
+    blocks = {n: host(w) for n, w in params["blocks"].items()}
+    out = {
+        "model.embed_tokens.weight": host(params["embed"]),
+        "model.norm.weight": host(params["final_ln"]),
+    }
+    if not cfg.tied_embeddings:
+        out["lm_head.weight"] = np.ascontiguousarray(
+            host(params["lm_head"]).T)
+    for i in range(cfg.n_layers):
+        for ours, theirs, t in _SALA_EVERY:
+            put(out, _SALA.format(i) + theirs, blocks[ours][i], t)
+    for kind, leaves in _SALA_MIXER.items():
+        for j, i in enumerate(_sala_layers(cfg, kind)):
+            for ours, theirs, t in leaves:
+                put(out, _SALA.format(i) + theirs, blocks[ours][j], t)
+    return out
+
+
+register_hf_family(
+    HFFamily(
+        "minicpm_sala",
+        _sala_config_from_hf,
+        _sala_config_to_hf,
+        params_from_sd=_sala_params_from_sd,
+        params_to_sd=_sala_params_to_sd,
+    )
+)
+
+
 # ---------------- lfm2_moe ----------------
 # LiquidAI/LFM2-8B-A1B: `layer_types` gives every layer its mixer behind
 # `operator_norm` — "conv", the gated short convolution (`conv.in_proj` ->
@@ -2112,6 +2358,8 @@ def infer_model_type(cfg: ModelConfig) -> str:
         return "gpt2"
     if cfg.is_hybrid:
         return "qwen3_next"
+    if cfg.n_sparse_layers or cfg.n_lightning_layers:
+        return "minicpm_sala"
     if cfg.is_pattern:
         return "nemotron_h"
     if cfg.is_latent:

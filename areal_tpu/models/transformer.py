@@ -49,9 +49,11 @@ from areal_tpu.models.config import (
     DENSE_PREFIX,
     GDN,
     LATENT,
+    LIGHTNING,
     MLP,
     MOE,
     SCONV,
+    SPARSE,
     SSM,
     WINDOW,
     LayerKind,
@@ -62,6 +64,12 @@ from areal_tpu.models.linear_attention import (
     init_linear_attn,
     linear_attn_forward,
     linear_attn_step,
+)
+from areal_tpu.models.lightning import (
+    LIGHTNING_LEAVES,
+    init_lightning,
+    lightning_forward,
+    lightning_step,
 )
 from areal_tpu.models.mamba import (
     SSM_LEAVES,
@@ -77,6 +85,7 @@ from areal_tpu.models.short_conv import (
     sconv_forward,
     sconv_step,
 )
+from areal_tpu.ops import block_sparse
 from areal_tpu.ops.attention import (
     decode_attention,
     inner_scope,
@@ -158,7 +167,7 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     LM = scanned(MOE)
     blocks = {
         "ln1": norm_init((L, D), dtype),
-        **attn_leaves(scanned(ATTENTION, WINDOW, LATENT), ks),
+        **attn_leaves(scanned(ATTENTION, WINDOW, LATENT, SPARSE), ks),
     }
     if not cfg.is_pattern:  # a second branch a layer: a second norm
         blocks["ln2"] = norm_init((L, D), dtype)
@@ -168,6 +177,8 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         blocks.update(init_linear_attn(cfg, ks[6], scanned(GDN), dense))
     if scanned(SCONV):
         blocks.update(init_sconv(cfg, ks[6], scanned(SCONV), dense))
+    if scanned(LIGHTNING):
+        blocks.update(init_lightning(cfg, ks[6], scanned(LIGHTNING), dense))
     if plan.prefix:
         # The leading dense layers: their own leaves, stacked over the
         # leading layers that own them under `dense_*`, the mixers the
@@ -1165,6 +1176,7 @@ def _packed_branches(
     cfg: ModelConfig, segment_ids: jax.Array, *attn_args, with_state=False,
     window_rope=None, ring: Optional[int] = None,
     expert_kernel: Optional[bool] = False, row_kernel=None,
+    ck_slots: Optional[int] = None,
 ):
     """The table of the programs over packed rows (the train stack,
     `prefill`): branch -> f(h, blk) -> (its output, what else it gives by
@@ -1180,7 +1192,9 @@ def _packed_branches(
     the grouped dispatch's matmuls are the Pallas kernel `grouped_matmul`
     (`expert_kernel_choice`).  `row_kernel`: the form of the Gated DeltaNet's
     chunked rule (`linear_attn_forward`'s `kernel`: None, by what the code
-    can see; the caller's MESH where it has more than one device)."""
+    can see; the caller's MESH where it has more than one device).
+    `ck_slots`: the kernels a row of the cache's compressed keys holds,
+    where the caller keeps what a block-sparse layer leaves."""
     kernel = cfg.is_moe and expert_kernel_choice(cfg, expert_kernel)
 
     def recurrent(forward):
@@ -1223,7 +1237,34 @@ def _packed_branches(
         out, tail = sconv_forward(h, blk, cfg, segment_ids, with_state=True)
         return out, {"conv": tail}
 
+    def sparse(h, blk):
+        """Softmax attention by block selection (`ops/block_sparse.py`):
+        no positions, the sigmoid output gate; it leaves k, v and, for a
+        cache, the compressed keys of the row's sequence."""
+        b, s, _ = h.shape
+        q, k, v = _block_kv(h, blk, cfg, *attn_args[:2])
+        sizes = block_sparse.Sizes.of(cfg)
+        attn, kc, knum = block_sparse.packed_attention(
+            q, k, v, segment_ids, sizes)
+        out = _attn_out(
+            attn.reshape(b, s, cfg.q_dim), blk, cfg, _attn_gate(h, blk, cfg))
+        left = {"k": k, "v": v}
+        if ck_slots is not None:
+            left["ck"] = block_sparse.compressed_of_last(
+                kc, knum, segment_ids, sizes, ck_slots)
+        return out, left
+
+    def lightning(h, blk):
+        if not with_state:
+            return lightning_forward(
+                h, blk, cfg, segment_ids, *attn_args[:2]), {}
+        out, state = lightning_forward(
+            h, blk, cfg, segment_ids, *attn_args[:2], with_state=True)
+        return out, {"state": state}
+
     return {
+        SPARSE: sparse,
+        LIGHTNING: lightning,
         ATTENTION: attention,
         WINDOW: window,
         LATENT: attention,
@@ -1270,6 +1311,40 @@ def _layer_forward(
     x, gave = _packed_layer(cfg, branches, kind, x, blk, named=True)
     aux = gave["aux"] if "aux" in gave else jnp.zeros((), jnp.float32)
     return x, aux, gave.get("counts")
+
+
+# Mixers whose layers are rematerialised a BRANCH at a time: at rows of 13 k
+# tokens a Lightning or block-sparse mixer's residuals (2.5 GB: the chunked
+# recurrence's fp32 blocks) and the MLP's (1.5 GB at a width of 16,384) do
+# not fit beside the state TOGETHER; a branch at a time the backward holds
+# one of them, for one more saved [B, S, D] a layer and no more recompute.
+_REMAT_BY_BRANCH = frozenset({SPARSE, LIGHTNING})
+
+
+def _branch_remat_layer(cfg: ModelConfig, branches, kind: LayerKind, remat):
+    """`_layer_forward` with every branch x += f(norm(x)) under the remat
+    policy on its own (`_remat_layer`) instead of the layer whole."""
+
+    def branch_step(branch, ln):
+        def step(x, blk):
+            h = _norm(x, blk[ln], blk.get(ln + "_b"), cfg)
+            out, more = branches[branch](h, blk)
+            out = checkpoint_name(out, _SAVED_AS.get(branch, "attn_out"))
+            return _residual(x, out, cfg), more
+
+        return _remat_layer(step, remat)
+
+    steps = [branch_step(b, ln) for b, ln in zip(kind, _BRANCH_NORMS)]
+
+    def layer(x, blk):
+        gave = {}
+        for step in steps:
+            x, more = step(x, blk)
+            gave.update(more)
+        aux = gave["aux"] if "aux" in gave else jnp.zeros((), jnp.float32)
+        return x, aux, gave.get("counts")
+
+    return layer
 
 
 def _block_forward(
@@ -1502,6 +1577,24 @@ _NO_SSM_LAYOUT = (
 )
 
 
+_NO_SALA_LAYOUT = (
+    "block-sparse attention beside Lightning attention (minicpm_sala) runs "
+    "under data and fsdp sharding only: the selection's compressed keys and "
+    "the Lightning state are not split over `model`, neither the selection "
+    "nor the chunked recurrence has a ring over a split sequence, and the "
+    "pipeline's stage scans one kind of layer (PERF.md section 7)"
+)
+
+
+_NO_SERVING_SALA = (
+    "block-sparse attention beside Lightning attention (minicpm_sala) "
+    "generates on the static decode program only: the serving plane's "
+    "ragged paged attention has no selection (compressed keys beside the "
+    "pages, chosen pages a lane), and a Lightning layer's state has no slot "
+    "beside the pool yet (PERF.md section 7)"
+)
+
+
 def plan_refusal(cfg: ModelConfig, serving: bool):
     """What of `cfg.plan` the serving plane (`serving`; its chunk
     `decode_step_ragged_paged` walks a plan of two-branch layers whose
@@ -1511,6 +1604,9 @@ def plan_refusal(cfg: ModelConfig, serving: bool):
     name, or None for a plan both can: every refusal of a plane or a
     layout asks here."""
     plan = cfg.plan
+    if plan.count(SPARSE, LIGHTNING):
+        return HybridLayoutError(
+            _NO_SERVING_SALA if serving else _NO_SALA_LAYOUT)
     if plan.count(GDN):
         return HybridLayoutError(
             _NO_SERVING_STATE if serving else _NO_HYBRID_LAYOUT)
@@ -1549,7 +1645,9 @@ _MOE_LEAVES = (
 )
 _LEAF_BRANCHES = {
     **dict.fromkeys(
-        _FULL_ATTN_LEAVES + _LATENT_LEAVES, (ATTENTION, WINDOW, LATENT)),
+        _FULL_ATTN_LEAVES + _LATENT_LEAVES,
+        (ATTENTION, WINDOW, LATENT, SPARSE)),
+    **dict.fromkeys(LIGHTNING_LEAVES, (LIGHTNING,)),
     **dict.fromkeys(LINEAR_LEAVES, (GDN,)),
     **dict.fromkeys(SSM_LEAVES, (SSM,)),
     **dict.fromkeys(SCONV_LEAVES, (SCONV,)),
@@ -1657,7 +1755,8 @@ def _blocks(
         row_kernel=row_kernel,
     )
     layers = {
-        kind: _remat_layer(
+        kind: _branch_remat_layer(cfg, branches, kind, remat)
+        if set(kind) & _REMAT_BY_BRANCH else _remat_layer(
             functools.partial(_layer_forward, cfg, branches, kind), remat
         )
         for kind in plan.kinds
@@ -1842,7 +1941,14 @@ class KVCache:
       head_dim], ring = min(attn_window, S_max): slot s of the row lies at
       entry s mod ring, so the ring holds the last `ring` slots written
       and nothing older — what a window layer can still see
-      (`ring_valid`)."""
+      (`ring_valid`);
+    - block-sparse attention: `k` / `v` as softmax attention's and `ck`
+      [layers, B, S_max / stride, n_kv, head_dim], the COMPRESSED keys the
+      selection scores against, one row a `sparse_kernel_stride` tokens by
+      kernel number within the row's sequence, appended as kernels
+      complete (`block_sparse.compressed_step`);
+    - Lightning attention: `state` [layers, B, H, d, d] in fp32 and no
+      `conv`."""
 
     k: Optional[jax.Array]
     v: Optional[jax.Array]
@@ -1851,6 +1957,7 @@ class KVCache:
     latent: Optional[jax.Array] = None
     wk: Optional[jax.Array] = None
     wv: Optional[jax.Array] = None
+    ck: Optional[jax.Array] = None
 
     @property
     def s_max(self) -> int:
@@ -1859,13 +1966,14 @@ class KVCache:
 
 # The cache's populations: field -> the branches that keep it.
 _CACHE_FIELDS = {
-    "k": (ATTENTION,),
-    "v": (ATTENTION,),
-    "state": (GDN, SSM),
+    "k": (ATTENTION, SPARSE),
+    "v": (ATTENTION, SPARSE),
+    "state": (GDN, SSM, LIGHTNING),
     "conv": (GDN, SSM, SCONV),
     "latent": (LATENT,),
     "wk": (WINDOW,),
     "wv": (WINDOW,),
+    "ck": (SPARSE,),
 }
 jax.tree_util.register_dataclass(
     KVCache, data_fields=list(_CACHE_FIELDS), meta_fields=[]
@@ -1932,8 +2040,17 @@ def init_kv_cache(
         return KVCache(k=None, v=None, latent=jnp.zeros(
             (plan.count(LATENT), batch, s_max, cfg.latent_dim), dtype))
     # Without an attention layer: no layers of k/v, the window's length.
-    shape = (plan.count(ATTENTION), batch, s_max, cfg.n_kv_heads, cfg.head_dim)
+    shape = (plan.count(ATTENTION, SPARSE), batch, s_max, cfg.n_kv_heads,
+             cfg.head_dim)
     cache = KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
+    if plan.count(SPARSE):
+        cache.ck = jnp.zeros(
+            (plan.count(SPARSE), batch, -(-s_max // cfg.sparse_kernel_stride),
+             cfg.n_kv_heads, cfg.head_dim), dtype)
+    if plan.count(LIGHTNING):  # a state and no tail
+        cache.state = jnp.zeros(
+            (plan.count(LIGHTNING), batch, cfg.lightning_n_heads,
+             cfg.lightning_head_dim, cfg.lightning_head_dim), jnp.float32)
     for branch, shapes in _RECURRENT_SHAPES.items():
         if plan.count(branch):
             state, conv = shapes(cfg)
@@ -2096,6 +2213,7 @@ def prefill(
         cfg, segment_ids, cos, sin, use_flash, with_state=True,
         window_rope=window_rope,
         ring=None if cache.wk is None else cache.wk.shape[2],
+        ck_slots=None if cache.ck is None else cache.ck.shape[2],
     )
 
     def body(y, step):
@@ -2164,6 +2282,7 @@ def decode_step(
     experts_in_place: Optional[bool] = None,
     row_kernel=None,  # None | bool | Mesh
     expert_kernel: Optional[bool] = None,
+    with_sparse_counts: bool = False,
 ) -> Tuple[jax.Array, ...]:
     """One decode step: write the new token's k/v at cache slot `slot`
     (shared by every row — the right-aligned prompt layout makes the write a
@@ -2171,7 +2290,10 @@ def decode_step(
     live window `[valid_from, slot]`, return fp32 logits [B, V] and the
     updated cache — and, `with_moe_counts`, the step's rows per expert of
     every layer ([L, E] int32; MoE models only), which the generator's
-    counters reduce inside its decode loop.
+    counters reduce inside its decode loop; `with_sparse_counts`, in their
+    place, what every block-sparse layer read ([layers, 3] fp32: keys
+    read, keys cached, rows still under `sparse_dense_len`:
+    `block_sparse.decode_attention`).
 
     The cache rides the layer scan as CARRY (updated in place by XLA), so
     per-token HBM traffic is one (B, n_kv, d) write + one window read per
@@ -2307,6 +2429,36 @@ def decode_step(
 
         return branch
 
+    def attend_sparse(h, blk, cache, li):
+        """Block-sparse attention of one token per row: the token's k/v
+        and, where it completes a kernel, the row's next compressed key go
+        into layer li; the selection reads the compressed keys and the
+        attention the chosen blocks' rows alone.  Its counts ride out as
+        the branch's third result."""
+        sizes = block_sparse.Sizes.of(cfg)
+        q, k, v = _block_kv(h, blk, cfg, cos, sin)
+        kc = jax.lax.dynamic_update_slice(
+            cache.k, k.astype(cache.k.dtype)[None], (li, 0, slot, 0, 0))
+        vc = jax.lax.dynamic_update_slice(
+            cache.v, v.astype(cache.v.dtype)[None], (li, 0, slot, 0, 0))
+        with jax.named_scope("layer/sparse_attn/compress"):
+            ck = block_sparse.compressed_step(
+                cache.ck, kc, li, slot, valid_from, sizes)
+        attn, reads = block_sparse.decode_attention(
+            q,
+            jax.lax.dynamic_index_in_dim(kc, li, axis=0, keepdims=False),
+            jax.lax.dynamic_index_in_dim(vc, li, axis=0, keepdims=False),
+            jax.lax.dynamic_index_in_dim(ck, li, axis=0, keepdims=False),
+            valid_from, slot, sizes,
+        )
+        ao = _attn_out(
+            attn.reshape(b, 1, cfg.q_dim), blk, cfg, _attn_gate(h, blk, cfg))
+        return ao, dataclasses.replace(cache, k=kc, v=vc, ck=ck), reads
+
+    def step_lightning(h, blk, cache, li):
+        out, states = lightning_step(h, blk, cfg, cache.state, li, cos, sin)
+        return out, dataclasses.replace(cache, state=states), None
+
     def short_conv(h, blk, cache, li):
         """The gated short convolution shifts layer li of the tails in
         place."""
@@ -2324,6 +2476,8 @@ def decode_step(
     # branch, which is its place in the branch's population of the cache
     # and in the stacked expert leaves.
     branches = {
+        SPARSE: attend_sparse,
+        LIGHTNING: step_lightning,
         ATTENTION: attend,
         WINDOW: attend_window,
         LATENT: attend_latent,
@@ -2364,10 +2518,10 @@ def decode_step(
     (x, new_cache, _), counts = jax.lax.scan(
         body, (x, cache, jnp.int32(0)), _unit_view(cfg, blocks)
     )
-    counts = _layer_outputs(plan.in_unit(MOE), counts)
+    counts = _layer_outputs(plan.in_unit(MOE, SPARSE), counts)
     x = _final_norm(params, cfg, x)
     logits = _head(params, cfg, x)[:, 0]  # [B, V]
-    if with_moe_counts:
+    if with_moe_counts or with_sparse_counts:
         return logits, new_cache, counts
     return logits, new_cache
 

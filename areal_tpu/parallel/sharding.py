@@ -126,6 +126,19 @@ _BLOCK_RULES: Dict[str, P] = {
     "sc_in": P(PIPE_AXIS, FSDP_AXIS, None),
     "sc_conv": P(PIPE_AXIS, None, None),
     "sc_out": P(PIPE_AXIS, None, FSDP_AXIS),
+    # Lightning attention: ZeRO-sharded over fsdp, NOT split over `model`
+    # — the constant decay is a head's and the fp32 state's heads would
+    # have to split with the projections; `attn_dispatch` refuses
+    # minicpm_sala's plan on a mesh with model > 1 by name (its block-
+    # sparse layers take the attention rules above).
+    "lt_wq": P(PIPE_AXIS, FSDP_AXIS, None),
+    "lt_wk": P(PIPE_AXIS, FSDP_AXIS, None),
+    "lt_wv": P(PIPE_AXIS, FSDP_AXIS, None),
+    "lt_wg": P(PIPE_AXIS, FSDP_AXIS, None),
+    "lt_q_norm": P(PIPE_AXIS, None),
+    "lt_k_norm": P(PIPE_AXIS, None),
+    "lt_norm": P(PIPE_AXIS, None),
+    "lt_wo": P(PIPE_AXIS, None, FSDP_AXIS),
 }
 
 _TOP_RULES: Dict[str, P] = {

@@ -1,0 +1,217 @@
+"""MiniCPM-SALA's controls, on the chip at the published widths
+(`benchmark/configs/minicpm-sala-l4-v8.json`).
+
+(1) THE TWO FORMS of the selected attention over one packed row of the
+cell's longest sequence (13,312 tokens, 32 / 2 heads of 128), each timed
+alone, forward and forward + backward: dense under the mask in chunks of
+queries (`block_sparse.packed_attention`, what the programs run) and the
+chosen blocks gathered with a key head's 16 query heads as the matmul's rows
+(`gathered_attention` below, the published kernel's form, which ran at a
+thirteenth of the speed and lives here alone); beside them the selection
+alone (compressed keys, scores, top-k) and the repo's flash kernel over the
+same row with NO selection (what a dense layer would cost).
+
+(2) THE SECOND READINGS of the tolerances: the plain reference with its
+Lightning state rounded to bfloat16 at every step against the reference
+proper over one sequence (mean and max |log-prob difference|), and the
+static program itself (`references.minicpm_sala.check_generator`) as it is
+— the first readings — and with the decode step's state rounded to
+bfloat16, which the state limit has to REFUSE.
+
+    chiprun -- python3 scripts/sala_controls.py [forms|tolerances|all] [n]
+
+Writes chiprun_out/sala_controls.json; one line a reading.  A reading is
+evidence only from a TPU run."""
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+from areal_tpu.models import lightning  # noqa: E402
+from areal_tpu.models import transformer as tfm  # noqa: E402
+from areal_tpu.ops import block_sparse  # noqa: E402
+from areal_tpu.ops.attention import packed_attention  # noqa: E402
+from benchmark import files  # noqa: E402
+from benchmark.references import minicpm_sala as ref  # noqa: E402
+from benchmark.run import model_config  # noqa: E402
+
+
+def _ms(fn, *args, reps=3):
+    """Milliseconds a call, the median of `reps` after one to compile."""
+    jax.block_until_ready(fn(*args))
+    times = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        times.append(1e3 * (time.perf_counter() - t0))
+    return float(np.median(times))
+
+
+def gathered_attention(q, k, v, segment_ids, sz):
+    """The form that was measured and not taken: `block_sparse.
+    packed_attention`'s selection, then each query's chosen blocks GATHERED
+    and attended with a key head's query heads as the matmul's rows (it
+    selects in every sequence, whatever `dense_len`).  One row at a time,
+    chunks of `QUERY_CHUNK` queries, a `jax.checkpoint` a chunk."""
+    bs, chunk = sz.block, block_sparse.QUERY_CHUNK
+
+    def row(q, k, v, seg):
+        s, hq, d = q.shape
+        n_kv = k.shape[1]
+        pos, start, _ = block_sparse._segments(seg)
+        kc, knum, kseg = block_sparse.compress_row(k, pos, seg, sz)
+        n_blocks = kc.shape[0] // sz.pool + 2
+        n = min(sz.topk, n_blocks)
+        parts = tuple(x.reshape(s // chunk, chunk, *x.shape[1:]) for x in (
+            q, seg, pos, start, jnp.arange(s, dtype=jnp.int32)))
+
+        @jax.checkpoint
+        def attend(k, v, xs):
+            qc, segc, posc, startc, idxc = xs
+            chosen = block_sparse._select_chunk(
+                jax.lax.stop_gradient(qc), kc, knum, kseg, segc, posc,
+                startc, sz)  # [T, Hkv, NBg] over global blocks
+            c0 = (startc + sz.kernel - 1) // sz.stride
+            first = startc - (c0 // sz.pool) * bs  # global block 0's index
+            order = jnp.argsort(~chosen, axis=-1, stable=True)[..., :n]
+            live = jnp.take_along_axis(chosen, order, axis=-1)
+            starts = first[:, None, None] + order * bs
+            rows = starts[..., None] + jnp.arange(bs)  # [T, Hkv, n, bs]
+            keep = live[..., None] & (rows <= idxc[:, None, None, None])
+            rows = jnp.clip(rows, 0, s - 1).reshape(chunk, n_kv, n * bs)
+            head = jnp.arange(n_kv)[None, :, None]
+            kg, vg = k[rows, head], v[rows, head]  # [T, Hkv, n * bs, d]
+            logits = jnp.einsum(
+                "tgrd,tgsd->tgrs", qc.reshape(chunk, n_kv, hq // n_kv, d), kg,
+                preferred_element_type=jnp.float32) * d**-0.5
+            logits = jnp.where(
+                keep.reshape(chunk, n_kv, 1, n * bs), logits,
+                block_sparse.NEG_INF)
+            out = jnp.einsum(
+                "tgrs,tgsd->tgrd",
+                jax.nn.softmax(logits, axis=-1).astype(v.dtype), vg,
+                preferred_element_type=jnp.float32)
+            return out.reshape(chunk, hq, d).astype(q.dtype)
+
+        return jax.lax.map(
+            lambda xs: attend(k, v, xs), parts).reshape(s, hq, d)
+
+    return jax.vmap(row)(q, k, v, segment_ids)
+
+
+def forms(cfg, n):
+    """The selected attention alone, one row of `n` tokens."""
+    sz = block_sparse.Sizes.of(cfg)
+    ks = jax.random.split(jax.random.PRNGKey(55), 3)
+    dtype = jnp.float32 if jax.default_backend() == "cpu" else jnp.bfloat16
+    q = jax.random.normal(ks[0], (1, n, cfg.n_q_heads, cfg.head_dim), dtype)
+    k = jax.random.normal(ks[1], (1, n, cfg.n_kv_heads, cfg.head_dim), dtype)
+    v = jax.random.normal(ks[2], (1, n, cfg.n_kv_heads, cfg.head_dim), dtype)
+    seg = jnp.ones((1, n), jnp.int32)
+    out = {"n_tokens": n}
+
+    attention = {
+        "mask": lambda q, k, v: block_sparse.packed_attention(
+            q, k, v, seg, sz)[0],
+        "gather": lambda q, k, v: gathered_attention(q, k, v, seg, sz),
+    }
+
+    def fwd(form):
+        return jax.jit(attention[form])
+
+    def both(form):
+        return jax.jit(jax.grad(lambda q, k, v: jnp.sum(
+            attention[form](q, k, v).astype(jnp.float32)), argnums=(0, 1, 2)))
+
+    dense = jax.jit(lambda q, k, v: packed_attention(q, k, v, seg, causal=True))
+    dense_both = jax.jit(jax.grad(lambda q, k, v: jnp.sum(packed_attention(
+        q, k, v, seg, causal=True).astype(jnp.float32)), argnums=(0, 1, 2)))
+    never = block_sparse.Sizes(**{**sz.__dict__, "dense_len": 10**9})
+    unselected = jax.jit(lambda q, k, v: block_sparse.packed_attention(
+        q, k, v, seg, never)[0])
+    for name, fn in (
+        ("mask_fwd_ms", fwd("mask")), ("mask_fwd_bwd_ms", both("mask")),
+        ("gather_fwd_ms", fwd("gather")), ("gather_fwd_bwd_ms", both("gather")),
+        ("mask_without_selection_fwd_ms", unselected),
+        ("flash_dense_fwd_ms", dense), ("flash_dense_fwd_bwd_ms", dense_both),
+    ):
+        try:
+            out[name] = _ms(fn, q, k, v)
+        except Exception as e:  # a form the chip refuses is a reading too
+            out[name] = f"failed: {type(e).__name__}: {str(e)[:200]}"
+        print("forms,", name, out[name], flush=True)
+    agree = jnp.abs(
+        fwd("mask")(q, k, v).astype(jnp.float32)
+        - fwd("gather")(q, k, v).astype(jnp.float32))
+    out["mask_vs_gather_max_abs"] = float(agree.max())
+    print("forms, mask against gather, max abs", out["mask_vs_gather_max_abs"],
+          flush=True)
+    return out
+
+
+def tolerances(cfg, config, n):
+    params = tfm.init_params(
+        cfg, jax.random.PRNGKey(config["benchmark"]["weights_seed"]))
+    tokens = np.random.default_rng(55).integers(0, 256, n).astype(np.int32)
+    padded = ref._padded(tokens)
+    cpu = jax.default_backend() == "cpu"
+    out = {"n_tokens": n, "tolerance": {
+        **ref.TOLERANCE,
+        **(ref.STATE_TOLERANCE_FP32 if cpu else ref.STATE_TOLERANCE)}}
+    want, _ = ref._next_token_logprobs(params, cfg, padded, n)
+    got, _ = ref._next_token_logprobs(
+        params, cfg, padded, n, ref.LOWER_PRECISION)
+    d = np.abs(got[: n - 1] - want[: n - 1])
+    out["reference_state_bf16"] = {
+        "mean_abs": float(d.mean()), "max_abs": float(d.max())}
+    print("reference, state in bfloat16, against the reference proper",
+          out["reference_state_bf16"], flush=True)
+
+    readings, problems = ref.check_generator(params, cfg, tokens)
+    out["static_program"] = {**readings, "problems": problems}
+    print("static program", readings, problems or "inside", flush=True)
+
+    step = lightning.lightning_step_jnp
+
+    def rounded(state, q, k, v):
+        state, y = step(state, q, k, v)
+        return jax.lax.reduce_precision(state, 8, 7), y
+
+    lightning.lightning_step_jnp = rounded
+    try:
+        readings, problems = ref.check_generator(params, cfg, tokens)
+    finally:
+        lightning.lightning_step_jnp = step
+    out["static_program_state_bf16"] = {**readings, "problems": problems}
+    print("static program, decode state rounded to bfloat16", readings,
+          "REFUSED by" if problems else "INSIDE (the limit does not hold)",
+          problems, flush=True)
+    out["refused"] = bool(problems)
+    return out
+
+
+def main():
+    what = sys.argv[1] if len(sys.argv) > 1 else "all"
+    n = int(sys.argv[2]) if len(sys.argv) > 2 else 13312
+    config = files.load_json("configs", "minicpm-sala-l4-v8.json")
+    cfg = model_config(config)
+    out = {"platform": jax.default_backend()}
+    if what in ("forms", "all"):
+        out["forms"] = forms(cfg, n)
+    if what in ("tolerances", "all"):
+        out["tolerances"] = tolerances(cfg, config, n)
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/sala_controls.json", "w") as f:
+        json.dump(out, f, indent=1)
+    # The control has to be refused: exit code 0 when it is.
+    return 0 if out.get("tolerances", {}).get("refused", True) else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

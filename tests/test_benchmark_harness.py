@@ -27,10 +27,17 @@ from benchmark.tests.test_moe_train_rows_gathered_share import *  # noqa: F401,F
 from benchmark.tests.test_ledger_readers import *  # noqa: F401,F403 — the cases
 from benchmark.tests import test_ssmd as ssmd_cases
 from benchmark.tests.test_ssmd import *  # noqa: F401,F403 — the cases (PR 53)
+from benchmark.tests import test_ssm_slab as slab_cases
 from benchmark.tests.test_ssm_slab import *  # noqa: F401,F403 — the cases (PR 54)
+from benchmark.tests.test_sala import *  # noqa: F401,F403 — the cases (PR 55)
 
 SPEC = files.benchmark_json()
 CELLS = [w["name"] for w in SPEC["workloads"]]
+# Two metrics that had no `workloads` list — every cell reported them — got
+# the list of the eleven cells before PR 55's: its cell runs no flash kernel,
+# their readers find nothing there, and a list-less metric has to be
+# reported by every cell that reports `train_tokens_per_s`.
+LISTED_BY_PR55 = {"flash_fwd_share", "flash_bwd_share"}
 
 
 GLM_CELL = "glm47f-rollout64-1k"
@@ -248,7 +255,7 @@ def test_the_glm_cell_is_as_the_issue_parametrised_it():
     listed = {
         m["name"] for m in SPEC["end_to_end"] + SPEC["per_layer"]
         if GLM_CELL in m.get("workloads", [])
-    }
+    } - LISTED_BY_PR55
     assert listed == {name for name, *_ in GLM_ENTRIES} | {
         "gen_tokens_per_s", "decode_ms_per_step", "decode_loop_ms",
         "moe_experts_touched", "moe_decode_mlp_ms", "moe_route_share",
@@ -302,7 +309,7 @@ def test_the_nemotron_cell_is_as_the_issue_parametrised_it():
     listed = {
         m["name"] for m in SPEC["end_to_end"] + SPEC["per_layer"]
         if NEMO_CELL in m.get("workloads", [])
-    }
+    } - LISTED_BY_PR55
     assert listed == {name for name, *_ in NEMO_ENTRIES} | {
         "gen_tokens_per_s", "decode_ms_per_step", "decode_loop_ms",
         "moe_experts_touched", "moe_decode_mlp_ms", "moe_route_share",
@@ -451,7 +458,7 @@ def test_the_mellum_cell_is_as_the_issue_parametrised_it():
     listed = {
         m["name"] for m in SPEC["end_to_end"] + SPEC["per_layer"]
         if MELLUM_CELL in m.get("workloads", [])
-    }
+    } - LISTED_BY_PR55
     assert listed == {name for name, *_ in MELLUM_ENTRIES} | SHARE_CELL_LISTS
     then = CELLS[: CELLS.index(MELLUM_CELL) + 1]
     for m in SPEC["end_to_end"] + SPEC["per_layer"]:
@@ -485,7 +492,8 @@ def test_the_lfm2_cell_is_as_the_issue_parametrised_it():
     assert conf["source"] == (
         "https://huggingface.co/LiquidAI/LFM2-8B-A1B/blob/main/config.json")
     assert len(entry["why"]) <= 200 and len(conf["why"]) <= 200
-    assert len(CELLS) == 11 and len(SPEC["configs"]) == 9
+    # PR 55 appended the twelfth cell and the tenth configuration.
+    assert len(CELLS) == 12 and len(SPEC["configs"]) == 10
     assert [w["name"] for w in SPEC["workloads"] if w["chips"] == 4] == [
         "q7b-realloc-4chip"]
     assert (cell["route"], cell["timed_steps"], cell["traffic_seed"]) == (
@@ -508,11 +516,12 @@ def test_the_lfm2_cell_is_as_the_issue_parametrised_it():
     listed = {
         m["name"] for m in SPEC["end_to_end"] + SPEC["per_layer"]
         if LFM2_CELL in m.get("workloads", [])
-    }
+    } - LISTED_BY_PR55
     assert listed == {name for name, *_ in LFM2_ENTRIES} | SHARE_CELL_LISTS
     for m in SPEC["end_to_end"] + SPEC["per_layer"]:
         if LFM2_CELL in m.get("workloads", []):  # the last static cell's
-            static = [w for w in m["workloads"] if "serving" not in w]
+            static = [w for w in m["workloads"]  # ... before PR 55's
+                      if "serving" not in w and w != "sala-docrl8-longctx"]
             assert static[-1] == LFM2_CELL, m["name"]
     for name in listed:
         assert callable(files.load_module("metrics", name).read), name
@@ -1523,16 +1532,36 @@ def test_the_sconv_readers_say_nothing_without_their_scopes_or_counters():
         + 4 * 32 * 64 * 1 * 4096 ** 2 / 2)
 
 
+def _spec_before(first_later_entry):
+    """BENCHMARK.json as it stood before the per-layer entry called
+    `first_later_entry` was appended, and before PR 55's configuration and
+    cell (the last of their lists)."""
+    at = _at(SPEC["per_layer"], first_later_entry)
+    assert SPEC["workloads"][-1]["name"] == "sala-docrl8-longctx"
+    return dict(
+        SPEC, per_layer=SPEC["per_layer"][:at],
+        workloads=SPEC["workloads"][:-1], configs=SPEC["configs"][:-1])
+
+
 def test_the_entries_are_the_last_and_the_cell_lists_what_it_reports(  # noqa: F811
         monkeypatch):
     """PR 53's case pins ITS eight entries as the last of `per_layer`; PR
     54 appended two behind them for the same cell (`benchmark/tests/
-    test_ssm_slab.py` pins those as the last).  So: PR 53's case on the
-    list as it stood before — `benchmark/tests/` is not a perf PR's to
+    test_ssm_slab.py` pins those as the last) and PR 55 seven more, a
+    configuration and a cell (`test_sala.py`).  So: PR 53's case on the
+    lists as they stood before — `benchmark/tests/` is not a later PR's to
     edit, as above."""
     at = _at(SPEC["per_layer"], "ssm_serving_state_ms")
-    assert [m["name"] for m in SPEC["per_layer"][at:]] == [
-        "ssm_serving_state_ms", "ssm_slot_step_live_share"]
-    before = dict(SPEC, per_layer=SPEC["per_layer"][:at])
-    monkeypatch.setattr(files, "benchmark_json", lambda: before)
+    assert [m["name"] for m in SPEC["per_layer"][at: at + 3]] == [
+        "ssm_serving_state_ms", "ssm_slot_step_live_share",
+        "sparse_decode_ms"]
+    monkeypatch.setattr(
+        files, "benchmark_json", lambda: _spec_before("ssm_serving_state_ms"))
     ssmd_cases.test_the_entries_are_the_last_and_the_cell_lists_what_it_reports()
+
+
+def test_the_slab_entries_are_the_last_of_per_layer(monkeypatch):  # noqa: F811
+    """PR 54's case on the list as it stood before PR 55's entries."""
+    monkeypatch.setattr(
+        files, "benchmark_json", lambda: _spec_before("sparse_decode_ms"))
+    slab_cases.test_the_slab_entries_are_the_last_of_per_layer()
