@@ -1176,7 +1176,7 @@ def _packed_branches(
     cfg: ModelConfig, segment_ids: jax.Array, *attn_args, with_state=False,
     window_rope=None, ring: Optional[int] = None,
     expert_kernel: Optional[bool] = False, row_kernel=None,
-    ck_slots: Optional[int] = None,
+    ck_slots: Optional[int] = None, backward: bool = False,
 ):
     """The table of the programs over packed rows (the train stack,
     `prefill`): branch -> f(h, blk) -> (its output, what else it gives by
@@ -1194,7 +1194,10 @@ def _packed_branches(
     chunked rule (`linear_attn_forward`'s `kernel`: None, by what the code
     can see; the caller's MESH where it has more than one device).
     `ck_slots`: the kernels a row of the cache's compressed keys holds,
-    where the caller keeps what a block-sparse layer leaves."""
+    where the caller keeps what a block-sparse layer leaves.  `backward`:
+    whether the caller differentiates the stack under a remat policy
+    (`_blocks`: a gradient program) — where `use_flash` is None a
+    block-sparse layer takes the flash kernels there alone (`sparse`)."""
     kernel = cfg.is_moe and expert_kernel_choice(cfg, expert_kernel)
 
     def recurrent(forward):
@@ -1244,8 +1247,19 @@ def _packed_branches(
         b, s, _ = h.shape
         q, k, v = _block_kv(h, blk, cfg, *attn_args[:2])
         sizes = block_sparse.Sizes.of(cfg)
+        # By what the code can see (None) the kernels serve the GRADIENT
+        # programs alone: there they replace three recomputations of
+        # `attend` by two of a faster one.  A program with no backward
+        # (`forward`, prefill) keeps the `jnp` form although the kernels
+        # are 2.7 times faster there too (PERF.md section 6, PR 56): with
+        # them in the programs the reference check runs at set-up, a warm
+        # run that followed a parent's read `peak_hbm_gb` 214 MB higher
+        # (section 7).  True forces them anywhere.
+        use_flash = attn_args[2]
+        if use_flash is None and not backward:
+            use_flash = False
         attn, kc, knum = block_sparse.packed_attention(
-            q, k, v, segment_ids, sizes)
+            q, k, v, segment_ids, sizes, use_flash=use_flash)
         out = _attn_out(
             attn.reshape(b, s, cfg.q_dim), blk, cfg, _attn_gate(h, blk, cfg))
         left = {"k": k, "v": v}
@@ -1752,7 +1766,7 @@ def _blocks(
     branches = _packed_branches(
         cfg, segment_ids, cos, sin, use_flash, cp_mesh, None, cp_zigzag,
         window_rope=window_rope, expert_kernel=expert_kernel,
-        row_kernel=row_kernel,
+        row_kernel=row_kernel, backward=remat not in (False, None, "none"),
     )
     layers = {
         kind: _branch_remat_layer(cfg, branches, kind, remat)

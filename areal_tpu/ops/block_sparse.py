@@ -22,15 +22,32 @@ arithmetic:
   keys and block scores live in SLOT space — the kernel that ends at row
   index i is slot i // 16, the block b of a segment whose first kernel is
   slot c0 is global block c0 // 4 + b, pooled at phase c0 % 4 — so no
-  per-segment gather is needed; the attention itself is DENSE UNDER THE
-  MASK in chunks of queries: scores against every key of the row, the
-  [queries, blocks] choice expanded to keys by a one-hot matmul.  With
-  random weights neighbouring queries choose unrelated blocks, so a tile
-  of queries reads every block anyway; the chosen blocks GATHERED with a
-  key head's query heads as the matmul's rows (the published kernel's
-  form) ran at a thirteenth of this form's speed as XLA ops and is kept
-  where it was measured, `scripts/sala_controls.py` (PERF.md section 6,
-  PR 55).
+  per-segment gather is needed.  The selection (`_row_selection`: XLA ops,
+  a chunk of queries at a time, no gradient) gives every query its choice
+  over GLOBAL blocks — every block for a query of a sequence under
+  `dense_len` — and every key its global block; the attention under that
+  choice takes one of two forms (`use_flash`, the argument that picks
+  `ops.attention.packed_attention`'s form):
+  on a TPU backend, one device, whole tiles (`kernel_fits`) — and, from
+  the model's programs, in the GRADIENT programs alone: `transformer.
+  _packed_branches` hands `forward` and prefill False where nobody
+  forces True — the FLASH KERNELS with the choice as one more term of
+  the tile mask
+  (`flash_attention.BlockChoice`: scores, mask and probabilities stay in
+  VMEM, the causal half alone is multiplied, the backward is the kernels'
+  own `custom_vjp` from `o` and the logsumexp — 44 ms forward / 98
+  forward + backward a 13,312-token row against the mask form's 107 / 251:
+  PERF.md section 6, PR 56); elsewhere — off a TPU, on a mesh, with
+  `use_flash=False` — DENSE UNDER THE MASK in chunks of queries
+  (`_row_attend_mask`: scores against every key of the row, the choice
+  expanded to keys by a one-hot matmul, a `jax.checkpoint` a chunk), the
+  form the tests hold the kernels to.  With random weights neighbouring
+  queries choose unrelated blocks, so a tile of queries reads every block
+  anyway and neither form skips a tile for the choice; the chosen blocks
+  GATHERED with a key head's query heads as the matmul's rows (the
+  published kernel's form) ran at a thirteenth of the mask form's speed as
+  XLA ops and is kept where it was measured, `scripts/sala_controls.py`
+  (PERF.md section 6, PR 55).
 - `decode_attention` (one token a row through the cache): reads the
   compressed keys (one row per `stride` tokens) and the chosen blocks' rows
   of K and V, never the window.
@@ -246,55 +263,78 @@ def _attend_mask_chunk(q, k, v, chosen, key_block, seg_q, seg_k, idx_q):
     return out.reshape(t, hq, d).astype(q.dtype)
 
 
-def _row_attention(q, k, v, segment_ids, sz: Sizes, chunk: int):
-    """One packed row: q [S, Hq, d], k, v [S, Hkv, d] -> (out [S, Hq, d],
-    kc [NK, Hkv, d], each kernel's number within its segment [NK])."""
+def _chunked(chunk: int, *xs, fills=()):
+    """Arrays [S, ...] -> each as [chunks, chunk, ...], padded with its
+    fill (0 where `fills` gives none)."""
+    pad = -xs[0].shape[0] % chunk
+    return tuple(
+        jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1),
+                constant_values=fill).reshape(-1, chunk, *x.shape[1:])
+        for x, fill in zip(xs, tuple(fills) + (0,) * len(xs)))
+
+
+def _row_selection(q, k, segment_ids, sz: Sizes, chunk: int):
+    """One packed row: q [S, Hq, d], k [S, Hkv, d] -> (chosen [S, Hkv, NBg]
+    bool, a query's choice over GLOBAL blocks — every block for a query of
+    a sequence under `dense_len`: data, no second path —, each key's global
+    block [S], kc [NK, Hkv, d], each kernel's number within its segment
+    [NK]).  Selection runs a chunk of queries at a time and carries no
+    gradient."""
     s = q.shape[0]
     pos, start, length = _segments(segment_ids)
     with jax.named_scope("compress"):
         kc, knum, kseg = compress_row(
             jax.lax.stop_gradient(k), pos, segment_ids, sz)
-    idx = jnp.arange(s, dtype=jnp.int32)
     c0 = (start + sz.kernel - 1) // sz.stride
     key_block = pos // sz.block + c0 // sz.pool  # [S] global block of a key
     sparse = length >= sz.dense_len  # [S] per token, its sequence's
-    pad = -s % chunk
-    n_chunks = (s + pad) // chunk
-
-    def chunked(x, fill=0):
-        x = jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1),
-                    constant_values=fill)
-        return x.reshape(n_chunks, chunk, *x.shape[1:])
 
     def select(xs):
-        qc, segc, posc, startc, idxc = xs
+        segc, qc, posc, startc, sparsec = xs
         with jax.named_scope("select"):
-            return _select_chunk(
-                jax.lax.stop_gradient(qc), kc, knum, kseg, segc, posc,
-                startc, sz)
+            chosen = _select_chunk(qc, kc, knum, kseg, segc, posc, startc, sz)
+            return chosen | ~sparsec[:, None, None]
+
+    chosen = jax.lax.map(select, _chunked(
+        chunk, segment_ids, jax.lax.stop_gradient(q), pos, start, sparse,
+        fills=(-3,)))
+    return chosen.reshape(-1, *chosen.shape[2:])[:s], key_block, kc, knum
+
+
+def _row_attend_mask(q, k, v, segment_ids, chosen, key_block, chunk: int):
+    """One packed row under its choice, the `jnp` form: a chunk of queries
+    at a time, each under its own `jax.checkpoint`."""
+    s = q.shape[0]
 
     @jax.checkpoint
     def attend(k, v, xs):
-        qc, segc, posc, startc, idxc, sparsec, chosen = xs
+        segc, idxc, qc, chosenc = xs
         with jax.named_scope("attend"):
-            # A dense sequence's queries see every block.
-            chosen = chosen | ~sparsec[:, None, None]
             return _attend_mask_chunk(
-                qc, k, v, chosen, key_block, segc, segment_ids, idxc)
+                qc, k, v, chosenc, key_block, segc, segment_ids, idxc)
 
     # Dense under the mask a chunk multiplies EVERY key of the row, twice
     # the causal half.  Runs of chunks against the keys up to their own
     # end (62% of the square at four runs) were tried and read WORSE: the
     # score fusions over 5,632 and 6,656 keys ran at a tenth of the rate
     # of the one over all 13,312 (my chip run, PR 55: 0.62 s a step each
-    # against 0.06), so every chunk takes the whole row.
-    parts = (chunked(q), chunked(segment_ids, -3), chunked(pos),
-             chunked(start), chunked(idx, s))
-    chosen = jax.lax.map(select, parts)
-    out = jax.lax.map(
-        functools.partial(attend, k, v),
-        parts + (chunked(sparse), chosen))
-    return out.reshape(s + pad, *q.shape[1:])[:s], kc, knum
+    # against 0.06), so in this form every chunk takes the whole row; the
+    # kernel form (`packed_attention`) multiplies the causal half alone.
+    out = jax.lax.map(functools.partial(attend, k, v), _chunked(
+        chunk, segment_ids, jnp.arange(s, dtype=jnp.int32), q, chosen,
+        fills=(-3, s)))
+    return out.reshape(-1, *q.shape[1:])[:s]
+
+
+def kernel_fits(s: int, sz: Sizes) -> bool:
+    """Whether the flash kernels take a row of `s` tokens under this
+    choice: whole tiles, and a trip's keys within half a window of blocks
+    (`flash_attention.BlockChoice`: a key's global block is within one of
+    (its index + kernel - 1) // block wherever segments start)."""
+    from areal_tpu.ops.pallas import flash_attention as fa
+
+    return (s % min(s, fa.DEFAULT_BLOCK_K) == 0
+            and fa.TRIP_ROWS // sz.block + 2 < fa.CHOICE_BLOCKS // 2)
 
 
 def packed_attention(
@@ -304,16 +344,57 @@ def packed_attention(
     segment_ids: jax.Array,  # [B, S], 0 = pad
     sz: Sizes,
     chunk: int = QUERY_CHUNK,
+    use_flash=None,  # None = by platform | bool | a Mesh (the `jnp` form)
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Causal-within-segment attention over packed rows, by selection for
     every sequence of at least `dense_len` tokens -> (out [B, S, Hq, d], the
     rows' compressed keys by slot [B, NK, Hkv, d], each slot's kernel number
-    within its segment [B, NK], -1 where the slot holds none)."""
-    chunk = min(chunk, q.shape[1])
+    within its segment [B, NK], -1 where the slot holds none).  `use_flash`
+    (`ops.attention.packed_attention`'s): whether `attend` is the flash
+    kernels under the choice — None, on a TPU backend where `kernel_fits`;
+    True forces them (interpreted off a TPU); False, or a mesh of several
+    devices, the `jnp` form."""
+    from areal_tpu.ops.pallas import flash_attention as fa
+
+    s = q.shape[1]
+    interpret = fa._interpret()
+    if use_flash is None:
+        use_flash = kernel_fits(s, sz) and not interpret
+    kernels = use_flash is True
+    if kernels and not kernel_fits(s, sz):
+        raise ValueError(
+            f"the flash kernels take no row of {s} tokens under blocks of "
+            f"{sz.block} keys")
+    return _packed_attention(
+        q, k, v, segment_ids, sz=sz, chunk=min(chunk, s), kernels=kernels,
+        interpret=interpret)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("sz", "chunk", "kernels", "interpret"))
+def _packed_attention(q, k, v, segment_ids, *, sz, chunk, kernels, interpret):
+    """`packed_attention` behind ONE `jit` entry point: a program's call
+    sites (forward, the recomputation, every bucket's) are traced and
+    lowered once.  `interpret` is what the kernels ask at trace time
+    (`flash_attention._interpret`), here for the cached trace's key: a
+    trace must not be another backend's."""
+    from areal_tpu.ops.pallas import flash_attention as fa
+
+    del interpret
     with jax.named_scope("layer/sparse_attn"):
-        return jax.vmap(
-            lambda q, k, v, seg: _row_attention(q, k, v, seg, sz, chunk)
-        )(q, k, v, segment_ids)
+        chosen, key_block, kc, knum = jax.vmap(
+            lambda q, k, seg: _row_selection(q, k, seg, sz, chunk)
+        )(q, k, segment_ids)
+        if kernels:
+            with jax.named_scope("attend"):
+                out = fa.flash_attention(
+                    q, k, v, segment_ids,
+                    choice=fa.BlockChoice(chosen, key_block))
+        else:
+            out = jax.vmap(
+                lambda *row: _row_attend_mask(*row, chunk)
+            )(q, k, v, segment_ids, chosen, key_block)
+    return out, kc, knum
 
 
 def compressed_of_last(kc, knum, segment_ids, sz: Sizes, n_slots: int):

@@ -56,6 +56,15 @@ trips of up to 512 keys):
   part of the interval inside its chunk, and the chunk index is clamped to
   the interval's chunks, so a step with nothing to do re-uses the resident
   block and fetches nothing.
+- A BLOCK CHOICE (`BlockChoice`, attention by selection: `ops/
+  block_sparse.py`) is one more operand of all three kernels and one more
+  term of `_tile_mask`, `chosen[q, key_block[k]]`; the schedule is the
+  causal / segment one.  The term is a product on the MXU — the q block's
+  choice over a WINDOW of 128 blocks by the trip's keys' one-hot, both
+  0 / 1 in bf16 — because a key's block is aligned to no tile (a segment
+  starts anywhere) and compares and lane broadcasts a block would be
+  vector work, which is what bounds these kernels.  Without the operand a
+  call traces the program it always did.
 - Online-softmax accumulators (m, l, acc) live in VMEM scratch; output and
   logsumexp are written on the last chunk step.  The backward kernels
   recompute the probability tiles from the saved logsumexp instead of
@@ -266,14 +275,17 @@ def _chunk_index(n_chunks: int, tiles: int):
 
 
 def _tile_mask(seg_q, seg_k, qi, ki, block_q, block_k, causal, window=None,
-               k_major=False):
+               k_major=False, picked=None):
     """seg_q [bq, 1], seg_k [1, bk] -> the tile's [bq, bk] mask; `k_major`:
     seg_q [1, bq], seg_k [bk, 1] -> the same mask transposed, [bk, bq], for
     the kernel that walks k blocks (dkv).  A sequence's tokens are
     contiguous in the row, so the distance between two of its positions is
     the distance between their places in the row: `window` masks keys
-    `window` or more places behind the query."""
+    `window` or more places behind the query.  `picked`: the tile of a
+    block choice (`_picked_tile`), one more term."""
     mask = (seg_q == seg_k) & (seg_q > 0)
+    if picked is not None:
+        mask &= picked
     if causal:
         shape = (block_k, block_q) if k_major else (block_q, block_k)
         q_pos = qi * block_q + jax.lax.broadcasted_iota(
@@ -299,18 +311,25 @@ def _vmem(shape, dtype):
 
 
 def _call(name, trip, kernel, sched_refs, args, *, grid, in_specs, out_specs,
-          out_shape, scratch_shapes, resident_bytes):
+          out_shape, scratch_shapes, resident_bytes, choice=None):
     """The kernels' common `pallas_call`: two schedule tables by scalar
-    prefetch, a VMEM limit that holds the double-buffered resident
-    operands beside Mosaic's default 16 MiB for everything else, and
-    around the kernel's own scope the one that names its trip (`_widen`)."""
+    prefetch (and a block choice's window table, `_with_choice`), a VMEM
+    limit that holds the double-buffered resident operands beside Mosaic's
+    default 16 MiB for everything else, and around the kernel's own scope
+    the one that names its trip (`_widen`)."""
     from jax.experimental.pallas import tpu as pltpu
 
+    if choice is not None:
+        win, operands, specs, units = choice
+        kernel = _with_choice(kernel, units)
+        sched_refs = (*sched_refs, win)
+        args = (*operands, *args)
+        in_specs = [*specs, *in_specs]
     call = named_call(
         name,
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=len(sched_refs),
             grid=grid,
             in_specs=in_specs,
             out_specs=out_specs,
@@ -327,6 +346,109 @@ def _call(name, trip, kernel, sched_refs, args, *, grid, in_specs, out_specs,
 
 
 # ---------------------------------------------------------------------------
+# A block choice: one more term of the mask
+# ---------------------------------------------------------------------------
+
+
+class BlockChoice(NamedTuple):
+    """Attention by selection (`ops/block_sparse.py`): a query sees a key
+    only where `chosen[b, q, kv head, key_block[b, k]]`, ANDed with the
+    segment and causal terms.  A key head's query heads share its choice
+    (GQA stays in the index maps).  `key_block` follows the row — over any
+    trip of keys it moves by fewer than `CHOICE_BLOCKS / 2` blocks — but is
+    aligned to nothing: a segment starts anywhere."""
+
+    chosen: jax.Array  # [B, S, Hkv, NB] bool
+    key_block: jax.Array  # [B, S] int32, in [0, NB)
+
+
+# The blocks one WINDOW of a choice holds: a tile's term is a product on
+# the MXU, choice[rows, blocks] @ one-hot[blocks, columns] (0 / 1 in bf16,
+# exact), over the window of blocks that holds the tile's keys' — the cost
+# of one more QK^T whatever the row's length, on the unit that has the
+# slack (the kernels are bound by vector work), where `chosen[q,
+# key_block[k]]` as compares and lane broadcasts a block would be vector
+# work.  Windows start every half: a trip's blocks, fewer than that, lie
+# whole inside the window that starts at or below its first.  A window is
+# a leading index of its operand (a dynamic lane offset does not lower).
+CHOICE_BLOCKS = 128
+
+
+def _choice_windows(chosen: jax.Array) -> jax.Array:
+    """[B, S, Hkv, NB] bool -> [B, S, Hkv, W, CHOICE_BLOCKS] bf16: window
+    w the blocks from CHOICE_BLOCKS / 2 * w on (zeros past NB)."""
+    half = CHOICE_BLOCKS // 2
+    nb = chosen.shape[-1]
+    w = -(-nb // half)
+    x = jnp.pad(chosen, ((0, 0),) * 3 + ((0, (w + 1) * half - nb),))
+    x = x.reshape(*chosen.shape[:3], w + 1, half)
+    return jnp.concatenate(
+        [x[..., :-1, :], x[..., 1:, :]], axis=-1
+    ).astype(jnp.bfloat16)
+
+
+def _key_windows(key_block: jax.Array, unit: int, n_windows: int):
+    """[B, S] -> (the window of each run of `unit` keys, flat [B * S / unit]
+    int32 as the schedule's tables; each key's block within that window,
+    one-hot [B, S / unit, unit, CHOICE_BLOCKS] bf16)."""
+    b, s = key_block.shape
+    kb = key_block.reshape(b, s // unit, unit)
+    win = jnp.clip(
+        jnp.min(kb, axis=-1) // (CHOICE_BLOCKS // 2), 0, n_windows - 1
+    )
+    hot = jax.nn.one_hot(
+        kb - win[..., None] * (CHOICE_BLOCKS // 2), CHOICE_BLOCKS,
+        dtype=jnp.bfloat16,
+    )
+    return win.reshape(-1).astype(jnp.int32), hot
+
+
+def _choice_tables(choice: BlockChoice, nq, block_q, hkv, unit):
+    """-> (the choice by q block and window [B, nq, block_q, Hkv, W,
+    CHOICE_BLOCKS] bf16, W, and `_key_windows` by runs of `unit` keys)."""
+    sel = _choice_windows(choice.chosen)
+    n_win = sel.shape[3]
+    sel = sel.reshape(-1, nq, block_q, hkv, n_win, CHOICE_BLOCKS)
+    return sel, n_win, *_key_windows(choice.key_block, unit, n_win)
+
+
+def _picked_tile(choice, spread):
+    """[rows, CHOICE_BLOCKS] @ [CHOICE_BLOCKS, columns] -> the tile's bool
+    [rows, columns].  Forward and dq: the q block's choice by the trip's
+    keys' one-hot; dkv: the k block's one-hot by the trip's queries'
+    choice, the same term transposed."""
+    return jax.lax.dot_general(
+        choice, spread, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) > 0.5
+
+
+def _picked_keys(choice, b, hq, ki, j):
+    """Forward and dq, trip `ki` (the resident chunk's j-th) of grid row
+    `b`: the q block's [bq, bk] term, or None — and nothing traced —
+    without a choice."""
+    if choice is None:
+        return None
+    win_ref, sel_ref, hot_ref, trips = choice
+    win = win_ref[(b // hq) * trips + ki]
+    return _picked_tile(sel_ref[0, 0, win], hot_ref[0, j])
+
+
+def _with_choice(kernel, units: int):
+    """`kernel` under a block choice: a third prefetched table (`units` a
+    row: the window of each trip of keys; in dkv, of each k block, read by
+    the index maps alone) and two inputs ahead of the kernel's own, handed
+    to it as `choice`."""
+
+    def choosing(lo_ref, hi_ref, win_ref, sel_ref, hot_ref, *refs):
+        return kernel(
+            lo_ref, hi_ref, *refs, choice=(win_ref, sel_ref, hot_ref, units)
+        )
+
+    return choosing
+
+
+# ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
 
@@ -337,7 +459,7 @@ def _fwd_kernel(
     o_ref, lse_ref,  # outputs
     m_scr, l_scr, acc_scr,  # scratch
     *, scale: float, block_q: int, block_k: int, hq: int, nq: int,
-    tiles: int, causal: bool, window=None,
+    tiles: int, causal: bool, window=None, choice=None,
 ):
     b, qi, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
@@ -364,7 +486,7 @@ def _fwd_kernel(
         ) * scale  # [bq, bk] fp32
         mask = _tile_mask(
             seg_q, seg_k_ref[0, j][0:1, :], qi, ki, block_q, block_k, causal,
-            window,
+            window, picked=_picked_keys(choice, b, hq, ki, j),
         )
         s = jnp.where(mask, s, NEG_INF)
 
@@ -423,44 +545,73 @@ def _kv_row(hq: int, hkv: int):
     return lambda b: (b // hq) * hkv + (b % hq) // n_rep
 
 
-def _q_major_specs(hq, hkv, nq, nk, d, block_q, block_k, itemsize):
+def _q_major_specs(hq, hkv, nq, nk, d, block_q, block_k, itemsize,
+                   choice=None):
     """The kernels that walk q blocks (forward, dq): K/V tiles a step holds
     resident and their bytes, and the block specs — what a step holds of
-    the q side, and the resident K/V chunk with its ids."""
+    the q side, and the resident K/V chunk with its ids; last, a block
+    choice as `_call` takes it (None without one): every window of the q
+    block's choice a step, and resident beside K/V the keys' one-hot, a
+    trip a leading index."""
     token_bytes = 2 * d * itemsize + 8 * 4
+    if choice is not None:
+        token_bytes += CHOICE_BLOCKS * 2
     tiles = _resident_blocks(nk, block_k, token_bytes)
     kv_row = _kv_row(hq, hkv)
     chunk = _chunk_index(nk // tiles, tiles)
 
     def q_side(width):
         return pl.BlockSpec(
-            (1, block_q, width), lambda b, qi, c, lo, hi: (b, qi, 0)
+            (1, block_q, width), lambda b, qi, c, lo, hi, *_: (b, qi, 0)
         )
 
     seg_q = pl.BlockSpec(
-        (1, block_q, 8), lambda b, qi, c, lo, hi: (b // hq, qi, 0)
+        (1, block_q, 8), lambda b, qi, c, lo, hi, *_: (b // hq, qi, 0)
     )
     seg_kb = pl.BlockSpec(
         (1, tiles, 8, block_k),
-        lambda b, qi, c, lo, hi: (
+        lambda b, qi, c, lo, hi, *_: (
             b // hq, chunk(lo, hi, (b // hq) * nq + qi, c), 0, 0
         ),
     )
     kv = pl.BlockSpec(
         (1, tiles * block_k, d),
-        lambda b, qi, c, lo, hi: (
+        lambda b, qi, c, lo, hi, *_: (
             kv_row(b), chunk(lo, hi, (b // hq) * nq + qi, c), 0
         ),
     )
-    return tiles, tiles * block_k * token_bytes, q_side, seg_q, seg_kb, kv
+    if choice is not None:
+        sel, n_win, win, hot = _choice_tables(
+            choice, nq, block_q, hkv, block_k
+        )
+        sel = sel.transpose(0, 3, 1, 4, 2, 5).reshape(
+            -1, nq, n_win, block_q, CHOICE_BLOCKS
+        )
+        choice = (
+            win,
+            (sel, hot.swapaxes(2, 3)),  # one-hot [B, nk, blocks, bk]
+            [
+                pl.BlockSpec(
+                    (1, 1, n_win, block_q, CHOICE_BLOCKS),
+                    lambda b, qi, c, *_: (kv_row(b), qi, 0, 0, 0),
+                ),
+                pl.BlockSpec((1, tiles, CHOICE_BLOCKS, block_k), seg_kb.index_map),
+            ],
+            nk,
+        )
+    return (
+        tiles, tiles * block_k * token_bytes, q_side, seg_q, seg_kb, kv,
+        choice,
+    )
 
 
 def _fwd(
-    q, k, v, seg, sched, hq, scale, block_q, block_k, causal, window=None
+    q, k, v, seg, sched, hq, scale, block_q, block_k, causal, window=None,
+    choice=None,
 ) -> Tuple[jax.Array, jax.Array]:
     """q: [B*hq, S, D]; k/v: [B*hkv, S, D] (unexpanded GQA); seg: [B, S]
-    int32; sched: the tiles to visit.  Returns (o [B*hq,S,D],
-    lse [B*hq,S,1])."""
+    int32; sched: the tiles to visit; choice: a `BlockChoice` or None.
+    Returns (o [B*hq,S,D], lse [B*hq,S,1])."""
     bh, s, d = q.shape
     hkv = k.shape[0] // seg.shape[0]
     nq = pl.cdiv(s, block_q)
@@ -469,8 +620,10 @@ def _fwd(
         "keys",
     )
     nk = pl.cdiv(s, block_k)
-    tiles, resident, q_side, seg_q_spec, seg_kb_spec, kv_spec = (
-        _q_major_specs(hq, hkv, nq, nk, d, block_q, block_k, k.dtype.itemsize)
+    tiles, resident, q_side, seg_q_spec, seg_kb_spec, kv_spec, choice = (
+        _q_major_specs(
+            hq, hkv, nq, nk, d, block_q, block_k, k.dtype.itemsize, choice
+        )
     )
     seg_q, seg_kb = _seg_layouts(seg, block_k)
     return _call(
@@ -496,6 +649,7 @@ def _fwd(
             _vmem((block_q, d), jnp.float32),
         ],
         resident_bytes=resident,
+        choice=choice,
     )
 
 
@@ -510,6 +664,7 @@ def _dq_kernel(
     dq_ref,
     dq_scr,
     *, scale, block_q, block_k, hq, nq, tiles, causal, window=None,
+    choice=None,
 ):
     b, qi, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
@@ -533,7 +688,7 @@ def _dq_kernel(
         ) * scale
         mask = _tile_mask(
             seg_q, seg_k_ref[0, j][0:1, :], qi, ki, block_q, block_k, causal,
-            window,
+            window, picked=_picked_keys(choice, b, hq, ki, j),
         )
         p = jnp.where(mask, jnp.exp(s - lse), 0.0)
         dp = jax.lax.dot_general(
@@ -561,6 +716,7 @@ def _dkv_kernel(
     dk_ref, dv_ref,
     dk_scr, dv_scr,
     *, scale, block_q, block_k, hq, nk, tiles, causal, window=None,
+    choice=None,
 ):
     b, ki, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
@@ -572,6 +728,10 @@ def _dkv_kernel(
     k = k_ref[0]
     v = v_ref[0]
     seg_k = seg_k_ref[0][:, 0:1]  # [bk, 1]
+    sel_ref = hot = None
+    if choice is not None:  # the k block's one-hot [bk, blocks], a step's own
+        _, sel_ref, hot_ref, _ = choice
+        hot = hot_ref[0, 0]
 
     def tile(qi, _):
         j = qi - c * tiles
@@ -586,6 +746,8 @@ def _dkv_kernel(
         mask = _tile_mask(
             seg_q_ref[0, j][0:1, :], seg_k, qi, ki, block_q, block_k, causal,
             window, k_major=True,
+            picked=None if hot is None else _picked_tile(
+                hot, sel_ref[0, 0, j]),
         )
         p = jnp.where(mask, jnp.exp(s - lse), 0.0)
         dv_scr[:] += jax.lax.dot_general(
@@ -613,7 +775,7 @@ def _dkv_kernel(
 
 
 def _dq(q, k, v, do, lse, delta, seg, sched, hq, scale, block_q, block_k,
-        causal, window=None) -> jax.Array:
+        causal, window=None, choice=None) -> jax.Array:
     """dq [B*hq, S, D] in q's type: one grid step a q block, the loop over
     its live keys a trip at a time (`_widen`)."""
     bh, s, d = q.shape
@@ -624,8 +786,10 @@ def _dq(q, k, v, do, lse, delta, seg, sched, hq, scale, block_q, block_k,
         "keys",
     )
     nk = pl.cdiv(s, block_k)
-    tiles, resident, q_side, seg_q_spec, seg_kb_spec, kv_spec = (
-        _q_major_specs(hq, hkv, nq, nk, d, block_q, block_k, k.dtype.itemsize)
+    tiles, resident, q_side, seg_q_spec, seg_kb_spec, kv_spec, choice = (
+        _q_major_specs(
+            hq, hkv, nq, nk, d, block_q, block_k, k.dtype.itemsize, choice
+        )
     )
     seg_q, seg_kb = _seg_layouts(seg, block_k)
     return _call(
@@ -647,11 +811,12 @@ def _dq(q, k, v, do, lse, delta, seg, sched, hq, scale, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
         scratch_shapes=[_vmem((block_q, d), jnp.float32)],
         resident_bytes=resident,
+        choice=choice,
     )
 
 
 def _dkv(q, k, v, do, lse, delta, seg, sched, hq, scale, block_q, block_k,
-         causal, window=None) -> Tuple[jax.Array, jax.Array]:
+         causal, window=None, choice=None) -> Tuple[jax.Array, jax.Array]:
     """dk, dv [B*hq, S, D] fp32, per Q-HEAD (the grid walks q heads; the
     caller sums the heads that share a kv head): one grid step a k block,
     K/V and the k ids by step, the q side resident, the loop over its live
@@ -670,19 +835,21 @@ def _dkv(q, k, v, do, lse, delta, seg, sched, hq, scale, block_q, block_k,
     seg_k, seg_qb = _seg_layouts(seg, block_q)
     lse, delta = (x.reshape(bh, nq, 1, block_q) for x in (lse, delta))
     token_bytes = 2 * d * q.dtype.itemsize + 3 * _ROW_BYTES
+    if choice is not None:
+        token_bytes += CHOICE_BLOCKS * 2
     tiles = _resident_blocks(nq, block_q, token_bytes)
     kv_row = _kv_row(hq, hkv)
     chunk = _chunk_index(nq // tiles, tiles)
 
     def k_side(rows, width=d):
         return pl.BlockSpec(
-            (1, block_k, width), lambda b, ki, c, lo, hi: (rows(b), ki, 0)
+            (1, block_k, width), lambda b, ki, c, lo, hi, *_: (rows(b), ki, 0)
         )
 
     def q_resident(rows=lambda b: b):
         return pl.BlockSpec(
             (1, tiles * block_q, d),
-            lambda b, ki, c, lo, hi: (
+            lambda b, ki, c, lo, hi, *_: (
                 rows(b), chunk(lo, hi, (b // hq) * nk + ki, c), 0
             ),
         )
@@ -690,11 +857,40 @@ def _dkv(q, k, v, do, lse, delta, seg, sched, hq, scale, block_q, block_k,
     def q_rows(sublanes, rows=lambda b: b):
         return pl.BlockSpec(
             (1, tiles, sublanes, block_q),
-            lambda b, ki, c, lo, hi: (
+            lambda b, ki, c, lo, hi, *_: (
                 rows(b), chunk(lo, hi, (b // hq) * nk + ki, c), 0, 0
             ),
         )
 
+    if choice is not None:
+        # The term transposed: the k block's one-hot [bk, blocks] a step,
+        # and resident beside Q the queries' choice in the k block's
+        # WINDOW (the index map's, from the prefetched table), [blocks, bq]
+        # a trip.
+        sel, n_win, win, hot = _choice_tables(
+            choice, nq, block_q, hkv, block_k
+        )
+        sel = sel.transpose(0, 3, 4, 1, 5, 2).reshape(
+            -1, n_win, nq, CHOICE_BLOCKS, block_q
+        )
+        choice = (
+            win,
+            (sel, hot),
+            [
+                pl.BlockSpec(
+                    (1, 1, tiles, CHOICE_BLOCKS, block_q),
+                    lambda b, ki, c, lo, hi, win: (
+                        kv_row(b), win[(b // hq) * nk + ki],
+                        chunk(lo, hi, (b // hq) * nk + ki, c), 0, 0,
+                    ),
+                ),
+                pl.BlockSpec(
+                    (1, 1, block_k, CHOICE_BLOCKS),
+                    lambda b, ki, c, *_: (b // hq, ki, 0, 0),
+                ),
+            ],
+            nk,
+        )
     return _call(
         "flash_dkv",
         trip,
@@ -726,11 +922,12 @@ def _dkv(q, k, v, do, lse, delta, seg, sched, hq, scale, block_q, block_k,
             _vmem((block_k, d), jnp.float32),
         ],
         resident_bytes=tiles * block_q * token_bytes,
+        choice=choice,
     )
 
 
 def _bwd(
-    scale, block_q, block_k, causal, res, do, window=None
+    scale, block_q, block_k, causal, res, do, window=None, choice=None
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     q, k, v, o, lse, seg, sched = res
     bh, s, d = q.shape
@@ -741,7 +938,7 @@ def _bwd(
         o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1, keepdims=True
     )  # [BH, S, 1]
     args = (q, k, v, do, lse, delta, seg, sched, hq, scale, block_q, block_k,
-            causal, window)
+            causal, window, choice)
     dq = _dq(*args)
     dk_x, dv_x = _dkv(*args)
 
@@ -760,28 +957,32 @@ def _bwd(
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
-def _flash_bhsd(q, k, v, seg, sched, scale, block_q, block_k, causal, window):
-    hq = q.shape[0] // seg.shape[0]
-    o, _ = _fwd(
-        q, k, v, seg, sched, hq, scale, block_q, block_k, causal, window
-    )
-    return o
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
+def _flash_bhsd(
+    q, k, v, seg, sched, choice, scale, block_q, block_k, causal, window
+):
+    return _flash_fwd_rule(
+        q, k, v, seg, sched, choice, scale, block_q, block_k, causal, window
+    )[0]
 
 
 def _flash_fwd_rule(
-    q, k, v, seg, sched, scale, block_q, block_k, causal, window
+    q, k, v, seg, sched, choice, scale, block_q, block_k, causal, window
 ):
     hq = q.shape[0] // seg.shape[0]
     o, lse = _fwd(
-        q, k, v, seg, sched, hq, scale, block_q, block_k, causal, window
+        q, k, v, seg, sched, hq, scale, block_q, block_k, causal, window,
+        choice,
     )
-    return o, (q, k, v, o, lse, seg, sched)
+    return o, ((q, k, v, o, lse, seg, sched), choice)
 
 
 def _flash_bwd_rule(scale, block_q, block_k, causal, window, res, do):
+    # Ids, the schedule and a choice (bools and indices) carry no gradient.
+    res, choice = res
     return (
-        *_bwd(scale, block_q, block_k, causal, res, do, window), None, None
+        *_bwd(scale, block_q, block_k, causal, res, do, window, choice),
+        None, None, None,
     )
 
 
@@ -797,6 +998,7 @@ def flash_attention(
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
     window: "int | None" = None,
+    choice: "BlockChoice | None" = None,
 ) -> jax.Array:
     """Segment-aware causal flash attention over packed rows.  GQA is
     native: kv stays at n_kv heads and the kernel's BlockSpec index maps
@@ -804,7 +1006,9 @@ def flash_attention(
     `window` (a Python int, with `causal`): a query sees the last `window`
     keys of its sequence, itself included — one more term in the tile mask
     and a band in the schedule (`live_schedule`); None traces the program
-    it always did."""
+    it always did.  `choice`: a `BlockChoice`, one more term in the tile
+    mask of all three kernels and nothing in the schedule; it carries no
+    gradient, and None traces the program it always did."""
     if window is not None and not causal:
         raise ValueError("a sliding window is causal")
     b, s, hq, d = q.shape
@@ -826,7 +1030,7 @@ def flash_attention(
     o = _flash_bhsd(
         to_bhsd(q), to_bhsd(k), to_bhsd(v), seg,
         live_schedule(seg, block_q, block_k, causal, window),
-        d**-0.5, block_q, block_k, causal, window,
+        choice, d**-0.5, block_q, block_k, causal, window,
     )
     return o.reshape(b, hq, s, d).transpose(0, 2, 1, 3)
 

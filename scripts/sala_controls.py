@@ -1,15 +1,19 @@
 """MiniCPM-SALA's controls, on the chip at the published widths
 (`benchmark/configs/minicpm-sala-l4-v8.json`).
 
-(1) THE TWO FORMS of the selected attention over one packed row of the
+(1) THE THREE FORMS of the selected attention over one packed row of the
 cell's longest sequence (13,312 tokens, 32 / 2 heads of 128), each timed
-alone, forward and forward + backward: dense under the mask in chunks of
-queries (`block_sparse.packed_attention`, what the programs run) and the
-chosen blocks gathered with a key head's 16 query heads as the matmul's rows
+alone, forward and forward + backward: the flash kernels under the block
+choice (`block_sparse.packed_attention(use_flash=True)`, what the programs
+run on a TPU), dense under the mask in chunks of queries (`use_flash=False`,
+what they run elsewhere and the kernels' oracle) and the chosen blocks
+gathered with a key head's 16 query heads as the matmul's rows
 (`gathered_attention` below, the published kernel's form, which ran at a
-thirteenth of the speed and lives here alone); beside them the selection
-alone (compressed keys, scores, top-k) and the repo's flash kernel over the
-same row with NO selection (what a dense layer would cost).
+thirteenth of the mask form's speed and lives here alone); beside them the
+selection alone (compressed keys, scores, top-k) and the repo's flash kernel
+over the same row with NO selection (what a dense layer would cost).
+`tiles`: the share of tiles the cell's own choice leaves empty
+(`empty_tiles`).
 
 (2) THE SECOND READINGS of the tolerances: the plain reference with its
 Lightning state rounded to bfloat16 at every step against the reference
@@ -18,7 +22,7 @@ static program itself (`references.minicpm_sala.check_generator`) as it is
 — the first readings — and with the decode step's state rounded to
 bfloat16, which the state limit has to REFUSE.
 
-    chiprun -- python3 scripts/sala_controls.py [forms|tolerances|all] [n]
+    chiprun -- python3 scripts/sala_controls.py [forms|tiles|tolerances|all] [n]
 
 Writes chiprun_out/sala_controls.json; one line a reading.  A reading is
 evidence only from a TPU run."""
@@ -118,8 +122,10 @@ def forms(cfg, n):
 
     attention = {
         "mask": lambda q, k, v: block_sparse.packed_attention(
-            q, k, v, seg, sz)[0],
+            q, k, v, seg, sz, use_flash=False)[0],
         "gather": lambda q, k, v: gathered_attention(q, k, v, seg, sz),
+        "kernel": lambda q, k, v: block_sparse.packed_attention(
+            q, k, v, seg, sz, use_flash=True)[0],
     }
 
     def fwd(form):
@@ -134,8 +140,14 @@ def forms(cfg, n):
         q, k, v, seg, causal=True).astype(jnp.float32)), argnums=(0, 1, 2)))
     never = block_sparse.Sizes(**{**sz.__dict__, "dense_len": 10**9})
     unselected = jax.jit(lambda q, k, v: block_sparse.packed_attention(
-        q, k, v, seg, never)[0])
+        q, k, v, seg, never, use_flash=False)[0])
+    select = jax.jit(lambda q, k: jax.vmap(
+        lambda q, k, seg: block_sparse._row_selection(
+            q, k, seg, sz, block_sparse.QUERY_CHUNK)[0])(q, k, seg))
+    out["select_ms"] = _ms(select, q, k)
+    print("forms, select_ms", out["select_ms"], flush=True)
     for name, fn in (
+        ("kernel_fwd_ms", fwd("kernel")), ("kernel_fwd_bwd_ms", both("kernel")),
         ("mask_fwd_ms", fwd("mask")), ("mask_fwd_bwd_ms", both("mask")),
         ("gather_fwd_ms", fwd("gather")), ("gather_fwd_bwd_ms", both("gather")),
         ("mask_without_selection_fwd_ms", unselected),
@@ -146,12 +158,53 @@ def forms(cfg, n):
         except Exception as e:  # a form the chip refuses is a reading too
             out[name] = f"failed: {type(e).__name__}: {str(e)[:200]}"
         print("forms,", name, out[name], flush=True)
-    agree = jnp.abs(
-        fwd("mask")(q, k, v).astype(jnp.float32)
-        - fwd("gather")(q, k, v).astype(jnp.float32))
-    out["mask_vs_gather_max_abs"] = float(agree.max())
-    print("forms, mask against gather, max abs", out["mask_vs_gather_max_abs"],
-          flush=True)
+    want = fwd("mask")(q, k, v).astype(jnp.float32)
+    for form in ("gather", "kernel"):
+        key = f"mask_vs_{form}_max_abs"
+        out[key] = float(
+            jnp.abs(want - fwd(form)(q, k, v).astype(jnp.float32)).max())
+        print("forms, mask against", form, "max abs", out[key], flush=True)
+    return out
+
+
+def empty_tiles(cfg, config, n, tile=128):
+    """What the cell's choice would leave a tile-level skip: layer 0's q
+    and k from the cell's weights over one row of `n` random bytes, the
+    selection, and on the host the share of (q tile, k tile, key head)
+    triples at or below the diagonal in which no query of the tile chose
+    any block the tile's keys lie in."""
+    sz = block_sparse.Sizes.of(cfg)
+    params = tfm.init_params(
+        cfg, jax.random.PRNGKey(config["benchmark"]["weights_seed"]))
+    tokens = jnp.asarray(
+        np.random.default_rng(55).integers(0, 256, n).astype(np.int32))[None]
+    seg = jnp.ones((1, n), jnp.int32)
+
+    @jax.jit
+    def choice(params):
+        blk = {name: params["blocks"][name][0] for name in (
+            "ln1", "wq", "wk", "wv", "q_norm", "k_norm")}
+        x = tfm._embed(params, cfg, tokens, jnp.arange(n)[None])
+        q, k, _ = tfm._qkv(
+            tfm._norm(x, blk["ln1"], None, cfg), blk, cfg, None, None)
+        return block_sparse._row_selection(
+            q[0], k[0], seg[0], sz, block_sparse.QUERY_CHUNK)[:2]
+
+    chosen, key_block = (np.asarray(x) for x in choice(params))
+    nt = n // tile
+    # [q tile, key head, block]: some query of the tile chose the block
+    any_q = chosen.reshape(nt, tile, *chosen.shape[1:]).any(axis=1)
+    blocks = key_block.reshape(nt, tile)
+    live = np.zeros((nt, nt, chosen.shape[1]), bool)
+    for j in range(nt):
+        live[:, j] = any_q[:, :, np.unique(blocks[j])].any(axis=-1)
+    below = np.tril(np.ones((nt, nt), bool))[..., None]
+    out = {
+        "n_tokens": n, "tile": tile,
+        "blocks_a_query": float(chosen.sum(-1).mean()),
+        "empty_tile_share": float(1 - live[below[..., 0]].mean()),
+    }
+    print("tiles,", out, flush=True)
     return out
 
 
@@ -204,10 +257,16 @@ def main():
     out = {"platform": jax.default_backend()}
     if what in ("forms", "all"):
         out["forms"] = forms(cfg, n)
+    if what in ("tiles", "all"):
+        out["tiles"] = empty_tiles(cfg, config, n)
     if what in ("tolerances", "all"):
         out["tolerances"] = tolerances(cfg, config, n)
     os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/sala_controls.json", "w") as f:
+    path = "chiprun_out/sala_controls.json"
+    if os.path.exists(path):  # an earlier part of the same call
+        with open(path) as f:
+            out = {**json.load(f), **out}
+    with open(path, "w") as f:
         json.dump(out, f, indent=1)
     # The control has to be refused: exit code 0 when it is.
     return 0 if out.get("tolerances", {}).get("refused", True) else 1

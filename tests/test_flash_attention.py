@@ -5,6 +5,7 @@ Models the reference's CUDA-extension parity tests
 kernel code in pallas interpret mode on CPU.
 """
 
+import functools
 import re
 
 import numpy as np
@@ -170,6 +171,92 @@ _BF16_CASES = {
     "window_gqa_d128": (1024, 4, 1, 128, 256, [600, 400]),
     "window_d256": (512, 2, 1, 256, 128, [500]),
 }
+
+
+class TestBlockChoice:
+    """The three kernels under a block choice (`BlockChoice`: a query sees
+    a key where `chosen[q, key_block[k]]`) against the `jnp` form of
+    `ops/block_sparse.py`, dense under the mask, on the choice its own
+    selection makes: compressed keys, block scores, forced and top blocks
+    at toy sizes (kernels of 8 every 4, blocks of 16, 6 a query, selection
+    from 128 tokens on).  One row of 512 tokens, 4 / 1 heads of 128: every
+    case runs the programs the first compiled."""
+
+    S, N_BLOCKS = 512, 34
+    # name -> sequence lengths
+    CASES = {
+        # one sparse sequence from index 0: a key's block is its index's
+        "one_sequence_from_0": [512],
+        # a dense short sequence, then a sparse one from index 37: its
+        # blocks of 16 keys straddle every tile's edge; padding after
+        "sparse_from_37_beside_a_dense_one": [37, 400],
+    }
+
+    @staticmethod
+    @jax.jit
+    def _select(q, k, seg):
+        from areal_tpu.ops import block_sparse
+
+        sz = block_sparse.Sizes(
+            kernel=8, stride=4, block=16, topk=6, init_blocks=1, window=32,
+            dense_len=128)
+        return jax.vmap(
+            lambda q, k, seg: block_sparse._row_selection(q, k, seg, sz, 128)
+        )(q, k, seg)[:2]
+
+    @staticmethod
+    @functools.partial(jax.jit, static_argnums=0)
+    def _out_and_grads(form, q, k, v, seg, chosen, key_block, w):
+        """`form`: "mask", "kernels", or "plain" (the kernels, no choice)."""
+        from areal_tpu.ops import block_sparse
+        from areal_tpu.ops.pallas.flash_attention import BlockChoice
+
+        def attend(q, k, v):
+            if form == "mask":
+                return jax.vmap(
+                    lambda *row: block_sparse._row_attend_mask(*row, 128)
+                )(q, k, v, seg, chosen, key_block)
+            choice = BlockChoice(chosen, key_block)
+            return flash_attention(
+                q, k, v, seg, choice=None if form == "plain" else choice)
+
+        out, vjp = jax.vjp(attend, q, k, v)
+        return (out, *vjp(w))
+
+    def _case(self, rng, name):
+        q, k, v, _ = _inputs(rng, b=1, s=self.S, hq=4, hkv=1, d=128)
+        lens = self.CASES[name]
+        seg = jnp.asarray(_packed_row(self.S, lens))
+        chosen, key_block = self._select(q, k, seg)
+        assert chosen.shape == (1, self.S, 1, self.N_BLOCKS)
+        # the choice is one: a dense sequence's queries see every block, a
+        # sparse one's 6
+        picked = np.asarray(chosen.sum(-1))[0, :, 0]
+        assert picked[sum(lens) - 1] == 6
+        assert len(lens) == 1 or picked[lens[0] - 1] == self.N_BLOCKS
+        real = (seg > 0)[..., None, None]
+        w = jnp.asarray(rng.normal(size=q.shape), jnp.float32) * real
+        return (q, k, v, seg, chosen, key_block, w), real
+
+    @pytest.mark.parametrize("name", list(CASES))
+    def test_forward_and_gradients_match_the_mask_form(self, rng, name):
+        args, real = self._case(rng, name)
+        want, *want_grads = self._out_and_grads("mask", *args)
+        got, *got_grads = self._out_and_grads("kernels", *args)
+        np.testing.assert_allclose(
+            got, jnp.where(real, want, 0), atol=TOL["float32"])
+        _assert_grads_close(got_grads, want_grads, TOL["float32"])
+
+    def test_every_block_chosen_is_the_plain_kernel_bit_for_bit(self, rng):
+        args, _ = self._case(rng, "sparse_from_37_beside_a_dense_one")
+        every = jnp.ones_like(args[4])
+        plain = self._out_and_grads("plain", *args)
+        chosen = self._out_and_grads("kernels", *args[:4], every, *args[5:])
+        for got, want in zip(chosen, plain):
+            np.testing.assert_array_equal(got, want)
+        # and the choice is not every block: the selection shows
+        selected = self._out_and_grads("kernels", *args)[0]
+        assert np.abs(np.asarray(selected - plain[0])).max() > 0.1
 
 
 class TestTripWidths:
@@ -639,6 +726,31 @@ class TestTPULowering:
             assert f"%{kernel}" in text
             # the trip the chooser gave this shape
             assert re.search(scope, text), scope
+
+    def test_flash_under_a_block_choice_compiles_for_v5e(
+            self, one_chip, _no_persistent_cache):
+        """`sala-docrl8-longctx`'s call — one 13,312-token row, 32 / 2 heads,
+        a choice over 210 blocks of 64 keys: beside K/V a step holds the
+        keys' one-hot (256 bytes a token) and the q block's four windows."""
+        from areal_tpu.ops.pallas.flash_attention import BlockChoice
+
+        def sds(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        b, s, n_q, n_kv, d, n_blocks = 1, 13312, 32, 2, 128, 210
+        q, kv = (sds((b, s, h, d), jnp.bfloat16) for h in (n_q, n_kv))
+        ints = sds((b, s), jnp.int32)
+
+        def loss(q, k, v, seg, chosen, key_block):
+            return flash_attention(
+                q, k, v, seg, choice=BlockChoice(chosen, key_block)
+            ).astype(jnp.float32).sum()
+
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, kv, kv, ints, sds((b, s, n_kv, n_blocks), jnp.bool_), ints
+        ).compile().as_text()
+        for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
+            assert f"%{kernel}" in text
 
     # The paged attention kernel's calls: (lanes, table columns, pool pages,
     # layers, q heads, kv heads, pool dtype).

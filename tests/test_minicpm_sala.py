@@ -287,6 +287,116 @@ def test_loss_and_gradients_match_the_reference(cfg, params):
             err_msg=name)
 
 
+# ---------------------------------- the selected attention on the flash kernels
+
+
+def _row_of_384(cfg, seed=12):
+    """A dense short sequence, a sparse one from index 60 — its blocks of
+    16 keys straddle every tile's edge — and a third, in a row of three
+    tiles of 128."""
+    seqs = _sequences(cfg, (60, 200, 117), seed=seed)
+    return _packed(seqs, pads=7)
+
+
+@pytest.mark.parametrize("program", ["forward", "prefill"])
+def test_the_kernel_form_is_the_mask_form(cfg, params, program):
+    """`use_flash=True` (the flash kernels under the block choice,
+    interpreted here) against `use_flash=False` (dense under the mask)."""
+    tokens, segs = _row_of_384(cfg)
+    if program == "forward":
+        # a pad's logits are no one's (the kernels write zeros there)
+        run = lambda flash: jax.jit(  # noqa: E731
+            lambda p: tfm.forward(
+                p, cfg, tokens, segs, use_flash=flash)[:, :-7]
+        )(params)
+    else:
+        # one sequence a row, right-aligned, as the static program lays
+        # prompts out: the logits of the last token and what the cache keeps
+        seq = _sequences(cfg, (300,), seed=13)[0]
+        tok = jnp.asarray(np.concatenate([np.zeros(84, np.int32), seq]))[None]
+        seg = (jnp.arange(384) >= 84).astype(jnp.int32)[None]
+        cache = tfm.init_kv_cache(cfg, 1, 448, dtype=jnp.float32)
+        run = lambda flash: jax.jit(  # noqa: E731
+            lambda p: tfm.prefill(p, cfg, tok, seg, cache, use_flash=flash)
+        )(params)
+    for got, want in zip(jax.tree.leaves(run(True)), jax.tree.leaves(run(False))):
+        np.testing.assert_allclose(got, want, **TOL)
+
+
+def test_the_kernel_forms_gradient_of_a_two_layer_plan(params):
+    """Selection then Lightning attention, each branch rematerialised on
+    its own (`_REMAT_BY_BRANCH`): the flash `custom_vjp` under the choice
+    against the `jnp` form's `jax.grad`, every leaf."""
+    two = FAMILY.config_from_hf(_toy_hf(
+        num_hidden_layers=2, mixer_types=["minicpm4", "lightning-attn"]))
+    two = dataclasses.replace(two, param_dtype="float32")
+    assert two.plan.unit == ((SPARSE, MLP), (LIGHTNING, MLP))
+    p = _params(two, seed=7)
+    tokens, segs = _row_of_384(two)
+
+    def score(p, flash):
+        logits = tfm.forward(
+            p, two, tokens, segs, remat="full", use_flash=flash)
+        lp = jax.nn.log_softmax(logits[0, :-1], axis=-1)
+        picked = jnp.take_along_axis(lp, tokens[0, 1:, None], axis=-1)[:, 0]
+        return jnp.sum(picked * (segs[0, 1:] > 0))
+
+    grad = jax.jit(jax.value_and_grad(score), static_argnums=1)
+    loss, got = grad(p, True)
+    want_loss, want = grad(p, False)
+    np.testing.assert_allclose(loss, want_loss, rtol=1e-4)
+    for (path, g), w in zip(
+            jax.tree_util.tree_flatten_with_path(got)[0], jax.tree.leaves(want)):
+        name = jax.tree_util.keystr(path)
+        scale = float(jnp.abs(w).max())
+        assert scale > 0, name
+        np.testing.assert_allclose(
+            np.asarray(g) / scale, np.asarray(w) / scale, atol=2e-3,
+            err_msg=name)
+
+
+def test_by_itself_the_kernel_form_serves_the_gradient_program_alone(
+        cfg, monkeypatch):
+    """`use_flash=None` on a TPU backend: the flash kernels under the
+    choice where the stack is differentiated under a remat policy, the
+    `jnp` form in `forward` and prefill (their programs are the ones the
+    reference check runs at set-up); True forces the kernels anywhere."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    shapes = jax.eval_shape(
+        lambda: tfm.init_params(cfg, jax.random.PRNGKey(0)))
+    ints = jax.ShapeDtypeStruct((1, 384), jnp.int32)
+
+    def traced(f):
+        return str(jax.make_jaxpr(f)(shapes, ints, ints))
+
+    def loss(p, tok, seg):
+        return jnp.sum(tfm.hidden_states(p, cfg, tok, seg, remat="full")[0])
+
+    def fill(p, tok, seg, **kw):
+        cache = tfm.init_kv_cache(cfg, 1, 448, dtype=jnp.float32)
+        return tfm.prefill(p, cfg, tok, seg, cache, **kw)
+
+    grad = traced(jax.grad(loss))
+    assert all(k in grad for k in ("flash_fwd", "flash_dq", "flash_dkv"))
+    assert "flash_fwd" not in traced(lambda *a: tfm.forward(a[0], cfg, *a[1:]))
+    assert "flash_fwd" not in traced(fill)
+    assert "flash_fwd" in traced(lambda *a: fill(*a, use_flash=True))
+
+
+def test_the_kernel_form_refuses_a_row_of_no_whole_tiles():
+    sz = block_sparse.Sizes(
+        kernel=8, stride=4, block=16, topk=5, init_blocks=1, window=32,
+        dense_len=64)
+    q = jnp.zeros((1, 208, 4, 16))
+    with pytest.raises(ValueError, match="no row of 208 tokens"):
+        block_sparse.packed_attention(
+            q, q[:, :, :2], q[:, :, :2], jnp.ones((1, 208), jnp.int32), sz,
+            use_flash=True)
+    assert not block_sparse.kernel_fits(208, sz)
+    assert block_sparse.kernel_fits(13312, block_sparse.Sizes.of(
+        bench_run.model_config(files.load_json("configs", CONFIG))))
+
+
 def test_the_fused_logprob_head_divides_by_logits_scaling(cfg, params):
     seq = _sequences(cfg, (140,), seed=3)[0]
     tokens, segs = _packed([seq], pads=0)
