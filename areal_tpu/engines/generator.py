@@ -51,6 +51,7 @@ from areal_tpu.engines.offload import HostOffloadMixin
 from areal_tpu.engines.packing import decode_bucket_len as bucket_len
 from areal_tpu.engines.paging import PageAllocator, PagePoolExhausted
 from areal_tpu.models import transformer as tfm
+from areal_tpu.models.branches import LoopStep
 from areal_tpu.models.config import ModelConfig
 from areal_tpu.ops.sampling import sample_token
 from areal_tpu.parallel import sharding
@@ -452,12 +453,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         self.decode_compiles = 0
         self.cache_copy_bytes = 0
         self.last_pool_stats: Dict[str, Any] = {}
-        # MoE decode counters of the current generate() call, summed on the
-        # device inside the decode loop: [experts touched, fullest expert's
-        # rows, steps] (see _fold_moe_counters).
-        self._moe_decode_sums = np.zeros((_n_moe_counters(cfg),))
-        self._window_live_sums = np.zeros((2,))
-        self._sparse_sums = np.zeros((3,))
+        self._decode_sums = self._zero_decode_sums()
         # Serving-plane chunk counters of the current generate() call
         # (see _serving_counters); folded into last_pool_stats at its end.
         self._chunk_stats: Dict[str, Any] = _new_chunk_stats()
@@ -770,18 +766,21 @@ class GeneratorEngine(HostOffloadMixin, Engine):
 
             return self._assemble(sample, prompt_key, prompt_lens, results, n)
 
+    def _zero_decode_sums(self) -> Dict[str, np.ndarray]:
+        """The kinds' decode counters of a generate call, summed on the
+        device inside the decode loop (`Branch.counter`), by name."""
+        return {
+            name: np.zeros((counter.width(self.cfg),))
+            for name, counter in tfm.decode_counters(self.cfg).items()
+        }
+
     def _reset_call_counters(self) -> None:
         """The per-call counters, at the start of a generate call."""
         self.prefill_dispatches = 0
         self.decode_compiles = 0
         self.cache_copy_bytes = 0
         self.last_pool_stats = {}
-        self._moe_decode_sums = np.zeros((_n_moe_counters(self.cfg),))
-        self._window_live_sums = np.zeros((2,))
-        # Block-sparse layers, summed over a call's decode iterations and
-        # layers: keys read (chosen blocks x block + compressed rows, a key
-        # head's), keys cached, rows still under `sparse_dense_len`.
-        self._sparse_sums = np.zeros((3,))
+        self._decode_sums = self._zero_decode_sums()
         self.lanes_dispatched = 0
         self.lanes_live = 0
         self.lanes_slack = 0
@@ -2334,11 +2333,15 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             alloc.table[s, :sp],
         )
 
-    @property
+    @functools.cached_property
     def _has_state(self) -> bool:
         """Whether a request holds a slot of recurrent state beside its
-        pages on the serving plane (a plan with Mamba-2 layers)."""
-        return self.cfg.n_ssm_layers > 0
+        pages on the serving plane (a kind the chunk runs keeps a `state`:
+        Mamba-2 layers).  Asked several times a chunk: read off the table
+        once."""
+        return any(
+            "state" in b.cache and b.serve
+            for b in tfm.branches_of(self.cfg).values())
 
     @property
     def _paged_kernel(self) -> Optional[bool]:
@@ -2371,30 +2374,6 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         expert leaves themselves — asked of the placed params, outside
         the trace (`tfm.expert_leaves_in_place`); False for dense models."""
         return tfm.expert_leaves_in_place(self.cfg, self.params["blocks"])
-
-    def _fold_moe_counters(self) -> None:
-        """MoE decode counters into last_pool_stats: per decode step and MoE
-        layer, the experts with at least one row and the rows on the
-        fullest expert (means over every step of this generate()); and
-        which way the expert weights reached the ragged kernels (1: the
-        parameters' own buffers, 0: the layer scan's slices)."""
-        touched, rows_max, steps, *share = self._moe_decode_sums
-        if steps:
-            self.last_pool_stats.update(
-                moe_experts_touched=touched / steps,
-                moe_rows_per_expert_max=rows_max / steps,
-                moe_decode_steps=int(steps),
-                moe_expert_leaves_in_place=int(self._expert_leaves_in_place),
-            )
-            if share:
-                # One expert-parallel rank's share: (row, choice) pairs
-                # that fell to experts held here, of all the router made,
-                # over every decode step and layer.  Balanced routing
-                # reads n_experts / router_width.
-                self.last_pool_stats.update(
-                    moe_rows_local=float(share[0]),
-                    moe_rows_routed=float(share[1]),
-                )
 
     # -- one fixed-shape chunk --
 
@@ -2435,91 +2414,33 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             prompt_tok[r, sp - len(toks):] = toks
             prompt_len[r] = len(toks)
 
+        cfg = self.cfg
         fn = self._get_gen_fn(b, sp, s_total, gconfig, with_cache)
         # What the cache holds beside k/v, from shapes alone.
         cache = jax.eval_shape(
-            lambda: tfm.init_kv_cache(
-                self.cfg, b, s_total, dtype=self.compute_dtype
-            )
+            lambda: tfm.init_kv_cache(cfg, b, s_total, dtype=self.compute_dtype)
         )
-
-        def nbytes(*xs):
-            return sum(x.size * x.dtype.itemsize for x in xs)
-
-        if cache.wk is not None:  # rings beside the full layers' windows
-            cfg = self.cfg
-            self.last_pool_stats.update(
-                window_cache_bytes=nbytes(cache.wk, cache.wv),
-                kv_cache_bytes=nbytes(cache.k, cache.v),
-                # every attention layer at s_max, as a cache without rings
-                kv_cache_bytes_unwindowed=2 * cache.wk.dtype.itemsize * (
-                    (cfg.n_attn_layers + cfg.n_window_layers) * b * s_total
-                    * cfg.kv_dim
-                ),
-                window_slots=b * cache.wk.shape[2],
-            )
-        if cache.state is not None and cache.conv is not None:
-            self.last_pool_stats.update(  # the two kinds of state
-                kv_cache_bytes=nbytes(cache.k, cache.v),
-                state_cache_bytes=nbytes(cache.state, cache.conv),
-            )
-        if cache.ck is not None:  # k/v, compressed keys, Lightning state
-            self.last_pool_stats.update(
-                kv_cache_bytes=nbytes(cache.k, cache.v),
-                compressed_cache_bytes=nbytes(cache.ck),
-                lightning_state_bytes=nbytes(cache.state),
-            )
-        if self.cfg.n_sconv_layers:  # tails beside the attention layers' k/v
-            cfg = self.cfg
-            self.last_pool_stats.update(
-                conv_cache_bytes=nbytes(cache.conv),
-                kv_cache_bytes=nbytes(cache.k, cache.v),
-                # k/v at every layer of the plan, as an all-attention model
-                kv_cache_bytes_all_attention=2 * cache.k.dtype.itemsize * (
-                    cfg.n_layers * b * s_total * cfg.kv_dim
-                ),
-            )
-        if cache.latent is not None:  # beside what per-head k/v would take
-            cfg = self.cfg
-            self.last_pool_stats.update(
-                latent_cache_bytes=nbytes(cache.latent),
-                kv_cache_bytes_as_heads=cache.latent.dtype.itemsize * (
-                    cfg.n_layers * b * s_total * cfg.n_kv_heads
-                    * (cfg.head_dim + cfg.v_head_dim)
-                ),
-            )
+        for branch in tfm.branches_of(cfg).values():
+            if branch.cache_stats:
+                self.last_pool_stats.update(
+                    branch.cache_stats(cfg, cache, b, s_total))
         with tracer.span("gen_chunk", cat="compute", b=b_real, sp=sp):
             with tracer.span("gen_dispatch", cat="compute"):
-                toks, logps, gen_len, *rest = fn(
+                toks, logps, gen_len, sums, *cache = fn(
                     self.params, prompt_tok, prompt_len, key
                 )
-                cache = rest.pop() if with_cache else None
             with tracer.span("gen_wait", cat="compute"):
                 toks, logps, gen_len = (
                     to_host(toks),
                     to_host(logps),
                     to_host(gen_len),
                 )
-                if self.cfg.n_sparse_layers:  # [read, cached, dense rows]
-                    self._sparse_sums += to_host(rest.pop()).astype(float)
-                    read, cached, dense = self._sparse_sums
-                    self.last_pool_stats.update(
-                        sparse_keys_read=float(read),
-                        sparse_keys_cached=float(cached),
-                        sparse_dense_rows=float(dense),
-                    )
-                if self.cfg.n_window_layers:  # [live ring entries, steps]
-                    live, steps = to_host(rest.pop()).astype(float)
-                    self._window_live_sums += (live, steps)
-                    self.last_pool_stats["window_slots_live"] = float(
-                        self._window_live_sums[0]
-                        / max(self._window_live_sums[1], 1.0)
-                    )
-                if rest:  # [experts touched, fullest expert's rows, steps]
-                    self._moe_decode_sums += to_host(rest[0]).astype(float)
-                    self._fold_moe_counters()
+                for name, counter in tfm.decode_counters(cfg).items():
+                    self._decode_sums[name] += to_host(sums[name]).astype(float)
+                    self.last_pool_stats.update(counter.report(
+                        self._decode_sums[name], cfg, self.params))
         if with_cache:
-            return toks, logps, gen_len, cache
+            return toks, logps, gen_len, cache[0]
         return toks, logps, gen_len
 
     def _get_gen_fn(
@@ -2537,6 +2458,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         max_new = g.max_new_tokens
         row_kernel = self._row_kernel
         expert_kernel = self._expert_kernel
+        counters = tfm.decode_counters(cfg)
         wave = self._prefill_wave_rows(b, sp)
 
         @jax.jit
@@ -2570,10 +2492,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
 
             def body(state):
                 (step, logits, key, done, gen_len, out_toks, out_logps,
-                 cache, *more) = state
-                sparse = more.pop() if cfg.n_sparse_layers else None
-                ring_live = more.pop() if cfg.n_window_layers else None
-                moe = more
+                 cache, sums) = state
                 key, sub = jax.random.split(key)
                 if g.min_new_tokens > 0:
                     logits = jnp.where(
@@ -2593,42 +2512,34 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                 gen_len = gen_len + (~done).astype(jnp.int32)
                 new_done = done | (tok == eos)
                 pos = prompt_len + step  # RoPE position per row
-                next_logits, cache, *counts = tfm.decode_step(
-                    params, cfg, tok, pos, cache, sp + step, valid_from,
-                    with_moe_counts=cfg.is_moe, experts_in_place=in_place,
+                slot = sp + step
+                next_logits, cache, given = tfm.decode_step(
+                    params, cfg, tok, pos, cache, slot, valid_from,
+                    with_counts=True, experts_in_place=in_place,
                     row_kernel=row_kernel, expert_kernel=expert_kernel,
-                    with_sparse_counts=cfg.n_sparse_layers > 0,
                 )
-                if sparse is not None:  # [layers, 3] of this iteration
-                    sparse = sparse + jnp.sum(
-                        counts.pop().reshape(-1, 3), axis=0)
-                if cfg.is_moe:
-                    moe = [moe[0] + _moe_step_counters(counts[0], cfg, bsz)]
-                if ring_live is not None:
-                    # (row, ring entry) pairs this step's window layers
-                    # each read, and 1 for the step: summed here, no sync.
-                    live = tfm.ring_valid(
-                        sp + step, valid_from, cache.wk.shape[2])
-                    moe = [*moe, ring_live + jnp.stack(
-                        [jnp.sum(live).astype(jnp.float32), jnp.float32(1.0)])]
-                if sparse is not None:
-                    moe = [*moe, sparse]
+                # What each kind's counter makes of this step: summed here,
+                # no sync.
+                at = LoopStep(slot, valid_from, cache, bsz)
+                sums = {
+                    name: sums[name] + counter.step(given.get(name), cfg, at)
+                    for name, counter in counters.items()
+                }
                 return (
                     step + 1, next_logits, key, new_done, gen_len,
-                    out_toks, out_logps, cache, *moe,
+                    out_toks, out_logps, cache, sums,
                 )
 
-            state = (0, logits0, key, done, gen_len, out_toks, out_logps, cache)
-            if cfg.is_moe:  # two sums + the steps they run over (+ share)
-                state += (jnp.zeros((_n_moe_counters(cfg),), jnp.float32),)
-            if cfg.n_window_layers:  # live ring entries + the steps
-                state += (jnp.zeros((2,), jnp.float32),)
-            if cfg.n_sparse_layers:  # keys read, keys cached, dense rows
-                state += (jnp.zeros((3,), jnp.float32),)
-            state = jax.lax.while_loop(cond, body, state)
-            _, _, _, _, gen_len, out_toks, out_logps, cache, *moe = state
+            sums = {
+                name: jnp.zeros((counter.width(cfg),), jnp.float32)
+                for name, counter in counters.items()
+            }
+            state = jax.lax.while_loop(cond, body, (
+                0, logits0, key, done, gen_len, out_toks, out_logps, cache,
+                sums))
+            _, _, _, _, gen_len, out_toks, out_logps, cache, sums = state
             # `with_cache`: what the loop leaves in the cache, last.
-            return (out_toks, out_logps, gen_len, *moe) + (
+            return (out_toks, out_logps, gen_len, sums) + (
                 (cache,) if with_cache else ()
             )
 
@@ -2700,31 +2611,6 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             lambda i, r: results[(i, r)],
             prompt_lens=prompt_lens,
         )
-
-
-def _n_moe_counters(cfg) -> int:
-    """Length of `_moe_step_counters`' vector for this config."""
-    return 5 if cfg.expert_share else 3
-
-
-def _moe_step_counters(counts: jax.Array, cfg, n_rows: int) -> jax.Array:
-    """One decode step's rows per expert [L, E] -> f32 [3]: experts with at
-    least one row and the fullest expert's rows (both means over layers),
-    and 1 for the step — what the decode loop sums with no host sync.  A
-    rank's share of the experts (`cfg.expert_share`) adds two: the rows
-    that reached experts held here, and the rows the router sent anywhere
-    (`n_rows` tokens x k choices x L layers)."""
-    out = [
-        jnp.mean(jnp.sum(counts > 0, axis=-1).astype(jnp.float32)),
-        jnp.mean(jnp.max(counts, axis=-1).astype(jnp.float32)),
-        jnp.float32(1.0),
-    ]
-    if cfg.expert_share:
-        out += [
-            jnp.sum(counts).astype(jnp.float32),
-            jnp.float32(n_rows * cfg.n_experts_per_tok * counts.shape[0]),
-        ]
-    return jnp.stack(out)
 
 
 def assemble_rollout(

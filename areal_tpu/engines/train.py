@@ -244,7 +244,9 @@ class TrainEngine(HostOffloadMixin, Engine):
         self.cfg = cfg
         # The band the window layers' flash schedule keeps, and with it one
         # more of `_grid_counts`' keys.
-        self._flash_window = cfg.attn_window if cfg.n_window_layers else None
+        self._flash_window = next(
+            (b.flash_window(cfg) for b in tfm.branches_of(cfg).values()
+             if b.flash_window), None)
         self._grid_keys = _GRID_COUNTS + (
             (_WINDOW_TILES,) if self._flash_window else ())
         self.mesh = mesh
@@ -406,23 +408,13 @@ class TrainEngine(HostOffloadMixin, Engine):
         ]
 
     def _grad_compiler_options(self) -> Dict[str, Any]:
-        """What the gradient programs are compiled with beside the defaults:
-        where they run the Gated DeltaNet's rule on its Pallas sweep
-        (`linear_attention.chunk_kernel_form`), XLA:TPU's scheduler is held to
-        half of the memory it may spend on its own overlap.  With the
-        rule's blocks in VMEM the 8,192-token program's temporaries fall
-        from 10.2 GB to 5.8, and with that room the scheduler writes three
-        times the instructions for the same work — 284 MB where the `jnp`
-        form's program, compiled against its own memory need, takes 98 —
-        and a loaded program is resident HBM (`peak_hbm_gb` + 1.6%).  Held
-        to half, the two programs take 96 + 87 MB and a step 0.3% longer
-        (PERF.md section 6, PR 52)."""
-        from areal_tpu.models.linear_attention import chunk_kernel_form
-
-        if self.cfg.n_linear_layers and chunk_kernel_form(
-                self.cfg, self._row_kernel):
-            return {"xla_tpu_scheduler_percent_shared_memory_limit": 50}
-        return {}
+        """What the gradient programs are compiled with beside the
+        defaults: what the plan's kinds ask for (`Branch.grad_options`)."""
+        out = {}
+        for branch in tfm.branches_of(self.cfg).values():
+            if branch.grad_options:
+                out.update(branch.grad_options(self.cfg, self._row_kernel))
+        return out
 
     def _get_grad_fn(self, loss_fn: Callable):
         if loss_fn in self._grad_fns:
@@ -471,37 +463,10 @@ class TrainEngine(HostOffloadMixin, Engine):
                             batch["tokens"].size * cfg.n_experts_per_tok,
                         ),
                     }
-                if cfg.has_recurrent_state or cfg.n_sconv_layers:
-                    # Every segment start is a restart of the recurrence
-                    # and of the conv inside a packed row.
-                    seg = batch["segment_ids"]
-                    starts = (seg[:, 1:] != seg[:, :-1]) & (seg[:, 1:] > 0)
-                if cfg.n_linear_layers:
-                    stats = {
-                        **stats,
-                        "linear_attn/segments_per_row": jnp.mean(
-                            (seg[:, 0] > 0) + jnp.sum(starts, axis=-1)
-                        ).astype(jnp.float32),
-                    }
-                if cfg.n_ssm_layers:
-                    # What the chunked scan ran over, summed over the Mamba
-                    # layers: chunks, and the restarts.
-                    n_chunks = seg.shape[0] * -(-seg.shape[1] // cfg.ssm_chunk)
-                    stats = {
-                        **stats,
-                        "ssm/chunks": jnp.float32(cfg.n_ssm_layers * n_chunks),
-                        "ssm/segment_restarts": cfg.n_ssm_layers * (
-                            jnp.sum(seg[:, 0] > 0) + jnp.sum(starts)
-                        ).astype(jnp.float32),
-                    }
-                if cfg.n_sconv_layers:
-                    # The short convolutions' restarts, summed over them.
-                    stats = {
-                        **stats,
-                        "sconv/segment_restarts": cfg.n_sconv_layers * (
-                            jnp.sum(seg[:, 0] > 0) + jnp.sum(starts)
-                        ).astype(jnp.float32),
-                    }
+                for name, branch in tfm.branches_of(cfg).items():
+                    if branch.train_stats:
+                        stats = {**stats, **branch.train_stats(
+                            cfg, cfg.plan.count(name), batch["segment_ids"])}
                 return total * loss_scale, stats
 
             with jax.named_scope("train/grad"):

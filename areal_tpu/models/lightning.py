@@ -35,11 +35,18 @@ Parameters (leaves of `params["blocks"]`, stacked [n_lightning_layers, ...]):
     lt_wo                [H * d, D]
 """
 
+import dataclasses
 from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from areal_tpu.models.branches import (
+    Branch,
+    HybridLayoutError,
+    Refusal,
+    nbytes,
+)
 from areal_tpu.models.config import ModelConfig
 from areal_tpu.ops.norms import apply_rotary, rms_norm
 
@@ -230,3 +237,71 @@ def lightning_step(
         state, y = lightning_step_jnp(state, q[:, 0], k[:, 0], v[:, 0])
         states = jax.lax.dynamic_update_index_in_dim(states, state, li, axis=0)
     return _out(y[:, None], gate, blk, cfg), states
+
+
+# The kind's record (`models/branches.py`).
+
+
+def _packed(ctx, h, blk):
+    if not ctx.with_state:
+        return lightning_forward(
+            h, blk, ctx.cfg, ctx.segment_ids, ctx.cos, ctx.sin), {}
+    out, state = lightning_forward(
+        h, blk, ctx.cfg, ctx.segment_ids, ctx.cos, ctx.sin, with_state=True)
+    return out, {"state": state}
+
+
+def _step(ctx, h, blk, cache, li):
+    out, states = lightning_step(
+        h, blk, ctx.cfg, cache.state, li, ctx.cos, ctx.sin)
+    return out, dataclasses.replace(cache, state=states), {}
+
+
+_CACHE = {  # a state and no tail
+    "state": lambda cfg, batch, s_max, dtype: (
+        (batch, cfg.lightning_n_heads, cfg.lightning_head_dim,
+         cfg.lightning_head_dim), jnp.float32),
+}
+
+
+def _matmul_params(cfg: ModelConfig) -> int:
+    """ONE Lightning-attention layer's matmul parameters (q, k, v, the
+    gate, the output projection), its recurrence counted as the 2 * d * d
+    multiply-adds a head's state takes per token (add k^T v, read q S)."""
+    w = cfg.lightning_dim
+    return 5 * cfg.hidden_dim * w + 2 * w * cfg.lightning_head_dim
+
+
+# minicpm_sala's two mixers refuse in the same words: the block-sparse
+# kind's record (`transformer.py`) holds this one too.
+SALA_REFUSAL = Refusal(
+    HybridLayoutError,
+    "block-sparse attention beside Lightning attention (minicpm_sala) runs "
+    "under data and fsdp sharding only: the selection's compressed keys and "
+    "the Lightning state are not split over `model`, neither the selection "
+    "nor the chunked recurrence has a ring over a split sequence, and the "
+    "pipeline's stage scans one kind of layer (PERF.md section 7)",
+    "block-sparse attention beside Lightning attention (minicpm_sala) "
+    "generates on the static decode program only: the serving plane's "
+    "ragged paged attention has no selection (compressed keys beside the "
+    "pages, chosen pages a lane), and a Lightning layer's state has no slot "
+    "beside the pool yet (PERF.md section 7)",
+)
+
+# `remat_alone`: at rows of 13 k tokens a Lightning or block-sparse mixer's
+# residuals (2.5 GB: the chunked recurrence's fp32 blocks) and the MLP's
+# (1.5 GB at a width of 16,384) do not fit beside the state TOGETHER; a
+# branch at a time the backward holds one of them, for one more saved
+# [B, S, D] a layer and no more recompute.
+BRANCH = Branch(
+    leaves=LIGHTNING_LEAVES,
+    init=init_lightning,
+    cache=_CACHE,
+    packed=_packed,
+    step=_step,
+    refusal=SALA_REFUSAL,
+    remat_alone=True,
+    matmul_params=_matmul_params,
+    cache_stats=lambda cfg, cache, batch, s_max: {
+        "lightning_state_bytes": nbytes(cache.state)},
+)

@@ -48,11 +48,19 @@ Parameters (leaves of `params["blocks"]`, stacked [n_linear_layers, ...]):
     la_wo    [value_dim, D]
 """
 
+import dataclasses
 from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from areal_tpu.models.branches import (
+    Branch,
+    HybridLayoutError,
+    Refusal,
+    nbytes,
+    segment_starts,
+)
 from areal_tpu.models.config import ModelConfig
 
 CHUNK = 64
@@ -400,3 +408,104 @@ def linear_attn_step(
                 states, state, li, axis=0)
     y = _out(o, z, blk, cfg)
     return y[:, None], states, tails
+
+
+# The kind's record (`models/branches.py`).
+
+
+def _packed(ctx, h, blk):
+    if not ctx.with_state:
+        return linear_attn_forward(
+            h, blk, ctx.cfg, ctx.segment_ids, kernel=ctx.row_kernel), {}
+    out, state, tail = linear_attn_forward(
+        h, blk, ctx.cfg, ctx.segment_ids, with_state=True,
+        kernel=ctx.row_kernel)
+    return out, {"state": state, "conv": tail}
+
+
+def _step(ctx, h, blk, cache, li):
+    """Layer li of the state and the conv tail stepped in place (carried
+    like k/v)."""
+    out, states, tails = linear_attn_step(
+        h, blk, ctx.cfg, cache.state, cache.conv, li, ctx.row_kernel)
+    return out, dataclasses.replace(cache, state=states, conv=tails), {}
+
+
+_CACHE = {
+    "state": lambda cfg, batch, s_max, dtype: (
+        (batch, cfg.linear_n_v_heads, cfg.linear_k_head_dim,
+         cfg.linear_v_head_dim), jnp.float32),
+    "conv": lambda cfg, batch, s_max, dtype: (
+        (batch, cfg.linear_conv_kernel - 1, cfg.linear_conv_dim), dtype),
+}
+
+
+def _matmul_params(cfg: ModelConfig) -> int:
+    """ONE Gated DeltaNet layer's projections plus its recurrence counted
+    as the 3 * d_k * d_v multiply-adds a value head's state takes per token
+    (S^T k, S^T q, k d^T)."""
+    h, hv = cfg.hidden_dim, cfg.linear_n_v_heads
+    return (
+        h * (cfg.linear_conv_dim + cfg.linear_value_dim + 2 * hv)
+        + cfg.linear_value_dim * h
+        + 3 * hv * cfg.linear_k_head_dim * cfg.linear_v_head_dim
+    )
+
+
+def state_cache_stats(cfg: ModelConfig, cache, batch: int, s_max: int):
+    """The two kinds of state of a static program's cache: k/v beside a
+    recurrent branch's state and conv tails."""
+    return {
+        "kv_cache_bytes": nbytes(cache.k, cache.v),
+        "state_cache_bytes": nbytes(cache.state, cache.conv),
+    }
+
+
+def _grad_options(cfg: ModelConfig, row_kernel):
+    """Where the gradient programs run the rule on its Pallas sweep
+    (`chunk_kernel_form`), XLA:TPU's scheduler is held to half of the memory
+    it may spend on its own overlap.  With the rule's blocks in VMEM the
+    8,192-token program's temporaries fall from 10.2 GB to 5.8, and with
+    that room the scheduler writes three times the instructions for the
+    same work — 284 MB where the `jnp` form's program, compiled against its
+    own memory need, takes 98 — and a loaded program is resident HBM
+    (`peak_hbm_gb` + 1.6%).  Held to half, the two programs take 96 + 87 MB
+    and a step 0.3% longer (PERF.md section 6, PR 52)."""
+    if chunk_kernel_form(cfg, row_kernel):
+        return {"xla_tpu_scheduler_percent_shared_memory_limit": 50}
+    return {}
+
+
+def _train_stats(cfg: ModelConfig, n_layers: int, seg: jax.Array):
+    starts = segment_starts(seg)
+    return {
+        "linear_attn/segments_per_row": jnp.mean(
+            (seg[:, 0] > 0) + jnp.sum(starts, axis=-1)
+        ).astype(jnp.float32),
+    }
+
+
+BRANCH = Branch(
+    leaves=LINEAR_LEAVES,
+    init=init_linear_attn,
+    cache=_CACHE,
+    packed=_packed,
+    step=_step,
+    refusal=Refusal(
+        HybridLayoutError,
+        "a hybrid layer pattern (Gated DeltaNet layers beside attention "
+        "layers) runs under data and fsdp sharding only: no tensor "
+        "parallelism over DeltaNet heads, the chunked delta rule has no "
+        "ring over a split sequence, and a pipeline stage would have to be "
+        "whole periods (PERF.md section 7)",
+        "recurrent state has no slot on the serving plane yet: a hybrid "
+        "layer pattern (linear-attention layers) generates on the static "
+        "decode program only (at most max_decode_batch requests, no stop "
+        "sequences, no speculative decoding, max_new_tokens within "
+        "static_path_max_new)",
+    ),
+    matmul_params=_matmul_params,
+    cache_stats=state_cache_stats,
+    train_stats=_train_stats,
+    grad_options=_grad_options,
+)

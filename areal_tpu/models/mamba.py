@@ -63,14 +63,25 @@ Parameters (leaves of `params["blocks"]`, stacked [n_ssm_layers, ...]):
     ssm_out     [d_inner, D]
 """
 
+import dataclasses
 import math
 from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from areal_tpu.models.branches import (
+    Branch,
+    HybridLayoutError,
+    Refusal,
+    segment_restarts,
+)
 from areal_tpu.models.config import ModelConfig
-from areal_tpu.models.linear_attention import causal_conv, conv_tail_at
+from areal_tpu.models.linear_attention import (
+    causal_conv,
+    conv_tail_at,
+    state_cache_stats,
+)
 
 Params = Dict[str, jax.Array]
 
@@ -544,3 +555,83 @@ def ssm_ragged(
                 x, lanes)
         y = y.reshape(h.shape[0], cfg.ssm_inner_dim)
     return _out(y, z, blk, cfg), states, tails
+
+
+# The kind's record (`models/branches.py`).
+
+
+def _packed(ctx, h, blk):
+    if not ctx.with_state:
+        return ssm_forward(h, blk, ctx.cfg, ctx.segment_ids), {}
+    out, state, tail = ssm_forward(
+        h, blk, ctx.cfg, ctx.segment_ids, with_state=True)
+    return out, {"state": state, "conv": tail}
+
+
+def _step(ctx, h, blk, cache, li):
+    """Layer li of the state and the conv tail stepped in place (carried
+    like k/v)."""
+    out, states, tails = ssm_step(h, blk, ctx.cfg, cache.state, cache.conv, li)
+    return out, dataclasses.replace(cache, state=states, conv=tails), {}
+
+
+def _serve(ctx, h, blk, pools, li, at):
+    """`at`: (the layer's place among the unit's Mamba layers, the scan
+    step): its own buffers, and its place in them."""
+    j, pi = at
+    state, conv = list(pools.state), list(pools.conv)
+    out, state[j], conv[j] = ssm_ragged(
+        h[:, 0], blk, ctx.cfg, state[j], conv[j], pi, ctx.lanes,
+        kernel=ctx.paged_kernel)
+    return out[:, None], dataclasses.replace(
+        pools, state=tuple(state), conv=tuple(conv))
+
+
+_CACHE = {
+    "state": lambda cfg, batch, s_max, dtype: (
+        (batch, cfg.ssm_n_heads, cfg.ssm_head_dim, cfg.ssm_state_dim),
+        jnp.float32),
+    "conv": lambda cfg, batch, s_max, dtype: (
+        (batch, cfg.ssm_conv_kernel - 1, cfg.ssm_conv_dim), dtype),
+}
+
+
+def _matmul_params(cfg: ModelConfig) -> int:
+    """ONE Mamba-2 layer's matmul parameters, its recurrence counted as
+    the multiply-adds a token takes: 2 * d_inner * N for the state (add the
+    token's outer product, read y = S C) — what the decode step does; the
+    chunked form's extra in-chunk products are not counted as useful."""
+    h, di = cfg.hidden_dim, cfg.ssm_inner_dim
+    return h * cfg.ssm_in_dim + di * h + 2 * di * cfg.ssm_state_dim
+
+
+def _train_stats(cfg: ModelConfig, n_layers: int, seg: jax.Array):
+    """What the chunked scan ran over, summed over the Mamba layers:
+    chunks, and the restarts."""
+    n_chunks = seg.shape[0] * -(-seg.shape[1] // cfg.ssm_chunk)
+    return {
+        "ssm/chunks": jnp.float32(n_layers * n_chunks),
+        "ssm/segment_restarts": n_layers * segment_restarts(seg),
+    }
+
+
+BRANCH = Branch(
+    leaves=SSM_LEAVES,
+    init=init_ssm,
+    cache=_CACHE,
+    packed=_packed,
+    step=_step,
+    serve=_serve,
+    refusal=Refusal(
+        HybridLayoutError,
+        "Mamba-2 mixers in two-branch layers run under data and fsdp "
+        "sharding only: the Mamba heads, their conv channels and their "
+        "state are not split over `model`, the chunked scan has no ring "
+        "over a split sequence, and the pipeline's stage scans one kind of "
+        "layer (PERF.md section 7)",
+        None,  # a slot of state beside the page pool (`ssm_ragged`)
+    ),
+    matmul_params=_matmul_params,
+    cache_stats=state_cache_stats,
+    train_stats=_train_stats,
+)

@@ -24,11 +24,19 @@ Parameters (leaves of `params["blocks"]`, stacked [n_sconv_layers, ...]):
     sc_out   [D, D]
 """
 
+import dataclasses
 from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from areal_tpu.models.branches import (
+    Branch,
+    HybridLayoutError,
+    Refusal,
+    nbytes,
+    segment_restarts,
+)
 from areal_tpu.models.config import ModelConfig
 from areal_tpu.models.linear_attention import causal_conv, conv_tail_at
 
@@ -106,3 +114,77 @@ def sconv_step(
             tails, window[:, 1:], li, axis=0
         )
     return _out(c_gate, conv, blk)[:, None], tails
+
+
+# The kind's record (`models/branches.py`).
+
+
+def _packed(ctx, h, blk):
+    if not ctx.with_state:
+        return sconv_forward(h, blk, ctx.cfg, ctx.segment_ids), {}
+    out, tail = sconv_forward(
+        h, blk, ctx.cfg, ctx.segment_ids, with_state=True)
+    return out, {"conv": tail}
+
+
+def _step(ctx, h, blk, cache, li):
+    """Layer li of the tails shifted in place."""
+    out, tails = sconv_step(h, blk, ctx.cfg, cache.conv, li)
+    return out, dataclasses.replace(cache, conv=tails), {}
+
+
+_CACHE = {  # a tail and no state
+    "conv": lambda cfg, batch, s_max, dtype: (
+        (batch, cfg.sconv_kernel - 1, cfg.hidden_dim), dtype),
+}
+
+
+def _matmul_params(cfg: ModelConfig) -> int:
+    """ONE gated short-convolution layer's matmul parameters: in_proj [D,
+    3 D] and out_proj [D, D], and the depthwise conv's K multiply-adds a
+    channel."""
+    h = cfg.hidden_dim
+    return 4 * h * h + cfg.sconv_kernel * h
+
+
+def _cache_stats(cfg: ModelConfig, cache, batch: int, s_max: int):
+    """The tails beside the attention layers' k/v, and k/v at every layer
+    of the plan, as an all-attention model would keep."""
+    return {
+        "conv_cache_bytes": nbytes(cache.conv),
+        "kv_cache_bytes": nbytes(cache.k, cache.v),
+        "kv_cache_bytes_all_attention": 2 * cache.k.dtype.itemsize * (
+            cfg.n_layers * batch * s_max * cfg.kv_dim
+        ),
+    }
+
+
+def _train_stats(cfg: ModelConfig, n_layers: int, seg: jax.Array):
+    """The short convolutions' restarts, summed over them."""
+    return {"sconv/segment_restarts": n_layers * segment_restarts(seg)}
+
+
+BRANCH = Branch(
+    leaves=SCONV_LEAVES,
+    init=init_sconv,
+    cache=_CACHE,
+    packed=_packed,
+    step=_step,
+    refusal=Refusal(
+        HybridLayoutError,
+        "gated short-convolution layers beside attention layers run under "
+        "data and fsdp sharding only: the conv's channels and its cached "
+        "tail are not split over `model`, the conv has no halo over a split "
+        "sequence, and the pipeline's stage scans one kind of layer "
+        "(PERF.md section 7)",
+        "a short convolution's tail has no slot beside the page pool on the "
+        "serving plane yet, and its chunk has one kind of layer and none "
+        "before the scan: gated short-convolution layers beside attention "
+        "layers generate on the static decode program only (at most "
+        "max_decode_batch requests, no stop sequences, no speculative "
+        "decoding, max_new_tokens within static_path_max_new)",
+    ),
+    matmul_params=_matmul_params,
+    cache_stats=_cache_stats,
+    train_stats=_train_stats,
+)

@@ -14,11 +14,13 @@ real_llm_base.py (blocks, heads) — re-designed for XLA:
 - `is_critic` swaps the LM head for a scalar value head
   (reference: real_llm_base.py:358-453).
 - A layer is a tuple of residual branches x += f(norm(x)) and the model is
-  `cfg.plan`: prefix + unit x repeats.  Every program (the train stack
-  `_blocks`, `prefill`, `decode_step`) steps the prefix, then scans the
-  repeats with a unit's layers unrolled, each branch through the program's
-  own table; each branch's leaves are stacked over the layers that have it
-  (`_unit_view`, `_unit_layer`, `_LEAF_BRANCHES`).
+  `cfg.plan`: prefix + unit x repeats.  ONE function walks it (`_walk`: the
+  prefix, then a scan of the repeats with a unit's layers unrolled) and
+  every program (the train stack `_blocks`, `prefill`, `decode_step`, the
+  serving chunk) is a caller of it that hands each branch to its kind's
+  record (`models/branches.py`: `BRANCHES`, filled at the end of this
+  module); each branch's leaves are stacked over the layers that have it
+  (`_unit_view`, `_unit_layer`, `Branch.leaves`).
 
 Functions:
     init_params(cfg, key)                                  -> params
@@ -38,12 +40,25 @@ Functions:
 import dataclasses
 import functools
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from areal_tpu.models import lightning, linear_attention, mamba, short_conv
+from areal_tpu.models.branches import (  # noqa: F401 - the errors' callers
+    BRANCHES,
+    Branch,
+    Counter,
+    Ctx,
+    HybridLayoutError,
+    LatentLayoutError,
+    Refusal,
+    WindowLayoutError,
+    branches_of,
+    nbytes,
+)
 from areal_tpu.models.config import (
     ATTENTION,
     DENSE_PREFIX,
@@ -59,32 +74,7 @@ from areal_tpu.models.config import (
     LayerKind,
     ModelConfig,
 )
-from areal_tpu.models.linear_attention import (
-    LINEAR_LEAVES,
-    init_linear_attn,
-    linear_attn_forward,
-    linear_attn_step,
-)
-from areal_tpu.models.lightning import (
-    LIGHTNING_LEAVES,
-    init_lightning,
-    lightning_forward,
-    lightning_step,
-)
-from areal_tpu.models.mamba import (
-    SSM_LEAVES,
-    init_ssm,
-    slot_lanes_of,
-    ssm_forward,
-    ssm_ragged,
-    ssm_step,
-)
-from areal_tpu.models.short_conv import (
-    SCONV_LEAVES,
-    init_sconv,
-    sconv_forward,
-    sconv_step,
-)
+from areal_tpu.models.mamba import slot_lanes_of
 from areal_tpu.ops import block_sparse
 from areal_tpu.ops.attention import (
     decode_attention,
@@ -171,14 +161,9 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     }
     if not cfg.is_pattern:  # a second branch a layer: a second norm
         blocks["ln2"] = norm_init((L, D), dtype)
-    if scanned(SSM):
-        blocks.update(init_ssm(cfg, ks[6], scanned(SSM), dense))
-    if scanned(GDN):
-        blocks.update(init_linear_attn(cfg, ks[6], scanned(GDN), dense))
-    if scanned(SCONV):
-        blocks.update(init_sconv(cfg, ks[6], scanned(SCONV), dense))
-    if scanned(LIGHTNING):
-        blocks.update(init_lightning(cfg, ks[6], scanned(LIGHTNING), dense))
+    for name, branch in BRANCHES.items():  # a mixer's leaves, its module's
+        if branch.init and scanned(name):
+            blocks.update(branch.init(cfg, ks[6], scanned(name), dense))
     if plan.prefix:
         # The leading dense layers: their own leaves, stacked over the
         # leading layers that own them under `dense_*`, the mixers the
@@ -193,11 +178,12 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             "wu": dense(kd[7], (K, D, F), D),
             "wd": dense(kd[8], (K, F, D), F),
         }
-        if plan.in_prefix(SCONV):
-            lead.update(init_sconv(
-                cfg, jax.random.fold_in(k_blocks, 2), plan.in_prefix(SCONV),
-                dense,
-            ))
+        for name, branch in BRANCHES.items():
+            if branch.init and plan.in_prefix(name):
+                lead.update(branch.init(
+                    cfg, jax.random.fold_in(k_blocks, 2),
+                    plan.in_prefix(name), dense,
+                ))
         blocks.update({DENSE_PREFIX + n: w for n, w in lead.items()})
     if cfg.norm_type == "layernorm":
         blocks["ln1_b"] = jnp.zeros((L, D), dtype)
@@ -1172,193 +1158,164 @@ def _rope(cfg: ModelConfig, positions: jax.Array):
         return full, rope_cos_sin(positions, dim, window_theta)
 
 
-def _packed_branches(
-    cfg: ModelConfig, segment_ids: jax.Array, *attn_args, with_state=False,
-    window_rope=None, ring: Optional[int] = None,
-    expert_kernel: Optional[bool] = False, row_kernel=None,
-    ck_slots: Optional[int] = None, backward: bool = False,
-):
-    """The table of the programs over packed rows (the train stack,
-    `prefill`): branch -> f(h, blk) -> (its output, what else it gives by
-    name — `aux`, the MoE aux loss; `counts`, rows per expert over the real
-    tokens [E] int32; and what it leaves in the cache, by `KVCache` field:
-    k and v, the one latent row a token and, `with_state`, a recurrent
-    branch's final state and conv tail at the row's last valid token, the
-    short convolution's tail there).
-    `attn_args`: `_attention`'s from `cos` on.  `window_rope`: the window
-    layers' (cos, sin) in a plan that has them (`_rope`); `ring`: the
-    entries of their cache, where the caller keeps what they leave (the
-    ring a row's last slots fill: `_ring_tail`).  `expert_kernel`: whether
-    the grouped dispatch's matmuls are the Pallas kernel `grouped_matmul`
-    (`expert_kernel_choice`).  `row_kernel`: the form of the Gated DeltaNet's
-    chunked rule (`linear_attn_forward`'s `kernel`: None, by what the code
-    can see; the caller's MESH where it has more than one device).
-    `ck_slots`: the kernels a row of the cache's compressed keys holds,
-    where the caller keeps what a block-sparse layer leaves.  `backward`:
-    whether the caller differentiates the stack under a remat policy
-    (`_blocks`: a gradient program) — where `use_flash` is None a
-    block-sparse layer takes the flash kernels there alone (`sparse`)."""
-    kernel = cfg.is_moe and expert_kernel_choice(cfg, expert_kernel)
+# The attention and MLP kinds over packed rows (`Branch.packed`): f(ctx, h,
+# blk) -> (its output, what else it gives by name — `aux`, the MoE aux
+# loss; `counts`, rows per expert over the real tokens [E] int32; and what
+# it leaves in the cache, by `KVCache` field).
 
-    def recurrent(forward):
-        def branch(h, blk):
-            if not with_state:
-                return forward(h, blk, cfg, segment_ids), {}
-            out, state, tail = forward(
-                h, blk, cfg, segment_ids, with_state=True
-            )
-            return out, {"state": state, "conv": tail}
 
-        return branch
+def _attention_packed(ctx: Ctx, h, blk):
+    return _attention(
+        h, blk, ctx.cfg, ctx.segment_ids, ctx.cos, ctx.sin, ctx.use_flash,
+        ctx.cp_mesh, ctx.cp_manual, ctx.cp_zigzag,
+        scope="full" if ctx.window_rope else None,
+    )
 
-    def experts(h, blk):
-        out, aux, counts = _mlp_moe(
-            h, blk, cfg, valid=segment_ids > 0, kernel=kernel
-        )
-        return out, {"aux": aux, "counts": counts}
 
-    def attention(h, blk):
-        return _attention(
-            h, blk, cfg, segment_ids, *attn_args,
-            scope="full" if window_rope else None,
-        )
-
-    def window(h, blk):
-        out, left = _attention(
-            h, blk, cfg, segment_ids, *window_rope, *attn_args[2:],
-            window=cfg.attn_window, scope="window",
-        )
-        if ring is None:
-            return out, {}
-        return out, {
-            "wk": _ring_tail(left["k"], ring), "wv": _ring_tail(left["v"], ring)
-        }
-
-    def short_conv(h, blk):
-        if not with_state:
-            return sconv_forward(h, blk, cfg, segment_ids), {}
-        out, tail = sconv_forward(h, blk, cfg, segment_ids, with_state=True)
-        return out, {"conv": tail}
-
-    def sparse(h, blk):
-        """Softmax attention by block selection (`ops/block_sparse.py`):
-        no positions, the sigmoid output gate; it leaves k, v and, for a
-        cache, the compressed keys of the row's sequence."""
-        b, s, _ = h.shape
-        q, k, v = _block_kv(h, blk, cfg, *attn_args[:2])
-        sizes = block_sparse.Sizes.of(cfg)
-        # By what the code can see (None) the kernels serve the GRADIENT
-        # programs alone: there they replace three recomputations of
-        # `attend` by two of a faster one.  A program with no backward
-        # (`forward`, prefill) keeps the `jnp` form although the kernels
-        # are 2.7 times faster there too (PERF.md section 6, PR 56): with
-        # them in the programs the reference check runs at set-up, a warm
-        # run that followed a parent's read `peak_hbm_gb` 214 MB higher
-        # (section 7).  True forces them anywhere.
-        use_flash = attn_args[2]
-        if use_flash is None and not backward:
-            use_flash = False
-        attn, kc, knum = block_sparse.packed_attention(
-            q, k, v, segment_ids, sizes, use_flash=use_flash)
-        out = _attn_out(
-            attn.reshape(b, s, cfg.q_dim), blk, cfg, _attn_gate(h, blk, cfg))
-        left = {"k": k, "v": v}
-        if ck_slots is not None:
-            left["ck"] = block_sparse.compressed_of_last(
-                kc, knum, segment_ids, sizes, ck_slots)
-        return out, left
-
-    def lightning(h, blk):
-        if not with_state:
-            return lightning_forward(
-                h, blk, cfg, segment_ids, *attn_args[:2]), {}
-        out, state = lightning_forward(
-            h, blk, cfg, segment_ids, *attn_args[:2], with_state=True)
-        return out, {"state": state}
-
-    return {
-        SPARSE: sparse,
-        LIGHTNING: lightning,
-        ATTENTION: attention,
-        WINDOW: window,
-        LATENT: attention,
-        GDN: recurrent(
-            functools.partial(linear_attn_forward, kernel=row_kernel)),
-        SSM: recurrent(ssm_forward),
-        SCONV: short_conv,
-        MLP: lambda h, blk: (
-            _mlp_dense(h, blk, cfg), {"aux": jnp.zeros((), jnp.float32)}),
-        MOE: experts,
+def _window_packed(ctx: Ctx, h, blk):
+    """The window layers' own rotary table; where the caller keeps what
+    they leave, the ring a row's last slots fill (`_ring_tail`)."""
+    out, left = _attention(
+        h, blk, ctx.cfg, ctx.segment_ids, *ctx.window_rope, ctx.use_flash,
+        ctx.cp_mesh, ctx.cp_manual, ctx.cp_zigzag,
+        window=ctx.cfg.attn_window, scope="window",
+    )
+    if ctx.ring is None:
+        return out, {}
+    return out, {
+        "wk": _ring_tail(left["k"], ctx.ring),
+        "wv": _ring_tail(left["v"], ctx.ring),
     }
 
 
-# Named checkpoints for remat="dots_small" (see `_remat_layer`): a mixer's
-# output and an MLP's are the SMALL per-token dots ([*, D]) whose saving
-# lets backward skip only the fat gate/up recompute candidates' DOWNSTREAM
-# — memory ~2x "full" remat instead of the ~7x of "dots".
-_SAVED_AS = {MLP: "mlp_out", MOE: "mlp_out"}
+def _sparse_packed(ctx: Ctx, h, blk):
+    """Softmax attention by block selection (`ops/block_sparse.py`):
+    no positions, the sigmoid output gate; it leaves k, v and, for a
+    cache, the compressed keys of the row's sequence."""
+    cfg, segment_ids = ctx.cfg, ctx.segment_ids
+    b, s, _ = h.shape
+    q, k, v = _block_kv(h, blk, cfg, ctx.cos, ctx.sin)
+    sizes = block_sparse.Sizes.of(cfg)
+    # By what the code can see (None) the kernels serve the GRADIENT
+    # programs alone: there they replace three recomputations of
+    # `attend` by two of a faster one.  A program with no backward
+    # (`forward`, prefill) keeps the `jnp` form although the kernels
+    # are 2.7 times faster there too (PERF.md section 6, PR 56): with
+    # them in the programs the reference check runs at set-up, a warm
+    # run that followed a parent's read `peak_hbm_gb` 214 MB higher
+    # (section 7).  True forces them anywhere.
+    use_flash = ctx.use_flash
+    if use_flash is None and not ctx.backward:
+        use_flash = False
+    attn, kc, knum = block_sparse.packed_attention(
+        q, k, v, segment_ids, sizes, use_flash=use_flash)
+    out = _attn_out(
+        attn.reshape(b, s, cfg.q_dim), blk, cfg, _attn_gate(h, blk, cfg))
+    left = {"k": k, "v": v}
+    if ctx.ck_slots is not None:
+        left["ck"] = block_sparse.compressed_of_last(
+            kc, knum, segment_ids, sizes, ctx.ck_slots)
+    return out, left
 
 
-def _packed_layer(
-    cfg: ModelConfig, branches, kind: LayerKind, x: jax.Array, blk: Params,
-    named: bool = False,
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """One layer over packed rows, x += f(norm(x)) a branch -> (y, what
-    else its branches gave: `_packed_branches`).  `named`: the branches'
-    outputs are the named checkpoints of a remat policy."""
-    gave = {}
-    for branch, ln in zip(kind, _BRANCH_NORMS):
-        h = _norm(x, blk[ln], blk.get(ln + "_b"), cfg)
-        out, more = branches[branch](h, blk)
-        if named:
-            out = checkpoint_name(out, _SAVED_AS.get(branch, "attn_out"))
-        x = _residual(x, out, cfg)
-        gave.update(more)
-    return x, gave
+def _mlp_packed(ctx: Ctx, h, blk):
+    return _mlp_dense(h, blk, ctx.cfg), {"aux": jnp.zeros((), jnp.float32)}
 
 
-def _layer_forward(
-    cfg: ModelConfig, branches, kind: LayerKind, x: jax.Array, blk: Params
-) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
-    """`_packed_layer` for the train stack -> (y, MoE aux loss, rows per
-    expert; None without experts): what one remat region puts out."""
-    x, gave = _packed_layer(cfg, branches, kind, x, blk, named=True)
-    aux = gave["aux"] if "aux" in gave else jnp.zeros((), jnp.float32)
-    return x, aux, gave.get("counts")
+def _moe_packed(ctx: Ctx, h, blk):
+    out, aux, counts = _mlp_moe(
+        h, blk, ctx.cfg, valid=ctx.segment_ids > 0, kernel=ctx.expert_kernel
+    )
+    return out, {"aux": aux, "counts": counts}
 
 
-# Mixers whose layers are rematerialised a BRANCH at a time: at rows of 13 k
-# tokens a Lightning or block-sparse mixer's residuals (2.5 GB: the chunked
-# recurrence's fp32 blocks) and the MLP's (1.5 GB at a width of 16,384) do
-# not fit beside the state TOGETHER; a branch at a time the backward holds
-# one of them, for one more saved [B, S, D] a layer and no more recompute.
-_REMAT_BY_BRANCH = frozenset({SPARSE, LIGHTNING})
+def _packed_ctx(
+    cfg: ModelConfig, segment_ids, cos, sin, use_flash, remat=None,
+    expert_kernel: Optional[bool] = False, **more,
+) -> Ctx:
+    """The context of a GRADIENT stack over packed rows (`_blocks`, a
+    pipeline stage).  `expert_kernel`: whether the grouped dispatch's
+    matmuls are the Pallas kernel `grouped_matmul`
+    (`expert_kernel_choice`); `remat`: the policy the caller
+    differentiates the stack under — where `use_flash` is None a
+    block-sparse layer takes the flash kernels under one alone
+    (`_sparse_packed`)."""
+    return Ctx(
+        cfg, cos, sin, segment_ids, use_flash=use_flash,
+        backward=remat not in (False, None, "none"),
+        expert_kernel=cfg.is_moe and expert_kernel_choice(cfg, expert_kernel),
+        **more,
+    )
 
 
-def _branch_remat_layer(cfg: ModelConfig, branches, kind: LayerKind, remat):
-    """`_layer_forward` with every branch x += f(norm(x)) under the remat
-    policy on its own (`_remat_layer`) instead of the layer whole."""
+def _packed_call(ctx: Ctx):
+    """A walk's `call` over packed rows: no carry, no place in one."""
+
+    def call(branch, h, blk, carry, li, at):
+        out, left = BRANCHES[branch].packed(ctx, h, blk)
+        return out, None, left
+
+    return call
+
+
+# The norm in front of a layer's first and second branch.
+_BRANCH_NORMS = ("ln1", "ln2")
+
+
+def _layer_of(
+    cfg: ModelConfig, kind: LayerKind, call, gives, wrap=None, nth=None
+):
+    """ONE layer of `kind` as f(x, blk, carry, pi) -> (y, carry, what its
+    branches gave of the names `gives`): x += f(norm(x)) a branch, the
+    branch through `call(branch, h, blk, carry, li, at) -> (out, carry,
+    what it gives by name)` — the one loop over a layer's branches every
+    program runs.
+
+    `nth`: for a walk with a carry, the layer's index, a branch, among
+    the prefix's layers with the branch or, in scan step `pi`, among the
+    unit's: `li` is then the layer's index among ALL the plan's layers
+    with the branch — behind the prefix's and the earlier steps' — and
+    `at` (nth, pi); both None without.
+    `wrap`: a gradient stack's remat policy (`_remat_layer`'s, bound):
+    the branches' outputs are the named checkpoints of `Branch.saved_as`,
+    every layer gives an `aux` (zero where no branch has one), and the
+    layer goes under the policy whole or, with a `Branch.remat_alone`
+    kind, a branch at a time."""
+    plan = cfg.plan
 
     def branch_step(branch, ln):
-        def step(x, blk):
+        def step(x, blk, carry, pi):
+            li = at = None
+            if nth is not None:
+                li, at = nth[branch], (nth[branch], pi)
+                if pi is not None:
+                    n, lead = plan.in_unit(branch), plan.in_prefix(branch)
+                    li = pi if n == 1 else pi * n + li
+                    li = li + lead if lead else li
             h = _norm(x, blk[ln], blk.get(ln + "_b"), cfg)
-            out, more = branches[branch](h, blk)
-            out = checkpoint_name(out, _SAVED_AS.get(branch, "attn_out"))
-            return _residual(x, out, cfg), more
+            out, carry, gave = call(branch, h, blk, carry, li, at)
+            if wrap is not None:
+                out = checkpoint_name(out, BRANCHES[branch].saved_as)
+            return _residual(x, out, cfg), carry, {
+                n: gave[n] for n in gives if n in gave}
 
-        return _remat_layer(step, remat)
+        return step
 
+    alone = wrap is not None and any(BRANCHES[b].remat_alone for b in kind)
     steps = [branch_step(b, ln) for b, ln in zip(kind, _BRANCH_NORMS)]
+    if alone:
+        steps = [wrap(step) for step in steps]
 
-    def layer(x, blk):
+    def layer(x, blk, carry, pi):
         gave = {}
         for step in steps:
-            x, more = step(x, blk)
+            x, carry, more = step(x, blk, carry, pi)
             gave.update(more)
-        aux = gave["aux"] if "aux" in gave else jnp.zeros((), jnp.float32)
-        return x, aux, gave.get("counts")
+        if wrap is not None and "aux" not in gave:
+            gave["aux"] = jnp.zeros((), jnp.float32)
+        return x, carry, gave
 
-    return layer
+    return layer if wrap is None or alone else wrap(layer)
 
 
 def _block_forward(
@@ -1372,11 +1329,15 @@ def _block_forward(
     cp_manual: "Optional[Tuple[str, int]]" = None,
 ) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
     """The (attention, MLP) layer a pipeline stage scans
-    (`parallel/pipeline.py`), the ring's manual region handed through."""
-    branches = _packed_branches(
-        cfg, segment_ids, cos, sin, use_flash, None, cp_manual
-    )
-    return _layer_forward(cfg, branches, cfg.plan.unit[-1], x, blk)
+    (`parallel/pipeline.py`), the ring's manual region handed through ->
+    (y, MoE aux loss, rows per expert; None without experts)."""
+    ctx = _packed_ctx(cfg, segment_ids, cos, sin, use_flash, cp_manual=cp_manual)
+    y, _, gave = _layer_of(
+        cfg, cfg.plan.unit[-1],
+        _packed_call(ctx),
+        ("aux", "counts"), wrap=lambda layer: layer,
+    )(x, blk, None, None)
+    return y, gave["aux"], gave.get("counts")
 
 
 _ZIGZAG_SNAPSHOT: "Optional[bool]" = None
@@ -1520,133 +1481,46 @@ def _remat_layer(body, remat):
     return body
 
 
-class HybridLayoutError(NotImplementedError):
-    """A layout or plane a hybrid layer pattern (linear-attention layers
-    with recurrent state beside softmax-attention layers) cannot run on
-    yet, refused by name rather than run wrong."""
-
-
-class LatentLayoutError(NotImplementedError):
-    """A layout or plane latent attention (a cache of latent rows, an
-    absorbed decode step, leading dense layers before the scan) cannot run
-    on yet, refused by name rather than run wrong."""
-
-
-_NO_HYBRID_LAYOUT = (
-    "a hybrid layer pattern (Gated DeltaNet layers beside attention "
-    "layers) runs under data and fsdp sharding only: no tensor parallelism "
-    "over DeltaNet heads, the chunked delta rule has no ring over a split "
-    "sequence, and a pipeline stage would have to be whole periods "
-    "(PERF.md section 7)"
-)
-
-
-_NO_LATENT_LAYOUT = (
-    "latent attention and leading dense layers run under data and fsdp "
-    "sharding only: the heads of the low-rank projections are not split "
-    "over `model`, the ring over a split sequence and the pipeline's "
-    "stages were not tested with them (PERF.md section 7)"
-)
-
-
-class WindowLayoutError(NotImplementedError):
-    """A layout or plane a mix of sliding-window and full-attention layers
-    (a ring of `attn_window` slots beside a cache of every slot, two
-    rotary tables) cannot run on yet, refused by name rather than run as
-    full attention."""
-
-
-_NO_WINDOW_LAYOUT = (
-    "sliding-window layers beside full-attention layers run under data and "
-    "fsdp sharding only: the ring attention over a split sequence has no "
-    "window, the pipeline's stage scans one kind of layer with one rotary "
-    "table, and the window layers' heads were not tested split over "
-    "`model` (PERF.md section 7)"
-)
-
-
-_NO_SCONV_LAYOUT = (
-    "gated short-convolution layers beside attention layers run under data "
-    "and fsdp sharding only: the conv's channels and its cached tail are "
-    "not split over `model`, the conv has no halo over a split sequence, "
-    "and the pipeline's stage scans one kind of layer (PERF.md section 7)"
-)
-
-
-_NO_PATTERN_LAYOUT = (
+# A plan of one-branch layers is refused for its SHAPE, whatever its kinds.
+_PATTERN_REFUSAL = Refusal(
+    HybridLayoutError,
     "a pattern of one-branch layers (Mamba-2, experts or attention alone) "
     "runs under data and fsdp sharding only: the Mamba heads, their conv "
     "channels and their state are not split over `model`, the chunked scan "
     "has no ring over a split sequence, and the pipeline has no stage of a "
-    "pattern's layers (PERF.md section 7)"
-)
-
-
-_NO_SSM_LAYOUT = (
-    "Mamba-2 mixers in two-branch layers run under data and fsdp sharding "
-    "only: the Mamba heads, their conv channels and their state are not "
-    "split over `model`, the chunked scan has no ring over a split "
-    "sequence, and the pipeline's stage scans one kind of layer (PERF.md "
-    "section 7)"
-)
-
-
-_NO_SALA_LAYOUT = (
-    "block-sparse attention beside Lightning attention (minicpm_sala) runs "
-    "under data and fsdp sharding only: the selection's compressed keys and "
-    "the Lightning state are not split over `model`, neither the selection "
-    "nor the chunked recurrence has a ring over a split sequence, and the "
-    "pipeline's stage scans one kind of layer (PERF.md section 7)"
-)
-
-
-_NO_SERVING_SALA = (
-    "block-sparse attention beside Lightning attention (minicpm_sala) "
-    "generates on the static decode program only: the serving plane's "
-    "ragged paged attention has no selection (compressed keys beside the "
-    "pages, chosen pages a lane), and a Lightning layer's state has no slot "
-    "beside the pool yet (PERF.md section 7)"
+    "pattern's layers (PERF.md section 7)",
+    "a Mamba-2 layer's recurrent state and conv tail have no slot on the "
+    "serving plane yet, and its chunk has one kind of layer: a pattern of "
+    "one-branch layers generates on the static decode program only (at "
+    "most max_decode_batch requests, no stop sequences, no speculative "
+    "decoding, max_new_tokens within static_path_max_new)",
 )
 
 
 def plan_refusal(cfg: ModelConfig, serving: bool):
     """What of `cfg.plan` the serving plane (`serving`; its chunk
     `decode_step_ragged_paged` walks a plan of two-branch layers whose
-    mixers are softmax attention over pages of per-head k/v or Mamba-2
-    with a slot of state beside the pool) or else a mesh split over
-    `model`, `seq` or `pipe` cannot run yet -> the error to raise, by
-    name, or None for a plan both can: every refusal of a plane or a
-    layout asks here."""
-    plan = cfg.plan
-    if plan.count(SPARSE, LIGHTNING):
-        return HybridLayoutError(
-            _NO_SERVING_SALA if serving else _NO_SALA_LAYOUT)
-    if plan.count(GDN):
-        return HybridLayoutError(
-            _NO_SERVING_STATE if serving else _NO_HYBRID_LAYOUT)
-    if plan.count(SCONV):
-        return HybridLayoutError(
-            _NO_SERVING_SCONV if serving else _NO_SCONV_LAYOUT)
-    if plan.count(LATENT) or plan.prefix:
-        return LatentLayoutError(
-            _NO_SERVING_LATENT if serving else _NO_LATENT_LAYOUT)
+    kinds have a `Branch.serve`) or else a mesh split over `model`, `seq`
+    or `pipe` cannot run yet -> the error to raise, by name, or None for a
+    plan both can: every refusal of a plane or a layout asks here.  The
+    first kind of the table that refuses speaks; leading layers are
+    refused in latent attention's words, which name them."""
     if cfg.is_pattern:
-        return HybridLayoutError(
-            _NO_SERVING_PATTERN if serving else _NO_PATTERN_LAYOUT)
-    if plan.count(SSM) and not serving:
-        return HybridLayoutError(_NO_SSM_LAYOUT)
-    if plan.count(WINDOW):
-        return WindowLayoutError(
-            _NO_SERVING_WINDOW if serving else _NO_WINDOW_LAYOUT)
+        return _PATTERN_REFUSAL.of(serving)
+    refusing = set(branches_of(cfg)) | ({LATENT} if cfg.plan.prefix else set())
+    for name, branch in BRANCHES.items():
+        if name in refusing and branch.refusal and branch.refusal.of(serving):
+            return branch.refusal.of(serving)
     return None
 
 
-# The block leaves by the branch that owns them: a leaf is stacked over the
-# layers with that branch, in layer order (attention's and latent
-# attention's share `wo`, a dense MLP's and the experts' `wg` / `wu` /
-# `wd`: one of the two a model; a window layer has a full layer's leaves,
-# stacked with them in layer order).  `ln1` is every layer's, `ln2` every
-# layer's with a second branch (`_owns`).
+# The attention and MLP kinds' block leaves (each mixer module's beside its
+# own code): a leaf is stacked over the layers with a branch that owns it,
+# in layer order (attention's and latent attention's share `wo`, a dense
+# MLP's and the experts' `wg` / `wu` / `wd`: one of the two a model; a
+# window layer has a full layer's leaves, stacked with them in layer
+# order).  `ln1` is every layer's, `ln2` every layer's with a second branch
+# (`_owns`).
 _FULL_ATTN_LEAVES = (
     "wq", "wk", "wv", "wo", "wqg", "bq", "bk", "bv", "bo", "q_norm", "k_norm",
 )
@@ -1657,26 +1531,24 @@ _MOE_LEAVES = (
     "router", "router_bias", "wg", "wu", "wd", "ws_g", "ws_u", "ws_d",
     "ws_gate",
 )
-_LEAF_BRANCHES = {
-    **dict.fromkeys(
-        _FULL_ATTN_LEAVES + _LATENT_LEAVES,
-        (ATTENTION, WINDOW, LATENT, SPARSE)),
-    **dict.fromkeys(LIGHTNING_LEAVES, (LIGHTNING,)),
-    **dict.fromkeys(LINEAR_LEAVES, (GDN,)),
-    **dict.fromkeys(SSM_LEAVES, (SSM,)),
-    **dict.fromkeys(SCONV_LEAVES, (SCONV,)),
-    **dict.fromkeys(_MOE_LEAVES + ("bproj", "bfc"), (MLP, MOE)),
-}
-# The norm in front of a layer's first and second branch.
-_BRANCH_NORMS = ("ln1", "ln2")
 
 
-def _owns(kind: LayerKind, leaf: str) -> bool:
-    """Whether a layer of this kind has the block leaf."""
+def _leaf_owners() -> Dict[str, list]:
+    """Block leaf -> the kinds whose records list it."""
+    owners = {}
+    for name, branch in BRANCHES.items():
+        for leaf in branch.leaves:
+            owners.setdefault(leaf, []).append(name)
+    return owners
+
+
+def _owns(kind: LayerKind, leaf: str, owners=None) -> bool:
+    """Whether a layer of this kind has the block leaf (`owners`:
+    `_leaf_owners()`, for a caller that asks of many leaves)."""
     if leaf.startswith("ln2"):
         return len(kind) > 1
-    return leaf not in _LEAF_BRANCHES or any(
-        b in kind for b in _LEAF_BRANCHES[leaf])
+    kinds = (_leaf_owners() if owners is None else owners).get(leaf)
+    return not kinds or any(b in kind for b in kinds)
 
 
 def _unit_view(cfg: ModelConfig, blocks: Params) -> Params:
@@ -1685,11 +1557,11 @@ def _unit_view(cfg: ModelConfig, blocks: Params) -> Params:
     leaf, ...] — a leaf ONE layer of the unit owns stays [repeats, ...].
     Leading-axis reshapes: no data moves, and a unit of one layer gets the
     leaves as they are.  The prefix's leaves are not in it."""
-    plan, out = cfg.plan, {}
+    plan, out, by_leaf = cfg.plan, {}, _leaf_owners()
     for name, w in blocks.items():
         if name.startswith(DENSE_PREFIX):
             continue
-        n = sum(_owns(kind, name) for kind in plan.unit)
+        n = sum(_owns(kind, name, by_leaf) for kind in plan.unit)
         out[name] = w if n == 1 else w.reshape(plan.repeats, n, *w.shape[1:])
     return out
 
@@ -1698,10 +1570,11 @@ def _unit_layer(cfg: ModelConfig, step: Params, j: int):
     """Layer j of one scan step's slice of `_unit_view` -> (its kind, for
     each of its branches the layer's index among the unit's layers with
     that branch, its leaves)."""
-    unit = cfg.plan.unit
+    unit, by_leaf = cfg.plan.unit, _leaf_owners()
     blk = {}
     for name, w in step.items():
-        owners = [i for i, kind in enumerate(unit) if _owns(kind, name)]
+        owners = [
+            i for i, kind in enumerate(unit) if _owns(kind, name, by_leaf)]
         if j in owners:
             blk[name] = w if len(owners) == 1 else w[owners.index(j)]
     index = {b: sum(b in kind for kind in unit[:j]) for b in unit[j]}
@@ -1712,7 +1585,7 @@ def _prefix_layers(cfg: ModelConfig, blocks: Params) -> list:
     """The prefix's layers, each as `_unit_layer` gives a unit's — (kind,
     its index among the prefix's layers with each of its branches, its
     leaves under the layer's own names); [] without leading layers."""
-    prefix = cfg.plan.prefix
+    prefix, by_leaf = cfg.plan.prefix, _leaf_owners()
     lead = {
         n[len(DENSE_PREFIX):]: w
         for n, w in blocks.items() if n.startswith(DENSE_PREFIX)
@@ -1722,8 +1595,8 @@ def _prefix_layers(cfg: ModelConfig, blocks: Params) -> list:
             kind,
             {b: sum(b in k for k in prefix[:i]) for b in kind},
             {  # a leaf is stacked over the leading layers that own it
-                n: w[sum(_owns(k, n) for k in prefix[:i])]
-                for n, w in lead.items() if _owns(kind, n)
+                n: w[sum(_owns(k, n, by_leaf) for k in prefix[:i])]
+                for n, w in lead.items() if _owns(kind, n, by_leaf)
             },
         )
         for i, kind in enumerate(prefix)
@@ -1752,43 +1625,93 @@ def _layer_outputs(n_in_unit: int, stacked, lead=()):
     return jnp.concatenate([jnp.stack(lead), stacked])
 
 
+def _walk(
+    cfg: ModelConfig, blocks: Params, x, carry, call, gives=(), summed=(),
+    wrap=None, unroll: bool = False,
+):
+    """THE walk of `cfg.plan`, every program's: the prefix's layers one by
+    one, then ONE `lax.scan` over the repeats of the plan's unit, a unit's
+    layers unrolled inside it, each layer `_layer_of(call, wrap)` — the
+    train stack `_blocks`, `prefill`, `decode_step` and the serving chunk
+    `decode_step_ragged_paged` differ in `call`, in what they carry and in
+    what they read of what the layers give.
+
+    `carry`: what the layers hand on beside x (a cache; None over packed
+    rows), a scan carry.  `gives`: the names read of what the branches
+    give, in the order the scan puts them out -> out[name], stacked over
+    the layers that gave one, the prefix's first (None where none did) —
+    a name of `summed` instead the unit's sum, a scan step.  `unroll`: a
+    plan of ONE repeat steps its unit without a scan, at the static step 0.
+    -> (x, carry, out)."""
+    plan = cfg.plan
+    indexed = carry is not None
+    layers, in_unit = {}, {}
+
+    def layer(kind, nth):
+        """`_layer_of`, made once a kind where no layer asks its place."""
+        key = (kind, tuple(nth.items()) if indexed else None)
+        if key not in layers:
+            layers[key] = _layer_of(
+                cfg, kind, call, gives, wrap, nth if indexed else None)
+        return layers[key]
+
+    def body(state, step):
+        y, carry, pi = state
+        gave = {name: [] for name in gives}
+        for j in range(len(plan.unit)):
+            kind, nth, blk = _unit_layer(cfg, step, j)
+            y, carry, more = layer(kind, nth)(y, blk, carry, pi)
+            for name in gives:
+                if name in summed and gave[name]:
+                    gave[name] = [gave[name][0] + more[name]]
+                else:
+                    gave[name].append(more.get(name))
+        for name in gives:
+            in_unit[name] = sum(g is not None for g in gave[name])
+        return (y, carry, pi if pi is None else pi + 1), tuple(
+            _unit_outputs(gave[name]) for name in gives)
+
+    lead = []  # the prefix's layers come first in their populations
+    for kind, nth, blk in _prefix_layers(cfg, blocks):
+        x, carry, more = layer(kind, nth)(x, blk, carry, None)
+        lead.append(more)
+    view = _unit_view(cfg, blocks)
+    if unroll:
+        (x, carry, _), stacked = body(
+            (x, carry, 0), jax.tree.map(lambda w: w[0], view))
+    else:
+        (x, carry, _), stacked = jax.lax.scan(
+            body, (x, carry, jnp.int32(0) if indexed else None), view)
+    return x, carry, {
+        name: ys if name in summed else _layer_outputs(
+            in_unit[name], ys, [g[name] for g in lead if name in g])
+        for name, ys in zip(gives, stacked)
+    }
+
+
 def _blocks(
     blocks: Params, cfg: ModelConfig, x, segment_ids, cos, sin, remat,
     use_flash, cp_mesh=None, cp_zigzag: bool = False, window_rope=None,
     expert_kernel: Optional[bool] = False, row_kernel=None,
 ):
-    """The block stack of every model: the prefix's layers, then ONE
-    `lax.scan` over the repeats of the plan's unit, a unit's layers
-    unrolled inside it, every layer (all its branches) under the remat
-    policy on its own.
+    """The block stack of every model over packed rows, every layer (all
+    its branches, or each on its own: `_layer_of`) under the remat policy.
+    `row_kernel`: the form of the Gated DeltaNet's chunked rule
+    (`linear_attn_forward`'s `kernel`: None, by what the code can see; the
+    caller's MESH where it has more than one device).
     -> (x, aux loss per repeat, rows per expert [n_moe_layers, E])."""
-    plan = cfg.plan
-    branches = _packed_branches(
-        cfg, segment_ids, cos, sin, use_flash, cp_mesh, None, cp_zigzag,
-        window_rope=window_rope, expert_kernel=expert_kernel,
-        row_kernel=row_kernel, backward=remat not in (False, None, "none"),
+    ctx = _packed_ctx(
+        cfg, segment_ids, cos, sin, use_flash, remat, expert_kernel,
+        cp_mesh=cp_mesh, cp_zigzag=cp_zigzag, window_rope=window_rope,
+        row_kernel=row_kernel,
     )
-    layers = {
-        kind: _branch_remat_layer(cfg, branches, kind, remat)
-        if set(kind) & _REMAT_BY_BRANCH else _remat_layer(
-            functools.partial(_layer_forward, cfg, branches, kind), remat
-        )
-        for kind in plan.kinds
-    }
-
-    def body(y, step):
-        aux, counts = None, []
-        for j in range(len(plan.unit)):
-            kind, _, blk = _unit_layer(cfg, step, j)
-            y, a, c = layers[kind](y, blk)
-            aux = a if aux is None else aux + a
-            counts.append(c)
-        return y, (aux, _unit_outputs(counts))
-
-    for kind, _, blk in _prefix_layers(cfg, blocks):  # dense MLP: no aux
-        x, _, _ = layers[kind](x, blk)
-    x, (auxes, counts) = jax.lax.scan(body, x, _unit_view(cfg, blocks))
-    return x, auxes, _layer_outputs(plan.in_unit(MOE), counts)
+    x, _, gave = _walk(
+        cfg, blocks, x, None,
+        _packed_call(ctx),
+        gives=("aux", "counts"), summed=("aux",),
+        wrap=functools.partial(_remat_layer, remat=remat),
+    )
+    return x, gave["aux"], gave["counts"]
 
 
 @jax.named_scope("head_logprob")
@@ -1936,33 +1859,25 @@ def forward_with_aux(
 class KVCache:
     """The static decode program's cache, one population a kind of branch
     (`cfg.plan`), each stacked over the layers that have the branch and
-    None where none does; an MLP or expert branch keeps nothing.
+    None where none does — the rows the kinds' records say a layer keeps
+    (`Branch.cache`, [layers, B, ...] each; an MLP or expert branch keeps
+    nothing):
 
-    - softmax attention: `k` / `v` [layers, B, S_max, n_kv, head_dim], full
-      precision (its windows are small; the int8 mode lives on the serving
+    - `k` / `v`: softmax and block-sparse attention's per-head keys and
+      values by slot, full precision (the int8 mode lives on the serving
       plane's `PagedKVCache`);
-    - latent attention, in their place: `latent` [layers, B, S_max,
-      kv_lora_rank + qk_rope_head_dim], ONE row a token and layer — the
-      normed latent vector beside the roped key part all heads share — and
-      the decode step attends over the rows themselves (`decode_step`);
-    - a recurrent branch: `state` in fp32 and the causal conv's last inputs
-      `conv` — Gated DeltaNet [layers, B, hv, dk, dv] and [layers, B, K-1,
-      C], Mamba-2 [layers, B, H, head_dim, N] and [layers, B, K-1,
-      conv_dim] (`_RECURRENT_SHAPES`);
-    - the gated short convolution: `conv` alone, [layers, B, K-1, D] in the
-      compute type — the row's last gated inputs — and no `state`;
-    - sliding-window attention: `wk` / `wv` [layers, B, ring, n_kv,
-      head_dim], ring = min(attn_window, S_max): slot s of the row lies at
-      entry s mod ring, so the ring holds the last `ring` slots written
-      and nothing older — what a window layer can still see
-      (`ring_valid`);
-    - block-sparse attention: `k` / `v` as softmax attention's and `ck`
-      [layers, B, S_max / stride, n_kv, head_dim], the COMPRESSED keys the
-      selection scores against, one row a `sparse_kernel_stride` tokens by
-      kernel number within the row's sequence, appended as kernels
-      complete (`block_sparse.compressed_step`);
-    - Lightning attention: `state` [layers, B, H, d, d] in fp32 and no
-      `conv`."""
+    - `latent`, in their place: latent attention's ONE row a token — the
+      normed latent vector beside the roped key part all heads share;
+    - `state` (fp32) and `conv`: a recurrent branch's state and its causal
+      conv's last inputs (Gated DeltaNet, Mamba-2; Lightning attention a
+      state alone, the gated short convolution a tail alone);
+    - `wk` / `wv`: a sliding-window layer's ring of min(attn_window, S_max)
+      slots — slot s at entry s mod ring, so the ring holds the last `ring`
+      slots written and nothing older (`ring_valid`);
+    - `ck`: a block-sparse layer's COMPRESSED keys, one row a
+      `sparse_kernel_stride` tokens by kernel number within the row's
+      sequence, appended as kernels complete
+      (`block_sparse.compressed_step`)."""
 
     k: Optional[jax.Array]
     v: Optional[jax.Array]
@@ -1978,19 +1893,9 @@ class KVCache:
         return (self.latent if self.k is None else self.k).shape[2]
 
 
-# The cache's populations: field -> the branches that keep it.
-_CACHE_FIELDS = {
-    "k": (ATTENTION, SPARSE),
-    "v": (ATTENTION, SPARSE),
-    "state": (GDN, SSM, LIGHTNING),
-    "conv": (GDN, SSM, SCONV),
-    "latent": (LATENT,),
-    "wk": (WINDOW,),
-    "wv": (WINDOW,),
-    "ck": (SPARSE,),
-}
 jax.tree_util.register_dataclass(
-    KVCache, data_fields=list(_CACHE_FIELDS), meta_fields=[]
+    KVCache, data_fields=[f.name for f in dataclasses.fields(KVCache)],
+    meta_fields=[],
 )
 
 
@@ -2033,54 +1938,25 @@ def _cache_update(kc, vc, ksc, vsc, k, v, rows, rows_s, quant: bool):
     )
 
 
-# A recurrent branch's (state, conv tail) shapes for one layer and row.
-_RECURRENT_SHAPES = {
-    GDN: lambda cfg: (
-        (cfg.linear_n_v_heads, cfg.linear_k_head_dim, cfg.linear_v_head_dim),
-        (cfg.linear_conv_kernel - 1, cfg.linear_conv_dim),
-    ),
-    SSM: lambda cfg: (
-        (cfg.ssm_n_heads, cfg.ssm_head_dim, cfg.ssm_state_dim),
-        (cfg.ssm_conv_kernel - 1, cfg.ssm_conv_dim),
-    ),
-}
-
-
 def init_kv_cache(
     cfg: ModelConfig, batch: int, s_max: int, dtype=None
 ) -> KVCache:
-    dtype, plan = dtype or cfg.dtype, cfg.plan
-    if plan.count(LATENT):
-        return KVCache(k=None, v=None, latent=jnp.zeros(
-            (plan.count(LATENT), batch, s_max, cfg.latent_dim), dtype))
-    # Without an attention layer: no layers of k/v, the window's length.
-    shape = (plan.count(ATTENTION, SPARSE), batch, s_max, cfg.n_kv_heads,
-             cfg.head_dim)
-    cache = KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
-    if plan.count(SPARSE):
-        cache.ck = jnp.zeros(
-            (plan.count(SPARSE), batch, -(-s_max // cfg.sparse_kernel_stride),
-             cfg.n_kv_heads, cfg.head_dim), dtype)
-    if plan.count(LIGHTNING):  # a state and no tail
-        cache.state = jnp.zeros(
-            (plan.count(LIGHTNING), batch, cfg.lightning_n_heads,
-             cfg.lightning_head_dim, cfg.lightning_head_dim), jnp.float32)
-    for branch, shapes in _RECURRENT_SHAPES.items():
-        if plan.count(branch):
-            state, conv = shapes(cfg)
-            cache.state = jnp.zeros(
-                (plan.count(branch), batch, *state), jnp.float32)
-            cache.conv = jnp.zeros((plan.count(branch), batch, *conv), dtype)
-    if plan.count(SCONV):  # a tail and no state
-        cache.conv = jnp.zeros(
-            (plan.count(SCONV), batch, cfg.sconv_kernel - 1, cfg.hidden_dim),
-            dtype,
-        )
-    if plan.count(WINDOW):
-        ring = (plan.count(WINDOW), batch, min(cfg.attn_window, s_max),
-                cfg.n_kv_heads, cfg.head_dim)
-        cache.wk, cache.wv = jnp.zeros(ring, dtype), jnp.zeros(ring, dtype)
-    return cache
+    """What the plan's kinds keep (`Branch.cache`), each population over
+    the layers of every kind that keeps it."""
+    dtype, plan, kinds = dtype or cfg.dtype, cfg.plan, branches_of(cfg)
+    keep = {}
+    for branch in kinds.values():
+        keep.update({f: row for f, row in branch.cache.items() if f not in keep})
+    if "latent" not in keep:
+        # Without an attention layer: no layers of k/v, the window's length
+        # (`KVCache.s_max`).
+        keep = {**_KV, **keep}
+    kept = {"k": None, "v": None}
+    for field, row in keep.items():
+        shape, kind = row(cfg, batch, s_max, dtype)
+        layers = plan.count(*(n for n, b in kinds.items() if field in b.cache))
+        kept[field] = jnp.zeros((layers, *shape), kind)
+    return KVCache(**kept)
 
 
 @jax.named_scope("layer/attn_qkv")
@@ -2221,40 +2097,18 @@ def prefill(
     positions = positions_from_segments(segment_ids)
     x = _embed(params, cfg, tokens, positions)
     (cos, sin), window_rope = _rope(cfg, positions)
-
-    plan = cfg.plan
-    branches = _packed_branches(
-        cfg, segment_ids, cos, sin, use_flash, with_state=True,
-        window_rope=window_rope,
+    ctx = Ctx(
+        cfg, cos, sin, segment_ids, window_rope, use_flash, with_state=True,
         ring=None if cache.wk is None else cache.wk.shape[2],
         ck_slots=None if cache.ck is None else cache.ck.shape[2],
     )
-
-    def body(y, step):
-        per_layer = []
-        for j in range(len(plan.unit)):
-            kind, _, blk = _unit_layer(cfg, step, j)
-            y, left = _packed_layer(cfg, branches, kind, y, blk)
-            per_layer.append(left)
-        return y, tuple(
-            _unit_outputs([left.get(f) for left in per_layer])
-            for f in _CACHE_FIELDS
-        )
-
-    lead = []  # the prefix's layers come first in their populations
-    for kind, _, blk in _prefix_layers(cfg, params["blocks"]):
-        x, left = _packed_layer(cfg, branches, kind, x, blk)
-        lead.append(left)
-    x, by_field = jax.lax.scan(body, x, _unit_view(cfg, params["blocks"]))
-
-    # Each population's new entries [its layers, ...] ...
-    entries = {
-        field: _layer_outputs(
-            plan.in_unit(*keepers), stacked,
-            [left[field] for left in lead if field in left],
-        )
-        for (field, keepers), stacked in zip(_CACHE_FIELDS.items(), by_field)
-    }
+    # Each population's new entries [its layers, ...], the prefix's layers
+    # first ...
+    x, _, entries = _walk(
+        cfg, params["blocks"], x, None,
+        _packed_call(ctx),
+        gives=tuple(_CACHE_FIELDS),
+    )
 
     def place(buf, new):
         """... in its buffer: a state and a ring are all new, a window
@@ -2283,6 +2137,127 @@ def _prefill_head(params: Params, cfg: ModelConfig, x, segment_ids):
     return _head(params, cfg, x_last)[:, 0]
 
 
+# The attention and MLP kinds, one token per row (`Branch.step`): f(ctx, h,
+# blk, cache, li) -> (its output, the cache, what its decode counter reads);
+# li: the layer's index among all the layers with the branch, which is its
+# place in the branch's population of the cache and in the stacked expert
+# leaves.
+
+
+def _put_token(buf: jax.Array, new: jax.Array, li, slot) -> jax.Array:
+    """k or v [B,1,h,d] -> [1,B,1,h,d] written at (layer, :, slot)."""
+    return jax.lax.dynamic_update_slice(
+        buf, new.astype(buf.dtype)[None], (li, 0, slot, 0, 0))
+
+
+def _layer_of_cache(buf: jax.Array, li) -> jax.Array:
+    return jax.lax.dynamic_index_in_dim(buf, li, axis=0, keepdims=False)
+
+
+def _attention_step(ctx: Ctx, h, blk, cache, li):
+    """Softmax attention of one token per row through k/v layer li."""
+    cfg, slot = ctx.cfg, ctx.slot
+    full = "full" if ctx.window_rope else None  # the inner scope, a mixed plan
+    q, k, v = _block_kv(h, blk, cfg, ctx.cos, ctx.sin, full)  # [B,1,h,d]
+    kc = _put_token(cache.k, k, li, slot)
+    vc = _put_token(cache.v, v, li, slot)
+    k_layer, v_layer = _layer_of_cache(kc, li), _layer_of_cache(vc, li)
+    attn = decode_attention(
+        q, k_layer, v_layer, ctx.valid_from, slot + 1, scope=full
+    )
+    ao = _attn_out(
+        attn.reshape(h.shape[0], 1, cfg.q_dim), blk, cfg,
+        _attn_gate(h, blk, cfg), scope=full,
+    )
+    return ao, dataclasses.replace(cache, k=kc, v=vc), {}
+
+
+def _latent_step(ctx: Ctx, h, blk, cache, li):
+    """ABSORBED latent attention of one token per row through the latent
+    rows of layer li (`KVCache.latent`, one row a token): the query is
+    carried into the latent space, scores and the weighted sum are taken
+    against the rows themselves and the value up-projection comes after —
+    the numbers of the materialised form `prefill` and training run, in
+    another order."""
+    cfg, slot = ctx.cfg, ctx.slot
+    q, row = _latent_q_absorbed(h, blk, cfg, ctx.cos, ctx.sin)
+    rows = jax.lax.dynamic_update_slice(
+        cache.latent, row.astype(cache.latent.dtype)[None],
+        (li, 0, slot, 0),
+    )
+    attn = latent_decode_attention(
+        q[:, 0], rows, li, ctx.valid_from, slot + 1, cfg.kv_lora_rank,
+        cfg.head_dim**-0.5, use_kernel=ctx.row_kernel,
+    )
+    ao = _attn_out(attn.reshape(h.shape[0], 1, -1), blk, cfg, absorbed=True)
+    return ao, dataclasses.replace(cache, latent=rows), {}
+
+
+def _window_step(ctx: Ctx, h, blk, cache, li):
+    """Sliding-window attention of one token per row through ring li
+    (`KVCache.wk` / `wv`, min(attn_window, S_max) slots): the token's k/v
+    go to entry `slot` mod ring, over the slot that left the window, and
+    the live entries (`ring_valid`: the same for every window layer of
+    the step) are read where they lie — softmax does not ask for their
+    order — with the window layers' own rotary table (`_rope`)."""
+    cfg, slot = ctx.cfg, ctx.slot
+    q, k, v = _block_kv(h, blk, cfg, *ctx.window_rope, "window")
+    at = slot % cache.wk.shape[2]
+    kc = _put_token(cache.wk, k, li, at)
+    vc = _put_token(cache.wv, v, li, at)
+    attn = decode_attention(
+        q, _layer_of_cache(kc, li), _layer_of_cache(vc, li),
+        ctx.valid_from, slot + 1, valid=ctx.live, scope="window",
+    )
+    ao = _attn_out(
+        attn.reshape(h.shape[0], 1, cfg.q_dim), blk, cfg, scope="window")
+    return ao, dataclasses.replace(cache, wk=kc, wv=vc), {}
+
+
+def _sparse_step(ctx: Ctx, h, blk, cache, li):
+    """Block-sparse attention of one token per row: the token's k/v
+    and, where it completes a kernel, the row's next compressed key go
+    into layer li; the selection reads the compressed keys and the
+    attention the chosen blocks' rows alone.  What it read ([3] fp32:
+    keys read, keys cached, rows still under `sparse_dense_len`) rides out
+    for the kind's counter."""
+    cfg, slot = ctx.cfg, ctx.slot
+    sizes = block_sparse.Sizes.of(cfg)
+    q, k, v = _block_kv(h, blk, cfg, ctx.cos, ctx.sin)
+    kc = _put_token(cache.k, k, li, slot)
+    vc = _put_token(cache.v, v, li, slot)
+    with jax.named_scope("layer/sparse_attn/compress"):
+        ck = block_sparse.compressed_step(
+            cache.ck, kc, li, slot, ctx.valid_from, sizes)
+    attn, reads = block_sparse.decode_attention(
+        q, _layer_of_cache(kc, li), _layer_of_cache(vc, li),
+        _layer_of_cache(ck, li), ctx.valid_from, slot, sizes,
+    )
+    ao = _attn_out(
+        attn.reshape(h.shape[0], 1, cfg.q_dim), blk, cfg,
+        _attn_gate(h, blk, cfg))
+    return ao, dataclasses.replace(cache, k=kc, v=vc, ck=ck), {SPARSE: reads}
+
+
+def _mlp_step(ctx: Ctx, h, blk, cache, li):
+    return _mlp_dense(h, blk, ctx.cfg), cache, {}
+
+
+def _moe_step(ctx: Ctx, h, blk, cache, li):
+    out, _, counts = _mlp_moe(
+        h, blk, ctx.cfg, stacked=ctx.stacked, layer=li,
+        kernel=ctx.expert_kernel,
+    )
+    return out, cache, {MOE: counts}
+
+
+def decode_counters(cfg: ModelConfig) -> Dict[str, Counter]:
+    """The decode counters of `cfg.plan`'s kinds, by name, in the order
+    the static loop carries their sums."""
+    found = [b.counter for b in branches_of(cfg).values() if b.counter]
+    return {c.name: c for c in sorted(found, key=lambda c: c.name)}
+
+
 @jax.named_scope("gen/decode_step")
 def decode_step(
     params: Params,
@@ -2292,24 +2267,24 @@ def decode_step(
     cache: KVCache,
     slot: jax.Array,  # scalar int32 — cache slot written for ALL rows
     valid_from: jax.Array,  # [B] int32 — first valid cache slot per row
-    with_moe_counts: bool = False,
+    with_counts: bool = False,
     experts_in_place: Optional[bool] = None,
     row_kernel=None,  # None | bool | Mesh
     expert_kernel: Optional[bool] = None,
-    with_sparse_counts: bool = False,
 ) -> Tuple[jax.Array, ...]:
     """One decode step: write the new token's k/v at cache slot `slot`
     (shared by every row — the right-aligned prompt layout makes the write a
     single `dynamic_update_slice`, not a per-row scatter), attend over the
     live window `[valid_from, slot]`, return fp32 logits [B, V] and the
-    updated cache — and, `with_moe_counts`, the step's rows per expert of
-    every layer ([L, E] int32; MoE models only), which the generator's
-    counters reduce inside its decode loop; `with_sparse_counts`, in their
-    place, what every block-sparse layer read ([layers, 3] fp32: keys
-    read, keys cached, rows still under `sparse_dense_len`:
-    `block_sparse.decode_attention`).
+    updated cache — and, `with_counts`, a dict of what the plan's kinds
+    give their decode counters (`decode_counters`, by `Counter.name`, each
+    stacked over the kind's layers: a mixture's rows per expert [L, E]
+    int32, what a block-sparse layer read [layers, 3] fp32), which the
+    generator's counters reduce inside its decode loop.
 
-    The cache rides the layer scan as CARRY (updated in place by XLA), so
+    The layers are `cfg.plan`'s, walked by `_walk`, each branch through
+    its record's `step`.  The cache rides the layer scan as CARRY (updated
+    in place by XLA), so
     per-token HBM traffic is one (B, n_kv, d) write + one window read per
     layer instead of a full-cache rewrite (the fix for the one-hot scatter
     this replaces).  Reference semantics: the fused decode step replayed via
@@ -2342,201 +2317,34 @@ def decode_step(
     device on its rows (`shard_map` over the batch axes); a bool forces
     either form.
 
-    A sliding-window layer (`cfg.window_pattern`) keeps its k/v in a ring
-    of min(attn_window, S_max) slots (`KVCache.wk` / `wv`): the token's
-    entry goes to `slot` mod ring, over the slot that just left the window,
-    and attention reads the ring's live entries where they lie
-    (`ring_valid`: from `slot` and `valid_from` alone, the same for every
-    window layer of the step) with the window layers' own rotary table
-    (`_rope`); the full layers beside it keep every slot and read
-    `[valid_from, slot]` as ever.
-
-    Latent attention (`cfg.is_latent`) runs its ABSORBED form here: the
-    cache holds one latent row a token (`KVCache.latent`), the query is
-    carried into the latent space, scores and the weighted sum are taken
-    against the rows themselves and the value up-projection comes after
-    (`_latent_q_absorbed`, `latent_decode_attention`, `_attn_out`) — the
-    numbers of the materialised form `prefill` and training run, in
-    another order, and no per-head k/v is ever built.  Leading dense
-    layers step before the scan, through the first layers of the cache.
-    """
-    b = tokens.shape[0]
+    What a kind does with its population of the cache is its `step`'s to
+    say (`_window_step`: the ring; `_latent_step`: the absorbed form, no
+    per-head k/v ever built).  Leading dense layers step before the scan,
+    through the first layers of the cache."""
     x = _embed(params, cfg, tokens, positions)[:, None, :]  # [B,1,D]
     (cos, sin), window_rope = _rope(cfg, positions[:, None])
     slot = jnp.asarray(slot, jnp.int32)
     blocks, stacked = _scan_blocks(cfg, params["blocks"], experts_in_place)
-    expert_kernel = stacked is not None and expert_kernel_choice(
-        cfg, expert_kernel
+    ctx = Ctx(
+        cfg, cos, sin, window_rope=window_rope, row_kernel=row_kernel,
+        stacked=stacked, slot=slot, valid_from=valid_from,
+        expert_kernel=stacked is not None and expert_kernel_choice(
+            cfg, expert_kernel),
+        # the same entries of every window layer's ring
+        live=ring_valid(slot, valid_from, cache.wk.shape[2])
+        if window_rope else None,
     )
-
-    plan = cfg.plan
-
-    def attend_latent(h, blk, cache, li):
-        """Absorbed latent attention of one token per row through the
-        latent rows of layer li."""
-        q, row = _latent_q_absorbed(h, blk, cfg, cos, sin)
-        rows = jax.lax.dynamic_update_slice(
-            cache.latent, row.astype(cache.latent.dtype)[None],
-            (li, 0, slot, 0),
-        )
-        attn = latent_decode_attention(
-            q[:, 0], rows, li, valid_from, slot + 1, cfg.kv_lora_rank,
-            cfg.head_dim**-0.5, use_kernel=row_kernel,
-        )
-        ao = _attn_out(attn.reshape(b, 1, -1), blk, cfg, absorbed=True)
-        return ao, dataclasses.replace(cache, latent=rows), None
-
-    full = "full" if window_rope else None  # the inner scope, a mixed plan
-
-    def attend(h, blk, cache, li):
-        """Softmax attention of one token per row through k/v layer li."""
-        q, k, v = _block_kv(h, blk, cfg, cos, sin, full)  # [B,1,h,d]
-        # k/v [B,1,h,d] -> [1,B,1,h,d] written at (layer, :, slot).
-        kc = jax.lax.dynamic_update_slice(
-            cache.k, k.astype(cache.k.dtype)[None], (li, 0, slot, 0, 0)
-        )
-        vc = jax.lax.dynamic_update_slice(
-            cache.v, v.astype(cache.v.dtype)[None], (li, 0, slot, 0, 0)
-        )
-        k_layer = jax.lax.dynamic_index_in_dim(kc, li, axis=0, keepdims=False)
-        v_layer = jax.lax.dynamic_index_in_dim(vc, li, axis=0, keepdims=False)
-        attn = decode_attention(
-            q, k_layer, v_layer, valid_from, slot + 1, scope=full
-        )
-        ao = _attn_out(
-            attn.reshape(b, 1, cfg.q_dim), blk, cfg, _attn_gate(h, blk, cfg),
-            scope=full,
-        )
-        return ao, dataclasses.replace(cache, k=kc, v=vc), None
-
-    if window_rope:  # the same entries of every window layer's ring
-        ring = cache.wk.shape[2]
-        live = ring_valid(slot, valid_from, ring)
-
-    def attend_window(h, blk, cache, li):
-        """Sliding-window attention of one token per row through ring li:
-        the token's k/v go to entry `slot` mod ring, over the slot that
-        left the window, and the live entries are read where they lie —
-        softmax does not ask for their order."""
-        q, k, v = _block_kv(h, blk, cfg, *window_rope, "window")
-        at = (li, 0, slot % ring, 0, 0)
-        kc = jax.lax.dynamic_update_slice(
-            cache.wk, k.astype(cache.wk.dtype)[None], at)
-        vc = jax.lax.dynamic_update_slice(
-            cache.wv, v.astype(cache.wv.dtype)[None], at)
-        attn = decode_attention(
-            q,
-            jax.lax.dynamic_index_in_dim(kc, li, axis=0, keepdims=False),
-            jax.lax.dynamic_index_in_dim(vc, li, axis=0, keepdims=False),
-            valid_from, slot + 1, valid=live, scope="window",
-        )
-        ao = _attn_out(attn.reshape(b, 1, cfg.q_dim), blk, cfg, scope="window")
-        return ao, dataclasses.replace(cache, wk=kc, wv=vc), None
-
-    def recurrent(step, *kernel):
-        """A recurrent branch steps layer li of the state and the conv
-        tail in place (carried like k/v)."""
-
-        def branch(h, blk, cache, li):
-            out, sc, cc = step(h, blk, cfg, cache.state, cache.conv, li, *kernel)
-            return out, dataclasses.replace(cache, state=sc, conv=cc), None
-
-        return branch
-
-    def attend_sparse(h, blk, cache, li):
-        """Block-sparse attention of one token per row: the token's k/v
-        and, where it completes a kernel, the row's next compressed key go
-        into layer li; the selection reads the compressed keys and the
-        attention the chosen blocks' rows alone.  Its counts ride out as
-        the branch's third result."""
-        sizes = block_sparse.Sizes.of(cfg)
-        q, k, v = _block_kv(h, blk, cfg, cos, sin)
-        kc = jax.lax.dynamic_update_slice(
-            cache.k, k.astype(cache.k.dtype)[None], (li, 0, slot, 0, 0))
-        vc = jax.lax.dynamic_update_slice(
-            cache.v, v.astype(cache.v.dtype)[None], (li, 0, slot, 0, 0))
-        with jax.named_scope("layer/sparse_attn/compress"):
-            ck = block_sparse.compressed_step(
-                cache.ck, kc, li, slot, valid_from, sizes)
-        attn, reads = block_sparse.decode_attention(
-            q,
-            jax.lax.dynamic_index_in_dim(kc, li, axis=0, keepdims=False),
-            jax.lax.dynamic_index_in_dim(vc, li, axis=0, keepdims=False),
-            jax.lax.dynamic_index_in_dim(ck, li, axis=0, keepdims=False),
-            valid_from, slot, sizes,
-        )
-        ao = _attn_out(
-            attn.reshape(b, 1, cfg.q_dim), blk, cfg, _attn_gate(h, blk, cfg))
-        return ao, dataclasses.replace(cache, k=kc, v=vc, ck=ck), reads
-
-    def step_lightning(h, blk, cache, li):
-        out, states = lightning_step(h, blk, cfg, cache.state, li, cos, sin)
-        return out, dataclasses.replace(cache, state=states), None
-
-    def short_conv(h, blk, cache, li):
-        """The gated short convolution shifts layer li of the tails in
-        place."""
-        out, cc = sconv_step(h, blk, cfg, cache.conv, li)
-        return out, dataclasses.replace(cache, conv=cc), None
-
-    def experts(h, blk, cache, li):
-        out, _, counts = _mlp_moe(
-            h, blk, cfg, stacked=stacked, layer=li, kernel=expert_kernel
-        )
-        return out, cache, counts
-
-    # branch -> f(h, blk, cache, li) -> (its output, the cache, rows per
-    # expert or None); li: the layer's index among all the layers with the
-    # branch, which is its place in the branch's population of the cache
-    # and in the stacked expert leaves.
-    branches = {
-        SPARSE: attend_sparse,
-        LIGHTNING: step_lightning,
-        ATTENTION: attend,
-        WINDOW: attend_window,
-        LATENT: attend_latent,
-        GDN: recurrent(linear_attn_step, row_kernel),
-        SSM: recurrent(ssm_step),
-        SCONV: short_conv,
-        MLP: lambda h, blk, cache, li: (_mlp_dense(h, blk, cfg), cache, None),
-        MOE: experts,
-    }
-
-    def layer(kind, nth, y, blk, cache, pi=None):
-        """`nth`: the layer's index, a branch, among the prefix's layers
-        with the branch or, in scan step `pi`, among the unit's."""
-        counts = None
-        for branch, ln in zip(kind, _BRANCH_NORMS):
-            li = nth[branch]
-            if pi is not None:  # behind the prefix's and the earlier steps'
-                n, lead = plan.in_unit(branch), plan.in_prefix(branch)
-                li = pi if n == 1 else pi * n + li
-                li = li + lead if lead else li
-            h = _norm(y, blk[ln], blk.get(ln + "_b"), cfg)
-            out, cache, c = branches[branch](h, blk, cache, li)
-            y = _residual(y, out, cfg)
-            counts = c if c is not None else counts
-        return y, cache, counts
-
-    def body(carry, step):
-        y, cache, pi = carry
-        counts = []
-        for j in range(len(plan.unit)):
-            kind, nth, blk = _unit_layer(cfg, step, j)
-            y, cache, c = layer(kind, nth, y, blk, cache, pi)
-            counts.append(c)
-        return (y, cache, pi + 1), _unit_outputs(counts)
-
-    for kind, nth, blk in _prefix_layers(cfg, blocks):
-        x, cache, _ = layer(kind, nth, x, blk, cache)
-    (x, new_cache, _), counts = jax.lax.scan(
-        body, (x, cache, jnp.int32(0)), _unit_view(cfg, blocks)
+    x, new_cache, counts = _walk(
+        cfg, blocks, x, cache,
+        lambda branch, h, blk, cache, li, at: BRANCHES[branch].step(
+            ctx, h, blk, cache, li),
+        gives=tuple(decode_counters(cfg)),
     )
-    counts = _layer_outputs(plan.in_unit(MOE, SPARSE), counts)
     x = _final_norm(params, cfg, x)
     logits = _head(params, cfg, x)[:, 0]  # [B, V]
-    if with_moe_counts or with_sparse_counts:
-        return logits, new_cache, counts
+    if with_counts:
+        return logits, new_cache, {
+            n: c for n, c in counts.items() if c is not None}
     return logits, new_cache
 
 
@@ -2637,34 +2445,34 @@ def init_paged_kv_cache(
     cfg: ModelConfig, n_pages: int, page_size: int, dtype=None,
     n_slots: int = 0,
 ) -> PagedKVCache:
-    """`n_slots`: the generator's slots, for a plan with Mamba-2 layers (a
-    slot of state and a conv tail a request; the tail in the compute type,
-    as `init_kv_cache` keeps it)."""
+    """`n_slots`: the generator's slots, for a plan whose kinds keep a
+    `state` or a `conv` (`Branch.cache`: a slot of state and a conv tail a
+    request; the tail in the compute type, as `init_kv_cache` keeps it)."""
     refusal = plan_refusal(cfg, serving=True)
     if refusal:
         raise refusal
-    n_attn = cfg.plan.count(ATTENTION)
+    plan = cfg.plan
+    n_attn = plan.count(ATTENTION)
     shape = (n_attn, n_pages, page_size, cfg.n_kv_heads * cfg.head_dim)
     dtype = dtype or cfg.dtype
+    quant = dtype in (jnp.int8, "int8")
     slots = {}
-    if cfg.n_ssm_layers:
-        if n_slots < 1:
-            raise ValueError(
-                "a plan with Mamba-2 layers keeps a slot of state a request "
-                "beside the page pool: init_paged_kv_cache needs n_slots"
-            )
-        state, conv = _RECURRENT_SHAPES[SSM](cfg)
-        plan = cfg.plan
-        tail_dtype = cfg.dtype if dtype in (jnp.int8, "int8") else dtype
-        slots = dict(
-            state=tuple(
-                jnp.zeros((plan.repeats, n_slots, *state), jnp.float32)
-                for _ in range(plan.in_unit(SSM))),
-            conv=tuple(
-                jnp.zeros((plan.repeats, n_slots, *conv), tail_dtype)
-                for _ in range(plan.in_unit(SSM))),
-        )
-    if dtype in (jnp.int8, "int8"):
+    for name, branch in branches_of(cfg).items():
+        for field in ("state", "conv"):
+            if field not in branch.cache:
+                continue
+            if n_slots < 1:
+                raise ValueError(
+                    "a plan with Mamba-2 layers keeps a slot of state a "
+                    "request beside the page pool: init_paged_kv_cache "
+                    "needs n_slots"
+                )
+            row, kind = branch.cache[field](
+                cfg, n_slots, 0, cfg.dtype if quant else dtype)
+            slots[field] = tuple(
+                jnp.zeros((plan.repeats, *row), kind)
+                for _ in range(plan.in_unit(name)))
+    if quant:
         s_shape = (n_attn, n_pages, cfg.n_kv_heads, page_size)
         return PagedKVCache(
             k=jnp.zeros(shape, jnp.int8),
@@ -2708,50 +2516,45 @@ def _pool_rows(cache: "PagedKVCache", n_kv: int, page, off):
     return rows.astype(jnp.int32), rows_s.astype(jnp.int32), stride
 
 
-_NO_SERVING_STATE = (
-    "recurrent state has no slot on the serving plane yet: a hybrid layer "
-    "pattern (linear-attention layers) generates on the static decode "
-    "program only (at most max_decode_batch requests, no stop sequences, "
-    "no speculative decoding, max_new_tokens within static_path_max_new)"
-)
+class _Pages(NamedTuple):
+    """What one serving chunk step hands its attention layers: the lanes'
+    page-table rows and windows, layer 0's flat rows of their writes
+    (`_pool_rows`), whether the pool is int8, the live pages' schedule."""
+
+    table: jax.Array  # [T, max_pages]
+    valid_to: jax.Array  # [T]
+    rows: jax.Array
+    rows_s: jax.Array
+    stride: int
+    quant: bool
+    schedule: Any
 
 
-_NO_SERVING_PATTERN = (
-    "a Mamba-2 layer's recurrent state and conv tail have no slot on the "
-    "serving plane yet, and its chunk has one kind of layer: a pattern of "
-    "one-branch layers generates on the static decode program only (at "
-    "most max_decode_batch requests, no stop sequences, no speculative "
-    "decoding, max_new_tokens within static_path_max_new)"
-)
+def _attention_serve(ctx: Ctx, h, blk, pools, li, at):
+    """A lane's k/v into its page of layer li among the attention layers,
+    and ragged paged attention through the slot's page-table row."""
+    cfg, pg = ctx.cfg, ctx.pages
+    q, k, v = _block_kv(h, blk, cfg, ctx.cos, ctx.sin)  # [T, 1, h, d]
+    kc, vc, ksc, vsc = _cache_update(
+        pools.k, pools.v, pools.k_scale, pools.v_scale, k[:, 0], v[:, 0],
+        li * pg.stride + pg.rows,
+        li * pg.stride * cfg.n_kv_heads + pg.rows_s, pg.quant,
+    )
+    attn = ragged_paged_attention(
+        q[:, 0], kc, vc, li, pg.table, pg.valid_to,
+        k_scale=ksc, v_scale=vsc,
+        use_kernel=ctx.paged_kernel, schedule=pg.schedule,
+    )
+    return _attn_out(attn.reshape(h.shape[0], 1, cfg.q_dim), blk, cfg), (
+        dataclasses.replace(pools, k=kc, v=vc, k_scale=ksc, v_scale=vsc))
 
 
-_NO_SERVING_SCONV = (
-    "a short convolution's tail has no slot beside the page pool on the "
-    "serving plane yet, and its chunk has one kind of layer and none before "
-    "the scan: gated short-convolution layers beside attention layers "
-    "generate on the static decode program only (at most max_decode_batch "
-    "requests, no stop sequences, no speculative decoding, max_new_tokens "
-    "within static_path_max_new)"
-)
+def _mlp_serve(ctx: Ctx, h, blk, pools, li, at):
+    return _mlp_dense(h, blk, ctx.cfg), pools
 
 
-_NO_SERVING_WINDOW = (
-    "the serving plane's chunk scans one kind of attention over pages of "
-    "every slot, with one rotary table: it would run the sliding-window "
-    "layers as full ones.  A mix of window and full attention layers "
-    "generates on the static decode program only (at most max_decode_batch "
-    "requests, no stop sequences, no speculative decoding, max_new_tokens "
-    "within static_path_max_new)"
-)
-
-
-_NO_SERVING_LATENT = (
-    "latent rows have no pages on the serving plane yet, and its chunk has "
-    "no layer before the scan: latent attention and leading dense layers "
-    "generate on the static decode program only (at most max_decode_batch "
-    "requests, no stop sequences, no speculative decoding, max_new_tokens "
-    "within static_path_max_new)"
-)
+def _moe_serve(ctx: Ctx, h, blk, pools, li, at):
+    return _mlp_moe(h, blk, ctx.cfg, stacked=ctx.stacked, layer=li)[0], pools
 
 
 @jax.named_scope("gen/decode_step")
@@ -2790,10 +2593,10 @@ def decode_step_ragged_paged(
     model's expert leaves
     reach the ragged kernels as in `decode_step` (`experts_in_place`).
 
-    The layers are `cfg.plan`'s, walked as `decode_step` walks them: the
-    scan steps the plan's unit, a unit's layers unrolled, each branch
-    through the table below.  An ATTENTION branch reads and writes the
-    pages of its own layer among the attention layers; a Mamba-2 branch
+    The layers are `cfg.plan`'s, walked as `decode_step` walks them
+    (`_walk`), each branch through its record's `serve`, the pool the
+    walk's carry.  An attention branch reads and writes the pages of its
+    own layer among the attention layers; a branch with a slot of state
     (`mamba.ssm_ragged`) steps its layer of the slots' state and conv
     tails over the slot's lanes of this step — `slot_lanes`, the most
     lanes one slot may hold in a stream (the caller's W), sizes its slab
@@ -2802,7 +2605,6 @@ def decode_step_ragged_paged(
     refusal = plan_refusal(cfg, serving=True)
     if refusal:
         raise refusal
-    t = tokens.shape[0]
     b = page_table.shape[0]
     live = row_of < b
     rid = jnp.minimum(row_of.astype(jnp.int32), b - 1)
@@ -2817,7 +2619,6 @@ def decode_step_ragged_paged(
         cache, cfg.n_kv_heads, wp_page, wp_off
     )
     valid_to = jnp.where(live, positions + 1, 0).astype(jnp.int32)
-    quant = cache.quantized
     if paged_kernel is None:
         from areal_tpu.base.distributed import is_tpu_backend
 
@@ -2830,9 +2631,8 @@ def decode_step_ragged_paged(
             pt_tok, valid_to, cache.n_pages, cache.page_size,
             cfg.n_q_heads // cfg.n_kv_heads,
         )
-    plan = cfg.plan
     lanes = None
-    if plan.count(SSM):  # the same slab of lanes for every Mamba layer
+    if cache.state is not None:  # the same slab of lanes for every layer
         if not slot_lanes:
             raise ValueError(
                 "a plan with Mamba-2 layers needs slot_lanes: the most "
@@ -2840,87 +2640,23 @@ def decode_step_ragged_paged(
             )
         lanes = slot_lanes_of(row_of, positions, b, slot_lanes)
 
-    # The carry: (y, k pool, v pool, their scales, [the unit's Mamba
-    # layers' states, then their conv tails,] the scan step).  A plan
-    # without state carries what it always did.
-    n_ssm = plan.in_unit(SSM)
-
-    def attend(h, blk, pools, li, at):
-        kc, vc, ksc, vsc, *slots = pools
-        q, k, v = _block_kv(h, blk, cfg, cos, sin)  # [T, 1, h, d]
-        kc, vc, ksc, vsc = _cache_update(
-            kc, vc, ksc, vsc, k[:, 0], v[:, 0], li * stride + rows0,
-            li * stride * cfg.n_kv_heads + rows_s0, quant,
-        )
-        attn = ragged_paged_attention(
-            q[:, 0], kc, vc, li, pt_tok, valid_to,
-            k_scale=ksc if quant else None,
-            v_scale=vsc if quant else None,
-            use_kernel=paged_kernel, schedule=schedule,
-        )
-        return _attn_out(attn.reshape(t, 1, cfg.q_dim), blk, cfg), (
-            kc, vc, ksc, vsc, *slots)
-
-    def recur(h, blk, pools, li, at):
-        """`at`: (the layer's place among the unit's Mamba layers, the
-        scan step): its own buffers, and its place in them."""
-        j, pi = at
-        s_at, c_at = 4 + j, 4 + n_ssm + j  # behind k, v and their scales
-        pools = list(pools)
-        out, pools[s_at], pools[c_at] = ssm_ragged(
-            h[:, 0], blk, cfg, pools[s_at], pools[c_at], pi, lanes,
-            kernel=paged_kernel)
-        return out[:, None], tuple(pools)
-
-    def experts(h, blk, pools, li, at):
-        return _mlp_moe(h, blk, cfg, stacked=stacked, layer=li)[0], pools
-
-    branches = {
-        ATTENTION: attend,
-        SSM: recur,
-        MLP: lambda h, blk, pools, li, at: (_mlp_dense(h, blk, cfg), pools),
-        MOE: experts,
-    }
-
-    def body(carry, step):
-        y, *pools, pi = carry
-        for j in range(len(plan.unit)):
-            kind, nth, blk = _unit_layer(cfg, step, j)
-            for branch, ln in zip(kind, _BRANCH_NORMS):
-                n = plan.in_unit(branch)
-                li = pi if n == 1 else pi * n + nth[branch]
-                h = _norm(y, blk[ln], blk.get(ln + "_b"), cfg)
-                out, pools = branches[branch](
-                    h, blk, pools, li, (nth[branch], pi))
-                y = _residual(y, out, cfg)
-        return (y, *pools, pi + 1), None
-
     blocks, stacked = _scan_blocks(cfg, params["blocks"], experts_in_place)
-    ksc0 = cache.k_scale if quant else jnp.zeros((0,), jnp.bfloat16)
-    vsc0 = cache.v_scale if quant else jnp.zeros((0,), jnp.bfloat16)
-    slots = () if lanes is None else (*cache.state, *cache.conv)
-    carry = (x, cache.k, cache.v, ksc0, vsc0, *slots)
-    if slots and plan.repeats == 1:
-        # One step of the unit: no scan, so a layer's place in its buffers
-        # is STATIC, step 0, and the buffer is read as it lies
-        # (`PagedKVCache`).
-        step = jax.tree.map(lambda w: w[0], _unit_view(cfg, blocks))
-        (x, kc, vc, ksc, vsc, *slots, _), _ = body((*carry, 0), step)
-    else:
-        (x, kc, vc, ksc, vsc, *slots, _), _ = jax.lax.scan(
-            body, (*carry, jnp.int32(0)), _unit_view(cfg, blocks),
-        )
-    x = _final_norm(params, cfg, x)
-    logits = _head(params, cfg, x)[:, 0]  # [T, V]
-    state = tuple(slots[:n_ssm]) if slots else None
-    conv = tuple(slots[n_ssm:]) if slots else None
-    return logits, PagedKVCache(
-        k=kc, v=vc,
-        k_scale=ksc if quant else None,
-        v_scale=vsc if quant else None,
-        state=state, conv=conv,
-        page_size=cache.page_size,
+    ctx = Ctx(
+        cfg, cos, sin, stacked=stacked, paged_kernel=paged_kernel,
+        lanes=lanes, pages=_Pages(
+            pt_tok, valid_to, rows0, rows_s0, stride, cache.quantized,
+            schedule),
     )
+    # One step of the unit with state: no scan, so a layer's place in its
+    # buffers is STATIC, step 0, and the buffer is read as it lies
+    # (`PagedKVCache`).
+    x, cache, _ = _walk(
+        cfg, blocks, x, cache,
+        lambda branch, *args: (*BRANCHES[branch].serve(ctx, *args), {}),
+        unroll=lanes is not None and cfg.plan.repeats == 1,
+    )
+    x = _final_norm(params, cfg, x)
+    return _head(params, cfg, x)[:, 0], cache  # logits [T, V]
 
 
 def copy_pages(
@@ -2957,3 +2693,285 @@ def copy_pages(
             ),
         )
     return new
+
+
+# --------------------------------------------------------------------------
+# The attention and MLP kinds' records, and the table
+# --------------------------------------------------------------------------
+
+
+def _kv_row(cfg: ModelConfig, batch, s_max, dtype):
+    return (batch, s_max, cfg.n_kv_heads, cfg.head_dim), dtype
+
+
+def _ring_row(cfg: ModelConfig, batch, s_max, dtype):
+    return (batch, min(cfg.attn_window, s_max), cfg.n_kv_heads,
+            cfg.head_dim), dtype
+
+
+def _attn_matmul_params(cfg: ModelConfig) -> int:
+    """ONE softmax-attention layer's projections (the query's twice where
+    it also gives the output gate)."""
+    h, d = cfg.hidden_dim, cfg.head_dim
+    q_mats = 2 if cfg.attn_gate else 1
+    return (
+        h * (q_mats * cfg.n_q_heads * d + 2 * cfg.n_kv_heads * d)
+        + cfg.n_q_heads * d * h
+    )
+
+
+def _latent_matmul_params(cfg: ModelConfig) -> int:
+    """Latent attention: the two low-rank query projections, the latent
+    and shared-rope projection, the key and value up-projections and the
+    output projection (the materialised form training and prefill run)."""
+    h, d, hq, c = cfg.hidden_dim, cfg.head_dim, cfg.n_q_heads, cfg.kv_lora_rank
+    return (
+        h * cfg.q_lora_rank + cfg.q_lora_rank * hq * d
+        + h * cfg.latent_dim
+        + c * hq * (cfg.qk_nope_head_dim + cfg.v_head_dim)
+        + hq * cfg.v_head_dim * h
+    )
+
+
+def _softmax_flops(cfg: ModelConfig, n_tokens, sum_sq_seqlens) -> float:
+    """QK^T and attn @ V over every key (a window layer is counted as a
+    full one: this bounds the program's own estimate, the benchmark's
+    `peaks_swa.py` counts the band), the causal factor folded into the
+    constant as the reference counts it (flops_counter.py)."""
+    return 2.0 * 2.0 * cfg.n_q_heads * cfg.head_dim * sum_sq_seqlens
+
+
+def _sparse_flops(cfg: ModelConfig, n_tokens, sum_sq_seqlens) -> float:
+    """A token's SELECTED keys (topk blocks, never more than its sequence
+    has), not all of them, and its scores against one compressed key per
+    stride."""
+    hd = cfg.n_q_heads * cfg.head_dim
+    chosen = min(
+        sum_sq_seqlens, float(n_tokens) * cfg.sparse_topk * cfg.sparse_block_size)
+    return 4.0 * hd * chosen + 2.0 * hd * sum_sq_seqlens / cfg.sparse_kernel_stride
+
+
+def _mlp_matmul_params(cfg: ModelConfig) -> int:
+    return (3 if cfg.mlp_gated else 2) * cfg.hidden_dim * cfg.intermediate_dim
+
+
+def _moe_matmul_params(cfg: ModelConfig):
+    """Of the routed experts the ones HELD here: a rank's share computes
+    n_experts / router_width of a token's k choices in expectation, beside
+    the whole router and the shared expert (its gate where it has one)."""
+    h, n_mats = cfg.hidden_dim, 3 if cfg.mlp_gated else 2
+    inter = cfg.moe_intermediate_dim or cfg.intermediate_dim
+    held = cfg.n_experts_per_tok * cfg.n_experts / cfg.router_width
+    out = n_mats * h * inter * held + h * cfg.router_width
+    if cfg.shared_expert_dim:
+        out += n_mats * h * cfg.shared_expert_dim + (
+            h if cfg.shared_expert_gated else 0)
+    return out
+
+
+def _moe_step_counters(counts: jax.Array, cfg: ModelConfig, at) -> jax.Array:
+    """One decode step's rows per expert [L, E] -> f32 [3]: experts with at
+    least one row and the fullest expert's rows (both means over layers),
+    and 1 for the step.  A rank's share of the experts
+    (`cfg.expert_share`) adds two: the rows that reached experts held
+    here, and the rows the router sent anywhere (`at.rows` tokens x k
+    choices x L layers)."""
+    out = [
+        jnp.mean(jnp.sum(counts > 0, axis=-1).astype(jnp.float32)),
+        jnp.mean(jnp.max(counts, axis=-1).astype(jnp.float32)),
+        jnp.float32(1.0),
+    ]
+    if cfg.expert_share:
+        out += [
+            jnp.sum(counts).astype(jnp.float32),
+            jnp.float32(at.rows * cfg.n_experts_per_tok * counts.shape[0]),
+        ]
+    return jnp.stack(out)
+
+
+def _moe_report(sums, cfg: ModelConfig, params: Params) -> Dict[str, Any]:
+    """Per decode step and MoE layer, the experts with at least one row
+    and the rows on the fullest expert (means over every step of a
+    generate call); and which way the expert weights reached the ragged
+    kernels (1: the parameters' own buffers, 0: the layer scan's slices)."""
+    touched, rows_max, steps, *share = sums
+    if not steps:
+        return {}
+    out = dict(
+        moe_experts_touched=touched / steps,
+        moe_rows_per_expert_max=rows_max / steps,
+        moe_decode_steps=int(steps),
+        moe_expert_leaves_in_place=int(
+            expert_leaves_in_place(cfg, params["blocks"])),
+    )
+    if share:
+        # One expert-parallel rank's share: (row, choice) pairs that fell
+        # to experts held here, of all the router made, over every decode
+        # step and layer.  Balanced routing reads n_experts / router_width.
+        out.update(
+            moe_rows_local=float(share[0]), moe_rows_routed=float(share[1]))
+    return out
+
+
+def _window_step_counters(given, cfg: ModelConfig, at) -> jax.Array:
+    """(row, ring entry) pairs this step's window layers each read, and 1
+    for the step."""
+    live = ring_valid(at.slot, at.valid_from, at.cache.wk.shape[2])
+    return jnp.stack([jnp.sum(live).astype(jnp.float32), jnp.float32(1.0)])
+
+
+def _window_cache_stats(cfg: ModelConfig, cache, batch: int, s_max: int):
+    """Rings beside the full layers' windows, and every attention layer at
+    s_max, as a cache without rings."""
+    return {
+        "window_cache_bytes": nbytes(cache.wk, cache.wv),
+        "kv_cache_bytes": nbytes(cache.k, cache.v),
+        "kv_cache_bytes_unwindowed": 2 * cache.wk.dtype.itemsize * (
+            cfg.plan.count(ATTENTION, WINDOW) * batch * s_max * cfg.kv_dim
+        ),
+        "window_slots": batch * cache.wk.shape[2],
+    }
+
+
+def _latent_cache_stats(cfg: ModelConfig, cache, batch: int, s_max: int):
+    """The latent rows beside what per-head k/v would take."""
+    return {
+        "latent_cache_bytes": nbytes(cache.latent),
+        "kv_cache_bytes_as_heads": cache.latent.dtype.itemsize * (
+            cfg.n_layers * batch * s_max * cfg.n_kv_heads
+            * (cfg.head_dim + cfg.v_head_dim)
+        ),
+    }
+
+
+def _sparse_cache_stats(cfg: ModelConfig, cache, batch: int, s_max: int):
+    return {
+        "kv_cache_bytes": nbytes(cache.k, cache.v),
+        "compressed_cache_bytes": nbytes(cache.ck),
+    }
+
+
+_KV = {"k": _kv_row, "v": _kv_row}
+# In the order the first kind of a plan that refuses a plane or a layout
+# speaks (`plan_refusal`) and a cache's populations are made
+# (`init_kv_cache`).
+BRANCHES.update({
+    SPARSE: Branch(
+        leaves=_FULL_ATTN_LEAVES,
+        cache={**_KV, "ck": lambda cfg, batch, s_max, dtype: (
+            (batch, -(-s_max // cfg.sparse_kernel_stride), cfg.n_kv_heads,
+             cfg.head_dim), dtype)},
+        packed=_sparse_packed,
+        step=_sparse_step,
+        refusal=lightning.SALA_REFUSAL,
+        remat_alone=True,  # as a Lightning layer's: `lightning.BRANCH`
+        matmul_params=_attn_matmul_params,
+        attn_flops=_sparse_flops,
+        counter=Counter(
+            SPARSE, lambda cfg: 3,
+            lambda given, cfg, at: jnp.sum(given.reshape(-1, 3), axis=0),
+            lambda sums, cfg, params: dict(
+                sparse_keys_read=float(sums[0]),
+                sparse_keys_cached=float(sums[1]),
+                sparse_dense_rows=float(sums[2]),
+            ),
+        ),
+        cache_stats=_sparse_cache_stats,
+    ),
+    LIGHTNING: lightning.BRANCH,
+    ATTENTION: Branch(
+        leaves=_FULL_ATTN_LEAVES,
+        cache=_KV,
+        packed=_attention_packed,
+        step=_attention_step,
+        serve=_attention_serve,
+        matmul_params=_attn_matmul_params,
+        attn_flops=_softmax_flops,
+    ),
+    GDN: linear_attention.BRANCH,
+    SCONV: short_conv.BRANCH,
+    LATENT: Branch(
+        leaves=_LATENT_LEAVES + ("wo", "bo"),
+        cache={"latent": lambda cfg, batch, s_max, dtype: (
+            (batch, s_max, cfg.latent_dim), dtype)},
+        packed=_attention_packed,
+        step=_latent_step,
+        refusal=Refusal(
+            LatentLayoutError,
+            "latent attention and leading dense layers run under data and "
+            "fsdp sharding only: the heads of the low-rank projections are "
+            "not split over `model`, the ring over a split sequence and the "
+            "pipeline's stages were not tested with them (PERF.md section 7)",
+            "latent rows have no pages on the serving plane yet, and its "
+            "chunk has no layer before the scan: latent attention and "
+            "leading dense layers generate on the static decode program "
+            "only (at most max_decode_batch requests, no stop sequences, no "
+            "speculative decoding, max_new_tokens within "
+            "static_path_max_new)",
+        ),
+        matmul_params=_latent_matmul_params,
+        attn_flops=_softmax_flops,
+        cache_stats=_latent_cache_stats,
+    ),
+    SSM: mamba.BRANCH,
+    WINDOW: Branch(
+        leaves=_FULL_ATTN_LEAVES,
+        cache={"wk": _ring_row, "wv": _ring_row},
+        packed=_window_packed,
+        step=_window_step,
+        refusal=Refusal(
+            WindowLayoutError,
+            "sliding-window layers beside full-attention layers run under "
+            "data and fsdp sharding only: the ring attention over a split "
+            "sequence has no window, the pipeline's stage scans one kind of "
+            "layer with one rotary table, and the window layers' heads were "
+            "not tested split over `model` (PERF.md section 7)",
+            "the serving plane's chunk scans one kind of attention over "
+            "pages of every slot, with one rotary table: it would run the "
+            "sliding-window layers as full ones.  A mix of window and full "
+            "attention layers generates on the static decode program only "
+            "(at most max_decode_batch requests, no stop sequences, no "
+            "speculative decoding, max_new_tokens within "
+            "static_path_max_new)",
+        ),
+        matmul_params=_attn_matmul_params,
+        attn_flops=_softmax_flops,
+        flash_window=lambda cfg: cfg.attn_window,
+        counter=Counter(
+            WINDOW, lambda cfg: 2, _window_step_counters,
+            lambda sums, cfg, params: dict(
+                window_slots_live=float(sums[0] / max(sums[1], 1.0))),
+        ),
+        cache_stats=_window_cache_stats,
+    ),
+    # A mixer's output and an MLP's are the SMALL per-token dots ([*, D])
+    # whose saving (remat="dots_small", `_remat_layer`) lets backward skip
+    # only the fat gate/up recompute candidates' DOWNSTREAM — memory ~2x
+    # "full" remat instead of the ~7x of "dots".
+    MLP: Branch(
+        leaves=("wg", "wu", "wd", "bproj", "bfc"),
+        packed=_mlp_packed,
+        step=_mlp_step,
+        serve=_mlp_serve,
+        saved_as="mlp_out",
+        matmul_params=_mlp_matmul_params,
+    ),
+    MOE: Branch(
+        leaves=_MOE_LEAVES + ("bproj", "bfc"),
+        packed=_moe_packed,
+        step=_moe_step,
+        serve=_moe_serve,
+        saved_as="mlp_out",
+        matmul_params=_moe_matmul_params,
+        counter=Counter(
+            MOE, lambda cfg: 5 if cfg.expert_share else 3,
+            _moe_step_counters, _moe_report),
+    ),
+})
+
+# The cache's populations, in the order every program puts them out:
+# `KVCache` field -> the kinds that keep it.
+_CACHE_FIELDS = {
+    f.name: tuple(n for n, b in BRANCHES.items() if f.name in b.cache)
+    for f in dataclasses.fields(KVCache)
+}

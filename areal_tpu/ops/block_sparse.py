@@ -30,7 +30,7 @@ arithmetic:
   `ops.attention.packed_attention`'s form):
   on a TPU backend, one device, whole tiles (`kernel_fits`) — and, from
   the model's programs, in the GRADIENT programs alone: `transformer.
-  _packed_branches` hands `forward` and prefill False where nobody
+  _sparse_packed` hands `forward` and prefill False where nobody
   forces True — the FLASH KERNELS with the choice as one more term of
   the tile mask
   (`flash_attention.BlockChoice`: scores, mask and probabilities stay in

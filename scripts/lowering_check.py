@@ -16,7 +16,23 @@ that carry it and the chip host's slowness (PERF.md section 6, PR 50: 13 to
         [--json chiprun_out/lowering_check.json]
 
 The first program of a process also pays its imports and is left out of the
-medians (`--rows` gives three or more lengths for that reason)."""
+medians (`--rows` gives three or more lengths for that reason).
+
+`--hash-only` is the program-identity record of a refactoring: for every
+configuration of `BENCHMARK.json` (or `--configs`) it lowers, at the
+published widths and for the same described chip, the programs `--programs`
+names — `grad` (the gradient program above, the program's own kernel
+choice), `decode` (`prefill` + one `decode_step` with its counters), `serving`
+(one `decode_step_ragged_paged` chunk step; the plans the serving plane
+refuses print `refused`) — and prints one line a configuration and program
+with the sha256 of the StableHLO text — the results' names
+(`jax.result_info`) left out, and every Mosaic kernel's serialised module,
+which holds its callers' files and line numbers, replaced by the hash of its
+assembly without them (`program_text`).  Run it on two commits: equal hashes
+are equal programs.
+
+    python3 scripts/lowering_check.py --hash-only
+        [--programs grad,decode,serving] [--configs a,b]"""
 import argparse
 import gc
 import hashlib
@@ -88,9 +104,108 @@ def lowered(cfg, rows: int, length: int, device, kernel):
         gc.enable()
 
 
+def _shapes(tree, one):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), tree)
+
+
+def decode_text(cfg, device, rows=4, prompt=512, s_max=1024) -> str:
+    """The StableHLO text of `prefill` over `rows` prompts + one
+    `decode_step`, counters and all, on a cache made inside the program."""
+    from jax.sharding import SingleDeviceSharding
+
+    from areal_tpu.models import transformer as tfm
+
+    one = SingleDeviceSharding(device)
+    params = _shapes(jax.eval_shape(
+        lambda: tfm.init_params(cfg, jax.random.PRNGKey(0))), one)
+    ints = jax.ShapeDtypeStruct((rows, prompt), jnp.int32, sharding=one)
+    new = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one)
+
+    def program(params, tokens, seg, tok):
+        cache = tfm.init_kv_cache(cfg, rows, s_max)
+        logits, cache = tfm.prefill(params, cfg, tokens, seg, cache)
+        return logits, tfm.decode_step(
+            params, cfg, tok, jnp.full((rows,), prompt, jnp.int32), cache,
+            prompt, jnp.zeros((rows,), jnp.int32), with_counts=True)
+
+    return jax.jit(program).trace(params, ints, ints, new).lower().as_text()
+
+
+def serving_text(cfg, device, lanes=64, slots=16, pages=64, page=128) -> str:
+    """The StableHLO text of one step of the serving chunk over a stream of
+    `lanes` tokens, the pool an argument as the generator hands it in."""
+    from jax.sharding import SingleDeviceSharding
+
+    from areal_tpu.models import transformer as tfm
+
+    one = SingleDeviceSharding(device)
+    params = _shapes(jax.eval_shape(
+        lambda: tfm.init_params(cfg, jax.random.PRNGKey(0))), one)
+    pool = _shapes(jax.eval_shape(lambda: tfm.init_paged_kv_cache(
+        cfg, pages, page, n_slots=slots)), one)
+    stream = jax.ShapeDtypeStruct((lanes,), jnp.int32, sharding=one)
+    table = jax.ShapeDtypeStruct((slots, 8), jnp.int32, sharding=one)
+
+    def program(params, tokens, positions, pool, table, row_of):
+        return tfm.decode_step_ragged_paged(
+            params, cfg, tokens, positions, pool, table, row_of, slot_lanes=4)
+
+    return jax.jit(program).trace(
+        params, stream, stream, pool, table, stream).lower().as_text()
+
+
+def program_text(text: str) -> str:
+    """A lowered program's text with what is no part of the program taken
+    out: the results' names, and the debug locations inside each
+    `tpu_custom_call`'s serialised kernel (the body becomes the sha256 of
+    its assembly printed without them)."""
+    import base64
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    def body(match):
+        ctx = jax_mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            asm = ir.Module.parse(
+                base64.b64decode(match.group(2))
+            ).operation.get_asm(enable_debug_info=False)
+        return match.group(1) + hashlib.sha256(asm.encode()).hexdigest()
+
+    text = re.sub(r'(\\22body\\22: \\22)([A-Za-z0-9+/=]+)', body, text)
+    return re.sub(r' \{jax\.result_info = "[^"]*"\}', "", text)
+
+
+def hashes(names, programs, device):
+    """One line a configuration and program: the sha256 of its text."""
+    from areal_tpu.models import transformer as tfm
+    from benchmark import files
+    from benchmark import run as bench_run
+
+    for name in names:
+        cfg = bench_run.model_config(files.load_json("configs", name + ".json"))
+        for program in programs:
+            if program == "serving" and tfm.plan_refusal(cfg, serving=True):
+                print(f"{name} serving refused", flush=True)
+                continue
+            text = {
+                "grad": lambda: lowered(cfg, 1, 1024, device, None)[1],
+                "decode": lambda: decode_text(cfg, device),
+                "serving": lambda: serving_text(cfg, device),
+            }[program]()
+            text = program_text(text)
+            print(f"{name} {program} "
+                  f"{hashlib.sha256(text.encode()).hexdigest()[:16]} "
+                  f"{len(text)} bytes", flush=True)
+
+
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--configs", default=",".join(TOUCHED))
+    ap.add_argument("--configs", default=None)
+    ap.add_argument("--hash-only", action="store_true")
+    ap.add_argument("--programs", default="grad,decode,serving")
     ap.add_argument("--rows", default="4096,3072,2048,1024")
     ap.add_argument("--json", default=None)
     args = ap.parse_args()
@@ -103,9 +218,15 @@ def main():
     device = topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2").devices[0]
     jax.default_backend = lambda: "tpu"  # the kernels are not interpreted
+    if args.hash_only:
+        with open(os.path.join(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__))), "BENCHMARK.json")) as f:
+            every = [c["name"] for c in json.load(f)["configs"]]
+        names = args.configs.split(",") if args.configs else every
+        return hashes(names, args.programs.split(","), device)
     lengths = [int(r) for r in args.rows.split(",")]
     report = {}
-    for name in args.configs.split(","):
+    for name in (args.configs or ",".join(TOUCHED)).split(","):
         cfg = bench_run.model_config(files.load_json("configs", name + ".json"))
         # The two sides turn about, each at lengths of its own: a program
         # meets no trace of its own shapes, as in a cell, and a drift of

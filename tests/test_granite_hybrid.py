@@ -508,7 +508,7 @@ def test_the_chunk_on_the_state_kernel_equals_the_chunk_on_the_jnp_form(
 
     def roll(kernel):
         monkeypatch.setattr(
-            tfm, "ssm_ragged",
+            mamba, "ssm_ragged",
             lambda *a, kernel_=kernel, **kw: ragged(*a, kernel=kernel_))
         eng = _engine(cfg, params, slots=4, prefill_chunk_tokens=width)
         out, pool, served = eng.serving_rollout(

@@ -369,11 +369,212 @@ def test_prefill_and_four_decode_steps_are_forwards_logits(name):
     np.testing.assert_allclose(np.asarray(logits), want[:, p - 1], **TOL)
     step = jax.jit(lambda tok, pos, cache, slot: tfm.decode_step(
         params, cfg, tok, pos, cache, slot, jnp.zeros((b,), jnp.int32),
-        with_moe_counts=True))
+        with_counts=True))
     for i in range(steps):
         at = jnp.full((b,), p + i, jnp.int32)
         logits, cache, counts = step(tokens[:, p + i], at, cache, p + i)
         np.testing.assert_allclose(np.asarray(logits), want[:, p + i], **TOL)
-        assert counts.shape == (cfg.n_moe_layers, cfg.n_experts)
+        assert counts["moe"].shape == (cfg.n_moe_layers, cfg.n_experts)
     assert cache.k.shape[0] == cfg.n_attn_layers
     assert cache.state.shape[0] == (cfg.n_linear_layers or cfg.n_ssm_layers)
+
+
+# ---------------- (e) the seam: a kind is one record, every reader reads it
+
+
+def _benchmark_toys():
+    """{configuration: its traffic} of every configuration the benchmark
+    holds, from `BENCHMARK.json` (the first cell that runs it)."""
+    bench = files.benchmark_json()
+    traffic = {}
+    for cell in bench["workloads"]:
+        traffic.setdefault(cell["config"], cell["traffic"])
+    return {c["name"]: traffic[c["name"]] for c in bench["configs"]}
+
+
+def _toy(name) -> ModelConfig:
+    config, _ = bench_run.toy(
+        files.load_json("configs", name + ".json"),
+        files.load_json("traffic", _benchmark_toys()[name] + ".json"))
+    return bench_run.model_config(config)
+
+
+# (matmul_params, flops_train(cfg, 4096, 3 * 1024**2), flops_generate(cfg,
+# [512, 300], [128, 700])) of `base/monitor.py` at the parent of PR 57
+# (commit c41906c), each file's toy: printed there, before the counts were
+# sums over the records.
+_PARENT_FLOPS = {
+    "qwen2.5-math-1.5b": (106496, 7449083904.0, 800313344.0),
+    "r1-distill-qwen-7b-l8": (106496, 7449083904.0, 800313344.0),
+    "olmoe-1b-7b-0125-l3": (91136, 7071596544.0, 749932544.0),
+    "qwen3-next-80b-a3b-l4-e64": (172800, 6662651904.0, 792287232.0),
+    "glm-4.7-flash-l7-e8": (135936, 14212399104.0, 1460634624.0),
+    "nemotron-3-nano-30b-a3b-l9-e16": (179200, 6819938304.0, 813279232.0),
+    "mellum2-12b-a2.5b-l4-e16": (108544, 12331253760.0, 1258037248.0),
+    "lfm2-8b-a1b-e8": (203712, 7422345216.0, 893678592.0),
+    "granite-4.0-h-micro-l10": (234496, 8178892800.0, 994650112.0),
+    "minicpm-sala-l4-v8": (215040, 6190792704.0, 814128384.0),
+}
+
+
+def test_the_flops_are_pinned_for_every_configuration_of_the_benchmark():
+    assert set(_PARENT_FLOPS) == set(_benchmark_toys())
+
+
+@pytest.mark.parametrize("name", sorted(_PARENT_FLOPS))
+def test_every_reader_finds_a_kind_in_its_record(name):
+    from areal_tpu.base import monitor
+
+    cfg = _toy(name)
+    plan, table = cfg.plan, tfm.BRANCHES
+    kinds = tfm.branches_of(cfg)
+    assert {b for kind in plan.kinds for b in kind} == set(kinds)
+
+    # A block leaf is stacked over the layers of exactly the kinds whose
+    # records list it (a norm is every layer's).
+    blocks = jax.eval_shape(
+        lambda: tfm.init_params(cfg, jax.random.PRNGKey(0)))["blocks"]
+    for leaf, w in blocks.items():
+        name_ = leaf.removeprefix(DENSE_PREFIX)
+        if name_.startswith("ln"):
+            continue
+        owners = [n for n, b in table.items() if name_ in b.leaves]
+        assert owners, leaf
+        layers = (plan.in_prefix(*owners) if leaf.startswith(DENSE_PREFIX)
+                  else plan.repeats * plan.in_unit(*owners))
+        assert w.shape[0] == layers, leaf
+        for kind in plan.kinds:
+            assert tfm._owns(kind, name_) == any(b in owners for b in kind)
+
+    # Every population of the cache is what the records say its layers keep.
+    b, s = 3, 40
+    cache = jax.eval_shape(lambda: tfm.init_kv_cache(cfg, b, s))
+    for f in dataclasses.fields(cache):
+        keepers = [n for n, rec in kinds.items() if f.name in rec.cache]
+        got = getattr(cache, f.name)
+        assert (got is None) == (not keepers), f.name
+        for n in keepers:
+            shape, dtype = table[n].cache[f.name](cfg, b, s, cfg.dtype)
+            assert got.shape == (plan.count(*keepers), *shape), f.name
+            assert got.dtype == dtype, f.name
+
+    # The FLOP counts are the parent's.
+    params, train, gen = _PARENT_FLOPS[name]
+    assert monitor.matmul_params(cfg) == params
+    assert monitor.flops_train(cfg, 4096, 3 * 1024.0**2) == pytest.approx(
+        train, rel=1e-12)
+    assert monitor.flops_generate(
+        cfg, [512, 300], [128, 700]) == pytest.approx(gen, rel=1e-12)
+
+
+FAKE = "fake"
+
+
+def _fake_kind():
+    """A mixer no file under `areal_tpu/` knows: y = h + bias, and the
+    normed input of a row's last token as its one row of `state`."""
+    from areal_tpu.models.branches import Branch, HybridLayoutError, Refusal
+
+    def init(cfg, key, n, dense):
+        return {"fk_bias": dense(key, (n, cfg.hidden_dim), 1)}
+
+    def last_token(h, segment_ids):
+        idx = jnp.arange(segment_ids.shape[-1])
+        last = jnp.max(jnp.where(segment_ids > 0, idx, 0), axis=-1)
+        return jnp.take_along_axis(h, last[:, None, None], axis=1)[:, 0]
+
+    def packed(ctx, h, blk):
+        left = {}
+        if ctx.with_state:
+            left["state"] = last_token(h, ctx.segment_ids).astype(jnp.float32)
+        return h + blk["fk_bias"], left
+
+    def step(ctx, h, blk, cache, li):
+        state = jax.lax.dynamic_update_index_in_dim(
+            cache.state, h[:, 0].astype(jnp.float32), li, axis=0)
+        return h + blk["fk_bias"], dataclasses.replace(cache, state=state), {}
+
+    return Branch(
+        leaves=("fk_bias",), init=init, packed=packed, step=step,
+        cache={"state": lambda cfg, batch, s_max, dtype: (
+            (batch, cfg.hidden_dim), jnp.float32)},
+        refusal=Refusal(
+            HybridLayoutError, "a fake mixer runs on one device only",
+            "a fake mixer has no slot on the serving plane"),
+        matmul_params=lambda cfg: cfg.hidden_dim,
+    )
+
+
+@pytest.fixture
+def fake_cfg(monkeypatch):
+    """The kind registered from the test alone: its record in the table,
+    its character among the window pattern's, its leaf's sharding rule."""
+    from jax.sharding import PartitionSpec
+
+    from areal_tpu.models import config
+    from areal_tpu.parallel import sharding
+
+    monkeypatch.setitem(tfm.BRANCHES, FAKE, _fake_kind())
+    monkeypatch.setitem(config._WINDOW_KINDS, "X", FAKE)
+    monkeypatch.setitem(sharding._BLOCK_RULES, "fk_bias", PartitionSpec())
+    return dataclasses.replace(
+        _cfg("q1p5b"), n_layers=4, window_pattern="XFXF")
+
+
+def test_a_kind_registered_from_outside_runs_every_program(fake_cfg):
+    from areal_tpu.api.model_api import GenerationHyperparameters
+    from areal_tpu.base import monitor
+    from areal_tpu.base.topology import ParallelConfig, make_mesh
+    from areal_tpu.engines.generator import GeneratorEngine
+
+    cfg = fake_cfg
+    assert cfg.plan.unit == ((FAKE, "mlp"), ("attention", "mlp"))
+    params = _params(cfg)
+    assert params["blocks"]["fk_bias"].shape == (2, cfg.hidden_dim)
+    rng = np.random.default_rng(3)
+    b, p, steps, window = 2, 12, 3, 32
+    tokens = jnp.asarray(
+        rng.integers(0, cfg.vocab_size, (b, p + steps)), jnp.int32)
+    want = np.asarray(jax.jit(
+        lambda w: tfm.forward(w, cfg, tokens, jnp.ones_like(tokens)))(params))
+    # The bias is read: without it the logits move.
+    flat = {**params, "blocks": {
+        **params["blocks"],
+        "fk_bias": jnp.zeros_like(params["blocks"]["fk_bias"])}}
+    assert not np.allclose(want, np.asarray(
+        tfm.forward(flat, cfg, tokens, jnp.ones_like(tokens))), atol=1e-3)
+    logits, cache = jax.jit(lambda w: tfm.prefill(
+        w, cfg, tokens[:, :p], jnp.ones((b, p), jnp.int32),
+        tfm.init_kv_cache(cfg, b, window)))(params)
+    np.testing.assert_allclose(np.asarray(logits), want[:, p - 1], **TOL)
+    assert cache.state.shape == (2, b, cfg.hidden_dim)
+    assert cache.k.shape[0] == 2 and cache.conv is None
+    step = jax.jit(lambda tok, pos, cache, slot: tfm.decode_step(
+        params, cfg, tok, pos, cache, slot, jnp.zeros((b,), jnp.int32)))
+    for i in range(steps):
+        before = np.asarray(cache.state)
+        logits, cache = step(
+            tokens[:, p + i], jnp.full((b,), p + i, jnp.int32), cache, p + i)
+        np.testing.assert_allclose(np.asarray(logits), want[:, p + i], **TOL)
+        assert not np.array_equal(before, np.asarray(cache.state))
+    assert monitor.matmul_params(cfg) == (
+        monitor.matmul_params(dataclasses.replace(cfg, window_pattern="FFFF"))
+        - 2 * tfm.BRANCHES["attention"].matmul_params(cfg)
+        + 2 * cfg.hidden_dim)
+
+    # The generator's static program, and the serving plane's refusal.
+    mesh = make_mesh(ParallelConfig.from_str("d1"), jax.devices()[:1])
+    engine = GeneratorEngine(
+        cfg, params, mesh, eos_token_id=cfg.vocab_size, max_decode_batch=4,
+        kv_page_size=8)
+    g = GenerationHyperparameters(n=1, max_new_tokens=steps, greedy=True)
+    toks, _, gen_len = engine.static_rollout(
+        [np.asarray(tokens[r, :p]) for r in range(b)], g, jax.random.PRNGKey(0))
+    assert gen_len.tolist() == [steps] * b
+    assert toks[:, 0].tolist() == np.argmax(want[:, p - 1], axis=-1).tolist()
+    for serving, words in ((True, "no slot on the serving plane"),
+                           (False, "one device only")):
+        refusal = tfm.plan_refusal(cfg, serving)
+        assert type(refusal) is tfm.HybridLayoutError and words in str(refusal)
+    with pytest.raises(tfm.HybridLayoutError, match="no slot on the serving"):
+        tfm.init_paged_kv_cache(cfg, 8, 8)

@@ -683,10 +683,10 @@ def test_the_static_program_counts_its_tails_beside_the_attention_cache(
         np.testing.assert_allclose(logps[r, :12], want[len(p) - 1:], **TOL)
     from areal_tpu.base import monitor
 
-    assert monitor._attn_layers(cfg) == 2
+    assert sum(n for n, b in monitor._layers_of(cfg) if b.attn_flops) == 2
     d = cfg.hidden_dim
     assert monitor.matmul_params(cfg) == (
-        8 * (4 * d * d + 3 * d) + 2 * monitor._attn_params(cfg)
+        8 * (4 * d * d + 3 * d) + 2 * tfm.BRANCHES["attention"].matmul_params(cfg)
         + 2 * 3 * d * cfg.intermediate_dim
         + 8 * (3 * d * cfg.moe_intermediate_dim * 2 * 4 / 8 + d * 8)
         + d * cfg.vocab_size)

@@ -66,7 +66,7 @@ _prefill = jax.jit(
     static_argnums=1)
 _decode = jax.jit(
     lambda p, c, tok, pos, cache, slot, vf: tfm.decode_step(
-        p, c, tok, pos, cache, slot, vf, with_sparse_counts=True),
+        p, c, tok, pos, cache, slot, vf, with_counts=True),
     static_argnums=1)
 
 
@@ -475,7 +475,7 @@ def test_prefill_then_decode_through_the_cache_matches_the_reference(
         new = jnp.asarray([seq[n_prompt + i], other[n_prompt - 17 + i]])
         logits, cache, counts = _decode(
             params, cfg, new, jnp.asarray(lens + i), cache, sp + i, valid_from)
-        dense_rows += float(counts[0, 2])
+        dense_rows += float(counts["sparse"][0, 2])
         for r, n in enumerate(lens):
             np.testing.assert_allclose(
                 logits[r], want[r][n + i], err_msg=f"{why}, token {i}", **TOL)
@@ -488,7 +488,7 @@ def test_prefill_then_decode_through_the_cache_matches_the_reference(
     np.testing.assert_allclose(cache.state[:, 0], left["state"][:, 0], **TOL)
     if n_prompt >= cfg.sparse_dense_len:
         assert dense_rows == 0
-        read, cached = float(counts[0, 0]), float(counts[0, 1])
+        read, cached = (float(x) for x in counts["sparse"][0, :2])
         assert cached == sum(lens) + 2 * n_new
         assert read < 0.6 * cached  # 6 blocks of 16 + a key per 4 tokens
     else:

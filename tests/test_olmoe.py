@@ -198,8 +198,8 @@ def test_prefill_then_decode_through_the_cache_matches_the_reference(
         pos = jnp.asarray([n + step for n in lens], jnp.int32)
         logits, cache, counts = tfm.decode_step(
             params, cfg, tok, pos, cache, jnp.int32(sp + step), valid_from,
-            with_moe_counts=True)
-        assert (np.asarray(counts).sum(axis=1) == 2 * 2).all()  # rows x k
+            with_counts=True)
+        assert (np.asarray(counts["moe"]).sum(axis=1) == 2 * 2).all()  # rows x k
 
 
 def test_the_ragged_paged_stream_matches_the_reference(cfg, params):
@@ -362,7 +362,7 @@ def test_counters_on_a_hand_made_routing():
     """Four tokens, eight experts, top-2, a router that reads the choice
     off the token: the rows per expert, what the decode loop sums of them
     and what the train step reports are what the hand count says."""
-    from areal_tpu.engines.generator import _moe_step_counters
+    from areal_tpu.models.branches import LoopStep
     from areal_tpu.engines.train import _moe_stats
 
     cfg = ModelConfig(
@@ -384,7 +384,8 @@ def test_counters_on_a_hand_made_routing():
     valid = jnp.asarray([[True, True, True, False]])
     _, _, real = tfm._mlp_moe(jnp.asarray(h), blk, cfg, valid=valid)
     assert np.asarray(real).tolist() == [3, 2, 0, 0, 0, 1, 0, 0]
-    step = np.asarray(_moe_step_counters(jnp.stack([counts, real]), cfg, 4))
+    step = np.asarray(tfm.BRANCHES["moe"].counter.step(
+        jnp.stack([counts, real]), cfg, LoopStep(None, None, None, 4)))
     assert step.tolist() == [(4 + 3) / 2, (4 + 3) / 2, 1.0]
     stats = _moe_stats(aux, jnp.stack([counts, real]), cfg, 8)
     assert float(stats["moe/aux_loss"]) == float(aux)
@@ -472,10 +473,10 @@ def test_decode_in_place_equals_the_per_layer_formulation(cfg, n_layers):
         pos = jnp.full((b,), step, jnp.int32)
         logits, cache, counts = tfm.decode_step(
             params, cfg, tok, pos, cache, jnp.int32(step), valid_from,
-            with_moe_counts=True)
+            with_counts=True)
         want_logits, plain, want_counts = _plain_decode_step(
             params, cfg, tok, pos, plain, step, valid_from)
-        counts = np.asarray(counts)
+        counts = np.asarray(counts["moe"])
         assert counts.shape == (n_layers, cfg.n_experts)
         assert (counts == np.asarray(want_counts)).all()
         assert (counts.sum(axis=1) == b * k).all()
@@ -505,7 +506,7 @@ def _decode_jaxpr(cfg, params, b=4, in_place=None):
     z = jnp.zeros((b,), jnp.int32)
     return jax.make_jaxpr(
         lambda p, c: tfm.decode_step(
-            p, cfg, z, z, c, jnp.int32(0), z, with_moe_counts=cfg.is_moe,
+            p, cfg, z, z, c, jnp.int32(0), z, with_counts=True,
             experts_in_place=in_place)
     )(params, cache)
 
@@ -569,7 +570,7 @@ def test_decode_step_lowered_for_tpu_reshapes_the_parameter(cfg, params,
 
     def step(p, c, in_place):
         return tfm.decode_step(p, cfg, z, z, c, jnp.int32(0), z,
-                               with_moe_counts=True, experts_in_place=in_place)
+                               with_counts=True, experts_in_place=in_place)
 
     d, f, e = cfg.hidden_dim, cfg.intermediate_dim, cfg.n_experts
     le = cfg.n_layers * e

@@ -261,8 +261,8 @@ def test_prefill_then_decode_through_the_hybrid_cache_matches_the_reference(
         tok = jnp.asarray([r[p + t] for r, p in zip(rows, plens)], jnp.int32)
         logits, cache, counts = tfm.decode_step(
             params, cfg, tok, jnp.asarray(plen + t, jnp.int32), cache, sp + t,
-            jnp.asarray(sp - plen, jnp.int32), with_moe_counts=True)
-        assert counts.shape == (cfg.n_layers, cfg.n_experts)
+            jnp.asarray(sp - plen, jnp.int32), with_counts=True)
+        assert counts["moe"].shape == (cfg.n_layers, cfg.n_experts)
         for i, p in enumerate(plens):
             np.testing.assert_allclose(logits[i], want[i][p + t], **TOL)
 
@@ -386,13 +386,15 @@ def test_a_decode_step_that_keeps_its_state_in_bf16_is_not_correct(
     which `checks.reference_check` reports as not `correct`."""
     seq = _sequences(cfg, lens=(96,), seed=7)[0]
     assert np.isfinite(reference.next_token_logprobs(params, cfg, seq)).all()
-    inner = tfm.linear_attn_step
+    from areal_tpu.models import linear_attention
+
+    inner = linear_attention.linear_attn_step
 
     def rounded(h, blk, c, states, tails, li, *kernel):
         y, states, tails = inner(h, blk, c, states, tails, li, *kernel)
         return y, jax.lax.reduce_precision(states, 8, 7), tails
 
-    monkeypatch.setattr(tfm, "linear_attn_step", rounded)
+    monkeypatch.setattr(linear_attention, "linear_attn_step", rounded)
     jax.clear_caches()
     try:
         got = reference.next_token_logprobs(params, cfg, seq)
@@ -536,8 +538,8 @@ def test_the_train_step_counts_segment_starts_and_flops_follow_the_kinds(cfg):
     assert monitor.matmul_params(cfg) - 3 * rec == pytest.approx(
         peaks_hybrid.matmul_params(cfg))
     # A dense twin with softmax attention in every layer counts 4 of them.
-    assert monitor._attn_layers(cfg) == 1
-    assert monitor._attn_layers(tiny_config()) == tiny_config().n_layers
+    assert sum(n for n, b in monitor._layers_of(cfg) if b.attn_flops) == 1
+    assert sum(n for n, b in monitor._layers_of(tiny_config()) if b.attn_flops) == tiny_config().n_layers
     big = bench_run.model_config(
         files.load_json("configs", "qwen3-next-80b-a3b-l4-e64.json"))
     # Section "The cut" of ISSUE 32: bytes a decode step's mixers move.
