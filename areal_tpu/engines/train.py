@@ -466,7 +466,8 @@ class TrainEngine(HostOffloadMixin, Engine):
                 for name, branch in tfm.branches_of(cfg).items():
                     if branch.train_stats:
                         stats = {**stats, **branch.train_stats(
-                            cfg, cfg.plan.count(name), batch["segment_ids"])}
+                            cfg, cfg.plan.count(name), batch["segment_ids"],
+                            row_kernel)}
                 return total * loss_scale, stats
 
             with jax.named_scope("train/grad"):

@@ -143,8 +143,8 @@ class Branch:
     trainer counts the tiles it visits beside the full layers').
     `counter`: its sums in the static decode loop; `cache_stats(cfg, cache,
     batch, s_max)`: the `last_pool_stats` keys of a static program's cache;
-    `train_stats(cfg, n_layers, segment_ids)`: its keys of a train step's
-    stats; `grad_options(cfg, row_kernel)`: the compiler options a gradient
+    `train_stats(cfg, n_layers, segment_ids, row_kernel)`: its keys of a
+    train step's stats; `grad_options(cfg, row_kernel)`: the compiler options a gradient
     program with the kind asks for."""
 
     leaves: Tuple[str, ...] = ()

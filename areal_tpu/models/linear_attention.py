@@ -476,7 +476,7 @@ def _grad_options(cfg: ModelConfig, row_kernel):
     return {}
 
 
-def _train_stats(cfg: ModelConfig, n_layers: int, seg: jax.Array):
+def _train_stats(cfg: ModelConfig, n_layers: int, seg: jax.Array, row_kernel):
     starts = segment_starts(seg)
     return {
         "linear_attn/segments_per_row": jnp.mean(

@@ -13,12 +13,20 @@ WITH bias + SiLU over the x | B | C channels; after it y * silu(z), an
 RMSNorm over each GROUP of channels times a weight (gate first, then norm)
 and the output projection.
 
-Three forms of the same recurrence:
-- `ssm_forward` (training, `forward`, prefill): the chunked (SSD) form.
-  Inside a chunk of `ssm_chunk` tokens y = ((C B^T) * decay) (dt x); each
-  chunk's own contribution to the state and the read of the state it
-  starts from are batched matmuls over all chunks, and a `lax.scan` over
-  chunks carries S through an elementwise update alone.
+Four forms of the same recurrence:
+- `ssm_forward` (training, `forward`, prefill): the chunked (SSD) form,
+  `ssd_chunked`.  Inside a chunk of `ssm_chunk` tokens y = ((C B^T) *
+  decay) (dt x); each chunk's own contribution to the state and the read of
+  the state it starts from are batched matmuls over all chunks, and a
+  `lax.scan` over chunks carries S through an elementwise update alone.
+- the same chunked form as a Pallas sweep over a row's chunks
+  (`ops/pallas/ssd_chunk.py`, kernels `ssd_chunk_fwd` / `ssd_chunk_bwd`
+  under the scope `ssd_scan`): a head's [C, C] block and the carried state
+  stay in VMEM, a reverse sweep is its backward.  `ssm_forward` takes it in
+  the gradient program and `forward` on ONE TPU device where the widths make
+  whole tiles (`ssd_kernel_form`: no knob), and keeps `ssd_chunked`, the
+  sweep's oracle, off a TPU, under a Mesh and in prefill (`with_state`),
+  whose programs and bits stay what they were.
 - `ssm_step` (decode): one token against the carried S and the conv's last
   K-1 inputs.
 - `ssm_ragged` (the serving plane's chunk): a PACKED RAGGED stream in which
@@ -244,6 +252,23 @@ def ssd_chunked(
     return y, state.reshape(b, h, p, n)
 
 
+def ssd_kernel_form(cfg: ModelConfig, kernel=None, with_state=False) -> bool:
+    """Whether `ssm_forward` runs the recurrence on the Pallas sweep
+    `ssd_chunk`: by what the code can see (`flash_attention.
+    row_kernel_form`: a TPU backend, widths the kernel can cut; a bool
+    forces either) on ONE device — `kernel` a Mesh: a `pallas_call` is one
+    device's program — and without `with_state`: prefill reads the final
+    state, and its program and what a generator samples from it stay
+    `ssd_chunked`'s."""
+    from areal_tpu.ops.pallas import ssd_chunk
+    from areal_tpu.ops.pallas.flash_attention import row_kernel_form
+
+    use_kernel, mesh = row_kernel_form(kernel, ssd_chunk.fits(
+        cfg.ssm_n_heads, cfg.ssm_n_groups, cfg.ssm_head_dim,
+        cfg.ssm_state_dim, cfg.ssm_chunk))
+    return use_kernel and mesh is None and not with_state
+
+
 @jax.named_scope("layer/ssm")
 def ssm_forward(
     h: jax.Array,  # [B, S, D] normed layer input
@@ -251,10 +276,13 @@ def ssm_forward(
     cfg: ModelConfig,
     segment_ids: jax.Array,
     with_state: bool = False,
+    kernel=None,  # None | bool | Mesh: `flash_attention.row_kernel_form`
 ):
     """-> y [B, S, D]; `with_state` (prefill) adds the state at each row's
     last VALID token [B, H, P, N] fp32 and the conv's tail there [B, K-1,
-    conv_dim]."""
+    conv_dim].  The recurrence has one form per backend and caller
+    (`ssd_kernel_form`): the Pallas sweep `ssd_chunk` or `ssd_chunked`."""
+    use_kernel = ssd_kernel_form(cfg, kernel, with_state)
     with jax.named_scope("in_proj"):
         z, xbc, dt = _split_in(h @ blk["ssm_in"], cfg)
     with jax.named_scope("conv"):
@@ -263,13 +291,24 @@ def ssm_forward(
             + blk["ssm_conv_b"].astype(jnp.float32)
         )
     with jax.named_scope("ssd_scan"):
-        x, bm, cm = _split_conv(conv, cfg)
-        dt, a = _dt_a(dt, blk)
-        dt = jnp.where((segment_ids > 0)[..., None], dt, 0.0)
-        y, state = ssd_chunked(
-            x, dt, a, bm, cm, _fill_pads(segment_ids), cfg.ssm_chunk
-        )
-        y = y + blk["ssm_D"].astype(jnp.float32)[:, None] * x
+        if use_kernel:
+            # The sweep reads x, B and C where they lie in the conv's
+            # output, and adds the D skip itself.
+            from areal_tpu.ops.pallas import ssd_chunk
+
+            dt, a = _dt_a(dt, blk)
+            dt = jnp.where((segment_ids > 0)[..., None], dt, 0.0)
+            y = ssd_chunk.ssd_chunk(
+                conv, dt, a, blk["ssm_D"], _fill_pads(segment_ids),
+                cfg.ssm_chunk, cfg.ssm_head_dim, cfg.ssm_n_groups)
+        else:
+            x, bm, cm = _split_conv(conv, cfg)
+            dt, a = _dt_a(dt, blk)
+            dt = jnp.where((segment_ids > 0)[..., None], dt, 0.0)
+            y, state = ssd_chunked(
+                x, dt, a, bm, cm, _fill_pads(segment_ids), cfg.ssm_chunk
+            )
+            y = y + blk["ssm_D"].astype(jnp.float32)[:, None] * x
     out = _out(y.reshape(*y.shape[:2], cfg.ssm_inner_dim), z, blk, cfg)
     if with_state:
         idx = jnp.arange(segment_ids.shape[-1])
@@ -562,9 +601,11 @@ def ssm_ragged(
 
 def _packed(ctx, h, blk):
     if not ctx.with_state:
-        return ssm_forward(h, blk, ctx.cfg, ctx.segment_ids), {}
+        return ssm_forward(
+            h, blk, ctx.cfg, ctx.segment_ids, kernel=ctx.row_kernel), {}
     out, state, tail = ssm_forward(
-        h, blk, ctx.cfg, ctx.segment_ids, with_state=True)
+        h, blk, ctx.cfg, ctx.segment_ids, with_state=True,
+        kernel=ctx.row_kernel)
     return out, {"state": state, "conv": tail}
 
 
@@ -605,12 +646,15 @@ def _matmul_params(cfg: ModelConfig) -> int:
     return h * cfg.ssm_in_dim + di * h + 2 * di * cfg.ssm_state_dim
 
 
-def _train_stats(cfg: ModelConfig, n_layers: int, seg: jax.Array):
+def _train_stats(cfg: ModelConfig, n_layers: int, seg: jax.Array, row_kernel):
     """What the chunked scan ran over, summed over the Mamba layers:
-    chunks, and the restarts."""
-    n_chunks = seg.shape[0] * -(-seg.shape[1] // cfg.ssm_chunk)
+    chunks, those of them on the Pallas sweep (`ssd_kernel_form`: all or
+    none), and the restarts."""
+    n_chunks = n_layers * seg.shape[0] * -(-seg.shape[1] // cfg.ssm_chunk)
     return {
-        "ssm/chunks": jnp.float32(n_layers * n_chunks),
+        "ssm/chunks": jnp.float32(n_chunks),
+        "ssm/chunks_on_kernel": jnp.float32(
+            n_chunks * ssd_kernel_form(cfg, row_kernel)),
         "ssm/segment_restarts": n_layers * segment_restarts(seg),
     }
 

@@ -159,7 +159,7 @@ def _cache_stats(cfg: ModelConfig, cache, batch: int, s_max: int):
     }
 
 
-def _train_stats(cfg: ModelConfig, n_layers: int, seg: jax.Array):
+def _train_stats(cfg: ModelConfig, n_layers: int, seg: jax.Array, row_kernel):
     """The short convolutions' restarts, summed over them."""
     return {"sconv/segment_restarts": n_layers * segment_restarts(seg)}
 

@@ -781,7 +781,12 @@ _PARENT_PROGRAMS = {
     # could keep beside the hybrid's text.  Regenerated after loss, every
     # gradient leaf, prefill logits, every cache field and eight decode
     # steps were `np.array_equal` between the two commits (CHANGES.md).
-    ("pattern", "grad"): "e84b2254321de4ae4fe6fc5777a54721b31050ad4f712a54539e176912cdcea2",
+    # PR 58 gives the gradient program ONE more stat, `ssm/chunks_on_kernel`
+    # (at PR 58's parent, 6ead7dd, the text hashed e84b2254321de4ae...
+    # 12cdcea2): regenerated after the loss, every gradient leaf and the 18
+    # other stats were `np.array_equal` between the two commits on a packed
+    # batch, the new stat 0 beside `ssm/chunks` 128 (CHANGES.md).
+    ("pattern", "grad"): "a8176a3a89c6a275e5ed395b126882abb9720c0c94e498d98083792bb321bb4f",
     ("pattern", "gen"): "bd971236683b7a70284bd3c2e324b4a986b809ea89631ff0c8ed60c51aa4bbad",
 }
 

@@ -632,3 +632,39 @@ def test_the_dense_serving_step_lowers_to_the_parents_program(n_experts):
     ).lower(shapes, i32(12), i32(12), pool, i32(4, 4), i32(12)).as_text()
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == (
         _PARENT_SERVING_STEPS[n_experts])
+
+
+# ------------------------- the gradient program's scan on the Pallas sweep
+
+# The Mamba widths the sweep can cut (heads of 64 channels, a state of 128
+# columns, chunks of 128, ONE group as published), the rest of the toy as
+# it is.
+_WIDE = dict(ssm_n_heads=8, ssm_head_dim=64, ssm_state_dim=128,
+             ssm_n_groups=1, ssm_chunk=128)
+# `_program_sha(_cfg(**_WIDE, ssm_n_groups=2), "gen")` as printed at the
+# parent of PR 58 (6ead7dd): prefill + a decode step at those widths.
+_PARENT_WIDE_GEN = (
+    "39710ee420d2cef9222cc22aeec317b780e4dfa2609cac35b6085fbbc54cc0a4")
+
+
+def test_the_train_step_on_the_forced_sweep_is_the_jnp_forms(monkeypatch):
+    """The toy model's loss and the gradient of every leaf with the chunked
+    scan on `ssd_chunk` against `ssd_chunked`, inside this file's fp32
+    bounds (the case's body: `tests/test_ssd_chunk_kernel.py`)."""
+    from tests.test_ssd_chunk_kernel import (
+        train_step_on_the_sweep_is_the_jnp_forms,
+    )
+
+    cfg = _cfg(**_WIDE)
+    train_step_on_the_sweep_is_the_jnp_forms(
+        cfg, _params(cfg), monkeypatch, jit=True)
+
+
+def test_prefill_keeps_the_parents_program_at_the_sweeps_widths():
+    """Prefill reads the final state (`with_state`): at widths the sweep
+    takes in the gradient program, prefill + a decode step lower to the
+    text they lowered to at the parent of PR 58."""
+    from tests.test_glm4_moe_lite import _program_sha
+
+    assert _program_sha(
+        _cfg(**dict(_WIDE, ssm_n_groups=2)), "gen") == _PARENT_WIDE_GEN

@@ -745,6 +745,70 @@ def test_the_latent_family_lowers_to_the_parents_program(program):
     assert _program_sha(_glm_toy(), program) == _PARENT_PROGRAMS[program]
 
 
+# ------------------------- the gradient program's scan on the Pallas sweep
+
+# The Mamba widths the sweep can cut (heads of 64 channels, a state of 128
+# columns, chunks of 128), the rest of the toy as it is.
+_WIDE = dict(ssm_n_heads=8, ssm_head_dim=64, ssm_state_dim=128,
+             ssm_n_groups=2, ssm_chunk=128)
+# `_program_sha(_cfg(**_WIDE), "gen")` as printed at the parent of PR 58
+# (6ead7dd): prefill + a decode step at those widths.
+_PARENT_WIDE_GEN = (
+    "af3125c9f1f3f93f903e813656c7ca8925521f7781a7e4519c5fb4341538dfff")
+# ... and of the mixer alone under `with_state` on bf16 leaves, as the cell
+# holds them (a cast that moves in the trace shows only there).
+_PARENT_WIDE_MIXER_PREFILL = (
+    "7e23b15e84ef444950191640fb926a360d5e828b811a1ecb05d0d5032e9961ed")
+
+
+def test_the_train_step_on_the_forced_sweep_is_the_jnp_forms(monkeypatch):
+    """The toy model's loss and the gradient of every leaf with the chunked
+    scan on `ssd_chunk` against `ssd_chunked`, inside this file's fp32
+    bounds (the case's body: `tests/test_ssd_chunk_kernel.py`); the counter
+    says which form ran."""
+    from tests.test_ssd_chunk_kernel import (
+        train_step_on_the_sweep_is_the_jnp_forms,
+    )
+
+    cfg = _cfg(**_WIDE)
+    seg = train_step_on_the_sweep_is_the_jnp_forms(
+        cfg, _params(cfg), monkeypatch)
+    stats = mamba.BRANCH.train_stats(cfg, 4, seg, True)
+    assert float(stats["ssm/chunks_on_kernel"]) == float(
+        stats["ssm/chunks"]) == 4 * 3
+    assert float(mamba.BRANCH.train_stats(cfg, 4, seg, None)[
+        "ssm/chunks_on_kernel"]) == 0  # a CPU backend
+
+
+def test_prefill_keeps_the_parents_program_at_the_sweeps_widths(monkeypatch):
+    """Prefill reads the final state (`with_state`): at widths the sweep
+    takes in the gradient program, prefill + a decode step lower to the
+    text they lowered to at the parent of PR 58, and the mixer under
+    `with_state` lowers to that ONE text whatever backend JAX reports."""
+    import hashlib
+
+    from tests.test_glm4_moe_lite import _program_sha
+
+    cfg = _cfg(**_WIDE)
+    assert _program_sha(cfg, "gen") == _PARENT_WIDE_GEN
+    blk = jax.eval_shape(lambda: {
+        k: v[0].astype(jnp.bfloat16) for k, v in tfm.init_params(
+            cfg, jax.random.PRNGKey(0))["blocks"].items()
+        if k in mamba.SSM_LEAVES})
+    h = jax.ShapeDtypeStruct((2, 256, cfg.hidden_dim), jnp.bfloat16)
+    seg = jax.ShapeDtypeStruct((2, 256), jnp.int32)
+
+    def text():
+        return jax.jit(lambda h, blk, seg: mamba.ssm_forward(
+            h, blk, cfg, seg, with_state=True)).lower(h, blk, seg).as_text()
+
+    here = text()
+    assert hashlib.sha256(
+        here.encode()).hexdigest() == _PARENT_WIDE_MIXER_PREFILL
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert mamba.ssd_kernel_form(cfg) and text() == here
+
+
 # ------------------------------------------- the decode loop compiled for v5e
 
 
