@@ -139,6 +139,10 @@ class ModelConfig:
     # `wd`) where a gated expert has three.
     mlp_gated: bool = True
     proj_bias: bool = False  # biases on attn-out + mlp matmuls (gpt2)
+    # Where a residual branch's norm (`ln1` / `ln2`) sits: "input", x +
+    # f(norm(x)) (every other family), or "output", x + norm(f(x)) with f
+    # fed the raw stream (OLMo 2's block: olmo_hybrid).
+    branch_norm: str = "input"
     # ---- hybrid layer pattern (qwen3_next) ----
     # Layer i is softmax attention when (i + 1) % full_attn_interval == 0,
     # else a Gated DeltaNet (linear attention) block: the stack is scanned
@@ -150,6 +154,10 @@ class ModelConfig:
     linear_k_head_dim: int = 0
     linear_v_head_dim: int = 0
     linear_conv_kernel: int = 4
+    # The delta rule's beta = 2 sigmoid(b) instead of sigmoid(b) (FLA's
+    # `allow_neg_eigval`, olmo_hybrid): I - beta k k^T then has an
+    # eigenvalue in (-1, 1) and not in (0, 1).
+    linear_neg_eigval: bool = False
     # Rotary embedding on the first `rotary_dim` of head_dim only (HF
     # `partial_rotary_factor`); 0 = all of head_dim.
     rotary_dim: int = 0
@@ -320,6 +328,8 @@ class ModelConfig:
                 "attention and needs the positions it was trained over "
                 "(rope_yarn_original)"
             )
+        if self.branch_norm not in ("input", "output"):
+            raise ValueError(f"unknown branch_norm {self.branch_norm!r}")
         if self.moe_score_func not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown moe_score_func {self.moe_score_func!r}")
         if self.attention_multiplier and self.is_latent:

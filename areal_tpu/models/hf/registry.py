@@ -11,7 +11,9 @@ Families here (full reference parity, api/from_hf/*): llama, qwen2
 (learned positions, LayerNorm+bias, fused c_attn, non-gated gelu MLP), olmoe
 (64-expert MoE under `mlp.experts.{e}`, QK-norm over the whole projection,
 top-k router weights not renormalised), qwen3_next (a hybrid period of
-Gated DeltaNet and gated attention, a gated shared expert), glm4_moe_lite
+Gated DeltaNet and gated attention, a gated shared expert), olmo_hybrid
+(the same period with a dense MLP, the norms on a branch's output, the
+delta rule's beta in (0, 2), attention without positions), glm4_moe_lite
 (the deepseek_v3 block: latent attention, a sigmoid router with an
 untrained choice bias, an ungated shared expert, leading dense layers),
 nemotron_h (a pattern of one-branch layers: Mamba-2 mixers, ungated relu²
@@ -968,6 +970,244 @@ register_hf_family(
         _qwen3_next_config_to_hf,
         params_from_sd=_qwen3_next_params_from_sd,
         params_to_sd=_qwen3_next_params_to_sd,
+    )
+)
+
+
+# ---------------- olmo_hybrid ----------------
+# allenai/Olmo-Hybrid-7B: `layer_types` gives every layer its mixer —
+# "linear_attention", Flash Linear Attention's `GatedDeltaNet` (the rule of
+# `models/linear_attention.py`, as many key as value heads, d_v = 2 d_k, and
+# with `linear_allow_neg_eigval` beta = 2 sigmoid(b)), or "full_attention",
+# softmax attention with as many key as query heads — a dense SwiGLU MLP
+# behind each, the head untied.  Three conventions the config has NO key
+# for are the family's (OLMo 2, Olmo 3) and stated as assumptions in
+# `benchmark/configs/olmo-hybrid-7b-l4-v8.json`, one field each so that a
+# correction from the published module is a change of data: the norms sit
+# on a branch's OUTPUT (`branch_norm`: h = x + norm(mixer(x)), y = h +
+# norm(mlp(h)); `ln1` / `ln2` are `post_attention_layernorm` /
+# `post_feedforward_layernorm`), the full layers norm q and k over the
+# WHOLE projection (`qk_norm`, olmoe's), and with `rope_parameters.
+# rope_theta` null they take no positions (`pos_emb` "none").  The tensor
+# names are assumed too (OLMo 2's for the block, FLA's for the mixer under
+# `linear_attn.`; no network to re-read the module).
+
+_OLMOH_LAYER_TYPES = ("linear_attention", "full_attention")
+
+
+def _olmo_hybrid_interval(types, n_layers: int) -> int:
+    """`layer_types` as `full_attn_interval`: periods of n - 1 linear
+    layers and one full layer."""
+    if len(types) != n_layers or set(types) - set(_OLMOH_LAYER_TYPES):
+        raise ValueError(
+            f"layer_types {types!r} is not {n_layers} of "
+            f"{list(_OLMOH_LAYER_TYPES)}"
+        )
+    n = types.index("full_attention") + 1 if "full_attention" in types else 0
+    period = ["linear_attention"] * (n - 1) + ["full_attention"]
+    if n < 2 or n_layers % n or list(types) != period * (n_layers // n):
+        raise NotImplementedError(
+            f"olmo_hybrid layer_types {types!r}: the layers are whole "
+            "periods of linear_attention layers closed by one full_attention "
+            "layer"
+        )
+    return n
+
+
+def _olmo_hybrid_config_from_hf(hf: dict) -> ModelConfig:
+    for key, fine in (
+        ("attention_bias", False), ("hidden_act", "silu"),
+        ("rope_scaling", None), ("clip_qkv", None),
+    ):
+        if hf.get(key, fine) != fine:
+            raise NotImplementedError(
+                f"olmo_hybrid {key}={hf[key]!r} is not modelled"
+            )
+    theta = (hf.get("rope_parameters") or {}).get(
+        "rope_theta", hf.get("rope_theta"))
+    return ModelConfig(
+        n_layers=hf["num_hidden_layers"],
+        hidden_dim=hf["hidden_size"],
+        n_q_heads=hf["num_attention_heads"],
+        n_kv_heads=hf.get("num_key_value_heads", hf["num_attention_heads"]),
+        head_dim=hf.get("head_dim")
+        or hf["hidden_size"] // hf["num_attention_heads"],
+        intermediate_dim=hf["intermediate_size"],
+        vocab_size=hf["vocab_size"],
+        max_position_embeddings=hf.get("max_position_embeddings", 65536),
+        rms_norm_eps=hf.get("rms_norm_eps", 1e-6),
+        tied_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        # A null theta parameterises no rotary table: no positions in the
+        # full layers; order reaches the model through the linear layers.
+        pos_emb="none" if theta is None else "rope",
+        **({} if theta is None else {"rope_theta": float(theta)}),
+        qk_norm=True,
+        branch_norm="output",
+        full_attn_interval=_olmo_hybrid_interval(
+            hf["layer_types"], hf["num_hidden_layers"]),
+        linear_n_k_heads=hf["linear_num_key_heads"],
+        linear_n_v_heads=hf["linear_num_value_heads"],
+        linear_k_head_dim=hf["linear_key_head_dim"],
+        linear_v_head_dim=hf["linear_value_head_dim"],
+        linear_conv_kernel=hf["linear_conv_kernel_dim"],
+        linear_neg_eigval=bool(hf.get("linear_allow_neg_eigval", False)),
+    )
+
+
+def _olmo_hybrid_config_to_hf(cfg: ModelConfig) -> dict:
+    n = cfg.full_attn_interval
+    return {
+        "model_type": "olmo_hybrid",
+        "architectures": ["OlmoHybridForCausalLM"],
+        "torch_dtype": "bfloat16",
+        "num_hidden_layers": cfg.n_layers,
+        "layer_types": (
+            ["linear_attention"] * (n - 1) + ["full_attention"]
+        ) * cfg.n_periods,
+        "hidden_size": cfg.hidden_dim,
+        "intermediate_size": cfg.intermediate_dim,
+        "num_attention_heads": cfg.n_q_heads,
+        "num_key_value_heads": cfg.n_kv_heads,
+        "vocab_size": cfg.vocab_size,
+        "max_position_embeddings": cfg.max_position_embeddings,
+        "hidden_act": "silu",
+        "attention_bias": False,
+        "rms_norm_eps": cfg.rms_norm_eps,
+        "tie_word_embeddings": cfg.tied_embeddings,
+        "rope_parameters": {
+            "rope_theta": None if cfg.pos_emb == "none" else cfg.rope_theta},
+        "linear_num_key_heads": cfg.linear_n_k_heads,
+        "linear_num_value_heads": cfg.linear_n_v_heads,
+        "linear_key_head_dim": cfg.linear_k_head_dim,
+        "linear_value_head_dim": cfg.linear_v_head_dim,
+        "linear_conv_kernel_dim": cfg.linear_conv_kernel,
+        "linear_allow_neg_eigval": cfg.linear_neg_eigval,
+    }
+
+
+_OLMOH = "model.layers.{}."
+# ours <- the name behind the layer's prefix, transposed ([out, in] -> [in,
+# out]); the mixer's fused leaves (`la_wqkv`, `la_wba`, `la_conv`) below.
+_OLMOH_LAYER = (  # every layer
+    ("ln1", "post_attention_layernorm.weight", False),
+    ("ln2", "post_feedforward_layernorm.weight", False),
+    ("wg", "mlp.gate_proj.weight", True),
+    ("wu", "mlp.up_proj.weight", True),
+    ("wd", "mlp.down_proj.weight", True),
+)
+_OLMOH_FULL = (
+    ("wq", "self_attn.q_proj.weight", True),
+    ("wk", "self_attn.k_proj.weight", True),
+    ("wv", "self_attn.v_proj.weight", True),
+    ("wo", "self_attn.o_proj.weight", True),
+    ("q_norm", "self_attn.q_norm.weight", False),
+    ("k_norm", "self_attn.k_norm.weight", False),
+)
+_OLMOH_LINEAR = (
+    ("la_wz", "linear_attn.g_proj.weight", True),
+    ("la_A_log", "linear_attn.A_log", False),
+    ("la_dt_bias", "linear_attn.dt_bias", False),
+    ("la_norm", "linear_attn.o_norm.weight", False),
+    ("la_wo", "linear_attn.o_proj.weight", True),
+)
+# A fused leaf <- its parts, side by side: la_wqkv = q | k | v, la_wba = b |
+# a (projections, transposed), la_conv = the three depthwise convs' taps.
+_OLMOH_FUSED = {
+    "la_wqkv": ("q_proj", "k_proj", "v_proj"),
+    "la_wba": ("b_proj", "a_proj"),
+    "la_conv": ("q_conv1d", "k_conv1d", "v_conv1d"),
+}
+
+
+def _olmo_hybrid_groups(cfg):
+    every, full, linear = _q3n_layers(cfg)
+    return (
+        (_OLMOH_LAYER, every), (_OLMOH_FULL, full), (_OLMOH_LINEAR, linear),
+    ), linear
+
+
+def _olmo_hybrid_params_from_sd(cfg, sd, dtype=None):
+    import jax.numpy as jnp
+
+    dtype = dtype or cfg.dtype
+
+    def get(name):
+        if name not in sd:
+            raise KeyError(f"missing tensor {name!r} in checkpoint")
+        return np.asarray(sd[name], np.float32)
+
+    def stack(layers, fn):
+        return jnp.asarray(
+            np.stack([fn(_OLMOH.format(i)) for i in layers]), dtype)
+
+    groups, linear = _olmo_hybrid_groups(cfg)
+    blocks = {}
+    for group, layers in groups:
+        for ours, theirs, t in group:
+            blocks[ours] = stack(
+                layers,
+                lambda pre: get(pre + theirs).T if t else get(pre + theirs))
+
+    def part(pre, name):  # -> [in or K, out or C]
+        w = get(f"{pre}linear_attn.{name}.weight")
+        return w[:, 0].T if w.ndim == 3 else w.T  # a conv's [C, 1, K]
+
+    for ours, parts in _OLMOH_FUSED.items():
+        blocks[ours] = stack(linear, lambda pre: np.concatenate(
+            [part(pre, name) for name in parts], axis=1))
+    params = {
+        "embed": jnp.asarray(get("model.embed_tokens.weight"), dtype),
+        "blocks": blocks,
+        "final_ln": jnp.asarray(get("model.norm.weight"), dtype),
+    }
+    if not cfg.tied_embeddings:
+        params["lm_head"] = jnp.asarray(get("lm_head.weight").T, dtype)
+    return params
+
+
+def _olmo_hybrid_params_to_sd(cfg, params):
+    from areal_tpu.base.distributed import to_host
+
+    def host(x):
+        return to_host(x).astype(np.float32, copy=False)
+
+    blocks = {n: host(w) for n, w in params["blocks"].items()}
+    out = {
+        "model.embed_tokens.weight": host(params["embed"]),
+        "model.norm.weight": host(params["final_ln"]),
+    }
+    if not cfg.tied_embeddings:
+        out["lm_head.weight"] = np.ascontiguousarray(
+            host(params["lm_head"]).T)
+    groups, linear = _olmo_hybrid_groups(cfg)
+    for group, layers in groups:
+        for ours, theirs, t in group:
+            for j, i in enumerate(layers):
+                w = blocks[ours][j]
+                out[_OLMOH.format(i) + theirs] = (
+                    np.ascontiguousarray(w.T) if t else w)
+    kd, vd = cfg.linear_key_dim, cfg.linear_value_dim
+    hv = cfg.linear_n_v_heads
+    widths = {
+        "la_wqkv": (kd, kd, vd), "la_wba": (hv, hv), "la_conv": (kd, kd, vd)}
+    for ours, parts in _OLMOH_FUSED.items():
+        cuts = np.cumsum((0,) + widths[ours])
+        for j, i in enumerate(linear):
+            for n, name in enumerate(parts):
+                w = np.ascontiguousarray(
+                    blocks[ours][j][:, cuts[n]: cuts[n + 1]].T)
+                out[f"{_OLMOH.format(i)}linear_attn.{name}.weight"] = (
+                    w[:, None] if ours == "la_conv" else w)
+    return out
+
+
+register_hf_family(
+    HFFamily(
+        "olmo_hybrid",
+        _olmo_hybrid_config_from_hf,
+        _olmo_hybrid_config_to_hf,
+        params_from_sd=_olmo_hybrid_params_from_sd,
+        params_to_sd=_olmo_hybrid_params_to_sd,
     )
 )
 
@@ -2357,7 +2597,7 @@ def infer_model_type(cfg: ModelConfig) -> str:
     if cfg.norm_type == "layernorm":
         return "gpt2"
     if cfg.is_hybrid:
-        return "qwen3_next"
+        return "olmo_hybrid" if cfg.branch_norm == "output" else "qwen3_next"
     if cfg.n_sparse_layers or cfg.n_lightning_layers:
         return "minicpm_sala"
     if cfg.is_pattern:

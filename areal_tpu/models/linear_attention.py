@@ -10,6 +10,9 @@ Per value head, with a state S [d_k, d_v] kept in fp32:
 
 before it a causal depthwise conv + SiLU over the (q, k, v) channels, L2-
 normalised q and k, and after it a per-head RMSNorm gated by silu(z).
+beta_t = sigmoid(b_t), or 2 sigmoid(b_t) with `cfg.linear_neg_eigval`
+(olmo_hybrid: I - beta k k^T may then flip a direction's sign); both forms
+below take beta as it comes.
 
 Two forms of the same recurrence:
 - `linear_attn_forward` (training, `forward`, prefill): the chunked (WY)
@@ -97,12 +100,17 @@ def _l2norm(x: jax.Array, eps: float = 1e-6) -> jax.Array:
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
 
 
-def _gates(ba: jax.Array, blk: Params, hv: int):
-    """beta = sigmoid(b), g = -exp(A_log) softplus(a + dt_bias), fp32."""
+def _gates(ba: jax.Array, blk: Params, cfg: ModelConfig):
+    """beta = sigmoid(b) — times 2 with `cfg.linear_neg_eigval`, so that I -
+    beta k k^T has an eigenvalue in (-1, 1) — and g = -exp(A_log)
+    softplus(a + dt_bias), fp32."""
+    hv = cfg.linear_n_v_heads
     with jax.named_scope("gates"):
         ba = ba.astype(jnp.float32)
         b, a = ba[..., :hv], ba[..., hv:]
         beta = jax.nn.sigmoid(b)
+        if cfg.linear_neg_eigval:
+            beta = 2.0 * beta
         g = -jnp.exp(blk["la_A_log"].astype(jnp.float32)) * jax.nn.softplus(
             a + blk["la_dt_bias"].astype(jnp.float32)
         )
@@ -292,6 +300,27 @@ def chunk_kernel_form(cfg: ModelConfig, kernel=None, with_state=False) -> bool:
     return use_kernel and mesh is None and not with_state
 
 
+def _conv_reads_made_input(cfg: ModelConfig) -> bool:
+    """Whether `linear_attn_forward` puts the projection's output behind an
+    optimization barrier, so that it is MADE before the conv reads it: on a
+    TPU backend, where the heads are not whole 128-lane tiles of their own.
+    There (30 heads of 96 x 192, 11,520 channels) XLA:TPU otherwise reads
+    the conv's three shifted views straight off the projection's output, in
+    windows of the row, and the first tokens of a window see the wrong rows
+    behind them — with the rule on the sweep every 8th chunk of a 2,048-
+    token row, every 7th of 4,096, every 5th of 8,192, with it on the `jnp`
+    form the chunk at token 4,096 of an 8,192-token row and `la_conv`'s
+    gradient 140 times off; with the barrier both forms read the plain
+    reference's log-probs over the whole row, and at heads of 128 x 128
+    (8,192 channels) the programs are right without it (my chip runs, PR
+    59, PERF.md sections 6 and 7; rows under 1,536 never showed it)."""
+    from areal_tpu.base.distributed import is_tpu_backend
+    from areal_tpu.ops.pallas import delta_step
+
+    return is_tpu_backend() and not delta_step.fits(
+        cfg.linear_k_head_dim, cfg.linear_v_head_dim)
+
+
 @jax.named_scope("layer/linear_attn")
 def linear_attn_forward(
     h: jax.Array,  # [B, S, D] normed block input
@@ -313,9 +342,11 @@ def linear_attn_forward(
         qkv = h @ blk["la_wqkv"]
         z = h @ blk["la_wz"]
         ba = h @ blk["la_wba"]
+    if _conv_reads_made_input(cfg):
+        qkv = jax.lax.optimization_barrier(qkv)
     with jax.named_scope("conv"):
         conv = jax.nn.silu(causal_conv(qkv, blk["la_conv"], segment_ids))
-    beta, g = _gates(ba, blk, cfg.linear_n_v_heads)
+    beta, g = _gates(ba, blk, cfg)
     with jax.named_scope("delta_rule"):
         q, k, v = _split_heads(conv, cfg, repeat=not use_kernel)
         if use_kernel:
@@ -345,6 +376,19 @@ def delta_step_jnp(state, q, k, v, g, beta):
     return state * decay[..., None] + k[..., None] * d[..., None, :], o
 
 
+def step_kernel_form(cfg: ModelConfig, kernel=None):
+    """Which form `linear_attn_step` runs the recurrence in -> (on the
+    Pallas kernel `gdn_delta_step`, the mesh to `shard_map` it over or
+    None): by what the code can see (`flash_attention.row_kernel_form`: a
+    TPU backend, head widths of whole 128-lane tiles; a bool forces
+    either)."""
+    from areal_tpu.ops.pallas import delta_step
+    from areal_tpu.ops.pallas.flash_attention import row_kernel_form
+
+    return row_kernel_form(kernel, delta_step.fits(
+        cfg.linear_k_head_dim, cfg.linear_v_head_dim))
+
+
 @jax.named_scope("layer/linear_attn")
 def linear_attn_step(
     h: jax.Array,  # [B, 1, D]
@@ -371,10 +415,8 @@ def linear_attn_step(
       needs is a fusion of its own, so on a TPU it streamed the state from
       HBM twice (PERF.md §6, PR 42)."""
     from areal_tpu.ops.pallas import delta_step
-    from areal_tpu.ops.pallas.flash_attention import row_kernel_form
 
-    use_kernel, mesh = row_kernel_form(kernel, delta_step.fits(
-        cfg.linear_k_head_dim, cfg.linear_v_head_dim))
+    use_kernel, mesh = step_kernel_form(cfg, kernel)
     if not use_kernel:
         state = jax.lax.dynamic_index_in_dim(states, li, axis=0, keepdims=False)
     tail = jax.lax.dynamic_index_in_dim(tails, li, axis=0, keepdims=False)
@@ -392,7 +434,7 @@ def linear_attn_step(
         tails = jax.lax.dynamic_update_index_in_dim(
             tails, window[:, 1:], li, axis=0
         )
-    beta, g = _gates(ba, blk, cfg.linear_n_v_heads)
+    beta, g = _gates(ba, blk, cfg)
     with jax.named_scope("delta_step"):
         q, k, v = _split_heads(conv, cfg)  # [B, hv, d]
         if use_kernel:
@@ -461,6 +503,16 @@ def state_cache_stats(cfg: ModelConfig, cache, batch: int, s_max: int):
     }
 
 
+def _cache_stats(cfg: ModelConfig, cache, batch: int, s_max: int):
+    """`state_cache_stats` and the form the static program's decode step
+    runs the recurrence in (1: the Pallas kernel; a mesh keeps the
+    choice, so the engine's is the backend's)."""
+    return {
+        **state_cache_stats(cfg, cache, batch, s_max),
+        "gdn_step_on_kernel": int(step_kernel_form(cfg)[0]),
+    }
+
+
 def _grad_options(cfg: ModelConfig, row_kernel):
     """Where the gradient programs run the rule on its Pallas sweep
     (`chunk_kernel_form`), XLA:TPU's scheduler is held to half of the memory
@@ -482,6 +534,10 @@ def _train_stats(cfg: ModelConfig, n_layers: int, seg: jax.Array, row_kernel):
         "linear_attn/segments_per_row": jnp.mean(
             (seg[:, 0] > 0) + jnp.sum(starts, axis=-1)
         ).astype(jnp.float32),
+        # The chunked rule's form in this gradient program (1: the Pallas
+        # sweep `gdn_chunk`), a trace-time constant.
+        "linear_attn/rule_on_kernel": jnp.float32(
+            chunk_kernel_form(cfg, row_kernel)),
     }
 
 
@@ -505,7 +561,7 @@ BRANCH = Branch(
         "static_path_max_new)",
     ),
     matmul_params=_matmul_params,
-    cache_stats=state_cache_stats,
+    cache_stats=_cache_stats,
     train_stats=_train_stats,
     grad_options=_grad_options,
 )

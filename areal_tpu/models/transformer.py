@@ -1258,7 +1258,8 @@ def _packed_call(ctx: Ctx):
     return call
 
 
-# The norm in front of a layer's first and second branch.
+# The norm of a layer's first and second branch (in front of it, or over
+# its output: `cfg.branch_norm`).
 _BRANCH_NORMS = ("ln1", "ln2")
 
 
@@ -1266,10 +1267,12 @@ def _layer_of(
     cfg: ModelConfig, kind: LayerKind, call, gives, wrap=None, nth=None
 ):
     """ONE layer of `kind` as f(x, blk, carry, pi) -> (y, carry, what its
-    branches gave of the names `gives`): x += f(norm(x)) a branch, the
-    branch through `call(branch, h, blk, carry, li, at) -> (out, carry,
-    what it gives by name)` — the one loop over a layer's branches every
-    program runs.
+    branches gave of the names `gives`): x += f(norm(x)) a branch — or,
+    with `cfg.branch_norm` "output", x += norm(f(x)), the branch fed the
+    raw stream and the same `ln1` / `ln2` leaf over what it puts out
+    (scope `layer/post_norm`) — the branch through `call(branch, h, blk,
+    carry, li, at) -> (out, carry, what it gives by name)` — the one loop
+    over a layer's branches every program runs.
 
     `nth`: for a walk with a carry, the layer's index, a branch, among
     the prefix's layers with the branch or, in scan step `pi`, among the
@@ -1277,11 +1280,14 @@ def _layer_of(
     with the branch — behind the prefix's and the earlier steps' — and
     `at` (nth, pi); both None without.
     `wrap`: a gradient stack's remat policy (`_remat_layer`'s, bound):
-    the branches' outputs are the named checkpoints of `Branch.saved_as`,
+    the branches' outputs are the named checkpoints of `Branch.saved_as`
+    — what is ADDED to the stream, so with the norm on the output the
+    NORMED output: the backward of the add never recomputes the norm —
     every layer gives an `aux` (zero where no branch has one), and the
     layer goes under the policy whole or, with a `Branch.remat_alone`
     kind, a branch at a time."""
     plan = cfg.plan
+    post_norm = cfg.branch_norm == "output"
 
     def branch_step(branch, ln):
         def step(x, blk, carry, pi):
@@ -1292,8 +1298,13 @@ def _layer_of(
                     n, lead = plan.in_unit(branch), plan.in_prefix(branch)
                     li = pi if n == 1 else pi * n + li
                     li = li + lead if lead else li
-            h = _norm(x, blk[ln], blk.get(ln + "_b"), cfg)
-            out, carry, gave = call(branch, h, blk, carry, li, at)
+            if post_norm:
+                out, carry, gave = call(branch, x, blk, carry, li, at)
+                with jax.named_scope("layer/post_norm"):
+                    out = _norm(out, blk[ln], blk.get(ln + "_b"), cfg)
+            else:
+                h = _norm(x, blk[ln], blk.get(ln + "_b"), cfg)
+                out, carry, gave = call(branch, h, blk, carry, li, at)
             if wrap is not None:
                 out = checkpoint_name(out, BRANCHES[branch].saved_as)
             return _residual(x, out, cfg), carry, {

@@ -74,10 +74,26 @@ _NT = (((1,), (1,)), ((), ()))  # a @ b^T
 _TN = (((0,), (0,)), ((), ()))  # a^T @ b
 
 
+def _tiles(d: int) -> int:
+    """d rounded up to whole 128-lane tiles."""
+    return d + -d % LANES
+
+
 def fits(dk: int, dv: int) -> bool:
-    """Whether a head is whole 128-lane tiles both ways (a head is cut out
-    of its operand as a run of lanes)."""
-    return dk % LANES == 0 and dv % LANES == 0
+    """Whether the sweep takes a head: one of whole 128-lane tiles both ways
+    (a head is cut out of its operand as a run of lanes), or one that zero
+    columns make so (`gdn_chunk` appends them: 96 x 192 runs as 128 x 256)
+    at under twice the state's products — past that the `jnp` form's own
+    widths are the cheaper."""
+    return _tiles(dk) * _tiles(dv) < 2 * dk * dv
+
+
+def run_heads(hk: int, hv: int) -> int:
+    """Value heads the sweep runs: `hv`, or with as many key as value heads
+    the next whole groups of GROUP_H (30 run as 32: zero heads put out
+    zeros, and 30 side by side a trip would be one trip of 30 unrolled
+    chains)."""
+    return hv + -hv % GROUP_H if hk == hv else hv
 
 
 def group_for(hb: int, rep: int) -> int:
@@ -523,11 +539,32 @@ def _rule_bwd(form, save, interpret, res, do):
 _rule.defvjp(_rule_fwd, _rule_bwd)
 
 
+def _to_tiles(q, k, v, g, beta, heads: int):
+    """Zero columns appended to q, k and v up to whole 128-lane tiles and
+    zero heads (q, k, v, g, beta 0: they write and read nothing) up to
+    `heads`: no output of the rule changes, and a head's S [d_k, d_v] is
+    whole tiles with zeros past the head's own rows and columns."""
+    more = heads - v.shape[2]
+
+    def grown(x, width=None):
+        last = () if width is None else ((0, width - x.shape[-1]),)
+        return jnp.pad(x, ((0, 0), (0, 0), (0, more)) + last)
+
+    dk, dv = _tiles(q.shape[-1]), _tiles(v.shape[-1])
+    return grown(q, dk), grown(k, dk), grown(v, dv), grown(g), grown(beta)
+
+
 @functools.partial(
-    jax.jit, static_argnames=("hb", "group", "save", "operands", "interpret"))
-def _gdn_chunk(q, k, v, g, beta, segment_ids, *, hb, group, save, operands,
-               interpret):
-    b, s, hk, dk = q.shape
+    jax.jit,
+    static_argnames=("heads", "hb", "group", "save", "operands", "interpret"))
+def _gdn_chunk(q, k, v, g, beta, segment_ids, *, heads, hb, group, save,
+               operands, interpret):
+    b, s = q.shape[:2]
+    hv_out, dv_out = v.shape[-2:]
+    if (heads, _tiles(q.shape[-1]), _tiles(dv_out)) != (
+            hv_out, q.shape[-1], dv_out):
+        q, k, v, g, beta = _to_tiles(q, k, v, g, beta, heads)
+    hk, dk = q.shape[-2:]
     hv, dv = v.shape[-2:]
     pad = -s % CHUNK
     f32 = jnp.float32
@@ -547,7 +584,7 @@ def _gdn_chunk(q, k, v, g, beta, segment_ids, *, hb, group, save, operands,
         (hb, group, hk), save, interpret,
         q.reshape(b, sp, hk * dk), k.reshape(b, sp, hk * dk),
         v.reshape(b, sp, hv * dv), gc.reshape(b, sp, hv), beta, segment_ids)
-    return o.reshape(b, sp, hv, dv)[:, :s]
+    return o.reshape(b, sp, hv, dv)[:, :s, :hv_out, :dv_out]
 
 
 def gdn_chunk(
@@ -568,12 +605,14 @@ def gdn_chunk(
     with a gradient rule of its own.  One `jit` entry point: every layer of
     a program binds one traced function and its kernels are lowered once."""
     hk, hv = q.shape[2], v.shape[2]
-    hb = block_h or hv
+    heads = run_heads(hk, hv)
+    hb = block_h or heads
     group = group or group_for(hb, hv // hk)
-    assert hv % hb == 0 and hb % group == 0, (hv, hb, group)
+    assert heads % hb == 0 and hb % group == 0, (heads, hb, group)
     assert group % (hv // hk) == 0, (hv, hk, group)
     if interpret is None:
         interpret = _interpret()
     return _gdn_chunk(
-        q, k, v, g, beta, segment_ids, hb=hb, group=group, save=bool(save),
-        operands=jnp.dtype(operands), interpret=bool(interpret))
+        q, k, v, g, beta, segment_ids, heads=heads, hb=hb, group=group,
+        save=bool(save), operands=jnp.dtype(operands),
+        interpret=bool(interpret))

@@ -29,7 +29,9 @@ from benchmark.tests import test_ssmd as ssmd_cases
 from benchmark.tests.test_ssmd import *  # noqa: F401,F403 — the cases (PR 53)
 from benchmark.tests import test_ssm_slab as slab_cases
 from benchmark.tests.test_ssm_slab import *  # noqa: F401,F403 — the cases (PR 54)
+from benchmark.tests import test_sala as sala_cases
 from benchmark.tests.test_sala import *  # noqa: F401,F403 — the cases (PR 55)
+from benchmark.tests.test_gdnd import *  # noqa: F401,F403 — the cases (PR 59)
 
 SPEC = files.benchmark_json()
 CELLS = [w["name"] for w in SPEC["workloads"]]
@@ -41,6 +43,7 @@ LISTED_BY_PR55 = {"flash_fwd_share", "flash_bwd_share"}
 
 
 GLM_CELL = "glm47f-rollout64-1k"
+OLMOH_CELL = "olmoh-rollout64-512"  # PR 59's, the last of `workloads`
 # PR 38's entries, the last of `per_layer` but PR 39's one: the issue's
 # eight in its order, then the two twins the review asked for (`mfu_gen` and
 # `moe_train_mlp_mfu` over `benchmark/peaks_mla.py`, as the hybrid cell has).
@@ -492,8 +495,9 @@ def test_the_lfm2_cell_is_as_the_issue_parametrised_it():
     assert conf["source"] == (
         "https://huggingface.co/LiquidAI/LFM2-8B-A1B/blob/main/config.json")
     assert len(entry["why"]) <= 200 and len(conf["why"]) <= 200
-    # PR 55 appended the twelfth cell and the tenth configuration.
-    assert len(CELLS) == 12 and len(SPEC["configs"]) == 10
+    # PR 55 appended the twelfth cell and the tenth configuration, PR 59
+    # the thirteenth and the eleventh.
+    assert len(CELLS) == 13 and len(SPEC["configs"]) == 11
     assert [w["name"] for w in SPEC["workloads"] if w["chips"] == 4] == [
         "q7b-realloc-4chip"]
     assert (cell["route"], cell["timed_steps"], cell["traffic_seed"]) == (
@@ -520,8 +524,9 @@ def test_the_lfm2_cell_is_as_the_issue_parametrised_it():
     assert listed == {name for name, *_ in LFM2_ENTRIES} | SHARE_CELL_LISTS
     for m in SPEC["end_to_end"] + SPEC["per_layer"]:
         if LFM2_CELL in m.get("workloads", []):  # the last static cell's
-            static = [w for w in m["workloads"]  # ... before PR 55's
-                      if "serving" not in w and w != "sala-docrl8-longctx"]
+            static = [w for w in m["workloads"]  # ... before PR 55's, 59's
+                      if "serving" not in w
+                      and w not in ("sala-docrl8-longctx", OLMOH_CELL)]
             assert static[-1] == LFM2_CELL, m["name"]
     for name in listed:
         assert callable(files.load_module("metrics", name).read), name
@@ -567,7 +572,9 @@ def test_the_delta_rule_share_is_the_last_entry_and_the_hybrid_cells():
     assert SPEC["per_layer"][at] == {
         "name": "gdn_delta_rule_share", "unit": "%", "better": "lower",
         "source": "device_trace", "layer": "model step",
-        "moves": "train_tokens_per_s", "workloads": ["q3next-rollout64-512"],
+        "moves": "train_tokens_per_s",
+        # PR 59 appended the second cell with such layers.
+        "workloads": ["q3next-rollout64-512", OLMOH_CELL],
     }
     assert SPEC["per_layer"][at - 1]["name"] == SETUP_ENTRIES[-1][0]
     # PR 53's eight follow it (`benchmark/tests/test_ssmd.py`).
@@ -876,6 +883,38 @@ def test_cpu_rehearsal_of_the_olmoe_cell_is_correct():
     check = [l for l in lines if "weight check: " in l][-1]
     assert "'ok': True" in check and "'leaves': 15" in check, check
     assert any("olmoe reference" in l and "router_flips" in l for l in lines)
+
+
+def test_cpu_rehearsal_of_the_olmo_hybrid_cell_is_correct():
+    """The dense hybrid cell end to end at toy size (the config's `toy`
+    group keeps d_v = 2 d_k: heads of 12 x 24): the static program through
+    both populations of the cache, the chunked rule with beta in (0, 2) and
+    the norms on the branch outputs in the train step, the hand-back of all
+    22 leaves, the token-by-token reference with its state check for
+    generator and trainer."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=files.ROOT)
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run(
+        [sys.executable, "-m", "benchmark.run", "--workload", OLMOH_CELL,
+         "--seed", "3000000059", "--seconds", "1", "--trace", "0",
+         "--cpu-rehearsal"],
+        cwd=files.ROOT, env=env, capture_output=True, text=True, timeout=600,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert proc.stdout.strip() == ""  # platform=cpu: no result line
+    lines = proc.stderr.splitlines()
+    out = json.loads(
+        [l for l in lines if "would print: " in l][-1].split("would print: ")[1])
+    assert out["correct"] is True and out["failed"] == 0
+    assert out["attempted"] in (2 * 64, 3 * 64, 4 * 64)  # whole steps of 64
+    assert {"gen_tokens_per_s", "train_tokens_per_s", "samples_per_s",
+            "setup_s"} <= set(out["metrics"])
+    check = [l for l in lines if "weight check: " in l][-1]
+    assert "'ok': True" in check and "'leaves': 22" in check, check
+    assert any("olmo_hybrid reference" in l and "largest beta" in l
+               for l in lines)
+    assert any("olmo_hybrid state check" in l and l.endswith(" ok")
+               for l in lines)
 
 
 def test_cpu_rehearsal_of_the_qwen3_next_cell_is_correct():
@@ -1532,15 +1571,44 @@ def test_the_sconv_readers_say_nothing_without_their_scopes_or_counters():
         + 4 * 32 * 64 * 1 * 4096 ** 2 / 2)
 
 
+def _spec_before_pr59():
+    """BENCHMARK.json as it stood before PR 59 appended its configuration,
+    its cell and the cell's name to eleven `workloads` lists (it added no
+    per-layer entry: the list was full)."""
+    assert SPEC["workloads"][-1]["name"] == OLMOH_CELL
+    assert SPEC["workloads"][-2]["name"] == "sala-docrl8-longctx"
+
+    def without(m):
+        if OLMOH_CELL not in m.get("workloads", ()):
+            return m
+        return dict(m, workloads=[w for w in m["workloads"] if w != OLMOH_CELL])
+
+    return dict(
+        SPEC, workloads=SPEC["workloads"][:-1], configs=SPEC["configs"][:-1],
+        end_to_end=[without(m) for m in SPEC["end_to_end"]],
+        per_layer=[without(m) for m in SPEC["per_layer"]])
+
+
 def _spec_before(first_later_entry):
     """BENCHMARK.json as it stood before the per-layer entry called
     `first_later_entry` was appended, and before PR 55's configuration and
-    cell (the last of their lists)."""
-    at = _at(SPEC["per_layer"], first_later_entry)
-    assert SPEC["workloads"][-1]["name"] == "sala-docrl8-longctx"
+    cell (the last of their lists before PR 59's)."""
+    spec = _spec_before_pr59()
+    at = _at(spec["per_layer"], first_later_entry)
     return dict(
-        SPEC, per_layer=SPEC["per_layer"][:at],
-        workloads=SPEC["workloads"][:-1], configs=SPEC["configs"][:-1])
+        spec, per_layer=spec["per_layer"][:at],
+        workloads=spec["workloads"][:-1], configs=spec["configs"][:-1])
+
+
+def test_the_sala_entries_are_the_last_and_the_cell_lists_what_it_reports(  # noqa: F811
+        monkeypatch):
+    """PR 55's case pins ITS cell and configuration as the last and the
+    flash shares' lists as the eleven cells before it; PR 59 appended a
+    cell, a configuration and the cell's name to those lists.  So: PR 55's
+    case on the lists as they stood before — `benchmark/tests/` is not a
+    later PR's to edit."""
+    monkeypatch.setattr(files, "benchmark_json", _spec_before_pr59)
+    sala_cases.test_the_sala_entries_are_the_last_and_the_cell_lists_what_it_reports()
 
 
 def test_the_entries_are_the_last_and_the_cell_lists_what_it_reports(  # noqa: F811

@@ -86,13 +86,18 @@ CASES = {
     "two_value_heads_a_key_head": (_segments([(1, 70), (2, 58)]), 2, 4),
     "two_rows": (_segments([(1, 70), (2, 58), (0, 22)],
                            [(1, 64), (2, 86)]), 1, 2),
+    # (d_k, d_v) behind the heads: no whole 128-lane tiles, so the sweep
+    # runs them as 128 x 256 on zero columns, and three heads as four
+    "heads_of_96_by_192_run_as_whole_tiles":
+        (_segments([(1, 70), (2, 58), (0, 22)]), 3, 3, 96, 192),
 }
 
 
 @pytest.mark.parametrize("case", list(CASES), ids=str)
 def test_o_and_the_five_gradients_are_the_jnp_forms(case):
-    seg, hk, hv = CASES[case]
-    ops, weights = _operands(seg, hk, hv, seed=len(case))
+    seg, hk, hv, *widths = CASES[case]
+    dk, dv = widths or (D, D)
+    ops, weights = _operands(seg, hk, hv, seed=len(case), dk=dk, dv=dv)
     want = _o_and_grads(_oracle(seg, hv // hk), ops, weights)
     got = _o_and_grads(_kernel(seg), ops, weights)
     _assert_close(got, want)
@@ -143,6 +148,12 @@ def test_block_sizes_and_the_widths_the_kernel_takes():
     assert delta_chunk.group_for(6, 1) == 6
     assert delta_chunk.group_for(48, 12) == 48  # 12 value heads a key head
     assert delta_chunk.fits(128, 128) and delta_chunk.fits(128, 256)
+    # Zero columns make whole tiles of 96 x 192 at 1.78 times the products;
+    # 30 heads with their own keys run as 32, eight trips of four.
+    assert delta_chunk.fits(96, 192) and not delta_chunk.fits(64, 64)
+    assert delta_chunk.run_heads(30, 30) == 32
+    assert delta_chunk.run_heads(16, 32) == 32 == delta_chunk.run_heads(32, 32)
+    assert delta_chunk.run_heads(3, 6) == 6
     assert not delta_chunk.fits(16, 16) and not delta_chunk.fits(128, 64)
 
 

@@ -771,7 +771,14 @@ _PARENT_PROGRAMS = {
     ("dense", "gen"): "171e3c9a89af7ad0c9b290ecde922fc1ebbf80fca2313386d163c3ea85c1a4f5",
     ("olmoe", "grad"): "392f49aacb622abbf54641066381932faae6e7a4430ee90155f0b5d1d97bdd9b",
     ("olmoe", "gen"): "a1caf27c449fb3bde99b484d36122a5c82d25da70d62c4f5babaac87c7d16dae",
-    ("hybrid", "grad"): "f894b45f93ee466461a64df57c069b3b2a6e86a8ec4e6f16ebbd17805b05dd7d",
+    # PR 59 gives the hybrid's gradient program ONE more stat,
+    # `linear_attn/rule_on_kernel` (at PR 59's parent, 6c2020a, the text
+    # hashed f894b45f93ee4664...7805b05dd7d): regenerated after the loss,
+    # every gradient leaf and the other stats — 34 outputs — were
+    # `np.array_equal` between the two commits on a packed batch, the new
+    # stat 0 on a CPU backend (CHANGES.md).  Its `gen` text, and what
+    # `_gates` and `_layer_of` trace in every program here, are the parent's.
+    ("hybrid", "grad"): "65a1883734fb02e032cef0990d75f9d8ab1950bbbc12fe86d14cc3d6fb77f581",
     ("hybrid", "gen"): "8c20c9e547199be117390301a3db79f058811586d057bddd75deb7769f3fa91e",
     # The Nemotron-H toy (tests/test_nemotron_h.py `_cfg()`), from PR 43 on.
     # At PR 43's parent (750d69c) its programs hashed dd2e0ac17f0794d4...

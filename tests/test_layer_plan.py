@@ -38,12 +38,19 @@ BENCHMARK_CONFIGS = {
 }
 MADE_UP = ("pattern_E*M_x2", "hybrid_interval_2")
 NAMES = (*BENCHMARK_CONFIGS, *MADE_UP)
+# The plans the PROGRAMS are walked over against a hand-written loop: the
+# made-up units and one of them with every norm on its branch's output.
+POST_NORM = "hybrid_interval_2_post_norm"
+WALKED = (*MADE_UP, POST_NORM)
 
 
 def _cfg(name) -> ModelConfig:
     if name == "pattern_E*M_x2":  # an expert layer first, one '*' a unit
         return dataclasses.replace(
             _cfg("nemo3n"), n_layers=6, layer_pattern="E*M" * 2)
+    if name == POST_NORM:  # x + norm(f(x)) where every other plan norms x
+        return dataclasses.replace(
+            _cfg("hybrid_interval_2"), branch_norm="output")
     if name == "hybrid_interval_2":  # ONE Gated DeltaNet layer a period
         return dataclasses.replace(
             _cfg("q3next"), n_layers=4, full_attn_interval=2)
@@ -286,7 +293,9 @@ def test_the_stored_tree_is_the_parents(name):
 def _plain_forward(params, cfg, kinds, tokens, segment_ids):
     """The model as a plain Python loop over `kinds` (a layer's branches,
     spelled out by the test), each leaf indexed by how many EARLIER layers
-    have a branch that owns it — no plan, no view, no scan."""
+    have a branch that owns it — no plan, no view, no scan.  With
+    `cfg.branch_norm` "output" the loop is x + norm(f(x)), f fed x."""
+    post = cfg.branch_norm == "output"
     blocks = params["blocks"]
     owners = {
         "attention": tfm._FULL_ATTN_LEAVES,
@@ -301,7 +310,7 @@ def _plain_forward(params, cfg, kinds, tokens, segment_ids):
         for branch, ln in zip(kind, ("ln1", "ln2")):
             nth = sum(branch in k for k in kinds[:i])
             blk = {n: blocks[n][nth] for n in owners[branch] if n in blocks}
-            h = tfm._norm(x, blocks[ln][i], None, cfg)
+            h = x if post else tfm._norm(x, blocks[ln][i], None, cfg)
             if branch == "attention":
                 q, k, v = tfm._block_kv(h, blk, cfg, cos, sin)
                 a = packed_attention(q, k, v, segment_ids, causal=True)
@@ -314,17 +323,18 @@ def _plain_forward(params, cfg, kinds, tokens, segment_ids):
                 out = ssm_forward(h, blk, cfg, segment_ids)
             else:
                 out = tfm._mlp_moe(h, blk, cfg, valid=segment_ids > 0)[0]
-            x = x + out
+            x = x + (tfm._norm(out, blocks[ln][i], None, cfg) if post else out)
     return tfm._head(params, cfg, tfm._final_norm(params, cfg, x))
 
 
 _SPELLED_OUT = {
     "pattern_E*M_x2": [("moe",), ("attention",), ("ssm",)] * 2,
     "hybrid_interval_2": [("gdn", "moe"), ("attention", "moe")] * 2,
+    POST_NORM: [("gdn", "moe"), ("attention", "moe")] * 2,
 }
 
 
-@pytest.mark.parametrize("name", MADE_UP)
+@pytest.mark.parametrize("name", WALKED)
 def test_forward_is_the_layers_applied_one_by_one(name):
     cfg = _cfg(name)
     params = _params(cfg)
@@ -353,7 +363,7 @@ def test_forward_is_the_layers_applied_one_by_one(name):
     assert (0 < held).all() and (held <= real.sum() * cfg.n_experts_per_tok).all()
 
 
-@pytest.mark.parametrize("name", MADE_UP)
+@pytest.mark.parametrize("name", WALKED)
 def test_prefill_and_four_decode_steps_are_forwards_logits(name):
     cfg = _cfg(name)
     params = _params(cfg)
@@ -414,6 +424,11 @@ _PARENT_FLOPS = {
     "lfm2-8b-a1b-e8": (203712, 7422345216.0, 893678592.0),
     "granite-4.0-h-micro-l10": (234496, 8178892800.0, 994650112.0),
     "minicpm-sala-l4-v8": (215040, 6190792704.0, 814128384.0),
+    # PR 59's configuration has no count at that parent: the records' sums
+    # as PR 59 first printed them (222,720 matmul parameters as
+    # `benchmark/peaks_gdnd.py` counts the toy + 3 layers x 4 heads x 3 x 12
+    # x 24 of the recurrence).
+    "olmo-hybrid-7b-l4-v8": (233088, 8144289792.0, 990031872.0),
 }
 
 

@@ -662,26 +662,14 @@ def test_the_decode_loop_compiles_for_v5e_with_the_state_stepped_in_place(
 _GRAD_TEMP_BYTES_ON_THE_JNP_FORM = 8_367_263_744
 
 
-def test_the_gradient_program_compiles_for_v5e_with_the_rule_on_its_kernels(
-        v5e_chips, monkeypatch):
-    """Mosaic and XLA:TPU for real, at the cell's micro-batch (one packed
-    row of 8,192 tokens, the published widths, `remat="full"` as the train
-    engine has it): each of the three Gated DeltaNet layers runs its
-    chunked delta rule on the Pallas sweep — `gdn_chunk_fwd` in the forward
-    and in the recomputed forward, `gdn_chunk_bwd` in the backward, all
-    under `layer/linear_attn/delta_rule` — with no `while` and no
-    `InvertDiagBlocksLowerTriangular` left under that scope (the `jnp`
-    form's two loops of 128 trips and its solve), and the program's
-    temporaries are not above the `jnp` form's.  Prefill keeps the `jnp`
-    form: its lowered text holds no kernel of the rule."""
+def _grad_on_the_sweep(big, v5e_chips):
+    """Compile `big`'s gradient program at the cells' micro-batch for a
+    described v5e and hold it to the rule on its Pallas sweep in every
+    Gated DeltaNet layer -> (the chip's sharding, the params' shapes on it,
+    the program's temporary bytes)."""
     from jax.experimental.compilation_cache import compilation_cache
     from jax.sharding import SingleDeviceSharding
 
-    from areal_tpu.ops.pallas import delta_chunk
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    big = bench_run.model_config(
-        files.load_json("configs", "qwen3-next-80b-a3b-l4-e64.json"))
     chip = SingleDeviceSharding(v5e_chips[0])
     shapes = jax.eval_shape(
         lambda: tfm.init_params(big, jax.random.PRNGKey(0)))
@@ -718,7 +706,46 @@ def test_the_gradient_program_compiles_for_v5e_with_the_rule_on_its_kernels(
         ("gdn_chunk_bwd", "bwd"): n}, kernels
     assert not [line[:120] for line in under if " while(" in line]
     assert "InvertDiagBlocksLowerTriangular" not in compiled.as_text()
-    temp = compiled.memory_analysis().temp_size_in_bytes
+    return chip, params, compiled.memory_analysis().temp_size_in_bytes
+
+
+def test_heads_of_96_by_192_compile_for_v5e_on_the_sweep_as_whole_tiles(
+        v5e_chips, monkeypatch):
+    """`olmoh-rollout64-512`'s gradient program (PR 59): 30 heads of 96 x
+    192 are no whole 128-lane tiles, so `gdn_chunk` runs them as 32 of 128
+    x 256 on zero columns — Mosaic takes the blocks (a grid step's v, o and
+    carried S at twice q3next's width) and the program's temporaries are
+    well under what the `jnp` form asked the chip for and was REFUSED (9.18
+    GB to reserve beside 8.08 in use, my chip run, PR 59; this compile read
+    9.51 GB for that form and 5.11 for this: 4.16 before the projection's
+    output was held behind a barrier for the conv,
+    `linear_attention._conv_reads_made_input`)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    big = bench_run.model_config(
+        files.load_json("configs", "olmo-hybrid-7b-l4-v8.json"))
+    assert la.chunk_kernel_form(big) and not la.step_kernel_form(big)[0]
+    _, _, temp = _grad_on_the_sweep(big, v5e_chips)
+    assert temp <= 5_400_000_000, temp
+
+
+def test_the_gradient_program_compiles_for_v5e_with_the_rule_on_its_kernels(
+        v5e_chips, monkeypatch):
+    """Mosaic and XLA:TPU for real, at the cell's micro-batch (one packed
+    row of 8,192 tokens, the published widths, `remat="full"` as the train
+    engine has it): each of the three Gated DeltaNet layers runs its
+    chunked delta rule on the Pallas sweep — `gdn_chunk_fwd` in the forward
+    and in the recomputed forward, `gdn_chunk_bwd` in the backward, all
+    under `layer/linear_attn/delta_rule` — with no `while` and no
+    `InvertDiagBlocksLowerTriangular` left under that scope (the `jnp`
+    form's two loops of 128 trips and its solve), and the program's
+    temporaries are not above the `jnp` form's.  Prefill keeps the `jnp`
+    form: its lowered text holds no kernel of the rule."""
+    from areal_tpu.ops.pallas import delta_chunk
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    big = bench_run.model_config(
+        files.load_json("configs", "qwen3-next-80b-a3b-l4-e64.json"))
+    chip, params, temp = _grad_on_the_sweep(big, v5e_chips)
     assert temp <= _GRAD_TEMP_BYTES_ON_THE_JNP_FORM, temp
 
     # Prefill (64 rows, a 256-slot prompt window): `with_state`, so the
