@@ -29,8 +29,10 @@ Two forms of the same recurrence:
   conv's last K-1 inputs.  The recurrence itself has one form per backend:
   on a TPU the Pallas kernel `gdn_delta_step` (`ops/pallas/delta_step.py`),
   which keeps a head's [d_k, d_v] tile in VMEM for S^T k, S^T q and the
-  update and so reads the state once; elsewhere (CPU, toy head widths) the
-  same fp32 arithmetic as `jnp` ops, which is also the kernel's oracle.
+  update and so reads the state once — at 128 x 128 and at 96 x 192 alike
+  (`delta_step.fits`: rows in whole sublane tiles, columns in whole or
+  half lane tiles); elsewhere (CPU, toy head widths) the same fp32
+  arithmetic as `jnp` ops, which is also the kernel's oracle.
   The code picks by what it can see (`row_kernel_form`); there is no
   switch.
 
@@ -315,10 +317,9 @@ def _conv_reads_made_input(cfg: ModelConfig) -> bool:
     (8,192 channels) the programs are right without it (my chip runs, PR
     59, PERF.md sections 6 and 7; rows under 1,536 never showed it)."""
     from areal_tpu.base.distributed import is_tpu_backend
-    from areal_tpu.ops.pallas import delta_step
 
-    return is_tpu_backend() and not delta_step.fits(
-        cfg.linear_k_head_dim, cfg.linear_v_head_dim)
+    return is_tpu_backend() and bool(
+        cfg.linear_k_head_dim % 128 or cfg.linear_v_head_dim % 128)
 
 
 @jax.named_scope("layer/linear_attn")
@@ -380,8 +381,9 @@ def step_kernel_form(cfg: ModelConfig, kernel=None):
     """Which form `linear_attn_step` runs the recurrence in -> (on the
     Pallas kernel `gdn_delta_step`, the mesh to `shard_map` it over or
     None): by what the code can see (`flash_attention.row_kernel_form`: a
-    TPU backend, head widths of whole 128-lane tiles; a bool forces
-    either)."""
+    TPU backend, a head's tile one the kernel takes — `delta_step.fits`,
+    128 x 128 and 96 x 192 among them, the toys' 12 x 24 not; a bool
+    forces either)."""
     from areal_tpu.ops.pallas import delta_step
     from areal_tpu.ops.pallas.flash_attention import row_kernel_form
 
@@ -405,15 +407,18 @@ def linear_attn_step(
     made by the caller would be timed outside `layer/linear_attn` — and
     because that is what in place means on either backend:
 
-    - on a TPU backend (head widths whole 128-lane tiles) the Pallas kernel
-      `gdn_delta_step` takes the stack, reads layer `li`'s tiles where they
-      lie, ONCE, and writes them where they lie (the output aliases the
-      input, the layer is a prefetched scalar in both index maps): no
-      slice, no `dynamic-update-slice`, no other layer's byte touched;
+    - on a TPU backend (a head's tile one `delta_step.fits` takes) the
+      Pallas kernel `gdn_delta_step` takes the stack, reads layer `li`'s
+      tiles where they lie, ONCE, and writes them where they lie (the
+      output aliases the input, the layer is a prefetched scalar in both
+      index maps): no slice, no `dynamic-update-slice`, no other layer's
+      byte touched.  The stack keeps its shape [n_linear, B, hv, dk, dv]
+      at every width: a d_v of 192 lies in HBM as 256 lanes, and the kernel
+      moves those bytes once each way (PERF.md §6, PR 60);
     - elsewhere `delta_step_jnp`, the kernel's oracle: XLA fuses the
       update into the `dynamic-update-slice`, but the reduction that `d`
       needs is a fusion of its own, so on a TPU it streamed the state from
-      HBM twice (PERF.md §6, PR 42)."""
+      HBM twice (PERF.md §6, PRs 42 and 59)."""
     from areal_tpu.ops.pallas import delta_step
 
     use_kernel, mesh = step_kernel_form(cfg, kernel)

@@ -19,13 +19,31 @@ scalar in the index maps of the input and of the output block, so layer
 `li`'s tiles are read where they lie and written where they lie and no
 other layer's bytes are touched — no slice, no `dynamic-update-slice`.
 
-Grid (B, blocks of value heads).  k and q arrive as rows ([heads, 128],
-d_k on lanes) because a `[d_k, 1]` column pads to 128 lanes in HBM and
-would cost the state's bytes again; the kernel turns them into columns
-itself (an XLU tile transpose a head and operand).  The call is bound by
-its DMA, not by these: 0.41 ms a layer at 64 rows x 32 heads in the decode
+Grid (B, blocks of value heads).  A block is `block_h_for` heads' own
+[d_k, d_v] tiles — the array's last two dimensions, so any d_k of whole
+8-sublane tiles and any d_v of whole or half 128-lane tiles is a legal
+block (`fits`): 128 x 128 (qwen3_next) and 96 x 192 (olmo_hybrid, PR 60)
+run this one body.  A d_v of 192 lies in HBM as 256 lanes (`T(8,128)`: a
+program whose one argument is `f32[3,64,30,96,192]` counts 566 MB where
+the shapes count 425), so such a head moves 4/3 of its bytes — once each
+way, where the `jnp` form moved them three times (PERF.md section 6,
+PR 60); storing two heads side by side on the lanes would save the third,
+but the benchmark's reference reads `cache.state` as [.., h_v, d_k, d_v].
+
+k and q arrive as rows ([heads, lanes], d_k on whole lane tiles, zeros
+behind a d_k of 96: in HBM the row is that wide anyway) because a
+`[d_k, 1]` column pads to 128 lanes in HBM and would cost the state's
+bytes again; the kernel turns a row into a column itself — the row on 128
+sublanes, ONE XLU tile transpose a head and operand, cut to d_k rows —
+and, every lane of a column being the same number, that one turned lane
+tile serves every lane tile of the head: the body walks d_v a lane tile at
+a time (128 + 64 lanes of 192).  The call is bound by its DMA, not by
+these: 0.41 ms a layer at 64 rows x 32 heads of 128 x 128 in the decode
 loop (653 GB/s; 8, 16 or 32 heads a grid step, the columns made once a
-head block or once a head, all within 1%: chip runs, PR 42).  Every
+head block or once a head, all within 1%: chip runs, PR 42) and 0.575 ms
+at 30 heads of 96 x 192 (657 GB/s over the padded bytes; 8 or 16 heads a
+grid step within 0.3%, 24 heads 7% slower: chip runs, PR 60; `block_h_for`
+takes 8).  Every
 product and sum is an fp32 VPU operation — the mathematics and the
 precision of `models/linear_attention.delta_step_jnp`, which stays the
 path off a TPU backend and this kernel's oracle; only the order of the
@@ -38,25 +56,63 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from areal_tpu.ops.pallas.flash_attention import _interpret, named_call
 
 LANES = 128
-MAX_BLOCK_H = 16  # heads a grid step: 1 MB of tiles in, 1 MB out, twice
+SUBLANES = 8
+BLOCK_BYTES = 1 << 20  # a grid step's tiles: as much in, as much out, twice
 
 
 def fits(dk: int, dv: int) -> bool:
-    """Whether a head's tile is whole 128-lane tiles both ways (what the
-    kernel's blocks and its transposes need)."""
-    return dk % LANES == 0 and dv % LANES == 0
+    """Whether the kernel takes a head's [d_k, d_v] tile: rows that are
+    whole 8-sublane tiles (k's column is cut to them) and columns that are
+    whole or half 128-lane tiles (the widths compiled for the chip and
+    measured: 128, 192, 256)."""
+    return dk % SUBLANES == 0 and dv % (LANES // 2) == 0
 
 
-def block_h_for(hv: int) -> int:
-    """Heads a grid step: whole 8-sublane tiles of the [heads, 128]
-    operands, at most MAX_BLOCK_H; all of them where they are fewer."""
-    return min(hv, MAX_BLOCK_H)
+def block_h_for(hv: int, dk: int, dv: int) -> int:
+    """Heads a grid step: what makes about BLOCK_BYTES of tiles as they
+    lie (d_v on whole lane tiles) — 16 heads of 128 x 128 at 64 KB, 8 of
+    96 x 192 at 96 KB — in whole 8-sublane tiles of the [heads, lanes]
+    operands; all of them where they are fewer.  The call is bound by its
+    DMA at 8, 16 or 32 heads alike (module docstring); the body unrolls the
+    heads, so fewer of them trace and lower sooner."""
+    head = dk * (dv + -dv % LANES) * 4
+    return min(hv, max(SUBLANES, BLOCK_BYTES // head // SUBLANES * SUBLANES))
+
+
+# The body is written in `lax` primitives, not `jnp` operators: a `jnp`
+# operator on a traced value is a `jit` call of its own, the body unrolls
+# some twenty of them a head and lane tile, and a program that holds the
+# kernel paid 2.7 s of tracing for them in the benchmark's process (PERF.md
+# section 6, PR 60).  The primitives bound are the same ones.
+
+
+def _row(x, h: int, lo: int = 0, hi: int = 0):
+    """Head `h` of a [heads, lanes] value, lanes lo..hi (0: to the end):
+    a [1, w] row."""
+    return lax.slice(x, (h, lo), (h + 1, hi or x.shape[1]))
+
+
+def _colsum(x):
+    """A tile summed over its rows, as a [1, w] row."""
+    return lax.broadcast_in_dim(
+        lax.reduce_sum(x, (0,)), (1, x.shape[1]), (1,))
+
+
+def _column(row, dk: int):
+    """A head's k or q, one [1, lanes] row, as a column on every lane of
+    ONE lane tile [dk, 128]: the row on every sublane, turned (an XLU tile
+    transpose, hidden behind the DMA), and cut to the tile's rows where the
+    row came padded to whole lanes."""
+    col = lax.transpose(
+        lax.broadcast_in_dim(row, (LANES, row.shape[1]), (0, 1)), (1, 0))
+    return col if col.shape[0] == dk else lax.slice(col, (0, 0), (dk, LANES))
 
 
 def _delta_step_kernel(
@@ -66,25 +122,33 @@ def _delta_step_kernel(
     *, hb: int,
 ):
     del layer_ref
+    mul, add, sub = lax.mul, lax.add, lax.sub
     dk, dv = s_ref.shape[-2:]
-    k = k_ref[0]  # [hb, dk]
+    k = k_ref[0]  # [hb, d_k in whole lane tiles]
     q = q_ref[0]
     v = v_ref[0]  # [hb, dv]
     eg = eg_ref[0]  # [hb, dv], a head's e^g on every lane
     beta = beta_ref[0]  # [hb, 1]
-    kq = jnp.sum(k * q, axis=-1, keepdims=True)  # [hb, 1]
+    kq = lax.broadcast_in_dim(  # [hb, 1]
+        lax.reduce_sum(mul(k, q), (1,)), (hb, 1), (0,))
     for h in range(hb):
-        s = s_ref[h]  # [dk, dv]
-        # k and q as columns over the tile's lanes: the row on every
-        # sublane, turned (an XLU tile transpose, hidden behind the DMA).
-        kc = jnp.broadcast_to(k[h: h + 1], (dv, dk)).T  # [dk, dv]
-        qc = jnp.broadcast_to(q[h: h + 1], (dv, dk)).T
-        sk = jnp.sum(s * kc, axis=0, keepdims=True)  # [1, dv]
-        sq = jnp.sum(s * qc, axis=0, keepdims=True)
-        e = eg[h: h + 1]  # [1, dv]
-        d = beta[h: h + 1] * (v[h: h + 1] - e * sk)
-        o_ref[0, h: h + 1, :] = e * sq + kq[h: h + 1] * d
-        s_out_ref[h] = s * e + kc * d
+        kt = _column(_row(k, h), dk)  # [dk, 128]
+        qt = _column(_row(q, h), dk)
+        # The tile a lane tile at a time: every lane of a column is the
+        # same, so one turned tile serves them all, the last cut to what
+        # is left of d_v (64 lanes of 192).
+        for lo in range(0, dv, LANES):
+            hi = min(lo + LANES, dv)
+            whole = hi - lo == LANES
+            kc = kt if whole else lax.slice(kt, (0, 0), (dk, hi - lo))
+            qc = qt if whole else lax.slice(qt, (0, 0), (dk, hi - lo))
+            s = s_ref[h, :, lo:hi]  # [dk, w]
+            sk = _colsum(mul(s, kc))  # [1, w]
+            sq = _colsum(mul(s, qc))
+            e = _row(eg, h, lo, hi)
+            d = mul(_row(beta, h), sub(_row(v, h, lo, hi), mul(e, sk)))
+            o_ref[0, h: h + 1, lo:hi] = add(mul(e, sq), mul(_row(kq, h), d))
+            s_out_ref[h, :, lo:hi] = add(mul(s, e), mul(kc, d))
 
 
 @functools.partial(jax.jit, static_argnames=("block_h",))
@@ -102,7 +166,7 @@ def gdn_delta_step(
     `block_h`: heads a grid step (0: `block_h_for`); one that does not
     divide hv leaves a last block whose tail is read and never written."""
     _, b, hv, dk, dv = states.shape
-    hb = block_h or block_h_for(hv)
+    hb = block_h or block_h_for(hv, dk, dv)
     assert hb % 8 == 0 or hb == hv, (hb, hv)
     f32 = jnp.float32
 
@@ -119,6 +183,12 @@ def gdn_delta_step(
     eg = jnp.broadcast_to(jnp.exp(g.astype(f32))[..., None], (b, hv, dv))
     beta = beta.astype(f32)[..., None]
     q, k, v = q.astype(f32), k.astype(f32), v.astype(f32)
+    if dk % LANES:
+        # k and q as rows of whole lane tiles (zeros behind d_k = 96: in
+        # HBM the row is that wide already), so that the kernel can turn
+        # them.
+        to_lanes = ((0, 0), (0, 0), (0, -dk % LANES))
+        q, k = jnp.pad(q, to_lanes), jnp.pad(k, to_lanes)
     state_spec = pl.BlockSpec((None, None, hb, dk, dv), tiles)
     return named_call(
         "gdn_delta_step",

@@ -367,10 +367,12 @@ def test_the_state_the_decode_program_leaves_is_the_references(
 # ----------------------------------------------- the forms, as the program says
 
 
-def test_the_programs_say_which_form_of_the_rule_they_run(cfg):
+def test_the_programs_say_which_form_of_the_rule_they_run(cfg, monkeypatch):
     """The two numbers `gdn_kernel_forms` adds up: off a TPU both forms
-    are the `jnp` ones; on one the published 96 x 192 head is no whole
-    128-lane tile either way, where q3next's 128 x 128 is."""
+    are the `jnp` ones; on one the published 96 x 192 head is stepped by
+    `gdn_delta_step` as q3next's 128 x 128 is (rows in whole sublane
+    tiles, a lane tile and a half of columns: PR 60) and swept by
+    `gdn_chunk` on zero columns, and the toys' 12 x 24 by neither."""
     seg = jnp.ones((2, 64), jnp.int32)
     stats = la.BRANCH.train_stats(cfg, 3, seg, None)
     assert float(stats["linear_attn/rule_on_kernel"]) == 0.0
@@ -381,8 +383,19 @@ def test_the_programs_say_which_form_of_the_rule_they_run(cfg):
     assert pool["kv_cache_bytes"] == 2 * 2 * 64 * 4 * 16 * 4
     from areal_tpu.ops.pallas import delta_chunk, delta_step
 
-    assert delta_chunk.fits(96, 192) and not delta_step.fits(96, 192)
+    assert delta_chunk.fits(96, 192) and delta_step.fits(96, 192)
     assert delta_chunk.fits(128, 128) and delta_step.fits(128, 128)
+    assert not delta_step.fits(cfg.linear_k_head_dim, cfg.linear_v_head_dim)
+    # On a TPU backend the published widths' pool says the kernel, the
+    # toy's still the `jnp` form: the shapes decide, nothing else.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert la.BRANCH.cache_stats(cfg, cache, 2, 64)["gdn_step_on_kernel"] == 0
+    big = bench_run.model_config(
+        files.load_json("configs", "olmo-hybrid-7b-l4-v8.json"))
+    assert (big.linear_k_head_dim, big.linear_v_head_dim) == (96, 192)
+    assert la.step_kernel_form(big) == (True, None)
+    cache = jax.eval_shape(lambda: tfm.init_kv_cache(big, 2, 64))
+    assert la.BRANCH.cache_stats(big, cache, 2, 64)["gdn_step_on_kernel"] == 1
 
 
 def test_refusals_keep_their_name(cfg):

@@ -570,27 +570,39 @@ def v5e_chips():
     return topo.devices
 
 
-@pytest.mark.parametrize("mode", ["d1", "d2", "f2"])
+_Q3NEXT = ("qwen3-next-80b-a3b-l4-e64.json", (3, 32, 128, 128))
+# Heads that are no whole 128-lane tiles (PR 60): the same kernel, a block a
+# head's own [96, 192], the stack in the shape it always had.
+_OLMOH = ("olmo-hybrid-7b-l4-v8.json", (3, 30, 96, 192))
+
+
+@pytest.mark.parametrize("config,heads,mode", [
+    pytest.param(*_Q3NEXT, "d1", id="d1"),
+    pytest.param(*_Q3NEXT, "d2", id="d2"),
+    pytest.param(*_Q3NEXT, "f2", id="f2"),
+    pytest.param(*_OLMOH, "d1", id="96x192-d1"),
+    pytest.param(*_OLMOH, "d2", id="96x192-d2"),
+])
 def test_the_decode_loop_compiles_for_v5e_with_the_state_stepped_in_place(
-        v5e_chips, monkeypatch, mode):
-    """Mosaic and XLA:TPU for real, at the cell's size (64 rows, a
-    768-slot window, one period, the published widths), on one chip and
-    with the rows spread over two (`shard_map`, data or fsdp): each of the
-    three Gated DeltaNet layers steps its tiles of the stacked fp32 state
-    through the Pallas kernel `gdn_delta_step` under `layer/linear_attn/
-    delta_step` — the stack is the kernel's operand AND its result, so the
-    loop holds no `dynamic-update-slice` on the state and no copy, re-layout
-    or gather of the stack, of a device's part of it or of a layer's part
-    (an alias that did not take would show as a copy of 402 MB an
-    iteration)."""
+        v5e_chips, monkeypatch, config, heads, mode):
+    """Mosaic and XLA:TPU for real, at the cells' size (64 rows, a
+    768-slot window, one period, the published widths of `q3next-` and
+    `olmoh-rollout64-512`), on one chip and with the rows spread over two
+    (`shard_map`, data or fsdp): each of the three Gated DeltaNet layers
+    steps its tiles of the stacked fp32 state through the Pallas kernel
+    `gdn_delta_step` under `layer/linear_attn/delta_step` — the stack is
+    the kernel's operand AND its result, so the loop holds no
+    `dynamic-update-slice` on the state and no copy, re-layout or gather of
+    the stack, of a device's part of it or of a layer's part (an alias that
+    did not take would show as a copy of 402 MB an iteration, 566 at 96 x
+    192, whose 192 columns lie in HBM as 256 lanes)."""
     from jax.experimental.compilation_cache import compilation_cache
     from jax.sharding import PartitionSpec as P
 
     from areal_tpu.base.topology import BATCH_AXES
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    big = bench_run.model_config(
-        files.load_json("configs", "qwen3-next-80b-a3b-l4-e64.json"))
+    big = bench_run.model_config(files.load_json("configs", config))
     b, sp, st = 64, 256, 768
     pc = ParallelConfig.from_str(mode)
     mesh = make_mesh(pc, v5e_chips[: pc.world_size])
@@ -604,8 +616,11 @@ def test_the_decode_loop_compiles_for_v5e_with_the_state_stepped_in_place(
     params = jax.tree.map(placed, shapes, sharding.param_pspecs(shapes))
     rows = placed(jax.ShapeDtypeStruct((b,), jnp.int32), P(BATCH_AXES))
     # What the engine hands the decode step (`_row_kernel`), and whether
-    # the expert leaves can be read in place (not where fsdp splits them).
+    # the expert leaves can be read in place (not where fsdp splits them,
+    # and not in a model that has none).
     row_kernel = None if pc.world_size == 1 else mesh
+    in_place = tfm.expert_leaves_in_place(big, params["blocks"])
+    assert in_place == (big.is_moe and mode != "f2")
 
     def loop(params, tok, plen):
         cache = tfm.init_kv_cache(big, b, st, dtype=jnp.bfloat16)
@@ -614,7 +629,7 @@ def test_the_decode_loop_compiles_for_v5e_with_the_state_stepped_in_place(
             step, tok, cache = state
             logits, cache = tfm.decode_step(
                 params, big, tok, plen + step, cache, sp + step, sp - plen,
-                experts_in_place=mode != "f2", row_kernel=row_kernel)
+                experts_in_place=in_place, row_kernel=row_kernel)
             return step + 1, jnp.argmax(logits, -1).astype(jnp.int32), cache
 
         return jax.lax.while_loop(
@@ -629,7 +644,7 @@ def test_the_decode_loop_compiles_for_v5e_with_the_state_stepped_in_place(
         compilation_cache.reset_cache()
     n, hv = big.n_linear_layers, big.linear_n_v_heads
     dk, dv = big.linear_k_head_dim, big.linear_v_head_dim
-    assert (n, hv, dk, dv) == (3, 32, 128, 128)
+    assert (n, hv, dk, dv) == heads
     here = b // pc.world_size  # a device's rows
     stack = f"f32[{n},{here},{hv},{dk},{dv}]"
     shapes = {stack} | {
@@ -723,7 +738,7 @@ def test_heads_of_96_by_192_compile_for_v5e_on_the_sweep_as_whole_tiles(
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     big = bench_run.model_config(
         files.load_json("configs", "olmo-hybrid-7b-l4-v8.json"))
-    assert la.chunk_kernel_form(big) and not la.step_kernel_form(big)[0]
+    assert la.chunk_kernel_form(big)
     _, _, temp = _grad_on_the_sweep(big, v5e_chips)
     assert temp <= 5_400_000_000, temp
 
