@@ -508,6 +508,14 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         self._m_tokens = reg.counter(
             "areal_gen_tokens_total", "response tokens generated"
         )
+        self._m_prefill_rows = reg.counter(
+            "areal_gen_prefill_rows_total",
+            "rows the static program prefilled (a shared prompt once)",
+        )
+        self._m_prefill_rows_requested = reg.counter(
+            "areal_gen_prefill_rows_requested_total",
+            "rows the static program was asked to prefill",
+        )
         self._m_goodput = reg.gauge(
             "areal_gen_goodput_tokens_per_second",
             "tokens/s over the last completed generate call",
@@ -2378,8 +2386,11 @@ class GeneratorEngine(HostOffloadMixin, Engine):
     # -- one fixed-shape chunk --
 
     def _generate_chunk(self, chunk, gconfig, key, results) -> None:
+        # A row's source: the chunk row that first carries its prompt.
+        first: Dict[int, int] = {}
+        src = [first.setdefault(i, r) for r, (i, _, _) in enumerate(chunk)]
         toks, logps, gen_len = self.static_rollout(
-            [t for (_, _, t) in chunk], gconfig, key
+            [t for (_, _, t) in chunk], gconfig, key, src=src
         )
         for r, (i, rep, _) in enumerate(chunk):
             gl = int(gen_len[r])
@@ -2388,11 +2399,19 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             )
             results[(i, rep)] = (toks[r, :gl], logps[r, :gl], no_eos)
 
-    def static_rollout(self, prompts, gconfig, key, with_cache=False):
+    def static_rollout(
+        self, prompts, gconfig, key, with_cache=False, src=None
+    ):
         """One call of the static decode program over `prompts` (token
         arrays, at most one batch of them) -> host arrays (tokens [b,
         max_new], their log-probs, generated lengths [b]); rows past
         `len(prompts)` pad the batch to the mesh's batch sharding.
+
+        `src`: for each prompt the first row that carries the SAME prompt
+        (`src[r] <= r`, `src[src[r]] == src[r]`; None: every row its own).
+        Where the prefill goes in waves a repeated prompt is prefilled once
+        and lands at every row of its group (`_shared_rows`); the rows
+        still sample apart.
 
         `with_cache`: also the `KVCache` the program leaves, on the device
         (one more output of the same program, for a check that holds the
@@ -2415,7 +2434,22 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             prompt_len[r] = len(toks)
 
         cfg = self.cfg
-        fn = self._get_gen_fn(b, sp, s_total, gconfig, with_cache)
+        if src is not None and not all(
+            s <= r and np.array_equal(prompts[s], prompts[r])
+            for r, s in enumerate(src)
+        ):
+            raise ValueError(
+                f"src {list(src)} names a row that does not come first or "
+                f"does not carry the same prompt")
+        src = self._shared_rows(b, sp, src)
+        fn = self._get_gen_fn(b, sp, s_total, gconfig, with_cache, src)
+        prefilled = b_real if src is None else len(set(src[:b_real]))
+        stats = self.last_pool_stats
+        stats["prefill_rows"] = stats.get("prefill_rows", 0) + prefilled
+        stats["prefill_rows_requested"] = (
+            stats.get("prefill_rows_requested", 0) + b_real)
+        self._m_prefill_rows.inc(prefilled)
+        self._m_prefill_rows_requested.inc(b_real)
         # What the cache holds beside k/v, from shapes alone.
         cache = jax.eval_shape(
             lambda: tfm.init_kv_cache(cfg, b, s_total, dtype=self.compute_dtype)
@@ -2424,7 +2458,10 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             if branch.cache_stats:
                 self.last_pool_stats.update(
                     branch.cache_stats(cfg, cache, b, s_total))
-        with tracer.span("gen_chunk", cat="compute", b=b_real, sp=sp):
+        with tracer.span(
+            "gen_chunk", cat="compute", b=b_real, sp=sp,
+            prefill_rows=prefilled, prefill_rows_requested=b_real,
+        ):
             with tracer.span("gen_dispatch", cat="compute"):
                 toks, logps, gen_len, sums, *cache = fn(
                     self.params, prompt_tok, prompt_len, key
@@ -2444,12 +2481,15 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         return toks, logps, gen_len
 
     def _get_gen_fn(
-        self, b, sp, s_total, g: GenerationHyperparameters, with_cache=False
+        self, b, sp, s_total, g: GenerationHyperparameters, with_cache=False,
+        src: Optional[Tuple[int, ...]] = None,
     ):
+        """The static program of one shape.  `src`: what `_shared_rows`
+        gave — None, every row prefilled, or each row's source row."""
         in_place = self._expert_leaves_in_place
         sig = (
             b, sp, s_total, g.max_new_tokens, g.min_new_tokens, g.greedy,
-            g.top_p, g.top_k, g.temperature, in_place, with_cache,
+            g.top_p, g.top_k, g.temperature, in_place, with_cache, src,
         )
         if sig in self._gen_fns:
             return self._gen_fns[sig]
@@ -2476,9 +2516,13 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                     params, cfg, prompt_tok, seg, cache,
                     use_flash=self._use_flash,
                 )
-            else:
+            elif src is None:
                 logits0, cache = self._prefill_in_waves(
                     params, prompt_tok, seg, cache, wave
+                )
+            else:
+                logits0, cache = self._prefill_distinct(
+                    params, prompt_tok, seg, cache, src
                 )
 
             out_toks = jnp.zeros((bsz, max_new), jnp.int32)
@@ -2566,6 +2610,80 @@ class GeneratorEngine(HostOffloadMixin, Engine):
              and r * sp <= PREFILL_WAVE_TOKENS),
             default=1,
         )
+
+    def _shared_rows(self, b: int, sp: int, src) -> Optional[Tuple[int, ...]]:
+        """Each of the `b` rows' source row where the program shares a
+        prefill, else None: only the wave path shares, and only where a
+        row repeats, so every other batch keeps the program it had.  Rows
+        past `src` (the pad to the batch sharding) are their own source."""
+        if src is None or self._prefill_wave_rows(b, sp) == b:
+            return None
+        src = tuple(int(s) for s in src)
+        src += tuple(range(len(src), b))
+        return None if len(set(src)) == b else src
+
+    def _prefill_distinct(self, params, prompt_tok, seg, cache, src):
+        """The wave path over the DISTINCT rows (`src`'s own values, in
+        row order), each landing at every row of its group -> (logits [B,
+        V], the cache).  A prefill has no random part: the rows of a
+        group got the same logits and cache, computed once each.
+
+        One scan, as `_prefill_in_waves`: a wave's part cache lands in the
+        whole one inside the wave, row by row at the rows a table names.
+        The scan makes one trip more than there are waves, and that trip
+        does nothing.  With one wave — every cell so far — a scan of its
+        trips alone is a loop of one trip: XLA:TPU inlines it, assigns the
+        decode loop's operands to other memory spaces than with the prefill
+        in a loop before it, and rounds a decode matmul differently (chip
+        runs, PR 61: lfm2's prefill bit for bit the parent's and its rows'
+        samples parting from step 19 on).  With the spare trip the loop
+        stays and the compiled decode loop is the parent's."""
+        cfg = self.cfg
+        bsz, sp = prompt_tok.shape
+        first = sorted(set(src))
+        rows = self._prefill_wave_rows(len(first), sp)
+        waves = len(first) // rows
+        # Where a distinct row lands: its group's rows, the last of them
+        # again up to the widest group's count (written twice, the same).
+        groups = [[r for r, s in enumerate(src) if s == f] for f in first]
+        width = max(len(g) for g in groups)
+        to = np.asarray(
+            [g + g[-1:] * (width - len(g)) for g in groups], np.int32)
+
+        def trips(x):  # [waves * rows, ...] -> [waves + 1, rows, ...]
+            x = x.reshape(waves, rows, *x.shape[1:])
+            return jnp.concatenate([x, jnp.zeros_like(x[:1])])
+
+        def wave(carry, xs):
+            i, tok, sg, to = xs
+
+            def run(carry):
+                cache, logits = carry
+                part = tfm.init_kv_cache(
+                    cfg, rows, cache.s_max, dtype=self.compute_dtype)
+                new, part = tfm.prefill(
+                    params, cfg, tok, sg, part, use_flash=self._use_flash)
+                for j in range(rows):
+                    for at in to[j]:
+                        cache = jax.tree.map(
+                            lambda whole, p: jax.lax.dynamic_update_slice_in_dim(
+                                whole, p[:, j:j + 1], at, axis=1),
+                            cache, part,
+                        )
+                        logits = jax.lax.dynamic_update_slice_in_dim(
+                            logits, new[j:j + 1], at, axis=0)
+                return cache, logits
+
+            return jax.lax.cond(i < waves, run, lambda c: c, carry), None
+
+        rows_of = np.asarray(first)
+        (cache, logits), _ = jax.lax.scan(
+            wave,
+            (cache, jnp.zeros((bsz, cfg.vocab_size), jnp.float32)),
+            (jnp.arange(waves + 1), trips(prompt_tok[rows_of]),
+             trips(seg[rows_of]), trips(jnp.asarray(to))),
+        )
+        return logits, cache
 
     def _prefill_in_waves(self, params, prompt_tok, seg, cache, rows: int):
         """`tfm.prefill` over `rows` rows at a time -> (logits [B, V], the
