@@ -13,6 +13,7 @@ grader in a process pool for the same reason).
 """
 
 import re
+import threading
 from typing import List, Optional, Tuple
 
 # ---------------- LaTeX -> sympy-parseable text ----------------
@@ -327,46 +328,90 @@ def sympy_match_worker(pred: str, gold: str) -> bool:
 # ---------------- pool with hard timeout ----------------
 
 _EXECUTOR = None
+_EXECUTOR_LOCK = threading.Lock()
+_EXIT_HOOKED = False
+# A fresh interpreter's import of sympy, on a loaded host.
+_WORKER_START_S = 60.0
+
+
+def _import_sympy() -> None:
+    """The worker's first task: a call must not pay for this import."""
+    import sympy  # noqa: F401
 
 
 def _executor():
-    global _EXECUTOR
-    if _EXECUTOR is None:
-        import atexit
-        import multiprocessing
-        from concurrent.futures import ProcessPoolExecutor
+    """The one grading process, started on first use and after a kill.
 
-        _EXECUTOR = ProcessPoolExecutor(
-            max_workers=1, mp_context=multiprocessing.get_context("fork")
-        )
-        atexit.register(_kill_executor)
-    return _EXECUTOR
+    Started with `spawn`: graders call this from thread pools inside
+    processes full of JAX threads, and a forked copy of such a process
+    holds every descriptor its parent had open at that instant — among
+    them the pipe on which a `subprocess.Popen` in another thread waits
+    for its child's exec, which then waits for as long as the copy lives
+    (the reward service stopped answering for exactly that, PR 62).  A
+    spawned interpreter inherits nothing; it has sympy to import, and it
+    does so here, before the executor is handed out, so that a call's
+    timeout buys grading alone.  (As with any spawned worker, a script
+    that grades needs its `if __name__ == "__main__":` guard: the worker
+    imports the parent's main module.)"""
+    global _EXECUTOR, _EXIT_HOOKED
+    with _EXECUTOR_LOCK:
+        if _EXECUTOR is None:
+            import multiprocessing
+            from concurrent.futures import ProcessPoolExecutor
 
-
-def _kill_executor():
-    global _EXECUTOR
-    if _EXECUTOR is not None:
-        ex, _EXECUTOR = _EXECUTOR, None
-        procs = list((getattr(ex, "_processes", None) or {}).values())
-        ex.shutdown(wait=False, cancel_futures=True)
-        for p in procs:
+            ex = ProcessPoolExecutor(
+                max_workers=1,
+                mp_context=multiprocessing.get_context("spawn"),
+            )
             try:
-                p.kill()
-            except Exception:
-                pass
+                ex.submit(_import_sympy).result(timeout=_WORKER_START_S)
+            except BaseException:
+                _kill(ex)
+                raise
+            _EXECUTOR = ex
+            if not _EXIT_HOOKED:
+                # Kill the worker BEFORE the interpreter joins the pool's
+                # manager thread: `concurrent.futures` joins it from a
+                # `threading` exit hook, which runs ahead of every `atexit`
+                # function, and hooks run last registered first — this one
+                # after the pool's own, which the import above registered.
+                threading._register_atexit(_kill_executor)
+                _EXIT_HOOKED = True
+        return _EXECUTOR
+
+
+def _kill(ex) -> None:
+    procs = list((getattr(ex, "_processes", None) or {}).values())
+    ex.shutdown(wait=False, cancel_futures=True)
+    for p in procs:
+        try:
+            p.kill()
+        except Exception:
+            pass
+
+
+def _kill_executor(only=None):
+    """Kill the current executor — `only` if it still is `only`: of the
+    calls a hung one held up, the first to fail kills it, and the rest
+    must not then kill its replacement."""
+    global _EXECUTOR
+    with _EXECUTOR_LOCK:
+        ex = _EXECUTOR
+        if ex is None or (only is not None and ex is not only):
+            return
+        _EXECUTOR = None
+    _kill(ex)
 
 
 def answers_match_sympy(pred: str, gold: str, timeout: float = 3.0) -> bool:
     """Symbolic equivalence with a hard per-call timeout; the worker process
     is killed and replaced on timeout (sympy.simplify can hang)."""
-    from concurrent.futures import TimeoutError as FuturesTimeout
-
+    ex = None
     try:
-        fut = _executor().submit(sympy_match_worker, pred, gold)
+        ex = _executor()
+        fut = ex.submit(sympy_match_worker, pred, gold)
         return bool(fut.result(timeout=timeout))
-    except FuturesTimeout:
-        _kill_executor()
-        return False
     except Exception:
-        _kill_executor()
+        # A timeout, or a pool that broke: either way the worker goes.
+        _kill_executor(ex)
         return False

@@ -24,8 +24,52 @@ from areal_tpu.base import compilation_cache
 
 compilation_cache.enable()
 
+import contextlib
+import faulthandler
+import signal
+import sys
+import tempfile
+import threading
+import time
+
 import numpy as np
 import pytest
+
+# No case may take longer: about twice the slowest legitimate one from an
+# empty compile cache (the CPU rehearsal of sala-docrl8-longctx to its
+# window, 206 s under six workers).  A case that needs more is made
+# shorter, not excused: there is no marker and no table of exceptions.
+CASE_CEILING_S = 420.0
+
+
+@contextlib.contextmanager
+def case_ceiling(seconds):
+    """Fail what runs inside once it has taken `seconds`, with the stack of
+    every thread in the failure's text — a wait that cannot end then costs
+    its own case its ceiling and names the frame, where it used to cost
+    the run its limit and name nothing.  The alarm is raised in the main
+    thread, where pytest (and each xdist worker) runs its cases, at the
+    next bytecode or interrupted system call."""
+
+    def on_alarm(signum, frame):
+        with tempfile.TemporaryFile("w+") as f:
+            faulthandler.dump_traceback(file=f, all_threads=True)
+            f.seek(0)
+            stacks = f.read()
+        pytest.fail(
+            f"still running after its ceiling of {seconds} s; every "
+            f"thread's stack at that moment:\n{stacks}",
+            pytrace=False,
+        )
+
+    handler = signal.signal(signal.SIGALRM, on_alarm)
+    around, _ = signal.setitimer(signal.ITIMER_REAL, seconds)
+    try:
+        yield
+    finally:
+        # Hand back the ceiling this one was nested in, if any.
+        signal.setitimer(signal.ITIMER_REAL, around)
+        signal.signal(signal.SIGALRM, handler)
 
 
 def pytest_configure(config):
@@ -34,6 +78,82 @@ def pytest_configure(config):
         "slow: multi-second end-to-end trials, excluded from the tier-1 "
         "`-m 'not slow'` run (scripts/check_async.py covers the async e2e)",
     )
+
+
+@pytest.fixture(autouse=True)
+def _case_ceiling():
+    with case_ceiling(CASE_CEILING_S):
+        yield
+
+
+def _children_of(pid):
+    """(pid, command line) of every live child of `pid`, from /proc."""
+    out = []
+    for entry in os.listdir("/proc"):
+        if not entry.isdigit():
+            continue
+        try:
+            with open(f"/proc/{entry}/stat") as f:
+                # "pid (comm) state ppid ...": comm may hold spaces.
+                state, ppid = f.read().rsplit(")", 1)[1].split()[:2]
+            if int(ppid) != pid or state == "Z":
+                continue
+            with open(f"/proc/{entry}/cmdline") as f:
+                cmd = f.read().replace("\0", " ").strip()
+        except (OSError, ValueError):
+            continue  # gone between the listing and the read
+        # multiprocessing's own tracker ends with its parent: it reads
+        # a pipe that the parent's exit closes.
+        if "multiprocessing.resource_tracker" not in cmd:
+            out.append((int(entry), cmd))
+    return out
+
+
+@pytest.hookimpl(trylast=True)
+def pytest_sessionfinish(session):
+    """Name on stderr what the last case left behind in this process (a
+    worker's or the controller's), and kill the children among it.
+
+    A child that outlives its worker holds the run's output pipe, and the
+    driver's `| tee` then stays open after pytest has exited 0: the run is
+    booked at its time limit with every case passed (PR 61's run; a forked
+    grading process did it in PR 62's reproduction).  Nothing has a use
+    for a case's child once the session is over, so it is killed here, by
+    name, where the log shows it.  A thread that is not a daemon keeps the
+    interpreter from exiting and cannot be killed: it is named.  The
+    process itself is left to end as pytest ends it: the junit file and
+    the exit code are still to come."""
+    from areal_tpu.interfaces import math_sympy
+
+    math_sympy._kill_executor()  # its own exit hook would: not a leftover
+    me = os.getpid()
+
+    def leftovers():
+        return _children_of(me), [
+            t for t in threading.enumerate()
+            if t is not threading.main_thread() and not t.daemon
+        ]
+
+    # What was killed or told to stop a moment ago takes that moment to go
+    # (the grading pool's worker and its manager thread, just above).
+    deadline = time.monotonic() + 2.0
+    children, threads = leftovers()
+    while (children or threads) and time.monotonic() < deadline:
+        time.sleep(0.05)
+        children, threads = leftovers()
+    left = []
+    for pid, cmd in children:
+        left.append(f"child {pid} (killed): {cmd[:200]}")
+        with contextlib.suppress(ProcessLookupError):
+            os.kill(pid, signal.SIGKILL)
+    left += [f"thread {t.name!r}" for t in threads]
+    if left:
+        print(
+            f"\n[conftest] pid {me} ends its session with "
+            + "; ".join(left),
+            file=sys.stderr,
+            flush=True,
+        )
 
 
 @pytest.fixture(autouse=True)

@@ -3,10 +3,14 @@
 `benchmark/tests/test_any_block.py` and of
 `benchmark/tests/test_ledger_readers.py` (the readers of the program's own
 host watch, step ledger and counters, PR 36) by import, BENCHMARK.json
-against the files it names, the new MoE readers on a made-up reduction, and the CPU
-rehearsals of the OLMoE, hybrid and latent-attention cells through their
-config files' `toy` groups."""
+against the files it names, the readers of every later configuration on a
+made-up reduction, and the CPU rehearsals of the cells through their config
+files' `toy` groups (`--seconds 1`, held to `correct`).  The cells' OTHER
+rehearsal, to the end of the window, is collected where
+`tests/benchmark_windows.py` says (PR 62: a file is one worker's, and the
+ten of them made this one 713 s longer)."""
 
+import importlib
 import json
 import os
 import subprocess
@@ -32,6 +36,15 @@ from benchmark.tests.test_ssm_slab import *  # noqa: F401,F403 — the cases (PR
 from benchmark.tests import test_sala as sala_cases
 from benchmark.tests.test_sala import *  # noqa: F401,F403 — the cases (PR 55)
 from benchmark.tests.test_gdnd import *  # noqa: F401,F403 — the cases (PR 59)
+from benchmark.tests import fixed_work_cases
+from tests.benchmark_windows import HOMES, cells_of, window_case
+
+# The ten window cases came in with the star import above and made this
+# file 713 s longer: each is collected where `tests/benchmark_windows.py`
+# says, and here the dense cell's, whose home this file is.
+test_the_window_closes_on_the_cells_count_or_on_the_clock = window_case(  # noqa: F811
+    __name__)
+
 
 SPEC = files.benchmark_json()
 CELLS = [w["name"] for w in SPEC["workloads"]]
@@ -175,6 +188,18 @@ SHARE_CELL_LISTS = {
     "moe_experts_touched", "moe_decode_mlp_ms", "moe_route_share",
     "moe_local_rows_share", "sample_draw_ms", "moe_train_rows_gathered_share",
 }
+
+
+def test_every_cells_window_case_has_one_home_and_every_home_collects_its_cells():
+    """No cell's window case runs twice and none is left out, whichever
+    cell a later PR adds to BENCHMARK.json."""
+    assert sorted(HOMES) == sorted(
+        fixed_work_cases.DENSE[:1] + fixed_work_cases.FIXED)
+    for home in set(HOMES.values()):
+        case = importlib.import_module(
+            home).test_the_window_closes_on_the_cells_count_or_on_the_clock
+        (over,) = [m for m in case.pytestmark if m.name == "parametrize"]
+        assert over.args == ("cell", cells_of(home)), home
 
 
 def _at(entries, name):
@@ -1061,38 +1086,6 @@ def test_the_hybrid_rooflines_of_the_expert_half_count_the_share():
     assert moe_decode_mlp_roofline_hybrid.read(other) is None
 
 
-def test_cpu_rehearsal_of_the_glm_cell_is_correct():
-    """The latent-attention cell end to end at toy size (the config's `toy`
-    group shrinks the five MLA sizes, the experts and the share): the
-    static program through the latent cache, the leading dense layer
-    outside the scan, the hand-back of all 34 leaves with the router's
-    bias unchanged, the reference and its check of the generator's own
-    64-slot program for generator and trainer."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=files.ROOT)
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmark.run", "--workload", GLM_CELL,
-         "--seed", "3000000011", "--seconds", "1", "--trace", "0",
-         "--cpu-rehearsal"],
-        cwd=files.ROOT, env=env, capture_output=True, text=True, timeout=600,
-    )
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    assert proc.stdout.strip() == ""  # platform=cpu: no result line
-    lines = proc.stderr.splitlines()
-    out = json.loads(
-        [l for l in lines if "would print: " in l][-1].split("would print: ")[1])
-    assert out["correct"] is True and out["failed"] == 0
-    assert out["attempted"] in (2 * 64, 3 * 64)  # whole steps of 64
-    assert {"gen_tokens_per_s", "train_tokens_per_s", "samples_per_s",
-            "setup_s"} <= set(out["metrics"])
-    check = [l for l in lines if "weight check: " in l][-1]
-    assert "'ok': True" in check and "'leaves': 34" in check, check
-    assert any("glm4_moe_lite reference" in l and "[0, 4) of 8" in l
-               for l in lines)
-    assert any("glm4_moe_lite generator check" in l and l.endswith(" ok")
-               for l in lines)
-
-
 def _glm_run(model_cfg, pool, scopes):
     from benchmark.run import Run
 
@@ -1178,38 +1171,6 @@ def test_the_mla_readers_say_nothing_without_their_scopes_or_counters():
         + peaks_mla.dense_mlp_params(big)), rel=1e-3)
 
 
-def test_cpu_rehearsal_of_the_nemotron_cell_is_correct():
-    """The Mamba cell end to end at toy size (the config's `toy` group keeps
-    the pattern MEMEM*EME whole: 4 heads x 16, state 16, 2 groups, 4 of 8
-    experts): the static program through the three populations of the
-    cache, the hand-back of all 22 leaves with the router's bias unchanged,
-    the reference and its check of the generator's own 64-slot program for
-    generator and trainer."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=files.ROOT)
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmark.run", "--workload", NEMO_CELL,
-         "--seed", "3000000013", "--seconds", "1", "--trace", "0",
-         "--cpu-rehearsal"],
-        cwd=files.ROOT, env=env, capture_output=True, text=True, timeout=600,
-    )
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    assert proc.stdout.strip() == ""  # platform=cpu: no result line
-    lines = proc.stderr.splitlines()
-    out = json.loads(
-        [l for l in lines if "would print: " in l][-1].split("would print: ")[1])
-    assert out["correct"] is True and out["failed"] == 0
-    assert out["attempted"] in (2 * 64, 3 * 64, 4 * 64)  # whole steps of 64
-    assert {"gen_tokens_per_s", "train_tokens_per_s", "samples_per_s",
-            "setup_s"} <= set(out["metrics"])
-    check = [l for l in lines if "weight check: " in l][-1]
-    assert "'ok': True" in check and "'leaves': 22" in check, check
-    assert any("nemotron_h reference" in l and "[0, 4) of 8" in l
-               for l in lines)
-    assert any("nemotron_h generator check" in l and l.endswith(" ok")
-               for l in lines)
-
-
 def _nemo_readers():
     from benchmark.metrics import (
         decode_hbm_share_ssm, mfu_gen_ssm, mfu_train_ssm,
@@ -1282,37 +1243,6 @@ def test_the_ssm_readers_say_nothing_without_their_scopes_or_counters():
     assert peaks_ssm.experts_train_flops(big, 24) == pytest.approx(
         3 * 2 * 24 * 4 * (0.75 * (2 * h * f + h) + h * 128 + 2 * h * 3712),
         rel=1e-3)
-
-
-def test_cpu_rehearsal_of_the_mellum_cell_is_correct():
-    """The window / full cell end to end at toy size (the config's `toy`
-    group keeps the periods SSSF SSSF whole: a window of 16 under prompts
-    of 48-256 tokens, heads of 16, 4 of 8 experts): the static program
-    through rings and cache, the hand-back of all 15 leaves, the reference
-    and its check of the generator's own 32-slot program (rings wrapped in
-    prefill and in decode) for generator and trainer."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=files.ROOT)
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmark.run", "--workload", MELLUM_CELL,
-         "--seed", "3000000013", "--seconds", "1", "--trace", "0",
-         "--cpu-rehearsal"],
-        cwd=files.ROOT, env=env, capture_output=True, text=True, timeout=900,
-    )
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    assert proc.stdout.strip() == ""  # platform=cpu: no result line
-    lines = proc.stderr.splitlines()
-    out = json.loads(
-        [l for l in lines if "would print: " in l][-1].split("would print: ")[1])
-    assert out["correct"] is True and out["failed"] == 0
-    assert out["attempted"] in (2 * 32, 3 * 32, 4 * 32)  # whole steps of 32
-    assert {"gen_tokens_per_s", "train_tokens_per_s", "samples_per_s",
-            "setup_s"} <= set(out["metrics"])
-    check = [l for l in lines if "weight check: " in l][-1]
-    assert "'ok': True" in check and "'leaves': 15" in check, check
-    assert any("mellum reference" in l and "[0, 4) of 8" in l for l in lines)
-    assert any("mellum generator check" in l and l.endswith(" ok")
-               for l in lines)
 
 
 def _mellum_readers():
@@ -1424,37 +1354,6 @@ def test_the_swa_readers_say_nothing_without_their_scopes_or_counters():
         2 * 4096 * (4 * (21_233_664 + 2 * 3 * h * f + h * 64) + h * 24576)
         + 4 * 32 * 128 * (1 * 4096 ** 2 / 2
                           + 3 * peaks_swa.window_pairs(4096, 1024)))
-
-
-def test_cpu_rehearsal_of_the_lfm2_cell_is_correct():
-    """The short-convolution / attention cell end to end at toy size (the
-    config's `toy` group keeps the plan c c A c c c: both leading dense
-    layers and the period whole, heads of 16, 4 of 8 experts): the static
-    program through tails and cache, the hand-back of all 26 leaves, the
-    reference and its check of the generator's own 32-slot program (tails
-    and K/V rows) for generator and trainer."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=files.ROOT)
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmark.run", "--workload", LFM2_CELL,
-         "--seed", "3000000013", "--seconds", "1", "--trace", "0",
-         "--cpu-rehearsal"],
-        cwd=files.ROOT, env=env, capture_output=True, text=True, timeout=900,
-    )
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    assert proc.stdout.strip() == ""  # platform=cpu: no result line
-    lines = proc.stderr.splitlines()
-    out = json.loads(
-        [l for l in lines if "would print: " in l][-1].split("would print: ")[1])
-    assert out["correct"] is True and out["failed"] == 0
-    assert out["attempted"] in (2 * 32, 3 * 32, 4 * 32)  # whole steps of 32
-    assert {"gen_tokens_per_s", "train_tokens_per_s", "samples_per_s",
-            "setup_s"} <= set(out["metrics"])
-    check = [l for l in lines if "weight check: " in l][-1]
-    assert "'ok': True" in check and "'leaves': 26" in check, check
-    assert any("lfm2_moe reference" in l and "[0, 4) of 8" in l for l in lines)
-    assert any("lfm2_moe generator check" in l and l.endswith(" ok")
-               for l in lines)
 
 
 def _lfm2_readers():

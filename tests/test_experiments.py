@@ -203,7 +203,7 @@ class TestPPOMathExperiment:
             # In the master's process the one host watch reports once.
             assert not [k for k in s if "/host/" in k]
             for node in ("actor_gen", "rew_inf", "actor_train"):
-                assert 0 <= s[f"{node}/perf/self_s"] < s[f"{node}/perf/time_s"]
+                assert s[f"{node}/perf/self_s"] >= 0
         closed = tracer.step_ledger()[-2:]
         assert [c["step"] for c in closed] == [1, 2]
         for c, s in zip(closed, stats):
@@ -215,12 +215,23 @@ class TestPPOMathExperiment:
                     "stats_sync"} <= names, names
             if mode == "value":
                 assert "gae" in names
-            # The handler's self time is its span's, from the same ledger.
-            (mfc,) = [k for k in names if k.startswith("mfc:actor@")
-                      and k.endswith("train_step")]
-            assert c["spans"][mfc][2] == pytest.approx(
-                s["actor_train/perf/self_s"], abs=1e-9
-            )
+            # The handler's self time is its span's, from the same ledger,
+            # and no more than the span's whole: one clock's two readings
+            # of one interval.  (`perf/time_s` is a third reading, taken
+            # inside the span: where next to nothing runs under a handler —
+            # `rew_inf`, a millisecond — it tied with the self time under
+            # load and `self_s < time_s` failed, PR 40.)
+            for node, model, call in (
+                    ("actor_gen", "actor_gen", "generate"),
+                    ("rew_inf", "reward", "inference"),
+                    ("actor_train", "actor", "train_step")):
+                (mfc,) = [k for k in names if k.startswith(f"mfc:{model}@")
+                          and k.endswith(call)]
+                _, total_s, self_s = c["spans"][mfc]
+                assert self_s == pytest.approx(
+                    s[f"{node}/perf/self_s"], abs=1e-9
+                )
+                assert self_s <= total_s
 
     def test_ppo_offload_and_difficulty_filter(self, tmp_path):
         """OffloadHook frees the ref model after each ref_inf call (it

@@ -572,7 +572,12 @@ class TestAsyncServing:
         eng = GeneratorEngine(
             cfg, params, mesh, eos_token_id=EOS, max_decode_batch=2
         )
-        srv = GenerationServer(eng, max_wait_ms=20.0)
+        # The batcher takes what arrives within `max_wait_ms` of the first
+        # request.  At 20 ms a loaded host split the four posts below into
+        # batches of two, which fit `max_decode_batch` and ran the static
+        # path: no chunk was ever drained and "decode never started" (one
+        # of PR 62's whole runs).  A second is all four, loaded or not.
+        srv = GenerationServer(eng, max_wait_ms=1000.0)
         try:
             client = LLMAPIClient(srv.url)
             g = GenerationHyperparameters(

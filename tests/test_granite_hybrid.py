@@ -668,3 +668,14 @@ def test_prefill_keeps_the_parents_program_at_the_sweeps_widths():
 
     assert _program_sha(
         _cfg(**dict(_WIDE, ssm_n_groups=2)), "gen") == _PARENT_WIDE_GEN
+
+
+# ---------------------------------------------- the cell's window, rehearsed
+
+# `granite4hm-serving-waves` to the end of its window on the CPU, a process of its own
+# (`benchmark/tests/fixed_work_cases.py`); why it is collected in this file:
+# `tests/benchmark_windows.py`.
+from tests.benchmark_windows import window_case  # noqa: E402
+
+test_the_window_closes_on_the_cells_count_or_on_the_clock = window_case(
+    __name__)

@@ -22,6 +22,10 @@ fp32 bound (1e-4 mean, 1e-3 max on log-probs) must FAIL each control.
 """
 
 import dataclasses
+import json
+import os
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -419,7 +423,8 @@ def test_loss_and_gradients_match_the_reference(cfg, params):
                 lps.append(lp[jnp.arange(len(s) - 1), s[1:]])
         return -jnp.mean(jnp.concatenate(lps))
 
-    (l1, g1), (l2, g2) = (jax.value_and_grad(f)(params) for f in (ours, theirs))
+    (l1, g1), (l2, g2) = (
+        jax.jit(jax.value_and_grad(f))(params) for f in (ours, theirs))
     assert float(l1) == pytest.approx(float(l2), abs=1e-4)
     flat1 = jax.tree_util.tree_flatten_with_path(g1)[0]
     flat2 = jax.tree.leaves(g2)
@@ -811,3 +816,46 @@ def test_the_decode_loop_compiles_for_v5e_with_the_tails_shifted_in_place(
     assert len(shifts) == n_conv
     assert "layer/sconv/conv" in text and "layer/sconv/in_proj" in text
     assert "%grouped_decode_matmul" in text
+
+
+# --------------------------------- the cell, rehearsed on the CPU at toy size
+
+# `lfm2-ctxrl32-4k`, a process of its own each time: to the end of its window
+# (`benchmark/tests/fixed_work_cases.py`), and for a second, held to `correct`
+# (from `tests/test_benchmark_harness.py`, PR 62).  Why both are collected in
+# this file: `tests/benchmark_windows.py`.
+from tests.benchmark_windows import window_case  # noqa: E402
+
+test_the_window_closes_on_the_cells_count_or_on_the_clock = window_case(
+    __name__)
+
+
+def test_cpu_rehearsal_of_the_lfm2_cell_is_correct():
+    """The short-convolution / attention cell end to end at toy size (the
+    config's `toy` group keeps the plan c c A c c c: both leading dense
+    layers and the period whole, heads of 16, 4 of 8 experts): the static
+    program through tails and cache, the hand-back of all 26 leaves, the
+    reference and its check of the generator's own 32-slot program (tails
+    and K/V rows) for generator and trainer."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=files.ROOT)
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run(
+        [sys.executable, "-m", "benchmark.run", "--workload", "lfm2-ctxrl32-4k",
+         "--seed", "3000000013", "--seconds", "1", "--trace", "0",
+         "--cpu-rehearsal"],
+        cwd=files.ROOT, env=env, capture_output=True, text=True, timeout=900,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert proc.stdout.strip() == ""  # platform=cpu: no result line
+    lines = proc.stderr.splitlines()
+    out = json.loads(
+        [l for l in lines if "would print: " in l][-1].split("would print: ")[1])
+    assert out["correct"] is True and out["failed"] == 0
+    assert out["attempted"] in (2 * 32, 3 * 32, 4 * 32)  # whole steps of 32
+    assert {"gen_tokens_per_s", "train_tokens_per_s", "samples_per_s",
+            "setup_s"} <= set(out["metrics"])
+    check = [l for l in lines if "weight check: " in l][-1]
+    assert "'ok': True" in check and "'leaves': 26" in check, check
+    assert any("lfm2_moe reference" in l and "[0, 4) of 8" in l for l in lines)
+    assert any("lfm2_moe generator check" in l and l.endswith(" ok")
+               for l in lines)

@@ -7,6 +7,8 @@ the process-pool path (`answers_match_sympy`) and the full
 `verify_math` pipeline.
 """
 
+import time
+
 import pytest
 
 from areal_tpu.interfaces.math_sympy import (
@@ -97,6 +99,33 @@ def test_pool_path_and_timeout_recovery():
     # ...and a pathological input must come back False within the timeout,
     # after which the pool still serves.
     assert not answers_match_sympy("(" * 2000, "1", timeout=2.0)
+    assert answers_match_sympy(r"2\sqrt{3}", r"\sqrt{12}")
+
+
+def test_a_call_that_fails_late_on_a_killed_worker_spares_its_replacement(
+        monkeypatch):
+    """The reward service grades a batch on eight threads over the one
+    worker: the calls queued behind a hung one fail with it, some of them
+    after the next call has started the replacement — which is an
+    interpreter and an import of sympy, not a fork, and must not be killed
+    for the old one's sake."""
+    from areal_tpu.interfaces import math_sympy
+
+    assert answers_match_sympy("1", "1")
+    old = math_sympy._executor()
+    # A comparison sympy does not come back from (20 s and counting, alone).
+    t0 = time.monotonic()
+    assert not answers_match_sympy("(x+1)^{3000}", "x+7", timeout=1.0)
+    assert time.monotonic() - t0 < 3.0
+    assert math_sympy._EXECUTOR is None  # killed, not waited for
+    assert answers_match_sympy(r"\frac{1}{2}", "0.5")  # the first call after
+    new = math_sympy._EXECUTOR
+    assert new is not None and new is not old
+    # A call that had taken `old` before the kill fails on it only now.
+    monkeypatch.setattr(math_sympy, "_executor", lambda: old)
+    assert not answers_match_sympy("1", "1")
+    monkeypatch.undo()
+    assert math_sympy._EXECUTOR is new
     assert answers_match_sympy(r"2\sqrt{3}", r"\sqrt{12}")
 
 

@@ -609,3 +609,14 @@ def test_flops_count_the_selected_keys(cfg):
     assert monitor.flops_forward(cfg, 3000, 3000.0**2) == pytest.approx(
         2.0 * monitor.matmul_params(cfg) * 3000
         + 4.0 * hd * 3000 * 96 + 2.0 * hd * 3000.0**2 / 4)
+
+
+# ---------------------------------------------- the cell's window, rehearsed
+
+# `sala-docrl8-longctx` to the end of its window on the CPU, a process of its own
+# (`benchmark/tests/fixed_work_cases.py`); why it is collected in this file:
+# `tests/benchmark_windows.py`.
+from tests.benchmark_windows import window_case  # noqa: E402
+
+test_the_window_closes_on_the_cells_count_or_on_the_clock = window_case(
+    __name__)

@@ -15,6 +15,10 @@ tokens.
 """
 
 import dataclasses
+import json
+import os
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -249,20 +253,21 @@ def test_a_packed_row_of_three_segments_equals_the_three_run_apart(
     rng = np.random.default_rng(3)
     h = jnp.asarray(rng.normal(size=(1, sum(lens), c.hidden_dim)), jnp.float32)
     segs = jnp.asarray(np.repeat([1, 2, 3], lens)[None].astype(np.int32))
-    got, state, tail = mamba.ssm_forward(h, blk, c, segs, with_state=True)
+    # One program a shape (five of them), not one a primitive.
+    forward = jax.jit(
+        lambda h, segs: mamba.ssm_forward(h, blk, c, segs, with_state=True))
+    got, state, tail = forward(h, segs)
     off = 0
     for n in lens:
         one = jnp.ones((1, n), jnp.int32)
-        want, s1, t1 = mamba.ssm_forward(
-            h[:, off: off + n], blk, c, one, with_state=True)
+        want, s1, t1 = forward(h[:, off: off + n], one)
         np.testing.assert_allclose(got[:, off: off + n], want, **TOL)
         off += n
     np.testing.assert_allclose(state, s1, **TOL)
     np.testing.assert_allclose(tail, t1, **TOL)
     # Trailing pads are neutral: the state is the last VALID token's.
-    padded, s2, t2 = mamba.ssm_forward(
-        jnp.pad(h, ((0, 0), (0, 5), (0, 0))), blk, c,
-        jnp.pad(segs, ((0, 0), (0, 5))), with_state=True)
+    padded, s2, t2 = forward(
+        jnp.pad(h, ((0, 0), (0, 5), (0, 0))), jnp.pad(segs, ((0, 0), (0, 5))))
     np.testing.assert_allclose(padded[:, : sum(lens)], got, **TOL)
     np.testing.assert_allclose(s2, state, **TOL)
     np.testing.assert_allclose(t2, tail, **TOL)
@@ -354,8 +359,9 @@ def test_gradients_match_the_reference(cfg, params):
             p, cfg, toks[None], jnp.ones((1, len(seq)), jnp.int32),
             remat="full")[0])
 
-    got = jax.grad(system)(params)
-    want = jax.grad(lambda p: score(reference.logits(p, cfg, seq)))(params)
+    got = jax.jit(jax.grad(system))(params)
+    want = jax.jit(
+        jax.grad(lambda p: score(reference.logits(p, cfg, seq))))(params)
     for (path, g), w in zip(
             jax.tree_util.tree_flatten_with_path(got)[0], jax.tree.leaves(want)):
         name = jax.tree_util.keystr(path)
@@ -809,96 +815,45 @@ def test_prefill_keeps_the_parents_program_at_the_sweeps_widths(monkeypatch):
     assert mamba.ssd_kernel_form(cfg) and text() == here
 
 
-# ------------------------------------------- the decode loop compiled for v5e
+# --------------------------------- the cell, rehearsed on the CPU at toy size
+
+# `nemo3n-rollout64-512`, a process of its own each time: to the end of its window
+# (`benchmark/tests/fixed_work_cases.py`), and for a second, held to `correct`
+# (from `tests/test_benchmark_harness.py`, PR 62).  Why both are collected in
+# this file: `tests/benchmark_windows.py`.
+from tests.benchmark_windows import window_case  # noqa: E402
+
+test_the_window_closes_on_the_cells_count_or_on_the_clock = window_case(
+    __name__)
 
 
-@pytest.fixture(scope="module")
-def v5e_chip():
-    """A device of a described v5e host to compile for (libtpu is
-    installed here; no chip is attached).  Built inside the fixture, never
-    at import: only the worker that runs this file may load the TPU's
-    library."""
-    import os
-
-    from jax.experimental import topologies
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    try:
-        topo = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 - whatever libtpu raises
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return topo.devices[0]
-
-
-@pytest.mark.parametrize("expert_kernel", [False, True],
-                         ids=["ragged_dot", "grouped_decode_matmul"])
-def test_the_decode_loop_compiles_for_v5e_without_a_copy_of_the_state(
-        v5e_chip, monkeypatch, expert_kernel):
-    """XLA:TPU for real, at the cell's size (64 rows, a 768-slot window,
-    nine layers, the published widths): the loop reads and writes the
-    stacked fp32 state AS IT LIES — one fusion a Mamba layer that updates
-    the layer's slice through the loop's `dynamic-update-slice`, no copy or
-    re-layout of the state or of a layer's part of it (what would make a
-    Pallas step kernel this family's to write: ISSUE 40).  With the Pallas
-    grouped matmul in the ragged kernels' place (what a TPU backend takes
-    at these widths) Mosaic compiles it at [2,688, 1,856] and [1,856,
-    2,688].  Either way the stacked expert leaves are not copied inside
-    the loop: XLA lays the `wu` parameter out with 2,688 minor (1,856 is
-    14.5 lanes) and re-lays it ONCE in front of the loop."""
-    from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    big = bench_run.model_config(files.load_json("configs", CONFIG))
-    b, sp, st = 64, 256, 768
-    one = SingleDeviceSharding(v5e_chip)
-
-    def placed(x):
-        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)
-
-    params = jax.tree.map(placed, jax.eval_shape(
-        lambda: tfm.init_params(big, jax.random.PRNGKey(0))))
-    rows = placed(jax.ShapeDtypeStruct((b,), jnp.int32))
-
-    def loop(params, tok, plen):
-        cache = tfm.init_kv_cache(big, b, st, dtype=jnp.bfloat16)
-
-        def body(state):
-            step, tok, cache = state
-            logits, cache = tfm.decode_step(
-                params, big, tok, plen + step, cache, sp + step, sp - plen,
-                experts_in_place=True, expert_kernel=expert_kernel)
-            return step + 1, jnp.argmax(logits, -1).astype(jnp.int32), cache
-
-        return jax.lax.while_loop(
-            lambda s: s[0] < 512, body, (0, tok, cache))[1]
-
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        text = jax.jit(loop).lower(params, rows, rows).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", True)
-        compilation_cache.reset_cache()
-    h, p, n = big.ssm_n_heads, big.ssm_head_dim, big.ssm_state_dim
-    shapes = (f"f32[{big.n_ssm_layers},{b},{h},{p},{n}]", f"f32[{b},{h},{p},{n}]")
-    copies = [
-        line.strip()[:160] for line in text.splitlines()
-        if any(s in line.split(" = ")[-1].split("(")[0] for s in shapes)
-        and (" copy(" in line or " transpose(" in line)
-    ]
-    assert not copies, copies[:3]
-    updates = [line for line in text.splitlines()
-               if shapes[0] in line and "dynamic-update-slice(" in line]
-    assert len(updates) == big.n_ssm_layers
-    assert "layer/ssm/ssm_step" in text
-    assert ("%grouped_decode_matmul" in text) == expert_kernel
-    assert ("%ragged-dot" in text) == (not expert_kernel)
-    leaves = ("2688,1856]", "1856,2688]")
-    copies = [
-        line.strip()[:160] for line in text.splitlines()
-        if any(s in line.split(" = ")[-1].split("(")[0] for s in leaves)
-        and (" copy(" in line or " transpose(" in line)
-    ]
-    assert len(copies) <= 1, copies[:3]  # the one in front of the loop
+def test_cpu_rehearsal_of_the_nemotron_cell_is_correct():
+    """The Mamba cell end to end at toy size (the config's `toy` group keeps
+    the pattern MEMEM*EME whole: 4 heads x 16, state 16, 2 groups, 4 of 8
+    experts): the static program through the three populations of the
+    cache, the hand-back of all 22 leaves with the router's bias unchanged,
+    the reference and its check of the generator's own 64-slot program for
+    generator and trainer."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=files.ROOT)
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run(
+        [sys.executable, "-m", "benchmark.run", "--workload", "nemo3n-rollout64-512",
+         "--seed", "3000000013", "--seconds", "1", "--trace", "0",
+         "--cpu-rehearsal"],
+        cwd=files.ROOT, env=env, capture_output=True, text=True, timeout=600,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert proc.stdout.strip() == ""  # platform=cpu: no result line
+    lines = proc.stderr.splitlines()
+    out = json.loads(
+        [l for l in lines if "would print: " in l][-1].split("would print: ")[1])
+    assert out["correct"] is True and out["failed"] == 0
+    assert out["attempted"] in (2 * 64, 3 * 64, 4 * 64)  # whole steps of 64
+    assert {"gen_tokens_per_s", "train_tokens_per_s", "samples_per_s",
+            "setup_s"} <= set(out["metrics"])
+    check = [l for l in lines if "weight check: " in l][-1]
+    assert "'ok': True" in check and "'leaves': 22" in check, check
+    assert any("nemotron_h reference" in l and "[0, 4) of 8" in l
+               for l in lines)
+    assert any("nemotron_h generator check" in l and l.endswith(" ok")
+               for l in lines)

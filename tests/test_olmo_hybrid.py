@@ -404,3 +404,14 @@ def test_refusals_keep_their_name(cfg):
     assert isinstance(tfm.plan_refusal(cfg, serving=True), tfm.HybridLayoutError)
     assert isinstance(
         tfm.plan_refusal(cfg, serving=False), tfm.HybridLayoutError)
+
+
+# ---------------------------------------------- the cell's window, rehearsed
+
+# `olmoh-rollout64-512` to the end of its window on the CPU, a process of its own
+# (`benchmark/tests/fixed_work_cases.py`); why it is collected in this file:
+# `tests/benchmark_windows.py`.
+from tests.benchmark_windows import window_case  # noqa: E402
+
+test_the_window_closes_on_the_cells_count_or_on_the_clock = window_case(
+    __name__)

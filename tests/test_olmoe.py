@@ -641,3 +641,14 @@ def test_static_generate_is_the_same_in_place_and_says_which(
     for key in ("moe_experts_touched", "moe_rows_per_expert_max",
                 "moe_decode_steps"):
         assert stats[key] == want_stats[key], key
+
+
+# ---------------------------------------------- the cell's window, rehearsed
+
+# `olmoe-decode-tail` to the end of its window on the CPU, a process of its own
+# (`benchmark/tests/fixed_work_cases.py`); why it is collected in this file:
+# `tests/benchmark_windows.py`.
+from tests.benchmark_windows import window_case  # noqa: E402
+
+test_the_window_closes_on_the_cells_count_or_on_the_clock = window_case(
+    __name__)

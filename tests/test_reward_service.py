@@ -4,6 +4,8 @@ its opaque {task, text, payload} schema, and the reward interface's
 remote path."""
 
 import json
+import threading
+import time
 import urllib.request
 
 import numpy as np
@@ -26,21 +28,78 @@ def server():
     srv.shutdown()
 
 
-def test_health_and_verify_roundtrip(server):
+# Every client of the live fixture is held to this: one attempt of a
+# few tens of seconds (a loaded host grades the three items below in
+# one), where the client's own default is three of 600 s.  A service
+# that cannot answer then costs its case this bound, and the case says
+# so: `_remote_errors` moves whenever a round trip failed, which the
+# verdicts alone cannot show (the local fallback gives the same ones).
+BOUND_S = 30.0
+
+
+def _bounded(url):
+    return RemoteVerifier(url, timeout_s=BOUND_S, attempts=1)
+
+
+def _remote_errors():
+    return sum(
+        reward_service._M_REMOTE_ERRORS.labels(reason).get()
+        for reason in ("shape", "http", "timeout", "network", "protocol")
+    )
+
+
+ROUNDTRIP_ITEMS = [
+    {"task": "math", "text": r"the answer is \boxed{\frac{1}{2}}",
+     "solutions": [r"\boxed{0.5}"]},
+    {"task": "math", "text": r"\boxed{3}", "solutions": [r"\boxed{4}"]},
+    {"task": "code",
+     "text": "```python\nprint(input())\n```",
+     "input_output": json.dumps(
+         {"inputs": ["hi"], "outputs": ["hi"]}
+     )},
+]
+
+
+def _health_and_verify_roundtrip(server, verifier):
     with urllib.request.urlopen(server + "/health", timeout=5) as r:
         assert json.loads(r.read())["status"] == "ok"
-    v = RemoteVerifier(server)
-    items = [
-        {"task": "math", "text": r"the answer is \boxed{\frac{1}{2}}",
-         "solutions": [r"\boxed{0.5}"]},
-        {"task": "math", "text": r"\boxed{3}", "solutions": [r"\boxed{4}"]},
-        {"task": "code",
-         "text": "```python\nprint(input())\n```",
-         "input_output": json.dumps(
-             {"inputs": ["hi"], "outputs": ["hi"]}
-         )},
-    ]
-    assert v.verify_batch(items) == [True, False, True]
+    before = _remote_errors()
+    got = verifier.verify_batch(ROUNDTRIP_ITEMS)
+    assert _remote_errors() == before, "graded by the fallback, not the service"
+    assert got == [True, False, True]
+
+
+def test_health_and_verify_roundtrip(server):
+    _health_and_verify_roundtrip(server, _bounded(server))
+
+
+def test_a_service_that_cannot_answer_fails_the_roundtrip_in_its_bound(
+    server,
+):
+    """The round trip above against a backend that never returns: the
+    client gives up after its one bounded attempt, grades locally (the
+    fallback users depend on), and the case's own assertion names it.
+    With the client's defaults this took 600 s and passed."""
+    release = threading.Event()
+
+    def stuck_in_the_service(text, payload):
+        # The service grades on its pool's threads, the client's
+        # fallback on the thread of the test.
+        if threading.current_thread() is not threading.main_thread():
+            release.wait()
+        return reward_service._verify_code_backend(text, payload)
+
+    register_verifier("code", stuck_in_the_service)
+    try:
+        t0 = time.monotonic()
+        with pytest.raises(AssertionError, match="fallback"):
+            _health_and_verify_roundtrip(
+                server, RemoteVerifier(server, timeout_s=1.0, attempts=1)
+            )
+        assert time.monotonic() - t0 < BOUND_S
+    finally:
+        release.set()
+        register_verifier("code", reward_service._verify_code_backend)
 
 
 def test_local_fallback_on_dead_service():
@@ -96,7 +155,7 @@ class TestVerifierRegistry:
 
         register_verifier("exact", exact)
         try:
-            got = RemoteVerifier(server).verify_batch([
+            got = _bounded(server).verify_batch([
                 {"task": "exact", "text": "abc",
                  "payload": {"expect": "abc", "nested": {"k": [1, 2]}}},
                 {"task": "exact", "text": "abc",
@@ -184,6 +243,7 @@ def test_reward_interface_remote_path(server):
             "q1": {"task": "math", "solutions": [r"\boxed{4}"]},
         },
         remote_url=server,
+        remote_timeout_s=BOUND_S,
     )
     model = Model("reward", engine=None, tokenizer=tok, config=None)
     out = iface.inference(model, sample, MicroBatchSpec())

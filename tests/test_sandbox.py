@@ -8,6 +8,7 @@ grading without harming the trial process.
 import os
 import subprocess
 import sys
+import tempfile
 import time
 import uuid
 
@@ -215,11 +216,22 @@ class TestCodeRewardUsesSandbox:
     def test_hanging_solution_times_out(self):
         assert self._grade("while True: pass") is False
 
-    def test_jail_cleaned_up(self, tmp_path):
+    def test_jail_cleaned_up(self, tmp_path, monkeypatch):
         before = set(os.listdir(tmp_path.parent))
+        # The jail of THIS grade: `/tmp` at large also holds the jails of
+        # grades in flight in other workers, and that of a run that was
+        # killed in one (`/tmp/areal_grade_nrzlpuse`, the driver's run of
+        # PR 61, failed this case in every run after it).
+        jails, make = [], tempfile.TemporaryDirectory
+
+        def watched(**kw):
+            jails.append(make(**kw))
+            return jails[-1]
+
+        monkeypatch.setattr(tempfile, "TemporaryDirectory", watched)
         self._grade("open('leftover','w').write('x'); print(9)")
         # The jail tmpdir (and anything the program wrote) is gone.
-        assert not [
-            d for d in os.listdir("/tmp") if d.startswith("areal_grade_")
-        ]
+        (jail,) = jails
+        assert os.path.basename(jail.name).startswith("areal_grade_")
+        assert not os.path.exists(jail.name)
         assert set(os.listdir(tmp_path.parent)) == before

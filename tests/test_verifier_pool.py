@@ -107,7 +107,11 @@ class TestMembership:
 
 class TestPooledGrading:
     def test_round_trip_through_one_worker(self, worker):
-        pool = VerifierPool(servers={"w": worker.url})
+        # One bounded attempt (the default is three of 60 s): a worker
+        # that cannot answer fails `graded_local == 0` below in 20 s.
+        pool = VerifierPool(
+            servers={"w": worker.url}, attempt_timeout_s=20.0, max_attempts=1
+        )
         assert pool.verify_batch([MATH_OK, MATH_BAD]) == [True, False]
         assert pool.graded_pooled == 2 and pool.graded_local == 0
         assert worker.graded == 2
