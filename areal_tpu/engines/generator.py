@@ -50,7 +50,7 @@ from areal_tpu.base.topology import batch_sharding_degree
 from areal_tpu.engines.offload import HostOffloadMixin
 from areal_tpu.engines.packing import decode_bucket_len as bucket_len
 from areal_tpu.engines.paging import PageAllocator, PagePoolExhausted
-from areal_tpu.models import transformer as tfm
+from areal_tpu.models import mamba, transformer as tfm
 from areal_tpu.models.branches import LoopStep
 from areal_tpu.models.config import ModelConfig
 from areal_tpu.ops.sampling import sample_token
@@ -125,7 +125,7 @@ def _new_state_stats() -> Dict[str, int]:
         "ssm_live_slot_chunks": 0, "ssm_slots_zeroed": 0,
         "ssm_prefix_would_share": 0, "ssm_lanes_decode": 0,
         "ssm_lanes_prefill": 0, "ssm_slot_steps_live": 0,
-        "ssm_interrupts_drained": 0,
+        "ssm_lanes_made": 0, "ssm_interrupts_drained": 0,
     }
 
 
@@ -1212,6 +1212,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                     ss["ssm_lanes_decode"] += int(lane_acc[4])
                     ss["ssm_lanes_prefill"] += int(lane_acc[5])
                     ss["ssm_slot_steps_live"] += int(lane_acc[6])
+                    ss["ssm_lanes_made"] += int(lane_acc[7])
 
                 # Register prefixes that FINISHED prefilling this chunk,
                 # before any retirement below can release the owner's pages:
@@ -1564,6 +1565,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             return self._gen_fns[sig]
         cfg = self.cfg
         has_state = self._has_state
+        slab_kernel = has_state and mamba.slab_kernel_form(cfg, paged_kernel)
         eos = self.eos_token_id
         # A spec row can emit up to K+1 tokens per inner step, plus one
         # fresh first token the step it leaves prefill.
@@ -1586,8 +1588,9 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             # recurrence took, by kind: (decode, prefill), and the (slot,
             # inner step) pairs in which the slot held a lane — the state
             # tiles the recurrence has to step; a slot without one is
-            # skipped (`ops/pallas/ssm_slab.py`).
-            lane_acc = jnp.zeros((7 if has_state else 4,), jnp.int32)
+            # skipped (`ops/pallas/ssm_slab.py`) — and the slab lanes a
+            # layer's chunk terms were made for (`mamba.slab_lanes_made`).
+            lane_acc = jnp.zeros((8 if has_state else 4,), jnp.int32)
             rows = jnp.arange(n_slots)
             lanes = jnp.arange(Wmax)
             lane_ids = jnp.arange(T)
@@ -1689,6 +1692,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                     jnp.sum(jnp.where(is_pref, 0, c)),
                     jnp.sum(jnp.where(is_pref, c, 0)),
                     jnp.sum((c > 0).astype(jnp.int32)),
+                    mamba.slab_lanes_made(c, Wmax, slab_kernel),
                 ] if has_state else []))
                 # Per-row lane-token slab, gathered into the stream.
                 idx = jnp.minimum(

@@ -40,19 +40,22 @@ Four forms of the same recurrence:
   halo.  A slot whose first lane is at position 0 starts from ZERO state
   and tail (a reused slot never sees its previous request); a slot with no
   lane, and every dead lane, leaves state and tail bit-identical.  A
-  decoding slot is the one-lane case of the same code.  The two places
-  that TOUCH the carried state — the lanes' read C . S0 and the rewrite
-  S_new — run, on a TPU backend on one device where a head's [P, N] tile
-  is whole (8, 128) tiles, as ONE Pallas kernel (`ops/pallas/ssm_slab.py`,
-  `ssd_slab_in_place`): the layer's buffer aliased to itself, a live
-  slot's tiles read once and rewritten where they lie, a slot with no
-  lane not visited at all.  Elsewhere (off a TPU, on a mesh, other
-  widths) they are the two `einsum`s of `ssd_slab`, the kernel's oracle,
-  and `where(held, new, state)` keeps the slots without a lane.  The
-  conv, the cumulative decays, the chunk's own lower triangle
-  (`slab_terms`), the D skip and the gather back to the stream are `jnp`
-  in both.  `ssm_step`, the static program's decode step, keeps its XLA
-  fusion: it already reads the state once.
+  decoding slot is the one-lane case of the same code.  On a TPU backend
+  on one device, where a head's [P, N] tile is whole (8, 128) tiles
+  (`slab_kernel_form`), the whole chunk a slot is ONE Pallas kernel
+  (`ops/pallas/ssm_slab.py`, `ssd_slab_in_place`): the layer's buffer
+  aliased to itself, a live slot's tiles read once and rewritten where
+  they lie, and the chunk's terms — dt x, the cumulative decays, C B^T,
+  the chunk's own lower triangle and the D skip — made in the same grid
+  step from x, B and C as the conv left them, for the slots that hold a
+  lane alone; a slot with no lane is not visited at all and costs
+  neither bytes nor terms.  Elsewhere (off a TPU, on a mesh, other
+  widths) the terms are `slab_terms` over the whole [n_slots, W] slab,
+  the state's part the two `einsum`s of `ssd_slab`, the kernel's oracle,
+  `where(held, new, state)` keeps the slots without a lane, and the D
+  skip is `jnp`.  The conv and the gather of y back to the stream are
+  `jnp` in both.  `ssm_step`, the static program's decode step, keeps its
+  XLA fusion: it already reads the state once.
 
 Packed rows: S and the conv restart at every segment start — the decay
 across a segment boundary is zero, the carried state is dropped for every
@@ -476,37 +479,63 @@ def _to_stream(v: jax.Array, lanes: "SlotLanes") -> jax.Array:
 
 
 def ssd_slab_in_place(
-    x, dt, a, bm, cm,  # as `ssd_slab`
+    conv: jax.Array,  # [R, W, H P + 2 G N] fp32: x | B | C (`_split_conv`)
+    dt, a,  # as `ssd_slab`
+    d: jax.Array,  # [H]: the skip's weight a head
     states: jax.Array,  # [steps, R, H, P, N] fp32: the layer's whole buffer
     li,  # the scan step that steps
     lanes: "SlotLanes",
     block_h: int = 0,  # heads a grid step (0: the kernel's own choice)
 ) -> Tuple[jax.Array, jax.Array]:
-    """`ssd_slab` with the state's part on the Pallas kernel
-    `ssm_slab.ssm_slab_step` -> (y of the STREAM's lanes [T, H, P],
-    states): step `li` of the slots that hold a lane read once and
-    rewritten where it lies, no other byte of the buffer touched.  The
-    kernel's read of the state comes back to the stream BEFORE it is
-    scaled and added: the slab has R x W lanes, the stream a fifth of
-    them, and the rows of a slot without a lane, which the kernel never
-    writes, are then never read either."""
+    """`ssd_slab` and the D skip as ONE call of the Pallas kernel
+    `ssm_slab.ssm_slab_step` -> (y of the STREAM's lanes [T, H P] with the
+    skip in it, states): step `li` of the slots that hold a lane read once
+    and rewritten where it lies, no other byte of the buffer touched, and
+    the chunk's terms (`slab_terms`' part) made inside the kernel for
+    those slots alone — the conv's rows go in as they are (the kernel
+    reads x, B and C where they lie in them), and the finished y comes
+    back to the stream.  The rows of a slot without a lane, which the
+    kernel never writes, are never read either."""
     from areal_tpu.ops.pallas import ssm_slab
 
-    r, w, h, p = x.shape
-    y, w_in, xw, s_keep = slab_terms(
-        x, dt, a, bm, cm, 1.0 - lanes.fresh.astype(jnp.float32))
-    states, y_raw = ssm_slab.ssm_slab_step(
-        states, li, lanes.live, lanes.n_live,
-        jnp.swapaxes(cm, 1, 2), jnp.swapaxes(bm, 1, 2),
-        xw.reshape(r, w, h * p), s_keep.reshape(r, h), block_h=block_h,
+    states, y = ssm_slab.ssm_slab_step(
+        states, li, lanes.live, lanes.n_live, conv, dt, a, d,
+        1.0 - lanes.fresh.astype(jnp.float32), block_h=block_h,
     )
     # A dead lane of the stream is clipped onto some slot's lane, written
-    # or not: what lies there may not be a number to scale.
-    y_raw = jnp.where(
-        _to_stream(lanes.valid, lanes)[:, None],
-        _to_stream(y_raw, lanes), 0.0).reshape(-1, *y.shape[2:])
-    y = _to_stream(y, lanes) + y_raw * _to_stream(w_in, lanes)[..., None]
-    return y.reshape(-1, h, p), states
+    # or not: what lies there may not be a number.
+    y = jnp.where(
+        _to_stream(lanes.valid, lanes)[:, None], _to_stream(y, lanes), 0.0)
+    return y, states
+
+
+def slab_kernel_form(cfg: ModelConfig, kernel: Optional[bool] = None) -> bool:
+    """Which form `ssm_ragged` takes for the chunk a slot, by what the code
+    can see: the Pallas kernel on a TPU backend (`kernel` None; a bool
+    forces either, a caller on a mesh passes False) where a head's [P, N]
+    tile is whole (8, 128) tiles and the heads cut into blocks
+    (`ssm_slab.fits`); the `jnp` `ssd_slab` elsewhere."""
+    from areal_tpu.base.distributed import is_tpu_backend
+    from areal_tpu.ops.pallas import ssm_slab
+
+    if kernel is None:
+        kernel = is_tpu_backend()
+    return bool(kernel) and ssm_slab.fits(
+        cfg.ssm_n_heads, cfg.ssm_n_groups, cfg.ssm_head_dim, cfg.ssm_state_dim)
+
+
+def slab_lanes_made(
+    count: jax.Array,  # [R] int32: each slot's lanes this inner step
+    width: int,  # W
+    kernel_form: bool,
+) -> jax.Array:
+    """Slab lanes for which one layer's inner step computes the chunk's
+    terms (the serving chunk's counter `ssm_lanes_made`): the kernel makes
+    them for the W lanes of each slot that holds one, `slab_terms` for
+    every lane of the [R, W] slab."""
+    if kernel_form:
+        return width * jnp.sum(count > 0, dtype=jnp.int32)
+    return jnp.int32(count.shape[0] * width)
 
 
 @jax.named_scope("layer/ssm")
@@ -527,26 +556,21 @@ def ssm_ragged(
     (`transformer.PagedKVCache`); they come in and go out whole so that
     the state's read and write lie under this scope, as in `ssm_step`.
 
-    The state's part of the chunk — the lanes' read of S0 and its rewrite
-    — takes one of two forms, picked by what the code can see: on a TPU
-    backend, where a head's [P, N] tile is whole (8, 128) tiles
-    (`ssm_slab.fits`), the Pallas kernel `ssm_slab_step`
+    The chunk a slot takes one of two forms, picked by what the code can
+    see (`slab_kernel_form`): on a TPU backend, where a head's [P, N] tile
+    is whole (8, 128) tiles, the Pallas kernel `ssm_slab_step`
     (`ssd_slab_in_place`: the buffer aliased to itself, a live slot's
-    tiles read once and rewritten where they lie, a slot with no lane
-    never touched); elsewhere `ssd_slab`, the kernel's oracle, whose new
+    tiles read once and rewritten where they lie, the chunk's terms made
+    inside for the live slots alone, a slot with no lane never touched);
+    elsewhere `slab_terms` + `ssd_slab`, the kernel's oracle, whose new
     state is written back under `where(held, new, state)`.  `kernel`: None,
     that choice; a bool forces either where the shapes fit (interpreted
     off a TPU) — a caller on a mesh passes False, the kernel is one
-    device's program.  The conv, the chunk's own lower triangle, the D
-    skip and the gather back to the stream are `jnp` in both."""
-    from areal_tpu.base.distributed import is_tpu_backend
-    from areal_tpu.ops.pallas import ssm_slab
-
+    device's program.  The conv and the gather back to the stream are
+    `jnp` in both; the D skip is inside the kernel and `jnp` beside
+    `ssd_slab`."""
     kk = cfg.ssm_conv_kernel
-    if kernel is None:
-        kernel = is_tpu_backend()
-    kernel = kernel and ssm_slab.fits(
-        cfg.ssm_n_heads, cfg.ssm_n_groups, cfg.ssm_head_dim, cfg.ssm_state_dim)
+    kernel = slab_kernel_form(cfg, kernel)
     with jax.named_scope("in_proj"):
         z, xbc, dt = _split_in(h @ blk["ssm_in"], cfg)
     with jax.named_scope("ssm_ragged"):
@@ -571,13 +595,14 @@ def ssm_ragged(
                 li, axis=0,
             )
         with jax.named_scope("ssd_scan"):
-            x, bm, cm = _split_conv(conv, cfg)  # [R, W, H, P], [R, W, G, N]
             dts, a = _dt_a(dt[lanes.idx], blk)
             dts = jnp.where(lanes.valid[..., None], dts, 0.0)
+            skip = blk["ssm_D"].astype(jnp.float32)
             if kernel:
                 y, states = ssd_slab_in_place(
-                    x, dts, a, bm, cm, states, li, lanes)
+                    conv, dts, a, skip, states, li, lanes)
             else:
+                x, bm, cm = _split_conv(conv, cfg)  # [R, W, H, P], [.., G, N]
                 state = jax.lax.dynamic_index_in_dim(
                     states, li, axis=0, keepdims=False)
                 y, new = ssd_slab(
@@ -589,9 +614,8 @@ def ssm_ragged(
                     jnp.where(held[:, None, None, None], new, state),
                     li, axis=0,
                 )
-                y = _to_stream(y, lanes)
-            y = y + blk["ssm_D"].astype(jnp.float32)[:, None] * _to_stream(
-                x, lanes)
+                y = _to_stream(y, lanes) + skip[:, None] * _to_stream(
+                    x, lanes)
         y = y.reshape(h.shape[0], cfg.ssm_inner_dim)
     return _out(y, z, blk, cfg), states, tails
 

@@ -16,7 +16,18 @@ bfloat16).
     chiprun -- python3 scripts/granite_controls.py [n_tokens]
 
 Writes chiprun_out/granite_controls.json; prints one line a reading with
-the limits beside it.  A reading is evidence only from a TPU run."""
+the limits beside it.  A reading is evidence only from a TPU run.
+
+(3) `--scope-split [--seed N]`: ONE traced run of
+`granite4hm-serving-waves` (`benchmark.run` unchanged, `--trace 1`) whose
+reduced trace is also written, by operation, to
+chiprun_out/granite_scope_split.json: every device operation under
+`gen/serving_chunk/.../layer/ssm` in milliseconds an inner step, summed by
+the mixer's scopes (`in_proj`, `ssm_ragged/conv`, `ssm_ragged/ssd_scan`,
+`out_norm_proj`) and, inside `ssd_scan`, the kernel `ssm_slab_step` apart
+from the `jnp` operations around it (the gathers to and from the slab, the
+decays and `keep`, the D skip; before PR 63 `slab_terms`' fusions too), each
+by its HLO name — what PERF.md section 5 item 0g quotes."""
 import json
 import os
 import sys
@@ -35,7 +46,62 @@ from benchmark.references.qwen3_next import state_readings  # noqa: E402
 from benchmark.run import model_config  # noqa: E402
 
 
+def scope_split(argv):
+    """`--scope-split`: the cell traced, the mixer's operations dumped."""
+    from benchmark import run as bench_run
+    from benchmark.metrics import _ssmd
+
+    seed = argv[argv.index("--seed") + 1] if "--seed" in argv else "6300001"
+    check_run, runs = bench_run.checks.check_run, []
+    # The harness hands its `Run` (steps, reduced trace) to the checks.
+    bench_run.checks.check_run = lambda run: runs.append(run) or check_run(run)
+    rehearsal = ["--cpu-rehearsal"] if "--cpu-rehearsal" in argv else []
+    rc = bench_run.main([
+        "--workload", "granite4hm-serving-waves", "--seed", seed,
+        "--seconds", "45", "--trace", "1"] + rehearsal)
+    run = runs[-1]
+    if not run.trace:  # a CPU rehearsal's trace has no device planes
+        print("scope split: no device trace to split", flush=True)
+        return rc
+    inner = _ssmd.inner_steps(run) * run.trace["traced_steps"]
+    ops = {
+        name: 1e3 * s / inner
+        for name, s in run.trace["op_seconds_scoped"].items()
+        if "gen/serving_chunk" in name and "/layer/ssm" in name}
+    parts = ("in_proj", "ssm_ragged/conv", "ssm_ragged/ssd_scan",
+             "out_norm_proj")
+    by_part = {
+        part: sum(ms for name, ms in ops.items() if f"/{part}" in name)
+        for part in parts}
+    scan = {n: ms for n, ms in ops.items() if "/ssm_ragged/ssd_scan" in n}
+    kernel = sum(ms for n, ms in scan.items() if "ssm_slab_step" in n)
+    out = {
+        "seed": int(seed), "inner_steps_traced": inner,
+        "layer_ssm_ms": sum(ops.values()), "by_scope_ms": by_part,
+        "ssd_scan_kernel_ms": kernel,
+        "ssd_scan_around_kernel_ms": by_part["ssm_ragged/ssd_scan"] - kernel,
+        "pool_last_step": {
+            k: v for k, v in run.steps[-1].get("pool", {}).items()
+            if k.startswith("ssm_") or k == "chunks"},
+        "ssd_scan_ops_ms": dict(sorted(scan.items(), key=lambda kv: -kv[1])),
+        "other_ops_ms": dict(sorted(
+            ((n, ms) for n, ms in ops.items() if n not in scan),
+            key=lambda kv: -kv[1])),
+    }
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/granite_scope_split.json", "w") as f:
+        json.dump(out, f, indent=1)
+    print("scope split, ms an inner step:", json.dumps(
+        {k: v for k, v in out.items() if not k.endswith("ops_ms")}),
+        flush=True)
+    for name, ms in list(out["ssd_scan_ops_ms"].items())[:24]:
+        print(f"  {ms:8.4f}  {name}", flush=True)
+    return rc
+
+
 def main():
+    if "--scope-split" in sys.argv:
+        return scope_split(sys.argv[1:])
     n = int(sys.argv[1]) if len(sys.argv) > 1 else 194
     config = files.load_json("configs", "granite-4.0-h-micro-l10.json")
     cfg = model_config(config)

@@ -486,14 +486,15 @@ def test_the_chunk_on_the_state_kernel_equals_the_chunk_on_the_jnp_form(
     """Six requests over four slots (two waves: two slots serve a second
     request from position 0), prompts in slices of W = 4, greedy: the
     serving chunk with the state's part of the recurrence on the Pallas
-    kernel `ssm_slab_step` (interpreted; a state of 128 columns so that a
-    head's tile fits it) against the chunk on `ssd_slab` — same tokens,
+    kernel `ssm_slab_step` (interpreted; a state of 128 columns and eight
+    heads of 16 channels, so that a head's tile is whole and a block of x
+    a whole lane tile of the conv's row) against the chunk on `ssd_slab` — same tokens,
     log-probs and what every slot is left with; the kernel is what ran;
     and the counter of (slot, inner step) pairs with a lane equals the
     host's count of the same run."""
     from areal_tpu.ops.pallas import ssm_slab
 
-    cfg = _cfg(ssm_state_dim=128)
+    cfg = _cfg(ssm_state_dim=128, ssm_n_heads=8)
     assert ssm_slab.fits(
         cfg.ssm_n_heads, cfg.ssm_n_groups, cfg.ssm_head_dim, cfg.ssm_state_dim)
     params = _params(cfg, seed=8)
@@ -535,6 +536,36 @@ def test_the_chunk_on_the_state_kernel_equals_the_chunk_on_the_jnp_form(
     assert stats["ssm_lanes_prefill"] == sum(lens)
     assert stats["ssm_slot_steps_live"] == sum(
         -(-n // width) + new for n in lens)
+
+
+@pytest.mark.parametrize("kernel", [True, False], ids=["kernel", "jnp"])
+def test_the_chunk_counts_the_slab_lanes_its_terms_were_made_for(
+        kernel, monkeypatch):
+    """`ssm_lanes_made` of a toy chunk (six requests over four slots, W =
+    4, the stream 16 lanes), a layer's inner step: the kernel makes the
+    chunk's terms for the W lanes of each slot that holds a lane, the
+    `jnp` form for every lane of the [4, W] slab whoever holds one."""
+    cfg = _cfg(ssm_state_dim=128, ssm_n_heads=8)
+    params = _params(cfg, seed=8)
+    lens, new, width, slots = (21, 9, 17, 12, 5, 14), 10, 4, 4
+    prompts = _sequences(cfg, lens=lens, seed=13)
+    g = GenerationHyperparameters(n=1, max_new_tokens=new, greedy=True)
+    monkeypatch.setattr(
+        mamba, "slab_kernel_form", lambda cfg_, kernel_=None: kernel)
+    eng = _engine(cfg, params, slots=slots, prefill_chunk_tokens=width)
+    eng.serving_rollout(prompts, g, jax.random.PRNGKey(0))
+    stats = eng.last_pool_stats
+    live = sum(-(-n // width) + new for n in lens)
+    assert stats["ssm_slot_steps_live"] == live
+    lanes = stats["ssm_lanes_decode"] + stats["ssm_lanes_prefill"]
+    assert lanes == len(lens) * new + sum(lens)
+    if kernel:
+        assert stats["ssm_lanes_made"] == width * live
+    else:
+        inner = eng.lanes_dispatched // (slots * width)
+        assert eng.lanes_dispatched == inner * slots * width
+        assert stats["ssm_lanes_made"] == inner * slots * width
+    assert stats["ssm_lanes_made"] >= lanes
 
 
 # ----------------------------------------------------------------- refusals

@@ -1,7 +1,7 @@
 """The Pallas kernel of the serving plane's Mamba-2 recurrence
-(`ops/pallas/ssm_slab.py`: the state's part of a slab step), interpreted on
-the CPU, against the `jnp` form it takes the place of on a TPU backend
-(`mamba.ssd_slab`) — and the serving chunk's inner loop compiled for a
+(`ops/pallas/ssm_slab.py`: one short SSD chunk a live slot, its terms made
+inside and its state stepped in place), interpreted on the CPU, against the
+`jnp` form it takes the place of on a TPU backend (`mamba.ssd_slab`) — and the serving chunk's inner loop compiled for a
 described v5e at the cell's size."""
 
 import jax
@@ -40,9 +40,30 @@ def _slab(counts, fresh, w, h, g, p, steps=3, seed=0, n=N):
     return (x, dt, a, bm, cm), states, lanes
 
 
+def _rows(x, bm, cm):
+    """x | B | C as the conv leaves them: [R, W, H P + 2 G N]."""
+    r, w = x.shape[:2]
+    return jnp.concatenate(
+        [v.reshape(r, w, -1) for v in (x, bm, cm)], axis=-1)
+
+
+def _skip(h, seed=0):
+    """D [H], far from one and from zero."""
+    return 0.5 + jax.random.uniform(jax.random.PRNGKey(100 + seed), (h,))
+
+
+def _in_place(ops, states, li, lanes, block_h=0, d=None):
+    """-> (y [T, H, P], states); `d` None: no skip (D = 0)."""
+    x, dt, a, bm, cm = ops
+    h, p = x.shape[2:]
+    y, states = mamba.ssd_slab_in_place(
+        _rows(x, bm, cm), dt, a, jnp.zeros((h,)) if d is None else d,
+        states, li, lanes, block_h=block_h)
+    return y.reshape(-1, h, p), states
+
+
 def _check(ops, states, lanes, li, block_h=0):
-    got_y, got_states = mamba.ssd_slab_in_place(
-        *ops, states, li, lanes, block_h=block_h)
+    got_y, got_states = _in_place(ops, states, li, lanes, block_h)
     got_y = got_y.reshape(ops[0].shape)
     want_y, want_state = mamba.ssd_slab(
         *ops, states[li], 1.0 - lanes.fresh.astype(jnp.float32))
@@ -97,20 +118,95 @@ def test_the_kernel_steps_the_live_slots_in_place_as_the_jnp_form_does(
     _check(ops, states, lanes, li, block_h)
 
 
+@pytest.mark.parametrize("counts,fresh,w,h,g,p,block_h", [
+    # one lane, three and W in one slab, all carried; the cell's W
+    ((1, 3, 8), (False,) * 3, 8, 2, 1, 64, 0),
+    # the same lane counts, a fresh slot among carried ones
+    ((3, 1, 8, 1), (False, True, False, False), 8, 2, 1, 64, 0),
+    # every live slot fresh, slots with no lane around and between them
+    ((0, 8, 0, 1, 3, 0), (False, True, False, True, True, False),
+     8, 2, 1, 64, 0),
+    # two groups: a block reads its own group's B and C
+    ((1, 3, 4, 0), (False, False, True, False), 4, 4, 2, 64, 0),
+    ((4, 0, 1, 3), (True, False, False, False), 4, 8, 2, 64, 2),
+    # block_h at its smallest (one head of 128 channels, two of 64) and
+    # at its largest (every head)
+    ((1, 0, 3, 8), (False,) * 4, 8, 4, 1, 128, 1),
+    ((1, 0, 3, 4), (False, False, True, False), 4, 8, 1, 64, 2),
+    ((1, 0, 3, 4), (False, False, True, False), 4, 8, 1, 64, 8),
+], ids=lambda x: str(x).replace(" ", ""))
+def test_the_kernel_makes_the_chunks_terms_for_the_live_slots_alone(
+        counts, fresh, w, h, g, p, block_h):
+    """`ssm_slab_step` itself, from the operands as they are gathered to
+    the slab (x, dt, A, B, C, D and the fresh flags: no `slab_terms`),
+    against `ssd_slab` + D x: the finished y of every lane a slot holds and
+    the state it leaves.  A slot with no lane is poisoned with NaN in every operand and
+    in its state: it is never read, its state keeps its bits, and no live
+    slot's numbers see it."""
+    (x, dt, a, bm, cm), states, lanes = _slab(
+        counts, fresh, w, h, g, p, seed=sum(counts) + h)
+    r, li = len(counts), 1
+    held = np.asarray(counts) > 0
+    carried = 1.0 - lanes.fresh.astype(jnp.float32)
+    want_y, want_state = mamba.ssd_slab(x, dt, a, bm, cm, states[li], carried)
+    d = _skip(h, seed=r)
+    want_y = want_y + d[:, None] * x  # the skip is the kernel's too
+    nan = jnp.asarray(~held)
+
+    def poisoned(v):
+        return jnp.where(nan.reshape((r,) + (1,) * (v.ndim - 1)), jnp.nan, v)
+
+    states = states.at[li].set(poisoned(states[li]))
+    got_states, got_y = ssm_slab.ssm_slab_step(
+        states, li, lanes.live, lanes.n_live, poisoned(_rows(x, bm, cm)),
+        poisoned(dt), a, d, carried, block_h=block_h)
+    live = np.asarray(lanes.valid)
+    got_y = np.asarray(got_y).reshape(r, w, h, p)
+    scale = float(jnp.max(jnp.abs(want_y)))
+    np.testing.assert_allclose(
+        got_y[live] / scale, np.asarray(want_y)[live] / scale, **TOL)
+    np.testing.assert_allclose(
+        np.asarray(got_states[li])[held], np.asarray(want_state)[held], **TOL)
+    assert np.isnan(np.asarray(got_states[li])[~held]).all()
+    for j in (0, 2):
+        np.testing.assert_array_equal(got_states[j], states[j])
+
+
+def test_the_stream_never_reads_the_rows_a_dead_lane_maps_to(monkeypatch):
+    """A dead lane of the stream is clipped onto some slot's row of the
+    slab's y, written or not: with the rows the kernel did not write (a
+    slot with no lane) poisoned with NaN, the stream's y is the live
+    lanes' numbers and zero at every dead lane."""
+    ops, states, lanes = _slab((3, 0, 1, 0), (False,) * 4, 4, 2, 1, 64, seed=5)
+    step = ssm_slab.ssm_slab_step
+
+    def poisoned_step(*args, **kw):
+        new, y = step(*args, **kw)
+        return new, jnp.where(
+            (lanes.count > 0)[:, None, None], y, jnp.nan)
+
+    want, _ = _in_place(ops, states, 0, lanes)
+    monkeypatch.setattr(ssm_slab, "ssm_slab_step", poisoned_step)
+    got, _ = _in_place(ops, states, 0, lanes)
+    np.testing.assert_array_equal(got, want)
+    dead = ~np.asarray(lanes.valid).reshape(-1)
+    assert dead.sum() == 12 and not np.asarray(got)[dead].any()
+
+
 def test_a_fresh_slot_ignores_the_state_it_holds():
     """Two runs that differ in a fresh slot's old state alone leave the
     same new state and y there (and the other slot's as they were)."""
     ops, states, lanes = _slab((2, 3), (True, False), 4, 2, 1, 64, seed=3)
     _, got = _check(ops, states, lanes, 1)
     other = states.at[1, 0].set(7.0 * states[1, 0] + 1.0)
-    _, got2 = mamba.ssd_slab_in_place(*ops, other, 1, lanes)
+    _, got2 = _in_place(ops, other, 1, lanes)
     np.testing.assert_array_equal(got2[1], got[1])
 
 
 def test_a_step_with_no_live_slot_changes_nothing():
     ops, states, lanes = _slab((0, 0, 0), (False,) * 3, 4, 4, 1, 64, seed=4)
     assert int(lanes.n_live) == 0
-    y, got = mamba.ssd_slab_in_place(*ops, states, 1, lanes)
+    y, got = _in_place(ops, states, 1, lanes)
     np.testing.assert_array_equal(got, states)
     assert not np.asarray(y).any()
 
@@ -128,10 +224,14 @@ def test_block_sizes_and_the_widths_the_kernel_takes():
     assert ssm_slab.block_h_for(64, 1, 64) == 32  # the cell's: two a slot
     assert ssm_slab.block_h_for(6, 1, 64) == 6
     assert ssm_slab.block_h_for(8, 2, 64) == 4  # a block within a group
-    assert ssm_slab.block_h_for(4, 1, 16) == 4  # every head: the full width
-    assert ssm_slab.block_h_for(4, 2, 16) == 0  # no block of whole lanes
+    assert ssm_slab.block_h_for(8, 1, 16) == 8  # 8 heads fill the lanes
+    # A block of x is a run of whole lane tiles of the conv's rows:
+    assert ssm_slab.block_h_for(4, 1, 16) == 0
+    assert ssm_slab.block_h_for(4, 2, 16) == 0
     assert ssm_slab.fits(64, 1, 64, 128) and ssm_slab.fits(128, 8, 64, 128)
-    assert ssm_slab.fits(4, 1, 16, 128)
+    assert ssm_slab.fits(8, 1, 16, 128) and not ssm_slab.fits(4, 1, 16, 128)
+    # B and C lie behind x at whole N-column blocks of the rows
+    assert not ssm_slab.fits(2, 1, 64, 256) and ssm_slab.fits(4, 1, 64, 256)
     assert not ssm_slab.fits(4, 1, 16, 16)  # the toy's N
     assert not ssm_slab.fits(4, 1, 12, 128)  # P is not whole sublanes
     assert not ssm_slab.fits(4, 2, 16, 128)
@@ -152,7 +252,7 @@ def test_ssm_ragged_takes_the_kernel_where_it_is_told_to_and_it_fits(
     cfg = ModelConfig(
         n_layers=1, hidden_dim=32, n_q_heads=2, n_kv_heads=2, head_dim=16,
         intermediate_dim=64, vocab_size=64, window_pattern="M",
-        ssm_n_heads=4, ssm_head_dim=16, ssm_state_dim=n, pos_emb="none",
+        ssm_n_heads=8, ssm_head_dim=16, ssm_state_dim=n, pos_emb="none",
         param_dtype="float32")
     keys = jax.random.split(jax.random.PRNGKey(2), 4)
     blk = jax.tree.map(lambda v: v[0], mamba.init_ssm(
@@ -167,7 +267,7 @@ def test_ssm_ragged_takes_the_kernel_where_it_is_told_to_and_it_fits(
     assert lanes.live.tolist() == [0, 2, 3, 3] and int(lanes.n_live) == 3
     h = jax.random.normal(keys[1], (t, cfg.hidden_dim))
     states = jax.random.normal(
-        keys[2], (2, slots, 4, 16, n), jnp.float32)
+        keys[2], (2, slots, 8, 16, n), jnp.float32)
     tails = jax.random.normal(
         keys[3], (2, slots, cfg.ssm_conv_kernel - 1, cfg.ssm_conv_dim))
     calls = []
