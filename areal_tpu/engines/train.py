@@ -33,7 +33,7 @@ from areal_tpu.base.distributed import is_primary, to_host
 from areal_tpu.engines import packing
 from areal_tpu.engines.offload import HostOffloadMixin
 from areal_tpu.models import transformer as tfm
-from areal_tpu.models.config import FROZEN_LEAVES, ModelConfig
+from areal_tpu.models.config import DENSE_PREFIX, FROZEN_LEAVES, ModelConfig
 from areal_tpu.parallel import realloc, sharding
 
 logger = logging.getLogger("train_engine")
@@ -85,12 +85,13 @@ def _trainable_mask(params):
     `FROZEN_LEAVES`; None where the model has none (every family but the
     sigmoid-routed one), so their optimizer is what it was."""
     blocks = params.get("blocks", {})
-    if not any(n in blocks for n in FROZEN_LEAVES):
+    frozen = [  # a leading layer's leaf is its layer's own under `dense_`
+        n for n in blocks if n.removeprefix(DENSE_PREFIX) in FROZEN_LEAVES]
+    if not frozen:
         return None
     mask = jax.tree.map(lambda _: True, params)
-    for n in FROZEN_LEAVES:
-        if n in blocks:
-            mask["blocks"][n] = False
+    for n in frozen:
+        mask["blocks"][n] = False
     return mask
 
 

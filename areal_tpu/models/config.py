@@ -17,7 +17,12 @@ DENSE_PREFIX = "dense_"
 # Block leaves no gradient reaches and no optimizer moves (the sigmoid
 # router's choice bias): the train engine keeps no moment for them and the
 # hand-back returns them as they were.
-FROZEN_LEAVES = ("router_bias",)
+FROZEN_LEAVES = (
+    "router_bias",
+    # the token indexer of a full latent layer (`models/latent_select.py`):
+    # its selection carries no gradient and the RL loss has no term for it
+    "idx_q", "idx_k", "idx_w", "idx_k_norm", "idx_k_norm_b",
+)
 
 # The residual branches x += f(norm(x)) a layer is made of: softmax attention
 # over per-head K/V, the same over the last `attn_window` keys alone, latent
@@ -29,6 +34,10 @@ ATTENTION, WINDOW, LATENT, GDN, SSM, SCONV, MLP, MOE = (
     "attention", "window", "latent", "gdn", "ssm", "sconv", "mlp", "moe",
 )
 SPARSE, LIGHTNING = "sparse", "lightning"
+# Latent attention by `window_pattern` (dots3_note): a full layer whose
+# queries read the `index_topk` latent rows a learned indexer SELECTS, and
+# a window layer in a latent geometry of its own over a ring of rows.
+LATENT_SELECT, LATENT_WINDOW = "latent_select", "latent_window"
 # One character of `layer_pattern` -> that layer's ONE branch.
 _PATTERN_KINDS = {"M": (SSM,), "E": (MOE,), "*": (ATTENTION,)}
 # One character of `window_pattern` -> that layer's mixer.
@@ -36,6 +45,8 @@ _WINDOW_KINDS = {
     "S": WINDOW, "F": ATTENTION, "C": SCONV, "M": SSM, "B": SPARSE,
     "L": LIGHTNING,
 }
+# The same characters where the plan's attention is latent.
+_LATENT_WINDOW_KINDS = {"S": LATENT_WINDOW, "F": LATENT_SELECT}
 
 LayerKind = Tuple[str, ...]  # a layer's branches, in order
 
@@ -297,6 +308,40 @@ class ModelConfig:
     # head over y and a sigmoid output gate.
     lightning_n_heads: int = 0
     lightning_head_dim: int = 0
+    # ---- latent attention in two geometries (dots3_note) ----
+    # With `window_pattern` and `kv_lora_rank`: an "F" layer is latent
+    # attention at the sizes above (q/k heads `head_dim` = nope + rope wide,
+    # v heads `v_head_dim`, which may differ) and an "S" layer latent
+    # attention at the `swa_*` sizes over the last `attn_window` keys, its
+    # cache a ring of latent rows, its rope `window_rope_theta`.
+    # `index_topk` > 0: an "F" layer's query attends over the `index_topk`
+    # visible keys of largest index score alone (ties to the lower
+    # position): I[t, s] = sum_j w[t, j] relu(qI_j[t] . kI[s]) over
+    # `index_n_heads` heads of `index_head_dim`, qI from the query's latent,
+    # kI a LayerNormed projection of the layer's input, both roped on their
+    # first `qk_rope_head_dim` columns; the cache keeps kI beside the latent
+    # row.  The indexer takes no gradient (`FROZEN_LEAVES`).
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    # One sigmoid gate a HEAD on the attention output before `o_proj`, a
+    # projection of the layer's normed input (`attn_gate`: one an element).
+    attn_gate_headwise: bool = False
+    # The normed query and key/value latents are multiplied by sqrt(hidden /
+    # rank) (`apply_mla_qkv_lora_rescale`); the shared rope key is not.
+    latent_rescale: bool = False
+    swa_n_heads: int = 0
+    swa_q_lora_rank: int = 0
+    swa_kv_lora_rank: int = 0
+    swa_qk_nope_head_dim: int = 0
+    swa_qk_rope_head_dim: int = 0
+    swa_v_head_dim: int = 0
+    # One tensor-parallel rank's share of the HEADS: `n_q_heads` and
+    # `swa_n_heads` are the heads HELD, of `head_share` times as many; the
+    # low-rank down-projections, their norms and the indexer are whole.  A
+    # layer's attention output is this rank's PARTIAL `o_proj` sum (no
+    # exchange).
+    head_share: int = 1
 
     def __post_init__(self):
         # The checks read the fields as given: `plan` is for what passed.
@@ -340,11 +385,13 @@ class ModelConfig:
             )
         if self.is_latent:
             qk = self.qk_nope_head_dim + self.qk_rope_head_dim
-            if not (qk == self.v_head_dim == self.head_dim):
+            if not (qk == self.head_dim and (
+                    self.window_pattern or qk == self.v_head_dim)):
                 raise NotImplementedError(
                     f"latent attention with q/k heads {qk} wide and v heads "
                     f"{self.v_head_dim} (head_dim {self.head_dim}): the "
-                    "attention kernels take one width for q, k and v"
+                    "attention kernels take one width for q, k and v (a "
+                    "plan by `window_pattern` carries v on zero columns)"
                 )
             if self.full_attn_interval > 1 or self.n_kv_heads != self.n_q_heads or not (
                 self.q_lora_rank and self.pos_emb == "rope"
@@ -418,21 +465,59 @@ class ModelConfig:
                     "mixer is 'F' or 'C' (a state before the scan was not "
                     "tested)"
                 )
+        if self.is_latent:
+            self._check_latent_windows()
+        elif (self.index_topk or self.attn_gate_headwise or self.latent_rescale
+              or self.head_share != 1):
+            raise NotImplementedError(
+                "the token indexer, the headwise gate, the latent rescale "
+                "and a share of the heads are latent attention's "
+                "(kv_lora_rank > 0) in a plan by `window_pattern`"
+            )
         if (
             self.layer_pattern or self.full_attn_interval > 1
-            or self.is_latent or (self.attn_gate and "B" not in pattern)
+            or (self.attn_gate and "B" not in pattern)
         ):
             raise NotImplementedError(
                 "a pattern of mixers stands beside plain softmax-attention "
-                "layers only: no one-branch pattern, Gated DeltaNet layers, "
-                "latent attention or output gate (but a block-sparse "
-                "layer's)"
+                "layers only: no one-branch pattern, Gated DeltaNet layers "
+                "or output gate (but a block-sparse layer's, and latent "
+                "attention's headwise one)"
             )
         if "S" in pattern[:self.first_k_dense]:
             raise NotImplementedError(
                 f"window_pattern {pattern!r}: a leading dense layer's mixer "
                 "is 'F' or 'C' (a ring before the scan was not tested)"
             )
+
+    def _check_latent_windows(self):
+        pattern = self.window_pattern
+        if set(pattern) - set("SF"):
+            raise NotImplementedError(
+                f"window_pattern {pattern!r}: latent attention stands in "
+                "'F' (full) and 'S' (sliding window) layers alone"
+            )
+        if "S" in pattern and not (
+            self.swa_n_heads and self.swa_q_lora_rank and self.swa_kv_lora_rank
+            and self.swa_qk_nope_head_dim and self.swa_v_head_dim
+            and self.swa_qk_rope_head_dim == self.qk_rope_head_dim
+        ):
+            raise NotImplementedError(
+                "an 'S' layer of latent attention needs its own geometry "
+                "(swa_n_heads, swa_q_lora_rank, swa_kv_lora_rank, "
+                "swa_qk_nope_head_dim, swa_v_head_dim) and the full layers' "
+                f"rope width ({self.swa_qk_rope_head_dim} against "
+                f"{self.qk_rope_head_dim}: one width of rotary table)"
+            )
+        if self.index_topk and not (
+            self.index_n_heads and self.index_head_dim >= self.qk_rope_head_dim
+        ):
+            raise ValueError(
+                "index_topk needs index_n_heads heads of index_head_dim, at "
+                "least the rope's width"
+            )
+        if self.head_share < 1:
+            raise ValueError(f"head_share {self.head_share} is below 1")
 
     def _check_sala(self):
         pattern = self.window_pattern
@@ -494,12 +579,11 @@ class ModelConfig:
         if self.window_pattern:
             k = self.first_k_dense
             rest = self.window_pattern[k:]
-            unit = tuple(
-                (_WINDOW_KINDS[c], mlp) for c in _shortest_unit(rest)
-            )
+            kinds = _LATENT_WINDOW_KINDS if self.is_latent else _WINDOW_KINDS
+            unit = tuple((kinds[c], mlp) for c in _shortest_unit(rest))
             return LayerPlan(
                 prefix=tuple(
-                    (_WINDOW_KINDS[c], MLP) for c in self.window_pattern[:k]
+                    (kinds[c], MLP) for c in self.window_pattern[:k]
                 ),
                 unit=unit,
                 repeats=len(rest) // len(unit),
@@ -526,7 +610,7 @@ class ModelConfig:
     @property
     def n_window_layers(self) -> int:
         """Layers whose cache is a ring of `attn_window` slots."""
-        return self.plan.count(WINDOW)
+        return self.plan.count(WINDOW, LATENT_WINDOW)
 
     @property
     def n_ssm_layers(self) -> int:
@@ -559,7 +643,7 @@ class ModelConfig:
     def n_attn_layers(self) -> int:
         """Layers that keep k/v (or latent rows) for every slot of the
         cache: a window layer keeps a ring (`n_window_layers`)."""
-        return self.plan.count(ATTENTION, LATENT)
+        return self.plan.count(ATTENTION, LATENT, LATENT_SELECT)
 
     @property
     def has_recurrent_state(self) -> bool:

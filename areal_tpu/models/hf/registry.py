@@ -2591,6 +2591,180 @@ register_hf_family(
 )
 
 
+# ---------------- dots3_note ----------------
+# dots-studio/dots3-note-prev, the language model (the vision and audio
+# towers and the multi-token-prediction module are not modelled): latent
+# attention in two geometries by `layer_types` — "full_attention" layers at
+# the deepseek_v3 keys behind a token indexer (`index_*`), and
+# "sliding_attention" layers at the `swa_*` keys over `sliding_window_size`
+# keys — a headwise output gate on both, glm4_moe_lite's sparse MLP.
+#
+# A `share` group cuts the model to ONE rank of a deployment: experts as
+# for glm4_moe_lite (`n_routed_experts` HELD of `share.router_num_experts`),
+# and HEADS: `num_attention_heads` / `swa_num_attention_heads` are the heads
+# held of `share.published_num_attention_heads` /
+# `share.published_swa_num_attention_heads`, as a tensor-parallel rank
+# holds them; the low-rank down-projections, their norms and the indexer
+# are whole.
+#
+# The catalog row gives the config and no tensor names: the state-dict
+# converters refuse by name.
+
+
+def _dots3_pattern(hf: dict) -> str:
+    kinds = {"full_attention": "F", "sliding_attention": "S"}
+    types = hf["layer_types"]
+    if set(types) - set(kinds) or len(types) != hf["num_hidden_layers"]:
+        raise NotImplementedError(
+            f"dots3_note layer_types {types!r}: num_hidden_layers entries "
+            "of full_attention / sliding_attention")
+    return "".join(kinds[t] for t in types)
+
+
+def _dots3_note_config_from_hf(hf: dict) -> ModelConfig:
+    for key, fine in (
+        ("rope_scaling", None), ("attention_bias", False),
+        ("hidden_act", "silu"), ("topk_method", "noaux_tc"),
+        ("scoring_func", "sigmoid"), ("moe_layer_freq", 1),
+        ("attention_gate_type", "headwise"),
+        ("swa_attention_gate_type", "headwise"),
+    ):
+        if hf.get(key, fine) != fine:
+            raise NotImplementedError(
+                f"dots3_note {key}={hf[key]!r} is not modelled")
+    share = hf.get("share") or {}
+    n_experts = hf["n_routed_experts"]
+    width = share.get("router_num_experts", n_experts)
+    heads, swa_heads = hf["num_attention_heads"], hf["swa_num_attention_heads"]
+    published = share.get("published_num_attention_heads", heads)
+    if published % heads or (
+        share.get("published_swa_num_attention_heads", swa_heads) * heads
+        != published * swa_heads
+    ):
+        raise ValueError(
+            "dots3_note share: both geometries' held heads are the same "
+            "whole share of the published ones")
+    nope, rope = hf["qk_nope_head_dim"], hf["qk_rope_head_dim"]
+    assumed = (hf.get("benchmark") or {}).get("assumed") or {}
+    return ModelConfig(
+        n_layers=hf["num_hidden_layers"],
+        hidden_dim=hf["hidden_size"],
+        n_q_heads=heads,
+        n_kv_heads=heads,
+        head_dim=nope + rope,
+        intermediate_dim=hf["intermediate_size"],
+        vocab_size=hf["vocab_size"],
+        max_position_embeddings=hf.get("max_position_embeddings", 524288),
+        rope_theta=float(hf.get("rope_theta", 80000000.0)),
+        window_rope_theta=float(hf.get("swa_rope_theta", 50000.0)),
+        rms_norm_eps=hf.get("rms_norm_eps", 1e-5),
+        tied_embeddings=hf.get("tie_word_embeddings", False),
+        q_lora_rank=hf["q_lora_rank"],
+        kv_lora_rank=hf["kv_lora_rank"],
+        qk_nope_head_dim=nope,
+        qk_rope_head_dim=rope,
+        v_head_dim=hf["v_head_dim"],
+        window_pattern=_dots3_pattern(hf),
+        attn_window=hf["sliding_window_size"],
+        swa_n_heads=swa_heads,
+        swa_q_lora_rank=hf["swa_q_lora_rank"],
+        swa_kv_lora_rank=hf["swa_kv_lora_rank"],
+        swa_qk_nope_head_dim=hf["swa_qk_nope_head_dim"],
+        swa_qk_rope_head_dim=hf["swa_qk_rope_head_dim"],
+        swa_v_head_dim=hf["swa_v_head_dim"],
+        index_n_heads=hf.get("index_n_heads", 0),
+        index_head_dim=hf.get("index_head_dim", 0),
+        index_topk=hf.get("index_topk", 0),
+        attn_gate_headwise=True,
+        latent_rescale=bool(hf.get("apply_mla_qkv_lora_rescale", False)),
+        head_share=published // heads,
+        first_k_dense=hf.get("first_k_dense_replace", 0),
+        n_experts=n_experts,
+        n_experts_per_tok=hf["num_experts_per_tok"],
+        moe_intermediate_dim=hf["moe_intermediate_size"],
+        moe_norm_topk=bool(hf.get("norm_topk_prob", True)),
+        moe_aux_loss_coef=0.0,
+        moe_score_func="sigmoid",
+        moe_routed_scale=float(hf.get("routed_scaling_factor", 1.0)),
+        router_bias_init_std=float(assumed.get("router_bias_init_std", 0.0)),
+        shared_expert_dim=(
+            hf.get("n_shared_experts", 0) * hf["moe_intermediate_size"]),
+        shared_expert_gated=False,
+        n_router_experts=0 if width == n_experts else width,
+        expert_offset=share.get("rank", 0) * n_experts,
+    )
+
+
+def _dots3_note_config_to_hf(cfg: ModelConfig) -> dict:
+    out = _llama_like_config_to_hf(cfg, "dots3_note")
+    out.pop("head_dim")  # not a key of this family: nope + rope
+    kinds = {"F": "full_attention", "S": "sliding_attention"}
+    out.update(
+        architectures=["Dots3NoteForConditionalGeneration"],
+        hidden_act=cfg.hidden_act,
+        attention_bias=False,
+        rope_scaling=None,
+        apply_mla_qkv_lora_rescale=cfg.latent_rescale,
+        attention_gate_type="headwise",
+        swa_attention_gate_type="headwise",
+        layer_types=[kinds[c] for c in cfg.window_pattern],
+        sliding_window_size=cfg.attn_window,
+        q_lora_rank=cfg.q_lora_rank,
+        kv_lora_rank=cfg.kv_lora_rank,
+        qk_nope_head_dim=cfg.qk_nope_head_dim,
+        qk_rope_head_dim=cfg.qk_rope_head_dim,
+        v_head_dim=cfg.v_head_dim,
+        swa_num_attention_heads=cfg.swa_n_heads,
+        swa_num_key_value_heads=cfg.swa_n_heads,
+        swa_q_lora_rank=cfg.swa_q_lora_rank,
+        swa_kv_lora_rank=cfg.swa_kv_lora_rank,
+        swa_qk_nope_head_dim=cfg.swa_qk_nope_head_dim,
+        swa_qk_rope_head_dim=cfg.swa_qk_rope_head_dim,
+        swa_v_head_dim=cfg.swa_v_head_dim,
+        swa_rope_theta=cfg.window_rope_theta,
+        index_n_heads=cfg.index_n_heads,
+        index_head_dim=cfg.index_head_dim,
+        index_topk=cfg.index_topk,
+        first_k_dense_replace=cfg.first_k_dense,
+        moe_layer_freq=1,
+        n_routed_experts=cfg.n_experts,
+        num_experts_per_tok=cfg.n_experts_per_tok,
+        moe_intermediate_size=cfg.moe_intermediate_dim,
+        n_shared_experts=cfg.shared_expert_dim // cfg.moe_intermediate_dim,
+        norm_topk_prob=cfg.moe_norm_topk,
+        routed_scaling_factor=cfg.moe_routed_scale,
+        scoring_func="sigmoid",
+        topk_method="noaux_tc",
+    )
+    if cfg.expert_share or cfg.head_share != 1:
+        out["share"] = {
+            "router_num_experts": cfg.router_width,
+            "rank": cfg.expert_offset // cfg.n_experts,
+            "published_num_attention_heads": cfg.n_q_heads * cfg.head_share,
+            "published_swa_num_attention_heads": (
+                cfg.swa_n_heads * cfg.head_share),
+        }
+    return out
+
+
+def _dots3_note_no_tensors(*_, **__):
+    raise NotImplementedError(
+        "dots3_note: the published tensor names are not in the catalog row "
+        "this family was written from; weights are drawn (`init_params`), "
+        "no checkpoint is read or written")
+
+
+register_hf_family(
+    HFFamily(
+        "dots3_note",
+        _dots3_note_config_from_hf,
+        _dots3_note_config_to_hf,
+        params_from_sd=_dots3_note_no_tensors,
+        params_to_sd=_dots3_note_no_tensors,
+    )
+)
+
+
 def infer_model_type(cfg: ModelConfig) -> str:
     """Best-fit HF family for a ModelConfig — the save path's dispatcher
     when the caller didn't record where the weights came from."""
@@ -2603,7 +2777,7 @@ def infer_model_type(cfg: ModelConfig) -> str:
     if cfg.is_pattern:
         return "nemotron_h"
     if cfg.is_latent:
-        return "glm4_moe_lite"
+        return "dots3_note" if cfg.window_pattern else "glm4_moe_lite"
     if cfg.n_sconv_layers:
         return "lfm2_moe"
     if cfg.n_ssm_layers:  # Mamba-2 mixers in two-branch layers

@@ -46,7 +46,13 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from areal_tpu.models import lightning, linear_attention, mamba, short_conv
+from areal_tpu.models import (
+    latent_select,
+    lightning,
+    linear_attention,
+    mamba,
+    short_conv,
+)
 from areal_tpu.models.branches import (  # noqa: F401 - the errors' callers
     BRANCHES,
     Branch,
@@ -64,6 +70,8 @@ from areal_tpu.models.config import (
     DENSE_PREFIX,
     GDN,
     LATENT,
+    LATENT_SELECT,
+    LATENT_WINDOW,
     LIGHTNING,
     MLP,
     MOE,
@@ -155,9 +163,12 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         return out
 
     LM = scanned(MOE)
+    n_attn = scanned(ATTENTION, WINDOW, LATENT, SPARSE)
     blocks = {
         "ln1": norm_init((L, D), dtype),
-        **attn_leaves(scanned(ATTENTION, WINDOW, LATENT, SPARSE), ks),
+        # latent attention by `window_pattern`: every mixer draws its own
+        **(attn_leaves(n_attn, ks)
+           if n_attn or not plan.count(LATENT_SELECT, LATENT_WINDOW) else {}),
     }
     if not cfg.is_pattern:  # a second branch a layer: a second norm
         blocks["ln2"] = norm_init((L, D), dtype)
@@ -1149,7 +1160,7 @@ def _rope(cfg: ModelConfig, positions: jax.Array):
     dim, theta = _rope_dim(cfg), cfg.rope_theta
     with jax.named_scope("rope/yarn" if yarn else "rope/plain"):
         full = rope_cos_sin(positions, dim, theta, yarn)
-    if not cfg.plan.count(WINDOW):
+    if not cfg.plan.count(WINDOW, LATENT_WINDOW):
         return full, None
     window_theta = cfg.window_rope_theta or theta
     if yarn is None and window_theta == theta:
@@ -1888,7 +1899,10 @@ class KVCache:
     - `ck`: a block-sparse layer's COMPRESSED keys, one row a
       `sparse_kernel_stride` tokens by kernel number within the row's
       sequence, appended as kernels complete
-      (`block_sparse.compressed_step`)."""
+      (`block_sparse.compressed_step`);
+    - `ikeys`: beside a selected latent layer's rows, the token indexer's
+      key a slot; `wlatent`: a latent window layer's ring of latent rows
+      (`models/latent_select.py`)."""
 
     k: Optional[jax.Array]
     v: Optional[jax.Array]
@@ -1898,10 +1912,18 @@ class KVCache:
     wk: Optional[jax.Array] = None
     wv: Optional[jax.Array] = None
     ck: Optional[jax.Array] = None
+    ikeys: Optional[jax.Array] = None
+    wlatent: Optional[jax.Array] = None
 
     @property
     def s_max(self) -> int:
         return (self.latent if self.k is None else self.k).shape[2]
+
+    @property
+    def ring(self) -> Optional[int]:
+        """Entries of the window layers' rings; None without one."""
+        rings = self.wlatent if self.wk is None else self.wk
+        return None if rings is None else rings.shape[2]
 
 
 jax.tree_util.register_dataclass(
@@ -2110,7 +2132,7 @@ def prefill(
     (cos, sin), window_rope = _rope(cfg, positions)
     ctx = Ctx(
         cfg, cos, sin, segment_ids, window_rope, use_flash, with_state=True,
-        ring=None if cache.wk is None else cache.wk.shape[2],
+        ring=cache.ring,
         ck_slots=None if cache.ck is None else cache.ck.shape[2],
     )
     # Each population's new entries [its layers, ...], the prefix's layers
@@ -2342,7 +2364,7 @@ def decode_step(
         expert_kernel=stacked is not None and expert_kernel_choice(
             cfg, expert_kernel),
         # the same entries of every window layer's ring
-        live=ring_valid(slot, valid_from, cache.wk.shape[2])
+        live=ring_valid(slot, valid_from, cache.ring)
         if window_rope else None,
     )
     x, new_cache, counts = _walk(
@@ -2924,6 +2946,8 @@ BRANCHES.update({
         attn_flops=_softmax_flops,
         cache_stats=_latent_cache_stats,
     ),
+    LATENT_SELECT: latent_select.SELECT_BRANCH,
+    LATENT_WINDOW: latent_select.WINDOW_BRANCH,
     SSM: mamba.BRANCH,
     WINDOW: Branch(
         leaves=_FULL_ATTN_LEAVES,

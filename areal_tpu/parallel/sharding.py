@@ -105,6 +105,25 @@ _BLOCK_RULES: Dict[str, P] = {
     "wk_b": P(PIPE_AXIS, FSDP_AXIS, None),
     "wv_b": P(PIPE_AXIS, FSDP_AXIS, None),
     "router_bias": P(PIPE_AXIS, None),
+    # Latent attention by `window_pattern` (`models/latent_select.py`): the
+    # headwise gate, the token indexer's leaves (its three matrices are
+    # stored as one vector a layer) and a window layer's own geometry under
+    # `sw_`, by the rules above: ZeRO over fsdp, nothing over `model`.
+    "hgate": P(PIPE_AXIS, FSDP_AXIS, None),
+    "idx_q": P(PIPE_AXIS, FSDP_AXIS),
+    "idx_k": P(PIPE_AXIS, FSDP_AXIS),
+    "idx_w": P(PIPE_AXIS, FSDP_AXIS),
+    "idx_k_norm": P(PIPE_AXIS, None),
+    "idx_k_norm_b": P(PIPE_AXIS, None),
+    "sw_wq_a": P(PIPE_AXIS, FSDP_AXIS, None),
+    "sw_q_a_norm": P(PIPE_AXIS, None),
+    "sw_wq_b": P(PIPE_AXIS, FSDP_AXIS, None),
+    "sw_wkv_a": P(PIPE_AXIS, FSDP_AXIS, None),
+    "sw_kv_a_norm": P(PIPE_AXIS, None),
+    "sw_wk_b": P(PIPE_AXIS, FSDP_AXIS, None),
+    "sw_wv_b": P(PIPE_AXIS, FSDP_AXIS, None),
+    "sw_wo": P(PIPE_AXIS, None, FSDP_AXIS),
+    "sw_hgate": P(PIPE_AXIS, FSDP_AXIS, None),
     # Mamba-2 leaves: ZeRO-sharded over fsdp, NOT split over `model` — the
     # heads sit in the packed output axis of `ssm_in` beside the groups'
     # B | C and the per-head dt, and the conv, the gated norm's groups and

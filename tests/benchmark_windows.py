@@ -38,6 +38,7 @@ HOMES = {
     "lfm2-ctxrl32-4k": "tests.test_lfm2_moe",
     "granite4hm-serving-waves": "tests.test_granite_hybrid",
     "olmoh-rollout64-512": "tests.test_olmo_hybrid",
+    "dots3n-docrl8-longctx": "tests.test_dots3_note",
 }
 
 

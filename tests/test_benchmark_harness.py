@@ -35,6 +35,7 @@ from benchmark.tests import test_ssm_slab as slab_cases
 from benchmark.tests.test_ssm_slab import *  # noqa: F401,F403 — the cases (PR 54)
 from benchmark.tests import test_sala as sala_cases
 from benchmark.tests.test_sala import *  # noqa: F401,F403 — the cases (PR 55)
+from benchmark.tests import test_gdnd as gdnd_cases
 from benchmark.tests.test_gdnd import *  # noqa: F401,F403 — the cases (PR 59)
 from benchmark.tests import fixed_work_cases
 from tests.benchmark_windows import HOMES, cells_of, window_case
@@ -56,7 +57,8 @@ LISTED_BY_PR55 = {"flash_fwd_share", "flash_bwd_share"}
 
 
 GLM_CELL = "glm47f-rollout64-1k"
-OLMOH_CELL = "olmoh-rollout64-512"  # PR 59's, the last of `workloads`
+OLMOH_CELL = "olmoh-rollout64-512"  # PR 59's
+DOTS_CELL = "dots3n-docrl8-longctx"  # PR 64's, the last of `workloads`
 # PR 38's entries, the last of `per_layer` but PR 39's one: the issue's
 # eight in its order, then the two twins the review asked for (`mfu_gen` and
 # `moe_train_mlp_mfu` over `benchmark/peaks_mla.py`, as the hybrid cell has).
@@ -424,7 +426,7 @@ def test_its_entry_is_the_last_and_lists_the_share_cells(monkeypatch):  # noqa: 
     from benchmark.tests import test_moe_train_rows_gathered_share as cases
 
     entry = SPEC["per_layer"][_at(SPEC["per_layer"], cases.reader.__name__.rsplit(".", 1)[1])]
-    later = [MELLUM_CELL, LFM2_CELL]
+    later = [MELLUM_CELL, LFM2_CELL, DOTS_CELL]  # PR 64's trains on it too
     assert entry["workloads"] == cases.SHARE_CELLS + later
     before = json.loads(json.dumps(SPEC))
     before["workloads"] = [
@@ -477,7 +479,8 @@ def test_the_mellum_cell_is_as_the_issue_parametrised_it():
     assert 4 * (sum(lengths) + 8 * 512) == 88344  # trained tokens a step
     n = len(MELLUM_ENTRIES)
     first = _at(SPEC["per_layer"], MELLUM_ENTRIES[0][0])
-    assert SPEC["per_layer"][first: first + n] == [
+    # (PR 64's cell has a band too: `flash_window_live_tile_share` lists it)
+    assert _spec_before_pr64()["per_layer"][first: first + n] == [
         {"name": name, "unit": unit, "better": better, "source": source,
          "layer": layer, "moves": moves, "workloads": [MELLUM_CELL]}
         for name, unit, better, source, layer, moves in MELLUM_ENTRIES
@@ -521,8 +524,8 @@ def test_the_lfm2_cell_is_as_the_issue_parametrised_it():
         "https://huggingface.co/LiquidAI/LFM2-8B-A1B/blob/main/config.json")
     assert len(entry["why"]) <= 200 and len(conf["why"]) <= 200
     # PR 55 appended the twelfth cell and the tenth configuration, PR 59
-    # the thirteenth and the eleventh.
-    assert len(CELLS) == 13 and len(SPEC["configs"]) == 11
+    # the thirteenth and the eleventh, PR 64 the fourteenth and the twelfth.
+    assert len(CELLS) == 14 and len(SPEC["configs"]) == 12
     assert [w["name"] for w in SPEC["workloads"] if w["chips"] == 4] == [
         "q7b-realloc-4chip"]
     assert (cell["route"], cell["timed_steps"], cell["traffic_seed"]) == (
@@ -551,7 +554,8 @@ def test_the_lfm2_cell_is_as_the_issue_parametrised_it():
         if LFM2_CELL in m.get("workloads", []):  # the last static cell's
             static = [w for w in m["workloads"]  # ... before PR 55's, 59's
                       if "serving" not in w
-                      and w not in ("sala-docrl8-longctx", OLMOH_CELL)]
+                      and w not in (
+                          "sala-docrl8-longctx", OLMOH_CELL, DOTS_CELL)]
             assert static[-1] == LFM2_CELL, m["name"]
     for name in listed:
         assert callable(files.load_module("metrics", name).read), name
@@ -1470,22 +1474,42 @@ def test_the_sconv_readers_say_nothing_without_their_scopes_or_counters():
         + 4 * 32 * 64 * 1 * 4096 ** 2 / 2)
 
 
-def _spec_before_pr59():
-    """BENCHMARK.json as it stood before PR 59 appended its configuration,
-    its cell and the cell's name to eleven `workloads` lists (it added no
-    per-layer entry: the list was full)."""
-    assert SPEC["workloads"][-1]["name"] == OLMOH_CELL
-    assert SPEC["workloads"][-2]["name"] == "sala-docrl8-longctx"
+def _without_last_cell(spec, cell):
+    """`spec` as it stood before its last configuration and its last cell,
+    `cell`, were appended and the cell's name to `workloads` lists."""
+    assert spec["workloads"][-1]["name"] == cell
 
     def without(m):
-        if OLMOH_CELL not in m.get("workloads", ()):
+        if cell not in m.get("workloads", ()):
             return m
-        return dict(m, workloads=[w for w in m["workloads"] if w != OLMOH_CELL])
+        return dict(m, workloads=[w for w in m["workloads"] if w != cell])
 
     return dict(
-        SPEC, workloads=SPEC["workloads"][:-1], configs=SPEC["configs"][:-1],
-        end_to_end=[without(m) for m in SPEC["end_to_end"]],
-        per_layer=[without(m) for m in SPEC["per_layer"]])
+        spec, workloads=spec["workloads"][:-1], configs=spec["configs"][:-1],
+        end_to_end=[without(m) for m in spec["end_to_end"]],
+        per_layer=[without(m) for m in spec["per_layer"]])
+
+
+def _spec_before_pr64():
+    """BENCHMARK.json as it stood before PR 64 appended its configuration,
+    its cell and the cell's name to `workloads` lists (no per-layer entry:
+    the list is full)."""
+    return _without_last_cell(SPEC, DOTS_CELL)
+
+
+def _spec_before_pr59():
+    """... and before PR 59 did the same, to eleven lists."""
+    spec = _spec_before_pr64()
+    assert spec["workloads"][-2]["name"] == "sala-docrl8-longctx"
+    return _without_last_cell(spec, OLMOH_CELL)
+
+
+def test_the_cell_lists_what_it_reports(monkeypatch):  # noqa: F811
+    """PR 59's case pins ITS cell and configuration as the last; PR 64
+    appended a cell, a configuration and the cell's name to lists.  So: PR
+    59's case on the lists as they stood before."""
+    monkeypatch.setattr(files, "benchmark_json", _spec_before_pr64)
+    gdnd_cases.test_the_cell_lists_what_it_reports()
 
 
 def _spec_before(first_later_entry):

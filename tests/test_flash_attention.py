@@ -72,6 +72,34 @@ class TestFlashForward:
             rtol=_tol(q), atol=_tol(q),
         )
 
+    @pytest.mark.parametrize("window", [None, 24])
+    def test_v_on_zero_columns_is_attention_at_unequal_widths(
+            self, rng, window):
+        """q/k heads wider than v heads (latent attention's 192 | 128 and
+        256 | 128): the kernels take one width, v padded with zero columns
+        up to it and the output's first columns kept — exact, with or
+        without a band."""
+        q, k, v, seg = _inputs(rng, d=32)
+        v = v[..., :20]
+        vp = jnp.pad(v, ((0, 0),) * 3 + ((0, 12),))
+        out = flash_attention(
+            q, k, vp, seg, block_q=64, block_k=64, window=window)
+        np.testing.assert_array_equal(np.asarray(out[..., 20:]), 0.0)
+        hq, hkv = q.shape[2], k.shape[2]
+        scores = jnp.einsum(
+            "bqhd,bkhd->bhqk", q, jnp.repeat(k, hq // hkv, axis=2)) * 32**-0.5
+        at = jnp.arange(q.shape[1])
+        mask = (seg[:, :, None] == seg[:, None, :]) & (seg[:, :, None] > 0) & (
+            at[None, :, None] >= at[None, None, :])
+        if window:
+            mask &= at[None, :, None] - at[None, None, :] < window
+        p = jax.nn.softmax(jnp.where(mask[:, None], scores, -1e30), axis=-1)
+        p = jnp.where(mask.any(-1)[:, None, :, None], p, 0.0)
+        want = jnp.einsum(
+            "bhqk,bkhd->bqhd", p, jnp.repeat(v, hq // hkv, axis=2))
+        np.testing.assert_allclose(
+            np.asarray(out[..., :20]), np.asarray(want), rtol=2e-5, atol=2e-5)
+
     @pytest.mark.parametrize("dtype", DTYPES)
     def test_single_block(self, rng, dtype):
         q, k, v, seg = _inputs(rng, s=128, dtype=dtype)
@@ -619,10 +647,14 @@ class TestTPULowering:
         "glm_20x5120x256": (1, 5120, 20, 20, 256),
         # lfm2_moe: heads of 64, half a lane tile, never before it
         "lfm2_32x8192x64": (1, 8192, 32, 8, 64),
+        # dots3_note's sliding layers: 8 held heads, q/k 256 with v (128)
+        # carried on zero columns, a band of 513 keys over rows of 13,312
+        "dots3_window_8x13312x256": (1, 13312, 8, 8, 256, 513),
     }
     # The trip each cell's FORWARD calls take (`_trip_blocks`), as the scope
     # around the kernel says it (dq's keys and dkv's queries: 512 everywhere).
-    FORWARD_TRIPS = {"glm_20x5120x256": 128, "q3next_16x8192x256": 128}
+    FORWARD_TRIPS = {"glm_20x5120x256": 128, "q3next_16x8192x256": 128,
+                     "dots3_window_8x13312x256": 128}
 
     def _cell(self, cell, sharding=None):
         b, s, n_q, n_kv, d, *window = self.CELL_SHAPES[cell]

@@ -429,6 +429,10 @@ _PARENT_FLOPS = {
     # `benchmark/peaks_gdnd.py` counts the toy + 3 layers x 4 heads x 3 x 12
     # x 24 of the recurrence).
     "olmo-hybrid-7b-l4-v8": (233088, 8144289792.0, 990031872.0),
+    # PR 64's neither: the records' sums as PR 64 first printed them (the
+    # toy's two full layers with their indexers, three sliding ones, the
+    # selected keys of a query capped at index_topk 256 and the band of 9).
+    "dots3-note-prev-l5-e8-h8": (163648, 8366456832.0, 1001366272.0),
 }
 
 

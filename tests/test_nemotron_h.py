@@ -674,7 +674,8 @@ def test_the_train_step_counts_chunks_and_restarts_and_keeps_no_moment(cfg):
     params = _params(cfg)
     mask = _trainable_mask(params)
     frozen = [n for n, t in mask["blocks"].items() if not t]
-    assert frozen == list(FROZEN_LEAVES) == ["router_bias"]
+    # (the other frozen leaves are a token indexer's: none here, PR 64)
+    assert frozen == ["router_bias"] == list(FROZEN_LEAVES[:1])
     mesh = make_mesh(ParallelConfig.from_str("d1"), jax.devices()[:1])
     engine = TrainEngine(cfg, params, mesh, ftspec=FinetuneSpec(1, 8, 8))
     seg = np.zeros((2, 40), np.int32)
