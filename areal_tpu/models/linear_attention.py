@@ -171,6 +171,41 @@ def causal_conv(
     return out
 
 
+def conv_kernel_form(
+    channels: int, taps: int, kernel=None, with_state=False
+) -> bool:
+    """Whether a mixer's causal conv + activation runs on the Pallas
+    operator `causal_conv_act` (`ops/pallas/causal_conv.py`; `causal_conv`
+    is its oracle and every other caller's form): by what the code can see
+    (`flash_attention.row_kernel_form`: a TPU backend; a bool forces
+    either) where the channels are whole 128-lane tiles, on ONE device —
+    `kernel` a Mesh: a `pallas_call` is one device's program — and without
+    `with_state`: prefill's program, and what a generator samples from it,
+    stay `causal_conv`'s.  The one chooser of the three kinds that run the
+    conv (Gated DeltaNet, Mamba-2, the gated short convolution)."""
+    from areal_tpu.ops.pallas import causal_conv as conv_kernels
+    from areal_tpu.ops.pallas.flash_attention import row_kernel_form
+
+    fits = conv_kernels.fits(channels, taps)
+    use_kernel, mesh = row_kernel_form(kernel, fits)
+    return use_kernel and fits and mesh is None and not with_state
+
+
+def conv_act(x, taps, bias, segment_ids, on_kernel: bool, act="silu"):
+    """act(causal_conv(x, taps, segment_ids) + bias), fp32 [B, S, C]: a
+    mixer's conv in the form `conv_kernel_form` chose — `on_kernel`: ONE
+    operator with its own backward; else the `jnp` ops.  bias [C] | None,
+    `act` "silu" | "identity"."""
+    if on_kernel:
+        from areal_tpu.ops.pallas.causal_conv import causal_conv_act
+
+        return causal_conv_act(x, taps, bias, segment_ids, act)
+    out = causal_conv(x, taps, segment_ids)
+    if bias is not None:
+        out = out + bias.astype(jnp.float32)
+    return jax.nn.silu(out) if act == "silu" else out
+
+
 def conv_tail(x: jax.Array, segment_ids: jax.Array, kk: int) -> jax.Array:
     """The last K-1 conv inputs of each row, zero where they belong to
     another segment than the row's last token: what `linear_attn_step`
@@ -337,16 +372,19 @@ def linear_attn_forward(
     The delta rule has one form per backend and caller
     (`chunk_kernel_form`): the Pallas sweep `gdn_chunk` in the gradient
     program and `forward` on one TPU device, `gated_delta_chunked`
-    elsewhere."""
+    elsewhere; so has the conv + SiLU before it (`conv_kernel_form`)."""
     use_kernel = chunk_kernel_form(cfg, kernel, with_state)
+    conv_kernel = conv_kernel_form(
+        cfg.linear_conv_dim, cfg.linear_conv_kernel, kernel, with_state)
     with jax.named_scope("in_proj"):
         qkv = h @ blk["la_wqkv"]
         z = h @ blk["la_wz"]
         ba = h @ blk["la_wba"]
-    if _conv_reads_made_input(cfg):
+    # A kernel's operand is made before the kernel reads it.
+    if not conv_kernel and _conv_reads_made_input(cfg):
         qkv = jax.lax.optimization_barrier(qkv)
     with jax.named_scope("conv"):
-        conv = jax.nn.silu(causal_conv(qkv, blk["la_conv"], segment_ids))
+        conv = conv_act(qkv, blk["la_conv"], None, segment_ids, conv_kernel)
     beta, g = _gates(ba, blk, cfg)
     with jax.named_scope("delta_rule"):
         q, k, v = _split_heads(conv, cfg, repeat=not use_kernel)
@@ -543,6 +581,9 @@ def _train_stats(cfg: ModelConfig, n_layers: int, seg: jax.Array, row_kernel):
         # sweep `gdn_chunk`), a trace-time constant.
         "linear_attn/rule_on_kernel": jnp.float32(
             chunk_kernel_form(cfg, row_kernel)),
+        # ... and the conv's (1: the Pallas operator `causal_conv_act`).
+        "linear_attn/conv_on_kernel": jnp.float32(conv_kernel_form(
+            cfg.linear_conv_dim, cfg.linear_conv_kernel, row_kernel)),
     }
 
 

@@ -89,7 +89,8 @@ from areal_tpu.models.branches import (
 )
 from areal_tpu.models.config import ModelConfig
 from areal_tpu.models.linear_attention import (
-    causal_conv,
+    conv_act,
+    conv_kernel_form,
     conv_tail_at,
     state_cache_stats,
 )
@@ -284,15 +285,16 @@ def ssm_forward(
     """-> y [B, S, D]; `with_state` (prefill) adds the state at each row's
     last VALID token [B, H, P, N] fp32 and the conv's tail there [B, K-1,
     conv_dim].  The recurrence has one form per backend and caller
-    (`ssd_kernel_form`): the Pallas sweep `ssd_chunk` or `ssd_chunked`."""
+    (`ssd_kernel_form`): the Pallas sweep `ssd_chunk` or `ssd_chunked`; so
+    has the conv + SiLU before it (`linear_attention.conv_kernel_form`)."""
     use_kernel = ssd_kernel_form(cfg, kernel, with_state)
     with jax.named_scope("in_proj"):
         z, xbc, dt = _split_in(h @ blk["ssm_in"], cfg)
     with jax.named_scope("conv"):
-        conv = jax.nn.silu(
-            causal_conv(xbc, blk["ssm_conv"], segment_ids)
-            + blk["ssm_conv_b"].astype(jnp.float32)
-        )
+        conv = conv_act(
+            xbc, blk["ssm_conv"], blk["ssm_conv_b"], segment_ids,
+            conv_kernel_form(
+                cfg.ssm_conv_dim, cfg.ssm_conv_kernel, kernel, with_state))
     with jax.named_scope("ssd_scan"):
         if use_kernel:
             # The sweep reads x, B and C where they lie in the conv's
@@ -673,12 +675,15 @@ def _matmul_params(cfg: ModelConfig) -> int:
 def _train_stats(cfg: ModelConfig, n_layers: int, seg: jax.Array, row_kernel):
     """What the chunked scan ran over, summed over the Mamba layers:
     chunks, those of them on the Pallas sweep (`ssd_kernel_form`: all or
-    none), and the restarts."""
+    none), the conv's form (1: the Pallas operator `causal_conv_act`) and
+    the restarts."""
     n_chunks = n_layers * seg.shape[0] * -(-seg.shape[1] // cfg.ssm_chunk)
     return {
         "ssm/chunks": jnp.float32(n_chunks),
         "ssm/chunks_on_kernel": jnp.float32(
             n_chunks * ssd_kernel_form(cfg, row_kernel)),
+        "ssm/conv_on_kernel": jnp.float32(conv_kernel_form(
+            cfg.ssm_conv_dim, cfg.ssm_conv_kernel, row_kernel)),
         "ssm/segment_restarts": n_layers * segment_restarts(seg),
     }
 

@@ -38,7 +38,11 @@ from areal_tpu.models.branches import (
     segment_restarts,
 )
 from areal_tpu.models.config import ModelConfig
-from areal_tpu.models.linear_attention import causal_conv, conv_tail_at
+from areal_tpu.models.linear_attention import (
+    conv_act,
+    conv_kernel_form,
+    conv_tail_at,
+)
 
 Params = Dict[str, jax.Array]
 
@@ -77,12 +81,17 @@ def sconv_forward(
     cfg: ModelConfig,
     segment_ids: jax.Array,
     with_state: bool = False,
+    kernel=None,  # None | bool | Mesh: `flash_attention.row_kernel_form`
 ):
     """-> y [B, S, D]; `with_state` (prefill) adds the gated inputs at each
-    row's last K - 1 valid tokens [B, K-1, D]."""
+    row's last K - 1 valid tokens [B, K-1, D].  The conv has one form per
+    backend and caller (`linear_attention.conv_kernel_form`)."""
     g, c_gate = _gated_input(h, blk)
     with jax.named_scope("conv"):
-        conv = causal_conv(g, blk["sc_conv"], segment_ids)
+        conv = conv_act(
+            g, blk["sc_conv"], None, segment_ids, conv_kernel_form(
+                cfg.hidden_dim, cfg.sconv_kernel, kernel, with_state),
+            act="identity")
     y = _out(c_gate, conv, blk)
     if with_state:
         idx = jnp.arange(segment_ids.shape[-1])
@@ -121,9 +130,11 @@ def sconv_step(
 
 def _packed(ctx, h, blk):
     if not ctx.with_state:
-        return sconv_forward(h, blk, ctx.cfg, ctx.segment_ids), {}
+        return sconv_forward(
+            h, blk, ctx.cfg, ctx.segment_ids, kernel=ctx.row_kernel), {}
     out, tail = sconv_forward(
-        h, blk, ctx.cfg, ctx.segment_ids, with_state=True)
+        h, blk, ctx.cfg, ctx.segment_ids, with_state=True,
+        kernel=ctx.row_kernel)
     return out, {"conv": tail}
 
 
@@ -160,8 +171,14 @@ def _cache_stats(cfg: ModelConfig, cache, batch: int, s_max: int):
 
 
 def _train_stats(cfg: ModelConfig, n_layers: int, seg: jax.Array, row_kernel):
-    """The short convolutions' restarts, summed over them."""
-    return {"sconv/segment_restarts": n_layers * segment_restarts(seg)}
+    """The short convolutions' restarts, summed over them, and the conv's
+    form in this gradient program (1: the Pallas operator
+    `causal_conv_act`), a trace-time constant."""
+    return {
+        "sconv/segment_restarts": n_layers * segment_restarts(seg),
+        "sconv/conv_on_kernel": jnp.float32(conv_kernel_form(
+            cfg.hidden_dim, cfg.sconv_kernel, row_kernel)),
+    }
 
 
 BRANCH = Branch(

@@ -486,9 +486,11 @@ def test_a_conv_that_reads_across_a_segment_start_fails(cfg, params, monkeypatch
     tokens = jnp.asarray(np.concatenate(seqs))[None]
     seg = jnp.asarray(np.concatenate(
         [np.full(30, 1), np.full(25, 2)]).astype(np.int32))[None]
-    blind = short_conv.causal_conv
+    from areal_tpu.models import linear_attention
+
+    blind = linear_attention.causal_conv  # where `conv_act` looks it up
     monkeypatch.setattr(
-        short_conv, "causal_conv",
+        linear_attention, "causal_conv",
         lambda x, taps, seg: blind(x, taps, jnp.ones_like(seg)))
     got = np.asarray(tfm.forward(params, cfg, tokens, seg))[0]
     first, second = (
