@@ -120,6 +120,24 @@ say for itself:
   (:func:`setup_take`), the totals from process start, and never again.
   Under a live profiler each phase writes an ``areal:compile``
   annotation at its end carrying ``dur_ms``, ``phase`` and ``fun``.
+- **HBM ledger** (PR 66) -- what the device's peak is made of and which
+  span set it.  The worker hands over three readers of the device
+  (:func:`hbm_readers`; nothing on a backend without ``memory_stats()``).
+  :func:`hbm_mark` reads the counters of every local device at the open
+  and the close of every request and phase (``setup:*``, ``mfc:*``,
+  ``param_sync:*``, ``fetch``, ``clear_cache``) and of the engines'
+  spans that end on a wait (``generate``, ``gen_wait``, ``chunk_wait``,
+  ``stats_reduce``, ``stats_sync``), chosen by name in ``_hbm_kind``: no
+  wait on the device is added.  A rise of the peak between two marks
+  belongs to the innermost span open over it (``between_requests``
+  outside any) and, under a live profiler, writes an ``areal:hbm_peak``
+  annotation.  The step record gains ``hbm: {in_use, peak, device, rises,
+  marks, mark_s}``, the program rows ``code_b``, ``temp_b``, ``arg_b``,
+  ``out_b``, ``alias_b`` and ``request``; :func:`close_step` returns
+  ``hbm/<key>`` (:func:`hbm_take`), from the FIRST close also the
+  account of the peak -- owners, code, temporaries and
+  ``hbm/unaccounted_gb``, the remainder -- which :func:`setup_report`
+  prints.
 """
 
 import atexit
@@ -325,6 +343,9 @@ def _thread_state() -> _ThreadState:
         return ts
 
 
+_NOT_OPEN = float("inf")
+
+
 class _Span:
     """Always timed: clock reads, the thread's stack and the step ledger
     with or without AREAL_TRACE; the event dict, the ring and the shard
@@ -332,7 +353,7 @@ class _Span:
 
     __slots__ = (
         "name", "cat", "args", "t0", "ann", "parent", "child_ns", "self_ns",
-        "ts",
+        "ts", "hbm", "mark",
     )
 
     def __init__(self, name: str, cat: Optional[str], args: Dict, ann):
@@ -342,6 +363,11 @@ class _Span:
         self.ann = ann
         self.child_ns = 0
         self.self_ns = 0
+        self.t0 = _NOT_OPEN  # another thread's mark may look before entry
+        # HBM ledger: 0 unmarked, else _HBM_WAIT or _HBM_REQUEST; `mark`
+        # is the one taken at the close (None where nothing reads).
+        self.hbm = _hbm["reader"] is not None and _hbm_kind(name)
+        self.mark = None
 
     def __enter__(self) -> Dict:
         ts = self.ts = _thread_state()
@@ -351,9 +377,13 @@ class _Span:
         if self.ann is not None:
             self.ann.__enter__()
         self.t0 = time.monotonic_ns()
+        if self.hbm:
+            _hbm_open(self)
         return self.args
 
     def __exit__(self, *exc) -> bool:
+        if self.hbm:
+            self.mark = hbm_mark("close:" + self.name)
         t1 = time.monotonic_ns()
         if self.ann is not None:
             self.ann.__exit__(*exc)
@@ -548,6 +578,9 @@ def flight_dump(
         "t_dump_us": int(time.time() * 1e6),
         "events": list(_flight),
     }
+    hbm = _hbm_snapshot()
+    if hbm is not None:
+        doc["hbm"] = hbm
     try:
         os.makedirs(d, exist_ok=True)
         with open(path, "w") as f:
@@ -666,7 +699,8 @@ def close_step(step: int, wall_s: Optional[float] = None) -> Dict[str, float]:
     LEDGER_STEPS), the host watch hands over its record, and a step that
     ran long writes its ``slow_step`` flight event.  ``wall_s`` defaults
     to the seconds since the last close.  Returns the step stats:
-    ``host/<key>``, ``time/slow_excess_s`` (0 for a step not flagged)
+    ``host/<key>``, ``time/slow_excess_s`` (0 for a step not flagged),
+    ``hbm/<key>`` where a device reader was handed over (:func:`hbm_take`)
     and, from the process's first close alone, ``setup/<key>``
     (:func:`setup_take`)."""
     now = time.monotonic_ns()
@@ -687,6 +721,7 @@ def close_step(step: int, wall_s: Optional[float] = None) -> Dict[str, float]:
             with _lock:
                 _threads.pop(ident, None)
     host = _watch.take() if _watch is not None else {}
+    hbm = _hbm_close()  # before the rows go: its mark may join them bytes
     with _lock:
         programs = list(_programs)
         _programs.clear()
@@ -701,11 +736,14 @@ def close_step(step: int, wall_s: Optional[float] = None) -> Dict[str, float]:
         "host": host,
         "programs": programs,
     }
+    if hbm is not None:
+        record["hbm"] = hbm
     excess = _judge_step(record)
     _steps.append(record)
     stats = {f"host/{k}": v for k, v in host.items()}
     stats["time/slow_excess_s"] = excess
     stats.update(setup_take())
+    stats.update(hbm_take())
     return stats
 
 
@@ -746,8 +784,9 @@ def _judge_step(record: Dict[str, Any]) -> float:
 def step_ledger() -> List[Dict[str, Any]]:
     """The last LEDGER_STEPS closed steps, oldest first: ``step``, ``t_us``
     (epoch microseconds at the close), ``wall_s``, ``spans`` (name -> (n,
-    total_s, self_s)), ``host`` and ``programs`` (the rows of the programs
-    compiled or loaded since the close before: :func:`program_event`)."""
+    total_s, self_s)), ``host``, ``programs`` (the rows of the programs
+    compiled or loaded since the close before: :func:`program_event`) and,
+    where a device reader was handed over, ``hbm`` (:func:`hbm_readers`)."""
     return list(_steps)
 
 
@@ -906,6 +945,10 @@ def _close_program(ts: _ThreadState, fun: str, backend_s: float) -> None:
         "hit": hit,
         "written": written,
         "span": ts.stack[-1].name if ts.stack else "",
+        # The request or phase that ran it, and when the row closed on
+        # the spans' clock (the HBM ledger's joins).
+        "request": _request_of(ts.stack[-1] if ts.stack else None),
+        "t_ns": time.monotonic_ns(),
     }
     with _lock:
         _programs.append(row)
@@ -982,7 +1025,8 @@ def program_table(rows: List[Dict[str, Any]], n: int = 10) -> str:
 def setup_report(stats: Dict[str, float], rows: List[Dict[str, Any]]) -> str:
     """What a log says of a set-up: the ``setup/<key>`` of ``stats``, the
     rows that took longest and, where the cache served some programs and
-    not others, the longest of those it had not kept."""
+    not others, the longest of those it had not kept; then, where the
+    process read a device, what its peak is made of (:func:`hbm_report`)."""
     totals = {k: round(v, 3) for k, v in stats.items() if "setup/" in k}
     text = (
         f"set-up ledger: {totals}\nthe longest of {len(rows)} programs "
@@ -996,6 +1040,457 @@ def setup_report(stats: Dict[str, float], rows: List[Dict[str, Any]]) -> str:
             f"cache had not kept them; the longest:\n"
             + program_table(written)
         )
+    account = _hbm["account"]
+    if account is not None:  # the process has closed a step on a device
+        text += "\n" + hbm_report(account, rows)
+    return text
+
+
+# ---------------- HBM ledger ----------------
+
+# Spans whose OPEN and CLOSE take a mark, by name: a request or a phase
+# of the build (what rises inside one and under no marked child is its
+# own, as self seconds are), and the engines' spans that end on a wait
+# the code makes anyway (the bytes a program took are then the device's).
+_HBM_REQUEST_PREFIXES = ("setup:", "mfc:", "param_sync:")
+_HBM_REQUEST_NAMES = frozenset(("fetch", "clear_cache"))
+_HBM_WAIT_NAMES = frozenset(
+    ("generate", "gen_wait", "chunk_wait", "stats_reduce", "stats_sync")
+)
+_HBM_WAIT, _HBM_REQUEST = 1, 2
+BETWEEN_REQUESTS = "between_requests"  # the idle gaps' name for no span
+
+_MARK_CAP = 4096  # marks kept; a serving step takes some hundreds
+_RISE_CAP = 256  # rises kept between two closes
+_OWNER_ROWS = ("weights", "moments", "cache", "other_live")
+_BYTE_COLUMNS = ("code_b", "temp_b", "arg_b", "out_b", "alias_b")
+
+_marks: collections.deque = collections.deque(maxlen=_MARK_CAP)
+
+
+def _fresh_hbm() -> Dict[str, Any]:
+    return {
+        # Handed over by the worker (hbm_readers); None: nothing to read.
+        "reader": None, "programs": None, "owners": None,
+        "last": None,  # the newest mark
+        "rises": collections.deque(maxlen=_RISE_CAP),  # since the last close
+        # Marks since the last close and what they took (the programs'
+        # read, where a mark makes one, is counted by itself).
+        "n": 0, "ns": 0,
+        "before_step": None,  # the peak at the first request's open
+        "programs_seen": 0,  # _setup's program count at the last read
+        "programs_read_s": 0.0,
+        "code": {},  # device -> code bytes of the programs loaded there
+        "account": None,  # made at the process's first close
+        "pending": {},  # hbm/<key> stats not yet taken
+    }
+
+
+_hbm = _fresh_hbm()
+
+
+def _hbm_kind(name: str) -> int:
+    if name in _HBM_WAIT_NAMES:
+        return _HBM_WAIT
+    if name in _HBM_REQUEST_NAMES or name.startswith(_HBM_REQUEST_PREFIXES):
+        return _HBM_REQUEST
+    return 0
+
+
+def _request_of(sp: Optional[_Span]) -> str:
+    """The innermost request or phase from ``sp`` outwards."""
+    while sp is not None:
+        if _hbm_kind(sp.name) == _HBM_REQUEST:
+            return sp.name
+        sp = sp.parent
+    return BETWEEN_REQUESTS
+
+
+def hbm_readers(stats, programs=None, owners=None) -> None:
+    """Hand the HBM ledger its readers of the device (``system/worker.py``
+    does, once its first mesh stands; this module never imports jax).
+
+    ``stats()`` -> ``{device id: device.memory_stats()}`` over every local
+    device of the process's meshes (``{}`` before there is one), or None
+    on a backend that keeps no such counters -- it is then never asked
+    again.  ``programs(expect)`` -> ``(rows, code)``: one row ``{name,
+    code_b, temp_b, arg_b, out_b, alias_b}`` an executable loaded since
+    the last call, oldest first (``name`` may be None where there are
+    ``expect`` of them), and ``{device id: code bytes of everything
+    loaded there}``.  ``owners(device id)`` -> ``{owner: bytes}`` of what the
+    engines keep on that device between calls (``weights``, ``moments``,
+    ``cache``) and ``other_live``, every buffer counted once."""
+    _hbm.update(reader=stats, programs=programs, owners=owners)
+
+
+def _hbm_read(which: str, *args):
+    """Ask one of the worker's readers.  One that raises is not asked
+    again and says so in the flight ring: the ledger's marks sit inside
+    the span machinery, which a reading of the device must never fail."""
+    try:
+        return _hbm[which](*args)
+    except Exception as e:
+        _hbm[which] = None
+        flight_event("hbm_reader_failed", reader=which, error=repr(e))
+        return None
+
+
+def hbm_mark(label: str, opening: Optional[_Span] = None):
+    """Read the device and append ``{label, t_ns, device, peak, in_use,
+    limit}`` to the process's marks: ``t_ns`` on the spans' clock,
+    ``peak`` the largest ``peak_bytes_in_use`` over the local devices and
+    ``device`` the one that holds it, ``in_use`` and ``limit`` of the
+    device fullest NOW (``reserved``, ``peak_reserved`` and
+    ``largest_alloc`` of the peak's device where the runtime gives them:
+    the TPU runtime keeps a program's temporaries in a reserve that
+    ``bytes_in_use`` does not count).  One ``memory_stats()`` call a
+    local device and no wait on any: ``_Span`` calls this at the open and
+    the close of the spans ``_hbm_kind`` names, ``close_step`` at a close.
+
+    A ``peak`` above the mark before is a RISE, owned by the innermost
+    span open over the whole interval between the two marks: on this
+    thread first (``opening``, the span this mark opens, left out), else
+    the newest marked span of another thread, else ``between_requests``.
+    It goes to the step record's ``rises`` and, under a live profiler, an
+    ``areal:hbm_peak`` annotation marks the interval's end.  Returns the
+    mark; None where there is nothing to read."""
+    reader = _hbm["reader"]
+    if reader is None:
+        return None
+    t0 = time.monotonic_ns()
+    devices = _hbm_read("reader")
+    if devices is None:
+        _hbm["reader"] = None
+        return None
+    if not devices:
+        return None
+    top = max(devices, key=lambda d: devices[d]["peak_bytes_in_use"])
+    full = max(devices, key=lambda d: devices[d]["bytes_in_use"])
+    now = time.monotonic_ns()
+    mark = {
+        "label": label, "t_ns": now, "device": top,
+        "peak": devices[top]["peak_bytes_in_use"],
+        "in_use": devices[full]["bytes_in_use"],
+        "limit": devices[full].get("bytes_limit", 0),
+    }
+    for key, name in (("reserved", "bytes_reserved"),
+                      ("peak_reserved", "peak_bytes_reserved"),
+                      ("largest_alloc", "largest_alloc_size")):
+        if name in devices[top]:
+            mark[key] = devices[top][name]
+    ts = _thread_state()
+    with _lock:
+        last, _hbm["last"] = _hbm["last"], mark
+        _marks.append(mark)
+        rise = None
+        if mark["peak"] > (last["peak"] if last is not None else 0):
+            since = last["t_ns"] if last is not None else now
+            owner = _span_open_since(ts, since, opening)
+            rise = {
+                "span": owner.name if owner else BETWEEN_REQUESTS,
+                "request": _request_of(owner),
+                "from": last["peak"] if last is not None else 0,
+                "to": mark["peak"], "device": top,
+                "in_use_before": last["in_use"] if last is not None else 0,
+                "since_ns": since, "t_ns": now,
+            }
+            _hbm["rises"].append(rise)
+        read = _setup["totals"]["programs"] != _hbm["programs_seen"]
+        _hbm["n"] += 1
+        _hbm["ns"] += time.monotonic_ns() - t0
+    if rise is not None:
+        grown = {"span": rise["span"], "from": rise["from"], "to": rise["to"]}
+        # Like `host_pause`: the interval [end - dur_ms, end] has ended.
+        ann = _annotate(
+            "hbm_peak", dict(grown, dur_ms=round((now - since) / 1e6, 3))
+        )
+        if ann:
+            with ann:
+                pass
+        complete("hbm_peak", since, now, cat="host", **grown)
+    if read:
+        _read_programs()
+    return mark
+
+
+def _span_open_since(
+    ts: _ThreadState, since_ns: int, opening: Optional[_Span]
+) -> Optional[_Span]:
+    """The innermost span open since ``since_ns`` or before: of this
+    thread's, else the latest-opened MARKED span of another thread's."""
+    for sp in reversed(ts.stack):
+        if sp is not opening and sp.t0 <= since_ns:
+            return sp
+    best = None
+    for other in list(_threads.values()):
+        if other is ts:
+            continue
+        for sp in reversed(list(other.stack)):
+            if sp.hbm and sp.t0 <= since_ns:
+                if best is None or sp.t0 > best.t0:
+                    best = sp
+                break
+    return best
+
+
+def _hbm_open(sp: _Span) -> None:
+    mark = hbm_mark("open:" + sp.name, opening=sp)
+    if (
+        mark is not None and _hbm["before_step"] is None
+        and sp.hbm == _HBM_REQUEST and not sp.name.startswith("setup:")
+    ):
+        _hbm["before_step"] = mark["peak"]
+
+
+def _alnum(name: str) -> str:
+    return "".join(c for c in name if c.isalnum())
+
+
+def _read_programs() -> None:
+    """Join the executables loaded since the last read to the program
+    ledger's rows of the same interval: the rows gain ``code_b``,
+    ``temp_b``, ``arg_b``, ``out_b`` and ``alias_b``.  In order of
+    loading, which on the chip is the rows' order name for name (PERF.md
+    section 6, PR 66); where the two counts differ the reader is asked
+    for the modules' names (``jit(f)`` is ``jit_f`` there: 4-28 ms a
+    program to read back) and the join is on them.  Called from a mark at
+    which the process's program count has moved, so never where nothing
+    compiles."""
+    reader = _hbm["programs"]
+    with _lock:
+        n = _setup["totals"]["programs"]
+        new = n - _hbm["programs_seen"]
+        _hbm["programs_seen"] = n
+        rows = list(_programs)[-new:] if new > 0 else []
+    if reader is None:
+        return
+    t0 = time.monotonic()
+    loaded, code = _hbm_read("programs", len(rows)) or ([], {})
+    if len(loaded) == len(rows):  # as a rule: one executable a row
+        for row, exe in zip(rows, loaded):
+            row.update({k: exe[k] for k in _BYTE_COLUMNS})
+    else:
+        left = [(_alnum(exe["name"]), exe) for exe in loaded]
+        for row in rows:
+            key = _alnum(row["fun"])
+            for i, (name, exe) in enumerate(left):
+                if name == key:
+                    row.update({k: exe[k] for k in _BYTE_COLUMNS})
+                    del left[i]
+                    break
+    with _lock:
+        _hbm["code"] = code
+        _hbm["programs_read_s"] += time.monotonic() - t0
+
+
+def _hbm_close() -> Optional[Dict[str, Any]]:
+    """The step record's ``hbm``: the closing mark's ``in_use``, ``peak``
+    and ``device``, the ``rises`` since the close before (none in a steady
+    step), the marks taken and their seconds; ``owners`` (the owners'
+    reader: a walk over the live arrays) from the first close and from a
+    step whose peak rose, and from the first close the ``account``.  The
+    ``hbm/<key>`` stats wait for :func:`hbm_take`."""
+    mark = hbm_mark("close_step")
+    if mark is None:
+        return None
+    with _lock:
+        rises = list(_hbm["rises"])
+        _hbm["rises"].clear()
+        n, ns = _hbm["n"], _hbm["ns"]
+        _hbm["n"] = _hbm["ns"] = 0
+        first = _hbm["account"] is None
+    record = {
+        "in_use": mark["in_use"], "peak": mark["peak"],
+        "device": mark["device"], "rises": rises, "marks": n,
+        "mark_s": ns / 1e9,
+    }
+    stats = {
+        "hbm/in_use_gb": mark["in_use"] / 1e9,
+        "hbm/peak_gb": mark["peak"] / 1e9,
+        "hbm/peak_rise_gb": sum(r["to"] - r["from"] for r in rises) / 1e9,
+        "hbm/marks": float(n),
+        "hbm/mark_s": ns / 1e9,
+    }
+    for key in ("reserved", "peak_reserved"):
+        if key in mark:
+            record[key] = mark[key]
+            stats[f"hbm/{key}_gb"] = mark[key] / 1e9
+    if first or rises:
+        t0 = time.monotonic()
+        record["owners"] = _read_owners(mark["device"])
+        record["owners_read_s"] = time.monotonic() - t0
+    if first:
+        record["account"] = _hbm["account"] = _account(
+            mark, record["owners"], rises
+        )
+        stats.update(_account_stats(_hbm["account"]))
+        stats["hbm/owners_read_s"] = record["owners_read_s"]
+    with _lock:
+        _hbm["pending"] = stats
+    return record
+
+
+def _read_owners(device) -> Dict[str, int]:
+    owners = dict.fromkeys(_OWNER_ROWS, 0)
+    if _hbm["owners"] is not None:
+        owners.update(_hbm_read("owners", device) or {})
+    return owners
+
+
+def _account(
+    mark: Dict[str, Any], owners: Dict[str, int], rises: List[Dict[str, Any]]
+) -> Dict[str, Any]:
+    """What the process's peak is made of, as far as the program can say:
+    the span that set it (the newest run of one span's rises is "the
+    peak's interval"), then ``rows``: the owners' bytes at this close,
+    ``code`` (the programs loaded on the peak's device), ``temp`` (the
+    largest temporaries among the programs loaded inside the peak's
+    interval -- in a first step a program is loaded where it first runs
+    -- else among those the peak's request ran, and no more than the peak
+    stands over what was in use when the interval began) and
+    ``unaccounted``, the
+    remainder, whatever its sign; beside them ``released`` (what was in
+    use when the peak's interval began and is gone at this close) and the
+    runtime's ``reserved`` bytes, which the peak does not hold."""
+    with _lock:
+        programs = list(_programs)
+        code = _hbm["code"].get(mark["device"], 0)
+    temps: Dict[str, int] = {}
+    for row in programs:
+        if "temp_b" in row:
+            temps[row["request"]] = max(
+                temps.get(row["request"], 0), row["temp_b"]
+            )
+    run: List[Dict[str, Any]] = []
+    for r in reversed(rises):
+        if run and (r["span"], r["request"]) != (
+            run[0]["span"], run[0]["request"]
+        ):
+            break
+        run.insert(0, r)
+    span, request, since, until, before = (
+        (run[0]["span"], run[0]["request"], run[0]["since_ns"],
+         run[-1]["t_ns"], run[0]["in_use_before"])
+        if run else (BETWEEN_REQUESTS, BETWEEN_REQUESTS, 0, mark["t_ns"], 0)
+    )
+    during = [
+        row["temp_b"] for row in programs
+        if "temp_b" in row and since <= row["t_ns"] <= until
+    ]
+    rows = dict(owners)
+    rows["code"] = code
+    # (no more of them than the peak stands over what was in use when its
+    # interval began: temporaries in the runtime's reserve are not in it)
+    rows["temp"] = min(
+        max(during) if during else temps.get(request, 0),
+        max(mark["peak"] - before, 0),
+    )
+    rows["unaccounted"] = mark["peak"] - sum(rows.values())
+    return {
+        "peak": mark["peak"], "device": mark["device"],
+        "span": span, "request": request,
+        "t_s": (until - _process_start_ns()) / 1e9,
+        "before_step": _hbm["before_step"] or 0,
+        "rows": rows, "temps": temps,
+        "released": max(before - mark["in_use"], 0),
+        "reserved": mark.get("peak_reserved", mark.get("reserved")),
+        "programs_read_s": _hbm["programs_read_s"],
+    }
+
+
+def _account_stats(account: Dict[str, Any]) -> Dict[str, float]:
+    """The first close's own ``hbm/<key>``: ``peak_before_step_gb``,
+    ``peak_step1_gb``, a ``<row>_gb`` each row of the account,
+    ``released_gb``, ``temp_max_gb`` and ``temp_gb/<request>`` (the
+    largest temporaries of all programs and of each request's),
+    ``programs_read_s`` (and ``owners_read_s``: what the two readers cost
+    the set-up)."""
+    out = {
+        "hbm/released_gb": account["released"] / 1e9,
+        "hbm/peak_before_step_gb": account["before_step"] / 1e9,
+        "hbm/peak_step1_gb": account["peak"] / 1e9,
+        "hbm/temp_max_gb": max(account["temps"].values(), default=0) / 1e9,
+        "hbm/programs_read_s": account["programs_read_s"],
+    }
+    for name, b in account["rows"].items():
+        out[f"hbm/{name}_gb"] = b / 1e9
+    for request, b in account["temps"].items():
+        out[f"hbm/temp_gb/{request}"] = b / 1e9
+    return out
+
+
+def hbm_take() -> Dict[str, float]:
+    """``hbm/<key>`` stats of the last close, once: ``in_use_gb``,
+    ``peak_gb``, ``peak_rise_gb`` (this step's: a timed step that raises
+    the process's peak is a finding), ``marks`` and ``mark_s`` (what the
+    ledger cost the step) and, after the process's FIRST close alone,
+    :func:`_account_stats`.  ``close_step`` returns them; a worker in a
+    process of its own adds them to its next reply.  Empty where there
+    is nothing to read (a CPU backend)."""
+    with _lock:
+        out, _hbm["pending"] = _hbm["pending"], {}
+    return out
+
+
+def hbm_marks() -> List[Dict[str, Any]]:
+    """The last marks, oldest first."""
+    return list(_marks)
+
+
+def _hbm_snapshot() -> Optional[Dict[str, Any]]:
+    """For a flight dump: the newest marks, the rises not yet closed and
+    who owns what on the peak's device now."""
+    last = _hbm["last"]
+    if last is None:
+        return None
+    return {
+        "marks": list(_marks)[-64:], "rises": list(_hbm["rises"]),
+        "owners": _read_owners(last["device"]),
+    }
+
+
+def byte_table(rows: List[Dict[str, Any]], column: str, n: int = 10) -> str:
+    """The ``n`` rows with the most bytes in ``column``, one a line."""
+    have = [r for r in rows if column in r]
+    return "\n".join(
+        "  " + " ".join(
+            f"{c[:-2]} {r[c] / 1e6:9.1f} MB" for c in _BYTE_COLUMNS
+        ) + f"  {r['fun']}  [{r['request']}]"
+        for r in sorted(have, key=lambda r: r[column], reverse=True)[:n]
+    )
+
+
+def hbm_report(account: Dict[str, Any], rows: List[Dict[str, Any]]) -> str:
+    """What a log says of the process's peak: the account's rows, the
+    remainder among them, and the programs with the largest temporaries
+    and the most code."""
+    gb = 1e9
+    text = (
+        f"HBM ledger: peak {account['peak'] / gb:.4f} GB on device "
+        f"{account['device']}, set inside `{account['span']}` (request "
+        f"`{account['request']}`) {account['t_s']:.1f} s after the "
+        f"process's start; {account['before_step'] / gb:.4f} GB before the "
+        f"first request\n"
+        + "\n".join(
+            f"  {name:<12s}{b / gb:9.4f} GB"
+            for name, b in account["rows"].items()
+        )
+        + f"\n  (of the remainder {account['released'] / gb:.4f} GB were in "
+        f"use when the peak's interval began and are released since"
+    )
+    if account["reserved"] is not None:
+        text += (
+            f"; the runtime's reserve, which `peak_bytes_in_use` does not "
+            f"hold, stood at {account['reserved'] / gb:.4f} GB at most"
+        )
+    text += ")"
+    joined = [r for r in rows if "temp_b" in r]
+    text += (
+        f"\n{len(joined)} of {len(rows)} programs have their bytes (read in "
+        f"{account['programs_read_s']:.3f} s); the largest temporaries:\n"
+        + byte_table(rows, "temp_b")
+        + "\nthe most code:\n" + byte_table(rows, "code_b")
+    )
     return text
 
 
@@ -1087,6 +1582,8 @@ def _reset_for_tests() -> None:
         _ledger_state.update(t_close_ns=None, dump_step=None)
         _programs.clear()
         _setup.update(_fresh_setup())
+        _marks.clear()
+        _hbm.update(_fresh_hbm())
         for ts in _threads.values():
             ts.stack.clear()
             ts.ledger.clear()

@@ -2801,3 +2801,23 @@ def assemble_rollout(
             ),
         },
     )
+
+
+def _hbm_owned(self) -> Dict[str, Any]:
+    """`HostOffloadMixin.hbm_owned`, with the `cache` a parked session
+    keeps between calls (page pool, recurrent state and conv tails inside
+    it, the logits and token buffers); a call that ran to its end keeps
+    none.  Down here, and not in the class, so that no line above moves:
+    a Mosaic kernel's module carries its callers' lines into the compile
+    cache's key."""
+    parked = [s for s in (self._session, self._ep_session) if s is not None]
+    return {
+        "weights": self.params,
+        "cache": [
+            (s.pool, s.logits_buf, s.tokens_buf, s.pending_tok)
+            for s in parked
+        ],
+    }
+
+
+GeneratorEngine.hbm_owned = _hbm_owned

@@ -116,3 +116,10 @@ class HostOffloadMixin:
         self._host_offload = None
         self._offload_shardings = None
         self._restore_state(state)
+
+    def hbm_owned(self) -> Dict[str, Any]:
+        """What this engine keeps on the device between calls, by owner,
+        for the worker's HBM ledger (`system/worker._hbm_owners` counts
+        the bytes, each buffer once): `weights` here; `moments` and
+        `cache` where an engine has them."""
+        return {"weights": self.params}

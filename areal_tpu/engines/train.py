@@ -1157,3 +1157,8 @@ class TrainEngine(HostOffloadMixin, Engine):
             host,
             self.opt_state,
         )
+
+    def hbm_owned(self) -> Dict[str, Any]:
+        """`HostOffloadMixin.hbm_owned`, with Adam's moments (and whatever
+        else the optimizer's state holds)."""
+        return {"weights": self.params, "moments": self.opt_state}

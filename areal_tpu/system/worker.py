@@ -14,6 +14,7 @@ import functools
 import os
 import threading
 import time
+import weakref
 from typing import Any, Dict, List, Optional
 
 import numpy as np
@@ -78,6 +79,156 @@ def _install_compile_listener() -> None:
     jax.monitoring.register_event_duration_secs_listener(tracer.program_event)
     jax.monitoring.register_event_listener(tracer.program_event)
     _compile_listener_installed = True
+
+
+# What the tracer's HBM ledger reads the device with (base/tracer.
+# hbm_readers): the local devices of every mesh a worker of this process
+# built, the workers whose engines own what lies there, and the loaded
+# executables already measured, (id, fingerprint) -> (code bytes, device
+# ids).  Nothing is kept on a backend without `memory_stats()` (CPU).
+_hbm_devices: Dict[int, Any] = {}
+_hbm_workers: "weakref.WeakSet[ModelWorker]" = weakref.WeakSet()
+_hbm_programs_seen: Dict[Any, Any] = {}
+
+
+def _hbm_watch(worker: "ModelWorker", mesh) -> None:
+    """Put `mesh`'s local devices and `worker`'s engines under the HBM
+    ledger's marks."""
+    import jax
+
+    local = {
+        d.id: d for d in mesh.devices.flat
+        if d.process_index == jax.process_index()
+    }
+    _hbm_devices.update(local)
+    if _hbm_stats() is None:
+        for i in local:
+            del _hbm_devices[i]
+        return
+    _hbm_workers.add(worker)
+    tracer.hbm_readers(_hbm_stats, _hbm_programs, _hbm_owners)
+
+
+def _hbm_stats() -> Optional[Dict[int, Dict[str, int]]]:
+    """`memory_stats()` of every watched device by id; None where the
+    backend keeps none (CPU)."""
+    out = {i: d.memory_stats() for i, d in _hbm_devices.items()}
+    return None if None in out.values() else out
+
+
+def _hbm_programs(expect: int):
+    """(a row of bytes each executable loaded since the last call, oldest
+    first; code bytes of everything loaded, by device): `client.
+    live_executables()` lists the newest first, and an executable is
+    measured (`get_compiled_memory_stats()`, 0.1-1 ms) once.  Its
+    module's name is read back (`hlo_modules()`: 4-28 ms a program on the
+    chip, PERF.md section 6, PR 66) only where the new ones are not the
+    `expect` the program ledger counted: their order is then not enough
+    to join them to its rows."""
+    global _hbm_programs_seen
+    client = next(iter(_hbm_devices.values())).client
+    fresh, live, code = [], {}, dict.fromkeys(_hbm_devices, 0)
+    for exe in client.live_executables():
+        key = (id(exe), exe.fingerprint)
+        known = _hbm_programs_seen.get(key)
+        if known is None:
+            ms = exe.get_compiled_memory_stats()
+            fresh.append((exe, ms))
+            known = (
+                ms.generated_code_size_in_bytes,
+                [d.id for d in exe.local_devices()],
+            )
+        live[key] = known
+        for i in known[1]:
+            if i in code:
+                code[i] += known[0]
+    _hbm_programs_seen = live  # what was freed since goes with its id
+    named = len(fresh) != expect
+    new = [
+        {
+            "name": exe.hlo_modules()[0].name if named else None,
+            "code_b": ms.generated_code_size_in_bytes,
+            "temp_b": ms.temp_size_in_bytes,
+            "arg_b": ms.argument_size_in_bytes,
+            "out_b": ms.output_size_in_bytes,
+            "alias_b": ms.alias_size_in_bytes,
+        }
+        for exe, ms in reversed(fresh)
+    ]
+    return new, code
+
+
+def _hbm_buffers(a, device_id: int):
+    """(identity, bytes) of each buffer of `a` on one device, WITHOUT
+    making a per-shard view of it: `addressable_shards` on a sharded array
+    makes one live array a shard and keeps them with it, and after a walk
+    that did so over every live array the four-chip cell's generator
+    weights outlived `release_params()` by 1.48 GB a chip a step until the
+    chip refused the gradient program's reserve (PERF.md section 6,
+    PR 66).  An unsharded array is known by its buffer's pointer; a
+    sharded one by the views something else already made of it
+    (`offload.buffers_alias` does, of the weights) or else by itself, an
+    even share of its bytes on every device that holds it."""
+    local = a.sharding.addressable_devices
+    if not any(d.id == device_id for d in local):
+        return []
+    if len(a.sharding.device_set) == 1:
+        return [(a.unsafe_buffer_pointer(), a.on_device_size_in_bytes())]
+    views = a.__dict__.get("addressable_shards")
+    if views is None:
+        return [(id(a), a.on_device_size_in_bytes() // len(local))]
+    return [
+        (v.data.unsafe_buffer_pointer(), v.data.on_device_size_in_bytes())
+        for v in views if v.device.id == device_id
+    ]
+
+
+def _hbm_owners(device_id: int) -> Dict[str, int]:
+    """Bytes on one device by owner: what each engine of this process says
+    it keeps between calls (`hbm_owned()`: weights, moments, cache), then
+    `other_live`, every other live array.  A buffer is counted once, for
+    the first owner that shows it: a colocated generator's weights that
+    alias the trainer's are the trainer's."""
+    import jax
+
+    seen = set()
+
+    def count(arrays) -> int:
+        n = 0
+        for a in arrays:
+            if not isinstance(a, jax.Array) or a.is_deleted():
+                continue
+            try:
+                buffers = _hbm_buffers(a, device_id)
+            except RuntimeError:  # donated while we walked: not live
+                continue
+            for key, size in buffers:
+                if key not in seen:
+                    seen.add(key)
+                    n += size
+        return n
+
+    out: Dict[str, int] = {}
+    for worker in list(_hbm_workers):
+        for model in worker.models.values():
+            owned = getattr(model.engine, "hbm_owned", None)
+            for name, tree in (owned() if owned else {}).items():
+                out[name] = out.get(name, 0) + count(jax.tree.leaves(tree))
+    out["other_live"] = count(jax.live_arrays())
+    return out
+
+
+def _hbm_perf(mark: Optional[Dict[str, Any]]) -> Dict[str, float]:
+    """Device memory after a request, from its span's closing mark: of
+    the fullest local device (reference: per-worker GPU mem/util tables,
+    model_worker.py:1434-1537).  Empty where the backend has no
+    `memory_stats()` (CPU)."""
+    if not mark:
+        return {}
+    perf = {"perf/hbm_gb": mark["in_use"] / 1e9}
+    if mark["limit"]:
+        perf["perf/hbm_frac"] = mark["in_use"] / mark["limit"]
+    return perf
 
 
 def _zero_filled(meta_row: SequenceSample, keys) -> SequenceSample:
@@ -288,6 +439,7 @@ class ModelWorker:
             with tracer.setup_span("mesh", model=key):
                 devices = jax.devices()[off : off + shard.parallel.world_size]
                 mesh = make_mesh(shard.parallel, devices)
+                _hbm_watch(self, mesh)
             with tracer.setup_span("weights", model=key):
                 cfg, params = _build_params_and_config(
                     shard.model, seed=self.config.seed, mesh=mesh
@@ -582,6 +734,8 @@ class ModelWorker:
 
         # Seconds of the handler's own: the mfc span under no child span.
         perf["perf/self_s"] = mfc_span.self_ns / 1e9
+        perf.update(_hbm_perf(mfc_span.mark))
+        _check_hbm_kill(perf)
         perf.update(self._own_host_record())
         if out_sample is not None:
             with tracer.span("mfc_scatter", cat="host", **_step(req)):
@@ -598,12 +752,15 @@ class ModelWorker:
     @staticmethod
     def _own_host_record() -> Dict[str, float]:
         """`host/<key>` stats (and, in the process's first reply,
-        `setup/<key>`) for an MFC's reply where this worker runs in a
-        process of its own; under the master's roof the master's step
-        close reports the one host watch and the one set-up they share."""
+        `setup/<key>`; `hbm/<key>` of the step closed last) for an MFC's
+        reply where this worker runs in a process of its own; under the
+        master's roof the master's step close reports the one host watch,
+        the one set-up and the one HBM ledger they share."""
         if tracer.role() == "master":
             return {}
-        return {**tracer.host_take(), **tracer.setup_take()}
+        return {
+            **tracer.host_take(), **tracer.setup_take(), **tracer.hbm_take()
+        }
 
     # ------------- pipeline-overlapped train stream -------------
     #
@@ -722,6 +879,7 @@ class ModelWorker:
             seconds = time.monotonic() - t0
         busy = st["busy_s"] + seconds
         perf = {"perf/time_s": busy, "perf/self_s": mfc_span.self_ns / 1e9}
+        perf.update(_hbm_perf(mfc_span.mark))
         perf.update(self._own_host_record())
         for k, v in tracer.take_compiles().items():
             perf[k] = st["compiles"].get(k, 0.0) + v
@@ -834,21 +992,8 @@ class ModelWorker:
                 u = monitor.mfu(flops, seconds, n_dev)
                 if u is not None:
                     perf["perf/mfu"] = u
-            # Device memory after the MFC (reference: per-worker GPU
-            # mem/util tables, model_worker.py:1434-1537).  TPU runtimes
-            # expose bytes_in_use/bytes_limit via memory_stats(); CPU
-            # devices return None.
-            if getattr(model.engine, "mesh", None) is not None:
-                stats = model.engine.mesh.devices.flat[0].memory_stats()
-                if stats and "bytes_in_use" in stats:
-                    perf["perf/hbm_gb"] = stats["bytes_in_use"] / 1e9
-                    if stats.get("bytes_limit"):
-                        perf["perf/hbm_frac"] = (
-                            stats["bytes_in_use"] / stats["bytes_limit"]
-                        )
         except Exception as e:  # perf accounting must never fail the MFC
             logger.warning(f"perf accounting failed: {e!r}")
-        _check_hbm_kill(perf)
         return perf
 
     # ---------------- cross-worker transfer plane ----------------
@@ -1155,10 +1300,11 @@ class ModelWorker:
         return {"accuracy": out}
 
     def _handle_clear_cache(self, req):
-        keep = set(req.get("keep_ids", ()))
-        for sid in list(self.data_cache):
-            if sid not in keep:
-                del self.data_cache[sid]
+        with tracer.span("clear_cache", cat="host", **_step(req)):
+            keep = set(req.get("keep_ids", ()))
+            for sid in list(self.data_cache):
+                if sid not in keep:
+                    del self.data_cache[sid]
         # Once-per-step broadcast from the master: a natural trace flush
         # point so shards stay current even if the worker later crashes,
         # and where a worker in a process of its own closes its step

@@ -10,9 +10,14 @@ line shows them only in a traced run, and only as shares.  This runs
 `benchmark.run.main` unchanged, in this process, and then writes
 `chiprun_out/stall/<cell>_<seed>_t<trace>.json`: per timed step the
 benchmark's own `wall_s` and host watch beside the program's `host/*`,
-`time/*` and `*/perf/self_s` stats, the ledger's closed steps (seconds
-and self seconds per span name), and every `host_pause` and `slow_step`
-flight event, whole.  PERF.md section 6 (PR 36) has the table made of it.
+`time/*`, `hbm/*` and `*/perf/self_s` stats, the warm-up step's the same
+(its `hbm/*` hold the account of the peak), the ledger's closed steps
+(seconds and self seconds per span name, the programs' rows, the `hbm`
+record with its rises), the newest HBM marks, the harness's own
+`memory_peak_bytes`, the nine `hbm_*` readers of `benchmark/metrics/`
+(no `BENCHMARK.json` entry carries them yet) and every `host_pause` and
+`slow_step` flight event, whole.  PERF.md section 6 (PR 36, PR 66) has
+the tables made of it.
 
 `--span-cost` times a span (tracing as `AREAL_TRACE` says) and one reading
 of the host watch, on this host, and runs nothing else.
@@ -25,7 +30,19 @@ import timeit
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-KEEP = ("host/", "time/")
+KEEP = ("host/", "time/", "hbm/")
+HBM_READERS = (
+    "hbm_weights_gb", "hbm_moments_gb", "hbm_cache_gb", "hbm_other_live_gb",
+    "hbm_code_gb", "hbm_step_temp_gb", "hbm_peak_before_step_gb",
+    "hbm_unaccounted_gb", "hbm_peak_rise_in_window_gb",
+)
+
+
+def kept(stats):
+    return {
+        k: v for k, v in stats.items()
+        if k.startswith(KEEP) or k.endswith("perf/self_s")
+    }
 
 
 def span_cost():
@@ -74,14 +91,18 @@ def main():
             "wall_s": s["wall_s"],
             "bench_host": s["host"],
             "bench_spans": s["spans"],
-            "stats": {
-                k: v for k, v in s["stats"].items()
-                if k.startswith(KEEP) or k.endswith("perf/self_s")
-            },
+            "stats": kept(s["stats"]),
         })
     out = {
         "cell": run.cell_name, "seed": run.seed, "traced": run.traced,
         "steps": steps,
+        "warmup": kept((run.warmup or {}).get("stats", {})),
+        "memory_peak_bytes": run.peak_bytes,
+        "hbm_readers": {
+            name: bench.files.load_module("metrics", name).read(run)
+            for name in HBM_READERS
+        },
+        "hbm_marks": getattr(tracer, "hbm_marks", list)()[-256:],
         # (a program from before PR 36 has no ledger: the probe then keeps
         # the benchmark's own record alone)
         "ledger": getattr(tracer, "step_ledger", list)(),

@@ -467,8 +467,11 @@ def test_the_ledger_has_a_row_a_program_under_the_span_that_compiled(trial):
     # file's trial may have compiled it)
     assert {"grad_dispatch", "apply_dispatch"} <= spans
     for row in step1["programs"] + step3["programs"]:
+        # (no byte columns: a CPU backend hands the HBM ledger no reader)
         assert set(row) == {"fun", "trace_s", "lower_s", "compile_s",
-                            "cache_load_s", "hit", "written", "span"}
+                            "cache_load_s", "hit", "written", "span",
+                            "request", "t_ns"}
+        assert row["request"].startswith(("mfc:", "setup:")), row
         assert row["hit"] == (row["cache_load_s"] > 0)
         assert not (row["hit"] and row["written"])
     # Step 3 built the generator's chunk again: traced and lowered in
