@@ -2413,9 +2413,9 @@ class GeneratorEngine(HostOffloadMixin, Engine):
 
         `src`: for each prompt the first row that carries the SAME prompt
         (`src[r] <= r`, `src[src[r]] == src[r]`; None: every row its own).
-        Where the prefill goes in waves a repeated prompt is prefilled once
-        and lands at every row of its group (`_shared_rows`); the rows
-        still sample apart.
+        Where the program shares a prefill (`_shared_rows`) a repeated
+        prompt is prefilled once and lands at every row of its group; the
+        rows still sample apart.
 
         `with_cache`: also the `KVCache` the program leaves, on the device
         (one more output of the same program, for a check that holds the
@@ -2445,7 +2445,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             raise ValueError(
                 f"src {list(src)} names a row that does not come first or "
                 f"does not carry the same prompt")
-        src = self._shared_rows(b, sp, src)
+        src = self._shared_rows(b, sp, gconfig.max_new_tokens, src)
         fn = self._get_gen_fn(b, sp, s_total, gconfig, with_cache, src)
         prefilled = b_real if src is None else len(set(src[:b_real]))
         stats = self.last_pool_stats
@@ -2515,18 +2515,18 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             cache = tfm.init_kv_cache(cfg, bsz, s_total, dtype=self.compute_dtype)
             # prefill returns logits at each row's last prompt token — the
             # distribution over the first response token.
-            if wave == bsz:
+            if src is not None:
+                logits0, cache = self._prefill_distinct(
+                    params, prompt_tok, seg, cache, src
+                )
+            elif wave == bsz:
                 logits0, cache = tfm.prefill(
                     params, cfg, prompt_tok, seg, cache,
                     use_flash=self._use_flash,
                 )
-            elif src is None:
+            else:
                 logits0, cache = self._prefill_in_waves(
                     params, prompt_tok, seg, cache, wave
-                )
-            else:
-                logits0, cache = self._prefill_distinct(
-                    params, prompt_tok, seg, cache, src
                 )
 
             out_toks = jnp.zeros((bsz, max_new), jnp.int32)
@@ -2615,12 +2615,21 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             default=1,
         )
 
-    def _shared_rows(self, b: int, sp: int, src) -> Optional[Tuple[int, ...]]:
+    def _shared_rows(
+        self, b: int, sp: int, max_new: int, src
+    ) -> Optional[Tuple[int, ...]]:
         """Each of the `b` rows' source row where the program shares a
-        prefill, else None: only the wave path shares, and only where a
-        row repeats, so every other batch keeps the program it had.  Rows
-        past `src` (the pad to the batch sharding) are their own source."""
-        if src is None or self._prefill_wave_rows(b, sp) == b:
+        prefill, else None.  It shares where the caller says which rows
+        repeat (`src`), one does, and the prefill is what the call spends:
+        the batch goes in waves, or it fits one and the prompt bucket is
+        longer than the decode budget (`sp > max_new`) on one device (a
+        mesh shards the batch axis the landing slices).  Every other batch
+        keeps the program it had.  Rows past `src` (the pad to the batch
+        sharding) are their own source."""
+        if src is None:
+            return None
+        heavy = self.mesh.size == 1 and sp > max_new
+        if self._prefill_wave_rows(b, sp) == b and not heavy:
             return None
         src = tuple(int(s) for s in src)
         src += tuple(range(len(src), b))

@@ -5,6 +5,12 @@ every row, at a benchmark cell's shape and published widths.
     chiprun -- python3 scripts/prefill_share_check.py run mellum2,lfm2,sala
     python3 scripts/prefill_share_check.py compile lfm2        # no chip
 
+Three cells prefill in waves; `q1p5b` (`q1p5b-train-longprompt`) fits ONE
+prefill and shares because its prompt bucket is longer than its decode budget
+(`GeneratorEngine._shared_rows`): four rows in the matmuls where the unshared
+program has sixteen, so there the rows' bits may part (a matmul rounds a row
+by the rows beside it) and `run` says by how much instead of `equal True`.
+
 `run` (the chip, about a minute and a half a cell): one engine over random
 weights of the cell's seed, the cell's count of prompts x its group, both
 programs on one key, twice each.  A line a call (seconds, `prefill_rows` of
@@ -12,7 +18,8 @@ programs on one key, twice each.  A line a call (seconds, `prefill_rows` of
 leaf of the `with_cache` cache EQUAL, bit for bit — the step at which each
 row's tokens part where they do not, and the rows whose prompt slots differ
 (prefill) as against those that part later (the decode loop).  Exit code 1
-unless every cell is equal.
+unless every cell that prefills in waves is equal, and every other cell's
+prompt slots lie within 5% of their mean size (bf16 sums in another order).
 
 `compile` (a described v5e, no chip, no time in it): both programs compiled
 with XLA:TPU, their temporaries, and the compiled DECODE LOOP's body with
@@ -37,27 +44,36 @@ CELLS = {
     "mellum2": ("mellum2-12b-a2.5b-l4-e16", "rollout32-ctx4k-512"),
     "lfm2": ("lfm2-8b-a1b-e8", "rollout32-ctx4k-512"),
     "sala": ("minicpm-sala-l4-v8", "rollout8-ctx9k-14k-256"),
+    "q1p5b": ("qwen2.5-math-1.5b", "long-prompts-short-answers"),
 }
 
 
 def cell(name, toy):
-    """(ModelConfig, the config file, prompt lengths longest first, the
-    group, new tokens) of a cell; `toy`: the files' toy sizes and a wave
-    budget that small batches pass."""
+    """(ModelConfig, the config file, the cell's prompt lengths longest
+    first, the group, new tokens) of a cell; `toy`: the files' toy sizes
+    and, where the cell's own batch goes in waves, a wave budget that the
+    toy batch passes too."""
     from areal_tpu.engines import generator
     from benchmark import files, run as bench_run
+    from benchmark.traffic.math_prompts import quantile_lengths
 
     config = files.load_json("configs", CELLS[name][0] + ".json")
     traffic = files.load_json("traffic", CELLS[name][1] + ".json")
+
+    def lengths(t):
+        return sorted(
+            quantile_lengths(t["prompt_len"], t["n_prompts"]), reverse=True)
+
+    in_waves = (
+        traffic["n_prompts"] * traffic["group"]
+        * generator.bucket_len(lengths(traffic)[0])
+        > generator.PREFILL_WAVE_TOKENS)
     if toy:
         config, traffic = bench_run.toy(config, traffic)
-        generator.PREFILL_WAVE_TOKENS = 256
-    span = traffic["prompt_len"]
-    top = min(span["hi"], traffic["dataset_max_length"] - traffic["max_new_tokens"])
-    lens = [int(top - i * (top - span["lo"]) / traffic["n_prompts"])
-            for i in range(traffic["n_prompts"])]
-    return (bench_run.model_config(config), config, lens, traffic["group"],
-            traffic["max_new_tokens"])
+        if in_waves:
+            generator.PREFILL_WAVE_TOKENS = 256
+    return (bench_run.model_config(config), config, lengths(traffic),
+            traffic["group"], traffic["max_new_tokens"])
 
 
 # ----------------------------------------------------------------- the chip
@@ -116,7 +132,19 @@ def run(name, toy):
           f" {part}; rows whose prompt slots differ {prompt}; first "
           f"log-probs differ by {float(np.abs(own[1][:, 0] - shared[1][:, 0]).max())}",
           flush=True)
-    return equal
+    if eng._prefill_wave_rows(len(rows), sp) < len(rows):
+        return equal
+    # One prefill of sixteen rows against one of four: the same mathematics
+    # through matmuls of another height.  How far the PROMPT's slots lie
+    # apart (past them the rows hold other tokens once a sample parts).
+    far = max(
+        float(np.abs(a[:, :, :sp] - b[:, :, :sp]).mean()
+              / max(np.abs(a[:, :, :sp]).mean(), 1e-30))
+        for a, b in zip(own[3:], shared[3:])
+        if a.ndim == 5 and a.shape[2] > sp)
+    print(f"[share] {name} fits one prefill: bits may part; the prompts' "
+          f"slots differ by {far:.3g} of their mean size", flush=True)
+    return far < 0.05
 
 
 # ------------------------------------------------------- a described v5e
@@ -193,16 +221,22 @@ def compile_both(name, toy):
     g = GenerationHyperparameters(n=1, max_new_tokens=new)
     bodies = {}
     for form, src in (("own", None), ("shared", [r - r % n for r in range(b)])):
-        fn = eng._get_gen_fn(b, sp, st, g, False, eng._shared_rows(b, sp, src))
+        shared = eng._shared_rows(b, sp, new, src)
+        fn = eng._get_gen_fn(b, sp, st, g, False, shared)
         t0 = time.monotonic()
         compiled = fn.lower(
             params, placed((b, sp), jnp.int32), placed((b,), jnp.int32),
             placed((2,), jnp.uint32)).compile()
         text = compiled.as_text()
         bodies[form] = text
-        print(f"[share] {name} {form}: compiled in {time.monotonic() - t0:.0f} s"
-              f", temporaries {compiled.memory_analysis().temp_size_in_bytes / 1e9:.3f}"
-              f" GB, {len(re.findall(r' while[(]', text))} loops", flush=True)
+        mem = compiled.memory_analysis()
+        print(f"[share] {name} {form} [{b}, {sp}] + {new}, prefills "
+              f"{b if shared is None else len(set(shared))} rows: compiled in "
+              f"{time.monotonic() - t0:.0f} s, temporaries "
+              f"{mem.temp_size_in_bytes / 1e9:.3f} GB, code "
+              f"{mem.generated_code_size_in_bytes / 1e6:.1f} MB, arguments "
+              f"{mem.argument_size_in_bytes / 1e9:.3f} GB, "
+              f"{len(re.findall(r' while[(]', text))} loops", flush=True)
     for spaces in (True, False):
         a, c = (decode_body(bodies[f], spaces) for f in ("own", "shared"))
         print(f"[share] {name} decode loop body, "

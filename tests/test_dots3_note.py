@@ -551,20 +551,25 @@ def test_the_program_itself_under_a_control_is_refused(cfg, params):
             assert d.mean() > _FP32["mean_abs"] or d.max() > _FP32["max_abs"]
 
 
-@pytest.mark.parametrize("wave_tokens", [None, 128],
-                         ids=["one_prefill", "waves"])
+@pytest.mark.parametrize("wave_tokens,rows", [(None, 8), (None, 2), (128, 2)],
+                         ids=["every_row", "one_prefill", "waves"])
 def test_what_the_generators_own_program_leaves_is_the_references(
-        cfg, params, monkeypatch, wave_tokens):
+        cfg, params, monkeypatch, wave_tokens, rows):
     """`check_generator` on the CPU: the static program over two prompts
     cut from the sequence, four rows each, its caches and its selection
-    under the `fp32` limits — with every row prefilled, and (`waves`: as
-    the cell's 13 k-token prompts go) with a prompt prefilled once and
-    landed at its group's rows, the last compared slot a landed copy; with
-    the ring read a slot off it is refused."""
+    under the `fp32` limits — with every row prefilled (`every_row`: the
+    check's `src` withheld), and with a prompt prefilled once and landed at
+    its group's rows, the last compared slot a landed copy: in ONE prefill,
+    its 128 slots of prompt against 8 new tokens (`one_prefill`), and in
+    waves, as the cell's 13 k-token prompts go; with the ring read a slot
+    off it is refused."""
     from areal_tpu.engines import generator
 
     if wave_tokens:
         monkeypatch.setattr(generator, "PREFILL_WAVE_TOKENS", wave_tokens)
+    if rows == 8:
+        monkeypatch.setattr(
+            generator.GeneratorEngine, "_shared_rows", lambda *a: None)
     monkeypatch.setattr(reference, "CHECK_NEW", 8)
     built, build = [], reference._engine
     monkeypatch.setattr(
@@ -572,7 +577,7 @@ def test_what_the_generators_own_program_leaves_is_the_references(
     seq = _sequences(cfg, lens=(120,), seed=9)[0]
     readings, problems = reference.check_generator(params, cfg, seq)
     assert not problems, problems
-    assert built[0].last_pool_stats["prefill_rows"] == (2 if wave_tokens else 8)
+    assert built[0].last_pool_stats["prefill_rows"] == rows
     assert readings["n_tokens"] == 2 * 8 and readings["select_flips"] == 0.0
     assert readings["select_keys_flipped"] == 0.0
     real = reference.rows_readings

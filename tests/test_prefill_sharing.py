@@ -1,9 +1,12 @@
-"""The static program's prefill in waves computes a group's prompt once
-(`GeneratorEngine._prefill_distinct`): `generate()` tells `static_rollout`
-which rows repeat, the distinct rows alone go through the waves and each
-lands at every row of its group.  Held here, for a toy of every plan family,
-to the program that prefills every row — and every batch that cannot share
-(no repeat, a single prefill, a direct caller) to the parent's program."""
+"""The static program's prefill computes a group's prompt once
+(`GeneratorEngine._prefill_distinct`) where the prefill is what the call
+spends — it goes in waves, or it fits one and the prompt bucket is longer
+than the decode budget (`_shared_rows`): `generate()` tells `static_rollout`
+which rows repeat, the distinct rows alone are prefilled and each lands at
+every row of its group.  Held here, for a toy of every plan family, to the
+program that prefills every row — and every batch that cannot share (no
+repeat, a decode budget as long as the prompt bucket, a mesh, a direct
+caller) to the parent's program."""
 
 import hashlib
 import importlib
@@ -26,6 +29,7 @@ from areal_tpu.models.config import ModelConfig, tiny_config
 # that their one wave is as wide as a wave of the sixteen rows: four.
 LENS = (200, 70, 40, 9)
 NEW = 6
+GROUPS = [r - r % 4 for r in range(16)]  # each of LENS four times: `src`
 
 
 def _family(name) -> ModelConfig:
@@ -76,11 +80,11 @@ def _engine(cfg, slots=64, layout="d1"):
         donation_safe_swap=False)
 
 
-def _generate(cfg, lens, n, share=True, **engine):
-    """`generate()` over prompts of `lens`, `n` a group -> (the engine, the
-    rollout, the cache each chunk's program left, each chunk's `src`).
-    `share` False: every chunk's `src` withheld, the program that prefills
-    every row."""
+def _generate(cfg, lens, n, share=True, new=NEW, **engine):
+    """`generate()` over prompts of `lens`, `n` a group, `new` tokens a row
+    -> (the engine, the rollout, the cache each chunk's program left, each
+    chunk's `src`).  `share` False: every chunk's `src` withheld, the
+    program that prefills every row."""
     eng = _engine(cfg, **engine)
     rollout, caches, srcs = eng.static_rollout, [], []
 
@@ -95,7 +99,7 @@ def _generate(cfg, lens, n, share=True, **engine):
     _, sample = _sample(cfg, lens)
     out = eng.generate(
         sample, MicroBatchSpec(),
-        GenerationHyperparameters(n=n, max_new_tokens=NEW), seed=3,
+        GenerationHyperparameters(n=n, max_new_tokens=new), seed=3,
         inflight=False)
     return eng, out, caches, srcs
 
@@ -147,8 +151,7 @@ def test_a_group_is_prefilled_once_and_lands_at_every_row(name, budget):
     cfg = _family(name)
     shared = _generate(cfg, LENS, n=4)
     eng, out, caches, srcs = shared
-    assert srcs == [[0] * 4 + [4] * 4 + [8] * 4 + [12] * 4]
-    assert _src_of(eng) == [tuple(srcs[0])]
+    assert srcs == [GROUPS] and _src_of(eng) == [tuple(GROUPS)]
     assert _stats(eng) == (4, 16)
     populations = {
         f for f in ("k", "v", "state", "conv", "latent", "wk", "wv", "ck")
@@ -170,6 +173,108 @@ def test_a_group_is_prefilled_once_and_lands_at_every_row(name, budget):
     # One prefill a group, and still four continuations.
     for group in _responses(out, 4):
         assert len({tuple(r.tolist()) for r in group}) > 1
+
+
+# ------------------------------- under the budget: the batch's own shape
+
+
+@pytest.mark.parametrize("name", ["dense", "mamba2_state"])
+def test_one_prefill_longer_than_the_decode_budget_is_shared(name):
+    """Sixteen rows of 256 slots fit the budget as it stands (48 k): ONE
+    prefill, of the four distinct rows, because 256 slots of prompt stand
+    against 6 new tokens."""
+    cfg = _family(name)
+    shared = _generate(cfg, LENS, n=4)
+    eng = shared[0]
+    assert eng._prefill_wave_rows(16, 256) == 16
+    assert _src_of(eng) == [tuple(GROUPS)] and _stats(eng) == (4, 16)
+    own = _generate(cfg, LENS, n=4, share=False)
+    assert _src_of(own[0]) == [None] and _stats(own[0]) == (16, 16)
+    _assert_same_rollout(shared, own, atol=1e-5)  # a prefill of 4 against 16
+    for group in _responses(shared[1], 4):
+        assert len({tuple(r.tolist()) for r in group}) > 1
+
+
+def test_one_prefill_no_longer_than_the_decode_budget_is_not_shared():
+    """The same batch with 256 new tokens a row: the decode loop is the
+    call, and the program is the one it had."""
+    eng, _, _, srcs = _generate(tiny_config(), LENS, n=4, new=256)
+    assert srcs == [GROUPS]
+    assert _src_of(eng) == [None] and _stats(eng) == (16, 16)
+    assert eng._shared_rows(16, 256, 255, GROUPS) == tuple(GROUPS)
+    assert eng._shared_rows(16, 256, 256, GROUPS) is None
+
+
+# ----------------------------------------------- the benchmark's own cells
+
+# Every cell of BENCHMARK.json on the static program, and why its generate
+# call shares its prefill (None: it does not, and keeps the parent's key).
+# The four cells in waves always did (PR 61); `q1p5b-train-longprompt` fits
+# one prefill of [16, 2560] against 64 new tokens; the other seven decode at
+# least as long as their prompt bucket, `q7b-realloc-4chip` on a mesh.
+_CELL_SHARES = {
+    "q1p5b-decode-static": None,
+    "q1p5b-train-longprompt": "prompt past the decode budget",
+    "q7b-realloc-4chip": None,
+    "olmoe-decode-tail": None,
+    "q3next-rollout64-512": None,
+    "glm47f-rollout64-1k": None,
+    "nemo3n-rollout64-512": None,
+    "mellum2-coderl32-4k": "waves",
+    "lfm2-ctxrl32-4k": "waves",
+    "sala-docrl8-longctx": "waves",
+    "olmoh-rollout64-512": None,
+    "dots3n-docrl8-longctx": "waves",
+}
+
+
+def _cell_shape(name):
+    """(rows, prompt bucket, decode budget, devices of the generator's
+    mesh, the group) of a cell, from the benchmark's own files."""
+    from benchmark import files
+    from benchmark.traffic.math_prompts import quantile_lengths
+
+    _, config, traffic = files.load_cell(name)
+    layout = config["benchmark"]["layout"]
+    mesh = ParallelConfig.from_str(
+        layout["gen_parallel"] or layout["actor_parallel"])
+    longest = max(quantile_lengths(traffic["prompt_len"], traffic["n_prompts"]))
+    return (
+        traffic["n_prompts"] * traffic["group"],
+        generator_mod.bucket_len(longest), traffic["max_new_tokens"],
+        mesh.world_size, traffic["group"])
+
+
+def test_the_table_names_every_static_cell():
+    from benchmark import files
+
+    static = {
+        w["name"] for w in files.benchmark_json()["workloads"]
+        if files.load_cell(w["name"])[0]["route"] == "static"}
+    assert static == set(_CELL_SHARES)
+
+
+@pytest.mark.parametrize("name", sorted(_CELL_SHARES))
+def test_which_cells_share_their_prefill(name):
+    b, sp, new, devices, group = _cell_shape(name)
+    why = _CELL_SHARES[name]
+
+    class Mesh:
+        size = devices
+
+    eng = object.__new__(GeneratorEngine)  # the rule reads its mesh alone
+    eng.mesh = Mesh()
+    src = [r - r % group for r in range(b)]
+    # A caller that names no repeats keeps the parent's program, first of
+    # all: the references' own generator calls are long prompts, few tokens.
+    assert eng._shared_rows(b, sp, new, None) is None
+    assert eng._shared_rows(b, sp, new, list(range(b))) is None
+    assert eng._shared_rows(b, sp, new, src) == (tuple(src) if why else None)
+    in_waves = eng._prefill_wave_rows(b, sp) < b
+    assert in_waves == (why == "waves")
+    assert in_waves or bool(why) == (sp > new and devices == 1)
+    if name == "q1p5b-train-longprompt":
+        assert (b, sp, new, len(set(src))) == (16, 2560, 64, 4)
 
 
 # ------------------------------------------------------------- edge cases
@@ -201,10 +306,11 @@ def test_more_distinct_rows_than_a_wave_holds_go_in_waves(monkeypatch):
 def test_rows_added_to_reach_the_batch_sharding_are_their_own_source(budget):
     cfg = tiny_config()
     eng = _engine(cfg)
-    assert eng._shared_rows(8, 256, [0, 0, 0, 3, 3, 3]) == (
+    assert eng._shared_rows(8, 256, NEW, [0, 0, 0, 3, 3, 3]) == (
         0, 0, 0, 3, 3, 3, 6, 7)
-    # A mesh shards the batch axis a wave would slice: one prefill of all
-    # eight rows (two of them pads), whatever repeats.
+    # A mesh shards the batch axis a wave, and the landing, would slice: one
+    # prefill of all eight rows (two of them pads), whatever repeats and
+    # however long the prompt bucket stands against the decode budget.
     eng, _, _, srcs = _generate(cfg, (9, 9), n=3, layout="d4")
     assert srcs == [[0, 0, 0, 3, 3, 3]] and eng.batch_shard == 4
     assert _src_of(eng) == [None] and _stats(eng) == (6, 6)
@@ -222,10 +328,13 @@ def test_a_source_row_carries_the_same_prompt_and_comes_first(src):
 
 
 # As printed at the parent of PR 61 (23a2611) by this file's `_program_sha`
-# for the same three calls: sha256 of the lowered `gen`, the results' names
-# left out.
+# for the same calls: sha256 of the lowered `gen`, the results' names left
+# out.  `_PARENT_SINGLE_128`: two groups of four in one prefill with 128 new
+# tokens, as printed at the parent of PR 67 (4cfbc9f), where the batch with
+# 6 new tokens still traced `_PARENT_SINGLE` and now shares its prefill.
 _PARENT_WAVES = "159158c36ec074cfd617a33f9c4232e52ed04638d083b30ff3a4103a29de9d45"
 _PARENT_SINGLE = "2e4adf5b01e12ecc2920abe1eac0b47b967a10fd650a49a0daaff7c39e83f536"
+_PARENT_SINGLE_128 = "81b066ddf376de66ce935bdf88b35029f4e92c76f8415939971b0192716f1641"
 _PARENT_KEY = (8, 128, 256, NEW, 0, False, 1.0, 0, 1.0, False, False)
 
 
@@ -239,32 +348,39 @@ def _program_sha(eng):
     return key, hashlib.sha256(text.encode()).hexdigest()
 
 
-@pytest.mark.parametrize("call,budget_tokens,parent", [
-    ("n=1", 512, _PARENT_WAVES),
-    ("direct", 512, _PARENT_WAVES),
-    ("under the budget", 48 * 1024, _PARENT_SINGLE),
+@pytest.mark.parametrize("call,budget_tokens,new,parent", [
+    ("n=1", 512, NEW, _PARENT_WAVES),
+    ("direct", 512, NEW, _PARENT_WAVES),
+    # Under the budget.  Two groups of four in ONE prefill keep the parent's
+    # program where the decode budget is as long as the prompt bucket (with
+    # 6 new tokens, PR 61's case, the batch shares since PR 67: above) ...
+    ("groups", 48 * 1024, 128, _PARENT_SINGLE_128),
+    # ... and a prompt bucket past the decode budget keeps it where no row
+    # repeats or the caller names none (the references' generator checks).
+    ("n=1", 48 * 1024, NEW, _PARENT_SINGLE),
+    ("direct", 48 * 1024, NEW, _PARENT_SINGLE),
 ])
 def test_a_batch_that_cannot_share_traces_the_parents_program(
-        monkeypatch, call, budget_tokens, parent):
+        monkeypatch, call, budget_tokens, new, parent):
     monkeypatch.setattr(generator_mod, "PREFILL_WAVE_TOKENS", budget_tokens)
     cfg = tiny_config()
     eng = _engine(cfg, slots=8)
-    g = GenerationHyperparameters(n=1, max_new_tokens=NEW)
+    g = GenerationHyperparameters(n=1, max_new_tokens=new)
     if call == "direct":  # as `benchmark/references/minicpm_sala.py` calls
         toks, _ = _sample(cfg, (70,))
         eng.static_rollout(toks * 8, g, jax.random.PRNGKey(1))
-    elif call == "n=1":  # eight distinct prompts, in waves of four
+    elif call == "n=1":  # eight distinct prompts (in waves of four)
         _, sample = _sample(cfg, (70, 9, 40, 33, 21, 60, 5, 17))
         eng.generate(sample, MicroBatchSpec(), g, seed=3)
     else:  # two groups of four in ONE prefill
         _, sample = _sample(cfg, (70, 9))
         eng.generate(
             sample, MicroBatchSpec(),
-            GenerationHyperparameters(n=4, max_new_tokens=NEW), seed=3)
-    assert eng._prefill_wave_rows(8, 128) == (8 if parent == _PARENT_SINGLE else 4)
+            GenerationHyperparameters(n=4, max_new_tokens=new), seed=3)
+    assert eng._prefill_wave_rows(8, 128) == (4 if parent == _PARENT_WAVES else 8)
     assert _stats(eng) == (8, 8)
     key, sha = _program_sha(eng)
-    assert key == (*_PARENT_KEY, None)
+    assert key == (*_PARENT_KEY[:3], new, *_PARENT_KEY[4:], None)
     assert sha == parent
 
 
