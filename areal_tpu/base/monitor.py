@@ -59,7 +59,22 @@ def flops_forward(
     plus the attention term over the exact sum of per-sequence s^2."""
     if sum_sq_seqlens is None:
         sum_sq_seqlens = float(n_tokens) ** 2
+    if getattr(cfg, "block_length", 0):
+        return _flops_two_streams(cfg, n_tokens, sum_sq_seqlens)
     return 2.0 * matmul_params(cfg) * n_tokens + _attn_flops(
+        _layers_of(cfg), cfg, n_tokens, sum_sq_seqlens)
+
+
+def _flops_two_streams(cfg, n_tokens: int, sum_sq_seqlens: float) -> float:
+    """`flops_forward` of a model that generates by diffusion over blocks
+    (`cfg.block_length`): the stack runs over STREAM slots — a clean and a
+    masked slot a token where every block is scored (the count here; a
+    loss mask that leaves the prompt's blocks out runs fewer) — a masked
+    query sees about the keys its clean twin does, and the head reads the
+    masked stream alone."""
+    head = 0 if cfg.is_critic else cfg.hidden_dim * cfg.vocab_size
+    layers = matmul_params(cfg) - head
+    return 2.0 * (2 * layers + head) * n_tokens + 2 * _attn_flops(
         _layers_of(cfg), cfg, n_tokens, sum_sq_seqlens)
 
 
@@ -79,6 +94,18 @@ def flops_generate(
     p_sq = float(sum(p * p for p in prompt_lens))
     total = flops_forward(cfg, int(p_tokens), p_sq)
     n, layers = 2.0 * matmul_params(cfg), _layers_of(cfg)
+    if getattr(cfg, "block_length", 0):
+        # A block of B tokens takes T denoising forwards and a commit, each
+        # of B tokens through the layers (the commit without the head);
+        # the prompt's blocks are prefilled with no head.
+        steps = cfg.denoising_forwards
+        head = 2.0 * cfg.hidden_dim * cfg.vocab_size
+        total = 2.0 * (matmul_params(cfg) - head / 2) * p_tokens + _attn_flops(
+            layers, cfg, int(p_tokens), p_sq)
+        for p, g in zip(prompt_lens, gen_lens):
+            total += ((steps + 1) * (n - head) + steps * head) * g + (
+                steps + 1) * _attn_flops(layers, cfg, g, g * p + g * g / 2.0)
+        return total
     for p, g in zip(prompt_lens, gen_lens):
         # sum over decode steps of (p + t) ~ g*p + g^2/2
         total += n * g + _attn_flops(layers, cfg, g, g * p + g * g / 2.0)

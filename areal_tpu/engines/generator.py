@@ -454,6 +454,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         self.cache_copy_bytes = 0
         self.last_pool_stats: Dict[str, Any] = {}
         self._decode_sums = self._zero_decode_sums()
+        self._bd_counts = 0.0
         # Serving-plane chunk counters of the current generate() call
         # (see _serving_counters); folded into last_pool_stats at its end.
         self._chunk_stats: Dict[str, Any] = _new_chunk_stats()
@@ -789,6 +790,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         self.cache_copy_bytes = 0
         self.last_pool_stats = {}
         self._decode_sums = self._zero_decode_sums()
+        self._bd_counts = 0.0  # `_block_rollout`'s, summed over its chunks
         self.lanes_dispatched = 0
         self.lanes_live = 0
         self.lanes_slack = 0
@@ -2403,6 +2405,75 @@ class GeneratorEngine(HostOffloadMixin, Engine):
             )
             results[(i, rep)] = (toks[r, :gl], logps[r, :gl], no_eos)
 
+    def _block_rollout(
+        self, prompts, gconfig, key, with_cache=False, steps=False
+    ):
+        """`static_rollout` of a model that generates by diffusion over
+        blocks (`cfg.block_length`; `engines/block_diffusion.py`): the same
+        call and the same results — `max_new_tokens` tokens a row at most,
+        their log-probs, `gen_len` — from the program's loop over BLOCKS.
+        A row's whole prompt blocks end at the bucket `sp`, its tail rides
+        in its first block at slots [sp, sp + B): with `with_cache`, row
+        r's tokens lie in the cache's slots from sp - B floor(len / B) on.
+        `steps`: also the denoising step that revealed each token [b,
+        max_new]."""
+        from areal_tpu.engines import block_diffusion as bd
+
+        cfg = self.cfg
+        bd.refuse(cfg, gconfig)
+        b_real = len(prompts)
+        b = -(-b_real // self.batch_shard) * self.batch_shard
+        sp = bucket_len(max(len(t) for t in prompts))
+        whole_tok, whole_len, tail_tok, tail_len = bd.split_prompts(
+            cfg, prompts, sp, self.pad_token_id, b)
+        nb = bd.n_blocks(cfg, gconfig.max_new_tokens, tail_len[:b_real])
+        # Every forward streams the whole window: the cache is cut to the
+        # next 128 slots past the last block, not to the token loop's
+        # bucket (a two-token tail would make 772 slots 1,024).
+        s_total = -(-(sp + nb * cfg.block_length) // 128) * 128
+        g = gconfig
+        sig = (
+            "blocks", b, sp, s_total, nb, g.max_new_tokens, g.greedy, g.top_p,
+            g.top_k, g.temperature, self._expert_leaves_in_place, with_cache,
+        )
+        if sig not in self._gen_fns:
+            self._gen_fns[sig] = bd.build(
+                self, b, sp, s_total, nb, g, with_cache)
+            logger.info(
+                f"compiled block generator for shape b={b} sp={sp} "
+                f"s_total={s_total} blocks={nb}")
+        fn = self._gen_fns[sig]
+        stats = self.last_pool_stats
+        for name in ("prefill_rows", "prefill_rows_requested"):
+            stats[name] = stats.get(name, 0) + b_real
+        self._m_prefill_rows.inc(b_real)
+        self._m_prefill_rows_requested.inc(b_real)
+        with tracer.span(
+            "gen_chunk", cat="compute", b=b_real, sp=sp, prefill_rows=b_real,
+            prefill_rows_requested=b_real, blocks=nb,
+        ):
+            with tracer.span("gen_dispatch", cat="compute"):
+                toks, logps, gen_len, sums, counts, step_of, *cache = fn(
+                    self.params, whole_tok, whole_len, tail_tok, tail_len, key)
+            with tracer.span("gen_wait", cat="compute"):
+                toks, logps, gen_len, counts = (
+                    to_host(toks), to_host(logps), to_host(gen_len),
+                    to_host(counts))
+                for name, counter in tfm.decode_counters(cfg).items():
+                    self._decode_sums[name] += to_host(
+                        sums[name]).astype(float)
+                    stats.update(counter.report(
+                        self._decode_sums[name], cfg, self.params))
+        self._bd_counts = self._bd_counts + counts.astype(float)
+        stats.update(bd.report(cfg, self._bd_counts, b))
+        tracer.counter("bd", **{
+            k.split("/")[1]: v for k, v in stats.items()
+            if k.startswith("bd/") and not isinstance(v, list)})
+        out = (toks, logps, gen_len)
+        if steps:
+            out += (to_host(step_of),)
+        return out + tuple(cache)
+
     def static_rollout(
         self, prompts, gconfig, key, with_cache=False, src=None
     ):
@@ -2420,7 +2491,13 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         `with_cache`: also the `KVCache` the program leaves, on the device
         (one more output of the same program, for a check that holds the
         cache to a reference): row r's prompt lies in slots [sp - len, sp),
-        sp = `bucket_len` of the longest prompt, its new tokens from sp."""
+        sp = `bucket_len` of the longest prompt, its new tokens from sp.
+
+        A model that generates by diffusion over blocks
+        (`cfg.block_length`) runs the program's loop over BLOCKS
+        (`_block_rollout`): the same call, the same results."""
+        if self.cfg.block_length:
+            return self._block_rollout(prompts, gconfig, key, with_cache)
         b_real = len(prompts)
         b = b_real
         while b % self.batch_shard:
@@ -2477,7 +2554,8 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                     to_host(gen_len),
                 )
                 for name, counter in tfm.decode_counters(cfg).items():
-                    self._decode_sums[name] += to_host(sums[name]).astype(float)
+                    self._decode_sums[name] += to_host(
+                        sums[name]).astype(float)
                     self.last_pool_stats.update(counter.report(
                         self._decode_sums[name], cfg, self.params))
         if with_cache:

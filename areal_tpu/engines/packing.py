@@ -12,7 +12,7 @@ ragged buffer per micro-batch we build a static [B, S] grid with segment ids.
 """
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -154,9 +154,22 @@ class RowPack:
     seq_map: List[Tuple[int, int, int]]
     n_rows: int
     row_len: int
+    # Two streams a sequence (`pack_sample(block_length=...)`): where a
+    # sequence's per-token output lies, (row, slot of out[lo], lo, hi) —
+    # out[lo:hi] read from the MASKED stream, zero elsewhere — and the
+    # streams' slot counts (`stream_stats`).
+    out_map: Optional[List[Tuple[int, int, int, int]]] = None
+    stats: Dict[str, float] = dataclasses.field(default_factory=dict)
 
     def unpack(self, dense: np.ndarray) -> np.ndarray:
         """[B, S, ...] -> packed 1D [sum(lens), ...] in original order."""
+        if self.out_map is not None:
+            parts = []
+            for (_, _, l), (r, at, lo, hi) in zip(self.seq_map, self.out_map):
+                out = np.zeros((l,) + dense.shape[2:], dense.dtype)
+                out[lo:hi] = dense[r, at : at + hi - lo]
+                parts.append(out)
+            return np.concatenate(parts, axis=0)
         parts = [dense[r, s : s + l] for (r, s, l) in self.seq_map]
         return np.concatenate(parts, axis=0)
 
@@ -199,8 +212,19 @@ def pack_sample(
     max_tokens_per_row: Optional[int] = None,
     row_len: Optional[int] = None,
     shard_blocks: Optional[List[List[int]]] = None,
+    block_length: int = 0,
+    mask_token_id: int = 0,
+    wanted_key: Optional[str] = None,
 ) -> RowPack:
     """Pack every sequence of `sample[token_key]` into dense rows.
+
+    `block_length` B > 0 (a model that generates by diffusion over blocks,
+    `ModelConfig.block_length`): a sequence lies in its row as TWO STREAMS
+    (`_stream_layout`), the row's budget counts stream slots, and the
+    token-aligned extras and the model's per-token output live at the
+    masked stream's places (`RowPack.out_map`).  `wanted_key`: the extra
+    key (> 0 at storage index j - 1) that says which tokens j want a
+    log-prob; None, every token but a sequence's first.
 
     extra_keys must be token-aligned with token_key (same seqlens).  The
     number of rows is FFD's count under `max_tokens_per_row`, rounded up to
@@ -226,6 +250,11 @@ def pack_sample(
             raise ValueError(
                 f"extra key {k!r} is not token-aligned with {token_key!r}"
             )
+    seq_lens = lens
+    if block_length:
+        layouts = _stream_layouts(
+            sample, token_key, wanted_key, block_length)
+        lens = [lay.slots for lay in layouts]  # what a row's budget counts
     cap = max_tokens_per_row or max(lens, default=1)
     cap = max(cap, max(lens, default=1))
     if shard_blocks is not None and len(shard_blocks) > 1:
@@ -263,6 +292,10 @@ def pack_sample(
         max((sum(lens[i] for i in g) for g in groups), default=1)
     )
 
+    if block_length:
+        return _pack_streams(
+            sample, token_key, extra_keys, groups, n_rows, s_pad, seq_lens,
+            layouts, block_length, mask_token_id)
     tok_src = np.asarray(sample.data[token_key])
     bounds = sample.cu_seqlens(token_key)
     extra_src = {k: np.asarray(sample.data[k]) for k in extra_keys}
@@ -295,4 +328,132 @@ def pack_sample(
     arrays.update(extras)
     return RowPack(
         arrays=arrays, seq_map=seq_map, n_rows=n_rows, row_len=s_pad
+    )
+
+
+# --------------------------------------------------------------------------
+# Two streams a sequence: the train forward of a model that generates by
+# diffusion over blocks (`ModelConfig.block_length`)
+# --------------------------------------------------------------------------
+
+# Rows the log-prob head reads are gathered to a static count: the most a
+# row holds, rounded up to this.
+_HEAD_QUANTUM = 512
+
+
+class _StreamLayout(NamedTuple):
+    """One sequence of L tokens as two streams of a row, B = block length:
+    a CLEAN stream — x_0 .. x_{L-1} at positions 0 .. L-1, padded to a
+    multiple of B (`clean` slots) — and a MASKED stream — B mask tokens a
+    block, at the positions of blocks `m0` .. `m1`, the blocks from the
+    first to the last that hold a wanted token (`masked` slots; 0 where
+    none is wanted).  `wanted` [L] marks the tokens that want a log-prob."""
+
+    clean: int
+    m0: int
+    masked: int
+    wanted: np.ndarray
+
+    @property
+    def slots(self) -> int:
+        return self.clean + self.masked
+
+
+def _stream_layouts(sample, token_key, wanted_key, blk: int):
+    lens = sample.seqlens_of(token_key)
+    src = bounds = None
+    if wanted_key is not None:
+        src = np.asarray(sample.data[wanted_key])
+        bounds = sample.cu_seqlens(wanted_key)
+    out = []
+    for i, l in enumerate(lens):
+        wanted = np.zeros(l, bool)
+        if src is None:
+            wanted[1:] = True
+        else:  # storage index j - 1 holds token j's
+            wanted[1:] = src[bounds[i] : bounds[i] + l - 1] > 0
+        at = np.flatnonzero(wanted)
+        m0, m1 = (at[0] // blk, at[-1] // blk) if len(at) else (0, -1)
+        out.append(_StreamLayout(
+            -(-l // blk) * blk, int(m0), int(m1 - m0 + 1) * blk, wanted))
+    return out
+
+
+def _pack_streams(
+    sample, token_key, extra_keys, groups, n_rows, s_pad, lens, layouts,
+    blk: int, mask_id: int,
+) -> RowPack:
+    """`pack_sample`'s rows for two streams a sequence.  One segment id a
+    sequence; `stream_ids` 0 clean, 1 masked; `positions` absolute in the
+    sequence, so a token's block is position // B; every stream starts on
+    a multiple of B in the row, so no block straddles a flash tile.  At
+    the masked stream's place of position j: `labels` x_j, `label_mask`
+    whether j wants a log-prob, and every extra key's value of storage
+    index j - 1.  `head_index` [rows, K]: the slots a row's wanted tokens
+    lie at (K the most a row has, rounded up to `_HEAD_QUANTUM`; the row
+    length where a row has fewer) — the log-prob head reads these alone."""
+    tok_src = np.asarray(sample.data[token_key])
+    bounds = sample.cu_seqlens(token_key)
+    extra_src = {k: np.asarray(sample.data[k]) for k in extra_keys}
+    ex_bounds = {k: sample.cu_seqlens(k) for k in extra_keys}
+
+    def grid(dtype, trailing=()):
+        return np.zeros((n_rows, s_pad) + tuple(trailing), dtype)
+
+    tokens, seg, pos, stream, labels = (grid(np.int32) for _ in range(5))
+    tokens = tokens.astype(tok_src.dtype)
+    label_mask = grid(np.float32)
+    extras = {k: grid(v.dtype, v.shape[1:]) for k, v in extra_src.items()}
+    seq_map = [None] * len(lens)
+    out_map = [None] * len(lens)
+    n_clean = n_masked = n_align = 0
+    for r, g in enumerate(groups):
+        off = 0
+        for seq_no, i in enumerate(g, start=1):
+            lay, l = layouts[i], lens[i]
+            toks = tok_src[bounds[i] : bounds[i + 1]]
+            tokens[r, off : off + l] = toks
+            seg[r, off : off + l] = seq_no
+            pos[r, off : off + l] = np.arange(l)
+            seq_map[i] = (r, off, l)
+            m = off + lay.clean  # the masked stream's first slot
+            first = lay.m0 * blk  # and its first position
+            n = lay.masked
+            tokens[r, m : m + n] = mask_id
+            seg[r, m : m + n] = seq_no
+            pos[r, m : m + n] = first + np.arange(n)
+            stream[r, m : m + n] = 1
+            hi = min(first + n, l)  # positions [first, hi) are tokens
+            labels[r, m : m + hi - first] = toks[first:hi]
+            label_mask[r, m : m + hi - first] = lay.wanted[first:hi]
+            lo = max(first, 1)  # storage index j - 1, j from lo
+            for k in extra_keys:
+                eb = ex_bounds[k][i]
+                extras[k][r, m + lo - first : m + hi - first] = extra_src[k][
+                    eb + lo - 1 : eb + hi - 1]
+            out_map[i] = (r, m + lo - first, lo - 1, max(hi - 1, lo - 1))
+            n_clean, n_masked = n_clean + l, n_masked + n
+            n_align += lay.clean - l
+            off += lay.slots
+    counts = (label_mask > 0).sum(axis=1)
+    k_rows = min(-(-max(int(counts.max()), 1) // _HEAD_QUANTUM)
+                 * _HEAD_QUANTUM, s_pad)
+    head_index = np.full((n_rows, k_rows), s_pad, np.int32)  # pad: dropped
+    for r in range(n_rows):
+        at = np.flatnonzero(label_mask[r] > 0)
+        head_index[r, : len(at)] = at
+    arrays = {
+        "tokens": tokens, "segment_ids": seg, "positions": pos,
+        "stream_ids": stream, "labels": labels, "label_mask": label_mask,
+        "head_index": head_index,
+    }
+    arrays.update(extras)
+    return RowPack(
+        arrays=arrays, seq_map=seq_map, n_rows=n_rows, row_len=s_pad,
+        out_map=out_map,
+        stats={
+            "clean_slots": n_clean, "masked_slots": n_masked,
+            "align_pad_slots": n_align, "head_rows": n_rows * k_rows,
+            "wanted_tokens": int(counts.sum()),
+        },
     )

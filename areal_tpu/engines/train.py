@@ -150,6 +150,10 @@ def _moe_stats(aux, counts, cfg: ModelConfig, pairs: int) -> Dict[str, jax.Array
 def _model_out(params, cfg: ModelConfig, x, batch, mesh: Mesh):
     """Per-token model output [B, S] from final hidden states (see
     transformer.per_token_output)."""
+    if cfg.block_length:  # two streams a sequence: labels in place
+        return tfm.block_token_output(
+            params, cfg, x, batch["labels"], batch["label_mask"],
+            batch["head_index"], mesh=mesh)
     return tfm.per_token_output(
         params, cfg, x, batch["tokens"], batch["segment_ids"], mesh=mesh
     )
@@ -383,6 +387,45 @@ class TrainEngine(HostOffloadMixin, Engine):
 
     # ---------------- core jitted fns ----------------
 
+    # Two streams a sequence (`cfg.block_length`: `packing._pack_streams`).
+
+    def _mb_split(self, mb_spec: MicroBatchSpec) -> MicroBatchSpec:
+        """How a batch is split into micro-batches before it is packed: by
+        the plan's tokens a micro-batch — but where a sequence lies in its
+        row as two streams, by `n_mbs` alone: the budget counts STREAM
+        slots, which `pack_sample` fills a row with, and `_pack_row_chunks`
+        hands the gradient program a row a device at a time."""
+        if not self.cfg.block_length:
+            return mb_spec
+        return MicroBatchSpec(n_mbs=mb_spec.n_mbs)
+
+    def _stream_args(self, extra_keys) -> Dict[str, Any]:
+        """`pack_sample`'s arguments for a model with `block_length`; the
+        tokens that want a log-prob are the loss mask's where the call has
+        one, else every token."""
+        cfg = self.cfg
+        if not cfg.block_length:
+            return {}
+        return dict(
+            block_length=cfg.block_length, mask_token_id=cfg.mask_token_id,
+            wanted_key="loss_mask" if "loss_mask" in extra_keys else None)
+
+    @staticmethod
+    def _stream_stats(packs) -> Dict[str, float]:
+        """`last_pack_stats`' `bd/*` keys of a call's packs (none for a
+        model without streams): slots of the clean and the masked streams,
+        the pad that aligns a stream to a block, the rows the head read,
+        and stream slots over the tokens trained (`bd/stream_overhead`)."""
+        if not packs or not packs[0].stats:
+            return {}
+        total = {k: sum(p.stats[k] for p in packs) for k in packs[0].stats}
+        slots = total["clean_slots"] + total["masked_slots"]
+        tracer.counter("bd_pack", **total)
+        return {
+            **{f"bd/{k}": float(v) for k, v in total.items()},
+            "bd/stream_overhead": slots / max(total["clean_slots"], 1),
+        }
+
     def _pack_row_chunks(self, arrays, max_tokens: Optional[int] = None):
         """Rows per jitted step, capped at batch_shard in two cases.
         1f1b-mem schedule (batch_axes x P, i.e. exactly P in-flight
@@ -395,8 +438,13 @@ class TrainEngine(HostOffloadMixin, Engine):
         so a group of four 13 k-token sequences is four rows of 13,312): a
         step then takes one row a device, and the step's tokens stay near
         the bound the plan set (the same loop accumulates)."""
-        b, row_len = next(iter(arrays.values())).shape[:2]
-        long_rows = bool(max_tokens) and row_len > max_tokens
+        b, row_len = arrays["segment_ids"].shape
+        long_rows = bool(max_tokens) and (
+            row_len > max_tokens
+            # Two streams a sequence: the micro-batch was split by tokens
+            # and its rows hold stream slots, more than `max_tokens` of
+            # them — a step takes a row a device all the same.
+            or (bool(self.cfg.block_length) and b * row_len > max_tokens))
         pipelined = self.pipe_schedule == "1f1b-mem" and self._pp_mesh is not None
         if not (long_rows or pipelined):
             return [arrays]
@@ -446,6 +494,7 @@ class TrainEngine(HostOffloadMixin, Engine):
                     with_moe_counts=True,
                     expert_kernel=expert_kernel,
                     row_kernel=row_kernel,
+                    stream_ids=batch.get("stream_ids"),
                 )
                 self._expert_matmuls = tuple(
                     b - a for a, b in zip(traced, tfm.expert_matmuls_traced())
@@ -662,7 +711,8 @@ class TrainEngine(HostOffloadMixin, Engine):
         t_entry = time.monotonic()
         self._ensure_loaded()
         with tracer.span("pack", cat="host"):
-            sharded_mbs = packing.split_sharded(sample, mb_spec)
+            sharded_mbs = packing.split_sharded(
+                sample, self._mb_split(mb_spec))
             packs = [
                 packing.pack_sample(
                     mb,
@@ -671,6 +721,7 @@ class TrainEngine(HostOffloadMixin, Engine):
                     n_rows_multiple=self.batch_shard,
                     max_tokens_per_row=mb_spec.max_tokens_per_mb,
                     shard_blocks=blocks,
+                    **self._stream_args(extra_keys),
                 )
                 for mb, blocks in sharded_mbs
             ]
@@ -698,6 +749,7 @@ class TrainEngine(HostOffloadMixin, Engine):
                 "pack_efficiency": grid["real_tokens"]
                 / max(grid["grid_tokens"], 1),
                 "n_micro_batches": len(chunks),
+                **self._stream_stats(packs),
             }
 
         grad_fn, grad_acc_fn = self._get_grad_fn(loss_fn)
@@ -839,7 +891,8 @@ class TrainEngine(HostOffloadMixin, Engine):
         """
         t_entry = time.monotonic()
         with tracer.span("pack", cat="host"):
-            sharded_mbs = packing.split_sharded(sample, mb_spec)
+            sharded_mbs = packing.split_sharded(
+                sample, self._mb_split(mb_spec))
             if any(blocks for _, blocks in sharded_mbs):
                 raise ValueError(
                     "streamed accumulation does not compose with "
@@ -854,6 +907,7 @@ class TrainEngine(HostOffloadMixin, Engine):
                     extra_keys=extra_keys,
                     n_rows_multiple=self.batch_shard,
                     max_tokens_per_row=mb_spec.max_tokens_per_mb,
+                    **self._stream_args(extra_keys),
                 )
                 for mb, _ in sharded_mbs
             ]
@@ -1041,7 +1095,9 @@ class TrainEngine(HostOffloadMixin, Engine):
         self._ensure_loaded()
         fwd = self._get_fwd_fn(post_fn)
         outs = []
-        for mb, blocks in packing.split_sharded(sample, mb_spec):
+        for mb, blocks in packing.split_sharded(
+            sample, self._mb_split(mb_spec)
+        ):
             pk = packing.pack_sample(
                 mb,
                 token_key,
@@ -1049,9 +1105,18 @@ class TrainEngine(HostOffloadMixin, Engine):
                 n_rows_multiple=self.batch_shard,
                 max_tokens_per_row=mb_spec.max_tokens_per_mb,
                 shard_blocks=blocks,
+                **self._stream_args(extra_keys),
             )
-            batch = self._device_batch(pk.arrays)
-            dense = to_host(fwd(self.params, batch))
+            # Two streams a sequence: the pack's rows hold more stream
+            # slots than the budget, a row a device a call (every other
+            # model's pack is one call, the program it always was).
+            chunks = [pk.arrays]
+            if self.cfg.block_length and not blocks:
+                chunks = self._pack_row_chunks(
+                    pk.arrays, mb_spec.max_tokens_per_mb)
+            dense = np.concatenate([
+                to_host(fwd(self.params, self._device_batch(c)))
+                for c in chunks])
             packed = pk.unpack(dense)
             out = SequenceSample(
                 keys={output_key},
@@ -1088,6 +1153,7 @@ class TrainEngine(HostOffloadMixin, Engine):
                 pp_mesh=pp_mesh,
                 pp_microbatches=pp_mbs,
                 row_kernel=row_kernel,
+                stream_ids=batch.get("stream_ids"),
             )
             return post_fn(_model_out(pc, cfg, x, batch, mesh), batch)
 
@@ -1162,3 +1228,4 @@ class TrainEngine(HostOffloadMixin, Engine):
         """`HostOffloadMixin.hbm_owned`, with Adam's moments (and whatever
         else the optimizer's state holds)."""
         return {"weights": self.params, "moments": self.opt_state}
+

@@ -112,6 +112,9 @@ class Ctx:
     paged_kernel: Optional[bool] = None
     pages: Any = None
     lanes: Any = None
+    # (block ids, stream ids) [B, S] of the block-causal mask over packed
+    # rows (`cfg.block_length`).
+    block_mask: Any = None
 
 
 @dataclasses.dataclass(frozen=True)

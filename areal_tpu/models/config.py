@@ -342,8 +342,23 @@ class ModelConfig:
     # layer's attention output is this rank's PARTIAL `o_proj` sum (no
     # exchange).
     head_share: int = 1
+    # Generation by diffusion over blocks (`engines/block_diffusion.py`):
+    # `block_length` B > 0 (0 = autoregressive: every other family) makes
+    # attention BLOCK-causal — token i sees token j of its sequence iff
+    # floor(pos_j / B) <= floor(pos_i / B) — the head's row at position i
+    # the distribution of the token AT i (no shift, the logit of
+    # `mask_token_id` left out of every softmax), a decode step a block of
+    # B tokens in `denoising_steps` forwards and a commit, and the
+    # trainer's input two streams (clean | masked).  A denoising step
+    # reveals the B / `denoising_steps` masked places its draw is most
+    # confident of (the family's `low_confidence_static`; the one sampler
+    # parameter, the model's own: `denoising_forwards`).
+    block_length: int = 0
+    mask_token_id: int = -1
+    denoising_steps: int = 0  # 0 = block_length: one token a forward
 
     def __post_init__(self):
+        self._check_blocks()
         # The checks read the fields as given: `plan` is for what passed.
         if (self.n_layers - self.first_k_dense) % self.full_attn_interval:
             raise ValueError(
@@ -518,6 +533,32 @@ class ModelConfig:
             )
         if self.head_share < 1:
             raise ValueError(f"head_share {self.head_share} is below 1")
+
+    def _check_blocks(self):
+        b = self.block_length
+        if not b:
+            return
+        if b < 0 or not 0 <= self.mask_token_id < self.vocab_size:
+            raise ValueError(
+                f"block_length {b} needs a mask_token_id inside the "
+                f"vocabulary ({self.mask_token_id} of {self.vocab_size})"
+            )
+        if not 0 <= self.denoising_steps <= b:
+            raise ValueError(
+                f"denoising_steps {self.denoising_steps} of a block of {b}")
+        other = (
+            self.window_pattern or self.layer_pattern or self.is_latent
+            or self.full_attn_interval > 1 or self.first_k_dense
+            or self.is_critic or self.pos_emb != "rope"
+        )
+        if other:
+            raise NotImplementedError(
+                f"block_length {b}: generation by diffusion over blocks is "
+                "built for full softmax-attention layers with rope alone — "
+                "a window or selected layer's band, latent rows, a "
+                "recurrent or convolution mixer's state and a value head "
+                "have no block-causal form here"
+            )
 
     def _check_sala(self):
         pattern = self.window_pattern
@@ -720,6 +761,13 @@ class ModelConfig:
     @property
     def router_width(self) -> int:
         return self.n_router_experts or self.n_experts
+
+    @property
+    def denoising_forwards(self) -> int:
+        """T: the denoising forwards a block of `block_length` tokens takes
+        (the generator's loop, its counters and every FLOP count read it
+        here)."""
+        return self.denoising_steps or self.block_length
 
     @property
     def expert_share(self) -> bool:

@@ -708,6 +708,107 @@ register_hf_family(
 )
 
 
+# ---------------- sdar_moe ----------------
+# JetLM/SDAR-*-A3B: the Qwen3-MoE block to the letter (per-head q/k
+# RMSNorm, a softmax router over `num_experts`, top-k renormalised, SwiGLU
+# experts of `moe_intermediate_size`, no shared expert, no biases; olmoe's
+# tensor names) under generation by DIFFUSION OVER BLOCKS (arXiv:2510.06303):
+# block-causal attention, in-place prediction, a block of `block_length`
+# tokens a decode step.  The published config.json states the layer alone;
+# the block's length, the mask token and the sampler's steps are arguments
+# of the family's own generate call, and a configuration file carries them
+# as keys of their own (the benchmark's lists them under `assumed`).  Of
+# the family's two unmasking rules the static one is built
+# (`_SDAR_REMASKING`; a configuration that names another is refused).  A
+# `share` group cuts the model to one expert-parallel rank, as mellum's
+# does.
+
+_SDAR_DEFAULTS = dict(block_length=4, mask_token_id=151669, denoising_steps=4)
+_SDAR_REMASKING = "low_confidence_static"
+
+
+def _sdar_moe_config_from_hf(hf: dict) -> ModelConfig:
+    for key, fine in (
+        ("attention_bias", False), ("hidden_act", "silu"),
+        ("decoder_sparse_step", 1), ("mlp_only_layers", []),
+        ("use_sliding_window", False), ("rope_scaling", None),
+    ):
+        if hf.get(key, fine) != fine:
+            raise NotImplementedError(
+                f"sdar_moe {key}={hf[key]!r} is not modelled")
+    rule = hf.get("remasking_strategy", _SDAR_REMASKING)
+    if rule != _SDAR_REMASKING:
+        raise NotImplementedError(
+            f"sdar_moe remasking_strategy={rule!r}: the block loop reveals "
+            f"a fixed count of places a step ({_SDAR_REMASKING!r}); a rule "
+            "whose steps follow the draws' confidence is not built")
+    share = hf.get("share") or {}
+    n_experts = hf["num_experts"]
+    width = share.get("router_num_experts", n_experts)
+    return ModelConfig(
+        n_layers=hf["num_hidden_layers"],
+        hidden_dim=hf["hidden_size"],
+        n_q_heads=hf["num_attention_heads"],
+        n_kv_heads=hf["num_key_value_heads"],
+        head_dim=hf.get("head_dim")
+        or hf["hidden_size"] // hf["num_attention_heads"],
+        intermediate_dim=hf["intermediate_size"],
+        vocab_size=hf["vocab_size"],
+        max_position_embeddings=hf.get("max_position_embeddings", 32768),
+        rope_theta=float(hf.get("rope_theta", 1000000.0)),
+        rms_norm_eps=hf.get("rms_norm_eps", 1e-6),
+        qk_norm=True,
+        qk_norm_per_head=True,
+        tied_embeddings=hf.get("tie_word_embeddings", False),
+        n_experts=n_experts,
+        n_experts_per_tok=hf["num_experts_per_tok"],
+        moe_intermediate_dim=hf["moe_intermediate_size"],
+        moe_norm_topk=bool(hf.get("norm_topk_prob", True)),
+        moe_aux_loss_coef=hf.get("router_aux_loss_coef", 0.001),
+        n_router_experts=0 if width == n_experts else width,
+        expert_offset=share.get("rank", 0) * n_experts,
+        **{k: type(v)(hf.get(k, v)) for k, v in _SDAR_DEFAULTS.items()},
+    )
+
+
+def _sdar_moe_config_to_hf(cfg: ModelConfig) -> dict:
+    out = _llama_like_config_to_hf(cfg, "sdar_moe")
+    out.update(
+        architectures=["SDARMoeForCausalLM"],
+        hidden_act=cfg.hidden_act,
+        attention_bias=False,
+        decoder_sparse_step=1,
+        mlp_only_layers=[],
+        use_sliding_window=False,
+        num_experts=cfg.n_experts,
+        num_experts_per_tok=cfg.n_experts_per_tok,
+        moe_intermediate_size=cfg.moe_intermediate_dim,
+        norm_topk_prob=cfg.moe_norm_topk,
+        router_aux_loss_coef=cfg.moe_aux_loss_coef,
+        remasking_strategy=_SDAR_REMASKING,
+        **{k: getattr(cfg, k) for k in _SDAR_DEFAULTS},
+    )
+    if cfg.expert_share:
+        out["share"] = {
+            "router_num_experts": cfg.router_width,
+            "rank": cfg.expert_offset // cfg.n_experts,
+        }
+    return out
+
+
+register_hf_family(
+    HFFamily(
+        "sdar_moe",
+        _sdar_moe_config_from_hf,
+        _sdar_moe_config_to_hf,
+        # Qwen3-MoE's names, which are olmoe's: self_attn.{q,k}_norm
+        # ([head_dim]), mlp.gate, mlp.experts.{e}.{gate,up,down}_proj.
+        params_from_sd=_olmoe_params_from_sd,
+        params_to_sd=_olmoe_params_to_sd,
+    )
+)
+
+
 # ---------------- qwen3_next ----------------
 # A hybrid layer pattern: layer i is gated softmax attention when (i + 1) %
 # full_attention_interval == 0, else Gated DeltaNet (linear attention);
@@ -2770,6 +2871,8 @@ def infer_model_type(cfg: ModelConfig) -> str:
     when the caller didn't record where the weights came from."""
     if cfg.norm_type == "layernorm":
         return "gpt2"
+    if cfg.block_length:
+        return "sdar_moe"
     if cfg.is_hybrid:
         return "olmo_hybrid" if cfg.branch_norm == "output" else "qwen3_next"
     if cfg.n_sparse_layers or cfg.n_lightning_layers:

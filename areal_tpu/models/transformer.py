@@ -1064,12 +1064,14 @@ def _attention(
     cp_zigzag: bool = False,
     window: Optional[int] = None,
     scope: Optional[str] = None,
+    blocks=None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """The attention branch (softmax over per-head k/v, or latent) over
     packed rows of normed `h` -> (its output, what it leaves in the cache:
     k and v, or the one latent row a token).  `window`: a query sees the
     last `window` keys of its sequence (a window layer; None, all of
-    them); `scope`: the layer's inner name in a mixed plan (`inner_scope`)."""
+    them); `scope`: the layer's inner name in a mixed plan (`inner_scope`);
+    `blocks`: the block-causal mask's ids (`Ctx.block_mask`)."""
     b, s, _ = h.shape
     if cfg.is_latent:
         q, k, v, row = _latent_qkv(h, blk, cfg, cos, sin)
@@ -1080,7 +1082,7 @@ def _attention(
     if cp_manual is None and cp_mesh is None:
         attn = packed_attention(
             q, k, v, segment_ids, causal=True, use_flash=use_flash,
-            window=window, scope=scope,
+            window=window, scope=scope, blocks=blocks,
         )
     else:
         with jax.named_scope("layer/attn"):
@@ -1179,7 +1181,7 @@ def _attention_packed(ctx: Ctx, h, blk):
     return _attention(
         h, blk, ctx.cfg, ctx.segment_ids, ctx.cos, ctx.sin, ctx.use_flash,
         ctx.cp_mesh, ctx.cp_manual, ctx.cp_zigzag,
-        scope="full" if ctx.window_rope else None,
+        scope="full" if ctx.window_rope else None, blocks=ctx.block_mask,
     )
 
 
@@ -1391,15 +1393,23 @@ def _backbone(
     pp_microbatches: int = 4,
     expert_kernel: Optional[bool] = False,
     row_kernel=None,
+    stream_ids=None,
 ) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
     """-> (final-normed hidden states, summed MoE aux loss, per-layer rows
-    per expert [L, E] int32 — None for dense models and under PP)."""
+    per expert [L, E] int32 — None for dense models and under PP).
+    `stream_ids` (`cfg.block_length` alone; None, every token clean): the
+    rows' two streams, `engines/packing.py`."""
     x = _embed(params, cfg, tokens, positions)
     (cos, sin), window_rope = _rope(cfg, positions)
 
     refusal = plan_refusal(cfg, serving=False)
     if refusal and (cp_mesh is not None or pp_mesh is not None):
         raise refusal
+    block_mask = _block_ids(cfg, positions, stream_ids)
+    if block_mask is not None and (
+        cp_mesh is not None or pp_mesh is not None
+    ):
+        raise BlockLayoutError(_BLOCK_REFUSAL.layout)
 
     if pp_mesh is not None:
         from areal_tpu.parallel.pipeline import pipelined_blocks
@@ -1458,6 +1468,7 @@ def _backbone(
         params["blocks"], cfg, x, segment_ids, cos, sin, remat, use_flash,
         cp_mesh, cp_zigzag=zz_inv is not None, window_rope=window_rope,
         expert_kernel=expert_kernel, row_kernel=row_kernel,
+        block_mask=block_mask,
     )
     x = _final_norm(params, cfg, x)
     if zz_inv is not None:
@@ -1527,6 +1538,8 @@ def plan_refusal(cfg: ModelConfig, serving: bool):
     plan both can: every refusal of a plane or a layout asks here.  The
     first kind of the table that refuses speaks; leading layers are
     refused in latent attention's words, which name them."""
+    if cfg.block_length:
+        return _BLOCK_REFUSAL.of(serving)
     if cfg.is_pattern:
         return _PATTERN_REFUSAL.of(serving)
     refusing = set(branches_of(cfg)) | ({LATENT} if cfg.plan.prefix else set())
@@ -1714,7 +1727,7 @@ def _walk(
 def _blocks(
     blocks: Params, cfg: ModelConfig, x, segment_ids, cos, sin, remat,
     use_flash, cp_mesh=None, cp_zigzag: bool = False, window_rope=None,
-    expert_kernel: Optional[bool] = False, row_kernel=None,
+    expert_kernel: Optional[bool] = False, row_kernel=None, block_mask=None,
 ):
     """The block stack of every model over packed rows, every layer (all
     its branches, or each on its own: `_layer_of`) under the remat policy.
@@ -1725,7 +1738,7 @@ def _blocks(
     ctx = _packed_ctx(
         cfg, segment_ids, cos, sin, use_flash, remat, expert_kernel,
         cp_mesh=cp_mesh, cp_zigzag=cp_zigzag, window_rope=window_rope,
-        row_kernel=row_kernel,
+        row_kernel=row_kernel, block_mask=block_mask,
     )
     x, _, gave = _walk(
         cfg, blocks, x, None,
@@ -1794,6 +1807,7 @@ def hidden_states(
     with_moe_counts: bool = False,
     expert_kernel: Optional[bool] = False,
     row_kernel=None,
+    stream_ids=None,
 ) -> Tuple[jax.Array, ...]:
     """Backbone only: final-layernormed hidden states [B, S, D] (+ MoE aux
     loss), WITHOUT the LM head.  Lets engines fuse the head into a chunked
@@ -1816,6 +1830,7 @@ def hidden_states(
     x, aux, counts = _backbone(
         params, cfg, tokens, segment_ids, positions, remat, use_flash,
         cp_mesh, pp_mesh, pp_microbatches, expert_kernel, row_kernel,
+        stream_ids,
     )
     return (x, aux, counts) if with_moe_counts else (x, aux)
 
@@ -1869,7 +1884,10 @@ def forward_with_aux(
         params, cfg, tokens, segment_ids, positions, remat, use_flash,
         cp_mesh, pp_mesh, pp_microbatches,
     )
-    return _head(params, cfg, x), aux
+    logits = _head(params, cfg, x)
+    if cfg.block_length:  # in place, the mask token's logit left out
+        logits = mask_logit_out(cfg, logits)
+    return logits, aux
 
 
 # --------------------------------------------------------------------------
@@ -2121,6 +2139,7 @@ def prefill(
     segment_ids: jax.Array,  # [B, S] 1 where valid, 0 pad (single segment/row)
     cache: KVCache,
     use_flash: "bool | None" = None,
+    head: bool = True,
 ) -> Tuple[jax.Array, KVCache]:
     """Run the prompt through the model, filling cache[:, :, :S] and
     returning fp32 logits [B, V] at each row's LAST VALID position (the
@@ -2134,6 +2153,7 @@ def prefill(
         cfg, cos, sin, segment_ids, window_rope, use_flash, with_state=True,
         ring=cache.ring,
         ck_slots=None if cache.ck is None else cache.ck.shape[2],
+        block_mask=_block_ids(cfg, positions, None),
     )
     # Each population's new entries [its layers, ...], the prefix's layers
     # first ...
@@ -2155,6 +2175,8 @@ def prefill(
         )
 
     new_cache = {f: place(getattr(cache, f), entries[f]) for f in _CACHE_FIELDS}
+    if not head:  # the block loop reads no logits of the prompt's blocks
+        return None, KVCache(**new_cache)
     return _prefill_head(params, cfg, x, segment_ids), KVCache(**new_cache)
 
 
@@ -3010,3 +3032,147 @@ _CACHE_FIELDS = {
     f.name: tuple(n for n, b in BRANCHES.items() if f.name in b.cache)
     for f in dataclasses.fields(KVCache)
 }
+
+
+# --------------------------------------------------------------------------
+# Generation by diffusion over blocks (`cfg.block_length`): the block-causal
+# mask's ids over packed rows, and the static program's BLOCK step
+# --------------------------------------------------------------------------
+
+
+class BlockLayoutError(NotImplementedError):
+    """A layout or plane generation by diffusion over blocks (a decode
+    step of a block of tokens, the block-causal mask, the two-stream train
+    forward) cannot run on yet, refused by name rather than run as an
+    autoregressive model."""
+
+
+_BLOCK_REFUSAL = Refusal(
+    BlockLayoutError,
+    "generation by diffusion over blocks (block_length > 0) shards over "
+    "data and fsdp alone: the block-causal mask runs on one device's flash "
+    "kernel or the dense form, so a mesh split over model, seq or pipe is "
+    "refused",
+    "generation by diffusion over blocks (block_length > 0) runs on the "
+    "STATIC decode program alone: the serving chunk yields one token a "
+    "lane and step, and its pages, prefix sharing and speculation assume "
+    "it",
+)
+
+
+def _block_ids(cfg: ModelConfig, positions, stream_ids):
+    """(block ids, stream ids) of the block-causal mask — a token's block
+    is floor(position / block_length), by its ABSOLUTE position in its
+    sequence — or None for every autoregressive model."""
+    if not cfg.block_length:
+        return None
+    if stream_ids is None:
+        stream_ids = jnp.zeros_like(positions)
+    return positions // cfg.block_length, stream_ids
+
+
+def mask_logit_out(cfg: ModelConfig, logits: jax.Array) -> jax.Array:
+    """Logits with the mask token's set to -inf: no position may hold it,
+    so sampler, trainer and reference leave it out of every softmax."""
+    return jnp.where(
+        jnp.arange(logits.shape[-1]) == cfg.mask_token_id, -jnp.inf, logits)
+
+
+def _attention_block_step(ctx: Ctx, h, blk, cache, li):
+    """`_attention_step` of a BLOCK of tokens a row: the block's k/v go
+    to its own slots [slot, slot + B) and every one of its B queries
+    attends [valid_from, slot + B) — the committed blocks and the whole of
+    its own."""
+    cfg, slot = ctx.cfg, ctx.slot
+    b, n = h.shape[:2]
+    q, k, v = _block_kv(h, blk, cfg, ctx.cos, ctx.sin)  # [B, Q, h, d]
+    kc = _put_token(cache.k, k, li, slot)
+    vc = _put_token(cache.v, v, li, slot)
+    # The block's Q queries see ONE window, so they are `decode_attention`'s
+    # one token a row with Q times the query heads a key head: [B, Q, g, r,
+    # d] -> [B, 1, g (Q r), d].  As an op of its own over [B, Q, ...] XLA
+    # copied the layer's k and v out of the stacked cache in front of every
+    # forward (2 x 67 MB a layer; my chip run, PR 68).
+    g, d = cfg.n_kv_heads, cfg.head_dim
+    q = q.reshape(b, n, g, -1, d).transpose(0, 2, 1, 3, 4)
+    attn = decode_attention(
+        q.reshape(b, 1, -1, d), _layer_of_cache(kc, li),
+        _layer_of_cache(vc, li), ctx.valid_from, slot + n,
+    )
+    attn = attn.reshape(b, g, n, -1, d).transpose(0, 2, 1, 3, 4)
+    ao = _attn_out(
+        attn.reshape(b, n, cfg.q_dim), blk, cfg, _attn_gate(h, blk, cfg))
+    return ao, dataclasses.replace(cache, k=kc, v=vc), {}
+
+
+def block_step(
+    params: Params,
+    cfg: ModelConfig,
+    tokens: jax.Array,  # [B, Q] int32 — a block a row (mask tokens or not)
+    positions: jax.Array,  # [B, Q] int32 — their RoPE positions
+    cache: KVCache,
+    slot: jax.Array,  # scalar int32 — the block's FIRST cache slot, all rows
+    valid_from: jax.Array,  # [B] int32 — first valid cache slot per row
+    head: bool = True,
+    experts_in_place: Optional[bool] = None,
+    expert_kernel: Optional[bool] = None,
+) -> Tuple[jax.Array, ...]:
+    """`decode_step` of a block of Q = `cfg.block_length` tokens a row:
+    one forward of the block against the cache -> (fp32 logits [B, Q, V] IN
+    PLACE — row i the distribution of the token AT i, the mask token's
+    logit -inf — or None without `head`, the cache, the experts' rows
+    [L, E]).  The block's k/v land in its own slots [slot, slot + Q): a
+    denoising forward leaves there what its part-masked block gave, read
+    by no later step — the COMMIT forward of the clean block overwrites it,
+    so what the cache keeps of a block is the commit's."""
+    x = _embed(params, cfg, tokens, positions)  # [B, Q, D]
+    (cos, sin), _ = _rope(cfg, positions)
+    slot = jnp.asarray(slot, jnp.int32)
+    blocks, stacked = _scan_blocks(cfg, params["blocks"], experts_in_place)
+    ctx = Ctx(
+        cfg, cos, sin, stacked=stacked, slot=slot, valid_from=valid_from,
+        expert_kernel=stacked is not None and expert_kernel_choice(
+            cfg, expert_kernel),
+    )
+
+    def call(branch, h, blk, cache, li, at):
+        step = (_attention_block_step if branch == ATTENTION
+                else BRANCHES[branch].step)
+        return step(ctx, h, blk, cache, li)
+
+    x, new_cache, counts = _walk(
+        cfg, blocks, x, cache, call, gives=tuple(decode_counters(cfg)))
+    logits = None
+    if head:
+        logits = mask_logit_out(cfg, _head(params, cfg, _final_norm(params, cfg, x)))
+    return logits, new_cache, {n: c for n, c in counts.items() if c is not None}
+
+
+def block_token_output(
+    params: Params,
+    cfg: ModelConfig,
+    x: jax.Array,  # [B, S, D] from hidden_states() over two-stream rows
+    labels: jax.Array,  # [B, S] int32 — x_j at the masked stream's place of j
+    label_mask: jax.Array,  # [B, S] — where a log-prob is wanted
+    head_index: jax.Array,  # [B, K] int32 — those slots, padded with S
+    chunk_size: int = 512,
+    mesh=None,
+) -> jax.Array:
+    """`per_token_output` of a model with `block_length`: [B, S] fp32, at
+    the masked stream's place of position j the log-probability of token j
+    IN PLACE (`ops/functional.fused_label_logprobs`, the mask token left
+    out), 0 elsewhere.  The head runs over the K gathered rows alone."""
+    from areal_tpu.ops.functional import fused_label_logprobs
+
+    b, s, _ = x.shape
+    at = jnp.minimum(head_index, s - 1)
+    real = head_index < s
+    lp = fused_label_logprobs(
+        jnp.take_along_axis(x, at[..., None], axis=1),
+        head_weights(params, cfg),
+        jnp.take_along_axis(labels, at, axis=1),
+        jnp.take_along_axis(label_mask, at, axis=1) * real,
+        chunk_size, mesh, exclude=cfg.mask_token_id,
+    )
+    return jnp.zeros((b, s), jnp.float32).at[
+        jnp.arange(b)[:, None], head_index].set(lp, mode="drop")

@@ -32,14 +32,27 @@ def inner_scope(scope: Optional[str]):
 
 
 def make_packed_mask(
-    segment_ids: jax.Array, causal: bool = True, window: Optional[int] = None
+    segment_ids: jax.Array, causal: bool = True, window: Optional[int] = None,
+    blocks=None,
 ) -> jax.Array:
     """[B, S] segment ids -> [B, 1, S, S] boolean mask (True = attend).
     `window` (with `causal`): a query sees the last `window` keys of its
-    sequence, itself included."""
+    sequence, itself included.  `blocks`: (block ids, stream ids), [B, S]
+    int32 each — the BLOCK-causal mask of generation by diffusion over
+    blocks, in the place of the causal term: a query sees a key of its
+    sequence that is a clean (stream 0) token of an EARLIER block, or a
+    token of its own stream and block (which may lie after it in the
+    row)."""
     seg_q = segment_ids[:, :, None]
     seg_k = segment_ids[:, None, :]
     mask = (seg_q == seg_k) & (seg_q > 0)
+    if blocks is not None:
+        blk, stream = blocks
+        earlier = (stream[:, None, :] == 0) & (
+            blk[:, None, :] < blk[:, :, None])
+        own = (stream[:, None, :] == stream[:, :, None]) & (
+            blk[:, None, :] == blk[:, :, None])
+        return (mask & (earlier | own))[:, None, :, :]
     if causal:
         s = segment_ids.shape[-1]
         idx = jnp.arange(s)
@@ -67,6 +80,7 @@ def packed_attention_reference(
     causal: bool = True,
     logits_soft_cap: Optional[float] = None,
     window: Optional[int] = None,
+    blocks=None,
 ) -> jax.Array:
     n_q, n_kv = q.shape[2], k.shape[2]
     k = repeat_kv(k, n_q // n_kv)
@@ -77,7 +91,8 @@ def packed_attention_reference(
     ) * scale
     if logits_soft_cap is not None:
         logits = logits_soft_cap * jnp.tanh(logits / logits_soft_cap)
-    mask = make_packed_mask(segment_ids, causal=causal, window=window)
+    mask = make_packed_mask(
+        segment_ids, causal=causal, window=window, blocks=blocks)
     logits = jnp.where(mask, logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     # Fully-masked (padding) rows produce uniform probs; zero them out.
@@ -420,22 +435,32 @@ def packed_attention(
     use_flash=None,  # None=auto | bool | Mesh (shard_map the kernel)
     window: Optional[int] = None,
     scope: Optional[str] = None,
+    blocks=None,
 ) -> jax.Array:
     """Dispatch: Pallas flash kernel on TPU, dense reference elsewhere.
     A Mesh value runs the kernel under shard_map with the standard layout
     (batch over data/fsdp, heads over model) — the multi-chip flash path.
     `window`: a Python int, a query sees the last `window` keys of its
     sequence (None: all of them, the program it always was).  `scope`: an
-    inner name under `layer/attn` (a mixed plan tells its kinds apart)."""
+    inner name under `layer/attn` (a mixed plan tells its kinds apart).
+    `blocks`: (block ids, stream ids) of the block-causal mask
+    (`make_packed_mask`), one device's kernel or the reference; None: the
+    program it always was."""
     with inner_scope(scope):
         return _packed_attention(
-            q, k, v, segment_ids, causal, use_flash, window)
+            q, k, v, segment_ids, causal, use_flash, window, blocks)
 
 
-def _packed_attention(q, k, v, segment_ids, causal, use_flash, window):
+def _packed_attention(
+    q, k, v, segment_ids, causal, use_flash, window, blocks=None
+):
     from jax.sharding import Mesh
 
     if isinstance(use_flash, Mesh):
+        if blocks is not None:
+            raise NotImplementedError(
+                "the block-causal mask runs on one device's flash kernel: a "
+                "mesh that shards the kernel is not built for it")
         from areal_tpu.ops.pallas.flash_attention import (
             flash_attention_sharded,
         )
@@ -450,7 +475,9 @@ def _packed_attention(q, k, v, segment_ids, causal, use_flash, window):
     if use_flash:
         from areal_tpu.ops.pallas.flash_attention import flash_attention
 
-        return flash_attention(q, k, v, segment_ids, causal=causal, window=window)
+        return flash_attention(
+            q, k, v, segment_ids, causal=causal, window=window, blocks=blocks)
     return packed_attention_reference(
-        q, k, v, segment_ids, causal=causal, window=window
+        q, k, v, segment_ids, causal=causal, window=window, blocks=blocks
     )
+

@@ -65,19 +65,56 @@ def fused_next_token_logprobs(
 
     [B, S] fp32; 0 at the last position of every segment and padding.
     """
-    vocab_parallel = None
-    if sharding.head_vocab_shards(mesh, head.shape[1]) > 1:
-        # The stored layout first: nothing moves forward, and its transpose
-        # brings dhead back inside the gradient program.  Without it the
-        # gradient leaves V-sharded and the optimizer step re-lays the
-        # weight and both moments there and back (6 all-to-alls, not 1).
-        head = jax.lax.with_sharding_constraint(
-            head, sharding.named(mesh, sharding.HEAD_STORED)
-        )
-        vocab_parallel = sharding.named(mesh, sharding.HEAD_VOCAB_PARALLEL)
-        head = jax.lax.with_sharding_constraint(head, vocab_parallel)
-    b, s, d = x.shape
+    head, vocab_parallel = _head_layout(head, mesh)
     labels = jnp.pad(tokens[:, 1:], ((0, 0), (0, 1)), constant_values=0)
+    lp = _chunked_label_logprobs(x, head, labels, chunk_size, vocab_parallel)
+    return jnp.where(shifted_label_mask(segment_ids), lp, 0.0)
+
+
+def _head_layout(head: jax.Array, mesh: Optional[Mesh]):
+    """(the head as a fused log-prob head reads it, its vocabulary-parallel
+    sharding or None): `fused_next_token_logprobs`' rule."""
+    if sharding.head_vocab_shards(mesh, head.shape[1]) <= 1:
+        return head, None
+    # The stored layout first: nothing moves forward, and its transpose
+    # brings dhead back inside the gradient program.  Without it the
+    # gradient leaves V-sharded and the optimizer step re-lays the weight
+    # and both moments there and back (6 all-to-alls, not 1).
+    head = jax.lax.with_sharding_constraint(
+        head, sharding.named(mesh, sharding.HEAD_STORED)
+    )
+    vocab_parallel = sharding.named(mesh, sharding.HEAD_VOCAB_PARALLEL)
+    return jax.lax.with_sharding_constraint(head, vocab_parallel), vocab_parallel
+
+
+@jax.named_scope("head_logprob")
+def fused_label_logprobs(
+    x: jax.Array,  # [B, K, D] final hidden states of the rows to score
+    head: jax.Array,  # [D, V]
+    labels: jax.Array,  # [B, K] int32 — the token AT each row's place
+    label_mask: jax.Array,  # [B, K] — where a label is wanted
+    chunk_size: int = 512,
+    mesh: Optional[Mesh] = None,
+    exclude: Optional[int] = None,
+) -> jax.Array:
+    """log softmax(x head)[label] a row, IN PLACE — no shift: the labels
+    are explicit, as a model that predicts the token at a position (and
+    not the next one) has them — by `fused_next_token_logprobs`' chunked,
+    checkpointed scan.  `exclude`: a vocabulary id left out of the softmax
+    (its logit -inf: a mask token no position may hold).  [B, K] fp32, 0
+    where `label_mask` is not set."""
+    head, vocab_parallel = _head_layout(head, mesh)
+    lp = _chunked_label_logprobs(
+        x, head, labels, chunk_size, vocab_parallel, exclude)
+    return jnp.where(label_mask > 0, lp, 0.0)
+
+
+def _chunked_label_logprobs(
+    x, head, labels, chunk_size, vocab_parallel, exclude=None
+):
+    """[B, S] fp32 log softmax(x head)[labels], a chunk of positions at a
+    time inside a checkpointed scan."""
+    b, s, d = x.shape
     t = b * s
     c = min(chunk_size, t)
     pad = (-t) % c
@@ -97,14 +134,16 @@ def fused_next_token_logprobs(
         )
         if vocab_parallel is not None:
             logits = jax.lax.with_sharding_constraint(logits, vocab_parallel)
+        if exclude is not None:
+            logits = jnp.where(
+                jnp.arange(logits.shape[-1]) == exclude, -jnp.inf, logits)
         lse = jax.scipy.special.logsumexp(logits, axis=-1)
         tgt = jnp.take_along_axis(logits, li[:, None], axis=-1)[:, 0]
         return carry, tgt - lse
 
     body = jax.checkpoint(body)
     _, lp = jax.lax.scan(body, None, (xc, lc))
-    lp = lp.reshape(-1)[:t].reshape(b, s)
-    return jnp.where(shifted_label_mask(segment_ids), lp, 0.0)
+    return lp.reshape(-1)[:t].reshape(b, s)
 
 
 def masked_normalization(

@@ -275,14 +275,17 @@ def _chunk_index(n_chunks: int, tiles: int):
 
 
 def _tile_mask(seg_q, seg_k, qi, ki, block_q, block_k, causal, window=None,
-               k_major=False, picked=None):
+               k_major=False, picked=None, block_causal=False):
     """seg_q [bq, 1], seg_k [1, bk] -> the tile's [bq, bk] mask; `k_major`:
     seg_q [1, bq], seg_k [bk, 1] -> the same mask transposed, [bk, bq], for
     the kernel that walks k blocks (dkv).  A sequence's tokens are
     contiguous in the row, so the distance between two of its positions is
     the distance between their places in the row: `window` masks keys
     `window` or more places behind the query.  `picked`: the tile of a
-    block choice (`_picked_tile`), one more term."""
+    block choice (`_picked_tile`), one more term.  `block_causal`: the ids
+    are `block_codes` and the mask the block-causal one."""
+    if block_causal:
+        return _block_tile_mask(seg_q, seg_k)
     mask = (seg_q == seg_k) & (seg_q > 0)
     if picked is not None:
         mask &= picked
@@ -298,6 +301,38 @@ def _tile_mask(seg_q, seg_k, qi, ki, block_q, block_k, causal, window=None,
         if window is not None:
             mask &= q_pos - k_pos < window
     return mask
+
+
+# The block-causal mask (generation by diffusion over blocks): the kernels'
+# trace-time `block_causal` is set and the id operand holds, a token, its
+# sequence, stream and block in ONE int32 (`block_codes`), so the kernels
+# take no operand they did not have and a call without `blocks` traces the
+# program it always did.
+_BLOCK_BITS, _STREAM_BIT = 16, 1 << 16
+
+
+def block_codes(seg, block_ids, stream_ids):
+    """[B, S] ids -> the kernels' id operand under `block_causal`: the
+    sequence's id above bit 17, the stream (0 clean, 1 masked) in bit 16,
+    the block below (65,536 blocks a sequence, 16,383 sequences a row)."""
+    return (
+        (seg.astype(jnp.int32) << (_BLOCK_BITS + 1))
+        | ((stream_ids.astype(jnp.int32) & 1) << _BLOCK_BITS)
+        | (block_ids.astype(jnp.int32) & (_STREAM_BIT - 1))
+    )
+
+
+def _block_tile_mask(code_q, code_k):
+    """`_tile_mask` of codes: the same sequence, and the key a clean token
+    of an EARLIER block or a token of the query's own stream and block.
+    No term reads a place in the row, so a key may lie after its query."""
+    low = 2 * _STREAM_BIT - 1  # stream and block
+    same_seq = ((code_q >> (_BLOCK_BITS + 1)) == (code_k >> (_BLOCK_BITS + 1))
+                ) & (code_q > low)
+    earlier = ((code_k & _STREAM_BIT) == 0) & (
+        (code_k & (_STREAM_BIT - 1)) < (code_q & (_STREAM_BIT - 1)))
+    own = (code_q & low) == (code_k & low)
+    return same_seq & (earlier | own)
 
 
 def _tile_rows(i, block):
@@ -459,7 +494,7 @@ def _fwd_kernel(
     o_ref, lse_ref,  # outputs
     m_scr, l_scr, acc_scr,  # scratch
     *, scale: float, block_q: int, block_k: int, hq: int, nq: int,
-    tiles: int, causal: bool, window=None, choice=None,
+    tiles: int, causal: bool, window=None, choice=None, block_causal=False,
 ):
     b, qi, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
@@ -487,6 +522,7 @@ def _fwd_kernel(
         mask = _tile_mask(
             seg_q, seg_k_ref[0, j][0:1, :], qi, ki, block_q, block_k, causal,
             window, picked=_picked_keys(choice, b, hq, ki, j),
+            block_causal=block_causal,
         )
         s = jnp.where(mask, s, NEG_INF)
 
@@ -607,7 +643,7 @@ def _q_major_specs(hq, hkv, nq, nk, d, block_q, block_k, itemsize,
 
 def _fwd(
     q, k, v, seg, sched, hq, scale, block_q, block_k, causal, window=None,
-    choice=None,
+    choice=None, block_causal=False,
 ) -> Tuple[jax.Array, jax.Array]:
     """q: [B*hq, S, D]; k/v: [B*hkv, S, D] (unexpanded GQA); seg: [B, S]
     int32; sched: the tiles to visit; choice: a `BlockChoice` or None.
@@ -633,6 +669,7 @@ def _fwd(
             _fwd_kernel,
             scale=scale, block_q=block_q, block_k=block_k, hq=hq, nq=nq,
             tiles=tiles, causal=causal, window=window,
+            block_causal=block_causal,
         ),
         (k_lo, k_hi),
         (seg_q, seg_kb, q, k, v),
@@ -664,7 +701,7 @@ def _dq_kernel(
     dq_ref,
     dq_scr,
     *, scale, block_q, block_k, hq, nq, tiles, causal, window=None,
-    choice=None,
+    choice=None, block_causal=False,
 ):
     b, qi, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
@@ -689,6 +726,7 @@ def _dq_kernel(
         mask = _tile_mask(
             seg_q, seg_k_ref[0, j][0:1, :], qi, ki, block_q, block_k, causal,
             window, picked=_picked_keys(choice, b, hq, ki, j),
+            block_causal=block_causal,
         )
         p = jnp.where(mask, jnp.exp(s - lse), 0.0)
         dp = jax.lax.dot_general(
@@ -716,7 +754,7 @@ def _dkv_kernel(
     dk_ref, dv_ref,
     dk_scr, dv_scr,
     *, scale, block_q, block_k, hq, nk, tiles, causal, window=None,
-    choice=None,
+    choice=None, block_causal=False,
 ):
     b, ki, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
@@ -748,6 +786,7 @@ def _dkv_kernel(
             window, k_major=True,
             picked=None if hot is None else _picked_tile(
                 hot, sel_ref[0, 0, j]),
+            block_causal=block_causal,
         )
         p = jnp.where(mask, jnp.exp(s - lse), 0.0)
         dv_scr[:] += jax.lax.dot_general(
@@ -775,7 +814,7 @@ def _dkv_kernel(
 
 
 def _dq(q, k, v, do, lse, delta, seg, sched, hq, scale, block_q, block_k,
-        causal, window=None, choice=None) -> jax.Array:
+        causal, window=None, choice=None, block_causal=False) -> jax.Array:
     """dq [B*hq, S, D] in q's type: one grid step a q block, the loop over
     its live keys a trip at a time (`_widen`)."""
     bh, s, d = q.shape
@@ -799,6 +838,7 @@ def _dq(q, k, v, do, lse, delta, seg, sched, hq, scale, block_q, block_k,
             _dq_kernel,
             scale=scale, block_q=block_q, block_k=block_k, hq=hq, nq=nq,
             tiles=tiles, causal=causal, window=window,
+            block_causal=block_causal,
         ),
         (k_lo, k_hi),
         (seg_q, seg_kb, q, k, v, do, lse, delta),
@@ -816,7 +856,8 @@ def _dq(q, k, v, do, lse, delta, seg, sched, hq, scale, block_q, block_k,
 
 
 def _dkv(q, k, v, do, lse, delta, seg, sched, hq, scale, block_q, block_k,
-         causal, window=None, choice=None) -> Tuple[jax.Array, jax.Array]:
+         causal, window=None, choice=None, block_causal=False,
+         ) -> Tuple[jax.Array, jax.Array]:
     """dk, dv [B*hq, S, D] fp32, per Q-HEAD (the grid walks q heads; the
     caller sums the heads that share a kv head): one grid step a k block,
     K/V and the k ids by step, the q side resident, the loop over its live
@@ -898,6 +939,7 @@ def _dkv(q, k, v, do, lse, delta, seg, sched, hq, scale, block_q, block_k,
             _dkv_kernel,
             scale=scale, block_q=block_q, block_k=block_k, hq=hq, nk=nk,
             tiles=tiles, causal=causal, window=window,
+            block_causal=block_causal,
         ),
         (q_lo, q_hi),
         (seg_qb, seg_k, q, k, v, do, lse, delta),
@@ -927,7 +969,8 @@ def _dkv(q, k, v, do, lse, delta, seg, sched, hq, scale, block_q, block_k,
 
 
 def _bwd(
-    scale, block_q, block_k, causal, res, do, window=None, choice=None
+    scale, block_q, block_k, causal, res, do, window=None, choice=None,
+    block_causal=False,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     q, k, v, o, lse, seg, sched = res
     bh, s, d = q.shape
@@ -938,7 +981,7 @@ def _bwd(
         o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1, keepdims=True
     )  # [BH, S, 1]
     args = (q, k, v, do, lse, delta, seg, sched, hq, scale, block_q, block_k,
-            causal, window, choice)
+            causal, window, choice, block_causal)
     dq = _dq(*args)
     dk_x, dv_x = _dkv(*args)
 
@@ -957,31 +1000,37 @@ def _bwd(
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11))
 def _flash_bhsd(
-    q, k, v, seg, sched, choice, scale, block_q, block_k, causal, window
+    q, k, v, seg, sched, choice, scale, block_q, block_k, causal, window,
+    block_causal=False,
 ):
     return _flash_fwd_rule(
-        q, k, v, seg, sched, choice, scale, block_q, block_k, causal, window
+        q, k, v, seg, sched, choice, scale, block_q, block_k, causal, window,
+        block_causal,
     )[0]
 
 
 def _flash_fwd_rule(
-    q, k, v, seg, sched, choice, scale, block_q, block_k, causal, window
+    q, k, v, seg, sched, choice, scale, block_q, block_k, causal, window,
+    block_causal,
 ):
     hq = q.shape[0] // seg.shape[0]
     o, lse = _fwd(
         q, k, v, seg, sched, hq, scale, block_q, block_k, causal, window,
-        choice,
+        choice, block_causal,
     )
     return o, ((q, k, v, o, lse, seg, sched), choice)
 
 
-def _flash_bwd_rule(scale, block_q, block_k, causal, window, res, do):
+def _flash_bwd_rule(
+    scale, block_q, block_k, causal, window, block_causal, res, do
+):
     # Ids, the schedule and a choice (bools and indices) carry no gradient.
     res, choice = res
     return (
-        *_bwd(scale, block_q, block_k, causal, res, do, window, choice),
+        *_bwd(scale, block_q, block_k, causal, res, do, window, choice,
+              block_causal),
         None, None, None,
     )
 
@@ -999,6 +1048,7 @@ def flash_attention(
     block_k: int = DEFAULT_BLOCK_K,
     window: "int | None" = None,
     choice: "BlockChoice | None" = None,
+    blocks=None,
 ) -> jax.Array:
     """Segment-aware causal flash attention over packed rows.  GQA is
     native: kv stays at n_kv heads and the kernel's BlockSpec index maps
@@ -1008,9 +1058,17 @@ def flash_attention(
     and a band in the schedule (`live_schedule`); None traces the program
     it always did.  `choice`: a `BlockChoice`, one more term in the tile
     mask of all three kernels and nothing in the schedule; it carries no
-    gradient, and None traces the program it always did."""
+    gradient, and None traces the program it always did.  `blocks`:
+    (block ids, stream ids) [B, S] — the BLOCK-causal mask in the causal
+    term's place (`ops/attention.make_packed_mask`): the ids ride the id
+    operand (`block_codes`), the schedule is the causal one — a stream
+    starts, and a block lies, on a multiple of the block's length in the
+    row (`engines/packing.py`), which divides a tile, so a visible key
+    after its query lies in the query's own tile."""
     if window is not None and not causal:
         raise ValueError("a sliding window is causal")
+    if blocks is not None and (window is not None or choice is not None):
+        raise ValueError("the block-causal mask takes no window or choice")
     b, s, hq, d = q.shape
     hkv = k.shape[2]
 
@@ -1027,10 +1085,13 @@ def flash_attention(
         return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
 
     seg = segment_ids.astype(jnp.int32)
+    # Under `blocks` the schedule stays the causal one of the sequences' ids.
+    ids = seg if blocks is None else block_codes(seg, *blocks)
     o = _flash_bhsd(
-        to_bhsd(q), to_bhsd(k), to_bhsd(v), seg,
+        to_bhsd(q), to_bhsd(k), to_bhsd(v), ids,
         live_schedule(seg, block_q, block_k, causal, window),
         choice, d**-0.5, block_q, block_k, causal, window,
+        blocks is not None,
     )
     return o.reshape(b, hq, s, d).transpose(0, 2, 1, 3)
 

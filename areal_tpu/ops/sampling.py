@@ -240,3 +240,46 @@ def spec_accept(
     chosen = jnp.take_along_axis(scaled, emitted[:, :, None], axis=-1)[..., 0]
     logps = chosen - lse
     return emitted, logps, n_acc + 1
+
+
+# --------------------------------------------------------------------------
+# Generation by diffusion over blocks: a draw with its confidence, and the
+# places a denoising step reveals
+# --------------------------------------------------------------------------
+
+
+@jax.named_scope("head_logprob")
+def draw_with_confidence(
+    logits: jax.Array,  # [N, V] fp32
+    u: jax.Array,  # [N] uniforms in [0, 1)
+    temperature: float = 1.0,
+    top_k: int = 0,
+    top_p: float = 1.0,
+    greedy: bool = False,
+) -> Tuple[jax.Array, jax.Array]:
+    """`sample_token`'s draw from its warped distribution, with that
+    distribution's probability of the token drawn (the CONFIDENCE an
+    unmasking rule ranks places by) -> (token [N] int32, confidence [N])."""
+    scaled = logits / jnp.maximum(temperature, 1e-6)
+    warped = apply_top_p(apply_top_k(scaled, top_k), top_p)
+    if greedy:
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        chosen = jnp.take_along_axis(warped, tok[:, None], axis=-1)[:, 0]
+        return tok, jnp.exp(chosen - jax.nn.logsumexp(warped, axis=-1))
+    tok, logp = _inverse_cdf_draw(warped, u)
+    return tok, jnp.exp(logp)
+
+
+def reveal_by_confidence(
+    conf: jax.Array,  # [R, B] fp32 — a draw's confidence a place
+    masked: jax.Array,  # [R, B] bool — the places still masked
+    n: jax.Array,  # scalar int — places to reveal this step
+) -> jax.Array:
+    """The places of a block a denoising step reveals, [R, B] bool: the `n`
+    masked places of largest confidence (all of them where fewer are
+    masked), ties to the lower position."""
+    c_i, c_j = conf[:, :, None], conf[:, None, :]
+    idx = jnp.arange(conf.shape[1])
+    ahead = (c_j > c_i) | ((c_j == c_i) & (idx[None, :] < idx[:, None])[None])
+    rank = jnp.sum(ahead & masked[:, None, :], axis=-1)  # among the masked
+    return masked & (rank < n)

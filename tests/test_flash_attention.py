@@ -14,7 +14,10 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from areal_tpu.engines import packing
+from areal_tpu.ops import attention
 from areal_tpu.ops.attention import packed_attention_reference
+from areal_tpu.ops.pallas import flash_attention as fa
 from areal_tpu.ops.pallas.flash_attention import flash_attention
 
 
@@ -285,6 +288,139 @@ class TestBlockChoice:
         # and the choice is not every block: the selection shows
         selected = self._out_and_grads("kernels", *args)[0]
         assert np.abs(np.asarray(selected - plain[0])).max() > 0.1
+
+
+class TestBlockCausal:
+    """The two-stream BLOCK-causal mask of generation by diffusion over
+    blocks (`flash_attention(blocks=(block ids, stream ids))`: a query sees
+    the clean keys of EARLIER blocks of its sequence and the keys of its
+    own stream and block, those after it in the row too): all three
+    kernels, interpreted, against `packed_attention_reference` under the
+    dense mask; a block never straddles a tile; the schedule is the causal
+    one of the sequences' ids."""
+
+    BLOCK = 4
+
+    def _rows(self, s=512):
+        """Two rows of sequences, each (length, first masked block): the
+        clean stream padded to the block, the masked stream behind it."""
+        def row(seqs):
+            seg, blk, stream = (np.zeros(s, np.int32) for _ in range(3))
+            off, b = 0, self.BLOCK
+            for n, (l, m0) in enumerate(seqs, 1):
+                seg[off: off + l] = n
+                blk[off: off + l] = np.arange(l) // b
+                off += -(-l // b) * b
+                m = (-(-l // b) - m0) * b
+                seg[off: off + m] = n
+                blk[off: off + m] = m0 + np.arange(m) // b
+                stream[off: off + m] = 1
+                off += m
+            return seg, blk, stream
+
+        rows = [row([(70, 3), (45, 0), (130, 20)]), row([(201, 10)])]
+        return tuple(jnp.asarray(np.stack(x)) for x in zip(*rows))
+
+    def _operands(self, rng, s=512, dtype=jnp.float32):
+        q, k, v, _ = _inputs(rng, b=2, s=s, dtype=dtype)
+        return q, k, v
+
+    @pytest.mark.parametrize("trip", [1, 4], ids=["trip128", "trip512"])
+    def test_forward_and_gradients_match_the_dense_mask(
+            self, rng, monkeypatch, trip):
+        _force_trip(monkeypatch, trip)
+        seg, blk, stream = self._rows()
+        q, k, v = self._operands(rng)
+        blocks = (blk, stream)
+        got = flash_attention(q, k, v, seg, blocks=blocks)
+        want = packed_attention_reference(q, k, v, seg, blocks=blocks)
+        np.testing.assert_allclose(got, want, rtol=_tol(q), atol=_tol(q))
+
+        def loss(fn):
+            return lambda q, k, v: (fn(q, k, v, seg, blocks=blocks) ** 2).sum()
+
+        g = jax.grad(loss(flash_attention), argnums=(0, 1, 2))(q, k, v)
+        w = jax.grad(loss(packed_attention_reference), argnums=(0, 1, 2))(
+            q, k, v)
+        _assert_grads_close(g, w, 2e-4)
+
+    def test_the_mask_is_the_rule_and_sees_keys_after_the_query(self):
+        seg, blk, stream = self._rows()
+        mask = np.asarray(attention.make_packed_mask(
+            seg, blocks=(blk, stream)))[:, 0]
+        seg, blk, stream = (np.asarray(x) for x in (seg, blk, stream))
+        for r in range(2):
+            same = (seg[r][:, None] == seg[r][None, :]) & (seg[r][:, None] > 0)
+            earlier = (stream[r][None, :] == 0) & (
+                blk[r][None, :] < blk[r][:, None])
+            own = (stream[r][None, :] == stream[r][:, None]) & (
+                blk[r][None, :] == blk[r][:, None])
+            np.testing.assert_array_equal(mask[r], same & (earlier | own))
+        # A clean token sees the rest of its block: keys AFTER it in the row.
+        assert mask[0, 0, 3] and not mask[0, 0, 4]
+        # A masked token sees no clean token of its own block.
+        first_masked = int(np.flatnonzero(stream[0])[0])
+        own_clean = np.flatnonzero(
+            (seg[0] == seg[0, first_masked]) & (stream[0] == 0)
+            & (blk[0] == blk[0, first_masked]))
+        assert not mask[0, first_masked, own_clean].any()
+        assert mask[0, first_masked, first_masked + 3]
+
+    def test_no_block_straddles_a_tile_and_the_schedule_is_the_causal_one(
+            self):
+        """Streams start on multiples of the block and a tile is a multiple
+        of it, so every visible key after its query lies in the query's own
+        tile: the causal schedule of the sequences' ids covers the mask."""
+        seg, blk, stream = self._rows()
+        mask = np.asarray(attention.make_packed_mask(
+            seg, blocks=(blk, stream)))[:, 0]
+        t = 128
+        sched = fa.live_schedule(seg, t, t, True)
+        n = seg.shape[1] // t
+        k_lo = np.asarray(sched.k_lo).reshape(2, n)
+        k_hi = np.asarray(sched.k_hi).reshape(2, n)
+        live = 0
+        for r in range(2):
+            for qi in range(n):
+                tiles = mask[r, qi * t: (qi + 1) * t].reshape(t, n, t)
+                has = tiles.any(axis=(0, 2))
+                assert not has[qi + 1:].any()  # nothing past the diagonal
+                for ki in np.flatnonzero(has):
+                    assert k_lo[r, qi] <= ki <= k_hi[r, qi]
+                live += int(has.sum())
+        counted, grid = packing.flash_tile_counts(np.asarray(seg))
+        assert live <= counted <= grid
+        starts = np.flatnonzero(np.diff(
+            np.asarray(seg[0]) * 2 + np.asarray(stream[0]), prepend=0) != 0)
+        assert all(i % self.BLOCK == 0 for i in starts
+                   if np.asarray(seg[0])[i] > 0)
+
+    def test_the_codes_hold_sequence_stream_and_block(self):
+        seg = jnp.asarray([[0, 1, 1, 16383]])
+        blk = jnp.asarray([[0, 0, 65535, 7]])
+        stream = jnp.asarray([[0, 0, 1, 1]])
+        code = np.asarray(fa.block_codes(seg, blk, stream))
+        assert (code >> 17).tolist() == [[0, 1, 1, 16383]]
+        assert ((code >> 16) & 1).tolist() == [[0, 0, 1, 1]]
+        assert (code & 0xFFFF).tolist() == [[0, 0, 65535, 7]]
+        assert (code >= 0).all()
+
+    def test_no_blocks_traces_the_program_it_always_was(self):
+        q = jax.ShapeDtypeStruct((1, 256, 4, 32), jnp.float32)
+        kv = jax.ShapeDtypeStruct((1, 256, 2, 32), jnp.float32)
+        seg = jax.ShapeDtypeStruct((1, 256), jnp.int32)
+        plain = jax.jit(flash_attention).lower(q, kv, kv, seg).as_text()
+        none = jax.jit(lambda *a: flash_attention(*a, blocks=None)).lower(
+            q, kv, kv, seg).as_text()
+        both = jax.jit(lambda q, k, v, s: flash_attention(
+            q, k, v, s, blocks=(s, s))).lower(q, kv, kv, seg).as_text()
+        strip = lambda text: text.split("\n", 1)[1]  # the module's name line
+        assert strip(plain) == strip(none) != strip(both)
+        with pytest.raises(ValueError, match="window or choice"):
+            flash_attention(
+                jnp.zeros(q.shape), jnp.zeros(kv.shape), jnp.zeros(kv.shape),
+                jnp.ones(seg.shape, jnp.int32), window=4,
+                blocks=(jnp.zeros(seg.shape, jnp.int32),) * 2)
 
 
 class TestTripWidths:
@@ -650,6 +786,8 @@ class TestTPULowering:
         # dots3_note's sliding layers: 8 held heads, q/k 256 with v (128)
         # carried on zero columns, a band of 513 keys over rows of 13,312
         "dots3_window_8x13312x256": (1, 13312, 8, 8, 256, 513),
+        # sdar_moe's two-stream rows under the block-causal mask
+        "sdar_blocks_32x8192x128": (1, 8192, 32, 4, 128, "blocks"),
     }
     # The trip each cell's FORWARD calls take (`_trip_blocks`), as the scope
     # around the kernel says it (dq's keys and dkv's queries: 512 everywhere).
@@ -659,6 +797,8 @@ class TestTPULowering:
     def _cell(self, cell, sharding=None):
         b, s, n_q, n_kv, d, *window = self.CELL_SHAPES[cell]
         window = window[0] if window else None
+        blocks = window == "blocks"
+        window = None if blocks else window
         q = jax.ShapeDtypeStruct((b, s, n_q, d), jnp.bfloat16,
                                  sharding=sharding)
         kv = jax.ShapeDtypeStruct((b, s, n_kv, d), jnp.bfloat16,
@@ -666,6 +806,8 @@ class TestTPULowering:
         seg = jax.ShapeDtypeStruct((b, s), jnp.int32, sharding=sharding)
 
         def attend(q, k, v, seg):
+            if blocks:  # the ids ride the id operand: any ids do
+                return flash_attention(q, k, v, seg, blocks=(seg, seg % 2))
             return flash_attention(q, k, v, seg, window=window)
 
         def loss(q, k, v, seg):

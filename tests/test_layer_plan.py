@@ -433,6 +433,10 @@ _PARENT_FLOPS = {
     # toy's two full layers with their indexers, three sliding ones, the
     # selected keys of a query capped at index_topk 256 and the band of 9).
     "dots3-note-prev-l5-e8-h8": (163648, 8366456832.0, 1001366272.0),
+    # PR 68's neither: as PR 68 first printed them.  Generation by diffusion
+    # over blocks: the train stack runs over two streams of slots and the
+    # head over one, a block takes T + 1 = 3 forwards (`base/monitor.py`).
+    "sdar-30b-a3b-chat-l8-e16": (70656, 12331253760.0, 1350709248.0),
 }
 
 

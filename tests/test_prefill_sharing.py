@@ -225,6 +225,9 @@ _CELL_SHARES = {
     "sala-docrl8-longctx": "waves",
     "olmoh-rollout64-512": None,
     "dots3n-docrl8-longctx": "waves",
+    # The block loop (`engines/block_diffusion.py`) never asks the rule: it
+    # prefills every row; by its shape the rule would not share either.
+    "sdar-rollout64-512": None,
 }
 
 
