@@ -112,6 +112,7 @@ def build(engine, b: int, sp: int, s_total: int, nb: int, g, with_cache):
     n_reveal = jnp.asarray(reveals(cfg), jnp.int32)
     in_place = engine._expert_leaves_in_place
     expert_kernel = engine._expert_kernel
+    row_kernel = engine._row_kernel
     counters = tfm.decode_counters(cfg)
     wave = engine._prefill_wave_rows(b, sp)
     dtype = engine.compute_dtype
@@ -120,7 +121,8 @@ def build(engine, b: int, sp: int, s_total: int, nb: int, g, with_cache):
     def forward(params, x, pos, cache, slot, valid_from, sums, head=True):
         logits, cache, given = tfm.block_step(
             params, cfg, x, pos, cache, slot, valid_from, head=head,
-            experts_in_place=in_place, expert_kernel=expert_kernel)
+            experts_in_place=in_place, expert_kernel=expert_kernel,
+            row_kernel=row_kernel)
         at = LoopStep(slot, valid_from, cache, x.shape[0] * blk)
         sums = {
             name: sums[name] + counter.step(given.get(name), cfg, at)

@@ -455,6 +455,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         self.last_pool_stats: Dict[str, Any] = {}
         self._decode_sums = self._zero_decode_sums()
         self._bd_counts = 0.0
+        self._kv_tiles = [0, 0]
         # Serving-plane chunk counters of the current generate() call
         # (see _serving_counters); folded into last_pool_stats at its end.
         self._chunk_stats: Dict[str, Any] = _new_chunk_stats()
@@ -791,6 +792,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         self.last_pool_stats = {}
         self._decode_sums = self._zero_decode_sums()
         self._bd_counts = 0.0  # `_block_rollout`'s, summed over its chunks
+        self._kv_tiles = [0, 0]  # `_kv_stats`: slot tiles live, allocated
         self.lanes_dispatched = 0
         self.lanes_live = 0
         self.lanes_slack = 0
@@ -2375,6 +2377,56 @@ class GeneratorEngine(HostOffloadMixin, Engine):
         rows."""
         return None if self.mesh.size == 1 else self.mesh
 
+    def _kv_stats(self, s_total: int, valid_from, ends) -> Dict[str, float]:
+        """What a static call's softmax attention ran on, as
+        `last_pool_stats` keys, on the host from numbers the call has:
+        `gen/kv_kernel` — 1.0 where the program's attention is the kernel
+        `kv_decode` (`tfm.kv_kernel_form`, the program's own chooser on the
+        same inputs), else 0.0 — and with the kernel `gen/kv_live_tile_share`
+        — the slot tiles inside a row's window [valid_from, end), summed
+        over the rows and the forwards of the generate call so far (`ends`:
+        each forward's `valid_to`), over the tiles allocated: what the
+        kernel reads of what the XLA form read."""
+        if not tfm.kv_kernel_form(self.cfg, self._row_kernel, s_total):
+            return {"gen/kv_kernel": 0.0}
+        from areal_tpu.ops.pallas.kv_decode import BLOCK_S as tile
+
+        lo = np.asarray(valid_from, np.int64)[:, None]
+        hi = np.asarray(ends, np.int64)[None, :]
+        live = np.where(hi > lo, (hi - 1) // tile - lo // tile + 1, 0)
+        self._kv_tiles[0] += int(live.sum())
+        self._kv_tiles[1] += live.size * -(-s_total // tile)
+        return {
+            "gen/kv_kernel": 1.0,
+            "gen/kv_live_tile_share":
+                self._kv_tiles[0] / max(self._kv_tiles[1], 1),
+        }
+
+    # The two loops' windows are made HERE and not in `static_rollout` /
+    # `_block_rollout`: with the token loop's three lines of numpy in
+    # `static_rollout`'s own body, the process's FIRST `jit(gen)` lowered in
+    # 4.7 s where it lowers in 2.9 — in a cell that never takes the kernel,
+    # before the lines ever ran (`nemo3n-rollout64-512`, twelve probes; PERF.md
+    # section 7, A2 (0)(iii)).  A call with plain arguments keeps 2.9.
+
+    def _kv_token_stats(self, s_total, sp, prompt_len, gen_len):
+        """`_kv_stats` of the token loop: it ran until its last row was
+        done, and step t attends [valid_from, sp + t + 1)."""
+        steps = int(gen_len.max(initial=0))
+        return self._kv_stats(
+            s_total, sp - prompt_len, sp + 1 + np.arange(steps))
+
+    def _kv_block_stats(self, s_total, sp, whole_len, blocks):
+        """`_kv_stats` of the block loop: the first block's log-prob
+        forward, then a block's denoising forwards and its commit, each
+        over [valid_from, the block's end)."""
+        cfg = self.cfg
+        ends = sp + cfg.block_length * np.arange(1, int(blocks) + 1)
+        return self._kv_stats(
+            s_total, sp - whole_len,
+            np.concatenate([ends[:1], np.repeat(
+                ends, cfg.denoising_forwards + 1)]))
+
     @property
     def _expert_kernel(self) -> Optional[bool]:
         """What the static program's in-place expert matmuls take: None,
@@ -2466,6 +2518,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                         self._decode_sums[name], cfg, self.params))
         self._bd_counts = self._bd_counts + counts.astype(float)
         stats.update(bd.report(cfg, self._bd_counts, b))
+        stats.update(self._kv_block_stats(s_total, sp, whole_len, counts[0]))
         tracer.counter("bd", **{
             k.split("/")[1]: v for k, v in stats.items()
             if k.startswith("bd/") and not isinstance(v, list)})
@@ -2558,6 +2611,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                         sums[name]).astype(float)
                     self.last_pool_stats.update(counter.report(
                         self._decode_sums[name], cfg, self.params))
+        stats.update(self._kv_token_stats(s_total, sp, prompt_len, gen_len))
         if with_cache:
             return toks, logps, gen_len, cache[0]
         return toks, logps, gen_len

@@ -2209,6 +2209,47 @@ def _layer_of_cache(buf: jax.Array, li) -> jax.Array:
     return jax.lax.dynamic_index_in_dim(buf, li, axis=0, keepdims=False)
 
 
+@functools.lru_cache(maxsize=None)
+def kv_cache_alone(cfg: ModelConfig) -> bool:
+    """Whether the static cache of `cfg.plan` is k/v alone: of the fields
+    its kinds' records keep (`Branch.cache`), `k` and `v` and no other — no
+    state, ring, latent row or compressed key.  From the records, once a
+    config; nothing is traced."""
+    keep = set()
+    for branch in branches_of(cfg).values():
+        keep.update(branch.cache)
+    return keep == {"k", "v"}
+
+
+def kv_kernel_form(cfg: ModelConfig, row_kernel, s_max: int) -> bool:
+    """Whether the static program's softmax attention is the Pallas kernel
+    `kv_decode` (`ops/pallas/kv_decode.py`: the stacked cache read in
+    place, a row's live keys alone) in `decode_attention`'s place, by what
+    the code can see: the plan's cache is k/v alone (`kv_cache_alone`;
+    every other plan keeps the program it has) and `row_kernel` says so
+    (`flash_attention.row_kernel_form`) — None: a TPU backend and shapes
+    the kernel can cut (`kv_decode.fits`); a bool forces either form
+    (interpreted off a TPU); a MESH keeps the XLA form (the kernel is one
+    device's program; `shard_map` over rows and KV heads is not built)."""
+    if not kv_cache_alone(cfg):
+        return False
+    from areal_tpu.ops.pallas.flash_attention import row_kernel_form
+    from areal_tpu.ops.pallas.kv_decode import fits
+
+    use_kernel, mesh = row_kernel_form(row_kernel, fits(s_max, cfg.head_dim))
+    return use_kernel and mesh is None
+
+
+def _kv_attention(q, kc, vc, li, valid_from, valid_to):
+    """`decode_attention` of q [B, T, n_q, d] (a row's T tokens see ONE
+    window) over layer li of the STACKED caches [L, B, S, n_kv, d], on the
+    kernel `kv_decode`: no layer sliced out.  Imported here, in the branch
+    that takes it."""
+    from areal_tpu.ops.pallas.kv_decode import kv_decode
+
+    return kv_decode(q, kc, vc, li, valid_from, valid_to)
+
+
 def _attention_step(ctx: Ctx, h, blk, cache, li):
     """Softmax attention of one token per row through k/v layer li."""
     cfg, slot = ctx.cfg, ctx.slot
@@ -2216,10 +2257,13 @@ def _attention_step(ctx: Ctx, h, blk, cache, li):
     q, k, v = _block_kv(h, blk, cfg, ctx.cos, ctx.sin, full)  # [B,1,h,d]
     kc = _put_token(cache.k, k, li, slot)
     vc = _put_token(cache.v, v, li, slot)
-    k_layer, v_layer = _layer_of_cache(kc, li), _layer_of_cache(vc, li)
-    attn = decode_attention(
-        q, k_layer, v_layer, ctx.valid_from, slot + 1, scope=full
-    )
+    if kv_kernel_form(cfg, ctx.row_kernel, kc.shape[2]):
+        attn = _kv_attention(q, kc, vc, li, ctx.valid_from, slot + 1)
+    else:
+        attn = decode_attention(
+            q, _layer_of_cache(kc, li), _layer_of_cache(vc, li),
+            ctx.valid_from, slot + 1, scope=full,
+        )
     ao = _attn_out(
         attn.reshape(h.shape[0], 1, cfg.q_dim), blk, cfg,
         _attn_gate(h, blk, cfg), scope=full,
@@ -2363,14 +2407,16 @@ def decode_step(
     spreads the rows over devices passes False, the kernel is one
     device's program).
 
-    `row_kernel`: the form of the two per-row cache kernels, in which a
+    `row_kernel`: the form of the per-row cache kernels, in which a
     row reads and writes nothing of another's — latent attention's
     `latent_decode` over the stacked rows and the Gated DeltaNet step's
     `gdn_delta_step` over the stacked recurrent state.  None = the Pallas
     kernel on a TPU backend, the XLA form elsewhere; a caller whose mesh
     spreads the rows over devices passes the MESH and the kernel runs per
     device on its rows (`shard_map` over the batch axes); a bool forces
-    either form.
+    either form.  Where the plan's cache is k/v alone it also picks softmax
+    attention's `kv_decode` over the stacked k/v (`kv_kernel_form`: one
+    device; a mesh keeps `decode_attention`).
 
     What a kind does with its population of the cache is its `step`'s to
     say (`_window_step`: the ring; `_latent_step`: the absorbed form, no
@@ -3088,18 +3134,22 @@ def _attention_block_step(ctx: Ctx, h, blk, cache, li):
     q, k, v = _block_kv(h, blk, cfg, ctx.cos, ctx.sin)  # [B, Q, h, d]
     kc = _put_token(cache.k, k, li, slot)
     vc = _put_token(cache.v, v, li, slot)
-    # The block's Q queries see ONE window, so they are `decode_attention`'s
-    # one token a row with Q times the query heads a key head: [B, Q, g, r,
-    # d] -> [B, 1, g (Q r), d].  As an op of its own over [B, Q, ...] XLA
-    # copied the layer's k and v out of the stacked cache in front of every
-    # forward (2 x 67 MB a layer; my chip run, PR 68).
-    g, d = cfg.n_kv_heads, cfg.head_dim
-    q = q.reshape(b, n, g, -1, d).transpose(0, 2, 1, 3, 4)
-    attn = decode_attention(
-        q.reshape(b, 1, -1, d), _layer_of_cache(kc, li),
-        _layer_of_cache(vc, li), ctx.valid_from, slot + n,
-    )
-    attn = attn.reshape(b, g, n, -1, d).transpose(0, 2, 1, 3, 4)
+    if kv_kernel_form(cfg, ctx.row_kernel, kc.shape[2]):
+        # The block's Q queries see ONE window: the kernel's tokens a row.
+        attn = _kv_attention(q, kc, vc, li, ctx.valid_from, slot + n)
+    else:
+        # One window, so the Q queries are `decode_attention`'s one token a
+        # row with Q times the query heads a key head: [B, Q, g, r, d] ->
+        # [B, 1, g (Q r), d].  As an op of its own over [B, Q, ...] XLA
+        # copied the layer's k and v out of the stacked cache in front of
+        # every forward (2 x 67 MB a layer; my chip run, PR 68).
+        g, d = cfg.n_kv_heads, cfg.head_dim
+        q = q.reshape(b, n, g, -1, d).transpose(0, 2, 1, 3, 4)
+        attn = decode_attention(
+            q.reshape(b, 1, -1, d), _layer_of_cache(kc, li),
+            _layer_of_cache(vc, li), ctx.valid_from, slot + n,
+        )
+        attn = attn.reshape(b, g, n, -1, d).transpose(0, 2, 1, 3, 4)
     ao = _attn_out(
         attn.reshape(b, n, cfg.q_dim), blk, cfg, _attn_gate(h, blk, cfg))
     return ao, dataclasses.replace(cache, k=kc, v=vc), {}
@@ -3116,6 +3166,7 @@ def block_step(
     head: bool = True,
     experts_in_place: Optional[bool] = None,
     expert_kernel: Optional[bool] = None,
+    row_kernel=None,  # None | bool | Mesh: `kv_kernel_form`
 ) -> Tuple[jax.Array, ...]:
     """`decode_step` of a block of Q = `cfg.block_length` tokens a row:
     one forward of the block against the cache -> (fp32 logits [B, Q, V] IN
@@ -3124,7 +3175,8 @@ def block_step(
     [L, E]).  The block's k/v land in its own slots [slot, slot + Q): a
     denoising forward leaves there what its part-masked block gave, read
     by no later step — the COMMIT forward of the clean block overwrites it,
-    so what the cache keeps of a block is the commit's."""
+    so what the cache keeps of a block is the commit's.  `row_kernel`:
+    `decode_step`'s, for the block's attention (`kv_kernel_form`)."""
     x = _embed(params, cfg, tokens, positions)  # [B, Q, D]
     (cos, sin), _ = _rope(cfg, positions)
     slot = jnp.asarray(slot, jnp.int32)
@@ -3133,6 +3185,7 @@ def block_step(
         cfg, cos, sin, stacked=stacked, slot=slot, valid_from=valid_from,
         expert_kernel=stacked is not None and expert_kernel_choice(
             cfg, expert_kernel),
+        row_kernel=row_kernel,
     )
 
     def call(branch, h, blk, cache, li, at):

@@ -324,6 +324,8 @@ def phase_kernels(geom, on_tpu):
 
     _latent_cell_shape(attention, dt, tol, on_tpu)
 
+    _kv_cell_shape(attention, dt, tol, on_tpu)
+
     _ssm_cell_shape(dt, tol, on_tpu)
 
     _gdn_chunk_cell_shape(on_tpu)
@@ -465,6 +467,55 @@ def _ssm_cell_shape(dt, tol, on_tpu, reps=10):
         f"{ms:.3f} ms a sweep where a v5e's 819 GB/s allow {floor_ms:.3f} "
         f"(host clock, {reps} sweeps over a state made in the program"
         + ("" if on_tpu else "; on the cpu, no device time") + ")")
+
+
+def _kv_cell_shape(attention, dt, tol, on_tpu):
+    """The kernel `kv_decode` against `decode_attention` on a STACKED k/v
+    cache at `q1p5b-decode-static`'s shape (8 rows, 1,280 slots of 2 key
+    heads under 12 query heads, 28 layers; 2 layers of 256 slots in
+    rehearsal) and at `olmoe-decode-tail`'s 16 ungrouped heads: every
+    layer, windows that start late and end early, an empty window's exact
+    zeros.  Its time is `scripts/kv_decode_bench.py`'s to read."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from areal_tpu.ops.pallas.kv_decode import kv_decode
+
+    b, d = 8, 128
+    rng = np.random.default_rng(11)
+    for g, rep, n_layers, s_max in (
+            ((2, 6, 28, 1280), (16, 1, 3, 1280)) if on_tpu
+            else ((2, 6, 2, 256), (16, 1, 2, 256))):
+        k, v = (jnp.asarray(
+            rng.standard_normal((n_layers, b, s_max, g, d)), dt)
+            for _ in range(2))
+        q = jnp.asarray(rng.standard_normal((b, 1, g * rep, d)), dt)
+        lo = rng.integers(0, 160, size=b)
+        hi = rng.integers(200, s_max + 1, size=b)
+        lo[0], hi[0], lo[1], hi[1] = 0, s_max, 9, 9  # whole window; empty
+        lo, hi = jnp.asarray(lo, jnp.int32), jnp.asarray(hi, jnp.int32)
+
+        def sweep(q, k, v, kernel):
+            def layer(li, acc):
+                if kernel:
+                    out = kv_decode(q, k, v, li, lo, hi)
+                else:
+                    out = attention.decode_attention(
+                        q, jax.lax.dynamic_index_in_dim(k, li, 0, False),
+                        jax.lax.dynamic_index_in_dim(v, li, 0, False), lo, hi)
+                return acc + out.astype(jnp.float32)
+            return jax.lax.fori_loop(
+                0, n_layers, layer, jnp.zeros(q.shape, jnp.float32))
+
+        sweep = jax.jit(sweep, static_argnums=3)
+        got, want = sweep(q, k, v, True), sweep(q, k, v, False)
+        err = _max_err(want, got)
+        check(err <= tol * n_layers,
+              f"kv decode kernel ({n_layers} layers x {g} key heads x {rep} "
+              f"of a stacked cache summed) == XLA form (max err {err:.2e})")
+        check(float(jnp.max(jnp.abs(got[1]))) == 0.0,
+              "kv decode kernel: an empty window's exact zeros")
 
 
 def _latent_cell_shape(attention, dt, tol, on_tpu, reps=10):

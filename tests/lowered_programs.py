@@ -25,6 +25,15 @@ from benchmark import run as bench_run
 # One dense configuration and one expert-parallel rank's share (windows
 # beside full layers: the kernels' band too), at their files' toy sizes.
 CONFIGS = ("qwen2.5-math-1.5b", "mellum2-12b-a2.5b-l4-e16")
+# One configuration a kind of static cache that holds more than k/v, by the
+# `KVCache` field that makes it so (`wk`: the share configuration above):
+# their `prefill` and `decode` are held too, since PR 70 (`kv_decode` is the
+# attention of the plans whose cache is k/v alone, and of no other).
+CACHE_KINDS = {
+    "state": "nemotron-3-nano-30b-a3b-l9-e16",
+    "latent": "glm-4.7-flash-l7-e8",
+    "ck": "minicpm-sala-l4-v8",
+}
 ROWS, LENGTH, S_MAX = 2, 256, 384
 
 
@@ -42,8 +51,9 @@ def _sha(text: str) -> str:
     return hashlib.sha256(text.encode()).hexdigest()
 
 
-def programs(cfg):
-    """{program: its lowered text's sha256} for `cfg`."""
+def programs(cfg, which=("grad", "prefill", "decode")):
+    """{program: its lowered text's sha256} for `cfg`, of those `which`
+    names."""
     params = jax.eval_shape(lambda: tfm.init_params(cfg, jax.random.PRNGKey(0)))
     ints = jax.ShapeDtypeStruct((ROWS, LENGTH), jnp.int32)
     new = jax.ShapeDtypeStruct((ROWS,), jnp.int32)
@@ -64,15 +74,19 @@ def programs(cfg):
             jnp.zeros((ROWS,), jnp.int32), with_counts=True)
 
     cache = jax.eval_shape(lambda: tfm.init_kv_cache(cfg, ROWS, S_MAX))
-    return {
-        "grad": _sha(jax.jit(jax.grad(loss)).lower(
-            params, ints, ints).as_text()),
-        "prefill": _sha(jax.jit(prefill).lower(params, ints, ints).as_text()),
-        "decode": _sha(jax.jit(decode).lower(params, new, cache).as_text()),
+    texts = {
+        "grad": lambda: jax.jit(jax.grad(loss)).lower(params, ints, ints),
+        "prefill": lambda: jax.jit(prefill).lower(params, ints, ints),
+        "decode": lambda: jax.jit(decode).lower(params, new, cache),
     }
+    return {name: _sha(texts[name]().as_text()) for name in which}
 
 
 if __name__ == "__main__":
     for name in CONFIGS:
         for program, sha in programs(toy_config(name)).items():
+            print(f'    ("{name}", "{program}"): "{sha}",')
+    for name in CACHE_KINDS.values():
+        for program, sha in programs(
+                toy_config(name), ("prefill", "decode")).items():
             print(f'    ("{name}", "{program}"): "{sha}",')

@@ -988,6 +988,80 @@ class TestTPULowering:
 
         return jax.jit(step, donate_argnums=(0,)), args
 
+    # The static cells whose cache is k/v alone: layers, rows, slots, key
+    # heads, query heads a key head, tokens a row and forward.
+    KV_SHAPES = {
+        "q1p5b-decode-static": (28, 8, 1280, 2, 6, 1),
+        "olmoe-decode-tail": (3, 8, 1280, 16, 1, 1),
+        "sdar-rollout64-512": (8, 64, 896, 4, 8, 4),
+        "q1p5b-train-longprompt": (28, 16, 2816, 2, 6, 1),
+    }
+
+    @pytest.mark.parametrize("cell", list(KV_SHAPES))
+    def test_kv_decode_loop_compiles_for_v5e(
+        self, cell, one_chip, _no_persistent_cache
+    ):
+        """Mosaic takes `kv_decode` at the cells' geometries, and in a
+        decode loop — every layer writes its token into the stacked cache
+        and attends — XLA:TPU hands it the cache AS IT LIES: the view
+        [L, B, S * n_kv, d] is a bitcast, no layer is sliced out and
+        nothing of a cache's or a layer's size is copied or re-laid (as
+        XLA ops: a copy of the layer's K and V a call, PERF.md section 7,
+        left by PR 68)."""
+        from areal_tpu.ops.pallas.kv_decode import kv_decode
+
+        layers, b, s, g, rep, tok = self.KV_SHAPES[cell]
+        d, sp = self.D, 256
+
+        def loop(q, k_new, v_new, valid_from):
+            kc = jnp.zeros((layers, b, s, g, d), q.dtype)
+            vc = jnp.zeros((layers, b, s, g, d), q.dtype)
+
+            def step(state):
+                i, kc, vc, acc = state
+                slot = sp + i * tok
+
+                def layer(c, li):
+                    kc, vc, acc = c
+                    kc = jax.lax.dynamic_update_slice(
+                        kc, k_new[None], (li, 0, slot, 0, 0))
+                    vc = jax.lax.dynamic_update_slice(
+                        vc, v_new[None], (li, 0, slot, 0, 0))
+                    out = kv_decode(
+                        q + acc, kc, vc, li, valid_from, slot + tok)
+                    return (kc, vc, out), None
+
+                kc, vc, acc = jax.lax.scan(
+                    layer, (kc, vc, acc), jnp.arange(layers))[0]
+                return i + 1, kc, vc, acc
+
+            return jax.lax.while_loop(
+                lambda st: st[0] < (s - sp) // tok, step,
+                (0, kc, vc, jnp.zeros_like(q)))[3]
+
+        def placed(shape, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        compiled = jax.jit(loop).lower(
+            placed((b, tok, g * rep, d)), placed((b, tok, g, d)),
+            placed((b, tok, g, d)), placed((b,), jnp.int32)).compile()
+        text = compiled.as_text()
+        assert "%kv_decode" in text
+        sizes = (
+            f"[{layers},{b},{s},{g},{d}]", f"[1,{b},{s},{g},{d}]",
+            f"[{b},{s},{g},{d}]", f"[{layers},{b},{s * g},{d}]",
+            f"[{b},{s * g},{d}]")
+        moved = [
+            line.strip()[:200] for line in text.splitlines()
+            if any(x in line.split(" = ")[-1].split("(")[0] for x in sizes)
+            and any(op in line for op in (
+                " copy(", " transpose(", " reshape(", " dynamic-slice("))
+        ]
+        assert not moved, moved[:2]
+        # Two caches and nothing of their size beside them.
+        cache_bytes = layers * b * s * g * d * 2
+        assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * cache_bytes
+
     @pytest.mark.parametrize("cell", list(PAGED_SHAPES))
     def test_paged_attention_kernel(self, cell):
         fn, args = self._paged_step(self.PAGED_SHAPES[cell])

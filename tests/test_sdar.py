@@ -837,6 +837,34 @@ def test_an_autoregressive_model_lowers_to_the_text_it_lowered_to(name):
         if n == name}
 
 
+# `python3 -m tests.lowered_programs` at cc9a84d, the commit before the
+# kernel `kv_decode` came in (PR 70).
+_CACHE_KINDS_AT_THE_PARENT = {
+    ("nemotron-3-nano-30b-a3b-l9-e16", "prefill"): "a70664c3bb3e6083d7144a1638a41b656fc283abcddf0a7e6aaef88f836227e1",
+    ("nemotron-3-nano-30b-a3b-l9-e16", "decode"): "7c89f3038efcc4f947cc6215e208971fa982f72a5b817c8e815b363bdafeb9f9",
+    ("glm-4.7-flash-l7-e8", "prefill"): "7f94aa7399082a343845a43c11d1b283af095ee5e172c72e065fdfd71a35e349",
+    ("glm-4.7-flash-l7-e8", "decode"): "c524b5c5f2f2a4fdede5e0bfb92b51552c84a3b319bfc7277582a11fdf2d0887",
+    ("minicpm-sala-l4-v8", "prefill"): "20274695b4754171f32c6092406a5d50db3f38c424d38ca228457dbe369ac68f",
+    ("minicpm-sala-l4-v8", "decode"): "5be3cb517c131f00f2b68dc6c1e4f24255e2d519e433dd06b628b8d0bcdb6088",
+}
+
+
+@pytest.mark.parametrize("name,program", sorted(_CACHE_KINDS_AT_THE_PARENT))
+def test_a_cache_that_holds_more_than_kv_lowers_to_the_text_it_lowered_to(
+        name, program):
+    """One toy configuration a kind of static cache — a recurrent `state`,
+    a `latent` row, compressed keys `ck` beside a state; the rings `wk` are
+    the share configuration above — keeps its `prefill` and its `decode`
+    step at the text they had before the kernel `kv_decode`: it is the
+    attention of the plans whose cache is k/v alone and of no other.  (Off
+    a TPU the kernel is nobody's choice, so the dense and the share toy
+    above keep all three.)"""
+    assert name in lowered_programs.CACHE_KINDS.values()
+    got = lowered_programs.programs(
+        lowered_programs.toy_config(name), (program,))
+    assert got[program] == _CACHE_KINDS_AT_THE_PARENT[(name, program)]
+
+
 # `sdar-rollout64-512`, a process of its own: to the end of its window
 # (`benchmark/tests/fixed_work_cases.py`).  Why it is collected here:
 # `tests/benchmark_windows.py`.
