@@ -14,6 +14,13 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     flags += " --xla_force_host_platform_device_count=8"
+# The toy programs are compiled to be run once or twice, and no number is
+# ever claimed on the CPU: LLVM at -O1, not XLA's default -O2 (side by side
+# on four cores each from empty compile caches, PR 71: 7-11% fewer CPU
+# seconds for `test_delta_chunk_kernel.py` and `test_granite_hybrid.py`).
+# The benchmark's rehearsals drop XLA_FLAGS.
+if "xla_backend_optimization_level" not in flags:
+    flags += " --xla_backend_optimization_level=1"
 os.environ["XLA_FLAGS"] = flags.strip()
 
 # Persistent XLA compilation cache: the suite compiles hundreds of tiny
@@ -24,11 +31,13 @@ from areal_tpu.base import compilation_cache
 
 compilation_cache.enable()
 
+import collections
 import contextlib
 import faulthandler
 import signal
 import sys
 import tempfile
+import textwrap
 import threading
 import time
 
@@ -78,12 +87,94 @@ def pytest_configure(config):
         "slow: multi-second end-to-end trials, excluded from the tier-1 "
         "`-m 'not slow'` run (scripts/check_async.py covers the async e2e)",
     )
+    controller = (config.getoption("dist", "no") != "no"
+                  and not hasattr(config, "workerinput"))
+    if controller and not config.getoption("collectonly"):
+        table = RunTable()
+        config.pluginmanager.register(table, "run_table")
+        signal.signal(signal.SIGTERM, table.on_sigterm)
 
 
 @pytest.fixture(autouse=True)
 def _case_ceiling():
     with case_ceiling(CASE_CEILING_S):
         yield
+
+
+class RunTable:
+    """Where a run's time went and where it stood, from the reports alone,
+    on the xdist controller: every file's seconds and cases, the twenty
+    longest cases and THE FILES NOT YET FINISHED — on stderr at the end of
+    a run and, for a run that `timeout` cuts, from the SIGTERM handler,
+    which then dies of the signal as the process did without it.  A cut
+    run writes neither its junit file nor its summary: before PR 71 it left
+    a row of dots, and what was still out had to be guessed."""
+
+    LINES, WIDTH = 40, 200
+
+    def __init__(self):
+        self.collected = collections.Counter()  # file -> cases collected
+        self.seconds = collections.Counter()  # file -> seconds so far
+        self.cases = collections.Counter()  # case -> set-up + call + teardown
+        self.done = collections.Counter()  # file -> cases whose teardown is in
+        self.t0 = time.monotonic()
+
+    @staticmethod
+    def _file(nodeid):
+        return nodeid.split("::", 1)[0]
+
+    def pytest_xdist_node_collection_finished(self, node, ids):
+        if not self.collected:  # every worker collects the same cases
+            self.collected.update(map(self._file, ids))
+
+    def pytest_runtest_logreport(self, report):
+        f = self._file(report.nodeid)
+        self.seconds[f] += report.duration
+        self.cases[report.nodeid] += report.duration
+        if report.when == "teardown":
+            self.done[f] += 1
+
+    def lines(self):
+        def short(name):
+            return name.removeprefix("tests/").replace(".py", "", 1)
+
+        def packed(title, words, room):
+            text = textwrap.wrap(
+                "  ".join(words) or "none", self.WIDTH,
+                break_long_words=False, break_on_hyphens=False)
+            if len(text) > room:
+                text[room - 1:] = [f"... and {len(text) - room + 1} more lines"]
+            return [title] + ["  " + t for t in text]
+
+        out = [
+            f"[conftest] run table after {time.monotonic() - self.t0:.0f} s: "
+            f"{sum(self.done.values())} of {sum(self.collected.values())} "
+            f"cases in, {sum(self.seconds.values()):.0f} case-seconds"]
+        open_files = [
+            f"{short(f)} {self.done[f]}/{n}"
+            for f, n in self.collected.items() if self.done[f] < n]
+        out += packed("files not yet finished (cases in / collected):",
+                      open_files, 8)
+        out += packed(
+            "the twenty longest cases (s):",
+            [f"{short(c)} {s:.0f}" for c, s in self.cases.most_common(20)], 12)
+        out += packed(
+            "every file's seconds/cases:",
+            [f"{short(f)} {s:.0f}/{self.done[f]}"
+             for f, s in self.seconds.most_common()],
+            self.LINES - len(out) - 1)
+        return out
+
+    def say(self):
+        print("\n" + "\n".join(self.lines()), file=sys.stderr, flush=True)
+
+    def pytest_terminal_summary(self):
+        self.say()
+
+    def on_sigterm(self, signum, frame):
+        self.say()
+        signal.signal(signal.SIGTERM, signal.SIG_DFL)
+        os.kill(os.getpid(), signal.SIGTERM)
 
 
 def _children_of(pid):
@@ -163,6 +254,23 @@ def _fresh_name_resolve():
     name_resolve.set_default(name_resolve.MemoryNameResolveRepository())
     yield
     name_resolve.reset()
+
+
+@pytest.fixture(scope="session")
+def v5e_chips():
+    """The devices of a described v5e host to compile for (libtpu is
+    installed here; no chip is attached).  Built inside the fixture, never
+    at import: only a worker that runs such a case loads the TPU's
+    library."""
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever libtpu raises
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return topo.devices
 
 
 @pytest.fixture

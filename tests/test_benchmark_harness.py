@@ -10,6 +10,7 @@ rehearsal, to the end of the window, is collected where
 `tests/benchmark_windows.py` says (PR 62: a file is one worker's, and the
 ten of them made this one 713 s longer)."""
 
+import functools
 import importlib
 import json
 import os
@@ -38,7 +39,8 @@ from benchmark.tests.test_sala import *  # noqa: F401,F403 — the cases (PR 55)
 from benchmark.tests import test_gdnd as gdnd_cases
 from benchmark.tests.test_gdnd import *  # noqa: F401,F403 — the cases (PR 59)
 from benchmark.tests import fixed_work_cases
-from tests.benchmark_windows import HOMES, cells_of, window_case
+from tests import benchmark_windows
+from tests.benchmark_windows import cells_of, window_case
 
 # The ten window cases came in with the star import above and made this
 # file 713 s longer: each is collected where `tests/benchmark_windows.py`
@@ -195,14 +197,55 @@ SHARE_CELL_LISTS = {
 
 def test_every_cells_window_case_has_one_home_and_every_home_collects_its_cells():
     """No cell's window case runs twice and none is left out, whichever
-    cell a later PR adds to BENCHMARK.json."""
-    assert sorted(HOMES) == sorted(
+    cell a later PR adds to BENCHMARK.json; and the same of the case that
+    holds a cell the count closes to `correct`."""
+    homes = benchmark_windows.CELLS
+    assert sorted(homes) == sorted(
         fixed_work_cases.DENSE[:1] + fixed_work_cases.FIXED)
-    for home in set(HOMES.values()):
-        case = importlib.import_module(
-            home).test_the_window_closes_on_the_cells_count_or_on_the_clock
-        (over,) = [m for m in case.pytestmark if m.name == "parametrize"]
-        assert over.args == ("cell", cells_of(home)), home
+    assert sorted(c for c, row in homes.items() if row.requests) == sorted(
+        fixed_work_cases.FIXED)
+    for home in {row.home for row in homes.values()}:
+        module = importlib.import_module(home)
+        cases = {
+            "test_the_window_closes_on_the_cells_count_or_on_the_clock":
+                cells_of(home),
+            "test_cpu_rehearsal_of_the_cell_is_correct":
+                cells_of(home, correct=True),
+        }
+        for name, cells in cases.items():
+            if not cells:  # the dense cell's home binds no `correct` case
+                assert not hasattr(module, name), home
+                continue
+            (over,) = [m for m in getattr(module, name).pytestmark
+                       if m.name == "parametrize"]
+            assert over.args == ("cell", cells), (home, name)
+
+
+def test_a_cells_two_cases_read_one_process(monkeypatch):
+    """`rehearsal(cell)` starts one process for the window case and the
+    `correct` case of a cell, whichever asks first and however often; the
+    dense cell's window, which the clock closes, goes through to its own."""
+    started = []
+
+    def rehearse(cwd, cell, trace=0, seconds=1):
+        started.append((cell, seconds))
+        return subprocess.CompletedProcess([], 0, "", (
+            "trial_seed=26 traffic_seed=26 timed_steps=4\n"
+            "timed steps, walls [1.0, 1.0, 1.0, 1.0]\n" if seconds == 600 else
+            "trial_seed=3 traffic_seed=3 timed_steps=None\n"
+            "timed steps, walls [0.6, 0.6]\n"))
+
+    monkeypatch.setattr(benchmark_windows, "rehearse", rehearse)
+    monkeypatch.setattr(benchmark_windows, "rehearsal", functools.lru_cache(
+        benchmark_windows.rehearsal.__wrapped__))
+    window = window_case("tests.test_olmoe")
+    window("olmoe-decode-tail")
+    assert benchmark_windows.rehearsal("olmoe-decode-tail").returncode == 0
+    window("olmoe-decode-tail")
+    assert benchmark_windows.rehearsal.cache_info().misses == 1
+    assert started == [("olmoe-decode-tail", 600)]
+    window_case("tests.test_benchmark_harness")("q1p5b-decode-static")
+    assert started[1:] == [("q1p5b-decode-static", 1)]
 
 
 def _at(entries, name):
@@ -899,118 +942,8 @@ def test_the_moe_readers_say_nothing_for_a_dense_model_or_no_trace():
             assert reader.read(run) is None, reader.__name__
 
 
-def test_cpu_rehearsal_of_the_olmoe_cell_is_correct():
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=files.ROOT)
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmark.run", "--workload",
-         "olmoe-decode-tail", "--seed", "2200000011", "--seconds", "1",
-         "--trace", "0", "--cpu-rehearsal"],
-        cwd=files.ROOT, env=env, capture_output=True, text=True, timeout=600,
-    )
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    assert proc.stdout.strip() == ""  # platform=cpu: no result line
-    lines = proc.stderr.splitlines()
-    out = json.loads(
-        [l for l in lines if "would print: " in l][-1].split("would print: ")[1])
-    assert out["correct"] is True and out["failed"] == 0 < out["attempted"]
-    assert {"gen_tokens_per_s", "train_tokens_per_s", "samples_per_s",
-            "setup_s"} <= set(out["metrics"])
-    check = [l for l in lines if "weight check: " in l][-1]
-    assert "'ok': True" in check and "'leaves': 15" in check, check
-    assert any("olmoe reference" in l and "router_flips" in l for l in lines)
 
 
-def test_cpu_rehearsal_of_the_olmo_hybrid_cell_is_correct():
-    """The dense hybrid cell end to end at toy size (the config's `toy`
-    group keeps d_v = 2 d_k: heads of 12 x 24): the static program through
-    both populations of the cache, the chunked rule with beta in (0, 2) and
-    the norms on the branch outputs in the train step, the hand-back of all
-    22 leaves, the token-by-token reference with its state check for
-    generator and trainer."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=files.ROOT)
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmark.run", "--workload", OLMOH_CELL,
-         "--seed", "3000000059", "--seconds", "1", "--trace", "0",
-         "--cpu-rehearsal"],
-        cwd=files.ROOT, env=env, capture_output=True, text=True, timeout=600,
-    )
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    assert proc.stdout.strip() == ""  # platform=cpu: no result line
-    lines = proc.stderr.splitlines()
-    out = json.loads(
-        [l for l in lines if "would print: " in l][-1].split("would print: ")[1])
-    assert out["correct"] is True and out["failed"] == 0
-    assert out["attempted"] in (2 * 64, 3 * 64, 4 * 64)  # whole steps of 64
-    assert {"gen_tokens_per_s", "train_tokens_per_s", "samples_per_s",
-            "setup_s"} <= set(out["metrics"])
-    check = [l for l in lines if "weight check: " in l][-1]
-    assert "'ok': True" in check and "'leaves': 22" in check, check
-    assert any("olmo_hybrid reference" in l and "largest beta" in l
-               for l in lines)
-    assert any("olmo_hybrid state check" in l and l.endswith(" ok")
-               for l in lines)
-
-
-def test_cpu_rehearsal_of_the_sdar_cell_is_correct():
-    """The block-diffusion cell end to end at toy size: the static
-    program's loop over blocks (prompts of every tail), the two-stream
-    train step in rows of stream slots, the hand-back of all 15 leaves, the
-    block-by-block reference for generator and trainer with its check of
-    the generator's own program (the rows the commits left)."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=files.ROOT)
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmark.run", "--workload", SDAR_CELL,
-         "--seed", "3000000068", "--seconds", "1", "--trace", "0",
-         "--cpu-rehearsal"],
-        cwd=files.ROOT, env=env, capture_output=True, text=True, timeout=600,
-    )
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    assert proc.stdout.strip() == ""  # platform=cpu: no result line
-    lines = proc.stderr.splitlines()
-    out = json.loads(
-        [l for l in lines if "would print: " in l][-1].split("would print: ")[1])
-    assert out["correct"] is True and out["failed"] == 0
-    assert out["attempted"] in (2 * 64, 3 * 64, 4 * 64)  # whole steps of 64
-    assert {"gen_tokens_per_s", "train_tokens_per_s", "samples_per_s",
-            "setup_s"} <= set(out["metrics"])
-    check = [l for l in lines if "weight check: " in l][-1]
-    assert "'ok': True" in check and "'leaves': 15" in check, check
-    assert any("sdar_moe reference" in l and "blocks of 4" in l
-               and "[0, 4) of 8" in l for l in lines)
-    assert any("sdar_moe generator check" in l and l.endswith(" ok")
-               for l in lines)
-    assert any("programs ['blocks']" in l for l in lines)
-
-
-def test_cpu_rehearsal_of_the_qwen3_next_cell_is_correct():
-    """The hybrid cell end to end at toy size: the static program through
-    both kinds of cache, the chunked scan in the train step, the hand-back
-    of all 28 leaves, the token-by-token reference for generator and
-    trainer."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=files.ROOT)
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmark.run", "--workload",
-         "q3next-rollout64-512", "--seed", "3000000007", "--seconds", "1",
-         "--trace", "0", "--cpu-rehearsal"],
-        cwd=files.ROOT, env=env, capture_output=True, text=True, timeout=600,
-    )
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    assert proc.stdout.strip() == ""  # platform=cpu: no result line
-    lines = proc.stderr.splitlines()
-    out = json.loads(
-        [l for l in lines if "would print: " in l][-1].split("would print: ")[1])
-    assert out["correct"] is True and out["failed"] == 0
-    assert out["attempted"] in (2 * 64, 3 * 64, 4 * 64)  # whole steps of 64
-    assert {"gen_tokens_per_s", "train_tokens_per_s", "samples_per_s",
-            "setup_s"} <= set(out["metrics"])
-    check = [l for l in lines if "weight check: " in l][-1]
-    assert "'ok': True" in check and "'leaves': 28" in check, check
-    assert any("qwen3_next reference" in l and "[0, 4) of 8" in l
-               for l in lines)
 
 
 def test_the_hybrid_readers_say_nothing_without_their_scopes_or_counters():

@@ -14,25 +14,6 @@ from areal_tpu.models import mamba
 from areal_tpu.models import transformer as tfm
 
 
-@pytest.fixture(scope="module")
-def v5e_chips():
-    """The devices of a described v5e host to compile for (libtpu is
-    installed here; no chip is attached).  Built inside the fixture, never
-    at import: only the worker that runs this file may load the TPU's
-    library."""
-    import os
-
-    from jax.experimental import topologies
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    try:
-        topo = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 - whatever libtpu raises
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return topo.devices
-
-
 CELLS = {  # cell -> (configuration, the kind's module, leaves, forward, scope)
     "olmoh": ("olmo-hybrid-7b-l4-v8.json", la, "LINEAR_LEAVES",
               "linear_attn_forward", "layer/linear_attn/conv"),

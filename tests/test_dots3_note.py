@@ -10,8 +10,6 @@ through the caches) against the plain reference
 import dataclasses
 import json
 import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -644,41 +642,69 @@ def test_generate_reports_the_three_caches_and_what_a_step_read(cfg, params):
     np.testing.assert_allclose(logps[2, :6], want[-6:], rtol=2e-3, atol=2e-4)
 
 
-# `dots3n-docrl8-longctx`, a process of its own each time: to the end of its
-# window (`benchmark/tests/fixed_work_cases.py`), and for a second, held to
-# `correct`.  Why both are collected here: `tests/benchmark_windows.py`.
-from tests.benchmark_windows import window_case  # noqa: E402
+# ----------------------------------- the gradient program compiled for v5e
+# (Mosaic and XLA:TPU for real with no chip attached, about a minute.  It
+# had a file of its own from PR 64 to PR 71: a file of ONE long case is
+# handed out last and was the tail of every run, 55 s of a cold one.)
+
+ROW = 13_312  # the cell's longest row: one sequence
+# `memory_analysis().temp_size_in_bytes` as this compile read it (PR 64:
+# 4,437,567,488, with the selection a [13312, 13312] mask held whole and
+# with it made and used a block at a time alike: the program's peak is not
+# in the attention).  Beside 11.13 GB of train state the chip's 15.75 GB
+# leave 4.6.
+_GRAD_TEMP_BYTES = 4_437_567_488
+
+
+def test_the_gradient_program_compiles_for_v5e_beside_its_state(
+        v5e_chips, monkeypatch):
+    """The one compile that sizes the cell: the gradient of the stack over
+    one packed row of 13,312 tokens at every published width, `remat="full"`
+    as the train engine has it.  Its temporaries are pinned (a hundredth of
+    room): `peak_hbm_gb` reads 15.4 of 15.75 on the chip, so what grows
+    them has to show here, before a chip call.  No value of the program
+    holds the row's length on two axes as a choice (`pred`) or a score
+    (`f32`): the selection is made and used a block of queries at a time,
+    and kept for the backward pass as words of 32 queries."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    big = bench_run.model_config(files.load_json("configs", CONFIG))
+    chip = SingleDeviceSharding(v5e_chips[0])
+    shapes = jax.eval_shape(
+        lambda: tfm.init_params(big, jax.random.PRNGKey(0)))
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, jnp.bfloat16, sharding=chip),
+        shapes)
+    row = jax.ShapeDtypeStruct((1, ROW), jnp.int32, sharding=chip)
+
+    def loss(p, tokens, seg):
+        x, aux = tfm.hidden_states(p, big, tokens, seg, remat="full")
+        return jnp.sum(x.astype(jnp.float32)) + aux
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(jax.grad(loss)).trace(
+            params, row, row).lower().compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= _GRAD_TEMP_BYTES * 1.01, temp
+    text = compiled.as_text()
+    assert f"u32[1,4,{ROW}]" in text  # a block's selection, packed
+    for square in (f"pred[1,{ROW},{ROW}]", f"f32[1,{ROW},{ROW}]",
+                   f"pred[{ROW // 128},1,128,{ROW}]"):  # whole, or stacked
+        assert square not in text, square
+
+
+# `dots3n-docrl8-longctx` rehearsed on the CPU, one process for both cases: to the
+# end of its window (`benchmark/tests/fixed_work_cases.py`) and held to
+# `correct`.  Why they are collected here: `tests/benchmark_windows.py`.
+from tests.benchmark_windows import correct_case, window_case  # noqa: E402
 
 test_the_window_closes_on_the_cells_count_or_on_the_clock = window_case(
     __name__)
-
-
-def test_cpu_rehearsal_of_the_dots3_cell_is_correct():
-    """The cell end to end at toy size: the static program through latent
-    rows, index keys and rings, the leading dense layer outside the scan,
-    the hand-back with the indexer's leaves and the router's bias
-    unchanged, the reference and its check of the generator's own 8-slot
-    program."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=files.ROOT)
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmark.run", "--workload",
-         "dots3n-docrl8-longctx", "--seed", "3000000064", "--seconds", "1",
-         "--trace", "0", "--cpu-rehearsal"],
-        cwd=files.ROOT, env=env, capture_output=True, text=True, timeout=600,
-    )
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    assert proc.stdout.strip() == ""  # platform=cpu: no result line
-    lines = proc.stderr.splitlines()
-    out = json.loads(
-        [l for l in lines if "would print: " in l][-1].split("would print: ")[1])
-    assert out["correct"] is True and out["failed"] == 0
-    assert out["attempted"] in (2 * 8, 3 * 8)  # whole steps of 8
-    assert {"train_tokens_per_s", "samples_per_s", "setup_s"} <= set(
-        out["metrics"])
-    check = [l for l in lines if "weight check: " in l][-1]
-    assert "'ok': True" in check and "'leaves': 55" in check, check
-    assert any("dots3_note reference" in l and "heads 2 / 2 of 4 / 4" in l
-               and "[0, 4) of 8" in l for l in lines)
-    assert any("dots3_note generator check" in l and l.endswith(" ok")
-               for l in lines)
+test_cpu_rehearsal_of_the_cell_is_correct = correct_case(__name__)

@@ -859,23 +859,11 @@ class TestTPULowering:
             assert all(k == jnp.float32 for k in kinds[-n_scratch:])
 
     @pytest.fixture(scope="class")
-    def one_chip(self):
-        """A described v5e chip to compile for (libtpu is installed here;
-        no chip is attached).  Built inside the fixture, never at import:
-        only the worker that runs this file may load the TPU's library."""
-        import os
-
-        from jax.experimental import topologies
+    def one_chip(self, v5e_chips):
+        """A described v5e chip to compile for."""
         from jax.sharding import SingleDeviceSharding
 
-        os.environ.setdefault("TPU_LOG_DIR", "disabled")
-        try:
-            topo = topologies.get_topology_desc(
-                platform="tpu", topology_name="v5e:2x2"
-            )
-        except Exception as e:  # noqa: BLE001 - whatever libtpu raises
-            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-        return SingleDeviceSharding(topo.devices[0])
+        return SingleDeviceSharding(v5e_chips[0])
 
     @pytest.fixture
     def _no_persistent_cache(self):

@@ -21,11 +21,7 @@ max on log-probs) must FAIL the router or the cache a precision lower.
 
 import dataclasses
 import hashlib
-import json
-import os
 import re
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -878,25 +874,6 @@ def test_every_other_family_lowers_to_the_parents_program(name, program):
 # ------------------------------------------- the decode loop compiled for v5e
 
 
-@pytest.fixture(scope="module")
-def v5e_chips():
-    """The devices of a described v5e host to compile for (libtpu is
-    installed here; no chip is attached).  Built inside the fixture, never
-    at import: only the worker that runs this file may load the TPU's
-    library."""
-    import os
-
-    from jax.experimental import topologies
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    try:
-        topo = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 - whatever libtpu raises
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return topo.devices
-
-
 @pytest.mark.parametrize("mode", ["d1", "d2", "f2"])
 def test_the_decode_loop_compiles_for_v5e_without_a_copy_of_the_cache(
         v5e_chips, monkeypatch, mode):
@@ -966,43 +943,11 @@ def test_the_decode_loop_compiles_for_v5e_without_a_copy_of_the_cache(
 
 # --------------------------------- the cell, rehearsed on the CPU at toy size
 
-# `glm47f-rollout64-1k`, a process of its own each time: to the end of its window
-# (`benchmark/tests/fixed_work_cases.py`), and for a second, held to `correct`
-# (from `tests/test_benchmark_harness.py`, PR 62).  Why both are collected in
-# this file: `tests/benchmark_windows.py`.
-from tests.benchmark_windows import window_case  # noqa: E402
+# `glm47f-rollout64-1k` rehearsed on the CPU, one process for both cases: to the
+# end of its window (`benchmark/tests/fixed_work_cases.py`) and held to
+# `correct`.  Why they are collected here: `tests/benchmark_windows.py`.
+from tests.benchmark_windows import correct_case, window_case  # noqa: E402
 
 test_the_window_closes_on_the_cells_count_or_on_the_clock = window_case(
     __name__)
-
-
-def test_cpu_rehearsal_of_the_glm_cell_is_correct():
-    """The latent-attention cell end to end at toy size (the config's `toy`
-    group shrinks the five MLA sizes, the experts and the share): the
-    static program through the latent cache, the leading dense layer
-    outside the scan, the hand-back of all 34 leaves with the router's
-    bias unchanged, the reference and its check of the generator's own
-    64-slot program for generator and trainer."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=files.ROOT)
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmark.run", "--workload", "glm47f-rollout64-1k",
-         "--seed", "3000000011", "--seconds", "1", "--trace", "0",
-         "--cpu-rehearsal"],
-        cwd=files.ROOT, env=env, capture_output=True, text=True, timeout=600,
-    )
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    assert proc.stdout.strip() == ""  # platform=cpu: no result line
-    lines = proc.stderr.splitlines()
-    out = json.loads(
-        [l for l in lines if "would print: " in l][-1].split("would print: ")[1])
-    assert out["correct"] is True and out["failed"] == 0
-    assert out["attempted"] in (2 * 64, 3 * 64)  # whole steps of 64
-    assert {"gen_tokens_per_s", "train_tokens_per_s", "samples_per_s",
-            "setup_s"} <= set(out["metrics"])
-    check = [l for l in lines if "weight check: " in l][-1]
-    assert "'ok': True" in check and "'leaves': 34" in check, check
-    assert any("glm4_moe_lite reference" in l and "[0, 4) of 8" in l
-               for l in lines)
-    assert any("glm4_moe_lite generator check" in l and l.endswith(" ok")
-               for l in lines)
+test_cpu_rehearsal_of_the_cell_is_correct = correct_case(__name__)

@@ -703,10 +703,11 @@ def test_prefill_keeps_the_parents_program_at_the_sweeps_widths():
 
 # ---------------------------------------------- the cell's window, rehearsed
 
-# `granite4hm-serving-waves` to the end of its window on the CPU, a process of its own
-# (`benchmark/tests/fixed_work_cases.py`); why it is collected in this file:
-# `tests/benchmark_windows.py`.
-from tests.benchmark_windows import window_case  # noqa: E402
+# `granite4hm-serving-waves` rehearsed on the CPU, one process for both cases: to the
+# end of its window (`benchmark/tests/fixed_work_cases.py`) and held to
+# `correct`.  Why they are collected here: `tests/benchmark_windows.py`.
+from tests.benchmark_windows import correct_case, window_case  # noqa: E402
 
 test_the_window_closes_on_the_cells_count_or_on_the_clock = window_case(
     __name__)
+test_cpu_rehearsal_of_the_cell_is_correct = correct_case(__name__)

@@ -23,25 +23,6 @@ from tests.test_grouped_matmul_programs import TOUCHED, _big
 # ------------------------------------- an expert layer compiled for a v5e
 
 
-@pytest.fixture(scope="module")
-def v5e_chip():
-    """A device of a described v5e host to compile for (libtpu is
-    installed here; no chip is attached).  Built inside the fixture, never
-    at import: only the worker that runs this file may load the TPU's
-    library."""
-    import os
-
-    from jax.experimental import topologies
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    try:
-        topo = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 - whatever libtpu raises
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return topo.devices[0]
-
-
 # ------------------------------------------------------ the set-up pins
 # (traced and lowered for the described device: no compile)
 
@@ -61,7 +42,7 @@ def lowering_check():
 
 @pytest.mark.parametrize("name", TOUCHED)
 def test_a_gradient_program_lowers_each_kernel_once_and_shares_the_block(
-        name, v5e_chip, lowering_check, monkeypatch):
+        name, v5e_chips, lowering_check, monkeypatch):
     """What a warm set-up pays on every start is tracing and lowering (the
     compile cache's key is made from the lowered module), so the kernels
     are held to COUNTS of the text lowered for a TPU, not to a clock: a
@@ -84,8 +65,8 @@ def test_a_gradient_program_lowers_each_kernel_once_and_shares_the_block(
     # a row length of its own (the tables are a jitted function of the
     # sizes' shape and the slab's rows alone)
     length = 1920 - 128 * TOUCHED.index(name)
-    _, text = lowering_check.lowered(cfg, 1, length, v5e_chip, None)
-    _, plain = lowering_check.lowered(cfg, 1, length, v5e_chip, False)
+    _, text = lowering_check.lowered(cfg, 1, length, v5e_chips[0], None)
+    _, plain = lowering_check.lowered(cfg, 1, length, v5e_chips[0], False)
     # (none where another test of this process made a slab's of these rows)
     assert len(tables) in (0, 2), tables
     assert "ragged_dot" in plain and "grouped_matmul" not in plain
@@ -111,7 +92,7 @@ def test_a_gradient_program_lowers_each_kernel_once_and_shares_the_block(
 
 @pytest.mark.parametrize("name", TOUCHED)
 def test_an_expert_layer_compiles_for_v5e_with_ragged_dot_in_the_loop_alone(
-        name, v5e_chip, monkeypatch):
+        name, v5e_chips, monkeypatch):
     """Mosaic and XLA:TPU for real: one expert layer of the cell (the
     published widths, 1,024 tokens — an eighth of the cells' micro-batch,
     two slabs still: the compile's seconds follow the tokens, 36 against
@@ -129,7 +110,7 @@ def test_an_expert_layer_compiles_for_v5e_with_ragged_dot_in_the_loop_alone(
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = _big(name)
-    one = SingleDeviceSharding(v5e_chip)
+    one = SingleDeviceSharding(v5e_chips[0])
     tokens = 1024
     assert tfm.expert_slab_rows(
         cfg, tokens * cfg.n_experts_per_tok) < tokens * cfg.n_experts_per_tok

@@ -7,8 +7,6 @@ import from it."""
 
 import re
 
-import pytest
-
 import jax
 import jax.numpy as jnp
 
@@ -22,25 +20,6 @@ from tests.test_grouped_matmul import SHAPES
 # ----------------------------------------- the decode loop compiled for v5e
 
 
-@pytest.fixture(scope="module")
-def v5e_chip():
-    """A device of a described v5e host to compile for (libtpu is
-    installed here; no chip is attached).  Built inside the fixture, never
-    at import: only the worker that runs this file may load the TPU's
-    library."""
-    import os
-
-    from jax.experimental import topologies
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    try:
-        topo = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 - whatever libtpu raises
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return topo.devices[0]
-
-
 def _kernel_scopes(text):
     """The scope `benchmark/program_trace.py` gives each call of the
     kernel in a compiled program's text."""
@@ -51,7 +30,7 @@ def _kernel_scopes(text):
         re.search(r'op_name="([^"]+)"', c).group(1))[0] for c in calls]
 
 
-def test_the_scope_around_the_call_states_the_tile(v5e_chip, monkeypatch):
+def test_the_scope_around_the_call_states_the_tile(v5e_chips, monkeypatch):
     """`.../experts/w384x896/grouped_decode_matmul`: an outer scope names
     the step's tile, the kernel's own name (what `flash_time_share` and
     the ledger's breakdown key on) stays — read as the benchmark's trace
@@ -60,7 +39,7 @@ def test_the_scope_around_the_call_states_the_tile(v5e_chip, monkeypatch):
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     r, t, e, k, n = SHAPES["mellum_up"]
-    one = SingleDeviceSharding(v5e_chip)
+    one = SingleDeviceSharding(v5e_chips[0])
 
     def experts(xs, w, sizes, layer):
         with jax.named_scope("gen/decode_step/layer/mlp/experts"):
@@ -77,7 +56,7 @@ def test_the_scope_around_the_call_states_the_tile(v5e_chip, monkeypatch):
 
 
 def test_mellums_decode_loop_compiles_for_v5e_with_the_leaves_in_place(
-        v5e_chip, monkeypatch):
+        v5e_chips, monkeypatch):
     """Mosaic and XLA:TPU for real, at the cell's size (32 rows, 4,608
     slots, four layers, the published widths): the twelve calls an
     iteration compile at [384, 896] and [896, 2,304] tiles, under scopes
@@ -92,7 +71,7 @@ def test_mellums_decode_loop_compiles_for_v5e_with_the_leaves_in_place(
     big = bench_run.model_config(
         files.load_json("configs", "mellum2-12b-a2.5b-l4-e16.json"))
     b, sp, st = 32, 4096, 4608
-    one = SingleDeviceSharding(v5e_chip)
+    one = SingleDeviceSharding(v5e_chips[0])
 
     def placed(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)

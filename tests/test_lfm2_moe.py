@@ -22,10 +22,6 @@ fp32 bound (1e-4 mean, 1e-3 max on log-probs) must FAIL each control.
 """
 
 import dataclasses
-import json
-import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -737,27 +733,8 @@ def test_the_flash_kernels_at_heads_of_64_match_the_dense_mask():
 # ------------------------------------------- the decode loop compiled for v5e
 
 
-@pytest.fixture(scope="module")
-def v5e_chip():
-    """A device of a described v5e host to compile for (libtpu is
-    installed here; no chip is attached).  Built inside the fixture, never
-    at import: only the worker that runs this file may load the TPU's
-    library."""
-    import os
-
-    from jax.experimental import topologies
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    try:
-        topo = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 - whatever libtpu raises
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return topo.devices[0]
-
-
 def test_the_decode_loop_compiles_for_v5e_with_the_tails_shifted_in_place(
-        v5e_chip, monkeypatch):
+        v5e_chips, monkeypatch):
     """XLA:TPU and Mosaic for real, at the cell's size (32 rows, a
     4,608-slot window, the published widths): a conv layer is a handful of
     fusions — no kernel of its own is wanted — that shift the layer's tail
@@ -775,7 +752,7 @@ def test_the_decode_loop_compiles_for_v5e_with_the_tails_shifted_in_place(
     big = bench_run.model_config(files.load_json("configs", CONFIG))
     assert ragged_tiles_badly(big.hidden_dim, big.moe_intermediate_dim)
     b, sp, st = 32, 4096, 4608
-    one = SingleDeviceSharding(v5e_chip)
+    one = SingleDeviceSharding(v5e_chips[0])
 
     def placed(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)
@@ -822,42 +799,11 @@ def test_the_decode_loop_compiles_for_v5e_with_the_tails_shifted_in_place(
 
 # --------------------------------- the cell, rehearsed on the CPU at toy size
 
-# `lfm2-ctxrl32-4k`, a process of its own each time: to the end of its window
-# (`benchmark/tests/fixed_work_cases.py`), and for a second, held to `correct`
-# (from `tests/test_benchmark_harness.py`, PR 62).  Why both are collected in
-# this file: `tests/benchmark_windows.py`.
-from tests.benchmark_windows import window_case  # noqa: E402
+# `lfm2-ctxrl32-4k` rehearsed on the CPU, one process for both cases: to the
+# end of its window (`benchmark/tests/fixed_work_cases.py`) and held to
+# `correct`.  Why they are collected here: `tests/benchmark_windows.py`.
+from tests.benchmark_windows import correct_case, window_case  # noqa: E402
 
 test_the_window_closes_on_the_cells_count_or_on_the_clock = window_case(
     __name__)
-
-
-def test_cpu_rehearsal_of_the_lfm2_cell_is_correct():
-    """The short-convolution / attention cell end to end at toy size (the
-    config's `toy` group keeps the plan c c A c c c: both leading dense
-    layers and the period whole, heads of 16, 4 of 8 experts): the static
-    program through tails and cache, the hand-back of all 26 leaves, the
-    reference and its check of the generator's own 32-slot program (tails
-    and K/V rows) for generator and trainer."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=files.ROOT)
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmark.run", "--workload", "lfm2-ctxrl32-4k",
-         "--seed", "3000000013", "--seconds", "1", "--trace", "0",
-         "--cpu-rehearsal"],
-        cwd=files.ROOT, env=env, capture_output=True, text=True, timeout=900,
-    )
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    assert proc.stdout.strip() == ""  # platform=cpu: no result line
-    lines = proc.stderr.splitlines()
-    out = json.loads(
-        [l for l in lines if "would print: " in l][-1].split("would print: ")[1])
-    assert out["correct"] is True and out["failed"] == 0
-    assert out["attempted"] in (2 * 32, 3 * 32, 4 * 32)  # whole steps of 32
-    assert {"gen_tokens_per_s", "train_tokens_per_s", "samples_per_s",
-            "setup_s"} <= set(out["metrics"])
-    check = [l for l in lines if "weight check: " in l][-1]
-    assert "'ok': True" in check and "'leaves': 26" in check, check
-    assert any("lfm2_moe reference" in l and "[0, 4) of 8" in l for l in lines)
-    assert any("lfm2_moe generator check" in l and l.endswith(" ok")
-               for l in lines)
+test_cpu_rehearsal_of_the_cell_is_correct = correct_case(__name__)

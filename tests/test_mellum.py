@@ -20,11 +20,7 @@ fp32 bound (1e-4 mean, 1e-3 max on log-probs) must FAIL each control.
 """
 
 import dataclasses
-import json
 import math
-import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -735,42 +731,11 @@ def test_the_static_program_counts_its_rings_and_prefills_in_waves(
 
 # --------------------------------- the cell, rehearsed on the CPU at toy size
 
-# `mellum2-coderl32-4k`, a process of its own each time: to the end of its window
-# (`benchmark/tests/fixed_work_cases.py`), and for a second, held to `correct`
-# (from `tests/test_benchmark_harness.py`, PR 62).  Why both are collected in
-# this file: `tests/benchmark_windows.py`.
-from tests.benchmark_windows import window_case  # noqa: E402
+# `mellum2-coderl32-4k` rehearsed on the CPU, one process for both cases: to the
+# end of its window (`benchmark/tests/fixed_work_cases.py`) and held to
+# `correct`.  Why they are collected here: `tests/benchmark_windows.py`.
+from tests.benchmark_windows import correct_case, window_case  # noqa: E402
 
 test_the_window_closes_on_the_cells_count_or_on_the_clock = window_case(
     __name__)
-
-
-def test_cpu_rehearsal_of_the_mellum_cell_is_correct():
-    """The window / full cell end to end at toy size (the config's `toy`
-    group keeps the periods SSSF SSSF whole: a window of 16 under prompts
-    of 48-256 tokens, heads of 16, 4 of 8 experts): the static program
-    through rings and cache, the hand-back of all 15 leaves, the reference
-    and its check of the generator's own 32-slot program (rings wrapped in
-    prefill and in decode) for generator and trainer."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=files.ROOT)
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmark.run", "--workload", "mellum2-coderl32-4k",
-         "--seed", "3000000013", "--seconds", "1", "--trace", "0",
-         "--cpu-rehearsal"],
-        cwd=files.ROOT, env=env, capture_output=True, text=True, timeout=900,
-    )
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    assert proc.stdout.strip() == ""  # platform=cpu: no result line
-    lines = proc.stderr.splitlines()
-    out = json.loads(
-        [l for l in lines if "would print: " in l][-1].split("would print: ")[1])
-    assert out["correct"] is True and out["failed"] == 0
-    assert out["attempted"] in (2 * 32, 3 * 32, 4 * 32)  # whole steps of 32
-    assert {"gen_tokens_per_s", "train_tokens_per_s", "samples_per_s",
-            "setup_s"} <= set(out["metrics"])
-    check = [l for l in lines if "weight check: " in l][-1]
-    assert "'ok': True" in check and "'leaves': 15" in check, check
-    assert any("mellum reference" in l and "[0, 4) of 8" in l for l in lines)
-    assert any("mellum generator check" in l and l.endswith(" ok")
-               for l in lines)
+test_cpu_rehearsal_of_the_cell_is_correct = correct_case(__name__)

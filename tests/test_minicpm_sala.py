@@ -613,10 +613,11 @@ def test_flops_count_the_selected_keys(cfg):
 
 # ---------------------------------------------- the cell's window, rehearsed
 
-# `sala-docrl8-longctx` to the end of its window on the CPU, a process of its own
-# (`benchmark/tests/fixed_work_cases.py`); why it is collected in this file:
-# `tests/benchmark_windows.py`.
-from tests.benchmark_windows import window_case  # noqa: E402
+# `sala-docrl8-longctx` rehearsed on the CPU, one process for both cases: to the
+# end of its window (`benchmark/tests/fixed_work_cases.py`) and held to
+# `correct`.  Why they are collected here: `tests/benchmark_windows.py`.
+from tests.benchmark_windows import correct_case, window_case  # noqa: E402
 
 test_the_window_closes_on_the_cells_count_or_on_the_clock = window_case(
     __name__)
+test_cpu_rehearsal_of_the_cell_is_correct = correct_case(__name__)

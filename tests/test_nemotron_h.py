@@ -16,9 +16,6 @@ tokens.
 
 import dataclasses
 import json
-import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -818,43 +815,11 @@ def test_prefill_keeps_the_parents_program_at_the_sweeps_widths(monkeypatch):
 
 # --------------------------------- the cell, rehearsed on the CPU at toy size
 
-# `nemo3n-rollout64-512`, a process of its own each time: to the end of its window
-# (`benchmark/tests/fixed_work_cases.py`), and for a second, held to `correct`
-# (from `tests/test_benchmark_harness.py`, PR 62).  Why both are collected in
-# this file: `tests/benchmark_windows.py`.
-from tests.benchmark_windows import window_case  # noqa: E402
+# `nemo3n-rollout64-512` rehearsed on the CPU, one process for both cases: to the
+# end of its window (`benchmark/tests/fixed_work_cases.py`) and held to
+# `correct`.  Why they are collected here: `tests/benchmark_windows.py`.
+from tests.benchmark_windows import correct_case, window_case  # noqa: E402
 
 test_the_window_closes_on_the_cells_count_or_on_the_clock = window_case(
     __name__)
-
-
-def test_cpu_rehearsal_of_the_nemotron_cell_is_correct():
-    """The Mamba cell end to end at toy size (the config's `toy` group keeps
-    the pattern MEMEM*EME whole: 4 heads x 16, state 16, 2 groups, 4 of 8
-    experts): the static program through the three populations of the
-    cache, the hand-back of all 22 leaves with the router's bias unchanged,
-    the reference and its check of the generator's own 64-slot program for
-    generator and trainer."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=files.ROOT)
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmark.run", "--workload", "nemo3n-rollout64-512",
-         "--seed", "3000000013", "--seconds", "1", "--trace", "0",
-         "--cpu-rehearsal"],
-        cwd=files.ROOT, env=env, capture_output=True, text=True, timeout=600,
-    )
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    assert proc.stdout.strip() == ""  # platform=cpu: no result line
-    lines = proc.stderr.splitlines()
-    out = json.loads(
-        [l for l in lines if "would print: " in l][-1].split("would print: ")[1])
-    assert out["correct"] is True and out["failed"] == 0
-    assert out["attempted"] in (2 * 64, 3 * 64, 4 * 64)  # whole steps of 64
-    assert {"gen_tokens_per_s", "train_tokens_per_s", "samples_per_s",
-            "setup_s"} <= set(out["metrics"])
-    check = [l for l in lines if "weight check: " in l][-1]
-    assert "'ok': True" in check and "'leaves': 22" in check, check
-    assert any("nemotron_h reference" in l and "[0, 4) of 8" in l
-               for l in lines)
-    assert any("nemotron_h generator check" in l and l.endswith(" ok")
-               for l in lines)
+test_cpu_rehearsal_of_the_cell_is_correct = correct_case(__name__)

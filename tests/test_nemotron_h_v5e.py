@@ -19,29 +19,10 @@ from tests.test_nemotron_h import CONFIG
 # ------------------------------------------- the decode loop compiled for v5e
 
 
-@pytest.fixture(scope="module")
-def v5e_chip():
-    """A device of a described v5e host to compile for (libtpu is
-    installed here; no chip is attached).  Built inside the fixture, never
-    at import: only the worker that runs this file may load the TPU's
-    library."""
-    import os
-
-    from jax.experimental import topologies
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    try:
-        topo = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 - whatever libtpu raises
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return topo.devices[0]
-
-
 @pytest.mark.parametrize("expert_kernel", [False, True],
                          ids=["ragged_dot", "grouped_decode_matmul"])
 def test_the_decode_loop_compiles_for_v5e_without_a_copy_of_the_state(
-        v5e_chip, monkeypatch, expert_kernel):
+        v5e_chips, monkeypatch, expert_kernel):
     """XLA:TPU for real, at the cell's size (64 rows, a 768-slot window,
     nine layers, the published widths): the loop reads and writes the
     stacked fp32 state AS IT LIES — one fusion a Mamba layer that updates
@@ -59,7 +40,7 @@ def test_the_decode_loop_compiles_for_v5e_without_a_copy_of_the_state(
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     big = bench_run.model_config(files.load_json("configs", CONFIG))
     b, sp, st = 64, 256, 768
-    one = SingleDeviceSharding(v5e_chip)
+    one = SingleDeviceSharding(v5e_chips[0])
 
     def placed(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)

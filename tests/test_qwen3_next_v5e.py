@@ -23,25 +23,6 @@ from benchmark import run as bench_run
 # ------------------------------------------- the decode loop compiled for v5e
 
 
-@pytest.fixture(scope="module")
-def v5e_chips():
-    """The devices of a described v5e host to compile for (libtpu is
-    installed here; no chip is attached).  Built inside the fixture, never
-    at import: only the worker that runs this file may load the TPU's
-    library."""
-    import os
-
-    from jax.experimental import topologies
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    try:
-        topo = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 - whatever libtpu raises
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return topo.devices
-
-
 _Q3NEXT = ("qwen3-next-80b-a3b-l4-e64.json", (3, 32, 128, 128))
 # Heads that are no whole 128-lane tiles (PR 60): the same kernel, a block a
 # head's own [96, 192], the stack in the shape it always had.

@@ -865,10 +865,11 @@ def test_a_cache_that_holds_more_than_kv_lowers_to_the_text_it_lowered_to(
     assert got[program] == _CACHE_KINDS_AT_THE_PARENT[(name, program)]
 
 
-# `sdar-rollout64-512`, a process of its own: to the end of its window
-# (`benchmark/tests/fixed_work_cases.py`).  Why it is collected here:
-# `tests/benchmark_windows.py`.
-from tests.benchmark_windows import window_case  # noqa: E402
+# `sdar-rollout64-512` rehearsed on the CPU, one process for both cases: to the
+# end of its window (`benchmark/tests/fixed_work_cases.py`) and held to
+# `correct`.  Why they are collected here: `tests/benchmark_windows.py`.
+from tests.benchmark_windows import correct_case, window_case  # noqa: E402
 
 test_the_window_closes_on_the_cells_count_or_on_the_clock = window_case(
     __name__)
+test_cpu_rehearsal_of_the_cell_is_correct = correct_case(__name__)

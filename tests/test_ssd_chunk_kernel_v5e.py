@@ -17,25 +17,6 @@ from areal_tpu.models import transformer as tfm
 # ------------------------------------------- compiled for a described v5e
 
 
-@pytest.fixture(scope="module")
-def v5e_chips():
-    """The devices of a described v5e host to compile for (libtpu is
-    installed here; no chip is attached).  Built inside the fixture, never
-    at import: only the worker that runs this file may load the TPU's
-    library."""
-    import os
-
-    from jax.experimental import topologies
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    try:
-        topo = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 - whatever libtpu raises
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return topo.devices
-
-
 @pytest.mark.parametrize("config", [
     "nemotron-3-nano-30b-a3b-l9-e16.json", "granite-4.0-h-micro-l10.json"],
     ids=["nemo3n_eight_groups", "granite_one_group"])

@@ -293,25 +293,6 @@ def test_ssm_ragged_takes_the_kernel_where_it_is_told_to_and_it_fits(
 # ------------------------------------------- compiled for a described v5e
 
 
-@pytest.fixture(scope="module")
-def v5e_chips():
-    """The devices of a described v5e host to compile for (libtpu is
-    installed here; no chip is attached).  Built inside the fixture, never
-    at import: only the worker that runs this file may load the TPU's
-    library."""
-    import os
-
-    from jax.experimental import topologies
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    try:
-        topo = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 - whatever libtpu raises
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return topo.devices
-
-
 def test_the_serving_loop_compiles_for_v5e_with_the_state_stepped_in_place(
         v5e_chips, monkeypatch):
     """Mosaic and XLA:TPU for real, the serving chunk's inner loop at the

@@ -105,7 +105,8 @@ def test_ledger_self_time_is_duration_minus_children():
     o, i, l = spans["outer"], spans["inner"], spans["leaf"]
     assert l[1] == l[2] >= 0.01
     assert i[1] >= 0.04 and abs(i[2] - (i[1] - l[1])) < 1e-6
-    assert abs(o[2] - (o[1] - i[1])) < 1e-6 and 0.01 <= o[2] < 0.02
+    # From below only: a wall clock beside other jobs has no upper bound.
+    assert abs(o[2] - (o[1] - i[1])) < 1e-6 and 0.01 <= o[2]
     # The object the caller holds says the same after the block.
     assert abs(outer.self_ns / 1e9 - o[2]) < 1e-6
 
