@@ -165,7 +165,7 @@ def build(engine, b: int, sp: int, s_total: int, nb: int, g, with_cache):
         is_tail = place < tail_len[:, None]
         all_masked = jnp.full((bsz, blk), mask_id, jnp.int32)
         sums = {
-            name: jnp.zeros((counter.width(cfg),), jnp.float32)
+            name: jnp.zeros((counter.width(cfg, bsz * blk),), jnp.float32)
             for name, counter in counters.items()
         }
         # The first block with its tail masked too: the state its tokens'

@@ -51,7 +51,7 @@ from areal_tpu.engines.offload import HostOffloadMixin
 from areal_tpu.engines.packing import decode_bucket_len as bucket_len
 from areal_tpu.engines.paging import PageAllocator, PagePoolExhausted
 from areal_tpu.models import mamba, transformer as tfm
-from areal_tpu.models.branches import LoopStep
+from areal_tpu.models.branches import CallSums, LoopStep
 from areal_tpu.models.config import ModelConfig
 from areal_tpu.ops.sampling import sample_token
 from areal_tpu.parallel import sharding
@@ -776,13 +776,10 @@ class GeneratorEngine(HostOffloadMixin, Engine):
 
             return self._assemble(sample, prompt_key, prompt_lens, results, n)
 
-    def _zero_decode_sums(self) -> Dict[str, np.ndarray]:
+    def _zero_decode_sums(self) -> Dict[str, CallSums]:
         """The kinds' decode counters of a generate call, summed on the
         device inside the decode loop (`Branch.counter`), by name."""
-        return {
-            name: np.zeros((counter.width(self.cfg),))
-            for name, counter in tfm.decode_counters(self.cfg).items()
-        }
+        return {name: CallSums() for name in tfm.decode_counters(self.cfg)}
 
     def _reset_call_counters(self) -> None:
         """The per-call counters, at the start of a generate call."""
@@ -2711,7 +2708,7 @@ class GeneratorEngine(HostOffloadMixin, Engine):
                 )
 
             sums = {
-                name: jnp.zeros((counter.width(cfg),), jnp.float32)
+                name: jnp.zeros((counter.width(cfg, bsz),), jnp.float32)
                 for name, counter in counters.items()
             }
             state = jax.lax.while_loop(cond, body, (

@@ -15,6 +15,7 @@ import dataclasses
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax.numpy as jnp
+import numpy as np
 
 
 class HybridLayoutError(NotImplementedError):
@@ -56,14 +57,31 @@ class Counter(NamedTuple):
     `step(given, cfg, at)` reduces one decode step — `given`, what the
     kind's layers put out of `decode_step` under `name` ([layers, ...];
     None for a kind that gives nothing), `at` the loop's `LoopStep` — to
-    f32 [width(cfg)], the loop adds them up under `name`, and
-    `report(sums, cfg, params)` turns a generate call's sums into
-    `last_pool_stats` keys."""
+    f32 [width(cfg, at.rows)], the loop adds them up under `name`, and
+    `report(sums, cfg, params)` turns a generate call's sums (`CallSums`)
+    into `last_pool_stats` keys."""
 
     name: str
     width: Callable
     step: Callable
     report: Callable
+
+
+class CallSums(np.ndarray):
+    """A generate call's sums of one `Counter`, on the host.  The call's
+    programs may carry vectors of different widths (`Counter.width` reads
+    the loop's rows): `+=` adds a vector to the slots it has, so a slot
+    that only some programs carry sums over those."""
+
+    def __new__(cls):
+        return np.zeros((0,)).view(cls)
+
+    def __iadd__(self, more):
+        wide = max(self.size, more.size)
+        return (
+            np.pad(self, (0, wide - self.size))
+            + np.pad(more, (0, wide - more.size))
+        ).view(CallSums)
 
 
 class LoopStep(NamedTuple):

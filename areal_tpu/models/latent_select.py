@@ -722,7 +722,7 @@ SELECT_BRANCH = Branch(
     matmul_params=_select_matmul_params,
     attn_flops=_select_flops,
     counter=Counter(
-        LATENT_SELECT, lambda cfg: 4,
+        LATENT_SELECT, lambda cfg, rows: 4,
         lambda given, cfg, at: jnp.concatenate([
             jnp.sum(given.reshape(-1, 3), axis=0), jnp.ones((1,), jnp.float32)
         ]),

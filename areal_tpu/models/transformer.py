@@ -422,10 +422,11 @@ def _moe_route(x: jax.Array, blk: Params, cfg: ModelConfig):
     is index `n_experts` (sorts last, one-hot all zero), so every dispatch
     below computes this rank's part of the sum and nothing for the rest.
     What that costs is the dispatch's: `_experts_dense` and `_experts_topk`
-    pass over every (row, choice) pair, and so does `_experts_grouped` in a
-    decode step; over packed rows and in prefill it touches
+    pass over every (row, choice) pair; `_experts_grouped` touches
     `expert_slab_rows` of them — twice a balanced router's — and the rest
-    only where more than that many are held (its overflow, never a drop)."""
+    only where more than that many are held (its overflow, never a drop):
+    over packed rows and in prefill wherever that is fewer than all, in a
+    decode step where it is half of them or fewer (`decode_slab_rows`)."""
     router_logits = (x.astype(jnp.float32)) @ blk["router"].astype(jnp.float32)  # [T, E]
     if cfg.moe_score_func == "sigmoid":
         return _moe_route_sigmoid(router_logits, blk, cfg)
@@ -575,6 +576,20 @@ def expert_slabs_run(slab: int, pairs: int, held: jax.Array) -> jax.Array:
     return jnp.clip(-(-held // slab), 1, -(-pairs // slab))
 
 
+def decode_slab_rows(cfg: ModelConfig, pairs: int) -> int:
+    """`expert_slab_rows` for a decode step's `pairs`: the slab where it
+    leaves out at least half of them, else all — by the shapes alone.  Under
+    that the saving is a fraction of a share of a step that is latency and
+    not bytes (a token loop: 64 rows x 2 to 10 choices, one 512-row tile),
+    and not worth a loop with a traced bound in the step; a block loop's
+    forward routes 64 x 4 x 8 = 2,048 pairs for the 200 a rank of 16 in
+    128 holds, and XLA's scatter-add walks its update rows one by one."""
+    slab = expert_slab_rows(cfg, pairs)
+    if cfg.moe_dispatch == "grouped" and 2 * slab <= pairs:
+        return slab
+    return pairs
+
+
 def _experts_grouped(
     x, top_w, top_idx, one_hot, blk: Params, cfg: ModelConfig, layer=None,
     kernel: bool = False,
@@ -633,43 +648,50 @@ def _experts_grouped(
 
     One expert-parallel rank's share (`cfg.expert_share`) holds an eighth
     of the pairs, say, and they are the FIRST `sum(group_sizes)` of the
-    stable order (`_local_numbering`).  On the unstacked path (`layer` is
-    None: the gradient program, `forward`, `prefill`) it therefore runs on
-    a slab of the order's first `expert_slab_rows` pairs — twice what a
-    balanced router sends here — and not on all T*k: same rows at the same
-    offsets in the same groups, and what is left out of the scatter-add
-    were exact zeros.  Pairs held past the slab go through the same path a
-    slab at a time, every group's sizes cut to the slab's window, and their
-    sum is added (`_grouped_slabs`): a router that sends this rank more
-    than twice its share costs as many slabs as hold its pairs, never a
-    dropped pair.  The first slab stays OUTSIDE the loop: the benchmark's
-    readers find a program's ragged kernels by the row count of the scoped
-    activation product under `layer/mlp/experts`
-    (`benchmark/metrics/_moe.py`).  A decode step (stacked leaves, a few
-    hundred pairs: latency, not bytes) keeps every pair on the one path.
+    stable order (`_local_numbering`).  It therefore runs on a slab of the
+    order's first `expert_slab_rows` pairs — twice what a balanced router
+    sends here — and not on all T*k: same rows at the same offsets in the
+    same groups, and what is left out of the scatter-add were exact zeros.
+    Pairs held past the slab go through the same path a slab at a time,
+    every group's sizes cut to the slab's window, and their sum is added
+    (`_grouped_slabs`): a router that sends this rank more than twice its
+    share costs as many slabs as hold its pairs, never a dropped pair.  The
+    first slab stays OUTSIDE the loop: the benchmark's readers find a
+    program's ragged kernels by the row count of the scoped activation
+    product under `layer/mlp/experts` (`benchmark/metrics/_moe.py`).  One
+    algorithm on both paths, the slab's size read from the input: the
+    unstacked path (`layer` is None: the gradient program, `forward`,
+    `prefill`) takes the slab wherever it is fewer than all the pairs, a
+    decode step (stacked leaves) where it is half of them or fewer
+    (`decode_slab_rows`: a block loop's 2,048 pairs -> 512; a token loop's
+    few hundred stay on the one path), its first slab on the decode kernel
+    where `kernel` says so.
     """
     with jax.named_scope("dispatch"):
         flat_e = top_idx.reshape(-1)  # [T*k], token-major
         order = jnp.argsort(flat_e, stable=True)
         group_sizes = jnp.sum(one_hot, axis=(0, 1)).astype(jnp.int32)  # [E]
     pairs = order.shape[0]
-    slab = pairs if layer is not None else expert_slab_rows(cfg, pairs)
-    if slab == pairs:  # every expert held, a decode step, a share of half
+    slab = (expert_slab_rows if layer is None else decode_slab_rows)(cfg, pairs)
+    if slab == pairs:  # every expert held, a share of half, a token loop
         return _grouped_rows(
             x, top_w, order, group_sizes, blk, cfg, layer, kernel
         )
     experts = {n: blk[n] for n in _expert_leaves(cfg)}
     return _grouped_slabs(
-        cfg, slab, kernel, x, top_w, experts, order, group_sizes
+        cfg, slab, kernel, x, top_w, experts, order, group_sizes, layer
     )
 
 
-def _slabs(cfg: ModelConfig, slab: int, kernel: bool, order, group_sizes):
+def _slabs(
+    cfg: ModelConfig, slab: int, kernel: bool, order, group_sizes, layer=None
+):
     """The sorted pairs in slabs of `slab` -> (rows(i, x, top_w, experts,
     into): `_grouped_rows` over pairs [i*slab, (i+1)*slab) with every
     group's sizes cut to that window; later(body, first): `body(i, carry)`
     from `first` on over the slabs after the first that hold a held pair,
-    a loop with a traced bound and, as a rule, no trip)."""
+    a loop with a traced bound and, as a rule, no trip).  `layer`: the
+    experts are stacked leaves (a decode step)."""
     with jax.named_scope("dispatch"):
         # Past the last pair: index 0, beyond every group, so zeroed.
         padded = jnp.pad(order, (0, -order.shape[0] % slab))
@@ -691,7 +713,7 @@ def _slabs(cfg: ModelConfig, slab: int, kernel: bool, order, group_sizes):
         # 121 s where this reads + 10 (my chip runs, PR 50, `mellum2-coderl32-
         # 4k`).  A batch that overflows its slab runs XLA's kernel there.
         return _grouped_rows(
-            x, top_w, mine, sizes, experts, cfg,
+            x, top_w, mine, sizes, experts, cfg, layer,
             kernel=kernel and isinstance(i, int), into=into,
         )
 
@@ -707,7 +729,7 @@ def _slabs(cfg: ModelConfig, slab: int, kernel: bool, order, group_sizes):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
 def _grouped_slabs(
     cfg: ModelConfig, slab: int, kernel: bool, x, top_w, experts, order,
-    group_sizes,
+    group_sizes, layer=None,
 ):
     """A share's dispatch: `_grouped_rows` over the first `slab` pairs of
     the order, outside any control flow, plus the sum of the slabs after it
@@ -736,8 +758,10 @@ def _grouped_slabs(
     the loop runs again on the way back, each later slab's forward
     recomputed inside it and its gradients added in place (`kernel`: the
     first slab's expert matmuls are `grouped_matmul`, whose own rule
-    composes under `jax.vjp`; the loop's stay `ragged_dot`: `_slabs`)."""
-    rows, later = _slabs(cfg, slab, kernel, order, group_sizes)
+    composes under `jax.vjp`; the loop's stay `ragged_dot`: `_slabs`).
+    `layer` (a decode step's scan index, `experts` the stacked leaves)
+    rides as an ordinary argument without a gradient."""
+    rows, later = _slabs(cfg, slab, kernel, order, group_sizes, layer)
     return _slab_sum(rows, later, rows(0, x, top_w, experts), x, top_w, experts)
 
 
@@ -747,22 +771,24 @@ def _slab_sum(rows, later, first, x, top_w, experts):
     )
 
 
-def _grouped_slabs_fwd(cfg, slab, kernel, x, top_w, experts, order, group_sizes):
-    rows, later = _slabs(cfg, slab, kernel, order, group_sizes)
+def _grouped_slabs_fwd(
+    cfg, slab, kernel, x, top_w, experts, order, group_sizes, layer=None
+):
+    rows, later = _slabs(cfg, slab, kernel, order, group_sizes, layer)
     first, first_vjp = jax.vjp(functools.partial(rows, 0), x, top_w, experts)
     out = _slab_sum(rows, later, first, x, top_w, experts)
-    return out, (first_vjp, x, top_w, experts, order, group_sizes)
+    return out, (first_vjp, x, top_w, experts, order, group_sizes, layer)
 
 
 def _grouped_slabs_bwd(cfg, slab, kernel, res, ct):
-    first_vjp, x, top_w, experts, order, group_sizes = res
-    rows, later = _slabs(cfg, slab, kernel, order, group_sizes)
+    first_vjp, x, top_w, experts, order, group_sizes, layer = res
+    rows, later = _slabs(cfg, slab, kernel, order, group_sizes, layer)
 
     def add(i, grads):
         more = jax.vjp(functools.partial(rows, i), x, top_w, experts)[1](ct)
         return jax.tree.map(jnp.add, grads, more)
 
-    return (*later(add, first_vjp(ct)), None, None)
+    return (*later(add, first_vjp(ct)), None, None, None)
 
 
 _grouped_slabs.defvjp(_grouped_slabs_fwd, _grouped_slabs_bwd)
@@ -2870,13 +2896,30 @@ def _moe_matmul_params(cfg: ModelConfig):
     return out
 
 
+def _moe_slab(cfg: ModelConfig, rows: int) -> Tuple[int, int]:
+    """(slab, pairs) of a decode step of `rows` tokens: slab < pairs in the
+    programs whose grouped dispatch takes one (`decode_slab_rows`)."""
+    pairs = rows * cfg.n_experts_per_tok
+    return decode_slab_rows(cfg, pairs), pairs
+
+
+def _moe_counter_width(cfg: ModelConfig, rows: int) -> int:
+    slab, pairs = _moe_slab(cfg, rows)
+    return 3 + (2 if cfg.expert_share else 0) + (3 if slab < pairs else 0)
+
+
 def _moe_step_counters(counts: jax.Array, cfg: ModelConfig, at) -> jax.Array:
     """One decode step's rows per expert [L, E] -> f32 [3]: experts with at
     least one row and the fullest expert's rows (both means over layers),
     and 1 for the step.  A rank's share of the experts
     (`cfg.expert_share`) adds two: the rows that reached experts held
     here, and the rows the router sent anywhere (`at.rows` tokens x k
-    choices x L layers)."""
+    choices x L layers).  A program whose dispatch takes a slab
+    (`decode_slab_rows` of the step's pairs) adds three, and no other
+    program carries them: the rows its slabs left out (the pairs less the
+    dispatch's own loop bound, `expert_slabs_run`, times the slab; summed
+    over layers), the fullest layer's held pairs over the slab (past 1.0
+    the overflow ran), and 1 for the step."""
     out = [
         jnp.mean(jnp.sum(counts > 0, axis=-1).astype(jnp.float32)),
         jnp.mean(jnp.max(counts, axis=-1).astype(jnp.float32)),
@@ -2887,6 +2930,15 @@ def _moe_step_counters(counts: jax.Array, cfg: ModelConfig, at) -> jax.Array:
             jnp.sum(counts).astype(jnp.float32),
             jnp.float32(at.rows * cfg.n_experts_per_tok * counts.shape[0]),
         ]
+    slab, pairs = _moe_slab(cfg, at.rows)
+    if slab < pairs:
+        held = jnp.sum(counts, axis=-1)  # [L]: the dispatch's group sizes
+        gathered = jnp.minimum(expert_slabs_run(slab, pairs, held) * slab, pairs)
+        out += [
+            jnp.sum(pairs - gathered).astype(jnp.float32),
+            jnp.max(held).astype(jnp.float32) / slab,
+            jnp.float32(1.0),
+        ]
     return jnp.stack(out)
 
 
@@ -2894,7 +2946,12 @@ def _moe_report(sums, cfg: ModelConfig, params: Params) -> Dict[str, Any]:
     """Per decode step and MoE layer, the experts with at least one row
     and the rows on the fullest expert (means over every step of a
     generate call); and which way the expert weights reached the ragged
-    kernels (1: the parameters' own buffers, 0: the layer scan's slices)."""
+    kernels (1: the parameters' own buffers, 0: the layer scan's slices).
+    Where a program of the call took a slab, the generator-side twins of
+    the trainer's `moe/rows_gathered_share` and `moe/slab_fill_max`: the
+    rows the dispatch gathered (of `moe_rows_routed`; a step without a slab
+    gathers all of its own) and the fullest layer's held pairs over the
+    slab, mean over the steps that took one."""
     touched, rows_max, steps, *share = sums
     if not steps:
         return {}
@@ -2911,6 +2968,10 @@ def _moe_report(sums, cfg: ModelConfig, params: Params) -> Dict[str, Any]:
         # step and layer.  Balanced routing reads n_experts / router_width.
         out.update(
             moe_rows_local=float(share[0]), moe_rows_routed=float(share[1]))
+    if len(share) > 2 and share[4]:
+        out.update(
+            moe_rows_gathered=float(share[1] - share[2]),
+            moe_slab_fill_max=float(share[3] / share[4]))
     return out
 
 
@@ -2969,7 +3030,7 @@ BRANCHES.update({
         matmul_params=_attn_matmul_params,
         attn_flops=_sparse_flops,
         counter=Counter(
-            SPARSE, lambda cfg: 3,
+            SPARSE, lambda cfg, rows: 3,
             lambda given, cfg, at: jnp.sum(given.reshape(-1, 3), axis=0),
             lambda sums, cfg, params: dict(
                 sparse_keys_read=float(sums[0]),
@@ -3041,7 +3102,7 @@ BRANCHES.update({
         attn_flops=_softmax_flops,
         flash_window=lambda cfg: cfg.attn_window,
         counter=Counter(
-            WINDOW, lambda cfg: 2, _window_step_counters,
+            WINDOW, lambda cfg, rows: 2, _window_step_counters,
             lambda sums, cfg, params: dict(
                 window_slots_live=float(sums[0] / max(sums[1], 1.0))),
         ),
@@ -3067,8 +3128,7 @@ BRANCHES.update({
         saved_as="mlp_out",
         matmul_params=_moe_matmul_params,
         counter=Counter(
-            MOE, lambda cfg: 5 if cfg.expert_share else 3,
-            _moe_step_counters, _moe_report),
+            MOE, _moe_counter_width, _moe_step_counters, _moe_report),
     ),
 })
 

@@ -2,7 +2,7 @@
 and its overflow against the full gather, and the gradient program's
 log-probs against the forward-only program's and the plain reference's.
 
-Two checks a benchmark cell cannot make (random weights route near balance,
+Three checks a benchmark cell cannot make (random weights route near balance,
 so no cell trips the overflow; `benchmark/checks.reference_check` reads the
 trainer through its forward-only program), one JSON line each:
 
@@ -13,6 +13,14 @@ trainer through its forward-only program), one JSON line each:
             and one tilted toward the held experts until more than a slab
             of pairs is held here (the overflow runs: forward and every
             gradient within bf16 rounding of the full gather's).
+  decode    the same two routers through a decode step's dispatch —
+            `_experts_grouped(layer=...)` on STACKED leaves of two layers,
+            `--rows` tokens (a block loop's forward: 64 rows x 4) — against
+            every pair through `_grouped_rows` on the same leaves: no trip
+            (bit-equality, or the distance: a bf16 scatter-add of a token's
+            two or three held pairs may sum in another order on the chip)
+            and tilted, both within bf16 rounding; and what the loop's
+            counters would report.
   logprobs  one packed row through `hidden_states` + `per_token_output`
             twice — inside `jax.value_and_grad` under the trainer's remat
             policy, and forward only — and its first sequences through the
@@ -25,6 +33,8 @@ trainer through its forward-only program), one JSON line each:
 
 On the chip through the chip tool, at the cells' sizes:
   python scripts/check_moe_slab.py --config glm-4.7-flash-l7-e8.json --rows 5120
+  python scripts/check_moe_slab.py --config sdar-30b-a3b-chat-l8-e16.json \
+      --rows 256 --checks decode
 On the CPU the same code runs at whatever size fits (tier-1 runs it on a
 toy share, tests/test_moe_share_slab.py); a CPU run says nothing of the
 chip's rounding.  Exit code 1 if a check fails.
@@ -70,6 +80,13 @@ def _layer_leaves(cfg, key, tilt):
     return ks[4], blk
 
 
+def _full_gather(x, top_w, top_idx, one_hot, blk, cfg, layer=None):
+    """The dispatch before the slab: every pair through `_grouped_rows`."""
+    order = jnp.argsort(top_idx.reshape(-1), stable=True)
+    sizes = jnp.sum(one_hot, axis=(0, 1)).astype(jnp.int32)
+    return tfm._grouped_rows(x, top_w, order, sizes, blk, cfg, layer)
+
+
 def dispatch_check(cfg, rows: int, tilt: float, seed: int = 0) -> dict:
     """The slab against the full gather on `rows` tokens of one layer."""
     key, blk = _layer_leaves(cfg, jax.random.PRNGKey(seed), tilt)
@@ -77,11 +94,6 @@ def dispatch_check(cfg, rows: int, tilt: float, seed: int = 0) -> dict:
     x = x.at[:, 0].set(1.0).astype(cfg.dtype)
     cot = jax.random.normal(jax.random.fold_in(key, 1), x.shape).astype(x.dtype)
     experts = {n: blk[n] for n in tfm._expert_leaves(cfg)}
-
-    def full_gather(x, top_w, top_idx, one_hot, blk, cfg):
-        order = jnp.argsort(top_idx.reshape(-1), stable=True)
-        sizes = jnp.sum(one_hot, axis=(0, 1)).astype(jnp.int32)
-        return tfm._grouped_rows(x, top_w, order, sizes, blk, cfg)
 
     @jax.jit
     def both(x, experts):
@@ -94,7 +106,7 @@ def dispatch_check(cfg, rows: int, tilt: float, seed: int = 0) -> dict:
             out, vjp = jax.vjp(f, x, top_w, experts)
             return out, vjp(cot)
 
-        return (through(tfm._experts_grouped), through(full_gather),
+        return (through(tfm._experts_grouped), through(_full_gather),
                 jnp.sum(one_hot))
 
     (new, dnew), (old, dold), held = both(x, experts)
@@ -119,6 +131,52 @@ def dispatch_check(cfg, rows: int, tilt: float, seed: int = 0) -> dict:
         and tripped == (tilt > 0)  # the tilt is there to trip the overflow
         and (tripped or differ == 0)
         and max(report["forward_rel"], dx, dw, *dexp.values()) <= ROUNDING
+        and np.abs(f32(new)).max() > 0
+    )
+    return report
+
+
+def decode_check(cfg, rows: int, tilt: float, seed: int = 0) -> dict:
+    """A decode step's slab against every pair on the one path, on `rows`
+    tokens and the second of two stacked layers."""
+    from areal_tpu.models.branches import LoopStep
+
+    key, blk = _layer_leaves(cfg, jax.random.PRNGKey(seed), tilt)
+    x = jax.random.normal(key, (rows, cfg.hidden_dim), jnp.float32)
+    x = x.at[:, 0].set(1.0).astype(cfg.dtype)
+    stacked = {n: jnp.stack([blk[n][::-1], blk[n]])
+               for n in tfm._expert_leaves(cfg)}
+
+    @jax.jit
+    def both(x, stacked):
+        routed = tfm._moe_route(x, blk, cfg)[:3]
+        args = (x, *routed, stacked, cfg, jnp.int32(1))
+        return (tfm._experts_grouped(*args), _full_gather(*args),
+                jnp.sum(routed[2], axis=(0, 1)).astype(jnp.int32))
+
+    new, old, counts = both(x, stacked)
+    pairs = rows * cfg.n_experts_per_tok
+    slab = tfm.decode_slab_rows(cfg, pairs)
+    f32 = lambda a: np.asarray(a, np.float32)  # noqa: E731
+    report = {
+        "check": "decode", "rows": rows, "pairs": pairs, "slab": slab,
+        "tilt": tilt, "held": int(counts.sum()),
+        "slabs_run": int(tfm.expert_slabs_run(slab, pairs, counts.sum())),
+        "elements_differ": int((f32(new) != f32(old)).sum()),
+        "rel": float(np.abs(f32(new) - f32(old)).max()
+                     / max(np.abs(f32(old)).max(), 1e-30)),
+    }
+    counter = tfm.BRANCHES["moe"].counter
+    report.update(counter.report(np.asarray(counter.step(
+        counts[None], cfg, LoopStep(None, None, None, rows)), np.float64),
+        cfg, {"blocks": stacked}))
+    tripped = report["slabs_run"] > 1
+    report["ok"] = bool(
+        slab < pairs
+        and tripped == (tilt > 0)  # the tilt is there to trip the overflow
+        and report["rel"] <= ROUNDING
+        and report["moe_rows_gathered"] == min(
+            report["slabs_run"] * slab, pairs)
         and np.abs(f32(new)).max() > 0
     )
     return report
@@ -209,15 +267,20 @@ def main():
                    help="tokens in the packed row")
     p.add_argument("--tilt", type=float, default=2.0)
     p.add_argument("--seed", type=int, default=0)
-    p.add_argument("--skip-logprobs", action="store_true")
+    p.add_argument("--checks", default="dispatch,logprobs",
+                   help="of dispatch, decode, logprobs")
     args = p.parse_args()
+    checks = set(args.checks.split(","))
     ok = True
     for name in args.config:
         config = files.load_json("configs", name)
         cfg = brun.model_config(config)
-        reports = [dispatch_check(cfg, args.rows, 0.0, args.seed),
-                   dispatch_check(cfg, args.rows, args.tilt, args.seed)]
-        if not args.skip_logprobs:
+        reports = [
+            check(cfg, args.rows, tilt, args.seed)
+            for which, check in (("dispatch", dispatch_check),
+                                 ("decode", decode_check))
+            if which in checks for tilt in (0.0, args.tilt)]
+        if "logprobs" in checks:
             ref = files.load_module(
                 "references", config["benchmark"]["reference"])
             lens = [args.rows // 5] * 4 + [args.rows // 10]  # 90% packed

@@ -82,6 +82,25 @@ def _primitives(jaxpr) -> set:
     return names
 
 
+def _shapes(jaxpr, into=None):
+    """{primitive: result shapes} outside and inside every sub-jaxpr but a
+    loop's body."""
+    into = {} if into is None else into
+    for e in jaxpr.eqns:
+        into.setdefault(e.primitive.name, []).extend(
+            tuple(v.aval.shape) for v in e.outvars)
+        if e.primitive.name != "while":
+            for sub in jax.core.jaxprs_in_params(e.params):
+                _shapes(sub, into)
+    return into
+
+
+def _wide(by_op, rows):
+    """The [rows, >= F] results among `_shapes`: a dispatch's buffers."""
+    return [s for ss in by_op.values() for s in ss
+            if len(s) == 2 and s[0] == rows and s[1] >= F]
+
+
 def test_the_slab_is_twice_a_balanced_routers_rows_in_whole_tiles():
     cfg = _cfg()
     assert tfm.expert_slab_rows(cfg, T * K) == 512
@@ -226,27 +245,12 @@ def test_the_first_slab_is_outside_the_loop_and_the_loop_saves_nothing():
     def loss(h, blk):
         return jnp.sum(tfm._mlp_moe(h, blk, cfg)[0])
 
-    def shapes(jaxpr, into=None):
-        """{primitive: result shapes} outside and inside every sub-jaxpr
-        but a loop's body."""
-        into = {} if into is None else into
-        for e in jaxpr.eqns:
-            into.setdefault(e.primitive.name, []).extend(
-                tuple(v.aval.shape) for v in e.outvars)
-            if e.primitive.name != "while":
-                for sub in jax.core.jaxprs_in_params(e.params):
-                    shapes(sub, into)
-        return into
-
-    wide = lambda by_op, rows: [  # noqa: E731
-        s for ss in by_op.values() for s in ss
-        if len(s) == 2 and s[0] == rows and s[1] >= F]
-    plain = shapes(jax.make_jaxpr(loss)(h, blk).jaxpr)
+    plain = _shapes(jax.make_jaxpr(loss)(h, blk).jaxpr)
     assert "cond" not in plain and len(plain["while"]) > 0
-    assert wide(plain, slab) and not wide(plain, T * K)
-    grad = shapes(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(h, blk).jaxpr)
+    assert _wide(plain, slab) and not _wide(plain, T * K)
+    grad = _shapes(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(h, blk).jaxpr)
     assert "cond" not in grad and len(grad["while"]) > len(plain["while"])
-    assert wide(grad, slab) and not wide(grad, T * K)
+    assert _wide(grad, slab) and not _wide(grad, T * K)
     # What the gradient's forward pass keeps: one slab's activations.
     _, vjp = jax.vjp(loss, h, blk)
     kept = [tuple(x.shape) for x in jax.tree.leaves(vjp)]
@@ -273,20 +277,119 @@ def test_a_model_without_a_slab_lowers_as_before(case):
     assert not any(n.startswith("custom_vjp") for n in names), names
 
 
-def test_a_decode_step_of_a_share_has_no_slab():
-    """Stacked leaves (`layer` given): 2 x 320 pairs would round to a slab
-    of 512, and the decode program keeps every pair on the one path."""
-    cfg = _cfg()
-    h, blk = _layer(cfg, 0.0)
-    stacked = {n: blk[n][None] for n in tfm._expert_leaves(cfg)}
-    x = h[:, :2560]
-    jaxpr = jax.make_jaxpr(
-        lambda x: tfm._mlp_moe(x, blk, cfg, stacked=stacked, layer=0)[0]
-    )(x).jaxpr
-    assert tfm.expert_slab_rows(cfg, x.shape[1] * K) < x.shape[1] * K
-    assert not {"cond", "while"} & _primitives(jaxpr)
-    got = tfm._mlp_moe(x, blk, cfg, stacked=stacked, layer=0)[0]
-    np.testing.assert_allclose(got, tfm._mlp_moe(x, blk, cfg)[0], **TOL)
+# A decode step (stacked leaves): (held, router width, choices, tokens,
+# tilt, kernel) -> the pairs and what `decode_slab_rows` makes of them.
+DECODE_STEPS = {
+    "640_pairs_of_64_in_512": (64, 512, 10, 64, 0.0, False),  # a twin's
+    "256_pairs_of_8_in_64": (8, 64, 4, 64, 0.0, False),  # under one tile
+    "2048_pairs_of_16_in_128": (16, 128, 8, 256, 0.0, False),  # a block's
+    "2048_pairs_tilted": (16, 128, 8, 256, 30.0, False),
+    "2048_pairs_on_the_kernel": (16, 128, 8, 256, 0.0, True),
+    "2048_pairs_tilted_on_the_kernel": (16, 128, 8, 256, 30.0, True),
+}
+
+
+@pytest.mark.parametrize("case", list(DECODE_STEPS))
+def test_a_decode_step_takes_the_slab_that_leaves_out_half(case, monkeypatch):
+    """Stacked leaves (`layer` given): the step takes `expert_slab_rows`
+    where that is half its pairs or fewer, by the shapes alone.  A token
+    loop's pairs — 640 would round to a slab of 512, 256 are under one tile
+    — keep every pair on the one path: no loop, the program the rule was
+    not there for.  A block loop's 2,048 of a rank that holds 16 of 128
+    gather 512: a router as initialised gives the full gather's result bit
+    for bit, one tilted toward the held experts runs the overflow to the
+    same result, never a dropped pair, and the loop's counters say what was
+    gathered.  `kernel`: the first slab's matmuls are
+    `grouped_decode_matmul` (interpreted), the later slabs' `ragged_dot`."""
+    from areal_tpu.models.branches import LoopStep
+
+    held, width, k, tokens, tilt, kernel = DECODE_STEPS[case]
+    cfg = _cfg(n_experts=held, n_router_experts=width, expert_offset=held,
+               n_experts_per_tok=k)
+    h, blk = _layer(cfg, tilt)
+    x, pairs = h[:, :tokens], tokens * k
+    stacked = {n: jnp.stack([0.5 * blk[n], blk[n]])
+               for n in tfm._expert_leaves(cfg)}
+
+    def step(x):
+        return tfm._mlp_moe(
+            x, blk, cfg, stacked=stacked, layer=1, kernel=kernel)
+
+    slab = tfm.decode_slab_rows(cfg, pairs)
+    jaxpr = jax.make_jaxpr(step)(x)
+    with monkeypatch.context() as m:  # every pair on the one path
+        m.setattr(tfm, "decode_slab_rows", lambda cfg, pairs: pairs)
+        one_path = jax.make_jaxpr(step)(x)
+        with jax.default_matmul_precision("highest"):
+            want, _, _ = jax.jit(step)(x)
+    counter = tfm.BRANCHES["moe"].counter
+    if pairs < 2048:
+        assert tfm.expert_slab_rows(cfg, pairs) in (pairs, 512)
+        assert slab == pairs
+        assert str(jaxpr) == str(one_path)
+        assert not {"cond", "while"} & _primitives(jaxpr.jaxpr)
+        assert counter.width(cfg, tokens) == 5
+        np.testing.assert_allclose(want, tfm._mlp_moe(x, blk, cfg)[0], **TOL)
+        return
+    assert slab == 512 == tfm.expert_slab_rows(cfg, pairs)
+    names = _primitives(jaxpr.jaxpr)
+    # (a `cond` is the interpreted kernel's own `pl.when`)
+    assert "while" in names and (kernel or "cond" not in names)
+    assert ("pallas_call" in names) == kernel
+    outside = _shapes(jaxpr.jaxpr)  # the first slab's buffers: 512 rows
+    assert _wide(outside, 512) and not _wide(outside, pairs)
+    with jax.default_matmul_precision("highest"):
+        got, _, counts = jax.jit(step)(x)
+    held = int(counts.sum())
+    if tilt:  # every choice held here: four slabs
+        assert held == pairs
+        np.testing.assert_allclose(got, want, **TOL)
+    else:  # an eighth of the pairs, give or take: no trip
+        assert pairs // 16 < held < 512
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert float(jnp.abs(got).max()) > 1e-3
+    at = LoopStep(None, None, None, tokens)
+    assert counter.width(cfg, tokens) == 8
+    layers = jnp.stack([counts, counts])  # as the layer scan stacks them
+    report = counter.report(
+        2 * np.asarray(counter.step(layers, cfg, at)), cfg,
+        {"blocks": stacked})
+    assert report["moe_rows_routed"] == 2 * 2 * pairs
+    assert report["moe_rows_local"] == 2 * 2 * held
+    assert report["moe_slab_fill_max"] == held / 512
+    assert report["moe_rows_gathered"] == 2 * 2 * (pairs if tilt else 512)
+
+
+def test_a_call_of_programs_with_and_without_a_slab_adds_up():
+    """A generate call's chunks may differ in rows: one program's steps
+    take a slab and carry the three sums of it, another's do not.  The
+    host's `CallSums` adds each vector to the slots it has, and the report
+    counts a step without a slab as gathering every pair of its own."""
+    from areal_tpu.models.branches import CallSums, LoopStep
+
+    cfg = _cfg(n_experts=16, n_router_experts=128, expert_offset=16,
+               n_experts_per_tok=8)
+    counter = tfm.BRANCHES["moe"].counter
+    params = {"blocks": dict.fromkeys(tfm._expert_leaves(cfg), np.zeros(()))}
+    counts = jnp.full((2, 16), 10, jnp.int32)  # 160 pairs held a layer
+    sums = CallSums()
+    for tokens in (64, 256, 64):  # 512 pairs, 2,048, 512
+        step = np.asarray(counter.step(
+            counts, cfg, LoopStep(None, None, None, tokens)), float)
+        assert step.size == counter.width(cfg, tokens)
+        sums += step
+    assert isinstance(sums, CallSums) and sums.size == 8
+    report = counter.report(sums, cfg, params)
+    assert report["moe_decode_steps"] == 3
+    assert report["moe_rows_routed"] == 2 * (512 + 2048 + 512)
+    assert report["moe_rows_gathered"] == 2 * (512 + 512 + 512)
+    assert report["moe_slab_fill_max"] == 160 / 512  # of the one step's
+    # ... and a call without a slab anywhere reports neither
+    plain = CallSums()
+    plain += np.asarray(counter.step(
+        counts, cfg, LoopStep(None, None, None, 64)), float)
+    assert not {"moe_rows_gathered", "moe_slab_fill_max"} & set(
+        counter.report(plain, cfg, params))
 
 
 def test_pads_are_left_out_of_a_slab():
@@ -379,6 +482,12 @@ def test_the_chip_probe_runs_at_a_toy_size(score):
     assert flat["forward_elements_differ"] == 0
     assert tilted["ok"] and tilted["slabs_run"] > 1, tilted
     assert not probe.dispatch_check(cfg, T, 0.0, seed=1)["slabs_run"] > 1
+    # ... and a decode step's: stacked leaves, the loop's counters beside
+    step, tilted = (probe.decode_check(cfg, T, t) for t in (0.0, 4.0))
+    assert step["ok"] and step["slabs_run"] == 1 and not step["elements_differ"]
+    assert step["moe_rows_gathered"] == 512 and step["moe_slab_fill_max"] < 1
+    assert tilted["ok"] and tilted["moe_slab_fill_max"] > 1, tilted
+    assert tilted["moe_rows_gathered"] == 512 * tilted["slabs_run"] > 512
 
     oracle = dataclasses.replace(cfg, moe_dispatch="dense")
 

@@ -424,6 +424,48 @@ def test_the_block_loop_matches_the_reference(cfg, params, steps):
         8 * ((steps + 1) * n_blocks + 1))
 
 
+@pytest.mark.parametrize("loop", ["block", "token"])
+def test_the_block_loop_of_a_thin_share_gathers_a_slab_and_a_token_loop_none(
+        loop):
+    """A rank that holds 1 of its router's 8 experts, 128 rows: a block
+    forward routes 128 x 4 x 2 = 1,024 pairs and `decode_slab_rows` reads
+    512, so `block_step` (through `_walk`, stacked leaves) gathers one slab
+    a layer and forward, says so in `last_pool_stats`, and its kept tokens'
+    log-probs hold to the clean forward's as `check_generator` asks.  The
+    token loop of the same share routes 256 pairs a step: no slab, and the
+    counter vector its program carries has the shape it had."""
+    cfg = _cfg(held=1)
+    if loop == "token":
+        cfg = dataclasses.replace(cfg, block_length=0, mask_token_id=-1)
+    params = tfm.init_params(cfg, jax.random.PRNGKey(0))
+    eng = _engine(cfg, params, slots=128)
+    assert eng._expert_leaves_in_place
+    prompts = _seqs([5 + r % 8 for r in range(128)], seed=7)
+    toks, logps, gen_len = eng.static_rollout(
+        prompts, GenerationHyperparameters(n=1, max_new_tokens=6),
+        jax.random.PRNGKey(9))
+    stats = eng.last_pool_stats
+    rows = 128 * (B if loop == "block" else 1)
+    steps, pairs = stats["moe_decode_steps"], rows * cfg.n_experts_per_tok
+    assert stats["moe_rows_routed"] == steps * cfg.n_layers * pairs
+    assert 0 < stats["moe_rows_local"] < stats["moe_rows_routed"] / 4
+    width = tfm.decode_counters(cfg)["moe"].width(cfg, rows)
+    if loop == "token":
+        assert tfm.decode_slab_rows(cfg, pairs) == pairs == 256
+        assert width == 5 == eng._decode_sums["moe"].size
+        assert not {"moe_rows_gathered", "moe_slab_fill_max"} & set(stats)
+        return
+    assert tfm.decode_slab_rows(cfg, pairs) == 512 and width == 8
+    assert stats["bd/blocks"] == 3 and steps == 1 + 3 * 3
+    assert stats["moe_rows_gathered"] == steps * cfg.n_layers * 512
+    assert 0 < stats["moe_slab_fill_max"] < 1
+    for r in (0, 3, 77, 127):
+        seq = np.concatenate([prompts[r], toks[r, :6]])
+        want = reference.block_logprobs(params, cfg, seq)[len(prompts[r]):]
+        d = np.abs(want - logps[r, :6])
+        assert d.mean() <= FP32["mean_abs"] and d.max() <= FP32["max_abs"]
+
+
 def test_a_prefix_through_the_cache_gives_the_full_forward_s_logits(
         cfg, params):
     """prefill of two blocks + one block step of the clean third block ==
